@@ -39,8 +39,8 @@ def peak_flops_per_chip():
 def measure_trials(run_once, n_trials=None):
     """Robust wall-clock measurement shared by all benchmarks: time
     ``n_trials`` calls of ``run_once`` (default from PADDLE_TPU_BENCH_TRIALS,
-    5); when the spread exceeds 3x (a transient hit the shared chip), run
-    one more round and merge before taking the median.  ``run_once`` must
+    5); when the spread exceeds 3x (a transient on the host), run one
+    more round and merge before taking the median.  ``run_once`` must
     block until device completion.  Returns (median_seconds, all_trials).
     """
     import os
@@ -89,8 +89,10 @@ def main():
     if prec:
         jax.config.update("jax_default_matmul_precision", prec)
     import paddle_tpu as fluid
+    from paddle_tpu.executor import enable_compile_cache
     from paddle_tpu.models import transformer as T
 
+    enable_compile_cache(entry_point=True)
     on_tpu = any(d.platform != "cpu" for d in jax.devices())
     hp = T.ModelHyperParams()
     if on_tpu:
@@ -175,10 +177,9 @@ def main():
         for _ in range(warmup_calls):
             exe.run_steps(main_prog, feed=stacked,
                           fetch_list=[avg_cost.name], steps=steps)
-        # Robustness: a single-trial measurement on a shared chip can be
-        # poisoned by transient contention (a 19x-slow wall clock was
-        # observed once with bit-identical numerics).  Run several trials
-        # and report the median; print per-trial stats to stderr.
+        # Robustness: a single-trial wall clock can be poisoned by a
+        # transient on the host.  Run several trials and report the
+        # median; print per-trial stats to stderr.
         last_losses = [None]
 
         def run_once():

@@ -7,9 +7,8 @@ causal, B*S = 64k tokens, H=8, D=64 (transformer-base head shape).
 
 Methodology (r4): DEVICE time per iteration, read from an xplane trace
 of one jitted ``lax.scan`` of ITERS grad steps under ``jax.named_scope``
-(``profiler.measure_device_seconds``) — tenant-proof on the shared chip
-and free of the ~2.7 ms dispatch / ~100 ms sync wall-clock latencies
-that inflated the r2/r3 absolute numbers (ratios were unaffected).
+(``profiler.measure_device_seconds``) — scope-attributed, so free of
+the host's dispatch and sync wall-clock latencies.
 
 Writes ``BENCH_ATTENTION.md`` (the checked-in artifact the default
 ``PADDLE_TPU_FLASH_MIN_S`` cites) and prints one JSON line per S.
@@ -54,8 +53,8 @@ def time_path(use_pallas, S, B):
     def many(q, k, v):
         def body(qq, _):
             # the carry dependency (qq + 0*g) chains the iterations so
-            # XLA cannot elide them; the scope makes the device-time
-            # read tenant-proof on the shared chip
+            # XLA cannot elide them; the scope attributes the
+            # device-time read to THIS computation's events only
             with jax.named_scope(scope):
                 g = grad(qq, k, v)
             return qq + 0.0 * g[0], g[0][0, 0, 0, 0]

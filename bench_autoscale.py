@@ -223,16 +223,19 @@ def run_bench(duration=8.0, service_ms=40.0, base_rps=6.0,
               seed=7, model_dir=None, max_replicas=3, standby_pool=2):
     """Fixed-1 vs controller fleet under the same seeded 5× step, then
     the mid-ramp kill drill on the controller fleet; returns the
-    JSON-ready summary.  ``PADDLE_TPU_COMPILE_CACHE`` is pointed at a
-    shared temp dir for the whole run, so the fixed pass populates the
-    cache and every standby warm afterwards must HIT it."""
+    JSON-ready summary.  The persistent compile cache is on for the whole
+    run at the dir ``executor.resolve_compile_cache_dir`` gives an entry
+    point (a fixed path — it is part of the cache key), so the fixed pass
+    populates it at the latest and every standby warm afterwards must
+    HIT it."""
     own = model_dir is None
     if own:
         model_dir = build_model(
             tempfile.mkdtemp(prefix="ptauto_") + "/model")
+    from paddle_tpu.executor import resolve_compile_cache_dir
     prev_cache = os.environ.get("PADDLE_TPU_COMPILE_CACHE")
     os.environ["PADDLE_TPU_COMPILE_CACHE"] = \
-        tempfile.mkdtemp(prefix="ptauto_cache_")
+        resolve_compile_cache_dir(entry_point=True)
     try:
         kw = dict(duration=duration, service_ms=service_ms,
                   base_rps=base_rps, peak_rps=peak_rps,

@@ -43,9 +43,8 @@ with fluid.scope_guard(scope):
     _, rows = profiler.compiled_op_table(td)
     import shutil
     shutil.rmtree(td, ignore_errors=True)
-    # NOTE: whole-plane busy time is meaningless on the shared chip (the
-    # tracer records other tenants too — exp_probe_trace.py); the
-    # scope-attributed table below is the trustworthy signal
+    # NOTE: whole-plane busy time counts every program the process ran
+    # in the window; the scope-attributed table below is this step's
     total = sum(r[2] for r in rows)
     print(f"attributed: {total * 1e3 / STEPS:.1f} ms/step")
     for op, calls, sec in rows[:18]:

@@ -128,7 +128,6 @@ def make_step(nhwc):
     @jax.jit
     def step(params, x, labels):
         # named_scope: device-time reads match THIS program's events only
-        # (the shared chip's tracer also records other tenants)
         with jax.named_scope("resnet_train_step"):
             l, g = jax.value_and_grad(loss_fn)(params, x, labels, nhwc)
             new = jax.tree_util.tree_map(lambda p, gr: p - 0.1 * gr,

@@ -1,9 +1,9 @@
-"""Transformer-base MFU ceiling artifact (r5) — the ResNet-style rigor
-(BENCH_RESNET_CEILING.md) applied to the flagship bench model.
+"""Transformer-base MFU ceiling study (r5): the flagship bench model
+against a pure-JAX bound of the same step.
 
-Two measurements, both tenant-proof DEVICE time (xplane named scopes;
-wall clocks on this backend carry dispatch/sync latency and foreign
-tenants — see profiler.measure_device_seconds):
+Two measurements, both scope-attributed DEVICE time (xplane named
+scopes; wall clocks carry the host's dispatch/sync latency — see
+profiler.measure_device_seconds):
 
   part A (``ours``):    per-IR-op decomposition of the framework's
                         Transformer-base training step (B=256, S=256,
@@ -73,7 +73,7 @@ def run_ours():
                       fetch_list=[avg_cost.name], steps=STEPS)
         jax.profiler.stop_trace()
 
-    # tenant-proof total: every event inside one of OUR ptop_ scopes
+    # scope-attributed total: every event inside one of OUR ptop_ scopes
     total_s = profiler.scope_device_seconds(td, "ptop_")
     _, rows = profiler.compiled_op_table(td)
     import shutil

@@ -119,6 +119,22 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                 grads_of[out_name] = [tmp]
 
         grad_descs, input_grad_map = maker(op, block, no_grad)
+        # grad var name -> the forward input it is the gradient of.  The
+        # slot convention (<slot>@GRAD output beside a <slot> input)
+        # finds it for default-made descs; a custom maker that omits the
+        # forward input (dropout_grad needs only the mask) or reuses a
+        # forward op type (cast's grad is a cast, output slot "Out")
+        # declares it through its input_grad_map instead
+        fwd_of_grad = {g: f for f, g in input_grad_map.items()
+                       if f in op.input_arg_names}
+
+        def fwd_name_of(desc, slot, i, gname):
+            if slot.endswith(GRAD_SUFFIX):
+                fwd_names = desc["inputs"].get(slot[:-len(GRAD_SUFFIX)], [])
+                if i < len(fwd_names):
+                    return fwd_names[i]
+            return fwd_of_grad.get(gname)
+
         for desc in grad_descs:
             # rewire grad-op inputs: slot S@GRAD names are canonical
             # grad_var_name()s; replace with the actual available grad vars
@@ -141,26 +157,29 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                             actual.append("")  # missing grad -> zeros
                     actual_inputs[slot] = actual
                 else:
-                    actual_inputs[slot] = names
+                    # a maker that reuses a forward op type feeds the
+                    # output grad through a plain slot (cast's "X"):
+                    # canonical grad names there follow the same rewiring
+                    actual_inputs[slot] = [
+                        grads_of[n[:-len(GRAD_SUFFIX)]][0]
+                        if n.endswith(GRAD_SUFFIX)
+                        and n[:-len(GRAD_SUFFIX)] in grads_of else n
+                        for n in names]
             # rename grad outputs that would collide with an existing
             # pending contribution (reference _addup_repetitive_outputs_:
             # a var read by N ops receives N distinct grad names, summed
             # at consumption time)
             actual_outputs = {}
+            produced = []   # (grad var as emitted, forward var)
             for slot, names in desc["outputs"].items():
-                if not slot.endswith(GRAD_SUFFIX):
-                    actual_outputs[slot] = list(names)
-                    continue
-                in_slot = slot[:-len(GRAD_SUFFIX)]
-                fwd_names = desc["inputs"].get(in_slot, [])
                 renamed = []
                 for i, gname in enumerate(names):
-                    if not gname:
-                        renamed.append(gname)
-                        continue
-                    fwd_name = fwd_names[i] if i < len(fwd_names) else None
-                    if fwd_name is not None and grads_of.get(fwd_name):
-                        gname = unique_name(gname + "@RENAME")
+                    fwd_name = fwd_name_of(desc, slot, i, gname) \
+                        if gname else None
+                    if fwd_name is not None:
+                        if grads_of.get(fwd_name):
+                            gname = unique_name(gname + "@RENAME")
+                        produced.append((gname, fwd_name))
                     renamed.append(gname)
                 actual_outputs[slot] = renamed
             gop = block.append_op(type=desc["type"], inputs=actual_inputs,
@@ -170,22 +189,18 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                 for cb in callbacks:
                     cb(block, gop)
             # declare grad output vars + record availability
-            for slot, names in actual_outputs.items():
-                if not slot.endswith(GRAD_SUFFIX):
-                    continue
-                in_slot = slot[:-len(GRAD_SUFFIX)]
-                fwd_names = desc["inputs"].get(in_slot, [])
-                for i, gname in enumerate(names):
-                    if not gname:
-                        continue
-                    fwd_name = fwd_names[i] if i < len(fwd_names) else None
-                    if fwd_name is not None:
-                        fv = block.var(fwd_name)
-                        nv = block.create_var(name=gname, shape=fv.shape,
-                                              dtype=fv.dtype)
-                        nv.stop_gradient = True
-                        if gname not in grads_of[fwd_name]:
-                            grads_of[fwd_name].append(gname)
+            for gname, fwd_name in produced:
+                fv = block.var(fwd_name)
+                nv = block.create_var(name=gname, shape=fv.shape,
+                                      dtype=fv.dtype)
+                if nv.shape is None:
+                    # append_op declared the output before us, shapeless
+                    # (grad ops have no infer_shape): a gradient has its
+                    # forward var's shape
+                    nv.shape, nv.dtype = fv.shape, fv.dtype
+                nv.stop_gradient = True
+                if gname not in grads_of[fwd_name]:
+                    grads_of[fwd_name].append(gname)
 
     # final dedup: leaf vars (params, feeds) have no producing op on the
     # path, so their pending contributions were never summed — sum them

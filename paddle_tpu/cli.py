@@ -138,8 +138,8 @@ def _cmd_train(args):
     The script sees PADDLE_NUM_PASSES etc. like the reference's gflags."""
     if args.num_passes is not None:
         os.environ["PADDLE_NUM_PASSES"] = str(args.num_passes)
-    if args.use_tpu is not None:
-        os.environ["PADDLE_TPU_USE_TPU"] = str(int(args.use_tpu))
+    from paddle_tpu.executor import enable_compile_cache
+    enable_compile_cache(entry_point=True)
     if args.checkpoint_dir is not None:
         # consumed by fault.manager_from_env() in training scripts
         # (the paddle_trainer --save_dir analog)
@@ -205,10 +205,10 @@ def _cmd_serve(args):
     """HTTP inference server over a saved model (L6 serving runtime).
     With --master the replica enrolls in the serving fleet: register on
     readiness, heartbeat-renew the lease, drain cleanly on SIGTERM."""
+    from paddle_tpu.executor import enable_compile_cache
     from paddle_tpu.serving import serve
-    if args.compile_cache:
-        # before the predictor's Executor exists, so its compiles persist
-        os.environ["PADDLE_TPU_COMPILE_CACHE"] = args.compile_cache
+    # before the predictor's Executor exists, so its compiles persist
+    enable_compile_cache(args.compile_cache, entry_point=True)
     warmup_sizes = None
     if args.warmup_batch_sizes:
         warmup_sizes = [int(s) for s in args.warmup_batch_sizes.split(",")]
@@ -310,9 +310,9 @@ def _cmd_controller(args):
     from paddle_tpu.fault import GracefulShutdown
     from paddle_tpu.fleet import FleetController, FleetReplica, \
         FleetRouter
-    if args.compile_cache:
-        # before any standby's Executor exists, so warms hit the cache
-        os.environ["PADDLE_TPU_COMPILE_CACHE"] = args.compile_cache
+    from paddle_tpu.executor import enable_compile_cache
+    # before any standby's Executor exists, so warms hit the cache
+    enable_compile_cache(args.compile_cache, entry_point=True)
     router = FleetRouter(master_addr=args.master,
                          host=args.host, port=args.port,
                          default_deadline=args.default_deadline,
@@ -1286,7 +1286,6 @@ def main(argv=None):
     p = sub.add_parser("train", help="run a training script")
     p.add_argument("--config", required=True, help="python training script")
     p.add_argument("--num-passes", type=int, default=None)
-    p.add_argument("--use-tpu", type=int, default=None)
     p.add_argument("--checkpoint-dir", default=None,
                    help="export PADDLE_TPU_CKPT_DIR for the script's "
                         "fault.CheckpointManager")
@@ -1347,8 +1346,10 @@ def main(argv=None):
                         "(default: the batcher's bucket edges)")
     p.add_argument("--compile-cache", default=None,
                    help="persistent XLA compilation cache dir "
-                        "(PADDLE_TPU_COMPILE_CACHE): restarts reuse "
-                        "compiled executables instead of recompiling")
+                        "(PADDLE_TPU_COMPILE_CACHE; default "
+                        "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                        "wins over both): restarts reuse compiled "
+                        "executables instead of recompiling")
     p.add_argument("--master", default=None,
                    help="HOST:PORT of the fleet master: register this "
                         "replica for discovery and heartbeat-renew its "
@@ -1450,9 +1451,11 @@ def main(argv=None):
                    help="fleet lease TTL seconds for promoted standbys")
     p.add_argument("--compile-cache", default=None,
                    help="persistent XLA compilation cache dir "
-                        "(PADDLE_TPU_COMPILE_CACHE): standby warms "
-                        "reuse compiled executables — scale-up is a "
-                        "lease registration, not a compile")
+                        "(PADDLE_TPU_COMPILE_CACHE; default "
+                        "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR "
+                        "wins over both): standby warms reuse compiled "
+                        "executables — scale-up is a lease "
+                        "registration, not a compile")
     p.set_defaults(fn=_cmd_controller)
 
     p = sub.add_parser("stats", help="fetch a serving replica's /stats "

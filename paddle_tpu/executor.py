@@ -32,6 +32,7 @@ from paddle_tpu.scope import Scope, global_scope
 from paddle_tpu.ops import registry
 
 __all__ = ["Executor", "fetch_var", "enable_compile_cache",
+           "resolve_compile_cache_dir",
            "disable_compile_cache", "jit_cache_capacity"]
 
 logger = logging.getLogger(__name__)
@@ -112,43 +113,73 @@ def _as_device_array(value, dtype=None, device=None):
 
 
 # ---------------------------------------------------------------------------
-# persistent XLA compilation cache (PADDLE_TPU_COMPILE_CACHE): a restart
-# no longer recompiles every program from scratch — XLA executables are
-# stored under the cache dir keyed by the lowered module, and a second
-# process (or a second Executor re-tracing an identical program) loads
-# them instead of invoking the backend compiler.  Hit/miss counters land
-# in profiler.runtime_metrics (compile_cache.hits / .misses).
+# persistent XLA compilation cache: a restart no longer recompiles every
+# program from scratch — XLA executables are stored under the cache dir
+# keyed by the lowered module, and a second process (or a second Executor
+# re-tracing an identical program) loads them instead of invoking the
+# backend compiler.  Hit/miss counters land in profiler.runtime_metrics
+# (compile_cache.hits / .misses).
+#
+# WHERE the cache lives is decided in one place, resolve_compile_cache_dir:
+#   1. JAX_COMPILATION_CACHE_DIR set  -> the cache was placed from outside;
+#      jax reads it itself and this program never sets a dir in code;
+#   2. an explicit dir (--compile-cache / PADDLE_TPU_COMPILE_CACHE);
+#   3. entry points only (chip_smoke.py, `paddle_tpu train|serve|
+#      controller`, bench.py, bench_autoscale.py): the fixed
+#      <checkout>/.jax_cache — the path is part of the cache key, so it is
+#      never a temp dir, a pid or a time.
+# A bare library Executor with none of these set keeps no persistent cache.
 # ---------------------------------------------------------------------------
 
 _compile_cache_dir = None
 
 
-def enable_compile_cache(cache_dir):
-    """Point jax's persistent compilation cache at ``cache_dir`` and relax
-    its size/compile-time admission floors so every executable is cached
-    (the floors exist to keep trivial kernels out of shared caches; a
-    serving replica wants ALL of its programs warm).  Idempotent."""
+def _external_compile_cache_dir():
+    import os
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+
+
+def resolve_compile_cache_dir(explicit=None, entry_point=False):
+    """The dir the persistent cache should use, or ``""`` for none (see
+    the block comment above for the order)."""
+    import os
+    return (_external_compile_cache_dir() or str(explicit or "")
+            or os.environ.get("PADDLE_TPU_COMPILE_CACHE", "").strip()
+            or (os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), ".jax_cache")
+                if entry_point else ""))
+
+
+def enable_compile_cache(cache_dir=None, entry_point=False):
+    """Turn on jax's persistent compilation cache at the resolved dir and
+    relax its size/compile-time admission floors so every executable is
+    cached (the floors exist to keep trivial kernels out of shared caches;
+    a serving replica wants ALL of its programs warm).  Idempotent."""
     global _compile_cache_dir
+    cache_dir = resolve_compile_cache_dir(cache_dir, entry_point)
     if not cache_dir or _compile_cache_dir == cache_dir:
         return _compile_cache_dir is not None
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    if not _external_compile_cache_dir():
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _reset_jax_cache_memo()  # see below — without this, enabling after
     # the process has already compiled something is silently a no-op
-    _compile_cache_dir = str(cache_dir)
+    _compile_cache_dir = cache_dir
     from paddle_tpu import profiler as _profiler
     _profiler.install_jax_compile_listeners()
     return True
 
 
 def disable_compile_cache():
-    """Turn the persistent cache back off (tests; config symmetry)."""
+    """Turn the persistent cache back off (tests; config symmetry).  A
+    cache placed from outside is not this program's to turn off."""
     global _compile_cache_dir
     if _compile_cache_dir is None:
         return
-    jax.config.update("jax_compilation_cache_dir", None)
-    _reset_jax_cache_memo()
+    if not _external_compile_cache_dir():
+        jax.config.update("jax_compilation_cache_dir", None)
+        _reset_jax_cache_memo()
     _compile_cache_dir = None
 
 
@@ -164,13 +195,6 @@ def _reset_jax_cache_memo():
         logger.warning("could not reset jax compilation-cache state; "
                        "a cache dir set after the first compile may be "
                        "ignored", exc_info=True)
-
-
-def _maybe_enable_compile_cache_from_env():
-    import os
-    d = os.environ.get("PADDLE_TPU_COMPILE_CACHE", "").strip()
-    if d:
-        enable_compile_cache(d)
 
 
 def jit_cache_capacity():
@@ -345,7 +369,7 @@ class Executor:
         self._run_counter = 0
         self._verified = set()  # (id(program), version) PADDLE_TPU_VERIFY memo
         self._opt_cache = {}    # (id, version, feeds, fetches) -> program
-        _maybe_enable_compile_cache_from_env()
+        enable_compile_cache()
         from paddle_tpu import profiler as _profiler
         _profiler.install_jax_compile_listeners()
         from paddle_tpu.obs import perf as _perf
@@ -1244,7 +1268,7 @@ class Executor:
                 aux["release"] = release_map
             # whole-step scope: every emitted HLO op (including scan/
             # slicing glue outside the per-op ptop_ scopes) carries it,
-            # so tenant-proof WHOLE-STEP device time is one
+            # so scope-attributed WHOLE-STEP device time is one
             # scope_device_seconds("pt_step") read
             with jax.named_scope("pt_step"):
                 lower_block(block, env, rng_key, training, aux)

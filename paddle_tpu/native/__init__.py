@@ -5,29 +5,42 @@ The reference implements its IO/runtime layer in C++ (recordio at
 ``paddle/fluid/operators/reader/``); this package keeps that split: the
 compute path is XLA, the data path is native code.  The shared library is
 built on first use with g++ (no pybind11 in the image — flat C ABI +
-ctypes) and cached next to the sources.
+ctypes) into this directory, under a name that carries a hash of its
+source and compile command (git-ignored; never committed).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "recordio.cpp")
-_LIB = os.path.join(_DIR, "libpaddletpu_native.so")
 
 _lock = threading.Lock()
 _lib = None
 _build_error = None
 
 
-def _build():
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC,
-           "-o", _LIB, "-lz", "-lpthread"]
-    subprocess.run(cmd, check=True, capture_output=True)
+def _built(src, stem, incs=(), libs=()):
+    """Path of the shared library for ``src``, compiled on first use.
+    The name carries a hash of the source and the compile command, so a
+    binary left behind by an older source or another checkout is never
+    loaded — once a tree has been copied a file's mtime proves nothing."""
+    cmd = ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", *incs, src]
+    with open(src, "rb") as f:
+        tag = hashlib.sha256(
+            f.read() + " ".join(cmd + list(libs)).encode()).hexdigest()[:12]
+    lib = os.path.join(_DIR, f"{stem}.{tag}.so")
+    if not os.path.exists(lib):
+        tmp = f"{lib[:-3]}.{os.getpid()}.tmp.so"  # atomic under xdist
+        subprocess.run(cmd + ["-o", tmp, *libs], check=True,
+                       capture_output=True)
+        os.replace(tmp, lib)
+    return lib
 
 
 def load():
@@ -38,10 +51,8 @@ def load():
         if _lib is not None or _build_error is not None:
             return _lib
         try:
-            if (not os.path.exists(_LIB) or
-                    os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-                _build()
-            lib = ctypes.CDLL(_LIB)
+            lib = ctypes.CDLL(_built(_SRC, "libpaddletpu_native",
+                                     libs=("-lz", "-lpthread")))
         except Exception as e:  # pragma: no cover - toolchain missing
             _build_error = e
             return None
@@ -80,7 +91,6 @@ def load():
 # ---------------------------------------------------------------------------
 
 _CAPI_SRC = os.path.join(_DIR, "capi.cpp")
-_CAPI_LIB = os.path.join(_DIR, "libpaddletpu_capi.so")
 _capi_lib = None
 _capi_error = None
 
@@ -102,14 +112,10 @@ def load_capi():
         if _capi_lib is not None or _capi_error is not None:
             return _capi_lib
         try:
-            if (not os.path.exists(_CAPI_LIB) or
-                    os.path.getmtime(_CAPI_LIB) <
-                    os.path.getmtime(_CAPI_SRC)):
-                incs, libs = _python_flags()
-                cmd = (["g++", "-O2", "-shared", "-fPIC", "-std=c++17"]
-                       + incs + [_CAPI_SRC, "-o", _CAPI_LIB] + libs)
-                subprocess.run(cmd, check=True, capture_output=True)
-            lib = ctypes.CDLL(_CAPI_LIB, mode=ctypes.RTLD_GLOBAL)
+            incs, libs = _python_flags()
+            lib = ctypes.CDLL(_built(_CAPI_SRC, "libpaddletpu_capi",
+                                     incs, libs),
+                              mode=ctypes.RTLD_GLOBAL)
         except Exception as e:  # pragma: no cover - toolchain missing
             _capi_error = e
             return None

@@ -57,12 +57,8 @@ def _reference_attention(q, k, v, k_mask, causal, scale):
     return out.astype(q.dtype)
 
 
-try:  # pallas is TPU/GPU-oriented; import lazily-safe
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _M_INIT = -1e30
 
@@ -570,6 +566,14 @@ def _use_interpret():
     return not any(d.platform == "tpu" for d in jax.devices())
 
 
+def _count_flash_fallback():
+    """A flash-requested attention whose shape ``_flash_blocks`` refused
+    lowered as the composed XLA path (fires at trace time, once per
+    compiled signature)."""
+    from paddle_tpu.profiler import runtime_metrics
+    runtime_metrics.inc("attention.flash_fallback")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def fused_attention(q, k, v, k_mask, causal, scale, use_pallas):
     out, _ = _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas)
@@ -577,12 +581,13 @@ def fused_attention(q, k, v, k_mask, causal, scale, use_pallas):
 
 
 def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas):
-    if use_pallas and _HAS_PALLAS:
+    if use_pallas:
         res = _pallas_attention(q, k, v, k_mask, causal, scale,
                                 interpret=_use_interpret())
         if res is not None:
             out, lse = res
             return out, (q, k, v, k_mask, out, lse)
+        _count_flash_fallback()
     out = _reference_attention(q, k, v, k_mask, causal, scale)
     return out, (q, k, v, k_mask, None, None)
 
@@ -690,7 +695,7 @@ def sdpa_lower(ctx: LowerContext):
     use_flash = bool(ctx.attr("use_flash", True))
     # flash path has no attention-weight dropout; the graph builder falls
     # back to the composed path when dropout is requested in training
-    if use_flash and _HAS_PALLAS:
+    if use_flash:
         res = _pallas_attention(q, k, v, k_mask, causal, scale,
                                 interpret=_use_interpret())
         if res is not None:
@@ -699,6 +704,7 @@ def sdpa_lower(ctx: LowerContext):
             # saved residual; consumed by the grad op (flash backward)
             ctx.set_output("Lse", lse)
             return
+        _count_flash_fallback()
     ctx.set_output("Out", _reference_attention(q, k, v, k_mask, causal,
                                                scale))
 
@@ -830,15 +836,12 @@ def _pallas_softmax_fwd(x, row_bias, tri_bias, interpret):
             k += 1
         _fsm_fwd_kernel(xr, rb, tb, refs[-1])
 
-    try:
-        return pl.pallas_call(
-            kernel, grid=grid, in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, bs, Sk),
-                                   lambda b, h, i: (b, h, i, 0)),
-            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-            interpret=interpret)(*operands)
-    except Exception:  # pragma: no cover - lowering limits
-        return None
+    return pl.pallas_call(
+        kernel, grid=grid, in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, 1, bs, Sk),
+                               lambda b, h, i: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=interpret)(*operands)
 
 
 def _pallas_softmax_bwd(y, dy, interpret):
@@ -854,14 +857,11 @@ def _pallas_softmax_bwd(y, dy, interpret):
     if bs is None:
         return None
     spec = pl.BlockSpec((1, 1, bs, Sk), lambda b, h, i: (b, h, i, 0))
-    try:
-        return pl.pallas_call(
-            _fsm_bwd_kernel, grid=(B, H, Sq // bs),
-            in_specs=[spec, spec], out_specs=spec,
-            out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
-            interpret=interpret)(y, dy)
-    except Exception:  # pragma: no cover - lowering limits
-        return None
+    return pl.pallas_call(
+        _fsm_bwd_kernel, grid=(B, H, Sq // bs),
+        in_specs=[spec, spec], out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
+        interpret=interpret)(y, dy)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -885,9 +885,7 @@ def _xla_softmax(x, row_bias, tri_bias):
 
 
 def _fused_softmax_fwd(x, row_bias, tri_bias, interpret):
-    out = None
-    if _HAS_PALLAS:
-        out = _pallas_softmax_fwd(x, row_bias, tri_bias, interpret)
+    out = _pallas_softmax_fwd(x, row_bias, tri_bias, interpret)
     if out is None:
         # tiling/VMEM-gate fallback: same coverage signal as the
         # bias-decomposition fallback in nn_ops.softmax_lower — the
@@ -906,9 +904,7 @@ def _fused_softmax_bwd(interpret, y, g):
     # precision than its own XLA fallback below (ADVICE r5) — the
     # constant component of g cancels in (g - sum(g*y))*y, so exactly
     # the small differences a bf16 cast destroys are what dx is made of
-    dx = None
-    if _HAS_PALLAS:
-        dx = _pallas_softmax_bwd(y, g, interpret)
+    dx = _pallas_softmax_bwd(y, g, interpret)
     if dx is None:
         yf = y.astype(jnp.float32)
         gf = g.astype(jnp.float32)
@@ -927,12 +923,20 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # token's K/V row into its slot's tail page, then attends ONLY the pages
 # covering [0, len) — bytes read scale with live prefix length, not the
 # padded max_len.  The page-table feed is bucketed by the predictor so the
-# decode jit key stays constant per bucket.  A Pallas kernel (grid =
-# (slot, page), page picked by a scalar-prefetch table lookup, online
-# softmax across pages) serves TPUs; an XLA gather fallback shares the
-# same lowering contract and is the default off-TPU — interpret-mode
-# execution re-runs the kernel per call (unlike trace-once XLA), so tests
-# opt in via PADDLE_TPU_PAGED_INTERPRET=1 instead.
+# decode jit key stays constant per bucket.  Two lowerings share one
+# contract.  The Pallas kernel (grid = (slot, page), page picked by a
+# scalar-prefetch table lookup, online softmax across pages, all VPU — no
+# dot_general) is taken on a TPU whenever ``_paged_kernel_ok`` admits the
+# shape (head width a multiple of 128 lanes, page_len of 8 rows).  It ran
+# compiled on a v5e in PR 21's chip_smoke.py (8 heads x 128, page_len 16,
+# f32; all 7 page buckets held ``tpu_custom_call``, ``gen.paged.fallback``
+# stayed 0, tokens matched the cache-free reference; its speed: not
+# measured) and tests/test_tpu_compile.py holds it to the v5e compiler in
+# f32 and bf16.  The shipped ``GenConfig`` (d_head 16) FAILS the gate: on
+# a TPU it decodes through the XLA gather below, counted by
+# ``gen.paged.fallback``.  Off-TPU the gather is the default too —
+# interpret-mode execution re-runs the kernel per call (unlike trace-once
+# XLA), so tests opt in via PADDLE_TPU_PAGED_INTERPRET=1 instead.
 # ---------------------------------------------------------------------------
 
 def _paged_cache_update(kc, vc, k, v, page_table, lens):
@@ -978,7 +982,17 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
 
 
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, page_len, scale):
+                         acc_ref, m_ref, l_ref, *, page_len, n_head,
+                         scale):
+    """One (slot, page) grid step of the online softmax.
+
+    Everything stays in the pool's own ``[page_len, H*D]`` row layout:
+    head ``h`` is the static lane slice ``[h*D, (h+1)*D)`` (whole vregs
+    when ``D % 128 == 0``), scores are a VPU multiply + lane reduction
+    and the PV product a sublane reduction — no ``dot_general``, so
+    Mosaic sees neither a batch dimension nor an M=1 matmul.  The
+    running max / denominator are kept replicated across each head's
+    ``D`` lanes so every update is a plain elementwise op."""
     s = pl.program_id(0)
     p = pl.program_id(1)
 
@@ -988,83 +1002,76 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _M_INIT)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0].astype(jnp.float32)            # [H, D]
-    k = k_ref[0].astype(jnp.float32)            # [PL, H, D]
-    v = v_ref[0].astype(jnp.float32)
-    sc = jax.lax.dot_general(                   # [H, PL]: batch H, contract D
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale
+    D = q_ref.shape[-1] // n_head
     valid = lens_ref[s, 0] - p * page_len
-    col = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-    sc = jnp.where(col < valid, sc, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    e = jnp.exp(sc - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(e, axis=1, keepdims=True)
-    pv = jax.lax.dot_general(                   # [H, D]: batch H, contract PL
-        e, v, (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
-    acc_ref[...] = acc_ref[...] * alpha + pv
-    m_ref[...] = m_new
+    live = jax.lax.broadcasted_iota(jnp.int32, (page_len, 1), 0) < valid
+    for h in range(n_head):
+        sl = slice(h * D, (h + 1) * D)
+        q = q_ref[0, :, sl].astype(jnp.float32)     # [1, D]
+        k = k_ref[0, :, sl].astype(jnp.float32)     # [PL, D]
+        v = v_ref[0, :, sl].astype(jnp.float32)
+        sc = jnp.sum(q * k, axis=1, keepdims=True) * scale
+        sc = jnp.where(live, sc, NEG_INF)           # [PL, 1]
+        m_prev = m_ref[:, sl]                       # [1, D]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        e = jnp.exp(sc - m_new)                     # [PL, D]
+        l_ref[:, sl] = l_ref[:, sl] * alpha \
+            + jnp.sum(e, axis=0, keepdims=True)
+        acc_ref[:, sl] = acc_ref[:, sl] * alpha \
+            + jnp.sum(e * v, axis=0, keepdims=True)
+        m_ref[:, sl] = m_new
 
     @pl.when(p == pl.num_programs(1) - 1)
     def _finish():
-        # a free slot (lens == 0) masks every page: l stays 0, the guard
-        # yields finite garbage the scheduler never reads
+        # a free slot (lens == 0) masks every page: the output is finite
+        # garbage the scheduler never reads
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
+def _paged_kernel_ok(n_head, HD, PL, interpret):
+    """Shape gate of the paged kernel: heads must split H*D evenly, and
+    on the chip a head must cover whole 128-lane vregs and a page whole
+    8-row sublane tiles (the kernel slices refs at ``h*D`` lanes)."""
+    if HD % n_head:
+        return False
+    return interpret or not ((HD // n_head) % 128 or PL % 8)
+
+
 def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
                             interpret=False):
+    """Returns None when ``_paged_kernel_ok`` refuses the shape; any
+    lowering error past that gate surfaces to the caller."""
     S, P = page_table.shape
     NP, PL, HD = kc.shape
-    H = n_head
-    D = HD // H
-    if H * D != HD:
+    if not _paged_kernel_ok(n_head, HD, PL, interpret):
         return None
-    if not interpret and (D % 128 or PL % 8):
-        return None  # lane/sublane tiling gate
-    q4 = q.reshape(S, H, D)
-    kc4 = kc.reshape(NP, PL, H, D)
-    vc4 = vc.reshape(NP, PL, H, D)
     kernel = functools.partial(_paged_decode_kernel, page_len=PL,
-                               scale=scale)
-    try:
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2,
-                grid=(S, P),
-                in_specs=[
-                    pl.BlockSpec((1, H, D),
-                                 lambda s, p, pt, ln: (s, 0, 0)),
-                    pl.BlockSpec((1, PL, H, D),
-                                 lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
-                    pl.BlockSpec((1, PL, H, D),
-                                 lambda s, p, pt, ln: (pt[s, p], 0, 0, 0)),
-                ],
-                out_specs=pl.BlockSpec((1, H, D),
-                                       lambda s, p, pt, ln: (s, 0, 0)),
-                scratch_shapes=[
-                    pltpu.VMEM((H, D), jnp.float32),
-                    pltpu.VMEM((H, 1), jnp.float32),
-                    pltpu.VMEM((H, 1), jnp.float32),
-                ],
-            ),
-            out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
-            interpret=interpret,
-        )(page_table, lens, q4, kc4, vc4)
-    except Exception:  # pragma: no cover - lowering limits
-        return None
+                               n_head=n_head, scale=scale)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(S, P),
+            in_specs=[
+                pl.BlockSpec((1, 1, HD), lambda s, p, pt, ln: (s, 0, 0)),
+                pl.BlockSpec((1, PL, HD),
+                             lambda s, p, pt, ln: (pt[s, p], 0, 0)),
+                pl.BlockSpec((1, PL, HD),
+                             lambda s, p, pt, ln: (pt[s, p], 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, HD),
+                                   lambda s, p, pt, ln: (s, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((1, HD), jnp.float32)] * 3,
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, 1, HD), q.dtype),
+        interpret=interpret,
+    )(page_table, lens, q.reshape(S, 1, HD), kc, vc)
     return out.reshape(q.shape)
 
 
 def _paged_kernel_enabled(interpret):
-    if not _HAS_PALLAS:
-        return False
     if not interpret:
         return True
     import os

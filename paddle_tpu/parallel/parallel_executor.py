@@ -34,7 +34,8 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu import framework
-from paddle_tpu.executor import Executor, _CompiledBlock, lower_block
+from paddle_tpu.executor import (Executor, _CompiledBlock, _amp_enabled,
+                                 lower_block)
 from paddle_tpu.framework import default_main_program
 from paddle_tpu.scope import global_scope
 from paddle_tpu.parallel.mesh import default_mesh, DATA_AXIS
@@ -109,7 +110,7 @@ class ParallelExecutor(Executor):
                tuple(sorted((n, str(a.dtype), a.shape)
                             for n, a in feed_arrays.items())),
                feed_lods,
-               fetch_names, donate)
+               fetch_names, donate, _amp_enabled(program))
         if sig in self._cache:
             self._cache[sig] = self._cache.pop(sig)  # LRU bump
             _profiler.runtime_metrics.inc("jit_cache.hits")
@@ -156,6 +157,9 @@ class ParallelExecutor(Executor):
             repl,  # rng key
         )
         training = not program._is_inference
+        # the SAME mixed-precision switch as Executor._prepare: a program
+        # marked amp must not silently train in f32 once it meets a mesh
+        amp = _amp_enabled(program)
         from paddle_tpu.lod import DynLoD, SPLITS_SUFFIX
         lod_map = {}
         for n, lod in feed_lods:
@@ -171,7 +175,7 @@ class ParallelExecutor(Executor):
             env.update(inout_state)
             aux = {"rng_counter": 0, "scope": scope,
                    "lower_block": lower_block, "mesh": mesh,
-                   "lod": dict(lod_map),
+                   "lod": dict(lod_map), "amp": amp,
                    # opt-pipeline fact (see Executor._prepare): key-
                    # free ops skip their per-op fold_in at trace time
                    "rng_plan": True

@@ -29,12 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8 promotes shard_map out of experimental
-    from jax import shard_map
-    _SM_CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-    _SM_CHECK_KW = "check_rep"
+from jax import shard_map
 
 __all__ = ["gpipe", "stack_stage_params"]
 
@@ -125,5 +120,5 @@ def gpipe(stage_fn, stacked_params, microbatches, mesh: Mesh,
         lambda _: P(axis), stacked_params)
     fn = shard_map(per_device, mesh=mesh,
                    in_specs=(spec_params, P()), out_specs=P(),
-                   **{_SM_CHECK_KW: False})
+                   check_vma=False)
     return fn(stacked_params, microbatches)

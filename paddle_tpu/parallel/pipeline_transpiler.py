@@ -54,12 +54,7 @@ import numpy as np
 
 from paddle_tpu.framework import Parameter
 
-try:
-    from jax import shard_map
-    _SM_CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-    _SM_CHECK_KW = "check_rep"
+from jax import shard_map
 
 __all__ = ["pipeline_transpiler", "PipelinedProgram"]
 
@@ -603,7 +598,7 @@ class PipelinedProgram:
         fn = shard_map(per_device, mesh=mesh,
                        in_specs=(param_specs, xs_specs),
                        out_specs=out_specs,
-                       **{_SM_CHECK_KW: False})
+                       check_vma=False)
         return fn
 
 

@@ -18,8 +18,8 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["ring_attention"]
 
@@ -92,5 +92,5 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "seq", causal=False,
 
     fn = shard_map(local_fn, mesh=mesh,
                    in_specs=(spec, spec, spec), out_specs=spec,
-                   check_rep=False)
+                   check_vma=False)
     return fn(q, k, v)

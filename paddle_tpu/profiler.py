@@ -268,12 +268,12 @@ def device_busy_seconds(trace_dir):
     OVERLAPPING DMA copies whose durations multi-count wall time).  Falls
     back to the max non-async line sum when no 'XLA Ops' line exists.
 
-    SHARED-CHIP caveat (measured, exp_probe_trace.py): the device tracer
-    records EVERY program on the chip during the window — other tenants'
-    modules included — so this total can exceed your own program's time.
-    When that matters, wrap your computation in ``jax.named_scope`` and
-    use :func:`scope_device_seconds` / :func:`measure_device_seconds`
-    with ``scope=``, which foreign events cannot match."""
+    The device tracer records EVERY program the process ran on the chip
+    during the window, so this total can exceed the time of the one
+    computation you care about.  When that matters, wrap it in
+    ``jax.named_scope`` and use :func:`scope_device_seconds` /
+    :func:`measure_device_seconds` with ``scope=``, which other events
+    cannot match."""
     busy = 0.0
     for plane in _iter_xplanes(trace_dir):
         if not plane.name.startswith("/device:"):

@@ -83,6 +83,67 @@ class TestPersistentCompileCache:
             disable_compile_cache()  # double-disable is safe
 
 
+class TestCompileCachePlacement:
+    """ONE resolver decides where the persistent cache lives
+    (executor.resolve_compile_cache_dir): JAX_COMPILATION_CACHE_DIR,
+    when set, wins and the program sets no dir in code; else the
+    explicit dir / PADDLE_TPU_COMPILE_CACHE; else — entry points only —
+    the fixed <checkout>/.jax_cache.  Never a temp dir."""
+
+    def test_resolver_order(self, monkeypatch, tmp_path):
+        from paddle_tpu.executor import resolve_compile_cache_dir as r
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.delenv("PADDLE_TPU_COMPILE_CACHE", raising=False)
+        assert r() == ""                       # bare library: no cache
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert r(entry_point=True) == os.path.join(repo, ".jax_cache")
+        assert r(entry_point=True) == r(entry_point=True)   # fixed path
+        monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", "/env/dir")
+        assert r(entry_point=True) == "/env/dir"
+        assert r("/flag/dir", entry_point=True) == "/flag/dir"
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/outside")
+        assert r("/flag/dir", entry_point=True) == "/outside"
+
+    def test_external_dir_is_never_set_in_code(self, monkeypatch,
+                                               tmp_path):
+        import jax
+
+        from paddle_tpu import executor
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "outside"))
+        monkeypatch.setattr(executor, "_compile_cache_dir", None)
+        seen = []
+        real = jax.config.update
+        monkeypatch.setattr(
+            jax.config, "update",
+            lambda k, v: (seen.append(k), real(k, v))[1])
+        floors = {k: getattr(jax.config, k) for k in (
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")}
+        try:
+            assert executor.enable_compile_cache("/flag/dir")
+            assert executor.enable_compile_cache(entry_point=True)
+            fluid.Executor()
+            executor.disable_compile_cache()
+        finally:
+            for k, v in floors.items():
+                real(k, v)
+            executor._reset_jax_cache_memo()
+        assert "jax_compilation_cache_dir" not in seen
+        # it still relaxed the admission floors
+        assert "jax_persistent_cache_min_compile_time_secs" in seen
+
+    def test_only_the_resolver_names_the_jax_option(self):
+        """No second place in the tree points jax at a cache dir."""
+        import glob
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        files = glob.glob(os.path.join(repo, "*.py")) + glob.glob(
+            os.path.join(repo, "paddle_tpu", "**", "*.py"), recursive=True)
+        hits = [os.path.relpath(f, repo) for f in files
+                if "jax_compilation_cache_dir" in open(f).read()]
+        assert hits == [os.path.join("paddle_tpu", "executor.py")], hits
+
+
 class TestJitCacheCapacity:
     def _scale_program(self):
         main, startup = fluid.Program(), fluid.Program()

@@ -57,7 +57,12 @@ class TestMemoryOptimizeTransformer:
         fluid.optimizer.Adam(learning_rate=1e-3).minimize(avg_cost)
         plan = memory_optimize(fluid.default_main_program())
         assert len(plan.reuse_pairs) > 10
-        assert plan.peak_bytes_with_reuse < plan.peak_bytes
+        # reuse cannot lower THIS peak: it sits at the forward/backward
+        # boundary, where every activation is still owed to its grad op
+        # and no recycled buffer is live yet.  (Until PR 21 this asserted
+        # a strict drop — true only because append_backward stopped at
+        # the first dropout and most activations died in the forward.)
+        assert plan.peak_bytes_with_reuse <= plan.peak_bytes
         report = plan.report()
         assert "reuse pairs" in report and "savings" in report
 

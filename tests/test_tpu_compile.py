@@ -1,0 +1,177 @@
+"""What the chip's compiler must accept, checked without the chip, and the
+control flow of ``chip_smoke.py``, rehearsed on the CPU.
+
+The Pallas kernels of the main path are compiled at real widths for a
+DESCRIBED ``v5e:2x2`` topology (libtpu's compiler is installed; no device
+is attached): interpret-mode tests cannot see a lane-misaligned slice, a
+``dot_general`` Mosaic refuses or a VMEM overflow, this can.  Nothing
+runs, so nothing here says anything about results or times.
+
+All such compiles live in THIS file, and the topology is described only
+inside the module-scoped fixture below — never at import, in a ``skipif``
+or in ``parametrize`` arguments: one process at a time may load the TPU
+library, xdist workers each import every test file, and only the worker
+that is handed this file may load it.  The kernel builders are called
+with ``interpret=False`` directly (``_use_interpret()`` sees the CPU
+under a described device), in this process, with the persistent
+compilation cache off (an entry written for a described device cannot be
+read back without a chip).
+"""
+
+import os
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A sharding on one described v5e chip; compile cache off around the
+    module's compiles."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes):
+    """Compile ``fn`` for the described chip; returns the HLO text."""
+    import jax
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _flash_loss(q, k, v, mask):
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    out, _ = A._pallas_attention(q, k, v, mask, True,
+                                 q.shape[-1] ** -0.5, interpret=False)
+    return jnp.sum(out.astype(jnp.float32))
+
+
+def _flash_fwd_bwd(q, k, v, mask):
+    """Forward + both backward kernels, as the sdpa grad op calls them."""
+    from paddle_tpu.ops import attention_ops as A
+    scale = q.shape[-1] ** -0.5
+    out, lse = A._pallas_attention(q, k, v, mask, True, scale,
+                                   interpret=False)
+    return A._pallas_attention_bwd(q, k, v, mask, out, lse, out, True,
+                                   scale, interpret=False)
+
+
+@pytest.mark.parametrize("fn,bhsd", [
+    (_flash_loss, (32, 8, 1024, 64)),
+    (_flash_fwd_bwd, (32, 8, 1024, 64)),
+    (_flash_fwd_bwd, (8, 8, 2048, 64)),
+], ids=["fwd-S1024", "fwd+bwd-S1024", "fwd+bwd-S2048"])
+def test_flash_attention_compiles_for_v5e(one_chip, fn, bhsd):
+    import jax.numpy as jnp
+    B, H, S, D = bhsd
+    qkv = (bhsd, jnp.bfloat16)
+    hlo = _compile(fn, one_chip, qkv, qkv, qkv, ((B, S), jnp.bfloat16))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_paged_decode_compiles_for_v5e(one_chip, dtype):
+    """The decode kernel at chip_smoke phase 2's shapes: 8 slots, an
+    8-page bucket of a 512-page pool, page_len 16, 8 heads x 128."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, P, NP, PL, H, D = 8, 8, 512, 16, 8, 128
+    dt = jnp.dtype(dtype)
+
+    def fn(q, kc, vc, pt, lens):
+        out = A._pallas_paged_attention(q, kc, vc, pt, lens, H, D ** -0.5,
+                                        interpret=False)
+        assert out is not None, "shape gate refused the smoke's shapes"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 1, H * D), dt),
+                   ((NP, PL, H * D), dt), ((NP, PL, H * D), dt),
+                   ((S, P), jnp.int32), ((S, 1), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("bias", ["row", "causal"])
+def test_fused_softmax_compiles_for_v5e(one_chip, bias):
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    B, H, S = 32, 8, 1024
+
+    def fn(x, b):
+        row, tri = (b, None) if bias == "row" else (None, b)
+        out = A._pallas_softmax_fwd(x, row, tri, interpret=False)
+        assert out is not None, "tiling gate refused a real width"
+        return out
+
+    bshape = (B, S) if bias == "row" else (S, S)
+    hlo = _compile(fn, one_chip, ((B, H, S, S), jnp.bfloat16),
+                   (bshape, jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py's control flow at toy sizes on the CPU.  The script has no
+# CPU mode; the test stubs the ONE function every chip-only assertion goes
+# through, so a refactor of the phases cannot break the script unnoticed.
+# ---------------------------------------------------------------------------
+
+@pytest.fixture()
+def smoke(monkeypatch):
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke
+    monkeypatch.setattr(chip_smoke, "expect_chip", lambda cond, what: None)
+    import jax
+    was = jax.config.jax_default_matmul_precision
+    yield chip_smoke
+    jax.config.update("jax_default_matmul_precision", was)  # phase 2 sets it
+
+
+# dropout off: at a few dozen tokens its noise outweighs three windows of
+# learning, and "the loss falls" is one of the checks being rehearsed
+_TINY = dict(d_model=32, d_inner_hid=64, n_layer=1, n_head=2, d_key=16,
+             d_value=16, src_vocab_size=128, trg_vocab_size=128,
+             dropout=0.0)
+
+
+def test_chip_smoke_phases_rehearse_on_cpu(smoke, capsys, monkeypatch):
+    # the model file's flash crossover, lowered so a 128-token "long"
+    # sequence takes the flash path (interpret mode here)
+    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_S", "128")
+    smoke.phase_trainer(batch=2, seq=16, steps=3, calls=3, long_batch=1,
+                        long_seq=128, hp_overrides=_TINY)
+    smoke.phase_server(n_head=2, d_head=16, d_ffn=64, n_layer=1,
+                       vocab_size=64, max_len=32, num_slots=2, page_len=8,
+                       prompt_buckets=(8, 32), prompt_lens=(3, 12),
+                       new_tokens=4)
+    import json
+    lines = [json.loads(l) for l in capsys.readouterr().out.splitlines()
+             if l.startswith("{")]
+    phases = {l["phase"] for l in lines}
+    assert {"trainer", "trainer_long", "flash_kernel", "server",
+            "server_stream", "server_stats"} <= phases
+    assert all(l["matches_reference"] for l in lines
+               if l["phase"] == "server_stream")
+
+
+def test_chip_smoke_refuses_the_cpu(monkeypatch):
+    """No CPU mode: phase 0 fails before anything else is touched."""
+    monkeypatch.syspath_prepend(REPO)
+    import chip_smoke
+    with pytest.raises(chip_smoke.SmokeFailure, match="no TPU"):
+        chip_smoke.main([])
