@@ -70,9 +70,11 @@ def _metric(entry, kind, e2e_names, cell_names):
 
 
 def validate(manifest):
-    if set(manifest) != TOP_KEYS:
+    if set(manifest) - {"trace_in_run"} != TOP_KEYS:
         raise ManifestError(f"BENCHMARK.json keys {sorted(manifest)} != "
-                            f"{sorted(TOP_KEYS)}")
+                            f"{sorted(TOP_KEYS)} (+ trace_in_run)")
+    if manifest.get("trace_in_run", True) is not True:
+        raise ManifestError("trace_in_run is true or absent")
     if not isinstance(manifest["run_seconds"], int) or \
             not 1 <= manifest["run_seconds"] <= 51:
         raise ManifestError("run_seconds")

@@ -1,7 +1,13 @@
 #!/usr/bin/env python3
 """Run ONE cell of the benchmark once.
 
-    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
+
+``--trace 0`` measures the end-to-end metrics with all tracing off,
+``--trace 1`` is a traced run of its own that reads the per-layer ones,
+and ``--trace 2`` is a ``--trace 0`` run that, once its measured window
+has closed and been judged, traces a few seconds of the same traffic in
+the same process and prints both kinds of metric on the one last line.
 
 Everything about a cell is data, found by name: ``BENCHMARK.json`` (cells,
 configurations, metrics, bounds), ``benchmark/configs/<configuration>.json``,
@@ -22,6 +28,7 @@ import argparse
 import importlib.util
 import json
 import os
+import shutil
 import sys
 import time
 
@@ -34,6 +41,7 @@ for _p in (ROOT, HERE):
 
 from lib import manifest as _manifest  # noqa: E402
 from lib import peaks as _peaks        # noqa: E402
+from lib import spanclock as _spanclock  # noqa: E402
 from lib import xtrace as _xtrace      # noqa: E402
 
 
@@ -94,33 +102,49 @@ def require_chips(chips):
 
 class DeviceTracer:
     """Hands the traffic module ``start()`` / ``stop()`` around the part
-    of its window that is traced; off (both no-ops) in a ``--trace 0``
-    run.  One session per run."""
+    of its traffic that is traced; off (both no-ops) in a ``--trace 0``
+    run.  One session per run, started and stopped through the program's
+    own tracing control (``paddle_tpu.profiler``), whose returned dict
+    (``session``: the xplane written, ``span_to_trace_ns``) every reader
+    is handed."""
 
     def __init__(self, enabled, trace_dir):
         self.enabled = bool(enabled)
         self.trace_dir = trace_dir
-        self.t_start = self.t_stop = None
+        self.t_start = self.t_stop = self.session = None
         self._on = False
+        self.costs = {}     # seconds the control's own calls took
 
     def start(self):
         if not self.enabled or self.t_start is not None:
             return
-        import jax
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0    # device ops and host runtime only
-        opts.host_tracer_level = 1
-        jax.profiler.start_trace(self.trace_dir, profiler_options=opts)
+        from paddle_tpu import profiler
+        t0 = time.perf_counter()
+        profiler.start_profiler(profile_path=self.trace_dir)
         self._on = True
         self.t_start = time.perf_counter()
+        self.costs["start_s"] = self.t_start - t0
 
     def stop(self):
         if not self._on:
             return
-        import jax
+        from paddle_tpu import profiler
         self.t_stop = time.perf_counter()
         self._on = False
-        jax.profiler.stop_trace()
+        self.session = profiler.stop_profiler()
+        self.costs["stop_s"] = time.perf_counter() - self.t_stop
+
+    def warm(self):
+        """Start and stop the profiler once and throw that trace away,
+        so that the cost of the first start falls into no number."""
+        from paddle_tpu import profiler
+        t0 = time.perf_counter()
+        profiler.start_profiler(profile_path=self.trace_dir + ".warm")
+        t1 = time.perf_counter()
+        profiler.stop_profiler()
+        self.costs.update(first_start_s=t1 - t0,
+                          first_stop_s=time.perf_counter() - t1)
+        shutil.rmtree(self.trace_dir + ".warm", ignore_errors=True)
 
     @property
     def window_s(self):
@@ -177,20 +201,31 @@ def read_layer_metrics(entries, run):
     return out
 
 
-def breakdown_of(summary, top=10):
-    """Top device operations, and the longest idle gaps named by the
-    operations on either side (host spans are not on the device's clock
-    yet, so a gap cannot be named by what the host did)."""
+def breakdown_of(run, top=10):
+    """Top device operations, and the idle gaps of the first chip, each
+    named by the innermost program span that covers most of it on the
+    dispatching thread (``host:<span>``; the spans are put on the
+    trace's clock by the tracing control's ``span_to_trace_ns``).  A gap
+    that no span covers keeps the name of the device operations on
+    either side (``after:<op>|before:<op>``)."""
+    summary = run["trace"]
     events = summary["per_device"][min(summary["per_device"])]
     merged = _xtrace.union_intervals([(ev[0], ev[1]) for ev in events])
+    gaps = [(a1, b0) for (_, a1), (b0, _) in zip(merged, merged[1:])]
+    timeline = _spanclock.host_timeline(run)
+    under = _spanclock.name_gaps(gaps, timeline or [])
     ends, starts = {}, {}
     for ev in events:
         ends[ev[1]] = ev[2]
         starts.setdefault(ev[0], ev[2])
     by_name = {}
-    for (_, a1), (b0, _) in zip(merged, merged[1:]):
-        name = (f"after:{ends.get(a1, '?')[:40]}|before:"
-                f"{starts.get(b0, '?')[:40]}")
+    for (a1, b0), covered in zip(gaps, under):
+        span = max(covered, key=covered.get) if covered else None
+        if span is not None:
+            name = f"host:{span}"
+        else:
+            name = (f"after:{ends.get(a1, '?')[:40]}|before:"
+                    f"{starts.get(b0, '?')[:40]}")
         n, total = by_name.get(name, (0, 0.0))
         by_name[name] = (n + 1, total + (b0 - a1) / 1e9)
     ranked = sorted(by_name.items(), key=lambda kv: -kv[1][1])[:top]
@@ -249,23 +284,31 @@ def run_cell(cell, seed, seconds, trace, rehearsal=None):
     os.makedirs(out_dir, exist_ok=True)
     enable_cache()
 
-    tracer = DeviceTracer(bool(trace) and not rehearsal,
+    # a --trace 2 run is a --trace 0 run until its window is closed and
+    # judged: the traffic module sees ``traced`` false and no tracing is
+    # on; the tracer it is handed is used by its ``traced`` entry point
+    tracer = DeviceTracer(trace == 2 or (trace == 1 and not rehearsal),
                           os.path.join(out_dir, "trace"))
     ctx = make_context(cell, seed, seconds, config, workload, used, tracer,
-                       traced=bool(trace), rehearsal=bool(rehearsal))
+                       traced=trace == 1, rehearsal=bool(rehearsal))
 
+    stretch = None
     state = traffic.setup(ctx)
     try:
         t_window = time.time()
         raw = traffic.window(state, ctx)
         tracer.stop()
         verdict = traffic.verify(state, ctx, raw)
+        if trace == 2:
+            t_phase = time.perf_counter()
+            stretch = traced_stretch(traffic, state, ctx, tracer)
+            t_stretch = time.perf_counter()
     finally:
         traffic.close(state)
     setup_s = t_window - t_proc
 
     metrics = {}
-    if not trace:
+    if trace != 1:
         values = dict(raw["end_to_end"], setup_s=setup_s)
         for m in _manifest.metrics_of(manifest, "end_to_end", cell):
             if values.get(m["name"]) is None:
@@ -279,28 +322,35 @@ def run_cell(cell, seed, seconds, trace, rehearsal=None):
     result = {"correct": bool(verdict["correct"]),
               "attempted": int(raw["attempted"]),
               "failed": int(raw["failed"])}
-    if trace:
+    if trace == 1 or stretch is not None:
+        # what every reader is handed: spans and the device trace of the
+        # traced stretch, counters and client-side facts of the measured
+        # window (in a --trace 1 run the stretch lies inside the window)
+        stretch = stretch or {}
+        spans = stretch.get("spans", raw.get("spans", []))
+        if trace == 2:
+            # only what lies between the profiler's two clock marks, so
+            # that span and device-trace metrics cover the same seconds
+            spans = _spanclock.inside_session(spans, tracer.session)
         run = {"cell": cell, "config": config, "workload": workload,
-               "chips": entry["chips"], "raw": raw,
-               "spans": raw.get("spans", []),
+               "chips": entry["chips"], "raw": raw, "spans": spans,
                "counters": raw.get("counters", {}),
-               "facts": raw.get("facts", {}), "trace": None, "peaks": None}
-        if tracer.window_s is not None:
-            xplane = _xtrace.find_xplane(tracer.trace_dir)
-            summary = _xtrace.summarize(xplane, n_devices=entry["chips"],
-                                        top=40)
-            run["trace"] = summary
-            run["trace_window_s"] = tracer.window_s
-            run["peaks"] = _peaks.peaks_for(device["kind"])
-            device_out["busy_s"] = summary["busy_s"]
-            device_out["window_s"] = tracer.window_s
-            if summary["per_device"]:
-                result["breakdown"] = breakdown_of(summary)
-            say("trace", file=xplane, lines=summary.get("lines"),
-                busy_s=summary["busy_s"], window_s=tracer.window_s,
-                top_ops=summary["device_ops"])
-        metrics = read_layer_metrics(
-            _manifest.metrics_of(manifest, "per_layer", cell), run)
+               "facts": {**raw.get("facts", {}),
+                         **stretch.get("facts", {})},
+               "trace": None, "peaks": None, "session": tracer.session}
+        try:
+            result.update(reduce_trace(run, tracer, device, device_out))
+            metrics.update(read_layer_metrics(
+                _manifest.metrics_of(manifest, "per_layer", cell), run))
+        except Exception as e:      # the measured numbers outlive it
+            if trace == 1:
+                raise
+            say("trace_failed", where="reduce", error=repr(e))
+    if trace == 2:
+        shutil.rmtree(tracer.trace_dir, ignore_errors=True)
+        say("trace_phase", trace_phase_s=time.perf_counter() - t_phase,
+            traced_stretch_s=t_stretch - t_phase, **tracer.costs,
+            **(stretch or {}).get("observed", {}))
     result["metrics"] = metrics
     result["device"] = device_out
     if rehearsal:
@@ -310,12 +360,61 @@ def run_cell(cell, seed, seconds, trace, rehearsal=None):
     return result
 
 
+def traced_stretch(traffic, state, ctx, tracer):
+    """The traced part of a ``--trace 2`` run, after the measured window:
+    the profiler is started and stopped once for nothing, then the
+    traffic module's ``traced(state, ctx)`` sends a few seconds of the
+    same traffic with the program's spans and the profiler on (it calls
+    ``ctx["tracer"].start()`` / ``stop()`` as in a ``--trace 1`` window)
+    and returns ``spans`` and ``facts`` of that stretch.  It stays on
+    this, the main thread: ``jax.profiler.stop_trace`` takes three times
+    as long from any other (PERF.md), so a traffic module bounds every
+    wait of its ``traced`` itself.  A failure in here is reported and
+    loses only the per-layer metrics."""
+    try:
+        tracer.warm()
+        return traffic.traced(state, ctx)
+    except Exception as e:
+        say("trace_failed", where="traced stretch", error=repr(e))
+        return None
+    finally:
+        tracer.stop()
+
+
+def reduce_trace(run, tracer, device, device_out):
+    """Reduce the device trace (if one was taken) into ``run`` and
+    ``device_out``; returns what the result gains (``breakdown``)."""
+    if tracer.window_s is None:
+        return {}
+    xplane = (tracer.session or {}).get("xplane") \
+        or _xtrace.find_xplane(tracer.trace_dir)
+    summary = _xtrace.summarize(xplane, n_devices=run["chips"], top=40)
+    run["trace"] = summary
+    run["trace_window_s"] = tracer.window_s
+    say("trace", file=xplane, lines=summary.get("lines"),
+        busy_s=summary["busy_s"], window_s=tracer.window_s,
+        top_ops=summary["device_ops"],
+        session={k: v for k, v in (tracer.session or {}).items()
+                 if k != "xplane"})
+    if not summary["per_device"]:
+        return {}       # no device plane (a rehearsal on the CPU)
+    run["peaks"] = _peaks.peaks_for(device["kind"])
+    device_out["busy_s"] = summary["busy_s"]
+    device_out["window_s"] = tracer.window_s
+    say("clock_agreement", **(_spanclock.agreement(run) or {}))
+    idle = _spanclock.idle_by_span(run)
+    if idle:
+        say("idle_by_span", seconds={str(k): v for k, v in sorted(
+            idle.items(), key=lambda kv: -kv[1])})
+    return {"breakdown": breakdown_of(run)}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     args = ap.parse_args(argv)
     try:
         result = run_cell(args.workload, args.seed, args.seconds, args.trace)

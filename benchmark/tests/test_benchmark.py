@@ -13,6 +13,7 @@ import copy
 import json
 import os
 import sys
+import time
 
 import pytest
 
@@ -83,6 +84,139 @@ def test_summary_means_over_planes():
     one = xtrace.summarize(os.path.join(HERE, "data",
                                         "synthetic.xplane.pb"), n_devices=1)
     assert one["busy_s"] == pytest.approx(1800e-9)
+
+
+# -- spans on the trace's clock ----------------------------------------------
+
+OFFSET_NS = 500.0       # span ts * 1e9 + this = the synthetic trace's ns
+
+
+def _span(span_id, parent_id, name, start_ns, end_ns, tid=7):
+    return {"name": name, "trace_id": "t", "span_id": span_id,
+            "parent_id": parent_id, "ts": (start_ns - OFFSET_NS) / 1e9,
+            "dur": (end_ns - start_ns) / 1e9, "tid": tid, "attrs": {}}
+
+
+def _recorded_run(spans, facts=None, session=True):
+    """What the harness hands a reader, over the synthetic trace's first
+    device (busy [1000, 2000] and [3000, 3800] ns, one run of ``jit_step``
+    over [1000, 4000]) and a traced stretch of [500, 5500] ns."""
+    import run as harness
+    trace = xtrace.summarize(os.path.join(HERE, "data",
+                                          "synthetic.xplane.pb"),
+                             n_devices=1)
+    return harness, {
+        "spans": spans, "facts": facts or {}, "counters": {},
+        "trace": trace, "trace_window_s": 5e-6, "chips": 1,
+        "session": {"span_to_trace_ns": OFFSET_NS, "t_start": 0.0,
+                    "t_stop": 5e-6, "mark_width_ns": 0.0,
+                    "drift_ns": 0.0} if session else None}
+
+
+SERVE_SPANS = [
+    _span(1, None, "gen.sched.turn", 600, 5000),
+    _span(2, 1, "gen.decode_iteration", 800, 4200),
+    _span(3, 2, "gen.decode_step", 900, 4100),
+    _span(4, 3, "executor.run", 900, 4100),
+    _span(5, 4, "executor.feed", 900, 1000),
+    _span(6, 4, "executor.dispatch", 1000, 1100),
+    _span(7, 4, "executor.fetch", 1100, 4100),
+    _span(8, 2, "gen.emit", 4100, 4200),
+    # recorded after the fact, on no stack: must name no gap
+    _span(9, None, "gen.queue_wait", 100, 5400),
+    _span(10, 1, "gen.seed_slot", 4300, 4700),
+]
+
+
+def _read(name, run_dict):
+    import run as harness
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        entry = next(m for m in json.load(f)["per_layer"]
+                     if m["name"] == name)
+    got = harness.read_layer_metrics([entry], run_dict)
+    return got[name]["value"] if got else None
+
+
+def test_new_readers_on_recorded_data():
+    _, run_dict = _recorded_run(SERVE_SPANS)
+    # iteration 3400 ns less its decode step 3200 ns
+    assert _read("sched_self_ms_per_iteration", run_dict) == \
+        pytest.approx(200e-6)
+    assert _read("seed_slot_p50_ms", run_dict) == pytest.approx(400e-6)
+    # feed 100 idle + dispatch 100 busy + fetch 3000 of which 1700 busy
+    assert _read("executor_self_ms_per_step.serve", run_dict) == \
+        pytest.approx(1400e-6)
+    # idle 3200 ns of the stretch; under feed, iteration, fetch, emit and
+    # seed_slot 2000 of them, the rest under the bare turn or no span
+    assert _read("idle_named_share", run_dict) == pytest.approx(62.5)
+    _, waits = _recorded_run([], facts={"slot_wait_s": [.001] * 19 + [.5]})
+    assert _read("slot_wait_p95_ms", waits) == pytest.approx(
+        stats.percentile([.001] * 19 + [.5], 95) * 1e3)
+    _, train = _recorded_run(
+        [_span(1, None, "executor.run_steps", 900, 4100),
+         _span(2, 1, "executor.dispatch", 900, 1000)],
+        facts={"traced_steps": 2})
+    assert _read("executor_self_ms_per_step.train", train) == \
+        pytest.approx((3200 - 1800) / 2 * 1e-6)
+
+
+@pytest.mark.parametrize("name", [
+    "sched_self_ms_per_iteration", "seed_slot_p50_ms", "idle_named_share",
+    "executor_self_ms_per_step.serve", "executor_self_ms_per_step.train",
+    "slot_wait_p95_ms"])
+def test_new_readers_return_nothing_without_data(name):
+    # slot_wait_s is empty from a program without the always-on series
+    _, empty = _recorded_run([], facts={"traced_steps": 2,
+                                        "slot_wait_s": []})
+    assert _read(name, empty) is None
+    if name == "slot_wait_p95_ms":
+        return
+    # spans but no clock offset (a program without the tracing control)
+    _, no_clock = _recorded_run(SERVE_SPANS, session=False)
+    if name not in ("sched_self_ms_per_iteration", "seed_slot_p50_ms"):
+        assert _read(name, no_clock) is None
+    no_trace = dict(no_clock, trace=None)
+    if name not in ("sched_self_ms_per_iteration", "seed_slot_p50_ms"):
+        assert _read(name, no_trace) is None
+
+
+def test_breakdown_names_a_gap_by_the_covering_span():
+    harness, run_dict = _recorded_run(SERVE_SPANS)
+    # the one gap between device ops, [2000, 3000], lies in the fetch
+    assert harness.breakdown_of(run_dict)["idle_gaps"] == \
+        [["host:executor.fetch x1", pytest.approx(1000e-9)]]
+    harness, bare = _recorded_run([])
+    assert harness.breakdown_of(bare)["idle_gaps"] == \
+        [["after:while.1|before:all-reduce.3 x1", pytest.approx(1000e-9)]]
+
+
+def test_only_spans_between_the_clock_marks_reach_the_readers():
+    from lib import spanclock
+    session = {"t_start": (900 - OFFSET_NS) / 1e9,
+               "t_stop": (4250 - OFFSET_NS) / 1e9}
+    kept = spanclock.inside_session(SERVE_SPANS, session)
+    # the turn starts before the first mark, the seed ends after the
+    # second, the queue wait does both
+    assert [s["span_id"] for s in kept] == [3, 4, 5, 6, 7, 8]
+    assert spanclock.inside_session(SERVE_SPANS, None) == SERVE_SPANS
+
+
+def test_clock_agreement_reports_share_and_overhang():
+    from lib import spanclock
+    proof = {"clock_proof": {"span": "gen.decode_step",
+                             "holding": ["flash"]}}
+    _, run_dict = _recorded_run(SERVE_SPANS, facts=proof)
+    got = spanclock.agreement(run_dict)
+    assert got["contained_share"] == 1.0 and got["runs_paired"] == 1
+    assert got["largest_overhang_us"] == 0.0
+    late = [dict(s) for s in SERVE_SPANS]
+    late[2] = _span(3, 2, "gen.decode_step", 1100, 4100)
+    _, run_dict = _recorded_run(late, facts=proof)
+    got = spanclock.agreement(run_dict)
+    assert got["contained_share"] == 0.0
+    assert got["largest_overhang_us"] == pytest.approx(0.1)
+    _, none = _recorded_run(SERVE_SPANS)
+    assert spanclock.agreement(none) is None
 
 
 # -- generator ---------------------------------------------------------------
@@ -180,9 +314,11 @@ def test_the_committed_manifest_is_valid(good):
     lambda m: m["workloads"].append(dict(m["workloads"][0])),
     lambda m: m.update(run_seconds=52),
     lambda m: [w.update(chips=4) for w in m["workloads"]],
+    lambda m: m.update(trace_in_run=False),
+    lambda m: m.update(trace_in_the_run=True),
 ], ids=["moves", "unit-spaces", "unit-greek", "cell-space", "cell-slash",
         "metric-cell", "stray-key", "bound", "cell-twice", "run-seconds",
-        "too-many-four-chip"])
+        "too-many-four-chip", "trace-in-run-false", "unknown-top-key"])
 def test_manifest_refuses(good, breakage):
     bad = copy.deepcopy(good)
     breakage(bad)
@@ -264,14 +400,53 @@ def _rehearse(cell, toy, trace, seconds):
     return run.run_cell(cell, 2 ** 31 + 5, seconds, trace, rehearsal=toy)
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-def test_train_steps_rehearsal(trace):
+@pytest.fixture()
+def window_watch(monkeypatch):
+    """What tracing is on as the traffic module's ``window`` starts and
+    as it returns: a ``--trace 2`` run must be a ``--trace 0`` run until
+    then."""
+    import run
+    from paddle_tpu import profiler
+    from paddle_tpu.obs import trace
+    seen, real = [], run.load_module
+
+    def look(ctx):
+        seen.append({"traced": ctx["traced"], "ring_on": trace.enabled(),
+                     "spans": len(trace.snapshot_spans()),
+                     "profiling": profiler._session is not None})
+
+    def load(path, name):
+        module = real(path, name)
+        inner = getattr(module, "window", None)
+        if inner is not None:
+            def window(state, ctx, *args, **kwargs):
+                look(ctx)
+                out = inner(state, ctx, *args, **kwargs)
+                look(ctx)
+                return out
+            module.window = window
+        return module
+
+    trace.disable()
+    trace.clear()
+    monkeypatch.setattr(run, "load_module", load)
+    return seen
+
+
+ALL_OFF = {"traced": False, "ring_on": False, "spans": 0,
+           "profiling": False}
+
+
+@pytest.mark.parametrize("trace", [0, 1, 2])
+def test_train_steps_rehearsal(trace, window_watch):
     r = _rehearse("transformer_base.train_b256_s256", TOY_TRANSFORMER,
                   trace, 0.5)
     assert r["rehearsal"] and r["correct"] and r["failed"] == 0
     assert r["device"]["platform"] == "cpu"
     assert "busy_s" not in r["device"]
-    if trace:       # no device trace on the CPU: no device metric at all
+    if trace != 1:
+        assert window_watch == [ALL_OFF, ALL_OFF]
+    if trace == 1:  # no device trace on the CPU: no device metric at all
         assert r["metrics"] == {}
     else:
         assert set(r["metrics"]) == {"train_tokens_per_s_per_chip",
@@ -288,21 +463,81 @@ def test_train_mesh_rehearsal():
     assert r["correct"] and r["device"]["count"] >= 4
 
 
-@pytest.mark.parametrize("trace", [0, 1])
-def test_serve_open_loop_rehearsal(trace):
+SERVE_SPAN_METRICS = {
+    "prefill_p50_ms", "decode_step_p50_ms", "queue_wait_p50_ms",
+    "queue_wait_p95_ms", "executor_call_ms_per_step.serve",
+    "sched_self_ms_per_iteration", "seed_slot_p50_ms"}
+
+
+@pytest.mark.parametrize("trace", [0, 1, 2])
+def test_serve_open_loop_rehearsal(trace, window_watch):
     r = _rehearse("genlm_opt6.7b.chat_open", TOY_GENLM, trace, 3.0)
     assert r["rehearsal"] and r["correct"] and r["failed"] == 0
     assert r["attempted"] == 12
-    if trace:
-        assert "paged_attn_roofline" not in r["metrics"]
-        assert "decode_step_device_ms" not in r["metrics"]
-        assert {"prefill_p50_ms", "decode_step_p50_ms", "queue_wait_p50_ms",
-                "queue_wait_p95_ms", "executor_call_ms_per_step.serve"} \
-            <= set(r["metrics"])
-    else:
+    if trace != 1:
+        assert window_watch == [ALL_OFF, ALL_OFF]
         assert {"gap_p95_ms", "out_tokens_per_s", "setup_s"} \
             < set(r["metrics"])
         assert any(n.startswith("ttft_") for n in r["metrics"])
+    if trace:
+        # no device trace on the CPU: nothing that needs one
+        for name in ("paged_attn_roofline", "decode_step_device_ms",
+                     "idle_named_share", "executor_self_ms_per_step.serve"):
+            assert name not in r["metrics"]
+        assert SERVE_SPAN_METRICS <= set(r["metrics"])
+    else:
+        assert not SERVE_SPAN_METRICS & set(r["metrics"])
+    if trace == 2:      # both kinds side by side, from one process
+        assert {"ttft_p95_ms", "generator_late_p95_ms",
+                "slot_occupancy_mean"} <= set(r["metrics"])
+
+
+def test_a_failed_traced_stretch_keeps_the_measured_numbers(monkeypatch):
+    import run
+    real = run.load_module
+
+    def load(path, name):
+        module = real(path, name)
+        if hasattr(module, "traced"):
+            def traced(state, ctx):
+                raise RuntimeError("the traced stretch broke")
+            module.traced = traced
+        return module
+
+    monkeypatch.setattr(run, "load_module", load)
+    r = _rehearse("transformer_base.train_b256_s256", TOY_TRANSFORMER,
+                  2, 0.5)
+    assert r["correct"] and set(r["metrics"]) == {
+        "train_tokens_per_s_per_chip", "setup_s"}
+    from paddle_tpu import profiler
+    assert profiler._session is None
+
+
+def test_a_replay_that_stalls_is_given_up(monkeypatch, capsys):
+    import run
+    real = run.load_module
+
+    def load(path, name):
+        module = real(path, name)
+        if hasattr(module, "REPLAY_DRAIN_S"):
+            stream = module._stream
+
+            def stalling(client_cls, addr, ptrace, rid, *rest):
+                if rid.startswith("trace-"):
+                    time.sleep(30)
+                return stream(client_cls, addr, ptrace, rid, *rest)
+            module._stream = stalling
+            module.REPLAY_DRAIN_S = 0.5
+        return module
+
+    monkeypatch.setattr(run, "load_module", load)
+    t0 = time.perf_counter()
+    r = _rehearse("genlm_opt6.7b.chat_open", TOY_GENLM, 2, 3.0)
+    assert time.perf_counter() - t0 < 25     # did not wait the stall out
+    assert r["correct"] and r["failed"] == 0
+    assert {"gap_p95_ms", "out_tokens_per_s", "setup_s"} < set(r["metrics"])
+    assert not SERVE_SPAN_METRICS & set(r["metrics"])
+    assert "replayed requests failed" in capsys.readouterr().out
 
 
 def test_no_chip_means_no_result_line(capsys):

@@ -11,7 +11,9 @@ and ``output`` (lognormal ``median``/``sigma``/``min``/``cap``) and
 ``sample_seed`` -- all read by ``lib/openloop.build_schedule`` -- and
 ``reference_prompts`` (lengths), ``logits_tol`` (with its reason),
 ``trace_from`` (share of the window at which the traced part of a
-``--trace 1`` run starts) and ``trace_seconds``.
+``--trace 1`` run starts) and ``trace_seconds``.  A ``--trace 2`` run
+replays that part of the schedule after its measured window has drained
+(``traced``), ``LEAD_S`` seconds of lead-in before it.
 
 End-to-end, all from the client's side on the host's clock
 (``BENCHMARK.json`` says which of them a cell is judged by):
@@ -45,6 +47,16 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 from lib import openloop, stats  # noqa: E402
 from reference import gen_lm_ref  # noqa: E402
+
+# lead-in of a --trace 2 run's replayed slice: the schedule from this long
+# before the traced part, sent with spans on and the profiler still off,
+# so that the slots are as full as they were at that point of the window
+# (a stream lives ~1.2 s on the v5e)
+LEAD_S = 5.0
+# how long the replayed slice may take to drain after its last due time (a
+# stream lives ~1.2 s): a replay that stalls fails the traced stretch and
+# must not hold up the line with the measured numbers
+REPLAY_DRAIN_S = 10.0
 
 
 # ---------------------------------------------------------------------------
@@ -340,44 +352,87 @@ def _series(metrics, name):
     return entry.get("count") or 0, entry.get("total") or 0.0
 
 
+def _samples_since(metrics, name, count0):
+    """The samples of an always-on series since its count read
+    ``count0``; none from a program without that series or its reader."""
+    read = getattr(metrics, "samples", None)
+    n = _series(metrics, name)[0] - count0
+    return read(name, last=n) if read is not None and n > 0 else []
+
+
+def _trace_part(ctx, metrics, after_s, traced):
+    """On a thread of its own: ``after_s`` seconds from now, the device
+    profiler on for the cell's ``trace_seconds``; the paged kernel's
+    page counter read on either side."""
+    time.sleep(after_s)
+    traced["pages0"] = _series(metrics, "gen.paged.pages_touched")
+    ctx["tracer"].start()
+    time.sleep(float(ctx["workload"].get("trace_seconds", 5.0)))
+    traced["pages1"] = _series(metrics, "gen.paged.pages_touched")
+    ctx["tracer"].stop()
+
+
+def _traced_facts(cfg, ts_of, ok, traced):
+    """Facts the span and device-trace readers want: every request's due
+    time on the span clock (``ts_of`` maps a ``perf_counter`` reading
+    onto it), and the decode steps and live K/V rows of the traced
+    part."""
+    # the span that launches and waits for the decode executable, and an
+    # op found only in it (lib/spanclock.agreement)
+    facts = {"clock_proof": {"span": "gen.decode_step",
+                             "holding": ["ptop_paged_attention"]}}
+    if ts_of is not None:
+        facts["due_ts_by_request"] = {
+            r["rid"]: ts_of(r["request"]["due_t"]) for r in ok}
+    if "pages1" in traced:
+        facts["traced_decode_steps"] = \
+            traced["pages1"][0] - traced["pages0"][0]
+        facts["traced_live_rows"] = \
+            (traced["pages1"][1] - traced["pages0"][1]) \
+            * cfg["serving"]["page_len"]
+    return facts
+
+
+def _sender(state, ctx, prefix, schedule):
+    """``send(request)`` for :class:`openloop.OpenLoop`: the schedule's
+    prompts are made beforehand, the request id is ``prefix`` + index."""
+    cfg = ctx["config"]
+    prompts = {r["index"]: _prompt(cfg, ctx["seed31"], r["index"],
+                                   r["prompt_len"]) for r in schedule}
+
+    def send(request):
+        rid = f"{prefix}-{ctx['seed']}-{request['index']}"
+        rec = _stream(state["client_cls"], state["addr"], state["ptrace"],
+                      rid, prompts[request["index"]], request["max_new"])
+        rec["rid"] = rid
+        return rec
+    return send
+
+
 def window(state, ctx, schedule=None):
     cfg, wl, tracer = ctx["config"], ctx["workload"], ctx["tracer"]
     metrics, ptrace = state["metrics"], state["ptrace"]
     seconds = ctx["seconds"]
     schedule = schedule if schedule is not None else state["schedules"][0]
-    prompts = {r["index"]: _prompt(cfg, ctx["seed31"], r["index"],
-                                   r["prompt_len"]) for r in schedule}
-    tag = f"{ctx['seed']}"
-
-    def send(request):
-        rid = f"req-{tag}-{request['index']}"
-        rec = _stream(state["client_cls"], state["addr"], ptrace, rid,
-                      prompts[request["index"]], request["max_new"])
-        rec["rid"] = rid
-        return rec
+    send = _sender(state, ctx, "req", schedule)
 
     counter_names = ("compile.events", "compile_cache.misses",
                      "gen.paged.fallback", "gen.tokens", "gen.admissions")
     before = {n: metrics.counter(n) for n in counter_names}
     hist0 = dict(metrics.snapshot()["histograms"]
                  .get("gen.slot_occupancy", {}))
+    waits0 = _series(metrics, "gen.queue_wait_seconds")[0]
     traced = {}
-
-    def trace_part():
-        time.sleep(float(wl.get("trace_from", 0.3)) * seconds)
-        traced["pages0"] = _series(metrics, "gen.paged.pages_touched")
-        tracer.start()
-        time.sleep(float(wl.get("trace_seconds", 5.0)))
-        traced["pages1"] = _series(metrics, "gen.paged.pages_touched")
-        tracer.stop()
-
     tracer_thread = None
     if ctx["traced"]:
         ptrace.clear()
         with ptrace.span("bench.clock_mark"):
             mark_t = time.perf_counter()
         if tracer.enabled:
-            tracer_thread = threading.Thread(target=trace_part, daemon=True)
+            tracer_thread = threading.Thread(
+                target=_trace_part, daemon=True,
+                args=(ctx, metrics, float(wl.get("trace_from", 0.3))
+                      * seconds, traced))
             tracer_thread.start()
 
     loop = openloop.OpenLoop(schedule, send)
@@ -388,6 +443,7 @@ def window(state, ctx, schedule=None):
         tracer_thread.join(60)
 
     after = {n: metrics.counter(n) for n in counter_names}
+    slot_waits = _samples_since(metrics, "gen.queue_wait_seconds", waits0)
     hist1 = metrics.snapshot()["histograms"].get("gen.slot_occupancy", {})
     counters = {n: after[n] - before[n] for n in counter_names}
     counters["hist:gen.slot_occupancy"] = {
@@ -395,6 +451,7 @@ def window(state, ctx, schedule=None):
         if v - hist0.get(k, 0) > 0}
 
     ok = [r for r in records if r.get("ok")]
+    state["window_times"] = (loop.t_start, [r["times"] for r in ok])
     bad_shape = [r["rid"] for r in ok
                  if r["indices"] != list(range(r["request"]["max_new"]))]
     ttft = [r["times"][0] - r["request"]["due_t"] for r in ok if r["times"]]
@@ -409,23 +466,18 @@ def window(state, ctx, schedule=None):
     if gaps:
         end_to_end["gap_p95_ms"] = stats.percentile(gaps, 95) * 1e3
 
+    # slot_wait_s: the program's own account of every wait for a slot in
+    # the window, from the stream's creation (always on)
     spans, facts = [], {"lateness_s": openloop.OpenLoop.lateness(records),
-                        "ttft_s": ttft}
+                        "ttft_s": ttft, "slot_wait_s": slot_waits}
     if ctx["traced"]:
         spans = ptrace.snapshot_spans()
         mark = next((s for s in spans if s["name"] == "bench.clock_mark"),
                     None)
-        if mark is not None:
-            # span clock = perf_counter - offset
-            offset = mark_t - mark["ts"]
-            facts["due_ts_by_request"] = {
-                r["rid"]: r["request"]["due_t"] - offset for r in ok}
-        if "pages1" in traced:
-            facts["traced_decode_steps"] = \
-                traced["pages1"][0] - traced["pages0"][0]
-            facts["traced_live_rows"] = \
-                (traced["pages1"][1] - traced["pages0"][1]) \
-                * cfg["serving"]["page_len"]
+        # span clock = perf_counter - offset
+        facts.update(_traced_facts(
+            cfg, (lambda t: t - (mark_t - mark["ts"])) if mark else None,
+            ok, traced))
     return {
         "end_to_end": end_to_end,
         "attempted": len(schedule),
@@ -453,9 +505,76 @@ def window(state, ctx, schedule=None):
                 facts["lateness_s"], 95) * 1e3 if facts["lateness_s"]
                 else None,
             "drain_s": time.perf_counter() - t_end,
+            "slot_waits": len(slot_waits),
+            **{f"slot_wait_p{q}_ms": stats.percentile(slot_waits, q) * 1e3
+               for q in (50, 95) if slot_waits},
             "counters": {k: v for k, v in counters.items()
                          if not k.startswith("hist:")}},
     }
+
+
+def traced(state, ctx):
+    """The traced stretch of a ``--trace 2`` run, after the measured
+    window has drained: the slice of the cell's own schedule that a
+    ``--trace 1`` run traces (``trace_from`` x seconds, for
+    ``trace_seconds``) with ``LEAD_S`` seconds before it, replayed open
+    loop from its due times with the same prompts and fresh request ids.
+    The program's spans are on for the whole slice, the device profiler
+    after the lead-in."""
+    cfg, wl = ctx["config"], ctx["workload"]
+    metrics, ptrace = state["metrics"], state["ptrace"]
+    t_from = float(wl.get("trace_from", 0.3)) * ctx["seconds"]
+    lead = min(LEAD_S, t_from)
+    length = lead + float(wl.get("trace_seconds", 5.0))
+    piece = [dict(r, due=r["due"] - (t_from - lead))
+             for r in state["schedules"][0]
+             if t_from - lead <= r["due"] < t_from - lead + length]
+
+    part, sent = {}, {}
+    loop = openloop.OpenLoop(piece, _sender(state, ctx, "trace", piece))
+    ptrace.enable(1 << 18)
+    ptrace.clear()
+    try:
+        # the requests go out from a thread and the profiler is started
+        # and stopped on this one, the harness's main thread, where
+        # stopping is three times as fast (PERF.md)
+        sender = threading.Thread(
+            target=lambda: sent.update(records=loop.run(
+                drain_timeout=REPLAY_DRAIN_S)), daemon=True)
+        sender.start()
+        _trace_part(ctx, metrics, lead, part)
+        sender.join(length + 2 * REPLAY_DRAIN_S)
+        if "records" not in sent:
+            raise RuntimeError("the replayed slice did not drain")
+        spans = ptrace.snapshot_spans()
+    finally:
+        ptrace.disable()
+    records = sent["records"]
+    ok = [r for r in records if r.get("ok")]
+    if len(ok) != len(piece):
+        raise RuntimeError(f"{len(piece) - len(ok)} of {len(piece)} "
+                           f"replayed requests failed")
+    # what tracing costs, like for like: the median token gap of the
+    # lead-in (spans on) and of the traced part (spans + profiler) beside
+    # the same stretches of the measured window (all tracing off)
+    def gap_p50_ms(times, t0, a, b):
+        gaps = [y - x for ts in times for x, y in zip(ts, ts[1:])
+                if t0 + a <= x and y < t0 + b]
+        return stats.percentile(gaps, 50) * 1e3 if gaps else None
+
+    mine = [r["times"] for r in ok]
+    w_start, w_times = state["window_times"]
+    return {"spans": spans,
+            "facts": _traced_facts(cfg, ptrace.ts_of, ok, part),
+            "observed": {
+                "replayed_requests": len(piece), "lead_s": lead,
+                "lead_gap_p50_ms": gap_p50_ms(mine, loop.t_start, 0, lead),
+                "lead_gap_p50_ms_in_window": gap_p50_ms(
+                    w_times, w_start, t_from - lead, t_from),
+                "traced_gap_p50_ms": gap_p50_ms(mine, loop.t_start, lead,
+                                                length),
+                "traced_gap_p50_ms_in_window": gap_p50_ms(
+                    w_times, w_start, t_from, t_from - lead + length)}}
 
 
 def verify(state, ctx, raw):

@@ -16,7 +16,8 @@ Parameters (``benchmark/workloads/<cell>.json``):
 ``reference_rows``   sequences compared with the plain reference
 ``loss_rtol``        tolerance of that comparison (reason in the file)
 ``trace_calls``      blocking calls inside the traced part of a
-                     ``--trace 1`` run
+                     ``--trace 1`` run, and in the traced stretch after
+                     the measured window of a ``--trace 2`` run
 
 End-to-end: ``train_tokens_per_s_per_chip`` = target-side tokens of every
 call finished in the window over the window's seconds (the clock stops
@@ -165,6 +166,12 @@ def setup(ctx):
                                           steps=steps)   # numpy: blocks
                 return np.asarray(losses, np.float64).reshape(-1)
         state["call"], state["runner"] = call, runner
+        # the span that launches and waits for the step's executable,
+        # and an op found only in it (lib/spanclock.agreement)
+        state["clock_proof"] = \
+            {"span": "executor.run", "holding": ["all-reduce"]} \
+            if wl["runner"] == "parallel_run" \
+            else {"span": "executor.run_steps", "holding": ["while"]}
 
         # -- correctness, outside the window: the test-mode forward of
         # the program against the plain reference on the first rows
@@ -260,6 +267,7 @@ def window(state, ctx):
                 - state["fallback0"]},
         "facts": {"batch_per_chip": int(wl["batch"]),
                   "global_batch": state["batch"], "seq": state["seq"],
+                  "clock_proof": state["clock_proof"],
                   "steps_per_call": steps, "traced_calls": traced_calls,
                   "traced_steps": traced_calls * steps,
                   "tokens_per_step_per_chip": int(wl["batch"]) * state["seq"],
@@ -276,6 +284,35 @@ def window(state, ctx):
                      "call_seconds": durations[:12],
                      "window_loss_means": means[:6]},
     }
+
+
+def traced(state, ctx):
+    """The traced stretch of a ``--trace 2`` run: ``trace_calls`` more
+    blocking calls of the window's own ``call``, with the program's
+    spans and the device profiler on."""
+    ptrace, tracer = state["ptrace"], ctx["tracer"]
+    calls = int(ctx["workload"].get("trace_calls", 2))
+    ptrace.enable(1 << 16)
+    ptrace.clear()
+    try:
+        with state["fluid"].scope_guard(state["scope"]):
+            tracer.start()
+            t0 = time.perf_counter()
+            for i in range(calls):
+                losses = state["call"](i + 1)
+            elapsed = time.perf_counter() - t0
+            tracer.stop()
+        spans = ptrace.snapshot_spans()
+    finally:
+        ptrace.disable()
+    if not np.all(np.isfinite(losses)):
+        raise FloatingPointError(f"non-finite loss {losses}")
+    return {"spans": spans,
+            "facts": {"traced_calls": calls,
+                      "traced_steps": calls * state["steps"],
+                      "clock_proof": state["clock_proof"]},
+            "observed": {"traced_step_ms": 1e3 * elapsed
+                         / (calls * state["steps"])}}
 
 
 def verify(state, ctx, raw):

@@ -16,6 +16,7 @@ launch — no Python per-op work at all.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 
@@ -36,6 +37,7 @@ __all__ = ["Executor", "fetch_var", "enable_compile_cache",
            "disable_compile_cache", "jit_cache_capacity"]
 
 logger = logging.getLogger(__name__)
+_NO_SPAN = contextlib.nullcontext()
 
 # op types that exist for API parity but are no-ops inside a lowered block
 from paddle_tpu.ops.reader_ops import (READER_CREATE_OPS, READER_OPS,
@@ -220,6 +222,7 @@ class _CompiledBlock:
         self.inout_names = inout_names
         self.fetch_names = fetch_names
         self.uses_rng = uses_rng
+        self.fresh = True   # until its first call, which compiles it
 
 
 class ScopeEnv(dict):
@@ -388,6 +391,19 @@ class Executor:
         self._cache[sig] = value
         self._cache_inserts += 1
 
+    @staticmethod
+    def _compile_span(fresh, program, feed_arrays):
+        """``executor.compile`` around the first call of an executable a
+        jit-cache miss has just built: ``jax.jit`` traces, lowers and
+        compiles inside that call, so the span says WHICH call
+        recompiled and for how long.  Nothing on a cache hit."""
+        if not fresh:
+            return _NO_SPAN
+        return _span("executor.compile", program=id(program),
+                     version=program._version,
+                     feeds=sorted((n, str(a.dtype), tuple(a.shape))
+                                  for n, a in feed_arrays.items()))
+
     # ------------------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
             return_numpy=True, use_program_cache=True, sentinel=None):
@@ -530,8 +546,10 @@ class Executor:
                 (program.random_seed or 0) * 1000003 + self._run_counter)
 
             t0 = time.perf_counter()
-            fetches, new_state = compiled.fn(feed_arrays, ro_state,
-                                             inout_state, key)
+            fresh, compiled.fresh = compiled.fresh, False
+            with self._compile_span(fresh, program, feed_arrays):
+                fetches, new_state = compiled.fn(feed_arrays, ro_state,
+                                                 inout_state, key)
             dsp.set(fetches=len(fetch_names))
         dt = time.perf_counter() - t0
         from paddle_tpu import profiler as _profiler
@@ -742,180 +760,199 @@ class Executor:
         program = self._maybe_optimize(program, feed, fetch_names)
         block = program.global_block()
 
-        device = self._feed_device()
-        per_step_feed = {}
-        const_feed = {}
+        with _span("executor.run_steps", steps=steps):
+            return self._run_steps_traced(program, block, feed,
+                                          fetch_names, steps, scope,
+                                          return_numpy)
 
-        def is_lod_pair(v):
-            return isinstance(v, tuple) and len(v) == 2 and \
-                isinstance(v[1], (list, tuple))
+    def _run_steps_traced(self, program, block, feed, fetch_names, steps,
+                          scope, return_numpy):
+        """Body of :meth:`run_steps` in the three phases :meth:`run` has,
+        under the same span names: ``executor.feed`` (staging the window's
+        batches), ``executor.dispatch`` (compile lookup, state gather, the
+        one launch) and ``executor.fetch`` (state write-back and the host
+        conversion, which blocks until the device is done)."""
+        with _span("executor.feed"):
+            device = self._feed_device()
+            per_step_feed = {}
+            const_feed = {}
 
-        for name, value in feed.items():
-            if isinstance(value, list) and value and \
-                    all(is_lod_pair(v) for v in value):
-                # per-step ragged batches: bucketed mode pads the whole
-                # window to ONE bucket signature and threads the
-                # row-splits through the device-side loop as data — the
-                # streaming-LoD counterpart of the stacked dense feed
-                if not _lod_buckets_enabled(program):
+            def is_lod_pair(v):
+                return isinstance(v, tuple) and len(v) == 2 and \
+                    isinstance(v[1], (list, tuple))
+
+            for name, value in feed.items():
+                if isinstance(value, list) and value and \
+                        all(is_lod_pair(v) for v in value):
+                    # per-step ragged batches: bucketed mode pads the whole
+                    # window to ONE bucket signature and threads the
+                    # row-splits through the device-side loop as data — the
+                    # streaming-LoD counterpart of the stacked dense feed
+                    if not _lod_buckets_enabled(program):
+                        raise ValueError(
+                            f"run_steps got per-step LoD feeds for {name!r}; "
+                            f"enable bucketed mode (program.lod_buckets = "
+                            f"True) so the window shares one executable")
+                    if len(value) != steps:
+                        raise ValueError(
+                            f"run_steps: {name!r} has {len(value)} ragged "
+                            f"batches for {steps} steps")
+                    from paddle_tpu.lod import (bucket_ragged_feed,
+                                                next_bucket, SPLITS_SUFFIX)
+                    var = block.var(name) if block.has_var(name) else None
+                    dtype = var.dtype if var is not None else None
+                    rows = [np.asarray(v[0]).shape[0] for v in value]
+                    mls = []
+                    n_seqs = set()
+                    for _, lod in value:
+                        sp = np.asarray(lod[-1], np.int64)
+                        lens = sp[1:] - sp[:-1]
+                        mls.append(int(lens.max()) if len(lens) else 0)
+                        n_seqs.add(len(sp) - 1)
+                    if len(n_seqs) != 1:
+                        raise ValueError(
+                            f"run_steps: {name!r} batches disagree on "
+                            f"sequence count {sorted(n_seqs)}")
+                    nb = next_bucket(max(max(rows), 1))
+                    tb = next_bucket(max(max(mls), 1))
+                    padded_steps, splits_steps = [], []
+                    meta = None
+                    for v, lod in value:
+                        padded, splits, meta = bucket_ragged_feed(
+                            name, np.asarray(v), lod, n_bucket=nb,
+                            t_bucket=tb)
+                        padded_steps.append(padded)
+                        splits_steps.append(splits)
+                    per_step_feed[name] = _as_device_array(
+                        np.stack(padded_steps), dtype, device)
+                    per_step_feed[name + SPLITS_SUFFIX] = _as_device_array(
+                        np.stack(splits_steps), "int32", device)
+                    scope.set_lod(name, meta)
+                    continue
+                if is_lod_pair(value):
                     raise ValueError(
-                        f"run_steps got per-step LoD feeds for {name!r}; "
-                        f"enable bucketed mode (program.lod_buckets = "
-                        f"True) so the window shares one executable")
-                if len(value) != steps:
-                    raise ValueError(
-                        f"run_steps: {name!r} has {len(value)} ragged "
-                        f"batches for {steps} steps")
-                from paddle_tpu.lod import (bucket_ragged_feed,
-                                            next_bucket, SPLITS_SUFFIX)
+                        f"run_steps does not support a single LoD feed (got "
+                        f"one for {name!r}); pass a LIST of per-step "
+                        f"(value, lod) batches under program.lod_buckets, "
+                        f"or bucket/pad ragged batches and use run()")
                 var = block.var(name) if block.has_var(name) else None
                 dtype = var.dtype if var is not None else None
-                rows = [np.asarray(v[0]).shape[0] for v in value]
-                mls = []
-                n_seqs = set()
-                for _, lod in value:
-                    sp = np.asarray(lod[-1], np.int64)
-                    lens = sp[1:] - sp[:-1]
-                    mls.append(int(lens.max()) if len(lens) else 0)
-                    n_seqs.add(len(sp) - 1)
-                if len(n_seqs) != 1:
-                    raise ValueError(
-                        f"run_steps: {name!r} batches disagree on "
-                        f"sequence count {sorted(n_seqs)}")
-                nb = next_bucket(max(max(rows), 1))
-                tb = next_bucket(max(max(mls), 1))
-                padded_steps, splits_steps = [], []
-                meta = None
-                for v, lod in value:
-                    padded, splits, meta = bucket_ragged_feed(
-                        name, np.asarray(v), lod, n_bucket=nb,
-                        t_bucket=tb)
-                    padded_steps.append(padded)
-                    splits_steps.append(splits)
-                per_step_feed[name] = _as_device_array(
-                    np.stack(padded_steps), dtype, device)
-                per_step_feed[name + SPLITS_SUFFIX] = _as_device_array(
-                    np.stack(splits_steps), "int32", device)
-                scope.set_lod(name, meta)
-                continue
-            if is_lod_pair(value):
-                raise ValueError(
-                    f"run_steps does not support a single LoD feed (got "
-                    f"one for {name!r}); pass a LIST of per-step "
-                    f"(value, lod) batches under program.lod_buckets, "
-                    f"or bucket/pad ragged batches and use run()")
-            var = block.var(name) if block.has_var(name) else None
-            dtype = var.dtype if var is not None else None
-            arr = _as_device_array(value, dtype, device)
-            want_shape = tuple(var.shape) \
-                if var is not None and var.shape is not None else None
-            # an array with exactly one extra leading dim of length `steps`
-            # is treated as stacked per-step batches (documented behavior;
-            # reshape away any coincidental match)
-            if want_shape is not None and arr.ndim == len(want_shape) + 1 \
-                    and arr.shape[0] == steps:
-                per_step_feed[name] = arr        # stacked [steps, ...]
-            else:
-                const_feed[name] = arr           # one batch, reused
-            scope.set_lod(name, None)
+                arr = _as_device_array(value, dtype, device)
+                want_shape = tuple(var.shape) \
+                    if var is not None and var.shape is not None else None
+                # an array with exactly one extra leading dim of length `steps`
+                # is treated as stacked per-step batches (documented behavior;
+                # reshape away any coincidental match)
+                if want_shape is not None and arr.ndim == len(want_shape) + 1 \
+                        and arr.shape[0] == steps:
+                    per_step_feed[name] = arr        # stacked [steps, ...]
+                else:
+                    const_feed[name] = arr           # one batch, reused
+                scope.set_lod(name, None)
 
-        # reader ops: pull `steps` batches and ride the per-step axis of
-        # the device-side loop (double-buffer + scan = the full pipeline)
-        reader_feed = {}
-        _run_reader_ops(block, scope, reader_feed, device, steps=steps)
-        per_step_feed.update(reader_feed)
+            # reader ops: pull `steps` batches and ride the per-step axis of
+            # the device-side loop (double-buffer + scan = the full pipeline)
+            reader_feed = {}
+            _run_reader_ops(block, scope, reader_feed, device, steps=steps)
+            per_step_feed.update(reader_feed)
 
-        sample = dict(const_feed)
-        sample.update({n: a[0] for n, a in per_step_feed.items()})
-        parts = self._prepare(program, block, sample, tuple(fetch_names),
-                              scope)
-        sig = parts["sig"] + ("run_steps", steps,
-                              tuple(sorted(per_step_feed)))
-        step = parts["step"]
-        inout_names = parts["inout_names"]
-        create_state = parts["create_state"]
-        ro_names = parts["ro_names"]
+        with _span("executor.dispatch"):
+            sample = dict(const_feed)
+            sample.update({n: a[0] for n, a in per_step_feed.items()})
+            parts = self._prepare(program, block, sample, tuple(fetch_names),
+                                  scope)
+            sig = parts["sig"] + ("run_steps", steps,
+                                  tuple(sorted(per_step_feed)))
+            step = parts["step"]
+            inout_names = parts["inout_names"]
+            create_state = parts["create_state"]
+            ro_names = parts["ro_names"]
 
-        ro_state = {n: self._state_value(scope, n, device)
-                    for n in ro_names}
-        inout_state = {n: self._state_value(scope, n, device)
-                       for n in inout_names}
+            ro_state = {n: self._state_value(scope, n, device)
+                        for n in ro_names}
+            inout_state = {n: self._state_value(scope, n, device)
+                           for n in inout_names}
 
-        self._run_counter += 1
-        base_key = jax.random.PRNGKey(
-            (program.random_seed or 0) * 1000003 + self._run_counter)
+            self._run_counter += 1
+            base_key = jax.random.PRNGKey(
+                (program.random_seed or 0) * 1000003 + self._run_counter)
 
-        if parts["interpret"]:
-            # host ops: plain Python loop (still correct, just not fused)
-            keys = jax.random.split(base_key, steps)
-            outs = []
-            for i in range(steps):
-                feeds_i = dict(const_feed)
-                feeds_i.update({n: a[i] for n, a in per_step_feed.items()})
-                fetches, new_state = step(feeds_i, ro_state, inout_state,
-                                          keys[i])
-                inout_state = dict(inout_state)
-                inout_state.update(new_state)
-                outs.append(fetches)
-            for n, v in inout_state.items():
-                scope.set_var(n, v)
-            stacked = [jnp.stack([o[i] for o in outs])
-                       for i in range(len(fetch_names))]
-            return [np.asarray(v) for v in stacked] if return_numpy \
-                else stacked
-
-        from paddle_tpu import profiler as _profiler
-        if sig in self._cache:
-            self._cache[sig] = self._cache.pop(sig)
-            fn = self._cache[sig]
-            _profiler.runtime_metrics.inc("jit_cache.hits")
-        else:
-            _profiler.runtime_metrics.inc("jit_cache.misses")
-            def multi(const_feeds, per_feeds, ro_state, carry, base_key):
+            if parts["interpret"]:
+                # host ops: plain Python loop (still correct, just not fused)
                 keys = jax.random.split(base_key, steps)
+                outs = []
+                for i in range(steps):
+                    feeds_i = dict(const_feed)
+                    feeds_i.update({n: a[i] for n, a in per_step_feed.items()})
+                    fetches, new_state = step(feeds_i, ro_state, inout_state,
+                                              keys[i])
+                    inout_state = dict(inout_state)
+                    inout_state.update(new_state)
+                    outs.append(fetches)
+                for n, v in inout_state.items():
+                    scope.set_var(n, v)
+                stacked = [jnp.stack([o[i] for o in outs])
+                           for i in range(len(fetch_names))]
+                return [np.asarray(v) for v in stacked] if return_numpy \
+                    else stacked
 
-                def body(carry, xs):
-                    key, step_feeds = xs
-                    feeds = dict(const_feeds)
-                    feeds.update(step_feeds)
-                    fetches, new_state = step(feeds, ro_state, carry, key)
-                    new_carry = {n: new_state.get(n, carry[n])
-                                 for n in carry}
-                    return new_carry, tuple(fetches)
+            from paddle_tpu import profiler as _profiler
+            fresh = sig not in self._cache
+            if not fresh:
+                self._cache[sig] = self._cache.pop(sig)
+                fn = self._cache[sig]
+                _profiler.runtime_metrics.inc("jit_cache.hits")
+            else:
+                _profiler.runtime_metrics.inc("jit_cache.misses")
+                def multi(const_feeds, per_feeds, ro_state, carry, base_key):
+                    keys = jax.random.split(base_key, steps)
 
-                carry, ys = jax.lax.scan(body, carry, (keys, per_feeds))
-                return ys, carry
+                    def body(carry, xs):
+                        key, step_feeds = xs
+                        feeds = dict(const_feeds)
+                        feeds.update(step_feeds)
+                        fetches, new_state = step(feeds, ro_state, carry, key)
+                        new_carry = {n: new_state.get(n, carry[n])
+                                     for n in carry}
+                        return new_carry, tuple(fetches)
 
-            fn = jax.jit(multi, donate_argnums=(3,))
-            from paddle_tpu.obs import perf as _perf
-            if _perf.capture_enabled():
-                fn = _perf.instrument_jit(
-                    fn, label=_perf.jit_label(
-                        per_step_feed or const_feed, fetch_names,
-                        tag=f"scan{steps}"))
-            self._cache_insert(sig, fn)
+                    carry, ys = jax.lax.scan(body, carry, (keys, per_feeds))
+                    return ys, carry
 
-        carry = dict(inout_state)
-        # write-only persistables (create_state) ride the carry too so the
-        # final value lands back in the scope like run() does; uninitialized
-        # ones are seeded with zeros of their traced shape
-        missing = [n for n in create_state if n not in carry]
-        seeded = [n for n in missing if scope.find_var(n) is not None]
-        for n in seeded:
-            carry[n] = self._state_value(scope, n, device)
-        still = [n for n in missing if n not in carry]
-        if still:
-            _, out_shapes = jax.eval_shape(
-                step, sample, ro_state, inout_state, jax.random.PRNGKey(0))
-            for n in still:
-                if n in out_shapes:
-                    sd = out_shapes[n]
-                    carry[n] = jnp.zeros(sd.shape, sd.dtype)
-        t0 = time.perf_counter()
-        ys, final = fn(const_feed, per_step_feed, ro_state, carry, base_key)
-        for n, v in final.items():
-            scope.set_var(n, v)
-        result = [np.asarray(v) for v in ys] if return_numpy else list(ys)
+                fn = jax.jit(multi, donate_argnums=(3,))
+                from paddle_tpu.obs import perf as _perf
+                if _perf.capture_enabled():
+                    fn = _perf.instrument_jit(
+                        fn, label=_perf.jit_label(
+                            per_step_feed or const_feed, fetch_names,
+                            tag=f"scan{steps}"))
+                self._cache_insert(sig, fn)
+
+            carry = dict(inout_state)
+            # write-only persistables (create_state) ride the carry too so the
+            # final value lands back in the scope like run() does; uninitialized
+            # ones are seeded with zeros of their traced shape
+            missing = [n for n in create_state if n not in carry]
+            seeded = [n for n in missing if scope.find_var(n) is not None]
+            for n in seeded:
+                carry[n] = self._state_value(scope, n, device)
+            still = [n for n in missing if n not in carry]
+            if still:
+                _, out_shapes = jax.eval_shape(
+                    step, sample, ro_state, inout_state, jax.random.PRNGKey(0))
+                for n in still:
+                    if n in out_shapes:
+                        sd = out_shapes[n]
+                        carry[n] = jnp.zeros(sd.shape, sd.dtype)
+            t0 = time.perf_counter()
+            with self._compile_span(fresh, program, sample):
+                ys, final = fn(const_feed, per_step_feed, ro_state, carry,
+                               base_key)
+        with _span("executor.fetch"):
+            for n, v in final.items():
+                scope.set_var(n, v)
+            result = [np.asarray(v) for v in ys] if return_numpy \
+                else list(ys)
         from paddle_tpu.obs import perf as _perf
         gauge = _mfu_gauge_for(program)
         if return_numpy and gauge:

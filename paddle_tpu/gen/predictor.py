@@ -362,8 +362,11 @@ class GenPredictor:
         O(num_slots * max_len).  Paged bundles write the slot's
         ALLOCATED pages instead (prompt rows + zero fill — re-used
         pages carry no stale rows), so the per-admission transfer is
-        O(pages_needed * page_len)."""
+        O(pages_needed * page_len).  Returns the number of arrays
+        written, each by one eager device operation
+        (``gen.seed.eager_ops`` counts them)."""
         import jax.numpy as jnp
+        from paddle_tpu.profiler import runtime_metrics
         with self._lock:
             if self.paged:
                 pages = self._slot_pages.get(slot)
@@ -380,13 +383,15 @@ class GenPredictor:
                     buf.reshape(-1, arr.shape[2])[:rows] = arr[0, :rows]
                     cache = jnp.asarray(self._scope.find_var(name))
                     self._scope.set_var(name, cache.at[idx].set(buf))
-                return
-            for name, arr in zip(self.cache_vars, kv):
-                rows = min(arr.shape[1], self.max_len)
-                row = np.zeros((self.max_len, arr.shape[2]), arr.dtype)
-                row[:rows] = arr[0, :rows]
-                cache = jnp.asarray(self._scope.find_var(name))
-                self._scope.set_var(name, cache.at[slot].set(row))
+            else:
+                for name, arr in zip(self.cache_vars, kv):
+                    rows = min(arr.shape[1], self.max_len)
+                    row = np.zeros((self.max_len, arr.shape[2]), arr.dtype)
+                    row[:rows] = arr[0, :rows]
+                    cache = jnp.asarray(self._scope.find_var(name))
+                    self._scope.set_var(name, cache.at[slot].set(row))
+        runtime_metrics.inc("gen.seed.eager_ops", len(self.cache_vars))
+        return len(self.cache_vars)
 
     def clear_slot(self, slot):
         """Zero a reclaimed slot's cache rows (device-side slice
@@ -394,19 +399,18 @@ class GenPredictor:
         whole row (or, paged, seeds every re-allocated page) — but
         keeps a freed slot from pinning stale request data."""
         import jax.numpy as jnp
+        from paddle_tpu.profiler import runtime_metrics
         with self._lock:
+            where = slot
             if self.paged:
                 pages = self._slot_pages.get(slot)
                 if not pages:
                     return
-                idx = np.asarray(pages, np.int64)
-                for name in self.cache_vars:
-                    cache = jnp.asarray(self._scope.find_var(name))
-                    self._scope.set_var(name, cache.at[idx].set(0.0))
-                return
+                where = np.asarray(pages, np.int64)
             for name in self.cache_vars:
                 cache = jnp.asarray(self._scope.find_var(name))
-                self._scope.set_var(name, cache.at[slot].set(0.0))
+                self._scope.set_var(name, cache.at[where].set(0.0))
+        runtime_metrics.inc("gen.seed.eager_ops", len(self.cache_vars))
 
     # -- decode ------------------------------------------------------------
     def decode_step(self, tokens, positions, pos_onehot=None,

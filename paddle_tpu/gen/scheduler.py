@@ -387,23 +387,29 @@ class GenScheduler:
                     queued, self._queue = self._queue, []
                     active, self._slots = list(self._slots.items()), {}
                     break
-            # kill/migrate run HERE — between decode iterations, the
-            # only point every live stream is at a whole-token boundary
-            if self._abort_exc is not None:
-                self._do_abort()
-            if self._migrate_req:
-                self._do_migrate()
-            self._sweep_queue()
-            self._admit()
-            if self._slots:
-                self._decode_iteration()
-                # a completed iteration is forward progress: the restart
-                # budget bounds CONSECUTIVE crashes, not lifetime ones
-                with self._cv:
-                    self._restarts = 0
-            _profiler.runtime_metrics.set_gauge("gen.slots_active",
-                                                len(self._slots))
-            _slo_tick(self.slo_watchdog)
+            # one turn that has work: parent of every span below on
+            # this thread; its self time is the sweep, the gauge, the
+            # SLO tick and the lock waits
+            with _span("gen.sched.turn"):
+                # kill/migrate run HERE — between decode iterations, the
+                # only point every live stream is at a whole-token
+                # boundary
+                if self._abort_exc is not None:
+                    self._do_abort()
+                if self._migrate_req:
+                    self._do_migrate()
+                self._sweep_queue()
+                self._admit()
+                if self._slots:
+                    self._decode_iteration()
+                    # a completed iteration is forward progress: the
+                    # restart budget bounds CONSECUTIVE crashes, not
+                    # lifetime ones
+                    with self._cv:
+                        self._restarts = 0
+                _profiler.runtime_metrics.set_gauge("gen.slots_active",
+                                                    len(self._slots))
+                _slo_tick(self.slo_watchdog)
         # shutdown discards the slots wholesale; return their pages so
         # a later scheduler over the SAME predictor starts with a full
         # pool (the test suite reuses warmed predictors this way)
@@ -540,6 +546,14 @@ class GenScheduler:
                 stream = self._queue.pop(0)
                 slot_idx = self._free.pop(0)
                 self._admitting += 1
+                queued_behind = len(self._queue)
+            now = time.perf_counter()
+            waited = now - stream.created_t
+            _profiler.runtime_metrics.observe("gen.queue_wait_seconds",
+                                              waited)
+            _trace.record_span("gen.queue_wait", stream.created_t, waited,
+                               trace_id=stream.trace_id,
+                               queued_behind=queued_behind)
             if self.prefill_budget is not None:
                 cost = self.predictor.prefill_cost(len(stream.prompt))
                 spent += cost
@@ -558,45 +572,55 @@ class GenScheduler:
     def _prefill_into(self, slot_idx, stream):
         """Prefill one request and seed its slot; returns True when the
         slot stays occupied (request still generating)."""
+        with _trace.trace_context(stream.trace_id):
+            with _span("gen.admit", slot=slot_idx):
+                return self._admit_one(slot_idx, stream)
+
+    def _admit_one(self, slot_idx, stream):
         from paddle_tpu import profiler as _profiler
         t0 = time.perf_counter()
-        with _trace.trace_context(stream.trace_id):
-            try:
-                logits, kv = self.predictor.prefill(stream.prompt)
-            except BaseException as e:
-                stream.fail(e)
-                return False
+        try:
+            logits, kv = self.predictor.prefill(stream.prompt)
+        except BaseException as e:
+            stream.fail(e)
+            return False
         # counted only when prefill actually ran for an admitted
         # request — a failed prefill above never takes the slot
         _profiler.runtime_metrics.inc("gen.admissions")
         _profiler.runtime_metrics.observe("gen.prefill_seconds",
                                           time.perf_counter() - t0)
-        first = int(np.argmax(logits))
-        now = time.perf_counter()
-        _profiler.runtime_metrics.observe("gen.ttft_seconds",
-                                          now - stream.created_t)
-        _profiler.runtime_metrics.inc("gen.tokens")
-        stream.emit(first)
+        with _span("gen.first_token"):
+            first = int(np.argmax(logits))
+            now = time.perf_counter()
+            _profiler.runtime_metrics.observe("gen.ttft_seconds",
+                                              now - stream.created_t)
+            _profiler.runtime_metrics.inc("gen.tokens")
+            stream.emit(first)
         prompt_len = len(stream.prompt)
         if first == stream.eos_id:
             return self._finish(stream, "eos")
         if stream.max_new_tokens <= 1 or prompt_len >= self.predictor.max_len:
             return self._finish(stream, "length")
-        if getattr(self.predictor, "paged", False):
-            try:
-                self.predictor.alloc_slot_pages(
-                    slot_idx, self.predictor.pages_needed(
-                        prompt_len, stream.max_new_tokens))
-            except BaseException as e:
-                stream.fail(e)
-                return False
-            try:
-                self.predictor.write_slot(slot_idx, kv, prompt_len)
-            except BaseException:
-                self.predictor.free_slot_pages(slot_idx)
-                raise
-        else:
-            self.predictor.write_slot(slot_idx, kv, prompt_len)
+        with _span("gen.seed_slot") as seed:
+            if getattr(self.predictor, "paged", False):
+                try:
+                    pages = self.predictor.alloc_slot_pages(
+                        slot_idx, self.predictor.pages_needed(
+                            prompt_len, stream.max_new_tokens))
+                except BaseException as e:
+                    stream.fail(e)
+                    return False
+                seed.set(pages=len(pages))
+                try:
+                    written = self.predictor.write_slot(slot_idx, kv,
+                                                        prompt_len)
+                except BaseException:
+                    self.predictor.free_slot_pages(slot_idx)
+                    raise
+            else:
+                written = self.predictor.write_slot(slot_idx, kv,
+                                                    prompt_len)
+            seed.set(eager_ops=written)
         with self._cv:
             self._slots[slot_idx] = _Slot(stream, prompt_len, first)
         return True
@@ -652,6 +676,12 @@ class GenScheduler:
             live = sorted(self._slots.items())
         if not live:
             return
+        # what is left of this span beside its two children is the feed
+        # building below
+        with _span("gen.decode_iteration", live=len(live)):
+            self._step_and_emit(live, _profiler.runtime_metrics)
+
+    def _step_and_emit(self, live, metrics):
         S, L = self.predictor.num_slots, self.predictor.max_len
         tokens = np.zeros(S, np.int32)
         positions = np.zeros(S, np.int32)
@@ -669,7 +699,7 @@ class GenScheduler:
             else:
                 pos_onehot[idx, slot.pos] = 1.0
                 attn_mask[idx, :slot.pos + 1] = 1.0
-        _profiler.runtime_metrics.bucket("gen.slot_occupancy", len(live))
+        metrics.bucket("gen.slot_occupancy", len(live))
         t0 = time.perf_counter()
         if paged:
             logits = self.predictor.decode_step(tokens, positions,
@@ -678,23 +708,23 @@ class GenScheduler:
             logits = self.predictor.decode_step(tokens, positions,
                                                 pos_onehot, attn_mask)
         now = time.perf_counter()
-        _profiler.runtime_metrics.observe("gen.decode_step_seconds",
-                                          now - t0)
-        for idx, slot in live:
-            stream = slot.stream
-            token = int(np.argmax(logits[idx]))
-            slot.steps += 1
-            slot.pos += 1
-            slot.last_token = token
-            _profiler.runtime_metrics.inc("gen.tokens")
-            _profiler.runtime_metrics.observe("gen.intertoken_seconds",
-                                              now - slot.last_emit_t)
-            slot.last_emit_t = now
-            stream.emit(token)
-            done = 1 + slot.steps
-            if token == stream.eos_id:
-                self._finish(stream, "eos")
-                self._evict(idx)
-            elif done >= stream.max_new_tokens or slot.pos >= L:
-                self._finish(stream, "length")
-                self._evict(idx)
+        metrics.observe("gen.decode_step_seconds", now - t0)
+        with _span("gen.emit"):
+            for idx, slot in live:
+                stream = slot.stream
+                token = int(np.argmax(logits[idx]))
+                slot.steps += 1
+                slot.pos += 1
+                slot.last_token = token
+                metrics.inc("gen.tokens")
+                metrics.observe("gen.intertoken_seconds",
+                                now - slot.last_emit_t)
+                slot.last_emit_t = now
+                stream.emit(token)
+                done = 1 + slot.steps
+                if token == stream.eos_id:
+                    self._finish(stream, "eos")
+                    self._evict(idx)
+                elif done >= stream.max_new_tokens or slot.pos >= L:
+                    self._finish(stream, "length")
+                    self._evict(idx)

@@ -45,7 +45,7 @@ __all__ = ["span", "record_span", "enable", "disable", "enabled",
            "configure_from_env", "trace_context", "current_trace_id",
            "new_trace_id", "snapshot_spans", "snapshot_payload", "clear",
            "chrome_trace", "dump_chrome_trace", "set_process_name",
-           "process_name", "epoch_unix", "DEFAULT_RING"]
+           "process_name", "epoch_unix", "ts_of", "DEFAULT_RING"]
 
 DEFAULT_RING = 4096
 
@@ -81,6 +81,15 @@ def epoch_unix():
     anchor cross-process assembly normalizes clock skew against."""
     return time.time() - (time.perf_counter() - _EPOCH)
 
+
+def ts_of(perf_counter_s):
+    """The span-clock ``ts`` of a ``time.perf_counter()`` reading: what a
+    span that started at that instant carries.  A reader that holds its
+    own ``perf_counter`` times (a client's due time, a clock mark of the
+    device profiler) puts them beside spans with this."""
+    return perf_counter_s - _EPOCH
+
+
 _current_span = contextvars.ContextVar("paddle_tpu_span", default=None)
 _ambient_trace = contextvars.ContextVar("paddle_tpu_trace_id",
                                         default=None)
@@ -98,20 +107,30 @@ def new_trace_id():
     return f"{os.getpid():x}-{next(_trace_seq):x}-{os.urandom(4).hex()}"
 
 
+def _trace_id_under(parent):
+    """The trace id a span opened now, under ``parent``, joins: the id
+    :func:`trace_context` bound INSIDE ``parent`` (or with no span
+    open), else ``parent``'s own, else None."""
+    bound = _ambient_trace.get()
+    if bound is not None and (parent is None or bound[1] is parent):
+        return bound[0]
+    return parent.trace_id if parent is not None else None
+
+
 def current_trace_id():
-    """Trace id of the innermost active span, else the ambient id set by
-    :func:`trace_context`, else None."""
-    sp = _current_span.get()
-    if sp is not None:
-        return sp.trace_id
-    return _ambient_trace.get()
+    """Trace id a span opened now would join: the id bound by the
+    innermost :func:`trace_context` if it was bound inside the innermost
+    active span (or no span is open), else that span's, else None."""
+    return _trace_id_under(_current_span.get())
 
 
 @contextlib.contextmanager
 def trace_context(trace_id):
     """Bind an ambient trace id (e.g. an ``X-Request-Id``): spans opened
-    inside — on this thread/context — join that trace."""
-    token = _ambient_trace.set(trace_id)
+    inside — on this thread/context — join that trace.  Bound inside an
+    open span (a scheduler turn admitting one request), it overrides the
+    span's own id for what opens under it; the parent link stays."""
+    token = _ambient_trace.set((trace_id, _current_span.get()))
     try:
         yield trace_id
     finally:
@@ -159,10 +178,8 @@ class _Span:
     def __enter__(self):
         parent = _current_span.get()
         if parent is not None:
-            self.trace_id = parent.trace_id
             self.parent_id = parent.span_id
-        else:
-            self.trace_id = _ambient_trace.get() or new_trace_id()
+        self.trace_id = _trace_id_under(parent) or new_trace_id()
         self._token = _current_span.set(self)
         self.tid = threading.get_ident()
         self.t0 = time.perf_counter()

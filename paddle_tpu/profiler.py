@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import glob
 import os
 import threading
 import time
@@ -25,8 +26,125 @@ __all__ = ["cuda_profiler", "reset_profiler", "profiler",
            "RuntimeMetrics", "runtime_metrics", "record_latency",
            "install_jax_compile_listeners"]
 
-_trace_dir = None
-_start_time = None
+# ---------------------------------------------------------------------------
+# The ONE control that starts and stops tracing in the process that holds
+# the chip: the device profiler (``jax.profiler``) and the span ring
+# (``obs.trace``) together, tied by a clock mark so that a span's ``ts``
+# maps onto the device trace's nanoseconds.
+# ---------------------------------------------------------------------------
+
+CLOCK_MARK = "profiler.clock_mark"   # ring span + host-plane annotation
+
+_session = None     # the running device trace: one per process
+_session_lock = threading.Lock()
+
+
+def _clock_mark(index):
+    """One ``TraceAnnotation`` in the trace's host plane with the
+    ``perf_counter`` reading taken inside it, and the same as a ring
+    span: the pair that ties the two clocks."""
+    from paddle_tpu.obs import trace as _trace
+    with jax.profiler.TraceAnnotation(f"{CLOCK_MARK}#{index}"):
+        t = time.perf_counter()
+    _trace.record_span(CLOCK_MARK, t, 0.0, index=index)
+    return t
+
+
+def start_profiler(state="All", profile_path="/tmp/paddle_tpu_profile",
+                   ring_size=None):
+    """Start tracing in this process: the span ring is turned on if it is
+    off (``stop_profiler`` turns it off again), ``jax.profiler`` starts
+    writing under ``profile_path`` (device ops and the host runtime; no
+    Python tracer) and a clock mark is written.  Raises ``RuntimeError``
+    when a trace started here is already running, and whatever
+    ``jax.profiler.start_trace`` raises.  ``state`` is the reference
+    API's ('CPU'/'GPU'/'All') and is not read."""
+    global _session
+    from paddle_tpu.obs import trace as _trace
+    with _session_lock:
+        if _session is not None:
+            raise RuntimeError(
+                f"a device trace is already running (into "
+                f"{_session['trace_dir']}): stop_profiler() first")
+        ring_was_on = _trace.enabled()
+        _trace.enable(ring_size)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0    # device ops and host runtime only
+        opts.host_tracer_level = 1
+        try:
+            jax.profiler.start_trace(str(profile_path),
+                                     profiler_options=opts)
+        except BaseException:
+            if not ring_was_on:
+                _trace.disable()
+            raise
+        _session = {"trace_dir": str(profile_path),
+                    "ring_was_on": ring_was_on,
+                    "marks": [_clock_mark(0)]}
+
+
+def stop_profiler(sorted_key=None, profile_path=None):
+    """Stop the trace :func:`start_profiler` started and return what a
+    reader needs to put spans beside it::
+
+        {"trace_dir", "xplane",        # the ``.xplane.pb`` written
+         "span_to_trace_ns",           # trace ns = ts * 1e9 + this
+         "drift_ns",                   # second mark's offset - first's
+         "mark_width_ns",              # the first mark's own length
+         "t_start", "t_stop"}          # span-clock ts of the two marks
+
+    ``span_to_trace_ns`` is None when the marks are not in the trace.
+    Returns None when no trace was running.  The arguments are the
+    reference API's and are not read."""
+    global _session
+    from paddle_tpu.obs import trace as _trace
+    with _session_lock:
+        session, _session = _session, None
+        if session is None:
+            return None
+        try:
+            session["marks"].append(_clock_mark(1))
+        finally:
+            try:
+                jax.profiler.stop_trace()
+            finally:
+                if not session["ring_was_on"]:
+                    _trace.disable()
+    t0, t1 = (_trace.ts_of(t) for t in session["marks"])
+    out = {"trace_dir": session["trace_dir"], "xplane": None,
+           "span_to_trace_ns": None, "drift_ns": None,
+           "mark_width_ns": None, "t_start": t0, "t_stop": t1}
+    files = sorted(glob.glob(os.path.join(session["trace_dir"], "**",
+                                          "*.xplane.pb"), recursive=True),
+                   key=os.path.getmtime)
+    if files:
+        out["xplane"] = files[-1]
+        out.update(_clock_offsets(files[-1], t0, t1))
+    return out
+
+
+def _clock_offsets(xplane, t0, t1):
+    """Find the two clock marks in the trace's host plane; a mark's
+    ``perf_counter`` reading was taken inside its annotation, so it is
+    put at the annotation's middle (half its width is the error)."""
+    from jax.profiler import ProfileData
+    found = {}
+    for plane in ProfileData.from_file(xplane).planes:
+        if plane.name.startswith("/device:"):
+            continue        # the marks are host events
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(CLOCK_MARK + "#"):
+                    found[ev.name] = (float(ev.start_ns),
+                                      float(ev.duration_ns))
+    first, second = (found.get(f"{CLOCK_MARK}#{i}") for i in (0, 1))
+    if first is None:
+        return {}
+    offset = first[0] + first[1] / 2 - t0 * 1e9
+    out = {"span_to_trace_ns": offset, "mark_width_ns": first[1]}
+    if second is not None:
+        out["drift_ns"] = second[0] + second[1] / 2 - t1 * 1e9 - offset
+    return out
 
 
 @contextlib.contextmanager
@@ -41,25 +159,6 @@ def reset_profiler():
     reset_profiler)."""
     global _op_events
     _op_events = {}
-
-
-def start_profiler(state="All", profile_path="/tmp/paddle_tpu_profile"):
-    global _trace_dir, _start_time
-    _trace_dir = profile_path
-    _start_time = time.time()
-    try:
-        jax.profiler.start_trace(profile_path)
-    except Exception:  # already tracing
-        pass
-
-
-def stop_profiler(sorted_key=None, profile_path=None):
-    global _trace_dir
-    try:
-        jax.profiler.stop_trace()
-    except Exception:
-        pass
-    _trace_dir = None
 
 
 @contextlib.contextmanager
@@ -231,11 +330,11 @@ def measure_device_seconds(fn, scope=None):
     os.environ.setdefault("PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION",
                           "python")
     td = tempfile.mkdtemp(prefix="pttrace_")
-    jax.profiler.start_trace(td)
+    start_profiler(profile_path=td)
     try:
         fn()
     finally:
-        jax.profiler.stop_trace()
+        stop_profiler()
     try:
         if scope is not None:
             return scope_device_seconds(td, scope)
@@ -405,6 +504,14 @@ class RuntimeMetrics:
         with self._lock:
             return self._gauges.get(name)
 
+    def samples(self, name, last=None):
+        """The newest ``last`` samples kept of series ``name`` (all that
+        are kept when None), oldest first: with the difference of two
+        ``count`` readings for ``last``, the samples between them."""
+        with self._lock:
+            xs = list(self._series.get(name) or ())
+        return xs if last is None else xs[max(0, len(xs) - int(last)):]
+
     def percentiles(self, name, qs=(50, 95, 99)):
         """Window percentiles of ``name``; an unknown or empty series
         yields None per quantile (never raises — dashboards poll series
@@ -533,11 +640,11 @@ def compiled_profiler(trace_dir=None, sorted_key="total"):
     import tempfile
     own = trace_dir is None
     d = trace_dir or tempfile.mkdtemp(prefix="ptprof_")
-    jax.profiler.start_trace(d)
+    start_profiler(profile_path=d)
     try:
         yield d
     finally:
-        jax.profiler.stop_trace()
+        stop_profiler()
         try:
             table, _ = compiled_op_table(d, sorted_key)
             print(table)

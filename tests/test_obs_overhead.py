@@ -91,6 +91,54 @@ class TestDisabledTracingOverhead:
         assert m.snapshot()["series"][
             "obs_overhead.step_seconds"]["count"] == 5 * 2000
 
+    def test_scheduler_turn_overhead_under_5_percent(self):
+        """The spans one ``GenScheduler`` loop turn carries with tracing
+        off — the turn, one decode iteration with its step (an
+        ``Executor.run`` with its three phases) and its emit loop, and
+        one admission (queue wait, admit, prefill with its run, first
+        token, seed) — against the same modeled 1 ms step; a real decode
+        step on the chip is ten times that."""
+        trace.disable()
+
+        def run():
+            with trace.span("executor.run"):
+                for phase in ("executor.feed", "executor.dispatch",
+                              "executor.fetch"):
+                    with trace.span(phase):
+                        pass
+
+        def turn(i):
+            with trace.span("gen.sched.turn"):
+                trace.record_span("gen.queue_wait", 0.0, 0.0,
+                                  trace_id="r", queued_behind=0)
+                with trace.trace_context("r"):
+                    with trace.span("gen.admit", slot=0):
+                        with trace.span("gen.prefill", tokens=8):
+                            run()
+                        with trace.span("gen.first_token"):
+                            pass
+                        with trace.span("gen.seed_slot") as seed:
+                            seed.set(pages=1, eager_ops=8)
+                with trace.span("gen.decode_iteration", live=i):
+                    with trace.span("gen.decode_step"):
+                        run()
+                    with trace.span("gen.emit"):
+                        pass
+
+        def per_turn(iters=2000):
+            t0 = time.perf_counter()
+            for i in range(iters):
+                turn(i)
+            return (time.perf_counter() - t0) / iters
+
+        shell = min(per_turn() for _ in range(5))
+        budget = STEP_SECONDS * MAX_OVERHEAD_FRACTION
+        assert shell <= budget, (
+            f"disabled scheduler-turn spans cost {shell * 1e6:.1f}us a "
+            f"turn — over {MAX_OVERHEAD_FRACTION:.0%} of a "
+            f"{STEP_SECONDS * 1e3:.0f}ms step ({budget * 1e6:.0f}us)")
+        assert trace.snapshot_spans() == []
+
     def test_armed_slo_watchdog_stays_under_5_percent(self):
         """Satellite: the SLO evaluator's hot-loop hook with a REAL
         armed watchdog (interval not yet due — the steady state between

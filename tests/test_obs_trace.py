@@ -214,6 +214,15 @@ class TestMetricsRegressions:
         # snapshot of an empty registry is fine too
         assert m.snapshot()["series"] == {}
 
+    @pytest.mark.parametrize("last,want", [
+        (None, [1.0, 2.0, 3.0]), (2, [2.0, 3.0]), (0, []), (9, [1.0, 2.0, 3.0])])
+    def test_samples_are_the_newest_kept_oldest_first(self, last, want):
+        m = RuntimeMetrics()
+        for v in (1.0, 2.0, 3.0):
+            m.observe("x", v)
+        assert m.samples("x", last=last) == want
+        assert m.samples("never.observed", last=last) == []
+
     def test_record_latency_exception_path_observed_and_tagged(self):
         m = RuntimeMetrics()
         with pytest.raises(RuntimeError, match="kapow"):
