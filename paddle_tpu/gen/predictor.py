@@ -13,11 +13,16 @@ the vLLM/Orca phase boundary:
   update path) and the step's feed signature is constant, so admission
   and eviction never change the jit key.
 * :meth:`write_slot` / :meth:`clear_slot` are the (per-request, not
-  per-token) host-side slot writes that seed and reclaim cache rows.
+  per-token) slot writes that seed and reclaim cache rows: ONE compiled
+  call over every cache array with the pools donated
+  (:func:`_seed_pool`), fed the prefill's K/V as the device arrays the
+  prefill executable produced — the K/V never visit the host and no
+  pool is copied.
 
 ``warmup`` declares BOTH signature families — every prefill bucket and
-the decode signature family — through ``Executor.warmup``, so a server
-flips ``/readyz`` with the whole generation path compiled.
+the decode signature family — through ``Executor.warmup``, plus one
+seeding signature per prefill bucket, so a server flips ``/readyz``
+with the whole generation path compiled.
 
 PAGED bundles (meta carries ``page_len``; the default export) keep the
 KV pool as ``[num_pages, page_len, H*D]`` pages addressed through a
@@ -31,10 +36,14 @@ prefix pages, not ``max_len``.
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
+import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.obs.trace import span as _span
@@ -42,6 +51,39 @@ from paddle_tpu.obs.trace import span as _span
 __all__ = ["GenPredictor", "is_gen_bundle"]
 
 META_FILENAME = "gen_meta.json"
+
+
+@functools.partial(jax.jit, donate_argnums=0, static_argnames="max_rows")
+def _seed_pool(pools, kv, idx, n, *, max_rows):
+    """The one way the KV pool is written outside the decode step.
+
+    ``pools``: every cache array, ``[N, unit, H*D]`` (paged: pages of
+    ``page_len`` rows; dense: slots of ``max_len`` rows), DONATED — the
+    update is in place.  ``kv``: one ``[1, bucket, H*D]`` array per
+    pool (zeros on pad rows).  Entry ``idx[j]`` of every pool, for
+    ``j < n``, is written whole: rows ``j*unit ...`` of the first
+    ``max_rows`` K/V rows, zeros past them — a re-used page carries no
+    stale row.  ``idx`` has a fixed length and ``n`` is a traced trip
+    count, so the signature depends on the prompt BUCKET alone, never
+    on how many pages a request holds; ``n`` = 0 writes nothing."""
+    unit = pools[0].shape[1]
+    rows = min(kv[0].shape[1], max_rows)
+    src_units = -(-rows // unit)
+    src = [jnp.pad(a[0, :rows].astype(p.dtype),
+                   ((0, src_units * unit - rows), (0, 0)))
+           for p, a in zip(pools, kv)]
+
+    def write_entry(j, pools):
+        out = []
+        for pool, rows_of in zip(pools, src):
+            # past the K/V's rows the slice is clamped, then zeroed
+            entry = jax.lax.dynamic_slice_in_dim(rows_of, j * unit, unit)
+            entry = jnp.where(j < src_units, entry, 0)
+            out.append(jax.lax.dynamic_update_slice_in_dim(
+                pool, entry[None], idx[j], 0))
+        return tuple(out)
+
+    return jax.lax.fori_loop(0, n, write_entry, tuple(pools))
 
 
 def is_gen_bundle(model_dir):
@@ -139,6 +181,8 @@ class GenPredictor:
         # per-bucket static prefill FLOPs (analysis/cost): priced
         # lazily, consumed by GenScheduler's admission budget
         self._prefill_cost = {}
+        # clear_slot's zero rows, made once (device arrays)
+        self._clear_kv = None
         self._length_cost_fn = None
         self._page_cost_fn = None
 
@@ -331,10 +375,13 @@ class GenPredictor:
 
     def prefill(self, prompt):
         """Run one prompt (list/array of token ids); returns
-        ``(logits [V], kv)`` where ``kv`` is the per-layer masked K/V
-        list ``[k_0, v_0, ...]`` each ``[1, bucket, H*D]`` (zeros on pad
-        rows).  The prompt is padded to a declared bucket, so repeated
-        lengths share one executable."""
+        ``(logits [V], kv)``: ``logits`` on the host, ``kv`` the
+        per-layer masked K/V list ``[k_0, v_0, ...]`` each
+        ``[1, bucket, H*D]`` (zeros on pad rows) as the DEVICE arrays
+        the executable produced — :meth:`write_slot` takes them as they
+        are; only the last token's logits cross to the host.  The
+        prompt is padded to a declared bucket, so repeated lengths
+        share one executable."""
         prompt = list(prompt)
         if not prompt:
             raise ValueError("empty prompt")
@@ -347,70 +394,85 @@ class GenPredictor:
             with self._fluid.scope_guard(self._scope):
                 with _span("gen.prefill", tokens=len(prompt)):
                     outs = self._exe.run(self._pre_prog, feed=feed,
-                                         fetch_list=self._pre_fetch)
-        outs = [np.asarray(o) for o in outs]
-        return outs[0][0], outs[1:]
+                                         fetch_list=self._pre_fetch,
+                                         return_numpy=False)
+                    logits = np.asarray(outs[0])[0]
+        return logits, outs[1:]
 
-    # -- cache-slot lifecycle (per request, host-side) ---------------------
+    # -- cache-slot lifecycle (per request) --------------------------------
+    def _write_pool(self, kv, idx, n):
+        """Entries ``idx[:n]`` of every cache array <- ``kv``'s rows,
+        then zeros (:func:`_seed_pool`); caller holds ``_lock``.
+        Returns the device-resident pool arrays the call had to COPY
+        (their old buffer outlived the donation): 0 unless in-place
+        seeding broke."""
+        old = tuple(self._scope.find_var(name) for name in self.cache_vars)
+        new = _seed_pool(old, tuple(kv), idx, np.int32(n),
+                         max_rows=self.max_len)
+        for name, arr in zip(self.cache_vars, new):
+            self._scope.set_var(name, arr)
+        return sum(1 for a in old
+                   if isinstance(a, jax.Array) and not a.is_deleted())
+
+    def _seed_slot(self, slot, kv):
+        """``slot``'s entries of the pool <- ``kv`` (caller holds
+        ``_lock``): its allocated pages (paged; None before
+        ``alloc_slot_pages``) or its row (dense).  Returns what
+        :meth:`_write_pool` does; ``gen.seed.eager_ops`` counts it."""
+        from paddle_tpu.profiler import runtime_metrics
+        if self.paged:
+            pages = self._slot_pages.get(slot)
+            if not pages:
+                return None
+            idx = np.zeros(self.pages_per_slot, np.int32)
+            idx[:len(pages)] = pages
+        else:
+            idx, pages = np.asarray([slot], np.int32), (slot,)
+        copied = self._write_pool(kv, idx, len(pages))
+        runtime_metrics.inc("gen.seed.compiled_calls")
+        runtime_metrics.inc("gen.seed.eager_ops", copied)
+        return copied
+
+    def _zero_kv(self, bucket):
+        """All-zero K/V shaped like one ``bucket``'s prefill outputs
+        (what ``clear_slot`` and ``warmup`` seed with), committed to the
+        executor's device as those are — a differently placed argument
+        would be a second jit signature; transferred, not computed, so
+        making them compiles nothing."""
+        var = self._pre_fetch[1]
+        zeros = jax.device_put(
+            np.zeros((1, bucket, int(var.shape[-1])), str(var.dtype)),
+            self._exe.place.jax_device())
+        return [zeros] * len(self.cache_vars)
+
     def write_slot(self, slot, kv, prompt_len):
         """Seed cache slot ``slot`` with a prefill's K/V rows (the rest
-        of the row is zeroed — decode's add-writes land on zeros).
+        of the slot is zeroed — decode's add-writes land on zeros).
 
-        A device-side slice update (``at[slot].set``): only the one
-        seeded row crosses host->device, and the pool itself never
-        round-trips — per-admission cost stays O(max_len), not
-        O(num_slots * max_len).  Paged bundles write the slot's
-        ALLOCATED pages instead (prompt rows + zero fill — re-used
-        pages carry no stale rows), so the per-admission transfer is
-        O(pages_needed * page_len).  Returns the number of arrays
-        written, each by one eager device operation
-        (``gen.seed.eager_ops`` counts them)."""
-        import jax.numpy as jnp
-        from paddle_tpu.profiler import runtime_metrics
+        One compiled call for all ``2 * n_layer`` cache arrays, pools
+        donated: the K/V stay on the device and the pools are updated
+        in place.  Paged bundles write the slot's ALLOCATED pages whole
+        (prompt rows + zero fill — re-used pages carry no stale rows);
+        the dense layout writes the slot's row.  Returns the number of
+        pool arrays that were copied instead (0 when healthy)."""
         with self._lock:
-            if self.paged:
-                pages = self._slot_pages.get(slot)
-                if pages is None:
-                    raise RuntimeError(
-                        f"write_slot({slot}) before alloc_slot_pages")
-                idx = np.asarray(pages, np.int64)
-                cap = len(pages) * self.page_len
-                for name, arr in zip(self.cache_vars, kv):
-                    rows = min(arr.shape[1], self.max_len, cap)
-                    buf = np.zeros(
-                        (len(pages), self.page_len, arr.shape[2]),
-                        arr.dtype)
-                    buf.reshape(-1, arr.shape[2])[:rows] = arr[0, :rows]
-                    cache = jnp.asarray(self._scope.find_var(name))
-                    self._scope.set_var(name, cache.at[idx].set(buf))
-            else:
-                for name, arr in zip(self.cache_vars, kv):
-                    rows = min(arr.shape[1], self.max_len)
-                    row = np.zeros((self.max_len, arr.shape[2]), arr.dtype)
-                    row[:rows] = arr[0, :rows]
-                    cache = jnp.asarray(self._scope.find_var(name))
-                    self._scope.set_var(name, cache.at[slot].set(row))
-        runtime_metrics.inc("gen.seed.eager_ops", len(self.cache_vars))
-        return len(self.cache_vars)
+            copied = self._seed_slot(slot, kv)
+        if copied is None:
+            raise RuntimeError(
+                f"write_slot({slot}) before alloc_slot_pages")
+        return copied
 
     def clear_slot(self, slot):
-        """Zero a reclaimed slot's cache rows (device-side slice
-        update).  Not strictly required — admission overwrites the
-        whole row (or, paged, seeds every re-allocated page) — but
-        keeps a freed slot from pinning stale request data."""
-        import jax.numpy as jnp
-        from paddle_tpu.profiler import runtime_metrics
+        """Zero a reclaimed slot's cache rows — the same compiled call
+        as :meth:`write_slot`, fed zero rows of the smallest prompt
+        bucket.  Not strictly required — admission overwrites the whole
+        row (or, paged, seeds every re-allocated page) — but keeps a
+        freed slot from pinning stale request data."""
         with self._lock:
-            where = slot
-            if self.paged:
-                pages = self._slot_pages.get(slot)
-                if not pages:
-                    return
-                where = np.asarray(pages, np.int64)
-            for name in self.cache_vars:
-                cache = jnp.asarray(self._scope.find_var(name))
-                self._scope.set_var(name, cache.at[where].set(0.0))
-        runtime_metrics.inc("gen.seed.eager_ops", len(self.cache_vars))
+            if self._clear_kv is None:
+                self._clear_kv = self._zero_kv(
+                    min(self.prompt_buckets[0], self.max_len))
+            self._seed_slot(slot, self._clear_kv)
 
     # -- decode ------------------------------------------------------------
     def decode_step(self, tokens, positions, pos_onehot=None,
@@ -481,23 +543,21 @@ class GenPredictor:
 
     # -- warmup ------------------------------------------------------------
     def warmup(self):
-        """AOT-compile BOTH signature families — one prefill signature
-        per declared prompt bucket plus the decode signature family
-        (ONE signature for dense bundles; one per declared page bucket
-        for paged bundles) — so the first real ``/generate`` pays zero
-        compile time.  Returns a
+        """AOT-compile EVERY signature an admission or a decode step
+        uses — one prefill signature per declared prompt bucket, the
+        decode signature family (ONE signature for dense bundles; one
+        per declared page bucket for paged bundles) and one seeding
+        signature per prompt bucket (:func:`_seed_pool`) — so the first
+        real ``/generate`` pays zero compile time.  Returns a
         :class:`~paddle_tpu.obs.perf.WarmupReport` (int = fresh
         compiles; ``buckets`` carries one per-signature entry tagged
-        ``program: prefill|decode`` with compile seconds and
+        ``program: prefill|decode|seed`` with compile seconds and
         cold/persistent-hit/warm provenance — what ``/stats`` surfaces
         so a rolling restart's warm claim is checkable per bucket)."""
-        sigs = []
-        for b in self.prompt_buckets:
-            if b > self.max_len:
-                continue
-            sigs.append({"gen_ids": (1, b), "gen_pos": (1, b),
-                         "gen_mask": (1, b), "gen_attn_bias": (1, 1, b, b),
-                         "gen_last": (1, b)})
+        buckets = [b for b in self.prompt_buckets if b <= self.max_len]
+        sigs = [{"gen_ids": (1, b), "gen_pos": (1, b),
+                 "gen_mask": (1, b), "gen_attn_bias": (1, 1, b, b),
+                 "gen_last": (1, b)} for b in buckets]
         S, L = self.num_slots, self.max_len
         if self.paged:
             dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
@@ -523,4 +583,30 @@ class GenPredictor:
                     self._dec_prog, dec_sigs,
                     fetch_list=self._dec_fetch, scope=self._scope,
                     allow_state_updates=self.cache_vars)
-        return WarmupReport.merge(pre, dec, labels=("prefill", "decode"))
+            seed = self._warm_seeds(buckets)
+        return WarmupReport.merge(pre, dec, seed,
+                                  labels=("prefill", "decode", "seed"))
+
+    def _warm_seeds(self, buckets):
+        """Run the compiled seed once per prompt bucket with a trip
+        count of 0 (the pools pass through untouched); caller holds
+        ``_lock``."""
+        from paddle_tpu.obs.perf import WarmupReport
+        from paddle_tpu.profiler import runtime_metrics
+        idx = np.zeros(self.pages_per_slot if self.paged else 1, np.int32)
+        entries = []
+        for b in buckets:
+            size0 = _seed_pool._cache_size()
+            hits0 = runtime_metrics.counter("compile_cache.hits")
+            t0 = time.perf_counter()
+            kv = self._zero_kv(b)
+            self._write_pool(kv, idx, 0)
+            fresh = _seed_pool._cache_size() - size0
+            hit = runtime_metrics.counter("compile_cache.hits") - hits0
+            entries.append({
+                "signature": {"kv": [len(kv)] + list(kv[0].shape)},
+                "compiles": fresh,
+                "seconds": time.perf_counter() - t0,
+                "cache": ("warm" if fresh == 0 else
+                          "persistent-hit" if hit > 0 else "cold")})
+        return WarmupReport(sum(e["compiles"] for e in entries), entries)

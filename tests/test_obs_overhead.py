@@ -118,7 +118,8 @@ class TestDisabledTracingOverhead:
                         with trace.span("gen.first_token"):
                             pass
                         with trace.span("gen.seed_slot") as seed:
-                            seed.set(pages=1, eager_ops=8)
+                            seed.set(pages=1)
+                            seed.set(compiled_calls=1, eager_ops=0)
                 with trace.span("gen.decode_iteration", live=i):
                     with trace.span("gen.decode_step"):
                         run()

@@ -107,6 +107,42 @@ def test_paged_decode_compiles_for_v5e(one_chip, dtype):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_page_seed_compiles_for_v5e_in_place(one_chip, bucket):
+    """Admission's compiled seed (``gen/predictor.py:_seed_pool``) at the
+    serving cell's widths: 8 pools of 2048 pages x 16 rows x 4096 f32
+    (537 MB each), a prefill bucket's K/V, a 128-entry page list.  Every
+    pool input is aliased to an output (donated: updated in place), no
+    pool-shaped copy is in the program, and the compiler's temporaries
+    stay far under ONE pool."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.gen.predictor import _seed_pool
+    NP, PL, HD, PPS, N = 2048, 16, 4096, 128, 8
+    pool_bytes = NP * PL * HD * 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = tuple(sds((NP, PL, HD), jnp.float32) for _ in range(N))
+    kv = tuple(sds((1, bucket, HD), jnp.float32) for _ in range(N))
+    compiled = _seed_pool.lower(
+        pools, kv, sds((PPS,), jnp.int32), sds((), jnp.int32),
+        max_rows=PPS * PL).compile()
+    hlo = compiled.as_text()
+    header = hlo.split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == N, \
+        header
+    pool_shape = f"f32[{NP},{PL},{HD}]"
+    copies = [ln for ln in hlo.splitlines()
+              if " copy(" in ln and ln.split("=", 1)[1].split()[0]
+              .startswith(pool_shape)]
+    assert not copies, copies[:2]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < pool_bytes // 8, mem
+    assert mem.alias_size_in_bytes == N * pool_bytes, mem
+
+
 @pytest.mark.parametrize("bias", ["row", "causal"])
 def test_fused_softmax_compiles_for_v5e(one_chip, bias):
     import jax.numpy as jnp

@@ -164,6 +164,7 @@ class TestSchedulerSpans:
         waits0 = (m.snapshot()["series"].get("gen.queue_wait_seconds")
                   or {}).get("count") or 0
         eager0 = m.counter("gen.seed.eager_ops")
+        calls0 = m.counter("gen.seed.compiled_calls")
         sched = GenScheduler(gen_predictor, queue_size=8)
         try:
             streams = []
@@ -191,11 +192,14 @@ class TestSchedulerSpans:
                 parent = by_id[s["parent_id"]]
                 assert parent["name"] == "gen.admit"
                 assert s["trace_id"] == parent["trace_id"]
-        n_cache = len(gen_predictor.cache_vars)
+        # one compiled, pool-donating call per admission; no pool
+        # array copied (the pre-PR-24 eager path read n_cache here)
         for s in named("gen.seed_slot"):
-            assert s["attrs"]["eager_ops"] == n_cache
+            assert s["attrs"]["eager_ops"] == 0
+            assert s["attrs"]["compiled_calls"] == 1
             assert s["attrs"]["pages"] >= 1
-        assert m.counter("gen.seed.eager_ops") - eager0 == 3 * n_cache
+        assert m.counter("gen.seed.eager_ops") - eager0 == 0
+        assert m.counter("gen.seed.compiled_calls") - calls0 == 3
         assert m.snapshot()["series"]["gen.queue_wait_seconds"]["count"] \
             - waits0 == 3
         # ... and the series holds what the spans hold
