@@ -432,12 +432,14 @@ def _c_paged_attention(op, info):
     hd, pl, p = kc.shape[-1], kc.shape[1], pt.shape[1]
     if any(x < 0 for x in (hd, pl, p)):
         return None
+    # grouped query heads: Q is wider than the pool's rows of K/V heads
+    hq = q.shape[-1] if q.shape[-1] > 0 else hd
     s = q.shape[0] if q.shape[0] > 0 else 1
     t = p * pl
     item = _DTYPE_BYTES.get(str(kc.dtype), 4)
-    flops = 4 * s * t * hd                       # QK^T + PV per head-row
+    flops = 4 * s * t * hq                       # QK^T + PV per head-row
     bytes_ = (2 * s * t * hd          # K/V pages gathered
-              + 4 * s * hd            # q, k, v rows in + out
+              + 2 * s * (hq + hd)     # q, k, v rows in + out
               + 2 * s * hd) * item    # tail-page scatter write (k + v)
     return flops, bytes_
 
@@ -656,3 +658,94 @@ def _c_lstm(op, info):
 def _c_lstm_grad(op, info):
     fwd = _c_lstm(op, info)
     return None if fwd is None else (2 * fwd[0], io_bytes(op, info))
+
+
+# -- hybrid blocks: norms, state-space mixer, expert routing ---------------
+
+rule("relu2")(_per_element(2))
+rule("rms_norm", "gated_group_rms_norm")(_per_element(10))
+
+
+def _known(*dims):
+    return all(d is not None and d > 0 for d in dims)
+
+
+@rule("gqa_attention")
+def _c_gqa_attention(op, info):
+    q = _shape(info, op, "Q")
+    if q is None or len(q) != 3 or not _known(q[1], q[2]):
+        return None
+    return 4 * q[1] * q[1] * q[2], io_bytes(op, info)
+
+
+@rule("ssm_scan_conv", "ssm_update_conv")
+def _c_ssm_conv(op, info):
+    x, w = _shape(info, op, "X"), _shape(info, op, "W")
+    n = numel(x)
+    if n is None or w is None or not _known(w[0]):
+        return None
+    return 2 * n * w[0] + 10 * n, io_bytes(op, info)
+
+
+@rule("ssm_scan")
+def _c_ssm_scan(op, info):
+    """The chunked algorithm: per row, per head, C.B and the masked
+    product over the chunk's rows (2 q n / heads-per-group + 2 q p), the
+    chunk's own state and the read of the carried one (2 * 2 p n)."""
+    x = _shape(info, op, "X")
+    if x is None or len(x) != 3 or not _known(x[1]):
+        return None
+    h, p = int(op.attr("n_head")), int(op.attr("head_dim"))
+    g, n = int(op.attr("n_groups")), int(op.attr("state"))
+    q = min(int(op.attr("chunk", 128)), x[1])
+    rows = (x[0] if x[0] > 0 else 1) * x[1]
+    flops = rows * (2 * q * n * g + h * (2 * q * p + 4 * p * n))
+    return flops, io_bytes(op, info)
+
+
+@rule("ssm_update")
+def _c_ssm_update(op, info):
+    """One step: the whole state is read and written (io_bytes counts
+    State and StateOut), ~6 FLOPs an element of it."""
+    st = _shape(info, op, "State")
+    n = numel(st)
+    return None if n is None else (6 * n, io_bytes(op, info))
+
+
+@rule("moe_route")
+def _c_moe_route(op, info):
+    x, w = _shape(info, op, "X"), _shape(info, op, "W")
+    rows = numel(x[:-1]) if x is not None else None
+    if rows is None or w is None or not _known(*w):
+        return None
+    return 2 * rows * w[0] * w[1] + 10 * rows * w[1], io_bytes(op, info)
+
+
+@rule("moe_experts")
+def _c_moe_experts(op, info):
+    """Every row through every HELD expert (the lowering's dense
+    product): 4 * latent * hidden FLOPs a row an expert."""
+    x, w1 = _shape(info, op, "X"), _shape(info, op, "W1")
+    rows = numel(x[:-1]) if x is not None else None
+    if rows is None or w1 is None or len(w1) != 3 or not _known(*w1):
+        return None
+    return 4 * rows * w1[0] * w1[1] * w1[2], io_bytes(op, info)
+
+
+def _twice(fwd):
+    """A grad op built by the default grad maker carries the forward's
+    slots: about twice the forward's FLOPs."""
+    def fn(op, info):
+        cost = fwd(op, info)
+        return None if cost is None else (2 * cost[0], io_bytes(op, info))
+    return fn
+
+
+rule("split", "split_grad")(_per_element(1))
+rule("relu2_grad")(_per_element(2))
+rule("rms_norm_grad", "gated_group_rms_norm_grad")(_per_element(10))
+rule("gqa_attention_grad")(_twice(_c_gqa_attention))
+rule("ssm_scan_conv_grad")(_twice(_c_ssm_conv))
+rule("ssm_scan_grad")(_twice(_c_ssm_scan))
+rule("moe_route_grad")(_twice(_c_moe_route))
+rule("moe_experts_grad")(_twice(_c_moe_experts))

@@ -846,6 +846,7 @@ def check_gen_bundle(prefill, decode, meta):
     pre_feeds, pre_fetches = _names(pre_feeds), _names(pre_fetches)
     dec_feeds, dec_fetches = _names(dec_feeds), _names(dec_fetches)
     cache_vars = list(meta.get("cache_vars") or ())
+    state_vars = list(meta.get("state_vars") or ())
     num_slots = meta.get("num_slots")
     max_len = meta.get("max_len")
     page_len = meta.get("page_len")
@@ -992,15 +993,49 @@ def check_gen_bundle(prefill, decode, meta):
                 f"the bundle drifted between export and meta",
                 var=name, program="decode"))
 
+    # -- PTA019: per-slot state (not pages) must match the meta --------
+    for name in state_vars:
+        if not dec_block.has_var(name):
+            diags.append(Diagnostic(
+                "PTA019",
+                f"gen_meta names state var `{name}` but the decode "
+                f"program does not declare it", var=name,
+                program="decode"))
+            continue
+        if not getattr(dec_block.var(name), "persistable", False):
+            diags.append(Diagnostic(
+                "PTA019",
+                f"state var `{name}` is not persistable in the decode "
+                f"program — the slots' state would not live across "
+                f"steps", var=name, program="decode"))
+        shape, _ = _var_meta(dec_block, name)
+        if shape is not None and num_slots is not None and \
+                (len(shape) < 2 or shape[0] != int(num_slots)):
+            diags.append(Diagnostic(
+                "PTA019",
+                f"state var `{name}` is {shape} but must carry one row "
+                f"per slot (num_slots={num_slots}) — the bundle drifted "
+                f"between export and meta", var=name, program="decode"))
+    stats = list(meta.get("decode_stats") or ())
+    if stats and dec_fetches is not None and len(dec_fetches) != 2:
+        diags.append(Diagnostic(
+            "PTA019",
+            f"gen_meta declares decode_stats "
+            f"{[c.get('name') for c in stats]} but the decode program "
+            f"fetches {len(dec_fetches)} value(s), not logits + stats",
+            program="decode"))
+
     # -- PTA019: prefill fetch list must seed exactly the cache --------
-    if cache_vars and pre_fetches is not None:
-        want = 1 + len(cache_vars)  # logits + per-layer K/V
+    if (cache_vars or state_vars) and pre_fetches is not None:
+        # logits + per-layer K/V + one value per state array
+        want = 1 + len(cache_vars) + len(state_vars)
         if len(pre_fetches) != want:
             diags.append(Diagnostic(
                 "PTA019",
                 f"prefill fetches {len(pre_fetches)} value(s) but the "
                 f"decode cache needs {want} (logits + "
-                f"{len(cache_vars)} K/V tensors) — the prefill/decode "
+                f"{len(cache_vars)} K/V tensors + {len(state_vars)} "
+                f"state values) — the prefill/decode "
                 f"signatures drifted", program="prefill"))
         else:
             pre_block = pre_prog.global_block()
@@ -1017,6 +1052,19 @@ def check_gen_bundle(prefill, decode, meta):
                         f"dim {f_shape[-1]} but cache `{cache_name}` "
                         f"expects {c_shape[-1]} — seeding the slot "
                         f"would write misshapen rows",
+                        var=fetch_name, program="prefill"))
+            for fetch_name, state_name in zip(
+                    pre_fetches[1 + len(cache_vars):], state_vars):
+                f_shape, _ = _var_meta(pre_block, fetch_name)
+                s_shape, _ = _var_meta(dec_block, state_name)
+                if f_shape is not None and s_shape is not None and \
+                        tuple(f_shape[1:]) != tuple(s_shape[1:]):
+                    diags.append(Diagnostic(
+                        "PTA019",
+                        f"prefill state fetch `{fetch_name}` is "
+                        f"{f_shape} but a slot's row of `{state_name}` "
+                        f"is {tuple(s_shape[1:])} — seeding the slot "
+                        f"would write a misshapen state",
                         var=fetch_name, program="prefill"))
     return diags
 

@@ -281,7 +281,8 @@ def _r_matmul(op, tc):
                       op=op, var=op.input("X")[0])
         batch = xs[:-2] if len(xs) >= len(ys) else ys[:-2]
         out_shape = tuple(batch) + (xs[-2], ys[-1])
-    tc.set_output(op, "Out", shape=out_shape, dtype=x.dtype)
+    tc.set_output(op, "Out", shape=out_shape,
+                  dtype=op.attr("out_dtype", None) or x.dtype)
 
 
 @rule("elementwise_add", "elementwise_sub", "elementwise_mul",
@@ -865,15 +866,24 @@ def _r_paged_attention(op, tc):
                   f"KCache `{op.input('KCache')[0]}` {kc.shape} vs "
                   f"VCache `{op.input('VCache')[0]}` {vc.shape}",
                   op=op, var=op.input("KCache")[0])
-    if q.shape is not None and kc.shape is not None and \
-            q.shape[-1] > 0 and kc.shape[-1] > 0 and \
-            q.shape[-1] != kc.shape[-1]:
-        tc.report("PTA006",
-                  f"paged_attention Q `{op.input('Q')[0]}` feature dim "
-                  f"{q.shape[-1]} differs from the page pool's "
-                  f"{kc.shape[-1]} — the scatter would write misshapen "
-                  f"rows", op=op, var=op.input("Q")[0])
+    # the pool's rows hold the K/V heads: n_kv_head head widths (grouped
+    # query heads), as many as Q's n_head when the attr is absent
     n_head = op.attr("n_head", None)
+    n_kv = op.attr("n_kv_head", None) or n_head
+    row = kc.shape[-1] if kc.shape is not None else -1
+    for slot in ("Q", "K", "V"):
+        inf = tc.input_info(op, slot)
+        width = inf.shape[-1] if inf.shape is not None else -1
+        if slot == "Q" and n_head and width > 0 and \
+                width % int(n_head) == 0:
+            width = width // int(n_head) * int(n_kv)
+        if row > 0 and width > 0 and width != row:
+            tc.report("PTA006",
+                      f"paged_attention {slot} `{op.input(slot)[0]}` "
+                      f"feature dim {inf.shape[-1]} does not fit the page "
+                      f"pool's rows of {row} (n_head={n_head}, "
+                      f"n_kv_head={n_kv}) — the scatter would write "
+                      f"misshapen rows", op=op, var=op.input(slot)[0])
     if n_head and q.shape is not None and q.shape[-1] > 0 and \
             q.shape[-1] % int(n_head):
         tc.report("PTA006",
@@ -883,3 +893,182 @@ def _r_paged_attention(op, tc):
     tc.set_output(op, "Out", shape=q.shape, dtype=q.dtype)
     tc.set_output(op, "KCacheOut", shape=kc.shape, dtype=kc.dtype)
     tc.set_output(op, "VCacheOut", shape=vc.shape, dtype=vc.dtype)
+
+
+# -- hybrid blocks: norms, state-space mixer, expert routing ---------------
+
+def _same_as(op, tc, slot="X", out="Out"):
+    x = tc.input_info(op, slot)
+    tc.set_output(op, out, shape=x.shape, dtype=x.dtype)
+    return x
+
+
+def _last_dim_is(op, tc, slot, want, what):
+    inf = tc.input_info(op, slot)
+    if inf.shape is not None and want is not None and want > 0 and \
+            inf.shape[-1] > 0 and inf.shape[-1] != want:
+        tc.report("PTA006",
+                  f"{op.type} {slot} `{op.input(slot)[0]}` has "
+                  f"{inf.shape[-1]} {what}, expected {want}",
+                  op=op, var=op.input(slot)[0])
+
+
+def _int_index(op, tc, slot):
+    if not op.input(slot):
+        return
+    inf = tc.input_info(op, slot)
+    if inf.dtype is not None and inf.dtype not in ("int32", "int64"):
+        tc.report("PTA005",
+                  f"{op.type} {slot} `{op.input(slot)[0]}` must be an "
+                  f"integer tensor, got {inf.dtype}",
+                  op=op, var=op.input(slot)[0])
+
+
+@rule("relu2")
+def _r_relu2(op, tc):
+    _same_as(op, tc)
+
+
+@rule("rms_norm", "gated_group_rms_norm")
+def _r_rms_norm(op, tc):
+    x = _same_as(op, tc)
+    width = x.shape[-1] if x.shape is not None else None
+    _last_dim_is(op, tc, "Scale", width, "scales")
+    if op.type == "gated_group_rms_norm":
+        _last_dim_is(op, tc, "Gate", width, "gate features")
+        groups = int(op.attr("groups", 1))
+        if width and width > 0 and width % groups:
+            tc.report("PTA006",
+                      f"gated_group_rms_norm width {width} does not "
+                      f"split into {groups} groups", op=op,
+                      var=op.input("X")[0])
+
+
+def _ssm_widths(op):
+    h, p = int(op.attr("n_head")), int(op.attr("head_dim"))
+    g, n = int(op.attr("n_groups")), int(op.attr("state"))
+    return h, p, g, n
+
+
+@rule("ssm_scan_conv", "ssm_update_conv")
+def _r_ssm_conv(op, tc):
+    x = _same_as(op, tc)
+    w = tc.input_info(op, "W")
+    chans = x.shape[-1] if x.shape is not None else None
+    _last_dim_is(op, tc, "W", chans, "channels")
+    _last_dim_is(op, tc, "Bias", chans, "channels")
+    if op.type == "ssm_update_conv":
+        _int_index(op, tc, "Lens")
+        win = tc.input_info(op, "Window")
+        _last_dim_is(op, tc, "Window", chans, "channels")
+        tc.set_output(op, "WindowOut", shape=win.shape, dtype=win.dtype)
+    else:
+        taps = w.shape[0] - 1 if w.shape is not None and w.shape[0] > 0 \
+            else -1
+        tc.set_output(op, "Window", dtype="float32",
+                      shape=None if chans is None else (1, taps, chans))
+
+
+@rule("ssm_scan", "ssm_update")
+def _r_ssm(op, tc):
+    h, p, g, n = _ssm_widths(op)
+    x = tc.input_info(op, "X")
+    if h % g:
+        tc.report("PTA006", f"{op.type}: {h} heads do not split into {g} "
+                  f"groups", op=op, var=op.input("X")[0])
+    _last_dim_is(op, tc, "X", h * p + 2 * g * n, "features (x | B | C)")
+    _last_dim_is(op, tc, "Dt", h, "heads")
+    for slot in ("ALog", "D", "DtBias"):
+        _last_dim_is(op, tc, slot, h, "heads")
+    shape = None if x.shape is None else tuple(x.shape[:-1]) + (h * p,)
+    tc.set_output(op, "Out", shape=shape, dtype=x.dtype)
+    if op.type == "ssm_update":
+        _int_index(op, tc, "Lens")
+        st = tc.input_info(op, "State")
+        if st.shape is not None and len(st.shape) == 4 and \
+                all(d > 0 for d in st.shape[1:]) and \
+                tuple(st.shape[1:]) != (h, p, n):
+            tc.report("PTA006",
+                      f"ssm_update State `{op.input('State')[0]}` is "
+                      f"{st.shape}, expected [slots, {h}, {p}, {n}]",
+                      op=op, var=op.input("State")[0])
+        if st.dtype is not None and st.dtype != "float32":
+            tc.report("PTA005", f"ssm_update keeps its state in float32, "
+                      f"got {st.dtype}", op=op, var=op.input("State")[0])
+        tc.set_output(op, "StateOut", shape=st.shape, dtype=st.dtype)
+    else:
+        tc.set_output(op, "State", shape=(1, h, p, n), dtype="float32")
+
+
+@rule("moe_route")
+def _r_moe_route(op, tc):
+    x = tc.input_info(op, "X")
+    w = tc.input_info(op, "W")
+    if x.shape is not None and w.shape is not None and len(w.shape) == 2:
+        _last_dim_is(op, tc, "X", w.shape[0], "features")
+        _last_dim_is(op, tc, "Bias", w.shape[1], "experts")
+        k = int(op.attr("top_k"))
+        if 0 < w.shape[1] < k:
+            tc.report("PTA006", f"moe_route picks {k} of only "
+                      f"{w.shape[1]} experts", op=op, var=op.input("W")[0])
+    k = int(op.attr("top_k"))
+    shape = None if x.shape is None else tuple(x.shape[:-1]) + (k,)
+    tc.set_output(op, "TopkIdx", shape=shape, dtype="int32")
+    tc.set_output(op, "TopkWeight", shape=shape, dtype="float32")
+
+
+@rule("moe_experts")
+def _r_moe_experts(op, tc):
+    x = _same_as(op, tc)
+    w1, w2 = tc.input_info(op, "W1"), tc.input_info(op, "W2")
+    _int_index(op, tc, "TopkIdx")
+    _int_index(op, tc, "Lens")
+    if w1.shape is not None and w2.shape is not None:
+        if len(w1.shape) != 3 or len(w2.shape) != 3 or \
+                any(_dims_conflict(a, b) for a, b in zip(
+                    w1.shape, (w2.shape[0], w2.shape[2], w2.shape[1]))):
+            tc.report("PTA006",
+                      f"moe_experts W1 {w1.shape} / W2 {w2.shape} are not "
+                      f"[held, latent, hidden] / [held, hidden, latent]",
+                      op=op, var=op.input("W1")[0])
+        elif x.shape is not None:
+            _last_dim_is(op, tc, "X", w1.shape[1], "latent features")
+    tc.set_output(op, "Stats", shape=(1, 3), dtype="int32")
+
+
+@rule("gqa_attention")
+def _r_gqa_attention(op, tc):
+    q = _same_as(op, tc, "Q")
+    h, hkv = int(op.attr("n_head")), int(op.attr("n_kv_head"))
+    if h % hkv:
+        tc.report("PTA006", f"gqa_attention: {h} query heads do not "
+                  f"divide over {hkv} K/V heads", op=op,
+                  var=op.input("Q")[0])
+    elif q.shape is not None and q.shape[-1] > 0 and q.shape[-1] % h == 0:
+        for slot in ("K", "V"):
+            _last_dim_is(op, tc, slot, q.shape[-1] // h * hkv,
+                         "K/V features")
+
+
+@rule("split")
+def _r_split(op, tc):
+    x = tc.input_info(op, "X")
+    outs = op.output("Out")
+    axis = int(op.attr("axis", -1))
+    sections = list(op.attr("sections", None) or ())
+    for i, name in enumerate(outs):
+        shape = None
+        if x.shape is not None:
+            ax = axis % len(x.shape)
+            part = sections[i] if sections else (
+                x.shape[ax] // len(outs) if x.shape[ax] > 0 else -1)
+            shape = tuple(x.shape[:ax]) + (part,) + tuple(x.shape[ax + 1:])
+        tc.set(name, shape=shape, dtype=x.dtype)
+
+
+# the auto-vjp grads of the differentiable ops above follow the default
+# grad maker's slot convention
+rule("split_grad", "relu2_grad", "rms_norm_grad",
+     "gated_group_rms_norm_grad", "ssm_scan_conv_grad", "ssm_scan_grad",
+     "moe_route_grad", "moe_experts_grad",
+     "gqa_attention_grad")(_r_grad_mirror)

@@ -19,6 +19,15 @@ the vLLM/Orca phase boundary:
   prefill executable produced — the K/V never visit the host and no
   pool is copied.
 
+A bundle whose meta names ``state_vars`` (``models/hybrid_moe.py``)
+keeps, beside the pool, a second kind of per-slot cache: arrays
+``[num_slots, ...]`` that are not addressed through the page table (a
+state-space layer's recurrent state and conv window).  The prefill
+returns their values after the K/V, the same compiled call writes the
+slot's row of each with the slot's pages, and the decode step reads and
+writes them whole, in place.  What the meta holds decides; there is no
+flag.
+
 ``warmup`` declares BOTH signature families — every prefill bucket and
 the decode signature family — through ``Executor.warmup``, plus one
 seeding signature per prefill bucket, so a server flips ``/readyz``
@@ -53,19 +62,36 @@ __all__ = ["GenPredictor", "is_gen_bundle"]
 META_FILENAME = "gen_meta.json"
 
 
-@functools.partial(jax.jit, donate_argnums=0, static_argnames="max_rows")
-def _seed_pool(pools, kv, idx, n, *, max_rows):
-    """The one way the KV pool is written outside the decode step.
+@functools.partial(jax.jit, donate_argnums=(0, 4),
+                   static_argnames="max_rows")
+def _seed_pool(pools, kv, idx, n, states=(), new_states=(), slot=0, *,
+               max_rows):
+    """The one way the KV pool and the per-slot state are written
+    outside the decode step.
 
-    ``pools``: every cache array, ``[N, unit, H*D]`` (paged: pages of
-    ``page_len`` rows; dense: slots of ``max_len`` rows), DONATED — the
-    update is in place.  ``kv``: one ``[1, bucket, H*D]`` array per
-    pool (zeros on pad rows).  Entry ``idx[j]`` of every pool, for
+    ``pools``: every cache array, ``[N, unit, width]`` (paged: pages of
+    ``page_len`` rows; dense: slots of ``max_len`` rows; the row width is
+    each array's own), DONATED — the update is in place.  ``kv``: one
+    ``[1, bucket, width]`` array per pool (zeros on pad rows).
+    ``states``: every per-slot state array ``[num_slots, ...]``, DONATED;
+    ``new_states``: one ``[1, ...]`` value per state array, written whole
+    as row ``slot`` unless ``n`` is 0.  Entry ``idx[j]`` of every pool, for
     ``j < n``, is written whole: rows ``j*unit ...`` of the first
     ``max_rows`` K/V rows, zeros past them — a re-used page carries no
     stale row.  ``idx`` has a fixed length and ``n`` is a traced trip
     count, so the signature depends on the prompt BUCKET alone, never
-    on how many pages a request holds; ``n`` = 0 writes nothing."""
+    on how many pages a request holds; ``n`` = 0 writes nothing.
+    Returns the new pools followed by the new states, one flat tuple."""
+    def put_row(state, value):
+        # only the slot's row is touched; with ``n`` = 0 it is rewritten
+        # with itself
+        row = jnp.where(n > 0, value.astype(state.dtype),
+                        jax.lax.dynamic_slice_in_dim(state, slot, 1, 0))
+        return jax.lax.dynamic_update_slice_in_dim(state, row, slot, 0)
+
+    states = tuple(put_row(s, v) for s, v in zip(states, new_states))
+    if not pools:
+        return states
     unit = pools[0].shape[1]
     rows = min(kv[0].shape[1], max_rows)
     src_units = -(-rows // unit)
@@ -83,7 +109,7 @@ def _seed_pool(pools, kv, idx, n, *, max_rows):
                 pool, entry[None], idx[j], 0))
         return tuple(out)
 
-    return jax.lax.fori_loop(0, n, write_entry, tuple(pools))
+    return jax.lax.fori_loop(0, n, write_entry, tuple(pools)) + states
 
 
 def is_gen_bundle(model_dir):
@@ -107,6 +133,10 @@ class GenPredictor:
         self.vocab_size = int(self.meta["vocab_size"])
         self.eos_id = int(self.meta.get("eos_id", -1))
         self.cache_vars = list(self.meta["cache_vars"])
+        # per-slot state that is not pages, and what the decode step
+        # fetches beside the logits (both absent from a gen_lm bundle)
+        self.state_vars = list(self.meta.get("state_vars") or ())
+        self.decode_stats = list(self.meta.get("decode_stats") or ())
         self.prompt_buckets = [int(b) for b in self.meta["prompt_buckets"]]
         self.max_prompt_len = min(self.prompt_buckets[-1], self.max_len)
         self.paged = "page_len" in self.meta
@@ -149,9 +179,6 @@ class GenPredictor:
         # decode dispatches derive gen.decode_mfu (not train.mfu): the
         # executor keys the gauge off this program attribute
         self._dec_prog._mfu_gauge = "gen.decode_mfu"
-        if self.paged:
-            dec_block = self._dec_prog.global_block()
-            self._hd = int(dec_block.var(self.cache_vars[0]).shape[-1])
         # HBM census: the KV pool is its own collection — a paged
         # bundle's pool (plus its host page table) reports as
         # ``kv_pages``, the dense layout as ``kv_cache``; weakref'd so
@@ -171,11 +198,18 @@ class GenPredictor:
                 bufs.append(p._page_table)
             return bufs
 
-        self._hbm_token = _perf.register_hbm_provider(
-            "kv_pages" if self.paged else "kv_cache", _kv_buffers)
+        def _state_buffers():
+            p = ref()
+            return () if p is None else [
+                v for v in (p._scope.find_var(n) for n in p.state_vars)
+                if v is not None and hasattr(v, "nbytes")]
+
         # a reloaded predictor must not leave a dead provider behind
-        weakref.finalize(self, _perf.unregister_hbm_provider,
-                         self._hbm_token)
+        for collection, fn in (
+                ("kv_pages" if self.paged else "kv_cache", _kv_buffers),
+                ("gen_state", _state_buffers)):
+            weakref.finalize(self, _perf.unregister_hbm_provider,
+                             _perf.register_hbm_provider(collection, fn))
         # per-bucket constant prefill feeds (causal bias template)
         self._tri = {}
         # per-bucket static prefill FLOPs (analysis/cost): priced
@@ -212,12 +246,16 @@ class GenPredictor:
 
     def _page_write_cost(self, prompt_len):
         """Flop-equivalent of seeding a paged slot: every allocated
-        prompt page is written whole (k + v, per layer) — the page
-        dimension admission budgets must see on top of the prefill
+        prompt page of every cache array is written whole, at that
+        array's own row width, and every state array's row for the slot
+        — what admission budgets must see on top of the prefill
         forward."""
         pages = -(-max(int(prompt_len), 1) // self.page_len)
-        return (2.0 * float(self.meta.get("n_layer", 1)) *
-                pages * self.page_len * self._hd)
+        block = self._dec_prog.global_block()
+        rows = sum(int(block.var(n).shape[-1]) for n in self.cache_vars)
+        state = sum(int(np.prod(block.var(n).shape[1:]))
+                    for n in self.state_vars)
+        return float(pages * self.page_len * rows + state)
 
     def prefill_cost(self, prompt_len):
         """Static FLOPs of prefilling a prompt of ``prompt_len`` tokens
@@ -349,19 +387,33 @@ class GenPredictor:
     def _prefill_feed(self, prompt, bucket):
         from paddle_tpu.lod import pad_to_bucket
         p = len(prompt)
-        ids = pad_to_bucket(
-            np.asarray(prompt, np.int32).reshape(1, p), bucket, axis=1)
-        pos = np.arange(bucket, dtype=np.int32).reshape(1, bucket)
         mask = pad_to_bucket(np.ones((1, p), np.float32), bucket, axis=1)
-        tri = self._tri.get(bucket)
-        if tri is None:
-            tri = np.triu(np.full((bucket, bucket), -1e9, np.float32), 1)
-            self._tri[bucket] = tri
-        bias = tri[None, None] + (mask * 1e9 - 1e9)[:, None, None, :]
-        last = np.zeros((1, bucket), np.float32)
-        last[0, p - 1] = 1.0
-        return {"gen_ids": ids, "gen_pos": pos, "gen_mask": mask,
-                "gen_attn_bias": bias.astype(np.float32), "gen_last": last}
+
+        def ids():
+            return pad_to_bucket(
+                np.asarray(prompt, np.int32).reshape(1, p), bucket, axis=1)
+
+        def pos():
+            return np.arange(bucket, dtype=np.int32).reshape(1, bucket)
+
+        def bias():
+            tri = self._tri.get(bucket)
+            if tri is None:
+                tri = np.triu(np.full((bucket, bucket), -1e9, np.float32), 1)
+                self._tri[bucket] = tri
+            return (tri[None, None] + (mask * 1e9 - 1e9)[:, None, None, :]
+                    ).astype(np.float32)
+
+        def last():
+            one_hot = np.zeros((1, bucket), np.float32)
+            one_hot[0, p - 1] = 1.0
+            return one_hot
+
+        # only what the bundle's prefill program declares is built (the
+        # dense [b, b] bias is 4 MB at bucket 1024)
+        make = {"gen_ids": ids, "gen_pos": pos, "gen_mask": lambda: mask,
+                "gen_attn_bias": bias, "gen_last": last}
+        return {k: make[k]() for k in self._pre_feeds}
 
     def can_resume(self, total_len):
         """True when a resumed stream of ``total_len`` tokens (original
@@ -377,7 +429,9 @@ class GenPredictor:
         """Run one prompt (list/array of token ids); returns
         ``(logits [V], kv)``: ``logits`` on the host, ``kv`` the
         per-layer masked K/V list ``[k_0, v_0, ...]`` each
-        ``[1, bucket, H*D]`` (zeros on pad rows) as the DEVICE arrays
+        ``[1, bucket, width]`` (zeros on pad rows), followed by one
+        ``[1, ...]`` value per ``state_vars`` array (the state after the
+        prompt's last token), as the DEVICE arrays
         the executable produced — :meth:`write_slot` takes them as they
         are; only the last token's logits cross to the host.  The
         prompt is padded to a declared bucket, so repeated lengths
@@ -400,16 +454,20 @@ class GenPredictor:
         return logits, outs[1:]
 
     # -- cache-slot lifecycle (per request) --------------------------------
-    def _write_pool(self, kv, idx, n):
+    def _write_pool(self, kv, idx, n, slot=0):
         """Entries ``idx[:n]`` of every cache array <- ``kv``'s rows,
-        then zeros (:func:`_seed_pool`); caller holds ``_lock``.
-        Returns the device-resident pool arrays the call had to COPY
-        (their old buffer outlived the donation): 0 unless in-place
-        seeding broke."""
-        old = tuple(self._scope.find_var(name) for name in self.cache_vars)
-        new = _seed_pool(old, tuple(kv), idx, np.int32(n),
+        then zeros, and row ``slot`` of every state array <- the values
+        that follow the K/V in ``kv`` (:func:`_seed_pool`); caller holds
+        ``_lock``.  Returns the device-resident pool and state arrays
+        the call had to COPY (their old buffer outlived the donation):
+        0 unless in-place seeding broke."""
+        names = self.cache_vars + self.state_vars
+        old = tuple(self._scope.find_var(name) for name in names)
+        k = len(self.cache_vars)
+        new = _seed_pool(old[:k], tuple(kv[:k]), idx, np.int32(n), old[k:],
+                         tuple(kv[k:]), np.int32(slot),
                          max_rows=self.max_len)
-        for name, arr in zip(self.cache_vars, new):
+        for name, arr in zip(names, new):
             self._scope.set_var(name, arr)
         return sum(1 for a in old
                    if isinstance(a, jax.Array) and not a.is_deleted())
@@ -428,22 +486,33 @@ class GenPredictor:
             idx[:len(pages)] = pages
         else:
             idx, pages = np.asarray([slot], np.int32), (slot,)
-        copied = self._write_pool(kv, idx, len(pages))
+        copied = self._write_pool(kv, idx, len(pages), slot)
         runtime_metrics.inc("gen.seed.compiled_calls")
         runtime_metrics.inc("gen.seed.eager_ops", copied)
         return copied
 
     def _zero_kv(self, bucket):
-        """All-zero K/V shaped like one ``bucket``'s prefill outputs
+        """All-zero K/V and state values shaped like one ``bucket``'s
+        prefill outputs, each cache array's at its own row width and type
         (what ``clear_slot`` and ``warmup`` seed with), committed to the
         executor's device as those are — a differently placed argument
         would be a second jit signature; transferred, not computed, so
         making them compiles nothing."""
-        var = self._pre_fetch[1]
-        zeros = jax.device_put(
-            np.zeros((1, bucket, int(var.shape[-1])), str(var.dtype)),
-            self._exe.place.jax_device())
-        return [zeros] * len(self.cache_vars)
+        device = self._exe.place.jax_device()
+        k = len(self.cache_vars)
+        made = {}
+
+        def zeros(var, shape):
+            key = (tuple(shape), str(var.dtype))
+            if key not in made:
+                made[key] = jax.device_put(
+                    np.zeros(shape, jnp.dtype(str(var.dtype))), device)
+            return made[key]
+
+        return [zeros(v, (1, bucket, int(v.shape[-1])))
+                for v in self._pre_fetch[1:1 + k]] + \
+               [zeros(v, (1,) + tuple(int(d) for d in v.shape[1:]))
+                for v in self._pre_fetch[1 + k:]]
 
     def write_slot(self, slot, kv, prompt_len):
         """Seed cache slot ``slot`` with a prefill's K/V rows (the rest
@@ -489,6 +558,13 @@ class GenPredictor:
         ``max(lens)``, so the jit key is the bucket.  Returns logits
         ``[S, V]``.
 
+        A bundle with ``decode_stats`` fetches, with the logits, one
+        small int32 array ``[n, len(decode_stats)]`` a step; each column's
+        sum (or max, as the meta says) goes on the ``gen.decode_step``
+        span under the column's name, with ``live`` (live slots), and is
+        counted always-on: ``gen.<name with its first _ as a .>``, a
+        counter for a sum and a histogram for a max.
+
         The ``gen.decode.stall`` failpoint fires INSIDE the lock: a
         ``delay`` action models per-iteration device time serialized per
         replica (the decode bench's cost model), an ``error`` a device
@@ -499,21 +575,43 @@ class GenPredictor:
             "gen_token": np.asarray(tokens, np.int32).reshape(S, 1),
             "gen_pos": np.asarray(positions, np.int32).reshape(S, 1),
         }
+        live = None
         if self.paged:
             if lens is None:
                 raise ValueError("paged decode_step needs lens")
             feed.update(self._paged_decode_feed(
                 np.asarray(lens, np.int32).reshape(S, 1)))
+            live = int(np.count_nonzero(feed["gen_lens"]))
         else:
             feed["gen_pos_onehot"] = np.asarray(pos_onehot, np.float32)
             feed["gen_attn_mask"] = np.asarray(attn_mask, np.float32)
+        feed = {k: feed[k] for k in self._dec_feeds}
         with self._lock:
             chaos.fire("gen.decode.stall", slots=S)
             with self._fluid.scope_guard(self._scope):
-                with _span("gen.decode_step"):
-                    (logits,) = self._exe.run(self._dec_prog, feed=feed,
-                                              fetch_list=self._dec_fetch)
+                with _span("gen.decode_step") as step:
+                    logits, *stats = self._exe.run(
+                        self._dec_prog, feed=feed,
+                        fetch_list=self._dec_fetch)
+                    if stats and self.decode_stats:
+                        step.set(live=live,
+                                 **self._count_decode_stats(stats[0]))
         return np.asarray(logits)
+
+    def _count_decode_stats(self, stats):
+        """The step's ``decode_stats`` columns reduced over their rows:
+        counted always-on, returned for the span."""
+        from paddle_tpu.profiler import runtime_metrics
+        stats, out = np.asarray(stats), {}
+        for j, col in enumerate(self.decode_stats):
+            metric = "gen." + col["name"].replace("_", ".", 1)
+            if col.get("reduce") == "max":
+                out[col["name"]] = int(stats[:, j].max())
+                runtime_metrics.bucket(metric, out[col["name"]])
+            else:
+                out[col["name"]] = int(stats[:, j].sum())
+                runtime_metrics.inc(metric, out[col["name"]])
+        return out
 
     def _paged_decode_feed(self, lens):
         """Page-table + lens feed for one paged step: slice the table
@@ -555,9 +653,10 @@ class GenPredictor:
         cold/persistent-hit/warm provenance — what ``/stats`` surfaces
         so a rolling restart's warm claim is checkable per bucket)."""
         buckets = [b for b in self.prompt_buckets if b <= self.max_len]
-        sigs = [{"gen_ids": (1, b), "gen_pos": (1, b),
-                 "gen_mask": (1, b), "gen_attn_bias": (1, 1, b, b),
-                 "gen_last": (1, b)} for b in buckets]
+        sigs = [{k: v for k, v in {
+            "gen_ids": (1, b), "gen_pos": (1, b), "gen_mask": (1, b),
+            "gen_attn_bias": (1, 1, b, b), "gen_last": (1, b)}.items()
+            if k in self._pre_feeds} for b in buckets]
         S, L = self.num_slots, self.max_len
         if self.paged:
             dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
@@ -569,6 +668,7 @@ class GenPredictor:
             dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
                          "gen_pos_onehot": (S, L),
                          "gen_attn_mask": (S, L)}]
+        dec_sigs = [{k: sig[k] for k in self._dec_feeds} for sig in dec_sigs]
         from paddle_tpu.obs.perf import WarmupReport
         with self._lock:
             with self._fluid.scope_guard(self._scope):
@@ -582,7 +682,7 @@ class GenPredictor:
                 dec = self._exe.warmup(
                     self._dec_prog, dec_sigs,
                     fetch_list=self._dec_fetch, scope=self._scope,
-                    allow_state_updates=self.cache_vars)
+                    allow_state_updates=self.cache_vars + self.state_vars)
             seed = self._warm_seeds(buckets)
         return WarmupReport.merge(pre, dec, seed,
                                   labels=("prefill", "decode", "seed"))

@@ -620,7 +620,9 @@ class GenScheduler:
             else:
                 written = self.predictor.write_slot(slot_idx, kv,
                                                     prompt_len)
-            seed.set(compiled_calls=1, eager_ops=written)
+            seed.set(compiled_calls=1, eager_ops=written,
+                     state_arrays=len(getattr(self.predictor,
+                                              "state_vars", ())))
         with self._cv:
             self._slots[slot_idx] = _Slot(stream, prompt_len, first)
         return True

@@ -5,18 +5,19 @@ TPU-native layers API."""
 
 from paddle_tpu.models import (resnet, transformer, vgg, mnist,
                                seq2seq, stacked_lstm, gen_lm,
-                               gen_lm_long, wide_and_deep)
+                               gen_lm_long, wide_and_deep, hybrid_moe)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
            "seq2seq", "stacked_lstm", "gen_lm", "gen_lm_long",
-           "wide_and_deep", "ZOO_MODELS",
+           "wide_and_deep", "hybrid_moe", "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
 
 #: zoo model names accepted by :func:`build_train_program` (and by
 #: ``paddle_tpu lint --zoo``; the lint gate in
 #: tests/test_analysis_zoo.py iterates exactly this list)
 ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
-              "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep")
+              "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
+              "hybrid_moe")
 
 
 def build_train_program(name, backward=True):
@@ -80,6 +81,13 @@ def build_train_program(name, backward=True):
             hp.n_head = hp.n_layer = 2
             hp.d_head = 8
             cost, feeds = gen_lm_long.gen_lm_long_train_program(2, 16, hp)
+            fetches = [cost.name]
+        elif name == "hybrid_moe":
+            # one layer of each kind at toy widths, float32 (training
+            # keeps float32 parameters; the serving bundle's are bfloat16)
+            hp = hybrid_moe.HybridConfig()
+            hp.dtype = "float32"
+            cost, feeds = hybrid_moe.hybrid_moe_train_program(16, hp)
             fetches = [cost.name]
         else:
             raise ValueError(

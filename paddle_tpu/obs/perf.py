@@ -438,9 +438,11 @@ OPTIMIZER_STATE_PREFIXES = (
 
 #: census collections, in attribution priority order; provider-backed
 #: collections claim their buffers before the scope walk (``kv_pages``:
-#: a paged gen bundle's page pool + its host-side page tables)
-HBM_COLLECTIONS = ("kv_cache", "kv_pages", "prefetch", "embedding",
-                   "optimizer", "params")
+#: a paged gen bundle's page pool + its host-side page tables;
+#: ``gen_state``: a gen bundle's per-slot state that is not pages, e.g.
+#: the recurrent state and conv window of a state-space layer)
+HBM_COLLECTIONS = ("kv_cache", "kv_pages", "gen_state", "prefetch",
+                   "embedding", "optimizer", "params")
 
 _hbm_lock = threading.Lock()
 _hbm_providers = {}     # collection -> {token: callable}
@@ -553,6 +555,7 @@ def hbm_census(scope=None, metrics=None):
 
     claim("kv_cache", _provider_arrays("kv_cache"))
     claim("kv_pages", _provider_arrays("kv_pages"))
+    claim("gen_state", _provider_arrays("gen_state"))
     claim("prefetch", _provider_arrays("prefetch"))
     claim("embedding", _provider_arrays("embedding"))
 
@@ -598,6 +601,7 @@ def hbm_census(scope=None, metrics=None):
     m.set_gauge("hbm.optimizer_bytes", census["optimizer"])
     m.set_gauge("hbm.kv_cache_bytes", census["kv_cache"])
     m.set_gauge("hbm.kv_pages_bytes", census["kv_pages"])
+    m.set_gauge("hbm.gen_state_bytes", census["gen_state"])
     m.set_gauge("hbm.prefetch_bytes", census["prefetch"])
     m.set_gauge("hbm.embedding_bytes", census["embedding"])
     m.set_gauge("hbm.other_bytes", census["other"])

@@ -29,4 +29,6 @@ from paddle_tpu.ops import (  # noqa: F401
     ctc_ops,
     beam_search_ops,
     fused_ops,
+    ssm_ops,
+    moe_ops,
 )

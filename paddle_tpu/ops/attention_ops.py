@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.ops.registry import (
-    register_op, LowerContext, ShapeInferenceSkip)
+    register_op, LowerContext, ShapeInferenceSkip, infer_shape_unary)
 
 NEG_INF = -1e9
 
@@ -927,7 +927,11 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # contract.  The Pallas kernel (grid = (slot, page), page picked by a
 # scalar-prefetch table lookup, online softmax across pages, all VPU — no
 # dot_general) is taken on a TPU whenever ``_paged_kernel_ok`` admits the
-# shape (head width a multiple of 128 lanes, page_len of 8 rows).  It ran
+# shape (head width a multiple of 128 lanes, page_len of 8 rows).  Grouped
+# query heads: the pool's rows are ``Hkv*D`` wide (``n_kv_head`` K/V heads),
+# Q's ``H*D``, and query head ``h`` reads K/V head ``h // (H / Hkv)``; the
+# K/V heads are never copied out to ``H``.  ``Hkv == H`` is the same code
+# with a group of one.  It ran
 # compiled on a v5e in PR 21's chip_smoke.py (8 heads x 128, page_len 16,
 # f32; all 7 page buckets held ``tpu_custom_call``, ``gen.paged.fallback``
 # stayed 0, tokens matched the cache-free reference; its speed: not
@@ -953,8 +957,10 @@ def _paged_cache_update(kc, vc, k, v, page_table, lens):
     page = jnp.take_along_axis(page_table, (idx // PL)[:, None], axis=1)[:, 0]
     page = jnp.where(last >= 0, page, NP)
     row = idx % PL
-    kc = kc.at[page, row].set(k.reshape(k.shape[0], -1), mode="drop")
-    vc = vc.at[page, row].set(v.reshape(v.shape[0], -1), mode="drop")
+    kc = kc.at[page, row].set(k.reshape(k.shape[0], -1).astype(kc.dtype),
+                              mode="drop")
+    vc = vc.at[page, row].set(v.reshape(v.shape[0], -1).astype(vc.dtype),
+                              mode="drop")
     return kc, vc
 
 
@@ -964,12 +970,16 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
     dense pool's [S, max_len]) — still occupancy-proportional, just
     without the VMEM-resident online softmax."""
     S, P = page_table.shape
-    NP, PL, HD = kc.shape
+    NP, PL, HDkv = kc.shape
     H = n_head
-    D = HD // H
+    D = q.shape[-1] // H
+    Hkv = HDkv // D
     T = P * PL
-    kg = kc[page_table].reshape(S, T, H, D)
-    vg = vc[page_table].reshape(S, T, H, D)
+    kg = kc[page_table].reshape(S, T, Hkv, D)
+    vg = vc[page_table].reshape(S, T, Hkv, D)
+    if Hkv != H:
+        kg = jnp.repeat(kg, H // Hkv, axis=2)
+        vg = jnp.repeat(vg, H // Hkv, axis=2)
     qh = q.reshape(S, H, D).astype(jnp.float32)
     sc = jnp.einsum("shd,sthd->sht", qh, kg.astype(jnp.float32),
                     preferred_element_type=jnp.float32) * scale
@@ -986,9 +996,10 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
                          scale):
     """One (slot, page) grid step of the online softmax.
 
-    Everything stays in the pool's own ``[page_len, H*D]`` row layout:
-    head ``h`` is the static lane slice ``[h*D, (h+1)*D)`` (whole vregs
-    when ``D % 128 == 0``), scores are a VPU multiply + lane reduction
+    Everything stays in the pool's own ``[page_len, Hkv*D]`` row layout:
+    query head ``h`` is the static lane slice ``[h*D, (h+1)*D)`` of Q and
+    reads K/V head ``h // (H / Hkv)``, a static lane slice of the page
+    (whole vregs when ``D % 128 == 0``), scores are a VPU multiply + lane reduction
     and the PV product a sublane reduction — no ``dot_general``, so
     Mosaic sees neither a batch dimension nor an M=1 matmul.  The
     running max / denominator are kept replicated across each head's
@@ -1003,13 +1014,15 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     D = q_ref.shape[-1] // n_head
+    group = n_head // (k_ref.shape[-1] // D)
     valid = lens_ref[s, 0] - p * page_len
     live = jax.lax.broadcasted_iota(jnp.int32, (page_len, 1), 0) < valid
     for h in range(n_head):
         sl = slice(h * D, (h + 1) * D)
+        kv = slice(h // group * D, (h // group + 1) * D)
         q = q_ref[0, :, sl].astype(jnp.float32)     # [1, D]
-        k = k_ref[0, :, sl].astype(jnp.float32)     # [PL, D]
-        v = v_ref[0, :, sl].astype(jnp.float32)
+        k = k_ref[0, :, kv].astype(jnp.float32)     # [PL, D]
+        v = v_ref[0, :, kv].astype(jnp.float32)
         sc = jnp.sum(q * k, axis=1, keepdims=True) * scale
         sc = jnp.where(live, sc, NEG_INF)           # [PL, 1]
         m_prev = m_ref[:, sl]                       # [1, D]
@@ -1030,13 +1043,19 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-def _paged_kernel_ok(n_head, HD, PL, interpret):
-    """Shape gate of the paged kernel: heads must split H*D evenly, and
-    on the chip a head must cover whole 128-lane vregs and a page whole
-    8-row sublane tiles (the kernel slices refs at ``h*D`` lanes)."""
+def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None):
+    """Shape gate of the paged kernel: heads must split Q's ``H*D``
+    evenly and whole K/V heads the pool's row, the query heads divide
+    evenly over them, and on the chip a head must cover whole 128-lane
+    vregs and a page whole 8-row sublane tiles (the kernel slices refs
+    at ``h*D`` lanes)."""
     if HD % n_head:
         return False
-    return interpret or not ((HD // n_head) % 128 or PL % 8)
+    D = HD // n_head
+    HDkv = HD if HDkv is None else HDkv
+    if HDkv % D or n_head % (HDkv // D):
+        return False
+    return interpret or not (D % 128 or PL % 8)
 
 
 def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
@@ -1044,9 +1063,12 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     """Returns None when ``_paged_kernel_ok`` refuses the shape; any
     lowering error past that gate surfaces to the caller."""
     S, P = page_table.shape
-    NP, PL, HD = kc.shape
-    if not _paged_kernel_ok(n_head, HD, PL, interpret):
+    NP, PL, HDkv = kc.shape
+    HD = q.shape[-1]
+    if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv):
         return None
+    out_dtype = q.dtype
+    q = q.astype(kc.dtype)
     kernel = functools.partial(_paged_decode_kernel, page_len=PL,
                                n_head=n_head, scale=scale)
     out = pl.pallas_call(
@@ -1056,9 +1078,9 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
             grid=(S, P),
             in_specs=[
                 pl.BlockSpec((1, 1, HD), lambda s, p, pt, ln: (s, 0, 0)),
-                pl.BlockSpec((1, PL, HD),
+                pl.BlockSpec((1, PL, HDkv),
                              lambda s, p, pt, ln: (pt[s, p], 0, 0)),
-                pl.BlockSpec((1, PL, HD),
+                pl.BlockSpec((1, PL, HDkv),
                              lambda s, p, pt, ln: (pt[s, p], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, HD),
@@ -1068,7 +1090,7 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
         out_shape=jax.ShapeDtypeStruct((S, 1, HD), q.dtype),
         interpret=interpret,
     )(page_table, lens, q.reshape(S, 1, HD), kc, vc)
-    return out.reshape(q.shape)
+    return out.reshape(q.shape).astype(out_dtype)
 
 
 def _paged_kernel_enabled(interpret):
@@ -1093,8 +1115,9 @@ def _infer_paged_attn(op, block):
              no_gradient=True,
              stateful_outputs=("KCacheOut", "VCacheOut"))
 def paged_attention_lower(ctx: LowerContext):
-    """Q/K/V: [S, 1, H*D] this step's projections; KCache/VCache:
-    [num_pages, page_len, H*D] persistable pool; PageTable: [S, P] int32
+    """Q: [S, 1, H*D], K/V: [S, 1, Hkv*D] this step's projections;
+    KCache/VCache: [num_pages, page_len, Hkv*D] persistable pool (Hkv =
+    H unless the model groups its query heads); PageTable: [S, P] int32
     (P = the step's page bucket); Lens: [S, 1] int32 rows INCLUDING the
     current token (0 = free slot).  Out: [S, 1, H*D]; KCacheOut/
     VCacheOut name the cache vars themselves (in-place update).
@@ -1126,3 +1149,41 @@ def paged_attention_lower(ctx: LowerContext):
     ctx.set_output("Out", out)
     ctx.set_output("KCacheOut", kc)
     ctx.set_output("VCacheOut", vc)
+
+
+# ---------------------------------------------------------------------------
+# gqa_attention IR op: causal self-attention over ONE prompt with grouped
+# query heads (H query heads over Hkv K/V heads), the prefill-side partner of
+# a grouped paged_attention.  Plain XLA; scores and softmax in float32.
+# ---------------------------------------------------------------------------
+
+def gqa_attention(q, k, v, mask, n_head, n_kv_head, scale):
+    """``q`` [T, H*D]; ``k``, ``v`` [T, Hkv*D]; ``mask`` [T] (0 = pad
+    row, never attended).  Query head ``h`` reads K/V head
+    ``h // (H / Hkv)``.  Returns [T, H*D] in ``q``'s type."""
+    T = q.shape[0]
+    D = q.shape[-1] // n_head
+    g = n_head // n_kv_head
+    qh = q.reshape(T, n_kv_head, g, D)
+    kh, vh = k.reshape(T, n_kv_head, D), v.reshape(T, n_kv_head, D)
+    sc = jnp.einsum("qkgd,tkd->kgqt", qh, kh,
+                    preferred_element_type=jnp.float32) * scale
+    rows = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    seen = (cols <= rows) & (mask > 0)[None, :]
+    probs = jax.nn.softmax(jnp.where(seen, sc, NEG_INF), axis=-1)
+    out = jnp.einsum("kgqt,tkd->qkgd", probs.astype(v.dtype), vh,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
+
+
+@register_op("gqa_attention", infer_shape=infer_shape_unary("Q"),
+             no_grad_inputs=("Mask",))
+def gqa_attention_lower(ctx: LowerContext):
+    """Q [1, T, H*D]; K, V [1, T, Hkv*D]; Mask [1, T].  attrs n_head,
+    n_kv_head, scale.  Out [1, T, H*D]."""
+    out = gqa_attention(ctx.input("Q")[0], ctx.input("K")[0],
+                        ctx.input("V")[0], ctx.input("Mask")[0],
+                        int(ctx.attr("n_head")), int(ctx.attr("n_kv_head")),
+                        float(ctx.attr("scale", 1.0)))
+    ctx.set_output("Out", out[None])

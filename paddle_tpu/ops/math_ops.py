@@ -72,17 +72,21 @@ def _infer_matmul(op, block):
         shape = tuple(batch) + (xs[-2], ys[-1])
     out = block.var(op.output("Out")[0])
     out.shape = shape
-    out.dtype = x.dtype
+    out.dtype = op.attr("out_dtype", None) or x.dtype
 
 
 @register_op("matmul", infer_shape=_infer_matmul, amp_cast=("X", "Y"))
 def matmul_lower(ctx):
+    """attr ``out_dtype`` (optional): the type the products accumulate
+    into and the result keeps, e.g. float32 logits from bfloat16
+    operands; absent, the operands' own."""
     x, y = ctx.input("X"), ctx.input("Y")
     if ctx.attr("transpose_X", False):
         x = jnp.swapaxes(x, -1, -2) if x.ndim >= 2 else x
     if ctx.attr("transpose_Y", False):
         y = jnp.swapaxes(y, -1, -2) if y.ndim >= 2 else y
-    out = jnp.matmul(x, y)
+    out = jnp.matmul(x, y,
+                     preferred_element_type=ctx.attr("out_dtype", None))
     alpha = ctx.attr("alpha", 1.0)
     if alpha != 1.0:
         out = out * alpha

@@ -143,6 +143,94 @@ def test_page_seed_compiles_for_v5e_in_place(one_chip, bucket):
     assert mem.alias_size_in_bytes == N * pool_bytes, mem
 
 
+@pytest.mark.parametrize("pages", [8, 128])
+def test_grouped_paged_decode_compiles_for_v5e(one_chip, pages):
+    """The decode kernel with grouped query heads at the hybrid serving
+    cell's shapes: 32 slots, 32 bfloat16 query heads x 128 over a float32
+    pool whose rows hold 2 K/V heads (256 wide), page_len 16."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, NP, PL, H, HKV, D = 32, 4096, 16, 32, 2, 128
+
+    def fn(q, kc, vc, pt, lens):
+        out = A._pallas_paged_attention(q, kc, vc, pt, lens, H, D ** -0.5,
+                                        interpret=False)
+        assert out is not None, "shape gate refused grouped heads"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 1, H * D), jnp.bfloat16),
+                   ((NP, PL, HKV * D), jnp.float32),
+                   ((NP, PL, HKV * D), jnp.float32),
+                   ((S, pages), jnp.int32), ((S, 1), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_seed_of_pages_and_state_compiles_for_v5e_in_place(one_chip):
+    """Admission's compiled seed with per-slot state beside the pool, at
+    the hybrid serving cell's widths: 2 pools of 4096 pages x 16 rows x
+    256 f32 and five mixers' conv windows [32, 3, 10240] and recurrent
+    states [32, 128, 64, 128] (134 MB each).  Every pool and state input
+    is aliased to an output, and the temporaries stay far under one
+    state array: only the slot's row is touched."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.gen.predictor import _seed_pool
+    S, NP, PL, ROW, PPS, bucket = 32, 4096, 16, 256, 128, 1024
+    shapes = [(S, 3, 10240), (S, 128, 64, 128)] * 5
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = tuple(sds((NP, PL, ROW), jnp.float32) for _ in range(2))
+    kv = tuple(sds((1, bucket, ROW), jnp.bfloat16) for _ in range(2))
+    states = tuple(sds(shape, jnp.float32) for shape in shapes)
+    new = tuple(sds((1,) + shape[1:], jnp.float32) for shape in shapes)
+    compiled = _seed_pool.lower(
+        pools, kv, sds((PPS,), jnp.int32), sds((), jnp.int32), states, new,
+        sds((), jnp.int32), max_rows=PPS * PL).compile()
+    header = compiled.as_text().split("\n", 1)[0]
+    assert header.count("may-alias") + header.count("must-alias") == 12, \
+        header
+    mem = compiled.memory_analysis()
+    state_bytes = S * 128 * 64 * 128 * 4
+    assert mem.temp_size_in_bytes < state_bytes // 8, mem
+    assert mem.alias_size_in_bytes >= 5 * state_bytes, mem
+
+
+def test_hybrid_decode_ops_compile_for_v5e(one_chip):
+    """The one-token state update and the held experts' product at the
+    hybrid serving cell's widths (32 slots; 128 heads x 64 x state 128;
+    64 experts of 1024 x 2688): plain XLA, a state-sized or expert-sized
+    temporary would double the step's memory traffic."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe_ops, ssm_ops
+    S, H, C = 32, 128, 10240
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def update(x, dt, a, d, b, h, live):
+        return ssm_ops.ssm_update(x, dt, a, d, b, h, live, n_head=H,
+                                  head_dim=64, n_groups=8, state=128)
+
+    mem = jax.jit(update, donate_argnums=5).lower(
+        sds((S, C), bf), sds((S, H), bf), sds((H,), f32), sds((H,), f32),
+        sds((H,), f32), sds((S, H, 64, 128), f32),
+        sds((S,), jnp.bool_)).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 8 << 20, mem
+
+    def experts(u, idx, w, w1, w2, live):
+        return moe_ops.moe_experts(u, idx, w, w1, w2, 0, live)
+
+    mem = jax.jit(experts).lower(
+        sds((S, 1024), bf), sds((S, 22), jnp.int32), sds((S, 22), f32),
+        sds((64, 1024, 2688), bf), sds((64, 2688, 1024), bf),
+        sds((S,), jnp.bool_)).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < 64 << 20, mem
+
+
 @pytest.mark.parametrize("bias", ["row", "causal"])
 def test_fused_softmax_compiles_for_v5e(one_chip, bias):
     import jax.numpy as jnp
