@@ -30,8 +30,9 @@ flag.
 
 ``warmup`` declares BOTH signature families — every prefill bucket and
 the decode signature family — through ``Executor.warmup``, plus one
-seeding signature per prefill bucket, so a server flips ``/readyz``
-with the whole generation path compiled.
+seeding signature per prefill bucket and the scheduler's on-device token
+pick, so a server flips ``/readyz`` with the whole generation path
+compiled.
 
 PAGED bundles (meta carries ``page_len``; the default export) keep the
 KV pool as ``[num_pages, page_len, H*D]`` pages addressed through a
@@ -45,6 +46,7 @@ prefix pages, not ``max_len``.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import os
@@ -57,7 +59,7 @@ import numpy as np
 
 from paddle_tpu.obs.trace import span as _span
 
-__all__ = ["GenPredictor", "is_gen_bundle"]
+__all__ = ["GenPredictor", "is_gen_bundle", "pick_tokens"]
 
 META_FILENAME = "gen_meta.json"
 
@@ -110,6 +112,49 @@ def _seed_pool(pools, kv, idx, n, states=(), new_states=(), slot=0, *,
         return tuple(out)
 
     return jax.lax.fori_loop(0, n, write_entry, tuple(pools)) + states
+
+
+@jax.jit
+def _pick(logits, override):
+    ids = jnp.argmax(logits.reshape(logits.shape[0], -1), axis=-1)
+    return jnp.where(override >= 0, override, ids.astype(jnp.int32))[:, None]
+
+
+def pick_tokens(logits, override):
+    """The greedy next token of every slot, ``[S, 1]`` int32: the argmax
+    of the slot's row of ``logits`` (``[S, V]``; first index on ties, as
+    ``np.argmax``), or ``override[slot]`` where that is not negative (a
+    slot whose newest token the host holds: its first, from the prefill).
+
+    Logits that are a device array are picked from on the device, in one
+    compiled call, and the tokens stay there: fed to the next
+    :meth:`GenPredictor.decode_step` as they are, and read (``[S]`` ids,
+    not ``[S, V]`` logits) when the step is collected.  Host logits are
+    picked from on the host; ``None`` (no step to pick from) gives the
+    override alone."""
+    override = np.asarray(override, np.int32)
+    if isinstance(logits, jax.Array):
+        return _pick(logits, override)
+    if logits is None:
+        return np.maximum(override, 0)[:, None]
+    ids = np.argmax(np.asarray(logits).reshape(len(override), -1), axis=-1)
+    return np.where(override >= 0, override, ids.astype(np.int32))[:, None]
+
+
+def _warm_jit(fn, signature, run):
+    """``run()``, and the :class:`~paddle_tpu.obs.perf.WarmupReport`
+    bucket of what it compiled of the jitted ``fn``."""
+    from paddle_tpu.profiler import runtime_metrics
+    size0 = fn._cache_size()
+    hits0 = runtime_metrics.counter("compile_cache.hits")
+    t0 = time.perf_counter()
+    run()
+    fresh = fn._cache_size() - size0
+    hit = runtime_metrics.counter("compile_cache.hits") - hits0
+    return {"signature": signature, "compiles": fresh,
+            "seconds": time.perf_counter() - t0,
+            "cache": ("warm" if fresh == 0 else
+                      "persistent-hit" if hit > 0 else "cold")}
 
 
 def is_gen_bundle(model_dir):
@@ -217,6 +262,9 @@ class GenPredictor:
         self._prefill_cost = {}
         # clear_slot's zero rows, made once (device arrays)
         self._clear_kv = None
+        # the newest dispatched step's decode_stats array (None for a
+        # bundle without one): see decode_step(on_device=True)
+        self.last_decode_stats = None
         self._length_cost_fn = None
         self._page_cost_fn = None
 
@@ -545,7 +593,7 @@ class GenPredictor:
 
     # -- decode ------------------------------------------------------------
     def decode_step(self, tokens, positions, pos_onehot=None,
-                    attn_mask=None, lens=None):
+                    attn_mask=None, lens=None, on_device=False):
         """One decode iteration over the whole slot pool.
 
         ``tokens``/``positions``: int32 ``[S]`` (zeros for free slots).
@@ -565,6 +613,14 @@ class GenPredictor:
         counted always-on: ``gen.<name with its first _ as a .>``, a
         counter for a sum and a histogram for a max.
 
+        ``on_device`` is the scheduler's side of the call: the step is
+        dispatched and not waited for.  ``tokens`` may then be the device
+        array :func:`pick_tokens` made from the step before; the logits
+        come back as the device array the executable will fill, under
+        the caller's own ``gen.decode_step`` span, and the step's
+        ``decode_stats`` array is left unread in ``last_decode_stats``
+        for :meth:`count_decode_stats`.
+
         The ``gen.decode.stall`` failpoint fires INSIDE the lock: a
         ``delay`` action models per-iteration device time serialized per
         replica (the decode bench's cost model), an ``error`` a device
@@ -572,7 +628,8 @@ class GenPredictor:
         from paddle_tpu.fault import chaos
         S = self.num_slots
         feed = {
-            "gen_token": np.asarray(tokens, np.int32).reshape(S, 1),
+            "gen_token": tokens if isinstance(tokens, jax.Array)
+            else np.asarray(tokens, np.int32).reshape(S, 1),
             "gen_pos": np.asarray(positions, np.int32).reshape(S, 1),
         }
         live = None
@@ -589,18 +646,23 @@ class GenPredictor:
         with self._lock:
             chaos.fire("gen.decode.stall", slots=S)
             with self._fluid.scope_guard(self._scope):
-                with _span("gen.decode_step") as step:
+                with contextlib.nullcontext() if on_device \
+                        else _span("gen.decode_step") as step:
                     logits, *stats = self._exe.run(
                         self._dec_prog, feed=feed,
-                        fetch_list=self._dec_fetch)
-                    if stats and self.decode_stats:
-                        step.set(live=live,
-                                 **self._count_decode_stats(stats[0]))
-        return np.asarray(logits)
+                        fetch_list=self._dec_fetch,
+                        return_numpy=not on_device)
+                    self.last_decode_stats = stats[0] \
+                        if stats and self.decode_stats else None
+                    if not on_device and self.last_decode_stats is not None:
+                        step.set(live=live, **self.count_decode_stats(
+                            self.last_decode_stats))
+        return logits
 
-    def _count_decode_stats(self, stats):
-        """The step's ``decode_stats`` columns reduced over their rows:
-        counted always-on, returned for the span."""
+    def count_decode_stats(self, stats):
+        """A step's ``decode_stats`` array (read here, if it is still on
+        the device) with its columns reduced over their rows: counted
+        always-on, returned for the ``gen.decode_step`` span."""
         from paddle_tpu.profiler import runtime_metrics
         stats, out = np.asarray(stats), {}
         for j, col in enumerate(self.decode_stats):
@@ -644,12 +706,13 @@ class GenPredictor:
         """AOT-compile EVERY signature an admission or a decode step
         uses — one prefill signature per declared prompt bucket, the
         decode signature family (ONE signature for dense bundles; one
-        per declared page bucket for paged bundles) and one seeding
-        signature per prompt bucket (:func:`_seed_pool`) — so the first
-        real ``/generate`` pays zero compile time.  Returns a
+        per declared page bucket for paged bundles), one seeding
+        signature per prompt bucket (:func:`_seed_pool`) and the token
+        pick (:func:`pick_tokens`) — so the first real ``/generate``
+        pays zero compile time.  Returns a
         :class:`~paddle_tpu.obs.perf.WarmupReport` (int = fresh
         compiles; ``buckets`` carries one per-signature entry tagged
-        ``program: prefill|decode|seed`` with compile seconds and
+        ``program: prefill|decode|seed|pick`` with compile seconds and
         cold/persistent-hit/warm provenance — what ``/stats`` surfaces
         so a rolling restart's warm claim is checkable per bucket)."""
         buckets = [b for b in self.prompt_buckets if b <= self.max_len]
@@ -683,30 +746,45 @@ class GenPredictor:
                     self._dec_prog, dec_sigs,
                     fetch_list=self._dec_fetch, scope=self._scope,
                     allow_state_updates=self.cache_vars + self.state_vars)
+                pick = self._warm_pick(dec_sigs[0])
             seed = self._warm_seeds(buckets)
-        return WarmupReport.merge(pre, dec, seed,
-                                  labels=("prefill", "decode", "seed"))
+        return WarmupReport.merge(pre, dec, seed, pick,
+                                  labels=("prefill", "decode", "seed",
+                                          "pick"))
+
+    def _warm_pick(self, sig):
+        """The scheduler's round, once: a decode step's logits picked
+        from on the device (:func:`pick_tokens`) and the tokens fed to
+        the next step as the device array they are.  Zero feeds, as the
+        decode warm-up's; caller holds ``_lock`` inside the scope."""
+        from paddle_tpu.io import synth_feed_value
+        from paddle_tpu.obs.perf import WarmupReport
+        block = self._dec_prog.global_block()
+        feed = {name: synth_feed_value(shape, block.var(name).dtype)
+                for name, shape in sig.items()}
+
+        def round_trip():
+            for _ in range(2):
+                logits = self._exe.run(
+                    self._dec_prog, feed=feed, fetch_list=self._dec_fetch,
+                    return_numpy=False)[0]
+                feed["gen_token"] = pick_tokens(
+                    logits, np.full(self.num_slots, -1, np.int32))
+
+        entry = _warm_jit(_pick, {"logits": [self.num_slots,
+                                             self.vocab_size]}, round_trip)
+        return WarmupReport(entry["compiles"], [entry])
 
     def _warm_seeds(self, buckets):
         """Run the compiled seed once per prompt bucket with a trip
         count of 0 (the pools pass through untouched); caller holds
         ``_lock``."""
         from paddle_tpu.obs.perf import WarmupReport
-        from paddle_tpu.profiler import runtime_metrics
         idx = np.zeros(self.pages_per_slot if self.paged else 1, np.int32)
         entries = []
         for b in buckets:
-            size0 = _seed_pool._cache_size()
-            hits0 = runtime_metrics.counter("compile_cache.hits")
-            t0 = time.perf_counter()
             kv = self._zero_kv(b)
-            self._write_pool(kv, idx, 0)
-            fresh = _seed_pool._cache_size() - size0
-            hit = runtime_metrics.counter("compile_cache.hits") - hits0
-            entries.append({
-                "signature": {"kv": [len(kv)] + list(kv[0].shape)},
-                "compiles": fresh,
-                "seconds": time.perf_counter() - t0,
-                "cache": ("warm" if fresh == 0 else
-                          "persistent-hit" if hit > 0 else "cold")})
+            entries.append(_warm_jit(
+                _seed_pool, {"kv": [len(kv)] + list(kv[0].shape)},
+                lambda: self._write_pool(kv, idx, 0)))
         return WarmupReport(sum(e["compiles"] for e in entries), entries)

@@ -20,6 +20,18 @@ batch runs start-to-finish as a unit while later arrivals queue behind
 it.  That mode exists as the benchmark baseline (``bench_decode.py``):
 the measured gap between the two admission policies IS the
 continuous-batching win.
+
+Decode runs ONE STEP AHEAD of the host.  Nothing in a step needs the host
+before the next can start: decoding is greedy, positions advance by one,
+a live slot's pages do not change, and an ending by length is known
+before the token is.  Only the token comes from the device, and it stays
+there (``predictor.pick_tokens``).  So a turn with step *k* in flight
+dispatches step *k+1* first, then reads step *k*'s ``[S]`` token ids and
+emits them while the device runs *k+1*.  An ending the host cannot
+foresee (EOS, a cancel) costs one computed row that is thrown away; at
+every whole-token boundary (migrate, abort, close, crash) the step in
+flight is first collected and emitted, or dropped.  With nothing in
+flight (the first step after idle) a turn only dispatches.
 """
 
 from __future__ import annotations
@@ -30,6 +42,7 @@ import time
 
 import numpy as np
 
+from paddle_tpu.gen.predictor import pick_tokens
 from paddle_tpu.obs import trace as _trace
 from paddle_tpu.obs.slo import tick as _slo_tick
 from paddle_tpu.obs.trace import span as _span
@@ -133,12 +146,26 @@ class _Slot:
 
     def __init__(self, stream, prompt_len, first_token):
         self.stream = stream
-        # the NEXT decode step consumes first_token and writes its K/V
-        # at position prompt_len
+        # the next decode step DISPATCHED for this slot writes its K/V at
+        # ``pos``; ``steps`` counts those dispatched so far.  The slot's
+        # first step consumes first_token; the later ones are fed the
+        # device's own pick, and last_token is what the host has seen
         self.pos = prompt_len
         self.steps = 0
         self.last_token = first_token
         self.last_emit_t = time.perf_counter()
+
+
+class _Step:
+    """A dispatched decode step whose tokens nobody has read yet."""
+    __slots__ = ("rows", "logits", "stats")
+
+    def __init__(self, rows, logits, stats):
+        # rows: (slot index, _Slot, whether the stream reaches its length
+        # cap with this step) for each slot the step carries
+        self.rows = rows
+        self.logits = logits    # as decode_step(on_device=True) gave them
+        self.stats = stats      # the bundle's decode_stats array, or None
 
 
 class GenScheduler:
@@ -188,6 +215,7 @@ class GenScheduler:
         self._queue = []
         self._slots = {}          # slot index -> _Slot
         self._free = list(range(predictor.num_slots))
+        self._in_flight = None    # the uncollected _Step (scheduler thread)
         self._cv = threading.Condition()
         self._closed = False
         self._restarts = 0
@@ -348,6 +376,7 @@ class GenScheduler:
         from paddle_tpu import profiler as _profiler
         from paddle_tpu.serving import BatcherCrashed
         logger.exception("generation scheduler thread crashed")
+        self._in_flight = None
         with self._cv:
             queued, self._queue = self._queue, []
             active, self._slots = list(self._slots.values()), {}
@@ -380,27 +409,29 @@ class GenScheduler:
         while True:
             with self._cv:
                 while not self._queue and not self._slots and \
+                        self._in_flight is None and \
                         not self._closed and self._abort_exc is None \
                         and not self._migrate_req:
                     self._cv.wait(0.05)
                 if self._closed:
                     queued, self._queue = self._queue, []
                     active, self._slots = list(self._slots.items()), {}
+                    self._in_flight = None
                     break
             # one turn that has work: parent of every span below on
             # this thread; its self time is the sweep, the gauge, the
             # SLO tick and the lock waits
             with _span("gen.sched.turn"):
-                # kill/migrate run HERE — between decode iterations, the
-                # only point every live stream is at a whole-token
-                # boundary
+                # kill/migrate run HERE — between decode iterations;
+                # with the step in flight collected or dropped, every
+                # live stream is at a whole-token boundary
                 if self._abort_exc is not None:
                     self._do_abort()
                 if self._migrate_req:
                     self._do_migrate()
                 self._sweep_queue()
                 self._admit()
-                if self._slots:
+                if self._slots or self._in_flight is not None:
                     self._decode_iteration()
                     # a completed iteration is forward progress: the
                     # restart budget bounds CONSECUTIVE crashes, not
@@ -424,7 +455,10 @@ class GenScheduler:
     def _do_abort(self):
         """Scheduler-thread half of :meth:`abort_streams`: wholesale
         reset (slots, free list, page pool), every stream failed with
-        the retryable kill error."""
+        the retryable kill error.  The step in flight is dropped, as a
+        kill would: the device runs what was queued in order, so a later
+        admission's seed cannot be overtaken by its writes."""
+        self._in_flight = None
         with self._cv:
             exc, self._abort_exc = self._abort_exc, None
             queued, self._queue = self._queue, []
@@ -442,7 +476,12 @@ class GenScheduler:
         checkpoint every remaining stream at its current token boundary
         and hand it back as a ``("migrate", checkpoint)`` event, then
         release the slot/pages.  Queued (never-admitted) streams
-        migrate with zero emitted tokens."""
+        migrate with zero emitted tokens.  The step in flight is
+        collected and emitted first: a checkpoint's ``tokens`` are what
+        the client received, and nothing is queued on the device for a
+        slot whose pages go back."""
+        if self._in_flight is not None:
+            self._decode_iteration(dispatch=False)
         with self._cv:
             queued, self._queue = self._queue, []
             active = sorted(self._slots.items())
@@ -664,9 +703,11 @@ class GenScheduler:
             self._free.append(slot_idx)
         _profiler.runtime_metrics.inc("gen.evictions")
 
-    def _decode_iteration(self):
-        """One token for every live slot: sweep disconnects, build the
-        (constant-signature) step feeds, dispatch, scatter tokens."""
+    def _decode_iteration(self, dispatch=True):
+        """One scheduler turn of decoding: sweep disconnects, dispatch
+        the next step for every live slot that goes on (none with
+        ``dispatch`` false: a whole-token boundary), then collect and
+        emit the step that was in flight."""
         from paddle_tpu import profiler as _profiler
         # reclaim disconnected streams BEFORE paying a step for them
         with self._cv:
@@ -675,8 +716,8 @@ class GenScheduler:
             if slot.stream.cancelled:
                 self._evict(idx, reason="disconnect")
         with self._cv:
-            live = sorted(self._slots.items())
-        if not live:
+            live = sorted(self._slots.items()) if dispatch else []
+        if not live and self._in_flight is None:
             return
         # what is left of this span beside its two children is the feed
         # building below
@@ -685,7 +726,10 @@ class GenScheduler:
 
     def _step_and_emit(self, live, metrics):
         S, L = self.predictor.num_slots, self.predictor.max_len
-        tokens = np.zeros(S, np.int32)
+        prev = self._in_flight
+        carried = {idx: slot for idx, slot, _ in prev.rows} if prev else {}
+        # -1: the slot's token is the device's own pick from ``prev``
+        override = np.full(S, -1, np.int32)
         positions = np.zeros(S, np.int32)
         paged = getattr(self.predictor, "paged", False)
         if paged:
@@ -693,40 +737,67 @@ class GenScheduler:
         else:
             pos_onehot = np.zeros((S, L), np.float32)
             attn_mask = np.zeros((S, L), np.float32)
+        rows = []
         for idx, slot in live:
-            tokens[idx] = slot.last_token
+            cap = slot.stream.max_new_tokens
+            if 1 + slot.steps >= cap or slot.pos >= L:
+                continue    # ends by length with the step in flight
+            if carried.get(idx) is not slot:
+                override[idx] = slot.last_token
             positions[idx] = slot.pos
             if paged:
                 lens[idx] = slot.pos + 1
             else:
                 pos_onehot[idx, slot.pos] = 1.0
                 attn_mask[idx, :slot.pos + 1] = 1.0
-        metrics.bucket("gen.slot_occupancy", len(live))
+            slot.steps += 1
+            slot.pos += 1
+            rows.append((idx, slot, 1 + slot.steps >= cap or slot.pos >= L))
         t0 = time.perf_counter()
-        if paged:
-            logits = self.predictor.decode_step(tokens, positions,
-                                                lens=lens)
-        else:
-            logits = self.predictor.decode_step(tokens, positions,
-                                                pos_onehot, attn_mask)
+        kept = ()
+        # the scheduler thread's time in the predictor this turn: the
+        # next step's dispatch, then the wait for the one in flight
+        with _span("gen.decode_step", ahead=int(bool(rows and prev))) as step:
+            tokens = pick_tokens(prev.logits if prev else None, override)
+            self._in_flight = None
+            if rows:
+                metrics.bucket("gen.slot_occupancy", len(rows))
+                metrics.inc("gen.decode.steps")
+                if prev:
+                    metrics.inc("gen.decode.steps_ahead")
+                feeds = {"lens": lens} if paged else \
+                    {"pos_onehot": pos_onehot, "attn_mask": attn_mask}
+                logits = self.predictor.decode_step(
+                    tokens, positions, on_device=True, **feeds)
+                self._in_flight = _Step(rows, logits, getattr(
+                    self.predictor, "last_decode_stats", None))
+            if prev:
+                with _span("gen.collect"):
+                    ids = np.asarray(tokens).reshape(-1).tolist()
+                    attrs = {} if prev.stats is None else \
+                        self.predictor.count_decode_stats(prev.stats)
+                # a row whose slot was vacated since (EOS at the last
+                # collect, a cancel) was computed for nothing
+                kept = [row for row in prev.rows
+                        if self._slots.get(row[0]) is row[1]]
+                discarded = len(prev.rows) - len(kept)
+                metrics.inc("gen.decode.rows_discarded", discarded)
+                step.set(live=len(prev.rows), discarded=discarded, **attrs)
         now = time.perf_counter()
         metrics.observe("gen.decode_step_seconds", now - t0)
         with _span("gen.emit"):
-            for idx, slot in live:
+            for idx, slot, ends in kept:
                 stream = slot.stream
-                token = int(np.argmax(logits[idx]))
-                slot.steps += 1
-                slot.pos += 1
+                token = ids[idx]
                 slot.last_token = token
                 metrics.inc("gen.tokens")
                 metrics.observe("gen.intertoken_seconds",
                                 now - slot.last_emit_t)
                 slot.last_emit_t = now
                 stream.emit(token)
-                done = 1 + slot.steps
                 if token == stream.eos_id:
                     self._finish(stream, "eos")
                     self._evict(idx)
-                elif done >= stream.max_new_tokens or slot.pos >= L:
+                elif ends:
                     self._finish(stream, "length")
                     self._evict(idx)

@@ -182,7 +182,8 @@ class TestGenConsumers:
             def clear_slot(self, *a):
                 pass
 
-            def decode_step(self, tokens, positions, onehot, mask):
+            def decode_step(self, tokens, positions, pos_onehot, attn_mask,
+                            on_device=False):
                 out = np.zeros((self.num_slots, self.vocab_size),
                                np.float32)
                 out[:, 7] = 1.0

@@ -20,6 +20,8 @@ from paddle_tpu.gen import GenPredictor, GenScheduler, is_gen_bundle
 from paddle_tpu.models import gen_lm
 from paddle_tpu.serving import InferenceServer, ServingClient
 
+import gen_lookahead
+
 
 @pytest.fixture(scope="module")
 def bundle_dir(tmp_path_factory):
@@ -31,6 +33,16 @@ def bundle_dir(tmp_path_factory):
 @pytest.fixture(scope="module")
 def predictor(bundle_dir):
     p = GenPredictor(bundle_dir)
+    p.warmup()
+    return p
+
+
+@pytest.fixture(scope="module")
+def dense_predictor(tmp_path_factory):
+    d = str(tmp_path_factory.mktemp("genlm_dense") / "bundle")
+    gen_lm.export_gen_model(d, gen_lm.GenConfig(), num_slots=4,
+                            paged=False)
+    p = GenPredictor(d)
     p.warmup()
     return p
 
@@ -531,13 +543,8 @@ class TestPagedKV:
     occupancy-proportional decode bytes."""
 
     @pytest.fixture(scope="class")
-    def dense(self, tmp_path_factory):
-        d = str(tmp_path_factory.mktemp("genlm_dense") / "bundle")
-        gen_lm.export_gen_model(d, gen_lm.GenConfig(), num_slots=4,
-                                paged=False)
-        p = GenPredictor(d)
-        p.warmup()
-        return p
+    def dense(self, dense_predictor):
+        return dense_predictor
 
     def test_default_export_is_paged(self, predictor):
         assert predictor.paged
@@ -654,3 +661,95 @@ class TestPagedKV:
         small = paged_by_bucket[min(paged_by_bucket)]
         assert small < full, "decode bytes do not scale with pages"
         assert small <= 0.5 * dense_bytes, (small, dense_bytes)
+
+
+class TestLookahead:
+    """Decode runs one step ahead of the host: the next step is
+    dispatched from the device's own pick before the last step's tokens
+    are read.  The drills (``gen_lookahead.py``; a bundle with
+    ``state_vars`` runs them in ``test_hybrid_moe.py``) hold every stream
+    to the cache-free reference on both pool layouts."""
+
+    @pytest.fixture(params=["paged", "dense"])
+    def layout(self, request, predictor, dense_predictor):
+        return predictor if request.param == "paged" else dense_predictor
+
+    @pytest.fixture()
+    def ref(self, predictor):
+        return lambda prompt, n: _ref_greedy(predictor, prompt, n)
+
+    @pytest.mark.parametrize("drill", gen_lookahead.DRILLS,
+                             ids=lambda drill: drill.__name__)
+    def test_drill(self, drill, layout, ref):
+        drill(layout, ref)
+
+    def test_next_step_is_dispatched_before_the_last_is_read(
+            self, predictor, monkeypatch):
+        """The order of one stream's turns, and what crosses to the host:
+        step k+1 is dispatched, then step k's ``[S, 1]`` ids are read;
+        the ``[S, V]`` logits never are."""
+        import jax
+        from paddle_tpu.gen import scheduler as sched_mod
+        log = []
+
+        class OnDevice:
+            def __init__(self, array, read_as):
+                self.array, self.read_as = array, read_as
+
+            def __array__(self, *args, **kwargs):
+                log.append((self.read_as, tuple(self.array.shape)))
+                return np.asarray(self.array)
+
+        class Recording:
+            def __getattr__(self, name):
+                return getattr(predictor, name)
+
+            def decode_step(self, tokens, *args, **kwargs):
+                log.append(("dispatch",))
+                if isinstance(tokens, OnDevice):
+                    tokens = tokens.array
+                logits = predictor.decode_step(tokens, *args, **kwargs)
+                assert isinstance(logits, jax.Array)
+                return OnDevice(logits, "read logits")
+
+        real_pick = sched_mod.pick_tokens
+
+        def pick(logits, override):
+            if logits is not None:
+                logits = logits.array
+            tokens = real_pick(logits, override)
+            assert logits is None or isinstance(tokens, jax.Array)
+            return OnDevice(tokens, "read ids")
+
+        monkeypatch.setattr(sched_mod, "pick_tokens", pick)
+        prompt = [5, 9, 3, 17]
+        sched = GenScheduler(Recording(), queue_size=8)
+        try:
+            got = list(sched.submit(prompt, max_new_tokens=6))
+            gen_lookahead.settle(sched)
+        finally:
+            sched.close()
+        assert got == _ref_greedy(predictor, prompt, 6)
+        ids = ("read ids", (predictor.num_slots, 1))
+        # the pipeline fills (two dispatches), then every turn reads the
+        # step before the one it dispatched; the last has none to dispatch
+        assert log == [("dispatch",)] + [("dispatch",), ids] * 4 + [ids]
+
+    def test_warmup_compiles_the_pick(self, bundle_dir):
+        """``warmup()`` compiles the on-device pick with the decode
+        signatures: a warmed scheduler decodes with no compile event."""
+        from paddle_tpu.gen import predictor as predictor_mod
+        profiler.install_jax_compile_listeners()
+        predictor_mod._pick.clear_cache()
+        p = GenPredictor(bundle_dir)
+        picks = [b for b in p.warmup().buckets if b["program"] == "pick"]
+        assert [b["compiles"] for b in picks] == [1]
+        events = profiler.runtime_metrics.counter("compile.events")
+        sched = GenScheduler(p, queue_size=8)
+        try:
+            got = [list(sched.submit(prompt, max_new_tokens=5))
+                   for prompt in ([3, 5, 7], [9] * 20)]
+        finally:
+            sched.close()
+        assert [len(g) for g in got] == [5, 5]
+        assert profiler.runtime_metrics.counter("compile.events") == events

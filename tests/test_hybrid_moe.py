@@ -23,6 +23,8 @@ from paddle_tpu.models import gen_lm, hybrid_moe
 from paddle_tpu.ops import attention_ops, moe_ops, ssm_ops
 from paddle_tpu.serving import InferenceServer, ServingClient
 
+import gen_lookahead
+
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
 if BENCH not in sys.path:
@@ -480,6 +482,29 @@ def test_scheduler_streams_the_references_greedy_tokens(predictor, weights,
             assert list(s) == _ref_greedy(weights, cfg, p, 6)
     finally:
         sched.close()
+
+
+@pytest.mark.parametrize("drill", gen_lookahead.DRILLS,
+                         ids=lambda drill: drill.__name__)
+def test_the_lookahead_keeps_the_state_rows_with_their_streams(
+        drill, predictor, weights, cfg):
+    """The scheduler runs one step ahead of the host (``gen_lookahead.py``):
+    beside the pages a slot here has recurrent-state rows, which a
+    discarded row has written and the next seed must overwrite."""
+    memo = {}
+
+    def ref_greedy(prompt, n):
+        # the reference is causal: padded to one length (one shape to
+        # compile), a position's logits are those of the bare sequence;
+        # and greedy tokens are a prefix of any longer run's
+        seq = memo.setdefault(tuple(prompt), list(prompt))
+        while len(seq) < len(prompt) + n:
+            padded = seq + [0] * (predictor.max_len - len(seq))
+            seq.append(int(np.argmax(_ref_logits(
+                weights, cfg, padded, [len(seq) - 1])[0])))
+        return seq[len(prompt):len(prompt) + n]
+
+    drill(predictor, ref_greedy)
 
 
 def test_reprefill_failover_on_the_state_bundle(bundle_dir, weights, cfg):

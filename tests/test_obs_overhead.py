@@ -94,7 +94,8 @@ class TestDisabledTracingOverhead:
     def test_scheduler_turn_overhead_under_5_percent(self):
         """The spans one ``GenScheduler`` loop turn carries with tracing
         off — the turn, one decode iteration with its step (an
-        ``Executor.run`` with its three phases) and its emit loop, and
+        ``Executor.run`` with its three phases, then the collect of the
+        step that was in flight) and its emit loop, and
         one admission (queue wait, admit, prefill with its run, first
         token, seed) — against the same modeled 1 ms step; a real decode
         step on the chip is ten times that."""
@@ -121,8 +122,11 @@ class TestDisabledTracingOverhead:
                             seed.set(pages=1)
                             seed.set(compiled_calls=1, eager_ops=0)
                 with trace.span("gen.decode_iteration", live=i):
-                    with trace.span("gen.decode_step"):
+                    with trace.span("gen.decode_step", ahead=1) as step:
                         run()
+                        with trace.span("gen.collect"):
+                            pass
+                        step.set(live=i, discarded=0)
                     with trace.span("gen.emit"):
                         pass
 
