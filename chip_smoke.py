@@ -348,8 +348,7 @@ def phase_server(n_head=8, d_head=128, d_ffn=4096, n_layer=8,
         t0 = time.perf_counter()
         bundle = gen_lm.export_gen_model(
             tmp + "/bundle", hp, num_slots=num_slots,
-            prompt_buckets=list(prompt_buckets), paged=True,
-            page_len=page_len)
+            prompt_buckets=list(prompt_buckets), page_len=page_len)
         t_export = time.perf_counter() - t0
         # what `paddle_tpu serve --model D --warmup` builds (cli._cmd_serve
         # -> serving.serve -> InferenceServer), minus serve_forever's block

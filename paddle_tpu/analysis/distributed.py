@@ -850,11 +850,19 @@ def check_gen_bundle(prefill, decode, meta):
     num_slots = meta.get("num_slots")
     max_len = meta.get("max_len")
     page_len = meta.get("page_len")
-    paged = page_len is not None
+    if page_len is None:
+        # outside input: a bundle exported when a dense
+        # [num_slots, max_len] pool existed must fail here, at load and
+        # under lint, not mis-seed a page pool it does not have
+        return [Diagnostic(
+            "PTA019",
+            "gen_meta.json has no page_len — the dense KV layout was "
+            "removed and every bundle is a page pool; re-export it "
+            "(models.gen_lm.export_gen_model)", program="gen_meta")]
     num_pages = meta.get("num_pages")
     pt_feed = meta.get("page_table_feed", "gen_page_table")
     pages_per_slot = None
-    if paged and max_len is not None and int(page_len) > 0:
+    if max_len is not None and int(page_len) > 0:
         pages_per_slot = -(-int(max_len) // int(page_len))
 
     # -- PTA018: prompt buckets must be sane and inside the cache ------
@@ -882,48 +890,47 @@ def check_gen_bundle(prefill, decode, meta):
                 f"there compiles at request time",
                 program="gen_meta"))
 
-    # -- PTA018: page buckets — the paged decode jit-signature ladder --
-    if paged:
-        pbuckets = list(meta.get("page_buckets") or ())
-        if not pbuckets:
+    # -- PTA018: page buckets — the decode jit-signature ladder --
+    pbuckets = list(meta.get("page_buckets") or ())
+    if not pbuckets:
+        diags.append(Diagnostic(
+            "PTA018",
+            "paged gen bundle declares no page_buckets — every "
+            "distinct live page count compiles a fresh decode "
+            "executable", program="gen_meta"))
+    else:
+        if any(b2 <= b1 for b1, b2 in zip(pbuckets, pbuckets[1:])):
             diags.append(Diagnostic(
                 "PTA018",
-                "paged gen bundle declares no page_buckets — every "
-                "distinct live page count compiles a fresh decode "
-                "executable", program="gen_meta"))
-        else:
-            if any(b2 <= b1 for b1, b2 in zip(pbuckets, pbuckets[1:])):
-                diags.append(Diagnostic(
-                    "PTA018",
-                    f"page_buckets {pbuckets} are not strictly "
-                    f"increasing — row_bucket's edge walk needs sorted "
-                    f"edges, so lookups past the disorder fall off the "
-                    f"declared (warmed) ladder", program="gen_meta"))
-            if pages_per_slot is not None and \
-                    pbuckets[-1] < pages_per_slot:
-                diags.append(Diagnostic(
-                    "PTA018",
-                    f"largest page bucket {pbuckets[-1]} covers only "
-                    f"{pbuckets[-1] * int(page_len)} of max_len "
-                    f"{max_len} — a slot growing past it escapes the "
-                    f"declared (warmed) ladder and compiles at request "
-                    f"time", program="gen_meta"))
-            if pages_per_slot is not None and \
-                    pbuckets[-1] > pages_per_slot:
-                diags.append(Diagnostic(
-                    "PTA018",
-                    f"largest page bucket {pbuckets[-1]} exceeds the "
-                    f"per-slot page count {pages_per_slot} — the "
-                    f"bucket is declared (and warmed) but no slot can "
-                    f"ever reach it", program="gen_meta"))
+                f"page_buckets {pbuckets} are not strictly "
+                f"increasing — row_bucket's edge walk needs sorted "
+                f"edges, so lookups past the disorder fall off the "
+                f"declared (warmed) ladder", program="gen_meta"))
+        if pages_per_slot is not None and \
+                pbuckets[-1] < pages_per_slot:
+            diags.append(Diagnostic(
+                "PTA018",
+                f"largest page bucket {pbuckets[-1]} covers only "
+                f"{pbuckets[-1] * int(page_len)} of max_len "
+                f"{max_len} — a slot growing past it escapes the "
+                f"declared (warmed) ladder and compiles at request "
+                f"time", program="gen_meta"))
+        if pages_per_slot is not None and \
+                pbuckets[-1] > pages_per_slot:
+            diags.append(Diagnostic(
+                "PTA018",
+                f"largest page bucket {pbuckets[-1]} exceeds the "
+                f"per-slot page count {pages_per_slot} — the "
+                f"bucket is declared (and warmed) but no slot can "
+                f"ever reach it", program="gen_meta"))
 
     # -- PTA019: decode signature must be constant ---------------------
-    # (the paged page-table feed is the ONE sanctioned dynamic dim: its
+    # (the page-table feed is the ONE sanctioned dynamic dim: its
     # width is bucketed by the predictor, so the jit key is the bucket)
     dec_block = dec_prog.global_block()
     for name in dec_feeds or ():
         shape, _ = _var_meta(dec_block, name)
-        if paged and name == pt_feed:
+        if name == pt_feed:
             if shape is not None and len(shape) == 2 and \
                     num_slots is not None and shape[0] != int(num_slots):
                 diags.append(Diagnostic(
@@ -940,13 +947,13 @@ def check_gen_bundle(prefill, decode, meta):
                 f"{shape} — every decode step must share ONE jit "
                 f"signature; admission/eviction would recompile",
                 var=name, program="decode"))
-    if paged and pt_feed not in (dec_feeds or ()):
+    if pt_feed not in (dec_feeds or ()):
         diags.append(Diagnostic(
             "PTA019",
-            f"paged gen bundle's decode program does not feed "
+            f"gen bundle's decode program does not feed "
             f"`{pt_feed}` — page-bucketed decode cannot address the "
             f"pool", var=pt_feed, program="decode"))
-    if paged and num_pages is not None and pages_per_slot is not None \
+    if num_pages is not None and pages_per_slot is not None \
             and int(num_pages) < pages_per_slot:
         diags.append(Diagnostic(
             "PTA019",
@@ -971,25 +978,13 @@ def check_gen_bundle(prefill, decode, meta):
                 f"program — the KV pool would not live across steps",
                 var=name, program="decode"))
         shape, _ = _var_meta(dec_block, name)
-        if paged:
-            if shape is not None and num_pages is not None and \
-                    len(shape) >= 2 and \
-                    (shape[0] != int(num_pages) or
-                     shape[1] != int(page_len)):
-                diags.append(Diagnostic(
-                    "PTA019",
-                    f"cache var `{name}` is {shape} but gen_meta "
-                    f"declares [num_pages={num_pages}, "
-                    f"page_len={page_len}, ...] — the bundle drifted "
-                    f"between export and meta",
-                    var=name, program="decode"))
-        elif shape is not None and num_slots is not None and \
-                max_len is not None and len(shape) >= 2 and \
-                (shape[0] != int(num_slots) or shape[1] != int(max_len)):
+        if shape is not None and num_pages is not None and \
+                len(shape) >= 2 and \
+                (shape[0] != int(num_pages) or shape[1] != int(page_len)):
             diags.append(Diagnostic(
                 "PTA019",
                 f"cache var `{name}` is {shape} but gen_meta declares "
-                f"[num_slots={num_slots}, max_len={max_len}, ...] — "
+                f"[num_pages={num_pages}, page_len={page_len}, ...] — "
                 f"the bundle drifted between export and meta",
                 var=name, program="decode"))
 

@@ -182,11 +182,10 @@ def _check_gen_bundle():
 
 
 def _check_paged_kv():
-    """Paged-KV gate: a fresh paged gen export carries complete
-    page-bucket meta, the paged decode program lints clean, and the
-    static cost model prices the decode step proportionally to the fed
-    page count — the occupancy-proportional read contract
-    ``bench_paged.py`` times."""
+    """Paged-KV gate: a fresh gen export carries complete page-bucket
+    meta, the decode program lints clean, and the static cost model
+    prices the decode step proportionally to the fed page count — the
+    occupancy-proportional read contract."""
     import json
 
     from paddle_tpu import analysis

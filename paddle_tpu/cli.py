@@ -1212,7 +1212,7 @@ def _cmd_profile_memory(args):
     if args.json:
         print(_json.dumps(census, indent=2, sort_keys=True))
         return 0
-    for key in ("params", "optimizer", "kv_cache", "prefetch", "other",
+    for key in ("params", "optimizer", "kv_pages", "prefetch", "other",
                 "total", "high_watermark", "limit", "headroom"):
         if key in census:
             print(f"hbm.{key:<16}{census[key]:>14} bytes")

@@ -10,8 +10,8 @@ the vLLM/Orca phase boundary:
 * :meth:`decode_step` advances EVERY slot of the cache pool by one
   token.  The cache tensors are persistable state in the decode scope —
   they live on device across steps (the executor's donated in-place
-  update path) and the step's feed signature is constant, so admission
-  and eviction never change the jit key.
+  update path) and the step's feed signature is one of a declared few,
+  so admission and eviction never change the jit key.
 * :meth:`write_slot` / :meth:`clear_slot` are the (per-request, not
   per-token) slot writes that seed and reclaim cache rows: ONE compiled
   call over every cache array with the pools donated
@@ -34,14 +34,14 @@ seeding signature per prefill bucket and the scheduler's on-device token
 pick, so a server flips ``/readyz`` with the whole generation path
 compiled.
 
-PAGED bundles (meta carries ``page_len``; the default export) keep the
-KV pool as ``[num_pages, page_len, H*D]`` pages addressed through a
-host-side per-slot page table.  The predictor owns the page allocator
-(:meth:`alloc_slot_pages` / :meth:`free_slot_pages`, driven by the
-scheduler's admit/evict), pads the page-table feed to a declared
-``page_buckets`` edge each step (the decode jit key is the bucket), and
-warms one decode signature per bucket.  Decode reads scale with live
-prefix pages, not ``max_len``.
+The KV pool is ``[num_pages, page_len, H*D]`` pages addressed through a
+host-side per-slot page table (``page_len``, ``num_pages`` and
+``page_buckets`` are required keys of ``gen_meta.json``).  The predictor
+owns the page allocator (:meth:`alloc_slot_pages` /
+:meth:`free_slot_pages`, driven by the scheduler's admit/evict), pads
+the page-table feed to a declared ``page_buckets`` edge each step (the
+decode jit key is the bucket), and warms one decode signature per
+bucket.  Decode reads scale with live prefix pages, not ``max_len``.
 """
 
 from __future__ import annotations
@@ -71,9 +71,9 @@ def _seed_pool(pools, kv, idx, n, states=(), new_states=(), slot=0, *,
     """The one way the KV pool and the per-slot state are written
     outside the decode step.
 
-    ``pools``: every cache array, ``[N, unit, width]`` (paged: pages of
-    ``page_len`` rows; dense: slots of ``max_len`` rows; the row width is
-    each array's own), DONATED — the update is in place.  ``kv``: one
+    ``pools``: every cache array, ``[N, unit, width]`` (pages of
+    ``page_len`` rows; the row width is each array's own), DONATED — the
+    update is in place.  ``kv``: one
     ``[1, bucket, width]`` array per pool (zeros on pad rows).
     ``states``: every per-slot state array ``[num_slots, ...]``, DONATED;
     ``new_states``: one ``[1, ...]`` value per state array, written whole
@@ -184,19 +184,6 @@ class GenPredictor:
         self.decode_stats = list(self.meta.get("decode_stats") or ())
         self.prompt_buckets = [int(b) for b in self.meta["prompt_buckets"]]
         self.max_prompt_len = min(self.prompt_buckets[-1], self.max_len)
-        self.paged = "page_len" in self.meta
-        if self.paged:
-            self.page_len = int(self.meta["page_len"])
-            self.num_pages = int(self.meta["num_pages"])
-            self.page_buckets = [int(b)
-                                 for b in self.meta["page_buckets"]]
-            self.pages_per_slot = -(-self.max_len // self.page_len)
-            # host-side page allocator state (all mutated under _lock):
-            # the device only ever sees the bucketed table SLICE
-            self._page_table = np.zeros(
-                (self.num_slots, self.pages_per_slot), np.int32)
-            self._slot_pages = {}
-            self._free_list = list(range(self.num_pages))
 
         self._fluid = fluid
         self._scope = fluid.Scope()
@@ -221,13 +208,24 @@ class GenPredictor:
             (self._pre_prog, self._pre_feeds, self._pre_fetch),
             (self._dec_prog, self._dec_feeds, self._dec_fetch),
             self.meta)).raise_on_errors(where="gen.GenPredictor")
+        # the page pool's geometry, read once the check above has held
+        # the meta to it (a bundle without pages does not get this far)
+        self.page_len = int(self.meta["page_len"])
+        self.num_pages = int(self.meta["num_pages"])
+        self.page_buckets = [int(b) for b in self.meta["page_buckets"]]
+        self.pages_per_slot = -(-self.max_len // self.page_len)
+        # host-side page allocator state (all mutated under _lock): the
+        # device only ever sees the bucketed table SLICE
+        self._page_table = np.zeros(
+            (self.num_slots, self.pages_per_slot), np.int32)
+        self._slot_pages = {}
+        self._free_list = list(range(self.num_pages))
         # decode dispatches derive gen.decode_mfu (not train.mfu): the
         # executor keys the gauge off this program attribute
         self._dec_prog._mfu_gauge = "gen.decode_mfu"
-        # HBM census: the KV pool is its own collection — a paged
-        # bundle's pool (plus its host page table) reports as
-        # ``kv_pages``, the dense layout as ``kv_cache``; weakref'd so
-        # a dropped predictor releases cleanly
+        # HBM census: the KV pool (plus its host page table) is its own
+        # collection, ``kv_pages``; weakref'd so a dropped predictor
+        # releases cleanly
         import weakref
         from paddle_tpu.obs import perf as _perf
         ref = weakref.ref(self)
@@ -236,12 +234,10 @@ class GenPredictor:
             p = ref()
             if p is None:
                 return ()
-            bufs = [v for v in (p._scope.find_var(n)
+            return [v for v in (p._scope.find_var(n)
                                 for n in p.cache_vars)
-                    if v is not None and hasattr(v, "nbytes")]
-            if p.paged:
-                bufs.append(p._page_table)
-            return bufs
+                    if v is not None and hasattr(v, "nbytes")] + \
+                [p._page_table]
 
         def _state_buffers():
             p = ref()
@@ -251,8 +247,7 @@ class GenPredictor:
 
         # a reloaded predictor must not leave a dead provider behind
         for collection, fn in (
-                ("kv_pages" if self.paged else "kv_cache", _kv_buffers),
-                ("gen_state", _state_buffers)):
+                ("kv_pages", _kv_buffers), ("gen_state", _state_buffers)):
             weakref.finalize(self, _perf.unregister_hbm_provider,
                              _perf.register_hbm_provider(collection, fn))
         # per-bucket constant prefill feeds (causal bias template)
@@ -293,7 +288,7 @@ class GenPredictor:
             return self._length_cost_fn
 
     def _page_write_cost(self, prompt_len):
-        """Flop-equivalent of seeding a paged slot: every allocated
+        """Flop-equivalent of seeding a slot: every allocated
         prompt page of every cache array is written whole, at that
         array's own row width, and every state array's row for the slot
         — what admission budgets must see on top of the prefill
@@ -307,44 +302,38 @@ class GenPredictor:
 
     def prefill_cost(self, prompt_len):
         """Static FLOPs of prefilling a prompt of ``prompt_len`` tokens
-        (priced at its padded bucket — what the device actually runs;
-        paged bundles add the slot's page-seeding writes, so the memo
-        key grows a page dimension).  The GenScheduler weighs
+        (priced at its padded bucket — what the device actually runs —
+        plus the slot's page-seeding writes, so the memo is keyed by
+        bucket and pages).  The GenScheduler weighs
         admissions with this so one decode iteration never stalls
         behind an unbounded prefill burst.  Cheap after the first call
         per (bucket, pages); the underlying fit is warmed by
         GenScheduler construction."""
         prompt_len = int(prompt_len)
         bucket = self._bucket(prompt_len)
-        if self.paged:
-            pages = -(-max(prompt_len, 1) // self.page_len)
-            key = (bucket, pages)
-        else:
-            key = bucket
+        key = (bucket, -(-max(prompt_len, 1) // self.page_len))
         hit = self._prefill_cost.get(key)
         if hit is None:
-            hit = float(self._cost_fn()(bucket))
-            if self.paged:
-                hit += self._page_write_cost(prompt_len)
+            hit = float(self._cost_fn()(bucket)) + \
+                self._page_write_cost(prompt_len)
             self._prefill_cost[key] = hit
         return hit
 
     def plan_prompt_buckets(self, observed_lengths, max_edges=4):
         """Cost-optimal prompt buckets for an OBSERVED length
         distribution: ``lod.select_bucket_edges`` weighted by the
-        prefill program's static FLOPs-per-bucket (plus, for paged
-        bundles, the candidate length's page-seeding writes).  Returns
+        prefill program's static FLOPs-per-bucket plus the candidate
+        length's page-seeding writes.  Returns
         a sorted edge list (capped at the bundle's ``max_len``) an
         operator can bake into the next export's ``gen_meta.json``."""
         from paddle_tpu.lod import select_bucket_edges
         lengths = [min(max(int(n), 1), self.max_len)
                    for n in observed_lengths]
-        cost_of = self._cost_fn()
-        if self.paged:
-            base = cost_of
+        base = self._cost_fn()
 
-            def cost_of(n):
-                return float(base(n)) + self._page_write_cost(n)
+        def cost_of(n):
+            return float(base(n)) + self._page_write_cost(n)
+
         return select_bucket_edges(lengths, max_edges=max_edges,
                                    cost_of=cost_of)
 
@@ -357,8 +346,6 @@ class GenPredictor:
         that dimension carry the pages actually read).  Returns a
         sorted edge list an operator can bake into the next export's
         ``page_buckets``."""
-        if not self.paged:
-            raise ValueError("plan_page_buckets needs a paged bundle")
         from paddle_tpu.lod import select_bucket_edges
         counts = [min(max(-(-int(n) // self.page_len), 1),
                       self.pages_per_slot) for n in observed_lengths]
@@ -372,12 +359,10 @@ class GenPredictor:
         return select_bucket_edges(counts, max_edges=max_edges,
                                    cost_of=fn)
 
-    # -- page allocator (paged bundles; driven by the scheduler) -----------
+    # -- page allocator (driven by the scheduler) --------------------------
     @property
     def free_pages(self):
-        """Unallocated pool pages (paged bundles; 0 for dense)."""
-        if not self.paged:
-            return 0
+        """Unallocated pool pages."""
         with self._lock:
             return len(self._free_list)
 
@@ -411,8 +396,6 @@ class GenPredictor:
         """Return EVERY slot's pages to the pool — the scheduler's
         crash-reset path, which discards all slots wholesale; returns
         the number of pages freed."""
-        if not self.paged:
-            return 0
         with self._lock:
             slots = list(self._slot_pages)
         return sum(self.free_slot_pages(s) for s in slots)
@@ -422,8 +405,6 @@ class GenPredictor:
         returns the number freed.  The rows themselves are reclaimed
         lazily — re-allocation seeds pages via :meth:`write_slot`
         before any read addresses them."""
-        if not self.paged:
-            return 0
         with self._lock:
             pages = self._slot_pages.pop(slot, None)
             if not pages:
@@ -521,19 +502,15 @@ class GenPredictor:
                    if isinstance(a, jax.Array) and not a.is_deleted())
 
     def _seed_slot(self, slot, kv):
-        """``slot``'s entries of the pool <- ``kv`` (caller holds
-        ``_lock``): its allocated pages (paged; None before
-        ``alloc_slot_pages``) or its row (dense).  Returns what
+        """``slot``'s allocated pages of the pool <- ``kv`` (caller holds
+        ``_lock``); None before ``alloc_slot_pages``.  Returns what
         :meth:`_write_pool` does; ``gen.seed.eager_ops`` counts it."""
         from paddle_tpu.profiler import runtime_metrics
-        if self.paged:
-            pages = self._slot_pages.get(slot)
-            if not pages:
-                return None
-            idx = np.zeros(self.pages_per_slot, np.int32)
-            idx[:len(pages)] = pages
-        else:
-            idx, pages = np.asarray([slot], np.int32), (slot,)
+        pages = self._slot_pages.get(slot)
+        if not pages:
+            return None
+        idx = np.zeros(self.pages_per_slot, np.int32)
+        idx[:len(pages)] = pages
         copied = self._write_pool(kv, idx, len(pages), slot)
         runtime_metrics.inc("gen.seed.compiled_calls")
         runtime_metrics.inc("gen.seed.eager_ops", copied)
@@ -563,15 +540,14 @@ class GenPredictor:
                 for v in self._pre_fetch[1 + k:]]
 
     def write_slot(self, slot, kv, prompt_len):
-        """Seed cache slot ``slot`` with a prefill's K/V rows (the rest
-        of the slot is zeroed — decode's add-writes land on zeros).
+        """Seed cache slot ``slot`` with a prefill's K/V rows.
 
         One compiled call for all ``2 * n_layer`` cache arrays, pools
         donated: the K/V stay on the device and the pools are updated
-        in place.  Paged bundles write the slot's ALLOCATED pages whole
-        (prompt rows + zero fill — re-used pages carry no stale rows);
-        the dense layout writes the slot's row.  Returns the number of
-        pool arrays that were copied instead (0 when healthy)."""
+        in place.  The slot's ALLOCATED pages are written whole (prompt
+        rows + zero fill — re-used pages carry no stale rows).  Returns
+        the number of pool arrays that were copied instead (0 when
+        healthy)."""
         with self._lock:
             copied = self._seed_slot(slot, kv)
         if copied is None:
@@ -582,9 +558,9 @@ class GenPredictor:
     def clear_slot(self, slot):
         """Zero a reclaimed slot's cache rows — the same compiled call
         as :meth:`write_slot`, fed zero rows of the smallest prompt
-        bucket.  Not strictly required — admission overwrites the whole
-        row (or, paged, seeds every re-allocated page) — but keeps a
-        freed slot from pinning stale request data."""
+        bucket.  Not strictly required — admission seeds every
+        re-allocated page — but keeps a freed slot from pinning stale
+        request data."""
         with self._lock:
             if self._clear_kv is None:
                 self._clear_kv = self._zero_kv(
@@ -592,16 +568,12 @@ class GenPredictor:
             self._seed_slot(slot, self._clear_kv)
 
     # -- decode ------------------------------------------------------------
-    def decode_step(self, tokens, positions, pos_onehot=None,
-                    attn_mask=None, lens=None, on_device=False):
+    def decode_step(self, tokens, positions, lens, on_device=False):
         """One decode iteration over the whole slot pool.
 
         ``tokens``/``positions``: int32 ``[S]`` (zeros for free slots).
-        Dense bundles take ``pos_onehot``: f32 ``[S, L]`` write mask
-        (all-zero rows for free slots — their cache is never touched)
-        and ``attn_mask``: f32 ``[S, L]`` attendable-position mask.
-        Paged bundles take ``lens``: int32 ``[S]`` prefix rows
-        INCLUDING the current token (0 = free slot) — the page-table
+        ``lens``: int32 ``[S]`` prefix rows INCLUDING the current token
+        (0 = free slot: its pages are never touched) — the page-table
         feed is sliced to the smallest declared page bucket covering
         ``max(lens)``, so the jit key is the bucket.  Returns logits
         ``[S, V]``.
@@ -632,16 +604,9 @@ class GenPredictor:
             else np.asarray(tokens, np.int32).reshape(S, 1),
             "gen_pos": np.asarray(positions, np.int32).reshape(S, 1),
         }
-        live = None
-        if self.paged:
-            if lens is None:
-                raise ValueError("paged decode_step needs lens")
-            feed.update(self._paged_decode_feed(
-                np.asarray(lens, np.int32).reshape(S, 1)))
-            live = int(np.count_nonzero(feed["gen_lens"]))
-        else:
-            feed["gen_pos_onehot"] = np.asarray(pos_onehot, np.float32)
-            feed["gen_attn_mask"] = np.asarray(attn_mask, np.float32)
+        feed.update(self._paged_decode_feed(
+            np.asarray(lens, np.int32).reshape(S, 1)))
+        live = int(np.count_nonzero(feed["gen_lens"]))
         feed = {k: feed[k] for k in self._dec_feeds}
         with self._lock:
             chaos.fire("gen.decode.stall", slots=S)
@@ -676,7 +641,7 @@ class GenPredictor:
         return out
 
     def _paged_decode_feed(self, lens):
-        """Page-table + lens feed for one paged step: slice the table
+        """Page-table + lens feed for one step: slice the table
         to the smallest declared page bucket covering the longest live
         prefix (clamped to ``pages_per_slot`` — ``row_bucket`` past the
         declared ladder falls back to its power-of-two ladder, which
@@ -704,9 +669,8 @@ class GenPredictor:
     # -- warmup ------------------------------------------------------------
     def warmup(self):
         """AOT-compile EVERY signature an admission or a decode step
-        uses — one prefill signature per declared prompt bucket, the
-        decode signature family (ONE signature for dense bundles; one
-        per declared page bucket for paged bundles), one seeding
+        uses — one prefill signature per declared prompt bucket, one
+        decode signature per declared page bucket, one seeding
         signature per prompt bucket (:func:`_seed_pool`) and the token
         pick (:func:`pick_tokens`) — so the first real ``/generate``
         pays zero compile time.  Returns a
@@ -720,17 +684,10 @@ class GenPredictor:
             "gen_ids": (1, b), "gen_pos": (1, b), "gen_mask": (1, b),
             "gen_attn_bias": (1, 1, b, b), "gen_last": (1, b)}.items()
             if k in self._pre_feeds} for b in buckets]
-        S, L = self.num_slots, self.max_len
-        if self.paged:
-            dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
-                         "gen_page_table": (S, int(P)),
-                         "gen_lens": (S, 1)}
-                        for P in self.page_buckets
-                        if P <= self.pages_per_slot]
-        else:
-            dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
-                         "gen_pos_onehot": (S, L),
-                         "gen_attn_mask": (S, L)}]
+        S = self.num_slots
+        dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
+                     "gen_page_table": (S, int(P)), "gen_lens": (S, 1)}
+                    for P in self.page_buckets if P <= self.pages_per_slot]
         dec_sigs = [{k: sig[k] for k in self._dec_feeds} for sig in dec_sigs]
         from paddle_tpu.obs.perf import WarmupReport
         with self._lock:
@@ -740,8 +697,8 @@ class GenPredictor:
                     scope=self._scope)
                 # the decode step writes its (persistable) cache tensors
                 # in place — declare exactly those as intended state
-                # updates (a zero pos-onehot / zero lens feed writes
-                # nothing, so warmup leaves the pool untouched)
+                # updates (a zero lens feed writes nothing, so warmup
+                # leaves the pool untouched)
                 dec = self._exe.warmup(
                     self._dec_prog, dec_sigs,
                     fetch_list=self._dec_fetch, scope=self._scope,
@@ -780,7 +737,7 @@ class GenPredictor:
         count of 0 (the pools pass through untouched); caller holds
         ``_lock``."""
         from paddle_tpu.obs.perf import WarmupReport
-        idx = np.zeros(self.pages_per_slot if self.paged else 1, np.int32)
+        idx = np.zeros(self.pages_per_slot, np.int32)
         entries = []
         for b in buckets:
             kv = self._zero_kv(b)

@@ -194,8 +194,7 @@ class GenScheduler:
         # batch generation, not one decode iteration.
         self.prefill_budget = None if prefill_budget is None \
             or admission != "continuous" else float(prefill_budget)
-        if self.prefill_budget is not None and \
-                hasattr(predictor, "prefill_cost"):
+        if self.prefill_budget is not None:
             # warm the cost model's affine fit HERE (it walks the
             # prefill program twice) so no _admit pass pays it while
             # holding the scheduler lock
@@ -390,8 +389,7 @@ class GenScheduler:
         # the wholesale slot reset above must also reset the page
         # pool, or a crash strands every live allocation and the
         # restarted loop livelocks on page-aware admission
-        if getattr(self.predictor, "paged", False):
-            self.predictor.free_all_pages()
+        self.predictor.free_all_pages()
         if restart:
             _profiler.runtime_metrics.inc("gen.scheduler_restarts")
             self._thread = self._spawn_thread()
@@ -444,8 +442,7 @@ class GenScheduler:
         # shutdown discards the slots wholesale; return their pages so
         # a later scheduler over the SAME predictor starts with a full
         # pool (the test suite reuses warmed predictors this way)
-        if getattr(self.predictor, "paged", False):
-            self.predictor.free_all_pages()
+        self.predictor.free_all_pages()
         err = RuntimeError("generation scheduler shut down")
         for _, slot in active:
             slot.stream.fail(err)
@@ -464,8 +461,7 @@ class GenScheduler:
             queued, self._queue = self._queue, []
             active, self._slots = list(self._slots.values()), {}
             self._free = list(range(self.predictor.num_slots))
-        if getattr(self.predictor, "paged", False):
-            self.predictor.free_all_pages()
+        self.predictor.free_all_pages()
         for slot in active:
             slot.stream.fail(exc)
         for stream in queued:
@@ -490,8 +486,7 @@ class GenScheduler:
                 self._checkpoint_out(slot.stream)
             else:
                 slot.stream.finish("disconnect")
-            if getattr(self.predictor, "paged", False):
-                self.predictor.free_slot_pages(idx)
+            self.predictor.free_slot_pages(idx)
             with self._cv:
                 self._slots.pop(idx, None)
                 self._free.append(idx)
@@ -559,19 +554,17 @@ class GenScheduler:
                         refill = not self._slots
                     if not refill:
                         return
-                if getattr(self.predictor, "paged", False):
-                    # page-aware admission: a request is only admitted
-                    # when the pool can cover its WHOLE length horizon
-                    # (allocation happens once, at admission), so decode
-                    # growth never fails mid-request; otherwise the
-                    # head-of-line request waits for an eviction to
-                    # return pages — backpressure, like the FLOPs
-                    # budget below, not an error
-                    head = self._queue[0]
-                    need = self.predictor.pages_needed(
-                        len(head.prompt), head.max_new_tokens)
-                    if need > self.predictor.free_pages:
-                        return
+                # page-aware admission: a request is only admitted when
+                # the pool can cover its WHOLE length horizon (allocation
+                # happens once, at admission), so decode growth never
+                # fails mid-request; otherwise the head-of-line request
+                # waits for an eviction to return pages — backpressure,
+                # like the FLOPs budget below, not an error
+                head = self._queue[0]
+                need = self.predictor.pages_needed(
+                    len(head.prompt), head.max_new_tokens)
+                if need > self.predictor.free_pages:
+                    return
                 if self.prefill_budget is not None and admitted_n:
                     # cost-weighted admission: stop once this pass has
                     # admitted its budget of static prefill FLOPs (the
@@ -641,27 +634,26 @@ class GenScheduler:
         if stream.max_new_tokens <= 1 or prompt_len >= self.predictor.max_len:
             return self._finish(stream, "length")
         with _span("gen.seed_slot") as seed:
-            if getattr(self.predictor, "paged", False):
-                try:
-                    pages = self.predictor.alloc_slot_pages(
-                        slot_idx, self.predictor.pages_needed(
-                            prompt_len, stream.max_new_tokens))
-                except BaseException as e:
-                    stream.fail(e)
-                    return False
-                seed.set(pages=len(pages))
-                try:
-                    written = self.predictor.write_slot(slot_idx, kv,
-                                                        prompt_len)
-                except BaseException:
-                    self.predictor.free_slot_pages(slot_idx)
-                    raise
-            else:
+            try:
+                pages = self.predictor.alloc_slot_pages(
+                    slot_idx, self.predictor.pages_needed(
+                        prompt_len, stream.max_new_tokens))
+            except BaseException as e:
+                stream.fail(e)
+                return False
+            seed.set(pages=len(pages))
+            try:
                 written = self.predictor.write_slot(slot_idx, kv,
                                                     prompt_len)
+            except BaseException as e:
+                # a fault in the seed is the scheduler's crash (_run); the
+                # stream, out of the queue and in no slot yet, is nobody
+                # else's to fail
+                self.predictor.free_slot_pages(slot_idx)
+                stream.fail(e)
+                raise
             seed.set(compiled_calls=1, eager_ops=written,
-                     state_arrays=len(getattr(self.predictor,
-                                              "state_vars", ())))
+                     state_arrays=len(self.predictor.state_vars))
         with self._cv:
             self._slots[slot_idx] = _Slot(stream, prompt_len, first)
         return True
@@ -691,13 +683,12 @@ class GenScheduler:
             # LOCAL consumer that cancelled must not block forever on a
             # stream nobody will ever finish
             slot.stream.finish("disconnect")
-        # paged bundles: EVERY eviction (eos / length / disconnect)
-        # returns the slot's pages to the pool — the admission
-        # backpressure above turns a leak here into a livelock; for
-        # disconnects this runs AFTER clear_slot, which addresses
-        # pages through the still-live allocation
-        if getattr(self.predictor, "paged", False):
-            self.predictor.free_slot_pages(slot_idx)
+        # EVERY eviction (eos / length / disconnect) returns the slot's
+        # pages to the pool — the admission backpressure above turns a
+        # leak here into a livelock; for disconnects this runs AFTER
+        # clear_slot, which addresses pages through the still-live
+        # allocation
+        self.predictor.free_slot_pages(slot_idx)
         with self._cv:
             self._slots.pop(slot_idx, None)
             self._free.append(slot_idx)
@@ -731,12 +722,7 @@ class GenScheduler:
         # -1: the slot's token is the device's own pick from ``prev``
         override = np.full(S, -1, np.int32)
         positions = np.zeros(S, np.int32)
-        paged = getattr(self.predictor, "paged", False)
-        if paged:
-            lens = np.zeros(S, np.int32)
-        else:
-            pos_onehot = np.zeros((S, L), np.float32)
-            attn_mask = np.zeros((S, L), np.float32)
+        lens = np.zeros(S, np.int32)
         rows = []
         for idx, slot in live:
             cap = slot.stream.max_new_tokens
@@ -745,11 +731,7 @@ class GenScheduler:
             if carried.get(idx) is not slot:
                 override[idx] = slot.last_token
             positions[idx] = slot.pos
-            if paged:
-                lens[idx] = slot.pos + 1
-            else:
-                pos_onehot[idx, slot.pos] = 1.0
-                attn_mask[idx, :slot.pos + 1] = 1.0
+            lens[idx] = slot.pos + 1
             slot.steps += 1
             slot.pos += 1
             rows.append((idx, slot, 1 + slot.steps >= cap or slot.pos >= L))
@@ -765,12 +747,10 @@ class GenScheduler:
                 metrics.inc("gen.decode.steps")
                 if prev:
                     metrics.inc("gen.decode.steps_ahead")
-                feeds = {"lens": lens} if paged else \
-                    {"pos_onehot": pos_onehot, "attn_mask": attn_mask}
                 logits = self.predictor.decode_step(
-                    tokens, positions, on_device=True, **feeds)
-                self._in_flight = _Step(rows, logits, getattr(
-                    self.predictor, "last_decode_stats", None))
+                    tokens, positions, lens=lens, on_device=True)
+                self._in_flight = _Step(rows, logits,
+                                        self.predictor.last_decode_stats)
             if prev:
                 with _span("gen.collect"):
                     ids = np.asarray(tokens).reshape(-1).tolist()

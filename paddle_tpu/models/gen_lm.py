@@ -10,23 +10,15 @@ programs, the vLLM/Orca-style entry pair:
   zero on pad rows) that seed the request's KV-cache slot.  The length
   axis is dynamic; callers pad to a ``lod.row_bucket`` edge so the jit
   key is the bucket, not the exact prompt length.
-* **decode** — ONE token for every slot of a fixed cache pool
-  ``[num_slots, max_len]``: reads the persistable cache tensors, writes
-  the new token's K/V at its position via a position-one-hot outer
-  product (an in-place persistable update, so the cache never leaves
-  the device), and attends over the full cache under a runtime length
-  mask.  Every decode step has the SAME signature — admission and
-  eviction never recompile.
-
-The default export uses the PAGED decode variant
-(:func:`build_paged_decode_program`): the cache pool lives as
-``[num_pages, page_len, H*D]`` fixed-size pages plus a per-slot page
-table, and each step attends only the pages covering ``[0, len)`` per
-slot — decode reads scale with live prefix length instead of the padded
-``max_len`` (ROADMAP item 3).  The page-table feed's width is bucketed
-(``page_buckets``) so the jit key stays constant per bucket; the dense
-variant remains exportable with ``paged=False`` (the equivalence
-baseline and bench comparison point).
+* **decode** (:func:`build_paged_decode_program`) — ONE token for every
+  slot of a fixed pool: the persistable cache lives as
+  ``[num_pages, page_len, H*D]`` fixed-size pages plus a per-slot page
+  table; a step scatters the new token's K/V row into its slot's tail
+  page (an in-place persistable update, so the cache never leaves the
+  device) and attends only the pages covering ``[0, len)`` per slot —
+  decode reads scale with live prefix length, not ``max_len``.  The
+  page-table feed's width is bucketed (``page_buckets``), so the jit
+  key is the bucket — admission and eviction never recompile.
 
 The third entry, :func:`gen_lm_train_program`, is the teacher-forced
 training graph over the same parameter names (and the model-zoo lint
@@ -44,14 +36,14 @@ import paddle_tpu.layers as layers
 from paddle_tpu.initializer import NumpyArrayInitializer
 from paddle_tpu.param_attr import ParamAttr
 
-__all__ = ["GenConfig", "build_prefill_program", "build_decode_program",
+__all__ = ["GenConfig", "build_prefill_program",
            "build_paged_decode_program", "gen_lm_train_program",
            "export_gen_model", "META_FILENAME", "PAGE_LEN_DEFAULT",
            "paged_cache_var_names", "default_page_buckets"]
 
 META_FILENAME = "gen_meta.json"
 
-#: default KV page length (rows per page) for paged exports
+#: default KV page length (rows per page)
 PAGE_LEN_DEFAULT = 16
 
 
@@ -149,19 +141,9 @@ def _block_tail(x, attn, hp, idx):
     return _ln(x + _ffn(x, hp, idx), idx, "ln2")
 
 
-def cache_var_names(hp):
-    """The decode program's persistable KV-cache tensor names, in the
-    (k, v) per-layer order the prefill fetch list follows."""
-    names = []
-    for i in range(hp.n_layer):
-        names.append(f"genlm_cache_k_{i}")
-        names.append(f"genlm_cache_v_{i}")
-    return names
-
-
 def paged_cache_var_names(hp):
-    """The PAGED decode program's persistable page-pool tensor names,
-    in the same (k, v) per-layer order as :func:`cache_var_names`."""
+    """The decode program's persistable page-pool tensor names, in the
+    (k, v) per-layer order the prefill fetch list follows."""
     names = []
     for i in range(hp.n_layer):
         names.append(f"genlm_paged_k_{i}")
@@ -228,80 +210,12 @@ def build_prefill_program(hp):
 
 
 # ---------------------------------------------------------------------------
-# decode: one token for every cache slot, constant signature
-# ---------------------------------------------------------------------------
-
-def build_decode_program(hp, num_slots):
-    """Build the single-token decode step in the CURRENT program guard.
-
-    Feeds (ALL with static shapes — one jit signature forever):
-      ``gen_token`` [S, 1] int32 (last emitted token per slot),
-      ``gen_pos`` [S, 1] int32 (its position),
-      ``gen_pos_onehot`` [S, L] f32 (1 at the write position for live
-      slots, all-zero rows for free slots — the no-write mask),
-      ``gen_attn_mask`` [S, L] f32 (1 = attendable cache position,
-      INCLUDING the current token's own).
-    Persistable state: per-layer ``genlm_cache_k_i`` / ``genlm_cache_v_i``
-    [S, L, H*D], updated in place (the executor's donated inout path).
-    Fetches: ``logits`` [S, V].
-    """
-    import paddle_tpu as fluid
-
-    S, L = int(num_slots), int(hp.max_len)
-    hd = hp.n_head * hp.d_head
-
-    def data(name, shape, dtype="float32"):
-        return layers.data(name=name, shape=shape, dtype=dtype,
-                           append_batch_size=False)
-
-    token = data("gen_token", [S, 1], "int32")
-    pos = data("gen_pos", [S, 1], "int32")
-    pos_onehot = data("gen_pos_onehot", [S, L])
-    attn_mask = data("gen_attn_mask", [S, L])
-
-    block = fluid.default_main_program().global_block()
-    caches = {}
-    for name in cache_var_names(hp):
-        c = block.create_var(name=name, shape=[S, L, hd], dtype="float32")
-        c.persistable = True
-        c.stop_gradient = True
-        caches[name] = c
-
-    x = _embed(token, pos, hp)                         # [S, d]
-    x = layers.reshape(x, shape=[S, 1, hp.d_model])
-    po3 = layers.reshape(pos_onehot, shape=[S, L, 1])
-    bias = layers.reshape(layers.scale(attn_mask, scale=1e9, bias=-1e9),
-                          shape=[S, 1, 1, L])
-    for i in range(hp.n_layer):
-        q, k, v = _qkv(x, hp, i)                       # [S, 1, H*D]
-        ck, cv = caches[f"genlm_cache_k_{i}"], caches[f"genlm_cache_v_{i}"]
-        # scatter the new token's K/V into its cache position: an outer
-        # product against the position one-hot, added IN PLACE (free
-        # slots feed an all-zero one-hot row, so nothing is written)
-        for cache, new in ((ck, k), (cv, v)):
-            delta = layers.matmul(po3, new)            # [S, L, H*D]
-            block.append_op(type="elementwise_add",
-                            inputs={"X": [cache.name], "Y": [delta.name]},
-                            outputs={"Out": [cache.name]},
-                            attrs={"axis": -1})
-        # attention over the UPDATED cache (reads after the in-place
-        # write observe the current token's own K/V)
-        attn = _attend(q, ck, cv, bias, hp, i, q_len=1, k_len=L)
-        x = _block_tail(x, attn, hp, i)
-    x2 = layers.reshape(x, shape=[S, hp.d_model])
-    logits = layers.fc(x2, hp.vocab_size, bias_attr=False,
-                       param_attr=_pa("genlm_logits.w"))
-    feeds = ["gen_token", "gen_pos", "gen_pos_onehot", "gen_attn_mask"]
-    return feeds, [logits]
-
-
-# ---------------------------------------------------------------------------
-# paged decode: page-pool cache, page-table feed bucketed by page count
+# decode: one token for every slot; page-pool cache, page-table feed
+# bucketed by page count
 # ---------------------------------------------------------------------------
 
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
-    """Build the PAGED single-token decode step in the CURRENT program
-    guard.
+    """Build the single-token decode step in the CURRENT program guard.
 
     Feeds (static except the bucketed page-table width):
       ``gen_token`` [S, 1] int32, ``gen_pos`` [S, 1] int32,
@@ -431,25 +345,29 @@ def export_gen_model(dirname, hp: GenConfig = None, num_slots=8,
     shared parameter set) and ``<dirname>/gen_meta.json`` describing the
     cache pool geometry.  Returns ``dirname``.
 
-    ``paged=True`` (the default) exports the page-pool decode variant:
-    ``page_len`` rows per page (clamped to ``max_len``), ``num_pages``
-    pool pages (default ``num_slots * ceil(max_len / page_len)`` — every
-    slot can always grow to ``max_len``), ``page_buckets`` the declared
-    page-count jit-signature ladder.  ``paged=False`` keeps the dense
-    ``[num_slots, max_len]`` layout (the equivalence baseline)."""
+    The KV cache is a page pool: ``page_len`` rows per page (clamped to
+    ``max_len``), ``num_pages`` pool pages (default
+    ``num_slots * ceil(max_len / page_len)`` — every slot can always
+    grow to ``max_len``), ``page_buckets`` the declared page-count
+    jit-signature ladder.  ``paged`` is accepted for callers written
+    when a dense ``[num_slots, max_len]`` layout could be exported too;
+    that layout was removed and anything but ``True`` raises."""
     import paddle_tpu as fluid
     from paddle_tpu.lod import bucket_edges
 
+    if paged is not True:
+        raise ValueError(
+            f"export_gen_model(paged={paged!r}): the dense KV layout was "
+            f"removed; every bundle is the page-pool one (drop the keyword)")
     hp = hp or GenConfig()
     num_slots = int(num_slots)
     if prompt_buckets is None:
         prompt_buckets = bucket_edges(1, hp.max_len)
-    if paged:
-        page_len = max(1, min(int(page_len), int(hp.max_len)))
-        pps = -(-int(hp.max_len) // page_len)
-        num_pages = num_slots * pps if num_pages is None else int(num_pages)
-        if page_buckets is None:
-            page_buckets = default_page_buckets(pps)
+    page_len = max(1, min(int(page_len), int(hp.max_len)))
+    pps = -(-int(hp.max_len) // page_len)
+    num_pages = num_slots * pps if num_pages is None else int(num_pages)
+    if page_buckets is None:
+        page_buckets = default_page_buckets(pps)
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe = fluid.Executor()
@@ -462,23 +380,14 @@ def export_gen_model(dirname, hp: GenConfig = None, num_slots=8,
 
         dec_main, dec_startup = fluid.Program(), fluid.Program()
         with fluid.program_guard(dec_main, dec_startup):
-            if paged:
-                dec_feeds, dec_fetches = build_paged_decode_program(
-                    hp, num_slots, page_len, num_pages)
-            else:
-                dec_feeds, dec_fetches = build_decode_program(hp,
-                                                              num_slots)
+            dec_feeds, dec_fetches = build_paged_decode_program(
+                hp, num_slots, page_len, num_pages)
         # decode shares the ALREADY-initialized parameters (its startup
         # is never run); the cache pool starts as zeros
         hd = hp.n_head * hp.d_head
-        if paged:
-            for name in paged_cache_var_names(hp):
-                scope.set_var(name, np.zeros((num_pages, page_len, hd),
-                                             dtype="float32"))
-        else:
-            for name in cache_var_names(hp):
-                scope.set_var(name, np.zeros((num_slots, hp.max_len, hd),
-                                             dtype="float32"))
+        for name in paged_cache_var_names(hp):
+            scope.set_var(name, np.zeros((num_pages, page_len, hd),
+                                         dtype="float32"))
         _write_model(os.path.join(dirname, "decode"), dec_main,
                      dec_feeds, dec_fetches, exe)
 
@@ -489,17 +398,13 @@ def export_gen_model(dirname, hp: GenConfig = None, num_slots=8,
         "vocab_size": int(hp.vocab_size),
         "n_layer": int(hp.n_layer),
         "eos_id": int(hp.eos_id),
-        "cache_vars": (paged_cache_var_names(hp) if paged
-                       else cache_var_names(hp)),
+        "cache_vars": paged_cache_var_names(hp),
         "prompt_buckets": [int(b) for b in prompt_buckets],
+        "page_len": int(page_len),
+        "num_pages": int(num_pages),
+        "page_buckets": [int(b) for b in page_buckets],
+        "page_table_feed": "gen_page_table",
     }
-    if paged:
-        meta.update({
-            "page_len": int(page_len),
-            "num_pages": int(num_pages),
-            "page_buckets": [int(b) for b in page_buckets],
-            "page_table_feed": "gen_page_table",
-        })
     with open(os.path.join(dirname, META_FILENAME), "w") as f:
         json.dump(meta, f, indent=2)
     # post-export contract (analysis/distributed.py): the bundle's

@@ -1,8 +1,7 @@
 """Long-sequence flagship config of the generative LM (ROADMAP item 3):
 the :mod:`gen_lm` architecture with ``max_len`` 256 — 4x the base
-``GenConfig`` — the context length the PAGED KV layout exists for.  At
-256 the dense decode pool reads ``num_slots * 256`` K/V rows per step
-regardless of occupancy; the paged export reads only the live pages
+``GenConfig`` — the context length the paged KV layout exists for: a
+decode step reads each slot's live pages, not ``max_len`` rows
 (``docs/performance.md`` "Paged KV attention" has the occupancy math).
 
 Registered in ``ZOO_MODELS`` so the lint gate, distribute/pipeline

@@ -404,10 +404,10 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
 def export_hybrid_model(dirname, hp: HybridConfig = None, num_slots=8,
                         prompt_buckets=None, page_len=PAGE_LEN_DEFAULT,
                         num_pages=None, page_buckets=None):
-    """Export a generation bundle in ``gen_lm.export_gen_model``'s layout
-    (paged; this model has no dense-cache variant).  ``gen_meta.json``
-    names, beside the paged ``cache_vars``, the per-slot ``state_vars``
-    and the decode step's ``decode_stats``.  Returns ``dirname``."""
+    """Export a generation bundle in ``gen_lm.export_gen_model``'s
+    layout.  ``gen_meta.json`` names, beside the paged ``cache_vars``,
+    the per-slot ``state_vars`` and the decode step's ``decode_stats``.
+    Returns ``dirname``."""
     import paddle_tpu as fluid
     from paddle_tpu.lod import bucket_edges
 

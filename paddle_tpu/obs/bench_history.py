@@ -59,9 +59,6 @@ BENCH_METRICS = {
                "tokens_per_sec_ratio": ("higher", 0.25),
                "ttft_p99_ms": ("lower", 0.75),
                "lost_requests": ("max_abs", 0.0)},
-    "paged": {"speedup": ("higher", 0.30),
-              "bytes_ratio": ("lower", 0.10),
-              "paged_step_ms": ("lower", 0.75)},
     "elastic": {"resume_seconds": ("lower", 1.00),
                 "loss_delta_rel": ("max_abs", 1e-3),
                 "reshard_failures": ("max_abs", 0.0)},
@@ -297,10 +294,6 @@ def summary_metrics(bench, summary):
                 "tokens_per_sec_ratio": summary["tokens_per_sec_ratio"],
                 "ttft_p99_ms": summary["ttft_p99_ms"]["continuous"],
                 "lost_requests": cont["failures"]}
-    if bench == "paged":
-        return {"speedup": summary["speedup"],
-                "bytes_ratio": summary["bytes_ratio"],
-                "paged_step_ms": summary["paged"]["decode_step_ms"]}
     if bench == "compile":
         return {"reduction_best": summary["reduction_best"],
                 "reduction_second_best":
@@ -345,9 +338,9 @@ def summary_metrics(bench, summary):
                 out[opt] = summary[opt]
         return out
     raise ValueError(f"no trajectory extraction for bench {bench!r} "
-                     f"(known: serving, datapipe, fleet, decode, paged, "
-                     f"elastic, embedding, compile, train_transformer, "
-                     f"autoscale, gen_failover)")
+                     f"(known: serving, datapipe, fleet, decode, elastic, "
+                     f"embedding, compile, train_transformer, autoscale, "
+                     f"gen_failover)")
 
 
 def add_record_args(parser):

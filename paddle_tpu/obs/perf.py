@@ -441,8 +441,8 @@ OPTIMIZER_STATE_PREFIXES = (
 #: a paged gen bundle's page pool + its host-side page tables;
 #: ``gen_state``: a gen bundle's per-slot state that is not pages, e.g.
 #: the recurrent state and conv window of a state-space layer)
-HBM_COLLECTIONS = ("kv_cache", "kv_pages", "gen_state", "prefetch",
-                   "embedding", "optimizer", "params")
+HBM_COLLECTIONS = ("kv_pages", "gen_state", "prefetch", "embedding",
+                   "optimizer", "params")
 
 _hbm_lock = threading.Lock()
 _hbm_providers = {}     # collection -> {token: callable}
@@ -452,7 +452,7 @@ _hbm_high_watermark = [0.0]
 
 def register_hbm_provider(collection, fn):
     """Register ``fn`` (no args -> iterable of device arrays) as a
-    source of buffers for ``collection`` (``kv_cache`` / ``prefetch`` /
+    source of buffers for ``collection`` (``kv_pages`` / ``prefetch`` /
     custom).  Returns a token for :func:`unregister_hbm_provider`.
     Providers that raise are skipped, never fatal — the census is a
     diagnostic, not a dependency."""
@@ -532,7 +532,7 @@ def hbm_census(scope=None, metrics=None):
     ``hbm.*`` gauges.  ``scope`` defaults to the ambient global scope;
     its device arrays split into ``params`` vs ``optimizer`` by the
     accumulator naming convention, provider-backed collections
-    (``kv_cache``, ``prefetch``) claim their buffers first, and
+    (``kv_pages``, ``prefetch``) claim their buffers first, and
     everything unattributed lands in ``other``.  Returns the census
     dict.  Cost is O(live arrays) — run it on the
     ``PADDLE_TPU_HBM_CENSUS`` cadence or from ``profile memory``, not
@@ -553,7 +553,6 @@ def hbm_census(scope=None, metrics=None):
             counted.add(i)
             census[collection] += int(nbytes)
 
-    claim("kv_cache", _provider_arrays("kv_cache"))
     claim("kv_pages", _provider_arrays("kv_pages"))
     claim("gen_state", _provider_arrays("gen_state"))
     claim("prefetch", _provider_arrays("prefetch"))
@@ -599,7 +598,6 @@ def hbm_census(scope=None, metrics=None):
     m.inc("hbm.census_runs")
     m.set_gauge("hbm.params_bytes", census["params"])
     m.set_gauge("hbm.optimizer_bytes", census["optimizer"])
-    m.set_gauge("hbm.kv_cache_bytes", census["kv_cache"])
     m.set_gauge("hbm.kv_pages_bytes", census["kv_pages"])
     m.set_gauge("hbm.gen_state_bytes", census["gen_state"])
     m.set_gauge("hbm.prefetch_bytes", census["prefetch"])

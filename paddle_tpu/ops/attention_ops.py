@@ -966,9 +966,9 @@ def _paged_cache_update(kc, vc, k, v, page_table, lens):
 
 def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
     """Gather-based fallback: same contract as the kernel.  Reads only
-    the ``P`` table-listed pages per slot ([S, P*PL] keys instead of the
-    dense pool's [S, max_len]) — still occupancy-proportional, just
-    without the VMEM-resident online softmax."""
+    the ``P`` table-listed pages per slot ([S, P*PL] keys, not
+    [S, max_len]) — still occupancy-proportional, just without the
+    VMEM-resident online softmax."""
     S, P = page_table.shape
     NP, PL, HDkv = kc.shape
     H = n_head

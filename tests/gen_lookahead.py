@@ -1,7 +1,8 @@
 """Drills of the scheduler's one-step lookahead (``gen/scheduler.py``: the
 next decode step is dispatched before the last one's tokens are read),
-shared by ``test_gen.py`` (the paged and the dense ``gen_lm`` bundle) and
-``test_hybrid_moe.py`` (a bundle with ``state_vars``).
+shared by ``test_gen.py`` (the ``gen_lm`` bundle, on a roomy and on a
+tight page pool) and ``test_hybrid_moe.py`` (a bundle with
+``state_vars``).
 
 Each drill takes a warmed predictor with 4 slots and ``max_len`` 64 and
 ``ref(prompt, n)``, the cache-free greedy reference, and holds every
@@ -72,7 +73,7 @@ def rest(stream, timeout=60.0):
 
 
 def pool_is_whole(predictor):
-    return not predictor.paged or predictor.free_pages == predictor.num_pages
+    return predictor.free_pages == predictor.num_pages
 
 
 def eos_beside_live_neighbours(predictor, ref):

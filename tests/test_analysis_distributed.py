@@ -134,8 +134,12 @@ def _case_pta017_implicit_full_reshard():
         mesh_axes={"data": 2, "model": 2}))
 
 
-def _gen_family(num_slots=2, max_len=8, buckets=(8,), meta_slots=None):
-    """Hand-built prefill/decode pair + meta (no executor needed)."""
+def _paged_family(num_slots=2, max_len=16, page_len=4, num_pages=8,
+                  page_buckets=(1, 2, 4), feed_pt=True, pt_rows=None,
+                  cache_shape=None, prompt_buckets=(8,)):
+    """Hand-built prefill/decode pair + meta (no executor needed):
+    pools are ``[num_pages, page_len, hd]`` and decode feeds a
+    dynamic-width page table (the one sanctioned dynamic decode dim)."""
     pre, pb = _prog()
     pb.create_var(name="ids", shape=(1, -1), dtype="int32", is_data=True)
     pb.create_var(name="logits", shape=(1, 16), dtype="float32")
@@ -144,31 +148,40 @@ def _gen_family(num_slots=2, max_len=8, buckets=(8,), meta_slots=None):
     dec, db = _prog()
     db.create_var(name="tok", shape=(num_slots, 1), dtype="int32",
                   is_data=True)
+    feeds = ["tok"]
+    if feed_pt:
+        db.create_var(name="gen_page_table",
+                      shape=(pt_rows or num_slots, -1),
+                      dtype="int32", is_data=True)
+        feeds.append("gen_page_table")
     for name in ("cache_k_0", "cache_v_0"):
-        c = db.create_var(name=name, shape=(num_slots, max_len, 4),
+        c = db.create_var(name=name,
+                          shape=cache_shape or (num_pages, page_len, 4),
                           dtype="float32")
         c.persistable = True
     db.create_var(name="logits", shape=(num_slots, 16), dtype="float32")
-    meta = {"num_slots": meta_slots if meta_slots is not None
-            else num_slots,
-            "max_len": max_len,
+    meta = {"num_slots": num_slots, "max_len": max_len,
             "cache_vars": ["cache_k_0", "cache_v_0"],
-            "prompt_buckets": list(buckets)}
+            "prompt_buckets": list(prompt_buckets),
+            "page_len": page_len, "num_pages": num_pages,
+            "page_buckets": list(page_buckets),
+            "page_table_feed": "gen_page_table"}
     return ((pre, ["ids"], ["logits", "k0", "v0"]),
-            (dec, ["tok"], ["logits"]), meta)
+            (dec, feeds, ["logits"]), meta)
 
 
 def _case_pta018_bucket_escape():
     # the largest declared prompt bucket exceeds the cache length: it
     # is declared but never warmed -> compiles at request time
-    prefill, decode, meta = _gen_family(buckets=(8, 128))
+    prefill, decode, meta = _paged_family(prompt_buckets=(8, 128))
     return analysis.AnalysisResult(
         D.check_gen_bundle(prefill, decode, meta))
 
 
 def _case_pta019_signature_drift():
-    # meta claims 4 slots, the decode cache holds 2
-    prefill, decode, meta = _gen_family(num_slots=2, meta_slots=4)
+    # meta claims 8 pages, the decode pool holds 4
+    prefill, decode, meta = _paged_family(num_pages=8,
+                                          cache_shape=(4, 4, 4))
     return analysis.AnalysisResult(
         D.check_gen_bundle(prefill, decode, meta))
 
@@ -198,42 +211,6 @@ def test_negative_case_triggers_code(code):
     hit = next(d for d in result.diagnostics if d.code == code)
     # actionable: the diagnostic names a concrete var/op/member
     assert hit.var or hit.op_type or hit.program, hit.format()
-
-
-def _paged_family(num_slots=2, max_len=16, page_len=4, num_pages=8,
-                  page_buckets=(1, 2, 4), feed_pt=True, pt_rows=None,
-                  cache_shape=None):
-    """Hand-built PAGED prefill/decode pair + meta: pools are
-    ``[num_pages, page_len, hd]`` and decode feeds a dynamic-width
-    page table (the one sanctioned dynamic decode dim)."""
-    pre, pb = _prog()
-    pb.create_var(name="ids", shape=(1, -1), dtype="int32", is_data=True)
-    pb.create_var(name="logits", shape=(1, 16), dtype="float32")
-    pb.create_var(name="k0", shape=(1, -1, 4), dtype="float32")
-    pb.create_var(name="v0", shape=(1, -1, 4), dtype="float32")
-    dec, db = _prog()
-    db.create_var(name="tok", shape=(num_slots, 1), dtype="int32",
-                  is_data=True)
-    feeds = ["tok"]
-    if feed_pt:
-        db.create_var(name="gen_page_table",
-                      shape=(pt_rows or num_slots, -1),
-                      dtype="int32", is_data=True)
-        feeds.append("gen_page_table")
-    for name in ("cache_k_0", "cache_v_0"):
-        c = db.create_var(name=name,
-                          shape=cache_shape or (num_pages, page_len, 4),
-                          dtype="float32")
-        c.persistable = True
-    db.create_var(name="logits", shape=(num_slots, 16), dtype="float32")
-    meta = {"num_slots": num_slots, "max_len": max_len,
-            "cache_vars": ["cache_k_0", "cache_v_0"],
-            "prompt_buckets": [8],
-            "page_len": page_len, "num_pages": num_pages,
-            "page_buckets": list(page_buckets),
-            "page_table_feed": "gen_page_table"}
-    return ((pre, ["ids"], ["logits", "k0", "v0"]),
-            (dec, feeds, ["logits"]), meta)
 
 
 class TestPagedBundleDiagnostics:
@@ -555,6 +532,25 @@ def test_zoo_pipeline_split_verifies_clean(name):
 # multi-program CLI modes
 # ---------------------------------------------------------------------------
 
+def _bundle_with_edited_meta(tmp_path, edit):
+    """A toy ``gen_lm`` bundle exported clean, then its ``gen_meta.json``
+    rewritten by ``edit(meta)`` in place: ``(bundle dir, edited meta)``."""
+    from paddle_tpu.models import gen_lm
+    hp = gen_lm.GenConfig()
+    hp.vocab_size, hp.d_model, hp.d_ffn = 32, 16, 32
+    hp.n_head = hp.n_layer = 2
+    hp.d_head, hp.max_len = 8, 16
+    bundle = str(tmp_path / "bundle")
+    gen_lm.export_gen_model(bundle, hp, num_slots=2)
+    meta_path = os.path.join(bundle, "gen_meta.json")
+    with open(meta_path) as f:
+        meta = json.load(f)
+    edit(meta)
+    with open(meta_path, "w") as f:
+        json.dump(meta, f)
+    return bundle, meta
+
+
 class TestMultiProgramCli:
     def _write_model(self, path, program, feeds, fetches):
         os.makedirs(path, exist_ok=True)
@@ -585,19 +581,37 @@ class TestMultiProgramCli:
         stable drift code (the clean-bundle path joins the zoo gate in
         test_analysis_zoo.py)."""
         from paddle_tpu.cli import main
-        from paddle_tpu.models import gen_lm
-        hp = gen_lm.GenConfig()
-        hp.vocab_size, hp.d_model, hp.d_ffn = 32, 16, 32
-        hp.n_head = hp.n_layer = 2
-        hp.d_head, hp.max_len = 8, 16
-        bundle = str(tmp_path / "bundle")
-        gen_lm.export_gen_model(bundle, hp, num_slots=2)
-        meta_path = os.path.join(bundle, "gen_meta.json")
-        with open(meta_path) as f:
-            meta = json.load(f)
-        meta["num_slots"] = 5
-        with open(meta_path, "w") as f:
-            json.dump(meta, f)
+        bundle, _ = _bundle_with_edited_meta(
+            tmp_path, lambda meta: meta.update(num_slots=5))
+        assert main(["lint", bundle]) == 1
+        assert "PTA019" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("how", ["check", "load", "lint"])
+def test_a_bundle_without_pages_is_refused(how, tmp_path, capsys):
+    """A ``gen_meta.json`` without ``page_len`` is a bundle of the dense
+    KV layout, which was removed: PTA019 from the check, at
+    ``GenPredictor(...)`` and under ``paddle_tpu lint``, saying to
+    re-export, before anything seeds a pool."""
+    def strip_pages(meta):
+        for key in ("page_len", "num_pages", "page_buckets",
+                    "page_table_feed"):
+            del meta[key]
+
+    bundle, meta = _bundle_with_edited_meta(tmp_path, strip_pages)
+    if how == "check":
+        prefill = D.load_saved_program(os.path.join(bundle, "prefill"))
+        decode = D.load_saved_program(os.path.join(bundle, "decode"))
+        (diag,) = D.check_gen_bundle(prefill, decode, meta)
+        assert diag.code == "PTA019" and diag.severity == "error"
+        assert "re-export" in diag.message
+    elif how == "load":
+        from paddle_tpu.gen import GenPredictor
+        with pytest.raises(analysis.ProgramVerificationError) as ei:
+            GenPredictor(bundle)
+        assert "PTA019" in str(ei.value) and "page_len" in str(ei.value)
+    else:
+        from paddle_tpu.cli import main
         assert main(["lint", bundle]) == 1
         assert "PTA019" in capsys.readouterr().out
 
@@ -606,31 +620,12 @@ class TestMultiProgramCli:
 # export-time self-check wiring
 # ---------------------------------------------------------------------------
 
-def test_gen_export_self_check_rejects_drifted_bundle(tmp_path,
-                                                      monkeypatch):
+def test_gen_export_self_check_rejects_drifted_bundle(tmp_path):
     """export_gen_model verifies its own output: a meta writer that
     drifts from the decode program fails AT EXPORT, naming the pass."""
-    from paddle_tpu.models import gen_lm
-    real_cache_names = gen_lm.cache_var_names
-
-    def drifted(hp):
-        names = real_cache_names(hp)
-        return names + ["genlm_cache_ghost"]
-
-    hp = gen_lm.GenConfig()
-    hp.vocab_size, hp.d_model, hp.d_ffn = 32, 16, 32
-    hp.n_head = hp.n_layer = 2
-    hp.d_head, hp.max_len = 8, 16
-    bundle = str(tmp_path / "bundle")
     # build the real bundle first, then re-verify with a drifted meta
-    gen_lm.export_gen_model(bundle, hp, num_slots=2)
-    monkeypatch.setattr(gen_lm, "cache_var_names", drifted)
-    meta_path = os.path.join(bundle, "gen_meta.json")
-    with open(meta_path) as f:
-        meta = json.load(f)
-    meta["cache_vars"] = meta["cache_vars"] + ["genlm_cache_ghost"]
-    with open(meta_path, "w") as f:
-        json.dump(meta, f)
+    bundle, _ = _bundle_with_edited_meta(
+        tmp_path, lambda meta: meta["cache_vars"].append("genlm_cache_ghost"))
     with pytest.raises(analysis.ProgramVerificationError) as ei:
         analysis.verify_gen_bundle(bundle,
                                    where="gen_lm.export_gen_model")

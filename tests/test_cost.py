@@ -10,6 +10,8 @@ from paddle_tpu import layers
 from paddle_tpu.analysis import cost
 from paddle_tpu.lod import row_bucket, select_bucket_edges
 
+from fake_gen_predictor import FakeGenPredictor
+
 
 def _matmul_program(m=4, k=8, n=16):
     main = fluid.Program()
@@ -158,38 +160,7 @@ class TestGenConsumers:
         drains."""
         from paddle_tpu.gen.scheduler import GenScheduler
 
-        class FakePredictor:
-            num_slots = 4
-            vocab_size = 8
-            max_len = 32
-            max_prompt_len = 16
-            eos_id = -1
-            prefill_calls = []
-
-            def prefill(self, prompt):
-                self.prefill_calls.append(tuple(prompt))
-                kv = np.zeros((1, 1), np.float32)
-                logits = np.zeros(self.vocab_size, np.float32)
-                logits[7] = 1.0
-                return logits, kv
-
-            def prefill_cost(self, n):
-                return 100.0 * n
-
-            def write_slot(self, *a):
-                pass
-
-            def clear_slot(self, *a):
-                pass
-
-            def decode_step(self, tokens, positions, pos_onehot, attn_mask,
-                            on_device=False):
-                out = np.zeros((self.num_slots, self.vocab_size),
-                               np.float32)
-                out[:, 7] = 1.0
-                return out
-
-        pred = FakePredictor()
+        pred = FakeGenPredictor()
         s = GenScheduler(pred, queue_size=8, prefill_budget=250.0)
         try:
             streams = [s.submit([1, 2], max_new_tokens=2)
@@ -210,19 +181,13 @@ class TestGenConsumers:
         silently inert there."""
         from paddle_tpu.gen.scheduler import GenScheduler
 
-        class Pred:
-            num_slots, vocab_size, max_len = 2, 8, 16
-            max_prompt_len, eos_id = 8, -1
-
-            def prefill_cost(self, n):
-                return 1.0
-
-        s = GenScheduler(Pred(), admission="batch", prefill_budget=5.0)
+        s = GenScheduler(FakeGenPredictor(), admission="batch",
+                         prefill_budget=5.0)
         try:
             assert s.prefill_budget is None
         finally:
             s.close()
-        s = GenScheduler(Pred(), prefill_budget=5.0)
+        s = GenScheduler(FakeGenPredictor(), prefill_budget=5.0)
         try:
             assert s.prefill_budget == 5.0
         finally:

@@ -1,7 +1,7 @@
 """Admission's page seed (``gen/predictor.py:_seed_pool``): ONE compiled,
 pool-donating call per ``write_slot`` / ``clear_slot``.  It must write
 exactly what the eager ``cache.at[idx].set(buf)`` it replaced wrote, bit
-for bit, in both layouts; its signature must depend on the prompt bucket
+for bit; its signature must depend on the prompt bucket
 alone (never on the page count); the pools must be donated; and the
 prefill's K/V must stay device arrays from ``prefill`` to ``write_slot``."""
 
@@ -35,13 +35,6 @@ def paged(tmp_path_factory):
     p = _export(tmp_path_factory, "seed_paged")
     assert tuple(p.prompt_buckets) == BUCKETS
     assert (p.page_len, p.pages_per_slot) == (PAGE_LEN, PAGES_PER_SLOT)
-    return p
-
-
-@pytest.fixture(scope="module")
-def dense(tmp_path_factory):
-    p = _export(tmp_path_factory, "seed_dense", paged=False)
-    assert tuple(p.prompt_buckets) == BUCKETS and not p.paged
     return p
 
 
@@ -85,18 +78,6 @@ def _eager_paged(before, kv, pages, page_len, max_len):
         buf.reshape(-1, arr.shape[2])[:rows] = arr[0, :rows]
         pool = pool.copy()
         pool[np.asarray(pages)] = buf
-        want.append(pool)
-    return want
-
-
-def _eager_dense(before, kv, slot, max_len):
-    want = []
-    for pool, arr in zip(before, kv):
-        rows = min(arr.shape[1], max_len)
-        row = np.zeros((max_len, arr.shape[2]), arr.dtype)
-        row[:rows] = arr[0, :rows]
-        pool = pool.copy()
-        pool[slot] = row
         want.append(pool)
     return want
 
@@ -162,27 +143,6 @@ class TestSeedEqualsEagerWrite:
             np.testing.assert_array_equal(g, want)
         p.clear_slot(3)           # holds no pages: nothing to clear
 
-    @pytest.mark.parametrize("bucket", BUCKETS)
-    def test_dense_write_slot(self, dense, bucket):
-        p = dense
-        slot = bucket % p.num_slots or 3
-        prompt_len = max(1, bucket - 3)
-        kv = _kv(p, bucket, prompt_len, seed=bucket)
-        before = _fill_pools(p)
-        assert p.write_slot(slot, [jax.device_put(a) for a in kv],
-                            prompt_len) == 0
-        for g, w in zip(_pools(p),
-                        _eager_dense(before, kv, slot, p.max_len)):
-            np.testing.assert_array_equal(g, w)
-
-    def test_dense_clear_slot(self, dense):
-        before = _fill_pools(dense)
-        dense.clear_slot(2)
-        for g, b in zip(_pools(dense), before):
-            want = b.copy()
-            want[2] = 0.0
-            np.testing.assert_array_equal(g, want)
-
     def test_write_before_alloc_raises(self, paged):
         paged.free_all_pages()
         with pytest.raises(RuntimeError, match="before alloc_slot_pages"):
@@ -226,16 +186,14 @@ class TestSeedSignatures:
         assert predictor_mod._seed_pool._cache_size() - seeds0 \
             <= len(p.prompt_buckets)
 
-    @pytest.mark.parametrize("layout", ["paged", "dense"])
-    def test_pools_are_donated(self, layout, paged, dense):
+    def test_pools_are_donated(self, paged):
         """The array the scope held before ``write_slot`` is deleted by
         it (its buffer became the new pool's), and the compiled seed
         aliases every pool input to an output."""
-        p = paged if layout == "paged" else dense
+        p = paged
         _fill_pools(p)
-        if p.paged:
-            p.free_all_pages()
-            p.alloc_slot_pages(0, 2)
+        p.free_all_pages()
+        p.alloc_slot_pages(0, 2)
         held = [p._scope.find_var(n) for n in p.cache_vars]
         kv = [jax.device_put(a) for a in _kv(p, 16, 9, seed=5)]
         try:
@@ -245,7 +203,7 @@ class TestSeedSignatures:
         assert all(a.is_deleted() for a in held)
         assert not any(a.is_deleted() for a in kv)
         now = tuple(p._scope.find_var(n) for n in p.cache_vars)
-        idx = np.zeros(p.pages_per_slot if p.paged else 1, np.int32)
+        idx = np.zeros(p.pages_per_slot, np.int32)
         hlo = predictor_mod._seed_pool.lower(
             now, tuple(kv), idx, np.int32(1),
             max_rows=p.max_len).compile().as_text()

@@ -19,6 +19,7 @@ from paddle_tpu.obs import aggregate, bench_history, slo, trace
 from paddle_tpu.profiler import RuntimeMetrics
 from paddle_tpu.serving import InferenceServer
 
+from fake_gen_predictor import FakeGenPredictor
 from tests.test_obs_prom import assert_conformant
 
 
@@ -447,11 +448,7 @@ class TestSLOWatchdog:
              "max": 10.0}, interval=0.001)))
         monkeypatch.setenv(slo.SLO_ENV, str(spec))
 
-        class _StubPredictor:
-            num_slots, vocab_size, max_prompt_len = 2, 8, 4
-            max_len, eos_id = 8, 0
-
-        sched = GenScheduler(_StubPredictor(), queue_size=2)
+        sched = GenScheduler(FakeGenPredictor(), queue_size=2)
         try:
             assert sched.slo_watchdog is not None
             assert sched.slo_watchdog.spec.objectives[0]["name"] == "lat"

@@ -15,6 +15,31 @@ from paddle_tpu.analysis.opt import optimize_program
 from paddle_tpu.models import ZOO_MODELS, build_train_program
 
 
+def _id_bound(block, name):
+    """One past the largest valid value of the integer feed ``name``:
+    the class count of the cross-entropy that takes it as its label or
+    the height of the table it indexes, followed through reshapes; the
+    smallest where it has several consumers (ops come in program
+    order, so one pass follows it)."""
+    aliases, bounds = {name}, []
+    for op in block.ops:
+        for slot, args in op.inputs.items():
+            if aliases.isdisjoint(args):
+                continue
+            if op.type == "reshape":
+                aliases.update(op.output_arg_names)
+            elif op.type == "lookup_table" and slot == "Ids":
+                bounds.append(block.var(op.input("W")[0]).shape[0])
+            elif slot == "Label" and op.type in (
+                    "cross_entropy", "softmax_with_cross_entropy"):
+                scores = op.input("X") or op.input("Logits")
+                bounds.append(block.var(scores[0]).shape[-1])
+    if not bounds:
+        raise ValueError(f"integer feed {name!r}: no consumer says which "
+                         f"values are valid")
+    return int(min(bounds))
+
+
 def golden_feed(name, main_program, feed_names, seed=7):
     """A deterministic, VALID feed per zoo model (zero feeds make the
     transformer loss nan through its zero-token normalizer; LoD models
@@ -28,8 +53,8 @@ def golden_feed(name, main_program, feed_names, seed=7):
         return seq2seq.fake_batch(4, 5, 5, 16, 16, seed=seed)
     if name == "stacked_lstm":
         return stacked_lstm.fake_batch(4, 6, 16, seed=seed)
-    # dense models: random values in valid ranges (labels/ids stay
-    # inside the smallest zoo vocab/class count)
+    # dense models: random values in valid ranges (labels inside their
+    # head's class count, ids inside their table)
     rng = np.random.RandomState(seed)
     block = main_program.global_block()
     if feed_names is None:
@@ -41,7 +66,8 @@ def golden_feed(name, main_program, feed_names, seed=7):
         shape = tuple(2 if d is None or int(d) < 0 else int(d)
                       for d in (var.shape or (2,)))
         if var.dtype in ("int32", "int64"):
-            feed[fname] = rng.randint(0, 10, size=shape).astype(
+            feed[fname] = rng.randint(
+                0, _id_bound(block, fname), size=shape).astype(
                 var.dtype if var.dtype == "int32" else "int64")
         else:
             feed[fname] = rng.standard_normal(shape).astype("float32")
@@ -111,27 +137,19 @@ class TestGenBundleEquivalence:
         p = GenPredictor(bundle_dir)
         logits, kv = p.prefill(prompt)
         toks = [int(np.argmax(logits))]
-        if p.paged:   # the default export: pages precede the write
-            p.alloc_slot_pages(0, p.pages_needed(len(prompt), n))
+        p.alloc_slot_pages(0, p.pages_needed(len(prompt), n))
         p.write_slot(0, kv, len(prompt))
         pos = len(prompt)
         last = toks[0]
-        S, L = p.num_slots, p.max_len
+        S = p.num_slots
         for _ in range(n - 1):
             tokens = np.zeros(S, np.int32)
             positions = np.zeros(S, np.int32)
+            lens = np.zeros(S, np.int32)
             tokens[0] = last
             positions[0] = pos
-            if p.paged:
-                lens = np.zeros(S, np.int32)
-                lens[0] = pos + 1
-                step = p.decode_step(tokens, positions, lens=lens)
-            else:
-                onehot = np.zeros((S, L), np.float32)
-                mask = np.zeros((S, L), np.float32)
-                onehot[0, pos] = 1.0
-                mask[0, :pos + 1] = 1.0
-                step = p.decode_step(tokens, positions, onehot, mask)
+            lens[0] = pos + 1
+            step = p.decode_step(tokens, positions, lens=lens)
             last = int(np.argmax(step[0]))
             toks.append(last)
             pos += 1

@@ -268,14 +268,14 @@ class TestHbmCensus:
     def test_provider_collection(self):
         import jax.numpy as jnp
         pool = jnp.zeros((4, 16))
-        token = perf.register_hbm_provider("kv_cache", lambda: [pool])
+        token = perf.register_hbm_provider("kv_pages", lambda: [pool])
         try:
             census = perf.hbm_census(fluid.Scope())
-            assert census["kv_cache"] >= pool.nbytes
+            assert census["kv_pages"] >= pool.nbytes
         finally:
             perf.unregister_hbm_provider(token)
         census = perf.hbm_census(fluid.Scope())
-        assert census["kv_cache"] == 0
+        assert census["kv_pages"] == 0
 
     def test_census_tick_cadence(self):
         before = runtime_metrics.counter("hbm.census_runs")
@@ -383,7 +383,7 @@ class TestProfileCli:
         rc = cli.main(["profile", "memory", "--zoo", "mnist", "--json"])
         assert rc == 0
         census = json.loads(capsys.readouterr().out)
-        for k in ("params", "optimizer", "kv_cache", "prefetch",
+        for k in ("params", "optimizer", "kv_pages", "prefetch",
                   "other", "total", "high_watermark"):
             assert k in census
         assert census["params"] > 0
