@@ -657,6 +657,8 @@ class GenPredictor:
         touched = int(np.sum(-(-lens[live, 0] // self.page_len)))
         runtime_metrics.observe("gen.paged.pages_touched",
                                 float(touched))
+        runtime_metrics.observe("gen.paged.pages_in_bucket",
+                                float(lens.shape[0] * P))
         if touched:
             occupancy = (100.0 * float(lens[live, 0].sum()) /
                          (touched * self.page_len))

@@ -919,24 +919,48 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # ---------------------------------------------------------------------------
 # paged_attention IR op — occupancy-proportional decode reads over the gen
 # KV pool (ROADMAP item 3).  The gen cache lives as [num_pages, page_len,
-# H*D] pages plus a per-slot page table; each decode step appends the new
-# token's K/V row into its slot's tail page, then attends ONLY the pages
-# covering [0, len) — bytes read scale with live prefix length, not the
-# padded max_len.  The page-table feed is bucketed by the predictor so the
-# decode jit key stays constant per bucket.  Two lowerings share one
-# contract.  The Pallas kernel (grid = (slot, page), page picked by a
-# scalar-prefetch table lookup, online softmax across pages, all VPU — no
-# dot_general) is taken on a TPU whenever ``_paged_kernel_ok`` admits the
-# shape (head width a multiple of 128 lanes, page_len of 8 rows).  Grouped
-# query heads: the pool's rows are ``Hkv*D`` wide (``n_kv_head`` K/V heads),
-# Q's ``H*D``, and query head ``h`` reads K/V head ``h // (H / Hkv)``; the
-# K/V heads are never copied out to ``H``.  ``Hkv == H`` is the same code
-# with a group of one.  It ran
-# compiled on a v5e in PR 21's chip_smoke.py (8 heads x 128, page_len 16,
-# f32; all 7 page buckets held ``tpu_custom_call``, ``gen.paged.fallback``
-# stayed 0, tokens matched the cache-free reference; its speed: not
-# measured) and tests/test_tpu_compile.py holds it to the v5e compiler in
-# f32 and bf16.  The shipped ``GenConfig`` (d_head 16) FAILS the gate: on
+# Hkv*D] pages plus a per-slot page table; each decode step appends the new
+# token's K/V row into its slot's tail page, then attends ONLY the rows
+# [0, len).  The page-table feed is bucketed by the predictor so the decode
+# jit key stays constant per bucket; the bucket sets the WIDTH of the table
+# that is fed, not the work.  Two lowerings share one contract.
+#
+# The Pallas kernel (PR 30) is taken on a TPU whenever ``_paged_kernel_ok``
+# admits the shape (head width a multiple of 128 lanes, page_len whole
+# sublane tiles).  Grid = (slots,); the pools stay in HBM, the page table
+# and ``lens`` are scalar-prefetched.  Trip count: a slot makes
+# ``cdiv(lens, block rows)`` trips of an in-kernel loop, a free slot none;
+# neither the pages a slot holds past its length nor the table's ``0``
+# tail is visited.  A trip copies the NEXT block's live pages (one
+# ``make_async_copy`` a page; past a slot's last block, the first block of
+# the next live slot) into the other half of a double buffer, waits for
+# its own, and updates the online softmax chunk by chunk over the live
+# rows only.  Block choice (``_paged_blocking``): as many pages as come to
+# ~1 MB of K, so 4 pages of 4096-wide float32 rows and 64 of 256-wide; a
+# chunk is 64 rows where every query head has a K/V head of its own (a
+# VPU multiply + lane reduction for the scores, a sublane reduction for
+# PV: exact float32) and 512 where ``G = H / Hkv`` query heads share one:
+# those take their K/V head's rows in two float32 ``HIGHEST`` products
+# ``[G, D] x [D, rows]``, ``[G, rows] x [rows, D]`` on the MXU.  The K/V
+# heads are never copied out to ``H``; softmax state is float32.
+#
+# Measured on a v5e, the kernel alone (my chip runs, PR 30; the kernel it
+# replaced, grid (slots, page bucket) with one 16-row page a grid step,
+# in brackets): 16 slots x 32 heads x 128 on 4096-wide float32 rows in
+# the 64-page bucket at 5024 live rows 0.235 ms [0.565], 86% of what the
+# live K/V bytes take at 819 GB/s; 3 live slots of 16 in the 32-page
+# bucket 0.040 [0.231]; every slot filling the bucket 0.715 [0.768], 92%;
+# 32 slots x 32 query heads over 2 K/V heads (256-wide rows) in the
+# 128-page bucket at 20258 live rows 0.145 [1.641].  Block bytes of 0.5,
+# 1 and 2 MB and VPU chunks of 32, 64 and 128 rows read the same; the
+# grouped chunk reads 0.211 / 0.160 / 0.145 ms at 128 / 256 / 512 rows.
+# The K/V heads are walked by a loop that writes out 4 a trip
+# (``_PAGED_HEAD_UNROLL``): all 32 written out read 0.229 ms but cost
+# every decode executable 3.1 s more to trace and lower, 25 s of a warm
+# server start; one a trip reads 0.407 ms.
+# ``PERF.md`` section 6 has the serving cells.  tests/test_tpu_compile.py
+# holds it to the v5e compiler at both configurations' shapes, in float32
+# and bfloat16.  The shipped ``GenConfig`` (d_head 16) FAILS the gate: on
 # a TPU it decodes through the XLA gather below, counted by
 # ``gen.paged.fallback``.  Off-TPU the gather is the default too —
 # interpret-mode execution re-runs the kernel per call (unlike trace-once
@@ -991,106 +1015,269 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
     return out.reshape(q.shape).astype(q.dtype)
 
 
-def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_ref, m_ref, l_ref, *, page_len, n_head,
-                         scale):
-    """One (slot, page) grid step of the online softmax.
+# One block of the kernel's double buffer holds about this many bytes of K
+# (and as many of V): 4 pages of 4096-wide float32 rows, 64 of 256-wide.
+_PAGED_BLOCK_BYTES = 1 << 20
+# Rows of one online-softmax update.  A head of its own: what keeps its
+# [rows, D] tiles in vector registers.  Grouped heads: the two MXU
+# products read fastest at 512 (128 / 256 / 512 measured: comment above).
+_PAGED_CHUNK_ROWS = 64
+_PAGED_GROUPED_CHUNK_ROWS = 512
+# K/V heads a trip of the kernel's head loop takes, written out: the
+# scheduler needs a few independent heads to hide a head's reductions
+# (1 / 2 / 4 / 8 / all 32 read 0.407 / 0.254 / 0.235 / 0.231 / 0.229 ms),
+# and every head written out is traced and lowered again for each decode
+# executable (all 32: +3.1 s on each of a warm start's eight).
+_PAGED_HEAD_UNROLL = 4
 
-    Everything stays in the pool's own ``[page_len, Hkv*D]`` row layout:
-    query head ``h`` is the static lane slice ``[h*D, (h+1)*D)`` of Q and
-    reads K/V head ``h // (H / Hkv)``, a static lane slice of the page
-    (whole vregs when ``D % 128 == 0``), scores are a VPU multiply + lane reduction
-    and the PV product a sublane reduction — no ``dot_general``, so
-    Mosaic sees neither a batch dimension nor an M=1 matmul.  The
-    running max / denominator are kept replicated across each head's
-    ``D`` lanes so every update is a plain elementwise op."""
+
+def _paged_blocking(P, PL, HDkv, itemsize, grouped, block_pages=None):
+    """(pages a block, rows a chunk) from the shapes: a block is the unit
+    of the double buffer, as many pages as come to ``_PAGED_BLOCK_BYTES``
+    (never more than the table holds); a chunk the unit of the online
+    softmax, the largest whole number of pages that divides the block
+    and stays within ``_PAGED_CHUNK_ROWS`` (``_PAGED_GROUPED_CHUNK_ROWS``
+    where query heads share a K/V head)."""
+    if block_pages is None:
+        block_pages = _PAGED_BLOCK_BYTES // (PL * HDkv * itemsize)
+    block_pages = max(1, min(int(block_pages), P))
+    rows = _PAGED_GROUPED_CHUNK_ROWS if grouped else _PAGED_CHUNK_ROWS
+    chunk_pages = max(d for d in range(1, block_pages + 1)
+                      if block_pages % d == 0 and (d * PL <= rows or d == 1))
+    return block_pages, chunk_pages * PL
+
+
+def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         kbuf, vbuf, sems, ahead_ref, qs_ref, m_ref, l_ref,
+                         acc_ref, *, page_len, block_pages, chunk_rows,
+                         head_unroll, scale):
+    """One slot of the grid: the online softmax over the slot's LIVE rows.
+
+    The pools stay in HBM.  A slot makes ``cdiv(lens, block rows)`` trips
+    of an in-kernel loop and no more (a free slot none); each trip copies
+    the NEXT block's live pages (of this slot or, past its last block, of
+    the next live slot: ``ahead_ref`` carries that across grid steps)
+    into the other half of ``kbuf`` / ``vbuf`` before it waits for its
+    own, then updates the softmax chunk by chunk over the rows that are
+    live, K/V head by K/V head (a loop of ``head_unroll`` heads a trip).
+    Q arrives and Out leaves as ``[H, D]``, a slot's heads on sublanes
+    (laying them out inside the kernel read 0.08 ms a step slower);
+    ``m`` / ``l`` are kept replicated over a head's ``D`` lanes.  With
+    as many K/V heads as query heads a head's scores are a VPU multiply
+    + lane reduction and its PV product a sublane reduction, as before;
+    grouped query heads share their K/V head's rows in two float32
+    ``HIGHEST`` products ``[G, D] x [D, rows]`` and
+    ``[G, rows] x [rows, D]``."""
     s = pl.program_id(0)
-    p = pl.program_id(1)
+    S, P = pt_ref.shape
+    PL, CR = page_len, chunk_rows
+    BR = block_pages * PL
+    H, D = qs_ref.shape
+    G = H // (kbuf.shape[-1] // D)
+    f32 = jnp.float32
 
-    @pl.when(p == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def rows_of(slot):
+        return jnp.minimum(lens_ref[slot, 0], P * PL)
+
+    def block_copies(slot, blk, buf, start):
+        """Start, or wait for, the copies of the live pages of a block."""
+        live = jnp.minimum(pl.cdiv(rows_of(slot) - blk * BR, PL),
+                           block_pages)
+
+        def page(j, _):
+            src = pt_ref[slot, blk * block_pages + j]
+            dst = pl.ds(pl.multiple_of(j * PL, PL), PL)
+            for x, (hbm, buffer) in enumerate(((k_hbm, kbuf),
+                                               (v_hbm, vbuf))):
+                copy = pltpu.make_async_copy(
+                    hbm.at[src], buffer.at[buf, dst], sems.at[buf, x])
+                copy.start() if start else copy.wait()
+            return 0
+
+        jax.lax.fori_loop(0, live, page, 0)
+
+    def attend(buf, rows, valid):
+        """One chunk: ``valid`` of its rows are live (may exceed it)."""
+        if G == 1:
+            live = jax.lax.broadcasted_iota(jnp.int32, (CR, 1), 0) < valid
+        else:
+            live = jax.lax.broadcasted_iota(jnp.int32, (1, CR), 1) < valid
+
+        def head(g):
+            hs = pl.ds(pl.multiple_of(g * G, G), G)
+            kv = pl.ds(pl.multiple_of(g * D, D), D)
+            k = kbuf[buf, rows, kv].astype(f32)          # [CR, D]
+            v = vbuf[buf, rows, kv].astype(f32)
+            m_prev = m_ref[hs, :]                        # [G, D]
+            if G == 1:
+                sc = jnp.sum(qs_ref[hs, :] * k, axis=1, keepdims=True)
+                sc = jnp.where(live, sc, NEG_INF)        # [CR, 1]
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(sc, axis=0, keepdims=True))
+                e = jnp.exp(sc - m_new)                  # [CR, D]
+                e_sum = jnp.sum(e, axis=0, keepdims=True)
+                pv = jnp.sum(e * v, axis=0, keepdims=True)
+            else:
+                sc = jax.lax.dot_general(
+                    qs_ref[hs, :], k, (((1,), (1,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=f32)          # [G, CR]
+                sc = jnp.where(live, sc, NEG_INF)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(sc, axis=1, keepdims=True))
+                e = jnp.exp(sc - m_new[:, :1])           # [G, CR]
+                e_sum = jnp.sum(e, axis=1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    e, v, (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=f32)          # [G, D]
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[hs, :] = l_ref[hs, :] * alpha + e_sum
+            acc_ref[hs, :] = acc_ref[hs, :] * alpha + pv
+            m_ref[hs, :] = m_new
+
+        def heads(i, _):
+            for u in range(head_unroll):
+                head(i * head_unroll + u)
+            return 0
+
+        jax.lax.fori_loop(0, H // G // head_unroll, heads, 0)
+
+    n = rows_of(s)
+    n_blocks = pl.cdiv(n, BR)
+
+    @pl.when(s == 0)
+    def _first():
+        ahead_ref[0] = 0        # the half the next block is copied into
+        ahead_ref[1] = 0        # 1: its copies are already in flight
+        # a page that is never copied leaves its rows of the buffer as
+        # they were: masked rows weigh 0, and 0 x NaN would still be NaN
+        vbuf[...] = jnp.zeros_like(vbuf)
+
+    @pl.when(n_blocks == 0)
+    def _free():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(n_blocks > 0)
+    def _live():
+        buf0 = ahead_ref[0]
+
+        @pl.when(ahead_ref[1] == 0)
+        def _():
+            block_copies(s, 0, buf0, True)
+
+        nxt = jax.lax.fori_loop(
+            s + 1, S, lambda i, at: jnp.where(
+                (at == S) & (lens_ref[i, 0] > 0), i, at), S)
+        qs_ref[...] = q_ref[0].astype(f32) * scale
         m_ref[...] = jnp.full_like(m_ref, _M_INIT)
         l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    D = q_ref.shape[-1] // n_head
-    group = n_head // (k_ref.shape[-1] // D)
-    valid = lens_ref[s, 0] - p * page_len
-    live = jax.lax.broadcasted_iota(jnp.int32, (page_len, 1), 0) < valid
-    for h in range(n_head):
-        sl = slice(h * D, (h + 1) * D)
-        kv = slice(h // group * D, (h // group + 1) * D)
-        q = q_ref[0, :, sl].astype(jnp.float32)     # [1, D]
-        k = k_ref[0, :, kv].astype(jnp.float32)     # [PL, D]
-        v = v_ref[0, :, kv].astype(jnp.float32)
-        sc = jnp.sum(q * k, axis=1, keepdims=True) * scale
-        sc = jnp.where(live, sc, NEG_INF)           # [PL, 1]
-        m_prev = m_ref[:, sl]                       # [1, D]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        e = jnp.exp(sc - m_new)                     # [PL, D]
-        l_ref[:, sl] = l_ref[:, sl] * alpha \
-            + jnp.sum(e, axis=0, keepdims=True)
-        acc_ref[:, sl] = acc_ref[:, sl] * alpha \
-            + jnp.sum(e * v, axis=0, keepdims=True)
-        m_ref[:, sl] = m_new
+        def block(b, buf):
+            last = b == n_blocks - 1
 
-    @pl.when(p == pl.num_programs(1) - 1)
-    def _finish():
-        # a free slot (lens == 0) masks every page: the output is finite
-        # garbage the scheduler never reads
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+            @pl.when(jnp.logical_not(last))
+            def _():
+                block_copies(s, b + 1, 1 - buf, True)
+
+            @pl.when(last & (nxt < S))
+            def _():
+                block_copies(nxt, 0, 1 - buf, True)
+
+            block_copies(s, b, buf, False)
+            left = n - b * BR
+
+            def chunk(c, _):
+                r0 = pl.multiple_of(c * CR, CR)
+                attend(buf, pl.ds(r0, CR), left - r0)
+                return 0
+
+            jax.lax.fori_loop(0, pl.cdiv(jnp.minimum(left, BR), CR),
+                              chunk, 0)
+            return 1 - buf
+
+        ahead_ref[0] = jax.lax.fori_loop(0, n_blocks, block, buf0)
+        ahead_ref[1] = jnp.where(nxt < S, 1, 0)
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None):
+def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4):
     """Shape gate of the paged kernel: heads must split Q's ``H*D``
     evenly and whole K/V heads the pool's row, the query heads divide
     evenly over them, and on the chip a head must cover whole 128-lane
-    vregs and a page whole 8-row sublane tiles (the kernel slices refs
-    at ``h*D`` lanes)."""
+    vregs and a page whole sublane tiles of the pool's type (8 rows of
+    float32, 16 of bfloat16): the kernel slices refs at ``h*D`` lanes
+    and copies a page to ``j*PL`` rows."""
     if HD % n_head:
         return False
     D = HD // n_head
     HDkv = HD if HDkv is None else HDkv
     if HDkv % D or n_head % (HDkv // D):
         return False
-    return interpret or not (D % 128 or PL % 8)
+    return interpret or not (D % 128 or PL % (32 // itemsize))
 
 
 def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
-                            interpret=False):
+                            interpret=False, block_pages=None):
     """Returns None when ``_paged_kernel_ok`` refuses the shape; any
-    lowering error past that gate surfaces to the caller."""
-    S, P = page_table.shape
+    lowering error past that gate surfaces to the caller.
+    ``block_pages`` is for the tests: the kernel reads it from the
+    shapes (``_paged_blocking``)."""
+    P = page_table.shape[1]
     NP, PL, HDkv = kc.shape
     HD = q.shape[-1]
-    if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv):
+    itemsize = kc.dtype.itemsize
+    if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize):
         return None
-    out_dtype = q.dtype
-    q = q.astype(kc.dtype)
+    block_pages, chunk_rows = _paged_blocking(
+        P, PL, HDkv, itemsize, HDkv != HD, block_pages)
+    n_kv = HDkv // (HD // n_head)
+    head_unroll = max(u for u in range(1, _PAGED_HEAD_UNROLL + 1)
+                      if n_kv % u == 0)
+    return _paged_kernel_call(
+        q, kc, vc, page_table, lens, n_head=n_head, scale=scale,
+        interpret=interpret, block_pages=block_pages,
+        chunk_rows=chunk_rows, head_unroll=head_unroll)
+
+
+# inline: the call leaves no trace in the program (the kernel's event keeps
+# the op scope's name), but a model's layers, which call it with the same
+# shapes, share ONE trace and ONE lowering of the kernel's body
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "n_head", "scale", "interpret", "block_pages", "chunk_rows",
+    "head_unroll"))
+def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
+                       interpret, block_pages, chunk_rows, head_unroll):
+    S = page_table.shape[0]
+    NP, PL, HDkv = kc.shape
+    D = q.shape[-1] // n_head
     kernel = functools.partial(_paged_decode_kernel, page_len=PL,
-                               n_head=n_head, scale=scale)
+                               block_pages=block_pages,
+                               chunk_rows=chunk_rows,
+                               head_unroll=head_unroll, scale=scale)
+    head_rows = pl.BlockSpec((1, n_head, D), lambda s, pt, ln: (s, 0, 0))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(S, P),
-            in_specs=[
-                pl.BlockSpec((1, 1, HD), lambda s, p, pt, ln: (s, 0, 0)),
-                pl.BlockSpec((1, PL, HDkv),
-                             lambda s, p, pt, ln: (pt[s, p], 0, 0)),
-                pl.BlockSpec((1, PL, HDkv),
-                             lambda s, p, pt, ln: (pt[s, p], 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, HD),
-                                   lambda s, p, pt, ln: (s, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((1, HD), jnp.float32)] * 3,
+            grid=(S,),
+            in_specs=[head_rows, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=head_rows,
+            scratch_shapes=[
+                pltpu.VMEM((2, block_pages * PL, HDkv), kc.dtype),
+                pltpu.VMEM((2, block_pages * PL, HDkv), vc.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+            ] + [pltpu.VMEM((n_head, D), jnp.float32)] * 4,
         ),
-        out_shape=jax.ShapeDtypeStruct((S, 1, HD), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, n_head, D), kc.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, lens, q.reshape(S, 1, HD), kc, vc)
-    return out.reshape(q.shape).astype(out_dtype)
+    )(page_table, lens, q.reshape(S, n_head, D).astype(kc.dtype), kc, vc)
+    return out.reshape(q.shape).astype(q.dtype)
 
 
 def _paged_kernel_enabled(interpret):

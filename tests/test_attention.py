@@ -516,6 +516,90 @@ class TestPagedAttention:
         assert kernel is not None, "interpret kernel unexpectedly gated"
         fallback = np.asarray(A._xla_paged_attention(
             q, kc, vc, pt, lens, self.H, scale))
-        np.testing.assert_allclose(np.asarray(kernel), fallback,
+        live = np.asarray(lens)[:, 0] > 0
+        np.testing.assert_allclose(np.asarray(kernel)[live], fallback[live],
                                    rtol=1e-5, atol=1e-6)
+        # the free slot makes no trip: nothing of it is read or summed
         assert np.all(np.isfinite(np.asarray(kernel)))
+
+
+class TestPagedKernelWalksLiveRows:
+    """The decode kernel's trips follow ``lens`` in blocks of several
+    pages: whatever the block, it agrees with the gather lowering on
+    every kind of length, and it reads no page that holds no live row."""
+
+    S, D, PL, P, NP = 8, 8, 8, 5, 48
+    HEADS = {"as_many_kv_heads": (2, 2), "grouped": (4, 2)}
+
+    def _case(self, heads, block_pages, seed=3):
+        H, Hkv = self.HEADS[heads]
+        S, D, PL, P, NP = self.S, self.D, self.PL, self.P, self.NP
+        B = block_pages * PL
+        rng = np.random.RandomState(seed)
+        q = jnp.asarray(rng.randn(S, 1, H * D).astype("float32") * 0.5)
+        kc = jnp.asarray(rng.randn(NP, PL, Hkv * D).astype("float32"))
+        vc = jnp.asarray(rng.randn(NP, PL, Hkv * D).astype("float32"))
+        # a free slot, one row, a page's edge and one row past it, a
+        # block's edge and one row past it, the whole bucket, and ragged
+        lens = np.array([0, 1, PL, PL + 1, B, B + 1, P * PL, 19], "int32")
+        need = -(-lens // PL)
+        # pages up to a horizon past the length are the slot's own
+        # (allocated, not written yet); the table's tail repeats page 0
+        horizon = np.minimum(need + np.array([0, 2, 1, 0, 2, 1, 0, 2]), P)
+        pages = rng.permutation(np.arange(1, NP))
+        pt = np.zeros((S, P), "int32")
+        at = 0
+        for s in range(S):
+            pt[s, :horizon[s]] = pages[at:at + horizon[s]]
+            at += horizon[s]
+        needed = np.concatenate([pt[s, :need[s]] for s in range(S)])
+        return (q, kc, vc, jnp.asarray(pt), jnp.asarray(lens[:, None]),
+                H, needed)
+
+    @pytest.mark.parametrize("block_pages", [1, 2, 4])
+    @pytest.mark.parametrize("heads", list(HEADS))
+    def test_kernel_equals_the_gather_at_every_length(self, heads,
+                                                      block_pages):
+        from paddle_tpu.ops import attention_ops as A
+        q, kc, vc, pt, lens, H, _ = self._case(heads, block_pages)
+        scale = float(self.D) ** -0.5
+        got = np.asarray(A._pallas_paged_attention(
+            q, kc, vc, pt, lens, H, scale, interpret=True,
+            block_pages=block_pages))
+        want = np.asarray(A._xla_paged_attention(q, kc, vc, pt, lens, H,
+                                                 scale))
+        live = np.asarray(lens)[:, 0] > 0
+        np.testing.assert_allclose(got[live], want[live], rtol=1e-5,
+                                   atol=1e-6)
+        assert np.all(np.isfinite(got))
+
+    @pytest.mark.parametrize("block_pages", [1, 2, 4])
+    @pytest.mark.parametrize("heads", list(HEADS))
+    def test_dead_pages_are_never_read(self, heads, block_pages):
+        """Every pool page no live row needs is NaN: the pages a slot
+        holds past its length, page 0 that the table's tail and the free
+        slot's row name, and the pages nobody holds.  The parent's
+        kernel walked the bucket and weighed them with 0: NaN."""
+        from paddle_tpu.ops import attention_ops as A
+        q, kc, vc, pt, lens, H, needed = self._case(heads, block_pages)
+        assert 0 not in needed
+        dead = np.setdiff1d(np.arange(self.NP), needed)
+        poison = lambda c: c.at[dead].set(np.nan)
+        scale = float(self.D) ** -0.5
+        run = lambda k, v: np.asarray(A._pallas_paged_attention(
+            q, k, v, pt, lens, H, scale, interpret=True,
+            block_pages=block_pages))
+        got = run(poison(kc), poison(vc))
+        assert np.all(np.isfinite(got))
+        assert np.array_equal(got, run(kc, vc))
+
+    @pytest.mark.parametrize("shape,want", [
+        # (page bucket, page_len, Hkv*D, bytes an element, grouped)
+        ((64, 16, 4096, 4, False), (4, 64)),     # genlm_opt6.7b
+        ((128, 16, 256, 4, True), (64, 512)),    # nemotron3_super_ep8
+        ((2, 16, 4096, 4, False), (2, 32)),      # the smallest bucket
+        ((8, 16, 1024, 2, False), (8, 64)),      # chip_smoke's, bfloat16
+    ])
+    def test_block_and_chunk_come_from_the_shapes(self, shape, want):
+        from paddle_tpu.ops import attention_ops as A
+        assert A._paged_blocking(*shape) == want

@@ -559,6 +559,41 @@ class TestPagedKV:
         finally:
             s.close()
 
+    def test_paged_equivalence_through_the_interpreted_kernel(
+            self, bundle_dir, predictor, monkeypatch):
+        """The op's kernel lowering (interpret mode off the TPU) inside
+        the decode program: its trips follow ``lens``, so streams of
+        unlike lengths beside free slots decode to the reference's
+        tokens, and no bucket falls back to the gather."""
+        monkeypatch.setenv("PADDLE_TPU_PAGED_INTERPRET", "1")
+        fell_back = profiler.runtime_metrics.counter("gen.paged.fallback")
+        p = GenPredictor(bundle_dir)
+        s = GenScheduler(p, queue_size=8)
+        try:
+            prompts = ([5, 9, 3, 17], [6] * 21, [11, 2] * 17)
+            streams = [s.submit(pr, max_new_tokens=6) for pr in prompts]
+            for pr, st in zip(prompts, streams):
+                assert list(st) == _ref_greedy(predictor, pr, 6)
+        finally:
+            s.close()
+        assert profiler.runtime_metrics.counter("gen.paged.fallback") \
+            == fell_back
+
+    def test_pages_in_bucket_is_what_the_table_fed_holds(self, bundle_dir):
+        """``gen.paged.pages_in_bucket`` beside ``pages_touched``: slots x
+        the step's page bucket against the pages that hold live rows;
+        their ratio is the share of the table the kernel skips."""
+        p = GenPredictor(bundle_dir)
+        m = profiler.runtime_metrics
+        lens = np.zeros((p.num_slots, 1), "int32")
+        lens[1, 0] = 2 * p.page_len + 1           # 3 pages: bucket of 4
+        feed = p._paged_decode_feed(lens)
+        bucket = feed["gen_page_table"].shape[1]
+        assert bucket == 4
+        assert m.samples("gen.paged.pages_in_bucket", last=1) \
+            == [float(p.num_slots * bucket)]
+        assert m.samples("gen.paged.pages_touched", last=1) == [3.0]
+
     def test_page_allocator_lifecycle(self, bundle_dir):
         p = GenPredictor(bundle_dir)
         total = p.num_pages

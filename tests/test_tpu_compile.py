@@ -86,6 +86,17 @@ def test_flash_attention_compiles_for_v5e(one_chip, fn, bhsd):
     assert "tpu_custom_call" in hlo
 
 
+def _ragged_lens(S, P, PL):
+    """A ``lens`` that leaves blocks dead: a free slot, one row, lengths
+    ending inside a page and on a page's edge, one stream filling the
+    bucket (a constant of the compiled program: the kernel reads its trip
+    counts from it at run time either way)."""
+    import numpy as np
+    import jax.numpy as jnp
+    lens = np.array([0, 1, PL + 3, 2 * PL, P * PL] * S)[:S]
+    return jnp.asarray(np.minimum(lens, P * PL).reshape(S, 1), jnp.int32)
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_paged_decode_compiles_for_v5e(one_chip, dtype):
     """The decode kernel at chip_smoke phase 2's shapes: 8 slots, an
@@ -95,15 +106,37 @@ def test_paged_decode_compiles_for_v5e(one_chip, dtype):
     S, P, NP, PL, H, D = 8, 8, 512, 16, 8, 128
     dt = jnp.dtype(dtype)
 
-    def fn(q, kc, vc, pt, lens):
-        out = A._pallas_paged_attention(q, kc, vc, pt, lens, H, D ** -0.5,
-                                        interpret=False)
+    def fn(q, kc, vc, pt):
+        out = A._pallas_paged_attention(q, kc, vc, pt,
+                                        _ragged_lens(S, P, PL), H,
+                                        D ** -0.5, interpret=False)
         assert out is not None, "shape gate refused the smoke's shapes"
         return out
 
     hlo = _compile(fn, one_chip, ((S, 1, H * D), dt),
                    ((NP, PL, H * D), dt), ((NP, PL, H * D), dt),
-                   ((S, P), jnp.int32), ((S, 1), jnp.int32))
+                   ((S, P), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_paged_decode_compiles_for_v5e_at_the_widest_rows(one_chip):
+    """The decode kernel where its double buffer is largest: the serving
+    cell's 16 slots, 32 heads x 128 on 4096-wide float32 rows, the
+    64-page bucket of a 2048-page pool (4 pages a block: 2 x 2 x 1 MB of
+    VMEM), with a ``lens`` that leaves blocks dead."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, P, NP, PL, H, D = 16, 64, 2048, 16, 32, 128
+    assert A._paged_blocking(P, PL, H * D, 4, False) == (4, 64)
+
+    def fn(q, kc, vc, pt):
+        return A._pallas_paged_attention(q, kc, vc, pt,
+                                         _ragged_lens(S, P, PL), H,
+                                         D ** -0.5, interpret=False)
+
+    hlo = _compile(fn, one_chip, ((S, 1, H * D), jnp.float32),
+                   ((NP, PL, H * D), jnp.float32),
+                   ((NP, PL, H * D), jnp.float32), ((S, P), jnp.int32))
     assert "tpu_custom_call" in hlo
 
 
@@ -152,16 +185,17 @@ def test_grouped_paged_decode_compiles_for_v5e(one_chip, pages):
     from paddle_tpu.ops import attention_ops as A
     S, NP, PL, H, HKV, D = 32, 4096, 16, 32, 2, 128
 
-    def fn(q, kc, vc, pt, lens):
-        out = A._pallas_paged_attention(q, kc, vc, pt, lens, H, D ** -0.5,
-                                        interpret=False)
+    def fn(q, kc, vc, pt):
+        out = A._pallas_paged_attention(q, kc, vc, pt,
+                                        _ragged_lens(S, pages, PL), H,
+                                        D ** -0.5, interpret=False)
         assert out is not None, "shape gate refused grouped heads"
         return out
 
     hlo = _compile(fn, one_chip, ((S, 1, H * D), jnp.bfloat16),
                    ((NP, PL, HKV * D), jnp.float32),
                    ((NP, PL, HKV * D), jnp.float32),
-                   ((S, pages), jnp.int32), ((S, 1), jnp.int32))
+                   ((S, pages), jnp.int32))
     assert "tpu_custom_call" in hlo
 
 
