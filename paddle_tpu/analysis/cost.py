@@ -43,6 +43,7 @@ Registering a rule for a new op::
 
 from __future__ import annotations
 
+
 import numpy as np
 
 from paddle_tpu.analysis import typecheck
@@ -188,10 +189,15 @@ def validate_cost_report(obj):
     return problems
 
 
-def estimate(program):
+def estimate(program, paged_live_rows=None):
     """Walk the global block with typecheck shape propagation and price
     each op through its cost rule (unknown dims count as 1 — totals
-    undercount rather than guess).  Returns a :class:`CostReport`."""
+    undercount rather than guess).  ``paged_live_rows``: the live rows
+    a slot holds, where the caller knows them; the paged-attention
+    rules then charge those (never more than the step's page bucket
+    holds) instead of the bucket: the kernel walks the live pages, so a
+    wider bucket costs a wider table feed and a jit key, not reads.
+    Returns a :class:`CostReport`."""
     from paddle_tpu import profiler as _profiler
     block = program.global_block()
     diags = []
@@ -219,6 +225,8 @@ def estimate(program):
                 if v.shape is not None:
                     return VarInfo(v.shape, v.dtype)
             return inf
+
+        info.paged_live_rows = paged_live_rows
 
         flops_bytes = None
         fn = _RULES.get(op.type)
@@ -415,33 +423,69 @@ def _c_sdpa(op, info):
     return 4 * b * h * s * s * d, io_bytes(op, info)
 
 
-@rule("paged_attention")
-def _c_paged_attention(op, info):
-    """Paged decode attention prices the pages ACTUALLY addressed by
-    the step's page-table feed ([S, P] -> S*P*page_len token rows of
-    K and V), not the full pool — the whole point of the layout; a
-    full-pool ``io_bytes`` would price every bucket identically and
-    hide the occupancy win from ``row_cost_fn``/``gen.decode_mfu``."""
+def _paged_rows(op, info, cache_slot):
+    """(slots, rows charged a slot, the pool's VarInfo) of a paged op."""
     q = info(op.input("Q")[0]) if op.input("Q") else _UNKNOWN
-    kc = info(op.input("KCache")[0]) if op.input("KCache") else _UNKNOWN
+    pool = info(op.input(cache_slot)[0]) if op.input(cache_slot) \
+        else _UNKNOWN
     pt = info(op.input("PageTable")[0]) if op.input("PageTable") \
         else _UNKNOWN
-    if q.shape is None or kc.shape is None or pt.shape is None or \
-            len(kc.shape) != 3 or len(pt.shape) != 2:
+    if q.shape is None or pool.shape is None or pt.shape is None or \
+            len(pool.shape) != 3 or len(pt.shape) != 2:
         return None
-    hd, pl, p = kc.shape[-1], kc.shape[1], pt.shape[1]
-    if any(x < 0 for x in (hd, pl, p)):
+    hd, pl, p = pool.shape[-1], pool.shape[1], pt.shape[1]
+    if any(x < 0 for x in (hd, pl)):
         return None
+    s = q.shape[0] if q.shape[0] > 0 else 1
+    # rows charged a slot: the step's whole page bucket (all a program
+    # alone says: an upper bound, and what the XLA gather fallback
+    # reads), or the LIVE rows where the caller knows them
+    # (``estimate(paged_live_rows=)``): the kernel reads those whatever
+    # the bucket
+    rows, live = (p * pl if p > 0 else None), info.paged_live_rows
+    if live is not None:
+        rows = live if rows is None else min(rows, live)
+    return None if rows is None else (s, rows, q, pool)
+
+
+@rule("paged_attention")
+def _c_paged_attention(op, info):
+    """Paged decode attention prices the rows a step ADDRESSES, not the
+    full pool: the step's page bucket ([S, P] -> S*P*page_len rows of K
+    and V) from the program alone, the live rows under
+    ``paged_live_rows`` (the kernel's reads follow those)."""
+    found = _paged_rows(op, info, "KCache")
+    if found is None:
+        return None
+    s, t, q, kc = found
+    hd = kc.shape[-1]
     # grouped query heads: Q is wider than the pool's rows of K/V heads
     hq = q.shape[-1] if q.shape[-1] > 0 else hd
-    s = q.shape[0] if q.shape[0] > 0 else 1
-    t = p * pl
     item = _DTYPE_BYTES.get(str(kc.dtype), 4)
     flops = 4 * s * t * hq                       # QK^T + PV per head-row
-    bytes_ = (2 * s * t * hd          # K/V pages gathered
+    bytes_ = (2 * s * t * hd          # K/V pages read
               + 2 * s * (hq + hd)     # q, k, v rows in + out
               + 2 * s * hd) * item    # tail-page scatter write (k + v)
-    return flops, bytes_
+    return int(flops), int(bytes_)
+
+
+@rule("paged_attention_latent")
+def _c_paged_attention_latent(op, info):
+    """The latent form: ONE row a token is read once and is every
+    head's key and, in its leading ``v_width`` lanes, their value: 2
+    FLOPs a head a lane for the scores, 2 a value lane for the
+    context."""
+    found = _paged_rows(op, info, "Cache")
+    if found is None:
+        return None
+    s, t, q, pool = found
+    w, h, v = pool.shape[-1], int(op.attr("n_head")), int(op.attr("v_width"))
+    item = _DTYPE_BYTES.get(str(pool.dtype), 4)
+    flops = 2 * s * t * h * (w + v)
+    bytes_ = (s * t * w                # the live rows, once
+              + s * h * (w + v)        # absorbed queries in, context out
+              + 2 * s * w) * item      # this step's row in + its write
+    return int(flops), int(bytes_)
 
 
 def _per_element(mult):
@@ -670,6 +714,54 @@ def _known(*dims):
     return all(d is not None and d > 0 for d in dims)
 
 
+@rule("mla_attention")
+def _c_mla_attention(op, info):
+    """Prefill: K and V of every head expanded from the latent (2 L per
+    expanded lane), then causal-less scores and context over T rows."""
+    q, w = _shape(info, op, "Q"), _shape(info, op, "Wkvb")
+    if q is None or w is None or len(q) != 3 or not _known(q[1], *w):
+        return None
+    t, h = q[1], int(op.attr("n_head"))
+    qk = int(op.attr("nope_dim")) + int(op.attr("rope_dim"))
+    flops = 2 * t * w[0] * w[1] + 2 * t * t * h * (qk + int(op.attr("v_dim")))
+    return flops, io_bytes(op, info)
+
+
+@rule("mla_absorb")
+def _c_mla_absorb(op, info):
+    """One half of W_kvb against every row: 2 x latent x heads x (nope
+    | v) FLOPs a row."""
+    x, w = _shape(info, op, "X"), _shape(info, op, "Wkvb")
+    rows = numel(x[:-1]) if x is not None else None
+    if rows is None or w is None or not _known(*w):
+        return None
+    part = int(op.attr("v_dim" if op.attr("side") == "o" else "nope_dim"))
+    return 2 * rows * w[0] * int(op.attr("n_head")) * part, \
+        io_bytes(op, info)
+
+
+rule("pad", "pad_grad")(_per_element(1))
+rule("swiglu")(_per_element(6))
+rule("rope")(_per_element(6))
+
+
+@rule("moe_experts_gated")
+def _c_moe_experts_gated(op, info):
+    """A ROUTED product: a row goes through the experts it chose among
+    the held ones, on average ``top_k x held / experts`` of them, which
+    only the router knows; charged here as ``top_k`` gated experts a row
+    (an upper bound: every choice landing on this share), 6 x features x
+    hidden FLOPs each.  Bytes: the operands, every held expert once."""
+    x, wg = _shape(info, op, "X"), _shape(info, op, "Wg")
+    idx = _shape(info, op, "TopkIdx")
+    rows = numel(x[:-1]) if x is not None else None
+    if rows is None or wg is None or len(wg) != 3 or not _known(*wg) \
+            or idx is None or not _known(idx[-1]):
+        return None
+    per_row = min(idx[-1], wg[0])
+    return 6 * rows * per_row * wg[1] * wg[2], io_bytes(op, info)
+
+
 @rule("gqa_attention")
 def _c_gqa_attention(op, info):
     q = _shape(info, op, "Q")
@@ -749,3 +841,6 @@ rule("ssm_scan_conv_grad")(_twice(_c_ssm_conv))
 rule("ssm_scan_grad")(_twice(_c_ssm_scan))
 rule("moe_route_grad")(_twice(_c_moe_route))
 rule("moe_experts_grad")(_twice(_c_moe_experts))
+rule("moe_experts_gated_grad")(_twice(_c_moe_experts_gated))
+rule("mla_attention_grad")(_twice(_c_mla_attention))
+rule("swiglu_grad", "rope_grad")(_per_element(6))

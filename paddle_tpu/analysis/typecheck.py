@@ -1036,6 +1036,137 @@ def _r_moe_experts(op, tc):
     tc.set_output(op, "Stats", shape=(1, 3), dtype="int32")
 
 
+@rule("moe_experts_gated")
+def _r_moe_experts_gated(op, tc):
+    x = _same_as(op, tc)
+    wg, wu, wd = (tc.input_info(op, s) for s in ("Wg", "Wu", "Wd"))
+    _int_index(op, tc, "TopkIdx")
+    _int_index(op, tc, "Lens")
+    if None not in (wg.shape, wu.shape, wd.shape):
+        if any(len(w.shape) != 3 for w in (wg, wu, wd)) or \
+                any(_dims_conflict(a, b) for w in (wg, wu)
+                    for a, b in zip(w.shape, (wd.shape[0], wd.shape[2],
+                                              wd.shape[1]))):
+            tc.report("PTA006",
+                      f"moe_experts_gated Wg {wg.shape} / Wu {wu.shape} / "
+                      f"Wd {wd.shape} are not [held, features, hidden] x 2 "
+                      f"/ [held, hidden, features]",
+                      op=op, var=op.input("Wg")[0])
+        elif x.shape is not None:
+            _last_dim_is(op, tc, "X", wg.shape[1], "features")
+    tc.set_output(op, "Stats", shape=(1, 3), dtype="int32")
+
+
+# -- latent attention: rotary slice, gated activation, the two forms -------
+
+@rule("pad")
+def _r_pad(op, tc):
+    x = tc.input_info(op, "X")
+    p = list(op.attr("paddings") or ())
+    shape = None
+    if x.shape is not None:
+        if len(p) != 2 * len(x.shape):
+            tc.report("PTA006", f"pad: {len(p)} paddings for a tensor of "
+                      f"{len(x.shape)} dims (two a dim)", op=op,
+                      var=op.input("X")[0])
+        else:
+            shape = tuple(d if d < 0 else d + p[2 * i] + p[2 * i + 1]
+                          for i, d in enumerate(x.shape))
+    tc.set_output(op, "Out", shape=shape, dtype=x.dtype)
+
+
+@rule("rope")
+def _r_rope(op, tc):
+    x = _same_as(op, tc)
+    _int_index(op, tc, "Pos")
+    h, r = int(op.attr("n_head", 1)), int(op.attr("rope_dim"))
+    if r % 2 or (x.shape is not None and x.shape[-1] > 0 and
+                 (x.shape[-1] % h or x.shape[-1] // h < r)):
+        tc.report("PTA006",
+                  f"rope: a slice of {r} lanes (pairs) does not fit "
+                  f"{h} head(s) over {x.shape[-1] if x.shape else '?'} "
+                  f"features", op=op, var=op.input("X")[0])
+
+
+@rule("swiglu")
+def _r_swiglu(op, tc):
+    x = _same_as(op, tc)
+    y = tc.input_info(op, "Y")
+    if x.shape is not None and y.shape is not None and \
+            (len(x.shape) != len(y.shape) or any(
+                _dims_conflict(a, b) for a, b in zip(x.shape, y.shape))):
+        tc.report("PTA006", f"swiglu gate {x.shape} and up projection "
+                  f"{y.shape} differ", op=op, var=op.input("Y")[0])
+
+
+def _mla_widths(op, tc):
+    """(heads, nope, v) and W_kvb's latent width, W_kvb checked."""
+    h, nope, v = (int(op.attr(a)) for a in ("n_head", "nope_dim", "v_dim"))
+    w = tc.input_info(op, "Wkvb")
+    latent = None
+    if w.shape is not None and len(w.shape) == 2:
+        latent = w.shape[0]
+        _last_dim_is(op, tc, "Wkvb", h * (nope + v),
+                     "columns (heads x (nope + v))")
+    return h, nope, v, latent
+
+
+@rule("mla_attention")
+def _r_mla_attention(op, tc):
+    h, nope, v, latent = _mla_widths(op, tc)
+    r = int(op.attr("rope_dim"))
+    q = tc.input_info(op, "Q")
+    _last_dim_is(op, tc, "Q", h * (nope + r), "features (heads x (nope + "
+                                              "rope))")
+    lat = tc.input_info(op, "Latent")
+    if latent and lat.shape is not None and 0 < lat.shape[-1] < latent + r:
+        tc.report("PTA006",
+                  f"mla_attention Latent `{op.input('Latent')[0]}` is "
+                  f"{lat.shape[-1]} wide, under c_kv {latent} + rope {r}",
+                  op=op, var=op.input("Latent")[0])
+    shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
+    tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
+
+
+@rule("mla_absorb")
+def _r_mla_absorb(op, tc):
+    h, nope, v, latent = _mla_widths(op, tc)
+    x = tc.input_info(op, "X")
+    width = -1
+    if op.attr("side") == "o":
+        _last_dim_is(op, tc, "X", h * latent if latent else None,
+                     "features (heads x latent)")
+        width = h * v
+    elif x.shape is not None and x.shape[-1] > 0 and latent:
+        if x.shape[-1] % h or x.shape[-1] // h < nope:
+            tc.report("PTA006", f"mla_absorb: {x.shape[-1]} query features "
+                      f"do not hold {h} heads of nope {nope} + rope",
+                      op=op, var=op.input("X")[0])
+        width = x.shape[-1] + h * (latent - nope + int(op.attr("pad", 0)))
+    shape = None if x.shape is None else tuple(x.shape[:-1]) + (width,)
+    tc.set_output(op, "Out", shape=shape, dtype=x.dtype)
+
+
+@rule("paged_attention_latent")
+def _r_paged_attention_latent(op, tc):
+    q = tc.input_info(op, "Q")
+    cache = tc.input_info(op, "Cache")
+    _int_index(op, tc, "PageTable")
+    _int_index(op, tc, "Lens")
+    h, v = int(op.attr("n_head")), int(op.attr("v_width"))
+    row = cache.shape[-1] if cache.shape is not None else -1
+    _last_dim_is(op, tc, "Row", row, "features (the pool's row)")
+    _last_dim_is(op, tc, "Q", h * row if row > 0 else None,
+                 "features (heads x the pool's row)")
+    if 0 < row < v:
+        tc.report("PTA006", f"paged_attention_latent reads a value of {v} "
+                  f"lanes from rows of {row}", op=op,
+                  var=op.input("Cache")[0])
+    shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
+    tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
+    tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
+
+
 @rule("gqa_attention")
 def _r_gqa_attention(op, tc):
     q = _same_as(op, tc, "Q")
@@ -1070,5 +1201,6 @@ def _r_split(op, tc):
 # grad maker's slot convention
 rule("split_grad", "relu2_grad", "rms_norm_grad",
      "gated_group_rms_norm_grad", "ssm_scan_conv_grad", "ssm_scan_grad",
-     "moe_route_grad", "moe_experts_grad",
-     "gqa_attention_grad")(_r_grad_mirror)
+     "moe_route_grad", "moe_experts_grad", "moe_experts_gated_grad",
+     "gqa_attention_grad", "rope_grad", "swiglu_grad", "pad_grad",
+     "mla_attention_grad")(_r_grad_mirror)

@@ -108,10 +108,28 @@ def _as_device_array(value, dtype=None, device=None):
         want = jnp.dtype(dtype) if dtype != "bfloat16" else jnp.bfloat16
         if value.dtype != want and dtype not in (None,):
             value = value.astype(want)
+    if device is not None and isinstance(value, (np.ndarray, jax.Array)):
+        # one placement, committed, in the canonical dtype: what
+        # jnp.asarray and then device_put gave in two dispatches
+        return jax.device_put(value, device)
     arr = jnp.asarray(value)
     if device is not None:
         arr = jax.device_put(arr, device)
     return arr
+
+
+def _step_key(seed):
+    """``jax.random.PRNGKey(seed)`` for one step.  Under the default
+    threefry implementation the key is the seed's two 32-bit words, made
+    here on the host and handed over with the call; ``PRNGKey`` makes the
+    same two words in three dispatches of their own, each of which gives
+    the calling thread's GIL away (a serving decode turn pays one key a
+    step)."""
+    if jax.config.jax_default_prng_impl != "threefry2x32" or \
+            jax.config.jax_enable_custom_prng:
+        return jax.random.PRNGKey(seed)
+    high = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([high, seed & 0xFFFFFFFF], np.uint32)
 
 
 # ---------------------------------------------------------------------------
@@ -542,7 +560,7 @@ class Executor:
                            for n in compiled.inout_names}
 
             self._run_counter += 1
-            key = jax.random.PRNGKey(
+            key = _step_key(
                 (program.random_seed or 0) * 1000003 + self._run_counter)
 
             t0 = time.perf_counter()

@@ -214,6 +214,13 @@ class GenPredictor:
         self.num_pages = int(self.meta["num_pages"])
         self.page_buckets = [int(b) for b in self.meta["page_buckets"]]
         self.pages_per_slot = -(-self.max_len // self.page_len)
+        # bytes one cached row takes over all cache arrays, each at its
+        # own width and type (a K and a V row a layer, or one latent row)
+        block = self._dec_prog.global_block()
+        self.cache_row_bytes = sum(
+            int(block.var(n).shape[-1])
+            * jnp.dtype(str(block.var(n).dtype)).itemsize
+            for n in self.cache_vars)
         # host-side page allocator state (all mutated under _lock): the
         # device only ever sees the bucketed table SLICE
         self._page_table = np.zeros(
@@ -261,7 +268,6 @@ class GenPredictor:
         # bundle without one): see decode_step(on_device=True)
         self.last_decode_stats = None
         self._length_cost_fn = None
-        self._page_cost_fn = None
 
     # -- prefill -----------------------------------------------------------
     def _bucket(self, prompt_len):
@@ -340,24 +346,27 @@ class GenPredictor:
     def plan_page_buckets(self, observed_lengths, max_edges=4):
         """Cost-optimal page-count bucket edges for an OBSERVED
         prefix-length distribution: ``lod.select_bucket_edges`` over
-        live page counts, priced by the decode program's static cost as
-        a function of the page-table width (``cost.row_cost_fn``
-        probing the bucketed dim — the paged_attention cost rule makes
-        that dimension carry the pages actually read).  Returns a
+        live page counts.  The paged kernel reads the LIVE pages whatever
+        the bucket, so a step is priced once, at the observed mean
+        length (``cost.estimate(paged_live_rows=)``), and a bucket adds what it
+        really costs a step: the width of the page table fed.  Returns a
         sorted edge list an operator can bake into the next export's
         ``page_buckets``."""
+        from paddle_tpu.analysis import cost as _cost
         from paddle_tpu.lod import select_bucket_edges
-        counts = [min(max(-(-int(n) // self.page_len), 1),
-                      self.pages_per_slot) for n in observed_lengths]
+        lengths = [min(max(int(n), 1), self.max_len)
+                   for n in observed_lengths]
+        counts = [-(-n // self.page_len) for n in lengths]
         with self._lock:
-            if self._page_cost_fn is None:
-                from paddle_tpu.analysis import cost as _cost
-                self._page_cost_fn = _cost.row_cost_fn(
-                    self._dec_prog, batch_var="gen_page_table", dim=1,
-                    probe_rows=(1, max(self.pages_per_slot, 2)))
-            fn = self._page_cost_fn
+            step = float(_cost.estimate(
+                self._dec_prog, paged_live_rows=sum(lengths)
+                / max(len(lengths), 1)).total_flops)
+
+        def cost_of(pages):
+            return step + 4.0 * self.num_slots * pages
+
         return select_bucket_edges(counts, max_edges=max_edges,
-                                   cost_of=fn)
+                                   cost_of=cost_of)
 
     # -- page allocator (driven by the scheduler) --------------------------
     @property

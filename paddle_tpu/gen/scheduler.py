@@ -37,6 +37,7 @@ flight (the first step after idle) a turn only dispatches.
 from __future__ import annotations
 
 import logging
+import queue
 import threading
 import time
 
@@ -85,18 +86,23 @@ class GenStream:
         self.finish_reason = None
         self.error = None
         self.tokens = []
-        self._events = []
-        self._cv = threading.Condition()
+        self._events = queue.SimpleQueue()
+        # a consumer that serves many streams from one thread (the
+        # server's token relay) sets this: called with None after every
+        # event queued, on the scheduler thread
+        self.on_event = None
 
     # -- producer side (scheduler thread) ---------------------------------
     def _push(self, event):
-        with self._cv:
-            self._events.append(event)
-            self._cv.notify_all()
+        self._events.put(event)
+        hook = self.on_event
+        if hook is not None:
+            hook(None)
 
     def emit(self, token):
-        self.tokens.append(int(token))
-        self._push(("token", int(token)))
+        token = int(token)
+        self.tokens.append(token)
+        self._push(("token", token))
 
     def finish(self, reason):
         self.finish_reason = reason
@@ -116,15 +122,12 @@ class GenStream:
     def next_event(self, timeout=None):
         """Block for the next ``("token", id)`` / ``("done", reason)`` /
         ``("error", exc)`` event; returns None on timeout."""
-        deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cv:
-            while not self._events:
-                remaining = None if deadline is None \
-                    else deadline - time.monotonic()
-                if remaining is not None and remaining <= 0:
-                    return None
-                self._cv.wait(remaining if remaining is not None else 0.5)
-            return self._events.pop(0)
+        try:
+            if timeout is not None and timeout <= 0:
+                return self._events.get_nowait()
+            return self._events.get(timeout=timeout)
+        except queue.Empty:
+            return None
 
     def __iter__(self):
         """Yield token ids until the stream finishes; raises the
@@ -641,7 +644,8 @@ class GenScheduler:
             except BaseException as e:
                 stream.fail(e)
                 return False
-            seed.set(pages=len(pages))
+            seed.set(pages=len(pages),
+                     row_bytes=self.predictor.cache_row_bytes)
             try:
                 written = self.predictor.write_slot(slot_idx, kv,
                                                     prompt_len)

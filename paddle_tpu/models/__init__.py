@@ -5,11 +5,12 @@ TPU-native layers API."""
 
 from paddle_tpu.models import (resnet, transformer, vgg, mnist,
                                seq2seq, stacked_lstm, gen_lm,
-                               gen_lm_long, wide_and_deep, hybrid_moe)
+                               gen_lm_long, wide_and_deep, hybrid_moe,
+                               latent_moe)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
            "seq2seq", "stacked_lstm", "gen_lm", "gen_lm_long",
-           "wide_and_deep", "hybrid_moe", "ZOO_MODELS",
+           "wide_and_deep", "hybrid_moe", "latent_moe", "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
 
 #: zoo model names accepted by :func:`build_train_program` (and by
@@ -17,7 +18,7 @@ __all__ = ["resnet", "transformer", "vgg", "mnist",
 #: tests/test_analysis_zoo.py iterates exactly this list)
 ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
               "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
-              "hybrid_moe")
+              "hybrid_moe", "latent_moe")
 
 
 def build_train_program(name, backward=True):
@@ -88,6 +89,12 @@ def build_train_program(name, backward=True):
             hp = hybrid_moe.HybridConfig()
             hp.dtype = "float32"
             cost, feeds = hybrid_moe.hybrid_moe_train_program(16, hp)
+            fetches = [cost.name]
+        elif name == "latent_moe":
+            # a dense and two expert layers at toy widths, float32
+            hp = latent_moe.LatentMoEConfig()
+            hp.dtype = "float32"
+            cost, feeds = latent_moe.latent_moe_train_program(16, hp)
             fetches = [cost.name]
         else:
             raise ValueError(

@@ -270,19 +270,19 @@ def _moe(h, hp, i, lens=None):
     return y + s, routed["Stats"]
 
 
-def _embed(ids, hp):
+def _embed(ids, hp, prefix="hyb"):
     limit = (6.0 / (hp.vocab_size + hp.hidden_size)) ** 0.5
     return layers.embedding(
         ids, size=[int(hp.vocab_size), int(hp.hidden_size)], dtype=hp.dtype,
-        param_attr=ParamAttr(name="hyb_emb",
+        param_attr=ParamAttr(name=f"{prefix}_emb",
                              initializer=init_mod.Uniform(-limit, limit)))
 
 
-def _logits(x2, hp):
+def _logits(x2, hp, prefix="hyb"):
     """Final norm and the untied head over rows ``x2`` [R, d]; float32."""
-    h = _rms(x2, "hyb_norm.scale", hp)
-    head = _matrix(hp, "hyb_head.w", [int(hp.hidden_size),
-                                      int(hp.vocab_size)])
+    h = _rms(x2, f"{prefix}_norm.scale", hp)
+    head = _matrix(hp, f"{prefix}_head.w", [int(hp.hidden_size),
+                                            int(hp.vocab_size)])
     return _op("matmul", {"X": h, "Y": head}, {"Out": "float32"},
                {"out_dtype": "float32"})["Out"]
 

@@ -1,0 +1,407 @@
+"""Latent-attention / shared-expert mixture-of-experts causal LM (the
+``deepseek_v3`` / ``kimi_k2`` families' block) on the generative serving
+path: the same prefill + paged-decode program pair and bundle layout as
+``models/gen_lm.py`` and ``models/hybrid_moe.py``.
+
+Every layer is pre-norm and holds two sublayers, ``x <- x +
+MLA(RMSNorm(x))`` then ``x <- x + FFN(RMSNorm(x))``; a final RMSNorm
+precedes the untied head.
+
+* **MLA** (``ops/mla_ops.py``).  ``c_q = RMSNorm(h W_qa)``, ``[q_nope |
+  q_rope] = c_q W_qb`` a head; ``[c_kv | k_r] = h W_kva``, ``c_kv <-
+  RMSNorm(c_kv)``; ``q_rope`` and the ONE shared ``k_r`` are rotated
+  (YaRN frequencies).  What is cached a token a layer is the row ``[c_kv
+  | k_r]`` and nothing else, stored ``latent_row`` wide (the next
+  multiple of 128 lanes, zeros behind: the chip's DMA takes whole vregs,
+  and a 576-wide array is laid out 640 wide in its memory anyway).  TWO
+  attention programs over the same weights: the prefill EXPANDS K and V
+  of every head from the latent (``mla_attention``); the decode step
+  ABSORBS ``W_kvb`` into the query and out of the context (``mla_absorb``)
+  and attends over the cached rows as they are
+  (``paged_attention_latent``), reading each once for scores and values.
+* **FFN**.  The first ``first_k_dense_replace`` layers: ``W_d (silu(W_g
+  h) * W_u h)``, width ``intermediate_size``.  The others: a sigmoid
+  top-k router over ALL the model's experts on the full hidden state
+  (``moe_route``; the correction bias moves the choice only), gated
+  routed experts of width ``moe_intermediate_size`` over the experts
+  HELD (``experts_held`` from ``expert_offset``: one chip's share of an
+  expert-parallel deployment; ``moe_experts_gated``, a routed product),
+  plus one shared expert on every token.
+
+Matrices and activations are ``dtype`` (bfloat16) with float32
+accumulation; router scores, norm statistics, rotary angles, softmax and
+logits are float32; the latent pool is ``dtype``.
+
+``export_latent_model`` writes ``prefill/``, ``decode/`` and
+``gen_meta.json``; ``latent_moe_train_program`` is the teacher-forced
+training graph over the same parameter names (the model-zoo lint gate's
+view of this model).  The prefill feeds ``gen_ids``, ``gen_pos``,
+``gen_mask``, ``gen_last`` and fetches ``[logits, latent row per layer
+...]``; the decode step fetches ``[logits, stats]`` with ``stats``
+``[n_moe_layers, 3]`` int32 (``decode_stats``, as ``hybrid_moe``).
+"""
+
+from __future__ import annotations
+
+import json
+import os
+
+import numpy as np
+
+import paddle_tpu.layers as layers
+from paddle_tpu.models.gen_lm import (META_FILENAME, PAGE_LEN_DEFAULT,
+                                      _write_model, default_page_buckets)
+from paddle_tpu.models.hybrid_moe import (DECODE_STATS, _data, _embed,
+                                          _logits, _matrix, _op, _rms,
+                                          _vector)
+from paddle_tpu.ops.mla_ops import yarn_mscale
+
+__all__ = ["LatentMoEConfig", "build_prefill_program",
+           "build_paged_decode_program", "latent_moe_train_program",
+           "export_latent_model", "paged_cache_var_names"]
+
+
+class LatentMoEConfig:
+    """Toy-scale defaults; ``from_dict`` takes the published keys of a
+    ``kimi_k2`` / ``deepseek_v3`` ``config.json``."""
+    vocab_size = 64
+    hidden_size = 64
+    num_hidden_layers = 3
+    first_k_dense_replace = 1
+    eps = 1e-5                       # rms_norm_eps
+    # MLA
+    num_attention_heads = 4
+    q_lora_rank = 48
+    kv_lora_rank = 32
+    qk_nope_head_dim = 16
+    qk_rope_head_dim = 8
+    v_head_dim = 16
+    rope_theta = 10000.0
+    rope_scaling = None              # the published group, type "yarn"
+    # FFN
+    intermediate_size = 96
+    moe_intermediate_size = 32
+    n_routed_experts = 16
+    n_shared_experts = 1
+    num_experts_per_tok = 2
+    routed_scaling_factor = 2.5
+    norm_topk_prob = True
+    experts_held = None              # None: all of them
+    expert_offset = 0
+    dtype = "bfloat16"
+    max_len = 64
+    eos_id = -1
+
+    _KEYS = {"rms_norm_eps": "eps"}
+
+    @classmethod
+    def from_dict(cls, cfg):
+        hp = cls()
+        for key, value in cfg.items():
+            name = cls._KEYS.get(key, key)
+            if hasattr(cls, name) and not name.startswith("_"):
+                setattr(hp, name, value)
+        return hp
+
+    @property
+    def held(self):
+        return int(self.n_routed_experts if self.experts_held is None
+                   else self.experts_held)
+
+    @property
+    def latent_row(self):
+        """Lanes of the cached row: ``[c_kv | k_r]`` and zeros up to the
+        next multiple of 128."""
+        return -(-(int(self.kv_lora_rank) + int(self.qk_rope_head_dim))
+                 // 128) * 128
+
+    @property
+    def rope_attrs(self):
+        rs = dict(self.rope_scaling or {})
+        factor = float(rs.get("factor", 1.0))
+        return {"rope_dim": int(self.qk_rope_head_dim),
+                "theta": float(self.rope_theta), "factor": factor,
+                "original_max": int(rs.get(
+                    "original_max_position_embeddings", 4096)),
+                "beta_fast": float(rs.get("beta_fast", 32)),
+                "beta_slow": float(rs.get("beta_slow", 1)),
+                "mscale": yarn_mscale(factor, rs.get("mscale", 1.0))
+                / yarn_mscale(factor, rs.get("mscale_all_dim", 0.0))}
+
+    @property
+    def softmax_scale(self):
+        """``(nope + rope)^-1/2 m^2``, ``m`` YaRN's temperature over all
+        dimensions."""
+        rs = dict(self.rope_scaling or {})
+        m = yarn_mscale(float(rs.get("factor", 1.0)),
+                        rs.get("mscale_all_dim", 0.0))
+        return (int(self.qk_nope_head_dim)
+                + int(self.qk_rope_head_dim)) ** -0.5 * m * m
+
+    def is_moe(self, i):
+        return i >= int(self.first_k_dense_replace)
+
+    @property
+    def moe_layers(self):
+        return [i for i in range(int(self.num_hidden_layers))
+                if self.is_moe(i)]
+
+
+def paged_cache_var_names(hp):
+    """Page-pool tensors, ONE a layer (the latent row), in layer order."""
+    return [f"lat{i}_paged_c" for i in range(int(hp.num_hidden_layers))]
+
+
+def _gated_ffn(h, hp, prefix, width):
+    d = int(hp.hidden_size)
+    g = layers.matmul(h, _matrix(hp, f"{prefix}_gate.w", [d, width]))
+    u = layers.matmul(h, _matrix(hp, f"{prefix}_up.w", [d, width]))
+    a = _op("swiglu", {"X": g, "Y": u}, {"Out": hp.dtype})["Out"]
+    return layers.matmul(a, _matrix(hp, f"{prefix}_down.w", [width, d]))
+
+
+def _attention(h, hp, i, pos, mask=None, paged=None):
+    """MLA: prefill (expanded; returns the masked latent rows that seed
+    the pool) or the absorbed paged decode (``paged`` = pool, page
+    table, lens)."""
+    d, H = int(hp.hidden_size), int(hp.num_attention_heads)
+    L, R = int(hp.kv_lora_rank), int(hp.qk_rope_head_dim)
+    nope, vd = int(hp.qk_nope_head_dim), int(hp.v_head_dim)
+    rope = hp.rope_attrs
+    c_q = _rms(layers.matmul(h, _matrix(hp, f"lat{i}_qa.w",
+                                        [d, int(hp.q_lora_rank)])),
+               f"lat{i}_qnorm.scale", hp)
+    q = layers.matmul(c_q, _matrix(hp, f"lat{i}_qb.w",
+                                   [int(hp.q_lora_rank), H * (nope + R)]))
+    q = _op("rope", {"X": q, "Pos": pos}, {"Out": hp.dtype},
+            {"n_head": H, **rope})["Out"]
+    kva = layers.matmul(h, _matrix(hp, f"lat{i}_kva.w", [d, L + R]))
+    c_kv, k_r = layers.split(kva, [L, R], dim=2)
+    c_kv = _rms(c_kv, f"lat{i}_kvnorm.scale", hp)
+    k_r = _op("rope", {"X": k_r, "Pos": pos}, {"Out": hp.dtype},
+              {"n_head": 1, **rope})["Out"]
+    row = layers.concat([c_kv, k_r], axis=2)
+    if hp.latent_row > L + R:
+        row = layers.pad(row, [0, 0, 0, 0, 0, hp.latent_row - L - R])
+    w_kvb = _matrix(hp, f"lat{i}_kvb.w", [L, H * (nope + vd)])
+    attrs = {"n_head": H, "nope_dim": nope, "v_dim": vd}
+    scale = float(hp.softmax_scale)
+    if paged is None:
+        row = layers.elementwise_mul(row, layers.cast(mask, hp.dtype),
+                                     axis=0)
+        ctx = _op("mla_attention", {"Q": q, "Latent": row, "Wkvb": w_kvb,
+                                    "Mask": mask}, {"Out": hp.dtype},
+                  {**attrs, "rope_dim": R, "scale": scale})["Out"]
+    else:
+        pool, page_table, lens = paged
+        q_lat = _op("mla_absorb", {"X": q, "Wkvb": w_kvb},
+                    {"Out": hp.dtype},
+                    {**attrs, "side": "q",
+                     "pad": hp.latent_row - L - R})["Out"]
+        ctx = _op("paged_attention_latent",
+                  {"Q": q_lat, "Row": row, "Cache": pool,
+                   "PageTable": page_table, "Lens": lens},
+                  {"Out": hp.dtype, "CacheOut": pool},
+                  {"n_head": H, "v_width": L, "scale": scale})["Out"]
+        ctx = _op("mla_absorb", {"X": ctx, "Wkvb": w_kvb},
+                  {"Out": hp.dtype}, {**attrs, "side": "o"})["Out"]
+    return layers.matmul(ctx, _matrix(hp, f"lat{i}_o.w", [H * vd, d])), row
+
+
+def _moe(h, hp, i, lens):
+    """Routed experts over the share held + the shared expert; returns
+    the layer's output and the experts' stats.  ``lens`` [rows, 1]
+    int32: a row with 0 (a free slot, a pad row) has no assignment."""
+    d, E = int(hp.hidden_size), int(hp.n_routed_experts)
+    F = int(hp.moe_intermediate_size)
+    route = _op("moe_route",
+                {"X": h, "W": _matrix(hp, f"lat{i}_gate.w", [d, E]),
+                 "Bias": _vector(f"lat{i}_gate.bias", E, 0.0)},
+                {"TopkIdx": "int32", "TopkWeight": "float32"},
+                {"top_k": int(hp.num_experts_per_tok),
+                 "scaling": float(hp.routed_scaling_factor),
+                 "norm_topk": bool(hp.norm_topk_prob)})
+    routed = _op("moe_experts_gated",
+                 {"X": h, "TopkIdx": route["TopkIdx"],
+                  "TopkWeight": route["TopkWeight"],
+                  "Wg": _matrix(hp, f"lat{i}_wg", [hp.held, d, F]),
+                  "Wu": _matrix(hp, f"lat{i}_wu", [hp.held, d, F]),
+                  "Wd": _matrix(hp, f"lat{i}_wd", [hp.held, F, d]),
+                  "Lens": lens},
+                 {"Out": hp.dtype, "Stats": "int32"},
+                 {"expert_offset": int(hp.expert_offset)})
+    shared = _gated_ffn(h, hp, f"lat{i}_sh",
+                        F * int(hp.n_shared_experts))
+    return routed["Out"] + shared, routed["Stats"]
+
+
+def _layer(x, hp, i, pos, lens, mask=None, paged=None):
+    """One layer; returns ``(x, latent row, stats or None)``."""
+    out, row = _attention(_rms(x, f"lat{i}_norm1.scale", hp), hp, i, pos,
+                          mask=mask, paged=paged)
+    x = x + out
+    h = _rms(x, f"lat{i}_norm2.scale", hp)
+    if hp.is_moe(i):
+        out, stats = _moe(h, hp, i, lens)
+    else:
+        out, stats = _gated_ffn(h, hp, f"lat{i}_ffn",
+                                int(hp.intermediate_size)), None
+    return x + out, row, stats
+
+
+def build_prefill_program(hp):
+    """The prefill forward in the CURRENT program guard.
+
+    Feeds (length-dynamic; callers pad to a bucket): ``gen_ids`` [1, T]
+    int32, ``gen_pos`` [1, T] int32 (0 .. T-1), ``gen_mask`` [1, T] f32
+    (1 = real token, real tokens first), ``gen_last`` [1, T] f32 (one-hot
+    of the last real position).  The causal mask is built in the graph
+    from ``gen_mask``.  Fetches ``[logits [1, V], latent row per layer
+    [1, T, latent_row] ...]``, zeroed on pad rows."""
+    ids = _data("gen_ids", [1, -1], "int32")
+    pos = _data("gen_pos", [1, -1], "int32")
+    mask = _data("gen_mask", [1, -1])
+    last = _data("gen_last", [1, -1])
+    # pad rows take no routed expert
+    lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
+    x = _embed(ids, hp, "lat")
+    rows = []
+    for i in range(int(hp.num_hidden_layers)):
+        x, row, _ = _layer(x, hp, i, pos, lens, mask=mask)
+        rows.append(row)
+    last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]), hp.dtype)
+    lasth = layers.reshape(layers.matmul(last3, x),
+                           shape=[-1, int(hp.hidden_size)])
+    return (["gen_ids", "gen_pos", "gen_mask", "gen_last"],
+            [_logits(lasth, hp, "lat")] + rows)
+
+
+def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
+    """Teacher-forced training forward over ONE sequence in the current
+    program guard (the ops take one prompt at a time), over the serving
+    programs' parameter names and the prefill's expanded attention; also
+    the model-zoo lint gate's view of this model.  Returns ``(avg_cost,
+    feed_names)``; feeds ``gen_ids`` / ``gen_labels`` [1, T] int32."""
+    hp = hp or LatentMoEConfig()
+    T = int(seq_len)
+    ids = _data("gen_ids", [1, T], "int32")
+    labels = _data("gen_labels", [1, T], "int32")
+    pos = layers.assign(np.arange(T, dtype="int32").reshape(1, T))
+    mask = layers.assign(np.ones((1, T), "float32"))
+    lens = layers.assign(np.ones((T, 1), "int32"))
+    for v in (pos, mask, lens):
+        v.stop_gradient = True
+    x = _embed(ids, hp, "lat")
+    for i in range(int(hp.num_hidden_layers)):
+        x, _, _ = _layer(x, hp, i, pos, lens, mask=mask)
+    logits = _logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
+                     "lat")
+    cost = layers.softmax_with_cross_entropy(
+        logits, layers.reshape(labels, shape=[T, 1]))
+    return layers.mean(x=cost), ["gen_ids", "gen_labels"]
+
+
+def build_paged_decode_program(hp, num_slots, page_len, num_pages):
+    """The single-token decode step in the CURRENT program guard.
+
+    Feeds: ``gen_token`` [S, 1] int32, ``gen_pos`` [S, 1] int32 (the
+    token's position), ``gen_page_table`` [S, P] int32 (P bucketed by
+    the predictor), ``gen_lens`` [S, 1] int32 (rows INCLUDING the current
+    token; 0 = free slot: no page is written).  Persistable state,
+    updated in place: one latent pool a layer, ``[num_pages, page_len,
+    latent_row]`` in ``hp.dtype``.  Fetches ``[logits [S, V], stats
+    [n_moe, 3]]``."""
+    import paddle_tpu as fluid
+
+    S = int(num_slots)
+    token = _data("gen_token", [S, 1], "int32")
+    pos = _data("gen_pos", [S, 1], "int32")
+    page_table = _data("gen_page_table", [S, -1], "int32")
+    lens = _data("gen_lens", [S, 1], "int32")
+    block = fluid.default_main_program().global_block()
+    pools = {}
+    for name in paged_cache_var_names(hp):
+        v = block.create_var(
+            name=name, dtype=hp.dtype,
+            shape=[int(num_pages), int(page_len), hp.latent_row])
+        v.persistable = True
+        v.stop_gradient = True
+        pools[name] = v
+    x = layers.reshape(_embed(token, hp, "lat"),
+                       shape=[S, 1, int(hp.hidden_size)])
+    stats = []
+    for i in range(int(hp.num_hidden_layers)):
+        x, _, st = _layer(x, hp, i, pos, lens,
+                          paged=(pools[f"lat{i}_paged_c"], page_table, lens))
+        if st is not None:
+            stats.append(st)
+    fetches = [_logits(layers.reshape(x, shape=[S, int(hp.hidden_size)]),
+                       hp, "lat")]
+    if stats:
+        fetches.append(layers.concat(stats, axis=0))
+    return ["gen_token", "gen_pos", "gen_page_table", "gen_lens"], fetches
+
+
+def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
+                        prompt_buckets=None, page_len=PAGE_LEN_DEFAULT,
+                        num_pages=None, page_buckets=None):
+    """Export a generation bundle in ``gen_lm.export_gen_model``'s
+    layout; ``cache_vars`` names ONE pool a layer.  Returns
+    ``dirname``."""
+    import jax.numpy as jnp
+
+    import paddle_tpu as fluid
+    from paddle_tpu.lod import bucket_edges
+
+    hp = hp or LatentMoEConfig()
+    num_slots = int(num_slots)
+    if prompt_buckets is None:
+        prompt_buckets = bucket_edges(1, hp.max_len)
+    page_len = max(1, min(int(page_len), int(hp.max_len)))
+    pps = -(-int(hp.max_len) // page_len)
+    num_pages = num_slots * pps if num_pages is None else int(num_pages)
+    if page_buckets is None:
+        page_buckets = default_page_buckets(pps)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor()
+        pre_main, pre_startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(pre_main, pre_startup):
+            pre_feeds, pre_fetches = build_prefill_program(hp)
+        exe.run(pre_startup)
+        _write_model(os.path.join(dirname, "prefill"), pre_main,
+                     pre_feeds, pre_fetches, exe)
+        dec_main, dec_startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(dec_main, dec_startup):
+            dec_feeds, dec_fetches = build_paged_decode_program(
+                hp, num_slots, page_len, num_pages)
+        # decode shares the initialized parameters (its startup is never
+        # run); the pools start as zeros of the model's own type
+        block = dec_main.global_block()
+        for name in paged_cache_var_names(hp):
+            scope.set_var(name, np.zeros(block.var(name).shape,
+                                         jnp.dtype(hp.dtype)))
+        _write_model(os.path.join(dirname, "decode"), dec_main,
+                     dec_feeds, dec_fetches, exe)
+
+    meta = {
+        "format": "paddle_tpu.gen/1",
+        "num_slots": num_slots,
+        "max_len": int(hp.max_len),
+        "vocab_size": int(hp.vocab_size),
+        "n_layer": int(hp.num_hidden_layers),
+        "eos_id": int(hp.eos_id),
+        "cache_vars": paged_cache_var_names(hp),
+        "state_vars": [],
+        "decode_stats": DECODE_STATS if hp.moe_layers else [],
+        "prompt_buckets": [int(b) for b in prompt_buckets],
+        "page_len": int(page_len),
+        "num_pages": int(num_pages),
+        "page_buckets": [int(b) for b in page_buckets],
+        "page_table_feed": "gen_page_table",
+    }
+    with open(os.path.join(dirname, META_FILENAME), "w") as f:
+        json.dump(meta, f, indent=2)
+    from paddle_tpu.analysis import verify_gen_bundle
+    verify_gen_bundle(dirname, where="latent_moe.export_latent_model")
+    return dirname

@@ -31,4 +31,5 @@ from paddle_tpu.ops import (  # noqa: F401
     fused_ops,
     ssm_ops,
     moe_ops,
+    mla_ops,
 )

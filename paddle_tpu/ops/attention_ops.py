@@ -967,25 +967,25 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # XLA), so tests opt in via PADDLE_TPU_PAGED_INTERPRET=1 instead.
 # ---------------------------------------------------------------------------
 
-def _paged_cache_update(kc, vc, k, v, page_table, lens):
-    """Scatter this step's K/V row into each live slot's tail page.
+def _paged_cache_update(pools, rows, page_table, lens):
+    """Scatter this step's row of every pool (K and V, or the one
+    latent row) into each live slot's tail page.
 
     ``lens`` [S, 1] counts rows INCLUDING the token being decoded, so the
     write lands at position ``lens-1``; ``lens == 0`` marks a free slot
     and maps to an out-of-range page that ``mode="drop"`` discards —
     zero-filled warmup feeds therefore write nothing.
     """
-    NP, PL, _ = kc.shape
+    NP, PL, _ = pools[0].shape
     last = lens[:, 0] - 1
     idx = jnp.clip(last, 0)
     page = jnp.take_along_axis(page_table, (idx // PL)[:, None], axis=1)[:, 0]
     page = jnp.where(last >= 0, page, NP)
     row = idx % PL
-    kc = kc.at[page, row].set(k.reshape(k.shape[0], -1).astype(kc.dtype),
-                              mode="drop")
-    vc = vc.at[page, row].set(v.reshape(v.shape[0], -1).astype(vc.dtype),
-                              mode="drop")
-    return kc, vc
+    return tuple(
+        pool.at[page, row].set(x.reshape(x.shape[0], -1).astype(pool.dtype),
+                               mode="drop")
+        for pool, x in zip(pools, rows))
 
 
 def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
@@ -1039,7 +1039,9 @@ def _paged_blocking(P, PL, HDkv, itemsize, grouped, block_pages=None):
     and stays within ``_PAGED_CHUNK_ROWS`` (``_PAGED_GROUPED_CHUNK_ROWS``
     where query heads share a K/V head)."""
     if block_pages is None:
+        # a power of two: 32 pages of the 576-wide latent row, not 56
         block_pages = _PAGED_BLOCK_BYTES // (PL * HDkv * itemsize)
+        block_pages = 1 << max(block_pages.bit_length() - 1, 0)
     block_pages = max(1, min(int(block_pages), P))
     rows = _PAGED_GROUPED_CHUNK_ROWS if grouped else _PAGED_CHUNK_ROWS
     chunk_pages = max(d for d in range(1, block_pages + 1)
@@ -1047,10 +1049,9 @@ def _paged_blocking(P, PL, HDkv, itemsize, grouped, block_pages=None):
     return block_pages, chunk_pages * PL
 
 
-def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         kbuf, vbuf, sems, ahead_ref, qs_ref, m_ref, l_ref,
-                         acc_ref, *, page_len, block_pages, chunk_rows,
-                         head_unroll, scale):
+def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
+                         block_pages, chunk_rows, head_unroll, scale,
+                         latent=False):
     """One slot of the grid: the online softmax over the slot's LIVE rows.
 
     The pools stay in HBM.  A slot makes ``cdiv(lens, block rows)`` trips
@@ -1067,12 +1068,31 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
     + lane reduction and its PV product a sublane reduction, as before;
     grouped query heads share their K/V head's rows in two float32
     ``HIGHEST`` products ``[G, D] x [D, rows]`` and
-    ``[G, rows] x [rows, D]``."""
+    ``[G, rows] x [rows, D]``.
+
+    ``latent``: ONE pool, whose row is the key of all ``H`` heads and
+    whose leading ``Dv`` lanes (``acc_ref``'s width) are their value too
+    (``paged_attention_latent``).  The row is copied once and both
+    products read it from the buffer, in the pool's own type with
+    float32 accumulation, one MXU pass each: ``[H, Dk] x [Dk, rows]``
+    and, the weights rounded to that type, ``[H, rows] x [rows, Dv]``
+    (121 FLOP a cached byte at 64 heads: two ``HIGHEST`` float32
+    products, six passes each, would bound the kernel by the MXU at a
+    third of the chip's bandwidth)."""
+    if latent:
+        k_hbm, o_ref, kbuf, sems, ahead_ref, qs_ref, m_ref, l_ref, \
+            acc_ref = refs
+        vbuf, pools = kbuf, ((k_hbm, kbuf),)
+    else:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sems, ahead_ref, qs_ref, m_ref, \
+            l_ref, acc_ref = refs
+        pools = ((k_hbm, kbuf), (v_hbm, vbuf))
     s = pl.program_id(0)
     S, P = pt_ref.shape
     PL, CR = page_len, chunk_rows
     BR = block_pages * PL
     H, D = qs_ref.shape
+    Dv = acc_ref.shape[-1]
     G = H // (kbuf.shape[-1] // D)
     f32 = jnp.float32
 
@@ -1087,8 +1107,7 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         def page(j, _):
             src = pt_ref[slot, blk * block_pages + j]
             dst = pl.ds(pl.multiple_of(j * PL, PL), PL)
-            for x, (hbm, buffer) in enumerate(((k_hbm, kbuf),
-                                               (v_hbm, vbuf))):
+            for x, (hbm, buffer) in enumerate(pools):
                 copy = pltpu.make_async_copy(
                     hbm.at[src], buffer.at[buf, dst], sems.at[buf, x])
                 copy.start() if start else copy.wait()
@@ -1106,9 +1125,27 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         def head(g):
             hs = pl.ds(pl.multiple_of(g * G, G), G)
             kv = pl.ds(pl.multiple_of(g * D, D), D)
+            m_prev = m_ref[hs, :]                        # [G, Dv]
+            if latent:
+                k = kbuf[buf, rows, :]                   # [CR, Dk]
+                sc = jax.lax.dot_general(
+                    qs_ref[...], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=f32) * scale  # [H, CR]
+                sc = jnp.where(live, sc, NEG_INF)
+                m_new = jnp.maximum(
+                    m_prev, jnp.max(sc, axis=1, keepdims=True))
+                e = jnp.exp(sc - m_new[:, :1])
+                e_sum = jnp.sum(e, axis=1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    e.astype(k.dtype), k[:, :Dv], (((1,), (0,)), ((), ())),
+                    preferred_element_type=f32)          # [H, Dv]
+                alpha = jnp.exp(m_prev - m_new)
+                l_ref[...] = l_ref[...] * alpha + e_sum
+                acc_ref[...] = acc_ref[...] * alpha + pv
+                m_ref[...] = m_new
+                return
             k = kbuf[buf, rows, kv].astype(f32)          # [CR, D]
             v = vbuf[buf, rows, kv].astype(f32)
-            m_prev = m_ref[hs, :]                        # [G, D]
             if G == 1:
                 sc = jnp.sum(qs_ref[hs, :] * k, axis=1, keepdims=True)
                 sc = jnp.where(live, sc, NEG_INF)        # [CR, 1]
@@ -1169,7 +1206,8 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         nxt = jax.lax.fori_loop(
             s + 1, S, lambda i, at: jnp.where(
                 (at == S) & (lens_ref[i, 0] > 0), i, at), S)
-        qs_ref[...] = q_ref[0].astype(f32) * scale
+        # the latent form scales the float32 scores, not the stored query
+        qs_ref[...] = q_ref[0] if latent else q_ref[0].astype(f32) * scale
         m_ref[...] = jnp.full_like(m_ref, _M_INIT)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -1202,33 +1240,46 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
 
 
-def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4):
+def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4,
+                     v_width=None):
     """Shape gate of the paged kernel: heads must split Q's ``H*D``
     evenly and whole K/V heads the pool's row, the query heads divide
     evenly over them, and on the chip a head must cover whole 128-lane
     vregs and a page whole sublane tiles of the pool's type (8 rows of
     float32, 16 of bfloat16): the kernel slices refs at ``h*D`` lanes
-    and copies a page to ``j*PL`` rows."""
+    and copies a page to ``j*PL`` rows.  The latent form (``v_width``:
+    one row that is every head's key) copies and reads its row whole:
+    the row and the value's lanes, its head, must both be whole vregs."""
     if HD % n_head:
         return False
     D = HD // n_head
     HDkv = HD if HDkv is None else HDkv
     if HDkv % D or n_head % (HDkv // D):
         return False
+    if v_width is not None:
+        if HDkv != HD // n_head or not 0 < v_width <= HDkv:
+            return False
+        # the row is copied whole: a 576-wide row is 4.5 vregs, which
+        # the chip's DMA refuses
+        return interpret or not (v_width % 128 or HDkv % 128
+                                 or PL % (32 // itemsize))
     return interpret or not (D % 128 or PL % (32 // itemsize))
 
 
 def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
-                            interpret=False, block_pages=None):
+                            interpret=False, block_pages=None,
+                            v_width=None):
     """Returns None when ``_paged_kernel_ok`` refuses the shape; any
     lowering error past that gate surfaces to the caller.
     ``block_pages`` is for the tests: the kernel reads it from the
-    shapes (``_paged_blocking``)."""
+    shapes (``_paged_blocking``).  ``vc`` None with ``v_width``: the
+    latent form over the one pool ``kc``."""
     P = page_table.shape[1]
     NP, PL, HDkv = kc.shape
     HD = q.shape[-1]
     itemsize = kc.dtype.itemsize
-    if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize):
+    if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize,
+                            v_width):
         return None
     block_pages, chunk_rows = _paged_blocking(
         P, PL, HDkv, itemsize, HDkv != HD, block_pages)
@@ -1238,7 +1289,7 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     return _paged_kernel_call(
         q, kc, vc, page_table, lens, n_head=n_head, scale=scale,
         interpret=interpret, block_pages=block_pages,
-        chunk_rows=chunk_rows, head_unroll=head_unroll)
+        chunk_rows=chunk_rows, head_unroll=head_unroll, v_width=v_width)
 
 
 # inline: the call leaves no trace in the program (the kernel's event keeps
@@ -1246,38 +1297,47 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
 # shapes, share ONE trace and ONE lowering of the kernel's body
 @functools.partial(jax.jit, inline=True, static_argnames=(
     "n_head", "scale", "interpret", "block_pages", "chunk_rows",
-    "head_unroll"))
+    "head_unroll", "v_width"))
 def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
-                       interpret, block_pages, chunk_rows, head_unroll):
+                       interpret, block_pages, chunk_rows, head_unroll,
+                       v_width=None):
     S = page_table.shape[0]
     NP, PL, HDkv = kc.shape
     D = q.shape[-1] // n_head
+    latent = vc is None
+    Dv = v_width if latent else D
+    pools = (kc,) if latent else (kc, vc)
     kernel = functools.partial(_paged_decode_kernel, page_len=PL,
                                block_pages=block_pages,
                                chunk_rows=chunk_rows,
-                               head_unroll=head_unroll, scale=scale)
-    head_rows = pl.BlockSpec((1, n_head, D), lambda s, pt, ln: (s, 0, 0))
+                               head_unroll=head_unroll, scale=scale,
+                               latent=latent)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S,),
-            in_specs=[head_rows, pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=head_rows,
+            in_specs=[pl.BlockSpec((1, n_head, D),
+                                   lambda s, pt, ln: (s, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((1, n_head, Dv),
+                                   lambda s, pt, ln: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, block_pages * PL, HDkv), kc.dtype),
-                pltpu.VMEM((2, block_pages * PL, HDkv), vc.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((2, block_pages * PL, HDkv), pool.dtype)
+                for pool in pools] + [
+                pltpu.SemaphoreType.DMA((2, len(pools))),
                 pltpu.SMEM((2,), jnp.int32),
-            ] + [pltpu.VMEM((n_head, D), jnp.float32)] * 4,
+                # the query: scaled float32, or the latent form's as it is
+                pltpu.VMEM((n_head, D), kc.dtype if latent
+                           else jnp.float32),
+            ] + [pltpu.VMEM((n_head, Dv), jnp.float32)] * 3,
         ),
-        out_shape=jax.ShapeDtypeStruct((S, n_head, D), kc.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, n_head, Dv), kc.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, lens, q.reshape(S, n_head, D).astype(kc.dtype), kc, vc)
-    return out.reshape(q.shape).astype(q.dtype)
+    )(page_table, lens, q.reshape(S, n_head, D).astype(kc.dtype), *pools)
+    return out.reshape(q.shape[:-1] + (n_head * Dv,)).astype(q.dtype)
 
 
 def _paged_kernel_enabled(interpret):
@@ -1320,7 +1380,7 @@ def paged_attention_lower(ctx: LowerContext):
     lens = ctx.input("Lens")
     n_head = int(ctx.attr("n_head", 1))
     scale = float(ctx.attr("scale", 1.0))
-    kc, vc = _paged_cache_update(kc, vc, k, v, pt, lens)
+    kc, vc = _paged_cache_update((kc, vc), (k, v), pt, lens)
     out = None
     interpret = _use_interpret()
     if _paged_kernel_enabled(interpret):
@@ -1336,6 +1396,75 @@ def paged_attention_lower(ctx: LowerContext):
     ctx.set_output("Out", out)
     ctx.set_output("KCacheOut", kc)
     ctx.set_output("VCacheOut", vc)
+
+
+# ---------------------------------------------------------------------------
+# paged_attention_latent IR op: the decode step of multi-head latent
+# attention (``ops/mla_ops.py``) over ONE pool whose row a token is
+# ``[c_kv | k_rope]``: the key of every head and, in its leading
+# ``v_width`` lanes, their value.  The query arrives absorbed
+# (``mla_absorb``), the context leaves in the latent.  Same page table,
+# same walk of the live pages, same kernel (``latent=True``); the row is
+# stored and copied once.
+# ---------------------------------------------------------------------------
+
+def _xla_latent_attention(q, cache, page_table, lens, n_head, v_width,
+                          scale):
+    """Gather-based fallback of the latent form, float32."""
+    S, P = page_table.shape
+    NP, PL, W = cache.shape
+    T = P * PL
+    rows = cache[page_table].reshape(S, T, W).astype(jnp.float32)
+    qh = q.reshape(S, n_head, W).astype(jnp.float32)
+    sc = jnp.einsum("shw,stw->sht", qh, rows,
+                    preferred_element_type=jnp.float32) * scale
+    col = jax.lax.broadcasted_iota(jnp.int32, (S, 1, T), 2)
+    probs = jax.nn.softmax(jnp.where(col < lens[:, :, None], sc, NEG_INF),
+                           axis=-1)
+    out = jnp.einsum("sht,stv->shv", probs, rows[..., :v_width],
+                     preferred_element_type=jnp.float32)
+    # a free slot reads zeros, as the kernel writes them
+    out = jnp.where(lens[:, :, None] > 0, out, 0.0)
+    return out.reshape(q.shape[:-1] + (n_head * v_width,)).astype(q.dtype)
+
+
+def _infer_paged_latent(op, block):
+    q = block.var(op.input("Q")[0])
+    out = block.var(op.output("Out")[0])
+    if q.shape is None:
+        raise ShapeInferenceSkip()
+    out.shape = tuple(q.shape[:-1]) + (
+        int(op.attr("n_head")) * int(op.attr("v_width")),)
+    out.dtype = q.dtype
+
+
+@register_op("paged_attention_latent", infer_shape=_infer_paged_latent,
+             no_gradient=True, stateful_outputs=("CacheOut",))
+def paged_attention_latent_lower(ctx: LowerContext):
+    """Q: [S, 1, H*W] the absorbed queries (W = the pool's row width);
+    Row: [S, 1, W] this step's latent row; Cache: [num_pages, page_len,
+    W] persistable pool; PageTable, Lens as ``paged_attention``.  Out:
+    [S, 1, H*v_width], the context in the latent; CacheOut names the
+    cache var itself.  attrs: n_head, v_width, scale."""
+    q = ctx.input("Q")
+    pt, lens = ctx.input("PageTable"), ctx.input("Lens")
+    n_head, v_width = int(ctx.attr("n_head")), int(ctx.attr("v_width"))
+    scale = float(ctx.attr("scale", 1.0))
+    cache, = _paged_cache_update((ctx.input("Cache"),), (ctx.input("Row"),),
+                                 pt, lens)
+    out = None
+    interpret = _use_interpret()
+    if _paged_kernel_enabled(interpret):
+        out = _pallas_paged_attention(q, cache, None, pt, lens, n_head,
+                                      scale, interpret=interpret,
+                                      v_width=v_width)
+    if out is None:
+        from paddle_tpu.profiler import runtime_metrics
+        runtime_metrics.inc("gen.paged.fallback")
+        out = _xla_latent_attention(q, cache, pt, lens, n_head, v_width,
+                                    scale)
+    ctx.set_output("Out", out)
+    ctx.set_output("CacheOut", cache)
 
 
 # ---------------------------------------------------------------------------

@@ -1,0 +1,265 @@
+"""Multi-head latent attention (``models/latent_moe.py``): a rotary
+embedding with YaRN-scaled frequencies on a slice of each head, the
+gated feed-forward's activation, and the two forms of the attention
+itself over ONE compressed row a token a layer, ``[c_kv | k_rope]``.
+
+``rope`` rotates the trailing ``rope_dim`` lanes of every head by the
+row's position; pair ``i`` of a head's slice is lanes ``(i, i +
+rope_dim / 2)`` and turns by ``pos * f_i``.  ``yarn_frequencies`` gives
+``f_i``: between ``theta^(-2i/d)`` and that over ``factor``, blended by
+the linear ramp between the pairs whose wavelengths make ``beta_fast``
+and ``beta_slow`` turns in the original context.
+
+``mla_attention`` is the prefill form: K and V of every head are
+EXPANDED from the latent (``[k_nope | v] = c_kv W_kvb``), causal softmax
+in float32, query rows taken a block at a time.  The decode form keeps
+the latent as it is cached: ``mla_absorb`` folds ``W_kvb``'s key half
+into the query (``side`` ``"q"``: ``[q_nope W_k^T | q_rope]``, 576 wide
+a head) and its value half out of the context (``side`` ``"o"``), and
+``paged_attention_latent`` (``ops/attention_ops.py``) attends over the
+cached rows between the two.
+
+Op scopes on the device trace: ``ptop_rope*``, ``ptop_swiglu*``,
+``ptop_mla_attention*``, ``ptop_mla_absorb*``.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from paddle_tpu.ops.registry import (ShapeInferenceSkip, infer_shape_unary,
+                                     register_op)
+
+NEG_INF = -1e30
+# query rows of one block of the prefill's scores: 64 heads x 512 x 2048
+# float32 scores are 268 MB, where all 2048 rows at once are 1.07 GB
+MLA_QUERY_BLOCK = 512
+
+
+# ---------------------------------------------------------------------------
+# rotary embedding, YaRN frequencies
+# ---------------------------------------------------------------------------
+
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention temperature ``0.1 mscale ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(dim, theta, factor=1.0, original_max=4096,
+                     beta_fast=32.0, beta_slow=1.0):
+    """The ``dim / 2`` rotary frequencies (radians a position), float64
+    numpy.  ``factor`` <= 1 gives the plain ``theta^(-2i/dim)``."""
+    half = dim // 2
+    plain = theta ** (-np.arange(half, dtype=np.float64) * 2.0 / dim)
+    if factor <= 1:
+        return plain
+
+    def pair_of(turns):
+        # the pair whose wavelength makes ``turns`` turns in original_max
+        return dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(pair_of(beta_fast)), 0)
+    high = min(math.ceil(pair_of(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    # ramp 0: the pair turns fast enough to keep its frequency; 1: it is
+    # interpolated (divided by factor)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def rope(x, pos, n_head, rope_dim, freqs, mscale=1.0):
+    """``x`` [..., n_head * D]; ``pos`` broadcastable to ``x``'s leading
+    axes (int).  Rotates the last ``rope_dim`` lanes of every head;
+    angles, cos and sin in float32.  Returns ``x``'s type."""
+    lead = x.shape[:-1]
+    D = x.shape[-1] // n_head
+    half = rope_dim // 2
+    xh = x.reshape(lead + (n_head, D))
+    keep, a, b = (xh[..., :D - rope_dim],
+                  xh[..., D - rope_dim:D - half].astype(jnp.float32),
+                  xh[..., D - half:].astype(jnp.float32))
+    ang = pos.reshape(lead).astype(jnp.float32)[..., None, None] \
+        * jnp.asarray(freqs, jnp.float32)
+    cos, sin = jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
+    out = jnp.concatenate(
+        [keep, (a * cos - b * sin).astype(x.dtype),
+         (b * cos + a * sin).astype(x.dtype)], axis=-1)
+    return out.reshape(x.shape)
+
+
+@register_op("rope", infer_shape=infer_shape_unary(),
+             no_grad_inputs=("Pos",))
+def rope_lower(ctx):
+    """X [..., n_head * D]; Pos int32, one per row of X (any shape with
+    as many elements as X's leading axes).  attrs n_head, rope_dim,
+    theta, factor, original_max, beta_fast, beta_slow, mscale (cos and
+    sin are scaled by it)."""
+    x = ctx.input("X")
+    rope_dim = int(ctx.attr("rope_dim"))
+    freqs = yarn_frequencies(
+        rope_dim, float(ctx.attr("theta", 10000.0)),
+        float(ctx.attr("factor", 1.0)), int(ctx.attr("original_max", 4096)),
+        float(ctx.attr("beta_fast", 32.0)), float(ctx.attr("beta_slow", 1.0)))
+    ctx.set_output("Out", rope(x, ctx.input("Pos").reshape(x.shape[:-1]),
+                               int(ctx.attr("n_head", 1)), rope_dim, freqs,
+                               float(ctx.attr("mscale", 1.0))))
+
+
+# ---------------------------------------------------------------------------
+# gated feed-forward activation
+# ---------------------------------------------------------------------------
+
+def swiglu(gate, up):
+    """``silu(gate) * up`` in float32, result in ``gate``'s type."""
+    return (jax.nn.silu(gate.astype(jnp.float32))
+            * up.astype(jnp.float32)).astype(gate.dtype)
+
+
+@register_op("swiglu", infer_shape=infer_shape_unary())
+def swiglu_lower(ctx):
+    """X (the gate's pre-activation), Y (the up projection), alike."""
+    ctx.set_output("Out", swiglu(ctx.input("X"), ctx.input("Y")))
+
+
+# ---------------------------------------------------------------------------
+# latent attention: prefill (expanded) and the absorbed projections
+# ---------------------------------------------------------------------------
+
+def _split_kvb(w_kvb, n_head, nope, v_dim):
+    """``W_kvb`` [L, H * (nope + v)] -> ``W_k`` [L, H, nope], ``W_v``
+    [L, H, v]."""
+    w = w_kvb.reshape(w_kvb.shape[0], n_head, nope + v_dim)
+    return w[..., :nope], w[..., nope:]
+
+
+def mla_attention(q, latent, w_kvb, mask, n_head, nope, rope_dim, v_dim,
+                  scale, block=MLA_QUERY_BLOCK, flash=None, interpret=None):
+    """``q`` [T, H * (nope + rope)] (rotated); ``latent`` [T, >= L +
+    rope] (``c_kv`` after its norm | the rotated shared key | lanes that
+    pad the cached row, not read); ``mask`` [T] (0 = pad row, never
+    attended).  Returns [T, H * v] in ``q``'s type.
+
+    On the TPU (``flash``) the causal flash kernel of
+    ``ops/attention_ops.py`` takes the expanded heads (keys 192 wide,
+    values 128: it never asked them to be alike) and skips the blocks
+    above the diagonal; elsewhere, and where its gate refuses the
+    length, plain XLA a block of query rows at a time."""
+    from paddle_tpu.ops import attention_ops
+    if interpret is None:
+        interpret = attention_ops._use_interpret()
+    T, L = q.shape[0], w_kvb.shape[0]
+    w_k, w_v = _split_kvb(w_kvb, n_head, nope, v_dim)
+    c_kv, k_rope = latent[:, :L], latent[:, L:L + rope_dim]
+    k_nope = jnp.einsum("tl,lhd->thd", c_kv, w_k,
+                        preferred_element_type=jnp.float32).astype(q.dtype)
+    v = jnp.einsum("tl,lhd->thd", c_kv, w_v,
+                   preferred_element_type=jnp.float32).astype(q.dtype)
+    qh = q.reshape(T, n_head, nope + rope_dim)
+    if not interpret if flash is None else flash:
+        heads = lambda a: a.transpose(1, 0, 2)[None]      # [1, H, T, D]
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, None], (T, n_head, rope_dim))], axis=-1)
+        out = attention_ops._pallas_attention(
+            heads(qh), heads(k), heads(v), mask[None].astype(jnp.float32),
+            True, scale, interpret=interpret)
+        if out is not None:
+            return out[0][0].transpose(1, 0, 2).reshape(T, n_head * v_dim)
+    block = min(int(block), T)
+    while T % block:
+        block //= 2
+    seen_col = (mask > 0)[None, :]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (block, T), 1)
+
+    def rows(i):
+        qb = jax.lax.dynamic_slice_in_dim(qh, i * block, block, 0)
+        sc = jnp.einsum("qhd,thd->hqt", qb[..., :nope], k_nope,
+                        preferred_element_type=jnp.float32) \
+            + jnp.einsum("qhd,td->hqt", qb[..., nope:], k_rope,
+                         preferred_element_type=jnp.float32)
+        row = i * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, T), 0)
+        seen = (cols <= row) & seen_col
+        probs = jax.nn.softmax(jnp.where(seen, sc * scale, NEG_INF), axis=-1)
+        return jnp.einsum("hqt,thd->qhd", probs.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    out = jax.lax.map(rows, jnp.arange(T // block))
+    return out.reshape(T, n_head * v_dim)
+
+
+def _infer_mla_attention(op, block):
+    q = block.var(op.input("Q")[0])
+    if q.shape is None:
+        raise ShapeInferenceSkip()
+    out = block.var(op.output("Out")[0])
+    out.shape = tuple(q.shape[:-1]) + (
+        int(op.attr("n_head")) * int(op.attr("v_dim")),)
+    out.dtype = q.dtype
+
+
+@register_op("mla_attention", infer_shape=_infer_mla_attention,
+             no_grad_inputs=("Mask",))
+def mla_attention_lower(ctx):
+    """Q [1, T, H * (nope + rope)]; Latent [1, T, >= L + rope]; Wkvb [L,
+    H * (nope + v)]; Mask [1, T].  attrs n_head, nope_dim, rope_dim,
+    v_dim, scale.  Out [1, T, H * v]."""
+    out = mla_attention(
+        ctx.input("Q")[0], ctx.input("Latent")[0], ctx.input("Wkvb"),
+        ctx.input("Mask")[0], int(ctx.attr("n_head")),
+        int(ctx.attr("nope_dim")), int(ctx.attr("rope_dim")),
+        int(ctx.attr("v_dim")), float(ctx.attr("scale", 1.0)))
+    ctx.set_output("Out", out[None])
+
+
+def mla_absorb(x, w_kvb, n_head, nope, v_dim, side, pad=0):
+    """``side`` ``"q"``: ``x`` [R, H * (nope + rope)] -> [R, H * (L +
+    rope + pad)], each head's ``q_nope`` taken through ``W_k^T`` into
+    the latent, its rotary part kept behind it, then ``pad`` zero lanes
+    (the cached row's).  ``side`` ``"o"``: ``x`` [R, H * L] (the context
+    in the latent) -> [R, H * v] through ``W_v``."""
+    R, L = x.shape[0], w_kvb.shape[0]
+    w_k, w_v = _split_kvb(w_kvb, n_head, nope, v_dim)
+    if side == "o":
+        out = jnp.einsum("rhl,lhd->rhd", x.reshape(R, n_head, L), w_v,
+                         preferred_element_type=jnp.float32)
+        return out.astype(x.dtype).reshape(R, n_head * v_dim)
+    xh = x.reshape(R, n_head, -1)
+    lat = jnp.einsum("rhd,lhd->rhl", xh[..., :nope], w_k,
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    zeros = jnp.zeros((R, n_head, int(pad)), x.dtype)
+    return jnp.concatenate([lat, xh[..., nope:], zeros],
+                           axis=-1).reshape(R, -1)
+
+
+def _infer_mla_absorb(op, block):
+    x = block.var(op.input("X")[0])
+    w = block.var(op.input("Wkvb")[0])
+    if x.shape is None or w.shape is None:
+        raise ShapeInferenceSkip()
+    H, nope = int(op.attr("n_head")), int(op.attr("nope_dim"))
+    if op.attr("side") == "o":
+        width = H * int(op.attr("v_dim"))
+    else:
+        width = int(x.shape[-1]) + H * (
+            int(w.shape[0]) - nope + int(op.attr("pad") or 0))
+    out = block.var(op.output("Out")[0])
+    out.shape, out.dtype = tuple(x.shape[:-1]) + (width,), x.dtype
+
+
+@register_op("mla_absorb", infer_shape=_infer_mla_absorb)
+def mla_absorb_lower(ctx):
+    """X [..., width]; Wkvb [L, H * (nope + v)].  attrs n_head,
+    nope_dim, v_dim, side ("q" | "o"), pad (side "q")."""
+    x = ctx.input("X")
+    out = mla_absorb(x.reshape(-1, x.shape[-1]), ctx.input("Wkvb"),
+                     int(ctx.attr("n_head")), int(ctx.attr("nope_dim")),
+                     int(ctx.attr("v_dim")), str(ctx.attr("side")),
+                     int(ctx.attr("pad", 0)))
+    ctx.set_output("Out", out.reshape(x.shape[:-1] + out.shape[-1:]))

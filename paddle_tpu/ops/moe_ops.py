@@ -18,6 +18,18 @@ applied before the second product, which contracts experts and hidden
 units together.  Its second output counts what landed:
 ``[assignments, distinct experts touched, largest load of one expert]``.
 
+``moe_experts_gated`` is the same layer for GATED experts of three
+matrices, ``W_d (silu(W_g x) * W_u x)``, as a ROUTED product: the
+assignments that landed here are sorted by expert and each expert's rows
+go through its own matrices and no others (a grouped matrix product,
+``jax.experimental.pallas.ops.tpu.megablox.gmm``: a Pallas kernel whose
+grid covers the row tiles that hold a group and reads the matrices of
+the experts that have a row).  Exact: no capacity, no token dropped; an
+expert with no row is not read.  Off the TPU, and in a training graph
+(the routed form has no reverse mode), it is the dense product above
+with the gate (``routed=True`` takes the kernel in interpret mode, for
+the tests).
+
 The executor's op scope names them ``ptop_moe_route*`` /
 ``ptop_moe_experts*`` on the device trace.
 """
@@ -56,14 +68,119 @@ def moe_experts(u, idx, weights, w1, w2, expert_offset=0, live=None):
         here = here & live[:, None, None]
     combine = jnp.sum(jnp.where(here, weights[..., None], 0.0), axis=1)
     load = jnp.sum(here, axis=(0, 1), dtype=jnp.int32)  # [E]
-    stats = jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32),
-                       jnp.max(load)])
+    stats = _load_stats(load)
     h = jnp.einsum("tl,elf->etf", u, w1,
                    preferred_element_type=jnp.float32)
     h = jnp.square(jnp.maximum(h, 0.0)) * combine.T[:, :, None]
     out = jnp.einsum("etf,efl->tl", h.astype(u.dtype), w2,
                      preferred_element_type=jnp.float32)
     return out.astype(u.dtype), stats
+
+
+# Row tile of the grouped product: a row tile holds rows of one or more
+# experts and each expert in it reads its matrices whole, so the tile is
+# as small as the MXU's 128 rows.  Contraction and output tiles of 1024
+# stream an expert's matrix in 2 MB blocks.
+_GMM_ROW_TILE = 128
+_GMM_TILE = 1024
+# Sorted rows taken at a time: a 2048-row prompt's 16384 assignments put
+# ~512 rows on 12 of 384 experts, one chunk; all of them at once cost 8.1
+# ms a layer where the experts' read takes 1.3 (my chip run, PR 31)
+_GMM_CHUNK_ROWS = 512
+
+
+def _load_stats(load):
+    return jnp.stack([jnp.sum(load), jnp.sum(load > 0, dtype=jnp.int32),
+                      jnp.max(load)])
+
+
+def _tile(n, want):
+    """The largest multiple of 128 that divides ``n`` and is at most
+    ``want`` (``n`` itself where none does)."""
+    for t in range(min(want, n) // 128 * 128, 0, -128):
+        if n % t == 0:
+            return t
+    return n
+
+
+def moe_experts_gated(x, idx, weights, wg, wu, wd, expert_offset=0,
+                      live=None, routed=None, interpret=None):
+    """``x`` [T, d]; ``idx``/``weights`` [T, k]; ``wg``, ``wu`` [E, d, F];
+    ``wd`` [E, F, d]; ``live`` [T] bool.  Returns ``out`` [T, d] in
+    ``x``'s type (``sum_i w_i W_d^i (silu(W_g^i x) * W_u^i x)`` over the
+    assignments to the E experts held) and ``stats`` [3] int32."""
+    if interpret is None:
+        from paddle_tpu.ops.attention_ops import _use_interpret
+        interpret = _use_interpret()
+    E, d, F = wg.shape
+    T, k = idx.shape
+    local = idx.astype(jnp.int32) - int(expert_offset)
+    held = (local >= 0) & (local < E)
+    if live is not None:
+        held = held & live[:, None]
+    if not (not interpret if routed is None else routed):
+        here = held[..., None] & (local[..., None] == jnp.arange(E))
+        combine = jnp.sum(jnp.where(here, weights[..., None], 0.0), axis=1)
+        stats = _load_stats(jnp.sum(here, axis=(0, 1), dtype=jnp.int32))
+        g = jnp.einsum("td,edf->etf", x, wg,
+                       preferred_element_type=jnp.float32)
+        u = jnp.einsum("td,edf->etf", x, wu,
+                       preferred_element_type=jnp.float32)
+        h = jax.nn.silu(g) * u * combine.T[:, :, None]
+        out = jnp.einsum("etf,efd->td", h.astype(x.dtype), wd,
+                         preferred_element_type=jnp.float32)
+        return out.astype(x.dtype), stats
+
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    A = T * k
+    tm = _GMM_ROW_TILE if A >= _GMM_ROW_TILE else -(-A // 16) * 16
+    chunk = min(_GMM_CHUNK_ROWS, -(-A // tm) * tm)
+    # assignments sorted by the expert held here; the others last, in
+    # no group.  The sorted rows are taken ``chunk`` at a time and only
+    # the chunks that hold a row of a group are computed: the work
+    # follows the assignments that LANDED here, not ``T x k``
+    key = jnp.where(held, local, E).reshape(A)
+    # ONE sort carries each assignment's token and weight along (a
+    # gather by the order, and a scatter for the loads, cost more than
+    # the sort at 16384 assignments)
+    _, tok, w_sorted = jax.lax.sort(
+        (key, jnp.arange(A, dtype=jnp.int32) // k,
+         jnp.where(held, weights, 0.0).reshape(A)), num_keys=1)
+    sizes = jnp.sum(key[:, None] == jnp.arange(E, dtype=key.dtype),
+                    axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts, n_held = ends - sizes, ends[-1]
+    pad = -(-A // chunk) * chunk - A
+    tok, w_sorted = jnp.pad(tok, (0, pad)), jnp.pad(w_sorted, (0, pad))
+
+    def product(lhs, rhs, group_sizes):
+        kk, n = rhs.shape[1:]
+        return gmm(lhs, rhs, group_sizes,
+                   preferred_element_type=jnp.float32,
+                   tiling=(tm, _tile(kk, _GMM_TILE), _tile(n, _GMM_TILE)),
+                   interpret=interpret)
+
+    def one_chunk(c, out):
+        at = c * chunk
+        rows_of = jax.lax.dynamic_slice_in_dim(tok, at, chunk)
+        w = jax.lax.dynamic_slice_in_dim(w_sorted, at, chunk)
+        here = jnp.clip(jnp.minimum(ends, at + chunk)
+                        - jnp.maximum(starts, at), 0)
+        # a row outside every group is never written by the kernel
+        in_group = (jnp.arange(chunk) < n_held - at)[:, None]
+        rows = x[rows_of]
+        h = jnp.where(in_group, jax.nn.silu(product(rows, wg, here))
+                      * product(rows, wu, here), 0.0).astype(x.dtype)
+        y = jnp.where(in_group, product(h, wd, here), 0.0) * w[:, None]
+        return out.at[rows_of].add(y)
+
+    # as many trips as chunks hold a row (a branch in a fixed number of
+    # trips copied the [T, d] sum every trip: 5.0 ms a layer at 2048
+    # rows).  A loop to a traced bound has no reverse mode: a training
+    # graph takes the dense form (``moe_experts_gated_lower``)
+    out = jax.lax.fori_loop(0, -(-n_held // chunk), one_chunk,
+                            jnp.zeros((T, d), jnp.float32))
+    return out.astype(x.dtype), _load_stats(sizes)
 
 
 def _rows(x):
@@ -120,5 +237,24 @@ def moe_experts_lower(ctx):
         _rows(x), _rows(idx), _rows(w), ctx.input("W1"), ctx.input("W2"),
         int(ctx.attr("expert_offset", 0)),
         None if lens is None else lens.reshape(-1) > 0)
+    ctx.set_output("Out", out.reshape(x.shape))
+    ctx.set_output("Stats", stats[None])
+
+
+@register_op("moe_experts_gated", infer_shape=_infer_experts,
+             no_grad_inputs=("TopkIdx", "Lens"),
+             stop_gradient_outputs=("Stats",))
+def moe_experts_gated_lower(ctx):
+    """X [..., d]; TopkIdx, TopkWeight [..., k]; Wg, Wu [E, d, F]; Wd
+    [E, F, d]; Lens [rows, 1] int32, optional.  attr expert_offset.  Out
+    [..., d]; Stats [1, 3] int32, as ``moe_experts``."""
+    x = ctx.input("X")
+    lens = ctx.input("Lens")
+    out, stats = moe_experts_gated(
+        _rows(x), _rows(ctx.input("TopkIdx")), _rows(ctx.input("TopkWeight")),
+        ctx.input("Wg"), ctx.input("Wu"), ctx.input("Wd"),
+        int(ctx.attr("expert_offset", 0)),
+        None if lens is None else lens.reshape(-1) > 0,
+        routed=False if ctx.training else None)
     ctx.set_output("Out", out.reshape(x.shape))
     ctx.set_output("Stats", stats[None])

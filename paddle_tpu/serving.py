@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import json
 import logging
+import queue
 import threading
 import time
 
@@ -588,6 +589,8 @@ class InferenceServer:
 
         self.predictor = None
         self._gen = None          # GenScheduler for generation bundles
+        self._relay = None        # _TokenRelay, made by the first stream
+        self._relay_lock = threading.Lock()
         self.gen_predictor = None
         self._gen_conf = {"admission": str(gen_admission),
                           "queue_size": int(gen_queue_size),
@@ -936,7 +939,15 @@ class InferenceServer:
                 reclamation drill)."""
                 chaos.fire("gen.client.disconnect")
                 data = (json.dumps(obj) + "\n").encode()
-                self.wfile.write(b"%x\r\n" % len(data) + data + b"\r\n")
+                self.wfile.write(b"%x\r\n%b\r\n" % (len(data), data))
+                self.wfile.flush()
+
+            def _write_token(self, token, index):
+                """``_write_chunk({"token": token, "index": index})``,
+                byte for byte, without the encoder: the one chunk a
+                stream writes a decode step."""
+                chaos.fire("gen.client.disconnect")
+                self.wfile.write(_token_chunk(token, index))
                 self.wfile.flush()
 
             def _handle_generate(self, raw):
@@ -1100,11 +1111,15 @@ class InferenceServer:
                     try:
                         # indices continue at resume_from: the monotone
                         # token_index the router/client dedupe on
-                        self._write_chunk({"token": first[1],
-                                           "index": resume_from})
+                        self._write_token(first[1], resume_from)
                         index = resume_from + 1
+                        relayed = True
                         while True:
-                            ev = stream.next_event(timeout=300)
+                            if relayed:
+                                ev, index, relayed = server._token_relay() \
+                                    .relay(stream, self.connection, index)
+                            else:
+                                ev = stream.next_event(timeout=300)
                             if ev is None:
                                 # nobody will consume further tokens:
                                 # release the KV slot too
@@ -1118,8 +1133,7 @@ class InferenceServer:
                                 break
                             kind, value = ev
                             if kind == "token":
-                                self._write_chunk({"token": value,
-                                                   "index": index})
+                                self._write_token(value, index)
                                 index += 1
                             elif kind == "done":
                                 self._write_chunk(
@@ -1310,6 +1324,14 @@ class InferenceServer:
         if self._gen is not None:
             self._gen.abort_streams()
 
+    def _token_relay(self):
+        """The one writer thread of this server's streamed replies, made
+        with the first of them."""
+        with self._relay_lock:
+            if self._relay is None:
+                self._relay = _TokenRelay()
+            return self._relay
+
     def shutdown(self):
         # stop accepting FIRST: closing the batcher while handlers are
         # still arriving would turn their requests into non-retryable
@@ -1319,7 +1341,141 @@ class InferenceServer:
             self._batcher.close()
         if self._gen is not None:
             self._gen.close()
+        with self._relay_lock:
+            relay, self._relay = self._relay, None
+        if relay is not None:
+            relay.close()
         self._server.server_close()
+
+
+def _token_chunk(token, index):
+    """The chunked-transfer frame of the ndjson line ``{"token": token,
+    "index": index}``, as ``json.dumps`` writes it."""
+    data = b'{"token": %d, "index": %d}\n' % (token, index)
+    return b"%x\r\n%b\r\n" % (len(data), data)
+
+
+class _TokenRelay:
+    """One thread that writes the token chunks of every streamed reply.
+
+    With a handler thread a stream, a decode step of S live streams woke
+    S threads, each of which took the interpreter lock twice (to come
+    back from the queue, and to come back from ``send``) in turn with
+    the S readers and the scheduler: under one lock that chain of
+    wake-ups, not anybody's work, was most of a serving turn.  The relay
+    is woken once a step and sends the S chunks in a row; a handler
+    sleeps in :meth:`relay` until its stream's next event is not a token
+    (that event is handed back to it), a write fails (raised in the
+    handler), nothing has come for ``stall_s``, or the socket would
+    block: a reader that has stopped reading gets the rest of its chunk
+    and of its stream from its own handler thread, so it can stall
+    nobody else."""
+
+    class _Job:
+        __slots__ = ("stream", "sock", "index", "last_t", "done", "event",
+                     "error", "unsent")
+
+        def __init__(self, stream, sock, index):
+            self.stream, self.sock, self.index = stream, sock, index
+            self.last_t = time.monotonic()
+            self.done = threading.Event()
+            self.event = self.error = self.unsent = None
+
+    def __init__(self, stall_s=300.0):
+        self._stall_s = stall_s
+        self._wake = queue.SimpleQueue()
+        self._jobs = []
+        self._lock = threading.Lock()
+        self._closed = False
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name="gen-token-relay")
+        self._thread.start()
+
+    def relay(self, stream, sock, index):
+        """Blocks while this stream's token chunks are written, starting
+        at ``index``.  Returns ``(event, index, relayed)``: the stream's
+        next event that is not a token (``None``: stalled) and the index
+        of the next token; ``relayed`` false means the caller writes the
+        stream's remaining chunks itself."""
+        job = self._Job(stream, sock, index)
+        with self._lock:
+            mine = not self._closed and sock.gettimeout() is None
+            if mine:
+                self._jobs.append(job)
+        if not mine:
+            return stream.next_event(timeout=self._stall_s), index, False
+        stream.on_event = self._wake.put
+        self._wake.put(None)        # what was queued before the hook
+        job.done.wait()
+        stream.on_event = None
+        if job.error is not None:
+            raise job.error
+        if job.unsent is not None:
+            sock.sendall(job.unsent)
+            return stream.next_event(timeout=self._stall_s), job.index, \
+                False
+        return job.event, job.index, True
+
+    def close(self):
+        """Every stream still relayed is handed back as stalled; the
+        thread ends once none is left."""
+        with self._lock:
+            self._closed = True
+        self._wake.put(None)
+        self._thread.join(timeout=5)
+
+    def _run(self):
+        import socket
+        from paddle_tpu.fault import chaos
+        nowait = socket.MSG_DONTWAIT
+        while True:
+            try:
+                self._wake.get(timeout=1.0)
+                while True:         # one scan serves a whole step's wakes
+                    self._wake.get_nowait()
+            except queue.Empty:
+                pass
+            with self._lock:
+                jobs = list(self._jobs)
+            finished = []
+            now = time.monotonic()
+            for job in jobs:
+                try:
+                    while True:
+                        ev = job.stream.next_event(timeout=0)
+                        if ev is None:
+                            if self._closed or \
+                                    now - job.last_t > self._stall_s:
+                                finished.append(job)
+                            break
+                        job.last_t = now
+                        if ev[0] != "token":
+                            job.event = ev
+                            finished.append(job)
+                            break
+                        chaos.fire("gen.client.disconnect")
+                        data = _token_chunk(ev[1], job.index)
+                        job.index += 1
+                        try:
+                            sent = job.sock.send(data, nowait)
+                        except BlockingIOError:
+                            sent = 0
+                        if sent < len(data):
+                            job.unsent = data[sent:]
+                            finished.append(job)
+                            break
+                except Exception as e:  # boundary: raised in the handler
+                    job.error = e
+                    finished.append(job)
+            if finished:
+                with self._lock:
+                    for job in finished:
+                        self._jobs.remove(job)
+                for job in finished:
+                    job.done.set()
+            with self._lock:
+                if self._closed and not self._jobs:
+                    return
 
 
 def _history_with_hints(history, hints):
@@ -1335,6 +1491,51 @@ def _history_with_hints(history, hints):
         out.append(base if hint is None
                    else f"{base} retry-after={hint:g}s")
     return out
+
+
+class _ChunkedLines:
+    """``readline()`` over a streamed ndjson reply, off the response's
+    own buffered socket file: for a chunked body one ``readline`` (the
+    chunk's size) and one ``read`` (its bytes) a chunk.
+    ``HTTPResponse.readline`` peeks and reads a chunked body through
+    three layers of Python a line, twice the reader thread's time a
+    token (PERF.md section 6, PR 31).  ``b""`` at the body's end, and where the
+    socket closed between two chunks; a torn chunk raises what the caller
+    takes for a fault of the transport (``OSError``,
+    ``http.client.HTTPException``, ``ValueError``)."""
+
+    def __init__(self, resp):
+        self._resp = resp
+        self._fp = resp.fp      # None once the body has ended
+        self._buf = b""
+
+    def readline(self):
+        if not self._resp.chunked:
+            return self._resp.readline()
+        import http.client
+        while True:
+            end = self._buf.find(b"\n") + 1
+            if end:
+                line, self._buf = self._buf[:end], self._buf[end:]
+                return line
+            if self._fp is None:
+                line, self._buf = self._buf, b""
+                return line
+            head = self._fp.readline(65537)
+            size = int(head.split(b";", 1)[0], 16) if head else 0
+            if size == 0:
+                # the last chunk (then its trailers, up to the blank
+                # line), or the socket closed between two chunks: the
+                # caller says what an end without a terminal event means
+                while head and self._fp.readline(65537) not in (
+                        b"\r\n", b"\n", b""):
+                    pass
+                self._fp = None
+                continue
+            data = self._fp.read(size + 2)
+            if len(data) < size + 2:
+                raise http.client.IncompleteRead(data, size + 2 - len(data))
+            self._buf += data[:size]
 
 
 class ServingClient:
@@ -1650,12 +1851,13 @@ class ServingClient:
             nonlocal conn, resp
             resumes = 0
             resumable = bool(resume) and stream
+            lines = _ChunkedLines(resp)
             try:
                 while True:
                     failure = None
                     obj = None
                     try:
-                        line = resp.readline()
+                        line = lines.readline()
                         if not line:
                             if not resumable:
                                 return      # legacy: silent clean EOF
@@ -1723,6 +1925,7 @@ class ServingClient:
                                "retryable": not isinstance(
                                    e, ServingError)}
                         return
+                    lines = _ChunkedLines(resp)
                     resumes += 1
                     _profiler.runtime_metrics.inc("gen.session.resumes")
             finally:

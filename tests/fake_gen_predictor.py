@@ -10,6 +10,7 @@ class FakeGenPredictor:
     num_slots, vocab_size, max_len = 4, 8, 32
     max_prompt_len, eos_id = 16, -1
     state_vars = ()
+    cache_row_bytes = 4
     last_decode_stats = None
     free_pages = 1 << 20
 

@@ -100,6 +100,7 @@ class TestDisabledTracingOverhead:
         token, seed) — against the same modeled 1 ms step; a real decode
         step on the chip is ten times that."""
         trace.disable()
+        trace.clear()       # what an earlier test of this worker left
 
         def run():
             with trace.span("executor.run"):

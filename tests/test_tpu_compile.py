@@ -265,6 +265,94 @@ def test_hybrid_decode_ops_compile_for_v5e(one_chip):
     assert mem.temp_size_in_bytes < 64 << 20, mem
 
 
+@pytest.mark.parametrize("pages", [1, 32, 256])
+def test_latent_paged_decode_compiles_for_v5e(one_chip, pages):
+    """The decode kernel's latent form at the latent-attention serving
+    cell's shapes: 32 slots, 64 absorbed query heads over ONE bfloat16
+    pool whose 640-wide row (512 latent | 64 rotary | 64 zeros) is every
+    head's key and, in its first 512 lanes, their value; page_len 16,
+    the smallest, a middle and the widest page bucket of the 8192-page
+    pool (32 pages a block, chunks of 512 rows)."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, NP, PL, H, W, V = 32, 8192, 16, 64, 640, 512
+    if pages == 256:
+        assert A._paged_blocking(pages, PL, W, 2, True) == (32, 512)
+
+    def fn(q, cache, pt):
+        out = A._pallas_paged_attention(q, cache, None, pt,
+                                        _ragged_lens(S, pages, PL), H,
+                                        0.1447, interpret=False, v_width=V)
+        assert out is not None, "shape gate refused the latent row"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 1, H * W), jnp.bfloat16),
+                   ((NP, PL, W), jnp.bfloat16), ((S, pages), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_a_576_wide_latent_row_is_refused_by_the_gate_not_the_compiler():
+    """What the gate stands for: the chip's DMA takes whole vregs, so
+    the published 576 values a row are stored 640 wide (the compiler
+    lays a 576-wide array out 640 wide in HBM anyway)."""
+    from paddle_tpu.ops import attention_ops as A
+    assert not A._paged_kernel_ok(64, 64 * 576, 16, False, 576, 2,
+                                  v_width=512)
+    assert A._paged_kernel_ok(64, 64 * 640, 16, False, 640, 2, v_width=512)
+
+
+@pytest.mark.parametrize("rows", [32, 2048])
+def test_routed_gated_experts_compile_for_v5e(one_chip, rows):
+    """The routed product of gated experts (three grouped matrix
+    products, ``megablox.gmm``) at the latent-attention serving cell's
+    shapes: 12 held experts of 7168 x 2048 in bfloat16, a decode step's
+    32 rows and the widest prompt bucket's 2048, top-8 of 384.  No
+    temporary the size of the held experts (1.06 GB): an expert's
+    matrices are read where they lie."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe_ops
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def experts(x, idx, w, wg, wu, wd, live):
+        return moe_ops.moe_experts_gated(x, idx, w, wg, wu, wd, 0, live,
+                                         routed=True, interpret=False)
+
+    compiled = jax.jit(experts).lower(
+        sds((rows, 7168), bf), sds((rows, 8), jnp.int32),
+        sds((rows, 8), jnp.float32), sds((12, 7168, 2048), bf),
+        sds((12, 7168, 2048), bf), sds((12, 2048, 7168), bf),
+        sds((rows,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # one chunk of 512 sorted rows and its products, whatever the rows
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20, \
+        compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("rows", [128, 1024, 2048])
+def test_latent_prefill_attention_compiles_for_v5e(one_chip, rows):
+    """The prefill form of latent attention through the flash kernel at
+    the latent-attention serving cell's shapes: 64 heads expanded from a
+    512-wide latent, keys 192 wide (128 | the shared rotary 64), values
+    128, bfloat16; the smallest, a middle and the widest prompt
+    bucket."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mla_ops
+
+    def fn(q, latent, w_kvb, mask):
+        return mla_ops.mla_attention(q, latent, w_kvb, mask, 64, 128, 64,
+                                     128, 0.1447, flash=True,
+                                     interpret=False)
+
+    hlo = _compile(fn, one_chip, ((rows, 64 * 192), jnp.bfloat16),
+                   ((rows, 640), jnp.bfloat16),
+                   ((512, 64 * 256), jnp.bfloat16), ((rows,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.mark.parametrize("bias", ["row", "causal"])
 def test_fused_softmax_compiles_for_v5e(one_chip, bias):
     import jax.numpy as jnp
