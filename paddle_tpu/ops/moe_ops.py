@@ -11,24 +11,29 @@ the chosen scores, normalised over the chosen and scaled.
 expert_offset + held - 1`` (``held`` = the leading axis of its weights),
 ``sum_i w_i * W2_i . relu(W1_i u)^2`` over the assignments that landed on
 them.  Assignments to experts held elsewhere are dropped, not remapped;
-no token is dropped for capacity.  One product over all held experts
-(no per-expert loop): every token is pushed through every held expert
-and the combine weight, zero where the router did not choose it, is
-applied before the second product, which contracts experts and hidden
-units together.  Its second output counts what landed:
+no token is dropped for capacity.  Its second output counts what landed:
 ``[assignments, distinct experts touched, largest load of one expert]``.
-
 ``moe_experts_gated`` is the same layer for GATED experts of three
-matrices, ``W_d (silu(W_g x) * W_u x)``, as a ROUTED product: the
-assignments that landed here are sorted by expert and each expert's rows
-go through its own matrices and no others (a grouped matrix product,
+matrices, ``W_d (silu(W_g x) * W_u x)``.
+
+Both are ONE algorithm with the expert's body as its parameter (the up
+matrices, the activation that joins their products, the down matrix),
+in two forms.  ROUTED, a served program's on a TPU: the assignments that
+landed here are sorted by expert and each expert's rows go through its
+own matrices and no others (a grouped matrix product,
 ``jax.experimental.pallas.ops.tpu.megablox.gmm``: a Pallas kernel whose
 grid covers the row tiles that hold a group and reads the matrices of
 the experts that have a row).  Exact: no capacity, no token dropped; an
-expert with no row is not read.  Off the TPU, and in a training graph
-(the routed form has no reverse mode), it is the dense product above
-with the gate (``routed=True`` takes the kernel in interpret mode, for
-the tests).
+expert with no row is not read.  DENSE, off the TPU, in a training
+graph (the routed form's loop to a traced bound has no reverse mode) and
+at the row counts where it was measured faster (``_RELU2_DENSE_ROWS``):
+one product over all held experts, every token through every held expert
+with the combine weight, zero where the router did not choose it,
+applied before the down product, which contracts experts and hidden
+units together.  ``routed=True`` takes the kernel in interpret mode, for
+the tests.  Which form a lowering took is counted, once per compiled
+signature: ``gen.moe.routed_lowerings`` / ``gen.moe.dense_lowerings``
+(and ``<counter>.<op>``).
 
 The executor's op scope names them ``ptop_moe_route*`` /
 ``ptop_moe_experts*`` on the device trace.
@@ -36,10 +41,14 @@ The executor's op scope names them ``ptop_moe_route*`` /
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops.mla_ops import swiglu
 from paddle_tpu.ops.registry import ShapeInferenceSkip, register_op
+from paddle_tpu.ops.ssm_ops import relu2
 
 
 def moe_route(x, w_gate, bias, top_k, scaling=1.0, norm_topk=True):
@@ -54,27 +63,6 @@ def moe_route(x, w_gate, bias, top_k, scaling=1.0, norm_topk=True):
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
                              + 1e-20)
     return idx.astype(jnp.int32), weights * float(scaling)
-
-
-def moe_experts(u, idx, weights, w1, w2, expert_offset=0, live=None):
-    """``u`` [T, L]; ``idx``/``weights`` [T, k]; ``w1`` [E, L, F]; ``w2``
-    [E, F, L]; ``live`` [T] bool (rows that are not live have no
-    assignment).  Returns ``out`` [T, L] in ``u``'s type and ``stats``
-    [3] int32."""
-    E = w1.shape[0]
-    here = (idx - int(expert_offset))[..., None] \
-        == jnp.arange(E, dtype=idx.dtype)               # [T, k, E]
-    if live is not None:
-        here = here & live[:, None, None]
-    combine = jnp.sum(jnp.where(here, weights[..., None], 0.0), axis=1)
-    load = jnp.sum(here, axis=(0, 1), dtype=jnp.int32)  # [E]
-    stats = _load_stats(load)
-    h = jnp.einsum("tl,elf->etf", u, w1,
-                   preferred_element_type=jnp.float32)
-    h = jnp.square(jnp.maximum(h, 0.0)) * combine.T[:, :, None]
-    out = jnp.einsum("etf,efl->tl", h.astype(u.dtype), w2,
-                     preferred_element_type=jnp.float32)
-    return out.astype(u.dtype), stats
 
 
 # Row tile of the grouped product: a row tile holds rows of one or more
@@ -103,35 +91,57 @@ def _tile(n, want):
     return n
 
 
-def moe_experts_gated(x, idx, weights, wg, wu, wd, expert_offset=0,
-                      live=None, routed=None, interpret=None):
-    """``x`` [T, d]; ``idx``/``weights`` [T, k]; ``wg``, ``wu`` [E, d, F];
-    ``wd`` [E, F, d]; ``live`` [T] bool.  Returns ``out`` [T, d] in
-    ``x``'s type (``sum_i w_i W_d^i (silu(W_g^i x) * W_u^i x)`` over the
-    assignments to the E experts held) and ``stats`` [3] int32."""
-    if interpret is None:
-        from paddle_tpu.ops.attention_ops import _use_interpret
-        interpret = _use_interpret()
-    E, d, F = wg.shape
-    T, k = idx.shape
+def _count_lowering(form, op):
+    """Which form a lowering took (fires at trace time, once per
+    compiled signature, as ``gen.paged.fallback`` does)."""
+    from paddle_tpu.profiler import runtime_metrics
+    runtime_metrics.inc(f"gen.moe.{form}_lowerings")
+    runtime_metrics.inc(f"gen.moe.{form}_lowerings.{op}")
+
+
+def _held(idx, expert_offset, E, live):
+    """Each assignment's expert counted from the first one held, and
+    whether it landed on one of the ``E`` held by a live row."""
     local = idx.astype(jnp.int32) - int(expert_offset)
     held = (local >= 0) & (local < E)
     if live is not None:
         held = held & live[:, None]
-    if not (not interpret if routed is None else routed):
-        here = held[..., None] & (local[..., None] == jnp.arange(E))
-        combine = jnp.sum(jnp.where(here, weights[..., None], 0.0), axis=1)
-        stats = _load_stats(jnp.sum(here, axis=(0, 1), dtype=jnp.int32))
-        g = jnp.einsum("td,edf->etf", x, wg,
-                       preferred_element_type=jnp.float32)
-        u = jnp.einsum("td,edf->etf", x, wu,
-                       preferred_element_type=jnp.float32)
-        h = jax.nn.silu(g) * u * combine.T[:, :, None]
-        out = jnp.einsum("etf,efd->td", h.astype(x.dtype), wd,
-                         preferred_element_type=jnp.float32)
-        return out.astype(x.dtype), stats
+    return local, held
 
+
+def _dense_experts(x, idx, weights, up, act, down, expert_offset, live):
+    """Every row through every held expert; the combine weight, zero
+    where the router did not choose, is applied before the down
+    product, which contracts experts and hidden units together."""
+    E = down.shape[0]
+    local, held = _held(idx, expert_offset, E, live)
+    here = held[..., None] & (local[..., None] == jnp.arange(E))
+    combine = jnp.sum(jnp.where(here, weights[..., None], 0.0), axis=1)
+    stats = _load_stats(jnp.sum(here, axis=(0, 1), dtype=jnp.int32))
+    h = act(*(jnp.einsum("td,edf->etf", x, w,
+                         preferred_element_type=jnp.float32) for w in up))
+    h = h * combine.T[:, :, None]
+    out = jnp.einsum("etf,efd->td", h.astype(x.dtype), down,
+                     preferred_element_type=jnp.float32)
+    return out.astype(x.dtype), stats
+
+
+# behind a ``jit`` so that a model's layers, and its executables of one
+# row count, share ONE trace of the core, and an executable lowers it
+# once: traced and lowered a layer at a time, thirteen executables of
+# five layers added 9 s to a warm server start (my chip run, PR 32)
+@functools.partial(jax.jit,
+                   static_argnames=("act", "expert_offset", "interpret"))
+def _routed_experts(x, idx, weights, up, act, down, expert_offset, live,
+                    interpret):
+    """The assignments that landed here sorted by expert, each expert's
+    rows through its own matrices and no others (``megablox.gmm``): an
+    expert with no row is not read.  ``up`` the [E, d, F] matrices whose
+    products ``act`` joins into the hidden rows, ``down`` [E, F, d]."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
+    E, d = down.shape[0], x.shape[1]
+    T, k = idx.shape
+    local, held = _held(idx, expert_offset, E, live)
     A = T * k
     tm = _GMM_ROW_TILE if A >= _GMM_ROW_TILE else -(-A // 16) * 16
     chunk = min(_GMM_CHUNK_ROWS, -(-A // tm) * tm)
@@ -169,18 +179,74 @@ def moe_experts_gated(x, idx, weights, wg, wu, wd, expert_offset=0,
         # a row outside every group is never written by the kernel
         in_group = (jnp.arange(chunk) < n_held - at)[:, None]
         rows = x[rows_of]
-        h = jnp.where(in_group, jax.nn.silu(product(rows, wg, here))
-                      * product(rows, wu, here), 0.0).astype(x.dtype)
-        y = jnp.where(in_group, product(h, wd, here), 0.0) * w[:, None]
+        h = jnp.where(in_group, act(*(product(rows, m, here) for m in up)),
+                      0.0).astype(x.dtype)
+        y = jnp.where(in_group, product(h, down, here), 0.0) * w[:, None]
         return out.at[rows_of].add(y)
 
     # as many trips as chunks hold a row (a branch in a fixed number of
     # trips copied the [T, d] sum every trip: 5.0 ms a layer at 2048
     # rows).  A loop to a traced bound has no reverse mode: a training
-    # graph takes the dense form (``moe_experts_gated_lower``)
+    # graph takes the dense form
     out = jax.lax.fori_loop(0, -(-n_held // chunk), one_chunk,
                             jnp.zeros((T, d), jnp.float32))
     return out.astype(x.dtype), _load_stats(sizes)
+
+
+def _experts(op, x, idx, weights, up, act, down, expert_offset, live,
+             routed, interpret, dense_rows=()):
+    """The one switch between the two forms, read from the operands and
+    the platform: ``routed`` None takes the routed form on a TPU, but
+    for ``dense_rows`` (the row counts at which the op's dense form was
+    measured faster), and the dense one off it (the
+    kernel in interpret mode is for the tests); a lowering passes False
+    for a training graph."""
+    if interpret is None:
+        from paddle_tpu.ops.attention_ops import _use_interpret
+        interpret = _use_interpret()
+    if routed is None:
+        routed = not interpret and idx.shape[0] not in dense_rows
+    _count_lowering("routed" if routed else "dense", op)
+    if routed:
+        return _routed_experts(x, idx, weights, up, act, down,
+                               int(expert_offset), live, bool(interpret))
+    return _dense_experts(x, idx, weights, up, act, down, expert_offset,
+                          live)
+
+
+# Rows at which the dense ``relu2`` product beats the routed one on a
+# TPU, measured at 64 held experts of 1024 x 2688 under a top-22 of 512
+# (my chip runs, PR 32; PERF.md section 6): every held expert has a row
+# and the dense product is still bound by the weights' read, so there is
+# nothing to skip and it wins by 0.01-0.08 ms a layer (and an executable
+# without the grouped kernels is 0.35-0.5 s sooner ready).  Under 64 rows a
+# third of the experts have no row (routed 0.85 against 1.07 ms); over
+# 256 the dense product is bound by its FLOPs, 23 times the routed ones
+# (1.35 against 2.06 ms at 512 rows, 1.65 against 3.96 at 1024).  The
+# gated op has no such range: its dense form was slower wherever it was
+# measured (PR 31)
+_RELU2_DENSE_ROWS = range(64, 257)
+
+
+def moe_experts(u, idx, weights, w1, w2, expert_offset=0, live=None,
+                routed=None, interpret=None):
+    """``u`` [T, L]; ``idx``/``weights`` [T, k]; ``w1`` [E, L, F]; ``w2``
+    [E, F, L]; ``live`` [T] bool (rows that are not live have no
+    assignment).  Returns ``out`` [T, L] in ``u``'s type and ``stats``
+    [3] int32."""
+    return _experts("moe_experts", u, idx, weights, (w1,), relu2, w2,
+                    expert_offset, live, routed, interpret,
+                    dense_rows=_RELU2_DENSE_ROWS)
+
+
+def moe_experts_gated(x, idx, weights, wg, wu, wd, expert_offset=0,
+                      live=None, routed=None, interpret=None):
+    """``x`` [T, d]; ``idx``/``weights`` [T, k]; ``wg``, ``wu`` [E, d, F];
+    ``wd`` [E, F, d]; ``live`` [T] bool.  Returns ``out`` [T, d] in
+    ``x``'s type (``sum_i w_i W_d^i (silu(W_g^i x) * W_u^i x)`` over the
+    assignments to the E experts held) and ``stats`` [3] int32."""
+    return _experts("moe_experts_gated", x, idx, weights, (wg, wu),
+                    swiglu, wd, expert_offset, live, routed, interpret)
 
 
 def _rows(x):
@@ -236,7 +302,8 @@ def moe_experts_lower(ctx):
     out, stats = moe_experts(
         _rows(x), _rows(idx), _rows(w), ctx.input("W1"), ctx.input("W2"),
         int(ctx.attr("expert_offset", 0)),
-        None if lens is None else lens.reshape(-1) > 0)
+        None if lens is None else lens.reshape(-1) > 0,
+        routed=False if ctx.training else None)
     ctx.set_output("Out", out.reshape(x.shape))
     ctx.set_output("Stats", stats[None])
 

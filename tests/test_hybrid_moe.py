@@ -209,6 +209,116 @@ def test_moe_experts_drops_absent_experts_and_counts_what_landed():
     assert not np.asarray(out2[2]).any()
 
 
+def _relu2_case(T, offset, spread, E=8, k=6, L=64, F=48, seed=3):
+    """``E`` experts held from ``offset`` on; the router's choices are
+    ``k`` distinct experts of ``spread`` consecutive ones from 0."""
+    key = jax.random.split(jax.random.PRNGKey(seed), 5)
+    u = jax.random.normal(key[0], (T, L))
+    w1 = jax.random.normal(key[1], (E, L, F)) * 0.1
+    w2 = jax.random.normal(key[2], (E, F, L)) * 0.1
+    _, idx = jax.lax.top_k(jax.random.uniform(key[3], (T, spread)), k)
+    wgt = jax.random.uniform(key[4], (T, k)) + 0.5
+    return u, idx.astype(jnp.int32), wgt, w1, w2, offset
+
+
+@pytest.mark.parametrize("case", [
+    "offset_0_decode_rows", "a_middle_share_decode_rows",
+    "offset_0_a_prefill_bucket", "a_middle_share_a_prefill_bucket",
+    "no_assignment_lands_here", "every_held_expert_is_touched"])
+def test_the_routed_relu2_product_is_the_dense_one(case):
+    """``moe_experts`` through the routed core (the grouped kernel in
+    interpret mode) against its dense form and the plain sum over the
+    assignments, with the same ``Stats``; a third of the rows are not
+    live.  40 rows x top-6 are two chunks of sorted rows."""
+    u, idx, wgt, w1, w2, offset = {
+        "offset_0_decode_rows": lambda: _relu2_case(4, 0, 32),
+        "a_middle_share_decode_rows": lambda: _relu2_case(4, 8, 32),
+        "offset_0_a_prefill_bucket": lambda: _relu2_case(40, 0, 32),
+        "a_middle_share_a_prefill_bucket": lambda: _relu2_case(40, 16, 32),
+        # the router chooses among experts 0..15, this share holds 16..23
+        "no_assignment_lands_here": lambda: _relu2_case(4, 16, 16),
+        # it chooses 6 of the 8 held for each of 40 rows
+        "every_held_expert_is_touched": lambda: _relu2_case(40, 0, 8),
+    }[case]()
+    T, E = u.shape[0], w1.shape[0]
+    live = jnp.arange(T) % 3 != 1
+    dense, s0 = moe_ops.moe_experts(u, idx, wgt, w1, w2, offset, live,
+                                    routed=False)
+    routed, s1 = moe_ops.moe_experts(u, idx, wgt, w1, w2, offset, live,
+                                     routed=True)
+    want, load = np.zeros(u.shape, np.float32), np.zeros(E, int)
+    for t in range(T):
+        for e, wt in zip(np.asarray(idx[t]) - offset, np.asarray(wgt[t])):
+            if 0 <= e < E and bool(live[t]):
+                h = np.maximum(np.asarray(u[t] @ w1[e]), 0) ** 2
+                want[t] += wt * np.asarray(h @ w2[e])
+                load[e] += 1
+    np.testing.assert_allclose(routed, want, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(routed, dense, atol=4e-6)
+    assert s0.tolist() == s1.tolist() == [load.sum(), (load > 0).sum(),
+                                          load.max()]
+    assert not np.asarray(routed)[1::3].any()
+    if case == "no_assignment_lands_here":
+        assert s1.tolist() == [0, 0, 0] and not np.asarray(routed).any()
+    if case == "every_held_expert_is_touched":
+        assert s1[1] == E
+
+
+class _Lowering:
+    """What ``moe_experts_lower`` asks of a lower context."""
+
+    def __init__(self, training, **inputs):
+        self.training, self.inputs, self.out = training, inputs, {}
+
+    def input(self, slot):
+        return self.inputs.get(slot)
+
+    def attr(self, name, default=None):
+        return default
+
+    def set_output(self, slot, value):
+        self.out[slot] = value
+
+
+def test_a_training_and_a_cpu_lowering_are_dense_and_counted(monkeypatch):
+    """The routed form is a served program's on a TPU: a training graph
+    (its loop to a traced bound has no reverse mode) and any lowering
+    off the TPU take the dense form, and ``gen.moe.dense_lowerings``
+    says so; decode rows on a TPU go to the routed core."""
+    u, idx, wgt, w1, w2, _ = _relu2_case(4, 0, 32)
+    feeds = dict(X=u, TopkIdx=idx, TopkWeight=wgt, W1=w1, W2=w2)
+    count = profiler.runtime_metrics.counter
+
+    def routed(*a, **kw):
+        raise AssertionError("the routed core")
+    monkeypatch.setattr(moe_ops, "_routed_experts", routed)
+    was = count("gen.moe.dense_lowerings")
+    ctx = _Lowering(False, **feeds)             # served, on the CPU
+    moe_ops.moe_experts_lower(ctx)
+    assert ctx.out["Out"].shape == u.shape and ctx.out["Stats"].shape == (1, 3)
+    assert count("gen.moe.dense_lowerings") == was + 1
+    monkeypatch.setattr(attention_ops, "_use_interpret", lambda: False)
+    moe_ops.moe_experts_lower(_Lowering(True, **feeds))   # trained, "TPU"
+    assert count("gen.moe.dense_lowerings") == was + 2
+    assert count("gen.moe.dense_lowerings.moe_experts") >= 2
+    with pytest.raises(AssertionError, match="the routed core"):
+        moe_ops.moe_experts_lower(_Lowering(False, **feeds))
+    assert count("gen.moe.dense_lowerings") == was + 2
+    assert count("gen.moe.routed_lowerings.moe_experts") >= 1
+    # the row counts at which the dense product was measured faster
+    lo, hi = moe_ops._RELU2_DENSE_ROWS[0], moe_ops._RELU2_DENSE_ROWS[-1]
+    for rows, dense in ((lo - 1, 0), (lo, 1), (hi, 1), (hi + 1, 0)):
+        wide = dict(zip(("X", "TopkIdx", "TopkWeight", "W1", "W2"),
+                        _relu2_case(rows, 0, 32)))
+        was = count("gen.moe.dense_lowerings")
+        if dense:
+            moe_ops.moe_experts_lower(_Lowering(False, **wide))
+        else:
+            with pytest.raises(AssertionError, match="the routed core"):
+                moe_ops.moe_experts_lower(_Lowering(False, **wide))
+        assert count("gen.moe.dense_lowerings") == was + dense
+
+
 def test_moe_route_is_the_references():
     k = jax.random.split(jax.random.PRNGKey(6), 2)
     x, wg = jax.random.normal(k[0], (9, 64)), jax.random.normal(k[1],

@@ -332,6 +332,34 @@ def test_routed_gated_experts_compile_for_v5e(one_chip, rows):
         compiled.memory_analysis()
 
 
+@pytest.mark.parametrize("rows", [32, 1024])
+def test_routed_relu2_experts_compile_for_v5e(one_chip, rows):
+    """``moe_experts`` through the same routed core at the hybrid
+    serving cell's shapes: 64 held experts of 1024 x 2688 in bfloat16, a
+    decode step's 32 rows and the widest prompt bucket's 1024, top-22 of
+    512: two grouped products a layer and no temporary the size of the
+    held experts (0.70 GB)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import moe_ops
+    bf = jnp.bfloat16
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def experts(u, idx, w, w1, w2, live):
+        return moe_ops.moe_experts(u, idx, w, w1, w2, 0, live,
+                                   routed=True, interpret=False)
+
+    compiled = jax.jit(experts).lower(
+        sds((rows, 1024), bf), sds((rows, 22), jnp.int32),
+        sds((rows, 22), jnp.float32), sds((64, 1024, 2688), bf),
+        sds((64, 2688, 1024), bf), sds((rows,), jnp.bool_)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20, \
+        compiled.memory_analysis()
+
+
 @pytest.mark.parametrize("rows", [128, 1024, 2048])
 def test_latent_prefill_attention_compiles_for_v5e(one_chip, rows):
     """The prefill form of latent attention through the flash kernel at
