@@ -462,10 +462,13 @@ def _c_paged_attention(op, info):
     # grouped query heads: Q is wider than the pool's rows of K/V heads
     hq = q.shape[-1] if q.shape[-1] > 0 else hd
     item = _DTYPE_BYTES.get(str(kc.dtype), 4)
-    flops = 4 * s * t * hq                       # QK^T + PV per head-row
-    bytes_ = (2 * s * t * hd          # K/V pages read
-              + 2 * s * (hq + hd)     # q, k, v rows in + out
-              + 2 * s * hd) * item    # tail-page scatter write (k + v)
+    # rows a slot a step: 1, or a block's L (each reads every live row;
+    # the K/V pages are still read once a slot)
+    rows = q.shape[1] if len(q.shape) == 3 and q.shape[1] > 0 else 1
+    flops = 4 * s * t * hq * rows                # QK^T + PV per head-row
+    bytes_ = (2 * s * t * hd                 # K/V pages read
+              + 2 * s * rows * (hq + hd)     # q, k, v rows in + out
+              + 2 * s * rows * hd) * item    # the rows' scatter (k + v)
     return int(flops), int(bytes_)
 
 

@@ -19,6 +19,14 @@ the vLLM/Orca phase boundary:
   prefill executable produced — the K/V never visit the host and no
   pool is copied.
 
+A bundle whose meta carries ``block_length`` L > 1
+(``models/block_moe.py``) decodes a BLOCK of L rows a slot a step
+(``ops/block_ops.py``): a prefill runs the prompt and then mask rows to
+the end of the block that the next position lies in, a decode step
+forwards the L rows of each slot's block (its committed tokens are a
+``state_vars`` array), and a step that forwards a block whose tokens are
+all committed yields no token (the pass that stores its K/V).
+
 A bundle whose meta names ``state_vars`` (``models/hybrid_moe.py``)
 keeps, beside the pool, a second kind of per-slot cache: arrays
 ``[num_slots, ...]`` that are not addressed through the page table (a
@@ -182,6 +190,10 @@ class GenPredictor:
         # fetches beside the logits (both absent from a gen_lm bundle)
         self.state_vars = list(self.meta.get("state_vars") or ())
         self.decode_stats = list(self.meta.get("decode_stats") or ())
+        # rows a slot a decode step (1: a token a slot a step), and the
+        # token a masked row is fed
+        self.block_length = int(self.meta.get("block_length") or 1)
+        self.mask_token_id = int(self.meta.get("mask_token_id", 0))
         self.prompt_buckets = [int(b) for b in self.meta["prompt_buckets"]]
         self.max_prompt_len = min(self.prompt_buckets[-1], self.max_len)
 
@@ -378,10 +390,21 @@ class GenPredictor:
     def pages_needed(self, prompt_len, max_new_tokens=1):
         """Pages a request must hold to decode to its length horizon
         WITHOUT mid-request allocation (allocation happens once, at
-        admission — growth can never fail mid-decode)."""
-        horizon = min(self.max_len,
-                      int(prompt_len) + max(int(max_new_tokens), 1))
+        admission — growth can never fail mid-decode).  A block bundle's
+        horizon is the END of the block its last token lies in: every
+        step writes a whole block's rows."""
+        horizon = int(prompt_len) + max(int(max_new_tokens), 1)
+        horizon = min(self.max_len, self._block_end(horizon))
         return -(-max(horizon, 1) // self.page_len)
+
+    def _block_end(self, rows):
+        """``rows`` rounded up to a whole number of blocks."""
+        return -(-rows // self.block_length) * self.block_length
+
+    def _prefill_rows(self, bucket):
+        """Rows of a prefill at ``bucket``: a block bundle's prefill runs
+        up to one block of mask rows behind the prompt."""
+        return bucket + (self.block_length if self.block_length > 1 else 0)
 
     def alloc_slot_pages(self, slot, n):
         """Assign ``n`` pool pages to ``slot`` (prefix order).  Raises
@@ -424,7 +447,14 @@ class GenPredictor:
 
     def _prefill_feed(self, prompt, bucket):
         from paddle_tpu.lod import pad_to_bucket
-        p = len(prompt)
+        p, last_row = len(prompt), len(prompt) - 1
+        if self.block_length > 1:
+            # mask rows to the end of the block that position p lies in
+            # (a whole masked block where p opens one); the logits are
+            # row p's, which predict its own token
+            prompt = list(prompt) + [self.mask_token_id] * (
+                self._block_end(p + 1) - p)
+            bucket, last_row, p = self._prefill_rows(bucket), p, len(prompt)
         mask = pad_to_bucket(np.ones((1, p), np.float32), bucket, axis=1)
 
         def ids():
@@ -444,7 +474,7 @@ class GenPredictor:
 
         def last():
             one_hot = np.zeros((1, bucket), np.float32)
-            one_hot[0, p - 1] = 1.0
+            one_hot[0, last_row] = 1.0
             return one_hot
 
         # only what the bundle's prefill program declares is built (the
@@ -543,7 +573,7 @@ class GenPredictor:
                     np.zeros(shape, jnp.dtype(str(var.dtype))), device)
             return made[key]
 
-        return [zeros(v, (1, bucket, int(v.shape[-1])))
+        return [zeros(v, (1, self._prefill_rows(bucket), int(v.shape[-1])))
                 for v in self._pre_fetch[1:1 + k]] + \
                [zeros(v, (1,) + tuple(int(d) for d in v.shape[1:]))
                 for v in self._pre_fetch[1 + k:]]
@@ -578,7 +608,9 @@ class GenPredictor:
 
     # -- decode ------------------------------------------------------------
     def decode_step(self, tokens, positions, lens, on_device=False):
-        """One decode iteration over the whole slot pool.
+        """One decode iteration over the whole slot pool: for every live
+        slot the token at ``positions``, the logits for the position
+        after it.
 
         ``tokens``/``positions``: int32 ``[S]`` (zeros for free slots).
         ``lens``: int32 ``[S]`` prefix rows INCLUDING the current token
@@ -586,6 +618,21 @@ class GenPredictor:
         feed is sliced to the smallest declared page bucket covering
         ``max(lens)``, so the jit key is the bucket.  Returns logits
         ``[S, V]``.
+
+        A block bundle (``block_length`` L > 1) forwards the L rows of
+        the block that row ``lens - 1`` lies in (``lens`` is rounded up
+        to that block's end here: a step writes the block's rows whole),
+        commits the token at ``positions`` where that lies in the block,
+        and returns the logits of the block's leftmost masked row.  ONE
+        dispatch is one such pass.  A token that completes its block
+        (``positions + 1`` a multiple of L, ``lens = positions + 1``)
+        makes it the pass that stores the block's K/V, which yields no
+        logits of use; the caller opens the next block with a pass at the
+        same ``positions`` and ``lens = positions + 2`` (every row
+        masked, logits for ``positions + 1``).  The scheduler drives
+        both itself (``on_device``); a blocking call makes the second
+        pass here, for the slots that need it, so its logits are always
+        those for ``positions + 1``.
 
         A bundle with ``decode_stats`` fetches, with the logits, one
         small int32 array ``[n, len(decode_stats)]`` a step; each column's
@@ -606,16 +653,49 @@ class GenPredictor:
         ``delay`` action models per-iteration device time serialized per
         replica (the decode bench's cost model), an ``error`` a device
         fault in the decode step."""
+        S, L = self.num_slots, self.block_length
+        positions = np.asarray(positions, np.int32).reshape(S, 1)
+        lens = np.asarray(lens, np.int32).reshape(S, 1)
+        if L > 1:
+            lens = self._block_end(lens)
+        logits = self._dispatch_step(tokens, positions, lens, on_device)
+        if L == 1 or on_device:
+            return logits
+        stored = (lens == positions + 1) & (lens > 0)
+        if stored.any():
+            # these slots' pass stored their block: open the next one
+            opened = np.where(stored, lens + L, 0).astype(np.int32)
+            with self._lock:
+                for slot in np.flatnonzero(stored):
+                    held = len(self._slot_pages.get(int(slot), ()))
+                    if held * self.page_len < int(opened[slot, 0]):
+                        raise RuntimeError(
+                            f"slot {int(slot)} holds {held} page(s): too "
+                            f"few to open the block behind row "
+                            f"{int(lens[slot, 0])}")
+            second = self._dispatch_step(np.zeros((S, 1), np.int32),
+                                         positions, opened, False)
+            logits = np.where(stored, second, logits)
+        return logits
+
+    def _dispatch_step(self, tokens, positions, lens, on_device):
+        """One run of the decode program (``decode_step``'s feeds as the
+        program takes them, ``[S, 1]`` each)."""
         from paddle_tpu.fault import chaos
+        from paddle_tpu.profiler import runtime_metrics
         S = self.num_slots
         feed = {
             "gen_token": tokens if isinstance(tokens, jax.Array)
             else np.asarray(tokens, np.int32).reshape(S, 1),
-            "gen_pos": np.asarray(positions, np.int32).reshape(S, 1),
+            "gen_pos": positions,
         }
-        feed.update(self._paged_decode_feed(
-            np.asarray(lens, np.int32).reshape(S, 1)))
-        live = int(np.count_nonzero(feed["gen_lens"]))
+        feed.update(self._paged_decode_feed(lens))
+        live = int(np.count_nonzero(lens))
+        if self.block_length > 1:
+            runtime_metrics.inc("gen.block.forwards", live)
+            runtime_metrics.inc("gen.block.rows", live * self.block_length)
+            runtime_metrics.inc("gen.block.store_passes", int(
+                np.count_nonzero((lens == positions + 1) & (lens > 0))))
         feed = {k: feed[k] for k in self._dec_feeds}
         with self._lock:
             chaos.fire("gen.decode.stall", slots=S)
@@ -694,7 +774,8 @@ class GenPredictor:
         sigs = [{k: v for k, v in {
             "gen_ids": (1, b), "gen_pos": (1, b), "gen_mask": (1, b),
             "gen_attn_bias": (1, 1, b, b), "gen_last": (1, b)}.items()
-            if k in self._pre_feeds} for b in buckets]
+            if k in self._pre_feeds}
+                for b in map(self._prefill_rows, buckets)]
         S = self.num_slots
         dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
                      "gen_page_table": (S, int(P)), "gen_lens": (S, 1)}

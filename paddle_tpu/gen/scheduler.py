@@ -32,6 +32,18 @@ foresee (EOS, a cancel) costs one computed row that is thrown away; at
 every whole-token boundary (migrate, abort, close, crash) the step in
 flight is first collected and emitted, or dropped.  With nothing in
 flight (the first step after idle) a turn only dispatches.
+
+A step is not a token.  A bundle that decodes a BLOCK of
+``predictor.block_length`` rows a slot a step (``models/block_moe.py``)
+gives a slot one token in most of its turns and none in some: the turn in
+which a block's last token is committed forwards the block whole to store
+its K/V and yields nothing, and the slot's next turn opens the block
+behind it, all rows masked, for that block's first token.  So a slot
+counts the tokens its dispatched turns yield (``steps``) apart from the
+position it feeds (``pos``), a dispatched row says whether it yields,
+and the length cap, ``gen.tokens`` and every emit go by tokens.  The
+schedule of turns is a function of positions alone, so the host still
+knows every ending by length a step ahead of the token.
 """
 
 from __future__ import annotations
@@ -145,16 +157,21 @@ class GenStream:
 
 
 class _Slot:
-    __slots__ = ("stream", "pos", "steps", "last_token", "last_emit_t")
+    __slots__ = ("stream", "pos", "steps", "opening", "last_token",
+                 "last_emit_t")
 
     def __init__(self, stream, prompt_len, first_token):
         self.stream = stream
-        # the next decode step DISPATCHED for this slot writes its K/V at
-        # ``pos``; ``steps`` counts those dispatched so far.  The slot's
-        # first step consumes first_token; the later ones are fed the
-        # device's own pick, and last_token is what the host has seen
+        # the next decode step DISPATCHED for this slot feeds the token at
+        # ``pos`` and writes its K/V there; ``steps`` counts the tokens
+        # that the steps dispatched so far yield.  The slot's first step
+        # consumes first_token; the later ones are fed the device's own
+        # pick, and last_token is what the host has seen.  ``opening``
+        # (block bundles): the block before ``pos`` is stored, and the
+        # next step opens ``pos``'s block, whose token it does not have
         self.pos = prompt_len
         self.steps = 0
+        self.opening = False
         self.last_token = first_token
         self.last_emit_t = time.perf_counter()
 
@@ -165,7 +182,8 @@ class _Step:
 
     def __init__(self, rows, logits, stats):
         # rows: (slot index, _Slot, whether the stream reaches its length
-        # cap with this step) for each slot the step carries
+        # cap with this step, whether the step yields the slot a token)
+        # for each slot the step carries
         self.rows = rows
         self.logits = logits    # as decode_step(on_device=True) gave them
         self.stats = stats      # the bundle's decode_stats array, or None
@@ -211,6 +229,12 @@ class GenScheduler:
             slo_watchdog = _slo.watchdog_from_env()
         self.slo_watchdog = slo_watchdog
         self.predictor = predictor
+        # rows a slot a decode step, and the position at which a stream
+        # ends by length: ``max_len``, and one under it for a block
+        # bundle (the token at ``max_len`` would open a block past the
+        # pool's end)
+        self._block = getattr(predictor, "block_length", 1)
+        self._horizon = predictor.max_len - (self._block > 1)
         self.queue_size = max(1, int(queue_size))
         self.admission = admission
         self.max_restarts = max(0, int(max_restarts))
@@ -634,7 +658,7 @@ class GenScheduler:
         prompt_len = len(stream.prompt)
         if first == stream.eos_id:
             return self._finish(stream, "eos")
-        if stream.max_new_tokens <= 1 or prompt_len >= self.predictor.max_len:
+        if stream.max_new_tokens <= 1 or prompt_len >= self._horizon:
             return self._finish(stream, "length")
         with _span("gen.seed_slot") as seed:
             try:
@@ -720,9 +744,10 @@ class GenScheduler:
             self._step_and_emit(live, _profiler.runtime_metrics)
 
     def _step_and_emit(self, live, metrics):
-        S, L = self.predictor.num_slots, self.predictor.max_len
+        S, horizon, block = self.predictor.num_slots, self._horizon, \
+            self._block
         prev = self._in_flight
-        carried = {idx: slot for idx, slot, _ in prev.rows} if prev else {}
+        carried = {row[0]: row[1] for row in prev.rows} if prev else {}
         # -1: the slot's token is the device's own pick from ``prev``
         override = np.full(S, -1, np.int32)
         positions = np.zeros(S, np.int32)
@@ -730,15 +755,30 @@ class GenScheduler:
         rows = []
         for idx, slot in live:
             cap = slot.stream.max_new_tokens
-            if 1 + slot.steps >= cap or slot.pos >= L:
+            if 1 + slot.steps >= cap or slot.pos >= horizon:
                 continue    # ends by length with the step in flight
             if carried.get(idx) is not slot:
                 override[idx] = slot.last_token
-            positions[idx] = slot.pos
-            lens[idx] = slot.pos + 1
-            slot.steps += 1
-            slot.pos += 1
-            rows.append((idx, slot, 1 + slot.steps >= cap or slot.pos >= L))
+            yields = True
+            if slot.opening:
+                # the block before ``pos`` is stored: this turn forwards
+                # ``pos``'s block with every row masked (the token fed is
+                # not read) and yields the token at ``pos``
+                positions[idx] = slot.pos - 1
+                lens[idx] = slot.pos + 1
+                slot.opening = False
+            else:
+                positions[idx] = slot.pos
+                lens[idx] = slot.pos + 1
+                slot.pos += 1
+                # the token fed completes its block: the turn stores the
+                # block's K/V and yields nothing
+                slot.opening = block > 1 and slot.pos % block == 0
+                yields = not slot.opening
+            slot.steps += yields
+            rows.append((idx, slot, yields and (1 + slot.steps >= cap
+                                                or slot.pos >= horizon),
+                         yields))
         t0 = time.perf_counter()
         kept = ()
         # the scheduler thread's time in the predictor this turn: the
@@ -761,16 +801,21 @@ class GenScheduler:
                     attrs = {} if prev.stats is None else \
                         self.predictor.count_decode_stats(prev.stats)
                 # a row whose slot was vacated since (EOS at the last
-                # collect, a cancel) was computed for nothing
-                kept = [row for row in prev.rows
-                        if self._slots.get(row[0]) is row[1]]
-                discarded = len(prev.rows) - len(kept)
+                # collect, a cancel) was computed for nothing; a row of
+                # a storing turn has no token
+                live_rows = [row for row in prev.rows
+                             if self._slots.get(row[0]) is row[1]]
+                kept = [row for row in live_rows if row[3]]
+                discarded = len(prev.rows) - len(live_rows)
                 metrics.inc("gen.decode.rows_discarded", discarded)
-                step.set(live=len(prev.rows), discarded=discarded, **attrs)
+                step.set(live=len(prev.rows), discarded=discarded,
+                         yielded=len(kept),
+                         stored=sum(1 for row in prev.rows if not row[3]),
+                         block_rows=len(prev.rows) * block, **attrs)
         now = time.perf_counter()
         metrics.observe("gen.decode_step_seconds", now - t0)
         with _span("gen.emit"):
-            for idx, slot, ends in kept:
+            for idx, slot, ends, _ in kept:
                 stream = slot.stream
                 token = ids[idx]
                 slot.last_token = token

@@ -6,11 +6,12 @@ TPU-native layers API."""
 from paddle_tpu.models import (resnet, transformer, vgg, mnist,
                                seq2seq, stacked_lstm, gen_lm,
                                gen_lm_long, wide_and_deep, hybrid_moe,
-                               latent_moe)
+                               latent_moe, block_moe)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
            "seq2seq", "stacked_lstm", "gen_lm", "gen_lm_long",
-           "wide_and_deep", "hybrid_moe", "latent_moe", "ZOO_MODELS",
+           "wide_and_deep", "hybrid_moe", "latent_moe", "block_moe",
+           "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
 
 #: zoo model names accepted by :func:`build_train_program` (and by
@@ -18,7 +19,7 @@ __all__ = ["resnet", "transformer", "vgg", "mnist",
 #: tests/test_analysis_zoo.py iterates exactly this list)
 ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
               "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
-              "hybrid_moe", "latent_moe")
+              "hybrid_moe", "latent_moe", "block_moe")
 
 
 def build_train_program(name, backward=True):
@@ -95,6 +96,12 @@ def build_train_program(name, backward=True):
             hp = latent_moe.LatentMoEConfig()
             hp.dtype = "float32"
             cost, feeds = latent_moe.latent_moe_train_program(16, hp)
+            fetches = [cost.name]
+        elif name == "block_moe":
+            # two layers at toy widths under the block-causal mask, float32
+            hp = block_moe.BlockMoEConfig()
+            hp.dtype = "float32"
+            cost, feeds = block_moe.block_moe_train_program(16, hp)
             fetches = [cost.name]
         else:
             raise ValueError(

@@ -32,4 +32,5 @@ from paddle_tpu.ops import (  # noqa: F401
     ssm_ops,
     moe_ops,
     mla_ops,
+    block_ops,
 )

@@ -941,7 +941,11 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # VPU multiply + lane reduction for the scores, a sublane reduction for
 # PV: exact float32) and 512 where ``G = H / Hkv`` query heads share one:
 # those take their K/V head's rows in two float32 ``HIGHEST`` products
-# ``[G, D] x [D, rows]``, ``[G, rows] x [rows, D]`` on the MXU.  The K/V
+# ``[G, D] x [D, rows]``, ``[G, rows] x [rows, D]`` on the MXU (over a
+# pool narrower than float32, PR 33: the rows stay in the pool's type,
+# one pass for the scores, which are then exact, and two for the weights
+# split ``hi + lo``).  With ``L`` rows a slot a step (block decoding) the
+# kernel is handed ``L * H`` query heads, ``L * G`` to a K/V head.  The K/V
 # heads are never copied out to ``H``; softmax state is float32.
 #
 # Measured on a v5e, the kernel alone (my chip runs, PR 30; the kernel it
@@ -968,22 +972,28 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # ---------------------------------------------------------------------------
 
 def _paged_cache_update(pools, rows, page_table, lens):
-    """Scatter this step's row of every pool (K and V, or the one
-    latent row) into each live slot's tail page.
+    """Scatter this step's rows of every pool (K and V, or the one
+    latent row) into each live slot's pages.
 
-    ``lens`` [S, 1] counts rows INCLUDING the token being decoded, so the
-    write lands at position ``lens-1``; ``lens == 0`` marks a free slot
+    ``rows`` are ``[S, L, width]``: ``L`` rows a slot a step (1 where a
+    step decodes one token a slot, a block of ``L`` under block
+    decoding).  ``lens`` [S, 1] counts rows THROUGH the step's last, so
+    row ``j`` lands at position ``lens - L + j``, over whatever an
+    earlier step wrote there; ``lens == 0`` marks a free slot
     and maps to an out-of-range page that ``mode="drop"`` discards —
     zero-filled warmup feeds therefore write nothing.
     """
     NP, PL, _ = pools[0].shape
-    last = lens[:, 0] - 1
-    idx = jnp.clip(last, 0)
-    page = jnp.take_along_axis(page_table, (idx // PL)[:, None], axis=1)[:, 0]
-    page = jnp.where(last >= 0, page, NP)
+    S, L = rows[0].shape[:2]
+    # [S * L], a slot's rows in order
+    at = (lens[:, :1] - L + jnp.arange(L, dtype=lens.dtype)).reshape(-1)
+    idx = jnp.clip(at, 0)
+    page = jnp.take_along_axis(page_table, (idx // PL).reshape(S, L),
+                               axis=1).reshape(-1)
+    page = jnp.where(at >= 0, page, NP)
     row = idx % PL
     return tuple(
-        pool.at[page, row].set(x.reshape(x.shape[0], -1).astype(pool.dtype),
+        pool.at[page, row].set(x.reshape(S * L, -1).astype(pool.dtype),
                                mode="drop")
         for pool, x in zip(pools, rows))
 
@@ -992,10 +1002,13 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
     """Gather-based fallback: same contract as the kernel.  Reads only
     the ``P`` table-listed pages per slot ([S, P*PL] keys, not
     [S, max_len]) — still occupancy-proportional, just without the
-    VMEM-resident online softmax."""
+    VMEM-resident online softmax.  ``q`` [S, L, H*D] (or [S, H*D]: one
+    row a slot): each of a slot's ``L`` query rows reads every live
+    row."""
     S, P = page_table.shape
     NP, PL, HDkv = kc.shape
     H = n_head
+    L = 1 if q.ndim == 2 else q.shape[1]
     D = q.shape[-1] // H
     Hkv = HDkv // D
     T = P * PL
@@ -1004,7 +1017,10 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
     if Hkv != H:
         kg = jnp.repeat(kg, H // Hkv, axis=2)
         vg = jnp.repeat(vg, H // Hkv, axis=2)
-    qh = q.reshape(S, H, D).astype(jnp.float32)
+    if L > 1:
+        # a slot's L rows as L x H heads, each row's H over the same K/V
+        kg, vg = jnp.tile(kg, (1, 1, L, 1)), jnp.tile(vg, (1, 1, L, 1))
+    qh = q.reshape(S, L * H, D).astype(jnp.float32)
     sc = jnp.einsum("shd,sthd->sht", qh, kg.astype(jnp.float32),
                     preferred_element_type=jnp.float32) * scale
     col = jax.lax.broadcasted_iota(jnp.int32, (S, 1, T), 2)
@@ -1095,6 +1111,9 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
     Dv = acc_ref.shape[-1]
     G = H // (kbuf.shape[-1] // D)
     f32 = jnp.float32
+    # grouped heads over a pool narrower than float32: both products take
+    # the rows from the buffer in the pool's own type (see ``attend``)
+    narrow = not latent and G > 1 and kbuf.dtype != f32
 
     def rows_of(slot):
         return jnp.minimum(lens_ref[slot, 0], P * PL)
@@ -1144,8 +1163,9 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
                 acc_ref[...] = acc_ref[...] * alpha + pv
                 m_ref[...] = m_new
                 return
-            k = kbuf[buf, rows, kv].astype(f32)          # [CR, D]
-            v = vbuf[buf, rows, kv].astype(f32)
+            k, v = kbuf[buf, rows, kv], vbuf[buf, rows, kv]  # [CR, D]
+            if not narrow:
+                k, v = k.astype(f32), v.astype(f32)
             if G == 1:
                 sc = jnp.sum(qs_ref[hs, :] * k, axis=1, keepdims=True)
                 sc = jnp.where(live, sc, NEG_INF)        # [CR, 1]
@@ -1155,19 +1175,32 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
                 e_sum = jnp.sum(e, axis=0, keepdims=True)
                 pv = jnp.sum(e * v, axis=0, keepdims=True)
             else:
+                # narrow: the query and the rows are exact in the pool's
+                # type, so ONE pass gives the float32 scores exactly, and
+                # the weights go through in two parts of that type (``e =
+                # hi + lo`` to 16 bits): three passes where float32
+                # ``HIGHEST`` products of rows cast up take six each
+                exact = {} if narrow else {
+                    "precision": jax.lax.Precision.HIGHEST}
                 sc = jax.lax.dot_general(
                     qs_ref[hs, :], k, (((1,), (1,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=f32)          # [G, CR]
+                    preferred_element_type=f32, **exact)  # [G, CR]
+                if narrow:
+                    sc = sc * scale
                 sc = jnp.where(live, sc, NEG_INF)
                 m_new = jnp.maximum(
                     m_prev, jnp.max(sc, axis=1, keepdims=True))
                 e = jnp.exp(sc - m_new[:, :1])           # [G, CR]
                 e_sum = jnp.sum(e, axis=1, keepdims=True)
-                pv = jax.lax.dot_general(
-                    e, v, (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=f32)          # [G, D]
+                weigh = lambda part: jax.lax.dot_general(
+                    part, v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=f32, **exact)  # [G, D]
+                if narrow:
+                    hi = e.astype(v.dtype)
+                    pv = weigh(hi) + weigh((e - hi.astype(f32))
+                                           .astype(v.dtype))
+                else:
+                    pv = weigh(e)
             alpha = jnp.exp(m_prev - m_new)
             l_ref[hs, :] = l_ref[hs, :] * alpha + e_sum
             acc_ref[hs, :] = acc_ref[hs, :] * alpha + pv
@@ -1206,8 +1239,10 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
         nxt = jax.lax.fori_loop(
             s + 1, S, lambda i, at: jnp.where(
                 (at == S) & (lens_ref[i, 0] > 0), i, at), S)
-        # the latent form scales the float32 scores, not the stored query
-        qs_ref[...] = q_ref[0] if latent else q_ref[0].astype(f32) * scale
+        # the latent and the narrow form scale the float32 scores, not the
+        # stored query
+        qs_ref[...] = q_ref[0] if latent or narrow \
+            else q_ref[0].astype(f32) * scale
         m_ref[...] = jnp.full_like(m_ref, _M_INIT)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -1273,11 +1308,32 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     lowering error past that gate surfaces to the caller.
     ``block_pages`` is for the tests: the kernel reads it from the
     shapes (``_paged_blocking``).  ``vc`` None with ``v_width``: the
-    latent form over the one pool ``kc``."""
+    latent form over the one pool ``kc``.
+
+    ``q`` [S, L, H*D] with ``L`` > 1 (block decoding: ``L`` rows a slot,
+    each reading every live row): the kernel is handed ``L * H`` query
+    heads, the ``L * G`` that share a K/V head side by side, so a K/V
+    head's rows are copied once for all ``L`` rows and its two products
+    are ``[L * G, D] x [D, rows]`` and ``[L * G, rows] x [rows, D]``.
+    ``L`` = 1 is the call as it was."""
     P = page_table.shape[1]
     NP, PL, HDkv = kc.shape
     HD = q.shape[-1]
     itemsize = kc.dtype.itemsize
+    L = q.shape[1] if q.ndim == 3 and v_width is None else 1
+    if L > 1:
+        if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize):
+            return None
+        S, D = q.shape[0], HD // n_head
+        n_kv = HDkv // D
+        # [S, L, Hkv, G, D] -> [S, Hkv, L, G, D]: the kernel's head h
+        # reads K/V head h // (L * G)
+        grouped = (S, n_kv, L, n_head // n_kv, D)
+        qk = q.reshape(S, L, n_kv, n_head // n_kv, D).transpose(0, 2, 1, 3, 4)
+        out = _pallas_paged_attention(
+            qk.reshape(S, 1, L * HD), kc, vc, page_table, lens, L * n_head,
+            scale, interpret=interpret, block_pages=block_pages)
+        return out.reshape(grouped).transpose(0, 2, 1, 3, 4).reshape(q.shape)
     if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize,
                             v_width):
         return None
@@ -1327,8 +1383,11 @@ def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
                 for pool in pools] + [
                 pltpu.SemaphoreType.DMA((2, len(pools))),
                 pltpu.SMEM((2,), jnp.int32),
-                # the query: scaled float32, or the latent form's as it is
-                pltpu.VMEM((n_head, D), kc.dtype if latent
+                # the query: scaled float32, or as it is (the latent
+                # form; grouped heads over a pool narrower than float32)
+                pltpu.VMEM((n_head, D), kc.dtype
+                           if latent or (HDkv != n_head * D
+                                         and kc.dtype != jnp.float32)
                            else jnp.float32),
             ] + [pltpu.VMEM((n_head, Dv), jnp.float32)] * 3,
         ),
@@ -1362,11 +1421,16 @@ def _infer_paged_attn(op, block):
              no_gradient=True,
              stateful_outputs=("KCacheOut", "VCacheOut"))
 def paged_attention_lower(ctx: LowerContext):
-    """Q: [S, 1, H*D], K/V: [S, 1, Hkv*D] this step's projections;
-    KCache/VCache: [num_pages, page_len, Hkv*D] persistable pool (Hkv =
-    H unless the model groups its query heads); PageTable: [S, P] int32
-    (P = the step's page bucket); Lens: [S, 1] int32 rows INCLUDING the
-    current token (0 = free slot).  Out: [S, 1, H*D]; KCacheOut/
+    """Q: [S, L, H*D], K/V: [S, L, Hkv*D] this step's projections, ``L``
+    rows a slot (1 where a step decodes one token a slot, a block of
+    ``L`` under block decoding); KCache/VCache: [num_pages, page_len,
+    Hkv*D] persistable pool (Hkv = H unless the model groups its query
+    heads); PageTable: [S, P] int32 (P = the step's page bucket); Lens:
+    [S, 1] int32 rows THROUGH the step's last (0 = free slot).  The
+    ``L`` rows are written at positions ``Lens - L .. Lens - 1`` of the
+    slot's pages, over whatever an earlier step wrote there, and every
+    one of the ``L`` query rows reads every live row, its own ``L``
+    included: no mask inside the step.  Out: [S, L, H*D]; KCacheOut/
     VCacheOut name the cache vars themselves (in-place update).
 
     attrs: n_head (int), scale (float).
@@ -1471,12 +1535,16 @@ def paged_attention_latent_lower(ctx: LowerContext):
 # gqa_attention IR op: causal self-attention over ONE prompt with grouped
 # query heads (H query heads over Hkv K/V heads), the prefill-side partner of
 # a grouped paged_attention.  Plain XLA; scores and softmax in float32.
+# With a block width the mask is BLOCK-causal: a row sees every row of its
+# own block of ``block`` positions and of the blocks before it.
 # ---------------------------------------------------------------------------
 
-def gqa_attention(q, k, v, mask, n_head, n_kv_head, scale):
+def gqa_attention(q, k, v, mask, n_head, n_kv_head, scale, block=1):
     """``q`` [T, H*D]; ``k``, ``v`` [T, Hkv*D]; ``mask`` [T] (0 = pad
     row, never attended).  Query head ``h`` reads K/V head
-    ``h // (H / Hkv)``.  Returns [T, H*D] in ``q``'s type."""
+    ``h // (H / Hkv)``.  Row ``i`` sees column ``j`` iff ``j // block <=
+    i // block``: causal at ``block`` 1, bidirectional inside a block of
+    ``block`` rows above it.  Returns [T, H*D] in ``q``'s type."""
     T = q.shape[0]
     D = q.shape[-1] // n_head
     g = n_head // n_kv_head
@@ -1486,6 +1554,8 @@ def gqa_attention(q, k, v, mask, n_head, n_kv_head, scale):
                     preferred_element_type=jnp.float32) * scale
     rows = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
+    if block > 1:
+        rows, cols = rows // block, cols // block
     seen = (cols <= rows) & (mask > 0)[None, :]
     probs = jax.nn.softmax(jnp.where(seen, sc, NEG_INF), axis=-1)
     out = jnp.einsum("kgqt,tkd->qkgd", probs.astype(v.dtype), vh,
@@ -1497,9 +1567,11 @@ def gqa_attention(q, k, v, mask, n_head, n_kv_head, scale):
              no_grad_inputs=("Mask",))
 def gqa_attention_lower(ctx: LowerContext):
     """Q [1, T, H*D]; K, V [1, T, Hkv*D]; Mask [1, T].  attrs n_head,
-    n_kv_head, scale.  Out [1, T, H*D]."""
+    n_kv_head, scale, block (1: causal; L: block-causal, a row sees its
+    own block of L positions whole).  Out [1, T, H*D]."""
     out = gqa_attention(ctx.input("Q")[0], ctx.input("K")[0],
                         ctx.input("V")[0], ctx.input("Mask")[0],
                         int(ctx.attr("n_head")), int(ctx.attr("n_kv_head")),
-                        float(ctx.attr("scale", 1.0)))
+                        float(ctx.attr("scale", 1.0)),
+                        int(ctx.attr("block", 1)))
     ctx.set_output("Out", out[None])

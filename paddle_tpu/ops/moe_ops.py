@@ -3,9 +3,10 @@ HOLDS a contiguous share of a model's experts (one chip's part of an
 expert-parallel deployment; ``models/hybrid_moe.py``).
 
 ``moe_route`` scores every token against ALL the model's experts (the
-router is whole on every chip), in float32: sigmoid scores, the ``top_k``
-largest of ``score + bias`` (``bias`` moves the choice only), weights =
-the chosen scores, normalised over the chosen and scaled.
+router is whole on every chip), in float32: sigmoid scores, or a softmax
+over all the experts (``scoring``), the ``top_k`` largest of ``score +
+bias`` (``bias`` moves the choice only; a router may have none), weights
+= the chosen scores, normalised over the chosen and scaled.
 
 ``moe_experts`` computes, for experts ``expert_offset ..
 expert_offset + held - 1`` (``held`` = the leading axis of its weights),
@@ -51,13 +52,20 @@ from paddle_tpu.ops.registry import ShapeInferenceSkip, register_op
 from paddle_tpu.ops.ssm_ops import relu2
 
 
-def moe_route(x, w_gate, bias, top_k, scaling=1.0, norm_topk=True):
-    """``x`` [T, d], ``w_gate`` [d, E], ``bias`` [E].  Returns ``idx``
-    [T, k] int32 and ``weights`` [T, k] float32."""
+def moe_route(x, w_gate, bias, top_k, scaling=1.0, norm_topk=True,
+              scoring="sigmoid"):
+    """``x`` [T, d], ``w_gate`` [d, E], ``bias`` [E] or None.  ``scoring``
+    ``"sigmoid"``: each expert's score by itself; ``"softmax"``: a
+    float32 softmax over all ``E`` experts.  Returns ``idx`` [T, k] int32
+    (the ``top_k`` largest of ``score + bias``) and ``weights`` [T, k]
+    float32 (the chosen scores, over their sum where ``norm_topk``,
+    times ``scaling``)."""
     logits = jnp.matmul(x.astype(jnp.float32), w_gate.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), int(top_k))
+    scores = jax.nn.softmax(logits, axis=-1) if scoring == "softmax" \
+        else jax.nn.sigmoid(logits)
+    chosen_by = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(chosen_by, int(top_k))
     weights = jnp.take_along_axis(scores, idx, axis=-1)
     if norm_topk:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
@@ -130,21 +138,26 @@ def _dense_experts(x, idx, weights, up, act, down, expert_offset, live):
 # row count, share ONE trace of the core, and an executable lowers it
 # once: traced and lowered a layer at a time, thirteen executables of
 # five layers added 9 s to a warm server start (my chip run, PR 32)
-@functools.partial(jax.jit,
-                   static_argnames=("act", "expert_offset", "interpret"))
+@functools.partial(jax.jit, static_argnames=(
+    "act", "expert_offset", "interpret", "chunk_rows"))
 def _routed_experts(x, idx, weights, up, act, down, expert_offset, live,
-                    interpret):
+                    interpret, chunk_rows=_GMM_CHUNK_ROWS):
     """The assignments that landed here sorted by expert, each expert's
     rows through its own matrices and no others (``megablox.gmm``): an
     expert with no row is not read.  ``up`` the [E, d, F] matrices whose
-    products ``act`` joins into the hidden rows, ``down`` [E, F, d]."""
+    products ``act`` joins into the hidden rows, ``down`` [E, F, d].
+    ``chunk_rows``: sorted rows a trip of the loop takes (0: all of them
+    in one: a layer that holds every expert, where every assignment
+    lands and a chunk would only read an expert again at each boundary
+    it spans)."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     E, d = down.shape[0], x.shape[1]
     T, k = idx.shape
     local, held = _held(idx, expert_offset, E, live)
     A = T * k
     tm = _GMM_ROW_TILE if A >= _GMM_ROW_TILE else -(-A // 16) * 16
-    chunk = min(_GMM_CHUNK_ROWS, -(-A // tm) * tm)
+    chunk = min(chunk_rows or A, -(-A // tm) * tm)
+    chunk = -(-chunk // tm) * tm
     # assignments sorted by the expert held here; the others last, in
     # no group.  The sorted rows are taken ``chunk`` at a time and only
     # the chunks that hold a row of a group are computed: the work
@@ -194,7 +207,7 @@ def _routed_experts(x, idx, weights, up, act, down, expert_offset, live,
 
 
 def _experts(op, x, idx, weights, up, act, down, expert_offset, live,
-             routed, interpret, dense_rows=()):
+             routed, interpret, dense_rows=(), chunk_rows=None):
     """The one switch between the two forms, read from the operands and
     the platform: ``routed`` None takes the routed form on a TPU, but
     for ``dense_rows`` (the row counts at which the op's dense form was
@@ -208,8 +221,10 @@ def _experts(op, x, idx, weights, up, act, down, expert_offset, live,
         routed = not interpret and idx.shape[0] not in dense_rows
     _count_lowering("routed" if routed else "dense", op)
     if routed:
-        return _routed_experts(x, idx, weights, up, act, down,
-                               int(expert_offset), live, bool(interpret))
+        return _routed_experts(
+            x, idx, weights, up, act, down, int(expert_offset), live,
+            bool(interpret),
+            _GMM_CHUNK_ROWS if chunk_rows is None else int(chunk_rows))
     return _dense_experts(x, idx, weights, up, act, down, expert_offset,
                           live)
 
@@ -240,13 +255,15 @@ def moe_experts(u, idx, weights, w1, w2, expert_offset=0, live=None,
 
 
 def moe_experts_gated(x, idx, weights, wg, wu, wd, expert_offset=0,
-                      live=None, routed=None, interpret=None):
+                      live=None, routed=None, interpret=None,
+                      chunk_rows=None):
     """``x`` [T, d]; ``idx``/``weights`` [T, k]; ``wg``, ``wu`` [E, d, F];
     ``wd`` [E, F, d]; ``live`` [T] bool.  Returns ``out`` [T, d] in
     ``x``'s type (``sum_i w_i W_d^i (silu(W_g^i x) * W_u^i x)`` over the
     assignments to the E experts held) and ``stats`` [3] int32."""
     return _experts("moe_experts_gated", x, idx, weights, (wg, wu),
-                    swiglu, wd, expert_offset, live, routed, interpret)
+                    swiglu, wd, expert_offset, live, routed, interpret,
+                    chunk_rows=chunk_rows)
 
 
 def _rows(x):
@@ -266,13 +283,15 @@ def _infer_route(op, block):
 @register_op("moe_route", infer_shape=_infer_route,
              stop_gradient_outputs=("TopkIdx",))
 def moe_route_lower(ctx):
-    """X [..., d]; W [d, E]; Bias [E].  attrs top_k, scaling,
-    norm_topk.  TopkIdx [..., k] int32; TopkWeight [..., k] float32."""
+    """X [..., d]; W [d, E]; Bias [E], optional.  attrs top_k, scaling,
+    norm_topk, scoring ("sigmoid" | "softmax" over all E).  TopkIdx
+    [..., k] int32; TopkWeight [..., k] float32."""
     x = ctx.input("X")
     idx, w = moe_route(_rows(x), ctx.input("W"), ctx.input("Bias"),
                        int(ctx.attr("top_k")),
                        float(ctx.attr("scaling", 1.0)),
-                       bool(ctx.attr("norm_topk", True)))
+                       bool(ctx.attr("norm_topk", True)),
+                       str(ctx.attr("scoring", "sigmoid")))
     lead = x.shape[:-1]
     ctx.set_output("TopkIdx", idx.reshape(lead + idx.shape[-1:]))
     ctx.set_output("TopkWeight", w.reshape(lead + w.shape[-1:]))
@@ -313,8 +332,10 @@ def moe_experts_lower(ctx):
              stop_gradient_outputs=("Stats",))
 def moe_experts_gated_lower(ctx):
     """X [..., d]; TopkIdx, TopkWeight [..., k]; Wg, Wu [E, d, F]; Wd
-    [E, F, d]; Lens [rows, 1] int32, optional.  attr expert_offset.  Out
-    [..., d]; Stats [1, 3] int32, as ``moe_experts``."""
+    [E, F, d]; Lens [rows, 1] int32, optional.  attrs expert_offset,
+    chunk_rows (sorted rows a trip of the routed form takes; absent:
+    ``_GMM_CHUNK_ROWS``, 0: all in one, for a layer that holds every
+    expert).  Out [..., d]; Stats [1, 3] int32, as ``moe_experts``."""
     x = ctx.input("X")
     lens = ctx.input("Lens")
     out, stats = moe_experts_gated(
@@ -322,6 +343,7 @@ def moe_experts_gated_lower(ctx):
         ctx.input("Wg"), ctx.input("Wu"), ctx.input("Wd"),
         int(ctx.attr("expert_offset", 0)),
         None if lens is None else lens.reshape(-1) > 0,
-        routed=False if ctx.training else None)
+        routed=False if ctx.training else None,
+        chunk_rows=ctx.attr("chunk_rows", None))
     ctx.set_output("Out", out.reshape(x.shape))
     ctx.set_output("Stats", stats[None])

@@ -199,6 +199,31 @@ def test_grouped_paged_decode_compiles_for_v5e(one_chip, pages):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("pages", [8, 256])
+def test_block_paged_decode_compiles_for_v5e(one_chip, pages):
+    """The decode kernel handed a BLOCK of 4 rows a slot at the
+    block-diffusion serving cell's shapes: 32 slots x 4 rows x 32
+    bfloat16 query heads x 128 over a bfloat16 pool whose rows hold 4 K/V
+    heads (512 wide), page_len 16: 32 query rows share a K/V head's
+    copy."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, L, NP, PL, H, HKV, D = 32, 4, 8192, 16, 32, 4, 128
+
+    def fn(q, kc, vc, pt):
+        out = A._pallas_paged_attention(q, kc, vc, pt,
+                                        _ragged_lens(S, pages, PL), H,
+                                        D ** -0.5, interpret=False)
+        assert out is not None, "shape gate refused a block of rows"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, L, H * D), jnp.bfloat16),
+                   ((NP, PL, HKV * D), jnp.bfloat16),
+                   ((NP, PL, HKV * D), jnp.bfloat16),
+                   ((S, pages), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
 def test_seed_of_pages_and_state_compiles_for_v5e_in_place(one_chip):
     """Admission's compiled seed with per-slot state beside the pool, at
     the hybrid serving cell's widths: 2 pools of 4096 pages x 16 rows x
