@@ -849,7 +849,10 @@ def _r_paged_attention(op, tc):
     q = tc.input_info(op, "Q")
     kc = tc.input_info(op, "KCache")
     vc = tc.input_info(op, "VCache")
-    for slot in ("PageTable", "Lens"):
+    # RowLens: the optional limit a row (a step of two blocks a slot)
+    index_slots = ["PageTable", "Lens"] + \
+        (["RowLens"] if op.input("RowLens") else [])
+    for slot in index_slots:
         inf = tc.input_info(op, slot)
         if inf.dtype is not None and inf.dtype not in ("int32", "int64"):
             tc.report("PTA005",

@@ -20,12 +20,13 @@ the vLLM/Orca phase boundary:
   pool is copied.
 
 A bundle whose meta carries ``block_length`` L > 1
-(``models/block_moe.py``) decodes a BLOCK of L rows a slot a step
+(``models/block_moe.py``) decodes BLOCKS of L rows
 (``ops/block_ops.py``): a prefill runs the prompt and then mask rows to
-the end of the block that the next position lies in, a decode step
+the end of the block that the next position lies in, and a decode step
 forwards the L rows of each slot's block (its committed tokens are a
-``state_vars`` array), and a step that forwards a block whose tokens are
-all committed yields no token (the pass that stores its K/V).
+``state_vars`` array) and, where the token fed completes that block, the
+L masked rows of the next one in the same forward: every step yields
+every live slot a token.
 
 A bundle whose meta names ``state_vars`` (``models/hybrid_moe.py``)
 keeps, beside the pool, a second kind of per-slot cache: arrays
@@ -622,17 +623,16 @@ class GenPredictor:
         A block bundle (``block_length`` L > 1) forwards the L rows of
         the block that row ``lens - 1`` lies in (``lens`` is rounded up
         to that block's end here: a step writes the block's rows whole),
-        commits the token at ``positions`` where that lies in the block,
-        and returns the logits of the block's leftmost masked row.  ONE
-        dispatch is one such pass.  A token that completes its block
-        (``positions + 1`` a multiple of L, ``lens = positions + 1``)
-        makes it the pass that stores the block's K/V, which yields no
-        logits of use; the caller opens the next block with a pass at the
-        same ``positions`` and ``lens = positions + 2`` (every row
-        masked, logits for ``positions + 1``).  The scheduler drives
-        both itself (``on_device``); a blocking call makes the second
-        pass here, for the slots that need it, so its logits are always
-        those for ``positions + 1``.
+        commits the token at ``positions`` there, and returns the logits
+        of the block's leftmost masked row.  A token that completes its
+        block (``positions + 1`` a multiple of L) makes it the forward
+        that stores the block's K/V, and the SAME forward carries the
+        next block with every row masked (the program takes ``2L`` rows a
+        slot; the page-table feed covers ``lens + L`` for such a slot),
+        so the logits are always those for ``positions + 1``.  A
+        blocking call raises where such a slot holds too few pages for
+        the block it opens; the scheduler's allocation
+        (:meth:`pages_needed`) covers it.
 
         A bundle with ``decode_stats`` fetches, with the logits, one
         small int32 array ``[n, len(decode_stats)]`` a step; each column's
@@ -653,49 +653,30 @@ class GenPredictor:
         ``delay`` action models per-iteration device time serialized per
         replica (the decode bench's cost model), an ``error`` a device
         fault in the decode step."""
+        from paddle_tpu.fault import chaos
+        from paddle_tpu.profiler import runtime_metrics
         S, L = self.num_slots, self.block_length
         positions = np.asarray(positions, np.int32).reshape(S, 1)
         lens = np.asarray(lens, np.int32).reshape(S, 1)
+        live, walk = int(np.count_nonzero(lens)), None
         if L > 1:
             lens = self._block_end(lens)
-        logits = self._dispatch_step(tokens, positions, lens, on_device)
-        if L == 1 or on_device:
-            return logits
-        stored = (lens == positions + 1) & (lens > 0)
-        if stored.any():
-            # these slots' pass stored their block: open the next one
-            opened = np.where(stored, lens + L, 0).astype(np.int32)
-            with self._lock:
-                for slot in np.flatnonzero(stored):
-                    held = len(self._slot_pages.get(int(slot), ()))
-                    if held * self.page_len < int(opened[slot, 0]):
-                        raise RuntimeError(
-                            f"slot {int(slot)} holds {held} page(s): too "
-                            f"few to open the block behind row "
-                            f"{int(lens[slot, 0])}")
-            second = self._dispatch_step(np.zeros((S, 1), np.int32),
-                                         positions, opened, False)
-            logits = np.where(stored, second, logits)
-        return logits
-
-    def _dispatch_step(self, tokens, positions, lens, on_device):
-        """One run of the decode program (``decode_step``'s feeds as the
-        program takes them, ``[S, 1]`` each)."""
-        from paddle_tpu.fault import chaos
-        from paddle_tpu.profiler import runtime_metrics
-        S = self.num_slots
+            # slots whose token completes its block: the step stores the
+            # block and opens the next, whose rows the kernel walks too
+            fused = (positions + 1 == lens) & (lens > 0)
+            walk = lens + L * fused
+            if not on_device:
+                self._check_opened_pages(fused, walk)
+            n_fused = int(np.count_nonzero(fused))
+            runtime_metrics.inc("gen.block.forwards", live)
+            runtime_metrics.inc("gen.block.fused", n_fused)
+            runtime_metrics.inc("gen.block.rows", (live + n_fused) * L)
         feed = {
             "gen_token": tokens if isinstance(tokens, jax.Array)
             else np.asarray(tokens, np.int32).reshape(S, 1),
             "gen_pos": positions,
         }
-        feed.update(self._paged_decode_feed(lens))
-        live = int(np.count_nonzero(lens))
-        if self.block_length > 1:
-            runtime_metrics.inc("gen.block.forwards", live)
-            runtime_metrics.inc("gen.block.rows", live * self.block_length)
-            runtime_metrics.inc("gen.block.store_passes", int(
-                np.count_nonzero((lens == positions + 1) & (lens > 0))))
+        feed.update(self._paged_decode_feed(lens, walk))
         feed = {k: feed[k] for k in self._dec_feeds}
         with self._lock:
             chaos.fire("gen.decode.stall", slots=S)
@@ -713,6 +694,18 @@ class GenPredictor:
                             self.last_decode_stats))
         return logits
 
+    def _check_opened_pages(self, fused, walk):
+        """Raise where a slot whose step opens a block (``fused``) holds
+        fewer pages than ``walk``, the rows through that block's end."""
+        with self._lock:
+            for slot in np.flatnonzero(fused):
+                held = len(self._slot_pages.get(int(slot), ()))
+                if held * self.page_len < int(walk[slot, 0]):
+                    raise RuntimeError(
+                        f"slot {int(slot)} holds {held} page(s): too few "
+                        f"to open the block behind row "
+                        f"{int(walk[slot, 0]) - self.block_length}")
+
     def count_decode_stats(self, stats):
         """A step's ``decode_stats`` array (read here, if it is still on
         the device) with its columns reduced over their rows: counted
@@ -729,27 +722,31 @@ class GenPredictor:
                 runtime_metrics.inc(metric, out[col["name"]])
         return out
 
-    def _paged_decode_feed(self, lens):
+    def _paged_decode_feed(self, lens, walk=None):
         """Page-table + lens feed for one step: slice the table
         to the smallest declared page bucket covering the longest live
         prefix (clamped to ``pages_per_slot`` — ``row_bucket`` past the
         declared ladder falls back to its power-of-two ladder, which
-        must never widen the jit key beyond the pool)."""
+        must never widen the jit key beyond the pool).  ``walk``: the
+        rows each slot's step reads and writes through, where that is
+        more than the ``lens`` it is fed (a block bundle's slot that
+        opens its next block)."""
         from paddle_tpu.lod import row_bucket
         from paddle_tpu.profiler import runtime_metrics
-        live = lens[:, 0] > 0
+        rows = lens if walk is None else walk
+        live = rows[:, 0] > 0
         need = 1
         if live.any():
-            need = int(-(-int(lens[live, 0].max()) // self.page_len))
+            need = int(-(-int(rows[live, 0].max()) // self.page_len))
         P = min(row_bucket(max(need, 1), edges=self.page_buckets),
                 self.pages_per_slot)
-        touched = int(np.sum(-(-lens[live, 0] // self.page_len)))
+        touched = int(np.sum(-(-rows[live, 0] // self.page_len)))
         runtime_metrics.observe("gen.paged.pages_touched",
                                 float(touched))
         runtime_metrics.observe("gen.paged.pages_in_bucket",
-                                float(lens.shape[0] * P))
+                                float(rows.shape[0] * P))
         if touched:
-            occupancy = (100.0 * float(lens[live, 0].sum()) /
+            occupancy = (100.0 * float(rows[live, 0].sum()) /
                          (touched * self.page_len))
             runtime_metrics.bucket("gen.paged.page_occupancy",
                                    int(occupancy))

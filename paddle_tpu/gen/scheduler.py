@@ -33,17 +33,17 @@ every whole-token boundary (migrate, abort, close, crash) the step in
 flight is first collected and emitted, or dropped.  With nothing in
 flight (the first step after idle) a turn only dispatches.
 
-A step is not a token.  A bundle that decodes a BLOCK of
-``predictor.block_length`` rows a slot a step (``models/block_moe.py``)
-gives a slot one token in most of its turns and none in some: the turn in
-which a block's last token is committed forwards the block whole to store
-its K/V and yields nothing, and the slot's next turn opens the block
-behind it, all rows masked, for that block's first token.  So a slot
-counts the tokens its dispatched turns yield (``steps``) apart from the
-position it feeds (``pos``), a dispatched row says whether it yields,
-and the length cap, ``gen.tokens`` and every emit go by tokens.  The
-schedule of turns is a function of positions alone, so the host still
-knows every ending by length a step ahead of the token.
+A step carries more rows than tokens where a bundle decodes BLOCKS of
+``predictor.block_length`` rows (``models/block_moe.py``): a slot's turn
+forwards the block it is generating and, where the token it feeds
+completes that block, stores it and opens the next one in the same
+forward (the predictor and the program see to it: the feeds are a
+token's position and ``pos + 1`` rows, as every bundle's).  Every turn
+still yields every slot it carries one token; what the scheduler knows
+of blocks is where a stream ends by length (``_horizon``: the token at
+``max_len - 1`` would open a block past the pool's end) and, for the
+``gen.decode_step`` span, how many of a step's slots completed a block
+(``fused``).
 """
 
 from __future__ import annotations
@@ -157,36 +157,34 @@ class GenStream:
 
 
 class _Slot:
-    __slots__ = ("stream", "pos", "steps", "opening", "last_token",
-                 "last_emit_t")
+    __slots__ = ("stream", "pos", "steps", "last_token", "last_emit_t")
 
     def __init__(self, stream, prompt_len, first_token):
         self.stream = stream
         # the next decode step DISPATCHED for this slot feeds the token at
-        # ``pos`` and writes its K/V there; ``steps`` counts the tokens
-        # that the steps dispatched so far yield.  The slot's first step
+        # ``pos`` and writes its K/V there; ``steps`` counts those
+        # dispatched so far, a token each.  The slot's first step
         # consumes first_token; the later ones are fed the device's own
-        # pick, and last_token is what the host has seen.  ``opening``
-        # (block bundles): the block before ``pos`` is stored, and the
-        # next step opens ``pos``'s block, whose token it does not have
+        # pick, and last_token is what the host has seen
         self.pos = prompt_len
         self.steps = 0
-        self.opening = False
         self.last_token = first_token
         self.last_emit_t = time.perf_counter()
 
 
 class _Step:
     """A dispatched decode step whose tokens nobody has read yet."""
-    __slots__ = ("rows", "logits", "stats")
+    __slots__ = ("rows", "logits", "stats", "fused")
 
-    def __init__(self, rows, logits, stats):
+    def __init__(self, rows, logits, stats, fused):
         # rows: (slot index, _Slot, whether the stream reaches its length
-        # cap with this step, whether the step yields the slot a token)
-        # for each slot the step carries
+        # cap with this step) for each slot the step carries
         self.rows = rows
         self.logits = logits    # as decode_step(on_device=True) gave them
         self.stats = stats      # the bundle's decode_stats array, or None
+        # slots whose token completed a block: the step stored it and
+        # opened the next (0 unless the bundle decodes blocks)
+        self.fused = fused
 
 
 class GenScheduler:
@@ -747,38 +745,27 @@ class GenScheduler:
         S, horizon, block = self.predictor.num_slots, self._horizon, \
             self._block
         prev = self._in_flight
-        carried = {row[0]: row[1] for row in prev.rows} if prev else {}
+        carried = {idx: slot for idx, slot, _ in prev.rows} if prev else {}
         # -1: the slot's token is the device's own pick from ``prev``
         override = np.full(S, -1, np.int32)
         positions = np.zeros(S, np.int32)
         lens = np.zeros(S, np.int32)
-        rows = []
+        rows, fused = [], 0
         for idx, slot in live:
             cap = slot.stream.max_new_tokens
             if 1 + slot.steps >= cap or slot.pos >= horizon:
                 continue    # ends by length with the step in flight
             if carried.get(idx) is not slot:
                 override[idx] = slot.last_token
-            yields = True
-            if slot.opening:
-                # the block before ``pos`` is stored: this turn forwards
-                # ``pos``'s block with every row masked (the token fed is
-                # not read) and yields the token at ``pos``
-                positions[idx] = slot.pos - 1
-                lens[idx] = slot.pos + 1
-                slot.opening = False
-            else:
-                positions[idx] = slot.pos
-                lens[idx] = slot.pos + 1
-                slot.pos += 1
-                # the token fed completes its block: the turn stores the
-                # block's K/V and yields nothing
-                slot.opening = block > 1 and slot.pos % block == 0
-                yields = not slot.opening
-            slot.steps += yields
-            rows.append((idx, slot, yields and (1 + slot.steps >= cap
-                                                or slot.pos >= horizon),
-                         yields))
+            positions[idx] = slot.pos
+            lens[idx] = slot.pos + 1
+            slot.steps += 1
+            slot.pos += 1
+            # the token fed completes a block: the step stores it and
+            # opens the next
+            fused += block > 1 and slot.pos % block == 0
+            rows.append((idx, slot,
+                         1 + slot.steps >= cap or slot.pos >= horizon))
         t0 = time.perf_counter()
         kept = ()
         # the scheduler thread's time in the predictor this turn: the
@@ -794,28 +781,30 @@ class GenScheduler:
                 logits = self.predictor.decode_step(
                     tokens, positions, lens=lens, on_device=True)
                 self._in_flight = _Step(rows, logits,
-                                        self.predictor.last_decode_stats)
+                                        self.predictor.last_decode_stats,
+                                        fused)
             if prev:
                 with _span("gen.collect"):
                     ids = np.asarray(tokens).reshape(-1).tolist()
                     attrs = {} if prev.stats is None else \
                         self.predictor.count_decode_stats(prev.stats)
                 # a row whose slot was vacated since (EOS at the last
-                # collect, a cancel) was computed for nothing; a row of
-                # a storing turn has no token
-                live_rows = [row for row in prev.rows
-                             if self._slots.get(row[0]) is row[1]]
-                kept = [row for row in live_rows if row[3]]
-                discarded = len(prev.rows) - len(live_rows)
+                # collect, a cancel) was computed for nothing
+                kept = [row for row in prev.rows
+                        if self._slots.get(row[0]) is row[1]]
+                discarded = len(prev.rows) - len(kept)
                 metrics.inc("gen.decode.rows_discarded", discarded)
+                # ``stored``: slots whose turn yielded nothing; there
+                # has been none since a block is stored by the forward
+                # that opens the next (``fused``)
                 step.set(live=len(prev.rows), discarded=discarded,
-                         yielded=len(kept),
-                         stored=sum(1 for row in prev.rows if not row[3]),
-                         block_rows=len(prev.rows) * block, **attrs)
+                         yielded=len(kept), stored=0, fused=prev.fused,
+                         block_rows=(len(prev.rows) + prev.fused) * block,
+                         **attrs)
         now = time.perf_counter()
         metrics.observe("gen.decode_step_seconds", now - t0)
         with _span("gen.emit"):
-            for idx, slot, ends, _ in kept:
+            for idx, slot, ends in kept:
                 stream = slot.stream
                 token = ids[idx]
                 slot.last_token = token

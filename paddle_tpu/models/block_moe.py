@@ -14,7 +14,8 @@ Every layer is pre-norm, no biases: ``x <- x + Attn(RMSNorm(x))`` then
   positions are cut into blocks of ``block_length`` from 0 and a row sees
   every row of its own block and of the blocks before it
   (``gqa_attention`` with a block width in the prefill; in the decode
-  step the paged kernel's ``L`` rows a slot read every live row).
+  step ``paged_attention`` with a limit a row: the rows of a block read
+  the live rows through their own block's end).
 * **MoE.**  A float32 softmax router over all ``num_experts`` experts,
   the ``num_experts_per_tok`` largest, weights renormalised over the
   chosen (``moe_route`` with ``scoring="softmax"``, no bias, no
@@ -26,13 +27,19 @@ sequential``, one token a pass).  A masked row's input is the mask
 token's embedding and the logits of a row predict that row's OWN token.
 The prefill is one forward over the prompt followed by mask rows to the
 end of the block that position ``n`` lies in; its logits are row ``n``'s.
-A decode step forwards, for every slot, the ``L`` rows of the block it is
-generating (``ops/block_ops.py``: the block's committed tokens live on the
-device, the bundle's ``state_vars``), writes their K/V over the block's
-rows of the slot's pages and returns the logits of the leftmost masked
-row.  A block of ``L`` takes ``L`` such passes, each committing one token,
-and one more with every token committed, which yields nothing: its K/V
-are what later blocks read (the store pass).
+A decode step forwards ``2L`` rows a slot (``ops/block_ops.py``): half A,
+the ``L`` rows of the block the slot is generating (its committed tokens
+live on the device, the bundle's ``state_vars``), and half B, the block
+behind it.  It writes A's K/V over the block's rows of the slot's pages
+and returns the logits of the leftmost masked row, ONE row a slot through
+the head.  While the token fed does not complete block A, half B is dead:
+no K/V written, no routed expert, its output read by nothing.  The token
+that completes A makes A the forward with every token committed, whose
+K/V are what later blocks read, and B the next block with every row
+masked, whose first row is the one picked: the published loop's store
+pass rides with the opening pass of the next block, A's rows reading the
+rows through A and B's the ``L`` more (the same block-causal mask the two
+passes had).  A block of ``L`` takes ``L`` forwards, each yielding a token.
 
 Matrices and activations are ``dtype`` (bfloat16) with float32
 accumulation; router scores, norm statistics, rotary angles, softmax and
@@ -117,8 +124,8 @@ def _head_norm(x, name, hp, n_head):
 
 def _attention(h, hp, i, pos, mask=None, paged=None):
     """Prefill (block-causal; returns the masked K/V that seed the pool)
-    or the paged decode of a block's rows (``paged`` = pools, page table,
-    lens)."""
+    or the paged decode of a slot's two blocks of rows (``paged`` =
+    pools, page table, the rows' end, a limit a row)."""
     d = int(hp.hidden_size)
     H, Hkv, D = (int(hp.num_attention_heads), int(hp.num_key_value_heads),
                  int(hp.head_dim))
@@ -143,10 +150,11 @@ def _attention(h, hp, i, pos, mask=None, paged=None):
                   {"Out": hp.dtype},
                   {**attrs, "block": int(hp.block_length)})["Out"]
     else:
-        pk, pv, page_table, lens = paged
+        pk, pv, page_table, end, row_lens = paged
         ctx = _op("paged_attention",
                   {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
-                   "PageTable": page_table, "Lens": lens},
+                   "PageTable": page_table, "Lens": end,
+                   "RowLens": row_lens},
                   {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
                   attrs)["Out"]
     return layers.matmul(ctx, _matrix(hp, f"blk{i}_o.w", [H * D, d])), kv
@@ -247,20 +255,23 @@ def block_moe_train_program(seq_len, hp: BlockMoEConfig = None):
 
 
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
-    """The decode step over a block of ``L = block_length`` rows a slot,
-    in the CURRENT program guard.
+    """The decode step over ``2L`` rows a slot (``L = block_length``:
+    the block a slot is generating and the block behind it), in the
+    CURRENT program guard.
 
     Feeds: ``gen_token`` [S, 1] int32 (the newest committed token: the
     device's own pick, or the host's after an admission), ``gen_pos``
     [S, 1] int32 (its position), ``gen_page_table`` [S, P] int32,
-    ``gen_lens`` [S, 1] int32 (rows through the END of the block being
-    forwarded; 0 = free slot: nothing is written).  Where ``gen_pos``
-    lies in that block the token is committed there; where it lies in
-    the block before, the block is opened with every row masked
-    (``ops/block_ops.py``).  Persistable state, updated in place: the
-    page pools ``[num_pages, page_len, Hkv*D]`` in ``hp.dtype`` and the
-    slots' block tokens ``[S, L]`` int32.  Fetches ``[logits [S, V] of
-    each slot's leftmost masked row, stats [n_layer, 3]]``."""
+    ``gen_lens`` [S, 1] int32 (rows through the END of the block that
+    ``gen_pos`` lies in; 0 = free slot: nothing is written).  The token
+    is committed to its block; where it completes the block, the block
+    is stored and the next one opened in the same forward
+    (``ops/block_ops.py``), and the page table must cover ``gen_lens +
+    L`` rows.  Persistable state, updated in place: the page pools
+    ``[num_pages, page_len, Hkv*D]`` in ``hp.dtype`` and the slots'
+    block tokens ``[S, L]`` int32.  Fetches ``[logits [S, V] of each
+    slot's leftmost masked row (ONE row a slot goes through the head),
+    stats [n_layer, 3]]``."""
     import paddle_tpu as fluid
 
     S, L = int(num_slots), int(hp.block_length)
@@ -283,15 +294,16 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     rows = _op("block_rows",
                {"Token": token, "Pos": pos, "Lens": lens, "Block": state},
                {"Ids": "int32", "RowPos": "int32", "RowLens": "int32",
-                "Pick": "float32", "BlockOut": state},
+                "Pick": "float32", "End": "int32", "BlockOut": state},
                {"mask_id": int(hp.mask_token_id)})
     x = layers.reshape(_embed(rows["Ids"], hp, "blk"),
-                       shape=[S, L, int(hp.hidden_size)])
+                       shape=[S, 2 * L, int(hp.hidden_size)])
     stats = []
     for i in range(int(hp.num_hidden_layers)):
         x, _, st = _layer(x, hp, i, rows["RowPos"], rows["RowLens"],
                           paged=(pools[f"blk{i}_paged_k"],
-                                 pools[f"blk{i}_paged_v"], page_table, lens))
+                                 pools[f"blk{i}_paged_v"], page_table,
+                                 rows["End"], rows["RowLens"]))
         stats.append(st)
     picked = layers.matmul(layers.cast(rows["Pick"], hp.dtype), x)
     logits = _logits(layers.reshape(picked,

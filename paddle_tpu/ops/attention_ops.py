@@ -945,8 +945,12 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # pool narrower than float32, PR 33: the rows stay in the pool's type,
 # one pass for the scores, which are then exact, and two for the weights
 # split ``hi + lo``).  With ``L`` rows a slot a step (block decoding) the
-# kernel is handed ``L * H`` query heads, ``L * G`` to a K/V head.  The K/V
-# heads are never copied out to ``H``; softmax state is float32.
+# kernel is handed ``L * H`` query heads, ``L * G`` to a K/V head, and
+# (PR 34) a limit a query row where the call brings one (``RowLens``: a
+# step that carries two blocks a slot under the block-causal mask): one
+# more compare on the ``[L * G, rows]`` scores, the walk to the largest
+# limit; a call without limits is the kernel it was.  The K/V heads are
+# never copied out to ``H``; softmax state is float32.
 #
 # Measured on a v5e, the kernel alone (my chip runs, PR 30; the kernel it
 # replaced, grid (slots, page bucket) with one 16-row page a grid step,
@@ -971,26 +975,33 @@ fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 # XLA), so tests opt in via PADDLE_TPU_PAGED_INTERPRET=1 instead.
 # ---------------------------------------------------------------------------
 
-def _paged_cache_update(pools, rows, page_table, lens):
+def _paged_cache_update(pools, rows, page_table, lens, row_lens=None):
     """Scatter this step's rows of every pool (K and V, or the one
     latent row) into each live slot's pages.
 
     ``rows`` are ``[S, L, width]``: ``L`` rows a slot a step (1 where a
-    step decodes one token a slot, a block of ``L`` under block
+    step decodes one token a slot, the rows of two blocks under block
     decoding).  ``lens`` [S, 1] counts rows THROUGH the step's last, so
     row ``j`` lands at position ``lens - L + j``, over whatever an
     earlier step wrote there; ``lens == 0`` marks a free slot
     and maps to an out-of-range page that ``mode="drop"`` discards —
-    zero-filled warmup feeds therefore write nothing.
+    zero-filled warmup feeds therefore write nothing.  ``row_lens``
+    [S, L] (``paged_attention``'s ``RowLens``): a row whose entry is 0
+    lands nowhere either.
     """
     NP, PL, _ = pools[0].shape
     S, L = rows[0].shape[:2]
     # [S * L], a slot's rows in order
     at = (lens[:, :1] - L + jnp.arange(L, dtype=lens.dtype)).reshape(-1)
     idx = jnp.clip(at, 0)
+    # (a dead row's place may lie past the table that is fed: whatever
+    # the gather returns there is replaced)
     page = jnp.take_along_axis(page_table, (idx // PL).reshape(S, L),
                                axis=1).reshape(-1)
-    page = jnp.where(at >= 0, page, NP)
+    lands = at >= 0
+    if row_lens is not None:
+        lands &= row_lens.reshape(-1) > 0
+    page = jnp.where(lands, page, NP)
     row = idx % PL
     return tuple(
         pool.at[page, row].set(x.reshape(S * L, -1).astype(pool.dtype),
@@ -998,13 +1009,15 @@ def _paged_cache_update(pools, rows, page_table, lens):
         for pool, x in zip(pools, rows))
 
 
-def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
+def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
+                         row_lens=None):
     """Gather-based fallback: same contract as the kernel.  Reads only
     the ``P`` table-listed pages per slot ([S, P*PL] keys, not
     [S, max_len]) — still occupancy-proportional, just without the
     VMEM-resident online softmax.  ``q`` [S, L, H*D] (or [S, H*D]: one
     row a slot): each of a slot's ``L`` query rows reads every live
-    row."""
+    row, or with ``row_lens`` [S, L] the rows under its own limit (a
+    row whose limit is 0 reads zeros)."""
     S, P = page_table.shape
     NP, PL, HDkv = kc.shape
     H = n_head
@@ -1024,10 +1037,14 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale):
     sc = jnp.einsum("shd,sthd->sht", qh, kg.astype(jnp.float32),
                     preferred_element_type=jnp.float32) * scale
     col = jax.lax.broadcasted_iota(jnp.int32, (S, 1, T), 2)
-    sc = jnp.where(col < lens[:, :, None], sc, NEG_INF)
+    limit = lens[:, :, None] if row_lens is None \
+        else jnp.repeat(row_lens, H, axis=1)[:, :, None]
+    sc = jnp.where(col < limit, sc, NEG_INF)
     probs = jax.nn.softmax(sc, axis=-1)
     out = jnp.einsum("sht,sthd->shd", probs, vg.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
+    if row_lens is not None:
+        out = jnp.where(limit > 0, out, 0.0)
     return out.reshape(q.shape).astype(q.dtype)
 
 
@@ -1067,7 +1084,7 @@ def _paged_blocking(P, PL, HDkv, itemsize, grouped, block_pages=None):
 
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
                          block_pages, chunk_rows, head_unroll, scale,
-                         latent=False):
+                         latent=False, row_limits=False):
     """One slot of the grid: the online softmax over the slot's LIVE rows.
 
     The pools stay in HBM.  A slot makes ``cdiv(lens, block rows)`` trips
@@ -1094,7 +1111,16 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
     and, the weights rounded to that type, ``[H, rows] x [rows, Dv]``
     (121 FLOP a cached byte at 64 heads: two ``HIGHEST`` float32
     products, six passes each, would bound the kernel by the MXU at a
-    third of the chip's bandwidth)."""
+    third of the chip's bandwidth).
+
+    ``row_limits`` (grouped heads only): a ``[G, 1]`` int32 column
+    comes first among ``refs``, the rows each of a K/V head's ``G``
+    query rows sees, the same for every K/V head: a row is masked past
+    its own limit, the walk still follows ``lens`` (the largest), and
+    a query row whose limit is 0 leaves zeros."""
+    lim_ref = None
+    if row_limits:
+        lim_ref, *refs = refs
     if latent:
         k_hbm, o_ref, kbuf, sems, ahead_ref, qs_ref, m_ref, l_ref, \
             acc_ref = refs
@@ -1134,12 +1160,17 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
 
         jax.lax.fori_loop(0, live, page, 0)
 
-    def attend(buf, rows, valid):
-        """One chunk: ``valid`` of its rows are live (may exceed it)."""
+    def attend(buf, rows, valid, base):
+        """One chunk: ``valid`` of its rows are live (may exceed it);
+        it starts at the slot's row ``base`` (given with row limits)."""
         if G == 1:
             live = jax.lax.broadcasted_iota(jnp.int32, (CR, 1), 0) < valid
-        else:
+        elif lim_ref is None:
             live = jax.lax.broadcasted_iota(jnp.int32, (1, CR), 1) < valid
+        else:
+            # [G, CR]: each query row under its own limit (<= lens)
+            live = jax.lax.broadcasted_iota(jnp.int32, (1, CR), 1) \
+                < lim_ref[0] - base
 
         def head(g):
             hs = pl.ds(pl.multiple_of(g * G, G), G)
@@ -1263,7 +1294,8 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
 
             def chunk(c, _):
                 r0 = pl.multiple_of(c * CR, CR)
-                attend(buf, pl.ds(r0, CR), left - r0)
+                attend(buf, pl.ds(r0, CR), left - r0,
+                       None if lim_ref is None else b * BR + r0)
                 return 0
 
             jax.lax.fori_loop(0, pl.cdiv(jnp.minimum(left, BR), CR),
@@ -1272,7 +1304,15 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
 
         ahead_ref[0] = jax.lax.fori_loop(0, n_blocks, block, buf0)
         ahead_ref[1] = jnp.where(nxt < S, 1, 0)
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        if lim_ref is None:
+            o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+        else:
+            seen = lim_ref[0] > 0
+            for g in range(H // G):
+                hs = pl.ds(g * G, G)
+                o_ref[0, hs, :] = jnp.where(
+                    seen, acc_ref[hs, :] / l_ref[hs, :], 0.0
+                ).astype(o_ref.dtype)
 
 
 def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4,
@@ -1303,7 +1343,7 @@ def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4,
 
 def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
                             interpret=False, block_pages=None,
-                            v_width=None):
+                            v_width=None, row_lens=None):
     """Returns None when ``_paged_kernel_ok`` refuses the shape; any
     lowering error past that gate surfaces to the caller.
     ``block_pages`` is for the tests: the kernel reads it from the
@@ -1315,7 +1355,9 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     heads, the ``L * G`` that share a K/V head side by side, so a K/V
     head's rows are copied once for all ``L`` rows and its two products
     are ``[L * G, D] x [D, rows]`` and ``[L * G, rows] x [rows, D]``.
-    ``L`` = 1 is the call as it was."""
+    ``L`` = 1 is the call as it was.  ``row_lens`` [S, L] (with ``L`` >
+    1): what each of a slot's rows sees, ``lens`` the largest of them;
+    the kernel takes them as a column of ``L * G`` limits."""
     P = page_table.shape[1]
     NP, PL, HDkv = kc.shape
     HD = q.shape[-1]
@@ -1330,9 +1372,13 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
         # reads K/V head h // (L * G)
         grouped = (S, n_kv, L, n_head // n_kv, D)
         qk = q.reshape(S, L, n_kv, n_head // n_kv, D).transpose(0, 2, 1, 3, 4)
+        if row_lens is not None:
+            row_lens = jnp.repeat(row_lens.astype(jnp.int32),
+                                  n_head // n_kv, axis=1)[:, :, None]
         out = _pallas_paged_attention(
             qk.reshape(S, 1, L * HD), kc, vc, page_table, lens, L * n_head,
-            scale, interpret=interpret, block_pages=block_pages)
+            scale, interpret=interpret, block_pages=block_pages,
+            row_lens=row_lens)
         return out.reshape(grouped).transpose(0, 2, 1, 3, 4).reshape(q.shape)
     if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize,
                             v_width):
@@ -1345,7 +1391,8 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     return _paged_kernel_call(
         q, kc, vc, page_table, lens, n_head=n_head, scale=scale,
         interpret=interpret, block_pages=block_pages,
-        chunk_rows=chunk_rows, head_unroll=head_unroll, v_width=v_width)
+        chunk_rows=chunk_rows, head_unroll=head_unroll, v_width=v_width,
+        row_limits=row_lens)
 
 
 # inline: the call leaves no trace in the program (the kernel's event keeps
@@ -1356,7 +1403,7 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     "head_unroll", "v_width"))
 def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
                        interpret, block_pages, chunk_rows, head_unroll,
-                       v_width=None):
+                       v_width=None, row_limits=None):
     S = page_table.shape[0]
     NP, PL, HDkv = kc.shape
     D = q.shape[-1] // n_head
@@ -1367,7 +1414,10 @@ def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
                                block_pages=block_pages,
                                chunk_rows=chunk_rows,
                                head_unroll=head_unroll, scale=scale,
-                               latent=latent)
+                               latent=latent,
+                               row_limits=row_limits is not None)
+    # [S, G, 1]: the limits of the query rows that share a K/V head
+    limits = [] if row_limits is None else [row_limits]
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1375,6 +1425,8 @@ def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
             grid=(S,),
             in_specs=[pl.BlockSpec((1, n_head, D),
                                    lambda s, pt, ln: (s, 0, 0))]
+            + [pl.BlockSpec((1,) + lim.shape[1:],
+                            lambda s, pt, ln: (s, 0, 0)) for lim in limits]
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec((1, n_head, Dv),
                                    lambda s, pt, ln: (s, 0, 0)),
@@ -1395,7 +1447,8 @@ def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(page_table, lens, q.reshape(S, n_head, D).astype(kc.dtype), *pools)
+    )(page_table, lens, q.reshape(S, n_head, D).astype(kc.dtype), *limits,
+      *pools)
     return out.reshape(q.shape[:-1] + (n_head * Dv,)).astype(q.dtype)
 
 
@@ -1422,16 +1475,25 @@ def _infer_paged_attn(op, block):
              stateful_outputs=("KCacheOut", "VCacheOut"))
 def paged_attention_lower(ctx: LowerContext):
     """Q: [S, L, H*D], K/V: [S, L, Hkv*D] this step's projections, ``L``
-    rows a slot (1 where a step decodes one token a slot, a block of
-    ``L`` under block decoding); KCache/VCache: [num_pages, page_len,
-    Hkv*D] persistable pool (Hkv = H unless the model groups its query
-    heads); PageTable: [S, P] int32 (P = the step's page bucket); Lens:
-    [S, 1] int32 rows THROUGH the step's last (0 = free slot).  The
-    ``L`` rows are written at positions ``Lens - L .. Lens - 1`` of the
-    slot's pages, over whatever an earlier step wrote there, and every
-    one of the ``L`` query rows reads every live row, its own ``L``
-    included: no mask inside the step.  Out: [S, L, H*D]; KCacheOut/
-    VCacheOut name the cache vars themselves (in-place update).
+    rows a slot (1 where a step decodes one token a slot, the rows of
+    two blocks under block decoding); KCache/VCache: [num_pages,
+    page_len, Hkv*D] persistable pool (Hkv = H unless the model groups
+    its query heads); PageTable: [S, P] int32 (P = the step's page
+    bucket); Lens: [S, 1] int32 rows THROUGH the step's last (0 = free
+    slot).  The ``L`` rows are written at positions ``Lens - L .. Lens -
+    1`` of the slot's pages, over whatever an earlier step wrote there,
+    and every one of the ``L`` query rows reads every live row, its own
+    ``L`` included: no mask inside the step.  Out: [S, L, H*D];
+    KCacheOut/VCacheOut name the cache vars themselves (in-place
+    update).
+
+    RowLens (optional; ``L`` > 1): [S * L, 1] int32, a limit for each
+    row: row ``j`` reads the rows under ITS limit, so a step can carry
+    two blocks a slot under the block-causal mask (the first block's
+    rows ``L / 2`` fewer than the second's); a row whose limit is 0 is
+    dead: written nowhere, its output zeros.  The slot's pages are
+    walked to the largest limit.  A call without it lowers as it always
+    did.
 
     attrs: n_head (int), scale (float).
     """
@@ -1444,19 +1506,25 @@ def paged_attention_lower(ctx: LowerContext):
     lens = ctx.input("Lens")
     n_head = int(ctx.attr("n_head", 1))
     scale = float(ctx.attr("scale", 1.0))
-    kc, vc = _paged_cache_update((kc, vc), (k, v), pt, lens)
+    row_lens, walk = None, lens
+    if ctx.has_input("RowLens"):
+        row_lens = ctx.input("RowLens").reshape(q.shape[:2])
+        walk = jnp.max(row_lens, axis=1, keepdims=True)
+    kc, vc = _paged_cache_update((kc, vc), (k, v), pt, lens, row_lens)
     out = None
     interpret = _use_interpret()
     if _paged_kernel_enabled(interpret):
-        out = _pallas_paged_attention(q, kc, vc, pt, lens, n_head, scale,
-                                      interpret=interpret)
+        out = _pallas_paged_attention(q, kc, vc, pt, walk, n_head, scale,
+                                      interpret=interpret,
+                                      row_lens=row_lens)
     if out is None:
         # same coverage contract as attention.fused_softmax_fallback:
         # fires at trace time, once per compiled signature, whenever a
         # decode bucket lowered without the Pallas kernel
         from paddle_tpu.profiler import runtime_metrics
         runtime_metrics.inc("gen.paged.fallback")
-        out = _xla_paged_attention(q, kc, vc, pt, lens, n_head, scale)
+        out = _xla_paged_attention(q, kc, vc, pt, walk, n_head, scale,
+                                   row_lens=row_lens)
     ctx.set_output("Out", out)
     ctx.set_output("KCacheOut", kc)
     ctx.set_output("VCacheOut", vc)

@@ -1,26 +1,36 @@
 """Block decoding (``models/block_moe.py``): a step forwards, for every
-slot, the ``L`` rows of the block it is generating, and the tokens
-committed to that block so far live on the device, ``[S, L]`` int32 a
-decode program (a bundle's ``state_vars``).
+slot, ``2L`` rows: half A, the ``L`` rows of the block the slot is
+generating, and half B, the block behind it.  The tokens committed to
+block A so far live on the device, ``[S, L]`` int32 a decode program (a
+bundle's ``state_vars``).
 
 Positions are cut into blocks of ``L`` from 0.  A position is MASKED
 until a token is committed to it: its row's input is the mask token.
 Masked-ness is a matter of position alone (rows of the block behind the
 newest committed token), never of a token's value.
 
-``block_rows`` turns a step's feeds into the block's rows: the newest
-committed token ``Token`` at position ``Pos`` and ``Lens``, the rows
-through the END of the block being forwarded (0: a free slot).  With
-``start = Lens - L`` and ``at = Pos - start``:
+``block_rows`` turns a step's feeds into the rows: the newest committed
+token ``Token`` at position ``Pos`` and ``Lens``, the rows through the
+END of the block ``Pos`` lies in (0: a free slot).  With ``start = Lens
+- L`` and ``at = Pos - start`` (``0 <= at < L``) the token is committed
+at row ``at`` of block A (and kept in the state); A's rows behind it are
+masked.  Row ``j`` of the ``2L`` stands at position ``start + j``.
 
-* ``0 <= at < L``: the token is committed at row ``at`` of the block (and
-  kept in the state); rows behind it are masked.
-* ``at < 0`` (``Pos`` lies in the block before): the block is opened, all
-  of its rows masked; nothing is committed.
+* ``at < L - 1``: half B is DEAD (row limit 0): its K/V land nowhere, it
+  takes no routed expert and nothing reads its output.  A's rows see the
+  rows under ``Lens``.
+* ``at == L - 1``, the token completes block A (the host knows it as
+  ``(Pos + 1) % L == 0``): A is forwarded with every token committed and
+  its rows see the rows under ``Lens``: these are the block's final K/V,
+  what later blocks read.  B is the next block, every row masked; its
+  rows see the rows under ``Lens + L``, A's among them: the block-causal
+  mask.
 
-``Pick`` is the one-hot of the leftmost masked row, ``at + 1``, whose
-logits predict that row's own token; a block with no masked row left (the
-pass that stores its K/V) picks its last row, and yields nothing.
+``Pick`` is the one-hot of the leftmost masked row, ``at + 1`` of the
+``2L`` (B's first row where A is complete), whose logits predict that
+row's own token.  The state keeps block A's tokens after it is complete:
+the next step commits at row 0 and the rows behind are masked by
+position.
 
 ``block_tail`` reads, in a prefill, the tokens of the block that the row
 ``Last`` marks lies in: what seeds the state of a slot.
@@ -36,18 +46,24 @@ from paddle_tpu.ops.registry import ShapeInferenceSkip, register_op
 
 def block_rows(token, pos, lens, block, mask_id):
     """``token``, ``pos``, ``lens`` [S, 1] int32; ``block`` [S, L] int32.
-    Returns ``ids`` [S, L], ``row_pos`` [S, L], ``row_lens`` [S * L, 1]
-    (the slot's ``lens`` on each of its rows), ``pick`` [S, L] float32
-    one-hot, and the new ``block``."""
+    Returns ``ids`` [S, 2L], ``row_pos`` [S, 2L], ``row_lens`` [S * 2L,
+    1] (what each row sees; 0: a dead row), ``pick`` [S, 2L] float32
+    one-hot, ``end`` [S, 1] (rows through the last of the ``2L``: where
+    ``paged_attention`` puts them; 0 for a free slot) and the new
+    ``block``."""
     L = block.shape[1]
-    j = jnp.arange(L, dtype=jnp.int32)[None, :]
+    j = jnp.arange(2 * L, dtype=jnp.int32)[None, :]
+    live = lens > 0
     start = lens - L
     at = pos - start
-    block = jnp.where((j == at) & (lens > 0), token, block)
-    ids = jnp.where(j <= at, block, jnp.int32(mask_id))
-    pick = (j == jnp.clip(at + 1, 0, L - 1)).astype(jnp.float32)
-    row_lens = jnp.broadcast_to(lens, block.shape).reshape(-1, 1)
-    return ids, jnp.maximum(start, 0) + j, row_lens, pick, block
+    block = jnp.where((j[:, :L] == at) & live, token, block)
+    ids = jnp.where(j <= at, jnp.tile(block, (1, 2)), jnp.int32(mask_id))
+    pick = (j == jnp.clip(at + 1, 0, 2 * L - 1)).astype(jnp.float32)
+    # half A under ``lens``; half B under ``lens + L`` where A is complete
+    behind = jnp.where(live & (at == L - 1), lens + L, 0)
+    row_lens = jnp.where(j < L, lens, behind)
+    return (ids, jnp.maximum(start, 0) + j, row_lens.reshape(-1, 1), pick,
+            jnp.where(live, lens + L, 0), block)
 
 
 def _infer_block_rows(op, block):
@@ -55,10 +71,11 @@ def _infer_block_rows(op, block):
     if state.shape is None:
         raise ShapeInferenceSkip()
     S, L = (int(d) for d in state.shape)
-    for slot, shape, dtype in (("Ids", (S, L), "int32"),
-                               ("RowPos", (S, L), "int32"),
-                               ("RowLens", (S * L, 1), "int32"),
-                               ("Pick", (S, 1, L), "float32")):
+    for slot, shape, dtype in (("Ids", (S, 2 * L), "int32"),
+                               ("RowPos", (S, 2 * L), "int32"),
+                               ("RowLens", (S * 2 * L, 1), "int32"),
+                               ("Pick", (S, 1, 2 * L), "float32"),
+                               ("End", (S, 1), "int32")):
         v = block.var(op.output(slot)[0])
         v.shape, v.dtype = shape, dtype
 
@@ -68,15 +85,16 @@ def _infer_block_rows(op, block):
 def block_rows_lower(ctx):
     """Token, Pos, Lens [S, 1] int32; Block [S, L] int32 (persistable:
     the tokens committed to each slot's block).  attr mask_id.  Ids,
-    RowPos [S, L] int32; RowLens [S * L, 1] int32; Pick [S, 1, L]
-    float32; BlockOut names Block itself."""
-    ids, row_pos, row_lens, pick, state = block_rows(
+    RowPos [S, 2L] int32; RowLens [S * 2L, 1] int32; Pick [S, 1, 2L]
+    float32; End [S, 1] int32; BlockOut names Block itself."""
+    ids, row_pos, row_lens, pick, end, state = block_rows(
         ctx.input("Token"), ctx.input("Pos"), ctx.input("Lens"),
         ctx.input("Block"), int(ctx.attr("mask_id")))
     ctx.set_output("Ids", ids)
     ctx.set_output("RowPos", row_pos)
     ctx.set_output("RowLens", row_lens)
     ctx.set_output("Pick", pick[:, None, :])
+    ctx.set_output("End", end)
     ctx.set_output("BlockOut", state)
 
 

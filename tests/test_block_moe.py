@@ -5,10 +5,12 @@ with a block width, ``moe_route``'s softmax scoring, ``block_rows``), the
 exported bundle (block-causal prefill, the compiled seed, cached block
 steps through the paged pool) against the plain reference
 (``benchmark/reference/sdar_ref.py``) on seeded weights, and
-``GenScheduler``'s streams, token-less turns included, against the
-reference's published generation loop.  Toy widths: d 64, 4 query / 2 K/V
+``GenScheduler``'s streams (a completed block is stored by the forward
+that opens the next: every turn yields) against the reference's
+published generation loop.  Toy widths: d 64, 4 query / 2 K/V
 heads of 16, 8 experts top-2, 2 layers, blocks of 4."""
 
+import hashlib
 import os
 import sys
 
@@ -294,6 +296,157 @@ def test_grouped_heads_over_a_bfloat16_pool(rows):
                                    want, atol=6e-3, rtol=4e-3)
 
 
+def _two_block_case(group, seed=11):
+    """``_paged_case`` with ``2L`` rows a slot and a limit a row: slot 0
+    stores a block under 16 rows and opens the next under 20, slot 1
+    forwards its block under 8 with a dead second half, slot 2 stores its
+    FIRST block under 4 and opens the second under 8, slot 3 is free."""
+    q, kc, vc, pt, _, H, Hkv = _paged_case(2 * L, group, seed=seed)
+    row_lens = np.array([[16] * L + [20] * L, [8] * L + [0] * L,
+                         [4] * L + [8] * L, [0] * (2 * L)], "int32")
+    return q, kc, vc, pt, row_lens, H, Hkv
+
+
+@pytest.mark.parametrize("pool", ["float32", "bfloat16"])
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+@pytest.mark.parametrize("group", [8, 1])
+def test_paged_attention_with_a_limit_a_row(group, path, pool):
+    """Two blocks a slot in one call (``2L`` rows: at ``group`` 8 a K/V
+    head has ``L * G`` = 32 query rows a block, 64 in all): each row
+    reads the rows under ITS limit, the walk follows the largest, a dead
+    row reads zeros.  The kernel (in interpret mode) = the XLA form = the
+    dense product."""
+    q, kc, vc, pt, row_lens, H, Hkv = _two_block_case(group)
+    cast = (lambda a: jnp.asarray(a).astype(jnp.bfloat16)) \
+        if pool == "bfloat16" else jnp.asarray
+    walk = jnp.asarray(row_lens.max(1, keepdims=True))
+    args = (cast(q), cast(kc), cast(vc), jnp.asarray(pt), walk, H, 0.35)
+    if path == "xla":
+        got = attention_ops._xla_paged_attention(
+            *args, row_lens=jnp.asarray(row_lens))
+    else:
+        got = attention_ops._pallas_paged_attention(
+            *args, interpret=True, row_lens=jnp.asarray(row_lens))
+        assert got is not None
+    assert got.shape == q.shape and got.dtype == args[0].dtype
+    got = np.asarray(got.astype(jnp.float32))
+    f = lambda a: np.asarray(cast(a).astype(jnp.float32))
+    # a bfloat16 output is rounded to 2^-8 of its size
+    tol = dict(atol=3e-6) if pool == "float32" else dict(atol=6e-3,
+                                                         rtol=4e-3)
+    for s in range(4):
+        keys = f(kc)[pt[s]].reshape(-1, kc.shape[-1])
+        vals = f(vc)[pt[s]].reshape(-1, vc.shape[-1])
+        seen = np.arange(keys.shape[0])[None, :] < row_lens[s][:, None]
+        live = row_lens[s] > 0
+        assert not got[s][~live].any()
+        if live.any():
+            want = _dense_attention(f(q)[s][live], keys, vals, seen[live],
+                                    H, Hkv, 0.35)
+            np.testing.assert_allclose(got[s][live], want, **tol)
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_two_blocks_in_one_call_are_the_two_calls_they_replace(path):
+    """A store pass (the completed block's rows under ``n``) followed by
+    an opening pass (the next block's under ``n + L``), each a call of
+    ``L`` rows with no limit, leave the pool rows and the outputs that
+    ONE call of ``2L`` rows with limits leaves."""
+    q, kc, vc, pt, row_lens, H, Hkv = _two_block_case(8, seed=12)
+    rng = np.random.RandomState(13)
+    k, v = (jnp.asarray(rng.randn(4, 2 * L, Hkv * 8).astype("float32"))
+            for _ in range(2))
+    pools = (jnp.asarray(kc), jnp.asarray(vc))
+    q, pt, limits = jnp.asarray(q), jnp.asarray(pt), jnp.asarray(row_lens)
+    fn = attention_ops._xla_paged_attention if path == "xla" else \
+        (lambda *a, **kw: attention_ops._pallas_paged_attention(
+            *a, interpret=True, **kw))
+    # two calls: half A's rows end at its limit, half B's at its own
+    two, outs = pools, []
+    for half in (slice(0, L), slice(L, 2 * L)):
+        lens = limits[:, half][:, :1]
+        two = attention_ops._paged_cache_update(
+            two, (k[:, half], v[:, half]), pt, lens)
+        outs.append(fn(q[:, half], *two, pt, lens, H, 0.35))
+    # one call: the 2L rows end where half B would, live or dead
+    end = jnp.where(limits[:, :1] > 0, limits[:, :1] + L, 0)
+    one = attention_ops._paged_cache_update(pools, (k, v), pt, end, limits)
+    got = fn(q, *one, pt, jnp.max(limits, axis=1, keepdims=True), H, 0.35,
+             row_lens=limits)
+    for a, b in zip(one, two):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    live = np.asarray(limits) > 0
+    want = np.concatenate([np.asarray(o) for o in outs], axis=1)
+    np.testing.assert_allclose(np.asarray(got)[live], want[live], atol=3e-6)
+
+
+def _fingerprint(jaxpr, out=None):
+    """Primitive and output types of every equation, in order, through
+    every nested jaxpr (a kernel's body); source positions left out."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append(f"{eqn.primitive.name}:"
+                   f"{[str(v.aval) for v in eqn.outvars]}")
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _fingerprint(sub, out)
+    return out
+
+
+def _one_row_call(kind):
+    """The cache update, the kernel and the XLA form of a call that
+    decodes one row a slot, and its argument shapes."""
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    S, P, NP, PL, D = 4, 4, 32, 16, 128
+    z = jax.ShapeDtypeStruct
+    if kind == "latent":
+        def call(q, row, cache, pt, lens):
+            cache, = attention_ops._paged_cache_update((cache,), (row,), pt,
+                                                       lens)
+            return (attention_ops._pallas_paged_attention(
+                        q, cache, None, pt, lens, 8, 0.5, v_width=512),
+                    attention_ops._xla_latent_attention(q, cache, pt, lens,
+                                                        8, 512, 0.5))
+        return call, (z((S, 1, 8 * 640), bf16), z((S, 1, 640), bf16),
+                      z((NP, PL, 640), bf16), z((S, P), i32),
+                      z((S, 1), i32))
+    H, Hkv = (16, 2) if kind == "grouped heads" else (4, 4)
+
+    def call(q, k, v, kc, vc, pt, lens):
+        kc, vc = attention_ops._paged_cache_update((kc, vc), (k, v), pt,
+                                                   lens)
+        return (attention_ops._pallas_paged_attention(q, kc, vc, pt, lens,
+                                                      H, 0.5),
+                attention_ops._xla_paged_attention(q, kc, vc, pt, lens, H,
+                                                   0.5))
+    # the configuration with heads of their own feeds two-axis queries
+    q = (S, 1, H * D) if Hkv != H else (S, H * D)
+    return call, (z(q, f32), z((S, 1, Hkv * D), f32),
+                  z((S, 1, Hkv * D), f32), z((NP, PL, Hkv * D), f32),
+                  z((NP, PL, Hkv * D), f32), z((S, P), i32), z((S, 1), i32))
+
+
+@pytest.mark.parametrize("kind, equations, digest", [
+    ("heads of their own", 413, "02d0c5961d3cb409"),
+    ("grouped heads", 337, "70479ee8605ad095"),
+    ("latent", 289, "ba1c08a8e9d9a1ab")])
+def test_a_one_row_call_lowers_to_the_jaxpr_it_had(kind, equations, digest):
+    """The three configurations that decode a token a slot a step share
+    ``paged_attention``'s update, kernel and XLA form with the block
+    bundle: a call without row limits traces to the equations it traced
+    to before limits existed (the numbers are commit ``dae0d84``'s, by
+    this test's own ``_fingerprint`` under the installed JAX; after a JAX
+    upgrade read them anew from a checkout of that commit)."""
+    call, shapes = _one_row_call(kind)
+    lines = _fingerprint(jax.make_jaxpr(call)(*shapes).jaxpr)
+    assert len(lines) == equations
+    assert hashlib.sha256("\n".join(lines).encode()).hexdigest()[:16] \
+        == digest
+
+
 @pytest.mark.parametrize("path", ["xla", "kernel"])
 def test_one_row_a_slot_is_the_call_as_it_was(path):
     """``[S, 1, H*D]`` and the two-axis ``[S, H*D]`` queries of the
@@ -326,29 +479,46 @@ def test_the_cache_update_writes_a_block_over_the_rows_before():
     want = np.asarray(pool).copy()
     want[5, 3], want[1, 3] = np.asarray(rows[0, 0]), np.asarray(rows[1, 0])
     assert np.array_equal(np.asarray(one), want)
+    # a limit a row: a row whose limit is 0 lands nowhere, also where its
+    # place lies past the table that is fed (slot 0: rows 16-17)
+    limits = jnp.asarray([[12, 12, 0, 0], [4, 0, 4, 4], [0] * 4], jnp.int32)
+    dead, = attention_ops._paged_cache_update(
+        (pool,), (rows,), table, jnp.asarray([[18], [4], [0]], jnp.int32),
+        limits)
+    want = np.asarray(pool).copy()
+    want[5, 6:8] = np.asarray(rows[0, :2])  # rows 14-15
+    want[1, 0], want[1, 2:4] = np.asarray(rows[1, 0]), np.asarray(rows[1, 2:])
+    assert np.array_equal(np.asarray(dead), want)
 
 
-def test_block_rows_commits_opens_and_stores():
+def test_block_rows_commits_and_stores_with_the_opening():
     state = jnp.asarray([[11, 12, 13, 14], [21, 22, 23, 24],
                          [31, 32, 33, 34], [41, 42, 43, 44],
                          [51, 52, 53, 54]], jnp.int32)
     token = jnp.asarray([[7], [8], [9], [5], [6]], jnp.int32)
-    #           commit at 1   open    store (at 3)  first row   free
-    pos = jnp.asarray([[9], [11], [15], [4], [0]], jnp.int32)
+    #          commit at 1  at 2   completes (3)  first row   free
+    pos = jnp.asarray([[9], [14], [15], [4], [0]], jnp.int32)
     lens = jnp.asarray([[12], [16], [16], [8], [0]], jnp.int32)
-    ids, row_pos, row_lens, pick, new = map(np.asarray, block_ops.block_rows(
-        token, pos, lens, state, MASK))
-    assert ids[:4].tolist() == [[11, 7, MASK, MASK], [MASK] * 4,
-                                [31, 32, 33, 9], [5, MASK, MASK, MASK]]
-    assert new.tolist() == [[11, 7, 13, 14], [21, 22, 23, 24],
+    ids, row_pos, row_lens, pick, end, new = map(
+        np.asarray, block_ops.block_rows(token, pos, lens, state, MASK))
+    M = [MASK] * 4
+    # half A: the slot's block, committed through ``at``; half B: masked
+    assert ids[:4].tolist() == [[11, 7, MASK, MASK] + M,
+                                [21, 22, 8, MASK] + M,
+                                [31, 32, 33, 9] + M, [5, MASK, MASK, MASK] + M]
+    assert new.tolist() == [[11, 7, 13, 14], [21, 22, 8, 24],
                             [31, 32, 33, 9], [5, 42, 43, 44],
                             [51, 52, 53, 54]]
-    assert row_pos[:4].tolist() == [[8, 9, 10, 11], [12, 13, 14, 15],
-                                    [12, 13, 14, 15], [4, 5, 6, 7]]
-    # the leftmost masked row; a block with none left picks its last
-    assert pick.argmax(-1).tolist()[:4] == [2, 0, 3, 1]
-    assert row_lens.reshape(5, 4).tolist() == [[12] * 4, [16] * 4, [16] * 4,
-                                               [8] * 4, [0] * 4]
+    assert row_pos[:4].tolist() == [list(range(8, 16)), list(range(12, 20)),
+                                    list(range(12, 20)), list(range(4, 12))]
+    # the leftmost masked row: where the block is complete, the first of
+    # the block behind it
+    assert pick.argmax(-1).tolist()[:4] == [2, 3, 4, 1]
+    # half B is live (and sees half A) only behind a completed block
+    assert row_lens.reshape(5, 8).tolist() == [
+        [12] * 4 + [0] * 4, [16] * 4 + [0] * 4, [16] * 4 + [20] * 4,
+        [8] * 4 + [0] * 4, [0] * 8]
+    assert end.reshape(-1).tolist() == [16, 20, 20, 12, 0]
     tail = block_ops.block_tail(jnp.arange(100, 120, dtype=jnp.int32),
                                 jnp.zeros(20).at[9].set(1.0), 4)
     assert np.asarray(tail).tolist() == [108, 109, 110, 111]
@@ -402,9 +572,10 @@ def test_a_prompt_may_hold_the_mask_token(predictor, cfg, weights):
 @pytest.mark.parametrize("n", [8, 9, 10, 11])
 def test_cached_steps_match_the_reference(predictor, cfg, weights, n):
     """Prefill, the compiled seed, then seven cached steps through the
-    paged pool, across a block boundary (the store pass, then the pass
-    that opens the next block): every step's logits are the reference's
-    for the next position given everything before it."""
+    paged pool, across a block boundary (the step whose token completes
+    a block stores it and opens the next in one forward): every step's
+    logits are the reference's for the next position given everything
+    before it."""
     seq = _prompt(n, seed=20 + n)
     tok = int(np.argmax(_admit(predictor, 1, seq)))
     try:
@@ -419,8 +590,8 @@ def test_cached_steps_match_the_reference(predictor, cfg, weights, n):
 
 def test_slots_at_different_offsets_share_a_step(predictor, cfg, weights):
     """Three slots whose newest tokens sit at offsets 0, 2 and 3 of their
-    blocks (the last one's turn stores its block and opens the next) in
-    the same steps."""
+    blocks (the last one's step stores its block and opens the next; the
+    others' second halves are dead) in the same steps."""
     seqs = {0: _prompt(8, seed=31), 2: _prompt(14, seed=32),
             3: _prompt(11, seed=33)}
     toks = {s: int(np.argmax(_admit(predictor, s, seq)))
@@ -440,9 +611,69 @@ def test_slots_at_different_offsets_share_a_step(predictor, cfg, weights):
             predictor.free_slot_pages(s)
 
 
+def _slot_rows(predictor, slot, name, n):
+    """The first ``n`` rows that ``slot`` holds in pool ``name``."""
+    pool = np.asarray(predictor._scope.find_var(name))
+    pages = predictor._slot_pages[slot]
+    return pool[pages].reshape(-1, pool.shape[-1])[:n]
+
+
+def test_a_fused_step_stores_what_a_store_pass_stored(predictor):
+    """The K/V that a step's first half leaves in the pool are the
+    block's FINAL ones: those of a forward over the block with every
+    token committed under the block-causal mask, which is what a prefill
+    of the same tokens computes for its full blocks (and what the store
+    pass wrote).  Two blocks are completed here, each by one step that
+    also opened the next."""
+    seq = _prompt(9, seed=45)
+    tok = int(np.argmax(_admit(predictor, 2, seq)))
+    try:
+        for _ in range(7):                  # positions 9 .. 15
+            seq.append(tok)
+            tok = int(np.argmax(_step(predictor, {2: (tok, len(seq) - 1)})[2]))
+        assert len(seq) == 16
+        _, kv = predictor.prefill(seq)
+        for name, want in zip(predictor.cache_vars, kv):
+            np.testing.assert_allclose(
+                _slot_rows(predictor, 2, name, 16), np.asarray(want)[0, :16],
+                atol=2e-5)
+    finally:
+        predictor.free_slot_pages(2)
+
+
+def test_a_dead_half_writes_no_row_and_takes_no_expert(predictor):
+    """A slot whose token does not complete its block: the rows behind
+    the block (where a dead second half WOULD land) stay as they were,
+    and the step's assignments are those of the block's L rows; the
+    step that completes the block writes them and routes 2L rows."""
+    k = 2                                   # toy_config: top-2, 2 layers
+    seq = _prompt(9, seed=46)
+    tok = int(np.argmax(_admit(predictor, 0, seq)))
+    name = predictor.cache_vars[0]
+    try:
+        before = _slot_rows(predictor, 0, name, 24).copy()
+        _step(predictor, {0: (tok, 9)})     # at 1 of block 8-11
+        stats = predictor.count_decode_stats(predictor.last_decode_stats)
+        after = _slot_rows(predictor, 0, name, 24)
+        assert np.array_equal(after[:8], before[:8])
+        assert np.array_equal(after[12:], before[12:])  # rows 12-15: none
+        assert not np.array_equal(after[8:12], before[8:12])
+        assert stats["moe_assignments"] == L * k * 2
+        _step(predictor, {0: (5, 10)})
+        _step(predictor, {0: (6, 11)})      # completes 8-11, opens 12-15
+        stats = predictor.count_decode_stats(predictor.last_decode_stats)
+        last = _slot_rows(predictor, 0, name, 24)
+        assert not np.array_equal(last[12:16], before[12:16])
+        assert np.array_equal(last[16:], before[16:])
+        assert stats["moe_assignments"] == 2 * L * k * 2
+    finally:
+        predictor.free_slot_pages(0)
+
+
 def test_opening_a_block_needs_its_pages(predictor):
-    """A blocking step whose token completes a block opens the next one:
-    without pages for it the step is refused, not written to page 0."""
+    """A blocking step whose token completes a block opens the next one
+    in the same forward: without pages for it the step is refused, not
+    written to page 0."""
     prompt = _prompt(7, seed=40)
     logits, kv = predictor.prefill(prompt)
     predictor.alloc_slot_pages(0, predictor.pages_needed(7, 1))
@@ -485,13 +716,13 @@ def test_streams_are_the_published_loops(predictor, loop, n, m):
     assert gen_lookahead.pool_is_whole(predictor)
 
 
-def test_token_less_turns_are_counted_apart_from_tokens(predictor, loop):
+def test_every_turn_yields_and_fused_slots_are_counted(predictor, loop):
     """Four streams in one pool: ``gen.tokens`` counts tokens emitted,
-    ``gen.block.*`` the forwards, and the ``gen.decode_step`` spans say
-    what each collected step yielded and stored."""
+    ``gen.block.*`` the forwards, those that stored a block and opened
+    the next, and the live rows; the ``gen.decode_step`` spans say what
+    each collected step carried: no turn without a token."""
     m = profiler.runtime_metrics
-    names = ("gen.block.forwards", "gen.block.store_passes",
-             "gen.block.rows")
+    names = ("gen.block.forwards", "gen.block.fused", "gen.block.rows")
     asks = [(_prompt(n, seed=70 + n), k)
             for n, k in [(8, 12), (9, 12), (10, 8), (11, 9)]]
     before = {k: m.counter(k) for k in names}
@@ -508,16 +739,46 @@ def test_token_less_turns_are_counted_apart_from_tokens(predictor, loop):
     assert got == [loop(p, k) for p, k in asks]
     tokens = sum(k for _, k in asks)
     assert gained["gen.tokens"] == tokens
-    forwards, stores, rows = (m.counter(k) - before[k] for k in names)
-    # the prefill gives a stream's first token; every other one a
-    # yielding forward, and every completed block a store pass
-    assert forwards - stores == tokens - len(asks)
-    assert rows == forwards * L and stores >= 4
-    assert sum(a["live"] for a in spans) == forwards
-    assert sum(a["yielded"] for a in spans) == tokens - len(asks)
-    assert sum(a["stored"] for a in spans) == stores
-    assert all(a["block_rows"] == a["live"] * L for a in spans)
+    assert gained["gen.decode.rows_discarded"] == 0
+    forwards, fused, rows = (m.counter(k) - before[k] for k in names)
+    # the prefill gives a stream's first token, a forward every other one
+    assert forwards == tokens - len(asks)
+    # a forward that feeds position p with p + 1 a multiple of L is fused
+    assert fused == sum(
+        1 for p, k in asks for at in range(len(p), len(p) + k - 1)
+        if (at + 1) % L == 0) >= 8
+    assert rows == (forwards + fused) * L
+    assert sum(a["live"] for a in spans) == forwards \
+        == sum(a["yielded"] + a["discarded"] for a in spans)
+    assert sum(a["fused"] for a in spans) == fused
+    assert all(a["stored"] == 0 for a in spans)
+    assert all(a["block_rows"] == (a["live"] + a["fused"]) * L
+               for a in spans)
     assert all("moe_experts_touched" in a for a in spans)
+
+
+def test_a_discarded_row_is_a_forward_without_a_token(predictor, loop):
+    """An EOS the host could not foresee: the step dispatched ahead
+    carried the slot, so over the stream's spans ``live`` = ``yielded``
+    + ``discarded`` with one row discarded."""
+    prompt = _prompt(9, seed=75)
+    want = loop(prompt, 9)
+    k = next(i for i in range(1, 9) if want[i] not in want[:i])
+    ptrace.enable(1 << 12)
+    ptrace.clear()
+    try:
+        with gen_lookahead.scheduler(predictor) as (sched, gained):
+            got = list(sched.submit(prompt, max_new_tokens=9,
+                                    eos_id=want[k]))
+        spans = [s["attrs"] for s in ptrace.snapshot_spans()
+                 if s["name"] == "gen.decode_step" and "live" in s["attrs"]]
+    finally:
+        ptrace.disable()
+    assert got == want[:k + 1]
+    assert gained["gen.decode.rows_discarded"] == 1
+    assert sum(a["discarded"] for a in spans) == 1
+    assert sum(a["yielded"] for a in spans) == k
+    assert sum(a["live"] for a in spans) == k + 1
 
 
 @pytest.mark.parametrize("drill", [
@@ -527,9 +788,10 @@ def test_token_less_turns_are_counted_apart_from_tokens(predictor, loop):
     gen_lookahead.drain_and_abort_in_flight],
     ids=lambda d: d.__name__)
 def test_lookahead_drill(drill, predictor, loop):
-    """The lookahead's drills on a bundle whose turns may yield nothing:
-    an EOS or a cancel in mid-block beside live neighbours (the pages
-    come back), an admission with a step in flight, a drain's
+    """The lookahead's drills on a bundle whose steps carry blocks (the
+    device commits its own pick to the block, also where that completes
+    it): an EOS or a cancel in mid-block beside live neighbours (the
+    pages come back), an admission with a step in flight, a drain's
     checkpoints and a kill at a whole-token boundary."""
     drill(predictor, loop)
 

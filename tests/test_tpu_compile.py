@@ -224,6 +224,37 @@ def test_block_paged_decode_compiles_for_v5e(one_chip, pages):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("pages", [8, 256])
+def test_two_block_paged_decode_compiles_for_v5e(one_chip, pages):
+    """The decode kernel handed TWO blocks of 4 rows a slot with a limit
+    a row (the block that is stored sees 4 rows fewer than the block that
+    is opened; a slot that opens none has 4 dead rows) at the
+    block-diffusion serving cell's shapes: 64 query rows share a K/V
+    head's copy, under a ``[64, 1]`` column of limits."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, L, NP, PL, H, HKV, D = 32, 4, 8192, 16, 32, 4, 128
+
+    def fn(q, kc, vc, pt):
+        lens = _ragged_lens(S, pages, PL)
+        half = jnp.arange(2 * L)[None, :] // L
+        # every other slot opens a block; the others' second half is dead
+        opens = (jnp.arange(S) % 2 == 0)[:, None]
+        row_lens = jnp.where(half == 0, jnp.maximum(lens - L, 0),
+                             jnp.where(opens, lens, 0))
+        out = A._pallas_paged_attention(
+            q, kc, vc, pt, jnp.max(row_lens, axis=1, keepdims=True), H,
+            D ** -0.5, interpret=False, row_lens=row_lens)
+        assert out is not None, "shape gate refused two blocks of rows"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 2 * L, H * D), jnp.bfloat16),
+                   ((NP, PL, HKV * D), jnp.bfloat16),
+                   ((NP, PL, HKV * D), jnp.bfloat16),
+                   ((S, pages), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
 def test_seed_of_pages_and_state_compiles_for_v5e_in_place(one_chip):
     """Admission's compiled seed with per-slot state beside the pool, at
     the hybrid serving cell's widths: 2 pools of 4096 pages x 16 rows x
