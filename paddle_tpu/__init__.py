@@ -12,7 +12,7 @@ from paddle_tpu import framework
 from paddle_tpu.framework import (
     Program, Block, Operator, Variable, Parameter,
     default_main_program, default_startup_program, program_guard,
-    switch_main_program, switch_startup_program, unique_name,
+    name_scope, switch_main_program, switch_startup_program, unique_name,
 )
 from paddle_tpu.place import CPUPlace, TPUPlace, CUDAPlace, is_tpu_available
 from paddle_tpu.scope import Scope, global_scope, scope_guard

@@ -170,7 +170,9 @@ def constant_fold_pass(program, ctx):
     (``fill_constant``/``assign_value``) by evaluating them host-side
     and replacing each with a single ``assign_value`` carrying the
     result — shape-arithmetic scaffolding compiles to data instead of
-    HLO.  Folded-away producers become dead and fall to the DCE pass."""
+    HLO.  Folded-away producers become dead and fall to the DCE pass.
+    The replacement keeps the folded op's ``op_namescope`` / ``op_role``
+    (the last op of the chain: the one whose output it writes)."""
     from paddle_tpu.ops import registry
     block = program.global_block()
     const_env = {}
@@ -199,7 +201,8 @@ def constant_fold_pass(program, ctx):
                     and str(result.dtype) in _FOLDABLE_DTYPES \
                     and _int_fits(result):
                 const_env[out_name] = result
-                rep = _assign_value_op(block, out_name, result)
+                rep = framework.copy_op_annotations(
+                    op, _assign_value_op(block, out_name, result))
                 rep.attrs[RNG_SLOTS_ATTR] = _rng_slots(op)
                 folded += 1
                 new_ops.append(rep)
@@ -238,10 +241,15 @@ def _int_fits(value):
 # common subexpression elimination
 # ---------------------------------------------------------------------------
 
+#: attributes that say where an op sits, not what it computes
+_NOT_SEMANTIC_ATTRS = (RNG_SLOTS_ATTR, framework.OP_NAMESCOPE_ATTR,
+                       framework.OP_ROLE_ATTR)
+
+
 def _attr_key(attrs):
     parts = []
     for k in sorted(attrs):
-        if k == RNG_SLOTS_ATTR:
+        if k in _NOT_SEMANTIC_ATTRS:
             continue
         v = attrs[k]
         if isinstance(v, framework.Block):
@@ -259,7 +267,8 @@ def _attr_key(attrs):
 def cse_pass(program, ctx):
     """Deduplicate pure ops with identical ``(type, inputs, attrs)``:
     the later op is dropped and its consumers read the earlier op's
-    outputs.  Only single-writer names participate (renaming is unsafe
+    outputs (``op_namescope`` / ``op_role`` are no part of the identity:
+    the surviving, earlier op keeps its own).  Only single-writer names participate (renaming is unsafe
     off SSA), and protected names (fetches, feeds, persistables,
     sub-block reads) are never renamed away."""
     from paddle_tpu.ops import registry
@@ -366,7 +375,8 @@ def fuse_elementwise_pass(program, ctx):
     lowerings inside a single traced closure: one op's worth of
     per-op trace overhead (named_scope, context, RNG slot) instead of
     k, with identical array semantics (the member lowerings ARE the
-    semantics)."""
+    semantics).  The fused op carries the FIRST member's
+    ``op_namescope`` / ``op_role``."""
     from paddle_tpu.ops import registry
     block = program.global_block()
     ops = block.ops
@@ -423,7 +433,7 @@ def fuse_elementwise_pass(program, ctx):
                        {"sub_ops": [op.to_dict() for op in run],
                         RNG_SLOTS_ATTR: sum(_rng_slots(op)
                                             for op in run)})
-        new_ops.append(fop)
+        new_ops.append(framework.copy_op_annotations(run[0], fop))
         fused += 1
         fused_members += len(run)
         i = j + 1

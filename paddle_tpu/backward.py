@@ -62,8 +62,30 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                     callbacks=None, target_gradient=None):
     """Append grad ops for ``loss``; returns list of (param, grad_var)
     (reference ``backward.py:425``).  ``target_gradient`` optionally seeds
-    d(loss) with a caller-supplied cotangent Variable instead of ones."""
+    d(loss) with a caller-supplied cotangent Variable instead of ones.
+
+    Every op appended here carries ``op_role`` = ``backward``; a grad op
+    (and the sum of an op's repeated output gradients) inherits its
+    forward op's ``op_namescope``, the seed that of the op that made the
+    loss."""
     assert isinstance(loss, framework.Variable)
+    with framework.op_role_guard(loss.block.program,
+                                 framework.ROLE_BACKWARD):
+        return _append_backward(loss, parameter_list, no_grad_set,
+                                callbacks, target_gradient)
+
+
+def _scope_attrs(op, attrs=None):
+    """``attrs`` (a copy) under ``op``'s name scope, if it has one."""
+    attrs = dict(attrs or {})
+    scope = op.attr(framework.OP_NAMESCOPE_ATTR) if op is not None else None
+    if scope:
+        attrs[framework.OP_NAMESCOPE_ATTR] = scope
+    return attrs
+
+
+def _append_backward(loss, parameter_list, no_grad_set, callbacks,
+                     target_gradient):
     block = loss.block
     program = block.program
     no_grad = _collect_no_grad_set(block, no_grad_set)
@@ -72,16 +94,19 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
 
     # seed: d loss / d loss = 1 (or the supplied cotangent)
     loss_grad_name = grad_var_name(loss.name)
+    loss_op = loss.op
     if target_gradient is not None:
         block.append_op(type="assign",
                         inputs={"X": [target_gradient.name]},
-                        outputs={"Out": [loss_grad_name]})
+                        outputs={"Out": [loss_grad_name]},
+                        attrs=_scope_attrs(loss_op))
     else:
         block.append_op(
             type="fill_constant",
             outputs={"Out": [loss_grad_name]},
-            attrs={"shape": list(loss.shape or (1,)), "value": 1.0,
-                   "dtype": loss.dtype})
+            attrs=_scope_attrs(loss_op, {
+                "shape": list(loss.shape or (1,)), "value": 1.0,
+                "dtype": loss.dtype}))
     gv = block.create_var(name=loss_grad_name, shape=loss.shape or (1,),
                           dtype=loss.dtype)
     gv.stop_gradient = True
@@ -111,7 +136,8 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                 # sum into the canonical name (reference _addup_repetitive_)
                 tmp = unique_name(summed + "@RENAME")
                 block.append_op(type="sum", inputs={"X": list(glist)},
-                                outputs={"Out": [tmp]})
+                                outputs={"Out": [tmp]},
+                                attrs=_scope_attrs(op))
                 v0 = block.var(glist[0])
                 nv = block.create_var(name=tmp, shape=v0.shape,
                                       dtype=v0.dtype)
@@ -184,7 +210,7 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                 actual_outputs[slot] = renamed
             gop = block.append_op(type=desc["type"], inputs=actual_inputs,
                                   outputs=actual_outputs,
-                                  attrs=desc["attrs"])
+                                  attrs=_scope_attrs(op, desc["attrs"]))
             if callbacks:
                 for cb in callbacks:
                     cb(block, gop)

@@ -1223,8 +1223,9 @@ def _cmd_profile_step(args):
     """``paddle_tpu profile step``: N measured steps with the per-step
     breakdown armed (feed / dispatch / device-wait / fetch series) —
     composed with the jax.profiler plumbing via ``--trace-dir`` for an
-    XProf/Perfetto device timeline of the same window — plus the live
-    MFU the window sustained."""
+    XProf/Perfetto device timeline of the same window, reduced to the
+    device-time table by role / name scope / op type — plus the live MFU
+    the window sustained."""
     from paddle_tpu import profiler
     from paddle_tpu.obs import perf
 
@@ -1251,6 +1252,11 @@ def _cmd_profile_step(args):
     if mfu is not None:
         print(f"train.mfu={mfu:.4f} ({basis})")
     if args.trace_dir:
+        # where the device's time went, by role / name scope / op type
+        # (leaf events, mean over the chips: profiler.compiled_op_groups)
+        table, _ = profiler.compiled_op_table(
+            args.trace_dir, args.sorted_by, by=("role", "scope", "type"))
+        print(table)
         print(f"device trace written under {args.trace_dir} "
               f"(TensorBoard/XProf or Perfetto)")
     return 0

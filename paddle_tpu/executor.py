@@ -229,18 +229,58 @@ def jit_cache_capacity():
         return 64
 
 
+@contextlib.contextmanager
+def _first_call(program, feed_arrays):
+    """The ``executor.compile`` span, and for a program that carries name
+    scopes or op roles a persistent-cache key that includes metadata:
+    jax's key strips it by default, so a cache shared with a build that
+    lowers the same HLO under other scope names (the parent of the PR
+    that named them) would hand back ITS executable, stale ``op_name``s
+    included, and the device trace would attribute nothing.  A program
+    without annotations keeps the key it always had."""
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    annotated = any(
+        framework.OP_NAMESCOPE_ATTR in op.attrs
+        or framework.OP_ROLE_ATTR in op.attrs
+        for block in program.blocks for op in block.ops)
+    before = getattr(jax.config, flag)
+    if annotated:
+        jax.config.update(flag, True)
+    try:
+        with _span("executor.compile", program=id(program),
+                   version=program._version,
+                   feeds=sorted((n, str(a.dtype), tuple(a.shape))
+                                for n, a in feed_arrays.items())):
+            yield
+    finally:
+        if annotated:
+            jax.config.update(flag, before)
+
+
 class _CompiledBlock:
-    """A traced+jitted block for one feed/fetch signature."""
+    """A traced+jitted block for one feed/fetch signature.  ``place``
+    (mesh path only) puts the step's arguments where the executable's
+    shardings want them: ``place(span, *args) -> args``."""
 
     def __init__(self, fn, feed_names, ro_names, inout_names, fetch_names,
-                 uses_rng):
+                 uses_rng, place=None):
         self.fn = fn
+        self.place = place
         self.feed_names = feed_names
         self.ro_names = ro_names
         self.inout_names = inout_names
         self.fetch_names = fetch_names
         self.uses_rng = uses_rng
         self.fresh = True   # until its first call, which compiles it
+
+    def call(self, *args):
+        """Place (mesh path) and launch: ``executor.place`` and
+        ``executor.launch``, the last two stretches of a dispatch."""
+        if self.place is not None:
+            with _span("executor.place") as placed:
+                args = self.place(placed, *args)
+        with _span("executor.launch"):
+            return self.fn(*args)
 
 
 class ScopeEnv(dict):
@@ -329,8 +369,12 @@ def lower_block(block, env, rng_key, training, aux):
             # emitted HLO op's metadata, so XProf traces of the COMPILED
             # step attribute device time back to IR ops (reference
             # platform/profiler.h RecordEvent — here the attribution
-            # survives jit; see profiler.compiled_op_table)
-            with jax.named_scope(_profiler.op_scope_name(op)):
+            # survives jit; see profiler.compiled_op_table), behind the
+            # op's role and name scopes (framework.name_scope) where it
+            # carries them: pt_step/bwd/enc0/self_attn/core/ptop_...
+            with contextlib.ExitStack() as scopes:
+                for part in _profiler.op_scope_path(op):
+                    scopes.enter_context(jax.named_scope(part))
                 opdef.lower(ctx)
         env.update(ctx.outputs)
         if probing:
@@ -417,10 +461,7 @@ class Executor:
         recompiled and for how long.  Nothing on a cache hit."""
         if not fresh:
             return _NO_SPAN
-        return _span("executor.compile", program=id(program),
-                     version=program._version,
-                     feeds=sorted((n, str(a.dtype), tuple(a.shape))
-                                  for n, a in feed_arrays.items()))
+        return _first_call(program, feed_arrays)
 
     # ------------------------------------------------------------------
     def run(self, program=None, feed=None, fetch_list=None, scope=None,
@@ -509,7 +550,9 @@ class Executor:
                     return_numpy, sentinel=None):
         """Body of :meth:`run`, phase-annotated: ``executor.feed``
         (host->device conversion + reader pre-pass), ``executor.dispatch``
-        (compile lookup + XLA launch), ``executor.fetch`` (state
+        (``executor.lookup`` the compile lookup, ``executor.state`` the
+        state gathered from the scope, on a mesh ``executor.place``,
+        ``executor.launch`` the XLA launch), ``executor.fetch`` (state
         write-back + host conversion) — the spans that answer "where did
         step N spend its time"."""
         from paddle_tpu.obs import perf as _perf
@@ -550,14 +593,17 @@ class Executor:
         feed_dt = time.perf_counter() - t_feed
 
         with _span("executor.dispatch") as dsp:
-            compiled = self._get_compiled(program, block, feed_arrays,
-                                          tuple(fetch_names), scope,
-                                          donate=sentinel is None)
+            with _span("executor.lookup"):
+                compiled = self._get_compiled(program, block, feed_arrays,
+                                              tuple(fetch_names), scope,
+                                              donate=sentinel is None)
 
-            ro_state = {n: self._state_value(scope, n, device)
-                        for n in compiled.ro_names}
-            inout_state = {n: self._state_value(scope, n, device)
-                           for n in compiled.inout_names}
+            with _span("executor.state") as gathered:
+                ro_state = {n: self._state_value(scope, n, device)
+                            for n in compiled.ro_names}
+                inout_state = {n: self._state_value(scope, n, device)
+                               for n in compiled.inout_names}
+                gathered.set(arrays=len(ro_state) + len(inout_state))
 
             self._run_counter += 1
             key = _step_key(
@@ -566,8 +612,8 @@ class Executor:
             t0 = time.perf_counter()
             fresh, compiled.fresh = compiled.fresh, False
             with self._compile_span(fresh, program, feed_arrays):
-                fetches, new_state = compiled.fn(feed_arrays, ro_state,
-                                                 inout_state, key)
+                fetches, new_state = compiled.call(feed_arrays, ro_state,
+                                                   inout_state, key)
             dsp.set(fetches=len(fetch_names))
         dt = time.perf_counter() - t0
         from paddle_tpu import profiler as _profiler
@@ -787,9 +833,11 @@ class Executor:
                           scope, return_numpy):
         """Body of :meth:`run_steps` in the three phases :meth:`run` has,
         under the same span names: ``executor.feed`` (staging the window's
-        batches), ``executor.dispatch`` (compile lookup, state gather, the
-        one launch) and ``executor.fetch`` (state write-back and the host
-        conversion, which blocks until the device is done)."""
+        batches), ``executor.dispatch`` (``executor.lookup``: signature
+        and jit cache; ``executor.state``: the carry gathered from the
+        scope; ``executor.launch``: the one call) and ``executor.fetch``
+        (state write-back and the host conversion, which blocks until the
+        device is done)."""
         with _span("executor.feed"):
             device = self._feed_device()
             per_step_feed = {}
@@ -875,21 +923,47 @@ class Executor:
             per_step_feed.update(reader_feed)
 
         with _span("executor.dispatch"):
-            sample = dict(const_feed)
-            sample.update({n: a[0] for n, a in per_step_feed.items()})
-            parts = self._prepare(program, block, sample, tuple(fetch_names),
-                                  scope)
-            sig = parts["sig"] + ("run_steps", steps,
-                                  tuple(sorted(per_step_feed)))
-            step = parts["step"]
-            inout_names = parts["inout_names"]
-            create_state = parts["create_state"]
-            ro_names = parts["ro_names"]
+            with _span("executor.lookup"):
+                sample = dict(const_feed)
+                sample.update({n: a[0] for n, a in per_step_feed.items()})
+                parts = self._prepare(program, block, sample,
+                                      tuple(fetch_names), scope)
+                sig = parts["sig"] + ("run_steps", steps,
+                                      tuple(sorted(per_step_feed)))
+                step = parts["step"]
+                inout_names = parts["inout_names"]
+                create_state = parts["create_state"]
+                ro_names = parts["ro_names"]
+                fresh = False
+                if not parts["interpret"]:
+                    fn, fresh = self._scan_fn(sig, step, steps, fetch_names,
+                                              per_step_feed or const_feed)
 
-            ro_state = {n: self._state_value(scope, n, device)
-                        for n in ro_names}
-            inout_state = {n: self._state_value(scope, n, device)
-                           for n in inout_names}
+            with _span("executor.state") as gathered:
+                ro_state = {n: self._state_value(scope, n, device)
+                            for n in ro_names}
+                inout_state = {n: self._state_value(scope, n, device)
+                               for n in inout_names}
+                carry = dict(inout_state)
+                if not parts["interpret"]:
+                    # write-only persistables (create_state) ride the carry
+                    # too so the final value lands back in the scope like
+                    # run() does; uninitialized ones are seeded with zeros
+                    # of their traced shape
+                    missing = [n for n in create_state if n not in carry]
+                    for n in missing:
+                        if scope.find_var(n) is not None:
+                            carry[n] = self._state_value(scope, n, device)
+                    still = [n for n in missing if n not in carry]
+                    if still:
+                        _, out_shapes = jax.eval_shape(
+                            step, sample, ro_state, inout_state,
+                            jax.random.PRNGKey(0))
+                        for n in still:
+                            if n in out_shapes:
+                                sd = out_shapes[n]
+                                carry[n] = jnp.zeros(sd.shape, sd.dtype)
+                gathered.set(arrays=len(ro_state) + len(carry))
 
             self._run_counter += 1
             base_key = jax.random.PRNGKey(
@@ -914,58 +988,11 @@ class Executor:
                 return [np.asarray(v) for v in stacked] if return_numpy \
                     else stacked
 
-            from paddle_tpu import profiler as _profiler
-            fresh = sig not in self._cache
-            if not fresh:
-                self._cache[sig] = self._cache.pop(sig)
-                fn = self._cache[sig]
-                _profiler.runtime_metrics.inc("jit_cache.hits")
-            else:
-                _profiler.runtime_metrics.inc("jit_cache.misses")
-                def multi(const_feeds, per_feeds, ro_state, carry, base_key):
-                    keys = jax.random.split(base_key, steps)
-
-                    def body(carry, xs):
-                        key, step_feeds = xs
-                        feeds = dict(const_feeds)
-                        feeds.update(step_feeds)
-                        fetches, new_state = step(feeds, ro_state, carry, key)
-                        new_carry = {n: new_state.get(n, carry[n])
-                                     for n in carry}
-                        return new_carry, tuple(fetches)
-
-                    carry, ys = jax.lax.scan(body, carry, (keys, per_feeds))
-                    return ys, carry
-
-                fn = jax.jit(multi, donate_argnums=(3,))
-                from paddle_tpu.obs import perf as _perf
-                if _perf.capture_enabled():
-                    fn = _perf.instrument_jit(
-                        fn, label=_perf.jit_label(
-                            per_step_feed or const_feed, fetch_names,
-                            tag=f"scan{steps}"))
-                self._cache_insert(sig, fn)
-
-            carry = dict(inout_state)
-            # write-only persistables (create_state) ride the carry too so the
-            # final value lands back in the scope like run() does; uninitialized
-            # ones are seeded with zeros of their traced shape
-            missing = [n for n in create_state if n not in carry]
-            seeded = [n for n in missing if scope.find_var(n) is not None]
-            for n in seeded:
-                carry[n] = self._state_value(scope, n, device)
-            still = [n for n in missing if n not in carry]
-            if still:
-                _, out_shapes = jax.eval_shape(
-                    step, sample, ro_state, inout_state, jax.random.PRNGKey(0))
-                for n in still:
-                    if n in out_shapes:
-                        sd = out_shapes[n]
-                        carry[n] = jnp.zeros(sd.shape, sd.dtype)
             t0 = time.perf_counter()
             with self._compile_span(fresh, program, sample):
-                ys, final = fn(const_feed, per_step_feed, ro_state, carry,
-                               base_key)
+                with _span("executor.launch"):
+                    ys, final = fn(const_feed, per_step_feed, ro_state,
+                                   carry, base_key)
         with _span("executor.fetch"):
             for n, v in final.items():
                 scope.set_var(n, v)
@@ -988,6 +1015,42 @@ class Executor:
                             flops_scale=steps)
         _perf.census_tick(scope)
         return result
+
+    # ------------------------------------------------------------------
+    def _scan_fn(self, sig, step, steps, fetch_names, label_feeds):
+        """``(fn, fresh)``: the jitted ``steps``-step scan over ``step``
+        for ``sig``, from the jit cache or built (``fresh``: its first
+        call compiles)."""
+        from paddle_tpu import profiler as _profiler
+        if sig in self._cache:
+            self._cache[sig] = self._cache.pop(sig)
+            _profiler.runtime_metrics.inc("jit_cache.hits")
+            return self._cache[sig], False
+        _profiler.runtime_metrics.inc("jit_cache.misses")
+
+        def multi(const_feeds, per_feeds, ro_state, carry, base_key):
+            keys = jax.random.split(base_key, steps)
+
+            def body(carry, xs):
+                key, step_feeds = xs
+                feeds = dict(const_feeds)
+                feeds.update(step_feeds)
+                fetches, new_state = step(feeds, ro_state, carry, key)
+                new_carry = {n: new_state.get(n, carry[n])
+                             for n in carry}
+                return new_carry, tuple(fetches)
+
+            carry, ys = jax.lax.scan(body, carry, (keys, per_feeds))
+            return ys, carry
+
+        fn = jax.jit(multi, donate_argnums=(3,))
+        from paddle_tpu.obs import perf as _perf
+        if _perf.capture_enabled():
+            fn = _perf.instrument_jit(
+                fn, label=_perf.jit_label(label_feeds, fetch_names,
+                                          tag=f"scan{steps}"))
+        self._cache_insert(sig, fn)
+        return fn, True
 
     # ------------------------------------------------------------------
     def run_pipeline(self, program=None, pipeline=None, fetch_list=None,

@@ -34,6 +34,7 @@ __all__ = [
     "default_main_program",
     "default_startup_program",
     "program_guard",
+    "name_scope",
     "switch_main_program",
     "switch_startup_program",
     "unique_name",
@@ -122,6 +123,70 @@ GRAD_SUFFIX = "@GRAD"
 
 def grad_var_name(name):
     return name + GRAD_SUFFIX
+
+
+# ---------------------------------------------------------------------------
+# name scopes and op roles (reference ``fluid.name_scope`` -> the
+# ``op_namescope`` attribute; ``OpRole`` Forward / Backward / Optimize)
+# ---------------------------------------------------------------------------
+
+OP_NAMESCOPE_ATTR = "op_namescope"
+OP_ROLE_ATTR = "op_role"
+ROLE_FORWARD, ROLE_BACKWARD, ROLE_OPTIMIZE = "forward", "backward", "optimize"
+
+_name_scopes = []       # the open name scopes, outermost first
+_op_role = None         # (program, role) while a transpiler appends ops
+
+
+@contextlib.contextmanager
+def name_scope(name):
+    """Name the part of the model the ops appended inside belong to:
+    each gets the attribute ``op_namescope`` = the ``/``-joined path of
+    the open scopes (``enc0/self_attn/core``).  Nestable.  It adds no op
+    and no variable and leaves ``unique_name`` alone, so a program built
+    under scopes computes what it computed without them; the executor
+    carries the path into the device trace (docs/observability.md)."""
+    name = str(name)
+    if not name.strip("/"):
+        raise ValueError("name_scope needs a non-empty name")
+    _name_scopes.append(name.strip("/"))
+    try:
+        yield
+    finally:
+        _name_scopes.pop()
+
+
+@contextlib.contextmanager
+def op_role_guard(program, role):
+    """Ops appended to ``program`` inside carry ``op_role`` = ``role``
+    (``append_backward``: backward; ``Optimizer.minimize`` after it:
+    optimize).  An op without the attribute is a forward op."""
+    global _op_role
+    saved, _op_role = _op_role, (program, role)
+    try:
+        yield
+    finally:
+        _op_role = saved
+
+
+def _annotate(op):
+    """Stamp a freshly appended op with the open name scope and role; an
+    attribute the caller set (a grad op's inherited scope) stays."""
+    if _name_scopes and OP_NAMESCOPE_ATTR not in op.attrs:
+        op.attrs[OP_NAMESCOPE_ATTR] = "/".join(_name_scopes)
+    if _op_role is not None and _op_role[0] is op.block.program \
+            and OP_ROLE_ATTR not in op.attrs:
+        op.attrs[OP_ROLE_ATTR] = _op_role[1]
+    return op
+
+
+def copy_op_annotations(src, dst):
+    """``dst`` takes ``src``'s name scope and role (a pass that replaces
+    or fuses ops keeps the first op's)."""
+    for key in (OP_NAMESCOPE_ATTR, OP_ROLE_ATTR):
+        if key in src.attrs:
+            dst.attrs[key] = src.attrs[key]
+    return dst
 
 
 # ---------------------------------------------------------------------------
@@ -388,21 +453,21 @@ class Block:
 
     # -- ops ---------------------------------------------------------------
     def append_op(self, type, inputs=None, outputs=None, attrs=None):
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = _annotate(Operator(self, type, inputs, outputs, attrs))
         self.ops.append(op)
         self._infer_shape(op)
         self.program.bump_version()
         return op
 
     def prepend_op(self, type, inputs=None, outputs=None, attrs=None):
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = _annotate(Operator(self, type, inputs, outputs, attrs))
         self.ops.insert(0, op)
         self._infer_shape(op)
         self.program.bump_version()
         return op
 
     def insert_op(self, index, type, inputs=None, outputs=None, attrs=None):
-        op = Operator(self, type, inputs, outputs, attrs)
+        op = _annotate(Operator(self, type, inputs, outputs, attrs))
         self.ops.insert(index, op)
         self._infer_shape(op)
         self.program.bump_version()

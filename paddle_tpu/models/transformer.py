@@ -10,6 +10,14 @@ and one fused softmax(QK^T)V per head group.
 
 Used as the flagship model for ``__graft_entry__.py`` / ``bench.py``
 (north star: Transformer-base tokens/sec/chip, BASELINE.json).
+
+Every op is built under a ``name_scope`` whose names are API
+(docs/observability.md): ``embed``; ``enc<i>`` / ``dec<i>`` >
+``self_attn`` | ``cross_attn`` | ``ffn``; inside an attention sublayer
+``proj`` (the four ``fc`` and the head split / merge) and ``core`` (score
+product, masked softmax, context product, or the one fused op); ``post``
+around each ``pre_post_process_layer``; ``head`` (vocabulary ``fc`` and the
+loss).  The device trace carries them (``profiler.compiled_op_table``).
 """
 
 from __future__ import annotations
@@ -17,6 +25,7 @@ from __future__ import annotations
 import numpy as np
 
 import paddle_tpu.layers as layers
+from paddle_tpu.framework import name_scope
 from paddle_tpu.initializer import NumpyArrayInitializer
 from paddle_tpu.param_attr import ParamAttr
 
@@ -105,22 +114,22 @@ def multi_head_attention(queries, keys, values, d_key, d_value, d_model,
         # address parameters by role
         return ParamAttr(name=f"{prefix}_{role}.w") if prefix else None
 
-    q = layers.fc(queries, d_key * n_head, num_flatten_dims=2,
-                  bias_attr=False, param_attr=pa("q"))
-    k = layers.fc(keys, d_key * n_head, num_flatten_dims=2, bias_attr=False,
-                  param_attr=pa("k"))
-    v = layers.fc(values, d_value * n_head, num_flatten_dims=2,
-                  bias_attr=False, param_attr=pa("v"))
-
     def split_heads(x, d_per_head):
         # [B, S, H*D] -> [B, H, S, D]
         b, s = x.shape[0], x.shape[1]
         x = layers.reshape(x, shape=[b, s, n_head, d_per_head])
         return layers.transpose(x, perm=[0, 2, 1, 3])
 
-    q = split_heads(q, d_key)
-    k = split_heads(k, d_key)
-    v = split_heads(v, d_value)
+    with name_scope("proj"):
+        q = layers.fc(queries, d_key * n_head, num_flatten_dims=2,
+                      bias_attr=False, param_attr=pa("q"))
+        k = layers.fc(keys, d_key * n_head, num_flatten_dims=2,
+                      bias_attr=False, param_attr=pa("k"))
+        v = layers.fc(values, d_value * n_head, num_flatten_dims=2,
+                      bias_attr=False, param_attr=pa("v"))
+        q = split_heads(q, d_key)
+        k = split_heads(k, d_key)
+        v = split_heads(v, d_value)
     scale = float(d_key) ** -0.5
 
     # the VMEM-fused kernel wins once the [S,S] score tensor dominates HBM
@@ -144,34 +153,36 @@ def multi_head_attention(queries, keys, values, d_key, d_value, d_model,
     seq_parallel = _env_flag("PADDLE_TPU_SEQ_PARALLEL") and \
         keys is queries and k_mask is None
 
-    if seq_parallel and not dropout_rate:
-        ctx = layers.ring_attention(q, k, v, causal=causal, scale=scale)
-    elif use_flash and not dropout_rate:
-        ctx = layers.fused_attention(q, k, v, k_mask=k_mask, causal=causal,
-                                     scale=scale)
-    else:
-        product = layers.matmul(q, k, transpose_y=True, alpha=scale)
-        # fold the mask into the softmax op: under bf16 AMP the [B,H,S,S]
-        # scores then stay bf16 in HBM (an f32 add would otherwise promote
-        # and double the attention hot spot's traffic); softmax itself
-        # computes in f32 internally
-        bias = None
-        if k_mask is not None:
-            bias = _shared_padding_bias(k_mask)
-        if causal:
-            cb = _shared_causal_bias(q.block, q.shape[2])
-            bias = cb if bias is None else bias + cb
-        weights = layers.softmax(product, bias=bias)
-        if dropout_rate:
-            weights = layers.dropout(weights, dropout_prob=dropout_rate)
-        ctx = layers.matmul(weights, v)
+    with name_scope("core"):
+        if seq_parallel and not dropout_rate:
+            ctx = layers.ring_attention(q, k, v, causal=causal, scale=scale)
+        elif use_flash and not dropout_rate:
+            ctx = layers.fused_attention(q, k, v, k_mask=k_mask,
+                                         causal=causal, scale=scale)
+        else:
+            product = layers.matmul(q, k, transpose_y=True, alpha=scale)
+            # fold the mask into the softmax op: under bf16 AMP the
+            # [B,H,S,S] scores then stay bf16 in HBM (an f32 add would
+            # otherwise promote and double the attention hot spot's
+            # traffic); softmax itself computes in f32 internally
+            bias = None
+            if k_mask is not None:
+                bias = _shared_padding_bias(k_mask)
+            if causal:
+                cb = _shared_causal_bias(q.block, q.shape[2])
+                bias = cb if bias is None else bias + cb
+            weights = layers.softmax(product, bias=bias)
+            if dropout_rate:
+                weights = layers.dropout(weights, dropout_prob=dropout_rate)
+            ctx = layers.matmul(weights, v)
 
-    # [B, H, S, D] -> [B, S, H*D]
-    ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
-    b, s = ctx.shape[0], ctx.shape[1]
-    ctx = layers.reshape(ctx, shape=[b, s, n_head * d_value])
-    return layers.fc(ctx, d_model, num_flatten_dims=2, bias_attr=False,
-                     param_attr=pa("attnout"))
+    with name_scope("proj"):
+        # [B, H, S, D] -> [B, S, H*D]
+        ctx = layers.transpose(ctx, perm=[0, 2, 1, 3])
+        b, s = ctx.shape[0], ctx.shape[1]
+        ctx = layers.reshape(ctx, shape=[b, s, n_head * d_value])
+        return layers.fc(ctx, d_model, num_flatten_dims=2, bias_attr=False,
+                         param_attr=pa("attnout"))
 
 
 def positionwise_feed_forward(x, d_inner_hid, d_hid, prefix=None):
@@ -200,36 +211,51 @@ def pre_post_process_layer(prev_out, out, process_cmd, dropout_rate=0.0):
     return out
 
 
+def _post(prev_out, out, hp):
+    with name_scope("post"):
+        return pre_post_process_layer(prev_out, out, "dan", hp.dropout)
+
+
 def encoder_layer(enc_input, src_mask, hp: ModelHyperParams, idx=0):
-    attn = multi_head_attention(enc_input, None, None,
-                                hp.d_key, hp.d_value, hp.d_model,
-                                hp.n_head, hp.attention_dropout,
-                                k_mask=src_mask, use_flash=hp.use_flash,
-                                prefix=f"enc{idx}_attn")
-    attn = pre_post_process_layer(enc_input, attn, "dan", hp.dropout)
-    ffd = positionwise_feed_forward(attn, hp.d_inner_hid, hp.d_model,
-                                    prefix=f"enc{idx}")
-    return pre_post_process_layer(attn, ffd, "dan", hp.dropout)
+    with name_scope(f"enc{idx}"):
+        with name_scope("self_attn"):
+            attn = multi_head_attention(enc_input, None, None,
+                                        hp.d_key, hp.d_value, hp.d_model,
+                                        hp.n_head, hp.attention_dropout,
+                                        k_mask=src_mask,
+                                        use_flash=hp.use_flash,
+                                        prefix=f"enc{idx}_attn")
+            attn = _post(enc_input, attn, hp)
+        with name_scope("ffn"):
+            ffd = positionwise_feed_forward(attn, hp.d_inner_hid, hp.d_model,
+                                            prefix=f"enc{idx}")
+            return _post(attn, ffd, hp)
 
 
 def decoder_layer(dec_input, enc_output, src_mask, hp: ModelHyperParams,
                   idx=0):
-    self_attn = multi_head_attention(dec_input, None, None,
-                                     hp.d_key, hp.d_value, hp.d_model,
-                                     hp.n_head, hp.attention_dropout,
-                                     causal=True, use_flash=hp.use_flash,
-                                     prefix=f"dec{idx}_self")
-    self_attn = pre_post_process_layer(dec_input, self_attn, "dan",
-                                       hp.dropout)
-    cross = multi_head_attention(self_attn, enc_output, enc_output,
-                                 hp.d_key, hp.d_value, hp.d_model,
-                                 hp.n_head, hp.attention_dropout,
-                                 k_mask=src_mask, use_flash=hp.use_flash,
-                                 prefix=f"dec{idx}_cross")
-    cross = pre_post_process_layer(self_attn, cross, "dan", hp.dropout)
-    ffd = positionwise_feed_forward(cross, hp.d_inner_hid, hp.d_model,
-                                    prefix=f"dec{idx}")
-    return pre_post_process_layer(cross, ffd, "dan", hp.dropout)
+    with name_scope(f"dec{idx}"):
+        with name_scope("self_attn"):
+            self_attn = multi_head_attention(dec_input, None, None,
+                                             hp.d_key, hp.d_value,
+                                             hp.d_model, hp.n_head,
+                                             hp.attention_dropout,
+                                             causal=True,
+                                             use_flash=hp.use_flash,
+                                             prefix=f"dec{idx}_self")
+            self_attn = _post(dec_input, self_attn, hp)
+        with name_scope("cross_attn"):
+            cross = multi_head_attention(self_attn, enc_output, enc_output,
+                                         hp.d_key, hp.d_value, hp.d_model,
+                                         hp.n_head, hp.attention_dropout,
+                                         k_mask=src_mask,
+                                         use_flash=hp.use_flash,
+                                         prefix=f"dec{idx}_cross")
+            cross = _post(self_attn, cross, hp)
+        with name_scope("ffn"):
+            ffd = positionwise_feed_forward(cross, hp.d_inner_hid,
+                                            hp.d_model, prefix=f"dec{idx}")
+            return _post(cross, ffd, hp)
 
 
 def prepare_embedding(ids, pos_ids, vocab_size, hp: ModelHyperParams,
@@ -251,14 +277,16 @@ def prepare_embedding(ids, pos_ids, vocab_size, hp: ModelHyperParams,
 
 
 def encoder(src_ids, src_pos, src_mask, hp: ModelHyperParams):
-    x = prepare_embedding(src_ids, src_pos, hp.src_vocab_size, hp, "src")
+    with name_scope("embed"):
+        x = prepare_embedding(src_ids, src_pos, hp.src_vocab_size, hp, "src")
     for i in range(hp.n_layer):
         x = encoder_layer(x, src_mask, hp, idx=i)
     return x
 
 
 def decoder(trg_ids, trg_pos, enc_output, src_mask, hp: ModelHyperParams):
-    x = prepare_embedding(trg_ids, trg_pos, hp.trg_vocab_size, hp, "trg")
+    with name_scope("embed"):
+        x = prepare_embedding(trg_ids, trg_pos, hp.trg_vocab_size, hp, "trg")
     for i in range(hp.n_layer):
         x = decoder_layer(x, enc_output, src_mask, hp, idx=i)
     return x
@@ -306,24 +334,27 @@ def transformer(batch_size, src_len, trg_len, hp: ModelHyperParams = None,
         src_ids, trg_ids, src_mask, labels, weights = build_inputs(
             batch_size, src_len, trg_len, hp)
 
-    src_pos = _position_ids(batch_size, src_len)
-    trg_pos = _position_ids(batch_size, trg_len)
+    with name_scope("embed"):
+        src_pos = _position_ids(batch_size, src_len)
+        trg_pos = _position_ids(batch_size, trg_len)
 
     enc_out = encoder(src_ids, src_pos, src_mask, hp)
     dec_out = decoder(trg_ids, trg_pos, enc_out, src_mask, hp)
 
-    logits = layers.fc(dec_out, hp.trg_vocab_size, num_flatten_dims=2,
-                       bias_attr=False,
-                       param_attr=ParamAttr(name="proj_logits.w"))
-    logits2d = layers.reshape(
-        logits, shape=[batch_size * trg_len, hp.trg_vocab_size])
-    labels2d = layers.reshape(labels, shape=[batch_size * trg_len, 1])
-    cost = layers.softmax_with_cross_entropy(logits2d, labels2d)
-    weights2d = layers.reshape(weights, shape=[batch_size * trg_len, 1])
-    weighted = cost * weights2d
-    sum_cost = layers.reduce_sum(weighted)
-    token_count = layers.reduce_sum(weights2d)
-    avg_cost = sum_cost / token_count
+    with name_scope("head"):
+        logits = layers.fc(dec_out, hp.trg_vocab_size, num_flatten_dims=2,
+                           bias_attr=False,
+                           param_attr=ParamAttr(name="proj_logits.w"))
+        logits2d = layers.reshape(
+            logits, shape=[batch_size * trg_len, hp.trg_vocab_size])
+        labels2d = layers.reshape(labels, shape=[batch_size * trg_len, 1])
+        cost = layers.softmax_with_cross_entropy(logits2d, labels2d)
+        weights2d = layers.reshape(weights,
+                                   shape=[batch_size * trg_len, 1])
+        weighted = cost * weights2d
+        sum_cost = layers.reduce_sum(weighted)
+        token_count = layers.reduce_sum(weights2d)
+        avg_cost = sum_cost / token_count
     feeds = ["src_word", "trg_word", "src_mask", "lbl_word", "lbl_weight"]
     return avg_cost, feeds
 

@@ -122,14 +122,18 @@ class Optimizer:
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
-        """reference ``optimizer.py:224``."""
+        """reference ``optimizer.py:224``.  What is appended after the
+        backward pass (clipping, regularisation, the learning-rate and
+        accumulator ops, the updates) carries ``op_role`` = ``optimize``."""
         params_grads = append_backward(loss, parameter_list, no_grad_set,
                                        [error_clip_callback])
-        params_grads = append_gradient_clip_ops(params_grads)
-        params_grads = append_regularization_ops(params_grads,
-                                                 self.regularization)
-        optimize_ops = self._create_optimization_pass(params_grads, loss,
-                                                      startup_program)
+        with framework.op_role_guard(loss.block.program,
+                                     framework.ROLE_OPTIMIZE):
+            params_grads = append_gradient_clip_ops(params_grads)
+            params_grads = append_regularization_ops(params_grads,
+                                                     self.regularization)
+            optimize_ops = self._create_optimization_pass(
+                params_grads, loss, startup_program)
         return optimize_ops, params_grads
 
 

@@ -181,9 +181,13 @@ class ParallelExecutor(Executor):
                    "rng_plan": True
                    if getattr(program, "_opt_rng_plan", False)
                    else None}
-            lower_block(block, env, rng_key, training, aux)
-            fetches = [env[n] for n in fetch_names]
-            new_state = {n: env[n] for n in out_state_names if n in env}
+            # the whole-step scope Executor._prepare opens: a scope path
+            # reads pt_step/<role>/<scope...>/ptop_... on the mesh too
+            with jax.named_scope("pt_step"):
+                lower_block(block, env, rng_key, training, aux)
+                fetches = [env[n] for n in fetch_names]
+                new_state = {n: env[n] for n in out_state_names
+                             if n in env}
             return fetches, new_state
 
         # trace once abstractly to learn which state names actually get
@@ -204,25 +208,35 @@ class ParallelExecutor(Executor):
                     tag=f"mesh{tuple(mesh.devices.shape)}"))
         feed_shardings = in_shardings[0]
 
-        def place(a, sharding):
-            # skip the device_put dispatch when already placed (state is
-            # sharded after the first step; only feeds arrive fresh)
-            if getattr(a, "sharding", None) == sharding:
-                return a
-            return jax.device_put(a, sharding)
+        def place_args(span, feeds, ro_state, inout_state, rng_key):
+            """The step's arguments under the executable's shardings
+            (``executor.place``: ``arrays`` looked at, ``moved`` put by
+            a ``device_put``, ``bytes`` those held)."""
+            moved = [0, 0]
 
-        def fn(feeds, ro_state, inout_state, rng_key):
+            def place(a, sharding):
+                # skip the device_put dispatch when already placed (state
+                # is sharded after the first step; only feeds arrive fresh)
+                if getattr(a, "sharding", None) == sharding:
+                    return a
+                moved[0] += 1
+                moved[1] += int(getattr(a, "nbytes", 0))
+                return jax.device_put(a, sharding)
+
             feeds = {n: place(a, feed_shardings[n])
                      for n, a in feeds.items()}
             ro_state = {n: place(a, state_shardings[n])
                         for n, a in ro_state.items()}
             inout_state = {n: place(a, state_shardings[n])
                            for n, a in inout_state.items()}
-            rng_key = jax.device_put(rng_key, repl)
-            return jitted(feeds, ro_state, inout_state, rng_key)
+            rng_key = place(rng_key, repl)
+            span.set(arrays=len(feeds) + len(ro_state) + len(inout_state)
+                     + 1, moved=moved[0], bytes=moved[1])
+            return feeds, ro_state, inout_state, rng_key
 
-        compiled = _CompiledBlock(fn, base.feed_names, base.ro_names,
-                                  base.inout_names, tuple(fetch_names), True)
+        compiled = _CompiledBlock(jitted, base.feed_names, base.ro_names,
+                                  base.inout_names, tuple(fetch_names), True,
+                                  place=place_args)
         compiled.donated = donate
         compiled.perf = getattr(jitted, "perf", None)
         self._cache_insert(sig, compiled)

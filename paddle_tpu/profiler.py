@@ -269,6 +269,56 @@ def op_scope_name(op):
         .replace("/", "_")
 
 
+_ROLE_SCOPES = {"backward": "bwd", "optimize": "opt"}
+
+
+def op_scope_path(op):
+    """The named scopes the executor lowers an op under, outermost first:
+    ``bwd`` / ``opt`` for a backward / optimize op, one a component of
+    its ``op_namescope``, then its ``ptop_`` scope.  An op with neither
+    attribute has the ``ptop_`` scope alone, as it always had."""
+    parts = []
+    role = _ROLE_SCOPES.get(op.attrs.get("op_role"))
+    if role:
+        parts.append(role)
+    scope = op.attrs.get("op_namescope")
+    if scope:
+        parts += [p.replace(".", "_") for p in str(scope).split("/") if p]
+    parts.append(op_scope_name(op))
+    return parts
+
+
+def parse_scope_path(hlo_op_name):
+    """``(role, name scopes, op type)`` of an HLO ``op_name`` path
+    ``…/pt_step/<role>/<scope…>/ptop_<type>__<output>/…``: the role is
+    ``fwd`` where the path names none, the name scopes are the
+    components between the role (or ``pt_step``) and the first ``ptop_``
+    scope, the type is the deepest ``ptop_`` scope's (an op of a
+    sub-block lies inside its control-flow op's scope).  None for a path
+    without a ``ptop_`` scope.  An instruction the compiler made from
+    several (a copy between two ops) carries their paths joined by
+    ``;``: the path named most often counts, the first on a tie."""
+    # the trace's ``tf_op`` stat is ``<op_name>:<op type>``
+    paths = [p.split(":", 1)[0] for p in str(hlo_op_name).split(";")
+             if _SCOPE_PREFIX in p]
+    if not paths:
+        return None
+    hlo_op_name = collections.Counter(paths).most_common(1)[0][0]
+    parts = hlo_op_name.split("/")
+    at = next((i for i, p in enumerate(parts)
+               if p.startswith(_SCOPE_PREFIX)), None)
+    if at is None:
+        return None
+    op_type = parse_op_scope(hlo_op_name)[0]
+    roles = set(_ROLE_SCOPES.values())
+    start = next((i for i in range(at - 1, -1, -1)
+                  if parts[i] in roles or parts[i] == "pt_step"), None)
+    if start is None:
+        return "fwd", (), op_type
+    role = parts[start] if parts[start] in roles else "fwd"
+    return role, tuple(parts[start + 1:at]), op_type
+
+
 def parse_op_scope(hlo_op_name):
     """Deepest ptop_ scope component of an HLO op_name path, as
     (op_type, output_tag), or None."""
@@ -290,8 +340,11 @@ def iter_trace_events(trace_dir, device_only=False, exclude_async=False):
     ``device_only`` restricts to accelerator planes (``/device:...``) so
     host Python-tracer events cannot pollute device-time sums;
     ``exclude_async`` drops 'Async XLA Ops' lines, whose overlapping DMA
-    durations multi-count wall time.  Shared by :func:`compiled_op_table`
-    and the benchmark harnesses."""
+    durations multi-count wall time.  A SUM over every event of every
+    line (parents and the children they enclose alike): right for
+    :func:`scope_device_seconds` over a micro-benchmark's one scope, wrong
+    for a table of a whole step, which :func:`compiled_op_groups` builds
+    from leaf events instead."""
     for plane in _iter_xplanes(trace_dir):
         if device_only and not plane.name.startswith("/device:"):
             continue
@@ -343,15 +396,36 @@ def measure_device_seconds(fn, scope=None):
         shutil.rmtree(td, ignore_errors=True)
 
 
+_xplane_module = None
+
+
+def _xplane_pb2():
+    """The ``XSpace`` protobuf module that ships inside the installed
+    TensorFlow, loaded by its path: importing TensorFlow itself costs
+    seconds and, in the process that holds the chip, loads a second
+    accelerator runtime."""
+    global _xplane_module
+    if _xplane_module is None:
+        import importlib.util
+        try:
+            spec = importlib.util.find_spec("tensorflow")
+            path = os.path.join(os.path.dirname(spec.origin), "tsl",
+                                "profiler", "protobuf", "xplane_pb2.py")
+            inner = importlib.util.spec_from_file_location(
+                "_paddle_tpu_xplane_pb2", path)
+            module = importlib.util.module_from_spec(inner)
+            inner.loader.exec_module(module)
+        except Exception:   # another layout: the package import finds it
+            from tensorflow.tsl.profiler.protobuf import xplane_pb2 as module
+        _xplane_module = module
+    return _xplane_module
+
+
 def _iter_xplanes(trace_dir):
     """Yield every plane of every xplane proto under ``trace_dir``."""
     import glob as _glob
 
-    try:
-        from tensorflow.tsl.profiler.protobuf import xplane_pb2
-    except ImportError:  # pragma: no cover
-        from tsl.profiler.protobuf import xplane_pb2  # type: ignore
-
+    xplane_pb2 = _xplane_pb2()
     for path in _glob.glob(str(trace_dir) + "/**/*.xplane.pb",
                            recursive=True):
         xs = xplane_pb2.XSpace()
@@ -403,31 +477,117 @@ def scope_device_seconds(trace_dir, substring):
     return total_ps / 1e12
 
 
-def compiled_op_table(trace_dir, sorted_key="total"):
-    """Aggregate a jax.profiler trace (xplane protos under ``trace_dir``)
-    into per-IR-op device time, keyed by the named_scope labels the
-    executor emitted.  Returns (table_string, rows) where rows =
-    [(op_type, calls, total_seconds)] sorted descending."""
-    import collections
+def _leaf_op_events(plane):
+    """``(seconds, instruction name, scope path)`` of the events of a
+    device plane's ``XLA Ops`` line that enclose no other (a ``while`` or
+    a ``call`` encloses its body: counting both counts the body twice),
+    and the union of all of them in seconds."""
+    scope_stats = {k for k, m in plane.stat_metadata.items()
+                   if m.name == "tf_op"}
+    events = []
+    for line in plane.lines:
+        if line.name != "XLA Ops":
+            continue
+        for ev in line.events:
+            start = line.timestamp_ns * 1000 + ev.offset_ps
+            events.append((start, start + ev.duration_ps, ev.metadata_id))
+    events.sort(key=lambda e: (e[0], -e[1]))
+    leaves, busy, reach = [], 0, None
+    for i, (start, end, meta_id) in enumerate(events):
+        if reach is None or start > reach:
+            busy += end - start
+            reach = end
+        elif end > reach:
+            busy += end - reach
+            reach = end
+        nxt = events[i + 1] if i + 1 < len(events) else None
+        if nxt is not None and nxt[0] < end and nxt[1] <= end and \
+                (nxt[0] > start or nxt[1] < end):
+            continue
+        meta = plane.event_metadata[meta_id]
+        scope = next((st.str_value or plane.stat_metadata[st.ref_value].name
+                      for st in meta.stats
+                      if st.metadata_id in scope_stats), "")
+        name = meta.name.split(" = ", 1)[0].lstrip("%")
+        leaves.append(((end - start) / 1e12, name, scope))
+    return leaves, busy / 1e12
 
-    agg = collections.Counter()
-    calls = collections.Counter()
-    # exclude_async: overlapping DMA durations otherwise inflate per-op
-    # totals past wall time (the r3 ResNet conv attribution suffered this)
-    for cands, dur in iter_trace_events(trace_dir, exclude_async=True):
-        for c in cands:
-            parsed = parse_op_scope(c)
-            if parsed is not None:
-                agg[parsed[0]] += dur / 1e12
-                calls[parsed[0]] += 1
-                break
-    rows = sorted(((t, calls[t], s) for t, s in agg.items()),
-                  key=lambda r: r[1 if sorted_key == "calls" else 2],
+
+UNSCOPED = "(unscoped)"
+
+
+def compiled_op_groups(trace_dir, by=("type",), depth=2):
+    """Device time of a trace of COMPILED steps by group.  Per device
+    plane only the LEAF events of the ``XLA Ops`` line are counted, and
+    the result is the MEAN over the device planes (one plane a chip).
+
+    ``by`` picks the key: any of ``"role"`` (``fwd`` / ``bwd`` / ``opt``),
+    ``"scope"`` (the ``framework.name_scope`` path, cut to ``depth``
+    components, ``-`` where the op has none) and ``"type"`` (the IR op
+    type), read from the scope path the executor wrote
+    (:func:`parse_scope_path`).  Events under no ``ptop_`` scope (copies,
+    loop glue) are gathered under :data:`UNSCOPED`, so the rows sum to the
+    busy time but for genuine overlap.  Returns ``{"rows": [(*key, calls,
+    seconds)], "remat_seconds": events whose instruction name holds
+    ``.remat`` (an overlay: they are in the rows too), "busy_seconds": the
+    union, "planes": n}``."""
+    unknown = set(by) - {"role", "scope", "type"}
+    if unknown or not by:
+        raise ValueError(f"compiled_op_groups: by={by!r}")
+    seconds, calls = collections.Counter(), collections.Counter()
+    remat = busy = 0.0
+    planes = 0
+    for plane in _iter_xplanes(trace_dir):
+        if not plane.name.startswith("/device:"):
+            continue
+        leaves, plane_busy = _leaf_op_events(plane)
+        if not leaves:
+            continue
+        planes += 1
+        busy += plane_busy
+        for secs, name, scope in leaves:
+            parsed = parse_scope_path(scope) or parse_scope_path(name)
+            if parsed is None:
+                key = (UNSCOPED,) + ("",) * (len(by) - 1)
+            else:
+                fields = {"role": parsed[0],
+                          "scope": "/".join(parsed[1][:depth]) or "-",
+                          "type": parsed[2]}
+                key = tuple(fields[k] for k in by)
+            seconds[key] += secs
+            calls[key] += 1
+            if ".remat" in name:
+                remat += secs
+    n = max(planes, 1)
+    rows = [(*key, max(1, round(calls[key] / n)), secs / n)
+            for key, secs in seconds.items()]
+    return {"rows": rows, "remat_seconds": remat / n,
+            "busy_seconds": busy / n, "planes": planes}
+
+
+def compiled_op_table(trace_dir, sorted_key="total", by=("type",), depth=2):
+    """Aggregate a jax.profiler trace (xplane protos under ``trace_dir``)
+    into per-group device time (:func:`compiled_op_groups`: leaf events
+    only, mean over the chips).  Returns ``(table_string, rows)`` where
+    rows = ``[(*key, calls, total_seconds)]`` sorted descending; with the
+    default ``by`` that is ``[(op_type, calls, total_seconds)]``.  The
+    table ends with the ``.remat`` overlay and the busy union."""
+    groups = compiled_op_groups(trace_dir, by, depth)
+    rows = sorted(groups["rows"],
+                  key=lambda r: r[-2 if sorted_key == "calls" else -1],
                   reverse=True)
-    lines = [f"{'Event':<28}{'Calls':>8}{'Total(ms)':>12}{'Ave(ms)':>12}"]
-    for op_type, n, total in rows:
-        lines.append(f"{op_type:<28}{n:>8}{total * 1e3:>12.3f}"
+    width = 28 if len(by) == 1 else 44
+    lines = [f"{'Event':<{width}}{'Calls':>8}{'Total(ms)':>12}"
+             f"{'Ave(ms)':>12}"]
+    for *key, n, total in rows:
+        label = " ".join(k for k in key if k)
+        lines.append(f"{label:<{width}}{n:>8}{total * 1e3:>12.3f}"
                      f"{total / max(n, 1) * 1e3:>12.3f}")
+    if groups["planes"]:
+        lines.append(f"{'.remat (also counted above)':<{width}}{'':>8}"
+                     f"{groups['remat_seconds'] * 1e3:>12.3f}")
+        lines.append(f"{'busy (union, mean of ' + str(groups['planes']) + ' chips)':<{width}}"
+                     f"{'':>8}{groups['busy_seconds'] * 1e3:>12.3f}")
     return "\n".join(lines), rows
 
 
@@ -630,9 +790,11 @@ def install_jax_compile_listeners():
 
 
 @contextlib.contextmanager
-def compiled_profiler(trace_dir=None, sorted_key="total"):
-    """Trace compiled execution inside the block and print the per-IR-op
-    device-time table on exit (the compiled-path counterpart of
+def compiled_profiler(trace_dir=None, sorted_key="total",
+                      by=("role", "scope", "type"), depth=2):
+    """Trace compiled execution inside the block and print the device-time
+    table on exit, grouped by role / name scope / op type
+    (:func:`compiled_op_table`; the compiled-path counterpart of
     ``op_profiler``, which times interpret mode).  A temp trace dir is
     created — and removed afterwards — unless ``trace_dir`` is given
     (pass one to keep the raw xplane protos)."""
@@ -646,7 +808,7 @@ def compiled_profiler(trace_dir=None, sorted_key="total"):
     finally:
         stop_profiler()
         try:
-            table, _ = compiled_op_table(d, sorted_key)
+            table, _ = compiled_op_table(d, sorted_key, by, depth)
             print(table)
         finally:
             if own:

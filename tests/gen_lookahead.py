@@ -36,10 +36,13 @@ def scheduler(predictor, stall=None):
     try:
         yield sched, gained
         settle(sched)
-        gained.update({k: m.counter(k) - before[k] for k in COUNTERS})
     finally:
         chaos.clear()
         sched.close()
+        # read after the loop thread has ended: the turn that collects the
+        # last step clears ``_in_flight`` BEFORE it counts that step's
+        # rows, so ``settle`` can return inside that turn
+        gained.update({k: m.counter(k) - before[k] for k in COUNTERS})
 
 
 def settle(sched, timeout=30.0):

@@ -28,7 +28,8 @@ def _shell_once(metrics, i, watchdog=None, perf_record=None,
                 ledger=None, health=None):
     """The per-step instrumentation shell of Executor.run_pipeline +
     run AND the fleet-plane hooks the hot loops now carry: one step
-    span, three phase spans, one latency series, the SLO tick the
+    span, three phase spans and the four children of the dispatch, one
+    latency series, the SLO tick the
     GenScheduler loop makes (a None check unarmed; one clock read
     armed-but-not-due), and the device-perf hooks every Executor.run
     now pays — the MFU note (a None check without a compile record; a
@@ -46,8 +47,17 @@ def _shell_once(metrics, i, watchdog=None, perf_record=None,
                             metrics=metrics):
             with trace.span("executor.feed"):
                 pass
-            with trace.span("executor.dispatch"):
-                pass
+            with trace.span("executor.dispatch") as dsp:
+                # the four stretches of a dispatch (place: mesh path)
+                with trace.span("executor.lookup"):
+                    pass
+                with trace.span("executor.state") as gathered:
+                    gathered.set(arrays=2)
+                with trace.span("executor.place") as placed:
+                    placed.set(arrays=3, moved=1, bytes=8)
+                with trace.span("executor.launch"):
+                    pass
+                dsp.set(fetches=1)
             with trace.span("executor.fetch"):
                 pass
     slo.tick(watchdog)
@@ -104,10 +114,17 @@ class TestDisabledTracingOverhead:
 
         def run():
             with trace.span("executor.run"):
-                for phase in ("executor.feed", "executor.dispatch",
-                              "executor.fetch"):
-                    with trace.span(phase):
+                with trace.span("executor.feed"):
+                    pass
+                with trace.span("executor.dispatch"):
+                    with trace.span("executor.lookup"):
                         pass
+                    with trace.span("executor.state") as gathered:
+                        gathered.set(arrays=2)
+                    with trace.span("executor.launch"):
+                        pass
+                with trace.span("executor.fetch"):
+                    pass
 
         def turn(i):
             with trace.span("gen.sched.turn"):
@@ -238,8 +255,108 @@ class TestDisabledTracingOverhead:
         for i in range(100):
             _shell_once(m, i)
         spans = trace.snapshot_spans()
-        assert len(spans) == 256          # ring bound respected (4/step)
+        assert len(spans) == 256          # ring bound respected (8/step)
         assert {"train.step", "executor.feed", "executor.dispatch",
                 "executor.fetch"} <= {s["name"] for s in spans}
         trace.clear()
         trace.disable()
+
+
+class TestDispatchChildren:
+    """``executor.dispatch`` splits into ``executor.lookup`` / ``state``
+    / (mesh path) ``place`` / ``launch``: the same names, in that order,
+    inside the parent, in ``run``, ``run_steps`` and
+    ``ParallelExecutor.run``; nothing is recorded with the ring off."""
+
+    @staticmethod
+    def _program():
+        import paddle_tpu as fluid
+        from paddle_tpu import layers
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            x = layers.data("x", shape=[16], dtype="float32")
+            y = layers.data("y", shape=[1], dtype="int64")
+            out = layers.fc(layers.fc(x, 32, act="relu"), 4, act="softmax")
+            loss = layers.reduce_mean(layers.cross_entropy(out, y))
+            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        return fluid, main, startup, loss
+
+    @staticmethod
+    def _feed(rows=8):
+        import numpy as np
+        rng = np.random.RandomState(0)
+        return {"x": rng.rand(rows, 16).astype("f"),
+                "y": rng.randint(0, 4, (rows, 1)).astype("int64")}
+
+    def _calls(self):
+        """``{how: callable}`` over one program, each compiled already."""
+        import numpy as np
+        from paddle_tpu.parallel import ParallelExecutor
+        from paddle_tpu.parallel.mesh import make_mesh
+        import jax
+        fluid, main, startup, loss = self._program()
+        exe = fluid.Executor(fluid.CPUPlace())
+        feed = self._feed()
+        stacked = {k: np.stack([v, v]) for k, v in feed.items()}
+        mesh = make_mesh((4,), ("data",), devices=jax.devices()[:4])
+        pexe = ParallelExecutor(loss_name=loss.name, main_program=main,
+                                mesh=mesh)
+        # the mesh path leaves its state sharded over the mesh: a scope
+        # of its own
+        single, sharded = fluid.Scope(), fluid.Scope()
+        for scope in (single, sharded):
+            exe.run(startup, scope=scope)
+        calls = {
+            "run": lambda: exe.run(main, feed=feed, fetch_list=[loss],
+                                   scope=single),
+            "run_steps": lambda: exe.run_steps(
+                main, feed=stacked, fetch_list=[loss], steps=2,
+                scope=single),
+            "mesh": lambda: pexe.run(feed=feed, fetch_list=[loss.name],
+                                     scope=sharded)}
+        for call in calls.values():
+            call()      # compile outside what is looked at
+        return calls
+
+    def test_children_in_order_inside_the_parent(self):
+        calls = self._calls()
+        for how, call in calls.items():
+            trace.enable(ring_size=256)
+            trace.clear()
+            try:
+                call()
+                spans = trace.snapshot_spans()
+            finally:
+                trace.disable()
+                trace.clear()
+            (parent,) = [s for s in spans
+                         if s["name"] == "executor.dispatch"]
+            kids = sorted((s for s in spans
+                           if s["parent_id"] == parent["span_id"]),
+                          key=lambda s: s["ts"])
+            want = ["executor.lookup", "executor.state", "executor.launch"]
+            if how == "mesh":
+                want.insert(2, "executor.place")
+            assert [k["name"] for k in kids] == want, how
+            end = parent["ts"] + parent["dur"]
+            for a, b in zip(kids, kids[1:]):
+                assert a["ts"] + a["dur"] <= b["ts"] + 1e-9
+            assert kids[0]["ts"] >= parent["ts"] - 1e-9
+            assert kids[-1]["ts"] + kids[-1]["dur"] <= end + 1e-9
+            by_name = {k["name"]: k["attrs"] for k in kids}
+            assert by_name["executor.state"]["arrays"] > 0
+            if how == "mesh":
+                placed = by_name["executor.place"]
+                # state stays placed after the first step: the feeds
+                # and the key are what moves
+                assert placed["arrays"] > placed["moved"] >= 3
+                assert placed["bytes"] > 0
+            assert not any(s["name"] == "executor.compile" for s in spans)
+
+    def test_nothing_is_recorded_with_the_ring_off(self):
+        calls = self._calls()
+        trace.disable()
+        trace.clear()
+        for call in calls.values():
+            call()
+        assert trace.snapshot_spans() == []

@@ -250,12 +250,20 @@ class TestExecutorSpans:
             trace.clear()
             exe.run_steps(main, feed=feed, fetch_list=[cost], steps=4)
         spans = trace.snapshot_spans()
+        # spans land in the ring when they close: the dispatch's three
+        # children (no mesh: no executor.place) before the dispatch
         assert [s["name"] for s in spans] == [
-            "executor.feed", "executor.dispatch", "executor.fetch",
+            "executor.feed", "executor.lookup", "executor.state",
+            "executor.launch", "executor.dispatch", "executor.fetch",
             "executor.run_steps"]
         top = spans[-1]
         assert top["attrs"]["steps"] == 4
-        assert all(s["parent_id"] == top["span_id"] for s in spans[:3])
+        phases = [s for s in spans[:-1] if s["name"] in (
+            "executor.feed", "executor.dispatch", "executor.fetch")]
+        assert all(s["parent_id"] == top["span_id"] for s in phases)
+        dispatch = phases[1]
+        assert all(s["parent_id"] == dispatch["span_id"]
+                   for s in spans[1:4])
 
     @pytest.mark.parametrize("how", ["run", "run_steps"])
     def test_a_jit_cache_miss_yields_one_compile_span(self, regression,
