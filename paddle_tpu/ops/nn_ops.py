@@ -9,6 +9,7 @@ the MXU) instead of the reference's im2col+GEMM / cuDNN split.
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 
@@ -537,9 +538,95 @@ def _dropout_grad_maker(op, block, no_grad_set):
     return [desc], {x: g_x}
 
 
+# the generator's uint32[4] state is the op's two key words and their xor
+# with these two (the 64-bit golden ratio): a fixed rule, so a mask stays a
+# pure function of the op's key
+_MASK_STATE_SALT = np.array([0x9E3779B9, 0x7F4A7C15], np.uint32)
+
+
+def _mask_threshold(p):
+    """``(bits dtype, width, threshold)`` of a dropout draw: an element is
+    kept where its ``width`` random bits, read as an unsigned integer, are
+    ``>= threshold = round(p * 2**width)``.  16 bits where they give ``p``
+    to 1e-4 relative (0.1 -> 6554/65536 = 0.100006), else 32."""
+    for dtype, width in ((jnp.uint16, 16), (jnp.uint32, 32)):
+        threshold = int(round(p * 2 ** width))
+        if abs(threshold / 2 ** width - p) <= 1e-4 * p:
+            break
+    return dtype, width, threshold
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _draw_bits(key, shape, dtype):
+    # a jit of its own: the TPU compiler rebuilds the generator with no
+    # metadata, and inside a ``run_steps`` scan the instruction then takes
+    # the scan body's; an inlined call hands it the ``ptop_dropout`` scope
+    # the device trace is read by
+    words = jnp.asarray(key, jnp.uint32)
+    state = jnp.concatenate([words, words ^ _MASK_STATE_SALT])
+    return jax.lax.rng_bit_generator(state, shape, dtype=dtype)[1]
+
+
+def _dropout_keep(key, p, shape, mesh=None, batch_axis=0):
+    """The boolean keep mask of a training ``dropout``: XLA's own bit
+    generator (``rng_bit_generator``, one stand-alone instruction on the
+    TPU) seeded from the op's threefry key words, an integer compare
+    against the threshold, no float uniform.
+
+    On a mesh with a ``data`` axis that divides the batch axis, every
+    shard draws its own block from the key with its row-block index
+    folded in: GSPMD cannot partition the generator and would draw the
+    GLOBAL shape on every chip and slice it."""
+    from paddle_tpu.parallel.mesh import DATA_AXIS
+    dtype, width, threshold = _mask_threshold(p)
+    if threshold >= 2 ** width:          # p rounds to 1: nothing is kept
+        return jnp.zeros(shape, jnp.bool_)
+    shards = mesh.shape.get(DATA_AXIS, 1) if mesh is not None else 1
+    if shards > 1 and len(shape) > batch_axis and \
+            shape[batch_axis] % shards == 0:
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+        block = list(shape)
+        block[batch_axis] //= shards
+        spec = [None] * len(shape)
+        spec[batch_axis] = DATA_AXIS
+
+        def draw(key):
+            key = jax.random.fold_in(key, jax.lax.axis_index(DATA_AXIS))
+            return _draw_bits(key, tuple(block), dtype)
+
+        bits = shard_map(draw, mesh=mesh, in_specs=P(),
+                         out_specs=P(*spec))(key)
+    else:
+        bits = _draw_bits(key, shape, dtype)
+    return bits >= dtype(threshold)
+
+
 @register_op("dropout", infer_shape=_infer_dropout, uses_rng=True,
              grad_maker=_dropout_grad_maker, grad_lower=_dropout_grad_lower)
 def dropout_lower(ctx):
+    """Training: ``Out = X * Mask``, ``Mask`` 0/1 (``upscale_in_train``:
+    0 or 1/(1-p)) in X's dtype, for ``dropout_grad``.
+
+    A mask is a pure function of the op's key, i.e. of
+    ``(program.random_seed, the executor's run counter, the op's rng
+    slot)``, or of ``seed`` alone under ``fix_seed``: optimised and
+    unoptimised programs, a sentinel's replay and a ``fix_seed`` op see
+    the same mask again.  The key is threefry's (one ``fold_in`` an op);
+    the mask's BITS come from XLA's bit generator, 16 an element
+    (``_dropout_keep``: the fused 32-bit threefry draw cost the
+    Transformer-base step 45-50 of its 367 ms), so the drop probability
+    is ``round(p * 65536) / 65536`` (p = 0.1: 0.100006; a ``p`` that 16
+    bits miss by more than 1e-4 relative draws 32), and masks repeat on
+    one backend and one mesh shape, not across them: the generator's
+    algorithm is the backend's own, and under GSPMD every shard draws
+    its own block.  ``Mask`` is a second draw from the same key, so
+    the backward regenerates the mask and the forward keeps none.
+    Initialisers, ``sampling_id`` and ``nce`` keep threefry: their
+    values are what seeded parameters and tests pin.
+
+    Test mode draws nothing: ``downgrade_in_infer`` scales by ``1 - p``.
+    """
     x = ctx.input("X")
     p = ctx.attr("dropout_prob", 0.5)
     is_test = ctx.attr("is_test", False) or not ctx.training
@@ -552,13 +639,26 @@ def dropout_lower(ctx):
     seed = ctx.attr("seed", 0)
     key = jax.random.PRNGKey(seed) if ctx.attr("fix_seed", False) \
         else ctx.rng_key()
-    keep = jax.random.bernoulli(key, 1.0 - p, x.shape)
-    if impl == "upscale_in_train":
-        mask = keep.astype(x.dtype) / (1.0 - p)
-    else:
-        mask = keep.astype(x.dtype)
-    ctx.set_output("Out", x * mask)
-    ctx.set_output("Mask", mask)
+    from paddle_tpu.profiler import runtime_metrics
+    runtime_metrics.inc("dropout.mask_sites")
+    runtime_metrics.inc("dropout.mask_bits",
+                        int(np.prod(x.shape)) * _mask_threshold(p)[1])
+
+    def draw(key):
+        keep = _dropout_keep(key, p, x.shape, ctx.aux.get("mesh"),
+                             ctx.aux.get("batch_axis", 0))
+        if impl == "upscale_in_train":
+            return keep.astype(x.dtype) / (1.0 - p)
+        return keep.astype(x.dtype)
+
+    ctx.set_output("Out", x * draw(key))
+    # ``Mask`` is the same mask drawn AGAIN (the barrier keeps XLA from
+    # merging the two draws): the backward regenerates it where it reads
+    # it, as XLA did of itself with the fused threefry, instead of
+    # keeping 16 bits an element from the forward pass on (that cost
+    # Transformer-base at B256/S256 another 9 ms a step of recomputing
+    # what no longer fitted; PERF.md section 6, PR 36)
+    ctx.set_output("Mask", draw(jax.lax.optimization_barrier(key)))
 
 
 # ---------------------------------------------------------------------------

@@ -175,6 +175,7 @@ class ParallelExecutor(Executor):
             env.update(inout_state)
             aux = {"rng_counter": 0, "scope": scope,
                    "lower_block": lower_block, "mesh": mesh,
+                   "batch_axis": self.batch_axis,
                    "lod": dict(lod_map), "amp": amp,
                    # opt-pipeline fact (see Executor._prepare): key-
                    # free ops skip their per-op fold_in at trace time

@@ -2,6 +2,8 @@
 strategy: simulate clusters on one host, SURVEY.md §4.5;
 test_parallel_executor.py analog)."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -69,6 +71,68 @@ class TestDataParallel:
 
         np.testing.assert_allclose(dp_losses, ref_losses, rtol=2e-5,
                                    atol=1e-6)
+
+
+class TestDropoutOnADataMesh:
+    @pytest.mark.parametrize("mesh_shape,axes", [
+        ((4,), ("data",)), ((2, 2), ("data", "model"))])
+    def test_every_shard_draws_its_own_mask_of_its_own_shape(self, mesh_shape,
+                                                             axes):
+        """GSPMD does not partition ``rng_bit_generator`` (it draws the
+        GLOBAL shape on every device and slices it), so the ``dropout``
+        lowering draws per shard under a ``shard_map`` over ``data``;
+        beside a ``model`` axis (a tensor-parallel ``fc`` feeds the
+        dropout and a step is trained through it) the draw is split over
+        ``data`` alone and the same along ``model``."""
+        batch, cols, p, shards = 64, 256, 0.5, mesh_shape[0]
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 7
+        with fluid.program_guard(main, startup):
+            x = layers.data(name="x", shape=[batch, cols],
+                            append_batch_size=False)
+            hidden = layers.fc(input=x, size=cols, param_attr="tp_w",
+                               bias_attr=False)
+            dropped = layers.dropout(hidden, dropout_prob=p)
+            loss = layers.mean(layers.fc(input=dropped, size=1))
+            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+        mask_name = [op for op in main.global_block().ops
+                     if op.type == "dropout"][0].output("Mask")[0]
+        mesh = make_mesh(mesh_shape, axes,
+                         devices=jax.devices()[:int(np.prod(mesh_shape))])
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            fluid.Executor().run(startup)
+            pexe = ParallelExecutor(
+                loss_name=loss.name, main_program=main, mesh=mesh,
+                param_shardings=[(r"tp_w", P(None, "model"))]
+                if "model" in axes else None)
+            xv = np.random.RandomState(0).rand(batch, cols).astype("float32")
+            mask, loss_value = pexe.run(feed={"x": xv},
+                                        fetch_list=[mask_name, loss.name],
+                                        return_numpy=False)
+        assert np.isfinite(np.asarray(loss_value)).all()
+        assert {s.data.shape[0] for s in mask.addressable_shards} == \
+            {batch // shards}
+        mask = np.asarray(mask)
+        assert abs(mask.mean() - (1 - p)) < 4 * (p * (1 - p) / mask.size) ** .5
+        blocks = mask.reshape(shards, batch // shards, cols)
+        for i in range(shards):
+            for j in range(i + 1, shards):
+                differ = (blocks[i] != blocks[j]).mean()
+                assert abs(differ - 2 * p * (1 - p)) < 0.03, (i, j, differ)
+        # the compiled per-device program: nothing the generator makes
+        # is as large as the global mask (the CPU backend expands the
+        # instruction into Philox rounds under the same op_name)
+        texts = [e.perf["exec"].as_text() for e in pexe._cache.values()
+                 if getattr(e, "perf", None) and e.perf.get("exec")]
+        assert texts
+        sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+                 for text in texts for line in text.splitlines()
+                 if "ptop_dropout__" in line
+                 for dims in re.findall(r"= u(?:16|32|64)\[([0-9,]+)\]",
+                                        line)]
+        assert sizes and max(sizes) >= batch // shards * cols // 4
+        assert max(sizes) <= batch // shards * cols < batch * cols
 
 
 class TestRunPipelineParallel:
