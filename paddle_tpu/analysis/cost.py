@@ -743,6 +743,72 @@ def _c_mla_absorb(op, info):
         io_bytes(op, info)
 
 
+# learned sparse attention (ops/dsa_ops.py): what grows with the SQUARE of
+# a prefill's rows beside the attention itself
+
+def _dsa_projection_flops(op, info, rows):
+    wq, wk, ww = (_shape(info, op, s) for s in ("Wq", "Wk", "Ww"))
+    if None in (wq, wk, ww) or not _known(*wq, *wk, *ww):
+        return None
+    return 2 * rows * (wq[0] * wq[1] + wk[0] * wk[1] + ww[0] * ww[1])
+
+
+@rule("dsa_index")
+def _c_dsa_index(op, info):
+    """The indexer's three projections a row, and past ``top_k`` rows
+    every index head's product of every query row with every key row."""
+    x, wk = _shape(info, op, "X"), _shape(info, op, "Wk")
+    if x is None or len(x) != 3 or not _known(x[1]) or wk is None:
+        return None
+    t = x[1]
+    flops = _dsa_projection_flops(op, info, t)
+    if flops is None:
+        return None
+    if t > int(op.attr("top_k")):
+        flops += 2 * t * t * int(op.attr("n_head")) * wk[-1]
+    return flops, io_bytes(op, info)
+
+
+@rule("dsa_index_paged")
+def _c_dsa_index_paged(op, info):
+    """The decode step's: the projections a slot, then every index head
+    over the rows a slot ADDRESSES (the page bucket; the live rows where
+    the caller knows them), past ``top_k`` rows."""
+    x = _shape(info, op, "X")
+    pool = info(op.input("Cache")[0]) if op.input("Cache") else _UNKNOWN
+    pt = _shape(info, op, "PageTable")
+    if x is None or pool.shape is None or pt is None or \
+            not _known(x[0], pool.shape[1], pool.shape[2]):
+        return None
+    s, d_idx = x[0], pool.shape[2]
+    flops = _dsa_projection_flops(op, info, s)
+    if flops is None:
+        return None
+    rows = pt[1] * pool.shape[1] if pt[1] > 0 else None
+    if info.paged_live_rows is not None:
+        rows = info.paged_live_rows if rows is None \
+            else min(rows, info.paged_live_rows)
+    item = _DTYPE_BYTES.get(str(pool.dtype), 4)
+    bytes_ = io_bytes(op, info) - (_var_bytes(pool, 1) or 0) * 2
+    if rows is not None and rows > int(op.attr("top_k")):
+        flops += 2 * s * rows * int(op.attr("n_head")) * d_idx
+        bytes_ += s * rows * d_idx * item
+    return int(flops), int(max(bytes_, 0))
+
+
+@rule("dsa_select")
+def _c_dsa_select(op, info):
+    """An exact top-k without a sort: 32 counting passes over the
+    scores and a running count of the ties (about 70 operations a
+    score), nothing up to ``top_k`` rows."""
+    sc = _shape(info, op, "Scores")
+    n = numel(sc) if sc is not None else None
+    if n is None:
+        return None
+    flops = 70 * n if sc[-1] > int(op.attr("top_k")) else 0
+    return flops, io_bytes(op, info)
+
+
 rule("pad", "pad_grad")(_per_element(1))
 rule("swiglu")(_per_element(6))
 rule("rope")(_per_element(6))

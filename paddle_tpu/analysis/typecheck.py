@@ -1127,6 +1127,8 @@ def _r_mla_attention(op, tc):
                   f"mla_attention Latent `{op.input('Latent')[0]}` is "
                   f"{lat.shape[-1]} wide, under c_kv {latent} + rope {r}",
                   op=op, var=op.input("Latent")[0])
+    if q.shape is not None and len(q.shape) >= 2:
+        _select_matches(op, tc, q.shape[-2])
     shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
     tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
 
@@ -1165,9 +1167,93 @@ def _r_paged_attention_latent(op, tc):
         tc.report("PTA006", f"paged_attention_latent reads a value of {v} "
                   f"lanes from rows of {row}", op=op,
                   var=op.input("Cache")[0])
+    pt = tc.input_info(op, "PageTable")
+    if pt.shape is not None and cache.shape is not None and \
+            pt.shape[-1] > 0 and cache.shape[1] > 0:
+        _select_matches(op, tc, pt.shape[-1] * cache.shape[1])
     shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
     tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
     tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
+
+
+# learned sparse attention (ops/dsa_ops.py)
+
+def _dsa_index_widths(op, tc):
+    """The indexer's weights held to each other: Wq [q_lora, H * D], Wk
+    [d, D], KScale / KBias [D], Ww [d, H].  Returns D (or None)."""
+    h = int(op.attr("n_head"))
+    wk = tc.input_info(op, "Wk")
+    d_idx = wk.shape[-1] if wk.shape is not None and len(wk.shape) == 2 \
+        else None
+    r = int(op.attr("rope_dim"))
+    if d_idx is not None and d_idx > 0:
+        _last_dim_is(op, tc, "Wq", h * d_idx, "columns (index heads x the "
+                                               "key's lanes)")
+        _last_dim_is(op, tc, "KScale", d_idx, "lanes (the key's)")
+        _last_dim_is(op, tc, "KBias", d_idx, "lanes (the key's)")
+        if r % 2 or r > d_idx:
+            tc.report("PTA006", f"{op.type}: a rotary slice of {r} lanes "
+                      f"(pairs) does not fit an index head of {d_idx}",
+                      op=op, var=op.input("Wk")[0])
+    _last_dim_is(op, tc, "Ww", h, "columns (index heads)")
+    x, cq = tc.input_info(op, "X"), tc.input_info(op, "Cq")
+    if x.shape is not None and wk.shape is not None and len(wk.shape) == 2:
+        _last_dim_is(op, tc, "X", wk.shape[0], "features (Wk's rows)")
+    wq = tc.input_info(op, "Wq")
+    if cq.shape is not None and wq.shape is not None and len(wq.shape) == 2:
+        _last_dim_is(op, tc, "Cq", wq.shape[0], "features (Wq's rows)")
+    _int_index(op, tc, "Pos")
+    return d_idx
+
+
+@rule("dsa_index")
+def _r_dsa_index(op, tc):
+    d_idx = _dsa_index_widths(op, tc)
+    x = tc.input_info(op, "X")
+    lead = None if x.shape is None else tuple(x.shape[:-1])
+    tc.set_output(op, "Key", dtype=x.dtype, shape=None if lead is None
+                  else lead + (d_idx if d_idx else -1,))
+    tc.set_output(op, "Scores", dtype="float32", shape=None
+                  if lead is None else lead + (lead[-1],))
+
+
+@rule("dsa_index_paged")
+def _r_dsa_index_paged(op, tc):
+    d_idx = _dsa_index_widths(op, tc)
+    _int_index(op, tc, "PageTable")
+    _int_index(op, tc, "Lens")
+    x, cache = tc.input_info(op, "X"), tc.input_info(op, "Cache")
+    pt = tc.input_info(op, "PageTable")
+    if cache.shape is not None and d_idx:
+        _last_dim_is(op, tc, "Cache", d_idx, "lanes (the index key's)")
+    rows = -1
+    if pt.shape is not None and cache.shape is not None and \
+            pt.shape[-1] > 0 and cache.shape[1] > 0:
+        rows = pt.shape[-1] * cache.shape[1]
+    tc.set_output(op, "Scores", dtype="float32", shape=None
+                  if x.shape is None else (x.shape[0], 1, rows))
+    tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
+
+
+@rule("dsa_select")
+def _r_dsa_select(op, tc):
+    sc = tc.input_info(op, "Scores")
+    if sc.dtype is not None and sc.dtype != "float32":
+        tc.report("PTA005", f"dsa_select Scores `{op.input('Scores')[0]}` "
+                  f"must be float32 (the selection compares their bits), "
+                  f"got {sc.dtype}", op=op, var=op.input("Scores")[0])
+    _int_index(op, tc, "Lens")
+    if int(op.attr("top_k")) < 1:
+        tc.report("PTA006", "dsa_select keeps top_k >= 1 rows", op=op,
+                  var=op.input("Scores")[0])
+    tc.set_output(op, "Select", shape=sc.shape,
+                  dtype="int32" if op.input("Lens") else "int8")
+
+
+def _select_matches(op, tc, rows):
+    """An attention op's optional Select: one entry a row it may read."""
+    if op.input("Select"):
+        _last_dim_is(op, tc, "Select", rows, "rows (one a row read)")
 
 
 @rule("gqa_attention")

@@ -194,6 +194,11 @@ class GenPredictor:
         # rows a slot a decode step (1: a token a slot a step), and the
         # token a masked row is fed
         self.block_length = int(self.meta.get("block_length") or 1)
+        # learned sparse attention (``ops/dsa_ops.py``): ``top_k`` and the
+        # number of layers that score and select; None without
+        self.sparse_attention = self.meta.get("sparse_attention")
+        # what the newest decode step's selections counted to
+        self.last_selection_counts = {}
         self.mask_token_id = int(self.meta.get("mask_token_id", 0))
         self.prompt_buckets = [int(b) for b in self.meta["prompt_buckets"]]
         self.max_prompt_len = min(self.prompt_buckets[-1], self.max_len)
@@ -515,12 +520,49 @@ class GenPredictor:
         feed = self._prefill_feed(prompt, self._bucket(len(prompt)))
         with self._lock:
             with self._fluid.scope_guard(self._scope):
-                with _span("gen.prefill", tokens=len(prompt)):
+                with _span("gen.prefill", tokens=len(prompt),
+                           **self._prefill_selections(len(prompt))):
                     outs = self._exe.run(self._pre_prog, feed=feed,
                                          fetch_list=self._pre_fetch,
                                          return_numpy=False)
                     logits = np.asarray(outs[0])[0]
         return logits, outs[1:]
+
+    def _prefill_selections(self, n):
+        """A prompt of ``n`` rows under learned sparse attention: query
+        row ``t`` of every indexer scores ``t + 1`` rows and selects
+        ``min(t + 1, top_k)`` of them.  Span attributes; {} without."""
+        sp = self.sparse_attention
+        if not sp:
+            return {}
+        k = min(int(sp["top_k"]), n)
+        return {"dsa_rows_scored": sp["indexers"] * n * (n + 1) // 2,
+                "dsa_rows_selected": sp["indexers"]
+                * (k * (k + 1) // 2 + (n - k) * k)}
+
+    def _count_selections(self, lens):
+        """A decode step's selections, from the rows its slots hold: one
+        a live slot an indexer, over ``lens`` rows, ``min(lens, top_k)``
+        of them kept (all of them, the identity, up to ``top_k``).
+        Counted always-on (``gen.dsa.*``) and kept for the step's span;
+        {} without learned sparse attention."""
+        sp = self.sparse_attention
+        if not sp:
+            return {}
+        from paddle_tpu.profiler import runtime_metrics
+        rows = lens[lens > 0].astype(np.int64)
+        n, k = int(sp["indexers"]), int(sp["top_k"])
+        out = {"dsa_rows_scored": n * int(rows.sum()),
+               "dsa_rows_selected": n * int(np.minimum(rows, k).sum()),
+               "dsa_selections": n * int(rows.size),
+               "dsa_identity_selections": n * int((rows <= k).sum())}
+        runtime_metrics.inc("gen.dsa.rows_scored", out["dsa_rows_scored"])
+        runtime_metrics.inc("gen.dsa.rows_selected",
+                            out["dsa_rows_selected"])
+        runtime_metrics.inc("gen.dsa.selections", out["dsa_selections"])
+        runtime_metrics.inc("gen.dsa.identity_selections",
+                            out["dsa_identity_selections"])
+        return out
 
     # -- cache-slot lifecycle (per request) --------------------------------
     def _write_pool(self, kv, idx, n, slot=0):
@@ -641,6 +683,11 @@ class GenPredictor:
         counted always-on: ``gen.<name with its first _ as a .>``, a
         counter for a sum and a histogram for a max.
 
+        A bundle with ``sparse_attention`` (``ops/dsa_ops.py``) counts
+        the step's selections from ``lens`` (``_count_selections``):
+        ``dsa_rows_scored``, ``dsa_rows_selected``, ``dsa_selections``,
+        ``dsa_identity_selections`` on the span and as ``gen.dsa.*``.
+
         ``on_device`` is the scheduler's side of the call: the step is
         dispatched and not waited for.  ``tokens`` may then be the device
         array :func:`pick_tokens` made from the step before; the logits
@@ -678,6 +725,7 @@ class GenPredictor:
         }
         feed.update(self._paged_decode_feed(lens, walk))
         feed = {k: feed[k] for k in self._dec_feeds}
+        self.last_selection_counts = self._count_selections(lens)
         with self._lock:
             chaos.fire("gen.decode.stall", slots=S)
             with self._fluid.scope_guard(self._scope):
@@ -689,6 +737,8 @@ class GenPredictor:
                         return_numpy=not on_device)
                     self.last_decode_stats = stats[0] \
                         if stats and self.decode_stats else None
+                    if not on_device:
+                        step.set(**self.last_selection_counts)
                     if not on_device and self.last_decode_stats is not None:
                         step.set(live=live, **self.count_decode_stats(
                             self.last_decode_stats))

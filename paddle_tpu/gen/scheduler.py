@@ -780,6 +780,9 @@ class GenScheduler:
                     metrics.inc("gen.decode.steps_ahead")
                 logits = self.predictor.decode_step(
                     tokens, positions, lens=lens, on_device=True)
+                # the selections of the step just dispatched (learned
+                # sparse attention; {} without)
+                step.set(**self.predictor.last_selection_counts)
                 self._in_flight = _Step(rows, logits,
                                         self.predictor.last_decode_stats,
                                         fused)

@@ -6,12 +6,12 @@ TPU-native layers API."""
 from paddle_tpu.models import (resnet, transformer, vgg, mnist,
                                seq2seq, stacked_lstm, gen_lm,
                                gen_lm_long, wide_and_deep, hybrid_moe,
-                               latent_moe, block_moe)
+                               latent_moe, latent_moe_sparse, block_moe)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
            "seq2seq", "stacked_lstm", "gen_lm", "gen_lm_long",
-           "wide_and_deep", "hybrid_moe", "latent_moe", "block_moe",
-           "ZOO_MODELS",
+           "wide_and_deep", "hybrid_moe", "latent_moe",
+           "latent_moe_sparse", "block_moe", "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
 
 #: zoo model names accepted by :func:`build_train_program` (and by
@@ -19,7 +19,7 @@ __all__ = ["resnet", "transformer", "vgg", "mnist",
 #: tests/test_analysis_zoo.py iterates exactly this list)
 ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
               "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
-              "hybrid_moe", "latent_moe", "block_moe")
+              "hybrid_moe", "latent_moe", "latent_moe_sparse", "block_moe")
 
 
 def build_train_program(name, backward=True):
@@ -96,6 +96,14 @@ def build_train_program(name, backward=True):
             hp = latent_moe.LatentMoEConfig()
             hp.dtype = "float32"
             cost, feeds = latent_moe.latent_moe_train_program(16, hp)
+            fetches = [cost.name]
+        elif name == "latent_moe_sparse":
+            # the same under learned sparse attention: 16 rows of which a
+            # row attends 4, chosen by the first layer's indexer
+            hp = latent_moe_sparse.SparseLatentConfig()
+            hp.dtype = "float32"
+            cost, feeds = latent_moe_sparse.latent_moe_sparse_train_program(
+                16, hp)
             fetches = [cost.name]
         elif name == "block_moe":
             # two layers at toy widths under the block-causal mask, float32

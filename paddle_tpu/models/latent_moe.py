@@ -19,8 +19,19 @@ precedes the untied head.
   ABSORBS ``W_kvb`` into the query and out of the context (``mla_absorb``)
   and attends over the cached rows as they are
   (``paged_attention_latent``), reading each once for scores and values.
+* **Learned sparse attention** (``ops/dsa_ops.py``; a configuration
+  with ``index_topk``, the ``glm_moe_dsa`` family).  A layer whose
+  ``indexer_types`` entry is ``"full"`` holds an indexer: it scores
+  every cached row for the query row (``dsa_index`` /
+  ``dsa_index_paged``; its 128-lane key a token is cached in a SECOND
+  page pool under the same page table) and keeps the ``index_topk``
+  best (``dsa_select``); the layer's attention, and that of the
+  ``"shared"`` layers after it, runs over the selected rows and nowhere
+  else.  Up to ``index_topk`` rows the selection is the identity.  A
+  configuration without ``index_topk`` builds none of this.
 * **FFN**.  The first ``first_k_dense_replace`` layers: ``W_d (silu(W_g
-  h) * W_u h)``, width ``intermediate_size``.  The others: a sigmoid
+  h) * W_u h)``, width ``intermediate_size`` (or the layers that
+  ``mlp_layer_types`` calls ``"dense"``).  The others: a sigmoid
   top-k router over ALL the model's experts on the full hidden state
   (``moe_route``; the correction bias moves the choice only), gated
   routed experts of width ``moe_intermediate_size`` over the experts
@@ -78,6 +89,14 @@ class LatentMoEConfig:
     v_head_dim = 16
     rope_theta = 10000.0
     rope_scaling = None              # the published group, type "yarn"
+    rope_parameters = None           # or this group: rope_theta inside
+    # learned sparse attention (None: every row is attended)
+    index_topk = None
+    index_n_heads = 4
+    index_head_dim = 16
+    indexer_types = None             # a layer: "full" | "shared"
+    mlp_layer_types = None           # a layer: "dense" | "sparse"
+    layer_offset = 0                 # the published layer that is layer 0
     # FFN
     intermediate_size = 96
     moe_intermediate_size = 32
@@ -101,6 +120,8 @@ class LatentMoEConfig:
             name = cls._KEYS.get(key, key)
             if hasattr(cls, name) and not name.startswith("_"):
                 setattr(hp, name, value)
+        if hp.rope_parameters and "rope_theta" in hp.rope_parameters:
+            hp.rope_theta = hp.rope_parameters["rope_theta"]
         return hp
 
     @property
@@ -139,7 +160,30 @@ class LatentMoEConfig:
                 + int(self.qk_rope_head_dim)) ** -0.5 * m * m
 
     def is_moe(self, i):
+        if self.mlp_layer_types:
+            return self.mlp_layer_types[int(self.layer_offset) + i] \
+                == "sparse"
         return i >= int(self.first_k_dense_replace)
+
+    def indexer(self, i):
+        """``"full"`` (the layer scores and selects), ``"shared"`` (it
+        uses the selection of the nearest full layer before it) or None
+        (no sparse attention; also a shared layer with no full layer
+        before it in the layers held)."""
+        if not self.index_topk:
+            return None
+        kinds = self.indexer_types
+        at = int(self.layer_offset)
+        kind = kinds[at + i] if kinds else "full"
+        if kind == "full" or any(
+                not kinds or kinds[at + j] == "full" for j in range(i)):
+            return kind
+        return None
+
+    @property
+    def full_layers(self):
+        return [i for i in range(int(self.num_hidden_layers))
+                if self.indexer(i) == "full"]
 
     @property
     def moe_layers(self):
@@ -148,8 +192,10 @@ class LatentMoEConfig:
 
 
 def paged_cache_var_names(hp):
-    """Page-pool tensors, ONE a layer (the latent row), in layer order."""
-    return [f"lat{i}_paged_c" for i in range(int(hp.num_hidden_layers))]
+    """Page-pool tensors: ONE a layer (the latent row), in layer order,
+    then one a layer that holds an indexer (its key row)."""
+    return [f"lat{i}_paged_c" for i in range(int(hp.num_hidden_layers))] \
+        + [f"lat{i}_paged_ik" for i in hp.full_layers]
 
 
 def _gated_ffn(h, hp, prefix, width):
@@ -160,10 +206,45 @@ def _gated_ffn(h, hp, prefix, width):
     return layers.matmul(a, _matrix(hp, f"{prefix}_down.w", [width, d]))
 
 
-def _attention(h, hp, i, pos, mask=None, paged=None):
+def _indexer(h, c_q, hp, i, pos, mask=None, paged=None):
+    """The indexer of a ``full`` layer: returns ``(selection, the
+    prompt's key rows (pad rows not yet zeroed) or None)``."""
+    d, Hi, Di = (int(hp.hidden_size), int(hp.index_n_heads),
+                 int(hp.index_head_dim))
+    k = int(hp.index_topk)
+    inputs = {"Cq": c_q, "X": h, "Pos": pos,
+              "Wq": _matrix(hp, f"lat{i}_idx_qb.w",
+                            [int(hp.q_lora_rank), Hi * Di]),
+              "Wk": _matrix(hp, f"lat{i}_idx_k.w", [d, Di]),
+              "KScale": _vector(f"lat{i}_idx_knorm.scale", Di, 1.0),
+              "KBias": _vector(f"lat{i}_idx_knorm.bias", Di, 0.0),
+              "Ww": _matrix(hp, f"lat{i}_idx_w.w", [d, Hi])}
+    attrs = {"n_head": Hi, "rope_dim": int(hp.qk_rope_head_dim),
+             "theta": float(hp.rope_theta), "top_k": k}
+    if paged is None:
+        out = _op("dsa_index", inputs, {"Key": hp.dtype,
+                                        "Scores": "float32"}, attrs)
+        sel = _op("dsa_select", {"Scores": out["Scores"], "Mask": mask},
+                  {"Select": "int8"}, {"top_k": k})["Select"]
+        return sel, out["Key"]
+    pool, page_table, lens = paged
+    scores = _op("dsa_index_paged",
+                 {**inputs, "Cache": pool, "PageTable": page_table,
+                  "Lens": lens},
+                 {"Scores": "float32", "CacheOut": pool}, attrs)["Scores"]
+    sel = _op("dsa_select", {"Scores": scores, "Lens": lens},
+              {"Select": "int32"}, {"top_k": k})["Select"]
+    return sel, None
+
+
+def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
+               index_pool=None):
     """MLA: prefill (expanded; returns the masked latent rows that seed
     the pool) or the absorbed paged decode (``paged`` = pool, page
-    table, lens)."""
+    table, lens).  ``select``: the selection a ``shared`` layer attends
+    under; a ``full`` layer makes its own (``index_pool``: its key pool
+    in the decode step).  Returns ``(out, latent row, selection, index
+    key rows or None)``."""
     d, H = int(hp.hidden_size), int(hp.num_attention_heads)
     L, R = int(hp.kv_lora_rank), int(hp.qk_rope_head_dim)
     nope, vd = int(hp.qk_nope_head_dim), int(hp.v_head_dim)
@@ -173,6 +254,16 @@ def _attention(h, hp, i, pos, mask=None, paged=None):
                f"lat{i}_qnorm.scale", hp)
     q = layers.matmul(c_q, _matrix(hp, f"lat{i}_qb.w",
                                    [int(hp.q_lora_rank), H * (nope + R)]))
+    index_key = None
+    if hp.indexer(i) == "full":
+        select, index_key = _indexer(
+            h, c_q, hp, i, pos, mask=mask,
+            paged=paged and (index_pool,) + tuple(paged[1:]))
+    elif hp.indexer(i) is None:
+        select = None
+    sparse = {} if select is None else {"Select": select}
+    sparse_attrs = {} if select is None else {
+        "select_top_k": int(hp.index_topk)}
     q = _op("rope", {"X": q, "Pos": pos}, {"Out": hp.dtype},
             {"n_head": H, **rope})["Out"]
     kva = layers.matmul(h, _matrix(hp, f"lat{i}_kva.w", [d, L + R]))
@@ -190,8 +281,10 @@ def _attention(h, hp, i, pos, mask=None, paged=None):
         row = layers.elementwise_mul(row, layers.cast(mask, hp.dtype),
                                      axis=0)
         ctx = _op("mla_attention", {"Q": q, "Latent": row, "Wkvb": w_kvb,
-                                    "Mask": mask}, {"Out": hp.dtype},
-                  {**attrs, "rope_dim": R, "scale": scale})["Out"]
+                                    "Mask": mask, **sparse},
+                  {"Out": hp.dtype},
+                  {**attrs, "rope_dim": R, "scale": scale,
+                   **sparse_attrs})["Out"]
     else:
         pool, page_table, lens = paged
         q_lat = _op("mla_absorb", {"X": q, "Wkvb": w_kvb},
@@ -200,12 +293,14 @@ def _attention(h, hp, i, pos, mask=None, paged=None):
                      "pad": hp.latent_row - L - R})["Out"]
         ctx = _op("paged_attention_latent",
                   {"Q": q_lat, "Row": row, "Cache": pool,
-                   "PageTable": page_table, "Lens": lens},
+                   "PageTable": page_table, "Lens": lens, **sparse},
                   {"Out": hp.dtype, "CacheOut": pool},
-                  {"n_head": H, "v_width": L, "scale": scale})["Out"]
+                  {"n_head": H, "v_width": L, "scale": scale,
+                   **sparse_attrs})["Out"]
         ctx = _op("mla_absorb", {"X": ctx, "Wkvb": w_kvb},
                   {"Out": hp.dtype}, {**attrs, "side": "o"})["Out"]
-    return layers.matmul(ctx, _matrix(hp, f"lat{i}_o.w", [H * vd, d])), row
+    return layers.matmul(ctx, _matrix(hp, f"lat{i}_o.w", [H * vd, d])), \
+        row, select, index_key
 
 
 def _moe(h, hp, i, lens):
@@ -235,10 +330,14 @@ def _moe(h, hp, i, lens):
     return routed["Out"] + shared, routed["Stats"]
 
 
-def _layer(x, hp, i, pos, lens, mask=None, paged=None):
-    """One layer; returns ``(x, latent row, stats or None)``."""
-    out, row = _attention(_rms(x, f"lat{i}_norm1.scale", hp), hp, i, pos,
-                          mask=mask, paged=paged)
+def _layer(x, hp, i, pos, lens, mask=None, paged=None, select=None,
+           index_pool=None):
+    """One layer; returns ``(x, latent row, stats or None, selection,
+    index key rows or None)``: the selection is the layer's own where it
+    holds an indexer, else the one it was handed."""
+    out, row, select, index_key = _attention(
+        _rms(x, f"lat{i}_norm1.scale", hp), hp, i, pos, mask=mask,
+        paged=paged, select=select, index_pool=index_pool)
     x = x + out
     h = _rms(x, f"lat{i}_norm2.scale", hp)
     if hp.is_moe(i):
@@ -246,7 +345,7 @@ def _layer(x, hp, i, pos, lens, mask=None, paged=None):
     else:
         out, stats = _gated_ffn(h, hp, f"lat{i}_ffn",
                                 int(hp.intermediate_size)), None
-    return x + out, row, stats
+    return x + out, row, stats, select, index_key
 
 
 def build_prefill_program(hp):
@@ -257,7 +356,8 @@ def build_prefill_program(hp):
     (1 = real token, real tokens first), ``gen_last`` [1, T] f32 (one-hot
     of the last real position).  The causal mask is built in the graph
     from ``gen_mask``.  Fetches ``[logits [1, V], latent row per layer
-    [1, T, latent_row] ...]``, zeroed on pad rows."""
+    [1, T, latent_row] ..., index key rows per indexer [1, T,
+    index_head_dim] ...]``, zeroed on pad rows."""
     ids = _data("gen_ids", [1, -1], "int32")
     pos = _data("gen_pos", [1, -1], "int32")
     mask = _data("gen_mask", [1, -1])
@@ -265,15 +365,19 @@ def build_prefill_program(hp):
     # pad rows take no routed expert
     lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
     x = _embed(ids, hp, "lat")
-    rows = []
+    rows, keys, select = [], [], None
     for i in range(int(hp.num_hidden_layers)):
-        x, row, _ = _layer(x, hp, i, pos, lens, mask=mask)
+        x, row, _, select, key = _layer(x, hp, i, pos, lens, mask=mask,
+                                        select=select)
         rows.append(row)
+        if key is not None:     # seeds the index-key pool: zeros on pads
+            keys.append(layers.elementwise_mul(
+                key, layers.cast(mask, hp.dtype), axis=0))
     last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]), hp.dtype)
     lasth = layers.reshape(layers.matmul(last3, x),
                            shape=[-1, int(hp.hidden_size)])
     return (["gen_ids", "gen_pos", "gen_mask", "gen_last"],
-            [_logits(lasth, hp, "lat")] + rows)
+            [_logits(lasth, hp, "lat")] + rows + keys)
 
 
 def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
@@ -292,8 +396,10 @@ def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
     for v in (pos, mask, lens):
         v.stop_gradient = True
     x = _embed(ids, hp, "lat")
+    select = None
     for i in range(int(hp.num_hidden_layers)):
-        x, _, _ = _layer(x, hp, i, pos, lens, mask=mask)
+        x, _, _, select, _ = _layer(x, hp, i, pos, lens, mask=mask,
+                                    select=select)
     logits = _logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
                      "lat")
     cost = layers.softmax_with_cross_entropy(
@@ -309,7 +415,8 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     the predictor), ``gen_lens`` [S, 1] int32 (rows INCLUDING the current
     token; 0 = free slot: no page is written).  Persistable state,
     updated in place: one latent pool a layer, ``[num_pages, page_len,
-    latent_row]`` in ``hp.dtype``.  Fetches ``[logits [S, V], stats
+    latent_row]`` in ``hp.dtype``, and one index-key pool a layer that
+    holds an indexer, ``[num_pages, page_len, index_head_dim]``.  Fetches ``[logits [S, V], stats
     [n_moe, 3]]``."""
     import paddle_tpu as fluid
 
@@ -321,18 +428,22 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     block = fluid.default_main_program().global_block()
     pools = {}
     for name in paged_cache_var_names(hp):
+        width = int(hp.index_head_dim) if name.endswith("_ik") \
+            else hp.latent_row
         v = block.create_var(
             name=name, dtype=hp.dtype,
-            shape=[int(num_pages), int(page_len), hp.latent_row])
+            shape=[int(num_pages), int(page_len), width])
         v.persistable = True
         v.stop_gradient = True
         pools[name] = v
     x = layers.reshape(_embed(token, hp, "lat"),
                        shape=[S, 1, int(hp.hidden_size)])
-    stats = []
+    stats, select = [], None
     for i in range(int(hp.num_hidden_layers)):
-        x, _, st = _layer(x, hp, i, pos, lens,
-                          paged=(pools[f"lat{i}_paged_c"], page_table, lens))
+        x, _, st, select, _ = _layer(
+            x, hp, i, pos, lens,
+            paged=(pools[f"lat{i}_paged_c"], page_table, lens),
+            select=select, index_pool=pools.get(f"lat{i}_paged_ik"))
         if st is not None:
             stats.append(st)
     fetches = [_logits(layers.reshape(x, shape=[S, int(hp.hidden_size)]),
@@ -400,6 +511,10 @@ def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
         "page_buckets": [int(b) for b in page_buckets],
         "page_table_feed": "gen_page_table",
     }
+    if hp.full_layers:
+        # what the predictor counts a decode step's selections from
+        meta["sparse_attention"] = {"top_k": int(hp.index_topk),
+                                    "indexers": len(hp.full_layers)}
     with open(os.path.join(dirname, META_FILENAME), "w") as f:
         json.dump(meta, f, indent=2)
     from paddle_tpu.analysis import verify_gen_bundle

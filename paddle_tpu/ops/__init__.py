@@ -32,5 +32,6 @@ from paddle_tpu.ops import (  # noqa: F401
     ssm_ops,
     moe_ops,
     mla_ops,
+    dsa_ops,
     block_ops,
 )

@@ -1084,7 +1084,7 @@ def _paged_blocking(P, PL, HDkv, itemsize, grouped, block_pages=None):
 
 def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
                          block_pages, chunk_rows, head_unroll, scale,
-                         latent=False, row_limits=False):
+                         latent=False, row_limits=False, select=False):
     """One slot of the grid: the online softmax over the slot's LIVE rows.
 
     The pools stay in HBM.  A slot makes ``cdiv(lens, block rows)`` trips
@@ -1117,10 +1117,17 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
     comes first among ``refs``, the rows each of a K/V head's ``G``
     query rows sees, the same for every K/V head: a row is masked past
     its own limit, the walk still follows ``lens`` (the largest), and
-    a query row whose limit is 0 leaves zeros."""
-    lim_ref = None
+    a query row whose limit is 0 leaves zeros.
+
+    ``select`` (the latent form only): a ``[1, 1, rows]`` int32 mask of
+    the slot's rows comes next among ``refs`` (``dsa_select``'s; padded
+    to whole blocks): a row whose entry is 0 is masked like a row past
+    ``lens``.  Every live page is still walked."""
+    lim_ref = sel_ref = None
     if row_limits:
         lim_ref, *refs = refs
+    if select:
+        sel_ref, *refs = refs
     if latent:
         k_hbm, o_ref, kbuf, sems, ahead_ref, qs_ref, m_ref, l_ref, \
             acc_ref = refs
@@ -1162,11 +1169,15 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
 
     def attend(buf, rows, valid, base):
         """One chunk: ``valid`` of its rows are live (may exceed it);
-        it starts at the slot's row ``base`` (given with row limits)."""
+        it starts at the slot's row ``base`` (given with row limits or
+        a selection)."""
         if G == 1:
             live = jax.lax.broadcasted_iota(jnp.int32, (CR, 1), 0) < valid
         elif lim_ref is None:
             live = jax.lax.broadcasted_iota(jnp.int32, (1, CR), 1) < valid
+            if sel_ref is not None:
+                live &= sel_ref[0, :, pl.ds(pl.multiple_of(base, CR),
+                                            CR)] > 0
         else:
             # [G, CR]: each query row under its own limit (<= lens)
             live = jax.lax.broadcasted_iota(jnp.int32, (1, CR), 1) \
@@ -1295,7 +1306,8 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
             def chunk(c, _):
                 r0 = pl.multiple_of(c * CR, CR)
                 attend(buf, pl.ds(r0, CR), left - r0,
-                       None if lim_ref is None else b * BR + r0)
+                       None if lim_ref is None and sel_ref is None
+                       else b * BR + r0)
                 return 0
 
             jax.lax.fori_loop(0, pl.cdiv(jnp.minimum(left, BR), CR),
@@ -1343,7 +1355,7 @@ def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4,
 
 def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
                             interpret=False, block_pages=None,
-                            v_width=None, row_lens=None):
+                            v_width=None, row_lens=None, select=None):
     """Returns None when ``_paged_kernel_ok`` refuses the shape; any
     lowering error past that gate surfaces to the caller.
     ``block_pages`` is for the tests: the kernel reads it from the
@@ -1357,7 +1369,10 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     are ``[L * G, D] x [D, rows]`` and ``[L * G, rows] x [rows, D]``.
     ``L`` = 1 is the call as it was.  ``row_lens`` [S, L] (with ``L`` >
     1): what each of a slot's rows sees, ``lens`` the largest of them;
-    the kernel takes them as a column of ``L * G`` limits."""
+    the kernel takes them as a column of ``L * G`` limits.
+
+    ``select`` [S, 1, P * page_len] int (the latent form): 0 masks the
+    slot's row at that position."""
     P = page_table.shape[1]
     NP, PL, HDkv = kc.shape
     HD = q.shape[-1]
@@ -1392,7 +1407,7 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
         q, kc, vc, page_table, lens, n_head=n_head, scale=scale,
         interpret=interpret, block_pages=block_pages,
         chunk_rows=chunk_rows, head_unroll=head_unroll, v_width=v_width,
-        row_limits=row_lens)
+        row_limits=row_lens, select=select)
 
 
 # inline: the call leaves no trace in the program (the kernel's event keeps
@@ -1403,7 +1418,7 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     "head_unroll", "v_width"))
 def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
                        interpret, block_pages, chunk_rows, head_unroll,
-                       v_width=None, row_limits=None):
+                       v_width=None, row_limits=None, select=None):
     S = page_table.shape[0]
     NP, PL, HDkv = kc.shape
     D = q.shape[-1] // n_head
@@ -1415,9 +1430,16 @@ def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
                                chunk_rows=chunk_rows,
                                head_unroll=head_unroll, scale=scale,
                                latent=latent,
-                               row_limits=row_limits is not None)
+                               row_limits=row_limits is not None,
+                               select=select is not None)
     # [S, G, 1]: the limits of the query rows that share a K/V head
     limits = [] if row_limits is None else [row_limits]
+    if select is not None:
+        # [S, 1, whole blocks of rows]: the walk's last block may pass
+        # the table's rows
+        rows = -(-select.shape[-1] // (block_pages * PL)) * block_pages * PL
+        limits.append(jnp.pad(select.astype(jnp.int32), (
+            (0, 0), (0, 0), (0, rows - select.shape[-1]))))
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1541,8 +1563,9 @@ def paged_attention_lower(ctx: LowerContext):
 # ---------------------------------------------------------------------------
 
 def _xla_latent_attention(q, cache, page_table, lens, n_head, v_width,
-                          scale):
-    """Gather-based fallback of the latent form, float32."""
+                          scale, select=None):
+    """Gather-based fallback of the latent form, float32.  ``select`` [S,
+    1, P * page_len]: 0 masks the slot's row at that position."""
     S, P = page_table.shape
     NP, PL, W = cache.shape
     T = P * PL
@@ -1551,8 +1574,10 @@ def _xla_latent_attention(q, cache, page_table, lens, n_head, v_width,
     sc = jnp.einsum("shw,stw->sht", qh, rows,
                     preferred_element_type=jnp.float32) * scale
     col = jax.lax.broadcasted_iota(jnp.int32, (S, 1, T), 2)
-    probs = jax.nn.softmax(jnp.where(col < lens[:, :, None], sc, NEG_INF),
-                           axis=-1)
+    seen = col < lens[:, :, None]
+    if select is not None:
+        seen &= select > 0
+    probs = jax.nn.softmax(jnp.where(seen, sc, NEG_INF), axis=-1)
     out = jnp.einsum("sht,stv->shv", probs, rows[..., :v_width],
                      preferred_element_type=jnp.float32)
     # a free slot reads zeros, as the kernel writes them
@@ -1577,24 +1602,34 @@ def paged_attention_latent_lower(ctx: LowerContext):
     Row: [S, 1, W] this step's latent row; Cache: [num_pages, page_len,
     W] persistable pool; PageTable, Lens as ``paged_attention``.  Out:
     [S, 1, H*v_width], the context in the latent; CacheOut names the
-    cache var itself.  attrs: n_head, v_width, scale."""
+    cache var itself.  attrs: n_head, v_width, scale.
+
+    Select (optional, with attr select_top_k; ``ops/dsa_ops.py``): [S, 1,
+    P * page_len] int32, 0 = the slot's row at that position is left out
+    of the softmax.  Every live page is still walked.  A bucket of no
+    more than ``select_top_k`` rows cannot leave one out: the input is
+    then not read."""
     q = ctx.input("Q")
     pt, lens = ctx.input("PageTable"), ctx.input("Lens")
     n_head, v_width = int(ctx.attr("n_head")), int(ctx.attr("v_width"))
     scale = float(ctx.attr("scale", 1.0))
     cache, = _paged_cache_update((ctx.input("Cache"),), (ctx.input("Row"),),
                                  pt, lens)
+    select = None
+    if ctx.has_input("Select") and pt.shape[1] * cache.shape[1] \
+            > int(ctx.attr("select_top_k", 0)):
+        select = ctx.input("Select")
     out = None
     interpret = _use_interpret()
     if _paged_kernel_enabled(interpret):
         out = _pallas_paged_attention(q, cache, None, pt, lens, n_head,
                                       scale, interpret=interpret,
-                                      v_width=v_width)
+                                      v_width=v_width, select=select)
     if out is None:
         from paddle_tpu.profiler import runtime_metrics
         runtime_metrics.inc("gen.paged.fallback")
         out = _xla_latent_attention(q, cache, pt, lens, n_head, v_width,
-                                    scale)
+                                    scale, select=select)
     ctx.set_output("Out", out)
     ctx.set_output("CacheOut", cache)
 

@@ -140,7 +140,8 @@ def _split_kvb(w_kvb, n_head, nope, v_dim):
 
 
 def mla_attention(q, latent, w_kvb, mask, n_head, nope, rope_dim, v_dim,
-                  scale, block=MLA_QUERY_BLOCK, flash=None, interpret=None):
+                  scale, block=MLA_QUERY_BLOCK, flash=None, interpret=None,
+                  select=None):
     """``q`` [T, H * (nope + rope)] (rotated); ``latent`` [T, >= L +
     rope] (``c_kv`` after its norm | the rotated shared key | lanes that
     pad the cached row, not read); ``mask`` [T] (0 = pad row, never
@@ -150,7 +151,15 @@ def mla_attention(q, latent, w_kvb, mask, n_head, nope, rope_dim, v_dim,
     ``ops/attention_ops.py`` takes the expanded heads (keys 192 wide,
     values 128: it never asked them to be alike) and skips the blocks
     above the diagonal; elsewhere, and where its gate refuses the
-    length, plain XLA a block of query rows at a time."""
+    length, plain XLA a block of query rows at a time.
+
+    ``select`` [T, T] int8 (``ops/dsa_ops.py``; 0 = query row ``t``
+    leaves row ``s`` out of its softmax; causal and free of pad rows
+    already): on the TPU ``dsa_ops.selected_attention``, a flash forward
+    kernel that takes the selection's blocks beside the keys' and skips
+    the blocks above the diagonal; elsewhere plain XLA over every key
+    with the selection in the mask, the query block sized so that a
+    block's float32 scores stay near 512 MB."""
     from paddle_tpu.ops import attention_ops
     if interpret is None:
         interpret = attention_ops._use_interpret()
@@ -163,14 +172,27 @@ def mla_attention(q, latent, w_kvb, mask, n_head, nope, rope_dim, v_dim,
                    preferred_element_type=jnp.float32).astype(q.dtype)
     qh = q.reshape(T, n_head, nope + rope_dim)
     if not interpret if flash is None else flash:
-        heads = lambda a: a.transpose(1, 0, 2)[None]      # [1, H, T, D]
+        heads = lambda a: a.transpose(1, 0, 2)            # [H, T, D]
         k = jnp.concatenate([k_nope, jnp.broadcast_to(
             k_rope[:, None], (T, n_head, rope_dim))], axis=-1)
-        out = attention_ops._pallas_attention(
-            heads(qh), heads(k), heads(v), mask[None].astype(jnp.float32),
-            True, scale, interpret=interpret)
+        if select is not None:
+            from paddle_tpu.ops import dsa_ops
+            out = dsa_ops.selected_attention(
+                heads(qh), heads(k), heads(v), select.astype(jnp.int8),
+                scale=float(scale), interpret=interpret)
+        else:
+            out = attention_ops._pallas_attention(
+                heads(qh)[None], heads(k)[None], heads(v)[None],
+                mask[None].astype(jnp.float32), True, scale,
+                interpret=interpret)
+            out = None if out is None else out[0][0]
         if out is not None:
-            return out[0][0].transpose(1, 0, 2).reshape(T, n_head * v_dim)
+            return out.transpose(1, 0, 2).reshape(T, n_head * v_dim)
+    if select is not None:
+        # a power of two (the buckets are multiples of 2048 rows: the
+        # halving below then stops at once)
+        block = min(block, 1 << max(
+            3, ((1 << 27) // (n_head * T)).bit_length() - 1))
     block = min(int(block), T)
     while T % block:
         block //= 2
@@ -186,6 +208,9 @@ def mla_attention(q, latent, w_kvb, mask, n_head, nope, rope_dim, v_dim,
         row = i * block + jax.lax.broadcasted_iota(
             jnp.int32, (block, T), 0)
         seen = (cols <= row) & seen_col
+        if select is not None:
+            seen &= jax.lax.dynamic_slice_in_dim(select, i * block, block,
+                                                 0) > 0
         probs = jax.nn.softmax(jnp.where(seen, sc * scale, NEG_INF), axis=-1)
         return jnp.einsum("hqt,thd->qhd", probs.astype(v.dtype), v,
                           preferred_element_type=jnp.float32).astype(q.dtype)
@@ -205,16 +230,26 @@ def _infer_mla_attention(op, block):
 
 
 @register_op("mla_attention", infer_shape=_infer_mla_attention,
-             no_grad_inputs=("Mask",))
+             no_grad_inputs=("Mask", "Select"))
 def mla_attention_lower(ctx):
     """Q [1, T, H * (nope + rope)]; Latent [1, T, >= L + rope]; Wkvb [L,
     H * (nope + v)]; Mask [1, T].  attrs n_head, nope_dim, rope_dim,
-    v_dim, scale.  Out [1, T, H * v]."""
+    v_dim, scale.  Out [1, T, H * v].
+
+    Select (optional, with attr select_top_k): [1, T, T] int8, row ``t``
+    attends the rows it marks and no other.  Up to ``select_top_k`` rows
+    the selection is the identity and the input is not read."""
+    q = ctx.input("Q")[0]
+    select = None
+    if ctx.has_input("Select") and q.shape[0] > int(
+            ctx.attr("select_top_k", 0)):
+        select = ctx.input("Select")[0]
     out = mla_attention(
-        ctx.input("Q")[0], ctx.input("Latent")[0], ctx.input("Wkvb"),
+        q, ctx.input("Latent")[0], ctx.input("Wkvb"),
         ctx.input("Mask")[0], int(ctx.attr("n_head")),
         int(ctx.attr("nope_dim")), int(ctx.attr("rope_dim")),
-        int(ctx.attr("v_dim")), float(ctx.attr("scale", 1.0)))
+        int(ctx.attr("v_dim")), float(ctx.attr("scale", 1.0)),
+        select=select)
     ctx.set_output("Out", out[None])
 
 

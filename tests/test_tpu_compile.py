@@ -437,6 +437,121 @@ def test_latent_prefill_attention_compiles_for_v5e(one_chip, rows):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("pages", [64, 288])
+def test_selected_latent_paged_decode_compiles_for_v5e(one_chip, pages):
+    """The latent decode kernel under a SELECTION (learned sparse
+    attention, ``ops/dsa_ops.py``) at the sparse-attention serving cell's
+    shapes: 16 slots, 64 absorbed query heads over a 640-wide bfloat16
+    row, page_len 64, the first bucket past ``index_topk`` rows and the
+    widest of the 4608-page pool (8 pages a block, chunks of 512 rows);
+    the int32 mask a slot is sliced a chunk at a time on its lanes."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, NP, PL, H, W, V = 16, 4608, 64, 64, 640, 512
+    assert A._paged_blocking(pages, PL, W, 2, True) == (8, 512)
+
+    def fn(q, cache, pt, select):
+        out = A._pallas_paged_attention(q, cache, None, pt,
+                                        _ragged_lens(S, pages, PL), H,
+                                        0.0625, interpret=False, v_width=V,
+                                        select=select)
+        assert out is not None, "shape gate refused the latent row"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 1, H * W), jnp.bfloat16),
+                   ((NP, PL, W), jnp.bfloat16), ((S, pages), jnp.int32),
+                   ((S, 1, pages * PL), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_sparse_decode_indexer_and_selection_compile_for_v5e(one_chip):
+    """The decode step's indexer and its exact top-2048 at the
+    sparse-attention serving cell's widest bucket: 16 slots score 18432
+    rows of a 128-lane bfloat16 key pool with 32 index heads; the
+    selection's 32 counting passes and its cumulative sum need no sort
+    and little scratch."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import dsa_ops
+    S, NP, PL, P, Hi, Di, K = 16, 4608, 64, 288, 32, 128, 2048
+
+    def fn(q, w, cache, pt, lens):
+        rows = cache[pt].reshape(S, P * PL, Di)
+        scores = dsa_ops.index_scores(q[:, None], rows, w[:, None])
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, 1, P * PL), 2)
+        return dsa_ops.select_mask(scores, cols < lens[:, :, None], K) \
+            .astype(jnp.int32)
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    compiled = jax.jit(fn).lower(
+        sds((S, Hi, Di), jnp.bfloat16), sds((S, Hi), jnp.float32),
+        sds((NP, PL, Di), jnp.bfloat16), sds((S, P), jnp.int32),
+        sds((S, 1), jnp.int32)).compile()
+    assert "sort" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20, \
+        compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("rows", [4096, 16384])
+def test_sparse_prefill_selection_and_attention_compile_for_v5e(one_chip,
+                                                                rows):
+    """The prefill of a layer that holds an indexer at the
+    sparse-attention serving cell's widths, its first bucket past
+    ``index_topk`` rows and its widest: index scores of 32 heads x 128, the
+    exact top-2048 a query row (int8 [T, T]), then latent attention
+    expanded to 64 heads (keys 256 wide, values 256) under that selection
+    in the select flash kernel (blocks of 512 x 512, the selection's int8
+    block beside the keys'); everything fits beside the 7.4 GB the serving
+    window holds."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import dsa_ops, mla_ops
+    T, Hi, Di, K = rows, 32, 128, 2048
+
+    def fn(qi, ki, wi, q, latent, w_kvb, mask):
+        block = dsa_ops._query_block(T, dsa_ops.DSA_QUERY_BLOCK)
+        part = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * block,
+                                                         block, 0)
+        scores = jax.lax.map(
+            lambda i: dsa_ops.index_scores(part(qi, i), ki, part(wi, i)),
+            jnp.arange(T // block)).reshape(T, T)
+        select = dsa_ops.causal_select(scores, mask, K)
+        return mla_ops.mla_attention(q, latent, w_kvb, mask, 64, 192, 64,
+                                     256, 0.0625, select=select,
+                                     flash=True, interpret=False)
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    compiled = jax.jit(fn).lower(
+        sds((T, Hi, Di), jnp.bfloat16), sds((T, Di), jnp.bfloat16),
+        sds((T, Hi), jnp.float32), sds((T, 64 * 256), jnp.bfloat16),
+        sds((T, 640), jnp.bfloat16), sds((512, 64 * 448), jnp.bfloat16),
+        sds((T,), jnp.float32)).compile()
+    assert "sort" not in compiled.as_text()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 7 << 30, \
+        compiled.memory_analysis()
+
+
+def test_latent_prefill_attention_compiles_for_v5e_at_sparse_widths(one_chip):
+    """The flash kernel at the sparse-attention configuration's head
+    widths (keys 192 | 64 = 256, values 256) in its 2048-row bucket, where
+    the selection is the identity and is skipped."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mla_ops
+
+    def fn(q, latent, w_kvb, mask):
+        return mla_ops.mla_attention(q, latent, w_kvb, mask, 64, 192, 64,
+                                     256, 0.0625, flash=True,
+                                     interpret=False)
+
+    hlo = _compile(fn, one_chip, ((2048, 64 * 256), jnp.bfloat16),
+                   ((2048, 640), jnp.bfloat16),
+                   ((512, 64 * 448), jnp.bfloat16), ((2048,), jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
 @pytest.mark.parametrize("bias", ["row", "causal"])
 def test_fused_softmax_compiles_for_v5e(one_chip, bias):
     import jax.numpy as jnp
