@@ -1,50 +1,75 @@
-"""Flash-attention crossover micro-bench (VERDICT r2 item 4; r4
-methodology).
+"""Attention micro-bench: what one attention module costs the model,
+forward + backward, on the chip.
 
-Times fwd+bwd of fused attention — Pallas flash kernels vs composed XLA
-(``ops/attention_ops.py``) — at S in {256, 512, 1024, 2048, 4096}, bf16,
-causal, B*S = 64k tokens, H=8, D=64 (transformer-base head shape).
+The transformer's projections emit ``[B, S, H*D]`` and its output
+projection reads ``[B, S, H*D]``, so every path is timed FROM and TO that
+layout: the ``[B, H, S, D]`` kernels and the composed XLA path pay their
+head transposes inside the timed region, as they do in the model.
 
-Methodology (r4): DEVICE time per iteration, read from an xplane trace
+Paths (``ops/attention_ops.py``):
+  * ``packed``   the packed single-pass kernels (``ops/attention_packed``);
+  * ``bhsd``     transposes + the ``[B, H, S, D]`` Pallas kernels
+                 (single-pass up to S 1024, streaming flash above);
+  * ``composed`` transposes + plain XLA (``_reference_attention``).
+
+Shapes: Transformer-base heads (H 8, D 64), bf16, 32k tokens at S 256 /
+512 / 1024 (the long training cell's B32 x S1024 and the short cells'
+B256 x S256 among them), each causal and not; S 2048 / 4096 for the
+streaming kernels.  DEVICE time per iteration, read from an xplane trace
 of one jitted ``lax.scan`` of ITERS grad steps under ``jax.named_scope``
-(``profiler.measure_device_seconds``) — scope-attributed, so free of
-the host's dispatch and sync wall-clock latencies.
+(``profiler.measure_device_seconds``), median of the trials.
 
-Writes ``BENCH_ATTENTION.md`` (the checked-in artifact the default
-``PADDLE_TPU_FLASH_MIN_S`` cites) and prints one JSON line per S.
+``--blocks R:L[,R:L...]`` adds rows of the packed path at other blockings
+(query rows : feature lanes a program), which is how
+``attention_packed.ROWS`` / ``CAUSAL_ROWS`` / ``LANE_BLOCK`` /
+``CAUSAL_WIDE_MAX_S`` were chosen.
+
+Writes ``BENCH_ATTENTION.md`` and prints one JSON line per row.  Needs
+the chip: ``chiprun -- python bench_attention.py``.
 """
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import sys
 
 import numpy as np
 
-
 ITERS = 10
-TOKENS = 1 << 16
 HEADS, DIM = 8, 64
-SEQS = (256, 512, 1024, 2048, 4096)
+SHAPES = ((32, 1024), (64, 512), (256, 256))       # (B, S)
+LONG_SHAPES = ((16, 2048), (8, 4096))              # streaming kernels
+PATHS = ("packed", "bhsd", "composed")
 
 
-def time_path(use_pallas, S, B):
+def time_path(path, B, S, causal, blocks=None):
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.ops.attention_ops import fused_attention
+    from paddle_tpu.ops import attention_ops as A
+    from paddle_tpu.ops import attention_packed as P
     from paddle_tpu.profiler import measure_device_seconds
 
-    key = jax.random.PRNGKey(0)
-    q = jax.random.normal(key, (B, HEADS, S, DIM), jnp.bfloat16)
-    k = jax.random.normal(jax.random.PRNGKey(1), q.shape, jnp.bfloat16)
-    v = jax.random.normal(jax.random.PRNGKey(2), q.shape, jnp.bfloat16)
+    shape = (B, S, HEADS * DIM)
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), shape, jnp.bfloat16)
+               for i in range(3))
     k_mask = jnp.ones((B, S), jnp.bfloat16)
     scale = DIM ** -0.5
     scope = "attn_bench_iter"
+    was = (P.ROWS, P.CAUSAL_ROWS, P.LANE_BLOCK, P.CAUSAL_WIDE_MAX_S)
+    if blocks is not None:
+        P.ROWS = P.CAUSAL_ROWS = blocks[0]
+        P.LANE_BLOCK, P.CAUSAL_WIDE_MAX_S = blocks[1], P.MAX_S
 
     def loss(q, k, v):
-        out = fused_attention(q, k, v, k_mask, True, scale, use_pallas)
+        if path == "packed":
+            out = A.fused_attention(q, k, v, k_mask, causal, scale, True,
+                                    HEADS)
+        else:
+            out = A._pack_heads(A.fused_attention(
+                *(A._unpack_heads(x, HEADS) for x in (q, k, v)), k_mask,
+                causal, scale, path == "bhsd"))
         return jnp.sum(out.astype(jnp.float32))
 
     grad = jax.grad(loss, argnums=(0, 1, 2))
@@ -57,89 +82,114 @@ def time_path(use_pallas, S, B):
             # device-time read to THIS computation's events only
             with jax.named_scope(scope):
                 g = grad(qq, k, v)
-            return qq + 0.0 * g[0], g[0][0, 0, 0, 0]
+            return qq + 0.0 * (g[0] + g[1] + g[2]), g[0][0, 0, 0]
         _, ys = jax.lax.scan(body, q, jnp.arange(ITERS, dtype=jnp.int32))
         return ys[-1]
 
-    np.asarray(many(q, k, v))  # compile + settle
-    trials = []
-    for _ in range(int(os.environ.get("PADDLE_TPU_BENCH_TRIALS", "3"))):
-        dev_s = measure_device_seconds(
-            lambda: np.asarray(many(q, k, v)), scope=scope)
-        trials.append(dev_s / ITERS)
+    try:
+        np.asarray(many(q, k, v))  # compile + settle
+        trials = []
+        for _ in range(int(os.environ.get("PADDLE_TPU_BENCH_TRIALS", "3"))):
+            dev_s = measure_device_seconds(
+                lambda: np.asarray(many(q, k, v)), scope=scope)
+            trials.append(dev_s / ITERS * 1e3)
+    finally:
+        P.ROWS, P.CAUSAL_ROWS, P.LANE_BLOCK, P.CAUSAL_WIDE_MAX_S = was
     return float(np.median(trials)), trials
 
 
-def main():
+def timed(path, B, S, causal, blocks=None):
+    try:
+        return time_path(path, B, S, causal, blocks)
+    except Exception as e:  # composed OOMs once [B,H,S,S] f32 is too big
+        if "RESOURCE_EXHAUSTED" in str(e) or "memory" in str(e).lower():
+            return None, []
+        raise
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--blocks", default="",
+                    help="extra packed blockings, rows:lanes[,rows:lanes]")
+    ap.add_argument("--no-long", action="store_true",
+                    help="skip the S 2048 / 4096 rows")
+    args = ap.parse_args(argv)
+    extra = [tuple(int(x) for x in b.split(":"))
+             for b in args.blocks.split(",") if b]
+
     rows = []
-    for S in SEQS:
-        B = max(1, TOKENS // S)
 
-        def timed(use_pallas):
-            try:
-                per_iter, trials = time_path(use_pallas, S, B)
-                return per_iter * 1e3, [t * 1e3 for t in trials]
-            except Exception as e:  # XLA path OOMs once [B,H,S,S] f32
-                if "RESOURCE_EXHAUSTED" in str(e) or "memory" in \
-                        str(e).lower():
-                    return None, []
-                raise
-
-        flash_ms, flash_tr = timed(True)
-        xla_ms, xla_tr = timed(False)
-        row = {"S": S, "B": B,
-               "flash_ms": round(flash_ms, 3) if flash_ms else None,
-               "xla_ms": round(xla_ms, 3) if xla_ms else None,
-               "speedup": round(xla_ms / flash_ms, 3)
-               if flash_ms and xla_ms else None}
+    def add(path, B, S, causal, blocks=None):
+        ms, trials = timed(path, B, S, causal, blocks)
+        row = {"path": path, "B": B, "S": S, "causal": causal,
+               "blocks": list(blocks) if blocks else None,
+               "ms": round(ms, 3) if ms is not None else None}
         rows.append(row)
-        print(json.dumps(row))
-        print(f"#   flash trials {['%.2f' % t for t in flash_tr]} "
-              f"xla trials {['%.2f' % t for t in xla_tr]}",
-              file=sys.stderr)
+        print(json.dumps(row), flush=True)
+        print(f"#   trials {['%.3f' % t for t in trials]}", file=sys.stderr)
 
-    crossover = next(
-        (r["S"] for r in rows
-         if r["flash_ms"] and (r["xla_ms"] is None
-                               or r["speedup"] > 1.0)), None)
+    for B, S in SHAPES:
+        for causal in (False, True):
+            for path in PATHS:
+                add(path, B, S, causal)
+            for blocks in extra:
+                if S % blocks[0] == 0:
+                    add("packed", B, S, causal, blocks)
+    if not args.no_long:
+        for B, S in LONG_SHAPES:
+            for path in ("bhsd", "composed"):
+                add(path, B, S, True)
+
+    write_markdown(rows)
+
+
+def write_markdown(rows):
+    def cell(path, B, S, causal):
+        ms = next((r["ms"] for r in rows if r["blocks"] is None and
+                   (r["path"], r["B"], r["S"], r["causal"])
+                   == (path, B, S, causal)), "-")
+        return "OOM" if ms is None else ms
+
     lines = [
-        "# Flash-attention crossover (measured)",
+        "# One attention module, forward + backward (measured)",
         "",
-        f"Chip: {_device_kind()}; fwd+bwd, causal, bf16, "
-        f"B*S = {TOKENS} tokens, H={HEADS}, D={DIM}; per-iter DEVICE "
-        f"time (xplane, named-scope, median of trials — "
-        f"see bench_attention.py r4 methodology).",
+        f"Chip: {_device_kind()}; bf16, H={HEADS}, D={DIM}; operands and "
+        "result in the projections' `[B, S, H*D]` layout, so `bhsd` and "
+        "`composed` include their head transposes; per-iteration DEVICE "
+        "time in ms (xplane, named scope, median of trials; "
+        "`bench_attention.py`).",
         "",
-        "| S | B | flash ms/iter | XLA ms/iter | speedup |",
-        "|---|---|---|---|---|",
+        "| B | S | causal | packed | bhsd | composed | bhsd / packed |",
+        "|---|---|---|---|---|---|---|",
     ]
-    for r in rows:
-        xla = r["xla_ms"] if r["xla_ms"] is not None else "OOM"
-        sp = f"{r['speedup']}x" if r["speedup"] is not None else "inf"
-        lines.append(f"| {r['S']} | {r['B']} | {r['flash_ms']} | "
-                     f"{xla} | {sp} |")
+    for B, S in SHAPES + LONG_SHAPES:
+        for causal in (False, True):
+            got = [cell(p, B, S, causal) for p in PATHS]
+            if all(x == "-" for x in got):
+                continue
+            ratio = f"{got[1] / got[0]:.2f}x" if all(
+                isinstance(x, float) for x in got[:2]) else "-"
+            lines.append(f"| {B} | {S} | {causal} | {got[0]} | {got[1]} | "
+                         f"{got[2]} | {ratio} |")
+    tuned = [r for r in rows if r["blocks"]]
+    if tuned:
+        lines += ["", "Packed path at other blockings (query rows : "
+                  "feature lanes a program):", "",
+                  "| B | S | causal | rows:lanes | ms |", "|---|---|---|---|---|"]
+        lines += [f"| {r['B']} | {r['S']} | {r['causal']} | "
+                  f"{r['blocks'][0]}:{r['blocks'][1]} | {r['ms']} |"
+                  for r in tuned]
     lines += [
         "",
-        f"Measured ISOLATED-kernel crossover: flash wins from "
-        f"**S = {crossover}** (speedup > 1, or the composed path's "
-        f"[B,H,S,S] f32 scores no longer fit HBM).",
-        "",
-        "This DEVICE-time crossover agrees with the in-model evidence "
-        "(bench A/B + per-op profile, r4): the gate "
-        "(`PADDLE_TPU_FLASH_MIN_S`, models/transformer.py) defaults to "
-        "512.  At S=256 the composed path wins both isolated (QK^T at "
-        "D=64 half-fills the MXU while the [S,S] score round-trip is "
-        "cheap) and in-model, where the pallas custom call additionally "
-        "pins a [B,H,S,D] layout (~15ms/step of HBM transposes XLA "
-        "otherwise folds into the projection matmuls) and splits fusion "
-        "clusters (~11ms).  Earlier wall-clock versions of this bench "
-        "showed a fake S=256 flash win — dispatch/sync overhead "
-        "distorted sub-5ms kernels.",
+        "`packed` exists up to S 1024 (`attention_packed.MAX_S`); above it "
+        "the op unpacks and `bhsd` is the streaming flash kernels.  The "
+        "model takes the fused op from `PADDLE_TPU_FLASH_MIN_S` (512) up "
+        "and the composed path below it; `PERF.md` has the in-model "
+        "numbers.",
     ]
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "BENCH_ATTENTION.md"), "w") as f:
         f.write("\n".join(lines) + "\n")
-    print(f"# crossover S={crossover}", file=sys.stderr)
 
 
 def _device_kind():

@@ -414,9 +414,13 @@ def _c_conv2d_grad(op, info):
 @rule("scaled_dot_product_attention")
 def _c_sdpa(op, info):
     q = info(op.input("Q")[0]) if op.input("Q") else _UNKNOWN
-    if q.shape is None or len(q.shape) != 4:
+    if q.shape is None or len(q.shape) not in (3, 4):
         return None
-    b, h, s, d = q.shape
+    if len(q.shape) == 3:       # packed [B, S, H*D]: the same count
+        b, s, hd = q.shape
+        h, d = 1, hd
+    else:
+        b, h, s, d = q.shape
     if any(x < 0 for x in (h, s, d)):
         return None
     b = 1 if b < 0 else b

@@ -1069,10 +1069,12 @@ def ring_attention(q, k, v, causal=False, scale=None, seq_axis="seq",
 
 
 def fused_attention(q, k, v, k_mask=None, causal=False, scale=1.0,
-                    use_flash=True, name=None):
+                    use_flash=True, n_head=None, name=None):
     """Fused scaled-dot-product attention over [B, H, S, D] tensors
     (Pallas flash kernel on TPU; see ops/attention_ops.py).  ``k_mask`` is
-    [B, S_k] with 1 = attend."""
+    [B, S_k] with 1 = attend.  With ``n_head``, ``q, k, v`` are PACKED
+    [B, S, n_head * D], as a projection ``fc`` emits them, and so is the
+    result: no head transposes around the op."""
     helper = LayerHelper("scaled_dot_product_attention", name=name)
     out = helper.create_tmp_variable(q.dtype)
     # Lse: softmax log-normalizer residual saved by the flash kernel so the
@@ -1082,10 +1084,12 @@ def fused_attention(q, k, v, k_mask=None, causal=False, scale=1.0,
     inputs = {"Q": [q], "K": [k], "V": [v]}
     if k_mask is not None:
         inputs["KMask"] = [k_mask]
+    attrs = {"causal": causal, "scale": float(scale),
+             "use_flash": use_flash}
+    if n_head:
+        attrs["n_head"] = int(n_head)
     helper.append_op(type="scaled_dot_product_attention", inputs=inputs,
-                     outputs={"Out": [out], "Lse": [lse]},
-                     attrs={"causal": causal, "scale": float(scale),
-                            "use_flash": use_flash})
+                     outputs={"Out": [out], "Lse": [lse]}, attrs=attrs)
     return out
 
 

@@ -102,9 +102,10 @@ def multi_head_attention(queries, keys, values, d_key, d_value, d_model,
 
     ``k_mask`` [B, S_k] (1=attend) covers padding; ``causal`` covers the
     decoder self-attention triangle.  With ``use_flash`` the fused Pallas
-    kernel runs QK^T->softmax->AV in VMEM (no [B,H,S,S] HBM tensor); the
-    flash path applies no attention-weight dropout — the composed-op path
-    is used instead when attention dropout is requested.
+    kernel runs QK^T->softmax->AV in VMEM (no [B,H,S,S] HBM tensor) on the
+    projections' own [B, S, H*D] layout; the fused path applies no
+    attention-weight dropout — the composed-op path is used instead when
+    attention dropout is requested.
     """
     keys = queries if keys is None else keys
     values = keys if values is None else values
@@ -127,25 +128,21 @@ def multi_head_attention(queries, keys, values, d_key, d_value, d_model,
                       bias_attr=False, param_attr=pa("k"))
         v = layers.fc(values, d_value * n_head, num_flatten_dims=2,
                       bias_attr=False, param_attr=pa("v"))
-        q = split_heads(q, d_key)
-        k = split_heads(k, d_key)
-        v = split_heads(v, d_value)
     scale = float(d_key) ** -0.5
 
-    # the VMEM-fused kernel wins once the [S,S] score tensor dominates HBM
-    # traffic; crossover is workload-dependent, so the threshold is a knob
-    # (PADDLE_TPU_FLASH_MIN_S; default 512 = the measured v5e DEVICE-time
-    # crossover, BENCH_ATTENTION.md r4: S=256 flash 0.73x of composed,
-    # S=512 1.42x, S=2048 2.77x, S=4096 composed OOMs).  At S=256 the
-    # composed path also wins IN-MODEL for extra reasons (bench A/B +
-    # per-op profile): the pallas custom call pins a [B,H,S,D] layout
-    # costing ~15ms/step of HBM transposes which XLA otherwise folds
-    # into the projection matmuls, and the call boundary splits fusion
-    # clusters (~11ms) — at D=64, QK^T can at best half-fill the MXU's
-    # 128-deep systolic array while the [S,S] round-trip is still cheap.
+    # The fused op wins once the [S,S] score tensor dominates HBM traffic;
+    # the threshold is a knob (PADDLE_TPU_FLASH_MIN_S, default 512).  From
+    # it up, the op takes the projections' [B, S, H*D] as they are (the
+    # packed kernels, ops/attention_packed.py): no head transposes, which
+    # cost the long cell ~13 ms a step under `proj` while the op took
+    # [B,H,S,D] (PERF.md section 6, PR 35 and PR 39).  Below it the
+    # composed path stays: at S=256 the [S,S] round trip is cheap and XLA
+    # folds the transposes into the projection matmuls.  BENCH_ATTENTION.md
+    # has one module alone on the three paths; whether the packed kernels
+    # move the gate is PERF.md section 7's open question.
     import os
     flash_min_s = int(os.environ.get("PADDLE_TPU_FLASH_MIN_S", "512"))
-    use_flash = use_flash and (k.shape[2] >= flash_min_s)
+    use_flash = use_flash and (k.shape[1] >= flash_min_s)
     # sequence/context parallelism: shard S over the mesh 'seq' axis and
     # attend with the ppermute ring (parallel/ring_attention.py); only for
     # self-attention (q and k share the sequence sharding)
@@ -153,12 +150,23 @@ def multi_head_attention(queries, keys, values, d_key, d_value, d_model,
     seq_parallel = _env_flag("PADDLE_TPU_SEQ_PARALLEL") and \
         keys is queries and k_mask is None
 
+    if use_flash and not dropout_rate and not seq_parallel:
+        with name_scope("core"):
+            ctx = layers.fused_attention(q, k, v, k_mask=k_mask,
+                                         causal=causal, scale=scale,
+                                         n_head=n_head)
+        with name_scope("proj"):
+            return layers.fc(ctx, d_model, num_flatten_dims=2,
+                             bias_attr=False, param_attr=pa("attnout"))
+
+    with name_scope("proj"):
+        q = split_heads(q, d_key)
+        k = split_heads(k, d_key)
+        v = split_heads(v, d_value)
+
     with name_scope("core"):
         if seq_parallel and not dropout_rate:
             ctx = layers.ring_attention(q, k, v, causal=causal, scale=scale)
-        elif use_flash and not dropout_rate:
-            ctx = layers.fused_attention(q, k, v, k_mask=k_mask,
-                                         causal=causal, scale=scale)
         else:
             product = layers.matmul(q, k, transpose_y=True, alpha=scale)
             # fold the mask into the softmax op: under bf16 AMP the

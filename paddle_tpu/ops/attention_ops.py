@@ -1,20 +1,38 @@
-"""Fused scaled-dot-product attention with a Pallas TPU kernel.
+"""Fused scaled-dot-product attention with Pallas TPU kernels.
 
 The reference composes attention from mul/softmax/matmul graph ops
 (``python/paddle/fluid/nets.py`` scaled_dot_product_attention;
 ``test_parallel_executor.py`` transformer).  On TPU the [B,H,S,S] score
-tensor is the HBM-bandwidth hot spot, so the forward fuses
-QK^T -> mask -> softmax -> AV in ONE Pallas kernel per (batch, head,
-q-block): scores live only in VMEM.  K/V stream through VMEM one block at
-a time with an online softmax (VMEM use independent of sequence length),
-and the backward runs as two flash kernels (dq; dk+dv) from the saved
-log-sum-exp residual, with fully-masked causal blocks skipped.  Measured
-crossover (``bench_attention.py`` -> checked-in ``BENCH_ATTENTION.md``,
-v5e fwd+bwd causal bf16, 64k tokens, 1024-blocks): S=512 flash 1.13x of
-XLA, S=1024 1.47x, S=2048 1.94x, S=4096 XLA OOMs ([B,H,S,S] f32 scores)
-while flash runs.  Below the PADDLE_TPU_FLASH_MIN_S crossover (default
-512, from that artifact) the composed XLA path wins and is used
-instead.
+tensor is the HBM-bandwidth hot spot, so the fused op runs
+QK^T -> mask -> softmax -> AV with the scores only ever in VMEM.  Three
+kernel families, chosen from the operands' shapes:
+
+  * PACKED operands ``[B, S, H*D]`` with an ``n_head`` attribute, as the
+    transformer's projections emit them, S a multiple of 128 up to 1024,
+    ``S_q == S_k``: the packed single-pass kernels of
+    ``attention_packed`` (no head transposes, lane-dense side arrays;
+    counted by ``attention.packed_kernel``).  A packed shape they refuse
+    is unpacked here and takes one of the two below.
+  * ``[B, H, S, D]``, same bounds on S: the single-pass ``_smalls_*``
+    kernels, one backward kernel producing dq, dk, dv.  Their residual
+    ``[BH, S, 2]``, mask ``[BH, S, 1]`` and delta ``[BH, S, 1]`` are
+    padded 64-128x by the (8, 128) tiling (134 MB an array at
+    B32 x S1024 x H8): what the packed kernels were written to avoid.
+    ``ops/mla_ops.py``'s prefill calls them, one sequence a call.
+  * ``[B, H, S, D]`` beyond: the streaming flash kernels.  K/V stream
+    through VMEM one block at a time with an online softmax (VMEM use
+    independent of sequence length); the backward runs as two kernels
+    (dq; dk+dv) from the saved residual, fully masked causal blocks
+    skipped.
+
+Measured on a v5e (``bench_attention.py`` -> ``BENCH_ATTENTION.md``, one
+module forward + backward, bf16, H8 x D64, operands and result in the
+projections' layout so the ``[B, H, S, D]`` paths pay their transposes;
+my chip run, PR 39): B32 x S1024 packed 2.79 ms (causal 2.13), the
+``[B, H, S, D]`` kernels 4.08, composed XLA 10.19; B64 x S512 1.51 /
+2.90 / 5.20; B256 x S256 1.89 / 6.30 / 5.56.  The model's gate
+(``PADDLE_TPU_FLASH_MIN_S``, default 512, ``models/transformer.py``)
+predates the packed kernels: ``PERF.md`` section 7.
 
 Masking model (matches the transformer workloads):
   * ``k_mask`` [B, S_k] with 1 = attend / 0 = padding, optional;
@@ -28,10 +46,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.ops import attention_packed
+from paddle_tpu.ops.attention_packed import NEG_INF
 from paddle_tpu.ops.registry import (
     register_op, LowerContext, ShapeInferenceSkip, infer_shape_unary)
-
-NEG_INF = -1e9
 
 
 def _reference_attention(q, k, v, k_mask, causal, scale):
@@ -574,13 +592,63 @@ def _count_flash_fallback():
     runtime_metrics.inc("attention.flash_fallback")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def fused_attention(q, k, v, k_mask, causal, scale, use_pallas):
-    out, _ = _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas)
+def _count_packed_kernel():
+    """An attention op (forward or grad) lowered to the packed
+    ``[B, S, H*D]`` kernels (fires at trace time, once per op per
+    compiled signature).  A step built for them that reads 0 has taken
+    the ``[B, H, S, D]`` path in silence."""
+    from paddle_tpu.profiler import runtime_metrics
+    runtime_metrics.inc("attention.packed_kernel")
+
+
+def _unpack_heads(x, n_head):
+    """[B, S, H*D] -> [B, H, S, D]"""
+    B, S, HD = x.shape
+    return x.reshape(B, S, n_head, HD // n_head).transpose(0, 2, 1, 3)
+
+
+def _pack_heads(x):
+    """[B, H, S, D] -> [B, S, H*D]"""
+    B, H, S, D = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+
+
+def _packed_blocks(q, k, v, n_head, causal):
+    """The packed kernels' blocking for rank-3 ``[B, S, H*D]`` operands,
+    None where they refuse the shape (``attention_packed.plan``)."""
+    return attention_packed.plan(q.shape, k.shape, v.shape, n_head, causal)
+
+
+def fused_attention(q, k, v, k_mask, causal, scale, use_pallas,
+                    n_head=None):
+    """Differentiable fused attention.  ``q, k, v`` are ``[B, H, S, D]``,
+    or PACKED ``[B, S, H*D]`` with ``n_head`` given (the output is then
+    packed too): a packed shape the packed kernels refuse is unpacked and
+    takes the ``[B, H, S, D]`` path."""
+    if q.ndim == 3 and not (
+            use_pallas and _packed_blocks(q, k, v, n_head, causal)):
+        return _pack_heads(_fused_attention(
+            _unpack_heads(q, n_head), _unpack_heads(k, n_head),
+            _unpack_heads(v, n_head), k_mask, causal, scale, use_pallas,
+            None))
+    return _fused_attention(q, k, v, k_mask, causal, scale, use_pallas,
+                            n_head)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _fused_attention(q, k, v, k_mask, causal, scale, use_pallas, n_head):
+    out, _ = _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head)
     return out
 
 
-def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas):
+def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head):
+    if q.ndim == 3:     # packed, and a shape the packed kernels take
+        _count_packed_kernel()
+        out, res = attention_packed.attention(
+            q, k, v, k_mask, causal, scale, n_head,
+            _packed_blocks(q, k, v, n_head, causal),
+            interpret=_use_interpret())
+        return out, (q, k, v, k_mask, out, res)
     if use_pallas:
         res = _pallas_attention(q, k, v, k_mask, causal, scale,
                                 interpret=_use_interpret())
@@ -592,8 +660,15 @@ def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas):
     return out, (q, k, v, k_mask, None, None)
 
 
-def _fused_bwd(causal, scale, use_pallas, res, g):
+def _fused_bwd(causal, scale, use_pallas, n_head, res, g):
     q, k, v, k_mask, o, lse = res
+    if q.ndim == 3:
+        _count_packed_kernel()
+        dq, dk, dv = attention_packed.attention_bwd(
+            q, k, v, k_mask, o, lse, g, causal, scale, n_head,
+            _packed_blocks(q, k, v, n_head, causal),
+            interpret=_use_interpret())
+        return dq, dk, dv, None
     if lse is not None:
         dq, dk, dv = _pallas_attention_bwd(
             q, k, v, k_mask, o, lse, g, causal, scale,
@@ -607,7 +682,7 @@ def _fused_bwd(causal, scale, use_pallas, res, g):
     return dq, dk, dv, None
 
 
-fused_attention.defvjp(_fused_fwd, _fused_bwd)
+_fused_attention.defvjp(_fused_fwd, _fused_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -616,28 +691,47 @@ fused_attention.defvjp(_fused_fwd, _fused_bwd)
 
 def _infer_attn(op, block):
     q = block.var(op.input("Q")[0])
+    k = block.var(op.input("K")[0])
     v = block.var(op.input("V")[0])
     out = block.var(op.output("Out")[0])
     if q.shape is None or v.shape is None:
         raise ShapeInferenceSkip()
-    out.shape = tuple(q.shape[:3]) + (v.shape[3],)
+    packed = len(q.shape) == 3          # [B, S, H*D], n_head heads
+    n_head = op.attr("n_head", 0)
+    out.shape = tuple(q.shape[:-1]) + (v.shape[-1],)
     out.dtype = q.dtype
     lse_names = op.output("Lse")
     if lse_names:
         lse = block.var(lse_names[0])
-        # packed flash residual: (softmax running max, log denominator)
-        lse.shape = tuple(q.shape[:3]) + (2,)
+        if packed and k.shape is not None and attention_packed.plan(
+                tuple(q.shape), tuple(k.shape), tuple(v.shape), n_head):
+            lse.shape = attention_packed.res_shape(*q.shape)
+        else:
+            # [B, H, S, 2] residual of the [B, H, S, D] kernels: (softmax
+            # running max, log denominator)
+            lse.shape = ((q.shape[0], n_head, q.shape[1]) if packed
+                         else tuple(q.shape[:3])) + (2,)
         lse.dtype = "float32"
 
 
-def _attn_grad_lower(ctx: LowerContext):
-    qe = ctx.env[ctx.op.input("Q")[0]]
-    ke = ctx.env[ctx.op.input("K")[0]]
-    ve = ctx.env[ctx.op.input("V")[0]]
+def _attn_operands(ctx, amp_cast=True):
+    """(q, k, v, k_mask, n_head) of an attention op or its grad;
+    ``n_head`` is set only where the op is PACKED: rank-3 ``[B, S, H*D]``
+    ``Q`` with an ``n_head`` attribute."""
+    get = ctx.input if amp_cast \
+        else (lambda slot: ctx.env[ctx.op.input(slot)[0]])
+    q, k, v = get("Q"), get("K"), get("V")
     mask_names = ctx.op.input("KMask")
     k_mask = ctx.env[mask_names[0]] if mask_names else None
+    n_head = int(ctx.attr("n_head", 0)) if q.ndim == 3 else None
     if k_mask is None:
-        k_mask = jnp.ones((qe.shape[0], ke.shape[2]), qe.dtype)
+        k_mask = jnp.ones((q.shape[0], k.shape[1 if n_head else 2]),
+                          q.dtype)
+    return q, k, v, k_mask, n_head
+
+
+def _attn_grad_lower(ctx: LowerContext):
+    qe, ke, ve, k_mask, n_head = _attn_operands(ctx, amp_cast=False)
     causal = ctx.attr("causal", False)
     scale = ctx.attr("scale", 1.0)
     g = ctx.env[ctx.op.input("Out@GRAD")[0]]
@@ -653,27 +747,43 @@ def _attn_grad_lower(ctx: LowerContext):
     q, k, v = cast_in(qe), cast_in(ke), cast_in(ve)
     use_flash = bool(ctx.attr("use_flash", True))
 
-    # if the forward saved its flash residuals (Out + Lse), reuse them —
-    # the backward kernels run directly, no forward recompute
+    # if the forward saved its residuals (Out + Lse), reuse them — the
+    # backward kernels run directly, no forward recompute
     out_names = ctx.op.input("Out")
     lse_names = ctx.op.input("Lse")
     o = ctx.env.get(out_names[0]) if out_names else None
     lse = ctx.env.get(lse_names[0]) if lse_names else None
-    if use_flash and o is not None and lse is not None:
+    saved = use_flash and o is not None and lse is not None
+    blocks = _packed_blocks(q, k, v, n_head, causal) \
+        if saved and n_head else None
+    g = g.astype(q.dtype)
+    pack = (lambda x: x)
+    if n_head and not blocks:
+        # a packed op the packed kernels refused: the forward unpacked,
+        # and so does its grad
+        q, k, v, g = (_unpack_heads(x, n_head) for x in (q, k, v, g))
+        o = _unpack_heads(o, n_head) if saved else o
+        pack = _pack_heads
+    if blocks:
+        _count_packed_kernel()
+        dq, dk, dv = attention_packed.attention_bwd(
+            q, k, v, k_mask, o, lse, g, causal, float(scale), n_head,
+            blocks, interpret=_use_interpret())
+    elif saved:
         dq, dk, dv = _pallas_attention_bwd(
-            q, k, v, k_mask, o, lse, g.astype(q.dtype), causal,
-            float(scale), interpret=_use_interpret())
+            q, k, v, k_mask, o, lse, g, causal, float(scale),
+            interpret=_use_interpret())
     else:
         _, vjp_fn = jax.vjp(
             lambda q_, k_, v_: fused_attention(q_, k_, v_, k_mask,
                                                causal, scale, use_flash),
             q, k, v)
-        dq, dk, dv = vjp_fn(g.astype(q.dtype))
+        dq, dk, dv = vjp_fn(g)
     for slot, val, prim in (("Q@GRAD", dq, qe), ("K@GRAD", dk, ke),
                             ("V@GRAD", dv, ve)):
         names = ctx.op.output(slot)
         if names and names[0]:
-            ctx.outputs[names[0]] = val.astype(prim.dtype)
+            ctx.outputs[names[0]] = pack(val).astype(prim.dtype)
 
 
 @register_op("scaled_dot_product_attention", infer_shape=_infer_attn,
@@ -681,18 +791,31 @@ def _attn_grad_lower(ctx: LowerContext):
              amp_cast=("Q", "K", "V"))
 def sdpa_lower(ctx: LowerContext):
     """Q,K,V: [B, H, S, D]; KMask: [B, S_k] (1=attend); Out: [B, H, Sq, D].
+    PACKED: Q,K,V [B, S, H*D] with the ``n_head`` attribute, as projection
+    ``fc``s emit them; Out [B, Sq, H*Dv].  The packed kernels
+    (``attention_packed``) take it where ``plan`` admits the shape; else
+    the operands are unpacked here and take the [B, H, S, D] paths.
 
-    attrs: causal (bool), scale (float), use_flash (bool, default True).
+    attrs: causal (bool), scale (float), use_flash (bool, default True),
+    n_head (int, packed form only).
     """
-    q = ctx.input("Q")
-    k = ctx.input("K")
-    v = ctx.input("V")
-    k_mask = ctx.input("KMask")
-    if k_mask is None:
-        k_mask = jnp.ones((q.shape[0], k.shape[2]), q.dtype)
+    q, k, v, k_mask, n_head = _attn_operands(ctx)
     causal = ctx.attr("causal", False)
     scale = float(ctx.attr("scale", 1.0))
     use_flash = bool(ctx.attr("use_flash", True))
+    pack = (lambda x: x)
+    if n_head:
+        blocks = use_flash and _packed_blocks(q, k, v, n_head, causal)
+        if blocks:
+            _count_packed_kernel()
+            out, res = attention_packed.attention(
+                q, k, v, k_mask, causal, scale, n_head, blocks,
+                interpret=_use_interpret())
+            ctx.set_output("Out", out)
+            ctx.set_output("Lse", res)
+            return
+        q, k, v = (_unpack_heads(x, n_head) for x in (q, k, v))
+        pack = _pack_heads
     # flash path has no attention-weight dropout; the graph builder falls
     # back to the composed path when dropout is requested in training
     if use_flash:
@@ -700,13 +823,13 @@ def sdpa_lower(ctx: LowerContext):
                                 interpret=_use_interpret())
         if res is not None:
             out, lse = res
-            ctx.set_output("Out", out)
+            ctx.set_output("Out", pack(out))
             # saved residual; consumed by the grad op (flash backward)
             ctx.set_output("Lse", lse)
             return
         _count_flash_fallback()
-    ctx.set_output("Out", _reference_attention(q, k, v, k_mask, causal,
-                                               scale))
+    ctx.set_output("Out", pack(_reference_attention(q, k, v, k_mask, causal,
+                                                    scale)))
 
 
 # ---------------------------------------------------------------------------

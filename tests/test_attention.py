@@ -203,6 +203,342 @@ class TestSmallSSinglePass:
                                        rtol=1e-3, atol=1e-4)
 
 
+def _unpack(x, n_head):
+    b, s, hd = x.shape
+    return x.reshape(b, s, n_head, hd // n_head).transpose(0, 2, 1, 3)
+
+
+def _pack(x):
+    b, h, s, d = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
+
+
+def _packed_reference(q, k, v, mask, causal, scale, n_head):
+    """``_reference_attention`` on the unpacked operands, packed back."""
+    return _pack(_reference_attention(
+        _unpack(q, n_head), _unpack(k, n_head), _unpack(v, n_head), mask,
+        causal, scale))
+
+
+def _packed_counter():
+    from paddle_tpu.profiler import runtime_metrics
+    return runtime_metrics.counter("attention.packed_kernel")
+
+
+class TestPackedAttention:
+    """The packed ``[B, S, H*D]`` single-pass kernels
+    (``ops/attention_packed.py``), interpret mode: the path the long
+    training cell's 18 attention modules take."""
+
+    HEADS, DIM, SCALE = 2, 64, 0.125
+
+    def _qkv(self, S, padded, seed=11, s_k=None):
+        rng = np.random.RandomState(seed + S)
+        mk = lambda s: jnp.asarray(
+            rng.randn(1, s, self.HEADS * self.DIM).astype("float32") * 0.3)
+        s_k = s_k or S
+        mask = np.ones((1, s_k), "float32")
+        if padded:
+            mask[0, s_k - s_k // 3:] = 0.0
+        return mk(S), mk(s_k), mk(s_k), mk(S), jnp.asarray(mask)
+
+    def _both(self, q, k, v, g, mask, causal):
+        """(out, dq, dk, dv) of the fused op on packed operands and of the
+        reference on the unpacked ones."""
+        def fused(q_, k_, v_):
+            return fused_attention(q_, k_, v_, mask, causal, self.SCALE,
+                                   True, self.HEADS)
+
+        def ref(q_, k_, v_):
+            return _packed_reference(q_, k_, v_, mask, causal, self.SCALE,
+                                     self.HEADS)
+
+        got, vjp = jax.vjp(fused, q, k, v)
+        want, ref_vjp = jax.vjp(ref, q, k, v)
+        return (got,) + vjp(g), (want,) + ref_vjp(g)
+
+    @pytest.mark.parametrize("padded", [False, True],
+                             ids=["dense", "padded-keys"])
+    @pytest.mark.parametrize("S", [128, 256, 1024])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_forward_and_grads_match_reference(self, causal, S, padded):
+        q, k, v, g, mask = self._qkv(S, padded)
+        n0 = _packed_counter()
+        got, want = self._both(q, k, v, g, mask, causal)
+        assert _packed_counter() == n0 + 2      # forward + backward
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=2e-5, err_msg=name)
+
+    @pytest.mark.parametrize("rows", [128, 256])
+    def test_fully_masked_row_grads(self, rows, monkeypatch):
+        # keys 0-2 padded + causal: rows 0-2 have no live key and softmax
+        # over everything that carries one mask, the keys ABOVE the
+        # diagonal among them.  With 128-row blocks the programs would
+        # skip those keys: such a batch row takes the whole square.
+        from paddle_tpu.ops import attention_packed as P
+        monkeypatch.setattr(P, "CAUSAL_ROWS", rows)
+        q, k, v, g, mask = self._qkv(256, True)
+        mask = mask.at[:, :3].set(0.0)
+        got, want = self._both(q, k, v, g, mask, True)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-3, atol=1e-4, err_msg=name)
+
+    @pytest.mark.parametrize("rows,lanes", [(128, 128), (256, 128),
+                                            (128, 256)])
+    def test_every_blocking_gives_the_same_numbers(self, rows, lanes,
+                                                   monkeypatch):
+        from paddle_tpu.ops import attention_packed as P
+        rng = np.random.RandomState(5)
+        mk = lambda: jnp.asarray(rng.randn(2, 256, 256).astype("float32"))
+        q, k, v, g = mk(), mk(), mk(), mk()
+        mask = jnp.ones((2, 256), "float32").at[1, 200:].set(0.0)
+        for causal in (False, True):
+            assert P.plan(q.shape, k.shape, v.shape, 4, causal) == (256, 256)
+            assert P.plan((2, 1024, 512), (2, 1024, 512), (2, 1024, 512),
+                          8, causal) == ((256, 256) if causal else (512, 512))
+            out, res = P.attention(q, k, v, mask, causal, 0.125, 4,
+                                   (rows, lanes), interpret=True)
+            grads = P.attention_bwd(q, k, v, mask, out, res, g, causal,
+                                    0.125, 4, (rows, lanes), interpret=True)
+            want, vjp = jax.vjp(
+                lambda q_, k_, v_: _packed_reference(q_, k_, v_, mask,
+                                                     causal, 0.125, 4),
+                q, k, v)
+            for a, b in zip((out,) + tuple(grads), (want,) + vjp(g)):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=1e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("dim", [32, 128])
+    def test_other_head_widths(self, dim):
+        # 4 heads a 128-lane group at D 32, one at D 128
+        rng = np.random.RandomState(dim)
+        heads = 256 // dim
+        mk = lambda: jnp.asarray(rng.randn(1, 128, 256).astype("float32"))
+        q, k, v, g = mk(), mk(), mk(), mk()
+        mask = jnp.ones((1, 128), "float32").at[0, 100:].set(0.0)
+        got, vjp = jax.vjp(lambda *a: fused_attention(
+            *a, mask, True, dim ** -0.5, True, heads), q, k, v)
+        want, ref_vjp = jax.vjp(lambda *a: _packed_reference(
+            *a, mask, True, dim ** -0.5, heads), q, k, v)
+        for a, b in zip((got,) + vjp(g), (want,) + ref_vjp(g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=2e-5)
+
+    @pytest.mark.parametrize("s_q,s_k", [(192, 192), (128, 256)],
+                             ids=["S192", "Sq128-Sk256"])
+    def test_refused_shapes_take_the_old_path(self, s_q, s_k):
+        from paddle_tpu.ops import attention_packed as P
+        q, k, v, g, mask = self._qkv(s_q, True, s_k=s_k)
+        assert P.plan(q.shape, k.shape, v.shape, self.HEADS) is None
+        n0 = _packed_counter()
+        got, want = self._both(q, k, v, g, mask, False)
+        assert _packed_counter() == n0
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=2e-5)
+        # ... and the same numbers as the [B, H, S, D] op gives unpacked
+        old = fused_attention(_unpack(q, self.HEADS), _unpack(k, self.HEADS),
+                              _unpack(v, self.HEADS), mask, False,
+                              self.SCALE, True)
+        np.testing.assert_array_equal(np.asarray(got[0]),
+                                      np.asarray(_pack(old)))
+
+    @pytest.mark.parametrize("S,kernel", [(128, True), (192, False)],
+                             ids=["S128-packed", "S192-unpacked"])
+    def test_op_and_grad_op(self, S, kernel):
+        """The IR op on packed operands: one count a lowered op (forward
+        and grad), the residual in its lane-dense shape, the numbers of
+        the reference."""
+        rng = np.random.RandomState(S)
+        hd = self.HEADS * self.DIM
+        qv, kv, vv, wv = (rng.randn(2, S, hd).astype("float32") * 0.3
+                          for _ in range(4))
+        mv = np.ones((2, S), "float32")
+        mv[1, S - 7:] = 0.0
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            q, k, v, w = (layers.data(name=n, shape=[2, S, hd],
+                                      append_batch_size=False)
+                          for n in "qkvw")
+            m = layers.data(name="m", shape=[2, S], append_batch_size=False)
+            for x in (q, k, v):
+                x.stop_gradient = False
+            out = layers.fused_attention(q, k, v, k_mask=m, causal=True,
+                                         scale=self.SCALE,
+                                         n_head=self.HEADS)
+            loss = layers.reduce_sum(out * w)
+            fluid.append_backward(loss)
+        op = next(o for o in main.global_block().ops
+                  if o.type == "scaled_dot_product_attention")
+        lse = main.global_block().var(op.output("Lse")[0])
+        assert tuple(out.shape) == (2, S, hd)
+        assert tuple(lse.shape) == ((2, hd // 128, 8, S) if kernel
+                                    else (2, self.HEADS, S, 2))
+        n0 = _packed_counter()
+        got = fluid.Executor().run(
+            main, feed={"q": qv, "k": kv, "v": vv, "m": mv, "w": wv},
+            fetch_list=[out, "q@GRAD", "k@GRAD", "v@GRAD", lse])
+        assert _packed_counter() == n0 + (2 if kernel else 0)
+        assert got[4].shape == tuple(lse.shape)
+
+        want, vjp = jax.vjp(
+            lambda *a: _packed_reference(*a, jnp.asarray(mv), True,
+                                         self.SCALE, self.HEADS),
+            jnp.asarray(qv), jnp.asarray(kv), jnp.asarray(vv))
+        for a, b in zip(got[:4], (want,) + vjp(jnp.asarray(wv))):
+            np.testing.assert_allclose(a, np.asarray(b), rtol=1e-4,
+                                       atol=2e-5)
+
+
+def _transformer_ops(seq):
+    """The op list of a small Transformer + Adam built at ``seq`` (the
+    attention branch depends on nothing but the key length): type,
+    attributes, input and output names; a long attribute (the causal
+    constant) by its digest."""
+    import hashlib
+    from paddle_tpu.framework import unique_name_scope
+    from paddle_tpu.models import transformer as T
+    hp = T.ModelHyperParams()
+    hp.d_model, hp.d_inner_hid, hp.n_layer = 128, 256, 1
+    hp.n_head, hp.d_key, hp.d_value = 2, 64, 64
+    hp.src_vocab_size = hp.trg_vocab_size = 64
+    hp.max_length = seq
+    hp.dropout = hp.attention_dropout = 0.0
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 3
+    with fluid.program_guard(main, startup), unique_name_scope(""):
+        avg_cost, _ = T.transformer(2, seq, seq, hp)
+        fluid.optimizer.Adam(learning_rate=1e-3).minimize(avg_cost)
+
+    def short(v):
+        r = repr(v)
+        return r if len(r) <= 200 else \
+            "sha256:" + hashlib.sha256(r.encode()).hexdigest()
+
+    return [[op.type,
+             sorted([k, short(v)] for k, v in op.attrs.items()),
+             sorted([k, list(v)] for k, v in op.inputs.items()),
+             sorted([k, list(v)] for k, v in op.outputs.items())]
+            for op in main.global_block().ops]
+
+
+class TestTransformerAttentionBranches:
+    """What ``multi_head_attention`` builds on either side of the
+    ``PADDLE_TPU_FLASH_MIN_S`` gate (512)."""
+
+    def test_long_sequences_hand_the_op_the_projections_layout(self):
+        n0 = _packed_counter()
+        ops = _transformer_ops(1024)
+        assert _packed_counter() == n0      # building lowers nothing
+        types = [op[0] for op in ops]
+        attn = [i for i, t in enumerate(types)
+                if t == "scaled_dot_product_attention"]
+        assert len(attn) == 3               # enc self, dec self, cross
+        for i in attn:
+            attrs = dict(map(tuple, ops[i][1]))
+            assert attrs["n_head"] == "2"
+            # straight from the three projection muls, straight to the
+            # output projection's: nothing reshapes or transposes between
+            before = types[:i]
+            last_mul = max(j for j, t in enumerate(before) if t == "mul")
+            assert "transpose" not in types[last_mul:i]
+            assert "reshape" not in types[last_mul:i]
+            assert types[i + 1] == "mul"
+        assert "transpose" not in types and "softmax" not in types
+        # ... and their mirrors are gone from the backward too
+        assert "transpose_grad" not in types
+        assert types.count("scaled_dot_product_attention_grad") == 3
+
+    @pytest.mark.parametrize("amp", [False, True], ids=["f32", "bf16-amp"])
+    def test_long_transformer_trains_on_the_packed_kernels(self, amp):
+        from paddle_tpu.models import transformer as T
+        from paddle_tpu.profiler import runtime_metrics
+        hp = T.ModelHyperParams()
+        hp.d_model, hp.d_inner_hid, hp.n_layer = 128, 256, 1
+        hp.n_head, hp.d_key, hp.d_value = 2, 64, 64
+        hp.src_vocab_size = hp.trg_vocab_size = 64
+        hp.max_length = 512
+        hp.dropout = hp.attention_dropout = 0.0
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 5
+        with fluid.program_guard(main, startup):
+            cost, _ = T.transformer(2, 512, 512, hp)
+            fluid.optimizer.Adam(learning_rate=5e-3).minimize(cost)
+        main.amp = amp
+        exe = fluid.Executor()
+        exe.run(startup)
+        feed = T.fake_batch(2, 512, 512, hp)
+        n0 = _packed_counter()
+        f0 = runtime_metrics.counter("attention.flash_fallback")
+        losses = []
+        for _ in range(4):
+            (lv,) = exe.run(main, feed=feed, fetch_list=[cost])
+            losses.append(float(np.asarray(lv).reshape(())))
+        # one compiled signature: 3 forward + 3 backward ops lowered once
+        assert _packed_counter() == n0 + 6
+        assert runtime_metrics.counter("attention.flash_fallback") == f0
+        assert losses[-1] < losses[0], losses
+
+    def test_every_packed_call_keeps_its_own_op_scope(self):
+        """The kernels of one signature are traced once and their jaxpr
+        evaluated at each call site (``attention_packed._program``): each
+        site's ``pallas_call`` must still lie under ITS op's scope, which
+        is how the device trace (``flash_attn_roofline``, the
+        ``train_*_device_ms`` groups) tells the 18 + 18 calls apart."""
+        import re
+        from paddle_tpu.models import transformer as T
+        hp = T.ModelHyperParams()
+        hp.d_model, hp.d_inner_hid, hp.n_layer = 128, 256, 2
+        hp.n_head, hp.d_key, hp.d_value = 2, 64, 64
+        hp.src_vocab_size = hp.trg_vocab_size = 64
+        hp.max_length = 512
+        hp.dropout = hp.attention_dropout = 0.0
+        main, startup = fluid.Program(), fluid.Program()
+        with fluid.program_guard(main, startup):
+            cost, _ = T.transformer(1, 512, 512, hp)
+            fluid.optimizer.Adam(learning_rate=1e-3).minimize(cost)
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            exe = fluid.Executor()
+            exe.run(startup)
+            feeds = {k: jnp.asarray(v)
+                     for k, v in T.fake_batch(1, 512, 512, hp).items()}
+            block = main.global_block()
+            parts = exe._prepare(main, block, feeds, (cost.name,), scope)
+            state = lambda names: {n: jnp.asarray(scope.find_var(n))
+                                   for n in names}
+            text = jax.jit(parts["step"]).lower(
+                feeds, state(parts["ro_names"]), state(parts["inout_names"]),
+                jax.random.PRNGKey(0)).as_text(debug_info=True)
+        sites = set(re.findall(
+            r'(ptop_scaled_dot_product_attention(?:_grad)?__[\w.@]+)'
+            r'/pallas_call"', text))
+        fwd = {s for s in sites if "_grad__" not in s}
+        assert len(fwd) == 6 and len(sites - fwd) == 6, sorted(sites)
+
+    def test_short_sequences_build_the_parents_program(self):
+        # golden: tests/golden/transformer_s256_ops.json, written from the
+        # commit before the packed op (PR 38's tree) by this very
+        # function: below the gate nothing moves, op for op
+        import json
+        import os
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "golden", "transformer_s256_ops.json")
+        with open(path) as f:
+            want = json.load(f)
+        n0 = _packed_counter()
+        got = json.loads(json.dumps(_transformer_ops(256)))
+        assert _packed_counter() == n0
+        assert len(got) == len(want)
+        for i, (a, b) in enumerate(zip(got, want)):
+            assert a == b, f"op {i}: {a} != {b}"
+        types = [op[0] for op in got]
+        assert "scaled_dot_product_attention" not in types
+        assert types.count("softmax") == 3
+
+
 class TestComposedPathMaskWiring:
     """Regression (r5): ``layers.softmax`` was shadowed by the auto-
     generated unary wrapper in layers/ops.py, which swallowed the fused

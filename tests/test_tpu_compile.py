@@ -86,6 +86,44 @@ def test_flash_attention_compiles_for_v5e(one_chip, fn, bhsd):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("causal", [False, True],
+                         ids=["full", "causal"])
+def test_packed_attention_compiles_for_v5e_lane_dense(one_chip, causal):
+    """The packed forward and backward at the long training cell's shape,
+    ``[32, 1024, 8 * 64]``: the chip's compiler takes them (the in-kernel
+    transposes of the statistics, the scoped VMEM the calls ask for), and
+    no array that crosses a call has a last dimension under 128 lanes:
+    the ``[.., S, 2]`` residual and ``[.., S, 1]`` mask and delta of the
+    ``[B, H, S, D]`` kernels are padded 64-128x in HBM by the (8, 128)
+    tiling, 134 MB an array at this shape, and must not come back."""
+    import re
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_packed as P
+
+    def fn(q, k, v, mask, g):
+        blocks = P.plan(q.shape, k.shape, v.shape, 8, causal)
+        assert blocks is not None
+        out, res = P.attention(q, k, v, mask, causal, 0.125, 8, blocks)
+        return P.attention_bwd(q, k, v, mask, out, res, g, causal, 0.125,
+                               8, blocks)
+
+    x = ((32, 1024, 512), jnp.bfloat16)
+    hlo = _compile(fn, one_chip, x, x, x, ((32, 1024), jnp.bfloat16), x)
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2, hlo
+    for line in calls:
+        line = line.split("frontend_attributes")[0]
+        shapes = [tuple(int(d) for d in dims.split(","))
+                  for dims in re.findall(r"(?:bf16|f32|s32)\[([\d,]+)\]",
+                                         line)]
+        assert len(shapes) >= 5, line
+        # (rank 1: the prefetched scalars of the causal calls, SMEM)
+        narrow = [sh for sh in shapes if len(sh) > 1 and sh[-1] < 128]
+        assert not narrow, (narrow, line)
+        assert (32, 4, 8, 1024) in shapes       # the residual, 4 MB
+
+
 def _ragged_lens(S, P, PL):
     """A ``lens`` that leaves blocks dead: a free slot, one row, lengths
     ending inside a page and on a page's edge, one stream filling the
