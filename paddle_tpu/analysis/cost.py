@@ -469,10 +469,41 @@ def _c_paged_attention(op, info):
     # rows a slot a step: 1, or a block's L (each reads every live row;
     # the K/V pages are still read once a slot)
     rows = q.shape[1] if len(q.shape) == 3 and q.shape[1] > 0 else 1
+    vc = info(op.input("VCache")[0]) if op.input("VCache") else _UNKNOWN
+    if vc.shape is not None and 0 < vc.shape[-1] != hd:
+        # value heads of their own width: the V pool's rows, and Out
+        hv = vc.shape[-1]
+        hqv = hq * hv // hd
+        flops = 2 * s * t * (hq + hqv) * rows
+        bytes_ = (s * t * (hd + hv) + s * rows * (hq + hqv)
+                  + 2 * s * rows * (hd + hv)) * item
+        return int(flops), int(bytes_)
     flops = 4 * s * t * hq * rows                # QK^T + PV per head-row
     bytes_ = (2 * s * t * hd                 # K/V pages read
               + 2 * s * rows * (hq + hd)     # q, k, v rows in + out
               + 2 * s * rows * hd) * item    # the rows' scatter (k + v)
+    return int(flops), int(bytes_)
+
+
+@rule("window_attention_step")
+def _c_window_attention_step(op, info):
+    """A window layer's decode step reads its RING, never more than the
+    window's rows a slot and never more than the live rows the caller
+    knows (``estimate(paged_live_rows=)``): a constant of the bundle,
+    whatever the stream's length."""
+    q, kr, vr = (_shape(info, op, s) for s in ("Q", "KRing", "VRing"))
+    if q is None or kr is None or vr is None or len(kr) != 3 or \
+            not _known(q[0], q[-1], kr[1], kr[2], vr[2]):
+        return None
+    s, hq = q[0], q[-1]
+    rows = min(kr[1], int(op.attr("window")))
+    if info.paged_live_rows is not None:
+        rows = min(rows, max(int(info.paged_live_rows), 1))
+    hqv = hq * vr[2] // kr[2]
+    item = _DTYPE_BYTES.get(str(info(op.input("KRing")[0]).dtype), 4)
+    flops = 2 * s * rows * (hq + hqv)
+    bytes_ = (s * rows * (kr[2] + vr[2]) + s * (hq + hqv)
+              + 2 * s * (kr[2] + vr[2])) * item
     return int(flops), int(bytes_)
 
 
@@ -815,7 +846,7 @@ def _c_dsa_select(op, info):
 
 rule("pad", "pad_grad")(_per_element(1))
 rule("swiglu")(_per_element(6))
-rule("rope")(_per_element(6))
+rule("rope", "rope_partial")(_per_element(6))
 
 
 @rule("moe_experts_gated")
@@ -841,6 +872,23 @@ def _c_gqa_attention(op, info):
     if q is None or len(q) != 3 or not _known(q[1], q[2]):
         return None
     return 4 * q[1] * q[1] * q[2], io_bytes(op, info)
+
+
+def _c_prefill_attention(op, info):
+    """Grouped attention over one prompt with key and value heads of
+    their own widths: ``T x T / 2`` pairs under the causal mask, ``T x
+    window`` inside a band."""
+    q, k, v = (_shape(info, op, s) for s in ("Q", "K", "V"))
+    if q is None or k is None or v is None or len(q) != 3 or \
+            not _known(q[1], q[2], k[2], v[2]):
+        return None
+    t, window = q[1], int(op.attr("window", 0))
+    pairs = t * min(window, t) if window else t * (t + 1) // 2
+    hqv = q[2] * v[2] // k[2]
+    return 2 * pairs * (q[2] + hqv), io_bytes(op, info)
+
+
+rule("gqa_flash_attention", "window_attention")(_c_prefill_attention)
 
 
 @rule("ssm_scan_conv", "ssm_update_conv")
@@ -910,6 +958,9 @@ rule("split", "split_grad")(_per_element(1))
 rule("relu2_grad")(_per_element(2))
 rule("rms_norm_grad", "gated_group_rms_norm_grad")(_per_element(10))
 rule("gqa_attention_grad")(_twice(_c_gqa_attention))
+rule("gqa_flash_attention_grad", "window_attention_grad")(
+    _twice(_c_prefill_attention))
+rule("rope_partial_grad")(_per_element(6))
 rule("ssm_scan_conv_grad")(_twice(_c_ssm_conv))
 rule("ssm_scan_grad")(_twice(_c_ssm_scan))
 rule("moe_route_grad")(_twice(_c_moe_route))

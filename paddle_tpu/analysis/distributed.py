@@ -1011,6 +1011,9 @@ def check_gen_bundle(prefill, decode, meta):
                 f"state var `{name}` is {shape} but must carry one row "
                 f"per slot (num_slots={num_slots}) — the bundle drifted "
                 f"between export and meta", var=name, program="decode"))
+    # -- PTA019: a window layer's cache is a ring a slot, not pages -----
+    diags.extend(_check_window_caches(dec_prog, meta))
+
     stats = list(meta.get("decode_stats") or ())
     if stats and dec_fetches is not None and len(dec_fetches) != 2:
         diags.append(Diagnostic(
@@ -1061,6 +1064,59 @@ def check_gen_bundle(prefill, decode, meta):
                         f"is {tuple(s_shape[1:])} — seeding the slot "
                         f"would write a misshapen state",
                         var=fetch_name, program="prefill"))
+    return diags
+
+
+def _check_window_caches(dec_prog, meta):
+    """A sliding-window layer reads at most ``window`` rows a step, so
+    its cache must be bounded a slot (``[num_slots, ring >= window,
+    row]``, named in ``state_vars``) and must not grow with the
+    stream: a bundle whose window layer is paged by the stream's length
+    is refused.  Both the meta's ``window_attention`` and the decode
+    program's ``window_attention_step`` ops are held to it."""
+    diags = []
+    block = dec_prog.global_block()
+    cache_vars = set(meta.get("cache_vars") or ())
+    state_vars = set(meta.get("state_vars") or ())
+    win = meta.get("window_attention") or {}
+    window = int(win.get("window") or 0)
+    named = [(n, "gen_meta's window_attention.ring_vars")
+             for n in win.get("ring_vars") or ()]
+    ops = [op for op in block.ops if op.type == "window_attention_step"]
+    named += [(op.input(slot)[0], f"a window_attention_step's {slot}")
+              for op in ops for slot in ("KRing", "VRing")]
+    if ops and not win:
+        diags.append(Diagnostic(
+            "PTA019",
+            "the decode program holds sliding-window layers but gen_meta "
+            "has no window_attention entry — the predictor cannot tell "
+            "which layer keeps which kind of cache", program="gen_meta"))
+    for op in ops:
+        window = max(window, int(op.attr("window")))
+    for name, where in dict(named).items():
+        if name in cache_vars:
+            diags.append(Diagnostic(
+                "PTA019",
+                f"`{name}` ({where}) is a page pool (cache_vars): a "
+                f"window layer's cache would grow with the stream's "
+                f"length where a step reads {window} rows — keep it as a "
+                f"ring a slot (state_vars)", var=name, program="gen_meta"))
+            continue
+        if name not in state_vars:
+            diags.append(Diagnostic(
+                "PTA019",
+                f"`{name}` ({where}) is not among gen_meta's state_vars — "
+                f"the slot's ring would be neither seeded nor cleared",
+                var=name, program="gen_meta"))
+            continue
+        shape, _ = _var_meta(block, name) if block.has_var(name) \
+            else (None, None)
+        if shape is not None and (len(shape) != 3 or
+                                  0 < shape[1] < window):
+            diags.append(Diagnostic(
+                "PTA019",
+                f"ring `{name}` is {shape} but must be [num_slots, ring "
+                f">= window {window}, row]", var=name, program="decode"))
     return diags
 
 

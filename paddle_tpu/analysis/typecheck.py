@@ -860,10 +860,12 @@ def _r_paged_attention(op, tc):
                       f"`{op.input(slot)[0]}` must be an integer index "
                       f"tensor, got {inf.dtype}",
                       op=op, var=op.input(slot)[0])
+    # the pools share pages; their rows may differ (value heads of their
+    # own width), which the per-slot width checks below hold to the heads
     if kc.shape is not None and vc.shape is not None and \
             (len(kc.shape) != len(vc.shape) or
              any(_dims_conflict(a, b)
-                 for a, b in zip(kc.shape, vc.shape))):
+                 for a, b in zip(kc.shape[:-1], vc.shape[:-1]))):
         tc.report("PTA006",
                   f"paged_attention K/V pools disagree on geometry: "
                   f"KCache `{op.input('KCache')[0]}` {kc.shape} vs "
@@ -873,8 +875,18 @@ def _r_paged_attention(op, tc):
     # query heads), as many as Q's n_head when the attr is absent
     n_head = op.attr("n_head", None)
     n_kv = op.attr("n_kv_head", None) or n_head
-    row = kc.shape[-1] if kc.shape is not None else -1
+    k_row = kc.shape[-1] if kc.shape is not None else -1
+    v_row = vc.shape[-1] if vc.shape is not None else -1
+    if k_row > 0 and v_row > 0 and k_row != v_row and \
+            (not n_kv or int(n_kv) == int(n_head or 0)
+             or k_row % int(n_kv) or v_row % int(n_kv)):
+        tc.report("PTA006",
+                  f"paged_attention K/V pools disagree on geometry: "
+                  f"KCache rows of {k_row} vs VCache rows of {v_row} do "
+                  f"not hold the same {n_kv} grouped K/V heads",
+                  op=op, var=op.input("VCache")[0])
     for slot in ("Q", "K", "V"):
+        row = v_row if slot == "V" else k_row
         inf = tc.input_info(op, slot)
         width = inf.shape[-1] if inf.shape is not None else -1
         if slot == "Q" and n_head and width > 0 and \
@@ -893,7 +905,11 @@ def _r_paged_attention(op, tc):
                   f"paged_attention feature dim {q.shape[-1]} is not "
                   f"divisible by n_head={n_head}",
                   op=op, var=op.input("Q")[0])
-    tc.set_output(op, "Out", shape=q.shape, dtype=q.dtype)
+    out = q.shape
+    if out is not None and k_row > 0 and v_row > 0 and k_row != v_row \
+            and out[-1] > 0:
+        out = tuple(out[:-1]) + (out[-1] * v_row // k_row,)
+    tc.set_output(op, "Out", shape=out, dtype=q.dtype)
     tc.set_output(op, "KCacheOut", shape=kc.shape, dtype=kc.dtype)
     tc.set_output(op, "VCacheOut", shape=vc.shape, dtype=vc.dtype)
 
@@ -1270,6 +1286,87 @@ def _r_gqa_attention(op, tc):
                          "K/V features")
 
 
+@rule("rope_partial")
+def _r_rope_partial(op, tc):
+    x = tc.input_info(op, "X")
+    _int_index(op, tc, "Pos")
+    h, r = int(op.attr("n_head", 1)), int(op.attr("rope_dim"))
+    shape = x.shape
+    if x.shape is not None and x.shape[-1] > 0:
+        if r % 2 or x.shape[-1] % h or x.shape[-1] // h < r:
+            tc.report("PTA006",
+                      f"rope_partial: {r} leading lanes (pairs) do not "
+                      f"fit {h} head(s) over {x.shape[-1]} features",
+                      op=op, var=op.input("X")[0])
+        else:
+            width = max(int(op.attr("pad_to", 0)), x.shape[-1] // h)
+            shape = tuple(x.shape[:-1]) + (h * width,)
+    tc.set_output(op, "Out", shape=shape, dtype=x.dtype)
+
+
+def _window_heads(op, tc, n_kv=None):
+    """Hold Q / K / V of a grouped attention with key and value heads
+    of their own widths to its head counts; returns Out's shape."""
+    q, k, v = (tc.input_info(op, s) for s in ("Q", "K", "V"))
+    h = int(op.attr("n_head"))
+    if q.shape is None or q.shape[-1] <= 0 or q.shape[-1] % h:
+        return None
+    dk = q.shape[-1] // h
+    if n_kv is None and k.shape is not None and k.shape[-1] > 0:
+        n_kv = k.shape[-1] // dk
+    if not n_kv or h % n_kv:
+        tc.report("PTA006", f"{op.type}: {h} query heads do not divide "
+                  f"over {n_kv} K/V heads", op=op, var=op.input("Q")[0])
+        return None
+    _last_dim_is(op, tc, "K", n_kv * dk, "K features")
+    if op.input("Sink"):
+        _last_dim_is(op, tc, "Sink", h, "sink logits (one a head)")
+    if v.shape is None or v.shape[-1] <= 0 or v.shape[-1] % n_kv:
+        return None
+    return tuple(q.shape[:-1]) + (v.shape[-1] // n_kv * h,)
+
+
+@rule("gqa_flash_attention", "window_attention")
+def _r_window_attention(op, tc):
+    q = tc.input_info(op, "Q")
+    out = _window_heads(op, tc, int(op.attr("n_kv_head")))
+    tc.set_output(op, "Out", shape=out, dtype=q.dtype)
+    if op.type == "window_attention":
+        window, ring = int(op.attr("window")), int(op.attr("ring", 0))
+        if op.output("KRing") and ring < window:
+            tc.report("PTA006", f"window_attention: a ring of {ring} "
+                      f"rows cannot hold a window of {window}", op=op,
+                      var=op.output("KRing")[0])
+        for slot, src in (("KRing", "K"), ("VRing", "V")):
+            if op.output(slot):
+                x = tc.input_info(op, src)
+                tc.set_output(op, slot, dtype=x.dtype, shape=None
+                              if x.shape is None
+                              else (1, ring, x.shape[-1]))
+
+
+@rule("window_attention_step")
+def _r_window_attention_step(op, tc):
+    q = tc.input_info(op, "Q")
+    _int_index(op, tc, "Lens")
+    out = _window_heads(op, tc)
+    for ring_slot, src in (("KRing", "K"), ("VRing", "V")):
+        ring, x = tc.input_info(op, ring_slot), tc.input_info(op, src)
+        if ring.shape is not None and len(ring.shape) == 3:
+            if x.shape is not None:
+                _last_dim_is(op, tc, ring_slot, x.shape[-1],
+                             f"lanes a row ({src}'s)")
+            if 0 < ring.shape[1] < int(op.attr("window")):
+                tc.report("PTA006",
+                          f"window_attention_step: {ring_slot} holds "
+                          f"{ring.shape[1]} rows a slot, fewer than the "
+                          f"window of {op.attr('window')}", op=op,
+                          var=op.input(ring_slot)[0])
+        tc.set_output(op, ring_slot + "Out", shape=ring.shape,
+                      dtype=ring.dtype)
+    tc.set_output(op, "Out", shape=out, dtype=q.dtype)
+
+
 @rule("split")
 def _r_split(op, tc):
     x = tc.input_info(op, "X")
@@ -1292,4 +1389,5 @@ rule("split_grad", "relu2_grad", "rms_norm_grad",
      "gated_group_rms_norm_grad", "ssm_scan_conv_grad", "ssm_scan_grad",
      "moe_route_grad", "moe_experts_grad", "moe_experts_gated_grad",
      "gqa_attention_grad", "rope_grad", "swiglu_grad", "pad_grad",
-     "mla_attention_grad")(_r_grad_mirror)
+     "mla_attention_grad", "rope_partial_grad", "window_attention_grad",
+     "gqa_flash_attention_grad")(_r_grad_mirror)

@@ -197,8 +197,13 @@ class GenPredictor:
         # learned sparse attention (``ops/dsa_ops.py``): ``top_k`` and the
         # number of layers that score and select; None without
         self.sparse_attention = self.meta.get("sparse_attention")
-        # what the newest decode step's selections counted to
-        self.last_selection_counts = {}
+        # sliding-window layers beside full-attention ones
+        # (``models/window_moe.py``): which layer keeps a ring a slot
+        # (``state_vars``) and which pages; None without
+        self.window_attention = self.meta.get("window_attention")
+        # what the newest decode step's selections and the rows its two
+        # kinds of layer read counted to (the step span's attributes)
+        self.last_step_counts = {}
         self.mask_token_id = int(self.meta.get("mask_token_id", 0))
         self.prompt_buckets = [int(b) for b in self.meta["prompt_buckets"]]
         self.max_prompt_len = min(self.prompt_buckets[-1], self.max_len)
@@ -275,6 +280,10 @@ class GenPredictor:
                 ("kv_pages", _kv_buffers), ("gen_state", _state_buffers)):
             weakref.finalize(self, _perf.unregister_hbm_provider,
                              _perf.register_hbm_provider(collection, fn))
+        if self.window_attention:
+            from paddle_tpu.profiler import runtime_metrics
+            runtime_metrics.set_gauge("gen.window.ring_bytes",
+                                      self.ring_bytes())
         # per-bucket constant prefill feeds (causal bias template)
         self._tri = {}
         # per-bucket static prefill FLOPs (analysis/cost): priced
@@ -521,7 +530,8 @@ class GenPredictor:
         with self._lock:
             with self._fluid.scope_guard(self._scope):
                 with _span("gen.prefill", tokens=len(prompt),
-                           **self._prefill_selections(len(prompt))):
+                           **self._prefill_selections(len(prompt)),
+                           **self._prefill_window_pairs(len(prompt))):
                     outs = self._exe.run(self._pre_prog, feed=feed,
                                          fetch_list=self._pre_fetch,
                                          return_numpy=False)
@@ -539,6 +549,65 @@ class GenPredictor:
         return {"dsa_rows_scored": sp["indexers"] * n * (n + 1) // 2,
                 "dsa_rows_selected": sp["indexers"]
                 * (k * (k + 1) // 2 + (n - k) * k)}
+
+    def _prefill_window_pairs(self, n):
+        """A prompt of ``n`` rows, run at its bucket, through window and
+        full layers: the (query row, key row) pairs ONE window layer's
+        band and ONE full layer's causal triangle hold, and the key
+        blocks the two prefill kernels compute a layer for the bucket (0
+        where the composed form runs).  Span attributes; {} without
+        window layers."""
+        win = self.window_attention
+        if not win:
+            return {}
+        from paddle_tpu.ops.window_ops import key_blocks_computed
+        rows = self._prefill_rows(self._bucket(n))
+        w = min(int(win["window"]), n)
+        out = {"band_pairs": w * (w + 1) // 2 + (n - w) * w,
+               "causal_pairs": n * (n + 1) // 2,
+               "window_layers": len(win["layers"]),
+               "full_layers": len(win["full_layers"])}
+        for attr, heads, window in (
+                ("band_key_blocks", win.get("heads"), int(win["window"])),
+                ("causal_key_blocks", win.get("full_heads"), 0)):
+            if heads:
+                h, hkv = heads
+                out[attr] = hkv * key_blocks_computed(rows, h // hkv,
+                                                      window)[0]
+        return out
+
+    def _count_window_rows(self, lens):
+        """The rows a decode step's two kinds of layer read, from the rows
+        its slots hold: a full layer every live row, a window layer no
+        more than ``window`` of them (its ring).  Counted always-on
+        (``gen.window.*``) and kept for the step's span; {} without
+        window layers."""
+        win = self.window_attention
+        if not win:
+            return {}
+        from paddle_tpu.profiler import runtime_metrics
+        rows = lens[lens > 0].astype(np.int64)
+        live = int(rows.sum())
+        n_win, n_full = len(win["layers"]), len(win["full_layers"])
+        in_window = int(np.minimum(rows, int(win["window"])).sum())
+        out = {"full_rows": n_full * live, "window_rows": n_win * in_window,
+               # what the step would read with every layer paged by length
+               "all_rows": (n_full + n_win) * live,
+               "ring_bytes": sum(int(b) for b in win["row_bytes"])
+               * in_window}
+        runtime_metrics.inc("gen.window.rows_read", out["window_rows"])
+        runtime_metrics.inc("gen.window.rows_saved",
+                            n_win * (live - in_window))
+        return out
+
+    def ring_bytes(self):
+        """Bytes the window layers' rings hold over all slots: a
+        constant of the bundle (0 without window layers)."""
+        win = self.window_attention
+        if not win:
+            return 0
+        return self.num_slots * int(win["ring"]) * sum(
+            int(b) for b in win["row_bytes"])
 
     def _count_selections(self, lens):
         """A decode step's selections, from the rows its slots hold: one
@@ -683,6 +752,11 @@ class GenPredictor:
         counted always-on: ``gen.<name with its first _ as a .>``, a
         counter for a sum and a histogram for a max.
 
+        A bundle with ``window_attention`` (``models/window_moe.py``)
+        counts the rows its two kinds of layer read (``_count_window_rows``):
+        ``full_rows``, ``window_rows`` and ``ring_bytes`` on the span,
+        ``gen.window.rows_read`` / ``rows_saved`` always-on.
+
         A bundle with ``sparse_attention`` (``ops/dsa_ops.py``) counts
         the step's selections from ``lens`` (``_count_selections``):
         ``dsa_rows_scored``, ``dsa_rows_selected``, ``dsa_selections``,
@@ -725,7 +799,8 @@ class GenPredictor:
         }
         feed.update(self._paged_decode_feed(lens, walk))
         feed = {k: feed[k] for k in self._dec_feeds}
-        self.last_selection_counts = self._count_selections(lens)
+        self.last_step_counts = {**self._count_selections(lens),
+                                 **self._count_window_rows(lens)}
         with self._lock:
             chaos.fire("gen.decode.stall", slots=S)
             with self._fluid.scope_guard(self._scope):
@@ -738,7 +813,7 @@ class GenPredictor:
                     self.last_decode_stats = stats[0] \
                         if stats and self.decode_stats else None
                     if not on_device:
-                        step.set(**self.last_selection_counts)
+                        step.set(**self.last_step_counts)
                     if not on_device and self.last_decode_stats is not None:
                         step.set(live=live, **self.count_decode_stats(
                             self.last_decode_stats))

@@ -668,6 +668,12 @@ class GenScheduler:
                 return False
             seed.set(pages=len(pages),
                      row_bytes=self.predictor.cache_row_bytes)
+            win = getattr(self.predictor, "window_attention", None)
+            if win:
+                # a window layer is seeded with its ring, not pages: the
+                # prompt's last rows
+                seed.set(ring_rows=len(win["layers"])
+                         * min(prompt_len, int(win["ring"])))
             try:
                 written = self.predictor.write_slot(slot_idx, kv,
                                                     prompt_len)
@@ -781,8 +787,9 @@ class GenScheduler:
                 logits = self.predictor.decode_step(
                     tokens, positions, lens=lens, on_device=True)
                 # the selections of the step just dispatched (learned
-                # sparse attention; {} without)
-                step.set(**self.predictor.last_selection_counts)
+                # sparse attention) and the rows its full and its window
+                # layers read; {} without
+                step.set(**self.predictor.last_step_counts)
                 self._in_flight = _Step(rows, logits,
                                         self.predictor.last_decode_stats,
                                         fused)

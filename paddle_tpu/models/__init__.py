@@ -6,12 +6,13 @@ TPU-native layers API."""
 from paddle_tpu.models import (resnet, transformer, vgg, mnist,
                                seq2seq, stacked_lstm, gen_lm,
                                gen_lm_long, wide_and_deep, hybrid_moe,
-                               latent_moe, latent_moe_sparse, block_moe)
+                               latent_moe, latent_moe_sparse, block_moe,
+                               window_moe)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
            "seq2seq", "stacked_lstm", "gen_lm", "gen_lm_long",
            "wide_and_deep", "hybrid_moe", "latent_moe",
-           "latent_moe_sparse", "block_moe", "ZOO_MODELS",
+           "latent_moe_sparse", "block_moe", "window_moe", "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
 
 #: zoo model names accepted by :func:`build_train_program` (and by
@@ -19,7 +20,8 @@ __all__ = ["resnet", "transformer", "vgg", "mnist",
 #: tests/test_analysis_zoo.py iterates exactly this list)
 ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
               "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
-              "hybrid_moe", "latent_moe", "latent_moe_sparse", "block_moe")
+              "hybrid_moe", "latent_moe", "latent_moe_sparse", "block_moe",
+              "window_moe")
 
 
 def build_train_program(name, backward=True):
@@ -110,6 +112,13 @@ def build_train_program(name, backward=True):
             hp = block_moe.BlockMoEConfig()
             hp.dtype = "float32"
             cost, feeds = block_moe.block_moe_train_program(16, hp)
+            fetches = [cost.name]
+        elif name == "window_moe":
+            # a dense full layer and two expert window layers at toy
+            # widths (window 8 of 16 rows, a sink a head), float32
+            hp = window_moe.WindowMoEConfig()
+            hp.dtype = "float32"
+            cost, feeds = window_moe.window_moe_train_program(16, hp)
             fetches = [cost.name]
         else:
             raise ValueError(

@@ -34,4 +34,5 @@ from paddle_tpu.ops import (  # noqa: F401
     mla_ops,
     dsa_ops,
     block_ops,
+    window_ops,
 )

@@ -1149,7 +1149,8 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     Hkv = HDkv // D
     T = P * PL
     kg = kc[page_table].reshape(S, T, Hkv, D)
-    vg = vc[page_table].reshape(S, T, Hkv, D)
+    # value heads may be narrower than key heads (their pool's own rows)
+    vg = vc[page_table].reshape(S, T, Hkv, vc.shape[-1] // Hkv)
     if Hkv != H:
         kg = jnp.repeat(kg, H // Hkv, axis=2)
         vg = jnp.repeat(vg, H // Hkv, axis=2)
@@ -1168,7 +1169,7 @@ def _xla_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
                      preferred_element_type=jnp.float32)
     if row_lens is not None:
         out = jnp.where(limit > 0, out, 0.0)
-    return out.reshape(q.shape).astype(q.dtype)
+    return out.reshape(q.shape[:-1] + (-1,)).astype(q.dtype)
 
 
 # One block of the kernel's double buffer holds about this many bytes of K
@@ -1224,7 +1225,10 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
     + lane reduction and its PV product a sublane reduction, as before;
     grouped query heads share their K/V head's rows in two float32
     ``HIGHEST`` products ``[G, D] x [D, rows]`` and
-    ``[G, rows] x [rows, D]``.
+    ``[G, rows] x [rows, D]``.  Grouped heads may have value heads of
+    another width than their key heads (``vbuf``'s rows hold as many
+    heads, ``acc_ref``'s width each): head ``g``'s value is then its own
+    lanes of the V row.
 
     ``latent``: ONE pool, whose row is the key of all ``H`` heads and
     whose leading ``Dv`` lanes (``acc_ref``'s width) are their value too
@@ -1328,7 +1332,9 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
                 acc_ref[...] = acc_ref[...] * alpha + pv
                 m_ref[...] = m_new
                 return
-            k, v = kbuf[buf, rows, kv], vbuf[buf, rows, kv]  # [CR, D]
+            # value heads of their own width: their own lanes of the row
+            vs = kv if Dv == D else pl.ds(pl.multiple_of(g * Dv, Dv), Dv)
+            k, v = kbuf[buf, rows, kv], vbuf[buf, rows, vs]  # [CR, D]
             if not narrow:
                 k, v = k.astype(f32), v.astype(f32)
             if G == 1:
@@ -1451,7 +1457,7 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
 
 
 def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4,
-                     v_width=None):
+                     v_width=None, HDv=None):
     """Shape gate of the paged kernel: heads must split Q's ``H*D``
     evenly and whole K/V heads the pool's row, the query heads divide
     evenly over them, and on the chip a head must cover whole 128-lane
@@ -1459,13 +1465,21 @@ def _paged_kernel_ok(n_head, HD, PL, interpret, HDkv=None, itemsize=4,
     float32, 16 of bfloat16): the kernel slices refs at ``h*D`` lanes
     and copies a page to ``j*PL`` rows.  The latent form (``v_width``:
     one row that is every head's key) copies and reads its row whole:
-    the row and the value's lanes, its head, must both be whole vregs."""
+    the row and the value's lanes, its head, must both be whole vregs.
+    ``HDv``: the V pool's row where it is not the K pool's (value heads
+    of another width: grouped heads only, as many heads, whole vregs)."""
     if HD % n_head:
         return False
     D = HD // n_head
     HDkv = HD if HDkv is None else HDkv
     if HDkv % D or n_head % (HDkv // D):
         return False
+    if HDv is not None and HDv != HDkv:
+        n_kv = HDkv // D
+        if v_width is not None or n_kv == n_head or HDv % n_kv:
+            return False
+        if not interpret and HDv // n_kv % 128:
+            return False
     if v_width is not None:
         if HDkv != HD // n_head or not 0 < v_width <= HDkv:
             return False
@@ -1501,7 +1515,10 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
     HD = q.shape[-1]
     itemsize = kc.dtype.itemsize
     L = q.shape[1] if q.ndim == 3 and v_width is None else 1
+    HDv = None if vc is None else vc.shape[-1]
     if L > 1:
+        if HDv != HDkv:
+            return None
         if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize):
             return None
         S, D = q.shape[0], HD // n_head
@@ -1519,7 +1536,7 @@ def _pallas_paged_attention(q, kc, vc, page_table, lens, n_head, scale,
             row_lens=row_lens)
         return out.reshape(grouped).transpose(0, 2, 1, 3, 4).reshape(q.shape)
     if not _paged_kernel_ok(n_head, HD, PL, interpret, HDkv, itemsize,
-                            v_width):
+                            v_width, HDv):
         return None
     block_pages, chunk_rows = _paged_blocking(
         P, PL, HDkv, itemsize, HDkv != HD, block_pages)
@@ -1546,7 +1563,7 @@ def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
     NP, PL, HDkv = kc.shape
     D = q.shape[-1] // n_head
     latent = vc is None
-    Dv = v_width if latent else D
+    Dv = v_width if latent else vc.shape[-1] // (HDkv // D)
     pools = (kc,) if latent else (kc, vc)
     kernel = functools.partial(_paged_decode_kernel, page_len=PL,
                                block_pages=block_pages,
@@ -1576,7 +1593,7 @@ def _paged_kernel_call(q, kc, vc, page_table, lens, *, n_head, scale,
             out_specs=pl.BlockSpec((1, n_head, Dv),
                                    lambda s, pt, ln: (s, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, block_pages * PL, HDkv), pool.dtype)
+                pltpu.VMEM((2, block_pages * PL, pool.shape[-1]), pool.dtype)
                 for pool in pools] + [
                 pltpu.SemaphoreType.DMA((2, len(pools))),
                 pltpu.SMEM((2,), jnp.int32),
@@ -1610,6 +1627,12 @@ def _infer_paged_attn(op, block):
     if q.shape is None:
         raise ShapeInferenceSkip()
     out.shape = tuple(q.shape)
+    kc, vc = (block.var(op.input(s)[0]) for s in ("KCache", "VCache"))
+    if kc.shape is not None and vc.shape is not None and \
+            kc.shape[-1] != vc.shape[-1] and kc.shape[-1] > 0:
+        # value heads narrower (or wider) than key heads
+        out.shape = tuple(q.shape[:-1]) + (
+            q.shape[-1] * vc.shape[-1] // kc.shape[-1],)
     out.dtype = q.dtype
     # KCacheOut/VCacheOut alias the persistable cache vars (in-place
     # update idiom) — their shapes are already declared
@@ -1623,7 +1646,8 @@ def paged_attention_lower(ctx: LowerContext):
     rows a slot (1 where a step decodes one token a slot, the rows of
     two blocks under block decoding); KCache/VCache: [num_pages,
     page_len, Hkv*D] persistable pool (Hkv = H unless the model groups
-    its query heads); PageTable: [S, P] int32 (P = the step's page
+    its query heads; V and VCache may hold heads of another width,
+    Hkv*Dv, and Out is then [S, L, H*Dv]: grouped heads, ``L`` = 1); PageTable: [S, P] int32 (P = the step's page
     bucket); Lens: [S, 1] int32 rows THROUGH the step's last (0 = free
     slot).  The ``L`` rows are written at positions ``Lens - L .. Lens -
     1`` of the slot's pages, over whatever an earlier step wrote there,

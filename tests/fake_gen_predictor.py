@@ -12,7 +12,7 @@ class FakeGenPredictor:
     state_vars = ()
     cache_row_bytes = 4
     last_decode_stats = None
-    last_selection_counts = {}
+    last_step_counts = {}
     free_pages = 1 << 20
 
     def __init__(self):

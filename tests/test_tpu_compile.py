@@ -590,6 +590,122 @@ def test_latent_prefill_attention_compiles_for_v5e_at_sparse_widths(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+# -- sliding-window / full attention at key heads of 192 (stored 256) and
+# value heads of 128 (``ops/window_ops.py``; ``models/window_moe.py``) ------
+
+@pytest.mark.parametrize("rows", [512, 16384])
+@pytest.mark.parametrize("kind", ["band", "causal"])
+def test_window_and_full_prefill_kernels_compile_for_v5e(one_chip, kind,
+                                                         rows):
+    """The banded kernel with its sink (8 K/V heads, 8 query heads each)
+    and the causal grouped one (4 K/V heads, 16 each) in the smallest and
+    the largest prompt bucket; at 16384 rows the head-major copies around
+    a kernel are its temporaries: 1.07 GB by the compiler's account."""
+    import functools
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import window_ops
+    n_kv, window = (8, 128) if kind == "band" else (4, 0)
+    assert window_ops.flash_blocks(rows, 64 // n_kv, window) is not None
+    fn = functools.partial(window_ops.flash_attention, n_head=64,
+                           n_kv_head=n_kv, scale=192 ** -0.5, window=window,
+                           interpret=False)
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    args = [sds((rows, 64 * 256)), sds((rows, n_kv * 256)),
+            sds((rows, n_kv * 128))]
+    if window:
+        args.append(sds((64,), jnp.float32))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1200 << 20, \
+        compiled.memory_analysis()
+
+
+@pytest.mark.parametrize("pages", [64, 288])
+def test_paged_decode_with_narrower_value_heads_compiles_for_v5e(one_chip,
+                                                                 pages):
+    """A full layer's decode step: 64 query heads over 4 K/V heads, key
+    heads stored 256 lanes wide beside value heads of 128, 32 slots."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, PL = 32, 64
+
+    def fn(q, kc, vc, pt, lens):
+        out = A._pallas_paged_attention(q, kc, vc, pt, lens, 64,
+                                        192 ** -0.5, interpret=False)
+        assert out is not None, "the gate refused the published widths"
+        assert out.shape == (S, 1, 64 * 128)
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 1, 64 * 256), jnp.bfloat16),
+                   ((S * 288, PL, 4 * 256), jnp.bfloat16),
+                   ((S * 288, PL, 4 * 128), jnp.bfloat16),
+                   ((S, pages), jnp.int32), ((S, 1), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+def test_window_decode_step_compiles_for_v5e_in_place(one_chip):
+    """A window layer's decode step over its rings (32 slots x 128 rows,
+    8 K/V heads of 256 | 128): the row's scatter and the ring kernel,
+    rings donated: no copy of a ring, a handful of MB of temporaries."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import window_ops
+    S = 32
+
+    def fn(q, k, v, k_ring, v_ring, lens, sink):
+        assert window_ops._ring_kernel_ok(q, k_ring, v_ring, 64, False)
+        return window_ops.ring_step(q, k, v, k_ring, v_ring, lens, sink, 64,
+                                    192 ** -0.5, 128, kernel=False)
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        sds((S, 64 * 256)), sds((S, 8 * 256)), sds((S, 8 * 128)),
+        sds((S, 128, 8 * 256)), sds((S, 128, 8 * 128)),
+        sds((S,), jnp.int32), sds((64,), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= S * 128 * 8 * (256 + 128) * 2
+    assert memory.temp_size_in_bytes < 64 << 20, memory
+
+
+def test_a_full_layers_decode_attention_copies_no_projection_matrix(one_chip):
+    """One full layer of a decode step at the published widths: the q /
+    k / v projections, the partial rotary, the pool's update, the paged
+    kernel, the output projection.  Left to itself XLA served the
+    rotary's lane slices by TRANSPOSING W_q, a 100 MB copy a layer every
+    step (0.14-0.31 ms on the chip); ``rope_partial`` takes the projection's
+    output behind an optimization barrier, and the step holds no
+    temporary of a matrix's size."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A, window_ops
+    S = 32
+
+    def fn(h, wq, wk, wv, wo, pos, kc, vc, pt, lens):
+        q, k = jnp.matmul(h, wq), jnp.matmul(h, wk)
+        v = jnp.matmul(h, wv) * jnp.bfloat16(0.707)
+        q = window_ops.rope_partial(q, pos, 64, 64, 5e6, 256)
+        k = window_ops.rope_partial(k, pos, 4, 64, 5e6, 256)
+        kc, vc = A._paged_cache_update((kc, vc), (k, v), pt, lens, None)
+        ctx = A._pallas_paged_attention(q, kc, vc, pt, lens, 64, 192 ** -0.5,
+                                        interpret=False)
+        return h + jnp.matmul(ctx, wo), kc, vc
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(6, 7)).lower(
+        sds((S, 1, 4096)), sds((4096, 64 * 192)), sds((4096, 4 * 192)),
+        sds((4096, 4 * 128)), sds((64 * 128, 4096)), sds((S, 1), jnp.int32),
+        sds((S * 288, 64, 4 * 256)), sds((S * 288, 64, 4 * 128)),
+        sds((S, 288), jnp.int32), sds((S, 1), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20, \
+        compiled.memory_analysis()
+
+
 @pytest.mark.parametrize("bias", ["row", "causal"])
 def test_fused_softmax_compiles_for_v5e(one_chip, bias):
     import jax.numpy as jnp
