@@ -77,11 +77,12 @@ def _counter(name):
     return runtime_metrics.counter(name)
 
 
-def _hlo_texts(exe):
-    """HLO text of every executable ``exe`` has compiled, keyed by the
-    compile record's label (feed shapes + fetches)."""
+def _hlo_texts(exe, also=()):
+    """HLO text of every executable ``exe`` has compiled (and of the
+    compiled functions in ``also``: a predictor's decode turns), keyed by
+    the compile record's label (feed shapes + fetches)."""
     out = {}
-    for entry in exe._cache.values():
+    for entry in list(exe._cache.values()) + list(also):
         holder = getattr(entry, "perf", None)
         if holder and holder.get("exec") is not None:
             out[holder["label"]] = holder["exec"].as_text()
@@ -430,7 +431,8 @@ def phase_server(n_head=8, d_head=128, d_ffn=4096, n_layer=8,
             stats = ServingClient(addr, timeout=60.0).stats()
             fallback = stats["counters"].get("gen.paged.fallback", 0) - fb0
             decode = {label: text for label, text
-                      in _hlo_texts(predictor._exe).items()
+                      in _hlo_texts(predictor._exe,
+                                    predictor._turns.values()).items()
                       if "gen_page_table" in label}
             kernels = {label: "tpu_custom_call" in text
                        for label, text in decode.items()}

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import logging
+import threading
 import time
 
 import jax
@@ -123,8 +124,9 @@ def _step_key(seed):
     threefry implementation the key is the seed's two 32-bit words, made
     here on the host and handed over with the call; ``PRNGKey`` makes the
     same two words in three dispatches of their own, each of which gives
-    the calling thread's GIL away (a serving decode turn pays one key a
-    step)."""
+    the calling thread's GIL away (a serving decode turn makes one key a
+    step, and in a turn that changed no slot it is all the call takes
+    from the host: ``CompiledStep.call``)."""
     if jax.config.jax_default_prng_impl != "threefry2x32" or \
             jax.config.jax_enable_custom_prng:
         return jax.random.PRNGKey(seed)
@@ -281,6 +283,105 @@ class _CompiledBlock:
                 args = self.place(placed, *args)
         with _span("executor.launch"):
             return self.fn(*args)
+
+
+class CompiledStep:
+    """A program's step taken out of :meth:`Executor.run`, for a caller
+    that launches it every few milliseconds inside a ``jax.jit`` of its
+    own (``GenPredictor``'s decode turn): :meth:`Executor.compiled_step`
+    makes one per (program, feed names, fetch list, scope).
+
+    What ``run`` does on every call is done here once: the program is
+    classified (``Executor._prepare``) and its read-only and in-out state
+    is resolved from the scope into two flat tuples.  They are let go of
+    the moment anyone else writes the scope (``Scope.watch``: a seeded
+    slot, a weight load), so a replaced array is held by nothing here,
+    and looked up again by the next :meth:`call`.  ``ro_names`` /
+    ``inout_names`` / ``written`` name the tuples' entries.
+
+    :meth:`flat` is the TRACEABLE step, to be inlined in the caller's
+    jitted function (a nested ``jax.jit`` would rename the op scopes
+    ``pt_step/ptop_<op>`` that device traces are read by);
+    :meth:`call` launches that function under the spans of an
+    ``Executor.run`` and writes the new state back."""
+
+    def __init__(self, exe, program, scope, parts):
+        self._exe, self._program, self._scope = exe, program, scope
+        self._step = parts["step"]
+        self.ro_names = parts["ro_names"]
+        self.inout_names = parts["inout_names"]
+        # what a launch hands back, in order: the in-out state, then
+        # persistables the step writes without reading
+        self.written = self.inout_names + parts["create_state"]
+        # (ro, inout) as resolved, None once the scope was written; the
+        # mutex orders a write on another thread with a call under way
+        # (re-entrant: resolving a numpy value writes the scope itself)
+        self._state, self._mutex = None, threading.RLock()
+        scope.watch(self._forget)
+
+    def flat(self, feeds, ro, inout, key):
+        """``(fetches, written)``: the program's step over ``feeds`` (a
+        dict), ``ro`` / ``inout`` (tuples as :meth:`call` passes them)
+        and an RNG ``key``; ``written`` is a tuple in the order of
+        :attr:`written`.  Traceable, never jitted here."""
+        fetches, new_state = self._step(
+            feeds, dict(zip(self.ro_names, ro)),
+            dict(zip(self.inout_names, inout)), key)
+        return fetches, tuple(new_state[n] for n in self.written)
+
+    def _forget(self):
+        """Someone else wrote the scope: hold none of its arrays."""
+        with self._mutex:
+            self._state = None
+
+    def _resolve(self, name):
+        """``name``'s array in the scope, committed to the executor's
+        device: a loaded array is not, the step's outputs are, and the
+        two would be two signatures of one executable."""
+        device = self._exe._feed_device()
+        v = self._exe._state_value(self._scope, name, device)
+        if device is not None and isinstance(v, jax.Array) \
+                and not v.committed:
+            v = jax.device_put(v, device)
+            self._scope.set_var(name, v, by=self)
+        return v
+
+    def call(self, fn, feed):
+        """One launch: ``fn(*feed(), ro, inout, key) -> (out, written)``,
+        ``fn`` a jitted function that inlines :meth:`flat` (and donates
+        ``inout``).  Opens ``executor.run`` and its phases as
+        ``Executor.run`` does: ``executor.feed`` is ``feed()`` (the
+        caller building its arguments: nothing is converted here),
+        ``executor.dispatch`` the state looked up where the scope
+        changed and ``executor.launch``, ``executor.fetch`` the
+        write-back; ``executor.step_seconds`` and the HBM census's tick
+        as there.  Returns ``out``, unread."""
+        exe, scope = self._exe, self._scope
+        with _span("executor.run"), self._mutex:
+            with _span("executor.feed"):
+                args = feed()
+            with _span("executor.dispatch"):
+                if self._state is None:
+                    self._state = (tuple(map(self._resolve, self.ro_names)),
+                                   tuple(map(self._resolve,
+                                             self.inout_names)))
+                ro, inout = self._state
+                exe._run_counter += 1
+                key = _step_key((self._program.random_seed or 0) * 1000003
+                                + exe._run_counter)
+                t0 = time.perf_counter()
+                with _span("executor.launch"):
+                    out, written = fn(*args, ro, inout, key)
+            from paddle_tpu import profiler as _profiler
+            from paddle_tpu.obs import perf as _perf
+            _profiler.runtime_metrics.observe("executor.step_seconds",
+                                              time.perf_counter() - t0)
+            _perf.census_tick(scope)
+            with _span("executor.fetch"):
+                self._state = ro, written[:len(inout)]
+                for n, v in zip(self.written, written):
+                    scope.set_var(n, v, by=self)
+        return out
 
 
 class ScopeEnv(dict):
@@ -928,8 +1029,9 @@ class Executor:
                 sample.update({n: a[0] for n, a in per_step_feed.items()})
                 parts = self._prepare(program, block, sample,
                                       tuple(fetch_names), scope)
-                sig = parts["sig"] + ("run_steps", steps,
-                                      tuple(sorted(per_step_feed)))
+                sig = self._signature(
+                    program, block, sample, tuple(fetch_names), scope) + (
+                        "run_steps", steps, tuple(sorted(per_step_feed)))
                 step = parts["step"]
                 inout_names = parts["inout_names"]
                 create_state = parts["create_state"]
@@ -1204,6 +1306,29 @@ class Executor:
         return outs
 
     # ------------------------------------------------------------------
+    def compiled_step(self, program, feed_names, fetch_list, scope):
+        """The :class:`CompiledStep` of ``program`` fed ``feed_names`` and
+        fetching ``fetch_list`` over ``scope``: the step a caller launches
+        itself, every few milliseconds, without ``run``'s per-call lookup
+        and state walk.  ``PADDLE_TPU_VERIFY`` / ``PADDLE_TPU_OPT`` apply
+        once, here.  A program that has to be interpreted (a host op, op
+        profiling) has no such step and raises."""
+        fetch_names = tuple(f.name if isinstance(f, framework.Variable)
+                            else f for f in fetch_list)
+        feed = dict.fromkeys(feed_names)
+        if _env_flag("PADDLE_TPU_VERIFY"):
+            self._maybe_verify(program, feed, fetch_names)
+        program = self._maybe_optimize(program, feed, fetch_names)
+        parts = self._prepare(program, program.global_block(), feed,
+                              fetch_names, scope)
+        if parts["interpret"]:
+            raise NotImplementedError(
+                "compiled_step: the program has to be interpreted op by "
+                "op (a host op, or op profiling is on); run it through "
+                "Executor.run")
+        return CompiledStep(self, program, scope, parts)
+
+    # ------------------------------------------------------------------
     def _feed_device(self):
         """Target placement for feed arrays; ParallelExecutor overrides to
         None so sharded placement happens against the mesh instead."""
@@ -1253,13 +1378,11 @@ class Executor:
     def _prepare(self, program, block, feed_arrays, fetch_names, scope):
         """Classify block variables and build the traceable step function.
 
-        Returns a dict with the cache signature, the (untraced) ``step``
-        callable, the state-name partitions, and the interpret flag.
+        Returns a dict with the (untraced) ``step`` callable, the
+        state-name partitions, and the interpret flag.  Of ``feed_arrays``
+        only the names (and their lods in the scope) are read.
         O(#ops) — callers should hit the signature cache first.
         """
-        sig = self._signature(program, block, feed_arrays, fetch_names,
-                              scope)
-
         feed_names = tuple(sorted(feed_arrays))
 
         # classify non-feed external inputs (state) and written persistables
@@ -1404,7 +1527,7 @@ class Executor:
             n for n in inout_names + create_state
             if isinstance(_safe_var(block, n), framework.Parameter))
 
-        return {"sig": sig, "step": step, "feed_names": feed_names,
+        return {"step": step, "feed_names": feed_names,
                 "ro_names": ro_names, "inout_names": inout_names,
                 "create_state": create_state, "interpret": interpret,
                 "uses_rng": uses_rng, "param_names": param_names}

@@ -37,25 +37,39 @@ slot's row of each with the slot's pages, and the decode step reads and
 writes them whole, in place.  What the meta holds decides; there is no
 flag.
 
-``warmup`` declares BOTH signature families — every prefill bucket and
-the decode signature family — through ``Executor.warmup``, plus one
-seeding signature per prefill bucket and the scheduler's on-device token
-pick, so a server flips ``/readyz`` with the whole generation path
-compiled.
+``warmup`` declares BOTH signature families — every prefill bucket
+(``Executor.warmup``) and the decode turn of every page bucket — plus one
+seeding signature per prefill bucket, so a server flips ``/readyz`` with
+the whole generation path compiled.
+
+A decode TURN is one compiled call (:meth:`GenPredictor.dispatch_turn`).
+The per-slot decode state — the token each slot feeds next, its position,
+its rows and the whole ``[S, pages_per_slot]`` page table — lives on the
+device beside the pools, donated like them, and the call advances it:
+it slices the table to the step's page bucket (static: the jit key, one
+executable a bucket), runs the decode program's step, picks each slot's
+next token (the argmax, first index on ties) and adds one to the
+position and the rows of every live slot.  The host keeps a numpy mirror
+of that state for its bookkeeping and sends, as one argument of the same
+call, a small int32 PATCH of the rows that an admission, an eviction or
+an ending changed; a turn in which nothing changed passes the constant
+"nothing" patch that already sits on the device.  What comes back to the
+host is one small array a turn (:meth:`GenPredictor.read_turn`): the
+``[S]`` ids and the step's ``decode_stats``.
 
 The KV pool is ``[num_pages, page_len, H*D]`` pages addressed through a
-host-side per-slot page table (``page_len``, ``num_pages`` and
-``page_buckets`` are required keys of ``gen_meta.json``).  The predictor
-owns the page allocator (:meth:`alloc_slot_pages` /
-:meth:`free_slot_pages`, driven by the scheduler's admit/evict), pads
-the page-table feed to a declared ``page_buckets`` edge each step (the
-decode jit key is the bucket), and warms one decode signature per
-bucket.  Decode reads scale with live prefix pages, not ``max_len``.
+per-slot page table (``page_len``, ``num_pages`` and ``page_buckets`` are
+required keys of ``gen_meta.json``).  The predictor owns the page
+allocator (:meth:`alloc_slot_pages` / :meth:`free_slot_pages`, driven by
+the scheduler's admit/evict; a slot's changed row reaches the device in
+the next turn's patch), slices the table to a declared ``page_buckets``
+edge each step (the decode jit key is the bucket), and warms one decode
+executable per bucket.  Decode reads scale with live prefix pages, not
+``max_len``.
 """
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import json
 import os
@@ -68,7 +82,7 @@ import numpy as np
 
 from paddle_tpu.obs.trace import span as _span
 
-__all__ = ["GenPredictor", "is_gen_bundle", "pick_tokens"]
+__all__ = ["GenPredictor", "is_gen_bundle"]
 
 META_FILENAME = "gen_meta.json"
 
@@ -123,42 +137,48 @@ def _seed_pool(pools, kv, idx, n, states=(), new_states=(), slot=0, *,
     return jax.lax.fori_loop(0, n, write_entry, tuple(pools)) + states
 
 
-@jax.jit
-def _pick(logits, override):
-    ids = jnp.argmax(logits.reshape(logits.shape[0], -1), axis=-1)
-    return jnp.where(override >= 0, override, ids.astype(jnp.int32))[:, None]
+# a turn's patch: one int32 row a slot, ``[S, _P_TABLE + pages_per_slot]``
+# -- what of the slot's device-side decode state the host replaces
+# before the step runs
+_P_FLAGS, _P_TOKEN, _P_POS, _P_LENS, _P_TABLE = 0, 1, 2, 3, 4
+# bits of _P_FLAGS: the row's position and rows are set; its table row is
+_SET_ROW, _SET_TABLE = 1, 2
 
 
-def pick_tokens(logits, override):
-    """The greedy next token of every slot, ``[S, 1]`` int32: the argmax
-    of the slot's row of ``logits`` (``[S, V]``; first index on ties, as
-    ``np.argmax``), or ``override[slot]`` where that is not negative (a
-    slot whose newest token the host holds: its first, from the prefill).
-
-    Logits that are a device array are picked from on the device, in one
-    compiled call, and the tokens stay there: fed to the next
-    :meth:`GenPredictor.decode_step` as they are, and read (``[S]`` ids,
-    not ``[S, V]`` logits) when the step is collected.  Host logits are
-    picked from on the host; ``None`` (no step to pick from) gives the
-    override alone."""
-    override = np.asarray(override, np.int32)
-    if isinstance(logits, jax.Array):
-        return _pick(logits, override)
-    if logits is None:
-        return np.maximum(override, 0)[:, None]
-    ids = np.argmax(np.asarray(logits).reshape(len(override), -1), axis=-1)
-    return np.where(override >= 0, override, ids.astype(np.int32))[:, None]
-
-
-def _warm_jit(fn, signature, run):
-    """``run()``, and the :class:`~paddle_tpu.obs.perf.WarmupReport`
-    bucket of what it compiled of the jitted ``fn``."""
+def _host_call(fn, *args):
+    """``fn(*args)``, counted as ONE of the calls a decode turn costs the
+    thread that makes it (``gen.decode.host_calls``): the launch of a
+    compiled turn, a transfer to the device, a read from it.  Every such
+    call of the decode path goes through here, so the counter sees one
+    that comes back."""
     from paddle_tpu.profiler import runtime_metrics
-    size0 = fn._cache_size()
+    runtime_metrics.inc("gen.decode.host_calls")
+    return fn(*args)
+
+
+def _block_view(positions, lens, block_length):
+    """A block bundle's step, from each slot's position and rows (numpy
+    or traced arrays alike): ``(lens, fused, walk)``.  ``lens``: the rows
+    the step is fed, rounded up to the end of the block that row
+    ``lens - 1`` lies in (a step writes the block's rows whole);
+    ``fused``: the slots whose token completes its block, so that the
+    step stores the block and opens the next; ``walk``: the rows the
+    step reads and writes through (``lens``, and the opened block)."""
+    lens = -(-lens // block_length) * block_length
+    fused = (positions + 1 == lens) & (lens > 0)
+    return lens, fused, lens + block_length * fused
+
+
+def _warm_entry(signature, run, compiled):
+    """``run()``, and the :class:`~paddle_tpu.obs.perf.WarmupReport`
+    bucket of what it compiled: ``compiled()`` counts the executables
+    made so far."""
+    from paddle_tpu.profiler import runtime_metrics
+    size0 = compiled()
     hits0 = runtime_metrics.counter("compile_cache.hits")
     t0 = time.perf_counter()
     run()
-    fresh = fn._cache_size() - size0
+    fresh = compiled() - size0
     hit = runtime_metrics.counter("compile_cache.hits") - hits0
     return {"signature": signature, "compiles": fresh,
             "seconds": time.perf_counter() - t0,
@@ -244,15 +264,24 @@ class GenPredictor:
             int(block.var(n).shape[-1])
             * jnp.dtype(str(block.var(n).dtype)).itemsize
             for n in self.cache_vars)
-        # host-side page allocator state (all mutated under _lock): the
-        # device only ever sees the bucketed table SLICE
+        # host-side page allocator state (all mutated under _lock); the
+        # table is the host's mirror of the device's, and ``_stale_rows``
+        # the slots whose row the next turn's patch has to carry there
         self._page_table = np.zeros(
             (self.num_slots, self.pages_per_slot), np.int32)
+        self._stale_rows = set()
         self._slot_pages = {}
         self._free_list = list(range(self.num_pages))
-        # decode dispatches derive gen.decode_mfu (not train.mfu): the
-        # executor keys the gauge off this program attribute
-        self._dec_prog._mfu_gauge = "gen.decode_mfu"
+        # the decode turn (``_launch``): the device's decode state (token,
+        # position, rows, page table: made at the first turn), the host's
+        # mirror of its positions and rows, the constant patch of a turn
+        # in which nothing changed, and one compiled turn a page bucket
+        self._step = None
+        self._dev_state = None
+        self._dev_pos = np.zeros(self.num_slots, np.int32)
+        self._dev_lens = np.zeros(self.num_slots, np.int32)
+        self._no_patch = None
+        self._turns = {}
         # HBM census: the KV pool (plus its host page table) is its own
         # collection, ``kv_pages``; weakref'd so a dropped predictor
         # releases cleanly
@@ -291,8 +320,8 @@ class GenPredictor:
         self._prefill_cost = {}
         # clear_slot's zero rows, made once (device arrays)
         self._clear_kv = None
-        # the newest dispatched step's decode_stats array (None for a
-        # bundle without one): see decode_step(on_device=True)
+        # the newest blocking step's decode_stats array (None for a
+        # bundle without one)
         self.last_decode_stats = None
         self._length_cost_fn = None
 
@@ -437,6 +466,7 @@ class GenPredictor:
             self._slot_pages[slot] = pages
             self._page_table[slot, :] = 0
             self._page_table[slot, :n] = pages
+            self._stale_rows.add(slot)
             return list(pages)
 
     def free_all_pages(self):
@@ -458,6 +488,7 @@ class GenPredictor:
                 return 0
             self._free_list.extend(pages)
             self._page_table[slot, :] = 0
+            self._stale_rows.add(slot)
             return len(pages)
 
     def _prefill_feed(self, prompt, bucket):
@@ -719,38 +750,231 @@ class GenPredictor:
             self._seed_slot(slot, self._clear_kv)
 
     # -- decode ------------------------------------------------------------
-    def decode_step(self, tokens, positions, lens, on_device=False):
-        """One decode iteration over the whole slot pool: for every live
-        slot the token at ``positions``, the logits for the position
-        after it.
+    def _compiled_turn(self, pages):
+        """The compiled turn of page bucket ``pages``: ``turn(state,
+        patch, ro, inout, key) -> ((state, logits, read), written)`` over
+        the decode program's step (``Executor.compiled_step``, inlined:
+        the op scopes keep their names), ``state`` and ``inout`` donated.
+        Every bucket's turn is the same function but for the static width
+        of the table slice."""
+        fn = self._turns.get(pages)
+        if fn is not None:
+            return fn
+        if self._step is None:
+            self._step = self._exe.compiled_step(
+                self._dec_prog, self._dec_feeds, self._dec_fetch,
+                self._scope)
+        step, S, L = self._step, self.num_slots, self.block_length
+        block = self._dec_prog.global_block()
+        kinds = {n: jnp.dtype(str(block.var(n).dtype))
+                 for n in self._dec_feeds}
+        with_stats = bool(self.decode_stats)
+
+        def turn(state, patch, ro, inout, key):
+            token, pos, lens, table = state
+            flags = patch[:, _P_FLAGS, None]
+            set_row = (flags & _SET_ROW) > 0
+            token = jnp.where(patch[:, _P_TOKEN, None] >= 0,
+                              patch[:, _P_TOKEN, None], token)
+            pos = jnp.where(set_row, patch[:, _P_POS, None], pos)
+            lens = jnp.where(set_row, patch[:, _P_LENS, None], lens)
+            table = jnp.where((flags & _SET_TABLE) > 0,
+                              patch[:, _P_TABLE:], table)
+            feeds = {"gen_token": token, "gen_pos": pos,
+                     "gen_page_table": table[:, :pages],
+                     "gen_lens": _block_view(pos, lens, L)[0] if L > 1
+                     else lens}
+            (logits, *stats), written = step.flat(
+                {n: feeds[n].astype(kinds[n]) for n in kinds}, ro, inout,
+                key)
+            # the greedy pick: first index on ties, as np.argmax
+            ids = jnp.argmax(logits.reshape(S, -1), axis=-1
+                             ).astype(jnp.int32)
+            read = ids if not with_stats else jnp.concatenate(
+                [ids, stats[0].astype(jnp.int32).reshape(-1)])
+            live = (lens > 0).astype(jnp.int32)
+            return ((ids[:, None], pos + live, lens + live, table),
+                    logits, read), written
+
+        fn = jax.jit(turn, donate_argnums=(0, 3))
+        from paddle_tpu.obs import perf as _perf
+        if _perf.capture_enabled():
+            # the cost / memory record of the bucket's executable
+            # (``paddle_tpu profile compile``), as ``Executor.run``'s
+            fn = _perf.instrument_jit(
+                fn, label=f"gen_turn:gen_page_table:{S}x{pages}")
+        self._turns[pages] = fn
+        return fn
+
+    def _patch(self, tokens, positions, lens, every_row):
+        """The turn's patch (numpy), or None where nothing changed: the
+        rows whose position or rows differ from what the device holds
+        (all of them with ``every_row``), the tokens the host sets
+        (``tokens`` >= 0) and the table rows the allocator changed since
+        the last turn.  Caller holds ``_lock``."""
+        changed = np.ones(self.num_slots, bool) if every_row else \
+            (positions != self._dev_pos) | (lens != self._dev_lens)
+        if not (changed.any() or self._stale_rows or (tokens >= 0).any()):
+            return None
+        patch = np.zeros((self.num_slots, _P_TABLE + self.pages_per_slot),
+                         np.int32)
+        patch[:, _P_FLAGS] = _SET_ROW * changed
+        patch[:, _P_TOKEN] = tokens
+        patch[:, _P_POS] = positions
+        patch[:, _P_LENS] = lens
+        for slot in self._stale_rows:
+            patch[slot, _P_FLAGS] |= _SET_TABLE
+            patch[slot, _P_TABLE:] = self._page_table[slot]
+        self._stale_rows.clear()
+        return patch
+
+    def _launch(self, tokens, positions, lens, every_row=False, pages=None):
+        """Dispatch one decode turn, not waited for: ``(logits, read,
+        patched)``, the first two as the device arrays the executable
+        will fill (``read``: the ``[S]`` ids it picked, then the step's
+        ``decode_stats``, flat), ``patched`` whether the host changed
+        anything.
+
+        ``tokens``: int32 ``[S]``, the token a slot is fed where the host
+        holds it, -1 where it is the device's own pick from the turn
+        before.  ``positions`` / ``lens``: what the step is to run at;
+        only where they differ from what the device's state has advanced
+        to (or everywhere, with ``every_row``) do they travel, in the
+        patch.  ONE compiled call either way, and no transfer outside it:
+        a numpy patch is an argument of the call.  ``pages``: the page
+        bucket, where it is not to follow from ``lens`` (the warm-up's).
+
+        The ``gen.decode.stall`` failpoint fires INSIDE the lock: a
+        ``delay`` action models per-iteration device time serialized per
+        replica (the decode bench's cost model), an ``error`` a device
+        fault in the decode step."""
+        from paddle_tpu.fault import chaos
+        from paddle_tpu.obs import trace as _trace
+        from paddle_tpu.profiler import runtime_metrics
+        S, L = self.num_slots, self.block_length
+        fed = walk = lens
+        if L > 1:
+            # slots whose token completes its block: the step stores the
+            # block and opens the next, whose rows the kernel walks too
+            fed, fused, walk = _block_view(positions, lens, L)
+            live = int(np.count_nonzero(lens))
+            n_fused = int(np.count_nonzero(fused))
+            runtime_metrics.inc("gen.block.forwards", live)
+            runtime_metrics.inc("gen.block.fused", n_fused)
+            runtime_metrics.inc("gen.block.rows", (live + n_fused) * L)
+        if pages is None:
+            pages = self._page_bucket(walk)
+        self.last_step_counts = {**self._count_selections(fed),
+                                 **self._count_window_rows(fed)}
+        with self._lock:
+            chaos.fire("gen.decode.stall", slots=S)
+            t0 = time.perf_counter()
+            if self._dev_state is None:
+                device = self._exe.place.jax_device()
+                self._dev_state = tuple(
+                    _host_call(jax.device_put, np.zeros(shape, np.int32),
+                               device)
+                    for shape in ((S, 1), (S, 1), (S, 1),
+                                  self._page_table.shape))
+                nothing = np.zeros(
+                    (S, _P_TABLE + self.pages_per_slot), np.int32)
+                nothing[:, _P_TOKEN] = -1
+                # uncommitted, as a numpy patch is to ``jit``: the two
+                # kinds of turn are one executable
+                self._no_patch = _host_call(jnp.asarray, nothing)
+            turn, patch = self._compiled_turn(pages), None
+
+            def feed():     # the launch's ``executor.feed``
+                nonlocal patch
+                patch = self._patch(tokens, positions, lens, every_row)
+                return (self._dev_state,
+                        self._no_patch if patch is None else patch)
+
+            with self._fluid.scope_guard(self._scope):
+                self._dev_state, logits, read = self._step.call(
+                    functools.partial(_host_call, turn), feed)
+            advance = (lens > 0).astype(np.int32)
+            self._dev_pos = positions + advance
+            self._dev_lens = lens + advance
+            _trace.record_span("gen.dispatch", t0, time.perf_counter() - t0,
+                               parent_id=_trace.current_span_id(),
+                               patched=int(patch is not None), pages=pages)
+        return logits, read, patch is not None
+
+    def dispatch_turn(self, tokens, positions, lens):
+        """The scheduler's side of a decode turn: advance EVERY slot of
+        the pool by one token, dispatched and not waited for.  ``tokens``
+        / ``positions`` / ``lens``: int32 ``[S]`` as :meth:`_launch`
+        takes them (zeros for a free slot; ``tokens`` -1 where the slot
+        goes on from the device's own pick).  Returns the turn's small
+        result, still on the device, for :meth:`read_turn`; the step's
+        counts (selections, window rows) are in ``last_step_counts``.
+
+        Always-on: ``gen.decode.turns_steady`` (nothing changed: the
+        call took no array from the host but the RNG key) or
+        ``gen.decode.turns_patched``; and ``gen.decode.host_calls``,
+        counted where each is made (:func:`_host_call`): the launches of
+        a compiled turn, the transfers that put the decode state on the
+        device, and the reads of a turn's result (a blocking
+        ``decode_step`` reads the logits too)."""
+        from paddle_tpu.profiler import runtime_metrics
+        _, read, patched = self._launch(np.asarray(tokens, np.int32),
+                                        np.asarray(positions, np.int32),
+                                        np.asarray(lens, np.int32))
+        runtime_metrics.inc("gen.decode.turns_patched" if patched
+                            else "gen.decode.turns_steady")
+        return read
+
+    def read_turn(self, read):
+        """Wait for a dispatched turn and read it, in ONE transfer:
+        ``(ids, counts)``, the token every slot's row yielded (a list of
+        ``S``) and the step's ``decode_stats`` columns reduced and
+        counted (:meth:`count_decode_stats`; {} without)."""
+        ids, stats = self._split_read(_host_call(np.asarray, read))
+        return ids.tolist(), \
+            {} if stats is None else self.count_decode_stats(stats)
+
+    def _split_read(self, read):
+        S = self.num_slots
+        if not self.decode_stats:
+            return read, None
+        return read[:S], read[S:].reshape(-1, len(self.decode_stats))
+
+    def decode_step(self, tokens, positions, lens):
+        """One BLOCKING decode iteration over the whole slot pool: for
+        every live slot the token at ``positions``, the logits for the
+        position after it.  The same compiled turn as the scheduler's
+        (:meth:`dispatch_turn`) with every slot's row in the patch, then
+        the read of its result and of the logits.
 
         ``tokens``/``positions``: int32 ``[S]`` (zeros for free slots).
         ``lens``: int32 ``[S]`` prefix rows INCLUDING the current token
-        (0 = free slot: its pages are never touched) — the page-table
-        feed is sliced to the smallest declared page bucket covering
+        (0 = free slot: its pages are never touched) — the page table
+        is sliced to the smallest declared page bucket covering
         ``max(lens)``, so the jit key is the bucket.  Returns logits
         ``[S, V]``.
 
         A block bundle (``block_length`` L > 1) forwards the L rows of
         the block that row ``lens - 1`` lies in (``lens`` is rounded up
-        to that block's end here: a step writes the block's rows whole),
-        commits the token at ``positions`` there, and returns the logits
-        of the block's leftmost masked row.  A token that completes its
-        block (``positions + 1`` a multiple of L) makes it the forward
-        that stores the block's K/V, and the SAME forward carries the
-        next block with every row masked (the program takes ``2L`` rows a
-        slot; the page-table feed covers ``lens + L`` for such a slot),
-        so the logits are always those for ``positions + 1``.  A
-        blocking call raises where such a slot holds too few pages for
-        the block it opens; the scheduler's allocation
+        to that block's end in the turn: a step writes the block's rows
+        whole), commits the token at ``positions`` there, and returns the
+        logits of the block's leftmost masked row.  A token that
+        completes its block (``positions + 1`` a multiple of L) makes it
+        the forward that stores the block's K/V, and the SAME forward
+        carries the next block with every row masked (the program takes
+        ``2L`` rows a slot; the page bucket covers ``lens + L`` for such
+        a slot), so the logits are always those for ``positions + 1``.
+        This call raises where such a slot holds too few pages for the
+        block it opens; the scheduler's allocation
         (:meth:`pages_needed`) covers it.
 
-        A bundle with ``decode_stats`` fetches, with the logits, one
+        A bundle with ``decode_stats`` computes, with the logits, one
         small int32 array ``[n, len(decode_stats)]`` a step; each column's
         sum (or max, as the meta says) goes on the ``gen.decode_step``
         span under the column's name, with ``live`` (live slots), and is
         counted always-on: ``gen.<name with its first _ as a .>``, a
-        counter for a sum and a histogram for a max.
+        counter for a sum and a histogram for a max.  The array is kept
+        in ``last_decode_stats``.
 
         A bundle with ``window_attention`` (``models/window_moe.py``)
         counts the rows its two kinds of layer read (``_count_window_rows``):
@@ -760,64 +984,24 @@ class GenPredictor:
         A bundle with ``sparse_attention`` (``ops/dsa_ops.py``) counts
         the step's selections from ``lens`` (``_count_selections``):
         ``dsa_rows_scored``, ``dsa_rows_selected``, ``dsa_selections``,
-        ``dsa_identity_selections`` on the span and as ``gen.dsa.*``.
-
-        ``on_device`` is the scheduler's side of the call: the step is
-        dispatched and not waited for.  ``tokens`` may then be the device
-        array :func:`pick_tokens` made from the step before; the logits
-        come back as the device array the executable will fill, under
-        the caller's own ``gen.decode_step`` span, and the step's
-        ``decode_stats`` array is left unread in ``last_decode_stats``
-        for :meth:`count_decode_stats`.
-
-        The ``gen.decode.stall`` failpoint fires INSIDE the lock: a
-        ``delay`` action models per-iteration device time serialized per
-        replica (the decode bench's cost model), an ``error`` a device
-        fault in the decode step."""
-        from paddle_tpu.fault import chaos
-        from paddle_tpu.profiler import runtime_metrics
-        S, L = self.num_slots, self.block_length
-        positions = np.asarray(positions, np.int32).reshape(S, 1)
-        lens = np.asarray(lens, np.int32).reshape(S, 1)
-        live, walk = int(np.count_nonzero(lens)), None
-        if L > 1:
-            lens = self._block_end(lens)
-            # slots whose token completes its block: the step stores the
-            # block and opens the next, whose rows the kernel walks too
-            fused = (positions + 1 == lens) & (lens > 0)
-            walk = lens + L * fused
-            if not on_device:
-                self._check_opened_pages(fused, walk)
-            n_fused = int(np.count_nonzero(fused))
-            runtime_metrics.inc("gen.block.forwards", live)
-            runtime_metrics.inc("gen.block.fused", n_fused)
-            runtime_metrics.inc("gen.block.rows", (live + n_fused) * L)
-        feed = {
-            "gen_token": tokens if isinstance(tokens, jax.Array)
-            else np.asarray(tokens, np.int32).reshape(S, 1),
-            "gen_pos": positions,
-        }
-        feed.update(self._paged_decode_feed(lens, walk))
-        feed = {k: feed[k] for k in self._dec_feeds}
-        self.last_step_counts = {**self._count_selections(lens),
-                                 **self._count_window_rows(lens)}
-        with self._lock:
-            chaos.fire("gen.decode.stall", slots=S)
-            with self._fluid.scope_guard(self._scope):
-                with contextlib.nullcontext() if on_device \
-                        else _span("gen.decode_step") as step:
-                    logits, *stats = self._exe.run(
-                        self._dec_prog, feed=feed,
-                        fetch_list=self._dec_fetch,
-                        return_numpy=not on_device)
-                    self.last_decode_stats = stats[0] \
-                        if stats and self.decode_stats else None
-                    if not on_device:
-                        step.set(**self.last_step_counts)
-                    if not on_device and self.last_decode_stats is not None:
-                        step.set(live=live, **self.count_decode_stats(
-                            self.last_decode_stats))
-        return logits
+        ``dsa_identity_selections`` on the span and as ``gen.dsa.*``."""
+        S = self.num_slots
+        tokens = np.asarray(tokens, np.int32).reshape(S)
+        positions = np.asarray(positions, np.int32).reshape(S)
+        lens = np.asarray(lens, np.int32).reshape(S)
+        if self.block_length > 1:
+            self._check_opened_pages(
+                *_block_view(positions, lens, self.block_length)[1:])
+        with _span("gen.decode_step") as step:
+            logits, read, _ = self._launch(tokens, positions, lens,
+                                           every_row=True)
+            _, stats = self._split_read(_host_call(np.asarray, read))
+            self.last_decode_stats = stats
+            step.set(**self.last_step_counts)
+            if stats is not None:
+                step.set(live=int(np.count_nonzero(lens)),
+                         **self.count_decode_stats(stats))
+            return _host_call(np.asarray, logits)
 
     def _check_opened_pages(self, fused, walk):
         """Raise where a slot whose step opens a block (``fused``) holds
@@ -825,16 +1009,16 @@ class GenPredictor:
         with self._lock:
             for slot in np.flatnonzero(fused):
                 held = len(self._slot_pages.get(int(slot), ()))
-                if held * self.page_len < int(walk[slot, 0]):
+                if held * self.page_len < int(walk[slot]):
                     raise RuntimeError(
                         f"slot {int(slot)} holds {held} page(s): too few "
                         f"to open the block behind row "
-                        f"{int(walk[slot, 0]) - self.block_length}")
+                        f"{int(walk[slot]) - self.block_length}")
 
     def count_decode_stats(self, stats):
-        """A step's ``decode_stats`` array (read here, if it is still on
-        the device) with its columns reduced over their rows: counted
-        always-on, returned for the ``gen.decode_step`` span."""
+        """A step's ``decode_stats`` array with its columns reduced over
+        their rows: counted always-on, returned for the
+        ``gen.decode_step`` span."""
         from paddle_tpu.profiler import runtime_metrics
         stats, out = np.asarray(stats), {}
         for j, col in enumerate(self.decode_stats):
@@ -847,49 +1031,44 @@ class GenPredictor:
                 runtime_metrics.inc(metric, out[col["name"]])
         return out
 
-    def _paged_decode_feed(self, lens, walk=None):
-        """Page-table + lens feed for one step: slice the table
-        to the smallest declared page bucket covering the longest live
-        prefix (clamped to ``pages_per_slot`` — ``row_bucket`` past the
-        declared ladder falls back to its power-of-two ladder, which
-        must never widen the jit key beyond the pool).  ``walk``: the
-        rows each slot's step reads and writes through, where that is
-        more than the ``lens`` it is fed (a block bundle's slot that
-        opens its next block)."""
+    def _page_bucket(self, rows):
+        """The step's page bucket: the smallest declared one covering the
+        longest live prefix (clamped to ``pages_per_slot`` —
+        ``row_bucket`` past the declared ladder falls back to its
+        power-of-two ladder, which must never widen the jit key beyond
+        the pool).  ``rows``: int32 ``[S]``, the rows each slot's step
+        reads and writes through (for a block bundle's slot that opens
+        its next block, more than the ``lens`` it is fed).  Observes the
+        paged counters (``gen.paged.*``) of the step."""
         from paddle_tpu.lod import row_bucket
         from paddle_tpu.profiler import runtime_metrics
-        rows = lens if walk is None else walk
-        live = rows[:, 0] > 0
-        need = 1
-        if live.any():
-            need = int(-(-int(rows[live, 0].max()) // self.page_len))
+        live = rows[rows > 0]
+        need = int(-(-int(live.max()) // self.page_len)) if live.size else 1
         P = min(row_bucket(max(need, 1), edges=self.page_buckets),
                 self.pages_per_slot)
-        touched = int(np.sum(-(-rows[live, 0] // self.page_len)))
+        touched = int(np.sum(-(-live // self.page_len)))
         runtime_metrics.observe("gen.paged.pages_touched",
                                 float(touched))
         runtime_metrics.observe("gen.paged.pages_in_bucket",
                                 float(rows.shape[0] * P))
         if touched:
-            occupancy = (100.0 * float(rows[live, 0].sum()) /
+            occupancy = (100.0 * float(live.sum()) /
                          (touched * self.page_len))
             runtime_metrics.bucket("gen.paged.page_occupancy",
                                    int(occupancy))
-        with self._lock:
-            table = np.ascontiguousarray(self._page_table[:, :P])
-        return {"gen_page_table": table, "gen_lens": lens}
+        return P
 
     # -- warmup ------------------------------------------------------------
     def warmup(self):
-        """AOT-compile EVERY signature an admission or a decode step
+        """AOT-compile EVERY signature an admission or a decode turn
         uses — one prefill signature per declared prompt bucket, one
-        decode signature per declared page bucket, one seeding
-        signature per prompt bucket (:func:`_seed_pool`) and the token
-        pick (:func:`pick_tokens`) — so the first real ``/generate``
+        decode turn per declared page bucket (step, pick and state
+        advance are one executable) and one seeding signature per prompt
+        bucket (:func:`_seed_pool`) — so the first real ``/generate``
         pays zero compile time.  Returns a
         :class:`~paddle_tpu.obs.perf.WarmupReport` (int = fresh
         compiles; ``buckets`` carries one per-signature entry tagged
-        ``program: prefill|decode|seed|pick`` with compile seconds and
+        ``program: prefill|decode|seed`` with compile seconds and
         cold/persistent-hit/warm provenance — what ``/stats`` surfaces
         so a rolling restart's warm claim is checkable per bucket)."""
         buckets = [b for b in self.prompt_buckets if b <= self.max_len]
@@ -898,53 +1077,45 @@ class GenPredictor:
             "gen_attn_bias": (1, 1, b, b), "gen_last": (1, b)}.items()
             if k in self._pre_feeds}
                 for b in map(self._prefill_rows, buckets)]
-        S = self.num_slots
-        dec_sigs = [{"gen_token": (S, 1), "gen_pos": (S, 1),
-                     "gen_page_table": (S, int(P)), "gen_lens": (S, 1)}
-                    for P in self.page_buckets if P <= self.pages_per_slot]
-        dec_sigs = [{k: sig[k] for k in self._dec_feeds} for sig in dec_sigs]
         from paddle_tpu.obs.perf import WarmupReport
         with self._lock:
             with self._fluid.scope_guard(self._scope):
                 pre = self._exe.warmup(
                     self._pre_prog, sigs, fetch_list=self._pre_fetch,
                     scope=self._scope)
-                # the decode step writes its (persistable) cache tensors
-                # in place — declare exactly those as intended state
-                # updates (a zero lens feed writes nothing, so warmup
-                # leaves the pool untouched)
-                dec = self._exe.warmup(
-                    self._dec_prog, dec_sigs,
-                    fetch_list=self._dec_fetch, scope=self._scope,
-                    allow_state_updates=self.cache_vars + self.state_vars)
-                pick = self._warm_pick(dec_sigs[0])
+        dec = self._warm_turns()
+        with self._lock:
             seed = self._warm_seeds(buckets)
-        return WarmupReport.merge(pre, dec, seed, pick,
-                                  labels=("prefill", "decode", "seed",
-                                          "pick"))
+        return WarmupReport.merge(pre, dec, seed,
+                                  labels=("prefill", "decode", "seed"))
 
-    def _warm_pick(self, sig):
-        """The scheduler's round, once: a decode step's logits picked
-        from on the device (:func:`pick_tokens`) and the tokens fed to
-        the next step as the device array they are.  Zero feeds, as the
-        decode warm-up's; caller holds ``_lock`` inside the scope."""
-        from paddle_tpu.io import synth_feed_value
+    def _warm_turns(self):
+        """The decode turn of every declared page bucket, launched in
+        both its forms: with a patch from the host (every slot's rows set
+        to 0: a step over no live slot writes nothing, so the pools are
+        left as they were) and with the patch that sits on the device.
+        Counted as ``Executor.warmup`` counts (``warmup.signatures`` /
+        ``warmup.compiles``)."""
+        from paddle_tpu import profiler as _profiler
         from paddle_tpu.obs.perf import WarmupReport
-        block = self._dec_prog.global_block()
-        feed = {name: synth_feed_value(shape, block.var(name).dtype)
-                for name, shape in sig.items()}
+        S = self.num_slots
+        free, zeros = np.full(S, -1, np.int32), np.zeros(S, np.int32)
 
-        def round_trip():
-            for _ in range(2):
-                logits = self._exe.run(
-                    self._dec_prog, feed=feed, fetch_list=self._dec_fetch,
-                    return_numpy=False)[0]
-                feed["gen_token"] = pick_tokens(
-                    logits, np.full(self.num_slots, -1, np.int32))
+        def both_forms(pages):
+            for every_row in (True, False):
+                jax.block_until_ready(self._launch(
+                    free, zeros, zeros, every_row, pages=pages)[1])
 
-        entry = _warm_jit(_pick, {"logits": [self.num_slots,
-                                             self.vocab_size]}, round_trip)
-        return WarmupReport(entry["compiles"], [entry])
+        with _profiler.record_latency("executor.warmup_seconds"):
+            entries = [_warm_entry({"gen_page_table": [S, int(P)]},
+                                   functools.partial(both_forms, P),
+                                   lambda: len(self._turns))
+                       for P in self.page_buckets
+                       if P <= self.pages_per_slot]
+        compiled = sum(e["compiles"] for e in entries)
+        _profiler.runtime_metrics.inc("warmup.signatures", len(entries))
+        _profiler.runtime_metrics.inc("warmup.compiles", compiled)
+        return WarmupReport(compiled, entries)
 
     def _warm_seeds(self, buckets):
         """Run the compiled seed once per prompt bucket with a trip
@@ -955,7 +1126,8 @@ class GenPredictor:
         entries = []
         for b in buckets:
             kv = self._zero_kv(b)
-            entries.append(_warm_jit(
-                _seed_pool, {"kv": [len(kv)] + list(kv[0].shape)},
-                lambda: self._write_pool(kv, idx, 0)))
+            entries.append(_warm_entry(
+                {"kv": [len(kv)] + list(kv[0].shape)},
+                lambda: self._write_pool(kv, idx, 0),
+                _seed_pool._cache_size))
         return WarmupReport(sum(e["compiles"] for e in entries), entries)

@@ -24,14 +24,16 @@ continuous-batching win.
 Decode runs ONE STEP AHEAD of the host.  Nothing in a step needs the host
 before the next can start: decoding is greedy, positions advance by one,
 a live slot's pages do not change, and an ending by length is known
-before the token is.  Only the token comes from the device, and it stays
-there (``predictor.pick_tokens``).  So a turn with step *k* in flight
-dispatches step *k+1* first, then reads step *k*'s ``[S]`` token ids and
-emits them while the device runs *k+1*.  An ending the host cannot
-foresee (EOS, a cancel) costs one computed row that is thrown away; at
-every whole-token boundary (migrate, abort, close, crash) the step in
-flight is first collected and emitted, or dropped.  With nothing in
-flight (the first step after idle) a turn only dispatches.
+before the token is.  All of that state lives on the device and the step
+advances it (``GenPredictor.dispatch_turn``): a turn in which no slot was
+admitted, evicted or ended is ONE compiled call that takes nothing from
+the host, and ONE read.  So a turn with step *k* in flight dispatches
+step *k+1* first, then reads step *k*'s ``[S]`` token ids and emits them
+while the device runs *k+1*.  An ending the host cannot foresee (EOS, a
+cancel) costs one computed row that is thrown away; at every whole-token
+boundary (migrate, abort, close, crash) the step in flight is first
+collected and emitted, or dropped.  With nothing in flight (the first
+step after idle) a turn only dispatches.
 
 A step carries more rows than tokens where a bundle decodes BLOCKS of
 ``predictor.block_length`` rows (``models/block_moe.py``): a slot's turn
@@ -55,7 +57,6 @@ import time
 
 import numpy as np
 
-from paddle_tpu.gen.predictor import pick_tokens
 from paddle_tpu.obs import trace as _trace
 from paddle_tpu.obs.slo import tick as _slo_tick
 from paddle_tpu.obs.trace import span as _span
@@ -174,14 +175,13 @@ class _Slot:
 
 class _Step:
     """A dispatched decode step whose tokens nobody has read yet."""
-    __slots__ = ("rows", "logits", "stats", "fused")
+    __slots__ = ("rows", "read", "fused")
 
-    def __init__(self, rows, logits, stats, fused):
+    def __init__(self, rows, read, fused):
         # rows: (slot index, _Slot, whether the stream reaches its length
         # cap with this step) for each slot the step carries
         self.rows = rows
-        self.logits = logits    # as decode_step(on_device=True) gave them
-        self.stats = stats      # the bundle's decode_stats array, or None
+        self.read = read        # as dispatch_turn gave it: ids and stats
         # slots whose token completed a block: the step stored it and
         # opened the next (0 unless the bundle decodes blocks)
         self.fused = fused
@@ -742,8 +742,8 @@ class GenScheduler:
             live = sorted(self._slots.items()) if dispatch else []
         if not live and self._in_flight is None:
             return
-        # what is left of this span beside its two children is the feed
-        # building below
+        # what is left of this span beside its two children is the
+        # bookkeeping below
         with _span("gen.decode_iteration", live=len(live)):
             self._step_and_emit(live, _profiler.runtime_metrics)
 
@@ -753,7 +753,7 @@ class GenScheduler:
         prev = self._in_flight
         carried = {idx: slot for idx, slot, _ in prev.rows} if prev else {}
         # -1: the slot's token is the device's own pick from ``prev``
-        override = np.full(S, -1, np.int32)
+        tokens = np.full(S, -1, np.int32)
         positions = np.zeros(S, np.int32)
         lens = np.zeros(S, np.int32)
         rows, fused = [], 0
@@ -762,7 +762,7 @@ class GenScheduler:
             if 1 + slot.steps >= cap or slot.pos >= horizon:
                 continue    # ends by length with the step in flight
             if carried.get(idx) is not slot:
-                override[idx] = slot.last_token
+                tokens[idx] = slot.last_token
             positions[idx] = slot.pos
             lens[idx] = slot.pos + 1
             slot.steps += 1
@@ -777,27 +777,21 @@ class GenScheduler:
         # the scheduler thread's time in the predictor this turn: the
         # next step's dispatch, then the wait for the one in flight
         with _span("gen.decode_step", ahead=int(bool(rows and prev))) as step:
-            tokens = pick_tokens(prev.logits if prev else None, override)
             self._in_flight = None
             if rows:
                 metrics.bucket("gen.slot_occupancy", len(rows))
                 metrics.inc("gen.decode.steps")
                 if prev:
                     metrics.inc("gen.decode.steps_ahead")
-                logits = self.predictor.decode_step(
-                    tokens, positions, lens=lens, on_device=True)
+                read = self.predictor.dispatch_turn(tokens, positions, lens)
                 # the selections of the step just dispatched (learned
                 # sparse attention) and the rows its full and its window
                 # layers read; {} without
                 step.set(**self.predictor.last_step_counts)
-                self._in_flight = _Step(rows, logits,
-                                        self.predictor.last_decode_stats,
-                                        fused)
+                self._in_flight = _Step(rows, read, fused)
             if prev:
                 with _span("gen.collect"):
-                    ids = np.asarray(tokens).reshape(-1).tolist()
-                    attrs = {} if prev.stats is None else \
-                        self.predictor.count_decode_stats(prev.stats)
+                    ids, attrs = self.predictor.read_turn(prev.read)
                 # a row whose slot was vacated since (EOS at the last
                 # collect, a cancel) was computed for nothing
                 kept = [row for row in prev.rows

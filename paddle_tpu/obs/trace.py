@@ -43,6 +43,7 @@ import collections
 
 __all__ = ["span", "record_span", "enable", "disable", "enabled",
            "configure_from_env", "trace_context", "current_trace_id",
+           "current_span_id",
            "new_trace_id", "snapshot_spans", "snapshot_payload", "clear",
            "chrome_trace", "dump_chrome_trace", "set_process_name",
            "process_name", "epoch_unix", "ts_of", "DEFAULT_RING"]
@@ -122,6 +123,13 @@ def current_trace_id():
     innermost :func:`trace_context` if it was bound inside the innermost
     active span (or no span is open), else that span's, else None."""
     return _trace_id_under(_current_span.get())
+
+
+def current_span_id():
+    """The innermost open span's id on this thread/context, or None: the
+    ``parent_id`` for a :func:`record_span` that is to hang under it."""
+    parent = _current_span.get()
+    return None if parent is None else parent.span_id
 
 
 @contextlib.contextmanager

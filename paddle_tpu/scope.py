@@ -12,6 +12,7 @@ from __future__ import annotations
 __all__ = ["Scope", "global_scope", "scope_guard"]
 
 import contextlib
+import weakref
 
 
 class Scope:
@@ -21,6 +22,9 @@ class Scope:
         self.kids = []
         # LoD metadata (row-splits per level) carried next to ragged tensors
         self._lod = {}
+        # bound methods (held weakly) told of every write and erasure of
+        # this scope's own variables (``watch``)
+        self._watchers = []
 
     def new_scope(self):
         kid = Scope(parent=self)
@@ -33,6 +37,7 @@ class Scope:
         if s is not None:
             return s._vars[name]
         self._vars[name] = None
+        self._wrote(None)
         return None
 
     def find_scope(self, name):
@@ -50,14 +55,37 @@ class Scope:
     def has_var(self, name):
         return self.find_scope(name) is not None
 
-    def set_var(self, name, value):
-        s = self.find_scope(name)
-        (s or self)._vars[name] = value
+    def set_var(self, name, value, by=None):
+        """``by``: the watcher (``watch``) that makes this write itself
+        and is not to be told of it."""
+        s = self.find_scope(name) or self
+        s._vars[name] = value
+        if s._watchers:
+            s._wrote(by)
 
     def erase(self, names):
         for n in names:
             self._vars.pop(n, None)
             self._lod.pop(n, None)
+        self._wrote(None)
+
+    def watch(self, method):
+        """Call the bound ``method()`` after every ``set_var`` / ``erase``
+        that a lookup from this scope can see (its own and its
+        ancestors'), so a caller that keeps resolved arrays
+        (``Executor.compiled_step``) lets go of them the moment one is
+        replaced, and not when it next looks.  Held weakly."""
+        ref, s = weakref.WeakMethod(method), self
+        while s is not None:
+            s._watchers = [w for w in s._watchers if w() is not None]
+            s._watchers.append(ref)
+            s = s.parent
+
+    def _wrote(self, by):
+        for ref in self._watchers:
+            method = ref()
+            if method is not None and method.__self__ is not by:
+                method()
 
     def local_var_names(self):
         return list(self._vars)

@@ -11,7 +11,6 @@ class FakeGenPredictor:
     max_prompt_len, eos_id = 16, -1
     state_vars = ()
     cache_row_bytes = 4
-    last_decode_stats = None
     last_step_counts = {}
     free_pages = 1 << 20
 
@@ -48,5 +47,8 @@ class FakeGenPredictor:
     def clear_slot(self, slot):
         pass
 
-    def decode_step(self, tokens, positions, lens, on_device=False):
-        return self._logits(self.num_slots)
+    def dispatch_turn(self, tokens, positions, lens):
+        return np.argmax(self._logits(self.num_slots), axis=-1)
+
+    def read_turn(self, read):
+        return read.tolist(), {}

@@ -585,10 +585,9 @@ class TestPagedKV:
         their ratio is the share of the table the kernel skips."""
         p = GenPredictor(bundle_dir)
         m = profiler.runtime_metrics
-        lens = np.zeros((p.num_slots, 1), "int32")
-        lens[1, 0] = 2 * p.page_len + 1           # 3 pages: bucket of 4
-        feed = p._paged_decode_feed(lens)
-        bucket = feed["gen_page_table"].shape[1]
+        lens = np.zeros(p.num_slots, "int32")
+        lens[1] = 2 * p.page_len + 1              # 3 pages: bucket of 4
+        bucket = p._page_bucket(lens)
         assert bucket == 4
         assert m.samples("gen.paged.pages_in_bucket", last=1) \
             == [float(p.num_slots * bucket)]
@@ -784,44 +783,28 @@ class TestLookahead:
         assert min(least_free) == 0, "the pool was never exhausted"
 
     def test_next_step_is_dispatched_before_the_last_is_read(
-            self, predictor, monkeypatch):
+            self, predictor):
         """The order of one stream's turns, and what crosses to the host:
-        step k+1 is dispatched, then step k's ``[S, 1]`` ids are read;
-        the ``[S, V]`` logits never are."""
+        step k+1 is dispatched, then step k's ``[S]`` ids are read; the
+        ``[S, V]`` logits never are, and but for the first step's token
+        nothing is sent."""
         import jax
-        from paddle_tpu.gen import scheduler as sched_mod
         log = []
-
-        class OnDevice:
-            def __init__(self, array, read_as):
-                self.array, self.read_as = array, read_as
-
-            def __array__(self, *args, **kwargs):
-                log.append((self.read_as, tuple(self.array.shape)))
-                return np.asarray(self.array)
 
         class Recording:
             def __getattr__(self, name):
                 return getattr(predictor, name)
 
-            def decode_step(self, tokens, *args, **kwargs):
-                log.append(("dispatch",))
-                if isinstance(tokens, OnDevice):
-                    tokens = tokens.array
-                logits = predictor.decode_step(tokens, *args, **kwargs)
-                assert isinstance(logits, jax.Array)
-                return OnDevice(logits, "read logits")
+            def dispatch_turn(self, tokens, positions, lens):
+                log.append(("dispatch", int((np.asarray(tokens) >= 0).sum())))
+                read = predictor.dispatch_turn(tokens, positions, lens)
+                assert isinstance(read, jax.Array)
+                return read
 
-        real_pick = sched_mod.pick_tokens
+            def read_turn(self, read):
+                log.append(("read ids", tuple(read.shape)))
+                return predictor.read_turn(read)
 
-        def pick(logits, override):
-            if logits is not None:
-                logits = logits.array
-            tokens = real_pick(logits, override)
-            assert logits is None or isinstance(tokens, jax.Array)
-            return OnDevice(tokens, "read ids")
-
-        monkeypatch.setattr(sched_mod, "pick_tokens", pick)
         prompt = [5, 9, 3, 17]
         sched = GenScheduler(Recording(), queue_size=8)
         try:
@@ -830,20 +813,23 @@ class TestLookahead:
         finally:
             sched.close()
         assert got == _ref_greedy(predictor, prompt, 6)
-        ids = ("read ids", (predictor.num_slots, 1))
-        # the pipeline fills (two dispatches), then every turn reads the
-        # step before the one it dispatched; the last has none to dispatch
-        assert log == [("dispatch",)] + [("dispatch",), ids] * 4 + [ids]
+        ids = ("read ids", (predictor.num_slots,))
+        # the pipeline fills (two dispatches: the first is fed the
+        # prefill's token, every later one the device's own pick), then
+        # every turn reads the step before the one it dispatched; the
+        # last has none to dispatch
+        assert log == [("dispatch", 1)] + [("dispatch", 0), ids] * 4 + [ids]
 
-    def test_warmup_compiles_the_pick(self, bundle_dir):
-        """``warmup()`` compiles the on-device pick with the decode
-        signatures: a warmed scheduler decodes with no compile event."""
-        from paddle_tpu.gen import predictor as predictor_mod
+    def test_warmup_compiles_the_turn(self, bundle_dir):
+        """``warmup()`` compiles the decode turn of every page bucket,
+        step and pick and state advance in one executable: a warmed
+        scheduler decodes with no compile event."""
         profiler.install_jax_compile_listeners()
-        predictor_mod._pick.clear_cache()
         p = GenPredictor(bundle_dir)
-        picks = [b for b in p.warmup().buckets if b["program"] == "pick"]
-        assert [b["compiles"] for b in picks] == [1]
+        turns = [b for b in p.warmup().buckets if b["program"] == "decode"]
+        assert [b["compiles"] for b in turns] == [1] * len(p.page_buckets)
+        assert [b["signature"]["gen_page_table"][1] for b in turns] \
+            == p.page_buckets
         events = profiler.runtime_metrics.counter("compile.events")
         sched = GenScheduler(p, queue_size=8)
         try:

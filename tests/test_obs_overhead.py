@@ -103,9 +103,10 @@ class TestDisabledTracingOverhead:
 
     def test_scheduler_turn_overhead_under_5_percent(self):
         """The spans one ``GenScheduler`` loop turn carries with tracing
-        off — the turn, one decode iteration with its step (an
-        ``Executor.run`` with its three phases, then the collect of the
-        step that was in flight) and its emit loop, and
+        off — the turn, one decode iteration with its step (the
+        compiled turn's launch with its three phases and ``gen.dispatch``,
+        then the collect of the step that was in flight) and its emit
+        loop, and
         one admission (queue wait, admit, prefill with its run, first
         token, seed) — against the same modeled 1 ms step; a real decode
         step on the chip is ten times that."""
@@ -126,6 +127,21 @@ class TestDisabledTracingOverhead:
                 with trace.span("executor.fetch"):
                     pass
 
+        def launch():
+            # the decode turn's one compiled call
+            # (``Executor.compiled_step``): no lookup, no state walk
+            with trace.span("executor.run"):
+                with trace.span("executor.feed"):
+                    pass
+                with trace.span("executor.dispatch"):
+                    with trace.span("executor.launch"):
+                        pass
+                with trace.span("executor.fetch"):
+                    pass
+            trace.record_span("gen.dispatch", 0.0, 0.0,
+                              parent_id=trace.current_span_id(),
+                              patched=0, pages=1)
+
         def turn(i):
             with trace.span("gen.sched.turn"):
                 trace.record_span("gen.queue_wait", 0.0, 0.0,
@@ -141,7 +157,7 @@ class TestDisabledTracingOverhead:
                             seed.set(compiled_calls=1, eager_ops=0)
                 with trace.span("gen.decode_iteration", live=i):
                     with trace.span("gen.decode_step", ahead=1) as step:
-                        run()
+                        launch()
                         with trace.span("gen.collect"):
                             pass
                         step.set(live=i, discarded=0)
