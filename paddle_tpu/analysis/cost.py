@@ -50,8 +50,8 @@ from paddle_tpu.analysis import typecheck
 from paddle_tpu.analysis.typecheck import TypeEnv, VarInfo, _UNKNOWN
 
 __all__ = ["rule", "covered_op_types", "estimate", "op_flops",
-           "numel", "io_bytes", "CostReport", "validate_cost_report",
-           "row_cost_fn", "REPORT_KEYS"]
+           "numel", "io_bytes", "estimate_at", "CostReport",
+           "validate_cost_report", "row_cost_fn", "REPORT_KEYS"]
 
 _RULES = {}
 
@@ -290,6 +290,22 @@ def op_flops(op, block, default=None):
     return max(int(out[0]), 0)
 
 
+def estimate_at(program, shapes, **kwargs):
+    """:func:`estimate` with the declared shapes of the vars ``shapes``
+    names (feeds whose dynamic dims a caller knows) set for the walk and
+    put back after it.  Callers serialize: the program is mutated
+    meanwhile."""
+    block = program.global_block()
+    saved = {name: block.var(name).shape for name in shapes}
+    try:
+        for name, shape in shapes.items():
+            block.var(name).shape = tuple(int(d) for d in shape)
+        return estimate(program, **kwargs)
+    finally:
+        for name, shape in saved.items():
+            block.var(name).shape = shape
+
+
 def row_cost_fn(program, batch_var=None, dim=0, probe_rows=(8, 16)):
     """Fit ``flops(size)`` as an affine function of dim ``dim`` of
     ``batch_var`` (default: the program's first ``is_data`` var):
@@ -305,17 +321,13 @@ def row_cost_fn(program, batch_var=None, dim=0, probe_rows=(8, 16)):
                 break
     if batch_var is None:
         return lambda rows: float(rows)
-    var = block.var(batch_var)
-    saved = var.shape
+    declared = block.var(batch_var).shape
     points = []
-    try:
-        for rows in probe_rows:
-            shape = list(saved or (-1,))
-            shape[dim] = int(rows)
-            var.shape = tuple(shape)
-            points.append((rows, estimate(program).total_flops))
-    finally:
-        var.shape = saved
+    for rows in probe_rows:
+        shape = list(declared or (-1,))
+        shape[dim] = int(rows)
+        points.append((rows, estimate_at(
+            program, {batch_var: shape}).total_flops))
     (r0, f0), (r1, f1) = points
     if r1 == r0 or f1 <= f0:
         return lambda rows: float(max(f0, 1)) * rows / max(r0, 1)
@@ -877,7 +889,10 @@ def _c_gqa_attention(op, info):
 def _c_prefill_attention(op, info):
     """Grouped attention over one prompt with key and value heads of
     their own widths: ``T x T / 2`` pairs under the causal mask, ``T x
-    window`` inside a band."""
+    window`` inside a band.  ONE CHUNK of a prompt over the slot's pages
+    (``gqa_flash_attention_chunk``) is charged the pairs of its LAST
+    position, the end of its page bucket: ``T`` rows over the bucket's
+    rows, less the triangle above the diagonal."""
     q, k, v = (_shape(info, op, s) for s in ("Q", "K", "V"))
     if q is None or k is None or v is None or len(q) != 3 or \
             not _known(q[1], q[2], k[2], v[2]):
@@ -885,10 +900,22 @@ def _c_prefill_attention(op, info):
     t, window = q[1], int(op.attr("window", 0))
     pairs = t * min(window, t) if window else t * (t + 1) // 2
     hqv = q[2] * v[2] // k[2]
-    return 2 * pairs * (q[2] + hqv), io_bytes(op, info)
+    if not op.input("KCache") and not op.input("KRing"):
+        return 2 * pairs * (q[2] + hqv), io_bytes(op, info)
+    # a chunk reads of its caches the rows it attends and writes its
+    # own, never the whole pool or every slot's ring
+    rows = min(window, t) if window else 0
+    found = _paged_rows(op, info, "KCache") if not window else None
+    if found is not None and found[1] > t:
+        rows = found[1]
+        pairs += t * (rows - t)
+    item = _DTYPE_BYTES.get(str(info(op.input("K")[0]).dtype), 4)
+    bytes_ = (t * (q[2] + hqv) + (2 * t + rows) * (k[2] + v[2])) * item
+    return 2 * pairs * (q[2] + hqv), int(bytes_)
 
 
-rule("gqa_flash_attention", "window_attention")(_c_prefill_attention)
+rule("gqa_flash_attention", "gqa_flash_attention_chunk",
+     "window_attention")(_c_prefill_attention)
 
 
 @rule("ssm_scan_conv", "ssm_update_conv")

@@ -1023,6 +1023,12 @@ def check_gen_bundle(prefill, decode, meta):
             f"fetches {len(dec_fetches)} value(s), not logits + stats",
             program="decode"))
 
+    # -- PTA019: a chunk prefill writes the decode step's own caches ---
+    chunks = meta.get("prefill_chunks")
+    if chunks is not None:
+        return diags + _check_chunk_prefill(
+            pre_prog, pre_feeds, pre_fetches, dec_prog, meta)
+
     # -- PTA019: prefill fetch list must seed exactly the cache --------
     if (cache_vars or state_vars) and pre_fetches is not None:
         # logits + per-layer K/V + one value per state array
@@ -1064,6 +1070,62 @@ def check_gen_bundle(prefill, decode, meta):
                         f"is {tuple(s_shape[1:])} — seeding the slot "
                         f"would write a misshapen state",
                         var=fetch_name, program="prefill"))
+    return diags
+
+
+def _check_chunk_prefill(pre_prog, pre_feeds, pre_fetches, dec_prog, meta):
+    """``prefill_chunks`` in the meta: the prefill program runs ONE CHUNK
+    of a prompt a call and writes the slot's rows where the decode step
+    reads them, so it must hold every cache and state array of the
+    decode program under the same name, shape and type, persistable;
+    fetch the logits alone (nothing seeds a slot afterwards); take the
+    slot and its page-table row; and its rungs must be whole pages (a
+    chunk starts where the one before ended, on a page's first row)."""
+    diags = []
+    chunks = list(meta.get("prefill_chunks") or ())
+    page_len = int(meta.get("page_len") or 0)
+    if not chunks or any(b2 <= b1 for b1, b2 in zip(chunks, chunks[1:])) \
+            or any(int(c) <= 0 or (page_len and int(c) % page_len)
+                   for c in chunks):
+        diags.append(Diagnostic(
+            "PTA019",
+            f"prefill_chunks {chunks} must be increasing multiples of "
+            f"page_len {page_len} — a chunk begins on a page's first row",
+            program="gen_meta"))
+    pt_feed = meta.get("page_table_feed", "gen_page_table")
+    for name in (pt_feed, "gen_slot", "gen_pos", "gen_mask"):
+        if pre_feeds is not None and name not in pre_feeds:
+            diags.append(Diagnostic(
+                "PTA019",
+                f"the chunk prefill does not feed `{name}` — it cannot "
+                f"tell whose rows it continues, or where", var=name,
+                program="prefill"))
+    if pre_fetches is not None and len(pre_fetches) != 1:
+        diags.append(Diagnostic(
+            "PTA019",
+            f"the chunk prefill fetches {len(pre_fetches)} value(s), not "
+            f"the logits alone — its K/V go into the caches in place",
+            program="prefill"))
+    pre_block, dec_block = pre_prog.global_block(), dec_prog.global_block()
+    written = {n for op in pre_block.ops for n in op.output_arg_names}
+    for name in list(meta.get("cache_vars") or ()) + \
+            list(meta.get("state_vars") or ()):
+        if not pre_block.has_var(name) or \
+                not getattr(pre_block.var(name), "persistable", False) \
+                or name not in written:
+            diags.append(Diagnostic(
+                "PTA019",
+                f"the chunk prefill does not write `{name}` in place — "
+                f"the decode step would read rows nobody seeded",
+                var=name, program="prefill"))
+        elif dec_block.has_var(name) and \
+                _var_meta(pre_block, name) != _var_meta(dec_block, name):
+            diags.append(Diagnostic(
+                "PTA019",
+                f"`{name}` is {_var_meta(pre_block, name)} in the chunk "
+                f"prefill but {_var_meta(dec_block, name)} in the decode "
+                f"program — the two would not share one array",
+                var=name, program="prefill"))
     return diags
 
 

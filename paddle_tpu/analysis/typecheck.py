@@ -1326,23 +1326,32 @@ def _window_heads(op, tc, n_kv=None):
     return tuple(q.shape[:-1]) + (v.shape[-1] // n_kv * h,)
 
 
-@rule("gqa_flash_attention", "window_attention")
+@rule("gqa_flash_attention", "gqa_flash_attention_chunk", "window_attention")
 def _r_window_attention(op, tc):
     q = tc.input_info(op, "Q")
     out = _window_heads(op, tc, int(op.attr("n_kv_head")))
     tc.set_output(op, "Out", shape=out, dtype=q.dtype)
+    # one chunk of a prompt over the slot's own caches: they are held to
+    # the chunk's K and V, and pass through under their own names
+    caches = (("KCache", "K"), ("VCache", "V")) \
+        if op.type == "gqa_flash_attention_chunk" else \
+        (("KRing", "K"), ("VRing", "V")) if op.input("KRing") else ()
+    for slot, src in caches:
+        cache, x = tc.input_info(op, slot), tc.input_info(op, src)
+        if cache.shape is not None and len(cache.shape) == 3 and \
+                x.shape is not None:
+            _last_dim_is(op, tc, slot, x.shape[-1], f"lanes a row ({src}'s)")
+        tc.set_output(op, slot + "Out", shape=cache.shape, dtype=cache.dtype)
+    if caches:
+        _int_index(op, tc, "Pos")
+        _int_index(op, tc, "PageTable" if op.input("PageTable") else "Slot")
     if op.type == "window_attention":
-        window, ring = int(op.attr("window")), int(op.attr("ring", 0))
-        if op.output("KRing") and ring < window:
-            tc.report("PTA006", f"window_attention: a ring of {ring} "
-                      f"rows cannot hold a window of {window}", op=op,
-                      var=op.output("KRing")[0])
-        for slot, src in (("KRing", "K"), ("VRing", "V")):
-            if op.output(slot):
-                x = tc.input_info(op, src)
-                tc.set_output(op, slot, dtype=x.dtype, shape=None
-                              if x.shape is None
-                              else (1, ring, x.shape[-1]))
+        window = int(op.attr("window"))
+        held = tc.input_info(op, "KRing").shape if caches else None
+        if held is not None and len(held) == 3 and 0 < held[1] < window:
+            tc.report("PTA006", f"window_attention: KRing holds {held[1]} "
+                      f"rows a slot, fewer than the window of {window}",
+                      op=op, var=op.input("KRing")[0])
 
 
 @rule("window_attention_step")

@@ -37,6 +37,16 @@ slot's row of each with the slot's pages, and the decode step reads and
 writes them whole, in place.  What the meta holds decides; there is no
 flag.
 
+A bundle whose meta carries ``prefill_chunks`` (``models/window_moe.py``)
+prefills a prompt as a SEQUENCE OF CHUNKS (:meth:`GenPredictor.
+prefill_chunk`): each one compiled call, keyed by (chunk rows, page
+bucket), that reads the slot's earlier rows from the pools and the
+per-slot state where the decode step reads them and writes its own rows
+there, so nothing seeds the slot afterwards and the scheduler can run a
+decode turn between two chunks.  :meth:`prefill` + :meth:`write_slot`
+keep their contract on such a bundle through the same executables, on
+borrowed pages.
+
 ``warmup`` declares BOTH signature families — every prefill bucket
 (``Executor.warmup``) and the decode turn of every page bucket — plus one
 seeding signature per prefill bucket, so a server flips ``/readyz`` with
@@ -137,6 +147,20 @@ def _seed_pool(pools, kv, idx, n, states=(), new_states=(), slot=0, *,
     return jax.lax.fori_loop(0, n, write_entry, tuple(pools)) + states
 
 
+@functools.partial(jax.jit, static_argnames="rows")
+def _slot_rows(pools, states, idx, n, slot, *, rows):
+    """What :func:`_seed_pool` takes, read back out of the caches: of
+    every pool the first ``rows`` rows of the pages ``idx`` as ``[1,
+    rows, width]`` (zeros from row ``n`` on), then row ``slot`` of every
+    state array as ``[1, ...]``.  One signature a ``rows``."""
+    unit = pools[0].shape[1]
+    keep = (jnp.arange(rows) < n)[None, :, None]
+    kv = tuple(jnp.where(keep, pool[idx[:-(-rows // unit)]].reshape(
+        1, -1, pool.shape[-1])[:, :rows], 0) for pool in pools)
+    return kv + tuple(jax.lax.dynamic_slice_in_dim(s, slot, 1, 0)
+                      for s in states)
+
+
 # a turn's patch: one int32 row a slot, ``[S, _P_TABLE + pages_per_slot]``
 # -- what of the slot's device-side decode state the host replaces
 # before the step runs
@@ -227,6 +251,10 @@ class GenPredictor:
         self.mask_token_id = int(self.meta.get("mask_token_id", 0))
         self.prompt_buckets = [int(b) for b in self.meta["prompt_buckets"]]
         self.max_prompt_len = min(self.prompt_buckets[-1], self.max_len)
+        # the rows of a prefill chunk, ascending, where the bundle's
+        # prefill continues a slot's rows in place; () without
+        self.prefill_chunks = sorted(
+            int(c) for c in self.meta.get("prefill_chunks") or ())
 
         self._fluid = fluid
         self._scope = fluid.Scope()
@@ -316,8 +344,10 @@ class GenPredictor:
         # per-bucket constant prefill feeds (causal bias template)
         self._tri = {}
         # per-bucket static prefill FLOPs (analysis/cost): priced
-        # lazily, consumed by GenScheduler's admission budget
+        # lazily, consumed by GenScheduler's admission budget; a chunk's
+        # by (chunk rows, page bucket)
         self._prefill_cost = {}
+        self._chunk_cost = {}
         # clear_slot's zero rows, made once (device arrays)
         self._clear_kv = None
         # the newest blocking step's decode_stats array (None for a
@@ -372,6 +402,11 @@ class GenPredictor:
         per (bucket, pages); the underlying fit is warmed by
         GenScheduler construction."""
         prompt_len = int(prompt_len)
+        if self.prefill_chunks:
+            # its chunks, each at its own page bucket; they write their
+            # rows themselves: no seeding on top
+            return sum(self.chunk_cost(a, b - a)
+                       for a, b in self.chunk_spans(prompt_len))
         bucket = self._bucket(prompt_len)
         key = (bucket, -(-max(prompt_len, 1) // self.page_len))
         hit = self._prefill_cost.get(key)
@@ -379,6 +414,50 @@ class GenPredictor:
             hit = float(self._cost_fn()(bucket)) + \
                 self._page_write_cost(prompt_len)
             self._prefill_cost[key] = hit
+        return hit
+
+    def chunk_spans(self, prompt_len):
+        """``[(start, stop), ...]``: the chunks a prompt of
+        ``prompt_len`` tokens runs as, in order: the largest rung's rows
+        each, the last one what is left."""
+        top = self.prefill_chunks[-1]
+        return [(a, min(a + top, prompt_len))
+                for a in range(0, max(int(prompt_len), 1), top)]
+
+    def _chunk_shape(self, start, n):
+        """``(rows, pages)`` the chunk of ``n`` tokens at position
+        ``start`` runs at: the smallest rung that holds it and the
+        smallest page bucket that covers its last row (the jit key)."""
+        from paddle_tpu.lod import row_bucket
+        rows = next((c for c in self.prefill_chunks if c >= n), None)
+        if rows is None:
+            raise ValueError(f"a chunk of {n} tokens exceeds the bundle's "
+                             f"largest, {self.prefill_chunks[-1]} rows")
+        need = -(-(start + n) // self.page_len)
+        return rows, min(row_bucket(need, edges=self.page_buckets),
+                         self.pages_per_slot)
+
+    def chunk_cost(self, start, n):
+        """Static FLOPs of ONE prefill chunk of ``n`` tokens at position
+        ``start``, priced at what the device runs: its rung's rows over
+        its page bucket's (the memo's key).  What the scheduler's
+        ``prefill_budget`` weighs a chunk with."""
+        key = self._chunk_shape(start, n)
+        hit = self._chunk_cost.get(key)
+        if hit is None:
+            from paddle_tpu.analysis import cost as _cost
+            rows, pages = key
+            block = self._pre_prog.global_block()
+            # the feeds' dynamic dims: the page table's its bucket, every
+            # other the chunk's rows
+            shapes = {name: [d if d >= 0 else
+                             pages if name == "gen_page_table" else rows
+                             for d in block.var(name).shape]
+                      for name in self._pre_feeds}
+            with self._lock:
+                hit = float(_cost.estimate_at(self._pre_prog,
+                                              shapes).total_flops)
+            self._chunk_cost[key] = hit
         return hit
 
     def plan_prompt_buckets(self, observed_lengths, max_edges=4):
@@ -557,17 +636,100 @@ class GenPredictor:
             raise ValueError(
                 f"prompt of {len(prompt)} tokens exceeds the bundle's "
                 f"max prompt length {self.max_prompt_len}")
+        if self.prefill_chunks:
+            return self._prefill_by_chunks(prompt)
         feed = self._prefill_feed(prompt, self._bucket(len(prompt)))
         with self._lock:
             with self._fluid.scope_guard(self._scope):
                 with _span("gen.prefill", tokens=len(prompt),
-                           **self._prefill_selections(len(prompt)),
-                           **self._prefill_window_pairs(len(prompt))):
+                           **self._prefill_selections(len(prompt))):
                     outs = self._exe.run(self._pre_prog, feed=feed,
                                          fetch_list=self._pre_fetch,
                                          return_numpy=False)
                     logits = np.asarray(outs[0])[0]
         return logits, outs[1:]
+
+    def _prefill_by_chunks(self, prompt):
+        """:meth:`prefill` of a bundle whose prefill is a chunk program,
+        through the SAME chunk executables an admission runs: it borrows
+        a free slot (its per-slot state) and the pages the prompt takes
+        from the free list, runs the prompt's chunks there, reads the
+        rows back as the arrays :meth:`write_slot` takes (at the
+        prompt's bucket: one signature a bucket) and hands slot and
+        pages back.  Raises as :meth:`alloc_slot_pages` does where the
+        pool cannot cover it, and where no slot is free.  For callers
+        that hold no slot (a set-up check, a probe): the scheduler
+        admits through :meth:`prefill_chunk`."""
+        n = len(prompt)
+        with self._lock:
+            slot = next((i for i in range(self.num_slots)
+                         if i not in self._slot_pages), None)
+        if slot is None:
+            raise RuntimeError("prefill: every slot is taken, none to "
+                               "borrow for the prompt's chunks")
+        pages = self.alloc_slot_pages(slot, -(-n // self.page_len))
+        try:
+            for a, b in self.chunk_spans(n):
+                logits = self.prefill_chunk(slot, prompt[a:b], a)
+            idx = np.zeros(self.pages_per_slot, np.int32)
+            idx[:len(pages)] = pages
+            k = len(self.cache_vars)
+            with self._lock:
+                held = tuple(self._scope.find_var(name) for name in
+                             self.cache_vars + self.state_vars)
+                kv = _slot_rows(held[:k], held[k:], idx, np.int32(n),
+                                np.int32(slot), rows=self._bucket(n))
+        finally:
+            self.free_slot_pages(slot)
+        return np.asarray(logits)[0], list(kv)
+
+    def prefill_chunk(self, slot, ids, start):
+        """Run ONE CHUNK of ``slot``'s prompt: the tokens ``ids`` (no
+        more than the largest of ``prefill_chunks``), which stand at
+        positions ``start ..``; the chunks before it have run and the
+        slot holds its pages (:meth:`alloc_slot_pages`).  One compiled
+        call, keyed by (chunk rows, page bucket), dispatched and NOT
+        waited for: it reads the slot's rows ``0 .. start - 1`` from the
+        pools and the per-slot state, as the decode step does, and
+        writes its own rows there.  Returns the logits ``[1, V]`` of the
+        chunk's last token, still on the device: the prompt's last chunk
+        gives the first token.
+
+        One ``gen.prefill`` span a call, carrying THIS chunk's
+        ``tokens``, ``start``, ``rows`` (as run, pads included),
+        ``pages`` and, with window layers, its own ``band_pairs`` /
+        ``causal_pairs`` / ``*_key_blocks``.  Always-on:
+        ``gen.prefill.chunks``, ``gen.prefill.rows`` (real) and
+        ``gen.prefill.pad_rows``."""
+        from paddle_tpu.lod import pad_to_bucket
+        from paddle_tpu.profiler import runtime_metrics
+        ids = np.asarray(ids, np.int32).reshape(1, -1)
+        n, start = ids.shape[1], int(start)
+        rows, pages = self._chunk_shape(start, n)
+        last = np.zeros((1, rows), np.float32)
+        last[0, n - 1] = 1.0
+        feed = {"gen_ids": pad_to_bucket(ids, rows, axis=1),
+                "gen_pos": start + np.arange(rows, dtype=np.int32)[None],
+                "gen_mask": pad_to_bucket(np.ones((1, n), np.float32), rows,
+                                          axis=1),
+                "gen_last": last,
+                "gen_slot": np.full((1, 1), slot, np.int32)}
+        with self._lock:
+            # a copy: the call is not waited for, and the allocator
+            # rewrites its table in place
+            feed["gen_page_table"] = \
+                self._page_table[slot:slot + 1, :pages].copy()
+            with self._fluid.scope_guard(self._scope):
+                with _span("gen.prefill", tokens=n, start=start, rows=rows,
+                           pages=pages,
+                           **self._chunk_pairs(start, n, rows, pages)):
+                    logits, = self._exe.run(self._pre_prog, feed=feed,
+                                            fetch_list=self._pre_fetch,
+                                            return_numpy=False)
+        runtime_metrics.inc("gen.prefill.chunks")
+        runtime_metrics.inc("gen.prefill.rows", n)
+        runtime_metrics.inc("gen.prefill.pad_rows", rows - n)
+        return logits
 
     def _prefill_selections(self, n):
         """A prompt of ``n`` rows under learned sparse attention: query
@@ -581,30 +743,36 @@ class GenPredictor:
                 "dsa_rows_selected": sp["indexers"]
                 * (k * (k + 1) // 2 + (n - k) * k)}
 
-    def _prefill_window_pairs(self, n):
-        """A prompt of ``n`` rows, run at its bucket, through window and
-        full layers: the (query row, key row) pairs ONE window layer's
-        band and ONE full layer's causal triangle hold, and the key
-        blocks the two prefill kernels compute a layer for the bucket (0
+    def _chunk_pairs(self, start, n, rows, pages):
+        """A chunk of ``n`` real rows at positions ``start ..``, run as
+        ``rows`` rows over ``pages`` pages, through window and full
+        layers: the (query row, key row) pairs ONE window layer's band
+        and ONE full layer's causal trapezium hold for THESE rows (not
+        the prompt's whole triangle: the chunks' sum to it), and the key
+        blocks the two prefill kernels compute a layer for the chunk (0
         where the composed form runs).  Span attributes; {} without
         window layers."""
         win = self.window_attention
         if not win:
             return {}
         from paddle_tpu.ops.window_ops import key_blocks_computed
-        rows = self._prefill_rows(self._bucket(n))
-        w = min(int(win["window"]), n)
-        out = {"band_pairs": w * (w + 1) // 2 + (n - w) * w,
-               "causal_pairs": n * (n + 1) // 2,
+        w = int(win["window"])
+
+        def band(m):    # the band's pairs of rows 0 .. m - 1
+            return min(w, m) * (min(w, m) + 1) // 2 + max(m - w, 0) * w
+
+        out = {"band_pairs": band(start + n) - band(start),
+               "causal_pairs": n * start + n * (n + 1) // 2,
                "window_layers": len(win["layers"]),
                "full_layers": len(win["full_layers"])}
         for attr, heads, window in (
-                ("band_key_blocks", win.get("heads"), int(win["window"])),
+                ("band_key_blocks", win.get("heads"), w),
                 ("causal_key_blocks", win.get("full_heads"), 0)):
             if heads:
                 h, hkv = heads
-                out[attr] = hkv * key_blocks_computed(rows, h // hkv,
-                                                      window)[0]
+                out[attr] = hkv * key_blocks_computed(
+                    rows, h // hkv, window, start,
+                    keys=pages * self.page_len)[0]
         return out
 
     def _count_window_rows(self, lens):
@@ -716,10 +884,15 @@ class GenPredictor:
                     np.zeros(shape, jnp.dtype(str(var.dtype))), device)
             return made[key]
 
+        # a chunk prefill fetches no K/V: its caches ARE the decode
+        # program's, and so are their widths and types
+        block = self._dec_prog.global_block()
+        like = [block.var(n) for n in self.cache_vars + self.state_vars] \
+            if self.prefill_chunks else self._pre_fetch[1:]
         return [zeros(v, (1, self._prefill_rows(bucket), int(v.shape[-1])))
-                for v in self._pre_fetch[1:1 + k]] + \
+                for v in like[:k]] + \
                [zeros(v, (1,) + tuple(int(d) for d in v.shape[1:]))
-                for v in self._pre_fetch[1 + k:]]
+                for v in like[k:]]
 
     def write_slot(self, slot, kv, prompt_len):
         """Seed cache slot ``slot`` with a prefill's K/V rows.
@@ -814,7 +987,12 @@ class GenPredictor:
         the last turn.  Caller holds ``_lock``."""
         changed = np.ones(self.num_slots, bool) if every_row else \
             (positions != self._dev_pos) | (lens != self._dev_lens)
-        if not (changed.any() or self._stale_rows or (tokens >= 0).any()):
+        # a slot that holds pages and no live row is being ADMITTED chunk
+        # by chunk (its chunks are fed the host's table): its row
+        # travels once, with the turn that seats it
+        stale = {slot for slot in self._stale_rows
+                 if lens[slot] > 0 or slot not in self._slot_pages}
+        if not (changed.any() or stale or (tokens >= 0).any()):
             return None
         patch = np.zeros((self.num_slots, _P_TABLE + self.pages_per_slot),
                          np.int32)
@@ -822,10 +1000,10 @@ class GenPredictor:
         patch[:, _P_TOKEN] = tokens
         patch[:, _P_POS] = positions
         patch[:, _P_LENS] = lens
-        for slot in self._stale_rows:
+        for slot in stale:
             patch[slot, _P_FLAGS] |= _SET_TABLE
             patch[slot, _P_TABLE:] = self._page_table[slot]
-        self._stale_rows.clear()
+        self._stale_rows -= stale
         return patch
 
     def _launch(self, tokens, positions, lens, every_row=False, pages=None):
@@ -1061,28 +1239,41 @@ class GenPredictor:
     # -- warmup ------------------------------------------------------------
     def warmup(self):
         """AOT-compile EVERY signature an admission or a decode turn
-        uses — one prefill signature per declared prompt bucket, one
-        decode turn per declared page bucket (step, pick and state
-        advance are one executable) and one seeding signature per prompt
-        bucket (:func:`_seed_pool`) — so the first real ``/generate``
-        pays zero compile time.  Returns a
+        uses — one prefill signature per declared prompt bucket (a chunk
+        bundle: per chunk rung and page bucket), one decode turn per
+        declared page bucket (step, pick and state advance are one
+        executable) and one seeding signature per prompt bucket
+        (:func:`_seed_pool`; a chunk bundle: ``clear_slot``'s alone) —
+        so the first real ``/generate`` pays zero compile time.  Returns a
         :class:`~paddle_tpu.obs.perf.WarmupReport` (int = fresh
         compiles; ``buckets`` carries one per-signature entry tagged
         ``program: prefill|decode|seed`` with compile seconds and
         cold/persistent-hit/warm provenance — what ``/stats`` surfaces
         so a rolling restart's warm claim is checkable per bucket)."""
         buckets = [b for b in self.prompt_buckets if b <= self.max_len]
-        sigs = [{k: v for k, v in {
-            "gen_ids": (1, b), "gen_pos": (1, b), "gen_mask": (1, b),
-            "gen_attn_bias": (1, 1, b, b), "gen_last": (1, b)}.items()
-            if k in self._pre_feeds}
-                for b in map(self._prefill_rows, buckets)]
+        allow = False
+        if self.prefill_chunks:
+            # every (chunk rows, page bucket); zero feeds mask every row,
+            # so the caches pass through as they were.  Of the seeding
+            # signatures only ``clear_slot``'s: no admission seeds
+            sigs = [{"gen_ids": (1, c), "gen_pos": (1, c),
+                     "gen_mask": (1, c), "gen_last": (1, c),
+                     "gen_slot": (1, 1), "gen_page_table": (1, int(P))}
+                    for c in self.prefill_chunks for P in self.page_buckets
+                    if P <= self.pages_per_slot]
+            allow, buckets = self.cache_vars + self.state_vars, buckets[:1]
+        else:
+            sigs = [{k: v for k, v in {
+                "gen_ids": (1, b), "gen_pos": (1, b), "gen_mask": (1, b),
+                "gen_attn_bias": (1, 1, b, b), "gen_last": (1, b)}.items()
+                if k in self._pre_feeds}
+                    for b in map(self._prefill_rows, buckets)]
         from paddle_tpu.obs.perf import WarmupReport
         with self._lock:
             with self._fluid.scope_guard(self._scope):
                 pre = self._exe.warmup(
                     self._pre_prog, sigs, fetch_list=self._pre_fetch,
-                    scope=self._scope)
+                    scope=self._scope, allow_state_updates=allow)
         dec = self._warm_turns()
         with self._lock:
             seed = self._warm_seeds(buckets)

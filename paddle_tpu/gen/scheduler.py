@@ -35,6 +35,22 @@ boundary (migrate, abort, close, crash) the step in flight is first
 collected and emitted, or dropped.  With nothing in flight (the first
 step after idle) a turn only dispatches.
 
+An ADMISSION is a run of chunks between decode steps where the bundle's
+prefill can continue a slot's rows in place (``predictor.prefill_chunks``:
+``models/window_moe.py``).  A slot then has a third state beside free and
+decoding: ADMITTING, with a cursor into its prompt.  ``_admit`` allocates
+the request's pages (the whole horizon at once, as ever) and queues its
+chunks; every turn launches the decode step for the live slots FIRST and
+then at most one chunk (more only under an explicit ``prefill_budget``),
+so a live stream waits through a step and one chunk between two of its
+tokens, never through a whole prompt; with no stream live the chunks run
+back to back, since nobody waits.  Requests are admitted first come
+first served, one request's chunks before the next one's.  The admitting
+slot is not in the decode turn: its row of the device's decode state is
+patched once, when it is seated, after its last chunk, whose logits give
+the first token.  A bundle without a chunk program is admitted by one
+whole-prompt prefill on this thread (``_admit_one``), as before.
+
 A step carries more rows than tokens where a bundle decodes BLOCKS of
 ``predictor.block_length`` rows (``models/block_moe.py``): a slot's turn
 forwards the block it is generating and, where the token it feeds
@@ -173,6 +189,19 @@ class _Slot:
         self.last_emit_t = time.perf_counter()
 
 
+class _Admission:
+    """A request whose prompt is being prefilled chunk by chunk into the
+    slot it will decode in."""
+    __slots__ = ("stream", "slot_idx", "spans", "cursor", "logits", "t0")
+
+    def __init__(self, stream, slot_idx, spans):
+        self.stream, self.slot_idx = stream, slot_idx
+        self.spans = spans      # [(start, stop), ...] of its prompt
+        self.cursor = 0         # chunks dispatched so far
+        self.logits = None      # the last dispatched chunk's, unread
+        self.t0 = time.perf_counter()
+
+
 class _Step:
     """A dispatched decode step whose tokens nobody has read yet."""
     __slots__ = ("rows", "read", "fused")
@@ -258,6 +287,12 @@ class GenScheduler:
         # (prefill in flight): drain()'s all-idle check must count
         # these or it can declare the scheduler empty mid-admission
         self._admitting = 0
+        # a chunk-capable bundle's admissions: the one whose chunks are
+        # being dispatched, and those whose last chunk is dispatched and
+        # whose first token nobody has read yet (scheduler thread)
+        self._chunked = bool(getattr(predictor, "prefill_chunks", ()))
+        self._admission = None
+        self._awaiting = []
         self.migrated = []        # checkpoints handed back by drain()
         self._thread = self._spawn_thread()
 
@@ -401,8 +436,9 @@ class GenScheduler:
         from paddle_tpu.serving import BatcherCrashed
         logger.exception("generation scheduler thread crashed")
         self._in_flight = None
+        admitting = self._take_admissions()
         with self._cv:
-            queued, self._queue = self._queue, []
+            queued, self._queue = admitting + self._queue, []
             active, self._slots = list(self._slots.values()), {}
             self._free = list(range(self.predictor.num_slots))
             restart = not self._closed and \
@@ -433,11 +469,13 @@ class GenScheduler:
             with self._cv:
                 while not self._queue and not self._slots and \
                         self._in_flight is None and \
+                        self._admission is None and not self._awaiting and \
                         not self._closed and self._abort_exc is None \
                         and not self._migrate_req:
                     self._cv.wait(0.05)
                 if self._closed:
-                    queued, self._queue = self._queue, []
+                    queued = self._take_admissions() + self._queue
+                    self._queue = []
                     active, self._slots = list(self._slots.items()), {}
                     self._in_flight = None
                     break
@@ -461,6 +499,10 @@ class GenScheduler:
                     # lifetime ones
                     with self._cv:
                         self._restarts = 0
+                elif self._admission is not None or self._awaiting:
+                    # the live streams ended under an admission: nobody
+                    # waits for a token between its chunks any more
+                    self._admit_alone()
                 _profiler.runtime_metrics.set_gauge("gen.slots_active",
                                                     len(self._slots))
                 _slo_tick(self.slo_watchdog)
@@ -481,9 +523,10 @@ class GenScheduler:
         kill would: the device runs what was queued in order, so a later
         admission's seed cannot be overtaken by its writes."""
         self._in_flight = None
+        admitting = self._take_admissions()
         with self._cv:
             exc, self._abort_exc = self._abort_exc, None
-            queued, self._queue = self._queue, []
+            queued, self._queue = admitting + self._queue, []
             active, self._slots = list(self._slots.values()), {}
             self._free = list(range(self.predictor.num_slots))
         self.predictor.free_all_pages()
@@ -503,8 +546,14 @@ class GenScheduler:
         slot whose pages go back."""
         if self._in_flight is not None:
             self._decode_iteration(dispatch=False)
+        # an admitting stream has no token yet: it goes as a queued one
+        # does, and its slot and pages back
+        admitting = self._admissions()
+        for adm in admitting:
+            self._end_admission(adm)
         with self._cv:
-            queued, self._queue = self._queue, []
+            queued = [a.stream for a in admitting] + self._queue
+            self._queue = []
             active = sorted(self._slots.items())
         for idx, slot in active:
             if not slot.stream.cancelled:
@@ -574,6 +623,8 @@ class GenScheduler:
             with self._cv:
                 if not self._queue or not self._free:
                     return
+                if self._admission is not None:
+                    return      # one request's chunks before the next's
                 if self.admission == "batch":
                     if refill is None:
                         refill = not self._slots
@@ -590,7 +641,8 @@ class GenScheduler:
                     len(head.prompt), head.max_new_tokens)
                 if need > self.predictor.free_pages:
                     return
-                if self.prefill_budget is not None and admitted_n:
+                if self.prefill_budget is not None and admitted_n \
+                        and not self._chunked:
                     # cost-weighted admission: stop once this pass has
                     # admitted its budget of static prefill FLOPs (the
                     # first admission is always free so the queue
@@ -617,6 +669,18 @@ class GenScheduler:
                 _profiler.runtime_metrics.observe("gen.admission_cost",
                                                   cost)
             admitted_n += 1
+            if self._chunked:
+                self._admission = self._begin_admission(slot_idx, stream)
+                if self._admission is None:
+                    continue        # the pool could not cover it: failed
+                # with no stream live (or a batch refill, which nothing
+                # decodes beside) the chunks run back to back, here; else
+                # the decode turns take them, one a turn
+                if not refill and (self._slots
+                                   or self._in_flight is not None):
+                    return
+                self._admit_alone()
+                continue
             admitted = False
             try:
                 admitted = self._prefill_into(slot_idx, stream)
@@ -690,6 +754,168 @@ class GenScheduler:
             self._slots[slot_idx] = _Slot(stream, prompt_len, first)
         return True
 
+    # -- an admission as a run of chunks (``predictor.prefill_chunks``) ----
+    def _begin_admission(self, slot_idx, stream):
+        """Allocate the request's pages (its whole horizon, as ever) and
+        lay out its chunks.  Returns the :class:`_Admission`, which owns
+        the slot until :meth:`_seat` or :meth:`_end_admission`; None
+        where the pool could not cover it (the stream is failed, the
+        slot free again)."""
+        p, prompt_len = self.predictor, len(stream.prompt)
+        with _trace.trace_context(stream.trace_id), \
+                _span("gen.seed_slot") as seed:
+            try:
+                pages = p.alloc_slot_pages(slot_idx, p.pages_needed(
+                    prompt_len, stream.max_new_tokens))
+            except BaseException as e:
+                stream.fail(e)
+                with self._cv:
+                    self._admitting -= 1
+                    self._free.append(slot_idx)
+                return None
+            # nothing is seeded: the chunks write pages and state
+            seed.set(pages=len(pages), row_bytes=p.cache_row_bytes,
+                     compiled_calls=0, eager_ops=0,
+                     state_arrays=len(p.state_vars))
+            win = getattr(p, "window_attention", None)
+            if win:
+                seed.set(ring_rows=len(win["layers"])
+                         * min(prompt_len, int(win["ring"])))
+        return _Admission(stream, slot_idx, p.chunk_spans(prompt_len))
+
+    def _end_admission(self, adm, seated=False):
+        """``adm`` is over: its slot decodes (``seated``), or goes back
+        with its pages."""
+        if not seated:
+            self.predictor.free_slot_pages(adm.slot_idx)
+        if self._admission is adm:
+            self._admission = None
+        elif adm in self._awaiting:
+            self._awaiting.remove(adm)
+        with self._cv:
+            self._admitting -= 1
+            if not seated:
+                self._free.append(adm.slot_idx)
+
+    def _admissions(self):
+        """Those that wait for their first token, then the one whose
+        chunks are going out."""
+        return [a for a in self._awaiting + [self._admission] if a]
+
+    def _take_admissions(self):
+        """Every admitting stream, for a wholesale reset (crash, abort,
+        shutdown), which takes slots and pages back itself."""
+        taken = self._admissions()
+        self._admission, self._awaiting = None, []
+        with self._cv:
+            self._admitting -= len(taken)
+        return [a.stream for a in taken]
+
+    def _run_chunk(self, adm):
+        """Dispatch ``adm``'s next chunk, not waited for.  False where
+        the admission ended instead: its reader gone, or the chunk
+        failed (the stream's failure, as a failed prefill is)."""
+        from paddle_tpu import profiler as _profiler
+        stream = adm.stream
+        if stream.cancelled:
+            _profiler.runtime_metrics.inc("gen.disconnects")
+            self.predictor.clear_slot(adm.slot_idx)
+            stream.finish("disconnect")
+            self._end_admission(adm)
+            return False
+        a, b = adm.spans[adm.cursor]
+        try:
+            with _trace.trace_context(stream.trace_id):
+                adm.logits = self.predictor.prefill_chunk(
+                    adm.slot_idx, stream.prompt[a:b], a)
+        except BaseException as e:
+            stream.fail(e)
+            self._end_admission(adm)
+            return False
+        adm.cursor += 1
+        return True
+
+    def _dispatch_chunks(self, beside_step):
+        """This turn's share of the admission in progress, launched
+        behind the turn's decode step: ONE chunk, more only while an
+        explicit ``prefill_budget`` covers the next one's static cost.
+        An admission whose last chunk went out waits for its first token
+        (:meth:`_seat`, a turn later)."""
+        from paddle_tpu import profiler as _profiler
+        adm = self._admission
+        if adm is None:
+            return
+        spent, ran = 0.0, 0
+        while adm.cursor < len(adm.spans):
+            if ran:
+                if self.prefill_budget is None:
+                    break
+                a, b = adm.spans[adm.cursor]
+                spent += self.predictor.chunk_cost(a, b - a)
+                if spent > self.prefill_budget:
+                    break
+            if not self._run_chunk(adm):
+                break
+            ran += 1
+        if ran:
+            _profiler.runtime_metrics.inc(
+                "gen.prefill.turns_interleaved" if beside_step
+                else "gen.prefill.turns_alone", 1 if beside_step else ran)
+        if self._admission is adm and adm.cursor == len(adm.spans):
+            self._admission = None
+            self._awaiting.append(adm)
+
+    def _admit_alone(self):
+        """No stream is live: the chunks of every admission under way
+        run back to back and it is seated, since nobody waits for a
+        token between them."""
+        from paddle_tpu import profiler as _profiler
+        for adm in self._admissions():
+            while adm.cursor < len(adm.spans):
+                if not self._run_chunk(adm):
+                    break
+                _profiler.runtime_metrics.inc("gen.prefill.turns_alone")
+            else:
+                self._seat(adm)
+
+    def _seat(self, adm):
+        """``adm``'s last chunk is out: read its logits (the one wait of
+        an admission), emit the first token, and seat the stream in its
+        slot, whose row of the device's decode state the next turn's
+        patch sets."""
+        from paddle_tpu import profiler as _profiler
+        metrics = _profiler.runtime_metrics
+        stream, prompt_len = adm.stream, len(adm.stream.prompt)
+        try:
+            logits = np.asarray(adm.logits)[0]
+        except BaseException as e:
+            stream.fail(e)
+            self._end_admission(adm)
+            return
+        metrics.inc("gen.admissions")
+        metrics.inc("gen.prefill.admissions_chunked")
+        with _trace.trace_context(stream.trace_id):
+            with _span("gen.first_token"):
+                first = int(np.argmax(logits))
+                now = time.perf_counter()
+                metrics.observe("gen.prefill_seconds", now - adm.t0)
+                metrics.observe("gen.ttft_seconds", now - stream.created_t)
+                metrics.inc("gen.tokens")
+                stream.emit(first)
+        # the whole admission, first chunk to first token
+        _trace.record_span("gen.admit", adm.t0, now - adm.t0,
+                           trace_id=stream.trace_id, slot=adm.slot_idx,
+                           chunks=len(adm.spans))
+        if first == stream.eos_id:
+            self._finish(stream, "eos")
+        elif stream.max_new_tokens <= 1 or prompt_len >= self._horizon:
+            self._finish(stream, "length")
+        else:
+            with self._cv:
+                self._slots[adm.slot_idx] = _Slot(stream, prompt_len, first)
+            return self._end_admission(adm, seated=True)
+        self._end_admission(adm)
+
     def _finish(self, stream, reason):
         from paddle_tpu import profiler as _profiler
         stream.finish(reason)
@@ -745,9 +971,10 @@ class GenScheduler:
         # what is left of this span beside its two children is the
         # bookkeeping below
         with _span("gen.decode_iteration", live=len(live)):
-            self._step_and_emit(live, _profiler.runtime_metrics)
+            self._step_and_emit(live, _profiler.runtime_metrics,
+                                chunks=dispatch)
 
-    def _step_and_emit(self, live, metrics):
+    def _step_and_emit(self, live, metrics, chunks=True):
         S, horizon, block = self.predictor.num_slots, self._horizon, \
             self._block
         prev = self._in_flight
@@ -789,6 +1016,11 @@ class GenScheduler:
                 # layers read; {} without
                 step.set(**self.predictor.last_step_counts)
                 self._in_flight = _Step(rows, read, fused)
+            # the admissions whose last chunk went out a turn ago, and
+            # this turn's chunk, behind the step just launched
+            seats = list(self._awaiting)
+            if chunks:
+                self._dispatch_chunks(beside_step=bool(rows))
             if prev:
                 with _span("gen.collect"):
                     ids, attrs = self.predictor.read_turn(prev.read)
@@ -823,3 +1055,5 @@ class GenScheduler:
                 elif ends:
                     self._finish(stream, "length")
                     self._evict(idx)
+        for adm in seats:
+            self._seat(adm)

@@ -34,10 +34,20 @@ page pool, rows of ``Hkv * key_head_stored`` and ``Hkv * v_head_dim``
 (``cache_vars``; ``paged_attention`` takes the two widths).  A window
 layer's live in a RING a slot, ``[num_slots, ring, ...]`` (``state_vars``:
 position ``p`` at row ``p mod ring``, ``ring >= sliding_window``): its
-bytes a slot are a constant of the bundle, whatever ``max_len``.  The
-prefill returns the full layers' K/V and then each window layer's ring
-(the prompt's last ``ring`` rows); ``gen_meta.json``'s
-``window_attention`` says which layer has which.
+bytes a slot are a constant of the bundle, whatever ``max_len``;
+``gen_meta.json``'s ``window_attention`` says which layer has which.
+
+**The prefill is a CHUNK program.**  A prompt runs as a sequence of
+chunks of ``prefill_chunks`` rows (``gen_meta.json``; the largest for
+every chunk but the last, which takes the smallest that holds it), each
+one compiled call over the SAME pools and rings the decode step reads:
+a full layer writes the chunk's K/V into the slot's pages and attends
+the pages' rows ``0 .. P + C - 1``, a window layer attends the ring's
+rows before ``P`` followed by the chunk and leaves the chunk's last rows
+in the ring (``ops/window_ops.py``).  ``P`` = 0 is the first chunk: a
+prompt that fits one chunk runs the same program.  Nothing seeds a slot
+afterwards, and the scheduler can run a decode step for the live
+streams between two chunks (``gen/scheduler.py``).
 
 Matrices and activations are ``dtype`` (bfloat16) with float32
 accumulation; router scores, norm statistics, rotary angles, softmax and
@@ -59,10 +69,17 @@ from paddle_tpu.models.hybrid_moe import (DECODE_STATS, _data, _embed,
                                           _vector)
 from paddle_tpu.models.latent_moe import _gated_ffn
 
-__all__ = ["WindowMoEConfig", "build_prefill_program",
+__all__ = ["WindowMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "window_moe_train_program",
            "export_window_model", "paged_cache_var_names",
-           "ring_var_names"]
+           "ring_var_names", "chunk_rows"]
+
+# rows of a prefill chunk (the larger rung).  A chunk reads every matrix
+# once, so it should hold several times the rows at which a v5e's
+# products take as long as their operands' reads (~240), and it is what
+# a live stream waits through between two of its tokens, so no more:
+# PERF.md section 6 (PR 42) has the chip's readings at 512 / 1024 / 2048
+CHUNK_ROWS = 1024
 
 
 class WindowMoEConfig:
@@ -182,12 +199,14 @@ def ring_var_names(hp):
     return [f"win{i}_ring_{r}" for i in hp.window_layers for r in "kv"]
 
 
-def _attention(h, hp, i, pos, mask=None, last=None, cache=None):
-    """Layer ``i``'s attention: prefill (``mask``; returns the rows that
-    seed its cache: the masked K/V of a full layer, the ring of a window
-    layer where ``last`` is given) or the decode step (``cache`` = (k
-    pool, v pool, page table, lens) of a full layer, (k ring, v ring,
-    lens) of a window layer)."""
+def _attention(h, hp, i, pos, chunk=None, cache=None):
+    """Layer ``i``'s attention, one of three forms.  Neither ``chunk``
+    nor ``cache``: a whole sequence, nothing cached (the training
+    forward).  ``chunk`` = (k cache, v cache, page table [1, P] of a full
+    layer or slot [1, 1] of a window layer, mask [1, C]): ONE CHUNK of a
+    prompt over the slot's own caches, which it reads and writes.
+    ``cache`` = (k pool, v pool, page table, lens) of a full layer, (k
+    ring, v ring, lens) of a window layer: the decode step."""
     d = int(hp.hidden_size)
     H, Hkv, Dk, Dv, theta, has_sink = hp.attention(i)
     window = int(hp.sliding_window) if hp.is_window(i) else 0
@@ -207,23 +226,27 @@ def _attention(h, hp, i, pos, mask=None, last=None, cache=None):
             {"n_head": Hkv, **rope})["Out"]
     sink = _vector(f"win{i}_sink", H, 0.0) if has_sink else None
     attrs = {"n_head": H, "scale": float(Dk) ** -0.5}
-    seeds = []
-    if cache is None and window:
-        out = _op("window_attention",
-                  {"Q": q, "K": k, "V": v, "Sink": sink, "Last": last},
-                  {"Out": hp.dtype, **({} if last is None else {
-                      "KRing": hp.dtype, "VRing": hp.dtype})},
-                  {**attrs, "n_kv_head": Hkv, "window": window,
-                   "ring": hp.ring_rows})
-        ctx = out["Out"]
-        seeds = [] if last is None else [out["KRing"], out["VRing"]]
+    heads = {**attrs, "n_kv_head": Hkv}
+    if chunk is not None and window:
+        k_ring, v_ring, slot, mask = chunk
+        ctx = _op("window_attention",
+                  {"Q": q, "K": k, "V": v, "Sink": sink, "KRing": k_ring,
+                   "VRing": v_ring, "Slot": slot, "Pos": pos, "Mask": mask},
+                  {"Out": hp.dtype, "KRingOut": k_ring, "VRingOut": v_ring},
+                  {**heads, "window": window})["Out"]
+    elif chunk is not None:
+        pk, pv, page_table, mask = chunk
+        ctx = _op("gqa_flash_attention_chunk",
+                  {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
+                   "PageTable": page_table, "Pos": pos, "Mask": mask},
+                  {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
+                  heads)["Out"]
+    elif cache is None and window:
+        ctx = _op("window_attention", {"Q": q, "K": k, "V": v, "Sink": sink},
+                  {"Out": hp.dtype}, {**heads, "window": window})["Out"]
     elif cache is None:
-        mask_t = layers.cast(mask, hp.dtype)
-        k = layers.elementwise_mul(k, mask_t, axis=0)
-        v = layers.elementwise_mul(v, mask_t, axis=0)
-        seeds = [k, v]
         ctx = _op("gqa_flash_attention", {"Q": q, "K": k, "V": v},
-                  {"Out": hp.dtype}, {**attrs, "n_kv_head": Hkv})["Out"]
+                  {"Out": hp.dtype}, heads)["Out"]
     elif window:
         k_ring, v_ring, lens = cache
         ctx = _op("window_attention_step",
@@ -237,8 +260,8 @@ def _attention(h, hp, i, pos, mask=None, last=None, cache=None):
                   {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
                    "PageTable": page_table, "Lens": lens},
                   {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
-                  {**attrs, "n_kv_head": Hkv})["Out"]
-    return layers.matmul(ctx, _matrix(hp, f"win{i}_o.w", [H * Dv, d])), seeds
+                  heads)["Out"]
+    return layers.matmul(ctx, _matrix(hp, f"win{i}_o.w", [H * Dv, d]))
 
 
 def _moe(h, hp, i, lens):
@@ -266,46 +289,72 @@ def _moe(h, hp, i, lens):
     return routed["Out"], routed["Stats"]
 
 
-def _layer(x, hp, i, pos, lens, mask=None, last=None, cache=None):
-    """One layer; returns ``(x, the rows that seed its cache or [],
-    stats or None)``."""
-    out, seeds = _attention(_rms(x, f"win{i}_norm1.scale", hp), hp, i, pos,
-                            mask=mask, last=last, cache=cache)
-    x = x + out
+def _layer(x, hp, i, pos, lens, chunk=None, cache=None):
+    """One layer; returns ``(x, stats or None)``."""
+    x = x + _attention(_rms(x, f"win{i}_norm1.scale", hp), hp, i, pos,
+                       chunk=chunk, cache=cache)
     h = _rms(x, f"win{i}_norm2.scale", hp)
     if hp.is_moe(i):
         out, stats = _moe(h, hp, i, lens)
     else:
         out, stats = _gated_ffn(h, hp, f"win{i}_ffn",
                                 int(hp.intermediate_size)), None
-    return x + out, seeds, stats
+    return x + out, stats
 
 
-def build_prefill_program(hp):
-    """The prefill forward in the CURRENT program guard.
+def _caches(hp, num_slots, page_len, num_pages):
+    """The persistable caches of the CURRENT program, ``{(layer, "k" |
+    "v"): var}``, all ``hp.dtype``: a full layer's pools ``[num_pages,
+    page_len, row]``, a window layer's rings ``[num_slots, ring,
+    row]``."""
+    import paddle_tpu as fluid
+    block = fluid.default_main_program().global_block()
+    cache = {}
+    for i in range(int(hp.num_hidden_layers)):
+        lead, kind = ([int(num_slots), hp.ring_rows], "ring") \
+            if hp.is_window(i) else ([int(num_pages), int(page_len)], "paged")
+        for r, width in zip("kv", hp.row_widths(i)):
+            v = block.create_var(name=f"win{i}_{kind}_{r}",
+                                 shape=lead + [width], dtype=hp.dtype)
+            v.persistable = True
+            v.stop_gradient = True
+            cache[i, r] = v
+    return cache
 
-    Feeds (length-dynamic; callers pad to a bucket): ``gen_ids`` [1, T]
-    int32, ``gen_pos`` [1, T] int32 (0 .. T-1), ``gen_mask`` [1, T] f32
-    (1 = real token, real tokens first), ``gen_last`` [1, T] f32 (one-hot
-    of the last real position).  Fetches ``[logits [1, V], k, v a full
-    layer [1, T, row] (zeros on pad rows) ..., k ring, v ring a window
-    layer [1, ring, row] ...]``."""
+
+def build_chunk_program(hp, num_slots, page_len, num_pages):
+    """The prefill of ONE CHUNK of a prompt in the CURRENT program guard.
+
+    Feeds (length-dynamic; the predictor pads to a chunk rung):
+    ``gen_ids`` [1, C] int32, ``gen_pos`` [1, C] int32 (the rows'
+    positions ``P .. P + C - 1``), ``gen_mask`` [1, C] f32 (1 = real
+    token, real tokens first), ``gen_last`` [1, C] f32 (one-hot of the
+    prompt's last row where this chunk holds it, else zeros),
+    ``gen_slot`` [1, 1] int32 and ``gen_page_table`` [1, P] int32 (the
+    slot's row, P bucketed by the predictor and covering the chunk's
+    last real row).  Persistable state, read and updated in place, as
+    the decode step's: the full layers' pools and the window layers'
+    rings.  Fetches ``[logits [1, V]]`` (of the row ``gen_last``
+    names)."""
     ids = _data("gen_ids", [1, -1], "int32")
     pos = _data("gen_pos", [1, -1], "int32")
     mask = _data("gen_mask", [1, -1])
     last = _data("gen_last", [1, -1])
+    slot = _data("gen_slot", [1, 1], "int32")
+    page_table = _data("gen_page_table", [1, -1], "int32")
+    cache = _caches(hp, num_slots, page_len, num_pages)
     # pad rows take no routed expert
     lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
     x = _embed(ids, hp, "win")
-    paged, rings = [], []
     for i in range(int(hp.num_hidden_layers)):
-        x, seeds, _ = _layer(x, hp, i, pos, lens, mask=mask, last=last)
-        (rings if hp.is_window(i) else paged).extend(seeds)
+        x, _ = _layer(x, hp, i, pos, lens, chunk=(
+            cache[i, "k"], cache[i, "v"],
+            slot if hp.is_window(i) else page_table, mask))
     last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]), hp.dtype)
     lasth = layers.reshape(layers.matmul(last3, x),
                            shape=[-1, int(hp.hidden_size)])
-    return (["gen_ids", "gen_pos", "gen_mask", "gen_last"],
-            [_logits(lasth, hp, "win")] + paged + rings)
+    return (["gen_ids", "gen_pos", "gen_mask", "gen_last", "gen_slot",
+             "gen_page_table"], [_logits(lasth, hp, "win")])
 
 
 def window_moe_train_program(seq_len, hp: WindowMoEConfig = None):
@@ -319,13 +368,12 @@ def window_moe_train_program(seq_len, hp: WindowMoEConfig = None):
     ids = _data("gen_ids", [1, T], "int32")
     labels = _data("gen_labels", [1, T], "int32")
     pos = layers.assign(np.arange(T, dtype="int32").reshape(1, T))
-    mask = layers.assign(np.ones((1, T), "float32"))
     lens = layers.assign(np.ones((T, 1), "int32"))
-    for v in (pos, mask, lens):
+    for v in (pos, lens):
         v.stop_gradient = True
     x = _embed(ids, hp, "win")
     for i in range(int(hp.num_hidden_layers)):
-        x, _, _ = _layer(x, hp, i, pos, lens, mask=mask)
+        x, _ = _layer(x, hp, i, pos, lens)
     logits = _logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
                      "win")
     cost = layers.softmax_with_cross_entropy(
@@ -344,33 +392,18 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     full layer's pools ``[num_pages, page_len, row]`` and a window
     layer's rings ``[S, ring, row]``.  Fetches ``[logits [S, V], stats
     [n_moe, 3]]``."""
-    import paddle_tpu as fluid
-
     S = int(num_slots)
     token = _data("gen_token", [S, 1], "int32")
     pos = _data("gen_pos", [S, 1], "int32")
     page_table = _data("gen_page_table", [S, -1], "int32")
     lens = _data("gen_lens", [S, 1], "int32")
-    block = fluid.default_main_program().global_block()
-
-    def persistable(name, shape):
-        v = block.create_var(name=name, shape=list(shape), dtype=hp.dtype)
-        v.persistable = True
-        v.stop_gradient = True
-        return v
-
-    cache = {}
-    for i in range(int(hp.num_hidden_layers)):
-        lead, kind = ([S, hp.ring_rows], "ring") if hp.is_window(i) \
-            else ([int(num_pages), int(page_len)], "paged")
-        for r, width in zip("kv", hp.row_widths(i)):
-            cache[i, r] = persistable(f"win{i}_{kind}_{r}", lead + [width])
+    cache = _caches(hp, S, page_len, num_pages)
     x = layers.reshape(_embed(token, hp, "win"),
                        shape=[S, 1, int(hp.hidden_size)])
     stats = []
     for i in range(int(hp.num_hidden_layers)):
         held = (cache[i, "k"], cache[i, "v"])
-        x, _, st = _layer(
+        x, st = _layer(
             x, hp, i, pos, lens,
             cache=held + ((lens,) if hp.is_window(i)
                           else (page_table, lens)))
@@ -383,13 +416,29 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     return ["gen_token", "gen_pos", "gen_page_table", "gen_lens"], fetches
 
 
+def chunk_rows(page_len, max_prompt):
+    """The bundle's chunk rungs, ascending, from its shapes: the larger
+    is ``CHUNK_ROWS`` (no more than the longest prompt takes, whole
+    pages), the smaller half of it where that is whole pages too: a
+    prompt's LAST chunk takes it where it fits, so a prompt runs no more
+    than half the larger rung in pad rows."""
+    page_len = int(page_len)
+    top = min(int(CHUNK_ROWS), int(max_prompt))
+    top = max(-(-top // page_len) * page_len, page_len)
+    half = top // 2
+    return [top] if half % page_len or not half else [half, top]
+
+
 def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
                         prompt_buckets=None, page_len=PAGE_LEN_DEFAULT,
                         num_pages=None, page_buckets=None):
     """Export a generation bundle in ``gen_lm.export_gen_model``'s
-    layout.  ``cache_vars`` names the full layers' pools, ``state_vars``
-    the window layers' rings, and ``window_attention`` which layer has
-    which and what a ring row takes.  Returns ``dirname``."""
+    layout, its ``prefill`` the chunk program (``prefill_chunks`` in the
+    meta; ``prompt_buckets`` bounds the longest prompt and is what
+    ``GenPredictor.prefill`` + ``write_slot`` hand rows over in).
+    ``cache_vars`` names the full layers' pools, ``state_vars`` the
+    window layers' rings, and ``window_attention`` which layer has which
+    and what a ring row takes.  Returns ``dirname``."""
     import jax.numpy as jnp
 
     import paddle_tpu as fluid
@@ -412,8 +461,11 @@ def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
         exe = fluid.Executor()
         pre_main, pre_startup = fluid.Program(), fluid.Program()
         with fluid.program_guard(pre_main, pre_startup):
-            pre_feeds, pre_fetches = build_prefill_program(hp)
+            pre_feeds, pre_fetches = build_chunk_program(
+                hp, num_slots, page_len, num_pages)
         exe.run(pre_startup)
+        # written before the caches exist in the scope: the decode
+        # program's file alone holds them
         _write_model(os.path.join(dirname, "prefill"), pre_main,
                      pre_feeds, pre_fetches, exe)
         dec_main, dec_startup = fluid.Program(), fluid.Program()
@@ -441,6 +493,8 @@ def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
         "state_vars": ring_var_names(hp),
         "decode_stats": DECODE_STATS if hp.moe_layers else [],
         "prompt_buckets": [int(b) for b in prompt_buckets],
+        "prefill_chunks": chunk_rows(
+            page_len, min(max(prompt_buckets), int(hp.max_len))),
         "page_len": int(page_len),
         "num_pages": int(num_pages),
         "page_buckets": [int(b) for b in page_buckets],

@@ -14,11 +14,11 @@ widths and a rotary on the LEADING lanes of a head.
 
       P_h(t, u) = exp(s_h(t, u)) / (exp(b_h) + sum_u' exp(s_h(t, u')))
 
-  ``window_attention`` is the prefill form over one prompt: a BANDED
-  flash forward kernel that computes only the key blocks that meet the
-  band and starts its running sum from the sink (``m = b_h``, ``l = 1``,
-  ``acc = 0``).  It also hands back the slot's RING: the prompt's last
-  ``ring`` rows of K and V, position ``p`` at row ``p mod ring``.
+  ``window_attention`` is the prefill form: a BANDED flash forward
+  kernel that computes only the key blocks that meet the band and starts
+  its running sum from the sink (``m = b_h``, ``l = 1``, ``acc = 0``).
+  A slot's RING holds the last ``ring`` rows of K and V that went
+  through it, position ``p`` at row ``p mod ring``.
   ``window_attention_step`` is the decode form over that bounded
   per-slot cache ``[S, ring, Hkv * D]``: it writes this step's row at
   ``(Lens - 1) mod ring`` and attends over the rows of the ring that lie
@@ -35,6 +35,18 @@ widths and a rotary on the LEADING lanes of a head.
   computed nor copied); its decode step is ``paged_attention``
   (``ops/attention_ops.py``) over pools whose K rows and V rows differ
   in width.
+* A prompt can run as a sequence of CHUNKS, each reading the slot's
+  earlier rows from where the decode step reads them and writing its own
+  there.  A full layer's chunk is ``gqa_flash_attention_chunk``: it
+  writes the chunk's K/V rows into the slot's pages and attends the
+  chunk's queries (positions ``P .. P + C - 1``) over the pages' rows
+  ``0 .. P + C - 1``: the causal kernel with more key rows than query
+  rows and its diagonal shifted by ``P``, a scalar the kernel is handed
+  before its grid runs.  A window layer's is ``window_attention`` with
+  the rings as inputs: its keys are the slot's ring rows of positions
+  ``P - lead .. P - 1`` (``ring_lead``) followed by the chunk's own, and
+  it leaves the chunk's last ``ring`` rows in the ring (``ring_after``).
+  ``P`` = 0 is a prompt's first chunk, or all of it.
 
 Both prefill ops take ``[1, T, H * Dk]`` queries over ``Hkv`` K/V heads
 (query head ``h`` reads K/V head ``h // (H / Hkv)``); the ``G = H / Hkv``
@@ -44,9 +56,9 @@ query heads of a K/V head share its key blocks in one ``[G * rows, Dk] x
 kernel to).
 
 Op scopes on the device trace: ``ptop_window_attention__*`` (a window
-layer's prefill), ``ptop_window_attention_step*`` (its decode step),
-``ptop_gqa_flash_attention*`` (a full layer's prefill),
-``ptop_rope_partial*``.
+layer's prefill, whole or a chunk), ``ptop_window_attention_step*`` (its
+decode step), ``ptop_gqa_flash_attention*`` (a full layer's prefill,
+whole or a chunk), ``ptop_rope_partial*``.
 """
 
 from __future__ import annotations
@@ -130,10 +142,13 @@ def rope_partial_lower(ctx):
 # the composed form (toy sizes; what the kernel is held to)
 # ---------------------------------------------------------------------------
 
-def _seen(T, window):
-    rows = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    seen = cols <= rows
+def _seen(Tq, Tk, window, start=0, first=0):
+    """[Tq, Tk]: query row ``r`` stands at key index ``start + r`` and
+    sees the keys at or before it, from index ``first`` on, inside
+    ``window`` rows where that is not 0."""
+    rows = start + jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (Tq, Tk), 1)
+    seen = (cols <= rows) & (cols >= first)
     return seen & (rows - cols < window) if window else seen
 
 
@@ -149,69 +164,93 @@ def _with_sink(sc, sink):
 
 
 def composed_attention(q, k, v, n_head, n_kv_head, scale, window=0,
-                       sink=None):
-    """``q`` [T, H * Dk]; ``k`` [T, Hkv * Dk]; ``v`` [T, Hkv * Dv];
-    ``sink`` [H] float32 or None.  Causal, inside ``window`` rows where
-    that is not 0.  Scores and softmax in float32.  Returns [T, H * Dv]
-    in ``q``'s type."""
-    T = q.shape[0]
+                       sink=None, start=0, first=0):
+    """``q`` [Tq, H * Dk]; ``k`` [Tk, Hkv * Dk]; ``v`` [Tk, Hkv * Dv];
+    ``sink`` [H] float32 or None.  Query row ``r`` stands at key index
+    ``start + r`` (int or traced scalar; a whole prompt: 0 and ``Tk`` =
+    ``Tq``): causal from key ``first`` on, inside ``window`` rows where
+    that is not 0.  Scores and softmax in float32.  Returns [Tq, H *
+    Dv] in ``q``'s type."""
+    Tq, Tk = q.shape[0], k.shape[0]
     g = n_head // n_kv_head
-    qh = q.reshape(T, n_kv_head, g, -1)
-    kh, vh = k.reshape(T, n_kv_head, -1), v.reshape(T, n_kv_head, -1)
+    qh = q.reshape(Tq, n_kv_head, g, -1)
+    kh, vh = k.reshape(Tk, n_kv_head, -1), v.reshape(Tk, n_kv_head, -1)
     sc = jnp.einsum("qkgd,tkd->kgqt", qh, kh,
                     preferred_element_type=jnp.float32) * scale
-    sc = jnp.where(_seen(T, window), sc, NEG_INF)
+    sc = jnp.where(_seen(Tq, Tk, window, start, first), sc, NEG_INF)
     if sink is not None:
         sink = sink.astype(jnp.float32).reshape(n_kv_head, g, 1, 1)
     probs = _with_sink(sc, sink)
     out = jnp.einsum("kgqt,tkd->qkgd", probs.astype(v.dtype), vh,
                      preferred_element_type=jnp.float32)
-    return out.reshape(T, -1).astype(q.dtype)
+    return out.reshape(Tq, -1).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
 # the flash forward kernel: banded with a sink, or causal
 # ---------------------------------------------------------------------------
 
-def flash_blocks(T, group, window):
-    """(query rows, key rows) a block at ``T`` rows and ``group`` query
-    heads a K/V head; None where ``T`` is not whole blocks of both."""
+def flash_blocks(T, group, window, keys=None):
+    """(query rows, key rows) a block at ``T`` query rows and ``group``
+    query heads a K/V head, over ``keys`` key rows where the call is
+    causal (None: ``T`` of them); None where they are not whole blocks
+    of both."""
     bq = max(FLASH_LEFT_ROWS // group, 16)
     bk = BAND_KEY_BLOCK if window else CAUSAL_KEY_BLOCK
-    if T % bq or T % bk or bq % 16:
+    if T % bq or bq % 16:
         return None
-    if window and bq % bk:
+    if bq % bk if window else (T if keys is None else keys) % bk:
         return None
     return bq, bk
 
 
-def key_blocks_computed(T, group, window):
-    """Key blocks the kernel computes for ``T`` rows of ONE K/V head,
-    and the rows of a block; (0, 0) where the composed form runs."""
+def lead_rows(T, group, window):
+    """Key rows that stand BEFORE a chunk's own in a window call: whole
+    key blocks that cover ``window - 1`` rows where the kernel runs,
+    ``window - 1`` rows where the composed form does."""
     blocks = flash_blocks(T, group, window)
+    if blocks is None:
+        return window - 1
+    return -(-(window - 1) // blocks[1]) * blocks[1]
+
+
+def key_blocks_computed(T, group, window, start=0, keys=None):
+    """Key blocks the kernel computes for ``T`` query rows of ONE K/V
+    head that stand at positions ``start ..`` (0: a whole prompt, or its
+    first chunk) over ``keys`` key rows (causal; None: ``T``), and the
+    rows of a block; (0, 0) where the composed form runs."""
+    blocks = flash_blocks(T, group, window, keys)
     if blocks is None:
         return 0, 0
     bq, bk = blocks
     n_q = T // bq
     if window:
         lead, per = -(-(window - 1) // bk), bq // bk
-        return sum(min(i * per + per, lead + per) for i in range(n_q)), bk
-    return sum((i * bq + bq - 1) // bk + 1 for i in range(n_q)), bk
+        # lead-in blocks that hold no row: before position 0
+        dead = (lead * bk - min(start, lead * bk)) // bk
+        return sum(lead + per - max(0, min(lead + per, dead - i * per))
+                   for i in range(n_q)), bk
+    return sum((start + i * bq + bq - 1) // bk + 1 for i in range(n_q)), bk
 
 
-def _flash_kernel(*refs, scale, window, bq, bk, lead, sink):
+def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink):
     """One (K/V head, query block); the key blocks that meet the band
-    (``window`` > 0: ``lead`` blocks before the query block's own) or
-    lie at or under the diagonal stream through VMEM along the
-    innermost, sequential grid axis with an online softmax.  The ``G``
-    query heads that share the K/V head are the rows of ONE product."""
+    (``window`` > 0: the keys begin with ``lead`` blocks of the rows
+    before the chunk, of which those from index ``s`` on are real) or
+    lie at or under the diagonal (the chunk's first row stands at key
+    index ``s``) stream through VMEM along the innermost, sequential
+    grid axis with an online softmax.  The ``G`` query heads that share
+    the K/V head are the rows of ONE product."""
     if sink:
         sink_ref, *refs = refs
     q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr = refs
     i, j = pl.program_id(1), pl.program_id(2)
     G = q_ref.shape[1]
-    # the key block this step holds (before the caller's clamp)
-    kb = i * (bq // bk) - lead + j if window else j
+    s = s_ref[0]
+    # the key block this step holds (before the caller's clamp), and the
+    # key index the query block's first row stands at
+    kb = i * (bq // bk) + j if window else j
+    t0 = lead * bk + i * bq if window else s + i * bq
 
     @pl.when(j == 0)
     def _():
@@ -226,20 +265,20 @@ def _flash_kernel(*refs, scale, window, bq, bk, lead, sink):
     def update(masked):
         q = q_ref[0].reshape(G * bq, q_ref.shape[-1])
         k, v = k_ref[0], v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32) * scale
         if masked:
-            t = i * bq + (jax.lax.broadcasted_iota(
+            t = t0 + (jax.lax.broadcasted_iota(
                 jnp.int32, (G * bq, bk), 0) & (bq - 1))
             u = kb * bk + jax.lax.broadcasted_iota(
                 jnp.int32, (G * bq, bk), 1)
             seen = u <= t
             if window:
-                seen &= t - u < window
-            s = jnp.where(seen, s, NEG_INF)
+                seen &= (t - u < window) & (u >= s)
+            sc = jnp.where(seen, sc, NEG_INF)
         m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        p = jnp.exp(sc - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
         acc[...] = acc[...] * alpha + jax.lax.dot_general(
@@ -248,12 +287,13 @@ def _flash_kernel(*refs, scale, window, bq, bk, lead, sink):
         m_scr[...] = m_new
 
     if window:
-        pl.when(kb >= 0)(lambda: update(True))
+        # a lead-in block none of whose rows is real is not computed
+        pl.when(kb * bk + bk > s)(lambda: update(True))
     else:
         # under the diagonal a block is seen whole
-        whole = kb * bk + bk - 1 <= i * bq
+        whole = kb * bk + bk - 1 <= t0
         pl.when(whole)(lambda: update(False))
-        pl.when(jnp.logical_not(whole) & (kb * bk <= i * bq + bq - 1))(
+        pl.when(jnp.logical_not(whole) & (kb * bk <= t0 + bq - 1))(
             lambda: update(True))
 
     @pl.when(j == pl.num_programs(2) - 1)
@@ -262,34 +302,58 @@ def _flash_kernel(*refs, scale, window, bq, bk, lead, sink):
             .astype(o_ref.dtype)
 
 
+def _led(k, v, before):
+    """``k`` / ``v`` with the rows ``before`` = ``(k rows, v rows, n)``
+    led in front of them, and the first key index that is real (the last
+    ``n`` of the rows before are)."""
+    return (jnp.concatenate([before[0].astype(k.dtype), k]),
+            jnp.concatenate([before[1].astype(v.dtype), v]),
+            before[0].shape[0] - before[2])
+
+
 @functools.partial(jax.jit, static_argnames=(
     "n_head", "n_kv_head", "scale", "window", "interpret", "blocks"))
-def flash_attention(q, k, v, sink=None, *, n_head, n_kv_head, scale,
-                    window=0, interpret=False, blocks=None):
-    """``q`` [T, H * Dk]; ``k`` [T, Hkv * Dk]; ``v`` [T, Hkv * Dv];
-    ``sink`` [H] or None -> [T, H * Dv] in ``q``'s type, as
-    ``composed_attention``.  ``blocks`` is for the tests: the kernel
-    reads it from the shapes (``flash_blocks``); ``T`` must be whole
-    blocks."""
-    T = q.shape[0]
+def flash_attention(q, k, v, sink=None, start=0, before=None, *, n_head,
+                    n_kv_head, scale, window=0, interpret=False,
+                    blocks=None):
+    """``q`` [Tq, H * Dk]; ``sink`` [H] or None -> [Tq, H * Dv] in
+    ``q``'s type, as ``composed_attention``.
+
+    Causal (``window`` 0): ``k`` [Tk, Hkv * Dk], ``v`` [Tk, Hkv * Dv]
+    hold EVERY key row, the queries' own among them: query row ``r``
+    stands at key index ``start + r`` (an int32 scalar, traced or not;
+    ``Tk`` whole key blocks).  Banded: ``k`` / ``v`` [Tq, ...] are the
+    chunk's own rows and ``before`` = ``(k rows, v rows, n)`` the
+    ``lead_rows`` rows that stand before them, of which the LAST ``n``
+    (traced or not) are real; None: none is.  ``blocks`` is for the
+    tests: the kernel reads it from the shapes (``flash_blocks``);
+    ``Tq`` must be whole blocks."""
+    Tq = q.shape[0]
     G = n_head // n_kv_head
     Dk, Dv = k.shape[-1] // n_kv_head, v.shape[-1] // n_kv_head
-    bq, bk = blocks or flash_blocks(T, G, window)
+    bq, bk = blocks or flash_blocks(Tq, G, window, keys=k.shape[0])
     if bq & (bq - 1):
         raise ValueError(f"query block of {bq} rows is not a power of two")
     per = bq // bk if window else 0
     lead = -(-(window - 1) // bk) if window else 0
-    n_j = lead + per if window else T // bk
-    # head-major: a K/V head's G query heads side by side
-    qh = q.reshape(T, n_kv_head, G, Dk).transpose(1, 2, 0, 3)
-    kh = k.reshape(T, n_kv_head, Dk).transpose(1, 0, 2)
-    vh = v.reshape(T, n_kv_head, Dv).transpose(1, 0, 2)
     if window:
-        kv = lambda h, i, j: (h, jnp.maximum(i * per - lead + j, 0), 0)
+        if before is None:
+            before = (jnp.zeros((lead * bk, k.shape[-1]), k.dtype),
+                      jnp.zeros((lead * bk, v.shape[-1]), v.dtype), 0)
+        k, v, start = _led(k, v, before)
+    n_k = k.shape[0] // bk
+    n_j = lead + per if window else n_k
+    # head-major: a K/V head's G query heads side by side
+    qh = q.reshape(Tq, n_kv_head, G, Dk).transpose(1, 2, 0, 3)
+    kh = k.reshape(-1, n_kv_head, Dk).transpose(1, 0, 2)
+    vh = v.reshape(-1, n_kv_head, Dv).transpose(1, 0, 2)
+    if window:
+        kv = lambda h, i, j, s: (h, i * per + j, 0)
     else:
         # a block above the diagonal is not computed: hand the kernel the
         # diagonal's again, which is not copied a second time
-        kv = lambda h, i, j: (h, jnp.minimum(j, (i * bq + bq - 1) // bk), 0)
+        kv = lambda h, i, j, s: (h, jnp.minimum(jnp.minimum(
+            j, (s[0] + i * bq + bq - 1) // bk), n_k - 1), 0)
     operands, in_specs = [], []
     if sink is not None:
         # the sink a row of the left side: [Hkv, G * bq, 1]
@@ -297,51 +361,86 @@ def flash_attention(q, k, v, sink=None, *, n_head, n_kv_head, scale,
             sink.astype(jnp.float32).reshape(n_kv_head, G), bq,
             axis=1)[..., None])
         in_specs.append(pl.BlockSpec((1, G * bq, 1),
-                                     lambda h, i, j: (h, 0, 0)))
+                                     lambda h, i, j, s: (h, 0, 0)))
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, window=window, bq=bq,
                           bk=bk, lead=lead, sink=sink is not None),
-        grid=(n_kv_head, T // bq, n_j),
-        in_specs=in_specs + [
-            pl.BlockSpec((1, G, bq, Dk), lambda h, i, j: (h, 0, i, 0)),
-            pl.BlockSpec((1, bk, Dk), kv),
-            pl.BlockSpec((1, bk, Dv), kv)],
-        out_specs=pl.BlockSpec((1, G, bq, Dv), lambda h, i, j: (h, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_kv_head, G, T, Dv), q.dtype),
-        scratch_shapes=[pltpu.VMEM((G * bq, Dv), jnp.float32),
-                        pltpu.VMEM((G * bq, 1), jnp.float32),
-                        pltpu.VMEM((G * bq, 1), jnp.float32)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_kv_head, Tq // bq, n_j),
+            in_specs=in_specs + [
+                pl.BlockSpec((1, G, bq, Dk), lambda h, i, j, s: (h, 0, i, 0)),
+                pl.BlockSpec((1, bk, Dk), kv),
+                pl.BlockSpec((1, bk, Dv), kv)],
+            out_specs=pl.BlockSpec((1, G, bq, Dv),
+                                   lambda h, i, j, s: (h, 0, i, 0)),
+            scratch_shapes=[pltpu.VMEM((G * bq, Dv), jnp.float32),
+                            pltpu.VMEM((G * bq, 1), jnp.float32),
+                            pltpu.VMEM((G * bq, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((n_kv_head, G, Tq, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(*operands, qh, kh, vh)
-    return out.transpose(2, 0, 1, 3).reshape(T, n_head * Dv)
+    )(jnp.asarray(start, jnp.int32).reshape(1), *operands, qh, kh, vh)
+    return out.transpose(2, 0, 1, 3).reshape(Tq, n_head * Dv)
 
 
-def prefill_attention(q, k, v, sink, n_head, n_kv_head, scale, window):
-    """The kernel where ``T`` is whole blocks, else the composed form."""
+def prefill_attention(q, k, v, sink, n_head, n_kv_head, scale, window,
+                      start=0, before=None, interpret=None):
+    """The kernel where the rows are whole blocks, else the composed
+    form; arguments as ``flash_attention``'s (``interpret`` None: as the
+    backend has it)."""
     from paddle_tpu.ops.attention_ops import _use_interpret
-    if flash_blocks(q.shape[0], n_head // n_kv_head, window) is None:
-        return composed_attention(q, k, v, n_head, n_kv_head, scale,
-                                  window, sink)
-    return flash_attention(q, k, v, sink, n_head=n_head,
-                           n_kv_head=n_kv_head, scale=scale, window=window,
-                           interpret=_use_interpret())
+    if flash_blocks(q.shape[0], n_head // n_kv_head, window,
+                    keys=k.shape[0]) is not None:
+        return flash_attention(
+            q, k, v, sink, start, before, n_head=n_head,
+            n_kv_head=n_kv_head, scale=scale, window=window,
+            interpret=_use_interpret() if interpret is None else interpret)
+    first = 0
+    if before is not None:
+        start = before[0].shape[0]
+        k, v, first = _led(k, v, before)
+    return composed_attention(q, k, v, n_head, n_kv_head, scale, window,
+                              sink, start, first)
 
 
 # ---------------------------------------------------------------------------
 # the ring: a window layer's bounded per-slot cache
 # ---------------------------------------------------------------------------
 
+def ring_after(ring, rows, start, n):
+    """A slot's ring ``[R, W]`` once the first ``n`` of ``rows`` [T, W],
+    which stand at positions ``start ..``, have gone through it:
+    position ``p`` at row ``p mod R`` for the last ``min(n, R)`` of
+    them, the ring's own row elsewhere (``n`` = 0: all of it)."""
+    R = ring.shape[0]
+    r = jnp.arange(R, dtype=jnp.int32)
+    last = start + n - 1
+    p = last - jnp.mod(last - r, R)
+    taken = jnp.take(rows, jnp.clip(p - start, 0, rows.shape[0] - 1), axis=0)
+    return jnp.where((p >= start)[:, None], taken.astype(ring.dtype), ring)
+
+
 def ring_of(rows, last, ring):
     """``rows`` [T, W] a prompt's K (or V) rows; ``last`` the position of
     its last real row -> [ring, W]: position ``p`` at row ``p mod ring``
     for the last ``min(last + 1, ring)`` positions, zeros elsewhere."""
-    r = jnp.arange(ring, dtype=jnp.int32)
-    p = last - jnp.mod(last - r, ring)
-    taken = jnp.take(rows, jnp.maximum(p, 0), axis=0)
-    return jnp.where((p >= 0)[:, None], taken, 0).astype(rows.dtype)
+    return ring_after(jnp.zeros((ring, rows.shape[-1]), rows.dtype), rows,
+                      0, last + 1)
+
+
+def ring_lead(ring, start, rows):
+    """The ``rows`` rows that stand before position ``start``, in order,
+    out of a slot's ring ``[R, W]``, and how many of them are real (the
+    last ones: a position under 0 or one the ring no longer holds reads
+    zeros): ``([rows, W], n)``."""
+    R = ring.shape[0]
+    p = start - rows + jnp.arange(rows, dtype=jnp.int32)
+    real = (p >= 0) & (p >= start - R)
+    taken = jnp.take(ring, jnp.mod(p, R), axis=0)
+    return jnp.where(real[:, None], taken, 0), jnp.minimum(
+        jnp.minimum(start, R), rows)
 
 
 def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink):
@@ -499,22 +598,65 @@ def _infer_prefill(op, block):
     out = block.var(op.output("Out")[0])
     out.shape = tuple(q.shape[:-1]) + (v.shape[-1] // n_kv * n_head,)
     out.dtype = q.dtype
-    for slot, src in (("KRing", "K"), ("VRing", "V")):
-        if op.output(slot):
-            x = block.var(op.input(src)[0])
-            ring = block.var(op.output(slot)[0])
-            ring.shape = (1, int(op.attr("ring")), x.shape[-1])
-            ring.dtype = x.dtype
+    # KRingOut/VRingOut and KCacheOut/VCacheOut alias the persistable
+    # rings and pools (in-place update)
 
 
-def _prefill_lower(ctx, window):
-    q, k, v = ctx.input("Q")[0], ctx.input("K")[0], ctx.input("V")[0]
+def _qkv(ctx):
     sink = ctx.input("Sink") if ctx.has_input("Sink") else None
-    out = prefill_attention(q, k, v, sink, int(ctx.attr("n_head")),
-                            int(ctx.attr("n_kv_head")),
-                            float(ctx.attr("scale", 1.0)), window)
-    ctx.set_output("Out", out[None])
-    return k, v
+    return (ctx.input("Q")[0], ctx.input("K")[0], ctx.input("V")[0], sink,
+            int(ctx.attr("n_head")), int(ctx.attr("n_kv_head")),
+            float(ctx.attr("scale", 1.0)))
+
+
+def _chunk_rows(ctx):
+    """``(start, n)`` of a chunk: the position of its first row and how
+    many of its rows are real (they come first)."""
+    return (ctx.input("Pos").reshape(-1)[0].astype(jnp.int32),
+            jnp.sum(ctx.input("Mask") > 0).astype(jnp.int32))
+
+
+def chunk_over_pages(q, k, v, kc, vc, table, start, real, n_head, n_kv_head,
+                     scale, interpret=None):
+    """A full layer's attention of ONE CHUNK over the slot's pages.  ``q``
+    [C, H * Dk]; ``k`` [C, Hkv * Dk]; ``v`` [C, Hkv * Dv] the chunk's
+    rows, at positions ``start ..``; ``real`` [1, C] bool (real rows
+    first); pools ``[num_pages, page_len, Hkv * D]``; ``table`` [1, P]
+    the slot's pages.  The real rows are written at their positions, then
+    the chunk attends the pages' rows ``0 ..`` under the diagonal shifted
+    by ``start``.  Returns ``(out [C, H * Dv], kc, vc)``."""
+    from paddle_tpu.ops.attention_ops import _paged_cache_update
+    kc, vc = _paged_cache_update(
+        (kc, vc), (k[None], v[None]), table,
+        (start + q.shape[0]).reshape(1, 1), row_lens=real)
+    keys, vals = (pool[table[0]].reshape(-1, pool.shape[-1])
+                  for pool in (kc, vc))
+    out = prefill_attention(q, keys.astype(k.dtype), vals.astype(v.dtype),
+                            None, n_head, n_kv_head, scale, 0, start=start,
+                            interpret=interpret)
+    return out, kc, vc
+
+
+def chunk_over_ring(q, k, v, sink, k_ring, v_ring, slot, start, n, n_head,
+                    n_kv_head, scale, window, interpret=None):
+    """A window layer's attention of ONE CHUNK whose first ``n`` rows
+    are real, over slot ``slot``'s rows of the rings ``[num_slots, R,
+    Hkv * D]``: the ring's rows before ``start`` lead the chunk's own
+    in, and the chunk's last real rows go through the ring.  Returns
+    ``(out [C, H * Dv], k_ring, v_ring)``."""
+    rings = (k_ring, v_ring)
+    own = [jax.lax.dynamic_index_in_dim(r, slot, 0, keepdims=False)
+           for r in rings]
+    rows = lead_rows(q.shape[0], n_head // n_kv_head, window)
+    (k_lead, held), (v_lead, _) = (ring_lead(r, start, rows) for r in own)
+    out = prefill_attention(
+        q, k, v, sink, n_head, n_kv_head, scale, window,
+        before=(k_lead.astype(k.dtype), v_lead.astype(v.dtype), held),
+        interpret=interpret)
+    return (out,) + tuple(
+        jax.lax.dynamic_update_index_in_dim(
+            ring, ring_after(mine, x, start, n), slot, 0)
+        for ring, mine, x in zip(rings, own, (k, v)))
 
 
 @register_op("gqa_flash_attention", infer_shape=_infer_prefill)
@@ -522,24 +664,63 @@ def gqa_flash_attention_lower(ctx):
     """A full layer's prefill.  Q [1, T, H * Dk]; K [1, T, Hkv * Dk]; V
     [1, T, Hkv * Dv]: causal (real rows first: a pad row is seen by no
     real row).  attrs n_head, n_kv_head, scale.  Out [1, T, H * Dv]."""
-    _prefill_lower(ctx, 0)
+    q, k, v, _, *heads = _qkv(ctx)
+    ctx.set_output("Out", prefill_attention(q, k, v, None, *heads, 0)[None])
+
+
+@register_op("gqa_flash_attention_chunk", infer_shape=_infer_prefill,
+             no_gradient=True, stateful_outputs=("KCacheOut", "VCacheOut"))
+def gqa_flash_attention_chunk_lower(ctx):
+    """A full layer's prefill of ONE CHUNK of a prompt, over the slot's
+    pages.  Q [1, C, H * Dk]; K [1, C, Hkv * Dk]; V [1, C, Hkv * Dv] the
+    chunk's projections; KCache / VCache [num_pages, page_len, Hkv * D]
+    persistable pools; PageTable [1, P] int32 the slot's row (P a page
+    bucket that covers the chunk's last real row); Pos [1, C] int32 the
+    rows' positions ``start .. start + C - 1``; Mask [1, C] (1 = a real
+    row, real rows first).  The real rows are written at their positions
+    of the slot's pages, over whatever was there, and the chunk's
+    queries attend rows ``0 ..`` of the pages under the diagonal shifted
+    by ``start`` (a pad row is written nowhere and seen by no real row).
+    attrs n_head, n_kv_head, scale.  Out [1, C, H * Dv];
+    KCacheOut/VCacheOut name the pools themselves."""
+    q, k, v, _, *heads = _qkv(ctx)
+    out, kc, vc = chunk_over_pages(
+        q, k, v, ctx.input("KCache"), ctx.input("VCache"),
+        ctx.input("PageTable"), _chunk_rows(ctx)[0], ctx.input("Mask") > 0,
+        *heads)
+    ctx.set_output("Out", out[None])
+    ctx.set_output("KCacheOut", kc)
+    ctx.set_output("VCacheOut", vc)
 
 
 @register_op("window_attention", infer_shape=_infer_prefill,
-             no_grad_inputs=("Last",),
-             stop_gradient_outputs=("KRing", "VRing"))
+             no_grad_inputs=("KRing", "VRing", "Slot", "Pos", "Mask"),
+             stateful_outputs=("KRingOut", "VRingOut"))
 def window_attention_lower(ctx):
     """A window layer's prefill.  Q, K, V as ``gqa_flash_attention``;
-    Sink [H] float32 (optional); Last [1, T] (optional: the one-hot of
-    the last real row).  attrs n_head, n_kv_head, scale, window, ring.
-    Out [1, T, H * Dv]; with Last, KRing [1, ring, Hkv * Dk] and VRing
-    [1, ring, Hkv * Dv]: the slot's ring after the prompt."""
-    k, v = _prefill_lower(ctx, int(ctx.attr("window")))
-    if ctx.has_input("Last"):
-        last = jnp.argmax(ctx.input("Last")[0]).astype(jnp.int32)
-        ring = int(ctx.attr("ring"))
-        ctx.set_output("KRing", ring_of(k, last, ring)[None])
-        ctx.set_output("VRing", ring_of(v, last, ring)[None])
+    Sink [H] float32 (optional).  attrs n_head, n_kv_head, scale,
+    window.  Out [1, T, H * Dv].  With no further input: a WHOLE
+    sequence, nothing cached (the training forward).
+
+    ONE CHUNK of a prompt: KRing / VRing [num_slots, ring, Hkv * D]
+    persistable; Slot [1, 1] int32; Pos and Mask as
+    ``gqa_flash_attention_chunk``.  The chunk attends the ring's rows of
+    the ``window - 1`` positions before ``start`` followed by its own,
+    and its last real rows go through the slot's ring; KRingOut /
+    VRingOut name the rings themselves."""
+    q, k, v, sink, *heads = _qkv(ctx)
+    window = int(ctx.attr("window"))
+    if not ctx.has_input("KRing"):
+        ctx.set_output("Out", prefill_attention(q, k, v, sink, *heads,
+                                                window)[None])
+        return
+    out, k_ring, v_ring = chunk_over_ring(
+        q, k, v, sink, ctx.input("KRing"), ctx.input("VRing"),
+        ctx.input("Slot").reshape(-1)[0].astype(jnp.int32),
+        *_chunk_rows(ctx), *heads, window)
+    ctx.set_output("Out", out[None])
+    ctx.set_output("KRingOut", k_ring)
+    ctx.set_output("VRingOut", v_ring)
 
 
 def _infer_step(op, block):

@@ -590,6 +590,66 @@ def test_latent_prefill_attention_compiles_for_v5e_at_sparse_widths(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("kind, pages", [("full", 64), ("full", 288),
+                                         ("window", 0)])
+def test_a_prefill_chunk_compiles_for_v5e_in_place(one_chip, kind, pages):
+    """One layer of the CHUNK prefill at the published widths, 1024 rows
+    at position ``start`` (traced): the projections, the partial rotary,
+    then a full layer's rows into the slot's pages and the key-offset
+    causal kernel over the page bucket's rows (the smallest bucket and
+    the whole slot), or a window layer's ring rows led in front of the
+    chunk, the banded kernel and the ring's update; pools and rings
+    donated.  It holds NO copy of a projection matrix (PR 40's ``W_q``
+    finding: ``rope_partial`` keeps its barrier) and no temporary of a
+    pool's size: the gathered rows of ONE slot and the head-major copies
+    around the kernel."""
+    import re
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import window_ops
+    C, S, PL = 1024, 32, 64
+    n_kv, theta = (4, 5e6) if kind == "full" else (8, 1e4)
+
+    def fn(h, wq, wk, wv, wo, pos, mask, kc, vc, where, sink):
+        q, k = jnp.matmul(h, wq), jnp.matmul(h, wk)
+        v = jnp.matmul(h, wv) * jnp.bfloat16(0.707)
+        q = window_ops.rope_partial(q, pos, 64, 64, theta, 256)[0]
+        k = window_ops.rope_partial(k, pos, n_kv, 64, theta, 256)[0]
+        start, n = pos[0, 0], jnp.sum(mask > 0).astype(jnp.int32)
+        if kind == "full":
+            ctx, kc, vc = window_ops.chunk_over_pages(
+                q, k, v[0], kc, vc, where, start, mask > 0, 64, n_kv,
+                192 ** -0.5, interpret=False)
+        else:
+            ctx, kc, vc = window_ops.chunk_over_ring(
+                q, k, v[0], sink, kc, vc, where[0, 0], start, n, 64, n_kv,
+                192 ** -0.5, 128, interpret=False)
+        return h + jnp.matmul(ctx, wo)[None], kc, vc
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    caches = [sds((S * 288, PL, n_kv * 256)), sds((S * 288, PL, n_kv * 128)),
+              sds((1, pages), jnp.int32)] if kind == "full" else \
+        [sds((S, 128, n_kv * 256)), sds((S, 128, n_kv * 128)),
+         sds((1, 1), jnp.int32)]
+    compiled = jax.jit(fn, donate_argnums=(7, 8)).lower(
+        sds((1, C, 4096)), sds((4096, 64 * 192)), sds((4096, n_kv * 192)),
+        sds((4096, n_kv * 128)), sds((64 * 128, 4096)),
+        sds((1, C), jnp.int32), sds((1, C), jnp.float32), *caches,
+        sds((64,), jnp.float32)).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in hlo
+    # in place: both caches alias their outputs
+    assert memory.alias_size_in_bytes >= sum(
+        int(jnp.prod(jnp.asarray(c.shape))) * 2 for c in caches[:2])
+    matrices = {"4096,12288", "12288,4096", f"4096,{n_kv * 192}",
+                "8192,4096", "4096,8192"}
+    copied = [m for m in re.findall(r"= bf16\[([0-9,]+)\]\S* copy\(", hlo)
+              if m in matrices]
+    assert not copied, copied
+    assert memory.temp_size_in_bytes < 160 << 20, memory
+
+
 # -- sliding-window / full attention at key heads of 192 (stored 256) and
 # value heads of 128 (``ops/window_ops.py``; ``models/window_moe.py``) ------
 

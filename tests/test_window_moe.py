@@ -260,8 +260,124 @@ def test_the_reference_without_a_mechanism_is_not_the_program(
         assert _err(got[j], other[j]) > 50 * TOL, (what, j)
 
 
+# -- a prompt as a run of chunks against the same prompt in one -----------------
+
+@pytest.fixture(scope="module")
+def chunked(tmp_path_factory, cfg, weights):
+    """The bundle with chunk rungs of 8 and 16 rows (``predictor``'s are
+    24 and 48: every prompt here is ONE chunk there): a prompt of 45
+    rows is three chunks, wraps the 8-row ring twice inside each, and
+    walks page buckets of 2, 4 and 8 pages."""
+    path = str(tmp_path_factory.mktemp("win") / "chunked")
+    was, window_moe.CHUNK_ROWS = window_moe.CHUNK_ROWS, 16
+    try:
+        window_moe.export_window_model(path, _hp(cfg), num_slots=SLOTS,
+                                       prompt_buckets=BUCKETS,
+                                       page_len=PAGE_LEN)
+    finally:
+        window_moe.CHUNK_ROWS = was
+    p = GenPredictor(path)
+    assert p.prefill_chunks == [8, 16]
+    _install(p, weights)
+    p.warmup()
+    return p
+
+
+@pytest.mark.parametrize("n", [5, 16, 17, 29, 45], ids=[
+    "one_chunk", "a_chunk_edge", "a_row_past_it", "the_ring_wraps",
+    "every_page_bucket"])
+def test_a_prompt_in_chunks_is_the_prompt_in_one(predictor, chunked, n):
+    """The last row's logits, every full layer's page rows, every ring
+    and the next cached step's logits, chunk by chunk (8- and 16-row
+    rungs) against the single pass (one 24- or 48-row chunk)."""
+    prompt = _prompt(n, seed=100 + n)
+    spans = chunked.chunk_spans(n)
+    assert len(spans) == -(-n // 16) and len(predictor.chunk_spans(n)) == 1
+    assert [chunked._chunk_shape(a, b - a) for a, b in spans][-1] \
+        == (8 if (n - 1) % 16 < 8 else 16,
+            next(p for p in (1, 2, 4, 8) if p * PAGE_LEN >= n))
+    whole, parts = predictor.prefill(prompt), chunked.prefill(prompt)
+    assert np.abs(parts[0] - whole[0]).max() < 2e-5 * np.ptp(whole[0])
+    assert len(parts[1]) == len(whole[1]) == 2 * 2 + 2 * 5
+    # (a ring row no position of this prompt landed in keeps what the
+    # borrowed slot held before: nothing reads it)
+    landed = sorted({p % WINDOW for p in range(max(n - WINDOW, 0), n)})
+    for j, (got, want) in enumerate(zip(parts[1], whole[1])):
+        assert got.shape == want.shape
+        rows = slice(None) if j < 4 else landed
+        assert np.allclose(got[0, rows], want[0, rows], atol=2e-5)
+    # pages hold the prompt's rows and zeros behind; a ring the last 8
+    assert np.asarray(parts[1][0])[0, :n].any(axis=-1).all()
+    assert not np.asarray(parts[1][0])[0, n:].any()
+    tok, steps = int(np.argmax(whole[0])), []
+    for p, (_, kv) in ((predictor, whole), (chunked, parts)):
+        p.alloc_slot_pages(1, p.pages_needed(n, 2))
+        try:
+            assert p.write_slot(1, kv, n) == 0
+            steps.append(_step(p, {1: (tok, n)})[1])
+        finally:
+            p.free_slot_pages(1)
+    assert np.abs(steps[1] - steps[0]).max() < 2e-5 * np.ptp(steps[0])
+    # nothing was left allocated by either borrowing prefill
+    assert chunked.free_pages == chunked.num_pages
+
+
+def test_streams_admitted_in_chunks_decode_where_the_chunks_wrote(
+        predictor, chunked, weights, cfg):
+    """Through the scheduler nothing seeds the slot: the chunks write
+    pages and rings, the decode steps read them.  Three streams of 45,
+    17 and 5 rows, admitted beside each other, emit the tokens of the
+    reference's greedy forward; one traced admission of N chunks yields
+    N ``gen.prefill`` spans whose pairs sum to the prompt's."""
+    from paddle_tpu.gen import GenScheduler
+    from paddle_tpu.obs import trace as ptrace
+    names = ["gen.prefill.chunks", "gen.prefill.rows", "gen.prefill.pad_rows",
+             "gen.prefill.admissions_chunked", "gen.seed.compiled_calls"]
+    before = [profiler.runtime_metrics.counter(n) for n in names]
+    prompts = [_prompt(n, seed=200 + n) for n in (45, 17, 5)]
+    sched = GenScheduler(chunked)
+    ptrace.enable(1 << 14)
+    ptrace.clear()
+    try:
+        streams = []
+        for i, p in enumerate(prompts):
+            with ptrace.trace_context(f"request-{i}"):
+                streams.append(sched.submit(p, max_new_tokens=5))
+        served = [list(s) for s in streams]
+        spans = ptrace.snapshot_spans()
+    finally:
+        ptrace.disable()
+        sched.close()
+    for prompt, tokens in zip(prompts, served):
+        ids = prompt + tokens
+        want = _ref_logits(weights, cfg, ids,
+                           list(range(len(prompt) - 1, len(ids) - 1)))
+        assert tokens == [int(t) for t in np.argmax(want, axis=-1)]
+    after = [profiler.runtime_metrics.counter(n) for n in names]
+    # 3 + 2 + 1 chunks; 45 = 16 + 16 + 13 (of 16), 17 = 16 + 1 (of 8),
+    # 5 (of 8): 67 real rows and 3 + 7 + 3 pads; and no compiled seed
+    assert [b - a for a, b in zip(before, after)] == [6, 67, 13, 3, 0]
+    by_trace = {}
+    for s in spans:
+        if s["name"] == "gen.prefill":
+            by_trace.setdefault(s["trace_id"], []).append(s["attrs"])
+    chunks = by_trace[streams[0].trace_id]
+    assert [(c["start"], c["tokens"], c["rows"]) for c in chunks] \
+        == [(0, 16, 16), (16, 16, 16), (32, 13, 16)]
+    assert sum(c["causal_pairs"] for c in chunks) == 45 * 46 // 2
+    assert sum(c["band_pairs"] for c in chunks) == 36 + (45 - 8) * 8
+    assert [c["pages"] for c in chunks] == [2, 4, 8]
+    admits = [s for s in spans if s["name"] == "gen.admit"]
+    assert sorted(s["attrs"]["chunks"] for s in admits) == [1, 2, 3]
+    seeds = [s["attrs"] for s in spans if s["name"] == "gen.seed_slot"]
+    assert [a["compiled_calls"] for a in seeds] == [0, 0, 0]
+    assert chunked.free_pages == chunked.num_pages
+
+
 # -- the kernels in interpret mode against the composed forms ----------------------
 
+@pytest.mark.parametrize("start", [0, WINDOW - 1, WINDOW, 3 * 64 + 17],
+                         ids=["whole", "under_window", "a_window", "far"])
 @pytest.mark.parametrize("window, sink, hkv, dk, blocks", [
     (8, True, 2, 24, (16, 8)),      # the band, two key blocks a query block
     (8, True, 2, 32, (32, 8)),      # four own blocks and the lead-in
@@ -272,22 +388,77 @@ def test_the_reference_without_a_mechanism_is_not_the_program(
 ], ids=["band", "band_wide", "band_odd", "band_no_sink", "causal",
         "causal_wide"])
 def test_the_flash_kernel_is_the_composed_attention(window, sink, hkv, dk,
-                                                    blocks):
+                                                    blocks, start):
+    """A chunk of 64 rows at position ``start`` of a sequence (0: a whole
+    prompt) against the composed attention over the WHOLE sequence: the
+    causal kernel over all the keys with its diagonal shifted by
+    ``start`` (pad keys behind them), the banded one with the rows
+    before the chunk led in from where a ring would hold them."""
     rng = np.random.RandomState(window + dk)
-    T, H, dv = 64, 4, 16
+    C, H, dv = 64, 4, 16
+    T = start + C
     q, k, v = (jnp.asarray(rng.randn(T, w), jnp.float32)
                for w in (H * dk, hkv * dk, hkv * dv))
     b = jnp.asarray(rng.randn(H), jnp.float32) if sink else None
     want = window_ops.composed_attention(q, k, v, H, hkv, 0.2, window, b)
-    got = window_ops.flash_attention(
-        q, k, v, b, n_head=H, n_kv_head=hkv, scale=0.2, window=window,
-        interpret=True, blocks=blocks)
-    assert np.allclose(got, want, atol=2e-5)
+    kernel = dict(n_head=H, n_kv_head=hkv, scale=0.2, window=window,
+                  interpret=True, blocks=blocks)
+    if window:
+        lead = -(-(window - 1) // blocks[1]) * blocks[1]
+        before = [jnp.concatenate([jnp.zeros((lead, x.shape[1])), x])
+                  [start:start + lead] for x in (k, v)]
+        got = window_ops.flash_attention(
+            q[start:], k[start:], v[start:], b,
+            before=(*before, min(start, lead)), **kernel)
+    else:
+        # whole key blocks, and one more that no row may see
+        pad = -T % blocks[1] + blocks[1]
+        keys, vals = (jnp.concatenate([x, jnp.full((pad, x.shape[1]), 9.0)])
+                      for x in (k, v))
+        got = window_ops.flash_attention(q[start:], keys, vals, None,
+                                         jnp.int32(start), **kernel)
+    assert np.allclose(got, want[start:], atol=2e-5)
     # the composed form is the reference's: rows see what it says
     if window:
         alone = window_ops.composed_attention(
             q[-window:], k[-window:], v[-window:], H, hkv, 0.2, window, b)
         assert np.allclose(got[-1], alone[-1], atol=2e-5)
+    # and its own chunk form is the kernel's
+    if window:
+        again = window_ops.composed_attention(
+            q[start:], jnp.concatenate([before[0], k[start:]]),
+            jnp.concatenate([before[1], v[start:]]), H, hkv, 0.2, window, b,
+            start=lead, first=lead - min(start, lead))
+    else:
+        again = window_ops.composed_attention(q[start:], keys, vals, H, hkv,
+                                              0.2, 0, None, start=start)
+    assert np.allclose(again, want[start:], atol=2e-5)
+
+
+def test_a_ring_leads_a_chunk_in_and_takes_its_last_rows():
+    """Chunks of 5, 16, 3, 1 and 11 real rows (of 16 run) through a ring
+    of 8: before each, ``ring_lead`` hands out the 7 rows before the
+    chunk in order (zeros before position 0) and says how many are real;
+    after each, the ring is what ``ring_of`` makes of the whole prefix;
+    pad rows go nowhere."""
+    R, W = 8, 3
+    rows = jnp.arange(40 * W, dtype=jnp.float32).reshape(40, W) + 1
+    ring, pos = jnp.zeros((R, W)), 0
+    for n in (5, 16, 3, 1, 11):
+        lead, real = window_ops.ring_lead(ring, pos, R - 1)
+        assert np.array_equal(lead, jnp.concatenate(
+            [jnp.zeros((R - 1, W)), rows])[pos:pos + R - 1])
+        assert int(real) == min(pos, R - 1)
+        chunk = jnp.concatenate([rows[pos:pos + n],
+                                 jnp.full((16 - n, W), -1.0)])
+        ring = window_ops.ring_after(ring, chunk, pos, n)
+        pos += n
+        assert np.array_equal(ring, window_ops.ring_of(rows, pos - 1, R))
+    assert np.array_equal(window_ops.ring_after(ring, chunk, pos, 0), ring)
+    # a lead longer than the ring holds: what fell out reads zeros
+    lead, real = window_ops.ring_lead(ring, pos, 12)
+    assert int(real) == R and not np.asarray(lead[:4]).any()
+    assert np.array_equal(lead[4:], rows[pos - R:pos])
 
 
 def test_the_band_computes_the_blocks_that_meet_it_and_no_more():
@@ -300,6 +471,18 @@ def test_the_band_computes_the_blocks_that_meet_it_and_no_more():
     blocks, rows = window_ops.key_blocks_computed(16384, 16, 0)
     assert (blocks, rows) == (4 * sum(range(1, 33)), 512)
     assert window_ops.key_blocks_computed(40, 8, 128) == (0, 0)
+    # a chunk of 1024 rows: at position 0 its first query block has no
+    # lead-in, further on every block has; a full layer's walks the keys
+    # to its own diagonal, shifted by where it stands
+    assert window_ops.key_blocks_computed(1024, 8, 128, start=0)[0] == 11
+    assert window_ops.key_blocks_computed(1024, 8, 128, start=64)[0] == 12
+    assert window_ops.key_blocks_computed(1024, 8, 128, start=4096)[0] == 12
+    assert window_ops.key_blocks_computed(1024, 16, 0, start=0, keys=4096) \
+        == (4 * (1 + 2), 512)
+    assert window_ops.key_blocks_computed(
+        1024, 16, 0, start=8192, keys=12288)[0] == 4 * (17 + 18)
+    assert window_ops.lead_rows(1024, 8, 128) == 128
+    assert window_ops.lead_rows(40, 8, 128) == 127
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -471,8 +654,9 @@ def test_the_bundle_checks_and_every_new_op_has_its_rules(predictor):
     from paddle_tpu.analysis.analyzer import lint_program
     from paddle_tpu.analysis.distributed import load_saved_program
     new = {"rope_partial", "window_attention", "window_attention_step",
-           "gqa_flash_attention"}
+           "gqa_flash_attention_chunk"}
     assert new <= set(typecheck._RULES) and new <= cost.covered_op_types()
+    assert "gqa_flash_attention" in cost.covered_op_types()
     bundle_dir = predictor.bundle_dir
     pre = load_saved_program(os.path.join(bundle_dir, "prefill"))
     dec = load_saved_program(os.path.join(bundle_dir, "decode"))
@@ -500,25 +684,41 @@ def test_the_bundle_checks_and_every_new_op_has_its_rules(predictor):
     assert flops(40, "paged_attention") == 2 * 40 * a_row
     assert flops(20, "paged_attention") * 2 == flops(40, "paged_attention")
     assert not cost.estimate(dec[0], paged_live_rows=24).uncovered
-    # the prefill: a band grows with the rows, the triangle with their
-    # square
+    # a chunk of the prefill: a band grows with its rows, a full layer's
+    # pairs with its rows AND the rows of the page bucket under them
     block = pre[0].global_block()
-    saved = {n: block.var(n).shape for n in pre[1]}
 
-    def prefill_flops(rows, op_type):
-        for n in pre[1]:
-            block.var(n).shape = (1, rows)
-        try:
-            return cost.estimate(pre[0]).by_op_type()[op_type]["flops"]
-        finally:
-            for n, shape in saved.items():
-                block.var(n).shape = shape
+    def chunk_report(rows, pages):
+        return cost.estimate_at(pre[0], {
+            n: [d if d >= 0 else pages if n == "gen_page_table" else rows
+                for d in block.var(n).shape] for n in pre[1]})
+
+    def chunk_flops(rows, pages, op_type):
+        return chunk_report(rows, pages).by_op_type()[op_type]["flops"]
 
     a_pair = 2 * 4 * (stored + 16)
-    assert prefill_flops(32, "window_attention") == 5 * 32 * 8 * a_pair
-    assert prefill_flops(64, "window_attention") == 5 * 64 * 8 * a_pair
-    assert prefill_flops(32, "gqa_flash_attention") \
+    assert chunk_flops(32, 4, "window_attention") == 5 * 32 * 8 * a_pair
+    assert chunk_flops(64, 8, "window_attention") == 5 * 64 * 8 * a_pair
+    assert chunk_flops(32, 4, "gqa_flash_attention_chunk") \
         == 2 * (32 * 33 // 2) * a_pair
+    assert chunk_flops(16, 6, "gqa_flash_attention_chunk") \
+        == 2 * (16 * 17 // 2 + 16 * 32) * a_pair
+    # and it is charged the rows it touches (its own twice, the page
+    # bucket's once), not the pools whole
+    report = chunk_report(16, 6)
+    assert report.by_op_type()["gqa_flash_attention_chunk"]["bytes"] == 2 * 4 \
+        * (16 * 4 * (stored + 16) + (2 * 16 + 48) * (stored + 16))
+    # the predictor prices a chunk by (rung, page bucket) from the same
+    # rules, and a prompt by its chunks
+    assert predictor.prefill_chunks == meta["prefill_chunks"] == [24, 48]
+    assert predictor._chunk_shape(0, 20) == predictor._chunk_shape(0, 24) \
+        == (24, 4)
+    assert predictor.chunk_cost(0, 20) == predictor.chunk_cost(0, 24) \
+        == float(chunk_report(24, 4).total_flops)
+    assert predictor._chunk_shape(24, 30) == (48, 8)
+    assert predictor.chunk_cost(24, 30) \
+        == float(chunk_report(48, 8).total_flops)
+    assert predictor.prefill_cost(40) == predictor.chunk_cost(0, 40)
 
 
 @pytest.mark.parametrize("fault", ["paged_by_length", "not_a_state",
@@ -552,6 +752,36 @@ def test_a_window_layer_paged_by_the_streams_length_is_refused(predictor,
     assert any(want in m for m in found), found
 
 
+@pytest.mark.parametrize("fault", ["not_whole_pages", "fetches_rows",
+                                   "no_slot_feed", "another_pool"])
+def test_a_chunk_prefill_that_cannot_continue_a_slot_is_refused(predictor,
+                                                                fault):
+    from paddle_tpu.analysis import check_gen_bundle
+    from paddle_tpu.analysis.distributed import load_saved_program
+    bundle_dir = predictor.bundle_dir
+    prog, feeds, fetches = load_saved_program(
+        os.path.join(bundle_dir, "prefill"))
+    dec = load_saved_program(os.path.join(bundle_dir, "decode"))
+    with open(os.path.join(bundle_dir, "gen_meta.json")) as f:
+        meta = json.load(f)
+    if fault == "not_whole_pages":
+        meta["prefill_chunks"] = [12, 48]
+        want = "multiples of page_len 8"
+    elif fault == "fetches_rows":
+        fetches = list(fetches) + ["win0_paged_k"]
+        want = "not the logits alone"
+    elif fault == "no_slot_feed":
+        feeds = [n for n in feeds if n != "gen_slot"]
+        want = "does not feed `gen_slot`"
+    else:
+        var = prog.global_block().var("win0_paged_v")
+        var.shape = (var.shape[0] + 1,) + tuple(var.shape[1:])
+        want = "would not share one array"
+    found = [d.message for d in check_gen_bundle((prog, feeds, fetches), dec,
+                                                 meta) if d.code == "PTA019"]
+    assert any(want in m for m in found), found
+
+
 def test_mismatched_heads_and_rings_are_type_errors():
     from paddle_tpu.analysis.analyzer import lint_program
     from paddle_tpu.models.hybrid_moe import _data, _op
@@ -581,6 +811,41 @@ def test_mismatched_heads_and_rings_are_type_errors():
     assert "lanes a row (V's)" in messages          # 40 for 32
     assert "must be an integer" in messages
     assert "sink logits" in messages
+    # one chunk of a prefill over the same caches is held to them too
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        q, k, v = (_data(n, [1, 16, w]) for n, w in
+                   (("q", 4 * 24), ("k", 2 * 24), ("v", 2 * 16)))
+        pos, mask = _data("pos", [1, 16]), _data("mask", [1, 16])
+        slot = _data("slot", [1, 1], "int32")
+        table = _data("table", [1, 4], "int32")
+        block = main.global_block()
+        held = {}
+        for name, shape in (("rk", [2, 4, 48]), ("rv", [2, 8, 32]),
+                            ("pk", [6, 8, 48]), ("pv", [6, 8, 40])):
+            held[name] = block.create_var(name=name, shape=shape,
+                                          dtype="float32")
+            held[name].persistable = True
+        attrs = {"n_head": 4, "n_kv_head": 2, "scale": 1.0}
+        band = _op("window_attention",
+                   {"Q": q, "K": k, "V": v, "KRing": held["rk"],
+                    "VRing": held["rv"], "Slot": slot, "Pos": pos,
+                    "Mask": mask},
+                   {"Out": "float32", "KRingOut": held["rk"],
+                    "VRingOut": held["rv"]}, {**attrs, "window": 8})["Out"]
+        full = _op("gqa_flash_attention_chunk",
+                   {"Q": q, "K": k, "V": v, "KCache": held["pk"],
+                    "VCache": held["pv"], "PageTable": table, "Pos": pos,
+                    "Mask": mask},
+                   {"Out": "float32", "KCacheOut": held["pk"],
+                    "VCacheOut": held["pv"]}, attrs)["Out"]
+    result = lint_program(
+        main, feed_names=["q", "k", "v", "pos", "mask", "slot", "table"],
+        fetch_names=[band.name, full.name])
+    messages = " | ".join(d.message for d in result.errors)
+    assert "KRing holds 4 rows a slot, fewer than the window" in messages
+    assert "VCache" in messages and "lanes a row (V's)" in messages
+    assert "Pos `pos` must be an integer" in messages
 
 
 # -- the share, the zoo, the published keys -----------------------------------------
@@ -637,7 +902,7 @@ def test_a_sink_on_a_full_layer_is_refused_not_dropped(cfg):
     hp = _hp(dict(cfg, add_full_attention_sink_bias=True))
     with fluid.program_guard(fluid.Program(), fluid.Program()):
         with pytest.raises(NotImplementedError, match="sink"):
-            window_moe.build_prefill_program(hp)
+            window_moe.build_chunk_program(hp, SLOTS, PAGE_LEN, 24)
     short = _hp(cfg)
     short.ring = 4
     with pytest.raises(ValueError, match="cannot hold a window"):
