@@ -4,13 +4,13 @@ machine_translation/transformer, stacked_dynamic_lstm) — re-built on the
 TPU-native layers API."""
 
 from paddle_tpu.models import (resnet, transformer, vgg, mnist,
-                               seq2seq, stacked_lstm, gen_lm,
+                               seq2seq, stacked_lstm, decoder, gen_lm,
                                gen_lm_long, wide_and_deep, hybrid_moe,
                                latent_moe, latent_moe_sparse, block_moe,
                                window_moe)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
-           "seq2seq", "stacked_lstm", "gen_lm", "gen_lm_long",
+           "seq2seq", "stacked_lstm", "decoder", "gen_lm", "gen_lm_long",
            "wide_and_deep", "hybrid_moe", "latent_moe",
            "latent_moe_sparse", "block_moe", "window_moe", "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
@@ -22,6 +22,29 @@ ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
               "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
               "hybrid_moe", "latent_moe", "latent_moe_sparse", "block_moe",
               "window_moe")
+
+
+#: the serving decoders' entries: name -> (configuration class, its
+#: teacher-forced ``(seq_len, hp)`` train program over 16 rows)
+_DECODERS = {
+    # one layer of each kind (mixer, attention, experts)
+    "hybrid_moe": (hybrid_moe.HybridConfig,
+                   hybrid_moe.hybrid_moe_train_program),
+    # a dense and two expert layers
+    "latent_moe": (latent_moe.LatentMoEConfig,
+                   latent_moe.latent_moe_train_program),
+    # the same under learned sparse attention: 16 rows of which a row
+    # attends 4, chosen by the first layer's indexer
+    "latent_moe_sparse": (latent_moe_sparse.SparseLatentConfig,
+                          latent_moe_sparse.latent_moe_sparse_train_program),
+    # two layers under the block-causal mask
+    "block_moe": (block_moe.BlockMoEConfig,
+                  block_moe.block_moe_train_program),
+    # a dense full layer and two expert window layers (window 8 of 16
+    # rows, a sink a head)
+    "window_moe": (window_moe.WindowMoEConfig,
+                   window_moe.window_moe_train_program),
+}
 
 
 def build_train_program(name, backward=True):
@@ -86,39 +109,13 @@ def build_train_program(name, backward=True):
             hp.d_head = 8
             cost, feeds = gen_lm_long.gen_lm_long_train_program(2, 16, hp)
             fetches = [cost.name]
-        elif name == "hybrid_moe":
-            # one layer of each kind at toy widths, float32 (training
-            # keeps float32 parameters; the serving bundle's are bfloat16)
-            hp = hybrid_moe.HybridConfig()
+        elif name in _DECODERS:
+            # at the configuration's toy widths, float32 (training keeps
+            # float32 parameters; the serving bundle's are bfloat16)
+            config, train_program = _DECODERS[name]
+            hp = config()
             hp.dtype = "float32"
-            cost, feeds = hybrid_moe.hybrid_moe_train_program(16, hp)
-            fetches = [cost.name]
-        elif name == "latent_moe":
-            # a dense and two expert layers at toy widths, float32
-            hp = latent_moe.LatentMoEConfig()
-            hp.dtype = "float32"
-            cost, feeds = latent_moe.latent_moe_train_program(16, hp)
-            fetches = [cost.name]
-        elif name == "latent_moe_sparse":
-            # the same under learned sparse attention: 16 rows of which a
-            # row attends 4, chosen by the first layer's indexer
-            hp = latent_moe_sparse.SparseLatentConfig()
-            hp.dtype = "float32"
-            cost, feeds = latent_moe_sparse.latent_moe_sparse_train_program(
-                16, hp)
-            fetches = [cost.name]
-        elif name == "block_moe":
-            # two layers at toy widths under the block-causal mask, float32
-            hp = block_moe.BlockMoEConfig()
-            hp.dtype = "float32"
-            cost, feeds = block_moe.block_moe_train_program(16, hp)
-            fetches = [cost.name]
-        elif name == "window_moe":
-            # a dense full layer and two expert window layers at toy
-            # widths (window 8 of 16 rows, a sink a head), float32
-            hp = window_moe.WindowMoEConfig()
-            hp.dtype = "float32"
-            cost, feeds = window_moe.window_moe_train_program(16, hp)
+            cost, feeds = train_program(16, hp)
             fetches = [cost.name]
         else:
             raise ValueError(

@@ -55,16 +55,14 @@ view of this model).
 
 from __future__ import annotations
 
-import json
-import os
-
-import numpy as np
-
 import paddle_tpu.layers as layers
-from paddle_tpu.models.gen_lm import (META_FILENAME, PAGE_LEN_DEFAULT,
-                                      _write_model, default_page_buckets)
-from paddle_tpu.models.hybrid_moe import (DECODE_STATS, _data, _embed,
-                                          _logits, _matrix, _op, _rms)
+from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
+                                       DecoderConfig, decode_fetches,
+                                       decode_inputs, decoder_layer, embed,
+                                       export_bundle, last_row, logits,
+                                       matrix, op, persistable,
+                                       prefill_inputs, rms, routed_experts,
+                                       train_inputs, train_loss)
 
 __all__ = ["BlockMoEConfig", "build_prefill_program",
            "build_paged_decode_program", "block_moe_train_program",
@@ -74,7 +72,7 @@ __all__ = ["BlockMoEConfig", "build_prefill_program",
 STATE_VAR = "blk_block_tokens"
 
 
-class BlockMoEConfig:
+class BlockMoEConfig(DecoderConfig):
     """Toy-scale defaults; ``from_dict`` takes the published keys of an
     ``sdar_moe`` ``config.json`` and the generation settings its
     ``generation`` code takes (``block_length``, ``mask_token_id``)."""
@@ -98,15 +96,6 @@ class BlockMoEConfig:
 
     _KEYS = {"rms_norm_eps": "eps"}
 
-    @classmethod
-    def from_dict(cls, cfg):
-        hp = cls()
-        for key, value in cfg.items():
-            name = cls._KEYS.get(key, key)
-            if hasattr(cls, name) and not name.startswith("_"):
-                setattr(hp, name, value)
-        return hp
-
 
 def paged_cache_var_names(hp):
     """Page-pool tensors, (k, v) a layer, in layer order."""
@@ -118,7 +107,7 @@ def _head_norm(x, name, hp, n_head):
     """RMSNorm over each head's ``head_dim`` lanes, one scale a layer."""
     D = int(hp.head_dim)
     lead = [int(d) for d in x.shape[:-1]]
-    rows = _rms(layers.reshape(x, shape=[-1, D]), name, hp)
+    rows = rms(layers.reshape(x, shape=[-1, D]), name, hp)
     return layers.reshape(rows, shape=lead + [n_head * D])
 
 
@@ -130,15 +119,15 @@ def _attention(h, hp, i, pos, mask=None, paged=None):
     H, Hkv, D = (int(hp.num_attention_heads), int(hp.num_key_value_heads),
                  int(hp.head_dim))
     rope = {"rope_dim": D, "theta": float(hp.rope_theta)}
-    q = layers.matmul(h, _matrix(hp, f"blk{i}_q.w", [d, H * D]))
-    k = layers.matmul(h, _matrix(hp, f"blk{i}_k.w", [d, Hkv * D]))
-    v = layers.matmul(h, _matrix(hp, f"blk{i}_v.w", [d, Hkv * D]))
-    q = _op("rope", {"X": _head_norm(q, f"blk{i}_qnorm.scale", hp, H),
-                     "Pos": pos}, {"Out": hp.dtype},
-            {"n_head": H, **rope})["Out"]
-    k = _op("rope", {"X": _head_norm(k, f"blk{i}_knorm.scale", hp, Hkv),
-                     "Pos": pos}, {"Out": hp.dtype},
-            {"n_head": Hkv, **rope})["Out"]
+    q = layers.matmul(h, matrix(hp, f"blk{i}_q.w", [d, H * D]))
+    k = layers.matmul(h, matrix(hp, f"blk{i}_k.w", [d, Hkv * D]))
+    v = layers.matmul(h, matrix(hp, f"blk{i}_v.w", [d, Hkv * D]))
+    q = op("rope", {"X": _head_norm(q, f"blk{i}_qnorm.scale", hp, H),
+                    "Pos": pos}, {"Out": hp.dtype},
+           {"n_head": H, **rope})["Out"]
+    k = op("rope", {"X": _head_norm(k, f"blk{i}_knorm.scale", hp, Hkv),
+                    "Pos": pos}, {"Out": hp.dtype},
+           {"n_head": Hkv, **rope})["Out"]
     attrs = {"n_head": H, "n_kv_head": Hkv, "scale": float(D) ** -0.5}
     kv = []
     if paged is None:
@@ -146,52 +135,34 @@ def _attention(h, hp, i, pos, mask=None, paged=None):
         k = layers.elementwise_mul(k, mask_t, axis=0)
         v = layers.elementwise_mul(v, mask_t, axis=0)
         kv = [k, v]
-        ctx = _op("gqa_attention", {"Q": q, "K": k, "V": v, "Mask": mask},
-                  {"Out": hp.dtype},
-                  {**attrs, "block": int(hp.block_length)})["Out"]
+        ctx = op("gqa_attention", {"Q": q, "K": k, "V": v, "Mask": mask},
+                 {"Out": hp.dtype},
+                 {**attrs, "block": int(hp.block_length)})["Out"]
     else:
         pk, pv, page_table, end, row_lens = paged
-        ctx = _op("paged_attention",
-                  {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
-                   "PageTable": page_table, "Lens": end,
-                   "RowLens": row_lens},
-                  {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
-                  attrs)["Out"]
-    return layers.matmul(ctx, _matrix(hp, f"blk{i}_o.w", [H * D, d])), kv
-
-
-def _moe(h, hp, i, lens):
-    """The routed experts and their stats.  ``lens`` [rows, 1] int32: a
-    row with 0 (a free slot's, a pad row) has no assignment."""
-    d, E = int(hp.hidden_size), int(hp.num_experts)
-    F = int(hp.moe_intermediate_size)
-    route = _op("moe_route",
-                {"X": h, "W": _matrix(hp, f"blk{i}_gate.w", [d, E])},
-                {"TopkIdx": "int32", "TopkWeight": "float32"},
-                {"top_k": int(hp.num_experts_per_tok), "scaling": 1.0,
-                 "norm_topk": bool(hp.norm_topk_prob),
-                 "scoring": "softmax"})
-    routed = _op("moe_experts_gated",
-                 {"X": h, "TopkIdx": route["TopkIdx"],
-                  "TopkWeight": route["TopkWeight"],
-                  "Wg": _matrix(hp, f"blk{i}_wg", [E, d, F]),
-                  "Wu": _matrix(hp, f"blk{i}_wu", [E, d, F]),
-                  "Wd": _matrix(hp, f"blk{i}_wd", [E, F, d]),
-                  "Lens": lens},
-                 {"Out": hp.dtype, "Stats": "int32"},
-                 # every expert is held, so every assignment lands: the
-                 # sorted rows go through the grouped product in one
-                 {"expert_offset": 0, "chunk_rows": 0})
-    return routed["Out"], routed["Stats"]
+        ctx = op("paged_attention",
+                 {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
+                  "PageTable": page_table, "Lens": end,
+                  "RowLens": row_lens},
+                 {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
+                 attrs)["Out"]
+    return layers.matmul(ctx, matrix(hp, f"blk{i}_o.w", [H * D, d])), kv
 
 
 def _layer(x, hp, i, pos, lens, mask=None, paged=None):
-    """One layer; returns ``(x, [k, v] or [], stats)``."""
-    out, kv = _attention(_rms(x, f"blk{i}_norm1.scale", hp), hp, i, pos,
-                         mask=mask, paged=paged)
-    x = x + out
-    out, stats = _moe(_rms(x, f"blk{i}_norm2.scale", hp), hp, i, lens)
-    return x + out, kv, stats
+    """One layer; returns ``(x, [k, v] or [], stats)``.  ``lens`` [rows,
+    1] int32: a row with 0 (a free slot's, a pad row) takes no routed
+    expert."""
+    E = int(hp.num_experts)
+    return decoder_layer(
+        x, hp, f"blk{i}",
+        lambda h: _attention(h, hp, i, pos, mask=mask, paged=paged),
+        # a softmax router, no bias, no scaling, no shared expert.  Every
+        # expert is held, so every assignment lands: the sorted rows go
+        # through the grouped product in one (chunk_rows 0)
+        lambda h: routed_experts(
+            h, hp, f"blk{i}", lens, experts=E, held=E, expert_offset=0,
+            scaling=1.0, scoring="softmax", bias=False, chunk_rows=0))
 
 
 def build_prefill_program(hp):
@@ -207,24 +178,19 @@ def build_prefill_program(hp):
     block tokens [1, block_length] int32]``: the K/V zeroed on pad
     rows; the full blocks' are final, the open block's are written over
     by the decode steps that follow."""
-    ids = _data("gen_ids", [1, -1], "int32")
-    pos = _data("gen_pos", [1, -1], "int32")
-    mask = _data("gen_mask", [1, -1])
-    last = _data("gen_last", [1, -1])
+    ids, pos, mask, last = prefill_inputs()
     # pad rows take no routed expert
     lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
-    x = _embed(ids, hp, "blk")
+    x = embed(ids, hp, "blk")
     kv = []
     for i in range(int(hp.num_hidden_layers)):
         x, new, _ = _layer(x, hp, i, pos, lens, mask=mask)
         kv += new
-    last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]), hp.dtype)
-    lasth = layers.reshape(layers.matmul(last3, x),
-                           shape=[-1, int(hp.hidden_size)])
-    tail = _op("block_tail", {"Ids": ids, "Last": last}, {"Out": "int32"},
-               {"block_length": int(hp.block_length)})["Out"]
+    lasth = last_row(x, last, hp)
+    tail = op("block_tail", {"Ids": ids, "Last": last}, {"Out": "int32"},
+              {"block_length": int(hp.block_length)})["Out"]
     return (["gen_ids", "gen_pos", "gen_mask", "gen_last"],
-            [_logits(lasth, hp, "blk")] + kv + [tail])
+            [logits(lasth, hp, "blk")] + kv + [tail])
 
 
 def block_moe_train_program(seq_len, hp: BlockMoEConfig = None):
@@ -236,22 +202,12 @@ def block_moe_train_program(seq_len, hp: BlockMoEConfig = None):
     no part in).  Returns ``(avg_cost, feed_names)``; feeds ``gen_ids``
     / ``gen_labels`` [1, T] int32."""
     hp = hp or BlockMoEConfig()
-    T = int(seq_len)
-    ids = _data("gen_ids", [1, T], "int32")
-    labels = _data("gen_labels", [1, T], "int32")
-    pos = layers.assign(np.arange(T, dtype="int32").reshape(1, T))
-    mask = layers.assign(np.ones((1, T), "float32"))
-    lens = layers.assign(np.ones((T, 1), "int32"))
-    for v in (pos, mask, lens):
-        v.stop_gradient = True
-    x = _embed(ids, hp, "blk")
+    ids, labels, rows = train_inputs(seq_len, "pos", "mask", "lens")
+    x = embed(ids, hp, "blk")
     for i in range(int(hp.num_hidden_layers)):
-        x, _, _ = _layer(x, hp, i, pos, lens, mask=mask)
-    logits = _logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
-                     "blk")
-    cost = layers.softmax_with_cross_entropy(
-        logits, layers.reshape(labels, shape=[T, 1]))
-    return layers.mean(x=cost), ["gen_ids", "gen_labels"]
+        x, _, _ = _layer(x, hp, i, rows["pos"], rows["lens"],
+                         mask=rows["mask"])
+    return train_loss(x, labels, hp, "blk")
 
 
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
@@ -272,31 +228,18 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     block tokens ``[S, L]`` int32.  Fetches ``[logits [S, V] of each
     slot's leftmost masked row (ONE row a slot goes through the head),
     stats [n_layer, 3]]``."""
-    import paddle_tpu as fluid
-
     S, L = int(num_slots), int(hp.block_length)
-    token = _data("gen_token", [S, 1], "int32")
-    pos = _data("gen_pos", [S, 1], "int32")
-    page_table = _data("gen_page_table", [S, -1], "int32")
-    lens = _data("gen_lens", [S, 1], "int32")
-    block = fluid.default_main_program().global_block()
-
-    def persistable(name, shape, dtype):
-        v = block.create_var(name=name, shape=list(shape), dtype=dtype)
-        v.persistable = True
-        v.stop_gradient = True
-        return v
-
+    token, pos, page_table, lens = decode_inputs(S)
     row = int(hp.num_key_value_heads) * int(hp.head_dim)
     pools = {n: persistable(n, [int(num_pages), int(page_len), row],
                             hp.dtype) for n in paged_cache_var_names(hp)}
     state = persistable(STATE_VAR, [S, L], "int32")
-    rows = _op("block_rows",
-               {"Token": token, "Pos": pos, "Lens": lens, "Block": state},
-               {"Ids": "int32", "RowPos": "int32", "RowLens": "int32",
-                "Pick": "float32", "End": "int32", "BlockOut": state},
-               {"mask_id": int(hp.mask_token_id)})
-    x = layers.reshape(_embed(rows["Ids"], hp, "blk"),
+    rows = op("block_rows",
+              {"Token": token, "Pos": pos, "Lens": lens, "Block": state},
+              {"Ids": "int32", "RowPos": "int32", "RowLens": "int32",
+               "Pick": "float32", "End": "int32", "BlockOut": state},
+              {"mask_id": int(hp.mask_token_id)})
+    x = layers.reshape(embed(rows["Ids"], hp, "blk"),
                        shape=[S, 2 * L, int(hp.hidden_size)])
     stats = []
     for i in range(int(hp.num_hidden_layers)):
@@ -306,82 +249,32 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
                                  rows["End"], rows["RowLens"]))
         stats.append(st)
     picked = layers.matmul(layers.cast(rows["Pick"], hp.dtype), x)
-    logits = _logits(layers.reshape(picked,
-                                    shape=[S, int(hp.hidden_size)]),
-                     hp, "blk")
     return (["gen_token", "gen_pos", "gen_page_table", "gen_lens"],
-            [logits, layers.concat(stats, axis=0)])
+            decode_fetches(picked, stats, S, hp, "blk"))
 
 
 def export_block_model(dirname, hp: BlockMoEConfig = None, num_slots=8,
                        prompt_buckets=None, page_len=PAGE_LEN_DEFAULT,
                        num_pages=None, page_buckets=None):
-    """Export a generation bundle in ``gen_lm.export_gen_model``'s
-    layout.  ``gen_meta.json`` names the block tokens as the bundle's
+    """Export a generation bundle (``decoder.export_bundle``).
+    ``gen_meta.json`` names the block tokens as the bundle's
     ``state_vars`` and carries ``block_length`` and ``mask_token_id``.
     ``page_len`` and ``max_len`` must be multiples of ``block_length``
     (a block never straddles a page).  Returns ``dirname``."""
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.lod import bucket_edges
-
     hp = hp or BlockMoEConfig()
-    num_slots, L = int(num_slots), int(hp.block_length)
-    if prompt_buckets is None:
-        prompt_buckets = bucket_edges(1, hp.max_len)
+    L = int(hp.block_length)
     page_len = max(1, min(int(page_len), int(hp.max_len)))
     if page_len % L or int(hp.max_len) % L:
         raise ValueError(
             f"page_len {page_len} and max_len {hp.max_len} must be "
             f"multiples of block_length {L}")
-    pps = -(-int(hp.max_len) // page_len)
-    num_pages = num_slots * pps if num_pages is None else int(num_pages)
-    if page_buckets is None:
-        page_buckets = default_page_buckets(pps)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor()
-        pre_main, pre_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(pre_main, pre_startup):
-            pre_feeds, pre_fetches = build_prefill_program(hp)
-        exe.run(pre_startup)
-        _write_model(os.path.join(dirname, "prefill"), pre_main,
-                     pre_feeds, pre_fetches, exe)
-        dec_main, dec_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(dec_main, dec_startup):
-            dec_feeds, dec_fetches = build_paged_decode_program(
-                hp, num_slots, page_len, num_pages)
-        # decode shares the initialized parameters (its startup is never
-        # run); the pools and the block tokens start as zeros
-        block = dec_main.global_block()
-        for name in paged_cache_var_names(hp) + [STATE_VAR]:
-            var = block.var(name)
-            scope.set_var(name, np.zeros(var.shape,
-                                         jnp.dtype(str(var.dtype))))
-        _write_model(os.path.join(dirname, "decode"), dec_main,
-                     dec_feeds, dec_fetches, exe)
-
-    meta = {
-        "format": "paddle_tpu.gen/1",
-        "num_slots": num_slots,
-        "max_len": int(hp.max_len),
-        "vocab_size": int(hp.vocab_size),
-        "n_layer": int(hp.num_hidden_layers),
-        "eos_id": int(hp.eos_id),
-        "cache_vars": paged_cache_var_names(hp),
-        "state_vars": [STATE_VAR],
-        "decode_stats": DECODE_STATS,
-        "block_length": L,
-        "mask_token_id": int(hp.mask_token_id),
-        "prompt_buckets": [int(b) for b in prompt_buckets],
-        "page_len": int(page_len),
-        "num_pages": int(num_pages),
-        "page_buckets": [int(b) for b in page_buckets],
-        "page_table_feed": "gen_page_table",
-    }
-    with open(os.path.join(dirname, META_FILENAME), "w") as f:
-        json.dump(meta, f, indent=2)
-    from paddle_tpu.analysis import verify_gen_bundle
-    verify_gen_bundle(dirname, where="block_moe.export_block_model")
-    return dirname
+    return export_bundle(
+        dirname, hp, "block_moe.export_block_model",
+        lambda *pool: build_prefill_program(hp),
+        lambda *pool: build_paged_decode_program(hp, *pool),
+        paged_cache_var_names(hp), hp.num_hidden_layers,
+        num_slots=num_slots, prompt_buckets=prompt_buckets,
+        page_len=page_len, num_pages=num_pages, page_buckets=page_buckets,
+        state_vars=[STATE_VAR],
+        sections={"decode_stats": DECODE_STATS, "block_length": L,
+                  "mask_token_id": int(hp.mask_token_id)})

@@ -27,24 +27,20 @@ gate's view of this model).
 
 from __future__ import annotations
 
-import json
-import os
-
 import numpy as np
 
 import paddle_tpu.layers as layers
 from paddle_tpu.initializer import NumpyArrayInitializer
+from paddle_tpu.models.decoder import (META_FILENAME, PAGE_LEN_DEFAULT,
+                                       data, decode_inputs,
+                                       default_page_buckets, export_bundle,
+                                       op, persistable)
 from paddle_tpu.param_attr import ParamAttr
 
 __all__ = ["GenConfig", "build_prefill_program",
            "build_paged_decode_program", "gen_lm_train_program",
            "export_gen_model", "META_FILENAME", "PAGE_LEN_DEFAULT",
            "paged_cache_var_names", "default_page_buckets"]
-
-META_FILENAME = "gen_meta.json"
-
-#: default KV page length (rows per page)
-PAGE_LEN_DEFAULT = 16
 
 
 class GenConfig:
@@ -151,19 +147,6 @@ def paged_cache_var_names(hp):
     return names
 
 
-def default_page_buckets(pages_per_slot):
-    """Power-of-two page-count bucket ladder capped at ``pages_per_slot``
-    (NOT :func:`lod.bucket_edges`, whose fallback ladder floors at 8 —
-    page counts are small integers).  ``GenPredictor.plan_page_buckets``
-    replaces this with a measured-workload ladder."""
-    edges, b = [], 1
-    while b < int(pages_per_slot):
-        edges.append(b)
-        b *= 2
-    edges.append(int(pages_per_slot))
-    return sorted(set(edges))
-
-
 # ---------------------------------------------------------------------------
 # prefill: one prompt, dynamic (bucketed) length
 # ---------------------------------------------------------------------------
@@ -181,10 +164,6 @@ def build_prefill_program(hp):
     K/V [1, T, H*D] zeroed on pad rows (cache hygiene: decode add-writes
     land on zeros).
     """
-    def data(name, shape, dtype="float32"):
-        return layers.data(name=name, shape=shape, dtype=dtype,
-                           append_batch_size=False)
-
     ids = data("gen_ids", [1, -1], "int32")
     pos = data("gen_pos", [1, -1], "int32")
     mask = data("gen_mask", [1, -1])
@@ -230,29 +209,11 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     slot's tail page, then attention over ONLY the table's pages).
     Fetches: ``logits`` [S, V].
     """
-    import paddle_tpu as fluid
-    from paddle_tpu.layer_helper import LayerHelper
-
     S, PL, NP = int(num_slots), int(page_len), int(num_pages)
     hd = hp.n_head * hp.d_head
-
-    def data(name, shape, dtype="float32"):
-        return layers.data(name=name, shape=shape, dtype=dtype,
-                           append_batch_size=False)
-
-    token = data("gen_token", [S, 1], "int32")
-    pos = data("gen_pos", [S, 1], "int32")
-    page_table = data("gen_page_table", [S, -1], "int32")
-    lens = data("gen_lens", [S, 1], "int32")
-
-    block = fluid.default_main_program().global_block()
-    caches = {}
-    for name in paged_cache_var_names(hp):
-        c = block.create_var(name=name, shape=[NP, PL, hd],
-                             dtype="float32")
-        c.persistable = True
-        c.stop_gradient = True
-        caches[name] = c
+    token, pos, page_table, lens = decode_inputs(S)
+    caches = {name: persistable(name, [NP, PL, hd], "float32")
+              for name in paged_cache_var_names(hp)}
 
     x = _embed(token, pos, hp)                         # [S, d]
     x = layers.reshape(x, shape=[S, 1, hp.d_model])
@@ -260,16 +221,12 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
         q, k, v = _qkv(x, hp, i)                       # [S, 1, H*D]
         pk = caches[f"genlm_paged_k_{i}"]
         pv = caches[f"genlm_paged_v_{i}"]
-        helper = LayerHelper("paged_attention")
-        ctxv = helper.create_tmp_variable("float32")
-        helper.append_op(
-            type="paged_attention",
-            inputs={"Q": [q], "K": [k], "V": [v],
-                    "KCache": [pk], "VCache": [pv],
-                    "PageTable": [page_table], "Lens": [lens]},
-            outputs={"Out": [ctxv], "KCacheOut": [pk], "VCacheOut": [pv]},
-            attrs={"n_head": int(hp.n_head),
-                   "scale": float(hp.d_head) ** -0.5})
+        ctxv = op("paged_attention",
+                  {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
+                   "PageTable": page_table, "Lens": lens},
+                  {"Out": "float32", "KCacheOut": pk, "VCacheOut": pv},
+                  {"n_head": int(hp.n_head),
+                   "scale": float(hp.d_head) ** -0.5})["Out"]
         attn = layers.fc(ctxv, hp.d_model, num_flatten_dims=2,
                          bias_attr=False,
                          param_attr=_pa(f"genlm{i}_attnout.w"))
@@ -319,101 +276,27 @@ def gen_lm_train_program(batch_size, seq_len, hp: GenConfig = None):
 # export: one parameter set -> prefill/ + decode/ + gen_meta.json
 # ---------------------------------------------------------------------------
 
-def _write_model(dirname, program, feed_names, fetch_vars, executor):
-    """The ``__model__`` + ``__params__`` pair ``io.load_inference_model``
-    reads — written WITHOUT pruning (the decode program's in-place cache
-    writes are load-bearing side effects a fetch-target prune would
-    drop)."""
-    from paddle_tpu import io as _io
-    os.makedirs(dirname, exist_ok=True)
-    model = {
-        "program": program.to_dict(),
-        "feed_var_names": list(feed_names),
-        "fetch_var_names": [v.name for v in fetch_vars],
-    }
-    with open(os.path.join(dirname, "__model__"), "w") as f:
-        json.dump(model, f)
-    _io.save_persistables(executor, dirname, program, "__params__")
-
-
 def export_gen_model(dirname, hp: GenConfig = None, num_slots=8,
                      prompt_buckets=None, paged=True,
                      page_len=PAGE_LEN_DEFAULT, num_pages=None,
                      page_buckets=None):
-    """Export a generation bundle: ``<dirname>/prefill/``,
-    ``<dirname>/decode/`` (each a loadable inference model over ONE
-    shared parameter set) and ``<dirname>/gen_meta.json`` describing the
-    cache pool geometry.  Returns ``dirname``.
+    """Export a generation bundle (``decoder.export_bundle`` has the
+    layout and the pool's defaults): ``<dirname>/prefill/``,
+    ``<dirname>/decode/`` and ``<dirname>/gen_meta.json``.  Returns
+    ``dirname``.
 
-    The KV cache is a page pool: ``page_len`` rows per page (clamped to
-    ``max_len``), ``num_pages`` pool pages (default
-    ``num_slots * ceil(max_len / page_len)`` — every slot can always
-    grow to ``max_len``), ``page_buckets`` the declared page-count
-    jit-signature ladder.  ``paged`` is accepted for callers written
-    when a dense ``[num_slots, max_len]`` layout could be exported too;
-    that layout was removed and anything but ``True`` raises."""
-    import paddle_tpu as fluid
-    from paddle_tpu.lod import bucket_edges
-
+    ``paged`` is accepted for callers written when a dense ``[num_slots,
+    max_len]`` layout could be exported too; that layout was removed and
+    anything but ``True`` raises."""
     if paged is not True:
         raise ValueError(
             f"export_gen_model(paged={paged!r}): the dense KV layout was "
             f"removed; every bundle is the page-pool one (drop the keyword)")
     hp = hp or GenConfig()
-    num_slots = int(num_slots)
-    if prompt_buckets is None:
-        prompt_buckets = bucket_edges(1, hp.max_len)
-    page_len = max(1, min(int(page_len), int(hp.max_len)))
-    pps = -(-int(hp.max_len) // page_len)
-    num_pages = num_slots * pps if num_pages is None else int(num_pages)
-    if page_buckets is None:
-        page_buckets = default_page_buckets(pps)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor()
-        pre_main, pre_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(pre_main, pre_startup):
-            pre_feeds, pre_fetches = build_prefill_program(hp)
-        exe.run(pre_startup)
-        _write_model(os.path.join(dirname, "prefill"), pre_main,
-                     pre_feeds, pre_fetches, exe)
-
-        dec_main, dec_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(dec_main, dec_startup):
-            dec_feeds, dec_fetches = build_paged_decode_program(
-                hp, num_slots, page_len, num_pages)
-        # decode shares the ALREADY-initialized parameters (its startup
-        # is never run); the cache pool starts as zeros
-        hd = hp.n_head * hp.d_head
-        for name in paged_cache_var_names(hp):
-            scope.set_var(name, np.zeros((num_pages, page_len, hd),
-                                         dtype="float32"))
-        _write_model(os.path.join(dirname, "decode"), dec_main,
-                     dec_feeds, dec_fetches, exe)
-
-    meta = {
-        "format": "paddle_tpu.gen/1",
-        "num_slots": num_slots,
-        "max_len": int(hp.max_len),
-        "vocab_size": int(hp.vocab_size),
-        "n_layer": int(hp.n_layer),
-        "eos_id": int(hp.eos_id),
-        "cache_vars": paged_cache_var_names(hp),
-        "prompt_buckets": [int(b) for b in prompt_buckets],
-        "page_len": int(page_len),
-        "num_pages": int(num_pages),
-        "page_buckets": [int(b) for b in page_buckets],
-        "page_table_feed": "gen_page_table",
-    }
-    with open(os.path.join(dirname, META_FILENAME), "w") as f:
-        json.dump(meta, f, indent=2)
-    # post-export contract (analysis/distributed.py): the bundle's
-    # prefill/decode pair must satisfy the constant-jit-key contract
-    # (static decode signature, cache geometry matching the meta,
-    # prefill K/V fetches seeding exactly the cache) — a drifted
-    # bundle fails HERE, at export, not at the first /generate;
-    # unwarmable prompt buckets (the PTA018 recompile hazard) are
-    # logged at warning level by the same check
-    from paddle_tpu.analysis import verify_gen_bundle
-    verify_gen_bundle(dirname, where="gen_lm.export_gen_model")
-    return dirname
+    return export_bundle(
+        dirname, hp, "gen_lm.export_gen_model",
+        lambda *pool: build_prefill_program(hp),
+        lambda *pool: build_paged_decode_program(hp, *pool),
+        paged_cache_var_names(hp), hp.n_layer, num_slots=num_slots,
+        prompt_buckets=prompt_buckets, page_len=page_len,
+        num_pages=num_pages, page_buckets=page_buckets)

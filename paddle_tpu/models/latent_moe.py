@@ -54,17 +54,14 @@ view of this model).  The prefill feeds ``gen_ids``, ``gen_pos``,
 
 from __future__ import annotations
 
-import json
-import os
-
-import numpy as np
-
 import paddle_tpu.layers as layers
-from paddle_tpu.models.gen_lm import (META_FILENAME, PAGE_LEN_DEFAULT,
-                                      _write_model, default_page_buckets)
-from paddle_tpu.models.hybrid_moe import (DECODE_STATS, _data, _embed,
-                                          _logits, _matrix, _op, _rms,
-                                          _vector)
+from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
+                                       DecoderConfig, decode_fetches,
+                                       decode_inputs, decoder_layer, embed,
+                                       export_bundle, gated_ffn, last_row,
+                                       logits, matrix, op, persistable,
+                                       prefill_inputs, rms, routed_experts,
+                                       train_inputs, train_loss, vector)
 from paddle_tpu.ops.mla_ops import yarn_mscale
 
 __all__ = ["LatentMoEConfig", "build_prefill_program",
@@ -72,7 +69,7 @@ __all__ = ["LatentMoEConfig", "build_prefill_program",
            "export_latent_model", "paged_cache_var_names"]
 
 
-class LatentMoEConfig:
+class LatentMoEConfig(DecoderConfig):
     """Toy-scale defaults; ``from_dict`` takes the published keys of a
     ``kimi_k2`` / ``deepseek_v3`` ``config.json``."""
     vocab_size = 64
@@ -115,19 +112,10 @@ class LatentMoEConfig:
 
     @classmethod
     def from_dict(cls, cfg):
-        hp = cls()
-        for key, value in cfg.items():
-            name = cls._KEYS.get(key, key)
-            if hasattr(cls, name) and not name.startswith("_"):
-                setattr(hp, name, value)
+        hp = super().from_dict(cfg)
         if hp.rope_parameters and "rope_theta" in hp.rope_parameters:
             hp.rope_theta = hp.rope_parameters["rope_theta"]
         return hp
-
-    @property
-    def held(self):
-        return int(self.n_routed_experts if self.experts_held is None
-                   else self.experts_held)
 
     @property
     def latent_row(self):
@@ -198,14 +186,6 @@ def paged_cache_var_names(hp):
         + [f"lat{i}_paged_ik" for i in hp.full_layers]
 
 
-def _gated_ffn(h, hp, prefix, width):
-    d = int(hp.hidden_size)
-    g = layers.matmul(h, _matrix(hp, f"{prefix}_gate.w", [d, width]))
-    u = layers.matmul(h, _matrix(hp, f"{prefix}_up.w", [d, width]))
-    a = _op("swiglu", {"X": g, "Y": u}, {"Out": hp.dtype})["Out"]
-    return layers.matmul(a, _matrix(hp, f"{prefix}_down.w", [width, d]))
-
-
 def _indexer(h, c_q, hp, i, pos, mask=None, paged=None):
     """The indexer of a ``full`` layer: returns ``(selection, the
     prompt's key rows (pad rows not yet zeroed) or None)``."""
@@ -213,27 +193,27 @@ def _indexer(h, c_q, hp, i, pos, mask=None, paged=None):
                  int(hp.index_head_dim))
     k = int(hp.index_topk)
     inputs = {"Cq": c_q, "X": h, "Pos": pos,
-              "Wq": _matrix(hp, f"lat{i}_idx_qb.w",
-                            [int(hp.q_lora_rank), Hi * Di]),
-              "Wk": _matrix(hp, f"lat{i}_idx_k.w", [d, Di]),
-              "KScale": _vector(f"lat{i}_idx_knorm.scale", Di, 1.0),
-              "KBias": _vector(f"lat{i}_idx_knorm.bias", Di, 0.0),
-              "Ww": _matrix(hp, f"lat{i}_idx_w.w", [d, Hi])}
+              "Wq": matrix(hp, f"lat{i}_idx_qb.w",
+                           [int(hp.q_lora_rank), Hi * Di]),
+              "Wk": matrix(hp, f"lat{i}_idx_k.w", [d, Di]),
+              "KScale": vector(f"lat{i}_idx_knorm.scale", Di, 1.0),
+              "KBias": vector(f"lat{i}_idx_knorm.bias", Di, 0.0),
+              "Ww": matrix(hp, f"lat{i}_idx_w.w", [d, Hi])}
     attrs = {"n_head": Hi, "rope_dim": int(hp.qk_rope_head_dim),
              "theta": float(hp.rope_theta), "top_k": k}
     if paged is None:
-        out = _op("dsa_index", inputs, {"Key": hp.dtype,
-                                        "Scores": "float32"}, attrs)
-        sel = _op("dsa_select", {"Scores": out["Scores"], "Mask": mask},
-                  {"Select": "int8"}, {"top_k": k})["Select"]
+        out = op("dsa_index", inputs, {"Key": hp.dtype,
+                                       "Scores": "float32"}, attrs)
+        sel = op("dsa_select", {"Scores": out["Scores"], "Mask": mask},
+                 {"Select": "int8"}, {"top_k": k})["Select"]
         return sel, out["Key"]
     pool, page_table, lens = paged
-    scores = _op("dsa_index_paged",
-                 {**inputs, "Cache": pool, "PageTable": page_table,
-                  "Lens": lens},
-                 {"Scores": "float32", "CacheOut": pool}, attrs)["Scores"]
-    sel = _op("dsa_select", {"Scores": scores, "Lens": lens},
-              {"Select": "int32"}, {"top_k": k})["Select"]
+    scores = op("dsa_index_paged",
+                {**inputs, "Cache": pool, "PageTable": page_table,
+                 "Lens": lens},
+                {"Scores": "float32", "CacheOut": pool}, attrs)["Scores"]
+    sel = op("dsa_select", {"Scores": scores, "Lens": lens},
+             {"Select": "int32"}, {"top_k": k})["Select"]
     return sel, None
 
 
@@ -249,11 +229,11 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
     L, R = int(hp.kv_lora_rank), int(hp.qk_rope_head_dim)
     nope, vd = int(hp.qk_nope_head_dim), int(hp.v_head_dim)
     rope = hp.rope_attrs
-    c_q = _rms(layers.matmul(h, _matrix(hp, f"lat{i}_qa.w",
-                                        [d, int(hp.q_lora_rank)])),
-               f"lat{i}_qnorm.scale", hp)
-    q = layers.matmul(c_q, _matrix(hp, f"lat{i}_qb.w",
-                                   [int(hp.q_lora_rank), H * (nope + R)]))
+    c_q = rms(layers.matmul(h, matrix(hp, f"lat{i}_qa.w",
+                                      [d, int(hp.q_lora_rank)])),
+              f"lat{i}_qnorm.scale", hp)
+    q = layers.matmul(c_q, matrix(hp, f"lat{i}_qb.w",
+                                  [int(hp.q_lora_rank), H * (nope + R)]))
     index_key = None
     if hp.indexer(i) == "full":
         select, index_key = _indexer(
@@ -264,88 +244,76 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
     sparse = {} if select is None else {"Select": select}
     sparse_attrs = {} if select is None else {
         "select_top_k": int(hp.index_topk)}
-    q = _op("rope", {"X": q, "Pos": pos}, {"Out": hp.dtype},
-            {"n_head": H, **rope})["Out"]
-    kva = layers.matmul(h, _matrix(hp, f"lat{i}_kva.w", [d, L + R]))
+    q = op("rope", {"X": q, "Pos": pos}, {"Out": hp.dtype},
+           {"n_head": H, **rope})["Out"]
+    kva = layers.matmul(h, matrix(hp, f"lat{i}_kva.w", [d, L + R]))
     c_kv, k_r = layers.split(kva, [L, R], dim=2)
-    c_kv = _rms(c_kv, f"lat{i}_kvnorm.scale", hp)
-    k_r = _op("rope", {"X": k_r, "Pos": pos}, {"Out": hp.dtype},
-              {"n_head": 1, **rope})["Out"]
+    c_kv = rms(c_kv, f"lat{i}_kvnorm.scale", hp)
+    k_r = op("rope", {"X": k_r, "Pos": pos}, {"Out": hp.dtype},
+             {"n_head": 1, **rope})["Out"]
     row = layers.concat([c_kv, k_r], axis=2)
     if hp.latent_row > L + R:
         row = layers.pad(row, [0, 0, 0, 0, 0, hp.latent_row - L - R])
-    w_kvb = _matrix(hp, f"lat{i}_kvb.w", [L, H * (nope + vd)])
+    w_kvb = matrix(hp, f"lat{i}_kvb.w", [L, H * (nope + vd)])
     attrs = {"n_head": H, "nope_dim": nope, "v_dim": vd}
     scale = float(hp.softmax_scale)
     if paged is None:
         row = layers.elementwise_mul(row, layers.cast(mask, hp.dtype),
                                      axis=0)
-        ctx = _op("mla_attention", {"Q": q, "Latent": row, "Wkvb": w_kvb,
-                                    "Mask": mask, **sparse},
-                  {"Out": hp.dtype},
-                  {**attrs, "rope_dim": R, "scale": scale,
-                   **sparse_attrs})["Out"]
+        ctx = op("mla_attention", {"Q": q, "Latent": row, "Wkvb": w_kvb,
+                                   "Mask": mask, **sparse},
+                 {"Out": hp.dtype},
+                 {**attrs, "rope_dim": R, "scale": scale,
+                  **sparse_attrs})["Out"]
     else:
         pool, page_table, lens = paged
-        q_lat = _op("mla_absorb", {"X": q, "Wkvb": w_kvb},
-                    {"Out": hp.dtype},
-                    {**attrs, "side": "q",
-                     "pad": hp.latent_row - L - R})["Out"]
-        ctx = _op("paged_attention_latent",
-                  {"Q": q_lat, "Row": row, "Cache": pool,
-                   "PageTable": page_table, "Lens": lens, **sparse},
-                  {"Out": hp.dtype, "CacheOut": pool},
-                  {"n_head": H, "v_width": L, "scale": scale,
-                   **sparse_attrs})["Out"]
-        ctx = _op("mla_absorb", {"X": ctx, "Wkvb": w_kvb},
-                  {"Out": hp.dtype}, {**attrs, "side": "o"})["Out"]
-    return layers.matmul(ctx, _matrix(hp, f"lat{i}_o.w", [H * vd, d])), \
+        q_lat = op("mla_absorb", {"X": q, "Wkvb": w_kvb},
+                   {"Out": hp.dtype},
+                   {**attrs, "side": "q",
+                    "pad": hp.latent_row - L - R})["Out"]
+        ctx = op("paged_attention_latent",
+                 {"Q": q_lat, "Row": row, "Cache": pool,
+                  "PageTable": page_table, "Lens": lens, **sparse},
+                 {"Out": hp.dtype, "CacheOut": pool},
+                 {"n_head": H, "v_width": L, "scale": scale,
+                  **sparse_attrs})["Out"]
+        ctx = op("mla_absorb", {"X": ctx, "Wkvb": w_kvb},
+                 {"Out": hp.dtype}, {**attrs, "side": "o"})["Out"]
+    return layers.matmul(ctx, matrix(hp, f"lat{i}_o.w", [H * vd, d])), \
         row, select, index_key
 
 
-def _moe(h, hp, i, lens):
-    """Routed experts over the share held + the shared expert; returns
-    the layer's output and the experts' stats.  ``lens`` [rows, 1]
-    int32: a row with 0 (a free slot, a pad row) has no assignment."""
-    d, E = int(hp.hidden_size), int(hp.n_routed_experts)
-    F = int(hp.moe_intermediate_size)
-    route = _op("moe_route",
-                {"X": h, "W": _matrix(hp, f"lat{i}_gate.w", [d, E]),
-                 "Bias": _vector(f"lat{i}_gate.bias", E, 0.0)},
-                {"TopkIdx": "int32", "TopkWeight": "float32"},
-                {"top_k": int(hp.num_experts_per_tok),
-                 "scaling": float(hp.routed_scaling_factor),
-                 "norm_topk": bool(hp.norm_topk_prob)})
-    routed = _op("moe_experts_gated",
-                 {"X": h, "TopkIdx": route["TopkIdx"],
-                  "TopkWeight": route["TopkWeight"],
-                  "Wg": _matrix(hp, f"lat{i}_wg", [hp.held, d, F]),
-                  "Wu": _matrix(hp, f"lat{i}_wu", [hp.held, d, F]),
-                  "Wd": _matrix(hp, f"lat{i}_wd", [hp.held, F, d]),
-                  "Lens": lens},
-                 {"Out": hp.dtype, "Stats": "int32"},
-                 {"expert_offset": int(hp.expert_offset)})
-    shared = _gated_ffn(h, hp, f"lat{i}_sh",
-                        F * int(hp.n_shared_experts))
-    return routed["Out"] + shared, routed["Stats"]
+def _ffn(h, hp, i, lens):
+    """Layer ``i``'s feed-forward: the dense SwiGLU, or routed experts
+    over the share held + the shared expert.  Returns ``(out, the
+    experts' stats or None)``."""
+    if not hp.is_moe(i):
+        return gated_ffn(h, hp, f"lat{i}_ffn",
+                         int(hp.intermediate_size)), None
+    routed, stats = routed_experts(
+        h, hp, f"lat{i}", lens, experts=int(hp.n_routed_experts),
+        held=hp.held, expert_offset=hp.expert_offset,
+        scaling=hp.routed_scaling_factor)
+    shared = gated_ffn(h, hp, f"lat{i}_sh", int(hp.moe_intermediate_size)
+                       * int(hp.n_shared_experts))
+    return routed + shared, stats
 
 
 def _layer(x, hp, i, pos, lens, mask=None, paged=None, select=None,
            index_pool=None):
     """One layer; returns ``(x, latent row, stats or None, selection,
     index key rows or None)``: the selection is the layer's own where it
-    holds an indexer, else the one it was handed."""
-    out, row, select, index_key = _attention(
-        _rms(x, f"lat{i}_norm1.scale", hp), hp, i, pos, mask=mask,
-        paged=paged, select=select, index_pool=index_pool)
-    x = x + out
-    h = _rms(x, f"lat{i}_norm2.scale", hp)
-    if hp.is_moe(i):
-        out, stats = _moe(h, hp, i, lens)
-    else:
-        out, stats = _gated_ffn(h, hp, f"lat{i}_ffn",
-                                int(hp.intermediate_size)), None
-    return x + out, row, stats, select, index_key
+    holds an indexer, else the one it was handed.  ``lens`` [rows, 1]
+    int32: a row with 0 (a free slot, a pad row) takes no routed
+    expert."""
+    def attention(h):
+        out, *kept = _attention(h, hp, i, pos, mask=mask, paged=paged,
+                                select=select, index_pool=index_pool)
+        return out, kept
+
+    x, (row, select, index_key), stats = decoder_layer(
+        x, hp, f"lat{i}", attention, lambda h: _ffn(h, hp, i, lens))
+    return x, row, stats, select, index_key
 
 
 def build_prefill_program(hp):
@@ -358,13 +326,10 @@ def build_prefill_program(hp):
     from ``gen_mask``.  Fetches ``[logits [1, V], latent row per layer
     [1, T, latent_row] ..., index key rows per indexer [1, T,
     index_head_dim] ...]``, zeroed on pad rows."""
-    ids = _data("gen_ids", [1, -1], "int32")
-    pos = _data("gen_pos", [1, -1], "int32")
-    mask = _data("gen_mask", [1, -1])
-    last = _data("gen_last", [1, -1])
+    ids, pos, mask, last = prefill_inputs()
     # pad rows take no routed expert
     lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
-    x = _embed(ids, hp, "lat")
+    x = embed(ids, hp, "lat")
     rows, keys, select = [], [], None
     for i in range(int(hp.num_hidden_layers)):
         x, row, _, select, key = _layer(x, hp, i, pos, lens, mask=mask,
@@ -373,11 +338,8 @@ def build_prefill_program(hp):
         if key is not None:     # seeds the index-key pool: zeros on pads
             keys.append(layers.elementwise_mul(
                 key, layers.cast(mask, hp.dtype), axis=0))
-    last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]), hp.dtype)
-    lasth = layers.reshape(layers.matmul(last3, x),
-                           shape=[-1, int(hp.hidden_size)])
     return (["gen_ids", "gen_pos", "gen_mask", "gen_last"],
-            [_logits(lasth, hp, "lat")] + rows + keys)
+            [logits(last_row(x, last, hp), hp, "lat")] + rows + keys)
 
 
 def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
@@ -387,24 +349,13 @@ def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
     the model-zoo lint gate's view of this model.  Returns ``(avg_cost,
     feed_names)``; feeds ``gen_ids`` / ``gen_labels`` [1, T] int32."""
     hp = hp or LatentMoEConfig()
-    T = int(seq_len)
-    ids = _data("gen_ids", [1, T], "int32")
-    labels = _data("gen_labels", [1, T], "int32")
-    pos = layers.assign(np.arange(T, dtype="int32").reshape(1, T))
-    mask = layers.assign(np.ones((1, T), "float32"))
-    lens = layers.assign(np.ones((T, 1), "int32"))
-    for v in (pos, mask, lens):
-        v.stop_gradient = True
-    x = _embed(ids, hp, "lat")
+    ids, labels, rows = train_inputs(seq_len, "pos", "mask", "lens")
+    x = embed(ids, hp, "lat")
     select = None
     for i in range(int(hp.num_hidden_layers)):
-        x, _, _, select, _ = _layer(x, hp, i, pos, lens, mask=mask,
-                                    select=select)
-    logits = _logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
-                     "lat")
-    cost = layers.softmax_with_cross_entropy(
-        logits, layers.reshape(labels, shape=[T, 1]))
-    return layers.mean(x=cost), ["gen_ids", "gen_labels"]
+        x, _, _, select, _ = _layer(x, hp, i, rows["pos"], rows["lens"],
+                                    mask=rows["mask"], select=select)
+    return train_loss(x, labels, hp, "lat")
 
 
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
@@ -416,27 +367,16 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     token; 0 = free slot: no page is written).  Persistable state,
     updated in place: one latent pool a layer, ``[num_pages, page_len,
     latent_row]`` in ``hp.dtype``, and one index-key pool a layer that
-    holds an indexer, ``[num_pages, page_len, index_head_dim]``.  Fetches ``[logits [S, V], stats
-    [n_moe, 3]]``."""
-    import paddle_tpu as fluid
-
+    holds an indexer, ``[num_pages, page_len, index_head_dim]``.  Fetches
+    ``[logits [S, V], stats [n_moe, 3]]``."""
     S = int(num_slots)
-    token = _data("gen_token", [S, 1], "int32")
-    pos = _data("gen_pos", [S, 1], "int32")
-    page_table = _data("gen_page_table", [S, -1], "int32")
-    lens = _data("gen_lens", [S, 1], "int32")
-    block = fluid.default_main_program().global_block()
-    pools = {}
-    for name in paged_cache_var_names(hp):
-        width = int(hp.index_head_dim) if name.endswith("_ik") \
-            else hp.latent_row
-        v = block.create_var(
-            name=name, dtype=hp.dtype,
-            shape=[int(num_pages), int(page_len), width])
-        v.persistable = True
-        v.stop_gradient = True
-        pools[name] = v
-    x = layers.reshape(_embed(token, hp, "lat"),
+    token, pos, page_table, lens = decode_inputs(S)
+    pools = {name: persistable(
+        name, [int(num_pages), int(page_len),
+               int(hp.index_head_dim) if name.endswith("_ik")
+               else hp.latent_row], hp.dtype)
+        for name in paged_cache_var_names(hp)}
+    x = layers.reshape(embed(token, hp, "lat"),
                        shape=[S, 1, int(hp.hidden_size)])
     stats, select = [], None
     for i in range(int(hp.num_hidden_layers)):
@@ -446,77 +386,27 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
             select=select, index_pool=pools.get(f"lat{i}_paged_ik"))
         if st is not None:
             stats.append(st)
-    fetches = [_logits(layers.reshape(x, shape=[S, int(hp.hidden_size)]),
-                       hp, "lat")]
-    if stats:
-        fetches.append(layers.concat(stats, axis=0))
-    return ["gen_token", "gen_pos", "gen_page_table", "gen_lens"], fetches
+    return (["gen_token", "gen_pos", "gen_page_table", "gen_lens"],
+            decode_fetches(x, stats, S, hp, "lat"))
 
 
 def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
                         prompt_buckets=None, page_len=PAGE_LEN_DEFAULT,
                         num_pages=None, page_buckets=None):
-    """Export a generation bundle in ``gen_lm.export_gen_model``'s
-    layout; ``cache_vars`` names ONE pool a layer.  Returns
-    ``dirname``."""
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.lod import bucket_edges
-
+    """Export a generation bundle (``decoder.export_bundle``);
+    ``cache_vars`` names ONE pool a layer, and one a layer that holds an
+    indexer.  Returns ``dirname``."""
     hp = hp or LatentMoEConfig()
-    num_slots = int(num_slots)
-    if prompt_buckets is None:
-        prompt_buckets = bucket_edges(1, hp.max_len)
-    page_len = max(1, min(int(page_len), int(hp.max_len)))
-    pps = -(-int(hp.max_len) // page_len)
-    num_pages = num_slots * pps if num_pages is None else int(num_pages)
-    if page_buckets is None:
-        page_buckets = default_page_buckets(pps)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor()
-        pre_main, pre_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(pre_main, pre_startup):
-            pre_feeds, pre_fetches = build_prefill_program(hp)
-        exe.run(pre_startup)
-        _write_model(os.path.join(dirname, "prefill"), pre_main,
-                     pre_feeds, pre_fetches, exe)
-        dec_main, dec_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(dec_main, dec_startup):
-            dec_feeds, dec_fetches = build_paged_decode_program(
-                hp, num_slots, page_len, num_pages)
-        # decode shares the initialized parameters (its startup is never
-        # run); the pools start as zeros of the model's own type
-        block = dec_main.global_block()
-        for name in paged_cache_var_names(hp):
-            scope.set_var(name, np.zeros(block.var(name).shape,
-                                         jnp.dtype(hp.dtype)))
-        _write_model(os.path.join(dirname, "decode"), dec_main,
-                     dec_feeds, dec_fetches, exe)
-
-    meta = {
-        "format": "paddle_tpu.gen/1",
-        "num_slots": num_slots,
-        "max_len": int(hp.max_len),
-        "vocab_size": int(hp.vocab_size),
-        "n_layer": int(hp.num_hidden_layers),
-        "eos_id": int(hp.eos_id),
-        "cache_vars": paged_cache_var_names(hp),
-        "state_vars": [],
-        "decode_stats": DECODE_STATS if hp.moe_layers else [],
-        "prompt_buckets": [int(b) for b in prompt_buckets],
-        "page_len": int(page_len),
-        "num_pages": int(num_pages),
-        "page_buckets": [int(b) for b in page_buckets],
-        "page_table_feed": "gen_page_table",
-    }
+    sections = {"decode_stats": DECODE_STATS if hp.moe_layers else []}
     if hp.full_layers:
         # what the predictor counts a decode step's selections from
-        meta["sparse_attention"] = {"top_k": int(hp.index_topk),
-                                    "indexers": len(hp.full_layers)}
-    with open(os.path.join(dirname, META_FILENAME), "w") as f:
-        json.dump(meta, f, indent=2)
-    from paddle_tpu.analysis import verify_gen_bundle
-    verify_gen_bundle(dirname, where="latent_moe.export_latent_model")
-    return dirname
+        sections["sparse_attention"] = {"top_k": int(hp.index_topk),
+                                        "indexers": len(hp.full_layers)}
+    return export_bundle(
+        dirname, hp, "latent_moe.export_latent_model",
+        lambda *pool: build_prefill_program(hp),
+        lambda *pool: build_paged_decode_program(hp, *pool),
+        paged_cache_var_names(hp), hp.num_hidden_layers,
+        num_slots=num_slots, prompt_buckets=prompt_buckets,
+        page_len=page_len, num_pages=num_pages, page_buckets=page_buckets,
+        state_vars=[], sections=sections)

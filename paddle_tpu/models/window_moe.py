@@ -56,18 +56,14 @@ logits are float32; pool and rings are ``dtype``.
 
 from __future__ import annotations
 
-import json
-import os
-
-import numpy as np
-
 import paddle_tpu.layers as layers
-from paddle_tpu.models.gen_lm import (META_FILENAME, PAGE_LEN_DEFAULT,
-                                      _write_model, default_page_buckets)
-from paddle_tpu.models.hybrid_moe import (DECODE_STATS, _data, _embed,
-                                          _logits, _matrix, _op, _rms,
-                                          _vector)
-from paddle_tpu.models.latent_moe import _gated_ffn
+from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
+                                       DecoderConfig, data, decode_fetches,
+                                       decode_inputs, decoder_layer, embed,
+                                       export_bundle, gated_ffn, last_row,
+                                       logits, matrix, op, persistable,
+                                       prefill_inputs, routed_experts,
+                                       train_inputs, train_loss, vector)
 
 __all__ = ["WindowMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "window_moe_train_program",
@@ -82,7 +78,7 @@ __all__ = ["WindowMoEConfig", "build_chunk_program",
 CHUNK_ROWS = 1024
 
 
-class WindowMoEConfig:
+class WindowMoEConfig(DecoderConfig):
     """Toy-scale defaults; ``from_dict`` takes the published keys of a
     ``mimo_v2_flash`` ``config.json``."""
     vocab_size = 64
@@ -125,20 +121,6 @@ class WindowMoEConfig:
     eos_id = -1
 
     _KEYS = {"layernorm_epsilon": "eps"}
-
-    @classmethod
-    def from_dict(cls, cfg):
-        hp = cls()
-        for key, value in cfg.items():
-            name = cls._KEYS.get(key, key)
-            if hasattr(cls, name) and not name.startswith("_"):
-                setattr(hp, name, value)
-        return hp
-
-    @property
-    def held(self):
-        return int(self.n_routed_experts if self.experts_held is None
-                   else self.experts_held)
 
     @property
     def ring_rows(self):
@@ -215,91 +197,78 @@ def _attention(h, hp, i, pos, chunk=None, cache=None):
             "a sink on a full-attention layer: paged_attention has none")
     rope = {"rope_dim": int(Dk * float(hp.partial_rotary_factor)) // 2 * 2,
             "theta": theta, "pad_to": hp.stored(Dk)}
-    q = layers.matmul(h, _matrix(hp, f"win{i}_q.w", [d, H * Dk]))
-    k = layers.matmul(h, _matrix(hp, f"win{i}_k.w", [d, Hkv * Dk]))
+    q = layers.matmul(h, matrix(hp, f"win{i}_q.w", [d, H * Dk]))
+    k = layers.matmul(h, matrix(hp, f"win{i}_k.w", [d, Hkv * Dk]))
     v = layers.scale(
-        layers.matmul(h, _matrix(hp, f"win{i}_v.w", [d, Hkv * Dv])),
+        layers.matmul(h, matrix(hp, f"win{i}_v.w", [d, Hkv * Dv])),
         scale=float(hp.attention_value_scale))
-    q = _op("rope_partial", {"X": q, "Pos": pos}, {"Out": hp.dtype},
-            {"n_head": H, **rope})["Out"]
-    k = _op("rope_partial", {"X": k, "Pos": pos}, {"Out": hp.dtype},
-            {"n_head": Hkv, **rope})["Out"]
-    sink = _vector(f"win{i}_sink", H, 0.0) if has_sink else None
+    q = op("rope_partial", {"X": q, "Pos": pos}, {"Out": hp.dtype},
+           {"n_head": H, **rope})["Out"]
+    k = op("rope_partial", {"X": k, "Pos": pos}, {"Out": hp.dtype},
+           {"n_head": Hkv, **rope})["Out"]
+    sink = vector(f"win{i}_sink", H, 0.0) if has_sink else None
     attrs = {"n_head": H, "scale": float(Dk) ** -0.5}
     heads = {**attrs, "n_kv_head": Hkv}
     if chunk is not None and window:
         k_ring, v_ring, slot, mask = chunk
-        ctx = _op("window_attention",
-                  {"Q": q, "K": k, "V": v, "Sink": sink, "KRing": k_ring,
-                   "VRing": v_ring, "Slot": slot, "Pos": pos, "Mask": mask},
-                  {"Out": hp.dtype, "KRingOut": k_ring, "VRingOut": v_ring},
-                  {**heads, "window": window})["Out"]
+        ctx = op("window_attention",
+                 {"Q": q, "K": k, "V": v, "Sink": sink, "KRing": k_ring,
+                  "VRing": v_ring, "Slot": slot, "Pos": pos, "Mask": mask},
+                 {"Out": hp.dtype, "KRingOut": k_ring, "VRingOut": v_ring},
+                 {**heads, "window": window})["Out"]
     elif chunk is not None:
         pk, pv, page_table, mask = chunk
-        ctx = _op("gqa_flash_attention_chunk",
-                  {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
-                   "PageTable": page_table, "Pos": pos, "Mask": mask},
-                  {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
-                  heads)["Out"]
+        ctx = op("gqa_flash_attention_chunk",
+                 {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
+                  "PageTable": page_table, "Pos": pos, "Mask": mask},
+                 {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
+                 heads)["Out"]
     elif cache is None and window:
-        ctx = _op("window_attention", {"Q": q, "K": k, "V": v, "Sink": sink},
-                  {"Out": hp.dtype}, {**heads, "window": window})["Out"]
+        ctx = op("window_attention", {"Q": q, "K": k, "V": v, "Sink": sink},
+                 {"Out": hp.dtype}, {**heads, "window": window})["Out"]
     elif cache is None:
-        ctx = _op("gqa_flash_attention", {"Q": q, "K": k, "V": v},
-                  {"Out": hp.dtype}, heads)["Out"]
+        ctx = op("gqa_flash_attention", {"Q": q, "K": k, "V": v},
+                 {"Out": hp.dtype}, heads)["Out"]
     elif window:
         k_ring, v_ring, lens = cache
-        ctx = _op("window_attention_step",
-                  {"Q": q, "K": k, "V": v, "KRing": k_ring, "VRing": v_ring,
-                   "Lens": lens, "Sink": sink},
-                  {"Out": hp.dtype, "KRingOut": k_ring, "VRingOut": v_ring},
-                  {**attrs, "window": window})["Out"]
+        ctx = op("window_attention_step",
+                 {"Q": q, "K": k, "V": v, "KRing": k_ring, "VRing": v_ring,
+                  "Lens": lens, "Sink": sink},
+                 {"Out": hp.dtype, "KRingOut": k_ring, "VRingOut": v_ring},
+                 {**attrs, "window": window})["Out"]
     else:
         pk, pv, page_table, lens = cache
-        ctx = _op("paged_attention",
-                  {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
-                   "PageTable": page_table, "Lens": lens},
-                  {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
-                  heads)["Out"]
-    return layers.matmul(ctx, _matrix(hp, f"win{i}_o.w", [H * Dv, d]))
+        ctx = op("paged_attention",
+                 {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
+                  "PageTable": page_table, "Lens": lens},
+                 {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
+                 heads)["Out"]
+    return layers.matmul(ctx, matrix(hp, f"win{i}_o.w", [H * Dv, d]))
 
 
-def _moe(h, hp, i, lens):
-    """The routed experts over the share held, and their stats.  ``lens``
-    [rows, 1] int32: a row with 0 (a free slot's, a pad row) has no
-    assignment."""
-    d, E = int(hp.hidden_size), int(hp.n_routed_experts)
-    F = int(hp.moe_intermediate_size)
-    route = _op("moe_route",
-                {"X": h, "W": _matrix(hp, f"win{i}_gate.w", [d, E]),
-                 "Bias": _vector(f"win{i}_gate.bias", E, 0.0)},
-                {"TopkIdx": "int32", "TopkWeight": "float32"},
-                {"top_k": int(hp.num_experts_per_tok),
-                 "scaling": float(hp.routed_scaling_factor or 1.0),
-                 "norm_topk": bool(hp.norm_topk_prob)})
-    routed = _op("moe_experts_gated",
-                 {"X": h, "TopkIdx": route["TopkIdx"],
-                  "TopkWeight": route["TopkWeight"],
-                  "Wg": _matrix(hp, f"win{i}_wg", [hp.held, d, F]),
-                  "Wu": _matrix(hp, f"win{i}_wu", [hp.held, d, F]),
-                  "Wd": _matrix(hp, f"win{i}_wd", [hp.held, F, d]),
-                  "Lens": lens},
-                 {"Out": hp.dtype, "Stats": "int32"},
-                 {"expert_offset": int(hp.expert_offset)})
-    return routed["Out"], routed["Stats"]
+def _ffn(h, hp, i, lens):
+    """Layer ``i``'s feed-forward: the dense SwiGLU, or the routed
+    experts over the share held (no shared expert).  Returns ``(out, the
+    experts' stats or None)``."""
+    if not hp.is_moe(i):
+        return gated_ffn(h, hp, f"win{i}_ffn",
+                         int(hp.intermediate_size)), None
+    return routed_experts(
+        h, hp, f"win{i}", lens, experts=int(hp.n_routed_experts),
+        held=hp.held, expert_offset=hp.expert_offset,
+        scaling=hp.routed_scaling_factor or 1.0)
 
 
 def _layer(x, hp, i, pos, lens, chunk=None, cache=None):
-    """One layer; returns ``(x, stats or None)``."""
-    x = x + _attention(_rms(x, f"win{i}_norm1.scale", hp), hp, i, pos,
-                       chunk=chunk, cache=cache)
-    h = _rms(x, f"win{i}_norm2.scale", hp)
-    if hp.is_moe(i):
-        out, stats = _moe(h, hp, i, lens)
-    else:
-        out, stats = _gated_ffn(h, hp, f"win{i}_ffn",
-                                int(hp.intermediate_size)), None
-    return x + out, stats
+    """One layer; returns ``(x, stats or None)``.  ``lens`` [rows, 1]
+    int32: a row with 0 (a free slot's, a pad row) takes no routed
+    expert."""
+    x, _, stats = decoder_layer(
+        x, hp, f"win{i}",
+        lambda h: (_attention(h, hp, i, pos, chunk=chunk, cache=cache),
+                   None),
+        lambda h: _ffn(h, hp, i, lens))
+    return x, stats
 
 
 def _caches(hp, num_slots, page_len, num_pages):
@@ -307,18 +276,13 @@ def _caches(hp, num_slots, page_len, num_pages):
     "v"): var}``, all ``hp.dtype``: a full layer's pools ``[num_pages,
     page_len, row]``, a window layer's rings ``[num_slots, ring,
     row]``."""
-    import paddle_tpu as fluid
-    block = fluid.default_main_program().global_block()
     cache = {}
     for i in range(int(hp.num_hidden_layers)):
         lead, kind = ([int(num_slots), hp.ring_rows], "ring") \
             if hp.is_window(i) else ([int(num_pages), int(page_len)], "paged")
         for r, width in zip("kv", hp.row_widths(i)):
-            v = block.create_var(name=f"win{i}_{kind}_{r}",
-                                 shape=lead + [width], dtype=hp.dtype)
-            v.persistable = True
-            v.stop_gradient = True
-            cache[i, r] = v
+            cache[i, r] = persistable(f"win{i}_{kind}_{r}", lead + [width],
+                                      hp.dtype)
     return cache
 
 
@@ -336,25 +300,19 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     the decode step's: the full layers' pools and the window layers'
     rings.  Fetches ``[logits [1, V]]`` (of the row ``gen_last``
     names)."""
-    ids = _data("gen_ids", [1, -1], "int32")
-    pos = _data("gen_pos", [1, -1], "int32")
-    mask = _data("gen_mask", [1, -1])
-    last = _data("gen_last", [1, -1])
-    slot = _data("gen_slot", [1, 1], "int32")
-    page_table = _data("gen_page_table", [1, -1], "int32")
+    ids, pos, mask, last = prefill_inputs()
+    slot = data("gen_slot", [1, 1], "int32")
+    page_table = data("gen_page_table", [1, -1], "int32")
     cache = _caches(hp, num_slots, page_len, num_pages)
     # pad rows take no routed expert
     lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
-    x = _embed(ids, hp, "win")
+    x = embed(ids, hp, "win")
     for i in range(int(hp.num_hidden_layers)):
         x, _ = _layer(x, hp, i, pos, lens, chunk=(
             cache[i, "k"], cache[i, "v"],
             slot if hp.is_window(i) else page_table, mask))
-    last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]), hp.dtype)
-    lasth = layers.reshape(layers.matmul(last3, x),
-                           shape=[-1, int(hp.hidden_size)])
     return (["gen_ids", "gen_pos", "gen_mask", "gen_last", "gen_slot",
-             "gen_page_table"], [_logits(lasth, hp, "win")])
+             "gen_page_table"], [logits(last_row(x, last, hp), hp, "win")])
 
 
 def window_moe_train_program(seq_len, hp: WindowMoEConfig = None):
@@ -364,21 +322,11 @@ def window_moe_train_program(seq_len, hp: WindowMoEConfig = None):
     this model.  Returns ``(avg_cost, feed_names)``; feeds ``gen_ids`` /
     ``gen_labels`` [1, T] int32."""
     hp = hp or WindowMoEConfig()
-    T = int(seq_len)
-    ids = _data("gen_ids", [1, T], "int32")
-    labels = _data("gen_labels", [1, T], "int32")
-    pos = layers.assign(np.arange(T, dtype="int32").reshape(1, T))
-    lens = layers.assign(np.ones((T, 1), "int32"))
-    for v in (pos, lens):
-        v.stop_gradient = True
-    x = _embed(ids, hp, "win")
+    ids, labels, rows = train_inputs(seq_len, "pos", "lens")
+    x = embed(ids, hp, "win")
     for i in range(int(hp.num_hidden_layers)):
-        x, _ = _layer(x, hp, i, pos, lens)
-    logits = _logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
-                     "win")
-    cost = layers.softmax_with_cross_entropy(
-        logits, layers.reshape(labels, shape=[T, 1]))
-    return layers.mean(x=cost), ["gen_ids", "gen_labels"]
+        x, _ = _layer(x, hp, i, rows["pos"], rows["lens"])
+    return train_loss(x, labels, hp, "win")
 
 
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
@@ -393,12 +341,9 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     layer's rings ``[S, ring, row]``.  Fetches ``[logits [S, V], stats
     [n_moe, 3]]``."""
     S = int(num_slots)
-    token = _data("gen_token", [S, 1], "int32")
-    pos = _data("gen_pos", [S, 1], "int32")
-    page_table = _data("gen_page_table", [S, -1], "int32")
-    lens = _data("gen_lens", [S, 1], "int32")
+    token, pos, page_table, lens = decode_inputs(S)
     cache = _caches(hp, S, page_len, num_pages)
-    x = layers.reshape(_embed(token, hp, "win"),
+    x = layers.reshape(embed(token, hp, "win"),
                        shape=[S, 1, int(hp.hidden_size)])
     stats = []
     for i in range(int(hp.num_hidden_layers)):
@@ -409,11 +354,8 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
                           else (page_table, lens)))
         if st is not None:
             stats.append(st)
-    fetches = [_logits(layers.reshape(x, shape=[S, int(hp.hidden_size)]),
-                       hp, "win")]
-    if stats:
-        fetches.append(layers.concat(stats, axis=0))
-    return ["gen_token", "gen_pos", "gen_page_table", "gen_lens"], fetches
+    return (["gen_token", "gen_pos", "gen_page_table", "gen_lens"],
+            decode_fetches(x, stats, S, hp, "win"))
 
 
 def chunk_rows(page_len, max_prompt):
@@ -429,95 +371,55 @@ def chunk_rows(page_len, max_prompt):
     return [top] if half % page_len or not half else [half, top]
 
 
+def _window_section(hp):
+    """``gen_meta.json``'s ``window_attention``: which layer keeps which
+    kind of cache, and what the predictor counts a step's reads from."""
+    import jax.numpy as jnp
+    item = jnp.dtype(hp.dtype).itemsize
+    return {
+        "window": int(hp.sliding_window),
+        "ring": hp.ring_rows,
+        "layers": hp.window_layers,
+        "full_layers": hp.full_layers,
+        "ring_vars": ring_var_names(hp),
+        "row_bytes": [sum(hp.row_widths(i)) * item
+                      for i in hp.window_layers],
+        # (query heads, K/V heads) of a window and of a full layer
+        "heads": list(hp.attention(hp.window_layers[0])[:2]),
+        "full_heads": list(hp.attention(hp.full_layers[0])[:2])
+        if hp.full_layers else None,
+    }
+
+
 def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
                         prompt_buckets=None, page_len=PAGE_LEN_DEFAULT,
                         num_pages=None, page_buckets=None):
-    """Export a generation bundle in ``gen_lm.export_gen_model``'s
-    layout, its ``prefill`` the chunk program (``prefill_chunks`` in the
-    meta; ``prompt_buckets`` bounds the longest prompt and is what
+    """Export a generation bundle (``decoder.export_bundle``), its
+    ``prefill`` the chunk program (``prefill_chunks`` in the meta;
+    ``prompt_buckets`` bounds the longest prompt and is what
     ``GenPredictor.prefill`` + ``write_slot`` hand rows over in).
     ``cache_vars`` names the full layers' pools, ``state_vars`` the
     window layers' rings, and ``window_attention`` which layer has which
     and what a ring row takes.  Returns ``dirname``."""
-    import jax.numpy as jnp
-
-    import paddle_tpu as fluid
-    from paddle_tpu.lod import bucket_edges
-
     hp = hp or WindowMoEConfig()
-    num_slots = int(num_slots)
     if hp.ring_rows < int(hp.sliding_window):
         raise ValueError(f"a ring of {hp.ring_rows} rows cannot hold a "
                          f"window of {hp.sliding_window}")
-    if prompt_buckets is None:
-        prompt_buckets = bucket_edges(1, hp.max_len)
-    page_len = max(1, min(int(page_len), int(hp.max_len)))
-    pps = -(-int(hp.max_len) // page_len)
-    num_pages = num_slots * pps if num_pages is None else int(num_pages)
-    if page_buckets is None:
-        page_buckets = default_page_buckets(pps)
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor()
-        pre_main, pre_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(pre_main, pre_startup):
-            pre_feeds, pre_fetches = build_chunk_program(
-                hp, num_slots, page_len, num_pages)
-        exe.run(pre_startup)
-        # written before the caches exist in the scope: the decode
-        # program's file alone holds them
-        _write_model(os.path.join(dirname, "prefill"), pre_main,
-                     pre_feeds, pre_fetches, exe)
-        dec_main, dec_startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(dec_main, dec_startup):
-            dec_feeds, dec_fetches = build_paged_decode_program(
-                hp, num_slots, page_len, num_pages)
-        # decode shares the initialized parameters (its startup is never
-        # run); pools and rings start as zeros of the model's own type
-        block = dec_main.global_block()
-        for name in paged_cache_var_names(hp) + ring_var_names(hp):
-            scope.set_var(name, np.zeros(block.var(name).shape,
-                                         jnp.dtype(hp.dtype)))
-        _write_model(os.path.join(dirname, "decode"), dec_main,
-                     dec_feeds, dec_fetches, exe)
 
-    item = jnp.dtype(hp.dtype).itemsize
-    meta = {
-        "format": "paddle_tpu.gen/1",
-        "num_slots": num_slots,
-        "max_len": int(hp.max_len),
-        "vocab_size": int(hp.vocab_size),
-        "n_layer": int(hp.num_hidden_layers),
-        "eos_id": int(hp.eos_id),
-        "cache_vars": paged_cache_var_names(hp),
-        "state_vars": ring_var_names(hp),
-        "decode_stats": DECODE_STATS if hp.moe_layers else [],
-        "prompt_buckets": [int(b) for b in prompt_buckets],
-        "prefill_chunks": chunk_rows(
-            page_len, min(max(prompt_buckets), int(hp.max_len))),
-        "page_len": int(page_len),
-        "num_pages": int(num_pages),
-        "page_buckets": [int(b) for b in page_buckets],
-        "page_table_feed": "gen_page_table",
-    }
-    if hp.window_layers:
-        # which layer keeps which kind of cache, and what the predictor
-        # counts a step's reads from
-        meta["window_attention"] = {
-            "window": int(hp.sliding_window),
-            "ring": hp.ring_rows,
-            "layers": hp.window_layers,
-            "full_layers": hp.full_layers,
-            "ring_vars": ring_var_names(hp),
-            "row_bytes": [sum(hp.row_widths(i)) * item
-                          for i in hp.window_layers],
-            # (query heads, K/V heads) of a window and of a full layer
-            "heads": list(hp.attention(hp.window_layers[0])[:2]),
-            "full_heads": list(hp.attention(hp.full_layers[0])[:2])
-            if hp.full_layers else None,
-        }
-    with open(os.path.join(dirname, META_FILENAME), "w") as f:
-        json.dump(meta, f, indent=2)
-    from paddle_tpu.analysis import verify_gen_bundle
-    verify_gen_bundle(dirname, where="window_moe.export_window_model")
-    return dirname
+    def sections(meta):
+        own = {"decode_stats": DECODE_STATS if hp.moe_layers else [],
+               "prefill_chunks": chunk_rows(
+                   meta["page_len"],
+                   min(max(meta["prompt_buckets"]), int(hp.max_len)))}
+        if hp.window_layers:
+            own["window_attention"] = _window_section(hp)
+        return own
+
+    return export_bundle(
+        dirname, hp, "window_moe.export_window_model",
+        lambda *pool: build_chunk_program(hp, *pool),
+        lambda *pool: build_paged_decode_program(hp, *pool),
+        paged_cache_var_names(hp), hp.num_hidden_layers,
+        num_slots=num_slots, prompt_buckets=prompt_buckets,
+        page_len=page_len, num_pages=num_pages, page_buckets=page_buckets,
+        state_vars=ring_var_names(hp), sections=sections)

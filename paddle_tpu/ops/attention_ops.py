@@ -283,7 +283,8 @@ def _flash_blocks(S_q, S_k, interpret=False):
 # dq/dk/dv together (the streaming backward is two kernels, each
 # recomputing the scores).  Measured v5e fwd+bwd causal bf16, 64k tokens:
 # S=256 15.6ms vs 18.1 XLA / 18.9 streaming-flash; S=512 16.2ms vs
-# 19.9 / 18.0 (exp_smalls_attn.py artifact).
+# 19.9 / 18.0 (a one-off study on the chip that is no longer in the
+# tree: this comment is the numbers' record).
 # ---------------------------------------------------------------------------
 
 _SMALLS_MAX_S = 1024

@@ -458,7 +458,8 @@ def layer_norm_lower(ctx):
     X's dtype) is bf16, keeping the transformer residual stream bf16
     end-to-end — the statistics are still computed in f32 below.  An
     f32-promoted residual stream doubles the HBM traffic of every
-    LN/add pair (measured: exp_transformer_ceiling.py)."""
+    LN/add pair (measured on the chip by a one-off study that is no
+    longer in the tree)."""
     x = ctx.input("X")
     begin = ctx.attr("begin_norm_axis", 1)
     eps = ctx.attr("epsilon", 1e-5)

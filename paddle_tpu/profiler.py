@@ -373,7 +373,8 @@ def measure_device_seconds(fn, scope=None):
     substring when given.  Owns the trace-dir lifecycle and the
     pure-python protobuf env the xplane parser needs; wall clocks on
     this backend carry dispatch/sync latencies, so this is the shared
-    measurement harness for the bench scripts (exp_resnet_*.py)."""
+    measurement harness for the bench scripts (``bench_attention.py``,
+    ``bench_lstm.py``, ``bench_resnet.py``)."""
     import os
     import shutil
     import tempfile

@@ -41,8 +41,12 @@ def test_zoo_gate_covers_every_model_module():
 
     import paddle_tpu.models as models
     mod_dir = os.path.dirname(os.path.abspath(models.__file__))
+    # decoder.py is the library the serving builders share: it builds no
+    # program of its own, and every function of it is linted through the
+    # five models that call it
     modules = {n[:-3] for n in os.listdir(mod_dir)
-               if n.endswith(".py") and n != "__init__.py"}
+               if n.endswith(".py") and n not in ("__init__.py",
+                                                   "decoder.py")}
     assert modules == set(ZOO_MODELS), (
         f"models modules {sorted(modules)} != lint-gated zoo "
         f"{sorted(ZOO_MODELS)} — add the new model to ZOO_MODELS / "

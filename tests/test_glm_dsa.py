@@ -23,7 +23,7 @@ import paddle_tpu as fluid
 from paddle_tpu import profiler
 from paddle_tpu.analysis import cost
 from paddle_tpu.gen import GenPredictor
-from paddle_tpu.models import latent_moe
+from paddle_tpu.models import decoder, latent_moe
 from paddle_tpu.ops import attention_ops, dsa_ops, mla_ops, moe_ops
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -545,11 +545,11 @@ def test_a_selection_of_the_wrong_width_is_a_type_error():
         pool = block.create_var(name="pool", shape=[32, 8, 128],
                                 dtype="float32")
         pool.persistable = True
-        sel = latent_moe._op(
+        sel = decoder.op(
             "dsa_select", {"Scores": data("scores", [4, 1, 24]),
                            "Lens": data("lens", [4, 1], "int32")},
             {"Select": "int32"}, {"top_k": 8})["Select"]
-        latent_moe._op(
+        decoder.op(
             "paged_attention_latent",
             {"Q": data("q", [4, 1, 2 * 128]), "Row": data("row", [4, 1, 128]),
              "Cache": pool, "PageTable": data("table", [4, 2], "int32"),

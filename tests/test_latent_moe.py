@@ -23,7 +23,7 @@ import paddle_tpu as fluid
 from paddle_tpu import profiler
 from paddle_tpu.analysis import cost, typecheck
 from paddle_tpu.gen import GenPredictor, GenScheduler
-from paddle_tpu.models import latent_moe
+from paddle_tpu.models import decoder, latent_moe
 from paddle_tpu.ops import attention_ops, mla_ops, moe_ops
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -581,11 +581,11 @@ def _latent_op_program(table_width):
         pool = block.create_var(name="pool", shape=[32, 8, 128],
                                 dtype="float32")
         pool.persistable = True
-        latent_moe._op("paged_attention_latent",
-                       {"Q": q, "Row": row, "Cache": pool,
-                        "PageTable": table, "Lens": lens},
-                       {"Out": "float32", "CacheOut": pool},
-                       {"n_head": 2, "v_width": 32, "scale": 1.0})
+        decoder.op("paged_attention_latent",
+                   {"Q": q, "Row": row, "Cache": pool,
+                    "PageTable": table, "Lens": lens},
+                   {"Out": "float32", "CacheOut": pool},
+                   {"n_head": 2, "v_width": 32, "scale": 1.0})
     return main
 
 
