@@ -1015,13 +1015,23 @@ def check_gen_bundle(prefill, decode, meta):
     diags.extend(_check_window_caches(dec_prog, meta))
 
     stats = list(meta.get("decode_stats") or ())
-    if stats and dec_fetches is not None and len(dec_fetches) != 2:
+    # a bundle that drafts fetches its turn's yield behind them
+    spec = meta.get("speculative")
+    if stats and dec_fetches is not None and \
+            len(dec_fetches) != 2 + bool(spec):
         diags.append(Diagnostic(
             "PTA019",
             f"gen_meta declares decode_stats "
             f"{[c.get('name') for c in stats]} but the decode program "
-            f"fetches {len(dec_fetches)} value(s), not logits + stats",
-            program="decode"))
+            f"fetches {len(dec_fetches)} value(s), not logits + stats"
+            + " + the turn's yield" * bool(spec), program="decode"))
+    if spec and spec.get("draft_var") not in state_vars:
+        diags.append(Diagnostic(
+            "PTA019",
+            f"gen_meta's speculative.draft_var "
+            f"`{spec.get('draft_var')}` is not among its state_vars — a "
+            f"slot's draft would be neither seeded nor cleared",
+            var=spec.get("draft_var"), program="gen_meta"))
 
     # -- PTA019: a chunk prefill writes the decode step's own caches ---
     chunks = meta.get("prefill_chunks")

@@ -67,6 +67,16 @@ an ending changed; a turn in which nothing changed passes the constant
 host is one small array a turn (:meth:`GenPredictor.read_turn`): the
 ``[S]`` ids and the step's ``decode_stats``.
 
+A bundle whose meta carries ``speculative`` (``models/window_moe.py``
+with its MTP module loaded; ``ops/spec_ops.py``) yields ONE OR TWO tokens
+a slot a turn: the program forwards a slot's committed token and a draft
+behind it, verifies the draft and drafts again, so the turn advances a
+slot's position and rows by what its program yielded, which the host
+learns at the read (``read_turn``: a run of tokens a slot).  The host's
+mirror then holds which slots are live, no more; it sets a slot's row
+when the slot is seated and when it leaves, and a slot that goes on is
+the device's own.
+
 The KV pool is ``[num_pages, page_len, H*D]`` pages addressed through a
 per-slot page table (``page_len``, ``num_pages`` and ``page_buckets`` are
 required keys of ``gen_meta.json``).  The predictor owns the page
@@ -165,8 +175,9 @@ def _slot_rows(pools, states, idx, n, slot, *, rows):
 # -- what of the slot's device-side decode state the host replaces
 # before the step runs
 _P_FLAGS, _P_TOKEN, _P_POS, _P_LENS, _P_TABLE = 0, 1, 2, 3, 4
-# bits of _P_FLAGS: the row's position and rows are set; its table row is
-_SET_ROW, _SET_TABLE = 1, 2
+# bits of _P_FLAGS: the row's position and rows are set; its table row is;
+# the slot's draft row is off this turn (a bundle that drafts)
+_SET_ROW, _SET_TABLE, _NO_DRAFT = 1, 2, 4
 
 
 def _host_call(fn, *args):
@@ -245,6 +256,11 @@ class GenPredictor:
         # (``models/window_moe.py``): which layer keeps a ring a slot
         # (``state_vars``) and which pages; None without
         self.window_attention = self.meta.get("window_attention")
+        # self-speculative decoding (``ops/spec_ops.py``): the rows a
+        # slot's turn carries (the committed token and its drafts; 1
+        # without) and yields at most
+        self.speculative = self.meta.get("speculative")
+        self.spec_rows = int((self.speculative or {}).get("rows", 1))
         # what the newest decode step's selections and the rows its two
         # kinds of layer read counted to (the step span's attributes)
         self.last_step_counts = {}
@@ -515,8 +531,13 @@ class GenPredictor:
         WITHOUT mid-request allocation (allocation happens once, at
         admission — growth can never fail mid-decode).  A block bundle's
         horizon is the END of the block its last token lies in: every
-        step writes a whole block's rows."""
-        horizon = int(prompt_len) + max(int(max_new_tokens), 1)
+        step writes a whole block's rows.  A bundle that drafts writes a
+        draft's row behind the committed token's, and the turn that runs
+        one step ahead of the host's knowledge of the stream's end writes
+        both one row further: ``spec_rows - 1`` rows past the
+        horizon."""
+        horizon = int(prompt_len) + max(int(max_new_tokens), 1) \
+            + self.spec_rows - 1
         horizon = min(self.max_len, self._block_end(horizon))
         return -(-max(horizon, 1) // self.page_len)
 
@@ -670,7 +691,8 @@ class GenPredictor:
         pages = self.alloc_slot_pages(slot, -(-n // self.page_len))
         try:
             for a, b in self.chunk_spans(n):
-                logits = self.prefill_chunk(slot, prompt[a:b], a)
+                logits = self.prefill_chunk(
+                    slot, prompt[a:b], a, after=prompt[b] if b < n else None)
             idx = np.zeros(self.pages_per_slot, np.int32)
             idx[:len(pages)] = pages
             k = len(self.cache_vars)
@@ -683,11 +705,14 @@ class GenPredictor:
             self.free_slot_pages(slot)
         return np.asarray(logits)[0], list(kv)
 
-    def prefill_chunk(self, slot, ids, start):
+    def prefill_chunk(self, slot, ids, start, after=None):
         """Run ONE CHUNK of ``slot``'s prompt: the tokens ``ids`` (no
         more than the largest of ``prefill_chunks``), which stand at
         positions ``start ..``; the chunks before it have run and the
-        slot holds its pages (:meth:`alloc_slot_pages`).  One compiled
+        slot holds its pages (:meth:`alloc_slot_pages`).  ``after``: the
+        prompt's token behind the chunk (None: the chunk ends the
+        prompt), which a bundle that drafts needs: its MTP module's row
+        takes the token that follows the row's own.  One compiled
         call, keyed by (chunk rows, page bucket), dispatched and NOT
         waited for: it reads the slot's rows ``0 .. start - 1`` from the
         pools and the per-slot state, as the decode step does, and
@@ -714,6 +739,10 @@ class GenPredictor:
                                           axis=1),
                 "gen_last": last,
                 "gen_slot": np.full((1, 1), slot, np.int32)}
+        if "gen_next_ids" in self._pre_feeds:
+            follows = np.append(ids[0, 1:], -1 if after is None else after)
+            feed["gen_next_ids"] = pad_to_bucket(
+                follows.astype(np.int32)[None], rows, axis=1)
         with self._lock:
             # a copy: the call is not waited for, and the allocator
             # rewrites its table in place
@@ -942,6 +971,7 @@ class GenPredictor:
         kinds = {n: jnp.dtype(str(block.var(n).dtype))
                  for n in self._dec_feeds}
         with_stats = bool(self.decode_stats)
+        drafts = self.speculative is not None
 
         def turn(state, patch, ro, inout, key):
             token, pos, lens, table = state
@@ -957,9 +987,23 @@ class GenPredictor:
                      "gen_page_table": table[:, :pages],
                      "gen_lens": _block_view(pos, lens, L)[0] if L > 1
                      else lens}
+            if drafts:
+                feeds["gen_spec"] = (flags & _NO_DRAFT) == 0
             (logits, *stats), written = step.flat(
                 {n: feeds[n].astype(kinds[n]) for n in kinds}, ro, inout,
                 key)
+            if drafts:
+                # the program verified its own draft: a slot's (first
+                # token, second or -1, how many); the last of them is
+                # the committed token the next turn feeds
+                *stats, verdict = stats
+                count = verdict[:, 2:]
+                token = jnp.where(count > 1, verdict[:, 1:2], verdict[:, :1])
+                read = jnp.concatenate(
+                    [verdict.astype(jnp.int32).reshape(-1)]
+                    + [s.astype(jnp.int32).reshape(-1) for s in stats])
+                return ((token, pos + count, lens + count, table),
+                        logits, read), written
             # the greedy pick: first index on ties, as np.argmax
             ids = jnp.argmax(logits.reshape(S, -1), axis=-1
                              ).astype(jnp.int32)
@@ -979,14 +1023,23 @@ class GenPredictor:
         self._turns[pages] = fn
         return fn
 
-    def _patch(self, tokens, positions, lens, every_row):
+    def _patch(self, tokens, positions, lens, every_row, no_draft=False):
         """The turn's patch (numpy), or None where nothing changed: the
         rows whose position or rows differ from what the device holds
         (all of them with ``every_row``), the tokens the host sets
         (``tokens`` >= 0) and the table rows the allocator changed since
-        the last turn.  Caller holds ``_lock``."""
-        changed = np.ones(self.num_slots, bool) if every_row else \
-            (positions != self._dev_pos) | (lens != self._dev_lens)
+        the last turn.  ``no_draft``: every slot's draft row is off this
+        turn (a bundle that drafts; it travels with ``every_row``).
+        Caller holds ``_lock``."""
+        if every_row:
+            changed = np.ones(self.num_slots, bool)
+        elif self.speculative:
+            # the device advances a slot by what its program yielded: the
+            # host sets a row where it seats a stream (its token is the
+            # host's) and where a slot left
+            changed = (tokens >= 0) | ((lens > 0) != (self._dev_lens > 0))
+        else:
+            changed = (positions != self._dev_pos) | (lens != self._dev_lens)
         # a slot that holds pages and no live row is being ADMITTED chunk
         # by chunk (its chunks are fed the host's table): its row
         # travels once, with the turn that seats it
@@ -996,7 +1049,7 @@ class GenPredictor:
             return None
         patch = np.zeros((self.num_slots, _P_TABLE + self.pages_per_slot),
                          np.int32)
-        patch[:, _P_FLAGS] = _SET_ROW * changed
+        patch[:, _P_FLAGS] = _SET_ROW * changed + _NO_DRAFT * bool(no_draft)
         patch[:, _P_TOKEN] = tokens
         patch[:, _P_POS] = positions
         patch[:, _P_LENS] = lens
@@ -1006,7 +1059,8 @@ class GenPredictor:
         self._stale_rows -= stale
         return patch
 
-    def _launch(self, tokens, positions, lens, every_row=False, pages=None):
+    def _launch(self, tokens, positions, lens, every_row=False, pages=None,
+                no_draft=False):
         """Dispatch one decode turn, not waited for: ``(logits, read,
         patched)``, the first two as the device arrays the executable
         will fill (``read``: the ``[S]`` ids it picked, then the step's
@@ -1041,7 +1095,9 @@ class GenPredictor:
             runtime_metrics.inc("gen.block.fused", n_fused)
             runtime_metrics.inc("gen.block.rows", (live + n_fused) * L)
         if pages is None:
-            pages = self._page_bucket(walk)
+            # a draft's row lies behind the committed token's
+            pages = self._page_bucket(
+                walk + (self.spec_rows - 1) * (walk > 0) * (not no_draft))
         self.last_step_counts = {**self._count_selections(fed),
                                  **self._count_window_rows(fed)}
         with self._lock:
@@ -1064,13 +1120,16 @@ class GenPredictor:
 
             def feed():     # the launch's ``executor.feed``
                 nonlocal patch
-                patch = self._patch(tokens, positions, lens, every_row)
+                patch = self._patch(tokens, positions, lens, every_row,
+                                    no_draft)
                 return (self._dev_state,
                         self._no_patch if patch is None else patch)
 
             with self._fluid.scope_guard(self._scope):
                 self._dev_state, logits, read = self._step.call(
                     functools.partial(_host_call, turn), feed)
+            # (a bundle that drafts advances a slot by its program's
+            # yield: of its mirror only which slots are live is read)
             advance = (lens > 0).astype(np.int32)
             self._dev_pos = positions + advance
             self._dev_lens = lens + advance
@@ -1106,17 +1165,30 @@ class GenPredictor:
     def read_turn(self, read):
         """Wait for a dispatched turn and read it, in ONE transfer:
         ``(ids, counts)``, the token every slot's row yielded (a list of
-        ``S``) and the step's ``decode_stats`` columns reduced and
-        counted (:meth:`count_decode_stats`; {} without)."""
+        ``S``; of a bundle that drafts, the RUN of tokens every slot
+        yielded, in order: one or two, none for a free slot) and the
+        step's ``decode_stats`` columns reduced and counted
+        (:meth:`count_decode_stats`; {} without)."""
         ids, stats = self._split_read(_host_call(np.asarray, read))
-        return ids.tolist(), \
-            {} if stats is None else self.count_decode_stats(stats)
+        if self.speculative:
+            ids = [row[:n] for row, n in zip(ids[:, :-1].tolist(),
+                                             ids[:, -1].tolist())]
+        else:
+            ids = ids.tolist()
+        return ids, {} if stats is None else self.count_decode_stats(stats)
 
     def _split_read(self, read):
+        """A turn's read as ``(ids [S], stats or None)``; of a bundle
+        that drafts ``ids`` is ``[S, spec_rows + 1]``: a slot's tokens,
+        then how many of them it yielded."""
         S = self.num_slots
+        n = S * (self.spec_rows + 1) if self.speculative else S
+        ids, rest = read[:n], read[n:]
+        if self.speculative:
+            ids = ids.reshape(S, -1)
         if not self.decode_stats:
-            return read, None
-        return read[:S], read[S:].reshape(-1, len(self.decode_stats))
+            return ids, None
+        return ids, rest.reshape(-1, len(self.decode_stats))
 
     def decode_step(self, tokens, positions, lens):
         """One BLOCKING decode iteration over the whole slot pool: for
@@ -1146,6 +1218,11 @@ class GenPredictor:
         block it opens; the scheduler's allocation
         (:meth:`pages_needed`) covers it.
 
+        A bundle that drafts (``speculative``) runs this step with its
+        draft rows OFF: the token at ``positions`` is committed, the
+        logits are those for ``positions + 1``, and its MTP module
+        still fills its own cache row and drafts.
+
         A bundle with ``decode_stats`` computes, with the logits, one
         small int32 array ``[n, len(decode_stats)]`` a step; each column's
         sum (or max, as the meta says) goes on the ``gen.decode_step``
@@ -1171,8 +1248,11 @@ class GenPredictor:
             self._check_opened_pages(
                 *_block_view(positions, lens, self.block_length)[1:])
         with _span("gen.decode_step") as step:
-            logits, read, _ = self._launch(tokens, positions, lens,
-                                           every_row=True)
+            # a bundle that drafts commits the one token here: its draft
+            # rows are off, and the logits are the committed token's
+            logits, read, _ = self._launch(
+                tokens, positions, lens, every_row=True,
+                no_draft=self.speculative is not None)
             _, stats = self._split_read(_host_call(np.asarray, read))
             self.last_decode_stats = stats
             step.set(**self.last_step_counts)
@@ -1256,9 +1336,11 @@ class GenPredictor:
             # every (chunk rows, page bucket); zero feeds mask every row,
             # so the caches pass through as they were.  Of the seeding
             # signatures only ``clear_slot``'s: no admission seeds
-            sigs = [{"gen_ids": (1, c), "gen_pos": (1, c),
-                     "gen_mask": (1, c), "gen_last": (1, c),
-                     "gen_slot": (1, 1), "gen_page_table": (1, int(P))}
+            sigs = [{k: v for k, v in {
+                "gen_ids": (1, c), "gen_pos": (1, c), "gen_mask": (1, c),
+                "gen_last": (1, c), "gen_slot": (1, 1),
+                "gen_page_table": (1, int(P)),
+                "gen_next_ids": (1, c)}.items() if k in self._pre_feeds}
                     for c in self.prefill_chunks for P in self.page_buckets
                     if P <= self.pages_per_slot]
             allow, buckets = self.cache_vars + self.state_vars, buckets[:1]

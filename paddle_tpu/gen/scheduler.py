@@ -62,6 +62,19 @@ of blocks is where a stream ends by length (``_horizon``: the token at
 ``max_len - 1`` would open a block past the pool's end) and, for the
 ``gen.decode_step`` span, how many of a step's slots completed a block
 (``fused``).
+
+A turn yields a slot ONE OR TWO tokens where a bundle drafts
+(``predictor.speculative``: ``models/window_moe.py`` with its MTP module
+loaded): the program verifies its own draft and the device advances the
+slot by what it yielded, so the host learns a slot's position and count
+ONE TURN LATE, at the read.  A slot's ``pos`` and ``steps`` are then what
+the host has READ; the step ahead is dispatched for every slot that the
+step in flight cannot have ended for certain (a slot one token short of
+its cap ends with it whatever it yields), and a slot that the read shows
+ended is taken out then: the row computed for it meanwhile is thrown
+away, as after an EOS, and what it wrote lies inside the pages the
+request holds (``pages_needed`` counts that row).  A run is emitted in
+order and cut at ``max_new_tokens``.
 """
 
 from __future__ import annotations
@@ -262,6 +275,8 @@ class GenScheduler:
         # pool's end)
         self._block = getattr(predictor, "block_length", 1)
         self._horizon = predictor.max_len - (self._block > 1)
+        # a turn yields a slot a run of 1 .. spec_rows tokens
+        self._drafts = bool(getattr(predictor, "speculative", None))
         self.queue_size = max(1, int(queue_size))
         self.admission = admission
         self.max_restarts = max(0, int(max_restarts))
@@ -825,9 +840,13 @@ class GenScheduler:
             return False
         a, b = adm.spans[adm.cursor]
         try:
+            # a bundle that drafts takes the prompt's token behind the
+            # chunk too (its MTP rows embed the token that follows)
+            more = {"after": stream.prompt[b] if b < len(stream.prompt)
+                    else None} if self._drafts else {}
             with _trace.trace_context(stream.trace_id):
                 adm.logits = self.predictor.prefill_chunk(
-                    adm.slot_idx, stream.prompt[a:b], a)
+                    adm.slot_idx, stream.prompt[a:b], a, **more)
         except BaseException as e:
             stream.fail(e)
             self._end_admission(adm)
@@ -974,6 +993,29 @@ class GenScheduler:
             self._step_and_emit(live, _profiler.runtime_metrics,
                                 chunks=dispatch)
 
+    @staticmethod
+    def _count_runs(kept, runs, metrics):
+        """A collected turn of a bundle that drafts, counted always-on
+        (``gen.spec.*``) and returned for the ``gen.decode_step`` span:
+        ``slot_turns`` (slots that yielded), ``rows`` (query rows they
+        forwarded), ``drafted`` (drafts verified: one a slot turn; the
+        few turns whose draft row was off, at ``max_len``'s edge, count
+        as drafted and not accepted), ``accepted`` (drafts kept) and
+        ``emitted`` (tokens yielded, before a stream's cap cuts its last
+        run); ``gen.spec.run``: the histogram of a slot turn's run."""
+        lengths = [len(runs[idx]) for idx, _, _ in kept if runs[idx]]
+        out = {"slot_turns": len(lengths), "rows": 2 * len(lengths),
+               "drafted": len(lengths),
+               "accepted": sum(n > 1 for n in lengths),
+               "emitted": sum(lengths)}
+        metrics.inc("gen.spec.slot_turns", out["slot_turns"])
+        metrics.inc("gen.spec.drafted", out["drafted"])
+        metrics.inc("gen.spec.accepted", out["accepted"])
+        metrics.inc("gen.spec.emitted", out["emitted"])
+        for n in lengths:
+            metrics.bucket("gen.spec.run", n)
+        return out
+
     def _step_and_emit(self, live, metrics, chunks=True):
         S, horizon, block = self.predictor.num_slots, self._horizon, \
             self._block
@@ -986,6 +1028,22 @@ class GenScheduler:
         rows, fused = [], 0
         for idx, slot in live:
             cap = slot.stream.max_new_tokens
+            if self._drafts:
+                # ``steps`` and ``pos`` are what the host has READ; the
+                # step in flight yields this slot one token at least
+                ahead = int(carried.get(idx) is slot)
+                if 1 + slot.steps + ahead >= cap \
+                        or slot.pos + ahead >= horizon:
+                    continue    # ended, or ends for certain in flight
+                if not ahead:
+                    tokens[idx] = slot.last_token
+                # the most rows the slot can hold when this turn runs:
+                # the page bucket's bound (the device's own state is
+                # what the turn runs at)
+                positions[idx] = slot.pos + 2 * ahead
+                lens[idx] = positions[idx] + 1
+                rows.append((idx, slot, False))
+                continue
             if 1 + slot.steps >= cap or slot.pos >= horizon:
                 continue    # ends by length with the step in flight
             if carried.get(idx) is not slot:
@@ -1037,19 +1095,32 @@ class GenScheduler:
                          yielded=len(kept), stored=0, fused=prev.fused,
                          block_rows=(len(prev.rows) + prev.fused) * block,
                          **attrs)
+                if self._drafts:
+                    step.set(**self._count_runs(kept, ids, metrics))
         now = time.perf_counter()
         metrics.observe("gen.decode_step_seconds", now - t0)
         with _span("gen.emit"):
             for idx, slot, ends in kept:
                 stream = slot.stream
-                token = ids[idx]
-                slot.last_token = token
-                metrics.inc("gen.tokens")
-                metrics.observe("gen.intertoken_seconds",
-                                now - slot.last_emit_t)
-                slot.last_emit_t = now
-                stream.emit(token)
-                if token == stream.eos_id:
+                run = ids[idx] if self._drafts else [ids[idx]]
+                if self._drafts:
+                    # the device advanced the slot by its run; the run is
+                    # cut where the stream reaches its cap
+                    slot.pos += len(run)
+                    run = run[:stream.max_new_tokens - 1 - slot.steps]
+                    slot.steps += len(run)
+                    ends = 1 + slot.steps >= stream.max_new_tokens \
+                        or slot.pos >= horizon
+                for token in run:
+                    slot.last_token = token
+                    metrics.inc("gen.tokens")
+                    metrics.observe("gen.intertoken_seconds",
+                                    now - slot.last_emit_t)
+                    slot.last_emit_t = now
+                    stream.emit(token)
+                    if token == stream.eos_id:
+                        break
+                if run and slot.last_token == stream.eos_id:
                     self._finish(stream, "eos")
                     self._evict(idx)
                 elif ends:

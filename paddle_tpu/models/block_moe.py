@@ -59,9 +59,9 @@ import paddle_tpu.layers as layers
 from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
                                        DecoderConfig, decode_fetches,
                                        decode_inputs, decoder_layer, embed,
-                                       export_bundle, last_row, logits,
-                                       matrix, op, persistable,
-                                       prefill_inputs, rms, routed_experts,
+                                       export_bundle, head_norm, last_row,
+                                       logits, matrix, op, persistable,
+                                       prefill_inputs, routed_experts,
                                        train_inputs, train_loss)
 
 __all__ = ["BlockMoEConfig", "build_prefill_program",
@@ -103,14 +103,6 @@ def paged_cache_var_names(hp):
             for r in "kv"]
 
 
-def _head_norm(x, name, hp, n_head):
-    """RMSNorm over each head's ``head_dim`` lanes, one scale a layer."""
-    D = int(hp.head_dim)
-    lead = [int(d) for d in x.shape[:-1]]
-    rows = rms(layers.reshape(x, shape=[-1, D]), name, hp)
-    return layers.reshape(rows, shape=lead + [n_head * D])
-
-
 def _attention(h, hp, i, pos, mask=None, paged=None):
     """Prefill (block-causal; returns the masked K/V that seed the pool)
     or the paged decode of a slot's two blocks of rows (``paged`` =
@@ -122,10 +114,10 @@ def _attention(h, hp, i, pos, mask=None, paged=None):
     q = layers.matmul(h, matrix(hp, f"blk{i}_q.w", [d, H * D]))
     k = layers.matmul(h, matrix(hp, f"blk{i}_k.w", [d, Hkv * D]))
     v = layers.matmul(h, matrix(hp, f"blk{i}_v.w", [d, Hkv * D]))
-    q = op("rope", {"X": _head_norm(q, f"blk{i}_qnorm.scale", hp, H),
+    q = op("rope", {"X": head_norm(q, f"blk{i}_qnorm.scale", hp, H, D),
                     "Pos": pos}, {"Out": hp.dtype},
            {"n_head": H, **rope})["Out"]
-    k = op("rope", {"X": _head_norm(k, f"blk{i}_knorm.scale", hp, Hkv),
+    k = op("rope", {"X": head_norm(k, f"blk{i}_knorm.scale", hp, Hkv, D),
                     "Pos": pos}, {"Out": hp.dtype},
            {"n_head": Hkv, **rope})["Out"]
     attrs = {"n_head": H, "n_kv_head": Hkv, "scale": float(D) ** -0.5}

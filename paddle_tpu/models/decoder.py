@@ -10,9 +10,11 @@ meta.  From here it takes
 
 * the program vocabulary: :func:`op`, :func:`param`, :func:`matrix`,
   :func:`vector`, :func:`data`, :func:`persistable`;
-* the blocks every pre-norm decoder has: :func:`rms`, :func:`embed`,
+* the blocks every pre-norm decoder has: :func:`rms`, :func:`head_norm`,
+  :func:`embed`,
   :func:`logits`, :func:`gated_ffn`, :func:`routed_experts`, the
-  two-sublayer :func:`decoder_layer`;
+  two-sublayer :func:`decoder_layer`; and the multi-token-prediction
+  module any of them can append (:func:`mtp_module`, :func:`mtp_logits`);
 * the head and the tail of its three programs: :func:`prefill_inputs` /
   :func:`last_row`, :func:`decode_inputs` / :func:`decode_fetches`,
   :func:`train_inputs` / :func:`train_loss`;
@@ -36,14 +38,16 @@ import numpy as np
 
 import paddle_tpu.layers as layers
 from paddle_tpu import initializer as init_mod
-from paddle_tpu.framework import default_main_program
+from paddle_tpu.framework import default_main_program, name_scope
 from paddle_tpu.layer_helper import LayerHelper
 from paddle_tpu.param_attr import ParamAttr
 
 __all__ = ["META_FILENAME", "PAGE_LEN_DEFAULT", "DECODE_STATS",
            "DecoderConfig", "default_page_buckets", "op", "param", "matrix",
-           "vector", "data", "persistable", "rms", "embed", "logits",
-           "gated_ffn", "routed_experts", "decoder_layer", "prefill_inputs",
+           "vector", "data", "persistable", "rms", "head_norm", "embed",
+           "logits",
+           "gated_ffn", "routed_experts", "decoder_layer", "shared",
+           "mtp_module", "mtp_logits", "prefill_inputs",
            "last_row", "decode_inputs", "decode_fetches", "train_inputs",
            "train_loss", "write_model", "export_bundle"]
 
@@ -165,6 +169,14 @@ def rms(x, name, hp):
               {"epsilon": float(hp.eps)})["Out"]
 
 
+def head_norm(x, name, hp, n_head, width):
+    """RMSNorm over each head's ``width`` lanes of ``x`` [..., n_head *
+    width] (QK-norm), one float32 scale ``name`` a layer."""
+    lead = [int(d) for d in x.shape[:-1]]
+    rows = rms(layers.reshape(x, shape=[-1, width]), name, hp)
+    return layers.reshape(rows, shape=lead + [n_head * width])
+
+
 def embed(ids, hp, prefix):
     """The token embedding ``{prefix}_emb`` (no position is added: the
     attention, or a mixer, carries it)."""
@@ -262,6 +274,54 @@ def decoder_layer(x, hp, prefix, attention, ffn):
     x = x + out
     out, stats = ffn(rms(x, f"{prefix}_norm2.scale", hp))
     return x + out, kept, stats
+
+
+def shared(name):
+    """A parameter the CURRENT program already holds, by name: what a
+    second user of it takes (the embedding and the head that an MTP
+    module shares with the main model), so that it is made and
+    initialised once."""
+    return default_main_program().global_block().var(name)
+
+
+def mtp_module(h, next_ids, hp, prefix, block):
+    """The multi-token-prediction module of DeepSeek-V3 (arXiv:2412.19437
+    section 2.2; ``num_nextn_predict_layers`` 1), under the name scope
+    ``mtp`` (its ops' own scope on the device trace)::
+
+        h'_i = W_p [RMS_h(h_i) ; RMS_e(E[t_{i+1}])]      g_i = Block(h'_i)
+
+    ``h`` [..., d]: the main model's last residual, BEFORE its final
+    norm; ``next_ids`` int32, one a row of ``h``: the token that FOLLOWS
+    the row's own; ``E`` is the main model's embedding ``{prefix}_emb``,
+    which the program holds already (:func:`shared`).  ``block(x) ->
+    (x, stats or None)``: ONE decoder block, the caller's (its kind of
+    attention over its own cache).  Parameters: ``{prefix}_mtp_hnorm
+    .scale`` / ``_enorm.scale`` and ``{prefix}_mtp_proj.w`` [2d, d] (rows
+    ``0 .. d - 1`` take the hidden state's half).  Returns ``(g,
+    stats)``; the draft logits of a row are :func:`mtp_logits` of it."""
+    d = int(hp.hidden_size)
+    with name_scope("mtp"):
+        e = op("lookup_table", {"W": shared(f"{prefix}_emb"),
+                                "Ids": next_ids}, {"Out": hp.dtype},
+               {"is_sparse": False, "is_distributed": False,
+                "padding_idx": -1})["Out"]
+        e = layers.reshape(e, shape=[int(n) for n in h.shape])
+        both = layers.concat([rms(h, f"{prefix}_mtp_hnorm.scale", hp),
+                              rms(e, f"{prefix}_mtp_enorm.scale", hp)],
+                             axis=len(h.shape) - 1)
+        return block(layers.matmul(
+            both, matrix(hp, f"{prefix}_mtp_proj.w", [2 * d, d])))
+
+
+def mtp_logits(g2, hp, prefix):
+    """The draft logits of the MTP rows ``g2`` [R, d]: the module's own
+    final norm ``{prefix}_mtp_norm.scale`` and the main model's head
+    ``{prefix}_head.w`` (:func:`shared`); float32."""
+    with name_scope("mtp"):
+        h = rms(g2, f"{prefix}_mtp_norm.scale", hp)
+        return op("matmul", {"X": h, "Y": shared(f"{prefix}_head.w")},
+                  {"Out": "float32"}, {"out_dtype": "float32"})["Out"]
 
 
 # ---------------------------------------------------------------------------

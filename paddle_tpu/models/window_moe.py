@@ -49,6 +49,24 @@ prompt that fits one chunk runs the same program.  Nothing seeds a slot
 afterwards, and the scheduler can run a decode step for the live
 streams between two chunks (``gen/scheduler.py``).
 
+**The ``exaone_moe`` layout of the same** (K-EXAONE: ``from_dict`` takes
+its published keys, ``layer_types`` / ``mlp_layer_types`` / ``num_experts``
+/ ``num_shared_experts`` / ``rope_parameters``): one head shape for both
+kinds of layer, ``qk_norm`` (an RMSNorm over each q and k head's lanes
+before the rotary), ``full_attention_rotary`` false (a full layer's q and
+k are not rotated), no sink, and ``n_shared_experts`` shared SwiGLU
+experts on every token beside the routed ones.
+
+**Self-speculative decoding** (``num_nextn_predict_layers`` 1): the
+model's multi-token-prediction module (``decoder.mtp_module``: one more
+full-attention block, with a page pool of its own) is loaded and DRAFTS.  A decode turn forwards two rows a slot, the
+committed token and the draft kept in the per-slot state ``win_draft``,
+verifies the draft against the main model's own greedy pick, yields one
+or two tokens a slot and drafts again, all in the one program
+(``ops/spec_ops.py``); the chunk program runs the module over the
+prompt's rows, so that its cache is filled, and seeds the first draft.
+A window layer's ring then holds ``sliding_window + 1`` rows or more.
+
 Matrices and activations are ``dtype`` (bfloat16) with float32
 accumulation; router scores, norm statistics, rotary angles, softmax and
 logits are float32; pool and rings are ``dtype``.
@@ -60,15 +78,21 @@ import paddle_tpu.layers as layers
 from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
                                        DecoderConfig, data, decode_fetches,
                                        decode_inputs, decoder_layer, embed,
-                                       export_bundle, gated_ffn, last_row,
-                                       logits, matrix, op, persistable,
+                                       export_bundle, gated_ffn, head_norm,
+                                       last_row, logits, matrix, mtp_logits,
+                                       mtp_module, op, persistable,
                                        prefill_inputs, routed_experts,
                                        train_inputs, train_loss, vector)
 
 __all__ = ["WindowMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "window_moe_train_program",
            "export_window_model", "paged_cache_var_names",
-           "ring_var_names", "chunk_rows"]
+           "ring_var_names", "chunk_rows", "MTP", "DRAFT_VAR"]
+
+#: the layer key of the MTP module's block (its parameters are
+#: ``win_mtp_*``), and the per-slot state that holds a slot's draft
+MTP = "_mtp"
+DRAFT_VAR = "win_draft"
 
 # rows of a prefill chunk (the larger rung).  A chunk reads every matrix
 # once, so it should hold several times the rows at which a v5e's
@@ -107,6 +131,8 @@ class WindowMoEConfig(DecoderConfig):
     partial_rotary_factor = 0.334
     attention_value_scale = 0.707
     key_head_stored = None           # None: head_dim lanes
+    qk_norm = False                  # RMSNorm over each q and k head
+    full_attention_rotary = True     # False: full layers do not rotate
     # feed-forward
     intermediate_size = 96
     moe_intermediate_size = 32
@@ -114,23 +140,73 @@ class WindowMoEConfig(DecoderConfig):
     num_experts_per_tok = 2
     norm_topk_prob = True
     routed_scaling_factor = None     # None: 1
+    n_shared_experts = 0             # shared experts beside the routed
     experts_held = None              # None: all of them
     expert_offset = 0
+    # the multi-token-prediction module: 0 = not loaded, 1 = it drafts
+    num_nextn_predict_layers = 0
     dtype = "bfloat16"
     max_len = 64
     eos_id = -1
 
-    _KEYS = {"layernorm_epsilon": "eps"}
+    _KEYS = {"layernorm_epsilon": "eps", "rms_norm_eps": "eps",
+             "num_experts": "n_routed_experts",
+             "num_shared_experts": "n_shared_experts"}
+
+    @classmethod
+    def from_dict(cls, cfg):
+        """The published keys of a ``mimo_v2_flash`` ``config.json``, or
+        of an ``exaone_moe`` one (``layer_types`` names the kinds): its
+        two lists, one head shape and one theta for both kinds, a rotary
+        over the whole head, no value scale and no sink."""
+        cfg = dict(cfg)
+        kind = (cfg.get("mtp_layer_types") or ["full_attention"])[0]
+        if cfg.get("num_nextn_predict_layers") and kind != "full_attention":
+            raise NotImplementedError(
+                f"an MTP block of kind {kind!r}: the module's block is a "
+                "full-attention one over its own page pool")
+        if "layer_types" in cfg:
+            theta = (cfg.get("rope_parameters") or {}).get(
+                "rope_theta", cfg.get("rope_theta", 10000.0))
+            cfg.update(
+                hybrid_layer_pattern=[int(t == "sliding_attention")
+                                      for t in cfg["layer_types"]],
+                moe_layer_freq=[int(t == "sparse")
+                                for t in cfg["mlp_layer_types"]],
+                swa_num_attention_heads=cfg["num_attention_heads"],
+                swa_num_key_value_heads=cfg["num_key_value_heads"],
+                swa_head_dim=cfg["head_dim"], v_head_dim=cfg["head_dim"],
+                swa_v_head_dim=cfg["head_dim"], rope_theta=theta,
+                swa_rope_theta=theta, partial_rotary_factor=1.0,
+                attention_value_scale=None,
+                add_swa_attention_sink_bias=False,
+                add_full_attention_sink_bias=False)
+        return super().from_dict(cfg)
 
     @property
     def ring_rows(self):
         return int(self.ring or self.sliding_window)
 
+    @property
+    def drafts(self):
+        """The MTP module is loaded and a decode turn carries its draft."""
+        return int(self.num_nextn_predict_layers or 0) > 0
+
     def is_window(self, i):
-        return bool(self.hybrid_layer_pattern[int(self.layer_offset) + i])
+        # the MTP block is a full-attention one
+        return i != MTP and bool(
+            self.hybrid_layer_pattern[int(self.layer_offset) + i])
 
     def is_moe(self, i):
-        return bool(self.moe_layer_freq[int(self.layer_offset) + i])
+        # the MTP block's feed-forward is the model's sparse one
+        return i == MTP or bool(
+            self.moe_layer_freq[int(self.layer_offset) + i])
+
+    @property
+    def blocks(self):
+        """Every block that caches: the layers, then the MTP module's."""
+        return list(range(int(self.num_hidden_layers))) \
+            + ([MTP] if self.drafts else [])
 
     @property
     def window_layers(self):
@@ -172,8 +248,10 @@ class WindowMoEConfig(DecoderConfig):
 
 
 def paged_cache_var_names(hp):
-    """Page-pool tensors, (k, v) a FULL layer, in layer order."""
-    return [f"win{i}_paged_{r}" for i in hp.full_layers for r in "kv"]
+    """Page-pool tensors, (k, v) a FULL layer (the MTP module's block
+    among them), in layer order."""
+    return [f"win{i}_paged_{r}" for i in hp.blocks if not hp.is_window(i)
+            for r in "kv"]
 
 
 def ring_var_names(hp):
@@ -188,7 +266,9 @@ def _attention(h, hp, i, pos, chunk=None, cache=None):
     layer or slot [1, 1] of a window layer, mask [1, C]): ONE CHUNK of a
     prompt over the slot's own caches, which it reads and writes.
     ``cache`` = (k pool, v pool, page table, lens) of a full layer, (k
-    ring, v ring, lens) of a window layer: the decode step."""
+    ring, v ring, lens) of a window layer: the decode step; with one more
+    entry, ``row_lens`` [S * L, 1], a step of ``L`` rows a slot, each
+    under its own limit (``ops/spec_ops.py``)."""
     d = int(hp.hidden_size)
     H, Hkv, Dk, Dv, theta, has_sink = hp.attention(i)
     window = int(hp.sliding_window) if hp.is_window(i) else 0
@@ -199,13 +279,21 @@ def _attention(h, hp, i, pos, chunk=None, cache=None):
             "theta": theta, "pad_to": hp.stored(Dk)}
     q = layers.matmul(h, matrix(hp, f"win{i}_q.w", [d, H * Dk]))
     k = layers.matmul(h, matrix(hp, f"win{i}_k.w", [d, Hkv * Dk]))
-    v = layers.scale(
-        layers.matmul(h, matrix(hp, f"win{i}_v.w", [d, Hkv * Dv])),
-        scale=float(hp.attention_value_scale))
-    q = op("rope_partial", {"X": q, "Pos": pos}, {"Out": hp.dtype},
-           {"n_head": H, **rope})["Out"]
-    k = op("rope_partial", {"X": k, "Pos": pos}, {"Out": hp.dtype},
-           {"n_head": Hkv, **rope})["Out"]
+    v = layers.matmul(h, matrix(hp, f"win{i}_v.w", [d, Hkv * Dv]))
+    if hp.attention_value_scale is not None:
+        v = layers.scale(v, scale=float(hp.attention_value_scale))
+    if hp.qk_norm:
+        q = head_norm(q, f"win{i}_qnorm.scale", hp, H, Dk)
+        k = head_norm(k, f"win{i}_knorm.scale", hp, Hkv, Dk)
+    if window or hp.full_attention_rotary:
+        q = op("rope_partial", {"X": q, "Pos": pos}, {"Out": hp.dtype},
+               {"n_head": H, **rope})["Out"]
+        k = op("rope_partial", {"X": k, "Pos": pos}, {"Out": hp.dtype},
+               {"n_head": Hkv, **rope})["Out"]
+    elif hp.stored(Dk) != Dk:
+        raise NotImplementedError(
+            "a key head stored wider than it is, on a layer without the "
+            "rotary op that lays it out")
     sink = vector(f"win{i}_sink", H, 0.0) if has_sink else None
     attrs = {"n_head": H, "scale": float(Dk) ** -0.5}
     heads = {**attrs, "n_kv_head": Hkv}
@@ -230,17 +318,19 @@ def _attention(h, hp, i, pos, chunk=None, cache=None):
         ctx = op("gqa_flash_attention", {"Q": q, "K": k, "V": v},
                  {"Out": hp.dtype}, heads)["Out"]
     elif window:
-        k_ring, v_ring, lens = cache
+        k_ring, v_ring, lens, *row_lens = cache
         ctx = op("window_attention_step",
                  {"Q": q, "K": k, "V": v, "KRing": k_ring, "VRing": v_ring,
-                  "Lens": lens, "Sink": sink},
+                  "Lens": lens, "Sink": sink,
+                  "RowLens": row_lens[0] if row_lens else None},
                  {"Out": hp.dtype, "KRingOut": k_ring, "VRingOut": v_ring},
                  {**attrs, "window": window})["Out"]
     else:
-        pk, pv, page_table, lens = cache
+        pk, pv, page_table, lens, *row_lens = cache
         ctx = op("paged_attention",
                  {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
-                  "PageTable": page_table, "Lens": lens},
+                  "PageTable": page_table, "Lens": lens,
+                  "RowLens": row_lens[0] if row_lens else None},
                  {"Out": hp.dtype, "KCacheOut": pk, "VCacheOut": pv},
                  heads)["Out"]
     return layers.matmul(ctx, matrix(hp, f"win{i}_o.w", [H * Dv, d]))
@@ -248,15 +338,21 @@ def _attention(h, hp, i, pos, chunk=None, cache=None):
 
 def _ffn(h, hp, i, lens):
     """Layer ``i``'s feed-forward: the dense SwiGLU, or the routed
-    experts over the share held (no shared expert).  Returns ``(out, the
-    experts' stats or None)``."""
+    experts over the share held and, where the model has them, the
+    shared experts on every row.  Returns ``(out, the experts' stats or
+    None)``."""
     if not hp.is_moe(i):
         return gated_ffn(h, hp, f"win{i}_ffn",
                          int(hp.intermediate_size)), None
-    return routed_experts(
+    out, stats = routed_experts(
         h, hp, f"win{i}", lens, experts=int(hp.n_routed_experts),
         held=hp.held, expert_offset=hp.expert_offset,
         scaling=hp.routed_scaling_factor or 1.0)
+    if hp.n_shared_experts:
+        out = out + gated_ffn(h, hp, f"win{i}_sh",
+                              int(hp.moe_intermediate_size)
+                              * int(hp.n_shared_experts))
+    return out, stats
 
 
 def _layer(x, hp, i, pos, lens, chunk=None, cache=None):
@@ -277,7 +373,7 @@ def _caches(hp, num_slots, page_len, num_pages):
     page_len, row]``, a window layer's rings ``[num_slots, ring,
     row]``."""
     cache = {}
-    for i in range(int(hp.num_hidden_layers)):
+    for i in hp.blocks:
         lead, kind = ([int(num_slots), hp.ring_rows], "ring") \
             if hp.is_window(i) else ([int(num_pages), int(page_len)], "paged")
         for r, width in zip("kv", hp.row_widths(i)):
@@ -299,20 +395,46 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     last real row).  Persistable state, read and updated in place, as
     the decode step's: the full layers' pools and the window layers'
     rings.  Fetches ``[logits [1, V]]`` (of the row ``gen_last``
-    names)."""
+    names).
+
+    Where the MTP module drafts (``hp.drafts``) one more feed,
+    ``gen_next_ids`` [1, C] int32: the token that FOLLOWS each row (the
+    prompt shifted by one; -1 behind the prompt's last row, which takes
+    the main model's own pick).  The module runs
+    over the chunk's rows behind the main layers, so that its cache
+    holds the prompt's rows too, and where the chunk holds the prompt's
+    last row its pick there becomes the slot's first draft
+    (``win_draft``)."""
     ids, pos, mask, last = prefill_inputs()
     slot = data("gen_slot", [1, 1], "int32")
     page_table = data("gen_page_table", [1, -1], "int32")
     cache = _caches(hp, num_slots, page_len, num_pages)
     # pad rows take no routed expert
     lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
-    x = embed(ids, hp, "win")
-    for i in range(int(hp.num_hidden_layers)):
-        x, _ = _layer(x, hp, i, pos, lens, chunk=(
+
+    def block(x, i):
+        return _layer(x, hp, i, pos, lens, chunk=(
             cache[i, "k"], cache[i, "v"],
             slot if hp.is_window(i) else page_table, mask))
-    return (["gen_ids", "gen_pos", "gen_mask", "gen_last", "gen_slot",
-             "gen_page_table"], [logits(last_row(x, last, hp), hp, "win")])
+
+    x = embed(ids, hp, "win")
+    for i in range(int(hp.num_hidden_layers)):
+        x, _ = block(x, i)
+    feeds = ["gen_ids", "gen_pos", "gen_mask", "gen_last", "gen_slot",
+             "gen_page_table"]
+    first = logits(last_row(x, last, hp), hp, "win")
+    if hp.drafts:
+        draft = persistable(DRAFT_VAR, [int(num_slots), 1], "int32")
+        follows = op("spec_next_ids",
+                     {"NextIds": data("gen_next_ids", [1, -1], "int32"),
+                      "Logits": first}, {"Out": "int32"})["Out"]
+        g, _ = mtp_module(x, follows, hp, "win", lambda h: block(h, MTP))
+        op("spec_seed_draft",
+           {"Logits": mtp_logits(last_row(g, last, hp), hp, "win"),
+            "Last": last, "Slot": slot, "Draft": draft},
+           {"DraftOut": draft})
+        feeds.append("gen_next_ids")
+    return feeds, [first]
 
 
 def window_moe_train_program(seq_len, hp: WindowMoEConfig = None):
@@ -339,7 +461,12 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     written).  Persistable state, updated in place, all ``hp.dtype``: a
     full layer's pools ``[num_pages, page_len, row]`` and a window
     layer's rings ``[S, ring, row]``.  Fetches ``[logits [S, V], stats
-    [n_moe, 3]]``."""
+    [n_moe, 3]]``.
+
+    Where the MTP module drafts (``hp.drafts``) the step is
+    :func:`_build_draft_step`'s, of two rows a slot."""
+    if hp.drafts:
+        return _build_draft_step(hp, num_slots, page_len, num_pages)
     S = int(num_slots)
     token, pos, page_table, lens = decode_inputs(S)
     cache = _caches(hp, S, page_len, num_pages)
@@ -356,6 +483,66 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
             stats.append(st)
     return (["gen_token", "gen_pos", "gen_page_table", "gen_lens"],
             decode_fetches(x, stats, S, hp, "win"))
+
+
+def _build_draft_step(hp, num_slots, page_len, num_pages):
+    """The decode TURN of a bundle whose MTP module drafts, two rows a
+    slot (``ops/spec_ops.py``): the committed token ``gen_token`` at
+    ``gen_pos`` and, behind it, the slot's draft (the per-slot state
+    ``win_draft`` [S, 1] int32).  Feeds as every decode step's
+    (``gen_lens``: rows INCLUDING the committed token) and ``gen_spec``
+    [S, 1] int32: 0 turns a slot's draft row off (the row is dead: a
+    blocking step that commits one token and returns the logits behind
+    it).
+
+    The main layers forward both rows (a row sees the rows at or before
+    its own: the paged kernel's limit a row, the ring step's position a
+    row); the verify keeps the draft where it is the first row's own
+    greedy pick; the MTP module runs on the kept rows, fills its cache
+    and its last live row's pick is the slot's next draft.  Fetches
+    ``[logits [S, V] of the committed token's row, stats [n_moe + 1, 3],
+    yield [S, 3] int32]``: a slot's (first token, second token or -1,
+    how many: 0 for a free slot)."""
+    S, d = int(num_slots), int(hp.hidden_size)
+    token, pos, page_table, lens = decode_inputs(S)
+    cache = _caches(hp, S, page_len, num_pages)
+    draft = persistable(DRAFT_VAR, [S, 1], "int32")
+    rows = op("spec_rows",
+              {"Token": token, "Draft": draft, "Pos": pos, "Lens": lens,
+               "On": data("gen_spec", [S, 1], "int32")},
+              {"Ids": "int32", "RowPos": "int32", "End": "int32",
+               "RowLens": "int32"}, {"max_len": int(hp.max_len)})
+
+    def block(x, i, end, row_lens):
+        held = (cache[i, "k"], cache[i, "v"])
+        return _layer(x, hp, i, rows["RowPos"], row_lens,
+                      cache=held + ((end, row_lens) if hp.is_window(i)
+                                    else (page_table, end, row_lens)))
+
+    x = layers.reshape(embed(rows["Ids"], hp, "win"), shape=[S, 2, d])
+    stats = []
+    for i in range(int(hp.num_hidden_layers)):
+        x, st = block(x, i, rows["End"], rows["RowLens"])
+        if st is not None:
+            stats.append(st)
+    verdict = op("spec_verify",
+                 {"Logits": logits(layers.reshape(x, shape=[S * 2, d]), hp,
+                                   "win"),
+                  "Ids": rows["Ids"], "RowLens": rows["RowLens"]},
+                 {"Out": "int32", "NextIds": "int32", "MtpEnd": "int32",
+                  "MtpRowLens": "int32", "First": "float32"})
+    g, st = mtp_module(x, verdict["NextIds"], hp, "win",
+                       lambda h: block(h, MTP, verdict["MtpEnd"],
+                                       verdict["MtpRowLens"]))
+    stats.append(st)
+    last = op("spec_pick_row", {"X": g, "Verdict": verdict["Out"]},
+              {"Out": hp.dtype})["Out"]
+    op("spec_draft", {"Logits": mtp_logits(last, hp, "win"), "Lens": lens,
+                      "Draft": draft}, {"DraftOut": draft})
+    return (["gen_token", "gen_pos", "gen_page_table", "gen_lens",
+             "gen_spec"],
+            [verdict["First"], layers.concat(stats, axis=0),
+             verdict["Out"]])
 
 
 def chunk_rows(page_len, max_prompt):
@@ -380,7 +567,10 @@ def _window_section(hp):
         "window": int(hp.sliding_window),
         "ring": hp.ring_rows,
         "layers": hp.window_layers,
-        "full_layers": hp.full_layers,
+        # the MTP module's block counts as one more full layer (its index
+        # the one behind the last layer's)
+        "full_layers": hp.full_layers
+        + [int(hp.num_hidden_layers)] * hp.drafts,
         "ring_vars": ring_var_names(hp),
         "row_bytes": [sum(hp.row_widths(i)) * item
                       for i in hp.window_layers],
@@ -402,9 +592,12 @@ def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
     window layers' rings, and ``window_attention`` which layer has which
     and what a ring row takes.  Returns ``dirname``."""
     hp = hp or WindowMoEConfig()
-    if hp.ring_rows < int(hp.sliding_window):
+    # a turn with a draft writes one row past the committed one, which
+    # the committed row's window must not have lost
+    if hp.ring_rows < int(hp.sliding_window) + hp.drafts:
         raise ValueError(f"a ring of {hp.ring_rows} rows cannot hold a "
-                         f"window of {hp.sliding_window}")
+                         f"window of {hp.sliding_window}"
+                         + " and a draft's row" * hp.drafts)
 
     def sections(meta):
         own = {"decode_stats": DECODE_STATS if hp.moe_layers else [],
@@ -413,6 +606,11 @@ def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
                    min(max(meta["prompt_buckets"]), int(hp.max_len)))}
         if hp.window_layers:
             own["window_attention"] = _window_section(hp)
+        if hp.drafts:
+            # a turn carries ``rows`` rows a slot and yields 1 .. rows
+            # tokens; the draft lives in ``draft_var`` (a state array)
+            own["speculative"] = {"rows": 2, "draft_var": DRAFT_VAR,
+                                  "feed": "gen_spec"}
         return own
 
     return export_bundle(
@@ -422,4 +620,5 @@ def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
         paged_cache_var_names(hp), hp.num_hidden_layers,
         num_slots=num_slots, prompt_buckets=prompt_buckets,
         page_len=page_len, num_pages=num_pages, page_buckets=page_buckets,
-        state_vars=ring_var_names(hp), sections=sections)
+        state_vars=ring_var_names(hp) + [DRAFT_VAR] * hp.drafts,
+        sections=sections)

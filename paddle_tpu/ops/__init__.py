@@ -35,4 +35,5 @@ from paddle_tpu.ops import (  # noqa: F401
     dsa_ops,
     block_ops,
     window_ops,
+    spec_ops,
 )

@@ -443,7 +443,7 @@ def ring_lead(ring, start, rows):
         jnp.minimum(start, R), rows)
 
 
-def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink):
+def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink, rows=1):
     """One slot of the grid: its rings arrive whole (``[R, Hkv * D]``
     blocks, copied in while the slot before computes).  Row ``r`` holds
     position ``pos - ((pos - r) mod R)``: seen if that is not negative
@@ -457,20 +457,42 @@ def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink):
     this takes under 0.04 with the row's write: the MXU's latency, not
     its work; my chip runs, PR 40).  The weights go through the second
     product in two parts of the ring's type (``hi + lo``), as the paged
-    kernel's."""
+    kernel's.
+
+    ``rows`` > 1: the slot brings that many query rows, ``rows * heads``
+    query heads row-major, and ``lens_ref`` is ``[S, rows]``: row ``j``
+    stands at position ``lens_ref[s, j] - 1`` (0: a dead row, which sees
+    nothing and leaves zeros); the ring holds the rows through the
+    largest of them, and each query row sees the ``window`` rows that
+    end at its own."""
     if sink:
         sink_ref, *refs = refs
     k_ref, v_ref, o_ref = refs
     H, Dk = q_ref.shape[1:]
     R, Dv = k_ref.shape[1], o_ref.shape[2]
-    G = H // n_kv
+    G = H // rows // n_kv
     f32 = jnp.float32
-    pos = lens_ref[pl.program_id(0)] - 1
     r = jax.lax.broadcasted_iota(jnp.int32, (1, R), 1)
-    back = jax.lax.rem(pos - r + R, R)          # pos >= 0 > r - R
-    seen = (back <= pos) & (back < window)      # none where pos < 0
+    head = lambda shape: jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    if rows == 1:
+        pos = lens_ref[pl.program_id(0)] - 1
+        back = jax.lax.rem(pos - r + R, R)          # pos >= 0 > r - R
+        seen = (back <= pos) & (back < window)      # none where pos < 0
+    else:
+        s = pl.program_id(0)
+        mine = [lens_ref[s, j] - 1 for j in range(rows)]
+        top = functools.reduce(jnp.maximum, mine)
+        at = head((H, 1)) // (H // rows)
+        pos = functools.reduce(
+            lambda acc, j: jnp.where(at == j, mine[j], acc),
+            range(1, rows), jnp.full((H, 1), mine[0], jnp.int32))
+        back = jax.lax.rem(jnp.maximum(top, 0) - r + R, R)   # [1, R]
+        ago = back - (top - pos)                    # rows behind ITS position
+        seen = (ago >= 0) & (ago < window) & (back <= top) & (pos >= 0)
+    # query head ``h`` of a row reads K/V head ``h // G``
+    of_row = (lambda x: x) if rows == 1 else (lambda x: x % (H // rows))
     own = lambda width: (
-        jax.lax.broadcasted_iota(jnp.int32, (H, n_kv * width), 0) // G
+        of_row(head((H, n_kv * width))) // G
         == jax.lax.broadcasted_iota(jnp.int32, (H, n_kv * width), 1)
         // width)
     q = q_ref[0]
@@ -515,18 +537,24 @@ def ring_attention(q, k_ring, v_ring, lens, sink=None, *, n_head, scale,
                    window, interpret=False):
     """The decode step's attention over rings that already hold this
     step's row: ``q`` [S, H * Dk]; rings [S, R, Hkv * D]; ``lens`` [S]
-    int32 -> [S, H * Dv] in ``q``'s type (a free slot: zeros)."""
+    int32 -> [S, H * Dv] in ``q``'s type (a free slot: zeros).  ``q``
+    [S, L, H * Dk] with ``lens`` [S, L]: ``L`` rows a slot, each at its
+    own position (``_ring_kernel``'s ``rows``) -> [S, L, H * Dv]."""
     S, R, _ = k_ring.shape
-    Dk = q.shape[-1] // n_head
+    rows = 1 if q.ndim == 2 else q.shape[1]
+    Dk, heads = q.shape[-1] // n_head, n_head
     n_kv = k_ring.shape[-1] // Dk
     Dv = v_ring.shape[-1] // n_kv
     operands, in_specs = [], []
     if sink is not None:
-        operands.append(sink.astype(jnp.float32).reshape(n_head, 1))
-        in_specs.append(pl.BlockSpec((n_head, 1), lambda s, ln: (0, 0)))
+        operands.append(jnp.tile(sink.astype(jnp.float32), rows)
+                        .reshape(rows * n_head, 1))
+        in_specs.append(pl.BlockSpec((rows * n_head, 1),
+                                     lambda s, ln: (0, 0)))
+    n_head = rows * heads
     out = pl.pallas_call(
         functools.partial(_ring_kernel, scale=scale, window=window,
-                          n_kv=n_kv, sink=sink is not None),
+                          n_kv=n_kv, sink=sink is not None, rows=rows),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(S,),
             in_specs=[pl.BlockSpec((1, n_head, Dk),
@@ -541,7 +569,7 @@ def ring_attention(q, k_ring, v_ring, lens, sink=None, *, n_head, scale,
         interpret=interpret,
     )(lens.astype(jnp.int32), q.reshape(S, n_head, Dk).astype(k_ring.dtype),
       *operands, k_ring, v_ring)
-    return out.reshape(S, n_head * Dv)
+    return out.reshape(q.shape[:-1] + (heads * Dv,))
 
 
 def ring_step(q, k, v, k_ring, v_ring, lens, sink, n_head, scale, window,
@@ -583,6 +611,58 @@ def ring_step(q, k, v, k_ring, v_ring, lens, sink, n_head, scale, window,
                      precision=jax.lax.Precision.HIGHEST)
     out = jnp.where((pos >= 0)[:, None, None, None], out, 0.0)
     return out.reshape(S, -1).astype(q.dtype), k_ring, v_ring
+
+
+def ring_rows_step(q, k, v, k_ring, v_ring, row_lens, sink, n_head, scale,
+                   window, kernel=None):
+    """One decode step of ``L`` rows a slot over the rings (a committed
+    token and the drafts behind it: ``ops/spec_ops.py``).  ``q`` [S, L, H
+    * Dk]; ``k`` [S, L, Hkv * Dk]; ``v`` [S, L, Hkv * Dv]; ``row_lens``
+    [S, L]: row ``j`` stands at position ``row_lens[s, j] - 1`` (0 = a
+    dead row: written nowhere, zeros out) and sees the ``window`` rows
+    that end at its own; the live rows' positions are consecutive, so a
+    ring of ``window + L - 1`` rows still holds what the first of them
+    sees once the last is written.  ``kernel`` as ``ring_step``'s.
+    Returns ``(out [S, L, H * Dv], k_ring, v_ring)``."""
+    S, R, _ = k_ring.shape
+    L = q.shape[1]
+    Hkv = k_ring.shape[-1] // (q.shape[-1] // n_head)
+    G = n_head // Hkv
+    slot = jnp.arange(S, dtype=jnp.int32)
+    pos = row_lens.astype(jnp.int32) - 1                          # [S, L]
+    for j in range(L):
+        # a dead row lands nowhere
+        at = jnp.where(pos[:, j] >= 0, jnp.mod(pos[:, j], R), R)
+        k_ring = k_ring.at[slot, at].set(k[:, j].astype(k_ring.dtype),
+                                         mode="drop")
+        v_ring = v_ring.at[slot, at].set(v[:, j].astype(v_ring.dtype),
+                                         mode="drop")
+    if kernel is not None and _ring_kernel_ok(q, k_ring, v_ring, n_head,
+                                              kernel):
+        out = ring_attention(q, k_ring, v_ring, row_lens, sink,
+                             n_head=n_head, scale=scale, window=window,
+                             interpret=kernel)
+        return out, k_ring, v_ring
+    top = jnp.max(pos, axis=1)                                    # [S]
+    back = jnp.mod(jnp.maximum(top, 0)[:, None]
+                   - jnp.arange(R, dtype=jnp.int32)[None], R)     # [S, R]
+    ago = back[:, None, :] - (top[:, None] - pos)[:, :, None]     # [S, L, R]
+    seen = (ago >= 0) & (ago < window) & (back <= top[:, None])[:, None, :] \
+        & (pos >= 0)[:, :, None]
+    qh = q.reshape(S, L, Hkv, G, -1)
+    sc = jnp.einsum("slkgd,srkd->slkgr", qh.astype(k_ring.dtype),
+                    k_ring.reshape(S, R, Hkv, -1),
+                    preferred_element_type=jnp.float32) * scale
+    sc = jnp.where(seen[:, :, None, None, :], sc, NEG_INF)
+    if sink is not None:
+        sink = sink.astype(jnp.float32).reshape(1, 1, Hkv, G, 1)
+    probs = _with_sink(sc, sink)
+    out = jnp.einsum("slkgr,srkd->slkgd", probs,
+                     v_ring.reshape(S, R, Hkv, -1).astype(jnp.float32),
+                     preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
+    out = jnp.where((pos >= 0)[:, :, None, None, None], out, 0.0)
+    return out.reshape(S, L, -1).astype(q.dtype), k_ring, v_ring
 
 
 # ---------------------------------------------------------------------------
@@ -745,10 +825,27 @@ def window_attention_step_lower(ctx):
     Hkv * Dv] persistable; Lens [S, 1] int32 rows INCLUDING this step's
     (0 = free slot); Sink [H] (optional).  attrs n_head, scale, window
     (<= ring).  Out [S, 1, H * Dv]; KRingOut/VRingOut name the rings
-    themselves."""
+    themselves.
+
+    RowLens (optional) [S * L, 1] int32: the step brings ``L`` rows a
+    slot, Q [S, L, H * Dk] and K, V alike; row ``j`` stands at position
+    ``RowLens - 1`` (0 = a dead row) and sees the window that ends at its
+    own (``ring_rows_step``; ring >= window + L - 1).  Out [S, L, H *
+    Dv]."""
     from paddle_tpu.ops.attention_ops import _use_interpret
     q = ctx.input("Q")
     sink = ctx.input("Sink") if ctx.has_input("Sink") else None
+    if ctx.has_input("RowLens"):
+        out, k_ring, v_ring = ring_rows_step(
+            q, ctx.input("K"), ctx.input("V"), ctx.input("KRing"),
+            ctx.input("VRing"), ctx.input("RowLens").reshape(q.shape[:2]),
+            sink, int(ctx.attr("n_head")), float(ctx.attr("scale", 1.0)),
+            int(ctx.attr("window")),
+            kernel=None if _use_interpret() else False)
+        ctx.set_output("Out", out)
+        ctx.set_output("KRingOut", k_ring)
+        ctx.set_output("VRingOut", v_ring)
+        return
     # the kernel on the chip; off it the composed form
     out, k_ring, v_ring = ring_step(
         q[:, 0], ctx.input("K")[:, 0], ctx.input("V")[:, 0],

@@ -834,3 +834,61 @@ def test_chip_smoke_refuses_the_cpu(monkeypatch):
     import chip_smoke
     with pytest.raises(chip_smoke.SmokeFailure, match="no TPU"):
         chip_smoke.main([])
+
+
+# -- two query rows a slot a turn (self-speculative decoding:
+# ``ops/spec_ops.py``) at K-EXAONE's widths: 64 query heads over 8 K/V heads
+# of 128, 32 slots ---------------------------------------------------------
+
+@pytest.mark.parametrize("rows", [1, 2])
+def test_ring_step_of_a_committed_row_and_a_draft_compiles_for_v5e(one_chip,
+                                                                   rows):
+    """A window layer's decode turn over rings of 256 rows (the window's
+    128 + the draft's row, what tiles): the rows' scatter and the ring
+    kernel with ``rows`` query rows a slot, each at its own position."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import window_ops
+    S, R, H, n_kv, D = 32, 256, 64, 8, 128
+
+    def fn(q, k, v, k_ring, v_ring, lens):
+        if rows == 1:
+            return window_ops.ring_step(
+                q[:, 0], k[:, 0], v[:, 0], k_ring, v_ring, lens[:, 0], None,
+                H, D ** -0.5, 128, kernel=False)
+        return window_ops.ring_rows_step(q, k, v, k_ring, v_ring, lens, None,
+                                         H, D ** -0.5, 128, kernel=False)
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(
+        sds((S, rows, H * D)), sds((S, rows, n_kv * D)),
+        sds((S, rows, n_kv * D)), sds((S, R, n_kv * D)),
+        sds((S, R, n_kv * D)), sds((S, rows), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * S * R * n_kv * D * 2
+
+
+@pytest.mark.parametrize("pages", [16, 96])
+def test_paged_decode_of_a_committed_row_and_a_draft_compiles_for_v5e(
+        one_chip, pages):
+    """A full layer's decode turn: two query rows a slot, each under its
+    own limit (the draft's row sees the committed one), 16 query rows to
+    a K/V head in one product."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, PL, H, n_kv, D = 32, 64, 64, 8, 128
+
+    def fn(q, kc, vc, pt, lens, row_lens):
+        out = A._pallas_paged_attention(q, kc, vc, pt, lens, H, D ** -0.5,
+                                        interpret=False, row_lens=row_lens)
+        assert out is not None, "the gate refused the published widths"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 2, H * D), jnp.bfloat16),
+                   ((S * 96, PL, n_kv * D), jnp.bfloat16),
+                   ((S * 96, PL, n_kv * D), jnp.bfloat16),
+                   ((S, pages), jnp.int32), ((S, 1), jnp.int32),
+                   ((S, 2), jnp.int32))
+    assert "tpu_custom_call" in hlo
