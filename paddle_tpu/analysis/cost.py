@@ -777,6 +777,32 @@ def _c_mla_attention(op, info):
     return flops, io_bytes(op, info)
 
 
+@rule("mla_attention_chunk")
+def _c_mla_attention_chunk(op, info):
+    """ONE CHUNK of a prompt over the slot's pages, the latent rows as
+    they are cached: both halves of W_kvb against the chunk's rows (the
+    absorbed queries, the context taken out), then every head's scores
+    over a whole row and its context over the row's value lanes, charged
+    the pairs of the chunk's LAST position, the end of its page bucket
+    (``C`` rows over the bucket's, less the triangle above the
+    diagonal).  Bytes: the rows it attends, once, and its own; never the
+    pool."""
+    q, w = _shape(info, op, "Q"), _shape(info, op, "Wkvb")
+    found = _paged_rows(op, info, "Cache")
+    if q is None or w is None or len(q) != 3 or found is None or \
+            not _known(q[1], q[2], *w):
+        return None
+    c, h = q[1], int(op.attr("n_head"))
+    rows, pool = max(found[1], c), found[3]
+    row = pool.shape[-1]
+    pairs = c * (c + 1) // 2 + c * (rows - c)
+    flops = 2 * c * w[0] * w[1] + 2 * pairs * h * (row + w[0])
+    item = _DTYPE_BYTES.get(str(pool.dtype), 4)
+    bytes_ = (c * (q[2] + h * int(op.attr("v_dim")))
+              + (rows + 2 * c) * row) * item
+    return int(flops), int(bytes_)
+
+
 @rule("mla_absorb")
 def _c_mla_absorb(op, info):
     """One half of W_kvb against every row: 2 x latent x heads x (nope
@@ -816,30 +842,34 @@ def _c_dsa_index(op, info):
     return flops, io_bytes(op, info)
 
 
-@rule("dsa_index_paged")
+@rule("dsa_index_paged", "dsa_index_chunk")
 def _c_dsa_index_paged(op, info):
     """The decode step's: the projections a slot, then every index head
     over the rows a slot ADDRESSES (the page bucket; the live rows where
-    the caller knows them), past ``top_k`` rows."""
+    the caller knows them), past ``top_k`` rows.  ONE CHUNK of a prompt:
+    the same with the chunk's rows for slots, every one over the page
+    bucket's rows."""
     x = _shape(info, op, "X")
     pool = info(op.input("Cache")[0]) if op.input("Cache") else _UNKNOWN
     pt = _shape(info, op, "PageTable")
+    chunk = op.type == "dsa_index_chunk"
     if x is None or pool.shape is None or pt is None or \
-            not _known(x[0], pool.shape[1], pool.shape[2]):
+            not _known(x[1 if chunk else 0], pool.shape[1], pool.shape[2]):
         return None
-    s, d_idx = x[0], pool.shape[2]
+    s, d_idx = x[1 if chunk else 0], pool.shape[2]
     flops = _dsa_projection_flops(op, info, s)
     if flops is None:
         return None
     rows = pt[1] * pool.shape[1] if pt[1] > 0 else None
-    if info.paged_live_rows is not None:
+    if info.paged_live_rows is not None and not chunk:
         rows = info.paged_live_rows if rows is None \
             else min(rows, info.paged_live_rows)
     item = _DTYPE_BYTES.get(str(pool.dtype), 4)
     bytes_ = io_bytes(op, info) - (_var_bytes(pool, 1) or 0) * 2
     if rows is not None and rows > int(op.attr("top_k")):
         flops += 2 * s * rows * int(op.attr("n_head")) * d_idx
-        bytes_ += s * rows * d_idx * item
+        # (a chunk's rows are one slot's: the bucket's rows once)
+        bytes_ += (1 if chunk else s) * rows * d_idx * item
     return int(flops), int(max(bytes_, 0))
 
 

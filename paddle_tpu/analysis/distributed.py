@@ -1089,7 +1089,8 @@ def _check_chunk_prefill(pre_prog, pre_feeds, pre_fetches, dec_prog, meta):
     reads them, so it must hold every cache and state array of the
     decode program under the same name, shape and type, persistable;
     fetch the logits alone (nothing seeds a slot afterwards); take the
-    slot and its page-table row; and its rungs must be whole pages (a
+    slot's page-table row, and the slot itself where the bundle keeps
+    state a slot (``state_vars``); and its rungs must be whole pages (a
     chunk starts where the one before ended, on a page's first row)."""
     diags = []
     chunks = list(meta.get("prefill_chunks") or ())
@@ -1103,7 +1104,8 @@ def _check_chunk_prefill(pre_prog, pre_feeds, pre_fetches, dec_prog, meta):
             f"page_len {page_len} — a chunk begins on a page's first row",
             program="gen_meta"))
     pt_feed = meta.get("page_table_feed", "gen_page_table")
-    for name in (pt_feed, "gen_slot", "gen_pos", "gen_mask"):
+    slot_feed = ("gen_slot",) if meta.get("state_vars") else ()
+    for name in (pt_feed,) + slot_feed + ("gen_pos", "gen_mask"):
         if pre_feeds is not None and name not in pre_feeds:
             diags.append(Diagnostic(
                 "PTA019",
@@ -1118,8 +1120,18 @@ def _check_chunk_prefill(pre_prog, pre_feeds, pre_fetches, dec_prog, meta):
             program="prefill"))
     pre_block, dec_block = pre_prog.global_block(), dec_prog.global_block()
     written = {n for op in pre_block.ops for n in op.output_arg_names}
-    for name in list(meta.get("cache_vars") or ()) + \
-            list(meta.get("state_vars") or ()):
+    named = list(meta.get("cache_vars") or ()) + \
+        list(meta.get("state_vars") or ())
+    for name in sorted(written - set(named)):
+        if pre_block.has_var(name) and \
+                getattr(pre_block.var(name), "persistable", False):
+            diags.append(Diagnostic(
+                "PTA019",
+                f"the chunk prefill writes `{name}` in place but gen_meta "
+                f"names it neither a cache nor a state array — nobody "
+                f"would allocate, clear or census it", var=name,
+                program="gen_meta"))
+    for name in named:
         if not pre_block.has_var(name) or \
                 not getattr(pre_block.var(name), "persistable", False) \
                 or name not in written:

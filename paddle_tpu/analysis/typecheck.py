@@ -1130,7 +1130,17 @@ def _mla_widths(op, tc):
     return h, nope, v, latent
 
 
-@rule("mla_attention")
+def _paged_rows_of(op, tc, cache_slot="Cache"):
+    """Rows a paged op addresses a slot: its page table's width x the
+    pool's page length (None while either is unknown)."""
+    pt, cache = tc.input_info(op, "PageTable"), tc.input_info(op, cache_slot)
+    if pt.shape is not None and cache.shape is not None and \
+            pt.shape[-1] > 0 and cache.shape[1] > 0:
+        return pt.shape[-1] * cache.shape[1]
+    return None
+
+
+@rule("mla_attention", "mla_attention_chunk")
 def _r_mla_attention(op, tc):
     h, nope, v, latent = _mla_widths(op, tc)
     r = int(op.attr("rope_dim"))
@@ -1140,13 +1150,27 @@ def _r_mla_attention(op, tc):
     lat = tc.input_info(op, "Latent")
     if latent and lat.shape is not None and 0 < lat.shape[-1] < latent + r:
         tc.report("PTA006",
-                  f"mla_attention Latent `{op.input('Latent')[0]}` is "
+                  f"{op.type} Latent `{op.input('Latent')[0]}` is "
                   f"{lat.shape[-1]} wide, under c_kv {latent} + rope {r}",
                   op=op, var=op.input("Latent")[0])
-    if q.shape is not None and len(q.shape) >= 2:
-        _select_matches(op, tc, q.shape[-2])
     shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
     tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
+    if op.type == "mla_attention":
+        if q.shape is not None and len(q.shape) >= 2:
+            _select_matches(op, tc, q.shape[-2])
+        return
+    # one chunk of a prompt over the slot's pages: the pool holds the
+    # chunk's rows as they come, and passes through under its own name
+    cache = tc.input_info(op, "Cache")
+    if cache.shape is not None and len(cache.shape) == 3:
+        _last_dim_is(op, tc, "Latent", cache.shape[-1],
+                     "lanes (the pool's row)")
+    _int_index(op, tc, "PageTable")
+    _int_index(op, tc, "Pos")
+    rows = _paged_rows_of(op, tc)
+    if rows is not None:
+        _select_matches(op, tc, rows)
+    tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
 
 
 @rule("mla_absorb")
@@ -1183,10 +1207,9 @@ def _r_paged_attention_latent(op, tc):
         tc.report("PTA006", f"paged_attention_latent reads a value of {v} "
                   f"lanes from rows of {row}", op=op,
                   var=op.input("Cache")[0])
-    pt = tc.input_info(op, "PageTable")
-    if pt.shape is not None and cache.shape is not None and \
-            pt.shape[-1] > 0 and cache.shape[1] > 0:
-        _select_matches(op, tc, pt.shape[-1] * cache.shape[1])
+    rows = _paged_rows_of(op, tc)
+    if rows is not None:
+        _select_matches(op, tc, rows)
     shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
     tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
     tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
@@ -1233,21 +1256,21 @@ def _r_dsa_index(op, tc):
                   if lead is None else lead + (lead[-1],))
 
 
-@rule("dsa_index_paged")
+@rule("dsa_index_paged", "dsa_index_chunk")
 def _r_dsa_index_paged(op, tc):
     d_idx = _dsa_index_widths(op, tc)
     _int_index(op, tc, "PageTable")
-    _int_index(op, tc, "Lens")
+    if op.type == "dsa_index_paged":
+        _int_index(op, tc, "Lens")
     x, cache = tc.input_info(op, "X"), tc.input_info(op, "Cache")
-    pt = tc.input_info(op, "PageTable")
     if cache.shape is not None and d_idx:
         _last_dim_is(op, tc, "Cache", d_idx, "lanes (the index key's)")
-    rows = -1
-    if pt.shape is not None and cache.shape is not None and \
-            pt.shape[-1] > 0 and cache.shape[1] > 0:
-        rows = pt.shape[-1] * cache.shape[1]
-    tc.set_output(op, "Scores", dtype="float32", shape=None
-                  if x.shape is None else (x.shape[0], 1, rows))
+    rows = _paged_rows_of(op, tc) or -1
+    # the decode step: a row a slot; a chunk: its rows, of one slot
+    lead = None if x.shape is None else (x.shape[0], 1) \
+        if op.type == "dsa_index_paged" else tuple(x.shape[:2])
+    tc.set_output(op, "Scores", dtype="float32",
+                  shape=None if lead is None else lead + (rows,))
     tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
 
 
@@ -1259,6 +1282,7 @@ def _r_dsa_select(op, tc):
                   f"must be float32 (the selection compares their bits), "
                   f"got {sc.dtype}", op=op, var=op.input("Scores")[0])
     _int_index(op, tc, "Lens")
+    _int_index(op, tc, "Pos")
     if int(op.attr("top_k")) < 1:
         tc.report("PTA006", "dsa_select keeps top_k >= 1 rows", op=op,
                   var=op.input("Scores")[0])

@@ -37,10 +37,11 @@ slot's row of each with the slot's pages, and the decode step reads and
 writes them whole, in place.  What the meta holds decides; there is no
 flag.
 
-A bundle whose meta carries ``prefill_chunks`` (``models/window_moe.py``)
-prefills a prompt as a SEQUENCE OF CHUNKS (:meth:`GenPredictor.
-prefill_chunk`): each one compiled call, keyed by (chunk rows, page
-bucket), that reads the slot's earlier rows from the pools and the
+A bundle whose meta carries ``prefill_chunks`` (``models/window_moe.py``,
+``models/latent_moe.py``) prefills a prompt as a SEQUENCE OF CHUNKS
+(:meth:`GenPredictor.prefill_chunk`): each one compiled call, keyed by
+(chunk rows, page bucket), that reads the slot's earlier rows from the
+pools and the
 per-slot state where the decode step reads them and writes its own rows
 there, so nothing seeds the slot afterwards and the scheduler can run a
 decode turn between two chunks.  :meth:`prefill` + :meth:`write_slot`
@@ -443,15 +444,35 @@ class GenPredictor:
     def _chunk_shape(self, start, n):
         """``(rows, pages)`` the chunk of ``n`` tokens at position
         ``start`` runs at: the smallest rung that holds it and the
-        smallest page bucket that covers its last row (the jit key)."""
-        from paddle_tpu.lod import row_bucket
+        smallest page bucket that covers its last row and is no smaller
+        than the rung itself (the jit key; :meth:`_chunk_shapes` has
+        every one there is)."""
         rows = next((c for c in self.prefill_chunks if c >= n), None)
         if rows is None:
             raise ValueError(f"a chunk of {n} tokens exceeds the bundle's "
                              f"largest, {self.prefill_chunks[-1]} rows")
-        need = -(-(start + n) // self.page_len)
-        return rows, min(row_bucket(need, edges=self.page_buckets),
-                         self.pages_per_slot)
+        if start + n > self.max_prompt_len:
+            raise ValueError(
+                f"a chunk that ends at row {start + n} lies past the "
+                f"bundle's max prompt length {self.max_prompt_len}")
+        return rows, self._pages_bucket(max(start + n, rows))
+
+    def _pages_bucket(self, rows):
+        """The smallest declared page bucket that covers ``rows`` rows
+        (clamped to ``pages_per_slot``)."""
+        from paddle_tpu.lod import row_bucket
+        return min(row_bucket(-(-rows // self.page_len),
+                              edges=self.page_buckets), self.pages_per_slot)
+
+    def _chunk_shapes(self):
+        """Every ``(rows, pages)`` a chunk can run at, in order: a rung
+        over the page buckets from its own rows' to the longest
+        prompt's.  What :meth:`warmup` compiles."""
+        top = self._pages_bucket(self.max_prompt_len)
+        return [(c, P) for c in self.prefill_chunks
+                for P in sorted({min(int(b), self.pages_per_slot)
+                                 for b in self.page_buckets})
+                if self._pages_bucket(c) <= P <= top]
 
     def chunk_cost(self, start, n):
         """Static FLOPs of ONE prefill chunk of ``n`` tokens at position
@@ -662,8 +683,7 @@ class GenPredictor:
         feed = self._prefill_feed(prompt, self._bucket(len(prompt)))
         with self._lock:
             with self._fluid.scope_guard(self._scope):
-                with _span("gen.prefill", tokens=len(prompt),
-                           **self._prefill_selections(len(prompt))):
+                with _span("gen.prefill", tokens=len(prompt)):
                     outs = self._exe.run(self._pre_prog, feed=feed,
                                          fetch_list=self._pre_fetch,
                                          return_numpy=False)
@@ -723,7 +743,9 @@ class GenPredictor:
         One ``gen.prefill`` span a call, carrying THIS chunk's
         ``tokens``, ``start``, ``rows`` (as run, pads included),
         ``pages`` and, with window layers, its own ``band_pairs`` /
-        ``causal_pairs`` / ``*_key_blocks``.  Always-on:
+        ``causal_pairs`` / ``*_key_blocks``, under learned sparse
+        attention its own ``dsa_rows_scored`` / ``dsa_rows_selected``.
+        Always-on:
         ``gen.prefill.chunks``, ``gen.prefill.rows`` (real) and
         ``gen.prefill.pad_rows``."""
         from paddle_tpu.lod import pad_to_bucket
@@ -737,8 +759,9 @@ class GenPredictor:
                 "gen_pos": start + np.arange(rows, dtype=np.int32)[None],
                 "gen_mask": pad_to_bucket(np.ones((1, n), np.float32), rows,
                                           axis=1),
-                "gen_last": last,
-                "gen_slot": np.full((1, 1), slot, np.int32)}
+                "gen_last": last}
+        if "gen_slot" in self._pre_feeds:   # a bundle with state a slot
+            feed["gen_slot"] = np.full((1, 1), slot, np.int32)
         if "gen_next_ids" in self._pre_feeds:
             follows = np.append(ids[0, 1:], -1 if after is None else after)
             feed["gen_next_ids"] = pad_to_bucket(
@@ -751,7 +774,8 @@ class GenPredictor:
             with self._fluid.scope_guard(self._scope):
                 with _span("gen.prefill", tokens=n, start=start, rows=rows,
                            pages=pages,
-                           **self._chunk_pairs(start, n, rows, pages)):
+                           **self._chunk_pairs(start, n, rows, pages),
+                           **self._chunk_selections(start, n)):
                     logits, = self._exe.run(self._pre_prog, feed=feed,
                                             fetch_list=self._pre_fetch,
                                             return_numpy=False)
@@ -760,17 +784,23 @@ class GenPredictor:
         runtime_metrics.inc("gen.prefill.pad_rows", rows - n)
         return logits
 
-    def _prefill_selections(self, n):
-        """A prompt of ``n`` rows under learned sparse attention: query
-        row ``t`` of every indexer scores ``t + 1`` rows and selects
-        ``min(t + 1, top_k)`` of them.  Span attributes; {} without."""
+    def _chunk_selections(self, start, n):
+        """A chunk of ``n`` real rows at positions ``start ..`` under
+        learned sparse attention: the query row at position ``t`` of
+        every indexer scores ``t + 1`` rows and selects ``min(t + 1,
+        top_k)`` of them (a prompt's chunks sum to its whole triangle).
+        Span attributes; {} without."""
         sp = self.sparse_attention
         if not sp:
             return {}
-        k = min(int(sp["top_k"]), n)
-        return {"dsa_rows_scored": sp["indexers"] * n * (n + 1) // 2,
-                "dsa_rows_selected": sp["indexers"]
-                * (k * (k + 1) // 2 + (n - k) * k)}
+
+        def through(m):     # (scored, selected) of rows 0 .. m - 1
+            k = min(int(sp["top_k"]), m)
+            return m * (m + 1) // 2, k * (k + 1) // 2 + (m - k) * k
+
+        (s1, k1), (s0, k0) = through(start + n), through(start)
+        return {"dsa_rows_scored": sp["indexers"] * (s1 - s0),
+                "dsa_rows_selected": sp["indexers"] * (k1 - k0)}
 
     def _chunk_pairs(self, start, n, rows, pages):
         """A chunk of ``n`` real rows at positions ``start ..``, run as
@@ -1320,7 +1350,8 @@ class GenPredictor:
     def warmup(self):
         """AOT-compile EVERY signature an admission or a decode turn
         uses — one prefill signature per declared prompt bucket (a chunk
-        bundle: per chunk rung and page bucket), one decode turn per
+        bundle: per chunk rung and page bucket a chunk can run at,
+        :meth:`_chunk_shapes`), one decode turn per
         declared page bucket (step, pick and state advance are one
         executable) and one seeding signature per prompt bucket
         (:func:`_seed_pool`; a chunk bundle: ``clear_slot``'s alone) —
@@ -1333,16 +1364,16 @@ class GenPredictor:
         buckets = [b for b in self.prompt_buckets if b <= self.max_len]
         allow = False
         if self.prefill_chunks:
-            # every (chunk rows, page bucket); zero feeds mask every row,
-            # so the caches pass through as they were.  Of the seeding
-            # signatures only ``clear_slot``'s: no admission seeds
+            # every (chunk rows, page bucket) a chunk can run at; zero
+            # feeds mask every row, so the caches pass through as they
+            # were.  Of the seeding signatures only ``clear_slot``'s: no
+            # admission seeds
             sigs = [{k: v for k, v in {
                 "gen_ids": (1, c), "gen_pos": (1, c), "gen_mask": (1, c),
                 "gen_last": (1, c), "gen_slot": (1, 1),
-                "gen_page_table": (1, int(P)),
+                "gen_page_table": (1, P),
                 "gen_next_ids": (1, c)}.items() if k in self._pre_feeds}
-                    for c in self.prefill_chunks for P in self.page_buckets
-                    if P <= self.pages_per_slot]
+                    for c, P in self._chunk_shapes()]
             allow, buckets = self.cache_vars + self.state_vars, buckets[:1]
         else:
             sigs = [{k: v for k, v in {

@@ -43,7 +43,8 @@ from paddle_tpu.layer_helper import LayerHelper
 from paddle_tpu.param_attr import ParamAttr
 
 __all__ = ["META_FILENAME", "PAGE_LEN_DEFAULT", "DECODE_STATS",
-           "DecoderConfig", "default_page_buckets", "op", "param", "matrix",
+           "CHUNK_ROWS", "DecoderConfig", "default_page_buckets",
+           "chunk_rows", "op", "param", "matrix",
            "vector", "data", "persistable", "rms", "head_norm", "embed",
            "logits",
            "gated_ffn", "routed_experts", "decoder_layer", "shared",
@@ -60,6 +61,14 @@ PAGE_LEN_DEFAULT = 16
 DECODE_STATS = [{"name": "moe_assignments", "reduce": "sum"},
                 {"name": "moe_experts_touched", "reduce": "sum"},
                 {"name": "moe_max_load", "reduce": "max"}]
+
+# rows of a prefill chunk (the larger rung).  A chunk reads every matrix
+# once, so it should hold several times the rows at which a v5e's
+# products take as long as their operands' reads (~240), and it is what
+# a live stream waits through between two of its tokens, so no more:
+# PERF.md section 6 (PR 42, PR 46) has the chip's readings at 512 / 1024
+# / 2048
+CHUNK_ROWS = 1024
 
 
 class DecoderConfig:
@@ -99,6 +108,27 @@ def default_page_buckets(pages_per_slot):
         b *= 2
     edges.append(int(pages_per_slot))
     return sorted(set(edges))
+
+
+def chunk_rows(page_len, prompt_buckets, max_len):
+    """The chunk rungs of a bundle whose prefill is a chunk program,
+    ascending, from its shapes (``prompt_buckets`` bound and describe
+    its prompts): the larger is ``CHUNK_ROWS`` (no more than the longest
+    prompt takes, whole pages); the smaller, half of it where that is
+    whole pages too, is for a prompt's LAST chunk, which takes it where
+    it fits, so that a prompt runs no more than half the larger rung in
+    pad rows.  Every rung is an executable a page bucket to compile and
+    to warm, so the half rung is left out where it buys little: in a
+    bundle whose SHORTEST prompt bucket already spans two of the larger
+    rung, where the pad rows it saves are a few hundredths of a
+    prompt's."""
+    page_len = int(page_len)
+    top = min(int(CHUNK_ROWS), min(max(prompt_buckets), int(max_len)))
+    top = max(-(-top // page_len) * page_len, page_len)
+    half = top // 2
+    if half % page_len or not half or min(prompt_buckets) >= 2 * top:
+        return [top]
+    return [half, top]
 
 
 # ---------------------------------------------------------------------------

@@ -1,7 +1,8 @@
 """Latent-attention / shared-expert mixture-of-experts causal LM (the
 ``deepseek_v3`` / ``kimi_k2`` families' block) on the generative serving
 path: the same prefill + paged-decode program pair and bundle layout as
-``models/gen_lm.py`` and ``models/hybrid_moe.py``.
+``models/gen_lm.py`` and ``models/hybrid_moe.py``, its prefill ONE CHUNK
+of a prompt over the slot's own pools as ``models/window_moe.py``'s.
 
 Every layer is pre-norm and holds two sublayers, ``x <- x +
 MLA(RMSNorm(x))`` then ``x <- x + FFN(RMSNorm(x))``; a final RMSNorm
@@ -14,20 +15,25 @@ precedes the untied head.
   | k_r]`` and nothing else, stored ``latent_row`` wide (the next
   multiple of 128 lanes, zeros behind: the chip's DMA takes whole vregs,
   and a 576-wide array is laid out 640 wide in its memory anyway).  TWO
-  attention programs over the same weights: the prefill EXPANDS K and V
-  of every head from the latent (``mla_attention``); the decode step
-  ABSORBS ``W_kvb`` into the query and out of the context (``mla_absorb``)
-  and attends over the cached rows as they are
-  (``paged_attention_latent``), reading each once for scores and values.
+  attention forms over the same weights: a whole sequence (the training
+  forward) EXPANDS K and V of every head from the latent
+  (``mla_attention``); the serving programs ABSORB ``W_kvb`` into the
+  query and out of the context and attend over the cached rows as they
+  are, reading each once for scores and values: a chunk of a prompt
+  writes its rows into the slot's pages and attends the pages' rows
+  through its own (``mla_attention_chunk``), the decode step does with
+  its one row (``mla_absorb``, ``paged_attention_latent``).
 * **Learned sparse attention** (``ops/dsa_ops.py``; a configuration
   with ``index_topk``, the ``glm_moe_dsa`` family).  A layer whose
   ``indexer_types`` entry is ``"full"`` holds an indexer: it scores
   every cached row for the query row (``dsa_index`` /
-  ``dsa_index_paged``; its 128-lane key a token is cached in a SECOND
-  page pool under the same page table) and keeps the ``index_topk``
-  best (``dsa_select``); the layer's attention, and that of the
-  ``"shared"`` layers after it, runs over the selected rows and nowhere
-  else.  Up to ``index_topk`` rows the selection is the identity.  A
+  ``dsa_index_chunk`` / ``dsa_index_paged``; its 128-lane key a token is
+  cached in a SECOND page pool under the same page table) and keeps the
+  ``index_topk`` best (``dsa_select``); the layer's attention, and that
+  of the ``"shared"`` layers after it, runs over the selected rows and
+  nowhere else.  A row's selection depends on its own query and the keys
+  at or before it alone, so nothing but the two pools crosses a chunk's
+  edge.  Up to ``index_topk`` rows the selection is the identity.  A
   configuration without ``index_topk`` builds none of this.
 * **FFN**.  The first ``first_k_dense_replace`` layers: ``W_d (silu(W_g
   h) * W_u h)``, width ``intermediate_size`` (or the layers that
@@ -43,12 +49,13 @@ Matrices and activations are ``dtype`` (bfloat16) with float32
 accumulation; router scores, norm statistics, rotary angles, softmax and
 logits are float32; the latent pool is ``dtype``.
 
-``export_latent_model`` writes ``prefill/``, ``decode/`` and
-``gen_meta.json``; ``latent_moe_train_program`` is the teacher-forced
+``export_latent_model`` writes ``prefill/`` (the chunk program: a prompt
+runs as chunks of ``prefill_chunks`` rows, ``gen_meta.json``), ``decode/``
+and ``gen_meta.json``; ``latent_moe_train_program`` is the teacher-forced
 training graph over the same parameter names (the model-zoo lint gate's
-view of this model).  The prefill feeds ``gen_ids``, ``gen_pos``,
-``gen_mask``, ``gen_last`` and fetches ``[logits, latent row per layer
-...]``; the decode step fetches ``[logits, stats]`` with ``stats``
+view of this model).  The chunk program feeds ``gen_ids``, ``gen_pos``,
+``gen_mask``, ``gen_last``, ``gen_page_table`` and fetches ``[logits]``;
+the decode step fetches ``[logits, stats]`` with ``stats``
 ``[n_moe_layers, 3]`` int32 (``decode_stats``, as ``hybrid_moe``).
 """
 
@@ -56,15 +63,16 @@ from __future__ import annotations
 
 import paddle_tpu.layers as layers
 from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
-                                       DecoderConfig, decode_fetches,
-                                       decode_inputs, decoder_layer, embed,
+                                       DecoderConfig, chunk_rows, data,
+                                       decode_fetches, decode_inputs,
+                                       decoder_layer, embed,
                                        export_bundle, gated_ffn, last_row,
                                        logits, matrix, op, persistable,
                                        prefill_inputs, rms, routed_experts,
                                        train_inputs, train_loss, vector)
 from paddle_tpu.ops.mla_ops import yarn_mscale
 
-__all__ = ["LatentMoEConfig", "build_prefill_program",
+__all__ = ["LatentMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "latent_moe_train_program",
            "export_latent_model", "paged_cache_var_names"]
 
@@ -187,8 +195,11 @@ def paged_cache_var_names(hp):
 
 
 def _indexer(h, c_q, hp, i, pos, mask=None, paged=None):
-    """The indexer of a ``full`` layer: returns ``(selection, the
-    prompt's key rows (pad rows not yet zeroed) or None)``."""
+    """The indexer of a ``full`` layer, in :func:`_attention`'s three
+    forms: over a whole sequence (``mask`` alone), ONE CHUNK of a prompt
+    (``mask`` and ``paged`` = key pool, page table [1, P]) or the decode
+    step (``paged`` = key pool, page table, lens).  Returns the
+    selection."""
     d, Hi, Di = (int(hp.hidden_size), int(hp.index_n_heads),
                  int(hp.index_head_dim))
     k = int(hp.index_topk)
@@ -202,29 +213,39 @@ def _indexer(h, c_q, hp, i, pos, mask=None, paged=None):
     attrs = {"n_head": Hi, "rope_dim": int(hp.qk_rope_head_dim),
              "theta": float(hp.rope_theta), "top_k": k}
     if paged is None:
-        out = op("dsa_index", inputs, {"Key": hp.dtype,
-                                       "Scores": "float32"}, attrs)
-        sel = op("dsa_select", {"Scores": out["Scores"], "Mask": mask},
-                 {"Select": "int8"}, {"top_k": k})["Select"]
-        return sel, out["Key"]
-    pool, page_table, lens = paged
+        scores = op("dsa_index", inputs, {"Key": hp.dtype,
+                                          "Scores": "float32"},
+                    attrs)["Scores"]
+        return op("dsa_select", {"Scores": scores, "Mask": mask},
+                  {"Select": "int8"}, {"top_k": k})["Select"]
+    pool, page_table, *lens = paged
+    if not lens:
+        scores = op("dsa_index_chunk",
+                    {**inputs, "Mask": mask, "Cache": pool,
+                     "PageTable": page_table},
+                    {"Scores": "float32", "CacheOut": pool},
+                    attrs)["Scores"]
+        return op("dsa_select", {"Scores": scores, "Mask": mask,
+                                 "Pos": pos},
+                  {"Select": "int8"}, {"top_k": k})["Select"]
     scores = op("dsa_index_paged",
                 {**inputs, "Cache": pool, "PageTable": page_table,
-                 "Lens": lens},
+                 "Lens": lens[0]},
                 {"Scores": "float32", "CacheOut": pool}, attrs)["Scores"]
-    sel = op("dsa_select", {"Scores": scores, "Lens": lens},
-             {"Select": "int32"}, {"top_k": k})["Select"]
-    return sel, None
+    return op("dsa_select", {"Scores": scores, "Lens": lens[0]},
+              {"Select": "int32"}, {"top_k": k})["Select"]
 
 
 def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
                index_pool=None):
-    """MLA: prefill (expanded; returns the masked latent rows that seed
-    the pool) or the absorbed paged decode (``paged`` = pool, page
-    table, lens).  ``select``: the selection a ``shared`` layer attends
-    under; a ``full`` layer makes its own (``index_pool``: its key pool
-    in the decode step).  Returns ``(out, latent row, selection, index
-    key rows or None)``."""
+    """MLA, one of three forms.  ``mask`` alone: a whole sequence,
+    expanded, nothing cached (the training forward).  ``mask`` and
+    ``paged`` = (pool, page table [1, P]): ONE CHUNK of a prompt over the
+    slot's own pages, which it writes and reads as they are cached.
+    ``paged`` = (pool, page table, lens): the absorbed paged decode.
+    ``select``: the selection a ``shared`` layer attends under; a
+    ``full`` layer makes its own (``index_pool``: its key pool in the
+    serving forms).  Returns ``(out, selection)``."""
     d, H = int(hp.hidden_size), int(hp.num_attention_heads)
     L, R = int(hp.kv_lora_rank), int(hp.qk_rope_head_dim)
     nope, vd = int(hp.qk_nope_head_dim), int(hp.v_head_dim)
@@ -234,11 +255,9 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
               f"lat{i}_qnorm.scale", hp)
     q = layers.matmul(c_q, matrix(hp, f"lat{i}_qb.w",
                                   [int(hp.q_lora_rank), H * (nope + R)]))
-    index_key = None
     if hp.indexer(i) == "full":
-        select, index_key = _indexer(
-            h, c_q, hp, i, pos, mask=mask,
-            paged=paged and (index_pool,) + tuple(paged[1:]))
+        select = _indexer(h, c_q, hp, i, pos, mask=mask,
+                          paged=paged and (index_pool,) + tuple(paged[1:]))
     elif hp.indexer(i) is None:
         select = None
     sparse = {} if select is None else {"Select": select}
@@ -265,6 +284,15 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
                  {"Out": hp.dtype},
                  {**attrs, "rope_dim": R, "scale": scale,
                   **sparse_attrs})["Out"]
+    elif mask is not None:
+        pool, page_table = paged
+        ctx = op("mla_attention_chunk",
+                 {"Q": q, "Latent": row, "Wkvb": w_kvb, "Cache": pool,
+                  "PageTable": page_table, "Pos": pos, "Mask": mask,
+                  **sparse},
+                 {"Out": hp.dtype, "CacheOut": pool},
+                 {**attrs, "rope_dim": R, "scale": scale,
+                  **sparse_attrs})["Out"]
     else:
         pool, page_table, lens = paged
         q_lat = op("mla_absorb", {"X": q, "Wkvb": w_kvb},
@@ -280,7 +308,7 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
         ctx = op("mla_absorb", {"X": ctx, "Wkvb": w_kvb},
                  {"Out": hp.dtype}, {**attrs, "side": "o"})["Out"]
     return layers.matmul(ctx, matrix(hp, f"lat{i}_o.w", [H * vd, d])), \
-        row, select, index_key
+        select
 
 
 def _ffn(h, hp, i, lens):
@@ -301,45 +329,59 @@ def _ffn(h, hp, i, lens):
 
 def _layer(x, hp, i, pos, lens, mask=None, paged=None, select=None,
            index_pool=None):
-    """One layer; returns ``(x, latent row, stats or None, selection,
-    index key rows or None)``: the selection is the layer's own where it
-    holds an indexer, else the one it was handed.  ``lens`` [rows, 1]
-    int32: a row with 0 (a free slot, a pad row) takes no routed
-    expert."""
-    def attention(h):
-        out, *kept = _attention(h, hp, i, pos, mask=mask, paged=paged,
-                                select=select, index_pool=index_pool)
-        return out, kept
-
-    x, (row, select, index_key), stats = decoder_layer(
-        x, hp, f"lat{i}", attention, lambda h: _ffn(h, hp, i, lens))
-    return x, row, stats, select, index_key
+    """One layer; returns ``(x, stats or None, selection)``: the
+    selection is the layer's own where it holds an indexer, else the one
+    it was handed.  ``lens`` [rows, 1] int32: a row with 0 (a free slot,
+    a pad row) takes no routed expert."""
+    x, select, stats = decoder_layer(
+        x, hp, f"lat{i}",
+        lambda h: _attention(h, hp, i, pos, mask=mask, paged=paged,
+                             select=select, index_pool=index_pool),
+        lambda h: _ffn(h, hp, i, lens))
+    return x, stats, select
 
 
-def build_prefill_program(hp):
-    """The prefill forward in the CURRENT program guard.
+def _pools(hp, page_len, num_pages):
+    """The persistable page pools of the CURRENT program, ``{name:
+    var}``, all ``hp.dtype``: one latent pool a layer ``[num_pages,
+    page_len, latent_row]`` and one index-key pool a layer that holds an
+    indexer ``[num_pages, page_len, index_head_dim]``."""
+    return {name: persistable(
+        name, [int(num_pages), int(page_len),
+               int(hp.index_head_dim) if name.endswith("_ik")
+               else hp.latent_row], hp.dtype)
+        for name in paged_cache_var_names(hp)}
 
-    Feeds (length-dynamic; callers pad to a bucket): ``gen_ids`` [1, T]
-    int32, ``gen_pos`` [1, T] int32 (0 .. T-1), ``gen_mask`` [1, T] f32
-    (1 = real token, real tokens first), ``gen_last`` [1, T] f32 (one-hot
-    of the last real position).  The causal mask is built in the graph
-    from ``gen_mask``.  Fetches ``[logits [1, V], latent row per layer
-    [1, T, latent_row] ..., index key rows per indexer [1, T,
-    index_head_dim] ...]``, zeroed on pad rows."""
+
+def build_chunk_program(hp, num_slots, page_len, num_pages):
+    """The prefill of ONE CHUNK of a prompt in the CURRENT program guard.
+
+    Feeds (length-dynamic; the predictor pads to a chunk rung), the
+    names and meanings of ``window_moe.build_chunk_program``'s:
+    ``gen_ids`` [1, C] int32, ``gen_pos`` [1, C] int32 (the rows'
+    positions ``P .. P + C - 1``), ``gen_mask`` [1, C] f32 (1 = real
+    token, real tokens first), ``gen_last`` [1, C] f32 (one-hot of the
+    prompt's last row where this chunk holds it, else zeros) and
+    ``gen_page_table`` [1, P] int32 (the slot's row, P bucketed by the
+    predictor and covering the chunk's last real row); no ``gen_slot``:
+    nothing here is kept a slot.  Persistable state, read and updated in
+    place, as the decode step's: the latent pools and the index-key
+    pools.  Fetches ``[logits [1, V]]`` (of the row ``gen_last``
+    names)."""
     ids, pos, mask, last = prefill_inputs()
+    page_table = data("gen_page_table", [1, -1], "int32")
+    pools = _pools(hp, page_len, num_pages)
     # pad rows take no routed expert
     lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
     x = embed(ids, hp, "lat")
-    rows, keys, select = [], [], None
+    select = None
     for i in range(int(hp.num_hidden_layers)):
-        x, row, _, select, key = _layer(x, hp, i, pos, lens, mask=mask,
-                                        select=select)
-        rows.append(row)
-        if key is not None:     # seeds the index-key pool: zeros on pads
-            keys.append(layers.elementwise_mul(
-                key, layers.cast(mask, hp.dtype), axis=0))
-    return (["gen_ids", "gen_pos", "gen_mask", "gen_last"],
-            [logits(last_row(x, last, hp), hp, "lat")] + rows + keys)
+        x, _, select = _layer(
+            x, hp, i, pos, lens, mask=mask,
+            paged=(pools[f"lat{i}_paged_c"], page_table), select=select,
+            index_pool=pools.get(f"lat{i}_paged_ik"))
+    return (["gen_ids", "gen_pos", "gen_mask", "gen_last",
+             "gen_page_table"], [logits(last_row(x, last, hp), hp, "lat")])
 
 
 def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
@@ -353,8 +395,8 @@ def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
     x = embed(ids, hp, "lat")
     select = None
     for i in range(int(hp.num_hidden_layers)):
-        x, _, _, select, _ = _layer(x, hp, i, rows["pos"], rows["lens"],
-                                    mask=rows["mask"], select=select)
+        x, _, select = _layer(x, hp, i, rows["pos"], rows["lens"],
+                              mask=rows["mask"], select=select)
     return train_loss(x, labels, hp, "lat")
 
 
@@ -371,16 +413,12 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     ``[logits [S, V], stats [n_moe, 3]]``."""
     S = int(num_slots)
     token, pos, page_table, lens = decode_inputs(S)
-    pools = {name: persistable(
-        name, [int(num_pages), int(page_len),
-               int(hp.index_head_dim) if name.endswith("_ik")
-               else hp.latent_row], hp.dtype)
-        for name in paged_cache_var_names(hp)}
+    pools = _pools(hp, page_len, num_pages)
     x = layers.reshape(embed(token, hp, "lat"),
                        shape=[S, 1, int(hp.hidden_size)])
     stats, select = [], None
     for i in range(int(hp.num_hidden_layers)):
-        x, _, st, select, _ = _layer(
+        x, st, select = _layer(
             x, hp, i, pos, lens,
             paged=(pools[f"lat{i}_paged_c"], page_table, lens),
             select=select, index_pool=pools.get(f"lat{i}_paged_ik"))
@@ -393,18 +431,27 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
 def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
                         prompt_buckets=None, page_len=PAGE_LEN_DEFAULT,
                         num_pages=None, page_buckets=None):
-    """Export a generation bundle (``decoder.export_bundle``);
-    ``cache_vars`` names ONE pool a layer, and one a layer that holds an
-    indexer.  Returns ``dirname``."""
+    """Export a generation bundle (``decoder.export_bundle``), its
+    ``prefill`` the chunk program (``prefill_chunks`` in the meta, from
+    the bundle's shapes; ``prompt_buckets`` bounds the longest prompt
+    and is what ``GenPredictor.prefill`` + ``write_slot`` hand rows over
+    in).  ``cache_vars`` names ONE pool a layer, and one a layer that
+    holds an indexer.  Returns ``dirname``."""
     hp = hp or LatentMoEConfig()
-    sections = {"decode_stats": DECODE_STATS if hp.moe_layers else []}
-    if hp.full_layers:
-        # what the predictor counts a decode step's selections from
-        sections["sparse_attention"] = {"top_k": int(hp.index_topk),
-                                        "indexers": len(hp.full_layers)}
+
+    def sections(meta):
+        own = {"decode_stats": DECODE_STATS if hp.moe_layers else [],
+               "prefill_chunks": chunk_rows(
+                   meta["page_len"], meta["prompt_buckets"], hp.max_len)}
+        if hp.full_layers:
+            # what the predictor counts a step's selections from
+            own["sparse_attention"] = {"top_k": int(hp.index_topk),
+                                       "indexers": len(hp.full_layers)}
+        return own
+
     return export_bundle(
         dirname, hp, "latent_moe.export_latent_model",
-        lambda *pool: build_prefill_program(hp),
+        lambda *pool: build_chunk_program(hp, *pool),
         lambda *pool: build_paged_decode_program(hp, *pool),
         paged_cache_var_names(hp), hp.num_hidden_layers,
         num_slots=num_slots, prompt_buckets=prompt_buckets,

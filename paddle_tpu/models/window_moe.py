@@ -76,7 +76,8 @@ from __future__ import annotations
 
 import paddle_tpu.layers as layers
 from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
-                                       DecoderConfig, data, decode_fetches,
+                                       DecoderConfig, chunk_rows, data,
+                                       decode_fetches,
                                        decode_inputs, decoder_layer, embed,
                                        export_bundle, gated_ffn, head_norm,
                                        last_row, logits, matrix, mtp_logits,
@@ -87,19 +88,12 @@ from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
 __all__ = ["WindowMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "window_moe_train_program",
            "export_window_model", "paged_cache_var_names",
-           "ring_var_names", "chunk_rows", "MTP", "DRAFT_VAR"]
+           "ring_var_names", "MTP", "DRAFT_VAR"]
 
 #: the layer key of the MTP module's block (its parameters are
 #: ``win_mtp_*``), and the per-slot state that holds a slot's draft
 MTP = "_mtp"
 DRAFT_VAR = "win_draft"
-
-# rows of a prefill chunk (the larger rung).  A chunk reads every matrix
-# once, so it should hold several times the rows at which a v5e's
-# products take as long as their operands' reads (~240), and it is what
-# a live stream waits through between two of its tokens, so no more:
-# PERF.md section 6 (PR 42) has the chip's readings at 512 / 1024 / 2048
-CHUNK_ROWS = 1024
 
 
 class WindowMoEConfig(DecoderConfig):
@@ -545,19 +539,6 @@ def _build_draft_step(hp, num_slots, page_len, num_pages):
              verdict["Out"]])
 
 
-def chunk_rows(page_len, max_prompt):
-    """The bundle's chunk rungs, ascending, from its shapes: the larger
-    is ``CHUNK_ROWS`` (no more than the longest prompt takes, whole
-    pages), the smaller half of it where that is whole pages too: a
-    prompt's LAST chunk takes it where it fits, so a prompt runs no more
-    than half the larger rung in pad rows."""
-    page_len = int(page_len)
-    top = min(int(CHUNK_ROWS), int(max_prompt))
-    top = max(-(-top // page_len) * page_len, page_len)
-    half = top // 2
-    return [top] if half % page_len or not half else [half, top]
-
-
 def _window_section(hp):
     """``gen_meta.json``'s ``window_attention``: which layer keeps which
     kind of cache, and what the predictor counts a step's reads from."""
@@ -602,8 +583,7 @@ def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
     def sections(meta):
         own = {"decode_stats": DECODE_STATS if hp.moe_layers else [],
                "prefill_chunks": chunk_rows(
-                   meta["page_len"],
-                   min(max(meta["prompt_buckets"]), int(hp.max_len)))}
+                   meta["page_len"], meta["prompt_buckets"], hp.max_len)}
         if hp.window_layers:
             own["window_attention"] = _window_section(hp)
         if hp.drafts:

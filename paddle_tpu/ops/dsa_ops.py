@@ -17,17 +17,24 @@ query's low-rank latent after its norm::
     w   = (h W_w) n_head^-1/2 head_dim^-1/2
     I(t, s) = sum_j w_j(t) ReLU(q_j(t) . k(s))             float32
 
-``dsa_index`` is the prefill form (one prompt, ``Scores`` [1, T, T]);
-``dsa_index_paged`` the decode step's (writes this step's key row into
-the pool, scores the page bucket's rows: ``Scores`` [S, 1, P * page_len]
-in the slot's own row order).  ``dsa_select`` keeps, a query row, the
-``top_k`` largest scores among the rows the query may see (causal and
-real in the prefill, ``< Lens`` in the decode step), ties to the lower
-position as ``jax.lax.top_k`` has them, ALL of them while there are no
-more than ``top_k``: ``Select`` is a 0/1 mask, int8 [1, T, T] or int32
-[S, 1, P * page_len].  Where the rows cannot pass ``top_k`` (a bucket of
-no more rows) the selection is the identity: nothing is scored, the mask
-is what the query may see, and the attention ops skip it.
+``dsa_index`` is the whole-sequence form (one prompt, ``Scores`` [1, T,
+T]); ``dsa_index_chunk`` the serving prefill's (ONE CHUNK of a prompt:
+writes the chunk's key rows into the slot's pages of the pool, scores
+its C query rows against the page bucket's rows: ``Scores`` [1, C, P *
+page_len]); ``dsa_index_paged`` the decode step's (writes this step's
+key row into the pool, scores the page bucket's rows: ``Scores`` [S, 1,
+P * page_len]); both paged forms in the slot's own row order.
+``dsa_select`` keeps, a query row, the ``top_k`` largest scores among
+the rows the query may see (causal and real in the prefill, whole or by
+chunks; ``< Lens`` in the decode step), ties to the lower position as
+``jax.lax.top_k`` has them, ALL of them while there are no more than
+``top_k``: ``Select`` is a 0/1 mask, int8 [1, T, T] or [1, C, P *
+page_len], or int32 [S, 1, P * page_len].  A row's selection depends on
+that row's query and the keys at or before it alone, so a prompt's
+chunks select what the whole prompt would.  Where the rows cannot pass
+``top_k`` (a bucket of no more rows) the selection is the identity:
+nothing is scored, the mask is what the query may see, and the attention
+ops skip it.
 
 The selection is exact and takes no sort: the ``top_k``-th largest score
 of a row is found bit by bit (32 counting passes over an
@@ -35,10 +42,12 @@ order-preserving integer image of the float32 scores), then the ties at
 that value are taken from the left (a running count made of two small
 triangular products, not a cumulative sum over the whole row).
 
-The prefill's attention under a selection is ``selected_attention``, a
-flash forward kernel that takes the selection's int8 blocks beside the
-keys' (``mla_ops.mla_attention`` calls it on the TPU); the decode step's
-is the latent paged kernel with ``select=``.
+A whole sequence's attention under a selection is
+``selected_attention``, a flash forward kernel that takes the
+selection's int8 blocks beside the keys' (``mla_ops.mla_attention``
+calls it on the TPU); a chunk's is ``window_ops``'s kernel with
+``select=`` (``mla_ops.mla_attention_chunk``); the decode step's is the
+latent paged kernel with ``select=``.
 
 Op scopes on the device trace: ``ptop_dsa_index*`` (projections, rotary
 lanes, scores), ``ptop_dsa_select*`` (the top-k).
@@ -175,22 +184,27 @@ def _query_block(T, block):
     return block
 
 
-def causal_select(scores, mask, k, block=DSA_QUERY_BLOCK):
-    """The prefill's selection: ``scores`` [T, T], ``mask`` [T] (0 = pad
-    row) -> int8 [T, T], row ``t`` selecting among the real rows ``s <=
-    t``."""
-    T = scores.shape[0]
-    real = (mask > 0)[None, :]
-    block = _query_block(T, block)
+def causal_select(scores, mask, k, block=DSA_QUERY_BLOCK, start=0):
+    """The prefill's selection: ``scores`` [C, T] of C query rows,
+    which stand at key rows ``start ..`` (traced or not), over T key
+    rows; ``mask`` [C] (0 = pad row; real rows first, so the real key
+    rows are those before ``start`` and the real query rows' own) ->
+    int8 [C, T], row ``r`` selecting among the real rows ``s <= start +
+    r``.  A whole prompt is ``start`` 0 with ``C`` = ``T``."""
+    C, T = scores.shape
+    real = jnp.arange(T, dtype=jnp.int32)[None, :] \
+        < start + jnp.sum(mask > 0).astype(jnp.int32)
+    block = _query_block(C, block)
     cols = jax.lax.broadcasted_iota(jnp.int32, (block, T), 1)
 
     def rows(i):
-        row = i * block + jax.lax.broadcasted_iota(jnp.int32, (block, T), 0)
+        row = start + i * block \
+            + jax.lax.broadcasted_iota(jnp.int32, (block, T), 0)
         valid = (cols <= row) & real
         sc = jax.lax.dynamic_slice_in_dim(scores, i * block, block, 0)
         return select_mask(sc, valid, k).astype(jnp.int8)
 
-    return jax.lax.map(rows, jnp.arange(T // block)).reshape(T, T)
+    return jax.lax.map(rows, jnp.arange(C // block)).reshape(C, T)
 
 
 # ---------------------------------------------------------------------------
@@ -285,6 +299,16 @@ def _projections(ctx, c_q, h, pos):
         int(ctx.attr("rope_dim")), float(ctx.attr("theta", 10000.0)))
 
 
+def _query_blocks(fn, C, *arrays):
+    """``fn`` over blocks of ``DSA_QUERY_BLOCK`` of the ``C`` leading
+    rows of ``arrays``, the blocks' results side by side again."""
+    block = _query_block(C, DSA_QUERY_BLOCK)
+    part = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * block, block, 0)
+    out = jax.lax.map(lambda i: fn(*(part(a, i) for a in arrays)),
+                      jnp.arange(C // block))
+    return out.reshape((C,) + out.shape[2:])
+
+
 def _infer_dsa_index(op, block):
     h = block.var(op.input("X")[0])
     wk = block.var(op.input("Wk")[0])
@@ -311,11 +335,8 @@ def dsa_index_lower(ctx):
     if T <= int(ctx.attr("top_k")):
         ctx.set_output("Scores", jnp.zeros((1, T, T), jnp.float32))
         return
-    block = _query_block(T, DSA_QUERY_BLOCK)
-    part = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * block, block, 0)
-    scores = jax.lax.map(lambda i: index_scores(part(q, i), k, part(w, i)),
-                         jnp.arange(T // block))
-    ctx.set_output("Scores", scores.reshape(1, T, T))
+    ctx.set_output("Scores", _query_blocks(
+        lambda qb, wb: index_scores(qb, k, wb), T, q, w)[None])
 
 
 def _infer_dsa_index_paged(op, block):
@@ -326,8 +347,45 @@ def _infer_dsa_index_paged(op, block):
         raise ShapeInferenceSkip()
     P = pt.shape[-1]
     sc = block.var(op.output("Scores")[0])
-    sc.shape = (h.shape[0], 1, P * int(cache.shape[1]) if P > 0 else -1)
+    # the decode step: a row a slot; a chunk: its rows, of ONE slot
+    lead = (h.shape[0], 1) if op.type == "dsa_index_paged" \
+        else tuple(h.shape[:2])
+    sc.shape = lead + (P * int(cache.shape[1]) if P > 0 else -1,)
     sc.dtype = "float32"
+
+
+@register_op("dsa_index_chunk", infer_shape=_infer_dsa_index_paged,
+             no_gradient=True, stateful_outputs=("CacheOut",))
+def dsa_index_chunk_lower(ctx):
+    """The indexer of ONE CHUNK of a prompt.  Cq [1, C, q_lora]; X [1,
+    C, d]; Pos [1, C] the rows' positions ``start .. start + C - 1``;
+    Mask [1, C] (1 = a real row, real rows first); the weights as
+    ``dsa_index``; Cache [num_pages, page_len, D] the persistable
+    index-key pool; PageTable [1, P] the slot's row (P a page bucket
+    that covers the chunk's last real row).  The real rows' keys are
+    written at their positions of the slot's pages, then the chunk's
+    query rows score the bucket's ``P * page_len`` rows.  Scores [1, C,
+    P * page_len] float32, in the slot's row order (what lies behind a
+    query's own row, or behind the chunk's last real row, is whatever
+    the pages hold: ``dsa_select`` never looks); zeros where the bucket
+    cannot pass ``top_k`` rows.  CacheOut names the pool itself."""
+    from paddle_tpu.ops.attention_ops import _paged_cache_update
+    c_q, h = ctx.input("Cq")[0], ctx.input("X")[0]
+    C = h.shape[0]
+    pos, table = ctx.input("Pos").reshape(C), ctx.input("PageTable")
+    q, k, w = _projections(ctx, c_q, h, pos)
+    cache, = _paged_cache_update(
+        (ctx.input("Cache"),), (k[None],), table,
+        (pos[0] + C).astype(jnp.int32).reshape(1, 1),
+        row_lens=ctx.input("Mask") > 0)
+    ctx.set_output("CacheOut", cache)
+    T = table.shape[1] * cache.shape[1]
+    if T <= int(ctx.attr("top_k")):
+        ctx.set_output("Scores", jnp.zeros((1, C, T), jnp.float32))
+        return
+    rows = cache[table[0]].reshape(T, cache.shape[-1]).astype(k.dtype)
+    ctx.set_output("Scores", _query_blocks(
+        lambda qb, wb: index_scores(qb, rows, wb), C, q, w)[None])
 
 
 @register_op("dsa_index_paged", infer_shape=_infer_dsa_index_paged,
@@ -371,10 +429,14 @@ def _infer_dsa_select(op, block):
 
 @register_op("dsa_select", infer_shape=_infer_dsa_select, no_gradient=True)
 def dsa_select_lower(ctx):
-    """Scores [1, T, T] with Mask [1, T] (the prefill: row ``t`` selects
-    among the real rows ``s <= t``; Select int8 [1, T, T]) or Scores [S,
-    1, T] with Lens [S, 1] (the decode step: among the rows ``< Lens``;
-    Select int32 [S, 1, T]).  attr top_k."""
+    """Scores [1, T, T] with Mask [1, T] (a whole prompt: row ``t``
+    selects among the real rows ``s <= t``; Select int8 [1, T, T]);
+    Scores [1, C, T] with Mask [1, C] and Pos [1, C] (ONE CHUNK of a
+    prompt over the slot's ``T`` rows: row ``r`` among the real rows
+    ``s <= Pos[r]``, which are those before the chunk and the chunk's
+    own; Select int8 [1, C, T]); or Scores [S, 1, T] with Lens [S, 1]
+    (the decode step: among the rows ``< Lens``; Select int32 [S, 1,
+    T]).  attr top_k."""
     scores, k = ctx.input("Scores"), int(ctx.attr("top_k"))
     if ctx.has_input("Lens"):
         T = scores.shape[-1]
@@ -383,5 +445,7 @@ def dsa_select_lower(ctx):
         ctx.set_output("Select",
                        select_mask(scores, valid, k).astype(jnp.int32))
     else:
+        start = ctx.input("Pos").reshape(-1)[0].astype(jnp.int32) \
+            if ctx.has_input("Pos") else 0
         ctx.set_output("Select", causal_select(
-            scores[0], ctx.input("Mask")[0], k)[None])
+            scores[0], ctx.input("Mask")[0], k, start=start)[None])

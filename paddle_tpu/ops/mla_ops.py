@@ -19,8 +19,19 @@ a head) and its value half out of the context (``side`` ``"o"``), and
 ``paged_attention_latent`` (``ops/attention_ops.py``) attends over the
 cached rows between the two.
 
+``mla_attention_chunk`` is the serving prefill's form: ONE CHUNK of a
+prompt over the slot's own pages of the latent pool.  It writes the
+chunk's rows where the decode step reads them, then attends the chunk's
+queries (positions ``P .. P + C - 1``) over the pages' rows ``0 .. P + C
+- 1`` AS THEY ARE CACHED: the queries absorbed as the decode step's are,
+every head over the one 640-wide row a token (``window_ops``'s causal
+flash kernel with one K/V head, the diagonal shifted by ``P``, the
+selection's blocks beside the keys' where there is one), the context
+taken out of the latent.  Nothing is expanded, so a chunk's work follows
+the rows under its diagonal and not its page bucket.
+
 Op scopes on the device trace: ``ptop_rope*``, ``ptop_swiglu*``,
-``ptop_mla_attention*``, ``ptop_mla_absorb*``.
+``ptop_mla_attention*`` (whole sequence and chunk), ``ptop_mla_absorb*``.
 """
 
 from __future__ import annotations
@@ -298,3 +309,61 @@ def mla_absorb_lower(ctx):
                      int(ctx.attr("v_dim")), str(ctx.attr("side")),
                      int(ctx.attr("pad", 0)))
     ctx.set_output("Out", out.reshape(x.shape[:-1] + out.shape[-1:]))
+
+
+def mla_attention_chunk(q, row, w_kvb, pool, table, start, real, n_head,
+                        nope, rope_dim, v_dim, scale, select=None,
+                        interpret=None):
+    """ONE CHUNK of a prompt over the slot's pages.  ``q`` [C, H * (nope
+    + rope)] (rotated) and ``row`` [C, W] (the latent rows as cached)
+    stand at positions ``start ..``; ``real`` [1, C] bool (real rows
+    first); ``pool`` [num_pages, page_len, W]; ``table`` [1, P] the
+    slot's pages; ``select`` [C, P * page_len] int8 or None.  The real
+    rows are written at their positions, then the chunk attends the
+    pages' rows ``0 ..`` under the diagonal shifted by ``start`` (and
+    under the selection).  Returns ``(out [C, H * v], pool)``."""
+    from paddle_tpu.ops.attention_ops import _paged_cache_update
+    from paddle_tpu.ops.window_ops import prefill_attention
+    C, L, W = q.shape[0], w_kvb.shape[0], pool.shape[-1]
+    pool, = _paged_cache_update((pool,), (row[None],), table,
+                                (start + C).reshape(1, 1), row_lens=real)
+    keys = pool[table[0]].reshape(-1, W).astype(q.dtype)
+    q_lat = mla_absorb(q, w_kvb, n_head, nope, v_dim, "q",
+                       pad=W - L - rope_dim)
+    ctx = prefill_attention(q_lat, keys, keys[:, :L], None, n_head, 1,
+                            scale, 0, start=start, interpret=interpret,
+                            select=select)
+    return mla_absorb(ctx, w_kvb, n_head, nope, v_dim, "o"), pool
+
+
+@register_op("mla_attention_chunk", infer_shape=_infer_mla_attention,
+             no_gradient=True, stateful_outputs=("CacheOut",))
+def mla_attention_chunk_lower(ctx):
+    """Q [1, C, H * (nope + rope)]; Latent [1, C, W] the chunk's rows as
+    they are cached; Wkvb [L, H * (nope + v)]; Cache [num_pages,
+    page_len, W] the persistable latent pool; PageTable [1, P] int32
+    the slot's row (P a page bucket that covers the chunk's last real
+    row); Pos [1, C] int32 the rows' positions ``start .. start + C -
+    1``; Mask [1, C] (1 = a real row, real rows first).  attrs n_head,
+    nope_dim, rope_dim, v_dim, scale.  Out [1, C, H * v]; CacheOut names
+    the pool itself.  A pad row is written nowhere and seen by no real
+    row.
+
+    Select (optional, with attr select_top_k): [1, C, P * page_len] int8
+    over the slot's rows in order; up to ``select_top_k`` rows in the
+    bucket the selection is the identity and the input is not read."""
+    q = ctx.input("Q")[0]
+    pool, table = ctx.input("Cache"), ctx.input("PageTable")
+    select = None
+    if ctx.has_input("Select") and table.shape[1] * pool.shape[1] > int(
+            ctx.attr("select_top_k", 0)):
+        select = ctx.input("Select")[0]
+    out, pool = mla_attention_chunk(
+        q, ctx.input("Latent")[0], ctx.input("Wkvb"), pool, table,
+        ctx.input("Pos").reshape(-1)[0].astype(jnp.int32),
+        ctx.input("Mask") > 0, int(ctx.attr("n_head")),
+        int(ctx.attr("nope_dim")), int(ctx.attr("rope_dim")),
+        int(ctx.attr("v_dim")), float(ctx.attr("scale", 1.0)),
+        select=select)
+    ctx.set_output("Out", out[None])
+    ctx.set_output("CacheOut", pool)

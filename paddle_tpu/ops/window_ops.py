@@ -42,8 +42,12 @@ widths and a rotary on the LEADING lanes of a head.
   chunk's queries (positions ``P .. P + C - 1``) over the pages' rows
   ``0 .. P + C - 1``: the causal kernel with more key rows than query
   rows and its diagonal shifted by ``P``, a scalar the kernel is handed
-  before its grid runs.  A window layer's is ``window_attention`` with
-  the rings as inputs: its keys are the slot's ring rows of positions
+  before its grid runs (the latent builder's chunk is the same call with
+  ONE K/V head, the cached row, under all its query heads, and where a
+  layer selects its rows the selection's int8 blocks beside the keys':
+  ``mla_ops.mla_attention_chunk``).  A window layer's is
+  ``window_attention`` with the rings as inputs: its keys are the slot's
+  ring rows of positions
   ``P - lead .. P - 1`` (``ring_lead``) followed by the chunk's own, and
   it leaves the chunk's last ``ring`` rows in the ring (``ring_after``).
   ``P`` = 0 is a prompt's first chunk, or all of it.
@@ -164,20 +168,24 @@ def _with_sink(sc, sink):
 
 
 def composed_attention(q, k, v, n_head, n_kv_head, scale, window=0,
-                       sink=None, start=0, first=0):
+                       sink=None, start=0, first=0, select=None):
     """``q`` [Tq, H * Dk]; ``k`` [Tk, Hkv * Dk]; ``v`` [Tk, Hkv * Dv];
     ``sink`` [H] float32 or None.  Query row ``r`` stands at key index
     ``start + r`` (int or traced scalar; a whole prompt: 0 and ``Tk`` =
     ``Tq``): causal from key ``first`` on, inside ``window`` rows where
-    that is not 0.  Scores and softmax in float32.  Returns [Tq, H *
-    Dv] in ``q``'s type."""
+    that is not 0, and under ``select`` [Tq, Tk] (0 = the row leaves the
+    key out) where there is one.  Scores and softmax in float32.
+    Returns [Tq, H * Dv] in ``q``'s type."""
     Tq, Tk = q.shape[0], k.shape[0]
     g = n_head // n_kv_head
     qh = q.reshape(Tq, n_kv_head, g, -1)
     kh, vh = k.reshape(Tk, n_kv_head, -1), v.reshape(Tk, n_kv_head, -1)
     sc = jnp.einsum("qkgd,tkd->kgqt", qh, kh,
                     preferred_element_type=jnp.float32) * scale
-    sc = jnp.where(_seen(Tq, Tk, window, start, first), sc, NEG_INF)
+    seen = _seen(Tq, Tk, window, start, first)
+    if select is not None:
+        seen &= select > 0
+    sc = jnp.where(seen, sc, NEG_INF)
     if sink is not None:
         sink = sink.astype(jnp.float32).reshape(n_kv_head, g, 1, 1)
     probs = _with_sink(sc, sink)
@@ -233,17 +241,24 @@ def key_blocks_computed(T, group, window, start=0, keys=None):
     return sum((start + i * bq + bq - 1) // bk + 1 for i in range(n_q)), bk
 
 
-def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink):
+def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
+                  select=False):
     """One (K/V head, query block); the key blocks that meet the band
     (``window`` > 0: the keys begin with ``lead`` blocks of the rows
     before the chunk, of which those from index ``s`` on are real) or
     lie at or under the diagonal (the chunk's first row stands at key
     index ``s``) stream through VMEM along the innermost, sequential
     grid axis with an online softmax.  The ``G`` query heads that share
-    the K/V head are the rows of ONE product."""
+    the K/V head are the rows of ONE product.  ``select`` (causal): an
+    int8 block ``[bq, bk]`` of a selection comes beside the key block,
+    and a score counts where it marks the pair (the selection is causal
+    already), for every one of the ``G`` heads."""
     if sink:
         sink_ref, *refs = refs
-    q_ref, k_ref, v_ref, o_ref, acc, m_scr, l_scr = refs
+    q_ref, k_ref, v_ref, *refs = refs
+    if select:
+        sel_ref, *refs = refs
+    o_ref, acc, m_scr, l_scr = refs
     i, j = pl.program_id(1), pl.program_id(2)
     G = q_ref.shape[1]
     s = s_ref[0]
@@ -267,7 +282,13 @@ def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink):
         k, v = k_ref[0], v_ref[0]
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
-        if masked:
+        if select:
+            # (int8 is widened before it is compared: the v5e compiler
+            # refuses the narrow comparison)
+            marked = jnp.broadcast_to(
+                sel_ref[...].astype(jnp.int32)[None], (G, bq, bk))
+            sc = jnp.where(marked.reshape(G * bq, bk) > 0, sc, NEG_INF)
+        elif masked:
             t = t0 + (jax.lax.broadcasted_iota(
                 jnp.int32, (G * bq, bk), 0) & (bq - 1))
             u = kb * bk + jax.lax.broadcasted_iota(
@@ -313,8 +334,8 @@ def _led(k, v, before):
 
 @functools.partial(jax.jit, static_argnames=(
     "n_head", "n_kv_head", "scale", "window", "interpret", "blocks"))
-def flash_attention(q, k, v, sink=None, start=0, before=None, *, n_head,
-                    n_kv_head, scale, window=0, interpret=False,
+def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
+                    *, n_head, n_kv_head, scale, window=0, interpret=False,
                     blocks=None):
     """``q`` [Tq, H * Dk]; ``sink`` [H] or None -> [Tq, H * Dv] in
     ``q``'s type, as ``composed_attention``.
@@ -325,15 +346,20 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, *, n_head,
     ``Tk`` whole key blocks).  Banded: ``k`` / ``v`` [Tq, ...] are the
     chunk's own rows and ``before`` = ``(k rows, v rows, n)`` the
     ``lead_rows`` rows that stand before them, of which the LAST ``n``
-    (traced or not) are real; None: none is.  ``blocks`` is for the
-    tests: the kernel reads it from the shapes (``flash_blocks``);
-    ``Tq`` must be whole blocks."""
+    (traced or not) are real; None: none is.  ``select`` (causal alone)
+    [Tq, Tk] int8: query row ``r`` attends the keys it marks and no
+    other, whatever its head (a selection holds no key behind its row:
+    the diagonal is not looked at again).  ``blocks`` is for the tests:
+    the kernel reads it from the shapes (``flash_blocks``); ``Tq`` must
+    be whole blocks."""
     Tq = q.shape[0]
     G = n_head // n_kv_head
     Dk, Dv = k.shape[-1] // n_kv_head, v.shape[-1] // n_kv_head
     bq, bk = blocks or flash_blocks(Tq, G, window, keys=k.shape[0])
     if bq & (bq - 1):
         raise ValueError(f"query block of {bq} rows is not a power of two")
+    if window and select is not None:
+        raise ValueError("a selection comes with the causal form alone")
     per = bq // bk if window else 0
     lead = -(-(window - 1) // bk) if window else 0
     if window:
@@ -362,15 +388,20 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, *, n_head,
             axis=1)[..., None])
         in_specs.append(pl.BlockSpec((1, G * bq, 1),
                                      lambda h, i, j, s: (h, 0, 0)))
+    selected = []
+    if select is not None:
+        selected = [pl.BlockSpec(
+            (bq, bk), lambda h, i, j, s: (i, kv(h, i, j, s)[1]))]
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, window=window, bq=bq,
-                          bk=bk, lead=lead, sink=sink is not None),
+                          bk=bk, lead=lead, sink=sink is not None,
+                          select=select is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n_kv_head, Tq // bq, n_j),
             in_specs=in_specs + [
                 pl.BlockSpec((1, G, bq, Dk), lambda h, i, j, s: (h, 0, i, 0)),
                 pl.BlockSpec((1, bk, Dk), kv),
-                pl.BlockSpec((1, bk, Dv), kv)],
+                pl.BlockSpec((1, bk, Dv), kv)] + selected,
             out_specs=pl.BlockSpec((1, G, bq, Dv),
                                    lambda h, i, j, s: (h, 0, i, 0)),
             scratch_shapes=[pltpu.VMEM((G * bq, Dv), jnp.float32),
@@ -381,12 +412,13 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, *, n_head,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(jnp.asarray(start, jnp.int32).reshape(1), *operands, qh, kh, vh)
+    )(jnp.asarray(start, jnp.int32).reshape(1), *operands, qh, kh, vh,
+      *(() if select is None else (select.astype(jnp.int8),)))
     return out.transpose(2, 0, 1, 3).reshape(Tq, n_head * Dv)
 
 
 def prefill_attention(q, k, v, sink, n_head, n_kv_head, scale, window,
-                      start=0, before=None, interpret=None):
+                      start=0, before=None, interpret=None, select=None):
     """The kernel where the rows are whole blocks, else the composed
     form; arguments as ``flash_attention``'s (``interpret`` None: as the
     backend has it)."""
@@ -394,7 +426,7 @@ def prefill_attention(q, k, v, sink, n_head, n_kv_head, scale, window,
     if flash_blocks(q.shape[0], n_head // n_kv_head, window,
                     keys=k.shape[0]) is not None:
         return flash_attention(
-            q, k, v, sink, start, before, n_head=n_head,
+            q, k, v, sink, start, before, select, n_head=n_head,
             n_kv_head=n_kv_head, scale=scale, window=window,
             interpret=_use_interpret() if interpret is None else interpret)
     first = 0
@@ -402,7 +434,7 @@ def prefill_attention(q, k, v, sink, n_head, n_kv_head, scale, window,
         start = before[0].shape[0]
         k, v, first = _led(k, v, before)
     return composed_attention(q, k, v, n_head, n_kv_head, scale, window,
-                              sink, start, first)
+                              sink, start, first, select)
 
 
 # ---------------------------------------------------------------------------

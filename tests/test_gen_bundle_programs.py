@@ -59,9 +59,9 @@ KINDS = {
     "hybrid_moe": (hybrid_moe, hybrid_moe.HybridConfig,
                    "export_hybrid_model", False),
     "latent_moe": (latent_moe, latent_moe.LatentMoEConfig,
-                   "export_latent_model", False),
+                   "export_latent_model", True),
     "latent_moe_sparse": (latent_moe, latent_moe_sparse.SparseLatentConfig,
-                          "export_latent_model", False),
+                          "export_latent_model", True),
     "block_moe": (block_moe, block_moe.BlockMoEConfig,
                   "export_block_model", False),
     "window_moe": (window_moe, window_moe.WindowMoEConfig,

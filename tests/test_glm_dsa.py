@@ -4,10 +4,10 @@ indexer and the exact top-k against the plain reference
 (``benchmark/reference/glm_dsa_ref.py``: ``jax.lax.top_k``) and against
 numbers worked by hand (the tie rule, the identity up to ``index_topk``
 rows), the latent kernel under a selection against the gather, the
-exported bundle (prefill, the compiled seed of BOTH pools, cached decode
-steps) against the reference's full forward with a selection that
-decides, a re-used slot, the share arithmetic, the contract and the
-rules.  Toy widths: d 64, 4 heads x (16 | 8), latent 32 + rope 8, 4 index
+exported bundle (a prompt as a run of chunks over BOTH pools, each
+chunk's rows selecting among the rows before them, cached decode steps)
+against the reference's full forward with a selection that decides, a
+re-used slot, the share arithmetic, the contract and the rules.  Toy widths: d 64, 4 heads x (16 | 8), latent 32 + rope 8, 4 index
 heads x 16, ``index_topk`` 8, the published layers 2-6 (dense + full,
 three sparse + shared, sparse + full), contexts of 24-48 rows."""
 
@@ -133,6 +133,21 @@ def _admit(predictor, slot, prompt, horizon=8):
     return logits
 
 
+def _chunks(predictor, slot, prompt, horizon=8):
+    """``slot``'s pages, then the prompt's chunks one by one, as the
+    scheduler admits: a generator that yields after every chunk; the
+    last chunk's logits land in ``_chunks.logits[slot]``."""
+    predictor.alloc_slot_pages(slot, predictor.pages_needed(len(prompt),
+                                                            horizon))
+    for a, b in predictor.chunk_spans(len(prompt)):
+        _chunks.logits[slot] = np.asarray(
+            predictor.prefill_chunk(slot, prompt[a:b], a))[0]
+        yield a
+
+
+_chunks.logits = {}
+
+
 # -- the selection ----------------------------------------------------------------
 
 def test_the_selection_is_the_references_top_k_with_its_tie_rule():
@@ -182,6 +197,74 @@ def test_up_to_top_k_rows_the_selection_is_the_identity():
                                            block=8))
     assert np.array_equal(got.sum(-1), np.minimum(np.arange(32) + 1, 8))
     assert not np.triu(got, 1).any()
+
+
+@pytest.mark.parametrize("start, n", [(0, 16), (16, 16), (32, 13), (8, 5)])
+def test_a_chunks_selection_is_the_whole_prompts_rows(start, n):
+    """A chunk of 16 rows at ``start`` over a bucket of 64 key rows, of
+    which ``n`` are real: row ``r`` keeps the top 8 of the real rows ``s
+    <= start + r`` as the reference does on those very scores, and never
+    a row behind its own query, a pad row or a row past the slot's (the
+    table's tail is whatever page 0 holds: another slot's)."""
+    C, T, K = 16, 64, 8
+    scores = jnp.asarray(np.random.RandomState(start + n).randn(C, T),
+                         jnp.float32)
+    mask = jnp.asarray(np.arange(C) < n, jnp.float32)
+    got = np.asarray(dsa_ops.causal_select(scores, mask, K, block=8,
+                                           start=start))
+    assert got.shape == (C, T) and got.dtype == np.int8
+    rows, cols = np.arange(C)[:, None], np.arange(T)[None]
+    seen = (cols <= start + rows) & (cols < start + n)
+    assert not got[~seen].any()
+    assert np.array_equal(got.sum(-1), np.minimum(seen.sum(-1), K))
+    assert np.array_equal(got, ref.select_rows(scores, jnp.asarray(seen), K))
+    # the whole prompt is the chunk at 0 over its own rows
+    square = jnp.asarray(np.random.RandomState(5).randn(T, T), jnp.float32)
+    whole = np.asarray(dsa_ops.causal_select(square, jnp.ones(T), K))
+    part = np.asarray(dsa_ops.causal_select(
+        square[start:start + C], jnp.ones(C), K, start=start))
+    assert np.array_equal(part, whole[start:start + C])
+
+
+def test_the_chunk_kernel_under_a_selection_is_the_masked_softmax():
+    """``window_ops``'s flash kernel as the latent chunk calls it
+    (interpret mode): ONE K/V head under 4 query heads, 32 query rows at
+    key index 64 of 128, the selection's int8 blocks beside the keys';
+    against the composed form, and both against the softmax by hand."""
+    from paddle_tpu.ops import window_ops
+    C, T, H, W, L, start = 32, 128, 4, 128, 64, 64
+    rng = np.random.RandomState(3)
+    q = jnp.asarray(rng.randn(C, H * W), jnp.float32)
+    keys = jnp.asarray(rng.randn(T, W), jnp.float32)
+    scores = jnp.asarray(rng.randn(C, T), jnp.float32)
+    scores = scores.at[5, :32].set(-50.0)       # row 5 keeps rows >= 32
+    select = dsa_ops.causal_select(scores, jnp.ones(C), 24, start=start)
+    assert not np.asarray(select[5, :32]).any()
+    want = window_ops.composed_attention(q, keys, keys[:, :L], H, 1, 0.3,
+                                         start=start, select=select)
+    got = window_ops.flash_attention(
+        q, keys, keys[:, :L], None, start, None, select, n_head=H,
+        n_kv_head=1, scale=0.3, interpret=True, blocks=(16, 32))
+    assert np.allclose(got, want, atol=2e-5)
+    sc = np.einsum("qhw,tw->hqt", np.asarray(q).reshape(C, H, W),
+                   np.asarray(keys)) * 0.3
+    sc = np.where(np.asarray(select)[None] > 0, sc, -1e30)
+    pr = np.exp(sc - sc.max(-1, keepdims=True))
+    pr /= pr.sum(-1, keepdims=True)
+    assert np.allclose(want, np.einsum(
+        "hqt,tl->qhl", pr, np.asarray(keys)[:, :L]).reshape(C, H * L),
+        atol=2e-5)
+    # without one the call is what it was: the shifted diagonal
+    plain = window_ops.flash_attention(
+        q, keys, keys[:, :L], None, start, n_head=H, n_kv_head=1,
+        scale=0.3, interpret=True, blocks=(16, 32))
+    assert np.allclose(plain, window_ops.composed_attention(
+        q, keys, keys[:, :L], H, 1, 0.3, start=start), atol=2e-5)
+    with pytest.raises(ValueError, match="causal form alone"):
+        window_ops.flash_attention(
+            q, keys[:C], keys[:C, :L], None, 0, None, select[:, :C],
+            n_head=H, n_kv_head=1, scale=0.3, window=8, interpret=True,
+            blocks=(16, 16))
 
 
 def test_the_indexer_scores_are_the_references(cfg, weights):
@@ -329,6 +412,166 @@ def test_prefill_both_pools_and_cached_steps_match_the_reference(
         predictor.free_slot_pages(1)
 
 
+@pytest.fixture(scope="module")
+def chunked(tmp_path_factory, cfg, weights):
+    """The bundle with chunk rungs of 8 and 16 rows (``predictor``'s are
+    24 and 48: every prompt here is ONE chunk there): a prompt of 40
+    rows is three chunks, and the second one's first row already has
+    twice ``index_topk`` rows before it."""
+    path = str(tmp_path_factory.mktemp("dsa") / "chunked")
+    was, decoder.CHUNK_ROWS = decoder.CHUNK_ROWS, 16
+    try:
+        latent_moe.export_latent_model(path, _hp(cfg), num_slots=SLOTS,
+                                       prompt_buckets=BUCKETS,
+                                       page_len=PAGE_LEN)
+    finally:
+        decoder.CHUNK_ROWS = was
+    p = GenPredictor(path)
+    assert p.prefill_chunks == [8, 16]
+    _install(p, weights)
+    p.warmup()
+    return p
+
+
+@pytest.mark.parametrize("n", [6, 17, 40, 45])
+def test_a_prompt_in_chunks_selects_what_the_whole_prompt_would(
+        predictor, chunked, weights, cfg, n):
+    """Chunk by chunk (8- and 16-row rungs) against the single pass (one
+    24- or 48-row chunk) and against the reference: the last row's
+    logits, BOTH pools' rows, and three cached steps over the rows the
+    chunks wrote in place; the selection decides wherever a row has more
+    than ``index_topk`` rows before it."""
+    prompt = _prompt(n, seed=100 + n)
+    assert len(chunked.chunk_spans(n)) == -(-n // 16)
+    whole, parts = predictor.prefill(prompt), chunked.prefill(prompt)
+    want = _ref_logits(weights, cfg, prompt, [n - 1])[0]
+    dense = _ref_logits(weights, cfg, prompt, [n - 1], select=False)[0]
+    assert _err(whole[0], want) < 2e-4 and _err(parts[0], want) < 2e-4
+    assert (_err(dense, want) > 0.1) == (n > TOPK)
+    # five latent pools, then the two full layers' index keys
+    assert len(parts[1]) == len(whole[1]) == 7
+    for got, row in zip(parts[1], whole[1]):
+        assert got.shape == row.shape
+        assert np.allclose(got, row, atol=2e-5)
+        assert np.asarray(got)[0, :n].any(axis=-1).all()
+        assert not np.asarray(got)[0, n:].any()
+    assert chunked.free_pages == chunked.num_pages
+    list(_chunks(chunked, 1, prompt))
+    try:
+        assert _err(_chunks.logits[1], want) < 2e-4
+        toks, tok = list(prompt), int(np.argmax(_chunks.logits[1]))
+        for _ in range(3):
+            out = _step(chunked, {1: (tok, len(toks))})[1]
+            toks.append(tok)
+            assert _err(out, _ref_logits(weights, cfg, toks,
+                                         [len(toks) - 1])[0]) < 2e-4
+            tok = int(np.argmax(out))
+    finally:
+        chunked.free_slot_pages(1)
+
+
+def test_a_shared_layer_takes_the_full_layers_selection_inside_a_chunk(cfg):
+    """In the chunk program, as in the decode step: a ``full`` layer's
+    ``dsa_select`` output is the ``Select`` of its own attention and of
+    every ``shared`` layer's up to the next ``full`` one; only a full
+    layer holds an indexer, and it writes its own key pool."""
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        feeds, fetches = latent_moe.build_chunk_program(_hp(cfg), 4, 8, 32)
+    assert feeds == ["gen_ids", "gen_pos", "gen_mask", "gen_last",
+                     "gen_page_table"] and len(fetches) == 1
+    ops = main.global_block().ops
+    selects = [op.output("Select")[0] for op in ops
+               if op.type == "dsa_select"]
+    under = [op.input("Select")[0] for op in ops
+             if op.type == "mla_attention_chunk"]
+    assert len(selects) == 2 and len(under) == 5
+    assert under == [selects[0]] * 4 + [selects[1]]
+    index = [op for op in ops if op.type == "dsa_index_chunk"]
+    assert [op.output("CacheOut")[0] for op in index] == [
+        "lat0_paged_ik", "lat4_paged_ik"]
+    assert all(op.input("Pos") and op.input("Mask") for op in ops
+               if op.type == "dsa_select")
+    # nothing a decode metric could take for a step's attention
+    assert not any(op.type.startswith("paged_attention") for op in ops)
+    assert {op.type for op in ops if op.type.startswith(("mla_", "dsa_"))} \
+        == {"mla_attention_chunk", "dsa_index_chunk", "dsa_select"}
+
+
+def test_two_slots_admitted_alternately_select_for_themselves(chunked,
+                                                              weights, cfg):
+    """Prompts of 40 and 21 rows whose chunks alternate: each chunk
+    scores, selects and attends its own slot's rows and no other's."""
+    prompts = {0: _prompt(40, seed=301), 3: _prompt(21, seed=302)}
+    runs = {slot: _chunks(chunked, slot, p) for slot, p in prompts.items()}
+    try:
+        order = []
+        while runs:
+            for slot in list(runs):
+                start = next(runs[slot], None)
+                if start is None:
+                    del runs[slot]
+                else:
+                    order.append((slot, start))
+        assert order == [(0, 0), (3, 0), (0, 16), (3, 16), (0, 32)]
+        toks = {s: list(p) for s, p in prompts.items()}
+        last = dict(_chunks.logits)
+        for slot, p in prompts.items():
+            assert _err(last[slot], _ref_logits(weights, cfg, p,
+                                                [len(p) - 1])[0]) < 2e-4
+        for _ in range(2):
+            for slot in toks:
+                toks[slot].append(int(np.argmax(last[slot])))
+            out = _step(chunked, {s: (toks[s][-1], len(toks[s]) - 1)
+                                  for s in toks})
+            for slot in toks:
+                last[slot] = out[slot]
+                assert _err(out[slot], _ref_logits(
+                    weights, cfg, toks[slot],
+                    [len(toks[slot]) - 1])[0]) < 2e-4
+    finally:
+        for slot in prompts:
+            chunked.free_slot_pages(slot)
+
+
+def test_a_chunk_reads_no_stale_latent_row_or_index_key(chunked, weights,
+                                                        cfg):
+    """Whatever a former owner left in the pages (here: every row of
+    every pool set to 7, as a long stream's rows and keys would be): a
+    chunk writes its REAL rows into both pools and nothing else (a pad
+    row lands nowhere, nobody else's page is touched), its indexer
+    scores the bucket's rows but selects among the slot's own, and the
+    steps behind it read rows under ``lens`` alone."""
+    for name in chunked.cache_vars:
+        old = chunked._scope.find_var(name)
+        chunked._scope.set_var(name, jnp.full(old.shape, 7.0, old.dtype))
+    prompt = _prompt(21, seed=303)          # 16 + 5 (of 8: 3 pad rows)
+    try:
+        list(_chunks(chunked, 2, prompt, horizon=6))
+        assert _err(_chunks.logits[2],
+                    _ref_logits(weights, cfg, prompt, [20])[0]) < 2e-4
+        mine = chunked._slot_pages[2]
+        for name in chunked.cache_vars:
+            pool = np.asarray(chunked._scope.find_var(name))
+            rows = pool[mine].reshape(-1, pool.shape[-1])
+            assert (rows[:21] != 7.0).any(axis=-1).all()
+            assert (rows[21:] == 7.0).all()
+            others = [i for i in range(pool.shape[0]) if i not in mine]
+            assert (pool[others] == 7.0).all()
+        toks, tok = list(prompt), int(np.argmax(_chunks.logits[2]))
+        for _ in range(3):
+            out = _step(chunked, {2: (tok, len(toks))})[2]
+            toks.append(tok)
+            assert _err(out, _ref_logits(weights, cfg, toks,
+                                         [len(toks) - 1])[0]) < 2e-4
+            tok = int(np.argmax(out))
+    finally:
+        chunked.free_slot_pages(2)
+        for name in chunked.cache_vars:
+            old = chunked._scope.find_var(name)
+            chunked._scope.set_var(name, jnp.zeros(old.shape, old.dtype))
+
+
 def test_a_shared_layer_attends_under_the_full_layers_selection(weights,
                                                                 cfg):
     """The reference's own account: layers 1-3 attend under layer 0's
@@ -381,6 +624,9 @@ def test_a_freed_and_reused_slot_reads_no_stale_index_keys(predictor,
         old = predictor._scope.find_var(name)
         predictor._scope.set_var(name, jnp.full(old.shape, 7.0, old.dtype))
     short = _prompt(13, seed=32)
+    # (the prefill runs the prompt's chunk on two pages it borrows from
+    # the free list and hands back)
+    borrowed = list(predictor._free_list[:2])
     logits = _admit(predictor, 2, short, horizon=30)
     try:
         toks, tok = list(short), int(np.argmax(logits))
@@ -395,7 +641,8 @@ def test_a_freed_and_reused_slot_reads_no_stale_index_keys(predictor,
         pool = np.asarray(predictor._scope.find_var("lat0_paged_ik"))
         mine = predictor._slot_pages[2]
         assert not pool[mine[-1]].any()
-        others = [i for i in range(pool.shape[0]) if i not in mine]
+        others = [i for i in range(pool.shape[0])
+                  if i not in mine and i not in borrowed]
         assert (pool[others] == 7.0).all()
     finally:
         predictor.free_slot_pages(2)
@@ -438,9 +685,16 @@ def test_decode_steps_and_prefills_count_their_selections(predictor):
     assert step["attrs"]["dsa_rows_scored"] == 74
     assert step["attrs"]["dsa_rows_selected"] == 28
     pre = next(s for s in spans if s["name"] == "gen.prefill")
-    # 20 rows: 1 + 2 + ... + 20 scored, 1 + ... + 8 + 12 x 8 kept
+    # 20 rows, one chunk here: 1 + 2 + ... + 20 scored, 1 + ... + 8 + 12
+    # x 8 kept
     assert pre["attrs"]["dsa_rows_scored"] == 2 * 210
     assert pre["attrs"]["dsa_rows_selected"] == 2 * (36 + 96)
+    # a prompt's chunks sum to its whole triangle
+    parts = [predictor._chunk_selections(a, b - a)
+             for a, b in ((0, 16), (16, 32), (32, 37))]
+    assert sum(p["dsa_rows_scored"] for p in parts) == 2 * 37 * 38 // 2
+    assert sum(p["dsa_rows_selected"] for p in parts) \
+        == 2 * (36 + 29 * 8)
 
 
 # -- a configuration without an indexer builds what it built ------------------------
@@ -462,7 +716,7 @@ def test_a_configuration_without_indexer_keys_builds_the_same_programs():
     assert latent_moe.paged_cache_var_names(hp) == [
         f"lat{i}_paged_c" for i in range(5)]
     built = []
-    for build in (lambda: latent_moe.build_prefill_program(hp),
+    for build in (lambda: latent_moe.build_chunk_program(hp, 4, 8, 32),
                   lambda: latent_moe.build_paged_decode_program(
                       hp, 4, 8, 32)):
         main = fluid.Program()
@@ -478,13 +732,11 @@ def test_a_configuration_without_indexer_keys_builds_the_same_programs():
                             indexer_types=["full"] + ["shared"] * 4))
     main = fluid.Program()
     with fluid.program_guard(main, fluid.Program()):
-        latent_moe.build_prefill_program(sparse)
+        latent_moe.build_chunk_program(sparse, 4, 8, 32)
     types = [t for t, *_ in _op_list(main)]
-    extra = ("dsa_index", "dsa_select", "elementwise_mul", "cast")
     kept = [t for t in types if not t.startswith("dsa_")]
-    assert types.count("dsa_index") == types.count("dsa_select") == 1
-    assert len(kept) == len(built[0]) + 2 and set(types) - set(built[0]) \
-        <= set(extra)
+    assert types.count("dsa_index_chunk") == types.count("dsa_select") == 1
+    assert kept == built[0]
 
 
 # -- contract, typecheck, cost ---------------------------------------------------------
@@ -494,8 +746,9 @@ def test_the_bundle_checks_and_every_new_op_has_its_rules(bundle_dir):
     from paddle_tpu.analysis import check_gen_bundle, typecheck
     from paddle_tpu.analysis.analyzer import lint_program
     from paddle_tpu.analysis.distributed import load_saved_program
-    new = {"dsa_index", "dsa_index_paged", "dsa_select"}
-    assert new <= set(typecheck._RULES) and new <= cost.covered_op_types()
+    new = {"dsa_index_chunk", "dsa_index_paged", "dsa_select"}
+    assert new | {"dsa_index"} <= set(typecheck._RULES)
+    assert new | {"dsa_index"} <= cost.covered_op_types()
     pre = load_saved_program(os.path.join(bundle_dir, "prefill"))
     dec = load_saved_program(os.path.join(bundle_dir, "decode"))
     with open(os.path.join(bundle_dir, "gen_meta.json")) as f:
@@ -512,27 +765,29 @@ def test_the_bundle_checks_and_every_new_op_has_its_rules(bundle_dir):
         seen |= {op.type for op in prog.global_block().ops}
     assert new <= seen
     assert not cost.estimate(dec[0], paged_live_rows=24).uncovered
-    # the prefill's cost grows with the SQUARE of the rows past top_k:
-    # index scores and the selection beside the attention
-    block = pre[0].global_block()
-    saved = {n: block.var(n).shape for n in pre[1]}
+    # a chunk's index scores and selection grow with its rows x the
+    # page bucket's rows, past top_k rows in the bucket
+    def flops(rows, pages, only):
+        by_type = cost.estimate_at(
+            pre[0], {n: [1, pages if n == "gen_page_table" else rows]
+                     for n in pre[1]}).by_op_type()
+        return sum(by_type[t]["flops"] for t in only)
 
-    def flops(rows, only):
-        for n in pre[1]:
-            block.var(n).shape = (1, rows)
-        try:
-            by_type = cost.estimate(pre[0]).by_op_type()
-            return sum(by_type[t]["flops"] for t in only)
-        finally:
-            for n, shape in saved.items():
-                block.var(n).shape = shape
-
-    sparse = ("dsa_index", "dsa_select")
+    sparse = ("dsa_index_chunk", "dsa_select")
     proj = 2 * (48 * 64 + 64 * 16 + 64 * 4)
-    assert flops(8, sparse) == 2 * 8 * proj       # the identity: no n^2
-    for rows in (16, 32):   # two indexers: 4 heads x 16 lanes, 70 a score
-        assert flops(rows, sparse) - 2 * rows * proj \
-            == 2 * rows * rows * (2 * 4 * 16 + 70)
+    assert flops(8, 1, sparse) == 2 * 8 * proj    # the identity: no pairs
+    for rows, pages in ((8, 2), (16, 2), (16, 6)):
+        # two indexers: 4 heads x 16 lanes, 70 a score
+        assert flops(rows, pages, sparse) - 2 * rows * proj \
+            == 2 * rows * pages * PAGE_LEN * (2 * 4 * 16 + 70)
+    # the whole-sequence form stays the training forward's, with its rules
+    train = fluid.Program()
+    with fluid.program_guard(train, fluid.Program()):
+        latent_moe.latent_moe_train_program(
+            16, latent_moe.LatentMoEConfig.from_dict(toy_config()))
+    assert {"dsa_index", "dsa_select", "mla_attention"} <= {
+        op.type for op in train.global_block().ops}
+    assert not lint_program(train).errors
 
 
 def test_a_selection_of_the_wrong_width_is_a_type_error():

@@ -1,11 +1,12 @@
 """Latent-attention / shared-expert MoE LM on the paged serving path
 (``models/latent_moe.py``): the new ops against numbers worked by hand
 and against each other (the absorbed decode = the expanded form, the
-routed product = the dense one), the exported bundle (prefill, the
-compiled seed of ONE latent row a layer, cached decode steps) against the
-plain NON-absorbed reference (``benchmark/reference/kimi_k2_ref.py``) on
-seeded weights, the bundle contract, the typecheck and cost rules, and
-the expert-parallel share arithmetic.  Toy widths: d 64, 4 heads x (16 |
+routed product = the dense one), the exported bundle (a prompt as a run
+of chunks over the slot's own pool of ONE latent row a layer, cached
+decode steps) against the plain NON-absorbed reference
+(``benchmark/reference/kimi_k2_ref.py``) on seeded weights, the bundle
+contract, the typecheck and cost rules, and the expert-parallel share
+arithmetic.  Toy widths: d 64, 4 heads x (16 |
 8), latent 32 + rope 8 (stored 128 wide), 16 experts top-2, 3 layers of
 which the first dense."""
 
@@ -33,7 +34,7 @@ if BENCH not in sys.path:
 from models import kimi_k2 as adapter             # noqa: E402
 from reference import kimi_k2_ref as ref          # noqa: E402
 
-SLOTS, PAGE_LEN, BUCKETS = 4, 8, [8, 16, 32]
+SLOTS, PAGE_LEN, BUCKETS = 4, 8, [8, 16, 32, 48]
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 64, "mscale": 1,
         "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
         "type": "yarn"}
@@ -139,6 +140,22 @@ def _admit(predictor, slot, prompt, horizon=16):
                                                             horizon))
     assert predictor.write_slot(slot, kv, len(prompt)) == 0
     return logits
+
+
+def _chunks(predictor, slot, prompt, horizon=8):
+    """``slot``'s pages, then the prompt's chunks one by one, as the
+    scheduler admits: a generator that yields after every chunk but the
+    last and returns nothing; ``list()`` it to run it whole.  The last
+    chunk's logits land in ``_chunks.logits[slot]``."""
+    predictor.alloc_slot_pages(slot, predictor.pages_needed(len(prompt),
+                                                            horizon))
+    for a, b in predictor.chunk_spans(len(prompt)):
+        _chunks.logits[slot] = np.asarray(
+            predictor.prefill_chunk(slot, prompt[a:b], a))[0]
+        yield a
+
+
+_chunks.logits = {}
 
 
 # -- numbers worked by hand -----------------------------------------------------
@@ -522,6 +539,157 @@ def test_scheduler_streams_the_references_greedy_tokens(predictor, weights,
         assert s["attrs"]["row_bytes"] == predictor.cache_row_bytes
 
 
+# -- a prompt as a run of chunks over the slot's own pool -------------------------------
+
+@pytest.fixture(scope="module")
+def chunked(tmp_path_factory, cfg, weights):
+    """The bundle with chunk rungs of 8 and 16 rows (``predictor``'s are
+    24 and 48: every prompt here is ONE chunk there): a prompt of 45
+    rows is three chunks and walks page buckets of 2, 4 and 8 pages."""
+    was, decoder.CHUNK_ROWS = decoder.CHUNK_ROWS, 16
+    try:
+        path = _export(str(tmp_path_factory.mktemp("latent") / "chunked"),
+                       cfg)
+    finally:
+        decoder.CHUNK_ROWS = was
+    p = GenPredictor(path)
+    assert p.prefill_chunks == [8, 16]
+    _install(p, weights)
+    p.warmup()
+    return p
+
+
+@pytest.mark.parametrize("n", [5, 16, 17, 29, 45], ids=[
+    "one_chunk", "a_chunk_edge", "a_row_past_it", "two_chunks",
+    "every_page_bucket"])
+def test_a_prompt_in_chunks_is_the_prompt_in_one(predictor, chunked, weights,
+                                                 cfg, n):
+    """The last row's logits against the reference and every layer's
+    page rows, chunk by chunk (8- and 16-row rungs) against the single
+    pass (one 24- or 48-row chunk); then cached steps over the rows the
+    chunks wrote, in place."""
+    prompt = _prompt(n, seed=100 + n)
+    spans = chunked.chunk_spans(n)
+    assert len(spans) == -(-n // 16) and len(predictor.chunk_spans(n)) == 1
+    assert [chunked._chunk_shape(a, b - a) for a, b in spans][-1] \
+        == (8 if (n - 1) % 16 < 8 else 16,
+            next(p for p in (1, 2, 4, 8) if p * PAGE_LEN >= n))
+    whole, parts = predictor.prefill(prompt), chunked.prefill(prompt)
+    want = _ref_logits(weights, cfg, prompt, [n - 1])[0]
+    _close(whole[0], want)
+    _close(parts[0], want)
+    assert len(parts[1]) == len(whole[1]) == 3
+    for got, row in zip(parts[1], whole[1]):
+        assert got.shape == row.shape
+        assert np.allclose(got, row, atol=2e-5)
+        # the prompt's rows and zeros behind; 40 lanes of 128 hold values
+        assert np.asarray(got)[0, :n, :40].any(axis=-1).all()
+        assert not np.asarray(got)[0, n:].any()
+    assert chunked.free_pages == chunked.num_pages
+    list(_chunks(chunked, 1, prompt, horizon=4))
+    try:
+        _close(_chunks.logits[1], want)
+        seq = list(prompt)
+        tok = int(np.argmax(_chunks.logits[1]))
+        for _ in range(3):
+            out = _step(chunked, {1: (tok, len(seq))})[1]
+            seq.append(tok)
+            _close(out, _ref_logits(weights, cfg, seq, [len(seq) - 1])[0])
+            tok = int(np.argmax(out))
+    finally:
+        chunked.free_slot_pages(1)
+
+
+def test_two_slots_admitted_alternately_share_the_pool(chunked, weights,
+                                                       cfg):
+    """Two prompts of 45 and 21 rows whose chunks alternate, as two
+    admissions beside each other never do but any two slots' pages must
+    allow: each chunk reads its own slot's rows and no other's."""
+    prompts = {0: _prompt(45, seed=301), 3: _prompt(21, seed=302)}
+    runs = {slot: _chunks(chunked, slot, p) for slot, p in prompts.items()}
+    try:
+        order = []
+        while runs:
+            for slot in list(runs):
+                start = next(runs[slot], None)
+                if start is None:
+                    del runs[slot]
+                else:
+                    order.append((slot, start))
+        assert order == [(0, 0), (3, 0), (0, 16), (3, 16), (0, 32)]
+        seqs = {s: list(p) for s, p in prompts.items()}
+        last = dict(_chunks.logits)
+        for slot, p in prompts.items():
+            _close(last[slot], _ref_logits(weights, cfg, p,
+                                           [len(p) - 1])[0])
+        for _ in range(3):
+            for slot in seqs:
+                seqs[slot].append(int(np.argmax(last[slot])))
+            out = _step(chunked, {s: (seqs[s][-1], len(seqs[s]) - 1)
+                                  for s in seqs})
+            for slot in seqs:
+                last[slot] = out[slot]
+                _close(out[slot], _ref_logits(
+                    weights, cfg, seqs[slot], [len(seqs[slot]) - 1])[0])
+    finally:
+        for slot in prompts:
+            chunked.free_slot_pages(slot)
+
+
+def test_a_chunk_writes_its_real_rows_and_reads_no_stale_one(chunked,
+                                                             weights, cfg):
+    """Whatever a former owner left in the pages (here every row of
+    every pool set to 7): a chunk writes its REAL rows at their places
+    and nothing else (a pad row lands nowhere, nobody else's page is
+    touched), and neither it nor the steps behind it read a row past
+    the slot's own."""
+    for name in chunked.cache_vars:
+        old = chunked._scope.find_var(name)
+        chunked._scope.set_var(name, jnp.full(old.shape, 7.0, old.dtype))
+    prompt = _prompt(21, seed=303)           # 16 + 5 (of 8: 3 pad rows)
+    try:
+        list(_chunks(chunked, 2, prompt, horizon=4))
+        _close(_chunks.logits[2], _ref_logits(weights, cfg, prompt,
+                                              [20])[0])
+        mine = chunked._slot_pages[2]
+        for name in chunked.cache_vars:
+            pool = np.asarray(chunked._scope.find_var(name))
+            rows = pool[mine].reshape(-1, pool.shape[-1])
+            assert (rows[:21] != 7.0).any(axis=-1).all()
+            assert (rows[21:] == 7.0).all()
+            others = [i for i in range(pool.shape[0]) if i not in mine]
+            assert (pool[others] == 7.0).all()
+        seq, tok = list(prompt), int(np.argmax(_chunks.logits[2]))
+        for _ in range(2):
+            out = _step(chunked, {2: (tok, len(seq))})[2]
+            seq.append(tok)
+            _close(out, _ref_logits(weights, cfg, seq, [len(seq) - 1])[0])
+            tok = int(np.argmax(out))
+    finally:
+        chunked.free_slot_pages(2)
+        for name in chunked.cache_vars:
+            old = chunked._scope.find_var(name)
+            chunked._scope.set_var(name, jnp.zeros(old.shape, old.dtype))
+
+
+def test_a_chunk_past_the_longest_prompt_is_refused_not_compiled(chunked):
+    """The warm-up holds a rung over the page buckets from its own rows'
+    to the longest prompt's and no other pair: what could run past them
+    (a compile inside a serving window) raises instead."""
+    assert chunked.max_prompt_len == 48 and chunked.page_buckets == [
+        1, 2, 4, 8]
+    assert chunked._chunk_shapes() == [(8, 1), (8, 2), (8, 4), (8, 8),
+                                       (16, 2), (16, 4), (16, 8)]
+    # a chunk's bucket is never smaller than its own rung
+    assert chunked._chunk_shape(0, 3) == (8, 1)
+    assert chunked._chunk_shape(0, 9) == (16, 2)
+    assert chunked._chunk_shape(32, 16) == (16, 8)
+    with pytest.raises(ValueError, match="max prompt length"):
+        chunked._chunk_shape(48, 1)
+    with pytest.raises(ValueError, match="largest"):
+        chunked._chunk_shape(0, 17)
+
+
 # -- the bundle's contract, typecheck and cost rules ----------------------------------------
 
 def _bundle_parts(bundle_dir):
@@ -538,7 +706,11 @@ def test_gen_meta_names_one_pool_a_layer_and_the_bundle_checks(bundle_dir):
     assert meta["cache_vars"] == ["lat0_paged_c", "lat1_paged_c",
                                   "lat2_paged_c"]
     assert meta["state_vars"] == [] and len(meta["decode_stats"]) == 3
-    assert pre[1] == ["gen_ids", "gen_pos", "gen_mask", "gen_last"]
+    # the prefill is ONE CHUNK of a prompt over the slot's own pages; the
+    # rungs come from the bundle's shapes (no state a slot: no gen_slot)
+    assert pre[1] == ["gen_ids", "gen_pos", "gen_mask", "gen_last",
+                      "gen_page_table"] and len(pre[2]) == 1
+    assert meta["prefill_chunks"] == [24, 48]
     assert dec[1] == ["gen_token", "gen_pos", "gen_page_table", "gen_lens"]
     assert check_gen_bundle(pre, dec, meta) == []
     drifted = dict(meta, cache_vars=meta["cache_vars"][:2])
@@ -546,22 +718,41 @@ def test_gen_meta_names_one_pool_a_layer_and_the_bundle_checks(bundle_dir):
                for d in check_gen_bundle(pre, dec, drifted))
     block = dec[0].global_block()
     block.var("lat1_paged_c").shape = (SLOTS * 8, PAGE_LEN, 64)
-    assert any(d.code == "PTA019" and "feature dim" in d.message
+    assert any(d.code == "PTA019" and "share one array" in d.message
                for d in check_gen_bundle(pre, dec, meta))
 
 
 def test_both_programs_typecheck_and_every_new_op_has_its_rules(bundle_dir):
     from paddle_tpu.analysis.analyzer import lint_program
-    new = {"rope", "swiglu", "mla_attention", "mla_absorb",
-           "paged_attention_latent", "moe_experts_gated"}
+    new = {"rope", "swiglu", "mla_attention", "mla_attention_chunk",
+           "mla_absorb", "paged_attention_latent", "moe_experts_gated"}
     assert new <= set(typecheck._RULES) and new <= cost.covered_op_types()
     pre, dec, _ = _bundle_parts(bundle_dir)
+    # the whole-sequence form stays the training forward's
+    train = fluid.Program()
+    with fluid.program_guard(train, fluid.Program()):
+        _, train_feeds = latent_moe.latent_moe_train_program(
+            8, latent_moe.LatentMoEConfig.from_dict(toy_config()))
     seen = set()
-    for prog, feeds, fetches in (pre, dec):
+    for prog, feeds, fetches in (pre, dec, (train, train_feeds, None)):
         result = lint_program(prog, feed_names=feeds, fetch_names=fetches)
         assert not result.errors, [d.message for d in result.errors]
         seen |= {op.type for op in prog.global_block().ops}
     assert new <= seen
+    assert "mla_attention" not in {op.type
+                                   for op in pre[0].global_block().ops}
+    # a chunk is charged its rows over the page bucket's, never a pool
+    shapes = {n: [1, 16] for n in pre[1]}
+    by_bucket = [cost.estimate_at(
+        pre[0], dict(shapes, gen_page_table=[1, pages])).by_op_type()[
+            "mla_attention_chunk"] for pages in (2, 4)]
+    h, row, lat = 4, 128, 32
+    for pages, got in zip((2, 4), by_bucket):
+        pairs = 16 * 17 // 2 + 16 * (pages * PAGE_LEN - 16)
+        assert got["flops"] == 3 * (2 * 16 * lat * h * 32
+                                    + 2 * pairs * h * (row + lat))
+        assert got["bytes"] == 3 * 4 * (16 * h * (24 + 16)
+                                        + (pages * PAGE_LEN + 32) * row)
     # the table's width is the one dynamic dim: priced at live rows
     report = cost.estimate(dec[0], paged_live_rows=24)
     assert not report.uncovered

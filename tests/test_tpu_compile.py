@@ -650,6 +650,66 @@ def test_a_prefill_chunk_compiles_for_v5e_in_place(one_chip, kind, pages):
     assert memory.temp_size_in_bytes < 160 << 20, memory
 
 
+@pytest.mark.parametrize("pages, sparse", [(32, True), (128, True),
+                                           (288, True), (64, False)])
+def test_a_latent_prefill_chunk_compiles_for_v5e_in_place(one_chip, pages,
+                                                          sparse):
+    """The attention of ONE CHUNK of the latent builder's prefill, 1024
+    rows at position ``start`` (traced), at the sparse-attention serving
+    cell's widths (64 heads of 192 | 64, values 256, rows stored 640
+    wide in pages of 64; the indexer's 32 heads x 128 and its exact
+    top-2048 a query row over the page bucket's rows: the identity in the
+    smallest bucket, the whole slot in the widest) and at the latent
+    cell's without an indexer (heads of 128 | 64, pages of 16): the
+    chunk's rows into the slot's pages of both pools, the absorbed
+    queries over the cached rows in the key-offset flash kernel under
+    the selection's int8 blocks, the context taken out.  Pools donated;
+    no sort; no temporary of a pool's size (nothing is expanded to
+    heads: the gathered rows of ONE slot, the scores of one query block
+    and the selection)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import dsa_ops, mla_ops
+    from paddle_tpu.ops.attention_ops import _paged_cache_update
+    C, S = 1024, 16
+    nope, vd, PL = (192, 256, 64) if sparse else (128, 128, 16)
+    T = pages * PL
+
+    def fn(q, row, w_kvb, pool, table, pos, mask, qi, ki, wi, keys):
+        start, select = pos[0, 0], None
+        if sparse:
+            keys, = _paged_cache_update(
+                (keys,), (ki[None],), table, (start + C).reshape(1, 1),
+                row_lens=mask > 0)
+            if T > 2048:
+                rows = keys[table[0]].reshape(T, 128)
+                scores = dsa_ops._query_blocks(
+                    lambda qb, wb: dsa_ops.index_scores(qb, rows, wb), C,
+                    qi, wi)
+                select = dsa_ops.causal_select(scores, mask[0], 2048,
+                                               start=start)
+        out, pool = mla_ops.mla_attention_chunk(
+            q, row, w_kvb, pool, table, start, mask > 0, 64, nope, 64, vd,
+            0.0625, select=select, interpret=False)
+        return out, pool, keys
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    pools = [sds((S * 288, PL, 640)), sds((S * 288, PL, 128))]
+    compiled = jax.jit(fn, donate_argnums=(3, 10)).lower(
+        sds((C, 64 * (nope + 64))), sds((C, 640)),
+        sds((512, 64 * (nope + vd))), pools[0], sds((1, pages), jnp.int32),
+        sds((1, C), jnp.int32), sds((1, C), jnp.float32),
+        sds((C, 32, 128)), sds((C, 128)), sds((C, 32), jnp.float32),
+        pools[1]).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in hlo and "sort" not in hlo
+    # in place: both pools alias their outputs
+    assert memory.alias_size_in_bytes >= sum(
+        int(jnp.prod(jnp.asarray(p.shape))) * 2 for p in pools)
+    assert memory.temp_size_in_bytes < 320 << 20, memory
+
+
 # -- sliding-window / full attention at key heads of 192 (stored 256) and
 # value heads of 128 (``ops/window_ops.py``; ``models/window_moe.py``) ------
 

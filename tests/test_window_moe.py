@@ -26,7 +26,7 @@ import paddle_tpu as fluid
 from paddle_tpu import profiler
 from paddle_tpu.analysis import cost
 from paddle_tpu.gen import GenPredictor
-from paddle_tpu.models import window_moe
+from paddle_tpu.models import decoder, window_moe
 from paddle_tpu.ops import moe_ops
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -270,13 +270,13 @@ def chunked(tmp_path_factory, cfg, weights):
     rows is three chunks, wraps the 8-row ring twice inside each, and
     walks page buckets of 2, 4 and 8 pages."""
     path = str(tmp_path_factory.mktemp("win") / "chunked")
-    was, window_moe.CHUNK_ROWS = window_moe.CHUNK_ROWS, 16
+    was, decoder.CHUNK_ROWS = decoder.CHUNK_ROWS, 16
     try:
         window_moe.export_window_model(path, _hp(cfg), num_slots=SLOTS,
                                        prompt_buckets=BUCKETS,
                                        page_len=PAGE_LEN)
     finally:
-        window_moe.CHUNK_ROWS = was
+        decoder.CHUNK_ROWS = was
     p = GenPredictor(path)
     assert p.prefill_chunks == [8, 16]
     _install(p, weights)
@@ -543,9 +543,14 @@ def test_the_bundle_checks_and_every_new_op_has_its_rules(predictor):
         == (24, 4)
     assert predictor.chunk_cost(0, 20) == predictor.chunk_cost(0, 24) \
         == float(chunk_report(24, 4).total_flops)
-    assert predictor._chunk_shape(24, 30) == (48, 8)
-    assert predictor.chunk_cost(24, 30) \
+    # (a rung runs over no fewer pages than its own rows take, and no
+    # chunk ends past the longest prompt: nothing else is warmed)
+    assert predictor._chunk_shape(0, 30) == (48, 8)
+    assert predictor.chunk_cost(0, 30) \
         == float(chunk_report(48, 8).total_flops)
+    assert predictor._chunk_shapes() == [(24, 4), (24, 8), (48, 8)]
+    with pytest.raises(ValueError, match="max prompt length"):
+        predictor._chunk_shape(24, 30)
     assert predictor.prefill_cost(40) == predictor.chunk_cost(0, 40)
 
 

@@ -23,7 +23,7 @@ import pytest
 from paddle_tpu import profiler
 from paddle_tpu.analysis import check_gen_bundle
 from paddle_tpu.gen import GenPredictor, GenScheduler
-from paddle_tpu.models import window_moe
+from paddle_tpu.models import decoder, window_moe
 from paddle_tpu.ops import spec_ops, window_ops
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
@@ -123,13 +123,13 @@ def weights(cfg):
 @pytest.fixture(scope="module")
 def predictor(tmp_path_factory, cfg, weights):
     path = str(tmp_path_factory.mktemp("draft") / "bundle")
-    was, window_moe.CHUNK_ROWS = window_moe.CHUNK_ROWS, 16
+    was, decoder.CHUNK_ROWS = decoder.CHUNK_ROWS, 16
     try:
         window_moe.export_window_model(path, _hp(cfg), num_slots=SLOTS,
                                        prompt_buckets=BUCKETS,
                                        page_len=PAGE_LEN)
     finally:
-        window_moe.CHUNK_ROWS = was
+        decoder.CHUNK_ROWS = was
     p = GenPredictor(path)
     _install(p, weights)
     p.warmup()
