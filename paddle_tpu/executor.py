@@ -20,6 +20,7 @@ import contextlib
 import logging
 import threading
 import time
+import weakref
 
 import jax
 import jax.numpy as jnp
@@ -102,13 +103,20 @@ def _run_reader_ops(block, scope, feed_arrays, device, steps=None):
                     if not hasattr(arr, "devices") else arr
 
 
-def _as_device_array(value, dtype=None, device=None):
+def _host_value(value, dtype=None):
+    """A fed value with what the host does to it: a Python scalar made an
+    array, a numpy array cast to the variable's ``dtype``."""
     if isinstance(value, (int, float, bool)):
         value = np.asarray(value, dtype=dtype or None)
     if isinstance(value, np.ndarray) and dtype is not None:
         want = jnp.dtype(dtype) if dtype != "bfloat16" else jnp.bfloat16
         if value.dtype != want and dtype not in (None,):
             value = value.astype(want)
+    return value
+
+
+def _as_device_array(value, dtype=None, device=None):
+    value = _host_value(value, dtype)
     if device is not None and isinstance(value, (np.ndarray, jax.Array)):
         # one placement, committed, in the canonical dtype: what
         # jnp.asarray and then device_put gave in two dispatches
@@ -259,30 +267,218 @@ def _first_call(program, feed_arrays):
             jax.config.update(flag, before)
 
 
-class _CompiledBlock:
-    """A traced+jitted block for one feed/fetch signature.  ``place``
-    (mesh path only) puts the step's arguments where the executable's
-    shardings want them: ``place(span, *args) -> args``."""
+def _captured(fn, feed_arrays, fetch_names, tag=""):
+    """``fn`` (a fresh ``jax.jit``) wrapped so that its first call
+    AOT-compiles and captures the cost / memory record of this jit key
+    (``paddle_tpu profile compile``, the live MFU gauge, the headroom
+    check); ``fn`` itself with the capture off."""
+    from paddle_tpu.obs import perf as _perf
+    if not _perf.capture_enabled():
+        return fn
+    return _perf.instrument_jit(
+        fn, label=_perf.jit_label(feed_arrays, fetch_names, tag=tag))
 
-    def __init__(self, fn, feed_names, ro_names, inout_names, fetch_names,
-                 uses_rng, place=None):
-        self.fn = fn
-        self.place = place
-        self.feed_names = feed_names
-        self.ro_names = ro_names
-        self.inout_names = inout_names
-        self.fetch_names = fetch_names
-        self.uses_rng = uses_rng
-        self.fresh = True   # until its first call, which compiles it
 
-    def call(self, *args):
-        """Place (mesh path) and launch: ``executor.place`` and
-        ``executor.launch``, the last two stretches of a dispatch."""
-        if self.place is not None:
+class _DispatchRecord:
+    """What a call of one (signature, scope) would derive again although
+    it cannot have changed since the last one; :meth:`Executor.run`,
+    :meth:`Executor.run_steps`, ``ParallelExecutor.run`` and
+    :class:`CompiledStep` dispatch from it.
+
+    It holds the classification (``Executor._prepare``'s parts) and the
+    executable ``fn`` (``None`` for a caller that jits the step itself),
+    built once by the jit-cache miss that made the record, and the state:
+    ``ro`` and the carry (the in-out state; under ``run_steps`` the
+    scan's carry) as two flat tuples in name order, looked up in the
+    scope once (``resolve``).  After a launch the step's own outputs are
+    the next call's carry (``adopt``): the donated inputs are dead and
+    the outputs lie where the executable put them, so nothing is looked
+    up, compared or placed again.  The scope stays the truth: ``adopt``
+    writes every output back, and a write or erasure by anyone else
+    drops the arrays, not the classification, AT the write
+    (``Scope.watch``), so a replaced array is held by nothing here and
+    the next call resolves again.
+
+    ``shardings`` (a mesh alone): ``(feeds by name, state by name, key)``,
+    what ``place`` puts the arguments under.  An interpreted program
+    (a host op, op profiling, numerics probing) keeps no arrays and
+    takes no lock: its ops read and write the scope themselves.
+
+    The record belongs to one scope, held weakly, and lives and dies
+    with its jit-cache entry."""
+
+    def __init__(self, exe, scope, parts, fn=None, donated=True,
+                 shardings=None, scan_sample=None):
+        self._exe, self._scope = exe, weakref.ref(scope)
+        self.fn, self.fresh = fn, True  # fresh: its first call compiles
+        self.perf = getattr(fn, "perf", None)
+        self.step = parts["step"]
+        self.ro_names = parts["ro_names"]
+        self.inout_names = parts["inout_names"]
+        # what a launch hands back, in order: the in-out state, then
+        # persistables the step writes without reading
+        self.written = self.inout_names + parts["create_state"]
+        self.param_names = parts["param_names"]
+        self.interpret = parts["interpret"]
+        self.donated = donated and not self.interpret
+        self.shardings = shardings
+        # run_steps: the feeds of one step (shapes do), for the shapes of
+        # write-only persistables that ride the scan's carry
+        self._scan_sample = None if self.interpret else scan_sample
+        self.carry_names = self.inout_names
+        # (ro, carry) as resolved, None once the scope was written by
+        # someone else; ``_dirty``: written since the last ``resolve``
+        self._state, self._dirty, self._placed = None, False, False
+        # orders calls from two threads (``calling``; re-entrant: a
+        # caller's feed() may call back); ``_forget`` never waits for it,
+        # so two records over one scope cannot lock each other out
+        self._mutex = _NO_SPAN if self.interpret else threading.RLock()
+
+    def _forget(self):
+        """Someone else wrote the scope: hold none of its arrays."""
+        self._dirty, state, self._state = True, self._state, None
+        if state is not None and (state[0] or state[1]):
+            from paddle_tpu import profiler as _profiler
+            _profiler.runtime_metrics.inc("executor.record.forgets")
+
+    @contextlib.contextmanager
+    def calling(self):
+        """One call, from ``resolve`` to ``adopt``, calls from other
+        threads kept out.  A call that fails in between leaves a donating
+        record holding dead arrays, so the next one looks at the scope
+        again.  (A guarded step donates nothing: a tripped sentinel leaves
+        the record on the pre-step state.)"""
+        with self._mutex:
+            try:
+                yield
+            except BaseException:
+                if self.donated:
+                    self._state = None
+                raise
+
+    def _lookup(self, scope, name, device):
+        """``name``'s array in the scope.  For a caller that jits the step
+        itself (no ``fn`` here) it is committed to the executor's device:
+        a loaded array is not, the step's outputs are, and the two would
+        be two signatures of that caller's executable.  (``run``'s own
+        executable was compiled for what the scope held at its first
+        call; on a mesh ``place`` puts state under its sharding.)"""
+        v = self._exe._state_value(scope, name, device, by=self)
+        if self.fn is None and device is not None \
+                and isinstance(v, jax.Array) and not v.committed:
+            v = jax.device_put(v, device)
+            scope.set_var(name, v, by=self)
+        return v
+
+    def resolve(self, span=None):
+        """``(ro, carry)``: the record's own where it holds them, else
+        looked up in the scope (``span``: ``arrays`` handed to the step,
+        ``resolved`` how many of them this call looked up)."""
+        state, resolved = self._state, 0
+        if state is None:
+            self._dirty = False
+            scope, device = self._scope(), self._exe._feed_device()
+            if not self.interpret:
+                # told once of the next write by someone else: before the
+                # first lookup, so that none of them is missed
+                scope.watch(self._forget)
+            ro = tuple(self._lookup(scope, n, device)
+                       for n in self.ro_names)
+            carry = tuple(self._lookup(scope, n, device)
+                          for n in self.inout_names)
+            if self._scan_sample is not None:
+                carry = self._scan_carry(scope, device, ro, carry)
+            state, resolved = (ro, carry), len(ro) + len(carry)
+            self._placed = False
+            if not (self.interpret or self._dirty):
+                self._state = state
+        if span is not None:
+            span.set(arrays=len(state[0]) + len(state[1]),
+                     resolved=resolved)
+        return state
+
+    def _scan_carry(self, scope, device, ro, inout):
+        """The scan's carry over ``inout``: write-only persistables ride
+        it too, so their final value lands back in the scope as ``run``'s
+        does; one the scope does not hold yet is seeded with zeros of its
+        traced shape.  Sets ``carry_names``."""
+        create = self.written[len(self.inout_names):]
+        names = self.inout_names + tuple(
+            n for n in create if scope.find_var(n) is not None)
+        carry = inout + tuple(self._lookup(scope, n, device)
+                              for n in names[len(inout):])
+        still = [n for n in create if n not in names]
+        if still:
+            _, shapes = jax.eval_shape(
+                self.step, self._scan_sample,
+                dict(zip(self.ro_names, ro)),
+                dict(zip(self.inout_names, inout)), jax.random.PRNGKey(0))
+            still = [n for n in still if n in shapes]
+            names += tuple(still)
+            carry += tuple(jnp.zeros(shapes[n].shape, shapes[n].dtype)
+                           for n in still)
+        self.carry_names = names
+        return carry
+
+    def adopt(self, names, values):
+        """The step wrote ``values`` under ``names``, the carry first and
+        in ``carry_names``' order: they go back to the scope and are the
+        next call's carry, unless someone wrote the scope meanwhile."""
+        scope, by = self._scope(), None if self.interpret else self
+        for n, v in zip(names, values):
+            scope.set_var(n, v, by=by)
+        state = self._state
+        if state is not None:
+            self._state = state[0], tuple(values[:len(self.carry_names)])
+            if self._dirty:     # written on another thread just now
+                self._state = None
+
+    def place(self, span, feeds, ro, carry, key):
+        """The step's arguments under the executable's shardings
+        (``executor.place``: ``arrays`` handed to the step, ``checked``
+        those whose sharding was compared, ``moved`` put by the one
+        ``device_put``, ``bytes`` those held).  State the record placed
+        or the executable returned is where it belongs: per step the
+        feeds and the key are what is looked at."""
+        feed_at, state_at, key_at = self.shardings
+        names = tuple(feeds)
+        args = [feeds[n] for n in names] + [key]
+        want = [feed_at[n] for n in names] + [key_at]
+        if not self._placed:
+            args += ro + carry
+            want += [state_at[n] for n in self.ro_names + self.carry_names]
+        move = [i for i, (a, s) in enumerate(zip(args, want))
+                if getattr(a, "sharding", None) != s]
+        span.set(arrays=len(names) + 1 + len(ro) + len(carry),
+                 checked=len(args), moved=len(move),
+                 bytes=sum(int(getattr(args[i], "nbytes", 0))
+                           for i in move))
+        if move:
+            put = jax.device_put([args[i] for i in move],
+                                 [want[i] for i in move])
+            for i, a in zip(move, put):
+                args[i] = a
+        if not self._placed:
+            at = len(names) + 1
+            ro, carry = tuple(args[at:at + len(ro)]), \
+                tuple(args[at + len(ro):])
+            if self._state is not None:
+                self._state = ro, carry
+            self._placed = True
+        return dict(zip(names, args)), ro, carry, args[len(names)]
+
+    def call(self, feeds, ro, carry, key, *more):
+        """Place (a mesh alone) and launch: ``executor.place`` and
+        ``executor.launch``, the last two stretches of a dispatch.  The
+        executable takes the state as dicts by name (``more``: ``run_steps``'
+        per-step feeds, which go first)."""
+        if self.shardings is not None:
             with _span("executor.place") as placed:
-                args = self.place(placed, *args)
+                feeds, ro, carry, key = self.place(placed, feeds, ro,
+                                                   carry, key)
         with _span("executor.launch"):
-            return self.fn(*args)
+            return self.fn(feeds, *more, dict(zip(self.ro_names, ro)),
+                           dict(zip(self.carry_names, carry)), key)
 
 
 class CompiledStep:
@@ -291,13 +487,12 @@ class CompiledStep:
     own (``GenPredictor``'s decode turn): :meth:`Executor.compiled_step`
     makes one per (program, feed names, fetch list, scope).
 
-    What ``run`` does on every call is done here once: the program is
-    classified (``Executor._prepare``) and its read-only and in-out state
-    is resolved from the scope into two flat tuples.  They are let go of
-    the moment anyone else writes the scope (``Scope.watch``: a seeded
-    slot, a weight load), so a replaced array is held by nothing here,
-    and looked up again by the next :meth:`call`.  ``ro_names`` /
-    ``inout_names`` / ``written`` name the tuples' entries.
+    It dispatches from a :class:`_DispatchRecord` as ``run`` does, one
+    that holds no executable: the program is classified once and its
+    read-only and in-out state resolved from the scope into two flat
+    tuples, let go of the moment anyone else writes the scope (a seeded
+    slot, a weight load).  ``ro_names`` / ``inout_names`` / ``written``
+    name the tuples' entries.
 
     :meth:`flat` is the TRACEABLE step, to be inlined in the caller's
     jitted function (a nested ``jax.jit`` would rename the op scopes
@@ -305,46 +500,25 @@ class CompiledStep:
     :meth:`call` launches that function under the spans of an
     ``Executor.run`` and writes the new state back."""
 
-    def __init__(self, exe, program, scope, parts):
-        self._exe, self._program, self._scope = exe, program, scope
-        self._step = parts["step"]
-        self.ro_names = parts["ro_names"]
-        self.inout_names = parts["inout_names"]
-        # what a launch hands back, in order: the in-out state, then
-        # persistables the step writes without reading
-        self.written = self.inout_names + parts["create_state"]
-        # (ro, inout) as resolved, None once the scope was written; the
-        # mutex orders a write on another thread with a call under way
-        # (re-entrant: resolving a numpy value writes the scope itself)
-        self._state, self._mutex = None, threading.RLock()
-        scope.watch(self._forget)
+    def __init__(self, record, program):
+        self._record, self._program = record, program
+        self.ro_names = record.ro_names
+        self.inout_names = record.inout_names
+        self.written = record.written
+
+    @property
+    def _state(self):
+        return self._record._state
 
     def flat(self, feeds, ro, inout, key):
         """``(fetches, written)``: the program's step over ``feeds`` (a
         dict), ``ro`` / ``inout`` (tuples as :meth:`call` passes them)
         and an RNG ``key``; ``written`` is a tuple in the order of
         :attr:`written`.  Traceable, never jitted here."""
-        fetches, new_state = self._step(
+        fetches, new_state = self._record.step(
             feeds, dict(zip(self.ro_names, ro)),
             dict(zip(self.inout_names, inout)), key)
         return fetches, tuple(new_state[n] for n in self.written)
-
-    def _forget(self):
-        """Someone else wrote the scope: hold none of its arrays."""
-        with self._mutex:
-            self._state = None
-
-    def _resolve(self, name):
-        """``name``'s array in the scope, committed to the executor's
-        device: a loaded array is not, the step's outputs are, and the
-        two would be two signatures of one executable."""
-        device = self._exe._feed_device()
-        v = self._exe._state_value(self._scope, name, device)
-        if device is not None and isinstance(v, jax.Array) \
-                and not v.committed:
-            v = jax.device_put(v, device)
-            self._scope.set_var(name, v, by=self)
-        return v
 
     def call(self, fn, feed):
         """One launch: ``fn(*feed(), ro, inout, key) -> (out, written)``,
@@ -356,16 +530,13 @@ class CompiledStep:
         changed and ``executor.launch``, ``executor.fetch`` the
         write-back; ``executor.step_seconds`` and the HBM census's tick
         as there.  Returns ``out``, unread."""
-        exe, scope = self._exe, self._scope
-        with _span("executor.run"), self._mutex:
+        record = self._record
+        exe = record._exe
+        with _span("executor.run"), record.calling():
             with _span("executor.feed"):
                 args = feed()
             with _span("executor.dispatch"):
-                if self._state is None:
-                    self._state = (tuple(map(self._resolve, self.ro_names)),
-                                   tuple(map(self._resolve,
-                                             self.inout_names)))
-                ro, inout = self._state
+                ro, inout = record.resolve()
                 exe._run_counter += 1
                 key = _step_key((self._program.random_seed or 0) * 1000003
                                 + exe._run_counter)
@@ -376,11 +547,9 @@ class CompiledStep:
             from paddle_tpu.obs import perf as _perf
             _profiler.runtime_metrics.observe("executor.step_seconds",
                                               time.perf_counter() - t0)
-            _perf.census_tick(scope)
+            _perf.census_tick(record._scope())
             with _span("executor.fetch"):
-                self._state = ro, written[:len(inout)]
-                for n, v in zip(self.written, written):
-                    scope.set_var(n, v, by=self)
+                record.adopt(self.written, written)
         return out
 
 
@@ -651,11 +820,12 @@ class Executor:
                     return_numpy, sentinel=None):
         """Body of :meth:`run`, phase-annotated: ``executor.feed``
         (host->device conversion + reader pre-pass), ``executor.dispatch``
-        (``executor.lookup`` the compile lookup, ``executor.state`` the
-        state gathered from the scope, on a mesh ``executor.place``,
-        ``executor.launch`` the XLA launch), ``executor.fetch`` (state
-        write-back + host conversion) — the spans that answer "where did
-        step N spend its time"."""
+        (``executor.lookup`` the signature and the call's dispatch record,
+        ``executor.state`` the state the record holds, looked up in the
+        scope where it holds none, on a mesh ``executor.place``,
+        ``executor.launch`` the XLA launch), ``executor.fetch`` (the new
+        state adopted by the record and written back + host conversion) —
+        the spans that answer "where did step N spend its time"."""
         from paddle_tpu.obs import perf as _perf
         phases = _perf.step_phases_enabled()
         feed_arrays = {}
@@ -679,13 +849,12 @@ class Executor:
                                                 SPLITS_SUFFIX)
                     value, splits, meta = bucket_ragged_feed(
                         name, np.asarray(value), lod)
-                    feed_arrays[name] = _as_device_array(value, dtype,
-                                                         device)
-                    feed_arrays[name + SPLITS_SUFFIX] = _as_device_array(
-                        splits, "int32", device)
+                    feed_arrays[name] = self._feed_array(value, dtype)
+                    feed_arrays[name + SPLITS_SUFFIX] = self._feed_array(
+                        splits, "int32")
                     scope.set_lod(name, meta)
                     continue
-                feed_arrays[name] = _as_device_array(value, dtype, device)
+                feed_arrays[name] = self._feed_array(value, dtype)
                 # a dense feed must also CLEAR any stale lod from a
                 # previous ragged feed of the same variable
                 scope.set_lod(name, lod)
@@ -693,91 +862,99 @@ class Executor:
             _run_reader_ops(block, scope, feed_arrays, device)
         feed_dt = time.perf_counter() - t_feed
 
-        with _span("executor.dispatch") as dsp:
-            with _span("executor.lookup"):
-                compiled = self._get_compiled(program, block, feed_arrays,
-                                              tuple(fetch_names), scope,
-                                              donate=sentinel is None)
+        with contextlib.ExitStack() as held:
+            with _span("executor.dispatch") as dsp:
+                with _span("executor.lookup") as looked:
+                    record = self._get_compiled(program, block, feed_arrays,
+                                                tuple(fetch_names), scope,
+                                                donate=sentinel is None)
+                    looked.set(record="miss" if record.fresh else "hit")
+                held.enter_context(record.calling())
+                with _span("executor.state") as gathered:
+                    ro, inout = record.resolve(gathered)
 
-            with _span("executor.state") as gathered:
-                ro_state = {n: self._state_value(scope, n, device)
-                            for n in compiled.ro_names}
-                inout_state = {n: self._state_value(scope, n, device)
-                               for n in compiled.inout_names}
-                gathered.set(arrays=len(ro_state) + len(inout_state))
+                self._run_counter += 1
+                key = _step_key((program.random_seed or 0) * 1000003
+                                + self._run_counter)
 
-            self._run_counter += 1
-            key = _step_key(
-                (program.random_seed or 0) * 1000003 + self._run_counter)
-
-            t0 = time.perf_counter()
-            fresh, compiled.fresh = compiled.fresh, False
-            with self._compile_span(fresh, program, feed_arrays):
-                fetches, new_state = compiled.call(feed_arrays, ro_state,
-                                                   inout_state, key)
-            dsp.set(fetches=len(fetch_names))
-        dt = time.perf_counter() - t0
-        from paddle_tpu import profiler as _profiler
-        _profiler.runtime_metrics.observe("executor.step_seconds", dt)
-        holder = getattr(compiled, "perf", None)
-        perf_record = holder["record"] if holder else None
-        _perf.census_tick(scope)
-        with _span("executor.fetch"):
-            if sentinel is not None:
-                # the guard runs BEFORE write-back: a NumericalFault here
-                # leaves the scope holding the (undonated) pre-step state
-                # — the skip-step rung of the escalation ladder
-                fetches, new_state = sentinel.after_step(
-                    fetch_names, fetches, new_state,
-                    repro=lambda: self._repro_payload(
-                        program, feed_arrays, ro_state, inout_state,
-                        fetch_names),
-                    # for the fused health norms: the pre-step state
-                    # (valid: guarded steps never donate) and which of
-                    # its names are Parameters
-                    prev_state=inout_state,
-                    param_names=getattr(compiled, "param_names", ()))
-            if _check_nan_inf_enabled(program):
-                _check_nan_inf(fetch_names, fetches, new_state)
-            if phases:
-                # profile-step mode only: one explicit sync separates
-                # "device still computing" from host-side conversion
-                tw = time.perf_counter()
-                for v in list(fetches) + list(new_state.values()):
-                    if hasattr(v, "block_until_ready"):
-                        try:
-                            v.block_until_ready()
-                        except Exception:
-                            pass
-                t_fetch = time.perf_counter()
-                _profiler.runtime_metrics.observe(
-                    "perf.step.device_wait_seconds", t_fetch - tw)
-            for n, v in new_state.items():
-                scope.set_var(n, v)
-            result = [np.asarray(v) for v in fetches] if return_numpy \
-                else list(fetches)
-            gauge = _mfu_gauge_for(program)
-            if return_numpy and perf_record is not None and gauge:
-                # live MFU over the WHOLE step (feed staging -> fetch
-                # materialization): the numpy conversion above BLOCKED
-                # on the device, so this is an honest bench-style wall
-                # time (host feed/fetch overhead included, same as the
-                # analytical MFU bench.py reports).  The
-                # return_numpy=False path hands back async arrays — its
-                # submit time would overstate MFU by the async-dispatch
-                # factor, so no gauge from it.
-                _perf.note_step(perf_record, time.perf_counter() - t_feed,
-                                gauge=gauge,
-                                devices=getattr(self, "device_count", 1))
-            if phases:
-                _profiler.runtime_metrics.observe(
-                    "perf.step.feed_seconds", feed_dt)
-                _profiler.runtime_metrics.observe(
-                    "perf.step.dispatch_seconds", dt)
-                _profiler.runtime_metrics.observe(
-                    "perf.step.fetch_seconds",
-                    time.perf_counter() - t_fetch)
-            return result
+                t0 = time.perf_counter()
+                fresh, record.fresh = record.fresh, False
+                with self._compile_span(fresh, program, feed_arrays):
+                    fetches, new_state = record.call(feed_arrays, ro, inout,
+                                                     key)
+                if record.donated:
+                    # the donated arrays die where ``adopt`` replaces them,
+                    # under the device's step, and not with this frame,
+                    # after the host conversion has waited for the device
+                    # (918 arrays of 4 shards: 4 ms a dp4 step with the
+                    # chips dark)
+                    inout = None
+                dsp.set(fetches=len(fetch_names))
+            dt = time.perf_counter() - t0
+            from paddle_tpu import profiler as _profiler
+            _profiler.runtime_metrics.observe("executor.step_seconds", dt)
+            perf_record = record.perf["record"] if record.perf else None
+            _perf.census_tick(scope)
+            with _span("executor.fetch"):
+                if sentinel is not None:
+                    # the guard runs BEFORE write-back: a NumericalFault here
+                    # leaves the scope and the record holding the (undonated)
+                    # pre-step state — the skip-step rung of the escalation
+                    # ladder
+                    ro_state = dict(zip(record.ro_names, ro))
+                    inout_state = dict(zip(record.inout_names, inout))
+                    fetches, new_state = sentinel.after_step(
+                        fetch_names, fetches, new_state,
+                        repro=lambda: self._repro_payload(
+                            program, feed_arrays, ro_state, inout_state,
+                            fetch_names),
+                        # for the fused health norms: the pre-step state
+                        # (valid: guarded steps never donate) and which of
+                        # its names are Parameters
+                        prev_state=inout_state,
+                        param_names=record.param_names)
+                if _check_nan_inf_enabled(program):
+                    _check_nan_inf(fetch_names, fetches, new_state)
+                if phases:
+                    # profile-step mode only: one explicit sync separates
+                    # "device still computing" from host-side conversion
+                    tw = time.perf_counter()
+                    for v in list(fetches) + list(new_state.values()):
+                        if hasattr(v, "block_until_ready"):
+                            try:
+                                v.block_until_ready()
+                            except Exception:
+                                pass
+                    t_fetch = time.perf_counter()
+                    _profiler.runtime_metrics.observe(
+                        "perf.step.device_wait_seconds", t_fetch - tw)
+                names = [n for n in record.written if n in new_state]
+                record.adopt(names, [new_state[n] for n in names])
+                held.close()    # the host conversion waits for the device
+                result = [np.asarray(v) for v in fetches] if return_numpy \
+                    else list(fetches)
+                gauge = _mfu_gauge_for(program)
+                if return_numpy and perf_record is not None and gauge:
+                    # live MFU over the WHOLE step (feed staging -> fetch
+                    # materialization): the numpy conversion above BLOCKED
+                    # on the device, so this is an honest bench-style wall
+                    # time (host feed/fetch overhead included, same as the
+                    # analytical MFU bench.py reports).  The
+                    # return_numpy=False path hands back async arrays — its
+                    # submit time would overstate MFU by the async-dispatch
+                    # factor, so no gauge from it.
+                    _perf.note_step(perf_record, time.perf_counter() - t_feed,
+                                    gauge=gauge,
+                                    devices=getattr(self, "device_count", 1))
+                if phases:
+                    _profiler.runtime_metrics.observe(
+                        "perf.step.feed_seconds", feed_dt)
+                    _profiler.runtime_metrics.observe(
+                        "perf.step.dispatch_seconds", dt)
+                    _profiler.runtime_metrics.observe(
+                        "perf.step.fetch_seconds",
+                        time.perf_counter() - t_fetch)
+                return result
 
     # ------------------------------------------------------------------
     def _repro_payload(self, program, feed_arrays, ro_state, inout_state,
@@ -908,6 +1085,13 @@ class Executor:
         ``feed`` values may be either one batch (reused every step) or
         stacked ``[steps, ...]`` arrays (leading axis = step axis, sliced
         per step in-graph).  Fetches come back stacked ``[steps, ...]``.
+
+        A call computes its signature first (feed shapes and dtypes, the
+        fetch list, ``steps``, the program's version) and dispatches from
+        the record the jit cache holds under it: the program is
+        classified (``_prepare``) by the call that misses, and the carry
+        is the previous call's own output until someone else writes the
+        scope.
         """
         program = program if program is not None else default_main_program()
         if not isinstance(program, Program):
@@ -934,11 +1118,13 @@ class Executor:
                           scope, return_numpy):
         """Body of :meth:`run_steps` in the three phases :meth:`run` has,
         under the same span names: ``executor.feed`` (staging the window's
-        batches), ``executor.dispatch`` (``executor.lookup``: signature
-        and jit cache; ``executor.state``: the carry gathered from the
-        scope; ``executor.launch``: the one call) and ``executor.fetch``
-        (state write-back and the host conversion, which blocks until the
-        device is done)."""
+        batches), ``executor.dispatch`` (``executor.lookup``: the
+        signature, from shapes alone, FIRST, then the jit cache's dispatch
+        record, classified on a miss; ``executor.state``: the carry the
+        record holds, looked up in the scope where it holds none;
+        ``executor.launch``: the one call) and ``executor.fetch`` (the
+        final carry adopted and written back, and the host conversion,
+        which blocks until the device is done)."""
         with _span("executor.feed"):
             device = self._feed_device()
             per_step_feed = {}
@@ -1023,83 +1209,47 @@ class Executor:
             _run_reader_ops(block, scope, reader_feed, device, steps=steps)
             per_step_feed.update(reader_feed)
 
-        with _span("executor.dispatch"):
-            with _span("executor.lookup"):
-                sample = dict(const_feed)
-                sample.update({n: a[0] for n, a in per_step_feed.items()})
-                parts = self._prepare(program, block, sample,
-                                      tuple(fetch_names), scope)
-                sig = self._signature(
-                    program, block, sample, tuple(fetch_names), scope) + (
-                        "run_steps", steps, tuple(sorted(per_step_feed)))
-                step = parts["step"]
-                inout_names = parts["inout_names"]
-                create_state = parts["create_state"]
-                ro_names = parts["ro_names"]
-                fresh = False
-                if not parts["interpret"]:
-                    fn, fresh = self._scan_fn(sig, step, steps, fetch_names,
-                                              per_step_feed or const_feed)
+        with contextlib.ExitStack() as held:
+            with _span("executor.dispatch"):
+                with _span("executor.lookup") as looked:
+                    # one step's feeds, as shapes: the signature, the
+                    # classification and the carry's shapes read no more
+                    sample = dict(const_feed)
+                    sample.update(
+                        {n: jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
+                         for n, a in per_step_feed.items()})
+                    record = self._scan_record(
+                        program, block, sample, tuple(fetch_names), scope,
+                        steps, per_step_feed or const_feed,
+                        tuple(sorted(per_step_feed)))
+                    looked.set(record="miss" if record.fresh else "hit")
+                held.enter_context(record.calling())
+                with _span("executor.state") as gathered:
+                    ro, carry = record.resolve(gathered)
 
-            with _span("executor.state") as gathered:
-                ro_state = {n: self._state_value(scope, n, device)
-                            for n in ro_names}
-                inout_state = {n: self._state_value(scope, n, device)
-                               for n in inout_names}
-                carry = dict(inout_state)
-                if not parts["interpret"]:
-                    # write-only persistables (create_state) ride the carry
-                    # too so the final value lands back in the scope like
-                    # run() does; uninitialized ones are seeded with zeros
-                    # of their traced shape
-                    missing = [n for n in create_state if n not in carry]
-                    for n in missing:
-                        if scope.find_var(n) is not None:
-                            carry[n] = self._state_value(scope, n, device)
-                    still = [n for n in missing if n not in carry]
-                    if still:
-                        _, out_shapes = jax.eval_shape(
-                            step, sample, ro_state, inout_state,
-                            jax.random.PRNGKey(0))
-                        for n in still:
-                            if n in out_shapes:
-                                sd = out_shapes[n]
-                                carry[n] = jnp.zeros(sd.shape, sd.dtype)
-                gathered.set(arrays=len(ro_state) + len(carry))
+                self._run_counter += 1
+                base_key = jax.random.PRNGKey(
+                    (program.random_seed or 0) * 1000003 + self._run_counter)
 
-            self._run_counter += 1
-            base_key = jax.random.PRNGKey(
-                (program.random_seed or 0) * 1000003 + self._run_counter)
+                if record.interpret:
+                    # host ops: plain Python loop (still correct, just not
+                    # fused)
+                    return self._interpret_steps(
+                        record, const_feed, per_step_feed, ro, carry,
+                        base_key, steps, len(fetch_names), return_numpy)
 
-            if parts["interpret"]:
-                # host ops: plain Python loop (still correct, just not fused)
-                keys = jax.random.split(base_key, steps)
-                outs = []
-                for i in range(steps):
-                    feeds_i = dict(const_feed)
-                    feeds_i.update({n: a[i] for n, a in per_step_feed.items()})
-                    fetches, new_state = step(feeds_i, ro_state, inout_state,
-                                              keys[i])
-                    inout_state = dict(inout_state)
-                    inout_state.update(new_state)
-                    outs.append(fetches)
-                for n, v in inout_state.items():
-                    scope.set_var(n, v)
-                stacked = [jnp.stack([o[i] for o in outs])
-                           for i in range(len(fetch_names))]
-                return [np.asarray(v) for v in stacked] if return_numpy \
-                    else stacked
-
-            t0 = time.perf_counter()
-            with self._compile_span(fresh, program, sample):
-                with _span("executor.launch"):
-                    ys, final = fn(const_feed, per_step_feed, ro_state,
-                                   carry, base_key)
-        with _span("executor.fetch"):
-            for n, v in final.items():
-                scope.set_var(n, v)
-            result = [np.asarray(v) for v in ys] if return_numpy \
-                else list(ys)
+                t0 = time.perf_counter()
+                fresh, record.fresh = record.fresh, False
+                with self._compile_span(fresh, program, sample):
+                    ys, final = record.call(const_feed, ro, carry, base_key,
+                                            per_step_feed)
+                carry = None    # donated: dies in ``adopt``, as in ``run``
+            with _span("executor.fetch"):
+                record.adopt(record.carry_names,
+                             [final[n] for n in record.carry_names])
+                held.close()    # the host conversion waits for the device
+                result = [np.asarray(v) for v in ys] if return_numpy \
+                    else list(ys)
         from paddle_tpu.obs import perf as _perf
         gauge = _mfu_gauge_for(program)
         if return_numpy and gauge:
@@ -1109,8 +1259,7 @@ class Executor:
             # conversion above blocks on the device, so only this path
             # yields an honest window wall time (async submit time
             # would overstate MFU by orders of magnitude)
-            holder = getattr(fn, "perf", None)
-            _perf.note_step(holder["record"] if holder else None,
+            _perf.note_step(record.perf["record"] if record.perf else None,
                             time.perf_counter() - t0,
                             gauge=gauge,
                             devices=getattr(self, "device_count", 1),
@@ -1118,18 +1267,47 @@ class Executor:
         _perf.census_tick(scope)
         return result
 
-    # ------------------------------------------------------------------
-    def _scan_fn(self, sig, step, steps, fetch_names, label_feeds):
-        """``(fn, fresh)``: the jitted ``steps``-step scan over ``step``
-        for ``sig``, from the jit cache or built (``fresh``: its first
-        call compiles)."""
-        from paddle_tpu import profiler as _profiler
-        if sig in self._cache:
-            self._cache[sig] = self._cache.pop(sig)
-            _profiler.runtime_metrics.inc("jit_cache.hits")
-            return self._cache[sig], False
-        _profiler.runtime_metrics.inc("jit_cache.misses")
+    @staticmethod
+    def _interpret_steps(record, const_feed, per_step_feed, ro, inout,
+                         base_key, steps, n_fetches, return_numpy):
+        """``run_steps`` over a program that has to be interpreted: the
+        steps one by one, the state written back after the last."""
+        keys = jax.random.split(base_key, steps)
+        ro_state = dict(zip(record.ro_names, ro))
+        inout_state = dict(zip(record.inout_names, inout))
+        outs = []
+        for i in range(steps):
+            feeds_i = dict(const_feed)
+            feeds_i.update({n: a[i] for n, a in per_step_feed.items()})
+            fetches, new_state = record.step(feeds_i, ro_state, inout_state,
+                                             keys[i])
+            inout_state = dict(inout_state)
+            inout_state.update(new_state)
+            outs.append(fetches)
+        record.adopt(tuple(inout_state), tuple(inout_state.values()))
+        stacked = [jnp.stack([o[i] for o in outs]) for i in range(n_fetches)]
+        return [np.asarray(v) for v in stacked] if return_numpy else stacked
 
+    # ------------------------------------------------------------------
+    def _scan_record(self, program, block, sample, fetch_names, scope, steps,
+                     label_feeds, per_step_names):
+        """The dispatch record of ``steps`` steps of ``program`` in one
+        scan: the signature first (cheap: shapes, dtypes and names),
+        ``_prepare`` on a jit-cache miss alone."""
+        def jit(parts):
+            return _captured(self._scan_fn(parts["step"], steps), label_feeds,
+                             fetch_names, tag=f"scan{steps}"), None
+
+        sig = self._signature(program, block, sample, fetch_names, scope) \
+            + ("run_steps", steps, per_step_names)
+        return self._record(sig, program, block, sample, fetch_names, scope,
+                            jit, scan_sample=sample)
+
+    @staticmethod
+    def _scan_fn(step, steps):
+        """The jitted ``steps``-step scan over ``step``, the carry
+        donated.  (The executable's instructions carry this function's
+        name, ``..._scan_fn_..._multi``: a traced cell reads them.)"""
         def multi(const_feeds, per_feeds, ro_state, carry, base_key):
             keys = jax.random.split(base_key, steps)
 
@@ -1145,14 +1323,7 @@ class Executor:
             carry, ys = jax.lax.scan(body, carry, (keys, per_feeds))
             return ys, carry
 
-        fn = jax.jit(multi, donate_argnums=(3,))
-        from paddle_tpu.obs import perf as _perf
-        if _perf.capture_enabled():
-            fn = _perf.instrument_jit(
-                fn, label=_perf.jit_label(label_feeds, fetch_names,
-                                          tag=f"scan{steps}"))
-        self._cache_insert(sig, fn)
-        return fn, True
+        return jax.jit(multi, donate_argnums=(3,))
 
     # ------------------------------------------------------------------
     def run_pipeline(self, program=None, pipeline=None, fetch_list=None,
@@ -1319,14 +1490,14 @@ class Executor:
         if _env_flag("PADDLE_TPU_VERIFY"):
             self._maybe_verify(program, feed, fetch_names)
         program = self._maybe_optimize(program, feed, fetch_names)
-        parts = self._prepare(program, program.global_block(), feed,
-                              fetch_names, scope)
+        parts = self._classify(program, program.global_block(), feed,
+                               fetch_names, scope)
         if parts["interpret"]:
             raise NotImplementedError(
                 "compiled_step: the program has to be interpreted op by "
                 "op (a host op, or op profiling is on); run it through "
                 "Executor.run")
-        return CompiledStep(self, program, scope, parts)
+        return CompiledStep(_DispatchRecord(self, scope, parts), program)
 
     # ------------------------------------------------------------------
     def _feed_device(self):
@@ -1334,8 +1505,16 @@ class Executor:
         None so sharded placement happens against the mesh instead."""
         return self.place.jax_device()
 
+    def _feed_array(self, value, dtype):
+        """One of ``run``'s feeds as the step takes it: on the executor's
+        device.  (``ParallelExecutor`` leaves host memory on the host for
+        ``executor.place``.)"""
+        return _as_device_array(value, dtype, self._feed_device())
+
     # ------------------------------------------------------------------
-    def _state_value(self, scope, name, device):
+    def _state_value(self, scope, name, device, by=None):
+        """``name``'s value in the scope, a host array put on ``device``
+        and written back (``by``: the watcher that asks, ``Scope.watch``)."""
         v = scope.find_var(name)
         if v is None:
             raise RuntimeError(
@@ -1347,7 +1526,7 @@ class Executor:
             # (one extra compile on the second call)
             v = jax.device_put(jnp.asarray(v), device) if device is not None \
                 else jnp.asarray(v)
-            scope.set_var(name, v)
+            scope.set_var(name, v, by=by)
         return v
 
     # ------------------------------------------------------------------
@@ -1375,13 +1554,42 @@ class Executor:
                 tuple(fetch_names))
 
     # ------------------------------------------------------------------
+    def _interprets(self, program, block):
+        """Whether ``block`` has to run op by op, eagerly: a host op, op
+        profiling, numerics probing, or a run-once initializer."""
+        from paddle_tpu import profiler as _profiler
+        from paddle_tpu.obs import numerics as _numerics
+        interpret = _has_host_ops(
+            block, dyn=_lod_buckets_enabled(program))
+        if interpret and not getattr(program, "expect_host_ops", False):
+            _warn_host_op_cliff(program, block)
+        # the opt pipeline's compile-amortization gate: a run-once
+        # initializer whose static cost proves the XLA compile can
+        # never pay for itself executes op-by-op eagerly instead
+        # (34-51% of the zoo's measured cold start; JAX PRNG is
+        # deterministic across eager and compiled, so init values are
+        # unchanged)
+        return bool(interpret or _profiler.op_profiling_enabled()
+                    or _numerics.probing_enabled()
+                    or getattr(program, "_opt_interpret", False))
+
+    def _step_aux(self):
+        """What this executor adds to the ``aux`` every lowering sees
+        (``ParallelExecutor``: its mesh and batch axis)."""
+        return {}
+
     def _prepare(self, program, block, feed_arrays, fetch_names, scope):
-        """Classify block variables and build the traceable step function.
+        """Classify block variables and build the traceable step function:
+        THE classification of a program, for every executor and every way
+        to call one.
 
         Returns a dict with the (untraced) ``step`` callable, the
         state-name partitions, and the interpret flag.  Of ``feed_arrays``
-        only the names (and their lods in the scope) are read.
-        O(#ops) — callers should hit the signature cache first.
+        only the names (and their lods in the scope) are read: shapes do.
+        O(#ops): ``run``, ``run_steps`` and the mesh path compute the
+        cheap signature first and come here on a jit-cache miss alone
+        (``_classify``); what it returns lives in the miss's dispatch
+        record.
         """
         feed_names = tuple(sorted(feed_arrays))
 
@@ -1434,25 +1642,8 @@ class Executor:
         # persistables written but never read still need write-back
         create_state = tuple(n for n in written_state if n not in inout_names)
 
-        uses_rng = True  # cheap: always thread a key; XLA drops it if unused
-
         training = not program._is_inference
-        from paddle_tpu import profiler as _profiler
-        interpret = _has_host_ops(
-            block, dyn=_lod_buckets_enabled(program))
-        if interpret and not getattr(program, "expect_host_ops", False):
-            _warn_host_op_cliff(program, block)
-        interpret = interpret or _profiler.op_profiling_enabled()
-        from paddle_tpu.obs import numerics as _numerics
-        interpret = interpret or _numerics.probing_enabled()
-        # the opt pipeline's compile-amortization gate: a run-once
-        # initializer whose static cost proves the XLA compile can
-        # never pay for itself executes op-by-op eagerly instead
-        # (34-51% of the zoo's measured cold start; JAX PRNG is
-        # deterministic across eager and compiled, so init values are
-        # unchanged)
-        interpret = interpret or getattr(program, "_opt_interpret",
-                                         False)
+        interpret = self._interprets(program, block)
 
         from paddle_tpu.lod import DynLoD, SPLITS_SUFFIX
         lod_map = {}
@@ -1502,7 +1693,7 @@ class Executor:
                    # proven key-free skip their per-op fold_in
                    "rng_plan": True
                    if getattr(program, "_opt_rng_plan", False)
-                   else None}
+                   else None, **self._step_aux()}
             if release_map is not None:
                 stats = release_map[block.idx]["stats"]
                 stats["bytes"] = stats["vars"] = 0  # per-run measurement
@@ -1530,49 +1721,68 @@ class Executor:
         return {"step": step, "feed_names": feed_names,
                 "ro_names": ro_names, "inout_names": inout_names,
                 "create_state": create_state, "interpret": interpret,
-                "uses_rng": uses_rng, "param_names": param_names}
+                "param_names": param_names}
 
     # ------------------------------------------------------------------
+    def _cached(self, sig):
+        """The dispatch record the jit cache (an LRU) holds under ``sig``,
+        or None; counted (``jit_cache.*``, ``executor.record.hits``)."""
+        from paddle_tpu import profiler as _profiler
+        record = self._cache.pop(sig, None)
+        if record is None:
+            _profiler.runtime_metrics.inc("jit_cache.misses")
+            return None
+        self._cache[sig] = record   # LRU bump
+        _profiler.runtime_metrics.inc("jit_cache.hits")
+        _profiler.runtime_metrics.inc("executor.record.hits")
+        return record
+
+    def _classify(self, program, block, feed_arrays, fetch_names, scope):
+        """:meth:`_prepare`, for the record a jit-cache miss builds."""
+        from paddle_tpu import profiler as _profiler
+        _profiler.runtime_metrics.inc("executor.record.misses")
+        with _profiler.record_latency("executor.prepare_seconds"):
+            return self._prepare(program, block, feed_arrays, fetch_names,
+                                 scope)
+
+    def _record(self, sig, program, block, feed_arrays, fetch_names, scope,
+                jit, **kept):
+        """The dispatch record under ``sig``, from the jit cache or built:
+        classified, jitted by ``jit(parts) -> (fn, shardings)`` (compiled
+        by its first call) and kept under ``sig``."""
+        record = self._cached(sig)
+        if record is None:
+            parts = self._classify(program, block, feed_arrays, fetch_names,
+                                   scope)
+            # interpreted: op-by-op eager execution — needed when a host
+            # op (data-dependent shapes, numpy DP) is in the block; the
+            # reference's analogous path is its per-op CPU-kernel
+            # interpreter
+            fn, shardings = (parts["step"], None) if parts["interpret"] \
+                else jit(parts)
+            record = _DispatchRecord(self, scope, parts, fn,
+                                     shardings=shardings, **kept)
+            self._cache_insert(sig, record)
+        return record
+
     def _get_compiled(self, program, block, feed_arrays, fetch_names, scope,
                       donate=True):
-        from paddle_tpu import profiler as _profiler
+        """The dispatch record of one step of ``program``."""
         # donation is part of the executable's identity: a sentinel-
         # guarded step (donate=False) must be able to discard its update,
         # so the pre-step state buffers have to stay valid
         sig = self._signature(program, block, feed_arrays, fetch_names,
                               scope) + (("donate", donate),)
-        if sig in self._cache:
-            self._cache[sig] = self._cache.pop(sig)  # LRU bump
-            _profiler.runtime_metrics.inc("jit_cache.hits")
-            return self._cache[sig]
-        _profiler.runtime_metrics.inc("jit_cache.misses")
-        with _profiler.record_latency("executor.prepare_seconds"):
-            parts = self._prepare(program, block, feed_arrays, fetch_names,
-                                  scope)
+        return self._record(
+            sig, program, block, feed_arrays, fetch_names, scope,
+            lambda parts: self._jit_step(parts, feed_arrays, fetch_names,
+                                         scope, donate), donated=donate)
 
-        if parts["interpret"]:
-            # op-by-op eager execution — needed when a host op (data-
-            # dependent shapes, numpy DP) is in the block; the reference's
-            # analogous path is its per-op CPU-kernel interpreter
-            fn = parts["step"]
-        else:
-            fn = jax.jit(parts["step"],
-                         donate_argnums=(2,) if donate else ())
-            from paddle_tpu.obs import perf as _perf
-            if _perf.capture_enabled():
-                # the first call AOT-compiles and captures the cost/
-                # memory record for this jit key (paddle_tpu profile
-                # compile, the live MFU gauge, the headroom check)
-                fn = _perf.instrument_jit(
-                    fn, label=_perf.jit_label(feed_arrays, fetch_names))
-        compiled = _CompiledBlock(fn, parts["feed_names"],
-                                  parts["ro_names"], parts["inout_names"],
-                                  tuple(fetch_names), parts["uses_rng"])
-        compiled.donated = donate and not parts["interpret"]
-        compiled.perf = getattr(fn, "perf", None)
-        compiled.param_names = parts["param_names"]
-        self._cache_insert(sig, compiled)
-        return compiled
+    def _jit_step(self, parts, feed_arrays, fetch_names, scope, donate):
+        """``(fn, shardings)``: the classified step jitted (``shardings``:
+        what a mesh's record places its arguments under; None here)."""
+        fn = jax.jit(parts["step"], donate_argnums=(2,) if donate else ())
+        return _captured(fn, feed_arrays, fetch_names), None
 
     @staticmethod
     def fetch_missing_check(fetch_names, env):
@@ -1716,7 +1926,7 @@ def _warn_host_op_cliff(program, block):
         f"{sorted(set(culprits))} — the whole block runs op-by-op eager "
         f"instead of one compiled XLA computation; keep host ops "
         f"(metrics/decoding) in a separate program to keep training "
-        f"compiled", stacklevel=3)
+        f"compiled", stacklevel=4)
 
 
 def _has_host_ops(block, dyn=False):

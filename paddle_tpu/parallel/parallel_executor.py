@@ -33,11 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from paddle_tpu import framework
-from paddle_tpu.executor import (Executor, _CompiledBlock, _amp_enabled,
-                                 lower_block)
+from paddle_tpu.executor import Executor, _captured, _host_value
 from paddle_tpu.framework import default_main_program
-from paddle_tpu.scope import global_scope
 from paddle_tpu.parallel.mesh import default_mesh, DATA_AXIS
 
 __all__ = ["ParallelExecutor"]
@@ -99,36 +96,25 @@ class ParallelExecutor(Executor):
                            return_numpy=return_numpy, sentinel=sentinel)
 
     # -- sharding-aware compile ----------------------------------------
-    def _get_compiled(self, program, block, feed_arrays, fetch_names, scope,
-                      donate=True):
-        from paddle_tpu.executor import _freeze_lod
-        feed_lods = tuple(sorted(
-            (n, _freeze_lod(scope.find_lod(n))) for n in feed_arrays
-            if scope.find_lod(n) is not None))
-        from paddle_tpu import profiler as _profiler
-        sig = ("pexe", id(program), program._version, block.idx,
-               tuple(sorted((n, str(a.dtype), a.shape)
-                            for n, a in feed_arrays.items())),
-               feed_lods,
-               fetch_names, donate, _amp_enabled(program))
-        if sig in self._cache:
-            self._cache[sig] = self._cache.pop(sig)  # LRU bump
-            _profiler.runtime_metrics.inc("jit_cache.hits")
-            return self._cache[sig]
-        # count the sharded-wrapper miss HERE: super() below also counts
-        # its base-signature lookup, and that one can legitimately hit
-        # while this level re-jits (each parallel program holds two
-        # cache entries — base step + sharded wrapper)
-        _profiler.runtime_metrics.inc("jit_cache.misses")
+    def _interprets(self, program, block):
+        """Never: a mesh runs the compiled step, whatever the program
+        holds (the host-op warning is the plain executor's)."""
+        super()._interprets(program, block)
+        return False
 
-        base = super()._get_compiled(program, block, feed_arrays,
-                                     fetch_names, scope, donate=donate)
+    def _step_aux(self):
+        return {"mesh": self.mesh, "batch_axis": self.batch_axis}
+
+    def _jit_step(self, parts, feed_arrays, fetch_names, scope, donate):
+        """The SAME classified step as ``Executor``'s, jitted with explicit
+        shardings over the mesh; the record places its arguments under
+        them (``executor.place``)."""
         mesh = self.mesh
         repl = NamedSharding(mesh, P())
         data_size = dict(zip(mesh.axis_names,
                              mesh.devices.shape)).get(DATA_AXIS, 1)
 
-        def feed_sharding(name, arr):
+        def feed_sharding(arr):
             # batch-shard data along the batch axis over the 'data' mesh
             # axis when divisible
             if arr.ndim > 0 and data_size > 1 and \
@@ -142,109 +128,44 @@ class ParallelExecutor(Executor):
             v = scope.find_var(n)
             return getattr(v, "shape", None) if v is not None else None
 
+        ro_names, inout_names = parts["ro_names"], parts["inout_names"]
+        written = inout_names + parts["create_state"]
         state_shardings = {n: self._state_sharding(n, shape_of(n))
-                           for n in (*base.ro_names, *base.inout_names)}
-        out_state_names = list(dict.fromkeys(
-            list(base.inout_names) + _written_persistables(block)))
-        for n in out_state_names:
-            state_shardings.setdefault(
-                n, self._state_sharding(n, shape_of(n)))
-
+                           for n in (*ro_names, *written)}
+        feed_shardings = {n: feed_sharding(a)
+                          for n, a in feed_arrays.items()}
         in_shardings = (
-            {n: feed_sharding(n, a) for n, a in feed_arrays.items()},
-            {n: state_shardings[n] for n in base.ro_names},
-            {n: state_shardings[n] for n in base.inout_names},
+            feed_shardings,
+            {n: state_shardings[n] for n in ro_names},
+            {n: state_shardings[n] for n in inout_names},
             repl,  # rng key
         )
-        training = not program._is_inference
-        # the SAME mixed-precision switch as Executor._prepare: a program
-        # marked amp must not silently train in f32 once it meets a mesh
-        amp = _amp_enabled(program)
-        from paddle_tpu.lod import DynLoD, SPLITS_SUFFIX
-        lod_map = {}
-        for n, lod in feed_lods:
-            if isinstance(lod, tuple) and lod and lod[0] == "dyn":
-                lod_map[n] = DynLoD(n + SPLITS_SUFFIX, lod[1], lod[2])
-            else:
-                lod_map[n] = [list(level) for level in lod]
-
-        def step(feeds, ro_state, inout_state, rng_key):
-            env = {}
-            env.update(feeds)
-            env.update(ro_state)
-            env.update(inout_state)
-            aux = {"rng_counter": 0, "scope": scope,
-                   "lower_block": lower_block, "mesh": mesh,
-                   "batch_axis": self.batch_axis,
-                   "lod": dict(lod_map), "amp": amp,
-                   # opt-pipeline fact (see Executor._prepare): key-
-                   # free ops skip their per-op fold_in at trace time
-                   "rng_plan": True
-                   if getattr(program, "_opt_rng_plan", False)
-                   else None}
-            # the whole-step scope Executor._prepare opens: a scope path
-            # reads pt_step/<role>/<scope...>/ptop_... on the mesh too
-            with jax.named_scope("pt_step"):
-                lower_block(block, env, rng_key, training, aux)
-                fetches = [env[n] for n in fetch_names]
-                new_state = {n: env[n] for n in out_state_names
-                             if n in env}
-            return fetches, new_state
-
-        # trace once abstractly to learn which state names actually get
-        # produced, so out_shardings matches the returned dict exactly
-        out_shardings = (None, {n: state_shardings[n]
-                                for n in out_state_names})
-        jitted = jax.jit(step, in_shardings=in_shardings,
+        # written state keeps its input's sharding (which forces XLA to
+        # insert the gradient all-reduce / reduce-scatter)
+        out_shardings = (None, {n: state_shardings[n] for n in written})
+        jitted = jax.jit(parts["step"], in_shardings=in_shardings,
                          out_shardings=out_shardings,
                          donate_argnums=(2,) if donate else ())
-        from paddle_tpu.obs import perf as _perf
-        if _perf.capture_enabled():
-            # cost/memory capture on the sharded executable: the
-            # recorded FLOPs cover the WHOLE mesh, so note_step divides
-            # by device_count when deriving the live MFU gauge
-            jitted = _perf.instrument_jit(
-                jitted, label=_perf.jit_label(
-                    feed_arrays, fetch_names,
-                    tag=f"mesh{tuple(mesh.devices.shape)}"))
-        feed_shardings = in_shardings[0]
-
-        def place_args(span, feeds, ro_state, inout_state, rng_key):
-            """The step's arguments under the executable's shardings
-            (``executor.place``: ``arrays`` looked at, ``moved`` put by
-            a ``device_put``, ``bytes`` those held)."""
-            moved = [0, 0]
-
-            def place(a, sharding):
-                # skip the device_put dispatch when already placed (state
-                # is sharded after the first step; only feeds arrive fresh)
-                if getattr(a, "sharding", None) == sharding:
-                    return a
-                moved[0] += 1
-                moved[1] += int(getattr(a, "nbytes", 0))
-                return jax.device_put(a, sharding)
-
-            feeds = {n: place(a, feed_shardings[n])
-                     for n, a in feeds.items()}
-            ro_state = {n: place(a, state_shardings[n])
-                        for n, a in ro_state.items()}
-            inout_state = {n: place(a, state_shardings[n])
-                           for n, a in inout_state.items()}
-            rng_key = place(rng_key, repl)
-            span.set(arrays=len(feeds) + len(ro_state) + len(inout_state)
-                     + 1, moved=moved[0], bytes=moved[1])
-            return feeds, ro_state, inout_state, rng_key
-
-        compiled = _CompiledBlock(jitted, base.feed_names, base.ro_names,
-                                  base.inout_names, tuple(fetch_names), True,
-                                  place=place_args)
-        compiled.donated = donate
-        compiled.perf = getattr(jitted, "perf", None)
-        self._cache_insert(sig, compiled)
-        return compiled
+        # cost/memory capture on the sharded executable: the recorded
+        # FLOPs cover the WHOLE mesh, so note_step divides by device_count
+        # when deriving the live MFU gauge
+        jitted = _captured(jitted, feed_arrays, fetch_names,
+                           tag=f"mesh{tuple(mesh.devices.shape)}")
+        return jitted, (feed_shardings, state_shardings, repl)
 
     def _feed_device(self):
         return None
+
+    def _feed_array(self, value, dtype):
+        """Host memory stays on the host, in the dtype the device would
+        hold: ``executor.place`` puts it under its sharding in one
+        ``device_put``, not on device 0 first and then across."""
+        value = _host_value(value, dtype)
+        if isinstance(value, (np.ndarray, np.generic, list, tuple)):
+            value = np.asarray(value)
+            return value.astype(jax.dtypes.canonicalize_dtype(value.dtype),
+                                copy=False)
+        return jnp.asarray(value)
 
 
 def _spec_fits(spec, shape, mesh):
@@ -263,19 +184,3 @@ def _spec_fits(spec, shape, mesh):
         if dim is None or dim < 0 or dim % k:
             return False
     return True
-
-
-def _written_persistables(block):
-    from paddle_tpu.executor import _SKIP_OPS
-    out = []
-    for op in block.ops:
-        if op.type in _SKIP_OPS:  # reader vars hold host objects, not state
-            continue
-        for n in op.output_arg_names:
-            try:
-                var = block.var(n)
-            except KeyError:
-                continue
-            if var.persistable and n not in out:
-                out.append(n)
-    return out

@@ -12,6 +12,7 @@ from __future__ import annotations
 __all__ = ["Scope", "global_scope", "scope_guard"]
 
 import contextlib
+import threading
 import weakref
 
 
@@ -22,9 +23,10 @@ class Scope:
         self.kids = []
         # LoD metadata (row-splits per level) carried next to ragged tensors
         self._lod = {}
-        # bound methods (held weakly) told of every write and erasure of
-        # this scope's own variables (``watch``)
+        # bound methods (held weakly) to be told of the next write or
+        # erasure of this scope's own variables (``watch``)
         self._watchers = []
+        self._watch_lock = threading.Lock()
 
     def new_scope(self):
         kid = Scope(parent=self)
@@ -70,20 +72,27 @@ class Scope:
         self._wrote(None)
 
     def watch(self, method):
-        """Call the bound ``method()`` after every ``set_var`` / ``erase``
-        that a lookup from this scope can see (its own and its
-        ancestors'), so a caller that keeps resolved arrays
-        (``Executor.compiled_step``) lets go of them the moment one is
-        replaced, and not when it next looks.  Held weakly."""
+        """Call the bound ``method()`` at the NEXT ``set_var`` / ``erase``
+        by anyone else that a lookup from this scope can see (its own and
+        its ancestors'), so a caller that keeps resolved arrays (an
+        executor's dispatch record) lets go of them the moment one is
+        replaced, and not when it next looks.  Told once: whoever resolves
+        again watches again, so a write costs what the watchers that hold
+        something cost, not one call a watcher ever made.  Held weakly."""
         ref, s = weakref.WeakMethod(method), self
         while s is not None:
-            s._watchers = [w for w in s._watchers if w() is not None]
-            s._watchers.append(ref)
+            with s._watch_lock:
+                if ref not in s._watchers:
+                    s._watchers.append(ref)
             s = s.parent
 
     def _wrote(self, by):
-        for ref in self._watchers:
-            method = ref()
+        with self._watch_lock:
+            methods = [ref() for ref in self._watchers]
+            # the writer itself stays: its own write tells it nothing
+            self._watchers = [ref for ref, m in zip(self._watchers, methods)
+                              if m is not None and m.__self__ is by]
+        for method in methods:
             if method is not None and method.__self__ is not by:
                 method()
 

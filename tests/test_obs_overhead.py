@@ -361,11 +361,20 @@ class TestDispatchChildren:
             assert kids[-1]["ts"] + kids[-1]["dur"] <= end + 1e-9
             by_name = {k["name"]: k["attrs"] for k in kids}
             assert by_name["executor.state"]["arrays"] > 0
+            # the call dispatches from its record: compiled already, so
+            # a hit; ``run`` and ``run_steps`` share a scope here, so
+            # each looks up what the other's write-back replaced, and
+            # the mesh path, alone on its scope, looks up nothing
+            assert by_name["executor.lookup"]["record"] == "hit"
+            assert by_name["executor.state"]["resolved"] == \
+                (0 if how == "mesh"
+                 else by_name["executor.state"]["arrays"])
             if how == "mesh":
                 placed = by_name["executor.place"]
                 # state stays placed after the first step: the feeds
-                # and the key are what moves
+                # and the key are what is compared and what moves
                 assert placed["arrays"] > placed["moved"] >= 3
+                assert placed["checked"] == len(self._feed()) + 1
                 assert placed["bytes"] > 0
             assert not any(s["name"] == "executor.compile" for s in spans)
 
