@@ -957,6 +957,45 @@ def _c_ssm_conv(op, info):
     return 2 * n * w[0] + 10 * n, io_bytes(op, info)
 
 
+rule("ssm_chunk_conv")(_c_ssm_conv)
+
+
+def _kda_dims(op, info):
+    """``(rows, heads, head width)`` of a KDA op from its X (q | k | v)."""
+    x = _shape(info, op, "X")
+    rows = numel(x[:-1]) if x is not None else None
+    if rows is None or not _known(x[-1]):
+        return None
+    h = int(op.attr("n_head"))
+    return rows, h, x[-1] // (3 * h)
+
+
+@rule("kda_scan")
+def _c_kda_scan(op, info):
+    """The chunk-wise form in blocks of 64 rows: per row and head the
+    block's two pair matrices (2 * 2 q d), the solve against v | k (q
+    (d + d)), the three products with the carried state (3 * 2 d d) and
+    the block's own with its pseudo-values (2 q d)."""
+    dims = _kda_dims(op, info)
+    if dims is None:
+        return None
+    rows, h, d = dims
+    q = 64      # ops/kda_ops.BLOCK
+    return rows * h * (8 * q * d + 6 * d * d), io_bytes(op, info)
+
+
+@rule("kda_update")
+def _c_kda_update(op, info):
+    """One step: the whole state is read and written (io_bytes counts
+    State and StateOut), ~8 FLOPs an element of it."""
+    n = numel(_shape(info, op, "State"))
+    return None if n is None else (8 * n, io_bytes(op, info))
+
+
+rule("kda_gated_norm")(_per_element(12))
+rule("attention_out_gate", "attention_out_gate_grad")(_per_element(6))
+
+
 @rule("ssm_scan")
 def _c_ssm_scan(op, info):
     """The chunked algorithm: per row, per head, C.B and the masked

@@ -988,6 +988,76 @@ def _r_ssm_conv(op, tc):
                       shape=None if chans is None else (1, taps, chans))
 
 
+@rule("ssm_chunk_conv")
+def _r_ssm_chunk_conv(op, tc):
+    x = _same_as(op, tc)
+    chans = x.shape[-1] if x.shape is not None else None
+    for slot in ("W", "Bias", "Window"):
+        _last_dim_is(op, tc, slot, chans, "channels")
+    for slot in ("Slot", "Pos"):
+        _int_index(op, tc, slot)
+    win = tc.input_info(op, "Window")
+    tc.set_output(op, "WindowOut", shape=win.shape, dtype=win.dtype)
+
+
+@rule("kda_scan", "kda_update")
+def _r_kda(op, tc):
+    """X holds q | k | v of ``n_head`` heads of one width D; the state is
+    [slots, n_head, D, D] float32 and comes back as it went in."""
+    h = int(op.attr("n_head"))
+    x = tc.input_info(op, "X")
+    width = x.shape[-1] if x.shape is not None else None
+    d = None
+    if width is not None and width > 0:
+        if width % (3 * h):
+            tc.report("PTA006", f"{op.type}: {width} features do not "
+                      f"split into q | k | v of {h} heads", op=op,
+                      var=op.input("X")[0])
+        else:
+            d = width // (3 * h)
+    _last_dim_is(op, tc, "F", d and h * d, "decay channels")
+    _last_dim_is(op, tc, "DtBias", d and h * d, "decay channels")
+    _last_dim_is(op, tc, "B", h, "heads")
+    _last_dim_is(op, tc, "ALog", h, "heads")
+    for slot in ("Slot", "Pos", "Lens"):
+        _int_index(op, tc, slot)
+    st = tc.input_info(op, "State")
+    if d and st.shape is not None and len(st.shape) == 4 and \
+            all(n > 0 for n in st.shape[1:]) and \
+            tuple(st.shape[1:]) != (h, d, d):
+        tc.report("PTA006", f"{op.type} State `{op.input('State')[0]}` is "
+                  f"{st.shape}, expected [slots, {h}, {d}, {d}]", op=op,
+                  var=op.input("State")[0])
+    if st.dtype is not None and st.dtype != "float32":
+        tc.report("PTA005", f"{op.type} keeps its state in float32, got "
+                  f"{st.dtype}", op=op, var=op.input("State")[0])
+    shape = None if x.shape is None else \
+        tuple(x.shape[:-1]) + (h * d if d else -1,)
+    tc.set_output(op, "Out", shape=shape, dtype=x.dtype)
+    tc.set_output(op, "StateOut", shape=st.shape, dtype=st.dtype)
+
+
+@rule("kda_gated_norm")
+def _r_kda_gated_norm(op, tc):
+    x = _same_as(op, tc)
+    h = int(op.attr("n_head"))
+    width = x.shape[-1] if x.shape is not None else None
+    _last_dim_is(op, tc, "Gate", width, "gate features")
+    if width and width > 0:
+        if width % h:
+            tc.report("PTA006", f"kda_gated_norm width {width} does not "
+                      f"split into {h} heads", op=op, var=op.input("X")[0])
+        else:
+            _last_dim_is(op, tc, "Scale", width // h, "scales (one head's)")
+
+
+@rule("attention_out_gate")
+def _r_attention_out_gate(op, tc):
+    x = _same_as(op, tc)
+    _last_dim_is(op, tc, "Gate", x.shape[-1] if x.shape is not None
+                 else None, "gate features")
+
+
 @rule("ssm_scan", "ssm_update")
 def _r_ssm(op, tc):
     h, p, g, n = _ssm_widths(op)
@@ -1423,4 +1493,4 @@ rule("split_grad", "relu2_grad", "rms_norm_grad",
      "moe_route_grad", "moe_experts_grad", "moe_experts_gated_grad",
      "gqa_attention_grad", "rope_grad", "swiglu_grad", "pad_grad",
      "mla_attention_grad", "rope_partial_grad", "window_attention_grad",
-     "gqa_flash_attention_grad")(_r_grad_mirror)
+     "gqa_flash_attention_grad", "attention_out_gate_grad")(_r_grad_mirror)

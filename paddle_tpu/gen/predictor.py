@@ -309,6 +309,13 @@ class GenPredictor:
             int(block.var(n).shape[-1])
             * jnp.dtype(str(block.var(n).dtype)).itemsize
             for n in self.cache_vars)
+        # bytes ONE slot's rows of the per-slot state arrays take (a
+        # mixer's recurrent state and conv window, a window layer's ring):
+        # what an admission overwrites and an eviction gives up
+        self.state_bytes_per_slot = sum(
+            int(np.prod(block.var(n).shape[1:]))
+            * jnp.dtype(str(block.var(n).dtype)).itemsize
+            for n in self.state_vars)
         # host-side page allocator state (all mutated under _lock); the
         # table is the host's mirror of the device's, and ``_stale_rows``
         # the slots whose row the next turn's patch has to carry there

@@ -765,9 +765,21 @@ class GenScheduler:
                 raise
             seed.set(compiled_calls=1, eager_ops=written,
                      state_arrays=len(self.predictor.state_vars))
+        self._count_state("seeded")
         with self._cv:
             self._slots[slot_idx] = _Slot(stream, prompt_len, first)
         return True
+
+    def _count_state(self, what):
+        """A slot's rows of the per-slot state arrays were taken for a
+        stream (``seeded``: written by the seed, or by the admission's
+        first chunk from zeros) or given up (``freed``): always-on
+        ``gen.state.bytes_seeded`` / ``gen.state.bytes_freed``; nothing
+        for a bundle without ``state_vars``."""
+        from paddle_tpu import profiler as _profiler
+        held = getattr(self.predictor, "state_bytes_per_slot", 0)
+        if held:
+            _profiler.runtime_metrics.inc(f"gen.state.bytes_{what}", held)
 
     # -- an admission as a run of chunks (``predictor.prefill_chunks``) ----
     def _begin_admission(self, slot_idx, stream):
@@ -791,11 +803,13 @@ class GenScheduler:
             # nothing is seeded: the chunks write pages and state
             seed.set(pages=len(pages), row_bytes=p.cache_row_bytes,
                      compiled_calls=0, eager_ops=0,
-                     state_arrays=len(p.state_vars))
+                     state_arrays=len(p.state_vars),
+                     state_bytes=getattr(p, "state_bytes_per_slot", 0))
             win = getattr(p, "window_attention", None)
             if win:
                 seed.set(ring_rows=len(win["layers"])
                          * min(prompt_len, int(win["ring"])))
+        self._count_state("seeded")
         return _Admission(stream, slot_idx, p.chunk_spans(prompt_len))
 
     def _end_admission(self, adm, seated=False):
@@ -803,6 +817,7 @@ class GenScheduler:
         with its pages."""
         if not seated:
             self.predictor.free_slot_pages(adm.slot_idx)
+            self._count_state("freed")
         if self._admission is adm:
             self._admission = None
         elif adm in self._awaiting:
@@ -966,6 +981,7 @@ class GenScheduler:
         # clear_slot, which addresses pages through the still-live
         # allocation
         self.predictor.free_slot_pages(slot_idx)
+        self._count_state("freed")
         with self._cv:
             self._slots.pop(slot_idx, None)
             self._free.append(slot_idx)

@@ -110,20 +110,21 @@ def default_page_buckets(pages_per_slot):
     return sorted(set(edges))
 
 
-def chunk_rows(page_len, prompt_buckets, max_len):
+def chunk_rows(page_len, prompt_buckets, max_len, top=None):
     """The chunk rungs of a bundle whose prefill is a chunk program,
     ascending, from its shapes (``prompt_buckets`` bound and describe
-    its prompts): the larger is ``CHUNK_ROWS`` (no more than the longest
-    prompt takes, whole pages); the smaller, half of it where that is
-    whole pages too, is for a prompt's LAST chunk, which takes it where
-    it fits, so that a prompt runs no more than half the larger rung in
-    pad rows.  Every rung is an executable a page bucket to compile and
+    its prompts): the larger is ``top`` (None: ``CHUNK_ROWS``; no more
+    than the longest prompt takes, whole pages); the smaller, half of it
+    where that is whole pages too, is for a prompt's LAST chunk, which
+    takes it where it fits, so that a prompt runs no more than half the
+    larger rung in pad rows.  Every rung is an executable a page bucket to compile and
     to warm, so the half rung is left out where it buys little: in a
     bundle whose SHORTEST prompt bucket already spans two of the larger
     rung, where the pad rows it saves are a few hundredths of a
     prompt's."""
     page_len = int(page_len)
-    top = min(int(CHUNK_ROWS), min(max(prompt_buckets), int(max_len)))
+    top = min(int(top or CHUNK_ROWS), min(max(prompt_buckets),
+                                          int(max_len)))
     top = max(-(-top // page_len) * page_len, page_len)
     half = top // 2
     if half % page_len or not half or min(prompt_buckets) >= 2 * top:

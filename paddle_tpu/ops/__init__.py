@@ -30,6 +30,7 @@ from paddle_tpu.ops import (  # noqa: F401
     beam_search_ops,
     fused_ops,
     ssm_ops,
+    kda_ops,
     moe_ops,
     mla_ops,
     dsa_ops,

@@ -1826,3 +1826,14 @@ def gqa_attention_lower(ctx: LowerContext):
                         float(ctx.attr("scale", 1.0)),
                         int(ctx.attr("block", 1)))
     ctx.set_output("Out", out[None])
+
+
+@register_op("attention_out_gate", infer_shape=infer_shape_unary())
+def attention_out_gate_lower(ctx: LowerContext):
+    """The element-wise output gate of an attention sublayer: X (the
+    context, beside the paged kernel or the prefill's) and Gate [..., H *
+    D] (a projection of the sublayer's input).  Out = X * sigmoid(Gate),
+    the sigmoid in float32."""
+    x = ctx.input("X")
+    gate = jax.nn.sigmoid(ctx.input("Gate").astype(jnp.float32))
+    ctx.set_output("Out", (x.astype(jnp.float32) * gate).astype(x.dtype))

@@ -93,15 +93,19 @@ def relu2_lower(ctx):
 # causal depthwise conv with a carried window
 # ---------------------------------------------------------------------------
 
-def conv_scan(x, w, b, n_real):
+def conv_scan(x, w, b, n_real, before=None):
     """``x`` [T, C]; ``w`` [K, C] (tap K-1 multiplies the current row);
-    ``b`` [C].  Returns ``silu(conv(x) + b)`` [T, C] in ``x``'s type and
-    the window after ``n_real`` rows: rows ``n_real-K+1 .. n_real-1`` of
-    ``x`` (zeros before the sequence's start), float32 [K-1, C]."""
+    ``b`` [C] or None.  Returns ``silu(conv(x) + b)`` [T, C] in ``x``'s
+    type and the window after ``n_real`` rows: rows ``n_real-K+1 ..
+    n_real-1`` of ``x``, float32 [K-1, C].  ``before`` [K-1, C]: the rows
+    that precede ``x`` (a chunk that continues a sequence); None: zeros,
+    the sequence's start."""
     K = w.shape[0]
-    xf = jnp.pad(x.astype(jnp.float32), ((K - 1, 0), (0, 0)))
+    xf = x.astype(jnp.float32)
+    xf = jnp.pad(xf, ((K - 1, 0), (0, 0))) if before is None else \
+        jnp.concatenate([before.astype(jnp.float32), xf], axis=0)
     T = x.shape[0]
-    acc = b.astype(jnp.float32)[None, :]
+    acc = 0.0 if b is None else b.astype(jnp.float32)[None, :]
     for k in range(K):
         acc = acc + xf[k:k + T] * w[k].astype(jnp.float32)[None, :]
     window = jax.lax.dynamic_slice_in_dim(xf, n_real, K - 1, axis=0)
@@ -109,13 +113,15 @@ def conv_scan(x, w, b, n_real):
 
 
 def conv_update(x, window, w, b, live):
-    """One row per slot: ``x`` [S, C], ``window`` [S, K-1, C] float32.
+    """One row per slot: ``x`` [S, C], ``window`` [S, K-1, C] float32,
+    ``b`` [C] or None.
     Returns ``silu(conv + b)`` [S, C] and the shifted window; slots
     where ``live`` [S] is false keep their window."""
     xf = x.astype(jnp.float32)
     full = jnp.concatenate([window, xf[:, None, :]], axis=1)   # [S, K, C]
-    acc = jnp.sum(full * w.astype(jnp.float32)[None], axis=1) \
-        + b.astype(jnp.float32)[None, :]
+    acc = jnp.sum(full * w.astype(jnp.float32)[None], axis=1)
+    if b is not None:
+        acc = acc + b.astype(jnp.float32)[None, :]
     new = jnp.where(live[:, None, None], full[:, 1:], window)
     return jax.nn.silu(acc).astype(x.dtype), new
 
@@ -132,29 +138,67 @@ def _infer_scan_conv(op, block):
     win.dtype = "float32"
 
 
+def _bias(ctx):
+    return ctx.input("Bias") if ctx.has_input("Bias") else None
+
+
+def chunk_slot_state(ctx, array):
+    """What ONE CHUNK of a prompt starts from, of a per-slot state
+    ``array`` [num_slots, ...]: ``(slot, the slot's row)``, the row zeros
+    where the chunk is the prompt's first (Pos starts at 0 and the chunk
+    has a real row: a warm-up's chunk of pad rows leaves the slot
+    alone).  Reads the op's Slot [1, 1], Pos [1, C] and Mask [1, C]."""
+    slot = ctx.input("Slot").reshape(-1)[0].astype(jnp.int32)
+    first = (ctx.input("Pos").reshape(-1)[0] == 0) \
+        & (ctx.input("Mask").reshape(-1)[0] > 0)
+    held = jax.lax.dynamic_index_in_dim(array, slot, 0, keepdims=False)
+    return slot, jnp.where(first, 0.0, held)
+
+
 @register_op("ssm_scan_conv", infer_shape=_infer_scan_conv,
              no_grad_inputs=("Mask",), stop_gradient_outputs=("Window",))
 def ssm_scan_conv_lower(ctx):
-    """X [1, T, C]; W [K, C]; Bias [C]; Mask [1, T] (1 = real row, real
-    rows first).  Out [1, T, C]; Window [1, K-1, C] float32."""
+    """X [1, T, C]; W [K, C]; Bias [C] (optional); Mask [1, T] (1 = real
+    row, real rows first).  Out [1, T, C]; Window [1, K-1, C] float32."""
     x, mask = ctx.input("X"), ctx.input("Mask")
     n_real = jnp.sum(mask[0] > 0).astype(jnp.int32)
-    out, window = conv_scan(x[0], ctx.input("W"), ctx.input("Bias"), n_real)
+    out, window = conv_scan(x[0], ctx.input("W"), _bias(ctx), n_real)
     ctx.set_output("Out", out[None])
     ctx.set_output("Window", window[None])
+
+
+@register_op("ssm_chunk_conv", infer_shape=infer_shape_unary(),
+             no_gradient=True, stateful_outputs=("WindowOut",))
+def ssm_chunk_conv_lower(ctx):
+    """The conv over ONE CHUNK of a prompt, continuing the slot's window.
+    X [1, C, ch]; W [K, ch]; Bias [ch] (optional); Window [num_slots,
+    K-1, ch] persistable float32; Slot [1, 1] int32; Pos [1, C] int32
+    the rows' positions ``start ..``; Mask [1, C] (1 = a real row, real
+    rows first).  The slot's window leads the chunk's rows in (zeros
+    where the chunk is the prompt's first: position 0 and a real row) and
+    the window after the chunk's last real row is left there.  Out [1, C,
+    ch]; WindowOut names the window array itself."""
+    x, windows = ctx.input("X"), ctx.input("Window")
+    slot, held = chunk_slot_state(ctx, windows)
+    out, window = conv_scan(
+        x[0], ctx.input("W"), _bias(ctx),
+        jnp.sum(ctx.input("Mask") > 0).astype(jnp.int32), before=held)
+    ctx.set_output("Out", out[None])
+    ctx.set_output("WindowOut", jax.lax.dynamic_update_index_in_dim(
+        windows, window.astype(windows.dtype), slot, 0))
 
 
 @register_op("ssm_update_conv", infer_shape=infer_shape_unary(),
              no_gradient=True, stateful_outputs=("WindowOut",))
 def ssm_update_conv_lower(ctx):
-    """X [S, 1, C]; Window [S, K-1, C] persistable float32; W, Bias;
-    Lens [S, 1] int32 (0 = free slot).  Out [S, 1, C]; WindowOut names
-    the window var itself (in-place update)."""
+    """X [S, 1, C]; Window [S, K-1, C] persistable float32; W; Bias
+    (optional); Lens [S, 1] int32 (0 = free slot).  Out [S, 1, C];
+    WindowOut names the window var itself (in-place update)."""
     x = ctx.input("X")
     live = ctx.input("Lens")[:, 0] > 0
     out, new = conv_update(x.reshape(x.shape[0], x.shape[-1]),
                            ctx.input("Window"), ctx.input("W"),
-                           ctx.input("Bias"), live)
+                           _bias(ctx), live)
     ctx.set_output("Out", out.reshape(x.shape))
     ctx.set_output("WindowOut", new)
 

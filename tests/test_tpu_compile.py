@@ -952,3 +952,61 @@ def test_paged_decode_of_a_committed_row_and_a_draft_compiles_for_v5e(
                    ((S, pages), jnp.int32), ((S, 1), jnp.int32),
                    ((S, 2), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+def test_kda_update_and_chunk_scan_compile_for_v5e_in_place(one_chip):
+    """The KDA mixer's two forms at the linear-attention cell's widths (64
+    slots, 64 heads of 128: a matrix state [64, 64, 128, 128] float32,
+    268 MB a layer): the one-token update, as the Pallas kernel (Mosaic
+    takes its lane slices and broadcasts) and as plain XLA, over the
+    state aliased in place with no state-sized temporary; a 512-row chunk
+    of the chunk-wise scan writes the slot's state in place and keeps its
+    pair-by-pair decays far under two state arrays."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import kda_ops
+    S, H, D = 64, 64, 128
+    bf, f32 = jnp.bfloat16, jnp.float32
+    state_bytes = S * H * D * D * 4
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def prepared(qkv, f, b, a_log, dt_bias):
+        return kda_ops.prepare(qkv, f, b, a_log, dt_bias, H, 2.0)
+
+    def update(qkv, f, b, a_log, dt_bias, state, lens):
+        o, new = kda_ops.kda_step(state, *prepared(qkv, f, b, a_log,
+                                                   dt_bias))
+        return o.astype(bf), jnp.where((lens > 0)[:, None, None, None],
+                                       new, state)
+
+    def kernel(qkv, f, b, a_log, dt_bias, state, lens):
+        assert kda_ops.update_kernel_ok(state, False)
+        o, new = kda_ops.kda_update_kernel(
+            state, *prepared(qkv, f, b, a_log, dt_bias), lens,
+            interpret=False)
+        return o.astype(bf), new
+
+    for fn in (kernel, update):
+        compiled = jax.jit(fn, donate_argnums=5).lower(
+            sds((S, 3 * H * D), bf), sds((S, H * D), f32), sds((S, H), f32),
+            sds((H,), f32), sds((H * D,), f32), sds((S, H, D, D), f32),
+            sds((S,), jnp.int32)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 16 << 20, mem
+        assert mem.alias_size_in_bytes >= state_bytes, mem
+        assert ("tpu_custom_call" in compiled.as_text()) == (fn is kernel)
+
+    def chunk(qkv, f, b, a_log, dt_bias, state, mask):
+        o, last = kda_ops.kda_scan(*prepared(qkv, f, b, a_log, dt_bias),
+                                   state[3], mask)
+        return o.astype(bf), state.at[3].set(last)
+
+    T = 512
+    mem = jax.jit(chunk, donate_argnums=5).lower(
+        sds((T, 3 * H * D), bf), sds((T, H * D), f32), sds((T, H), f32),
+        sds((H,), f32), sds((H * D,), f32), sds((S, H, D, D), f32),
+        sds((T,), f32)).compile().memory_analysis()
+    assert mem.temp_size_in_bytes < state_bytes, mem
+    assert mem.alias_size_in_bytes >= state_bytes, mem
