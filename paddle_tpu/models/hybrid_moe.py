@@ -19,7 +19,12 @@ named by a pattern string:
 * ``K``  KDA mixer: gated delta-rule linear attention with a decay a key
   channel (``ops/kda_ops.py``).  Its cache is a per-slot MATRIX state a
   head ``[slots, heads, head_dim, head_dim]`` and the window of the conv
-  over q | k | v, both float32 ``state_vars``.
+  over q | k | v, both float32 ``state_vars``.  Both of its ops over
+  that state are Pallas kernels where the shapes allow (heads of 128,
+  rungs of whole 64-row blocks: the published widths), updating the
+  state in place: ``kda_update`` every slot's a decode step, ``kda_scan``
+  ONE slot's a prompt chunk, with the head's state in VMEM for the whole
+  chunk; at toy widths both lower as plain XLA (``ops/kda_ops.py``).
 * ``G``  ``*`` with an element-wise sigmoid output gate, a projection of
   the sublayer's input.
 * ``S``  the same router over gated SwiGLU experts on the hidden state

@@ -20,11 +20,10 @@ Two forms of the same recurrence
   and ``B_ij`` the same with ``q_i`` for ``j <= i``, the block's
   pseudo-values ``W`` solve ``(I + Diag(beta) A) W = Diag(beta) (V - (K
   e^G) S_0)``, and ``O = (Q e^G) S_0 + B W``, ``S_end = Diag(e^{G_end})
-  S_0 + (K e^{G_end - G})^T W``.  Everything but ``S_0`` is computed for
-  all blocks at once; a scan over the blocks carries the state.  ``e^{-G}``
+  S_0 + (K e^{G_end - G})^T W``.  ``e^{-G}``
   alone overflows under a strong decay, so a pair's decay is always
   formed as ONE exponential of a non-positive number: inside a sub-block
-  of ``SUB`` rows pair by pair, across sub-blocks against the running
+  pair by pair, across sub-blocks against the running
   sum at the later sub-block's start.  The unit triangular system is
   solved by substitution (rows inside a sub-block, then sub-blocks), not
   by a series in powers of ``A``, whose terms outgrow float32 when keys
@@ -32,7 +31,31 @@ Two forms of the same recurrence
   = 0 and ``beta`` = 0, which is the identity on the state.  The chunk
   takes the slot's state from the persistable array and leaves its own
   there; the prompt's FIRST chunk (position 0) starts from zeros whatever
-  the slot held.
+  the slot held.  ONE algorithm, two lowerings, chosen by the chunk's
+  shapes alone (:func:`scan_kernel_ok`; ``gen.kda.scan_lowerings.kernel``
+  / ``.xla`` count which, once a compiled signature):
+
+  - a Pallas kernel (:func:`kda_scan_kernel`) where a head is a multiple
+    of 128 wide and the rung whole blocks: grid (group of
+    ``_SCAN_HEADS`` heads, one after the other; stretch of rows), a
+    head's q, k, v, decay
+    projection and output read and written as 128-lane COLUMN BLOCKS of
+    the 2-D activations where the conv and the projections left them (no
+    head-major copy on either side), :func:`prepare`'s element-wise work
+    done in the kernel on the block it has just read (outside it, q, k,
+    v and g would be written and read back as float32: 134 MB a layer
+    and chunk, twice what the kernel moves), the pair decays, both pair
+    matrices and the substitution formed in VMEM, the head's [K, V]
+    state carried in scratch from block to block and written ONCE, into
+    the slot's row of the persistable array in place.  One layer's
+    512-row chunk at the linear-attention cell's widths: 0.71 ms where
+    the XLA form takes 2.13 (PERF.md section 6, PR 50), bounded by a
+    block's chain of dependent matrix products, six passes each
+    ("highest"), and substitution steps.
+  - plain XLA (:func:`kda_scan`) everywhere else (the toy widths of the
+    CPU tests, a chunk under one block), and the oracle the kernel is
+    tested against: everything but ``S_0`` for all blocks at once,
+    sub-blocks of ``SUB`` rows, a scan over the blocks carrying the state.
 * ``kda_update`` (decode, one token for every slot): the recurrence
   itself on the persistable state, in place; a slot with ``lens`` 0 keeps
   its state.  A Pallas kernel where the state's shape allows
@@ -45,15 +68,16 @@ Two forms of the same recurrence
 
 The state, the decay and every sum of the recurrence are float32
 whatever the activations' type; the chunk form's products run at
-"highest" precision (a few percent of a chunk's FLOPs).  The scan is a
-plain XLA lowering; the executor's op scope names both
-``ptop_kda_scan*`` / ``ptop_kda_update*`` on the device trace.  Neither
+"highest" precision in both lowerings (a few percent of a chunk's
+FLOPs).  The executor's op scope names both ops ``ptop_kda_scan*`` /
+``ptop_kda_update*`` on the device trace, kernels included.  Neither
 has a gradient (training through the scan is not written).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -297,6 +321,234 @@ def kda_scan(q, k, v, g, beta, S0, mask):
     return o, S_end
 
 
+# ---------------------------------------------------------------------------
+# the chunk-wise form as a kernel
+# ---------------------------------------------------------------------------
+
+#: rows of a chunk a grid step of the scan kernel holds in VMEM (both
+#: rungs of the linear-attention cell in one), the kernel's sub-block
+#: (blocks of ``BLOCK`` rows as the XLA form's; a larger sub-block is
+#: fewer, larger matrix products a row: one layer, 512 rows, four heads
+#: unrolled, 0.64 / 0.60 / 0.79 ms at 16 / 32 / 64, and at 32 the closest
+#: of the three to the recurrence where keys repeat; blocks of 128 rows:
+#: 0.60 / 0.56 / 0.75 but twice as far from it there; my chip runs, PR
+#: 50), and the heads a grid step takes, one after the other in a loop:
+#: unrolled, their chains of dependent products interleave (0.60 ms
+#: against the loop's 0.71, two and two 0.65) but every chunk executable
+#: then lowers four copies of the block's ~2,500 operations, 7 s each on
+#: the chip's host, and a warm start of the cell read 22 s over the
+#: parent's
+_SCAN_ROWS, _SCAN_SUB, _SCAN_HEADS = 512, 32, 4
+
+
+def scan_kernel_ok(x, state):
+    """The scan kernel reads a head's q, k, v, decay projection and
+    output as 128-lane column blocks of the 2-D activations and a head's
+    [K, V] state as whole tiles, a chunk as whole blocks of
+    ``BLOCK`` rows: ``x`` [C, 3 * H * D], ``state`` [slots, H, D,
+    D]."""
+    C, (_, H, K, V) = x.shape[0], state.shape
+    return (K == V and not K % 128 and x.shape[1] == 3 * H * K
+            and C >= BLOCK and not C % BLOCK)
+
+
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+    return jax.lax.dot_general(a, b, dims, precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _iota(shape, axis):
+    return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+
+def _running_sum(g):
+    """The running sum down the rows of ``g`` [Q, K] on the vector unit,
+    plain float32: inside a tile of 8 rows by three shifted adds, then
+    tile after tile on the row before it."""
+    row, out = _iota((8, 1), 0), []
+    for t in range(0, g.shape[0], 8):
+        a = g[t:t + 8]
+        for s in (1, 2, 4):
+            a = a + jnp.where(row >= s, pltpu.roll(a, s, 0), 0.0)
+        out.append(a + out[-1][7:8] if out else a)
+    return jnp.concatenate(out, axis=0)
+
+
+@jax.jit
+def _scan_block(q, k, v, G, beta, S0):
+    """One head, one block of ``BLOCK`` rows, on values in VMEM (jitted
+    so that its ~2,500 operations are traced ONCE a process, whatever
+    the rung and the executable; PERF.md section 6, PR 50, has what
+    tracing and lowering them over and over cost a warm start):
+    ``q`` (unit, scaled), ``k`` (unit), ``G`` (the running sum of the
+    log-decay, <= 0) [Q, K], ``v`` [Q, V], ``beta`` [Q, 1], ``S0`` [K,
+    V].  Returns ``(o [Q, V], the state after the block)``: the pair
+    matrices column by column inside a sub-block (ONE exponential of a
+    number <= 0 a pair; 8-row tiles, so a column costs only the tiles at
+    or below its row) and against the later sub-block's start across
+    them, the substitution, the products with the carried state."""
+    f32 = jnp.float32
+    Q, c, D = BLOCK, _SCAN_SUB, k.shape[1]
+    tiles = lambda a: [a[t:t + 8] for t in range(0, a.shape[0], 8)]
+    row_t, col_t = _iota((8, 1), 0), _iota((8, Q), 1)
+    e = jnp.exp(G)
+    both = _dot(jnp.concatenate([k * e, q * e], axis=0), S0)
+    R = beta * (v - both[:Q])
+    W, B = [], []
+    for n in range(Q // c):
+        sub = slice(n * c, (n + 1) * c)
+        Gs, ks, qs, bs = G[sub], k[sub], q[sub], beta[sub]
+        Rn, Bn = R[sub], jnp.zeros((c, Q), f32)
+        if n:
+            # against the running sum where THIS sub-block starts
+            ref = G[n * c - 1:n * c]
+            late = jnp.exp(Gs - ref)
+            early = jnp.concatenate(
+                [k[:n * c] * jnp.exp(ref - G[:n * c]),
+                 jnp.zeros((Q - n * c, D), f32)], axis=0)
+            cross = _dot(jnp.concatenate([ks * late, qs * late], axis=0),
+                         early, (((1,), (1,)), ((), ())))       # [2c, Q]
+            solved = jnp.concatenate(
+                W + [jnp.zeros((Q - n * c, v.shape[1]), f32)], axis=0)
+            Rn = Rn - _dot(bs * cross[:c], solved)
+            Bn = cross[c:]
+        # inside the sub-block, a column (one earlier row) at a time
+        Gs, ks, qs, bs, Rn, Bn = (tiles(a) for a in (Gs, ks, qs, bs, Rn, Bn))
+        cols = []
+        for j in range(c):
+            at, i = divmod(j, 8)
+            col = []
+            for t in range(at, c // 8):
+                pair = jnp.exp(jnp.minimum(Gs[t] - Gs[at][i:i + 1], 0.0)) \
+                    * ks[at][i:i + 1]
+                a = bs[t] * jnp.sum(ks[t] * pair, axis=1, keepdims=True)
+                b = jnp.sum(qs[t] * pair, axis=1, keepdims=True)
+                if t == at:
+                    a = jnp.where(row_t > i, a, 0.0)
+                    b = jnp.where(row_t >= i, b, 0.0)
+                col.append(a)
+                Bn[t] = jnp.where(col_t == n * c + j, b, Bn[t])
+            cols.append(col)
+        # the substitution: row j is final once the columns before it
+        # have been taken off
+        for j in range(c - 1):
+            at, i = divmod(j, 8)
+            for t in range(at, c // 8):
+                Rn[t] = Rn[t] - cols[j][t - at] * Rn[at][i:i + 1]
+        W += Rn
+        B += Bn
+    W = jnp.concatenate(W, axis=0)
+    o = both[Q:] + _dot(jnp.concatenate(B, axis=0), W)
+    end = G[Q - 1:Q]
+    through = jnp.sum(jnp.where(_iota((D, D), 0) == _iota((D, D), 1),
+                                jnp.exp(end), 0.0),
+                      axis=1, keepdims=True)                    # [K, 1]
+    return o, through * S0 + _dot(k * jnp.exp(end - G), W,
+                                  (((0,), (0,)), ((), ())))
+
+
+def _scan_kernel(where_ref, q_ref, k_ref, v_ref, f_ref, rate_ref, bias_ref,
+                 beta_ref, real_ref, s_ref, o_ref, so_ref, S, G, *, heads):
+    """``heads`` heads, one after the other, ``rows`` rows of the chunk
+    (grid: head group, stretch of rows; the heads' states ride in ``S``
+    from stretch to stretch).  ``q_ref``, ``k_ref``, ``v_ref`` [rows,
+    heads * D] are the heads' column blocks of the conv output,
+    ``f_ref`` of the decay's
+    projection, ``rate_ref`` / ``bias_ref`` [1, heads * D] of
+    ``exp(A_log)`` a channel and ``dt_bias``; ``beta_ref`` [rows, H]
+    (every head's, 0 on pad rows), ``real_ref`` [rows, 1]; ``s_ref`` /
+    ``so_ref`` [1, heads, K, V] the slot's state; ``where_ref`` (slot,
+    the prompt's first chunk).  A block of ``BLOCK`` rows at a
+    time: :func:`prepare`'s element-wise work and the running sum of the
+    log-decay, then :func:`_scan_block` a head.  The running sum goes
+    through the scratch ``G``: every use has to see ONE rounding of it
+    (a difference of two roundings of a sum in the thousands is a decay
+    wrong in the fourth digit, and a compiler that recomputes a value
+    where it is used, as XLA's does in interpret mode, makes two).
+    Nothing but ``o`` and the final state leaves VMEM."""
+    first, r = pl.program_id(0) * heads, pl.program_id(1)
+    Q, D = BLOCK, s_ref.shape[2]
+
+    @pl.when(r == 0)
+    def _():
+        S[...] = jnp.where(where_ref[1] > 0, 0.0, s_ref[0])
+
+    def head(rows, real, betas, j):
+        lanes = pl.ds(pl.multiple_of(j * D, D), D)
+        g = -rate_ref[:, lanes] * real * jax.nn.softplus(
+            f_ref[rows, lanes] + bias_ref[:, lanes])
+        beta = jnp.sum(jnp.where(_iota(betas.shape, 1) == first + j,
+                                 betas, 0.0), axis=1, keepdims=True)
+        G[j] = _running_sum(g)                                  # <= 0
+        o, S[j] = _scan_block(
+            l2norm(q_ref[rows, lanes]) * D ** -0.5,
+            l2norm(k_ref[rows, lanes]),
+            v_ref[rows, lanes].astype(jnp.float32), G[j], beta, S[j])
+        o_ref[rows, lanes] = o.astype(o_ref.dtype)
+
+    def block(i, carry):
+        rows = pl.ds(pl.multiple_of(i * Q, Q), Q)
+        real, betas = real_ref[rows, :], beta_ref[rows, :]
+
+        def one(j, carry):
+            head(rows, real, betas, j)
+            return carry
+
+        return jax.lax.fori_loop(0, heads, one, carry)
+
+    jax.lax.fori_loop(0, q_ref.shape[0] // Q, block, 0)
+
+    @pl.when(r == pl.num_programs(1) - 1)
+    def _():
+        so_ref[0] = S[...]
+
+
+@functools.partial(jax.jit, inline=True,
+                   static_argnames=("beta_scale", "interpret"))
+def kda_scan_kernel(x, f, b, a_log, dt_bias, state, slot, first, mask, *,
+                    beta_scale, interpret=False):
+    """:func:`prepare` and :func:`kda_scan` over one chunk as ONE kernel
+    on the activations where they lie: ``x`` [C, 3 * H * D] (q | k | v),
+    ``f`` [C, H * D], ``b`` [C, H], ``a_log`` [H], ``dt_bias`` [H * D],
+    ``state`` [slots, H, D, D] float32 (aliased to the new state: only
+    ``slot``'s row is written), ``first`` (the chunk starts from zeros),
+    ``mask`` [C].  Returns ``(o [C, H * D] as x, new state)``."""
+    C, (_, H, D, _) = x.shape[0], state.shape
+    # a wider head, fewer of them a grid step: the same bytes in VMEM
+    rows = math.gcd(C, _SCAN_ROWS)
+    hb = math.gcd(H, max(1, _SCAN_HEADS * 128 // D))
+    real = (mask.astype(jnp.float32) > 0).astype(jnp.float32)[:, None]
+    beta = beta_scale * jax.nn.sigmoid(b.astype(jnp.float32)) * real
+    rate = jnp.repeat(jnp.exp(a_log.astype(jnp.float32)), D)[None]
+    where = jnp.stack([slot, first]).astype(jnp.int32)
+    head = lambda base: pl.BlockSpec(
+        (rows, hb * D), lambda h, r, w: (r, base // hb + h))
+    param = pl.BlockSpec((1, hb * D), lambda h, r, w: (0, h))
+    held = pl.BlockSpec((1, hb, D, D), lambda h, r, w: (w[0], h, 0, 0))
+    o, new = pl.pallas_call(
+        functools.partial(_scan_kernel, heads=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(H // hb, C // rows),
+            in_specs=[head(0), head(H), head(2 * H), head(0), param, param,
+                      pl.BlockSpec((rows, H), lambda h, r, w: (r, 0)),
+                      pl.BlockSpec((rows, 1), lambda h, r, w: (r, 0)),
+                      held],
+            out_specs=[head(0), held],
+            scratch_shapes=[pltpu.VMEM((hb, D, D), jnp.float32),
+                            pltpu.VMEM((hb, BLOCK, D), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((C, H * D), x.dtype),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # (where, x, x, x, f, rate, dt_bias, beta, real, state): the
+        # slot's state is updated in place
+        input_output_aliases={9: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret, name="kda_scan",
+    )(where, x, x, x, f.astype(jnp.float32), rate,
+      dt_bias.astype(jnp.float32)[None], beta, real, state)
+    return o, new
+
+
 def gated_head_rms_norm(o, gate, scale, n_head, eps):
     """``RMSNorm_head(o) * scale * sigmoid(gate)``: the last axis is
     normalised a head at a time (``scale`` [head width], shared by the
@@ -340,8 +592,25 @@ def kda_scan_lower(ctx):
     prompt's first: position 0 and a real row) and leaves the state after
     its last real row there.  Out [1, C, H * D]; StateOut names the state
     array itself."""
-    from paddle_tpu.ops.ssm_ops import chunk_slot_state
+    from paddle_tpu.ops.attention_ops import _use_interpret
+    from paddle_tpu.ops.ssm_ops import chunk_slot, chunk_slot_state
+    from paddle_tpu.profiler import runtime_metrics
     x, state = ctx.input("X"), ctx.input("State")
+    kernel = scan_kernel_ok(x[0], state)
+    # which form this lowering took (fires at trace time, once per
+    # compiled signature, as ``gen.moe.*_lowerings`` do)
+    runtime_metrics.inc("gen.kda.scan_lowerings.kernel" if kernel
+                        else "gen.kda.scan_lowerings.xla")
+    if kernel:
+        slot, first = chunk_slot(ctx)
+        o, new = kda_scan_kernel(
+            x[0], ctx.input("F")[0], ctx.input("B")[0], ctx.input("ALog"),
+            ctx.input("DtBias"), state, slot, first, ctx.input("Mask")[0],
+            beta_scale=float(ctx.attr("beta_scale", 1.0)),
+            interpret=_use_interpret())
+        ctx.set_output("Out", o[None])
+        ctx.set_output("StateOut", new)
+        return
     q, k, v, g, beta = _prepared(ctx, x[0], ctx.input("F")[0],
                                  ctx.input("B")[0])
     slot, held = chunk_slot_state(ctx, state)

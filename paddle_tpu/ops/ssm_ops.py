@@ -142,15 +142,22 @@ def _bias(ctx):
     return ctx.input("Bias") if ctx.has_input("Bias") else None
 
 
-def chunk_slot_state(ctx, array):
-    """What ONE CHUNK of a prompt starts from, of a per-slot state
-    ``array`` [num_slots, ...]: ``(slot, the slot's row)``, the row zeros
-    where the chunk is the prompt's first (Pos starts at 0 and the chunk
-    has a real row: a warm-up's chunk of pad rows leaves the slot
+def chunk_slot(ctx):
+    """Where ONE CHUNK of a prompt keeps its per-slot state: ``(slot,
+    whether the chunk is the prompt's first)`` (Pos starts at 0 and the
+    chunk has a real row: a warm-up's chunk of pad rows leaves the slot
     alone).  Reads the op's Slot [1, 1], Pos [1, C] and Mask [1, C]."""
     slot = ctx.input("Slot").reshape(-1)[0].astype(jnp.int32)
     first = (ctx.input("Pos").reshape(-1)[0] == 0) \
         & (ctx.input("Mask").reshape(-1)[0] > 0)
+    return slot, first
+
+
+def chunk_slot_state(ctx, array):
+    """What ONE CHUNK of a prompt starts from, of a per-slot state
+    ``array`` [num_slots, ...]: ``(slot, the slot's row)``, the row zeros
+    where the chunk is the prompt's first (:func:`chunk_slot`)."""
+    slot, first = chunk_slot(ctx)
     held = jax.lax.dynamic_index_in_dim(array, slot, 0, keepdims=False)
     return slot, jnp.where(first, 0.0, held)
 

@@ -180,6 +180,166 @@ def test_kda_scan_equals_the_recurrence_a_token_at_a_time(T, real, how):
         <= 3e-5 * np.abs(np.asarray(state)).max()
 
 
+def _raw_inputs(T, H, D, seed=0, decay=1.0, beta_shift=0.0, same_keys=False):
+    """What ``kda_scan_lower`` is handed, so that ``prepare`` gives what
+    ``_recurrence_inputs`` gives: X [T, 3 * H * D], F [T, H * D], B [T,
+    H], ALog [H], DtBias [H * D] and a three-slot state."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    q, key, v = (jax.random.normal(k[j], (T, H, D)) for j in range(3))
+    if same_keys:
+        key = jnp.broadcast_to(key[:1], key.shape)
+    x = jnp.concatenate([a.reshape(T, -1) for a in (q, key, v)], axis=1)
+    return (x, jax.random.normal(k[3], (T, H * D)),
+            jax.random.normal(k[4], (T, H)) + beta_shift,
+            jnp.full((H,), np.log(decay), jnp.float32),
+            0.1 * jax.random.normal(k[6], (H * D,)),
+            jax.random.normal(k[5], (3, H, D, D)))
+
+
+def _token_at_a_time(raw, H, S0, real):
+    x, f, b, a_log, dt_bias, _ = raw
+    rows = kda_ops.prepare(x, f, b, a_log, dt_bias, H, 2.0)
+    state, want = jax.jit(lambda *rows: jax.lax.scan(
+        lambda S, row: kda_ops.kda_step(S, *row)[::-1], S0, rows))(
+        *(a[:real] for a in rows))
+    return np.asarray(want).reshape(real, -1), np.asarray(state)
+
+
+def _kernel_is_the_recurrence(T, real, how, H=2, first=0):
+    raw = _raw_inputs(T, H, 128, **how)
+    x, state = raw[0], raw[-1]
+    assert kda_ops.scan_kernel_ok(x, state)
+    mask = (jnp.arange(T) < real).astype(jnp.float32)
+    o, new = kda_ops.kda_scan_kernel(
+        *raw, jnp.int32(1), jnp.int32(first), mask, beta_scale=2.0,
+        interpret=True)
+    assert bool(jnp.isfinite(o).all())
+    want, last = _token_at_a_time(raw, H, state[1] * (1 - first), real)
+    assert np.abs(np.asarray(o[:real]) - want).max() \
+        <= 3e-5 * np.abs(want).max()
+    assert np.abs(np.asarray(new[1]) - last).max() \
+        <= 3e-5 * np.abs(last).max()
+    # only the chunk's slot is written
+    assert np.array_equal(new[0], state[0]) \
+        and np.array_equal(new[2], state[2])
+
+
+@pytest.mark.parametrize("T,real,how", [
+    (128, 128, {}), (128, 93, {}), (256, 137, {}), (128, 5, {}),
+    (128, 128, dict(decay=30.0)),                   # decays near 0
+    (256, 256, dict(decay=80.0)),
+    (128, 100, dict(decay=1e-3, beta_shift=6.0)),   # near 1; beta near 2
+    (128, 96, dict(decay=1e-4, beta_shift=6.0, same_keys=True))],
+    ids=["whole_blocks", "ragged", "four_blocks", "short", "decay_near_0",
+         "decay_near_0_long", "decay_near_1_beta_near_2", "repeated_keys"])
+def test_the_scan_kernel_equals_the_recurrence_a_token_at_a_time(T, real,
+                                                                 how):
+    """The Pallas kernel (interpret mode here; compiled for the chip in
+    ``tests/test_tpu_compile.py``) at the widths its gate takes, heads
+    of 128, rungs of whole blocks with pad rows, a non-zero state coming
+    in, on the raw activations: the same eight kinds of input as the XLA
+    form above, at the same 3e-5."""
+    _kernel_is_the_recurrence(T, real, how)
+
+
+def test_the_scan_kernel_carries_the_state_from_stretch_to_stretch(
+        monkeypatch):
+    """More rows than a grid step holds (``_SCAN_ROWS``, 64 here): the
+    state rides in scratch across the grid's second axis, and more head
+    groups than one (of two heads here); a prompt's first chunk starts
+    from zeros whatever the slot held."""
+    monkeypatch.setattr(kda_ops, "_SCAN_ROWS", 64)
+    monkeypatch.setattr(kda_ops, "_SCAN_HEADS", 2)
+    jax.clear_caches()
+    try:
+        _kernel_is_the_recurrence(192, 150, dict(seed=3), H=4)
+        _kernel_is_the_recurrence(192, 192, dict(seed=4), H=4, first=1)
+    finally:
+        jax.clear_caches()
+
+
+def _scan_op(T, H, D, slots=3):
+    """A program of ONE ``kda_scan`` op over feeds and the persistable
+    state ``st``: ``(main, out)``."""
+    from paddle_tpu.models.decoder import data, op, persistable
+    main = fluid.Program()
+    with fluid.program_guard(main, fluid.Program()):
+        state = persistable("st", [slots, H, D, D], "float32")
+        out = op("kda_scan",
+                 {"X": data("x", [1, T, 3 * H * D]),
+                  "F": data("f", [1, T, H * D]), "B": data("b", [1, T, H]),
+                  "ALog": data("a_log", [H]), "DtBias": data("dt", [H * D]),
+                  "State": state, "Slot": data("slot", [1, 1], "int32"),
+                  "Pos": data("pos", [1, T], "int32"),
+                  "Mask": data("mask", [1, T])},
+                 {"Out": "float32", "StateOut": state},
+                 {"n_head": H, "beta_scale": 2.0})["Out"]
+    return main, out
+
+
+def _run_scan_op(T, real, H, D, start):
+    raw = _raw_inputs(T, H, D, seed=5)
+    x, f, b, a_log, dt_bias, state = raw
+    state = np.asarray(state)           # the executor donates the scope's
+    main, out = _scan_op(T, H, D)
+    scope = fluid.Scope()
+    scope.set_var("st", jnp.array(state))
+    exe = fluid.Executor(fluid.CPUPlace())
+    o, = exe.run(main, scope=scope, fetch_list=[out], feed={
+        "x": np.asarray(x)[None], "f": np.asarray(f)[None],
+        "b": np.asarray(b)[None], "a_log": np.asarray(a_log),
+        "dt": np.asarray(dt_bias),
+        "slot": np.asarray([[1]], np.int32),
+        "pos": (start + np.arange(T, dtype=np.int32))[None],
+        "mask": (np.arange(T) < real).astype(np.float32)[None]})
+    want, last = _token_at_a_time(
+        raw, H, jnp.asarray(state[1] * (1.0 if start else 0.0)), real)
+    assert np.abs(np.asarray(o)[0, :real] - want).max() \
+        <= 3e-5 * np.abs(want).max()
+    new = np.asarray(scope.find_var("st"))
+    assert np.abs(new[1] - last).max() <= 3e-5 * np.abs(last).max()
+    assert np.array_equal(new[0], state[0]) \
+        and np.array_equal(new[2], state[2])
+
+
+def _scan_lowerings():
+    return {form: profiler.runtime_metrics.counter(
+        f"gen.kda.scan_lowerings.{form}") for form in ("kernel", "xla")}
+
+
+def test_the_gate_refuses_toy_widths_and_a_chunk_under_one_block():
+    """``scan_kernel_ok`` takes heads of 128 and rungs of whole blocks;
+    where it refuses, the op lowers as the XLA form, says so, and is the
+    recurrence all the same."""
+    ok = lambda T, H, D: kda_ops.scan_kernel_ok(
+        jnp.zeros((T, 3 * H * D)), jnp.zeros((3, H, D, D)))
+    assert ok(64, 4, 128) and ok(512, 64, 128) and ok(256, 3, 256)
+    assert not ok(64, 4, 16)            # the toy widths
+    assert not ok(32, 4, 128)           # a chunk under one block
+    assert not ok(96, 4, 128)           # not whole blocks
+    assert not kda_ops.scan_kernel_ok(jnp.zeros((64, 3 * 4 * 128)),
+                                      jnp.zeros((3, 4, 128, 64)))
+    for T, real, H, D, start in ((64, 50, 4, 16, 0), (32, 32, 2, 128, 7)):
+        before = _scan_lowerings()
+        _run_scan_op(T, real, H, D, start)
+        after = _scan_lowerings()
+        assert after["xla"] == before["xla"] + 1
+        assert after["kernel"] == before["kernel"]
+
+
+def test_the_lowering_counts_which_form_it_took(predictor):
+    """``gen.kda.scan_lowerings.kernel`` / ``.xla``, once a compiled
+    signature: the op at kernel widths (a chunk that continues its slot's
+    state, through the executor, in place) counts the first, the toy
+    bundle's chunk executables the second."""
+    assert _scan_lowerings()["xla"] >= 1        # the fixture's warm-up
+    before = _scan_lowerings()
+    _run_scan_op(128, 100, 2, 128, 64)
+    after = _scan_lowerings()
+    assert after["kernel"] == before["kernel"] + 1
+    assert after["xla"] == before["xla"]
+
+
 def test_kda_update_leaves_a_slot_that_is_not_live_untouched():
     q, k, v, g, beta, _ = _recurrence_inputs(3, H=4)
     state = jax.random.normal(jax.random.PRNGKey(9), (3, 4, 16, 16))

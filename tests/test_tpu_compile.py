@@ -959,9 +959,14 @@ def test_kda_update_and_chunk_scan_compile_for_v5e_in_place(one_chip):
     slots, 64 heads of 128: a matrix state [64, 64, 128, 128] float32,
     268 MB a layer): the one-token update, as the Pallas kernel (Mosaic
     takes its lane slices and broadcasts) and as plain XLA, over the
-    state aliased in place with no state-sized temporary; a 512-row chunk
-    of the chunk-wise scan writes the slot's state in place and keeps its
-    pair-by-pair decays far under two state arrays."""
+    state aliased in place with no state-sized temporary; a chunk of the
+    chunk-wise scan at the cell's two rungs (256 and 512 rows) as the
+    Pallas kernel (Mosaic takes its column blocks of the 2-D activations,
+    the sublane rolls of the running sum, the transposed product of the
+    state's advance and four heads' blocks in VMEM) writes ONE slot's
+    state in place with no temporary at all, and as plain XLA (what the
+    gate's refusals run) keeps its pair-by-pair decays far under two
+    state arrays."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import kda_ops
@@ -1002,6 +1007,22 @@ def test_kda_update_and_chunk_scan_compile_for_v5e_in_place(one_chip):
         o, last = kda_ops.kda_scan(*prepared(qkv, f, b, a_log, dt_bias),
                                    state[3], mask)
         return o.astype(bf), state.at[3].set(last)
+
+    def chunk_kernel(qkv, f, b, a_log, dt_bias, state, slot, first, mask):
+        assert kda_ops.scan_kernel_ok(qkv, state)
+        return kda_ops.kda_scan_kernel(qkv, f, b, a_log, dt_bias, state,
+                                       slot, first, mask, beta_scale=2.0,
+                                       interpret=False)
+
+    for T in (256, 512):
+        compiled = jax.jit(chunk_kernel, donate_argnums=5).lower(
+            sds((T, 3 * H * D), bf), sds((T, H * D), f32), sds((T, H), f32),
+            sds((H,), f32), sds((H * D,), f32), sds((S, H, D, D), f32),
+            sds((), jnp.int32), sds((), jnp.int32), sds((T,), f32)).compile()
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 1 << 20, mem
+        assert mem.alias_size_in_bytes >= state_bytes, mem
+        assert "tpu_custom_call" in compiled.as_text()
 
     T = 512
     mem = jax.jit(chunk, donate_argnums=5).lower(
