@@ -159,7 +159,32 @@ def _step_key(seed):
 #      <checkout>/.jax_cache — the path is part of the cache key, so it is
 #      never a temp dir, a pid or a time.
 # A bare library Executor with none of these set keeps no persistent cache.
+#
+# WHAT an entry is keyed by is decided here too, once, at import: the
+# lowered module WITH its metadata (jax strips it by default).  Every
+# instruction's ``op_name`` holds the scope path device traces are read by
+# (``pt_step/<role>/<name scope...>/ptop_<type>__<output>``, and the
+# predictor's ``gen_turn`` / ``gen_seed``), so two builds that lower the
+# same computation under other scope names must not share an executable:
+# the one loaded would carry the OTHER build's ``op_name``s and the trace
+# would attribute nothing, or the wrong thing.  The option covers every
+# program this process compiles (``Executor.run`` / ``run_steps`` /
+# ``compiled_step``, the predictor's own ``jax.jit``s, ``ParallelExecutor``)
+# and a cache placed from outside alike, and nothing flips it afterwards.
+#
+# The metadata in the key has to be the NAMES alone.  jax also records the
+# Python call stack of every equation in its location, and a function it
+# traces once a process (``jax.random.uniform``'s inner jit, any shared
+# helper) keeps the stack of whoever traced it FIRST: a run that exports a
+# bundle and a warm start of the same tree then lower the same program
+# with different locations, and the warm start would miss (seen on the
+# chip, PR 51: 14 of 54 executables of a warm serving start).  So no frame
+# goes into a location: a key is the computation and its ``op_name``s, the
+# same from any call site, process history and checkout path.
 # ---------------------------------------------------------------------------
+
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+jax.config.update("jax_traceback_in_locations_limit", 0)
 
 _compile_cache_dir = None
 
@@ -239,32 +264,13 @@ def jit_cache_capacity():
         return 64
 
 
-@contextlib.contextmanager
 def _first_call(program, feed_arrays):
-    """The ``executor.compile`` span, and for a program that carries name
-    scopes or op roles a persistent-cache key that includes metadata:
-    jax's key strips it by default, so a cache shared with a build that
-    lowers the same HLO under other scope names (the parent of the PR
-    that named them) would hand back ITS executable, stale ``op_name``s
-    included, and the device trace would attribute nothing.  A program
-    without annotations keeps the key it always had."""
-    flag = "jax_compilation_cache_include_metadata_in_key"
-    annotated = any(
-        framework.OP_NAMESCOPE_ATTR in op.attrs
-        or framework.OP_ROLE_ATTR in op.attrs
-        for block in program.blocks for op in block.ops)
-    before = getattr(jax.config, flag)
-    if annotated:
-        jax.config.update(flag, True)
-    try:
-        with _span("executor.compile", program=id(program),
-                   version=program._version,
-                   feeds=sorted((n, str(a.dtype), tuple(a.shape))
-                                for n, a in feed_arrays.items())):
-            yield
-    finally:
-        if annotated:
-            jax.config.update(flag, before)
+    """The ``executor.compile`` span around the first call of a fresh
+    executable."""
+    return _span("executor.compile", program=id(program),
+                 version=program._version,
+                 feeds=sorted((n, str(a.dtype), tuple(a.shape))
+                              for n, a in feed_arrays.items()))
 
 
 def _captured(fn, feed_arrays, fetch_names, tag=""):
@@ -778,8 +784,12 @@ class Executor:
             return program  # already an optimized clone (direct call)
         key = (id(program), program._version, tuple(sorted(feed or ())),
                tuple(fetch_names))
+        # a clone remembers WHOSE it is: ``id()`` is reused once a program
+        # is collected, and two short-lived programs with the same feeds
+        # and fetches (the two load programs of a generation bundle) can
+        # follow each other at one address
         cached = self._opt_cache.get(key)
-        if cached is not None:
+        if cached is not None and cached._opt_source() is program:
             return cached
         from paddle_tpu.analysis.opt import optimize_program
         optimized, report = optimize_program(
@@ -794,6 +804,7 @@ class Executor:
             release_memory(optimized)
         if len(self._opt_cache) > 256:  # id()-reuse bound, not a cache
             self._opt_cache.clear()
+        optimized._opt_source = weakref.ref(program)
         self._opt_cache[key] = optimized
         return optimized
 

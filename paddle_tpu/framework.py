@@ -139,21 +139,36 @@ _op_role = None         # (program, role) while a transpiler appends ops
 
 
 @contextlib.contextmanager
-def name_scope(name):
+def name_scope(name, instead_of=()):
     """Name the part of the model the ops appended inside belong to:
     each gets the attribute ``op_namescope`` = the ``/``-joined path of
     the open scopes (``enc0/self_attn/core``).  Nestable.  It adds no op
     and no variable and leaves ``unique_name`` alone, so a program built
     under scopes computes what it computed without them; the executor
-    carries the path into the device trace (docs/observability.md)."""
+    carries the path into the device trace (docs/observability.md).
+
+    ``instead_of``: names of which at most one is to be open at a time;
+    where the innermost open scope is one of them, this scope takes its
+    place while it is open and does not nest under it (the sublayer
+    groups of ``models/decoder.py``: a shared expert built inside a
+    routed layer's ``experts`` is ``dense``, not ``experts/dense``)."""
     name = str(name)
     if not name.strip("/"):
         raise ValueError("name_scope needs a non-empty name")
+    displaced = _name_scopes.pop() \
+        if _name_scopes and _name_scopes[-1] in instead_of else None
     _name_scopes.append(name.strip("/"))
     try:
         yield
     finally:
         _name_scopes.pop()
+        if displaced is not None:
+            _name_scopes.append(displaced)
+
+
+def open_name_scopes():
+    """The open name scopes, outermost first."""
+    return tuple(_name_scopes)
 
 
 @contextlib.contextmanager

@@ -110,6 +110,7 @@ META_FILENAME = "gen_meta.json"
 
 @functools.partial(jax.jit, donate_argnums=(0, 4),
                    static_argnames="max_rows")
+@jax.named_scope("gen_seed")
 def _seed_pool(pools, kv, idx, n, states=(), new_states=(), slot=0, *,
                max_rows):
     """The one way the KV pool and the per-slot state are written
@@ -127,7 +128,9 @@ def _seed_pool(pools, kv, idx, n, states=(), new_states=(), slot=0, *,
     stale row.  ``idx`` has a fixed length and ``n`` is a traced trip
     count, so the signature depends on the prompt BUCKET alone, never
     on how many pages a request holds; ``n`` = 0 writes nothing.
-    Returns the new pools followed by the new states, one flat tuple."""
+    Returns the new pools followed by the new states, one flat tuple.
+    Every instruction lies under the named scope ``gen_seed``: the
+    executable's role in a device trace (docs/observability.md)."""
     def put_row(state, value):
         # only the slot's row is touched; with ``n`` = 0 it is rewritten
         # with itself
@@ -995,7 +998,8 @@ class GenPredictor:
         the decode program's step (``Executor.compiled_step``, inlined:
         the op scopes keep their names), ``state`` and ``inout`` donated.
         Every bucket's turn is the same function but for the static width
-        of the table slice."""
+        of the table slice.  The turn's own instructions, around the
+        step's, lie under the named scope ``gen_turn``."""
         fn = self._turns.get(pages)
         if fn is not None:
             return fn
@@ -1011,44 +1015,46 @@ class GenPredictor:
         drafts = self.speculative is not None
 
         def turn(state, patch, ro, inout, key):
-            token, pos, lens, table = state
-            flags = patch[:, _P_FLAGS, None]
-            set_row = (flags & _SET_ROW) > 0
-            token = jnp.where(patch[:, _P_TOKEN, None] >= 0,
-                              patch[:, _P_TOKEN, None], token)
-            pos = jnp.where(set_row, patch[:, _P_POS, None], pos)
-            lens = jnp.where(set_row, patch[:, _P_LENS, None], lens)
-            table = jnp.where((flags & _SET_TABLE) > 0,
-                              patch[:, _P_TABLE:], table)
-            feeds = {"gen_token": token, "gen_pos": pos,
-                     "gen_page_table": table[:, :pages],
-                     "gen_lens": _block_view(pos, lens, L)[0] if L > 1
-                     else lens}
-            if drafts:
-                feeds["gen_spec"] = (flags & _NO_DRAFT) == 0
-            (logits, *stats), written = step.flat(
-                {n: feeds[n].astype(kinds[n]) for n in kinds}, ro, inout,
-                key)
-            if drafts:
-                # the program verified its own draft: a slot's (first
-                # token, second or -1, how many); the last of them is
-                # the committed token the next turn feeds
-                *stats, verdict = stats
-                count = verdict[:, 2:]
-                token = jnp.where(count > 1, verdict[:, 1:2], verdict[:, :1])
-                read = jnp.concatenate(
-                    [verdict.astype(jnp.int32).reshape(-1)]
-                    + [s.astype(jnp.int32).reshape(-1) for s in stats])
-                return ((token, pos + count, lens + count, table),
+            with jax.named_scope("gen_turn"):
+                token, pos, lens, table = state
+                flags = patch[:, _P_FLAGS, None]
+                set_row = (flags & _SET_ROW) > 0
+                token = jnp.where(patch[:, _P_TOKEN, None] >= 0,
+                                  patch[:, _P_TOKEN, None], token)
+                pos = jnp.where(set_row, patch[:, _P_POS, None], pos)
+                lens = jnp.where(set_row, patch[:, _P_LENS, None], lens)
+                table = jnp.where((flags & _SET_TABLE) > 0,
+                                  patch[:, _P_TABLE:], table)
+                feeds = {"gen_token": token, "gen_pos": pos,
+                         "gen_page_table": table[:, :pages],
+                         "gen_lens": _block_view(pos, lens, L)[0] if L > 1
+                         else lens}
+                if drafts:
+                    feeds["gen_spec"] = (flags & _NO_DRAFT) == 0
+                feeds = {n: feeds[n].astype(kinds[n]) for n in kinds}
+            (logits, *stats), written = step.flat(feeds, ro, inout, key)
+            with jax.named_scope("gen_turn"):
+                if drafts:
+                    # the program verified its own draft: a slot's (first
+                    # token, second or -1, how many); the last of them is
+                    # the committed token the next turn feeds
+                    *stats, verdict = stats
+                    count = verdict[:, 2:]
+                    token = jnp.where(count > 1, verdict[:, 1:2],
+                                      verdict[:, :1])
+                    read = jnp.concatenate(
+                        [verdict.astype(jnp.int32).reshape(-1)]
+                        + [s.astype(jnp.int32).reshape(-1) for s in stats])
+                    return ((token, pos + count, lens + count, table),
+                            logits, read), written
+                # the greedy pick: first index on ties, as np.argmax
+                ids = jnp.argmax(logits.reshape(S, -1), axis=-1
+                                 ).astype(jnp.int32)
+                read = ids if not with_stats else jnp.concatenate(
+                    [ids, stats[0].astype(jnp.int32).reshape(-1)])
+                live = (lens > 0).astype(jnp.int32)
+                return ((ids[:, None], pos + live, lens + live, table),
                         logits, read), written
-            # the greedy pick: first index on ties, as np.argmax
-            ids = jnp.argmax(logits.reshape(S, -1), axis=-1
-                             ).astype(jnp.int32)
-            read = ids if not with_stats else jnp.concatenate(
-                [ids, stats[0].astype(jnp.int32).reshape(-1)])
-            live = (lens > 0).astype(jnp.int32)
-            return ((ids[:, None], pos + live, lens + live, table),
-                    logits, read), written
 
         fn = jax.jit(turn, donate_argnums=(0, 3))
         from paddle_tpu.obs import perf as _perf

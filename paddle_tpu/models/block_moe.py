@@ -59,9 +59,10 @@ import paddle_tpu.layers as layers
 from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
                                        DecoderConfig, decode_fetches,
                                        decode_inputs, decoder_layer, embed,
-                                       export_bundle, head_norm, last_row,
-                                       logits, matrix, op, persistable,
-                                       prefill_inputs, routed_experts,
+                                       export_bundle, group, head_norm,
+                                       last_row, live_rows, logits, matrix, op,
+                                       persistable, prefill_inputs,
+                                       program_role, routed_experts,
                                        train_inputs, train_loss)
 
 __all__ = ["BlockMoEConfig", "build_prefill_program",
@@ -154,9 +155,11 @@ def _layer(x, hp, i, pos, lens, mask=None, paged=None):
         # through the grouped product in one (chunk_rows 0)
         lambda h: routed_experts(
             h, hp, f"blk{i}", lens, experts=E, held=E, expert_offset=0,
-            scaling=1.0, scoring="softmax", bias=False, chunk_rows=0))
+            scaling=1.0, scoring="softmax", bias=False, chunk_rows=0),
+        routed=True)
 
 
+@program_role("gen_prefill")
 def build_prefill_program(hp):
     """The prefill forward in the CURRENT program guard.
 
@@ -171,16 +174,17 @@ def build_prefill_program(hp):
     rows; the full blocks' are final, the open block's are written over
     by the decode steps that follow."""
     ids, pos, mask, last = prefill_inputs()
-    # pad rows take no routed expert
-    lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
+    lens = live_rows(mask)
     x = embed(ids, hp, "blk")
     kv = []
     for i in range(int(hp.num_hidden_layers)):
         x, new, _ = _layer(x, hp, i, pos, lens, mask=mask)
         kv += new
     lasth = last_row(x, last, hp)
-    tail = op("block_tail", {"Ids": ids, "Last": last}, {"Out": "int32"},
-              {"block_length": int(hp.block_length)})["Out"]
+    with group("head"):
+        tail = op("block_tail", {"Ids": ids, "Last": last},
+                  {"Out": "int32"},
+                  {"block_length": int(hp.block_length)})["Out"]
     return (["gen_ids", "gen_pos", "gen_mask", "gen_last"],
             [logits(lasth, hp, "blk")] + kv + [tail])
 
@@ -202,6 +206,7 @@ def block_moe_train_program(seq_len, hp: BlockMoEConfig = None):
     return train_loss(x, labels, hp, "blk")
 
 
+@program_role("gen_decode")
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     """The decode step over ``2L`` rows a slot (``L = block_length``:
     the block a slot is generating and the block behind it), in the
@@ -226,13 +231,13 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     pools = {n: persistable(n, [int(num_pages), int(page_len), row],
                             hp.dtype) for n in paged_cache_var_names(hp)}
     state = persistable(STATE_VAR, [S, L], "int32")
-    rows = op("block_rows",
-              {"Token": token, "Pos": pos, "Lens": lens, "Block": state},
-              {"Ids": "int32", "RowPos": "int32", "RowLens": "int32",
-               "Pick": "float32", "End": "int32", "BlockOut": state},
-              {"mask_id": int(hp.mask_token_id)})
-    x = layers.reshape(embed(rows["Ids"], hp, "blk"),
-                       shape=[S, 2 * L, int(hp.hidden_size)])
+    with group("embed"):
+        rows = op("block_rows",
+                  {"Token": token, "Pos": pos, "Lens": lens, "Block": state},
+                  {"Ids": "int32", "RowPos": "int32", "RowLens": "int32",
+                   "Pick": "float32", "End": "int32", "BlockOut": state},
+                  {"mask_id": int(hp.mask_token_id)})
+    x = embed(rows["Ids"], hp, "blk", lead=[S, 2 * L])
     stats = []
     for i in range(int(hp.num_hidden_layers)):
         x, _, st = _layer(x, hp, i, rows["RowPos"], rows["RowLens"],
@@ -240,7 +245,8 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
                                  pools[f"blk{i}_paged_v"], page_table,
                                  rows["End"], rows["RowLens"]))
         stats.append(st)
-    picked = layers.matmul(layers.cast(rows["Pick"], hp.dtype), x)
+    with group("head"):
+        picked = layers.matmul(layers.cast(rows["Pick"], hp.dtype), x)
     return (["gen_token", "gen_pos", "gen_page_table", "gen_lens"],
             decode_fetches(picked, stats, S, hp, "blk"))
 

@@ -11,13 +11,16 @@ meta.  From here it takes
 * the program vocabulary: :func:`op`, :func:`param`, :func:`matrix`,
   :func:`vector`, :func:`data`, :func:`persistable`;
 * the blocks every pre-norm decoder has: :func:`rms`, :func:`head_norm`,
-  :func:`embed`,
+  :func:`embed`, :func:`live_rows`,
   :func:`logits`, :func:`gated_ffn`, :func:`routed_experts`, the
   two-sublayer :func:`decoder_layer`; and the multi-token-prediction
   module any of them can append (:func:`mtp_module`, :func:`mtp_logits`);
 * the head and the tail of its three programs: :func:`prefill_inputs` /
   :func:`last_row`, :func:`decode_inputs` / :func:`decode_fetches`,
   :func:`train_inputs` / :func:`train_loss`;
+* the names its ops carry into the device trace: :func:`program_role`
+  (which program of the bundle: :data:`ROLES`) and :func:`group` (which
+  sublayer: :data:`GROUPS`);
 * :class:`DecoderConfig` (``from_dict`` over a published ``config.json``)
   and :func:`export_bundle`.
 
@@ -31,6 +34,8 @@ builder's programs to their recorded digests.
 
 from __future__ import annotations
 
+import contextlib
+import functools
 import json
 import os
 
@@ -38,15 +43,18 @@ import numpy as np
 
 import paddle_tpu.layers as layers
 from paddle_tpu import initializer as init_mod
-from paddle_tpu.framework import default_main_program, name_scope
+from paddle_tpu.framework import (default_main_program, name_scope,
+                                  open_name_scopes)
 from paddle_tpu.layer_helper import LayerHelper
 from paddle_tpu.param_attr import ParamAttr
 
 __all__ = ["META_FILENAME", "PAGE_LEN_DEFAULT", "DECODE_STATS",
-           "CHUNK_ROWS", "DecoderConfig", "default_page_buckets",
+           "CHUNK_ROWS", "ROLES", "GROUPS", "program_role", "group",
+           "mtp_scope",
+           "DecoderConfig", "default_page_buckets",
            "chunk_rows", "op", "param", "matrix",
            "vector", "data", "persistable", "rms", "head_norm", "embed",
-           "logits",
+           "live_rows", "logits",
            "gated_ffn", "routed_experts", "decoder_layer", "shared",
            "mtp_module", "mtp_logits", "prefill_inputs",
            "last_row", "decode_inputs", "decode_fetches", "train_inputs",
@@ -69,6 +77,54 @@ DECODE_STATS = [{"name": "moe_assignments", "reduce": "sum"},
 # PERF.md section 6 (PR 42, PR 46) has the chip's readings at 512 / 1024
 # / 2048
 CHUNK_ROWS = 1024
+
+#: the outermost name scope of a serving program: which program of the
+#: bundle an instruction of the device trace belongs to (the predictor
+#: names its own two executables ``gen_turn`` and ``gen_seed``)
+ROLES = ("gen_prefill", "gen_chunk", "gen_decode")
+
+#: the name scope under the role: which sublayer (docs/observability.md
+#: has what lies under each and the metric that reads it)
+GROUPS = ("embed", "attn", "mixer", "experts", "dense", "head")
+
+
+def program_role(role):
+    """Decorator of a builder's ``build_prefill_program`` /
+    ``build_chunk_program`` / ``build_paged_decode_program``: every op
+    the function appends lies under the name scope ``role`` (one of
+    :data:`ROLES`), whoever calls it.  A train program takes none."""
+    if role not in ROLES:
+        raise ValueError(f"program_role({role!r}): one of {ROLES}")
+
+    def named(build):
+        @functools.wraps(build)
+        def build_under_role(*args, **kwargs):
+            with name_scope(role):
+                return build(*args, **kwargs)
+        return build_under_role
+    return named
+
+
+def group(name):
+    """The name scope of ONE sublayer (one of :data:`GROUPS`).  One group
+    is open at a time: opened inside another it takes that one's place
+    (``name_scope``'s ``instead_of``), so a path reads ``<role>/<group>``
+    or ``<role>/mtp/<group>`` and never holds two."""
+    if name not in GROUPS:
+        raise ValueError(f"group({name!r}): one of {GROUPS}")
+    return name_scope(name, instead_of=GROUPS)
+
+
+@contextlib.contextmanager
+def mtp_scope():
+    """The MTP module's own name scope ``mtp``, outside its ops' groups
+    (``<role>/mtp/<group>``); inside itself it opens nothing, so what a
+    builder appends around :func:`mtp_logits` can share its scope."""
+    if "mtp" in open_name_scopes():
+        yield
+    else:
+        with name_scope("mtp"):
+            yield
 
 
 class DecoderConfig:
@@ -208,33 +264,53 @@ def head_norm(x, name, hp, n_head, width):
     return layers.reshape(rows, shape=lead + [n_head * width])
 
 
-def embed(ids, hp, prefix):
+def embed(ids, hp, prefix, lead=None):
     """The token embedding ``{prefix}_emb`` (no position is added: the
-    attention, or a mixer, carries it)."""
+    attention, or a mixer, carries it), reshaped to ``lead + [d]`` where
+    ``lead`` is given (a decode step's ``[slots, rows a slot]``); group
+    ``embed``."""
     limit = (6.0 / (hp.vocab_size + hp.hidden_size)) ** 0.5
-    return layers.embedding(
-        ids, size=[int(hp.vocab_size), int(hp.hidden_size)], dtype=hp.dtype,
-        param_attr=ParamAttr(name=f"{prefix}_emb",
-                             initializer=init_mod.Uniform(-limit, limit)))
+    with group("embed"):
+        x = layers.embedding(
+            ids, size=[int(hp.vocab_size), int(hp.hidden_size)],
+            dtype=hp.dtype,
+            param_attr=ParamAttr(name=f"{prefix}_emb",
+                                 initializer=init_mod.Uniform(-limit,
+                                                              limit)))
+        if lead is not None:
+            x = layers.reshape(x, shape=list(lead) + [int(hp.hidden_size)])
+        return x
+
+
+def live_rows(mask):
+    """``lens`` [rows, 1] int32 of a prefill's or a chunk's rows from its
+    ``mask`` [1, rows]: 1 on a real row, 0 on a pad row, which takes no
+    routed expert; group ``embed`` (it lays the step's rows out)."""
+    with group("embed"):
+        return layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
 
 
 def logits(x2, hp, prefix):
-    """Final norm and the untied head over rows ``x2`` [R, d]; float32."""
-    h = rms(x2, f"{prefix}_norm.scale", hp)
-    head = matrix(hp, f"{prefix}_head.w", [int(hp.hidden_size),
-                                           int(hp.vocab_size)])
-    return op("matmul", {"X": h, "Y": head}, {"Out": "float32"},
-              {"out_dtype": "float32"})["Out"]
+    """Final norm and the untied head over rows ``x2`` [R, d]; float32;
+    group ``head``."""
+    with group("head"):
+        h = rms(x2, f"{prefix}_norm.scale", hp)
+        head = matrix(hp, f"{prefix}_head.w", [int(hp.hidden_size),
+                                               int(hp.vocab_size)])
+        return op("matmul", {"X": h, "Y": head}, {"Out": "float32"},
+                  {"out_dtype": "float32"})["Out"]
 
 
 def gated_ffn(h, hp, prefix, width):
     """``W_d (silu(W_g h) * W_u h)`` of ``width``: a dense layer's FFN, a
-    shared expert."""
+    shared expert; group ``dense`` (also where a routed layer builds its
+    shared expert inside its ``experts``)."""
     d = int(hp.hidden_size)
-    g = layers.matmul(h, matrix(hp, f"{prefix}_gate.w", [d, width]))
-    u = layers.matmul(h, matrix(hp, f"{prefix}_up.w", [d, width]))
-    a = op("swiglu", {"X": g, "Y": u}, {"Out": hp.dtype})["Out"]
-    return layers.matmul(a, matrix(hp, f"{prefix}_down.w", [width, d]))
+    with group("dense"):
+        g = layers.matmul(h, matrix(hp, f"{prefix}_gate.w", [d, width]))
+        u = layers.matmul(h, matrix(hp, f"{prefix}_up.w", [d, width]))
+        a = op("swiglu", {"X": g, "Y": u}, {"Out": hp.dtype})["Out"]
+        return layers.matmul(a, matrix(hp, f"{prefix}_down.w", [width, d]))
 
 
 def routed_experts(h, hp, prefix, lens, *, experts, held, expert_offset,
@@ -259,52 +335,66 @@ def routed_experts(h, hp, prefix, lens, *, experts, held, expert_offset,
     ``latent`` = L: the LatentMoE form, ungated experts (``moe_experts``;
     ``{prefix}_w1`` / ``_w2``) in a latent of L between a down- and an
     up-projection (``{prefix}_down.w`` / ``_up.w``).  A shared expert is
-    its caller's addition."""
+    its caller's addition.  Group ``experts``: the router, the routed
+    product and the latent form's two projections."""
     d, F = int(hp.hidden_size), int(hp.moe_intermediate_size)
-    route_in = {"X": h, "W": matrix(hp, f"{prefix}_gate.w", [d, experts])}
-    if bias:
-        route_in["Bias"] = vector(f"{prefix}_gate.bias", experts, 0.0)
-    route_attrs = {"top_k": int(hp.num_experts_per_tok),
-                   "scaling": float(scaling),
-                   "norm_topk": bool(hp.norm_topk_prob)}
-    if scoring is not None:
-        route_attrs["scoring"] = scoring
-    route = op("moe_route", route_in,
-               {"TopkIdx": "int32", "TopkWeight": "float32"}, route_attrs)
-    attrs = {"expert_offset": int(expert_offset)}
-    if chunk_rows is not None:
-        attrs["chunk_rows"] = int(chunk_rows)
-    if latent is None:
-        kind, x = "moe_experts_gated", h
-        weights = {"Wg": matrix(hp, f"{prefix}_wg", [held, d, F]),
-                   "Wu": matrix(hp, f"{prefix}_wu", [held, d, F]),
-                   "Wd": matrix(hp, f"{prefix}_wd", [held, F, d])}
-    else:
-        kind = "moe_experts"
-        x = layers.matmul(h, matrix(hp, f"{prefix}_down.w", [d, latent]))
-        weights = {"W1": matrix(hp, f"{prefix}_w1", [held, latent, F]),
-                   "W2": matrix(hp, f"{prefix}_w2", [held, F, latent])}
-    routed = op(kind, {"X": x, "TopkIdx": route["TopkIdx"],
-                       "TopkWeight": route["TopkWeight"], **weights,
-                       "Lens": lens},
-                {"Out": hp.dtype, "Stats": "int32"}, attrs)
-    out = routed["Out"]
-    if latent is not None:
-        out = layers.matmul(out, matrix(hp, f"{prefix}_up.w", [latent, d]))
-    return out, routed["Stats"]
+    with group("experts"):
+        route_in = {"X": h,
+                    "W": matrix(hp, f"{prefix}_gate.w", [d, experts])}
+        if bias:
+            route_in["Bias"] = vector(f"{prefix}_gate.bias", experts, 0.0)
+        route_attrs = {"top_k": int(hp.num_experts_per_tok),
+                       "scaling": float(scaling),
+                       "norm_topk": bool(hp.norm_topk_prob)}
+        if scoring is not None:
+            route_attrs["scoring"] = scoring
+        route = op("moe_route", route_in,
+                   {"TopkIdx": "int32", "TopkWeight": "float32"},
+                   route_attrs)
+        attrs = {"expert_offset": int(expert_offset)}
+        if chunk_rows is not None:
+            attrs["chunk_rows"] = int(chunk_rows)
+        if latent is None:
+            kind, x = "moe_experts_gated", h
+            weights = {"Wg": matrix(hp, f"{prefix}_wg", [held, d, F]),
+                       "Wu": matrix(hp, f"{prefix}_wu", [held, d, F]),
+                       "Wd": matrix(hp, f"{prefix}_wd", [held, F, d])}
+        else:
+            kind = "moe_experts"
+            x = layers.matmul(h, matrix(hp, f"{prefix}_down.w",
+                                        [d, latent]))
+            weights = {"W1": matrix(hp, f"{prefix}_w1", [held, latent, F]),
+                       "W2": matrix(hp, f"{prefix}_w2", [held, F, latent])}
+        routed = op(kind, {"X": x, "TopkIdx": route["TopkIdx"],
+                           "TopkWeight": route["TopkWeight"], **weights,
+                           "Lens": lens},
+                    {"Out": hp.dtype, "Stats": "int32"}, attrs)
+        out = routed["Out"]
+        if latent is not None:
+            out = layers.matmul(out, matrix(hp, f"{prefix}_up.w",
+                                            [latent, d]))
+        return out, routed["Stats"]
 
 
-def decoder_layer(x, hp, prefix, attention, ffn):
+def decoder_layer(x, hp, prefix, attention, ffn, *, routed):
     """One pre-norm layer of two sublayers, ``x <- x +
     attention(RMSNorm(x))`` then ``x <- x + ffn(RMSNorm(x))``, the norms'
     scales ``{prefix}_norm1.scale`` / ``_norm2.scale``.  ``attention(h)
     -> (out, kept)``: ``kept`` is whatever the caller wants of it (the
     rows that seed a cache, a selection); ``ffn(h) -> (out, stats or
-    None)``.  Returns ``(x, kept, stats)``."""
-    out, kept = attention(rms(x, f"{prefix}_norm1.scale", hp))
-    x = x + out
-    out, stats = ffn(rms(x, f"{prefix}_norm2.scale", hp))
-    return x + out, kept, stats
+    None)``.  Returns ``(x, kept, stats)``.
+
+    A sublayer's norm and residual add go into the sublayer's group: the
+    first is ``attn``; the second ``experts`` where the layer is
+    ``routed``, else ``dense``.  A routed layer's second norm feeds its
+    shared expert as well as its router and lies under ``experts``; the
+    shared expert itself (:func:`gated_ffn`) is ``dense``."""
+    with group("attn"):
+        out, kept = attention(rms(x, f"{prefix}_norm1.scale", hp))
+        x = x + out
+    with group("experts" if routed else "dense"):
+        out, stats = ffn(rms(x, f"{prefix}_norm2.scale", hp))
+        return x + out, kept, stats
 
 
 def shared(name):
@@ -318,7 +408,9 @@ def shared(name):
 def mtp_module(h, next_ids, hp, prefix, block):
     """The multi-token-prediction module of DeepSeek-V3 (arXiv:2412.19437
     section 2.2; ``num_nextn_predict_layers`` 1), under the name scope
-    ``mtp`` (its ops' own scope on the device trace)::
+    ``mtp`` (its ops' own scope on the device trace, outside their
+    group: the module's input, its two norms and its projection are
+    ``mtp/embed``, its block's sublayers ``mtp/attn`` and so on)::
 
         h'_i = W_p [RMS_h(h_i) ; RMS_e(E[t_{i+1}])]      g_i = Block(h'_i)
 
@@ -332,24 +424,26 @@ def mtp_module(h, next_ids, hp, prefix, block):
     ``0 .. d - 1`` take the hidden state's half).  Returns ``(g,
     stats)``; the draft logits of a row are :func:`mtp_logits` of it."""
     d = int(hp.hidden_size)
-    with name_scope("mtp"):
-        e = op("lookup_table", {"W": shared(f"{prefix}_emb"),
-                                "Ids": next_ids}, {"Out": hp.dtype},
-               {"is_sparse": False, "is_distributed": False,
-                "padding_idx": -1})["Out"]
-        e = layers.reshape(e, shape=[int(n) for n in h.shape])
-        both = layers.concat([rms(h, f"{prefix}_mtp_hnorm.scale", hp),
-                              rms(e, f"{prefix}_mtp_enorm.scale", hp)],
-                             axis=len(h.shape) - 1)
-        return block(layers.matmul(
-            both, matrix(hp, f"{prefix}_mtp_proj.w", [2 * d, d])))
+    with mtp_scope():
+        with group("embed"):
+            e = op("lookup_table", {"W": shared(f"{prefix}_emb"),
+                                    "Ids": next_ids}, {"Out": hp.dtype},
+                   {"is_sparse": False, "is_distributed": False,
+                    "padding_idx": -1})["Out"]
+            e = layers.reshape(e, shape=[int(n) for n in h.shape])
+            both = layers.concat([rms(h, f"{prefix}_mtp_hnorm.scale", hp),
+                                  rms(e, f"{prefix}_mtp_enorm.scale", hp)],
+                                 axis=len(h.shape) - 1)
+            x = layers.matmul(
+                both, matrix(hp, f"{prefix}_mtp_proj.w", [2 * d, d]))
+        return block(x)
 
 
 def mtp_logits(g2, hp, prefix):
     """The draft logits of the MTP rows ``g2`` [R, d]: the module's own
     final norm ``{prefix}_mtp_norm.scale`` and the main model's head
-    ``{prefix}_head.w`` (:func:`shared`); float32."""
-    with name_scope("mtp"):
+    ``{prefix}_head.w`` (:func:`shared`); float32; ``mtp/head``."""
+    with mtp_scope(), group("head"):
         h = rms(g2, f"{prefix}_mtp_norm.scale", hp)
         return op("matmul", {"X": h, "Y": shared(f"{prefix}_head.w")},
                   {"Out": "float32"}, {"out_dtype": "float32"})["Out"]
@@ -373,10 +467,12 @@ def prefill_inputs(pos=True):
 
 def last_row(x, last, hp):
     """The row of ``x`` [1, T, d] that the one-hot ``last`` names, as
-    [1, d] (zeros where ``last`` is all zeros)."""
-    last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]), hp.dtype)
-    return layers.reshape(layers.matmul(last3, x),
-                          shape=[-1, int(hp.hidden_size)])
+    [1, d] (zeros where ``last`` is all zeros); group ``head``."""
+    with group("head"):
+        last3 = layers.cast(layers.reshape(last, shape=[1, 1, -1]),
+                            hp.dtype)
+        return layers.reshape(layers.matmul(last3, x),
+                              shape=[-1, int(hp.hidden_size)])
 
 
 def decode_inputs(num_slots, pos=True):
@@ -396,11 +492,12 @@ def decode_inputs(num_slots, pos=True):
 def decode_fetches(x, stats, num_slots, hp, prefix):
     """``[logits [S, V], stats [n_moe, 3]]`` from the rows ``x`` [S, 1,
     d] a slot and the expert layers' stats; no second fetch where no
-    layer routes."""
-    fetches = [logits(layers.reshape(
-        x, shape=[int(num_slots), int(hp.hidden_size)]), hp, prefix)]
-    if stats:
-        fetches.append(layers.concat(stats, axis=0))
+    layer routes; group ``head``."""
+    with group("head"):
+        fetches = [logits(layers.reshape(
+            x, shape=[int(num_slots), int(hp.hidden_size)]), hp, prefix)]
+        if stats:
+            fetches.append(layers.concat(stats, axis=0))
     return fetches
 
 
@@ -427,13 +524,14 @@ def train_inputs(seq_len, *rows):
 def train_loss(x, labels, hp, prefix):
     """``(avg_cost, feed_names)`` of a teacher-forced forward: the head
     over every row of ``x`` [1, T, d] and the mean cross entropy with
-    ``labels`` [1, T]."""
+    ``labels`` [1, T]; group ``head``."""
     T = int(labels.shape[1])
-    cost = layers.softmax_with_cross_entropy(
-        logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
-               prefix),
-        layers.reshape(labels, shape=[T, 1]))
-    return layers.mean(x=cost), ["gen_ids", "gen_labels"]
+    with group("head"):
+        cost = layers.softmax_with_cross_entropy(
+            logits(layers.reshape(x, shape=[T, int(hp.hidden_size)]), hp,
+                   prefix),
+            layers.reshape(labels, shape=[T, 1]))
+        return layers.mean(x=cost), ["gen_ids", "gen_labels"]
 
 
 # ---------------------------------------------------------------------------

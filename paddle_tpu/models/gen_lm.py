@@ -34,7 +34,7 @@ from paddle_tpu.initializer import NumpyArrayInitializer
 from paddle_tpu.models.decoder import (META_FILENAME, PAGE_LEN_DEFAULT,
                                        data, decode_inputs,
                                        default_page_buckets, export_bundle,
-                                       op, persistable)
+                                       group, op, persistable, program_role)
 from paddle_tpu.param_attr import ParamAttr
 
 __all__ = ["GenConfig", "build_prefill_program",
@@ -68,15 +68,18 @@ def _pos_table(hp):
 
 def _embed(ids, pos_ids, hp):
     """Shared token + position embedding (works for [B, T] prefill ids
-    and [S, 1] decode ids — lookup_table squeezes a trailing 1)."""
-    word = layers.embedding(ids, size=[hp.vocab_size, hp.d_model],
-                            param_attr=_pa("genlm_word_emb"))
-    word = layers.scale(word, scale=float(hp.d_model) ** 0.5)
-    pos = layers.embedding(
-        pos_ids, size=[hp.max_len, hp.d_model],
-        param_attr=_pa("genlm_pos_emb", trainable=False,
-                       initializer=NumpyArrayInitializer(_pos_table(hp))))
-    return word + pos
+    and [S, 1] decode ids — lookup_table squeezes a trailing 1); group
+    ``embed``."""
+    with group("embed"):
+        word = layers.embedding(ids, size=[hp.vocab_size, hp.d_model],
+                                param_attr=_pa("genlm_word_emb"))
+        word = layers.scale(word, scale=float(hp.d_model) ** 0.5)
+        pos = layers.embedding(
+            pos_ids, size=[hp.max_len, hp.d_model],
+            param_attr=_pa("genlm_pos_emb", trainable=False,
+                           initializer=NumpyArrayInitializer(
+                               _pos_table(hp))))
+        return word + pos
 
 
 def _ln(x, idx, tag):
@@ -133,8 +136,12 @@ def _attend(q, k, v, bias, hp, idx, q_len, k_len):
 
 
 def _block_tail(x, attn, hp, idx):
-    x = _ln(x + attn, idx, "ln1")
-    return _ln(x + _ffn(x, hp, idx), idx, "ln2")
+    """Post-norm: the residual add and norm behind the attention are
+    ``attn``'s, the FFN with its add and norm ``dense``."""
+    with group("attn"):
+        x = _ln(x + attn, idx, "ln1")
+    with group("dense"):
+        return _ln(x + _ffn(x, hp, idx), idx, "ln2")
 
 
 def paged_cache_var_names(hp):
@@ -151,6 +158,7 @@ def paged_cache_var_names(hp):
 # prefill: one prompt, dynamic (bucketed) length
 # ---------------------------------------------------------------------------
 
+@program_role("gen_prefill")
 def build_prefill_program(hp):
     """Build the prefill forward in the CURRENT program guard.
 
@@ -173,17 +181,19 @@ def build_prefill_program(hp):
     x = _embed(ids, pos, hp)
     kv = []
     for i in range(hp.n_layer):
-        q, k, v = _qkv(x, hp, i)
-        k_m = layers.elementwise_mul(k, mask, axis=0)
-        v_m = layers.elementwise_mul(v, mask, axis=0)
-        kv += [k_m, v_m]
-        attn = _attend(q, k_m, v_m, bias, hp, i, q_len=-1, k_len=-1)
+        with group("attn"):
+            q, k, v = _qkv(x, hp, i)
+            k_m = layers.elementwise_mul(k, mask, axis=0)
+            v_m = layers.elementwise_mul(v, mask, axis=0)
+            kv += [k_m, v_m]
+            attn = _attend(q, k_m, v_m, bias, hp, i, q_len=-1, k_len=-1)
         x = _block_tail(x, attn, hp, i)
-    last3 = layers.reshape(last, shape=[1, 1, -1])
-    lasth = layers.matmul(last3, x)                    # [1, 1, d]
-    lasth = layers.reshape(lasth, shape=[-1, hp.d_model])
-    logits = layers.fc(lasth, hp.vocab_size, bias_attr=False,
-                       param_attr=_pa("genlm_logits.w"))
+    with group("head"):
+        last3 = layers.reshape(last, shape=[1, 1, -1])
+        lasth = layers.matmul(last3, x)                    # [1, 1, d]
+        lasth = layers.reshape(lasth, shape=[-1, hp.d_model])
+        logits = layers.fc(lasth, hp.vocab_size, bias_attr=False,
+                           param_attr=_pa("genlm_logits.w"))
     feeds = ["gen_ids", "gen_pos", "gen_mask", "gen_attn_bias", "gen_last"]
     return feeds, [logits] + kv
 
@@ -193,6 +203,7 @@ def build_prefill_program(hp):
 # bucketed by page count
 # ---------------------------------------------------------------------------
 
+@program_role("gen_decode")
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     """Build the single-token decode step in the CURRENT program guard.
 
@@ -216,24 +227,27 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
               for name in paged_cache_var_names(hp)}
 
     x = _embed(token, pos, hp)                         # [S, d]
-    x = layers.reshape(x, shape=[S, 1, hp.d_model])
+    with group("embed"):
+        x = layers.reshape(x, shape=[S, 1, hp.d_model])
     for i in range(hp.n_layer):
-        q, k, v = _qkv(x, hp, i)                       # [S, 1, H*D]
-        pk = caches[f"genlm_paged_k_{i}"]
-        pv = caches[f"genlm_paged_v_{i}"]
-        ctxv = op("paged_attention",
-                  {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
-                   "PageTable": page_table, "Lens": lens},
-                  {"Out": "float32", "KCacheOut": pk, "VCacheOut": pv},
-                  {"n_head": int(hp.n_head),
-                   "scale": float(hp.d_head) ** -0.5})["Out"]
-        attn = layers.fc(ctxv, hp.d_model, num_flatten_dims=2,
-                         bias_attr=False,
-                         param_attr=_pa(f"genlm{i}_attnout.w"))
+        with group("attn"):
+            q, k, v = _qkv(x, hp, i)                   # [S, 1, H*D]
+            pk = caches[f"genlm_paged_k_{i}"]
+            pv = caches[f"genlm_paged_v_{i}"]
+            ctxv = op("paged_attention",
+                      {"Q": q, "K": k, "V": v, "KCache": pk, "VCache": pv,
+                       "PageTable": page_table, "Lens": lens},
+                      {"Out": "float32", "KCacheOut": pk, "VCacheOut": pv},
+                      {"n_head": int(hp.n_head),
+                       "scale": float(hp.d_head) ** -0.5})["Out"]
+            attn = layers.fc(ctxv, hp.d_model, num_flatten_dims=2,
+                             bias_attr=False,
+                             param_attr=_pa(f"genlm{i}_attnout.w"))
         x = _block_tail(x, attn, hp, i)
-    x2 = layers.reshape(x, shape=[S, hp.d_model])
-    logits = layers.fc(x2, hp.vocab_size, bias_attr=False,
-                       param_attr=_pa("genlm_logits.w"))
+    with group("head"):
+        x2 = layers.reshape(x, shape=[S, hp.d_model])
+        logits = layers.fc(x2, hp.vocab_size, bias_attr=False,
+                           param_attr=_pa("genlm_logits.w"))
     feeds = ["gen_token", "gen_pos", "gen_page_table", "gen_lens"]
     return feeds, [logits]
 
@@ -260,15 +274,18 @@ def gen_lm_train_program(batch_size, seq_len, hp: GenConfig = None):
 
     x = _embed(ids, pos, hp)
     for i in range(hp.n_layer):
-        q, k, v = _qkv(x, hp, i)
-        attn = _attend(q, k, v, bias, hp, i, q_len=T, k_len=T)
+        with group("attn"):
+            q, k, v = _qkv(x, hp, i)
+            attn = _attend(q, k, v, bias, hp, i, q_len=T, k_len=T)
         x = _block_tail(x, attn, hp, i)
-    logits = layers.fc(x, hp.vocab_size, num_flatten_dims=2,
-                       bias_attr=False, param_attr=_pa("genlm_logits.w"))
-    logits2d = layers.reshape(logits, shape=[B * T, hp.vocab_size])
-    labels2d = layers.reshape(labels, shape=[B * T, 1])
-    cost = layers.softmax_with_cross_entropy(logits2d, labels2d)
-    avg_cost = layers.mean(x=cost)
+    with group("head"):
+        logits = layers.fc(x, hp.vocab_size, num_flatten_dims=2,
+                           bias_attr=False,
+                           param_attr=_pa("genlm_logits.w"))
+        logits2d = layers.reshape(logits, shape=[B * T, hp.vocab_size])
+        labels2d = layers.reshape(labels, shape=[B * T, 1])
+        cost = layers.softmax_with_cross_entropy(logits2d, labels2d)
+        avg_cost = layers.mean(x=cost)
     return avg_cost, ["gen_ids", "gen_labels"]
 
 

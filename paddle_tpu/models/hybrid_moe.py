@@ -63,9 +63,11 @@ from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
                                        DecoderConfig, chunk_rows, data,
                                        decode_fetches,
                                        decode_inputs, embed, export_bundle,
-                                       gated_ffn,
-                                       last_row, logits, matrix, op, param,
-                                       persistable, prefill_inputs, rms,
+                                       gated_ffn, group,
+                                       last_row, live_rows, logits, matrix,
+                                       op, param,
+                                       persistable, prefill_inputs,
+                                       program_role, rms,
                                        routed_experts, train_inputs,
                                        train_loss, vector)
 
@@ -338,9 +340,10 @@ def _moe(h, hp, i, lens=None):
         h, hp, f"hyb{i}", lens, experts=int(hp.n_routed_experts),
         held=hp.held, expert_offset=hp.expert_offset,
         scaling=hp.routed_scaling_factor, latent=int(hp.moe_latent_size))
-    s = layers.matmul(h, matrix(hp, f"hyb{i}_sh1.w", [d, Fs]))
-    s = op("relu2", {"X": s}, {"Out": hp.dtype})["Out"]
-    s = layers.matmul(s, matrix(hp, f"hyb{i}_sh2.w", [Fs, d]))
+    with group("dense"):
+        s = layers.matmul(h, matrix(hp, f"hyb{i}_sh1.w", [d, Fs]))
+        s = op("relu2", {"X": s}, {"Out": hp.dtype})["Out"]
+        s = layers.matmul(s, matrix(hp, f"hyb{i}_sh2.w", [Fs, d]))
     return y + s, stats
 
 
@@ -360,6 +363,12 @@ def _shared_moe(h, hp, i, lens=None):
 #: the feed-forward sublayers by their letter
 _FFN = {"E": _moe, "S": _shared_moe}
 
+#: a sublayer's group (``decoder.GROUPS``) by its letter: its norm, what
+#: it computes and its residual add; a shared expert inside ``E`` / ``S``
+#: is ``dense``
+_GROUP = {"M": "mixer", "K": "mixer", "*": "attn", "G": "attn",
+          "E": "experts", "S": "experts"}
+
 
 def _caches(hp, num_slots, page_len, num_pages):
     """The persistable caches of the CURRENT program: ``(pools, state)``,
@@ -375,6 +384,7 @@ def _caches(hp, num_slots, page_len, num_pages):
     return pools, state
 
 
+@program_role("gen_chunk")
 def build_chunk_program(hp, num_slots, page_len, num_pages):
     """The prefill of ONE CHUNK of a prompt in the CURRENT program guard
     (a pattern of ``K``, ``*`` / ``G`` and ``E`` / ``S``).
@@ -399,27 +409,28 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     slot = data("gen_slot", [1, 1], "int32")
     page_table = data("gen_page_table", [1, -1], "int32")
     pools, state = _caches(hp, num_slots, page_len, num_pages)
-    # pad rows take no routed expert
-    lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
+    lens = live_rows(mask)
     x = embed(ids, hp, "hyb")
     for i, kind in enumerate(hp.pattern):
-        h = rms(x, f"hyb{i}_norm.scale", hp)
-        if kind == "K":
-            out = _kda(h, hp, i, (state[f"hyb{i}_conv_state"],
-                                  state[f"hyb{i}_kda_state"]),
-                       chunk=(slot, pos, mask))
-        elif kind in "*G":
-            out, _ = _attention(h, hp, i, gate=kind == "G", chunk=(
-                pools[f"hyb{i}_paged_k"], pools[f"hyb{i}_paged_v"],
-                page_table, pos, mask))
-        else:
-            out, _ = _FFN[kind](h, hp, i, lens=lens)
-        x = x + out
+        with group(_GROUP[kind]):
+            h = rms(x, f"hyb{i}_norm.scale", hp)
+            if kind == "K":
+                out = _kda(h, hp, i, (state[f"hyb{i}_conv_state"],
+                                      state[f"hyb{i}_kda_state"]),
+                           chunk=(slot, pos, mask))
+            elif kind in "*G":
+                out, _ = _attention(h, hp, i, gate=kind == "G", chunk=(
+                    pools[f"hyb{i}_paged_k"], pools[f"hyb{i}_paged_v"],
+                    page_table, pos, mask))
+            else:
+                out, _ = _FFN[kind](h, hp, i, lens=lens)
+            x = x + out
     return (["gen_ids", "gen_pos", "gen_mask", "gen_last", "gen_slot",
              "gen_page_table"],
             [logits(last_row(x, last, hp), hp, "hyb")])
 
 
+@program_role("gen_prefill")
 def build_prefill_program(hp):
     """The prefill forward in the CURRENT program guard.
 
@@ -433,16 +444,17 @@ def build_prefill_program(hp):
     x = embed(ids, hp, "hyb")
     kv, states = [], []
     for i, kind in enumerate(hp.pattern):
-        h = rms(x, f"hyb{i}_norm.scale", hp)
-        if kind == "M":
-            out, new = _mixer(h, hp, i, mask=mask)
-            states += new
-        elif kind in "*G":
-            out, new = _attention(h, hp, i, mask=mask, gate=kind == "G")
-            kv += new
-        else:
-            out, _ = _FFN[kind](h, hp, i)
-        x = x + out
+        with group(_GROUP[kind]):
+            h = rms(x, f"hyb{i}_norm.scale", hp)
+            if kind == "M":
+                out, new = _mixer(h, hp, i, mask=mask)
+                states += new
+            elif kind in "*G":
+                out, new = _attention(h, hp, i, mask=mask, gate=kind == "G")
+                kv += new
+            else:
+                out, _ = _FFN[kind](h, hp, i)
+            x = x + out
     return (["gen_ids", "gen_mask", "gen_last"],
             [logits(last_row(x, last, hp), hp, "hyb")] + kv + states)
 
@@ -460,18 +472,20 @@ def hybrid_moe_train_program(seq_len, hp: HybridConfig = None):
     ids, labels, rows = train_inputs(seq_len, "mask")
     x = embed(ids, hp, "hyb")
     for i, kind in enumerate(hp.pattern):
-        h = rms(x, f"hyb{i}_norm.scale", hp)
-        if kind == "M":
-            out, _ = _mixer(h, hp, i, mask=rows["mask"])
-        elif kind in "*G":
-            out, _ = _attention(h, hp, i, mask=rows["mask"],
-                                gate=kind == "G")
-        else:
-            out, _ = _FFN[kind](h, hp, i)
-        x = x + out
+        with group(_GROUP[kind]):
+            h = rms(x, f"hyb{i}_norm.scale", hp)
+            if kind == "M":
+                out, _ = _mixer(h, hp, i, mask=rows["mask"])
+            elif kind in "*G":
+                out, _ = _attention(h, hp, i, mask=rows["mask"],
+                                    gate=kind == "G")
+            else:
+                out, _ = _FFN[kind](h, hp, i)
+            x = x + out
     return train_loss(x, labels, hp, "hyb")
 
 
+@program_role("gen_decode")
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     """The single-token decode step in the CURRENT program guard.
 
@@ -486,26 +500,27 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     token, _, page_table, lens = decode_inputs(S, pos=False)
     pools, state = _caches(hp, S, page_len, num_pages)
 
-    x = layers.reshape(embed(token, hp, "hyb"),
-                       shape=[S, 1, int(hp.hidden_size)])
+    x = embed(token, hp, "hyb", lead=[S, 1])
     stats = []
     for i, kind in enumerate(hp.pattern):
-        h = rms(x, f"hyb{i}_norm.scale", hp)
-        if kind == "M":
-            out, _ = _mixer(h, hp, i, lens=lens,
-                            states=(state[f"hyb{i}_conv_state"],
-                                    state[f"hyb{i}_ssm_state"]))
-        elif kind == "K":
-            out = _kda(h, hp, i, (state[f"hyb{i}_conv_state"],
-                                  state[f"hyb{i}_kda_state"]), lens=lens)
-        elif kind in "*G":
-            out, _ = _attention(h, hp, i, gate=kind == "G", paged=(
-                pools[f"hyb{i}_paged_k"], pools[f"hyb{i}_paged_v"],
-                page_table, lens))
-        else:
-            out, st = _FFN[kind](h, hp, i, lens=lens)
-            stats.append(st)
-        x = x + out
+        with group(_GROUP[kind]):
+            h = rms(x, f"hyb{i}_norm.scale", hp)
+            if kind == "M":
+                out, _ = _mixer(h, hp, i, lens=lens,
+                                states=(state[f"hyb{i}_conv_state"],
+                                        state[f"hyb{i}_ssm_state"]))
+            elif kind == "K":
+                out = _kda(h, hp, i, (state[f"hyb{i}_conv_state"],
+                                      state[f"hyb{i}_kda_state"]),
+                           lens=lens)
+            elif kind in "*G":
+                out, _ = _attention(h, hp, i, gate=kind == "G", paged=(
+                    pools[f"hyb{i}_paged_k"], pools[f"hyb{i}_paged_v"],
+                    page_table, lens))
+            else:
+                out, st = _FFN[kind](h, hp, i, lens=lens)
+                stats.append(st)
+            x = x + out
     return (["gen_token", "gen_page_table", "gen_lens"],
             decode_fetches(x, stats, S, hp, "hyb"))
 

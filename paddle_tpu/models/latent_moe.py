@@ -66,9 +66,10 @@ from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
                                        DecoderConfig, chunk_rows, data,
                                        decode_fetches, decode_inputs,
                                        decoder_layer, embed,
-                                       export_bundle, gated_ffn, last_row,
-                                       logits, matrix, op, persistable,
-                                       prefill_inputs, rms, routed_experts,
+                                       export_bundle, gated_ffn,
+                                       last_row, live_rows, logits, matrix, op,
+                                       persistable, prefill_inputs,
+                                       program_role, rms, routed_experts,
                                        train_inputs, train_loss, vector)
 from paddle_tpu.ops.mla_ops import yarn_mscale
 
@@ -337,7 +338,7 @@ def _layer(x, hp, i, pos, lens, mask=None, paged=None, select=None,
         x, hp, f"lat{i}",
         lambda h: _attention(h, hp, i, pos, mask=mask, paged=paged,
                              select=select, index_pool=index_pool),
-        lambda h: _ffn(h, hp, i, lens))
+        lambda h: _ffn(h, hp, i, lens), routed=hp.is_moe(i))
     return x, stats, select
 
 
@@ -353,6 +354,7 @@ def _pools(hp, page_len, num_pages):
         for name in paged_cache_var_names(hp)}
 
 
+@program_role("gen_chunk")
 def build_chunk_program(hp, num_slots, page_len, num_pages):
     """The prefill of ONE CHUNK of a prompt in the CURRENT program guard.
 
@@ -371,8 +373,7 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     ids, pos, mask, last = prefill_inputs()
     page_table = data("gen_page_table", [1, -1], "int32")
     pools = _pools(hp, page_len, num_pages)
-    # pad rows take no routed expert
-    lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
+    lens = live_rows(mask)
     x = embed(ids, hp, "lat")
     select = None
     for i in range(int(hp.num_hidden_layers)):
@@ -400,6 +401,7 @@ def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
     return train_loss(x, labels, hp, "lat")
 
 
+@program_role("gen_decode")
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     """The single-token decode step in the CURRENT program guard.
 
@@ -414,8 +416,7 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     S = int(num_slots)
     token, pos, page_table, lens = decode_inputs(S)
     pools = _pools(hp, page_len, num_pages)
-    x = layers.reshape(embed(token, hp, "lat"),
-                       shape=[S, 1, int(hp.hidden_size)])
+    x = embed(token, hp, "lat", lead=[S, 1])
     stats, select = [], None
     for i in range(int(hp.num_hidden_layers)):
         x, st, select = _layer(

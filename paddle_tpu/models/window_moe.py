@@ -79,10 +79,12 @@ from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
                                        DecoderConfig, chunk_rows, data,
                                        decode_fetches,
                                        decode_inputs, decoder_layer, embed,
-                                       export_bundle, gated_ffn, head_norm,
-                                       last_row, logits, matrix, mtp_logits,
-                                       mtp_module, op, persistable,
-                                       prefill_inputs, routed_experts,
+                                       export_bundle, gated_ffn, group,
+                                       head_norm, last_row, live_rows, logits,
+                                       matrix,
+                                       mtp_logits, mtp_module, mtp_scope, op,
+                                       persistable, prefill_inputs,
+                                       program_role, routed_experts,
                                        train_inputs, train_loss, vector)
 
 __all__ = ["WindowMoEConfig", "build_chunk_program",
@@ -357,7 +359,7 @@ def _layer(x, hp, i, pos, lens, chunk=None, cache=None):
         x, hp, f"win{i}",
         lambda h: (_attention(h, hp, i, pos, chunk=chunk, cache=cache),
                    None),
-        lambda h: _ffn(h, hp, i, lens))
+        lambda h: _ffn(h, hp, i, lens), routed=hp.is_moe(i))
     return x, stats
 
 
@@ -376,6 +378,7 @@ def _caches(hp, num_slots, page_len, num_pages):
     return cache
 
 
+@program_role("gen_chunk")
 def build_chunk_program(hp, num_slots, page_len, num_pages):
     """The prefill of ONE CHUNK of a prompt in the CURRENT program guard.
 
@@ -403,8 +406,7 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     slot = data("gen_slot", [1, 1], "int32")
     page_table = data("gen_page_table", [1, -1], "int32")
     cache = _caches(hp, num_slots, page_len, num_pages)
-    # pad rows take no routed expert
-    lens = layers.reshape(layers.cast(mask, "int32"), shape=[-1, 1])
+    lens = live_rows(mask)
 
     def block(x, i):
         return _layer(x, hp, i, pos, lens, chunk=(
@@ -419,14 +421,16 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     first = logits(last_row(x, last, hp), hp, "win")
     if hp.drafts:
         draft = persistable(DRAFT_VAR, [int(num_slots), 1], "int32")
-        follows = op("spec_next_ids",
-                     {"NextIds": data("gen_next_ids", [1, -1], "int32"),
-                      "Logits": first}, {"Out": "int32"})["Out"]
+        with group("head"):
+            follows = op("spec_next_ids",
+                         {"NextIds": data("gen_next_ids", [1, -1], "int32"),
+                          "Logits": first}, {"Out": "int32"})["Out"]
         g, _ = mtp_module(x, follows, hp, "win", lambda h: block(h, MTP))
-        op("spec_seed_draft",
-           {"Logits": mtp_logits(last_row(g, last, hp), hp, "win"),
-            "Last": last, "Slot": slot, "Draft": draft},
-           {"DraftOut": draft})
+        with mtp_scope(), group("head"):
+            op("spec_seed_draft",
+               {"Logits": mtp_logits(last_row(g, last, hp), hp, "win"),
+                "Last": last, "Slot": slot, "Draft": draft},
+               {"DraftOut": draft})
         feeds.append("gen_next_ids")
     return feeds, [first]
 
@@ -445,6 +449,7 @@ def window_moe_train_program(seq_len, hp: WindowMoEConfig = None):
     return train_loss(x, labels, hp, "win")
 
 
+@program_role("gen_decode")
 def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     """The single-token decode step in the CURRENT program guard.
 
@@ -464,8 +469,7 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     S = int(num_slots)
     token, pos, page_table, lens = decode_inputs(S)
     cache = _caches(hp, S, page_len, num_pages)
-    x = layers.reshape(embed(token, hp, "win"),
-                       shape=[S, 1, int(hp.hidden_size)])
+    x = embed(token, hp, "win", lead=[S, 1])
     stats = []
     for i in range(int(hp.num_hidden_layers)):
         held = (cache[i, "k"], cache[i, "v"])
@@ -501,11 +505,12 @@ def _build_draft_step(hp, num_slots, page_len, num_pages):
     token, pos, page_table, lens = decode_inputs(S)
     cache = _caches(hp, S, page_len, num_pages)
     draft = persistable(DRAFT_VAR, [S, 1], "int32")
-    rows = op("spec_rows",
-              {"Token": token, "Draft": draft, "Pos": pos, "Lens": lens,
-               "On": data("gen_spec", [S, 1], "int32")},
-              {"Ids": "int32", "RowPos": "int32", "End": "int32",
-               "RowLens": "int32"}, {"max_len": int(hp.max_len)})
+    with group("embed"):
+        rows = op("spec_rows",
+                  {"Token": token, "Draft": draft, "Pos": pos, "Lens": lens,
+                   "On": data("gen_spec", [S, 1], "int32")},
+                  {"Ids": "int32", "RowPos": "int32", "End": "int32",
+                   "RowLens": "int32"}, {"max_len": int(hp.max_len)})
 
     def block(x, i, end, row_lens):
         held = (cache[i, "k"], cache[i, "v"])
@@ -513,30 +518,33 @@ def _build_draft_step(hp, num_slots, page_len, num_pages):
                       cache=held + ((end, row_lens) if hp.is_window(i)
                                     else (page_table, end, row_lens)))
 
-    x = layers.reshape(embed(rows["Ids"], hp, "win"), shape=[S, 2, d])
+    x = embed(rows["Ids"], hp, "win", lead=[S, 2])
     stats = []
     for i in range(int(hp.num_hidden_layers)):
         x, st = block(x, i, rows["End"], rows["RowLens"])
         if st is not None:
             stats.append(st)
-    verdict = op("spec_verify",
-                 {"Logits": logits(layers.reshape(x, shape=[S * 2, d]), hp,
-                                   "win"),
-                  "Ids": rows["Ids"], "RowLens": rows["RowLens"]},
-                 {"Out": "int32", "NextIds": "int32", "MtpEnd": "int32",
-                  "MtpRowLens": "int32", "First": "float32"})
+    with group("head"):
+        verdict = op("spec_verify",
+                     {"Logits": logits(layers.reshape(x, shape=[S * 2, d]),
+                                       hp, "win"),
+                      "Ids": rows["Ids"], "RowLens": rows["RowLens"]},
+                     {"Out": "int32", "NextIds": "int32", "MtpEnd": "int32",
+                      "MtpRowLens": "int32", "First": "float32"})
     g, st = mtp_module(x, verdict["NextIds"], hp, "win",
                        lambda h: block(h, MTP, verdict["MtpEnd"],
                                        verdict["MtpRowLens"]))
     stats.append(st)
-    last = op("spec_pick_row", {"X": g, "Verdict": verdict["Out"]},
-              {"Out": hp.dtype})["Out"]
-    op("spec_draft", {"Logits": mtp_logits(last, hp, "win"), "Lens": lens,
-                      "Draft": draft}, {"DraftOut": draft})
+    with mtp_scope(), group("head"):
+        last = op("spec_pick_row", {"X": g, "Verdict": verdict["Out"]},
+                  {"Out": hp.dtype})["Out"]
+        op("spec_draft", {"Logits": mtp_logits(last, hp, "win"),
+                          "Lens": lens, "Draft": draft},
+           {"DraftOut": draft})
+    with group("head"):
+        fetched_stats = layers.concat(stats, axis=0)
     return (["gen_token", "gen_pos", "gen_page_table", "gen_lens",
-             "gen_spec"],
-            [verdict["First"], layers.concat(stats, axis=0),
-             verdict["Out"]])
+             "gen_spec"], [verdict["First"], fetched_stats, verdict["Out"]])
 
 
 def _window_section(hp):

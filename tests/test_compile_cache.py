@@ -17,9 +17,13 @@ from paddle_tpu import profiler
 
 def _fc_program():
     """A fresh (main, startup, feed name, fetch) quad — param names fixed
-    so two independently-built copies lower to identical computations."""
+    and generated names started anew, as a restarted process starts them,
+    so two independently-built copies lower to identical computations
+    under identical op scopes (the persistent cache keys an entry with
+    its ``op_name``s: ``ptop_<type>__<output>`` is part of the key)."""
+    from paddle_tpu.framework import unique_name_scope
     main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup):
+    with unique_name_scope("cc_"), fluid.program_guard(main, startup):
         x = layers.data(name="xcc", shape=[4])
         pred = layers.fc(input=x, size=3,
                          param_attr=fluid.ParamAttr(name="wcc"),

@@ -6,7 +6,6 @@ lowered HLO's ``op_name`` metadata beside the ``ptop_`` scope
 Metadata only: the computation is what it was without them."""
 
 import contextlib
-import glob
 import json
 import os
 
@@ -301,38 +300,39 @@ class TestTransformerScopes:
         assert seen == set(rules)
 
 
-def test_serving_bundle_carries_no_annotation(tmp_path):
-    """Nothing of the serving path is annotated: a generative bundle's
-    programs hold neither attribute, so every op is lowered under its
-    ``ptop_`` scope alone, as it always was: the HLO, metadata included,
-    is what it was."""
+def test_serving_bundle_carries_its_role_and_groups(tmp_path):
+    """A generative bundle's programs are annotated since PR 51: every op
+    of the exported prefill / decode model names its program's role and a
+    sublayer (``tests/test_serving_scopes.py`` has the vocabulary), no op
+    carries an ``op_role``, and the executor lowers each under that path
+    before its ``ptop_`` scope."""
     from paddle_tpu.models import gen_lm
     hp = gen_lm.GenConfig()
     gen_lm.export_gen_model(str(tmp_path), hp, num_slots=2)
-    found = 0
-    for path in glob.glob(str(tmp_path / "**" / "*"), recursive=True):
-        if os.path.isfile(path):
-            with open(path, "rb") as f:
-                body = f.read()
-            found += 1
-            assert b"op_namescope" not in body and b"op_role" not in body, \
-                path
-    assert found
+    for part, role in (("prefill", "gen_prefill"), ("decode", "gen_decode")):
+        with open(tmp_path / part / "__model__") as f:
+            ops = json.load(f)["program"]["blocks"][0]["ops"]
+        assert ops and all(
+            op["attrs"][NS].split("/")[0] == role and ROLE not in op["attrs"]
+            for op in ops), part
     decode = fluid.Program()
     with fluid.program_guard(decode, fluid.Program()):
         gen_lm.build_paged_decode_program(hp, 2, 8, 8)
     assert _ops(decode) and all(
-        profiler.op_scope_path(op) == [profiler.op_scope_name(op)]
+        profiler.op_scope_path(op)
+        == op.attr(NS).split("/") + [profiler.op_scope_name(op)]
         for blk in decode.blocks for op in blk.ops)
 
 
 def test_a_scoped_program_keeps_its_own_compile_cache_entry(tmp_path,
                                                             monkeypatch):
-    """jax's persistent-cache key strips metadata, so the scoped twin of a
-    cached program would load the twin's executable with ITS ``op_name``s
-    (seen on the chip: the parent of PR 35 reported PR 35's scope names
-    under a shared cache).  A program that carries annotations keys its
-    entry with metadata; one without them keeps the key it always had."""
+    """jax's persistent-cache key strips metadata by default, so the
+    scoped twin of a cached program would load the twin's executable with
+    ITS ``op_name``s (seen on the chip: the parent of PR 35 reported PR
+    35's scope names under a shared cache).  Every program keys its entry
+    with its metadata (``executor.py``, at import), so the twin misses
+    and then finds its own entry, and so does the program without
+    annotations."""
     from paddle_tpu.executor import disable_compile_cache
     monkeypatch.setenv("PADDLE_TPU_COMPILE_CACHE", str(tmp_path / "xla"))
     counter = profiler.runtime_metrics.counter
@@ -350,16 +350,16 @@ def test_a_scoped_program_keeps_its_own_compile_cache_entry(tmp_path,
         hits, misses = counter("compile_cache.hits"), \
             counter("compile_cache.misses")
         exe.run(main, feed=_feed(), fetch_list=[loss], scope=scope)
-        assert getattr(jax.config, flag) is False     # put back
+        assert getattr(jax.config, flag) is True      # never flipped
         return counter("compile_cache.hits") - hits, \
             counter("compile_cache.misses") - misses
 
     try:
-        # one call site: with metadata in the key the traceback is too
+        # (the key holds the op names and no call stack: any call site)
         (_, cold), plain, twin, again = [
             run_fresh(scoped) for scoped in (False, False, True, True)]
         assert cold > 0                               # fills the cache
-        assert plain[0] > 0 and plain[1] == 0         # the old key: a hit
+        assert plain[0] > 0 and plain[1] == 0         # its own entry: a hit
         assert twin[1] > 0, "the scoped twin took the unscoped executable"
         assert again[0] > 0 and again[1] == 0         # its own entry
     finally:

@@ -429,6 +429,26 @@ class TestExecutorWiring:
                     scope=scope)
             assert len(exe._opt_cache) == n + 1
 
+    def test_a_clone_is_its_own_programs_alone(self, monkeypatch):
+        """The memo is keyed by ``id(program)``, which the next
+        short-lived program can take over (a generation bundle's two load
+        programs, same feeds and fetches: the decode model then loaded
+        the prefill's parameter list and its pools were never read).  An
+        entry is a hit for the program it was made from and no other."""
+        monkeypatch.setenv("PADDLE_TPU_OPT", "1")
+        first, _, cost = self._train()
+        second, _, _ = self._train()
+        exe = fluid.Executor()
+        clone = exe._maybe_optimize(first, {}, (cost.name,))
+        assert exe._maybe_optimize(first, {}, (cost.name,)) is clone
+        (key, entry), = exe._opt_cache.items()
+        # ``second`` at ``first``'s address, as after a collection
+        stale = (id(second),) + key[1:]
+        exe._opt_cache[stale] = entry
+        other = exe._maybe_optimize(second, {}, (cost.name,))
+        assert other is not clone
+        assert exe._maybe_optimize(second, {}, (cost.name,)) is other
+
     def test_amortize_gate_interprets_startup(self, monkeypatch):
         from paddle_tpu.analysis.opt.passes import AMORTIZE_MIN_OPS
         main, startup = fluid.Program(), fluid.Program()

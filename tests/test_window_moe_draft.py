@@ -207,9 +207,11 @@ def test_the_bundle_says_that_it_drafts(predictor, cfg):
     assert "gen_next_ids" in predictor._pre_feeds
     assert predictor._dec_feeds[-1] == "gen_spec"
     assert len(predictor._dec_fetch) == 3
-    # the module's ops carry a name scope of their own
+    # the module's ops carry a name scope of their own, between the
+    # program's role and their sublayer's group
     scoped = [op.type for op in predictor._dec_prog.global_block().ops
-              if op.attrs.get("op_namescope") == "mtp"]
+              if op.attrs.get("op_namescope", "").startswith(
+                  "gen_decode/mtp/")]
     assert "paged_attention" in scoped and "moe_experts_gated" in scoped
     assert scoped.count("rms_norm") >= 5     # h, e, two sublayers, final
 
