@@ -232,6 +232,17 @@ def is_gen_bundle(model_dir):
     return os.path.isfile(os.path.join(model_dir, META_FILENAME))
 
 
+def _moe_trips(block):
+    """``ops/moe_ops.trip_rows`` of every routed-expert op of a decode
+    program, in program order (the order of its ``decode_stats`` rows)."""
+    from paddle_tpu.ops.moe_ops import trip_rows
+    return [trip_rows(int(np.prod(block.var(op.input("TopkIdx")[0]).shape)),
+                      int(block.var(op.input("X")[0]).shape[-1]),
+                      op.attr("chunk_rows"))
+            for op in block.ops
+            if op.type in ("moe_experts", "moe_experts_gated")]
+
+
 class GenPredictor:
     """Load-once handle over a generation bundle; thread-compatible (one
     internal lock serializes executor access, mirroring Predictor)."""
@@ -319,6 +330,10 @@ class GenPredictor:
             int(np.prod(block.var(n).shape[1:]))
             * jnp.dtype(str(block.var(n).dtype)).itemsize
             for n in self.state_vars)
+        # the rows a trip of each expert layer's routed product takes,
+        # in the order of the ``decode_stats`` rows: what
+        # ``gen.moe.rows_carried`` is counted from
+        self._moe_trips = _moe_trips(block) if self.decode_stats else []
         # host-side page allocator state (all mutated under _lock); the
         # table is the host's mirror of the device's, and ``_stale_rows``
         # the slots whose row the next turn's patch has to carry there
@@ -1330,6 +1345,14 @@ class GenPredictor:
             else:
                 out[col["name"]] = int(stats[:, j].sum())
                 runtime_metrics.inc(metric, out[col["name"]])
+        if len(self._moe_trips) == len(stats):
+            # how full the routed products' trips were: the sorted rows
+            # that held an assignment over the rows the trips moved
+            from paddle_tpu.ops.moe_ops import rows_carried
+            runtime_metrics.inc("gen.moe.rows_landed", int(stats[:, 0].sum()))
+            runtime_metrics.inc("gen.moe.rows_carried", sum(
+                rows_carried(n, chunk)
+                for n, chunk in zip(stats[:, 0].tolist(), self._moe_trips)))
         return out
 
     def _page_bucket(self, rows):

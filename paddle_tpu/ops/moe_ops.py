@@ -25,16 +25,23 @@ own matrices and no others (a grouped matrix product,
 ``jax.experimental.pallas.ops.tpu.megablox.gmm``: a Pallas kernel whose
 grid covers the row tiles that hold a group and reads the matrices of
 the experts that have a row).  Exact: no capacity, no token dropped; an
-expert with no row is not read.  DENSE, off the TPU, in a training
-graph (the routed form's loop to a traced bound has no reverse mode) and
-at the row counts where it was measured faster (``_RELU2_DENSE_ROWS``):
-one product over all held experts, every token through every held expert
-with the combine weight, zero where the router did not choose it,
-applied before the down product, which contracts experts and hidden
-units together.  ``routed=True`` takes the kernel in interpret mode, for
-the tests.  Which form a lowering took is counted, once per compiled
-signature: ``gen.moe.routed_lowerings`` / ``gen.moe.dense_lowerings``
-(and ``<counter>.<op>``).
+expert with no row is not read.  The row bookkeeping around the kernels
+(the gather of a trip's rows, the masks, the weights, the sum of its
+outputs into their tokens) follows the rows that LANDED on the experts
+held, rounded up to one trip (a row tile at decode sizes), not the
+``T x k`` the layer sorts: a loop of trips to a traced bound (``trip_rows``;
+``gen.moe.row_chunk.<rows>`` says which an executable took,
+``gen.moe.rows_landed`` / ``gen.moe.rows_carried`` how full they ran).
+DENSE, off the TPU, in a training graph (the routed form's loop to a
+traced bound has no reverse mode) and at the row counts where it was
+measured faster (``_RELU2_DENSE_ROWS``): one product over all held
+experts, every token through every held expert with the combine weight,
+zero where the router did not choose it, applied before the down
+product, which contracts experts and hidden units together.
+``routed=True`` takes the kernel in interpret mode, for the tests.
+Which form a lowering took is counted, once per compiled signature:
+``gen.moe.routed_lowerings`` / ``gen.moe.dense_lowerings`` (and
+``<counter>.<op>``).
 
 The executor's op scope names them ``ptop_moe_route*`` /
 ``ptop_moe_experts*`` on the device trace.
@@ -79,10 +86,32 @@ def moe_route(x, w_gate, bias, top_k, scaling=1.0, norm_topk=True,
 # stream an expert's matrix in 2 MB blocks.
 _GMM_ROW_TILE = 128
 _GMM_TILE = 1024
-# Sorted rows taken at a time: a 2048-row prompt's 16384 assignments put
-# ~512 rows on 12 of 384 experts, one chunk; all of them at once cost 8.1
-# ms a layer where the experts' read takes 1.3 (my chip run, PR 31)
+# Sorted rows a trip takes at most, in a layer that sorts more than two
+# such trips' rows (a prompt's chunk): few trips, because every trip's
+# edge splits an expert whose matrices are then read twice (50 MB = 0.06
+# ms at 4096 x 2048) and every trip pays what the compiler copies in
+# front of its kernels: a whole prompt's ~2800 landed rows at top-22
+# over 64 of 512 experts are 1.53 ms a layer in 6 trips and 1.70 in 22
+# (my chip run, PR 52).  All 16384 sorted rows of a 2048-row prompt at
+# once, for the ~512 that landed on 12 of 384 experts, cost 8.1 ms a
+# layer where the experts' read takes 1.3 (my chip run, PR 31)
 _GMM_CHUNK_ROWS = 512
+# ... and no more rows than keep a trip's float32 outputs (rows x width)
+# under this: the scatter-add of a trip's rows runs out of fast memory
+# while they fit and row by row from HBM once they do not (512 rows of
+# 7168: 0.78 ms, 256 rows 0.74, two tiles 0.065 together; 512 rows of
+# 6144 0.27, four tiles 0.11; at 4096 a 256-row trip for the ~128 rows
+# that land of a 512-row chunk took 0.5 ms off a chunk of eight layers
+# and 512 rows 0.10 a layer; at 1024 nothing to speak of; my chip runs,
+# PR 52)
+_TRIP_BYTES = 4 << 20
+# Token rows up to which a trip's weighted outputs are summed into their
+# tokens by a one-hot product on the MXU (float32 at 'highest': exact
+# but for the order of a token's additions) and not by a scatter-add: a
+# decode step's.  9-18 us a layer of the 25-45 a tile-sized trip's
+# bookkeeping takes at 16-64 rows; at 256 rows x 2048 assignments it
+# saves 8 of 207 and at 1028 rows it costs 520 more (my chip run, PR 52)
+_ONEHOT_TOKENS = 128
 
 
 def _load_stats(load):
@@ -99,12 +128,15 @@ def _tile(n, want):
     return n
 
 
-def _count_lowering(form, op):
-    """Which form a lowering took (fires at trace time, once per
-    compiled signature, as ``gen.paged.fallback`` does)."""
+def _count_lowering(form, op, chunk=None):
+    """Which form a lowering took, and the rows a trip of a routed one
+    takes (fires at trace time, once per compiled signature, as
+    ``gen.paged.fallback`` does)."""
     from paddle_tpu.profiler import runtime_metrics
     runtime_metrics.inc(f"gen.moe.{form}_lowerings")
     runtime_metrics.inc(f"gen.moe.{form}_lowerings.{op}")
+    if chunk is not None:
+        runtime_metrics.inc(f"gen.moe.row_chunk.{chunk}")
 
 
 def _held(idx, expert_offset, E, live):
@@ -139,29 +171,26 @@ def _dense_experts(x, idx, weights, up, act, down, expert_offset, live):
 # once: traced and lowered a layer at a time, thirteen executables of
 # five layers added 9 s to a warm server start (my chip run, PR 32)
 @functools.partial(jax.jit, static_argnames=(
-    "act", "expert_offset", "interpret", "chunk_rows"))
+    "act", "expert_offset", "interpret", "chunk"))
 def _routed_experts(x, idx, weights, up, act, down, expert_offset, live,
-                    interpret, chunk_rows=_GMM_CHUNK_ROWS):
+                    interpret, chunk):
     """The assignments that landed here sorted by expert, each expert's
     rows through its own matrices and no others (``megablox.gmm``): an
     expert with no row is not read.  ``up`` the [E, d, F] matrices whose
     products ``act`` joins into the hidden rows, ``down`` [E, F, d].
-    ``chunk_rows``: sorted rows a trip of the loop takes (0: all of them
-    in one: a layer that holds every expert, where every assignment
-    lands and a chunk would only read an expert again at each boundary
-    it spans)."""
+    ``chunk``: the sorted rows a trip of the loop takes
+    (:func:`trip_rows`); the trips cover the rows that landed, rounded
+    up to one of them."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     E, d = down.shape[0], x.shape[1]
     T, k = idx.shape
     local, held = _held(idx, expert_offset, E, live)
     A = T * k
-    tm = _GMM_ROW_TILE if A >= _GMM_ROW_TILE else -(-A // 16) * 16
-    chunk = min(chunk_rows or A, -(-A // tm) * tm)
-    chunk = -(-chunk // tm) * tm
+    tm = min(_GMM_ROW_TILE, chunk)
     # assignments sorted by the expert held here; the others last, in
-    # no group.  The sorted rows are taken ``chunk`` at a time and only
-    # the chunks that hold a row of a group are computed: the work
-    # follows the assignments that LANDED here, not ``T x k``
+    # no group: the trips take the sorted rows from the front and stop
+    # behind the last one that LANDED, so the work follows those and
+    # not ``T x k``
     key = jnp.where(held, local, E).reshape(A)
     # ONE sort carries each assignment's token and weight along (a
     # gather by the order, and a scatter for the loads, cost more than
@@ -194,16 +223,51 @@ def _routed_experts(x, idx, weights, up, act, down, expert_offset, live,
         rows = x[rows_of]
         h = jnp.where(in_group, act(*(product(rows, m, here) for m in up)),
                       0.0).astype(x.dtype)
-        y = jnp.where(in_group, product(h, down, here), 0.0) * w[:, None]
-        return out.at[rows_of].add(y)
+        y = jnp.where(in_group, product(h, down, here), 0.0)
+        if T > _ONEHOT_TOKENS:
+            return out.at[rows_of].add(y * w[:, None])
+        mix = jnp.where(rows_of[:, None] == jnp.arange(T), w[:, None], 0.0)
+        return out + jax.lax.dot_general(
+            mix, y, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)
 
-    # as many trips as chunks hold a row (a branch in a fixed number of
-    # trips copied the [T, d] sum every trip: 5.0 ms a layer at 2048
-    # rows).  A loop to a traced bound has no reverse mode: a training
-    # graph takes the dense form
-    out = jax.lax.fori_loop(0, -(-n_held // chunk), one_chunk,
-                            jnp.zeros((T, d), jnp.float32))
+    # as many trips as hold a row (a branch in a fixed number of trips
+    # copied the [T, d] sum every trip: 5.0 ms a layer at 2048 rows).  A
+    # loop to a traced bound has no reverse mode: a training graph takes
+    # the dense form
+    out = jax.lax.fori_loop(0, rows_carried(n_held, chunk) // chunk,
+                            one_chunk, jnp.zeros((T, d), jnp.float32))
     return out.astype(x.dtype), _load_stats(sizes)
+
+
+def trip_rows(assignments, width, chunk_rows=None):
+    """The sorted rows a trip of the routed form takes over a layer's
+    ``assignments`` (``T x k``) of ``width`` features, a multiple of the
+    grouped product's row tile.  ``chunk_rows`` None: ONE row tile, so
+    that the trips cover the rows that landed rounded up to a tile, but
+    in a layer that sorts more than two ``_GMM_CHUNK_ROWS`` (a prompt's
+    chunk; up to that many rows, tiles are few trips even when every
+    row lands) as many tiles as ``_TRIP_BYTES`` and ``_GMM_CHUNK_ROWS``
+    allow; 0: all the sorted rows in one trip (a layer that holds every
+    expert); else that many rows."""
+    tm = _GMM_ROW_TILE if assignments >= _GMM_ROW_TILE \
+        else -(-assignments // 16) * 16
+    whole = -(-assignments // tm) * tm
+    if chunk_rows is not None:
+        return min(-(-(int(chunk_rows) or whole) // tm) * tm, whole)
+    if whole <= 2 * _GMM_CHUNK_ROWS:
+        return tm
+    return max(tm, min(_GMM_CHUNK_ROWS,
+                       _TRIP_BYTES // (4 * width) // tm * tm))
+
+
+def rows_carried(landed, chunk):
+    """Sorted rows the trips of one layer gather, mask and sum when
+    ``landed`` assignments (a count, traced or not) landed on the
+    experts held: ``landed`` rounded up to a trip (the core's bound, and
+    what ``gen.moe.rows_carried`` counts)."""
+    return -(-landed // chunk) * chunk
 
 
 def _experts(op, x, idx, weights, up, act, down, expert_offset, live,
@@ -219,14 +283,14 @@ def _experts(op, x, idx, weights, up, act, down, expert_offset, live,
         interpret = _use_interpret()
     if routed is None:
         routed = not interpret and idx.shape[0] not in dense_rows
-    _count_lowering("routed" if routed else "dense", op)
-    if routed:
-        return _routed_experts(
-            x, idx, weights, up, act, down, int(expert_offset), live,
-            bool(interpret),
-            _GMM_CHUNK_ROWS if chunk_rows is None else int(chunk_rows))
-    return _dense_experts(x, idx, weights, up, act, down, expert_offset,
-                          live)
+    if not routed:
+        _count_lowering("dense", op)
+        return _dense_experts(x, idx, weights, up, act, down,
+                              expert_offset, live)
+    chunk = trip_rows(idx.shape[0] * idx.shape[1], x.shape[1], chunk_rows)
+    _count_lowering("routed", op, chunk)
+    return _routed_experts(x, idx, weights, up, act, down,
+                           int(expert_offset), live, bool(interpret), chunk)
 
 
 # Rows at which the dense ``relu2`` product beats the routed one on a

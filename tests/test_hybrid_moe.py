@@ -229,7 +229,7 @@ def test_the_routed_relu2_product_is_the_dense_one(case):
     """``moe_experts`` through the routed core (the grouped kernel in
     interpret mode) against its dense form and the plain sum over the
     assignments, with the same ``Stats``; a third of the rows are not
-    live.  40 rows x top-6 are two chunks of sorted rows."""
+    live.  40 rows x top-6 are two row tiles of sorted rows."""
     u, idx, wgt, w1, w2, offset = {
         "offset_0_decode_rows": lambda: _relu2_case(4, 0, 32),
         "a_middle_share_decode_rows": lambda: _relu2_case(4, 8, 32),

@@ -300,8 +300,9 @@ def _experts_case(T=21, d=64, F=48, E=4, all_experts=16, k=2, seed=0):
 
 @pytest.mark.parametrize("offset,T", [(0, 21), (4, 21), (12, 21), (4, 700)])
 def test_the_routed_product_is_the_dense_one(offset, T):
-    """700 rows x top-2 are three chunks of sorted rows, the last two
-    with no row of a held expert: they are skipped."""
+    """700 rows x top-2 are 1400 sorted rows: the trips are 128-row
+    tiles over the ones that landed on a held expert, no trip for the
+    rest."""
     x, idx, w, wg, wu, wd = _experts_case(T=T)
     live = jnp.arange(T) % 5 != 0
     dense, s0 = moe_ops.moe_experts_gated(x, idx, w, wg, wu, wd, offset,

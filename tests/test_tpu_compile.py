@@ -421,7 +421,7 @@ def test_routed_gated_experts_compile_for_v5e(one_chip, rows):
         sds((12, 7168, 2048), bf), sds((12, 2048, 7168), bf),
         sds((rows,), jnp.bool_)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
-    # one chunk of 512 sorted rows and its products, whatever the rows
+    # a trip's 128 or 512 sorted rows and their products, whatever the rows
     assert compiled.memory_analysis().temp_size_in_bytes < 96 << 20, \
         compiled.memory_analysis()
 
