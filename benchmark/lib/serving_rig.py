@@ -21,7 +21,7 @@ import time
 
 import numpy as np
 
-from lib import hoststat, models, served, stats
+from lib import hoststat, models, served, stats, xtrace
 
 # lead-in of a --trace 2 run's traced stretch: traffic sent with spans on
 # and the profiler still off, so that the slots are as full as in the
@@ -381,7 +381,8 @@ def traced_stretch(state, ctx, lead, start, finish, what="traced"):
     harness's main thread, where stopping is three times as fast
     (PERF.md); ``finish()`` returns the stretch's records once they have
     drained, within a bound of its own.  Returns ``(ok records, spans,
-    facts)``; a request that failed fails the stretch."""
+    facts)``; a request that failed, or was still open when ``finish``'s
+    bound passed, fails the stretch (its part: ``drain``)."""
     ptrace, part = state["ptrace"], {}
     ptrace.enable(1 << 18)
     ptrace.clear()
@@ -394,8 +395,10 @@ def traced_stretch(state, ctx, lead, start, finish, what="traced"):
         ptrace.disable()
     ok = [r for r in records if r.get("ok")]
     if len(ok) != len(records):
-        raise RuntimeError(f"{len(records) - len(ok)} of {len(records)} "
-                           f"{what} requests failed")
+        raise xtrace.TracePartFailed(
+            "drain", f"{len(records) - len(ok)} of {len(records)} {what} "
+            f"requests failed: "
+            f"{[r.get('error') for r in records if not r.get('ok')][:3]}")
     return ok, spans, _traced_facts(ctx["config"], ptrace.ts_of, ok, part)
 
 
@@ -425,6 +428,18 @@ def verify(state, ctx, raw):
                 r["request"]["prompt_len"])))
         musts.append("served_ok")
     checks["correct"] = all(checks[k] for k in musts)
+    tol = float(ctx["workload"]["logits_tol"])
+    checks["compared"] = {
+        "prefill_err_of_range": [checks.get("prefill_err_of_range"), tol],
+        "decode_err_of_range": [checks.get("decode_err_of_range"), tol],
+        **({"served_gap_of_range": [checks["served_gap_of_range"],
+                                    checks["served_limit"]]}
+           if "served_ok" in musts else {}),
+        "failed_requests": [raw["failed"], 0],
+        "misshapen_streams": [len(raw["bad_shape"]), 0],
+        "paged_fallbacks_in_window": [c["gen.paged.fallback"], 0],
+        "compiles_in_window": [c["compile.events"]
+                               + c["compile_cache.misses"], 0]}
     return checks
 
 

@@ -20,6 +20,19 @@ OP_LINE = "XLA Ops"
 MODULE_LINE = "XLA Modules"     # one event per run of an executable
 
 
+class TracePartFailed(RuntimeError):
+    """A failure of a ``--trace 2`` run's traced stretch that knows which
+    part it is, for the run's ``trace_failed`` line: ``part`` is
+    ``"drain"`` (traffic that did not end inside its bound), ``"reader"``
+    (the reduction of the trace, or one metric's reader) or ``"profiler"``
+    (the program's tracing control).  What carries no ``part`` is the
+    traffic module's own."""
+
+    def __init__(self, part, what):
+        super().__init__(f"{part}: {what}")
+        self.part = part
+
+
 def find_xplane(trace_dir):
     files = sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                              recursive=True))

@@ -18,8 +18,10 @@ metric).  There is no ``if workload == ...`` here.
 
 The LAST stdout line is the contract's JSON object and nothing else; what
 else is worth seeing goes on earlier lines (one JSON object each) and into
-``benchmark/out/``.  Without a TPU (or with fewer chips than the cell asks
-for) the run exits non-zero and prints no result line.
+``benchmark/out/``.  The numbers ``correct`` was decided from come last in
+that object (``compared``: each reading beside its limit) and are the last
+lines on standard error.  Without a TPU (or with fewer chips than the cell
+asks for) the run exits non-zero and prints no result line.
 """
 
 from __future__ import annotations
@@ -115,12 +117,20 @@ class DeviceTracer:
         self._on = False
         self.costs = {}     # seconds the control's own calls took
 
+    def _control(self, call, *args, **kwargs):
+        """One call of the program's tracing control; its failure is the
+        profiler's, whoever made the call."""
+        try:
+            return call(*args, **kwargs)
+        except Exception as e:
+            raise _xtrace.TracePartFailed("profiler", repr(e)) from e
+
     def start(self):
         if not self.enabled or self.t_start is not None:
             return
         from paddle_tpu import profiler
         t0 = time.perf_counter()
-        profiler.start_profiler(profile_path=self.trace_dir)
+        self._control(profiler.start_profiler, profile_path=self.trace_dir)
         self._on = True
         self.t_start = time.perf_counter()
         self.costs["start_s"] = self.t_start - t0
@@ -131,7 +141,7 @@ class DeviceTracer:
         from paddle_tpu import profiler
         self.t_stop = time.perf_counter()
         self._on = False
-        self.session = profiler.stop_profiler()
+        self.session = self._control(profiler.stop_profiler)
         self.costs["stop_s"] = time.perf_counter() - self.t_stop
 
     def warm(self):
@@ -139,9 +149,10 @@ class DeviceTracer:
         so that the cost of the first start falls into no number."""
         from paddle_tpu import profiler
         t0 = time.perf_counter()
-        profiler.start_profiler(profile_path=self.trace_dir + ".warm")
+        self._control(profiler.start_profiler,
+                      profile_path=self.trace_dir + ".warm")
         t1 = time.perf_counter()
-        profiler.stop_profiler()
+        self._control(profiler.stop_profiler)
         self.costs.update(first_start_s=t1 - t0,
                           first_stop_s=time.perf_counter() - t1)
         shutil.rmtree(self.trace_dir + ".warm", ignore_errors=True)
@@ -183,7 +194,8 @@ def memory_peak_bytes(devices, program_peak=None):
 
 def read_layer_metrics(entries, run):
     """Each per-layer metric's own reader; one that finds nothing to read
-    returns None and the metric is left out of the line."""
+    returns None and the metric is left out of the line.  A reader that
+    raises is named in what is raised (part ``reader``)."""
     from lib import readers
     out = {}
     for m in entries:
@@ -197,12 +209,17 @@ def read_layer_metrics(entries, run):
         if "like" in spec:
             with open(os.path.join(folder, reads_as + ".json")) as f:
                 spec = {**json.load(f), **spec}
-        if os.path.exists(os.path.join(folder, reads_as + ".py")):
-            reader = load_module(os.path.join(folder, reads_as + ".py"),
-                                 "layer_metric_" + m["name"].replace(".", "_"))
-            value = reader.read(run, spec)
-        else:
-            value = readers.generic(run, spec)
+        try:
+            if os.path.exists(os.path.join(folder, reads_as + ".py")):
+                reader = load_module(
+                    os.path.join(folder, reads_as + ".py"),
+                    "layer_metric_" + m["name"].replace(".", "_"))
+                value = reader.read(run, spec)
+            else:
+                value = readers.generic(run, spec)
+        except Exception as e:
+            raise _xtrace.TracePartFailed(
+                "reader", f"{m['name']}: {e!r}") from e
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
@@ -352,7 +369,8 @@ def run_cell(cell, seed, seconds, trace, rehearsal=None):
         except Exception as e:      # the measured numbers outlive it
             if trace == 1:
                 raise
-            say("trace_failed", where="reduce", error=repr(e))
+            say("trace_failed", part=getattr(e, "part", "reader"),
+                where="reduce", error=repr(e))
     if trace == 2:
         shutil.rmtree(tracer.trace_dir, ignore_errors=True)
         say("trace_phase", trace_phase_s=time.perf_counter() - t_phase,
@@ -364,6 +382,9 @@ def run_cell(cell, seed, seconds, trace, rehearsal=None):
         result["rehearsal"] = True
     say("verdict", **verdict)
     say("observed", setup_s=setup_s, **raw.get("observed", {}))
+    # last of the line: every number ``correct`` was decided from, as
+    # ``name: [reading, limit]`` (a limit of null: reported, not held)
+    result["compared"] = verdict.get("compared", {})
     return result
 
 
@@ -376,13 +397,15 @@ def traced_stretch(traffic, state, ctx, tracer):
     and returns ``spans`` and ``facts`` of that stretch.  It stays on
     this, the main thread: ``jax.profiler.stop_trace`` takes three times
     as long from any other (PERF.md), so a traffic module bounds every
-    wait of its ``traced`` itself.  A failure in here is reported and
-    loses only the per-layer metrics."""
+    wait of its ``traced`` itself.  A failure in here is reported with
+    the part that failed (``lib.xtrace.TracePartFailed``) and loses only
+    the per-layer metrics."""
     try:
         tracer.warm()
         return traffic.traced(state, ctx)
     except Exception as e:
-        say("trace_failed", where="traced stretch", error=repr(e))
+        say("trace_failed", part=getattr(e, "part", "traffic"),
+            where="traced stretch", error=repr(e))
         return None
     finally:
         tracer.stop()
@@ -429,6 +452,10 @@ def main(argv=None):
         print(f"benchmark: {e}", file=sys.stderr)
         return 1
     sys.stdout.flush()
+    for name, (reading, limit) in result["compared"].items():
+        print(f"compared {name}: {reading!r} limit {limit!r}",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0
 

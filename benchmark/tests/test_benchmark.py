@@ -568,8 +568,12 @@ def test_serve_closed_loop_rehearsal(trace, window_watch, capsys):
     notes = [json.loads(l) for l in capsys.readouterr().out.splitlines()
              if l.startswith("{")]
     seen = next(n for n in notes if n["note"] == "observed")
-    # every client had a stream all through: the 4 slots full
-    assert seen["slot_occupancy_mean"] > 3 and seen["gap_p50_ms"] > 0
+    # several clients' streams at once: a loop of ONE client cannot pass 1.
+    # (No higher: on the CPU a toy's stream of 2-24 tokens of sub-ms steps
+    # lives about as long as its client's next request takes to be sent
+    # and admitted, so the mean reads 2.2-2.7 of 4 since PR 41.)
+    assert seen["slot_occupancy_mean"] > 1.5
+    assert seen["clients"] == 4 and seen["gap_p50_ms"] > 0
     for name in ("paged_attn_roofline.saturated", "idle_named_share.saturated",
                  "decode_step_hbm_roofline.saturated",
                  "decode_step_device_ms.saturated"):
@@ -633,18 +637,28 @@ def test_a_fault_seen_only_with_several_live_slots_is_not_correct(
     what the window served does not."""
     import numpy as np
     from paddle_tpu.gen.predictor import GenPredictor
-    real = GenPredictor.decode_step
+    # the scheduler's decode turn since PR 41: dispatched, then read one
+    # turn later (the blocking ``decode_step`` is the set-up check's)
+    dispatch, read_turn = GenPredictor.dispatch_turn, GenPredictor.read_turn
+    live_of = {}
 
-    def mixed_up(self, tokens, positions, *args, lens=None, **kwargs):
-        logits = np.asarray(real(self, tokens, positions, *args, lens=lens,
-                                 **kwargs))
-        live = np.flatnonzero(np.asarray(lens).reshape(-1) > 0)
-        if len(live) > 1:       # every live slot gets its neighbour's row
-            logits = logits.copy()
-            logits[live] = logits[np.roll(live, 1)]
-        return logits
+    def remember(self, tokens, positions, lens):
+        read = dispatch(self, tokens, positions, lens)
+        live_of[id(read)] = (read, np.flatnonzero(
+            np.asarray(lens).reshape(-1) > 0))
+        return read
 
-    monkeypatch.setattr(GenPredictor, "decode_step", mixed_up)
+    def mixed_up(self, read):
+        ids, counts = read_turn(self, read)
+        _, live = live_of.pop(id(read), (None, ()))
+        if len(live) > 1:       # every live slot gets its neighbour's token
+            ids = list(ids)
+            for slot, token in zip(live, [ids[i] for i in np.roll(live, 1)]):
+                ids[slot] = token
+        return ids, counts
+
+    monkeypatch.setattr(GenPredictor, "dispatch_turn", remember)
+    monkeypatch.setattr(GenPredictor, "read_turn", mixed_up)
     seen = []
     real_check = served.check
     monkeypatch.setattr(served, "check", lambda *a, **k: seen.append(

@@ -204,9 +204,13 @@ def test_the_new_entries_and_their_cells():
         good = manifest.validate(json.load(f))
     closed = [w["name"] for w in good["workloads"]
               if w["traffic"].endswith("_saturated")]
-    assert len(closed) == 8
+    # membership, not count: the eight closed-loop cells PR 51 gave the
+    # nine to; a later cell joins them behind
+    eight = closed[:8]
+    assert eight[0] == "genlm_opt6.7b.decode_saturated" \
+        and eight[-1] == "solar_open2_250b.longgen_saturated"
     by_name = {m["name"]: m for m in good["per_layer"]}
-    sparse = [c for c in closed if not c.startswith("genlm_")]
+    sparse = [c for c in eight if not c.startswith("genlm_")]
     mixers = ["nemotron3_super_ep8.decode_saturated",
               "solar_open2_250b.longgen_saturated"]
     cells = {"decode_mixer_device_ms": mixers,
@@ -214,7 +218,9 @@ def test_the_new_entries_and_their_cells():
              "decode_experts_glue_device_ms": sparse}
     for name in NINE:
         entry = by_name[name]
-        assert entry["workloads"] == cells.get(name, closed), name
+        listed = [c for c in entry["workloads"] if c in eight]
+        assert listed == cells.get(name, eight), name
+        assert set(entry["workloads"]) <= set(closed), name
         assert entry["better"] == "lower"
         assert entry["source"] == "device_trace"
         assert entry["layer"] == "Lowerings + kernels"
@@ -222,8 +228,9 @@ def test_the_new_entries_and_their_cells():
         assert entry["moves"] == ("gap_p99_ms" if name
                                   == "admission_run_device_ms"
                                   else "saturated_tokens_per_s")
-    # appended: the accepted entries come first, in their order
-    assert [m["name"] for m in good["per_layer"]][-9:] == [
+    # the nine are there, in the order they were accepted in (where in
+    # the list is a later PR's business)
+    assert [m["name"] for m in good["per_layer"] if m["name"] in NINE] == [
         "admission_device_share", "admission_run_device_ms"] + GROUPS[:4] \
         + ["decode_head_device_ms", "decode_other_device_ms",
            "decode_experts_glue_device_ms"]

@@ -57,7 +57,8 @@ def test_the_cell_is_in_the_manifest_with_its_metrics(good):
                                  "n_routed_experts", "vocab_size"]
     assert sum(w["name"] == CELL for w in good["workloads"]) == 1
     mine = {m["name"] for m in manifest.metrics_of(good, "per_layer", CELL)}
-    assert mine == set(NEW_METRICS + SHARED_METRICS)
+    # at least these: a later tracing PR gives the cell more
+    assert set(NEW_METRICS + SHARED_METRICS) <= mine
     # a sparse read would pass what these two count as the least time
     assert not {"mla_decode_roofline", "paged_attn_roofline.saturated"} & mine
     assert {m["name"] for m in manifest.metrics_of(good, "end_to_end", CELL)} \

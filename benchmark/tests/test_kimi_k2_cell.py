@@ -47,8 +47,9 @@ def test_the_cell_is_in_the_manifest_with_its_metrics(good):
     assert entry["chips"] == 1 and config["name"] == "kimi_k2.6_text"
     assert config["reduced"] == ["num_hidden_layers", "n_routed_experts",
                                  "vocab_size"]
-    assert good["workloads"][-1]["name"] == CELL
-    assert good["configs"][-1]["name"] == "kimi_k2.6_text"
+    # (by name, not by place: later cells go behind this one)
+    assert sum(w["name"] == CELL for w in good["workloads"]) == 1
+    assert sum(c["name"] == "kimi_k2.6_text" for c in good["configs"]) == 1
     assert sum(w["chips"] == 4 for w in good["workloads"]) == 1
     mine = {m["name"] for m in manifest.metrics_of(good, "per_layer", CELL)}
     assert set(NEW_METRICS + SHARED_METRICS) <= mine
@@ -208,9 +209,11 @@ def test_the_cell_rehearses_through_the_serving_rig(trace, capsys):
     assert {"saturated_tokens_per_s", "gap_p99_ms", "setup_s"} \
         <= set(r["metrics"])
     seen = next(n for n in notes if n["note"] == "observed")
-    # of 4 slots: the toy's streams are 2-24 tokens long, so a client is
-    # between two requests for a good part of a window of fast steps
-    assert seen["slot_occupancy_mean"] > 2.5
+    # several clients' streams at once: a loop of ONE client cannot pass 1.
+    # (No higher: on the CPU a toy's stream of 2-24 tokens of sub-ms steps
+    # lives about as long as its client's next request takes to be sent
+    # and admitted, so the mean reads 2.2-2.7 of 4 since PR 41.)
+    assert seen["slot_occupancy_mean"] > 1.5
     assert next(n for n in notes if n["note"] == "served")["served_ok"]
     for name in NEW_METRICS + ["moe_experts_roofline", "moe_device_share",
                                "paged_attn_roofline.saturated"]:

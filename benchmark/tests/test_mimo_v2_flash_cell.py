@@ -60,14 +60,16 @@ def test_the_cell_is_in_the_manifest_with_its_metrics(good):
     assert sum(w["name"] == CELL for w in good["workloads"]) == 1
     assert sum(c["name"] == "mimo_v2_flash" for c in good["configs"]) == 1
     mine = {m["name"] for m in manifest.metrics_of(good, "per_layer", CELL)}
-    assert mine == set(NEW_METRICS + SHARED_METRICS)
+    # at least these: a later tracing PR gives the cell more
+    assert set(NEW_METRICS + SHARED_METRICS) <= mine
     # latent attention and a learned selection are other models'
     assert not {m for m in mine if m.startswith(("mla_", "dsa_", "ssm_"))}
     assert {m["name"] for m in manifest.metrics_of(good, "end_to_end", CELL)} \
         == {"saturated_tokens_per_s", "gap_p99_ms", "setup_s"}
     for name in NEW_METRICS:
         entry = next(m for m in good["per_layer"] if m["name"] == name)
-        assert entry["workloads"] == [CELL] and entry["unit"] == "%"
+        # (this cell first: a later model with windows lists itself behind)
+        assert entry["workloads"][0] == CELL and entry["unit"] == "%"
         assert entry["moves"] == "saturated_tokens_per_s"
         assert os.path.exists(os.path.join(BENCH, "layer_metrics",
                                            name + ".json"))

@@ -58,7 +58,8 @@ def test_the_cell_is_in_the_manifest_with_its_metrics(good):
     assert len(good["workloads"]) >= 8
     assert sum(w["chips"] == 4 for w in good["workloads"]) == 1
     mine = {m["name"] for m in manifest.metrics_of(good, "per_layer", CELL)}
-    assert mine == set(SHARED_METRICS + [NEW_METRIC])
+    # at least these: a later tracing PR gives the cell more
+    assert set(SHARED_METRICS + [NEW_METRIC]) <= mine
     assert next(m for m in good["per_layer"]
                 if m["name"] == NEW_METRIC)["workloads"] == [CELL]
     assert {m["name"] for m in manifest.metrics_of(good, "end_to_end", CELL)} \
@@ -226,9 +227,11 @@ def test_the_cell_rehearses_through_the_serving_rig(trace, capsys):
                  "paged_attn_roofline.saturated"):
         assert name not in r["metrics"]     # no device trace on the CPU
     if trace:
-        # the published loop: 5 forwards a block of 4 tokens, fewer where
-        # a prompt's tail opened the block or a stream ended inside one
-        assert 1.0 < r["metrics"][NEW_METRIC]["value"] <= 1.3
+        # 4 forwards a block of 4 tokens since the store pass rides the
+        # next block's first pass (PR 34; the published loop's 5 read
+        # 1.25), a little off where a prompt's tail opened a block or a
+        # stream ended inside one
+        assert 0.9 <= r["metrics"][NEW_METRIC]["value"] <= 1.05
         # every expert is held: 4 slots x 4 rows x top-2 over 8 experts
         assert 1.0 <= r["metrics"]["moe_tokens_per_expert"]["value"] <= 8.0
         assert {"decode_step_p50_ms.saturated", "prefill_p50_ms.saturated",

@@ -59,16 +59,17 @@ def test_the_cell_is_in_the_manifest_with_its_metrics(good):
                                  "vocab_size"]
     assert sum(w["name"] == CELL for w in good["workloads"]) == 1
     assert sum(c["name"] == "solar_open2_250b" for c in good["configs"]) == 1
-    assert good["workloads"][-1]["name"] == CELL        # appended
     mine = {m["name"] for m in manifest.metrics_of(good, "per_layer", CELL)}
-    assert mine == set(NEW_METRICS + SHARED_METRICS)
+    # at least these: a later tracing PR gives the cell more
+    assert set(NEW_METRICS + SHARED_METRICS) <= mine
     # other models' mechanisms
     assert not {m for m in mine if m.startswith(
         ("mla_", "dsa_", "ssm_", "window_", "spec_", "mtp_"))}
     assert {m["name"] for m in manifest.metrics_of(good, "end_to_end", CELL)} \
         == {"saturated_tokens_per_s", "gap_p99_ms", "setup_s"}
-    # the three new entries close the list, and list this cell alone
-    assert [m["name"] for m in good["per_layer"][-3:]] == NEW_METRICS
+    # the three new entries, in their order, list this cell alone
+    assert [m["name"] for m in good["per_layer"]
+            if m["name"] in NEW_METRICS] == NEW_METRICS
     for name, moves in zip(NEW_METRICS, ("saturated_tokens_per_s",
                                          "gap_p99_ms",
                                          "saturated_tokens_per_s")):

@@ -123,6 +123,23 @@ def test_an_event_made_from_several_ops_counts_once_by_its_main_path():
         pytest.approx(3e-3)
 
 
+def test_the_steps_share_of_the_peak_is_over_the_groups_step():
+    """``train_step_mfu`` (PR 55) divides by the busy time the six parts
+    and the remainder add up to: the step the groups split is the step
+    whose share of the peak is claimed."""
+    planes = {"/device:TPU:0": (_leaves(), []),
+              "/device:TPU:1": (_leaves(2.0), [])}
+    run = _run(planes, busy_ms=66 * 1.5)
+    run["facts"].update(train_flops_per_token=1e6,
+                        tokens_per_step_per_chip=65536)
+    run["peaks"] = {"bf16_flops_per_s": 197e12}
+    step_ms = sum(_read(n, run) for n in PARTS + ["train_other_device_ms"])
+    assert _read("train_step_mfu", run) == pytest.approx(
+        100 * 1e6 * 65536 / (197e12 * step_ms / 1e3))
+    del run["facts"]["train_flops_per_token"]
+    assert _read("train_step_mfu", run) is None
+
+
 def test_no_recomputed_instruction_reads_zero_not_nothing():
     leaves = [ev for ev in _leaves() if ".remat" not in ev[2]]
     run = _run({"/device:TPU:0": (leaves, [])}, busy_ms=60, steps=1)
@@ -196,9 +213,10 @@ def test_the_new_entries_and_their_cells():
         assert by_name[name]["unit"] == "ms"
     assert by_name[SPANS[3]]["workloads"] == \
         ["transformer_base.train_dp4_b1024_s256"]
-    # appended: the accepted entries come first, in their order
+    # in the order they were accepted in (where in the list is a later
+    # PR's business)
     names = [m["name"] for m in good["per_layer"]]
-    assert names[-12:] == DEVICE + SPANS
+    assert [n for n in names if n in DEVICE + SPANS] == DEVICE + SPANS
     with open(os.path.join(BENCH, "layer_metrics",
                            "train_other_device_ms.json")) as f:
         assert json.load(f)["minus"] == PARTS

@@ -36,9 +36,24 @@ sys.path.insert(0, os.path.dirname(HERE))
 from lib import closedloop, serving_rig as rig, stats  # noqa: E402
 from lib.serving_rig import LEAD_S, close, verify  # noqa: E402,F401
 
-# how long the traced stretch's running streams may take to end once the
-# profiler has stopped (the longest output is ~17 s of steps)
+# the least the traced stretch's running streams are given to end once
+# the profiler has stopped; ``traced_drain_s`` follows the cell from there
 TRACED_DRAIN_S = 40.0
+
+
+def traced_drain_s(wl, gap_p50_ms):
+    """How long the traced stretch's streams may take to end: a stream
+    that a client sent just before the clients stop runs the workload's
+    output cap of turns, so the bound is one and a half times that cap
+    at the measured window's median token gap (the clients go on sending
+    while the profiler stops, so the drain's clock starts behind it),
+    at least ``TRACED_DRAIN_S`` (which also holds where tokens come in
+    batches and the median gap reads near 0) and never more than the
+    file's ``drain_timeout_s``, which bounds the measured window's own
+    drain."""
+    longest = 1.5 * wl["output"]["cap"] * (gap_p50_ms or 0.0) / 1e3
+    return min(max(TRACED_DRAIN_S, longest),
+               float(wl.get("drain_timeout_s", 120)))
 
 
 def warm_requests(wl, sv, page_buckets):
@@ -96,6 +111,7 @@ def window(state, ctx):
                          if gaps else None}
     raw["spans"] = []
     raw["observed"]["clients"] = wl["clients"]
+    state["window_gap_p50_ms"] = raw["observed"]["gap_p50_ms"]
     return raw
 
 
@@ -110,13 +126,15 @@ def traced(state, ctx):
     loop = closedloop.ClosedLoop(state["sample"],
                                  rig.sender(state, ctx, "trace"),
                                  wl["clients"])
+    drain_s = traced_drain_s(wl, state["window_gap_p50_ms"])
     ok, spans, facts = rig.traced_stretch(
-        state, ctx, LEAD_S, loop.start, lambda: loop.drain(TRACED_DRAIN_S))
+        state, ctx, LEAD_S, loop.start, lambda: loop.drain(drain_s))
     mine = [r["times"] for r in ok]
     w_start, w_times = state["window_times"]
     return {"spans": spans, "facts": facts,
             "observed": {
                 "traced_requests": len(ok), "lead_s": LEAD_S,
+                "traced_drain_bound_s": drain_s,
                 "lead_gap_p50_ms": rig.gap_p50_ms(mine, loop.t_start, 0,
                                                   LEAD_S),
                 "traced_gap_p50_ms": rig.gap_p50_ms(mine, loop.t_start,
