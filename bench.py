@@ -96,8 +96,9 @@ def main():
     on_tpu = any(d.platform != "cpu" for d in jax.devices())
     hp = T.ModelHyperParams()
     if on_tpu:
-        # operating-point overrides (long-context runs: S >= 512 takes
-        # the in-model flash path per BENCH_ATTENTION.md's crossover)
+        # operating-point overrides (the model takes the fused attention
+        # op wherever the packed kernels admit the shape, S 256 included:
+        # BENCH_ATTENTION.md)
         batch = int(os.environ.get("PADDLE_TPU_BENCH_BATCH", "256"))
         seq = int(os.environ.get("PADDLE_TPU_BENCH_SEQ", "256"))
         hp.max_length = max(hp.max_length, seq)

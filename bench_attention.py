@@ -12,9 +12,10 @@ Paths (``ops/attention_ops.py``):
                  (single-pass up to S 1024, streaming flash above);
   * ``composed`` transposes + plain XLA (``_reference_attention``).
 
-Shapes: Transformer-base heads (H 8, D 64), bf16, 32k tokens at S 256 /
-512 / 1024 (the long training cell's B32 x S1024 and the short cells'
-B256 x S256 among them), each causal and not; S 2048 / 4096 for the
+Shapes: Transformer-base heads (H 8, D 64), bf16, 32k tokens at S 512 /
+1024 and 64k at S 128 / 256 (the long training cell's B32 x S1024 and the
+short cells' B256 x S256 among them; B512 x S128 is the row that set
+``attention_packed.MIN_S``), each causal and not; S 2048 / 4096 for the
 streaming kernels.  DEVICE time per iteration, read from an xplane trace
 of one jitted ``lax.scan`` of ITERS grad steps under ``jax.named_scope``
 (``profiler.measure_device_seconds``), median of the trials.
@@ -39,7 +40,7 @@ import numpy as np
 
 ITERS = 10
 HEADS, DIM = 8, 64
-SHAPES = ((32, 1024), (64, 512), (256, 256))       # (B, S)
+SHAPES = ((32, 1024), (64, 512), (256, 256), (512, 128))       # (B, S)
 LONG_SHAPES = ((16, 2048), (8, 4096))              # streaming kernels
 PATHS = ("packed", "bhsd", "composed")
 
@@ -181,11 +182,15 @@ def write_markdown(rows):
                   for r in tuned]
     lines += [
         "",
-        "`packed` exists up to S 1024 (`attention_packed.MAX_S`); above it "
-        "the op unpacks and `bhsd` is the streaming flash kernels.  The "
-        "model takes the fused op from `PADDLE_TPU_FLASH_MIN_S` (512) up "
-        "and the composed path below it; `PERF.md` has the in-model "
-        "numbers.",
+        "`packed` exists from S 128 (`attention_packed.MIN_S`: the "
+        "shortest row above, where it beats `composed`) up to S 1024 "
+        "(`attention_packed.MAX_S`); above it the op unpacks and `bhsd` is "
+        "the streaming flash kernels.  The model "
+        "(`models.transformer.multi_head_attention`) takes the fused op "
+        "wherever `attention_packed.plan` admits the shapes (equal "
+        "lengths, a multiple of 128 in that range, head width 32 / 64 / "
+        "128) or the keys are 512 or longer, and the composed path "
+        "elsewhere; `PERF.md` has the in-model numbers.",
     ]
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "BENCH_ATTENTION.md"), "w") as f:

@@ -130,19 +130,22 @@ def multi_head_attention(queries, keys, values, d_key, d_value, d_model,
                       bias_attr=False, param_attr=pa("v"))
     scale = float(d_key) ** -0.5
 
-    # The fused op wins once the [S,S] score tensor dominates HBM traffic;
-    # the threshold is a knob (PADDLE_TPU_FLASH_MIN_S, default 512).  From
-    # it up, the op takes the projections' [B, S, H*D] as they are (the
-    # packed kernels, ops/attention_packed.py): no head transposes, which
-    # cost the long cell ~13 ms a step under `proj` while the op took
-    # [B,H,S,D] (PERF.md section 6, PR 35 and PR 39).  Below it the
-    # composed path stays: at S=256 the [S,S] round trip is cheap and XLA
-    # folds the transposes into the projection matmuls.  BENCH_ATTENTION.md
-    # has one module alone on the three paths; whether the packed kernels
-    # move the gate is PERF.md section 7's open question.
-    import os
-    flash_min_s = int(os.environ.get("PADDLE_TPU_FLASH_MIN_S", "512"))
-    use_flash = use_flash and (k.shape[1] >= flash_min_s)
+    # Which lowering the core gets is read off the operands' shapes: the
+    # fused op where the packed kernels take the projections' [B, S, H*D]
+    # as they are (``attention_packed.plan``: equal lengths, a multiple of
+    # 128 from its measured floor ``MIN_S`` to ``MAX_S``, head width 32 /
+    # 64 / 128; no head transposes, no [B,H,S,S] tensor in HBM), or where
+    # the keys are 512 or longer (the rule the [B,H,S,D] single-pass and
+    # streaming kernels were measured under: unequal lengths, S > 1024).
+    # Elsewhere the composed ops stay: short or odd lengths and narrow
+    # heads, where XLA folds the transposes into the projection matmuls
+    # and the [S,S] round trip is cheap.  BENCH_ATTENTION.md has one
+    # module alone on the three paths.
+    from paddle_tpu.ops import attention_packed
+    use_flash = use_flash and (
+        attention_packed.plan(tuple(q.shape), tuple(k.shape),
+                              tuple(v.shape), n_head, causal) is not None
+        or k.shape[1] >= 512)
     # sequence/context parallelism: shard S over the mesh 'seq' axis and
     # attend with the ppermute ring (parallel/ring_attention.py); only for
     # self-attention (q and k share the sequence sharding)

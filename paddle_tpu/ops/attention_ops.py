@@ -30,9 +30,17 @@ module forward + backward, bf16, H8 x D64, operands and result in the
 projections' layout so the ``[B, H, S, D]`` paths pay their transposes;
 my chip run, PR 39): B32 x S1024 packed 2.79 ms (causal 2.13), the
 ``[B, H, S, D]`` kernels 4.08, composed XLA 10.19; B64 x S512 1.51 /
-2.90 / 5.20; B256 x S256 1.89 / 6.30 / 5.56.  The model's gate
-(``PADDLE_TPU_FLASH_MIN_S``, default 512, ``models/transformer.py``)
-predates the packed kernels: ``PERF.md`` section 7.
+2.90 / 5.20; B256 x S256 1.89 / 6.30 / 5.56.  The model
+(``models.transformer.multi_head_attention``) builds this op wherever
+``attention_packed.plan`` admits the shapes or the keys are 512 or longer,
+and the composed ops elsewhere: no name in the environment chooses.
+
+On a mesh (``ctx.aux["mesh"]``, the ``ParallelExecutor``'s) every kernel
+call runs PER SHARD of the batch over the ``data`` axis (``_per_shard``):
+the partitioner has no rule for a ``tpu_custom_call`` (Mosaic refuses to
+lower one it would have to split), and a replicated call would hand each
+chip the gathered global batch.  A mesh the batch does not fit
+(``_kernels_fit``) takes the plain-XLA reference, counted as a fallback.
 
 Masking model (matches the transformer workloads):
   * ``k_mask`` [B, S_k] with 1 = attend / 0 = padding, optional;
@@ -436,20 +444,31 @@ def _smalls_attention_bwd(q, k, v, k_mask, o, res, g, causal, scale, G,
     return unflat(dq, D_k), unflat(dk, D_k), unflat(dv, D_v)
 
 
+def _kernel_admits(S_q, S_k, interpret):
+    """Whether the ``[B, H, S, D]`` kernels take these lengths: the
+    single-pass pair (any batch: a group of one always tiles) or blocks
+    the streaming kernels can walk.  The batch plays no part, so a shard
+    of it is judged as the whole is."""
+    if S_q == S_k and _smalls_group(1, S_q) is not None:
+        return True
+    return None not in _flash_blocks(S_q, S_k, interpret)
+
+
 def _pallas_attention(q, k, v, k_mask, causal, scale, interpret=False):
     """Returns (out, res); res [B,H,S_q,2] packs the softmax running max
-    and log-denominator, the residual consumed by the flash backward."""
+    and log-denominator, the residual consumed by the flash backward.
+    None where ``_kernel_admits`` refuses the lengths."""
     B, H, S_q, D_k = q.shape
     S_k = k.shape[2]
     D_v = v.shape[3]
+    if not _kernel_admits(S_q, S_k, interpret):
+        return None
     if S_q == S_k:
         G = _smalls_group(B * H, S_q)
         if G is not None:
             return _smalls_attention(q, k, v, k_mask, causal, scale, G,
                                      interpret)
     block_q, block_k = _flash_blocks(S_q, S_k, interpret)
-    if block_q is None or block_k is None:
-        return None
     grid = (B, H, S_q // block_q, S_k // block_k)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                scale=scale, block_q=block_q,
@@ -620,39 +639,119 @@ def _packed_blocks(q, k, v, n_head, causal):
     return attention_packed.plan(q.shape, k.shape, v.shape, n_head, causal)
 
 
+def _kernels_fit(mesh, batch, batch_axis=0):
+    """Whether a kernel can run in a step jitted over ``mesh``.  Mosaic
+    refuses a kernel the partitioner would have to split, so on more than
+    one device every call sits in a ``shard_map`` over the WHOLE mesh
+    (``_per_shard``), which cuts the batch over ``data``.  That fits where
+    ``data`` is the only populated axis and cuts the operands' leading
+    dimension evenly.  Elsewhere (``model`` / ``seq`` / ``pipe``
+    populated: a kernel would need the features or the rows regathered;
+    a batch the axis does not divide, or an executor that shards another
+    dimension than the first: it would need the GLOBAL batch on every
+    chip) the caller takes ``_reference_attention``, which the
+    partitioner can split any way, and counts
+    ``attention.flash_fallback``."""
+    from paddle_tpu.parallel.mesh import DATA_AXIS
+    if mesh is None or mesh.size == 1:
+        return True
+    n = mesh.shape.get(DATA_AXIS, 1)
+    return n == mesh.size and batch_axis == 0 and batch % n == 0
+
+
+def _per_shard(kernel, mesh, *arrays):
+    """``kernel(*arrays)``, every array and every result cut over the
+    mesh's ``data`` axis on its leading (batch) dimension: each chip runs
+    the Pallas call on its own rows, nothing is gathered and nothing
+    reduced (attention never mixes batch rows).  With no mesh, or one of
+    a single device, this is the plain call, not a ``shard_map`` over one
+    device.  The caller has asked ``_kernels_fit``."""
+    if mesh is None or mesh.size == 1:
+        return kernel(*arrays)
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.parallel.mesh import DATA_AXIS
+    rows = P(DATA_AXIS)
+    # check_vma: a pallas_call has no rule for the varying-axes check
+    return shard_map(kernel, mesh=mesh, in_specs=rows, out_specs=rows,
+                     check_vma=False)(*arrays)
+
+
+def _kernel_forward(q, k, v, k_mask, causal, scale, n_head, mesh):
+    """``(out, residual)`` of the Pallas forward, per shard of the batch
+    (the caller has asked ``_kernels_fit``): the packed pair where
+    ``n_head`` is set (the caller has asked ``_packed_blocks``), else the
+    ``[B, H, S, D]`` kernels, None where those refuse the lengths."""
+    interpret = _use_interpret()
+    if n_head:
+        _count_packed_kernel()
+        blocks = _packed_blocks(q, k, v, n_head, causal)
+
+        def kernel(q, k, v, mask):
+            return attention_packed.attention(
+                q, k, v, mask, causal, scale, n_head, blocks,
+                interpret=interpret)
+    elif _kernel_admits(q.shape[2], k.shape[2], interpret):
+        def kernel(q, k, v, mask):
+            return _pallas_attention(q, k, v, mask, causal, scale,
+                                     interpret=interpret)
+    else:
+        return None
+    return _per_shard(kernel, mesh, q, k, v, k_mask)
+
+
+def _kernel_backward(q, k, v, k_mask, o, res, g, causal, scale, n_head,
+                     mesh):
+    """dq, dk, dv from the forward's saved output and residual, by the
+    backward of the kernels ``_kernel_forward`` ran, on the same shards."""
+    interpret = _use_interpret()
+    if n_head:
+        _count_packed_kernel()
+        blocks = _packed_blocks(q, k, v, n_head, causal)
+
+        def kernel(q, k, v, mask, o, res, g):
+            return attention_packed.attention_bwd(
+                q, k, v, mask, o, res, g, causal, scale, n_head, blocks,
+                interpret=interpret)
+    else:
+        def kernel(q, k, v, mask, o, res, g):
+            return _pallas_attention_bwd(q, k, v, mask, o, res, g, causal,
+                                         scale, interpret=interpret)
+    return _per_shard(kernel, mesh, q, k, v, k_mask, o, res, g)
+
+
 def fused_attention(q, k, v, k_mask, causal, scale, use_pallas,
-                    n_head=None):
+                    n_head=None, mesh=None):
     """Differentiable fused attention.  ``q, k, v`` are ``[B, H, S, D]``,
     or PACKED ``[B, S, H*D]`` with ``n_head`` given (the output is then
     packed too): a packed shape the packed kernels refuse is unpacked and
-    takes the ``[B, H, S, D]`` path."""
+    takes the ``[B, H, S, D]`` path.  ``mesh``: the mesh of the jitted
+    step this is traced in, if any; a kernel runs where the batch fits it
+    (``_kernels_fit``), per shard."""
+    if use_pallas and not _kernels_fit(mesh, q.shape[0]):
+        _count_flash_fallback()
+        use_pallas = False
     if q.ndim == 3 and not (
             use_pallas and _packed_blocks(q, k, v, n_head, causal)):
         return _pack_heads(_fused_attention(
             _unpack_heads(q, n_head), _unpack_heads(k, n_head),
             _unpack_heads(v, n_head), k_mask, causal, scale, use_pallas,
-            None))
+            None, mesh))
     return _fused_attention(q, k, v, k_mask, causal, scale, use_pallas,
-                            n_head)
+                            n_head, mesh)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _fused_attention(q, k, v, k_mask, causal, scale, use_pallas, n_head):
-    out, _ = _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _fused_attention(q, k, v, k_mask, causal, scale, use_pallas, n_head,
+                     mesh):
+    out, _ = _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head,
+                        mesh)
     return out
 
 
-def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head):
-    if q.ndim == 3:     # packed, and a shape the packed kernels take
-        _count_packed_kernel()
-        out, res = attention_packed.attention(
-            q, k, v, k_mask, causal, scale, n_head,
-            _packed_blocks(q, k, v, n_head, causal),
-            interpret=_use_interpret())
-        return out, (q, k, v, k_mask, out, res)
-    if use_pallas:
-        res = _pallas_attention(q, k, v, k_mask, causal, scale,
-                                interpret=_use_interpret())
+def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head, mesh):
+    if use_pallas:      # rank 3: a shape the packed kernels take
+        res = _kernel_forward(q, k, v, k_mask, causal, scale, n_head, mesh)
         if res is not None:
             out, lse = res
             return out, (q, k, v, k_mask, out, lse)
@@ -661,19 +760,11 @@ def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head):
     return out, (q, k, v, k_mask, None, None)
 
 
-def _fused_bwd(causal, scale, use_pallas, n_head, res, g):
+def _fused_bwd(causal, scale, use_pallas, n_head, mesh, res, g):
     q, k, v, k_mask, o, lse = res
-    if q.ndim == 3:
-        _count_packed_kernel()
-        dq, dk, dv = attention_packed.attention_bwd(
-            q, k, v, k_mask, o, lse, g, causal, scale, n_head,
-            _packed_blocks(q, k, v, n_head, causal),
-            interpret=_use_interpret())
-        return dq, dk, dv, None
     if lse is not None:
-        dq, dk, dv = _pallas_attention_bwd(
-            q, k, v, k_mask, o, lse, g, causal, scale,
-            interpret=_use_interpret())
+        dq, dk, dv = _kernel_backward(q, k, v, k_mask, o, lse, g, causal,
+                                      scale, n_head, mesh)
         return dq, dk, dv, None
     _, vjp_fn = jax.vjp(
         lambda q_, k_, v_: _reference_attention(q_, k_, v_, k_mask,
@@ -731,6 +822,14 @@ def _attn_operands(ctx, amp_cast=True):
     return q, k, v, k_mask, n_head
 
 
+def _step_mesh(ctx, batch):
+    """``(mesh, fit)``: the mesh of the step being lowered, as the
+    executor put it into ``ctx.aux``, and whether the kernels can run on
+    it at this batch (``_kernels_fit``)."""
+    mesh = ctx.aux.get("mesh")
+    return mesh, _kernels_fit(mesh, batch, ctx.aux.get("batch_axis", 0))
+
+
 def _attn_grad_lower(ctx: LowerContext):
     qe, ke, ve, k_mask, n_head = _attn_operands(ctx, amp_cast=False)
     causal = ctx.attr("causal", False)
@@ -747,6 +846,7 @@ def _attn_grad_lower(ctx: LowerContext):
 
     q, k, v = cast_in(qe), cast_in(ke), cast_in(ve)
     use_flash = bool(ctx.attr("use_flash", True))
+    mesh, fit = _step_mesh(ctx, q.shape[0])
 
     # if the forward saved its residuals (Out + Lse), reuse them — the
     # backward kernels run directly, no forward recompute
@@ -765,19 +865,15 @@ def _attn_grad_lower(ctx: LowerContext):
         q, k, v, g = (_unpack_heads(x, n_head) for x in (q, k, v, g))
         o = _unpack_heads(o, n_head) if saved else o
         pack = _pack_heads
-    if blocks:
-        _count_packed_kernel()
-        dq, dk, dv = attention_packed.attention_bwd(
-            q, k, v, k_mask, o, lse, g, causal, float(scale), n_head,
-            blocks, interpret=_use_interpret())
-    elif saved:
-        dq, dk, dv = _pallas_attention_bwd(
+    if saved:   # a kernel ran in the forward, so the mesh fits
+        dq, dk, dv = _kernel_backward(
             q, k, v, k_mask, o, lse, g, causal, float(scale),
-            interpret=_use_interpret())
+            n_head if blocks else None, mesh)
     else:
         _, vjp_fn = jax.vjp(
-            lambda q_, k_, v_: fused_attention(q_, k_, v_, k_mask,
-                                               causal, scale, use_flash),
+            lambda q_, k_, v_: fused_attention(
+                q_, k_, v_, k_mask, causal, scale,
+                use_flash and fit, mesh=mesh),
             q, k, v)
         dq, dk, dv = vjp_fn(g)
     for slot, val, prim in (("Q@GRAD", dq, qe), ("K@GRAD", dk, ke),
@@ -804,24 +900,17 @@ def sdpa_lower(ctx: LowerContext):
     causal = ctx.attr("causal", False)
     scale = float(ctx.attr("scale", 1.0))
     use_flash = bool(ctx.attr("use_flash", True))
+    mesh, fit = _step_mesh(ctx, q.shape[0])
     pack = (lambda x: x)
-    if n_head:
-        blocks = use_flash and _packed_blocks(q, k, v, n_head, causal)
-        if blocks:
-            _count_packed_kernel()
-            out, res = attention_packed.attention(
-                q, k, v, k_mask, causal, scale, n_head, blocks,
-                interpret=_use_interpret())
-            ctx.set_output("Out", out)
-            ctx.set_output("Lse", res)
-            return
+    if n_head and not (use_flash and fit
+                       and _packed_blocks(q, k, v, n_head, causal)):
         q, k, v = (_unpack_heads(x, n_head) for x in (q, k, v))
-        pack = _pack_heads
+        pack, n_head = _pack_heads, None
     # flash path has no attention-weight dropout; the graph builder falls
     # back to the composed path when dropout is requested in training
     if use_flash:
-        res = _pallas_attention(q, k, v, k_mask, causal, scale,
-                                interpret=_use_interpret())
+        res = _kernel_forward(q, k, v, k_mask, causal, scale, n_head,
+                              mesh) if fit else None
         if res is not None:
             out, lse = res
             ctx.set_output("Out", pack(out))
@@ -873,10 +962,11 @@ def ring_attention_lower(ctx):
 
 # ---------------------------------------------------------------------------
 # fused last-axis softmax (+ additive attention bias) — the composed-path
-# companion of the flash kernel.  Below the flash crossover (S < 512) the
-# composed XLA path wins overall, but XLA materializes an f32 score
-# temporary between the softmax reduction passes when the f32 bias add is
-# fused in (measured r5: ~13 ms/step on Transformer-base B=256 S=256).
+# companion of the flash kernel.  Where the model builds the composed ops
+# (shapes no kernel takes, attention-weight dropout) XLA materializes an
+# f32 score temporary between the softmax reduction passes when the f32
+# bias add is fused in (measured r5: ~13 ms/step on Transformer-base
+# B=256 S=256, which built the composed ops then).
 # This kernel reads the bf16 scores ONCE per pass, applies the bias and
 # the full softmax in VMEM at f32, and writes bf16 — one read + one write
 # in the forward, two reads + one write in the backward.

@@ -56,6 +56,12 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e9
 _LANES = 128
 MAX_S = 1024
+# The shortest sequence the kernels are taken for: one lane tile of keys.
+# Measured, not a knob (``bench_attention.py``, B512 x S128 x H8 x D64, one
+# module forward + backward on a v5e, my chip run, PR 56;
+# ``BENCH_ATTENTION.md``): packed 1.90 ms against 3.00 composed and 6.32
+# for the [B, H, S, D] kernels, so nothing the tiling admits is left out.
+MIN_S = 128
 # Query rows a program (a [rows, S] float32 score tile and its three
 # backward companions stay inside VMEM) and feature lanes a program (whole
 # 128-lane groups: more groups = fewer grid steps, and the mask bias
@@ -86,7 +92,8 @@ def plan(q_shape, k_shape, v_shape, n_head, causal=False):
     B, S, HD = q_shape
     if k_shape != q_shape or v_shape != q_shape:   # S_q == S_k, D_k == D_v
         return None
-    if S % _LANES or S > MAX_S or HD % n_head or HD % _LANES:
+    if S % _LANES or not MIN_S <= S <= MAX_S or HD % n_head \
+            or HD % _LANES:
         return None
     if HD // n_head not in (32, 64, 128):
         return None

@@ -683,8 +683,7 @@ def softmax_lower(ctx):
         # attention-shaped: the Pallas single-pass kernel — measured
         # SLOWER in-model than the XLA path below (138.9 vs 132.7 ms/step
         # Transformer-base r5: the custom call splits the matmul/softmax
-        # fusion clusters, the same effect that gates flash attention to
-        # S >= 512) — kept as an opt-in experiment
+        # fusion clusters) — kept as an opt-in experiment
         from paddle_tpu.ops.attention_ops import (fused_softmax,
                                                   _use_interpret)
         B, H, Sq, Sk = x.shape

@@ -1,5 +1,8 @@
 """Fused (Pallas) attention vs composed-op reference, forward and grads."""
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -14,6 +17,7 @@ import jax.numpy as jnp
 
 
 B, H, S, D = 2, 4, 32, 16
+_GOLDEN = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden")
 
 
 def _qkv(seed=0):
@@ -395,9 +399,7 @@ class TestPackedAttention:
 def _transformer_ops(seq):
     """The op list of a small Transformer + Adam built at ``seq`` (the
     attention branch depends on nothing but the key length): type,
-    attributes, input and output names; a long attribute (the causal
-    constant) by its digest."""
-    import hashlib
+    attributes, input and output names (``_op_rows``)."""
     from paddle_tpu.framework import unique_name_scope
     from paddle_tpu.models import transformer as T
     hp = T.ModelHyperParams()
@@ -412,6 +414,14 @@ def _transformer_ops(seq):
         avg_cost, _ = T.transformer(2, seq, seq, hp)
         fluid.optimizer.Adam(learning_rate=1e-3).minimize(avg_cost)
 
+    return _op_rows(main.global_block())
+
+
+def _op_rows(block):
+    """A block's ops as comparable rows: type, attributes, input and
+    output names; a long attribute (the causal constant) by its digest."""
+    import hashlib
+
     def short(v):
         r = repr(v)
         return r if len(r) <= 200 else \
@@ -421,16 +431,92 @@ def _transformer_ops(seq):
              sorted([k, short(v)] for k, v in op.attrs.items()),
              sorted([k, list(v)] for k, v in op.inputs.items()),
              sorted([k, list(v)] for k, v in op.outputs.items())]
-            for op in main.global_block().ops]
+            for op in block.ops]
+
+
+def _attention_module_ops(s_q, s_k, d_head, dropout, causal=False):
+    """The ops ONE ``multi_head_attention`` of ``128 // d_head`` heads
+    and its backward build over ``[2, s_q, 128]`` queries and
+    ``[2, s_k, 128]`` keys."""
+    from paddle_tpu.framework import unique_name_scope
+    from paddle_tpu.models import transformer as T
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 3
+    with fluid.program_guard(main, startup), unique_name_scope(""):
+        x = layers.data(name="x", shape=[2, s_q, 128],
+                        append_batch_size=False)
+        x.stop_gradient = False
+        mem = None if s_k == s_q else layers.data(
+            name="mem", shape=[2, s_k, 128], append_batch_size=False)
+        mask = None if causal else layers.data(
+            name="mask", shape=[2, s_k], append_batch_size=False)
+        out = T.multi_head_attention(x, mem, mem, d_head, d_head, 128,
+                                     n_head=128 // d_head,
+                                     dropout_rate=dropout,
+                                     k_mask=mask, causal=causal)
+        fluid.append_backward(layers.mean(out))
+    return _op_rows(main.global_block())
+
+
+# S_q, S_k, head width, attention dropout, causal, the fused op?
+_GATE_CASES = [
+    pytest.param(256, 256, 64, 0.0, False, True, id="S256-D64"),
+    pytest.param(1024, 1024, 64, 0.0, False, True, id="S1024-D64"),
+    pytest.param(128, 128, 64, 0.0, True, True, id="S128-D64-causal"),
+    pytest.param(256, 256, 32, 0.0, False, True, id="S256-D32"),
+    # keys of 512 and more keep the older rule: the op unpacks what the
+    # packed kernels refuse and takes the [B, H, S, D] ones
+    pytest.param(128, 512, 64, 0.0, False, True, id="Sq128-Sk512"),
+    pytest.param(64, 64, 64, 0.0, False, False, id="S64-D64"),
+    pytest.param(192, 192, 64, 0.0, False, False, id="S192-D64"),
+    pytest.param(256, 256, 16, 0.0, False, False, id="S256-D16"),
+    pytest.param(128, 256, 64, 0.0, False, False, id="Sq128-Sk256"),
+    pytest.param(256, 256, 64, 0.1, False, False, id="S256-D64-dropout"),
+    pytest.param(64, 64, 16, 0.0, True, False, id="S64-D16-causal"),
+]
 
 
 class TestTransformerAttentionBranches:
-    """What ``multi_head_attention`` builds on either side of the
-    ``PADDLE_TPU_FLASH_MIN_S`` gate (512)."""
+    """Which ops ``multi_head_attention`` builds: read off the operands'
+    shapes (``attention_packed.plan``, or keys of 512 and more) and the
+    attention-weight dropout, with no name in the environment."""
 
-    def test_long_sequences_hand_the_op_the_projections_layout(self):
+    @pytest.mark.parametrize("s_q,s_k,d_head,dropout,causal,fused",
+                             _GATE_CASES)
+    def test_the_gate_reads_the_shapes(self, s_q, s_k, d_head, dropout,
+                                       causal, fused, request):
         n0 = _packed_counter()
-        ops = _transformer_ops(1024)
+        got = json.loads(json.dumps(
+            _attention_module_ops(s_q, s_k, d_head, dropout, causal)))
+        assert _packed_counter() == n0      # building lowers nothing
+        types = [op[0] for op in got]
+        if fused:
+            i = types.index("scaled_dot_product_attention")
+            assert types.count("scaled_dot_product_attention") == 1
+            assert types.count("scaled_dot_product_attention_grad") == 1
+            assert dict(map(tuple, got[i][1]))["n_head"] == \
+                str(128 // d_head)
+            # packed: straight from the projections to the output's
+            assert types[i - 1] == "mul" and types[i + 1] == "mul"
+            for t in ("transpose", "transpose_grad", "softmax", "matmul",
+                      "reshape"):
+                assert t not in types, t
+            return
+        # the composed ops, op for op what the commit before this rule
+        # built (tests/golden/attention_composed_ops.json, written from
+        # PR 55's tree by this very function)
+        with open(os.path.join(_GOLDEN, "attention_composed_ops.json")) as f:
+            want = json.load(f)[request.node.callspec.id]
+        assert "scaled_dot_product_attention" not in types
+        assert types.count("softmax") == 1
+        assert len(got) == len(want)
+        for i, (a, b) in enumerate(zip(got, want)):
+            assert a == b, f"op {i}: {a} != {b}"
+
+    @pytest.mark.parametrize("seq", [256, 1024])
+    def test_the_model_hands_the_op_the_projections_layout(self, seq):
+        n0 = _packed_counter()
+        ops = _transformer_ops(seq)
         assert _packed_counter() == n0      # building lowers nothing
         types = [op[0] for op in ops]
         attn = [i for i, t in enumerate(types)
@@ -517,26 +603,6 @@ class TestTransformerAttentionBranches:
             r'/pallas_call"', text))
         fwd = {s for s in sites if "_grad__" not in s}
         assert len(fwd) == 6 and len(sites - fwd) == 6, sorted(sites)
-
-    def test_short_sequences_build_the_parents_program(self):
-        # golden: tests/golden/transformer_s256_ops.json, written from the
-        # commit before the packed op (PR 38's tree) by this very
-        # function: below the gate nothing moves, op for op
-        import json
-        import os
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "golden", "transformer_s256_ops.json")
-        with open(path) as f:
-            want = json.load(f)
-        n0 = _packed_counter()
-        got = json.loads(json.dumps(_transformer_ops(256)))
-        assert _packed_counter() == n0
-        assert len(got) == len(want)
-        for i, (a, b) in enumerate(zip(got, want)):
-            assert a == b, f"op {i}: {a} != {b}"
-        types = [op[0] for op in got]
-        assert "scaled_dot_product_attention" not in types
-        assert types.count("softmax") == 3
 
 
 class TestComposedPathMaskWiring:

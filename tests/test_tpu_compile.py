@@ -26,14 +26,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A sharding on one described v5e chip; compile cache off around the
-    module's compiles."""
+def topo():
+    """The described v5e:2x2; compile cache off around the module's
+    compiles."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -42,9 +41,16 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A sharding on one described v5e chip."""
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile(fn, sharding, *shapes):
@@ -122,6 +128,56 @@ def test_packed_attention_compiles_for_v5e_lane_dense(one_chip, causal):
         narrow = [sh for sh in shapes if len(sh) > 1 and sh[-1] < 128]
         assert not narrow, (narrow, line)
         assert (32, 4, 8, 1024) in shapes       # the residual, 4 MB
+
+
+@pytest.mark.parametrize("shape,names", [
+    ((4,), ("data",)), ((4, 1), ("data", "model"))],
+    ids=["data4", "data4-model1"])
+def test_packed_attention_on_a_data_mesh_compiles_per_shard(topo, shape,
+                                                            names,
+                                                            monkeypatch):
+    """The dp4 cell's attention at its real size, as the op lowers it on
+    a mesh (``attention_ops._per_shard``): global batch 1024 over the
+    described chips' ``data`` axis.  Mosaic refuses a kernel the
+    partitioner would have to split ("cannot be automatically
+    partitioned"), under a ``shard_map`` that leaves an axis to it too,
+    so every call has to arrive inside one over the WHOLE mesh: each
+    ``tpu_custom_call`` then works on its shard's rows and nothing gathers
+    its operands."""
+    import re
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from paddle_tpu.ops import attention_ops as A
+    monkeypatch.setattr(A, "_use_interpret", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices).reshape(shape), names)
+    B, S, HD, heads = 1024, 256, 512, 8
+
+    def fn(q, k, v, mask, g):
+        outs = []
+        for causal in (False, True):
+            out, res = A._kernel_forward(q, k, v, mask, causal, 0.125,
+                                         heads, mesh)
+            outs += A._kernel_backward(q, k, v, mask, out, res, g, causal,
+                                       0.125, heads, mesh)
+        return outs
+
+    rows = NamedSharding(mesh, PartitionSpec("data"))
+    x = jax.ShapeDtypeStruct((B, S, HD), jnp.bfloat16, sharding=rows)
+    mask = jax.ShapeDtypeStruct((B, S), jnp.bfloat16, sharding=rows)
+    hlo = jax.jit(fn).lower(x, x, x, mask, x).compile().as_text()
+    calls = [line for line in hlo.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 4, hlo
+    local = B // shape[0]
+    for line in calls:
+        line = line.split("frontend_attributes")[0]
+        dims = {tuple(int(d) for d in dims.split(","))[0]
+                for dims in re.findall(r"(?:bf16|f32|s32)\[([\d,]+)\]",
+                                       line)}
+        assert dims == {local}, line
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
 
 
 def _ragged_lens(S, P, PL):
@@ -868,12 +924,19 @@ _TINY = dict(d_model=32, d_inner_hid=64, n_layer=1, n_head=2, d_key=16,
              dropout=0.0)
 
 
-def test_chip_smoke_phases_rehearse_on_cpu(smoke, capsys, monkeypatch):
-    # the model file's flash crossover, lowered so a 128-token "long"
-    # sequence takes the flash path (interpret mode here)
-    monkeypatch.setenv("PADDLE_TPU_FLASH_MIN_S", "128")
+def test_chip_smoke_phases_rehearse_on_cpu(smoke, capsys):
+    # the "long" sub-phase at a shape the packed kernels' plan admits (128
+    # tokens, two heads of 64), so the model builds the fused op of its
+    # own accord and the kernels run (interpret mode here)
+    from paddle_tpu.profiler import runtime_metrics
+    packed0 = runtime_metrics.counter("attention.packed_kernel")
     smoke.phase_trainer(batch=2, seq=16, steps=3, calls=3, long_batch=1,
-                        long_seq=128, hp_overrides=_TINY)
+                        long_seq=128,
+                        hp_overrides=dict(_TINY, d_model=128, d_key=64,
+                                          d_value=64))
+    # the windows (S 16) built the composed ops; the long build lowered
+    # 3 + 3 fused ops, its use_flash=False twin none
+    assert runtime_metrics.counter("attention.packed_kernel") == packed0 + 6
     smoke.phase_server(n_head=2, d_head=16, d_ffn=64, n_layer=1,
                        vocab_size=64, max_len=32, num_slots=2, page_len=8,
                        prompt_buckets=(8, 32), prompt_lens=(3, 12),
