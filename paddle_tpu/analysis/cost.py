@@ -816,6 +816,57 @@ def _c_mla_absorb(op, info):
         io_bytes(op, info)
 
 
+@rule("latent_window_attention")
+def _c_latent_window_attention(op, info):
+    """A window layer of latent attention over ``T`` rows, ``T x
+    min(window, T)`` pairs.  A whole sequence EXPANDS K and V (2 L a
+    lane) and takes (nope + rope + v) lanes a head a pair; ONE CHUNK
+    over the slot's ring is absorbed: both halves of W_kvb against the
+    chunk's rows, then every head's score over a whole row and its
+    context over the row's value lanes.  A chunk reads of the ring the
+    window's rows and writes its own, never every slot's ring."""
+    q, w, lat = (_shape(info, op, s) for s in ("Q", "Wkvb", "Latent"))
+    if q is None or w is None or lat is None or len(q) != 3 or \
+            not _known(q[1], q[2], lat[2], *w):
+        return None
+    t, h, window = q[1], int(op.attr("n_head")), int(op.attr("window"))
+    pairs = t * min(window, t)
+    if not op.input("Ring"):
+        lanes = int(op.attr("nope_dim")) + int(op.attr("rope_dim")) \
+            + int(op.attr("v_dim"))
+        return 2 * t * w[0] * w[1] + 2 * pairs * h * lanes, \
+            io_bytes(op, info)
+    item = _DTYPE_BYTES.get(str(info(op.input("Latent")[0]).dtype), 4)
+    bytes_ = (t * (q[2] + h * int(op.attr("v_dim")))
+              + (2 * t + min(window, t)) * lat[2]) * item
+    return int(2 * t * w[0] * w[1] + 2 * pairs * h * (lat[2] + w[0])), \
+        int(bytes_)
+
+
+@rule("latent_window_step")
+def _c_latent_window_step(op, info):
+    """A window layer's decode step over its ring of latent rows: ONE row
+    a token is every head's key and, in its leading ``v_width`` lanes,
+    their value; never more than the window's rows a slot nor than the
+    live rows the caller knows (``estimate(paged_live_rows=)``)."""
+    q, ring = _shape(info, op, "Q"), _shape(info, op, "Ring")
+    if q is None or ring is None or len(ring) != 3 or \
+            not _known(q[0], ring[1], ring[2]):
+        return None
+    s, h, v = q[0], int(op.attr("n_head")), int(op.attr("v_width"))
+    rows = min(ring[1], int(op.attr("window")))
+    if info.paged_live_rows is not None:
+        rows = min(rows, max(int(info.paged_live_rows), 1))
+    item = _DTYPE_BYTES.get(str(info(op.input("Ring")[0]).dtype), 4)
+    flops = 2 * s * rows * h * (ring[2] + v)
+    bytes_ = (s * rows * ring[2] + s * h * (ring[2] + v)
+              + 2 * s * ring[2]) * item
+    return int(flops), int(bytes_)
+
+
+rule("head_gate")(_per_element(12))
+
+
 # learned sparse attention (ops/dsa_ops.py): what grows with the SQUARE of
 # a prefill's rows beside the attention itself
 
@@ -1098,4 +1149,6 @@ rule("moe_route_grad")(_twice(_c_moe_route))
 rule("moe_experts_grad")(_twice(_c_moe_experts))
 rule("moe_experts_gated_grad")(_twice(_c_moe_experts_gated))
 rule("mla_attention_grad")(_twice(_c_mla_attention))
+rule("latent_window_attention_grad")(_twice(_c_latent_window_attention))
+rule("head_gate_grad")(_per_element(12))
 rule("swiglu_grad", "rope_grad")(_per_element(6))

@@ -1280,7 +1280,7 @@ def _paged_rows_of(op, tc, cache_slot="Cache"):
     return None
 
 
-@rule("mla_attention", "mla_attention_chunk")
+@rule("mla_attention", "mla_attention_chunk", "latent_window_attention")
 def _r_mla_attention(op, tc):
     h, nope, v, latent = _mla_widths(op, tc)
     r = int(op.attr("rope_dim"))
@@ -1295,6 +1295,14 @@ def _r_mla_attention(op, tc):
                   op=op, var=op.input("Latent")[0])
     shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
     tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
+    if op.type == "latent_window_attention":
+        if op.input("Ring"):
+            # one chunk of a prompt: the slot's ring takes the chunk's rows
+            _int_index(op, tc, "Slot")
+            _int_index(op, tc, "Pos")
+            _ring_holds(op, tc, None if lat.shape is None
+                        else lat.shape[-1])
+        return
     if op.type == "mla_attention":
         if q.shape is not None and len(q.shape) >= 2:
             _select_matches(op, tc, q.shape[-2])
@@ -1353,6 +1361,48 @@ def _r_paged_attention_latent(op, tc):
     shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
     tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
     tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
+
+
+def _ring_holds(op, tc, row):
+    """A ring of latent rows ``Ring`` [slots, ring, row] held to the row
+    that goes through it and to the window; passes through as RingOut."""
+    ring = tc.input_info(op, "Ring")
+    if ring.shape is not None and len(ring.shape) == 3:
+        if row is not None and row > 0:
+            _last_dim_is(op, tc, "Ring", row, "lanes a row (the latent "
+                                              "row's)")
+        if 0 < ring.shape[1] < int(op.attr("window")):
+            tc.report("PTA006", f"{op.type}: Ring holds {ring.shape[1]} "
+                      f"rows a slot, fewer than the window of "
+                      f"{op.attr('window')}", op=op, var=op.input("Ring")[0])
+    tc.set_output(op, "RingOut", shape=ring.shape, dtype=ring.dtype)
+
+
+@rule("latent_window_step")
+def _r_latent_window_step(op, tc):
+    q, row = tc.input_info(op, "Q"), tc.input_info(op, "Row")
+    _int_index(op, tc, "Lens")
+    h, v = int(op.attr("n_head")), int(op.attr("v_width"))
+    width = row.shape[-1] if row.shape is not None else -1
+    _ring_holds(op, tc, width)
+    _last_dim_is(op, tc, "Q", h * width if width > 0 else None,
+                 "features (heads x the ring's row)")
+    if 0 < width < v:
+        tc.report("PTA006", f"latent_window_step reads a value of {v} "
+                  f"lanes from rows of {width}", op=op,
+                  var=op.input("Row")[0])
+    shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
+    tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
+
+
+@rule("head_gate")
+def _r_head_gate(op, tc):
+    x = _same_as(op, tc)
+    h = int(op.attr("n_head"))
+    _last_dim_is(op, tc, "Gate", h, "logits (one a head)")
+    if x.shape is not None and x.shape[-1] > 0 and x.shape[-1] % h:
+        tc.report("PTA006", f"head_gate: {x.shape[-1]} features do not "
+                  f"divide over {h} heads", op=op, var=op.input("X")[0])
 
 
 # learned sparse attention (ops/dsa_ops.py)
@@ -1562,7 +1612,8 @@ rule("split_grad", "relu2_grad", "rms_norm_grad",
      "gated_group_rms_norm_grad", "ssm_scan_conv_grad", "ssm_scan_grad",
      "moe_route_grad", "moe_experts_grad", "moe_experts_gated_grad",
      "gqa_attention_grad", "rope_grad", "swiglu_grad", "pad_grad",
-     "mla_attention_grad", "rope_partial_grad", "window_attention_grad",
+     "mla_attention_grad", "latent_window_attention_grad",
+     "head_gate_grad", "rope_partial_grad", "window_attention_grad",
      "gqa_flash_attention_grad", "attention_out_gate_grad",
      "mamba_scan_grad", "diff_attention_pad_grad",
      "diff_attention_out_grad")(_r_grad_mirror)

@@ -6,14 +6,15 @@ TPU-native layers API."""
 from paddle_tpu.models import (resnet, transformer, vgg, mnist,
                                seq2seq, stacked_lstm, decoder, gen_lm,
                                gen_lm_long, wide_and_deep, hybrid_moe,
-                               latent_moe, latent_moe_sparse, block_moe,
-                               window_moe, hybrid_decoder)
+                               latent_moe, latent_moe_sparse,
+                               latent_moe_window, block_moe, window_moe,
+                               hybrid_decoder)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
            "seq2seq", "stacked_lstm", "decoder", "gen_lm", "gen_lm_long",
            "wide_and_deep", "hybrid_moe", "latent_moe",
-           "latent_moe_sparse", "block_moe", "window_moe", "hybrid_decoder",
-           "ZOO_MODELS",
+           "latent_moe_sparse", "latent_moe_window", "block_moe",
+           "window_moe", "hybrid_decoder", "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
 
 #: zoo model names accepted by :func:`build_train_program` (and by
@@ -21,8 +22,9 @@ __all__ = ["resnet", "transformer", "vgg", "mnist",
 #: tests/test_analysis_zoo.py iterates exactly this list)
 ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
               "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
-              "hybrid_moe", "latent_moe", "latent_moe_sparse", "block_moe",
-              "window_moe", "hybrid_decoder")
+              "hybrid_moe", "latent_moe", "latent_moe_sparse",
+              "latent_moe_window", "block_moe", "window_moe",
+              "hybrid_decoder")
 
 
 #: the serving decoders' entries: name -> (configuration class, its
@@ -38,6 +40,11 @@ _DECODERS = {
     # attends 4, chosen by the first layer's indexer
     "latent_moe_sparse": (latent_moe_sparse.SparseLatentConfig,
                           latent_moe_sparse.latent_moe_sparse_train_program),
+    # a full layer under its own indexer (4 of 16 rows) and two window
+    # layers of latent attention (window 8), gated head-wise
+    "latent_moe_window": (
+        latent_moe_window.WindowLatentConfig,
+        latent_moe_window.latent_moe_window_train_program),
     # two layers under the block-causal mask
     "block_moe": (block_moe.BlockMoEConfig,
                   block_moe.block_moe_train_program),

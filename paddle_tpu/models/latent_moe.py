@@ -35,6 +35,28 @@ precedes the untied head.
   at or before it alone, so nothing but the two pools crosses a chunk's
   edge.  Up to ``index_topk`` rows the selection is the identity.  A
   configuration without ``index_topk`` builds none of this.
+* **Window layers** (a configuration with ``layer_types``, the
+  ``dots3_note`` family).  A ``sliding_attention`` layer is the same
+  latent form at the ``swa_*`` sizes (its own heads, ranks, rotary base
+  and scale; ``LatentMoEConfig.attention(i)``), row ``t`` seeing rows
+  ``u <= t`` with ``t - u < sliding_window_size``, no indexer.  What a
+  full layer keeps in pages it keeps in a RING a slot (``state_vars``
+  ``lat<i>_ring_c``, ``[num_slots, ring, its latent row]``, position
+  ``p`` at row ``p mod ring``): its bytes are a constant of the bundle.
+  ``latent_window_attention`` is its whole-sequence form (expanded) and
+  its chunk form (absorbed: the ring's rows before the chunk, then the
+  chunk's own, under the band; the chunk's last rows left in the ring),
+  ``latent_window_step`` between two ``mla_absorb`` its decode step.
+  ``gen_meta.json`` then carries ``window_attention`` beside
+  ``sparse_attention``, and the chunk program one more feed,
+  ``gen_slot``.  Every ``full_attention`` layer there holds its own
+  indexer.  **A head-wise gate** (``attention_gate_type`` /
+  ``swa_attention_gate_type`` ``"headwise"``): ``g = sigmoid(h W_g)``,
+  ``W_g`` hidden x heads, head ``j``'s output times ``g_j`` before
+  ``W_o`` (``head_gate``).  **The rescale**
+  (``apply_mla_qkv_lora_rescale``): ``c_q`` and ``c_kv`` times (hidden /
+  their rank)^1/2 behind their norms; the cached ``c_kv`` is the
+  rescaled one.  A configuration without these keys builds none of it.
 * **FFN**.  The first ``first_k_dense_replace`` layers: ``W_d (silu(W_g
   h) * W_u h)``, width ``intermediate_size`` (or the layers that
   ``mlp_layer_types`` calls ``"dense"``).  The others: a sigmoid
@@ -75,7 +97,8 @@ from paddle_tpu.ops.mla_ops import yarn_mscale
 
 __all__ = ["LatentMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "latent_moe_train_program",
-           "export_latent_model", "paged_cache_var_names"]
+           "export_latent_model", "paged_cache_var_names",
+           "ring_var_names"]
 
 
 class LatentMoEConfig(DecoderConfig):
@@ -103,6 +126,24 @@ class LatentMoEConfig(DecoderConfig):
     indexer_types = None             # a layer: "full" | "shared"
     mlp_layer_types = None           # a layer: "dense" | "sparse"
     layer_offset = 0                 # the published layer that is layer 0
+    # window layers of latent attention beside the full ones (None: every
+    # layer is full): a layer "full_attention" | "sliding_attention"; a
+    # sliding layer takes the ``swa_*`` sizes and keeps a ring a slot
+    layer_types = None
+    sliding_window_size = None
+    ring = None                      # None: whole 128-row tiles
+    swa_num_attention_heads = 4
+    swa_q_lora_rank = 48
+    swa_kv_lora_rank = 32
+    swa_qk_nope_head_dim = 16
+    swa_qk_rope_head_dim = 8
+    swa_v_head_dim = 16
+    swa_rope_theta = 10000.0
+    # "headwise": head j's output times sigmoid((h W_g)_j) before W_o
+    attention_gate_type = None
+    swa_attention_gate_type = None
+    # c_q and c_kv times (hidden / their rank)^1/2 behind their norms
+    apply_mla_qkv_lora_rescale = False
     # FFN
     intermediate_size = 96
     moe_intermediate_size = 32
@@ -128,17 +169,51 @@ class LatentMoEConfig(DecoderConfig):
 
     @property
     def latent_row(self):
-        """Lanes of the cached row: ``[c_kv | k_r]`` and zeros up to the
-        next multiple of 128."""
-        return -(-(int(self.kv_lora_rank) + int(self.qk_rope_head_dim))
-                 // 128) * 128
+        """Lanes of a full layer's cached row: ``[c_kv | k_r]`` and
+        zeros up to the next multiple of 128."""
+        return self.attention(None)["row"]
+
+    def is_window(self, i):
+        """Layer ``i`` is a ``sliding_attention`` one (None: a full
+        layer's sizes are asked for)."""
+        return i is not None and bool(self.layer_types) and self.layer_types[
+            int(self.layer_offset) + i] == "sliding_attention"
+
+    def attention(self, i):
+        """Layer ``i``'s attention sizes by its kind: ``H``, ``q_rank``,
+        ``L`` (the latent's rank), ``nope``, ``R`` (rotary lanes),
+        ``vd``, ``theta``, ``gate``, ``window`` (0: full) and ``row``
+        (lanes of the cached row)."""
+        key = "swa_" if self.is_window(i) else ""
+        a = {name: int(getattr(self, key + attr)) for name, attr in (
+            ("H", "num_attention_heads"), ("q_rank", "q_lora_rank"),
+            ("L", "kv_lora_rank"), ("nope", "qk_nope_head_dim"),
+            ("R", "qk_rope_head_dim"), ("vd", "v_head_dim"))}
+        a["theta"] = float(getattr(self, key + "rope_theta"))
+        a["gate"] = getattr(self, key + "attention_gate_type")
+        a["window"] = int(self.sliding_window_size) if key else 0
+        a["row"] = -(-(a["L"] + a["R"]) // 128) * 128
+        return a
+
+    @property
+    def ring_rows(self):
+        """Rows of a window layer's ring: the window in whole 128-row
+        tiles (the ring kernel's scores are ``[heads, ring]``: the ring
+        is their lane axis)."""
+        return int(self.ring or -(-int(self.sliding_window_size) // 128)
+                   * 128)
 
     @property
     def rope_attrs(self):
+        return self.rope_of(None)
+
+    def rope_of(self, i):
+        """The ``rope`` op's attrs of layer ``i``'s kind."""
+        a = self.attention(i)
         rs = dict(self.rope_scaling or {})
         factor = float(rs.get("factor", 1.0))
-        return {"rope_dim": int(self.qk_rope_head_dim),
-                "theta": float(self.rope_theta), "factor": factor,
+        return {"rope_dim": a["R"],
+                "theta": a["theta"], "factor": factor,
                 "original_max": int(rs.get(
                     "original_max_position_embeddings", 4096)),
                 "beta_fast": float(rs.get("beta_fast", 32)),
@@ -148,13 +223,16 @@ class LatentMoEConfig(DecoderConfig):
 
     @property
     def softmax_scale(self):
-        """``(nope + rope)^-1/2 m^2``, ``m`` YaRN's temperature over all
-        dimensions."""
+        return self.scale_of(None)
+
+    def scale_of(self, i):
+        """``(nope + rope)^-1/2 m^2`` of layer ``i``'s kind, ``m`` YaRN's
+        temperature over all dimensions."""
+        a = self.attention(i)
         rs = dict(self.rope_scaling or {})
         m = yarn_mscale(float(rs.get("factor", 1.0)),
                         rs.get("mscale_all_dim", 0.0))
-        return (int(self.qk_nope_head_dim)
-                + int(self.qk_rope_head_dim)) ** -0.5 * m * m
+        return (a["nope"] + a["R"]) ** -0.5 * m * m
 
     def is_moe(self, i):
         if self.mlp_layer_types:
@@ -167,7 +245,7 @@ class LatentMoEConfig(DecoderConfig):
         uses the selection of the nearest full layer before it) or None
         (no sparse attention; also a shared layer with no full layer
         before it in the layers held)."""
-        if not self.index_topk:
+        if not self.index_topk or self.is_window(i):
             return None
         kinds = self.indexer_types
         at = int(self.layer_offset)
@@ -183,16 +261,35 @@ class LatentMoEConfig(DecoderConfig):
                 if self.indexer(i) == "full"]
 
     @property
+    def window_layers(self):
+        return [i for i in range(int(self.num_hidden_layers))
+                if self.is_window(i)]
+
+    @property
+    def paged_layers(self):
+        """The layers that keep pages: every one that is no window
+        layer."""
+        return [i for i in range(int(self.num_hidden_layers))
+                if not self.is_window(i)]
+
+    @property
     def moe_layers(self):
         return [i for i in range(int(self.num_hidden_layers))
                 if self.is_moe(i)]
 
 
 def paged_cache_var_names(hp):
-    """Page-pool tensors: ONE a layer (the latent row), in layer order,
-    then one a layer that holds an indexer (its key row)."""
-    return [f"lat{i}_paged_c" for i in range(int(hp.num_hidden_layers))] \
+    """Page-pool tensors: ONE a layer that is not a window layer (the
+    latent row), in layer order, then one a layer that holds an indexer
+    (its key row)."""
+    return [f"lat{i}_paged_c" for i in hp.paged_layers] \
         + [f"lat{i}_paged_ik" for i in hp.full_layers]
+
+
+def ring_var_names(hp):
+    """Per-slot ring tensors, ONE a window layer (its latent row), in
+    layer order."""
+    return [f"lat{i}_ring_c" for i in hp.window_layers]
 
 
 def _indexer(h, c_q, hp, i, pos, mask=None, paged=None):
@@ -246,16 +343,20 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
     ``paged`` = (pool, page table, lens): the absorbed paged decode.
     ``select``: the selection a ``shared`` layer attends under; a
     ``full`` layer makes its own (``index_pool``: its key pool in the
-    serving forms).  Returns ``(out, selection)``."""
-    d, H = int(hp.hidden_size), int(hp.num_attention_heads)
-    L, R = int(hp.kv_lora_rank), int(hp.qk_rope_head_dim)
-    nope, vd = int(hp.qk_nope_head_dim), int(hp.v_head_dim)
-    rope = hp.rope_attrs
-    c_q = rms(layers.matmul(h, matrix(hp, f"lat{i}_qa.w",
-                                      [d, int(hp.q_lora_rank)])),
-              f"lat{i}_qnorm.scale", hp)
+    serving forms).  A WINDOW layer (``hp.is_window``) keeps a ring a
+    slot where a full layer keeps pages: its ``paged`` is (ring, slot
+    [1, 1]) in a chunk, (ring, lens) in the decode step, and it takes no
+    selection.  Returns ``(out, selection)``."""
+    d, a = int(hp.hidden_size), hp.attention(i)
+    H, L, R, nope, vd = a["H"], a["L"], a["R"], a["nope"], a["vd"]
+    rope = hp.rope_of(i)
+    rescale = lambda c, rank: layers.scale(
+        c, scale=(d / rank) ** 0.5) if hp.apply_mla_qkv_lora_rescale else c
+    c_q = rescale(rms(layers.matmul(h, matrix(hp, f"lat{i}_qa.w",
+                                              [d, a["q_rank"]])),
+                      f"lat{i}_qnorm.scale", hp), a["q_rank"])
     q = layers.matmul(c_q, matrix(hp, f"lat{i}_qb.w",
-                                  [int(hp.q_lora_rank), H * (nope + R)]))
+                                  [a["q_rank"], H * (nope + R)]))
     if hp.indexer(i) == "full":
         select = _indexer(h, c_q, hp, i, pos, mask=mask,
                           paged=paged and (index_pool,) + tuple(paged[1:]))
@@ -268,46 +369,68 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
            {"n_head": H, **rope})["Out"]
     kva = layers.matmul(h, matrix(hp, f"lat{i}_kva.w", [d, L + R]))
     c_kv, k_r = layers.split(kva, [L, R], dim=2)
-    c_kv = rms(c_kv, f"lat{i}_kvnorm.scale", hp)
+    c_kv = rescale(rms(c_kv, f"lat{i}_kvnorm.scale", hp), L)
     k_r = op("rope", {"X": k_r, "Pos": pos}, {"Out": hp.dtype},
              {"n_head": 1, **rope})["Out"]
     row = layers.concat([c_kv, k_r], axis=2)
-    if hp.latent_row > L + R:
-        row = layers.pad(row, [0, 0, 0, 0, 0, hp.latent_row - L - R])
+    if a["row"] > L + R:
+        row = layers.pad(row, [0, 0, 0, 0, 0, a["row"] - L - R])
     w_kvb = matrix(hp, f"lat{i}_kvb.w", [L, H * (nope + vd)])
     attrs = {"n_head": H, "nope_dim": nope, "v_dim": vd}
-    scale = float(hp.softmax_scale)
+    scale = float(hp.scale_of(i))
+    whole = {**attrs, "rope_dim": R, "scale": scale, **sparse_attrs}
+    absorb = lambda x, side, **pad: op(
+        "mla_absorb", {"X": x, "Wkvb": w_kvb}, {"Out": hp.dtype},
+        {**attrs, "side": side, **pad})["Out"]
     if paged is None:
         row = layers.elementwise_mul(row, layers.cast(mask, hp.dtype),
                                      axis=0)
+    if a["window"] and (paged is None or mask is not None):
+        ring, slot = paged or (None, None)
+        ctx = op("latent_window_attention",
+                 {"Q": q, "Latent": row, "Wkvb": w_kvb, "Mask": mask,
+                  "Ring": ring, "Slot": slot,
+                  "Pos": pos if paged else None},
+                 {"Out": hp.dtype, **({"RingOut": ring} if paged else {})},
+                 {**whole, "window": a["window"]})["Out"]
+    elif a["window"]:
+        ring, lens = paged
+        ctx = op("latent_window_step",
+                 {"Q": absorb(q, "q", pad=a["row"] - L - R), "Row": row,
+                  "Ring": ring, "Lens": lens},
+                 {"Out": hp.dtype, "RingOut": ring},
+                 {"n_head": H, "v_width": L, "scale": scale,
+                  "window": a["window"]})["Out"]
+        ctx = absorb(ctx, "o")
+    elif paged is None:
         ctx = op("mla_attention", {"Q": q, "Latent": row, "Wkvb": w_kvb,
                                    "Mask": mask, **sparse},
-                 {"Out": hp.dtype},
-                 {**attrs, "rope_dim": R, "scale": scale,
-                  **sparse_attrs})["Out"]
+                 {"Out": hp.dtype}, whole)["Out"]
     elif mask is not None:
         pool, page_table = paged
         ctx = op("mla_attention_chunk",
                  {"Q": q, "Latent": row, "Wkvb": w_kvb, "Cache": pool,
                   "PageTable": page_table, "Pos": pos, "Mask": mask,
                   **sparse},
-                 {"Out": hp.dtype, "CacheOut": pool},
-                 {**attrs, "rope_dim": R, "scale": scale,
-                  **sparse_attrs})["Out"]
+                 {"Out": hp.dtype, "CacheOut": pool}, whole)["Out"]
     else:
         pool, page_table, lens = paged
-        q_lat = op("mla_absorb", {"X": q, "Wkvb": w_kvb},
-                   {"Out": hp.dtype},
-                   {**attrs, "side": "q",
-                    "pad": hp.latent_row - L - R})["Out"]
         ctx = op("paged_attention_latent",
-                 {"Q": q_lat, "Row": row, "Cache": pool,
-                  "PageTable": page_table, "Lens": lens, **sparse},
+                 {"Q": absorb(q, "q", pad=a["row"] - L - R), "Row": row,
+                  "Cache": pool, "PageTable": page_table, "Lens": lens,
+                  **sparse},
                  {"Out": hp.dtype, "CacheOut": pool},
                  {"n_head": H, "v_width": L, "scale": scale,
                   **sparse_attrs})["Out"]
-        ctx = op("mla_absorb", {"X": ctx, "Wkvb": w_kvb},
-                 {"Out": hp.dtype}, {**attrs, "side": "o"})["Out"]
+        ctx = absorb(ctx, "o")
+    if a["gate"] == "headwise":
+        ctx = op("head_gate",
+                 {"X": ctx, "Gate": layers.matmul(
+                     h, matrix(hp, f"lat{i}_og.w", [d, H]))},
+                 {"Out": hp.dtype}, {"n_head": H})["Out"]
+    elif a["gate"]:
+        raise NotImplementedError(f"an attention gate of type "
+                                  f"{a['gate']!r}")
     return layers.matmul(ctx, matrix(hp, f"lat{i}_o.w", [H * vd, d])), \
         select
 
@@ -342,16 +465,31 @@ def _layer(x, hp, i, pos, lens, mask=None, paged=None, select=None,
     return x, stats, select
 
 
-def _pools(hp, page_len, num_pages):
-    """The persistable page pools of the CURRENT program, ``{name:
-    var}``, all ``hp.dtype``: one latent pool a layer ``[num_pages,
-    page_len, latent_row]`` and one index-key pool a layer that holds an
-    indexer ``[num_pages, page_len, index_head_dim]``."""
-    return {name: persistable(
+def _pools(hp, num_slots, page_len, num_pages):
+    """The persistable caches of the CURRENT program, ``{name: var}``,
+    all ``hp.dtype``: one latent pool a full layer ``[num_pages,
+    page_len, latent_row]``, one index-key pool a layer that holds an
+    indexer ``[num_pages, page_len, index_head_dim]`` and one ring a
+    window layer ``[num_slots, ring, its latent row]``."""
+    pools = {name: persistable(
         name, [int(num_pages), int(page_len),
                int(hp.index_head_dim) if name.endswith("_ik")
                else hp.latent_row], hp.dtype)
         for name in paged_cache_var_names(hp)}
+    for i in hp.window_layers:
+        pools[f"lat{i}_ring_c"] = persistable(
+            f"lat{i}_ring_c", [int(num_slots), hp.ring_rows,
+                               hp.attention(i)["row"]], hp.dtype)
+    return pools
+
+
+def _cache(pools, hp, i, *rows):
+    """Layer ``i``'s ``paged`` of :func:`_attention`: its ring and the
+    first of ``rows`` (the slot, or the lens) where it is a window
+    layer, else its pool and the others (the page table, the lens)."""
+    if hp.is_window(i):
+        return (pools[f"lat{i}_ring_c"], rows[0])
+    return (pools[f"lat{i}_paged_c"],) + rows[1:]
 
 
 @program_role("gen_chunk")
@@ -365,24 +503,27 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     token, real tokens first), ``gen_last`` [1, C] f32 (one-hot of the
     prompt's last row where this chunk holds it, else zeros) and
     ``gen_page_table`` [1, P] int32 (the slot's row, P bucketed by the
-    predictor and covering the chunk's last real row); no ``gen_slot``:
-    nothing here is kept a slot.  Persistable state, read and updated in
-    place, as the decode step's: the latent pools and the index-key
-    pools.  Fetches ``[logits [1, V]]`` (of the row ``gen_last``
+    predictor and covering the chunk's last real row); ``gen_slot`` [1,
+    1] int32 with window layers alone: without them nothing here is kept
+    a slot.  Persistable state, read and updated in place, as the decode
+    step's: the latent pools, the index-key pools and the window layers'
+    rings.  Fetches ``[logits [1, V]]`` (of the row ``gen_last``
     names)."""
     ids, pos, mask, last = prefill_inputs()
+    slot = data("gen_slot", [1, 1], "int32") if hp.window_layers else None
     page_table = data("gen_page_table", [1, -1], "int32")
-    pools = _pools(hp, page_len, num_pages)
+    pools = _pools(hp, num_slots, page_len, num_pages)
     lens = live_rows(mask)
     x = embed(ids, hp, "lat")
     select = None
     for i in range(int(hp.num_hidden_layers)):
         x, _, select = _layer(
             x, hp, i, pos, lens, mask=mask,
-            paged=(pools[f"lat{i}_paged_c"], page_table), select=select,
+            paged=_cache(pools, hp, i, slot, page_table), select=select,
             index_pool=pools.get(f"lat{i}_paged_ik"))
-    return (["gen_ids", "gen_pos", "gen_mask", "gen_last",
-             "gen_page_table"], [logits(last_row(x, last, hp), hp, "lat")])
+    return (["gen_ids", "gen_pos", "gen_mask", "gen_last"]
+            + ["gen_slot"] * bool(hp.window_layers) + ["gen_page_table"],
+            [logits(last_row(x, last, hp), hp, "lat")])
 
 
 def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
@@ -411,22 +552,46 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     token; 0 = free slot: no page is written).  Persistable state,
     updated in place: one latent pool a layer, ``[num_pages, page_len,
     latent_row]`` in ``hp.dtype``, and one index-key pool a layer that
-    holds an indexer, ``[num_pages, page_len, index_head_dim]``.  Fetches
+    holds an indexer, ``[num_pages, page_len, index_head_dim]``; a window
+    layer's ring ``[S, ring, its latent row]`` in their place.  Fetches
     ``[logits [S, V], stats [n_moe, 3]]``."""
     S = int(num_slots)
     token, pos, page_table, lens = decode_inputs(S)
-    pools = _pools(hp, page_len, num_pages)
+    pools = _pools(hp, S, page_len, num_pages)
     x = embed(token, hp, "lat", lead=[S, 1])
     stats, select = [], None
     for i in range(int(hp.num_hidden_layers)):
         x, st, select = _layer(
             x, hp, i, pos, lens,
-            paged=(pools[f"lat{i}_paged_c"], page_table, lens),
+            paged=_cache(pools, hp, i, lens, page_table, lens),
             select=select, index_pool=pools.get(f"lat{i}_paged_ik"))
         if st is not None:
             stats.append(st)
     return (["gen_token", "gen_pos", "gen_page_table", "gen_lens"],
             decode_fetches(x, stats, S, hp, "lat"))
+
+
+def _window_section(hp, rows):
+    """``gen_meta.json``'s ``window_attention``, the keys
+    ``window_moe``'s has: which layer keeps a ring and which pages, and
+    what the predictor counts a step's reads and a chunk's pairs from.
+    ``heads``: the (query heads, K/V heads) the banded kernel is handed
+    for a window layer's chunk of ``rows`` rows (``mla_ops.band_groups``:
+    the ONE latent row goes as that many copies); ``full_heads``: a full
+    layer's heads over its one cached row."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.mla_ops import band_groups
+    item = jnp.dtype(hp.dtype).itemsize
+    win, full = hp.attention(hp.window_layers[0]), hp.paged_layers
+    return {
+        "window": win["window"], "ring": hp.ring_rows,
+        "layers": hp.window_layers, "full_layers": full,
+        "ring_vars": ring_var_names(hp),
+        "row_bytes": [hp.attention(i)["row"] * item
+                      for i in hp.window_layers],
+        "heads": [win["H"], band_groups(rows, win["H"], win["window"])],
+        "full_heads": [hp.attention(full[0])["H"], 1] if full else None,
+    }
 
 
 def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
@@ -448,6 +613,9 @@ def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
             # what the predictor counts a step's selections from
             own["sparse_attention"] = {"top_k": int(hp.index_topk),
                                        "indexers": len(hp.full_layers)}
+        if hp.window_layers:
+            own["window_attention"] = _window_section(
+                hp, own["prefill_chunks"][-1])
         return own
 
     return export_bundle(
@@ -457,4 +625,4 @@ def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
         paged_cache_var_names(hp), hp.num_hidden_layers,
         num_slots=num_slots, prompt_buckets=prompt_buckets,
         page_len=page_len, num_pages=num_pages, page_buckets=page_buckets,
-        state_vars=[], sections=sections)
+        state_vars=ring_var_names(hp), sections=sections)

@@ -30,8 +30,25 @@ selection's blocks beside the keys' where there is one), the context
 taken out of the latent.  Nothing is expanded, so a chunk's work follows
 the rows under its diagonal and not its page bucket.
 
+A WINDOW layer of latent attention (``models/latent_moe.py`` with
+``layer_types``: row ``t`` sees rows ``u <= t`` with ``t - u < window``)
+keeps, where a full layer keeps pages, a RING of latent rows a slot
+(``[num_slots, ring, W]``, position ``p`` at row ``p mod ring``), and
+runs the same absorbed forms over it with ``window_ops``'s kernels as
+they are: ``latent_window_step`` (the decode step: the row's scatter and
+the ring kernel handed ONE ring, whose row is every head's key and, in
+its leading ``v_width`` lanes, their value) and
+``latent_window_attention`` (one chunk: the ring's rows before the chunk
+led in front of the chunk's own under the banded flash kernel, the one
+row copied ``band_groups`` times so that the kernel's block rule admits
+64 heads; a whole sequence: expanded, the band as the kernel's).
+``head_gate`` multiplies head ``j``'s attention output by ``sigmoid`` of
+the gate's ``j``-th logit.
+
 Op scopes on the device trace: ``ptop_rope*``, ``ptop_swiglu*``,
-``ptop_mla_attention*`` (whole sequence and chunk), ``ptop_mla_absorb*``.
+``ptop_mla_attention*`` (whole sequence and chunk), ``ptop_mla_absorb*``,
+``ptop_latent_window_attention*``, ``ptop_latent_window_step*``,
+``ptop_head_gate*``.
 """
 
 from __future__ import annotations
@@ -367,3 +384,207 @@ def mla_attention_chunk_lower(ctx):
         select=select)
     ctx.set_output("Out", out[None])
     ctx.set_output("CacheOut", pool)
+
+
+# ---------------------------------------------------------------------------
+# latent attention inside a window: a RING of latent rows a slot
+# ---------------------------------------------------------------------------
+
+def head_gate(x, gate, n_head):
+    """``x`` [..., H * v] (a layer's attention output before ``W_o``),
+    ``gate`` [..., H] (the gate's logits): head ``j``'s lanes times
+    ``sigmoid(gate_j)``, the sigmoid and the product in float32.
+    Returns ``x``'s type."""
+    g = jax.nn.sigmoid(gate.astype(jnp.float32))[..., None]
+    xh = x.reshape(x.shape[:-1] + (n_head, -1)).astype(jnp.float32)
+    return (xh * g).astype(x.dtype).reshape(x.shape)
+
+
+@register_op("head_gate", infer_shape=infer_shape_unary())
+def head_gate_lower(ctx):
+    """X [..., H * v]; Gate [..., H] the gate's logits, a row for every
+    row of X.  attrs n_head.  Out = X, head ``j`` times
+    ``sigmoid(Gate_j)``."""
+    ctx.set_output("Out", head_gate(ctx.input("X"), ctx.input("Gate"),
+                                    int(ctx.attr("n_head"))))
+
+
+def latent_window_attention(q, latent, w_kvb, n_head, nope, rope_dim, v_dim,
+                            scale, window, interpret=None):
+    """A WHOLE sequence under the band, nothing cached (the training
+    forward): ``q`` [T, H * (nope + rope)] (rotated), ``latent`` [T, >=
+    L + rope]; K and V of every head EXPANDED from the latent as
+    ``mla_attention`` does, then ``window_ops``'s banded attention with
+    every head its own K/V head (row ``t`` sees rows ``u <= t`` with ``t
+    - u < window``; real rows first, so no real row sees a pad row).
+    Returns [T, H * v] in ``q``'s type."""
+    from paddle_tpu.ops.window_ops import prefill_attention
+    T, L = q.shape[0], w_kvb.shape[0]
+    w_k, w_v = _split_kvb(w_kvb, n_head, nope, v_dim)
+    c_kv, k_rope = latent[:, :L], latent[:, L:L + rope_dim]
+    k_nope = jnp.einsum("tl,lhd->thd", c_kv, w_k,
+                        preferred_element_type=jnp.float32).astype(q.dtype)
+    v = jnp.einsum("tl,lhd->thd", c_kv, w_v,
+                   preferred_element_type=jnp.float32).astype(q.dtype)
+    k = jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope[:, None].astype(q.dtype), (T, n_head, rope_dim))], axis=-1)
+    return prefill_attention(q, k.reshape(T, -1), v.reshape(T, -1), None,
+                             n_head, n_head, scale, window,
+                             interpret=interpret)
+
+
+def band_groups(rows, n_head, window):
+    """K/V heads the banded flash kernel is handed for ONE latent row
+    under ``n_head`` absorbed query heads: the kernel takes the ``G``
+    query heads of a K/V head as the rows of one product, ``G x query
+    block`` = ``window_ops.FLASH_LEFT_ROWS`` of them, and its query
+    block is whole key blocks, so 64 heads go as 4 groups of 16 over 4
+    copies of the row (128 query rows a block).  1 where no grouping
+    makes whole blocks of ``rows`` (the composed form runs)."""
+    from paddle_tpu.ops.window_ops import flash_blocks
+    for groups in (g for g in range(1, n_head + 1) if n_head % g == 0):
+        if flash_blocks(rows, n_head // groups, window) is not None:
+            return groups
+    return 1
+
+
+def latent_window_chunk(q, row, w_kvb, ring, slot, start, n, n_head, nope,
+                        rope_dim, v_dim, scale, window, interpret=None):
+    """ONE CHUNK of a prompt through a window layer of latent attention.
+    ``q`` [C, H * (nope + rope)] (rotated) and ``row`` [C, W] (the latent
+    rows as cached) stand at positions ``start ..``, the first ``n`` of
+    them real; ``ring`` [num_slots, R, W].  ABSORBED, as the decode
+    step: the keys are the slot's ring rows of the positions before
+    ``start`` (``window_ops.ring_lead``) followed by the chunk's own
+    rows, the values their leading ``L`` lanes, under the band
+    (``window_ops``'s banded flash kernel as it is, the row copied a
+    group of heads: ``band_groups``); the chunk's last real rows go
+    through the ring.  Returns ``(out [C, H * v], ring)``."""
+    from paddle_tpu.ops.window_ops import (lead_rows, prefill_attention,
+                                           ring_after, ring_lead)
+    C, L, W = q.shape[0], w_kvb.shape[0], ring.shape[-1]
+    own = jax.lax.dynamic_index_in_dim(ring, slot, 0, keepdims=False)
+    groups = band_groups(C, n_head, window)
+    lead, held = ring_lead(own, start, lead_rows(C, n_head // groups,
+                                                 window))
+    q_lat = mla_absorb(q, w_kvb, n_head, nope, v_dim, "q",
+                       pad=W - L - rope_dim)
+    keys, lead = row.astype(q.dtype), lead.astype(q.dtype)
+    wide = lambda a: jnp.tile(a, (1, groups))
+    ctx = prefill_attention(
+        q_lat, wide(keys), wide(keys[:, :L]), None, n_head, groups, scale,
+        window, before=(wide(lead), wide(lead[:, :L]), held),
+        interpret=interpret)
+    ring = jax.lax.dynamic_update_index_in_dim(
+        ring, ring_after(own, row, start, n), slot, 0)
+    return mla_absorb(ctx, w_kvb, n_head, nope, v_dim, "o"), ring
+
+
+def _infer_latent_window(op, block):
+    _infer_mla_attention(op, block)
+    # RingOut aliases the persistable ring (in-place update)
+
+
+@register_op("latent_window_attention", infer_shape=_infer_latent_window,
+             no_grad_inputs=("Mask", "Ring", "Slot", "Pos"),
+             stateful_outputs=("RingOut",))
+def latent_window_attention_lower(ctx):
+    """A window layer of latent attention, its prefill.  Q [1, T, H *
+    (nope + rope)]; Latent [1, T, W] the rows as they are cached; Wkvb
+    [L, H * (nope + v)]; Mask [1, T] (1 = a real row, real rows first).
+    attrs n_head, nope_dim, rope_dim, v_dim, scale, window.  Out [1, T,
+    H * v].  With no further input: a WHOLE sequence, expanded, nothing
+    cached (the training forward).
+
+    ONE CHUNK of a prompt: Ring [num_slots, ring, W] persistable; Slot
+    [1, 1] int32; Pos [1, T] int32 the rows' positions ``start ..``.
+    The chunk attends the ring's rows of the ``window - 1`` positions
+    before ``start`` followed by its own, absorbed, and its last real
+    rows go through the slot's ring; RingOut names the ring itself."""
+    q, latent, w_kvb = ctx.input("Q")[0], ctx.input("Latent")[0], \
+        ctx.input("Wkvb")
+    sizes = (int(ctx.attr("n_head")), int(ctx.attr("nope_dim")),
+             int(ctx.attr("rope_dim")), int(ctx.attr("v_dim")),
+             float(ctx.attr("scale", 1.0)), int(ctx.attr("window")))
+    if not ctx.has_input("Ring"):
+        ctx.set_output("Out", latent_window_attention(
+            q, latent, w_kvb, *sizes)[None])
+        return
+    out, ring = latent_window_chunk(
+        q, latent, w_kvb, ctx.input("Ring"),
+        ctx.input("Slot").reshape(-1)[0].astype(jnp.int32),
+        ctx.input("Pos").reshape(-1)[0].astype(jnp.int32),
+        jnp.sum(ctx.input("Mask") > 0).astype(jnp.int32), *sizes)
+    ctx.set_output("Out", out[None])
+    ctx.set_output("RingOut", ring)
+
+
+def latent_ring_step(q, row, ring, lens, n_head, v_width, scale, window,
+                     kernel=None):
+    """One decode step over a ring of latent rows.  ``q`` [S, H * W]
+    (absorbed: ``mla_absorb`` side ``"q"``); ``row`` [S, W] this step's
+    latent row; ``ring`` [S, R, W]; ``lens`` [S] rows INCLUDING this
+    step's (0 = free slot: nothing written, zeros out).  The row is
+    written at ``(lens - 1) mod R`` and every head attends the ring's
+    rows inside the window, ONE row serving as key and, its leading
+    ``v_width`` lanes, as value.  ``kernel``: None = the composed form;
+    else the Pallas kernel's ``interpret`` flag (``window_ops``'s ring
+    kernel, one K/V head, the ring read once).  Returns ``(out [S, H *
+    v_width], ring)``."""
+    from paddle_tpu.ops.window_ops import ring_attention
+    S, R, W = ring.shape
+    pos = lens.astype(jnp.int32) - 1
+    # a free slot's row lands nowhere
+    at = jnp.where(pos >= 0, jnp.mod(pos, R), R)
+    ring = ring.at[jnp.arange(S, dtype=jnp.int32), at].set(
+        row.astype(ring.dtype), mode="drop")
+    if kernel is not None and (kernel or not (
+            W % 128 or v_width % 128 or R % (32 // ring.dtype.itemsize))):
+        return ring_attention(q, ring, None, lens, n_head=n_head,
+                              scale=scale, window=window, interpret=kernel,
+                              v_width=v_width), ring
+    # row r holds position pos - ((pos - r) mod R), if that is not negative
+    back = jnp.mod(pos[:, None] - jnp.arange(R, dtype=jnp.int32)[None], R)
+    seen = (back <= pos[:, None]) & (back < window)              # [S, R]
+    sc = jnp.einsum("shd,srd->shr", q.reshape(S, n_head, W).astype(
+        ring.dtype), ring, preferred_element_type=jnp.float32) * scale
+    probs = jax.nn.softmax(jnp.where(seen[:, None], sc, NEG_INF), axis=-1)
+    out = jnp.einsum("shr,srd->shd", probs,
+                     ring[..., :v_width].astype(jnp.float32),
+                     preferred_element_type=jnp.float32,
+                     precision=jax.lax.Precision.HIGHEST)
+    out = jnp.where((pos >= 0)[:, None, None], out, 0.0)
+    return out.reshape(S, -1).astype(q.dtype), ring
+
+
+def _infer_latent_window_step(op, block):
+    q = block.var(op.input("Q")[0])
+    if q.shape is None:
+        raise ShapeInferenceSkip()
+    out = block.var(op.output("Out")[0])
+    out.shape = tuple(q.shape[:-1]) + (
+        int(op.attr("n_head")) * int(op.attr("v_width")),)
+    out.dtype = q.dtype
+    # RingOut aliases the persistable ring (in-place update)
+
+
+@register_op("latent_window_step", infer_shape=_infer_latent_window_step,
+             no_gradient=True, stateful_outputs=("RingOut",))
+def latent_window_step_lower(ctx):
+    """A window layer of latent attention, its decode step.  Q [S, 1, H
+    * W] the absorbed queries; Row [S, 1, W] this step's latent row as
+    cached; Ring [S, ring, W] persistable; Lens [S, 1] int32 rows
+    INCLUDING this step's (0 = free slot).  attrs n_head, v_width (the
+    row's leading lanes that are the value), scale, window (<= ring).
+    Out [S, 1, H * v_width] the context in the latent; RingOut names the
+    ring itself."""
+    from paddle_tpu.ops.attention_ops import _use_interpret
+    q = ctx.input("Q")
+    # the kernel on the chip; off it the composed form
+    out, ring = latent_ring_step(
+        q[:, 0], ctx.input("Row")[:, 0], ctx.input("Ring"),
+        ctx.input("Lens").reshape(q.shape[0]), int(ctx.attr("n_head")),
+        int(ctx.attr("v_width")), float(ctx.attr("scale", 1.0)),
+        int(ctx.attr("window")), kernel=None if _use_interpret() else False)
+    ctx.set_output("Out", out[:, None])
+    ctx.set_output("RingOut", ring)

@@ -475,9 +475,14 @@ def ring_lead(ring, start, rows):
         jnp.minimum(start, R), rows)
 
 
-def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink, rows=1):
+def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink, rows=1,
+                 v_width=0):
     """One slot of the grid: its rings arrive whole (``[R, Hkv * D]``
-    blocks, copied in while the slot before computes).  Row ``r`` holds
+    blocks, copied in while the slot before computes).  ``v_width`` > 0:
+    ONE ring comes, of ONE K/V head, and the values are the leading
+    ``v_width`` lanes of its rows (a ring of latent rows,
+    ``mla_ops.latent_ring_step``: the block is read once for both
+    products).  Row ``r`` holds
     position ``pos - ((pos - r) mod R)``: seen if that is not negative
     and inside the window.  ALL query heads take their scores in one
     product: the queries are laid out block-diagonally (``[H, Hkv *
@@ -499,7 +504,7 @@ def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink, rows=1):
     end at its own."""
     if sink:
         sink_ref, *refs = refs
-    k_ref, v_ref, o_ref = refs
+    k_ref, *v_ref, o_ref = refs
     H, Dk = q_ref.shape[1:]
     R, Dv = k_ref.shape[1], o_ref.shape[2]
     G = H // rows // n_kv
@@ -541,7 +546,7 @@ def _ring_kernel(lens_ref, q_ref, *refs, scale, window, n_kv, sink, rows=1):
     if sink:
         den = den + jnp.exp(sink_ref[...] - m)
     p = e / jnp.where(den > 0, den, 1.0)
-    v = v_ref[0]
+    v = v_ref[0][0] if v_ref else k_ref[0][:, :v_width]
     hi = p.astype(v.dtype)
     weigh = lambda part: jax.lax.dot_general(
         part, v, (((1,), (0,)), ((), ())), preferred_element_type=f32)
@@ -564,19 +569,22 @@ def _ring_kernel_ok(q, k_ring, v_ring, n_head, interpret):
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "n_head", "scale", "window", "interpret"))
+    "n_head", "scale", "window", "interpret", "v_width"))
 def ring_attention(q, k_ring, v_ring, lens, sink=None, *, n_head, scale,
-                   window, interpret=False):
+                   window, interpret=False, v_width=0):
     """The decode step's attention over rings that already hold this
     step's row: ``q`` [S, H * Dk]; rings [S, R, Hkv * D]; ``lens`` [S]
     int32 -> [S, H * Dv] in ``q``'s type (a free slot: zeros).  ``q``
     [S, L, H * Dk] with ``lens`` [S, L]: ``L`` rows a slot, each at its
-    own position (``_ring_kernel``'s ``rows``) -> [S, L, H * Dv]."""
+    own position (``_ring_kernel``'s ``rows``) -> [S, L, H * Dv].
+    ``v_ring`` None: ``k_ring`` [S, R, Dk] is a ring of ONE head whose
+    leading ``v_width`` lanes are the values."""
     S, R, _ = k_ring.shape
     rows = 1 if q.ndim == 2 else q.shape[1]
     Dk, heads = q.shape[-1] // n_head, n_head
     n_kv = k_ring.shape[-1] // Dk
-    Dv = v_ring.shape[-1] // n_kv
+    Dv = v_width if v_ring is None else v_ring.shape[-1] // n_kv
+    v_rings = [] if v_ring is None else [v_ring]
     operands, in_specs = [], []
     if sink is not None:
         operands.append(jnp.tile(sink.astype(jnp.float32), rows)
@@ -586,13 +594,15 @@ def ring_attention(q, k_ring, v_ring, lens, sink=None, *, n_head, scale,
     n_head = rows * heads
     out = pl.pallas_call(
         functools.partial(_ring_kernel, scale=scale, window=window,
-                          n_kv=n_kv, sink=sink is not None, rows=rows),
+                          n_kv=n_kv, sink=sink is not None, rows=rows,
+                          v_width=0 if v_rings else v_width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(S,),
             in_specs=[pl.BlockSpec((1, n_head, Dk),
                                    lambda s, ln: (s, 0, 0))] + in_specs + [
-                pl.BlockSpec((1, R, n_kv * Dk), lambda s, ln: (s, 0, 0)),
-                pl.BlockSpec((1, R, n_kv * Dv), lambda s, ln: (s, 0, 0))],
+                pl.BlockSpec((1, R, n_kv * Dk), lambda s, ln: (s, 0, 0))] + [
+                pl.BlockSpec((1, R, n_kv * Dv), lambda s, ln: (s, 0, 0))
+                for _ in v_rings],
             out_specs=pl.BlockSpec((1, n_head, Dv),
                                    lambda s, ln: (s, 0, 0))),
         out_shape=jax.ShapeDtypeStruct((S, n_head, Dv), q.dtype),
@@ -600,7 +610,7 @@ def ring_attention(q, k_ring, v_ring, lens, sink=None, *, n_head, scale,
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(lens.astype(jnp.int32), q.reshape(S, n_head, Dk).astype(k_ring.dtype),
-      *operands, k_ring, v_ring)
+      *operands, k_ring, *v_rings)
     return out.reshape(q.shape[:-1] + (heads * Dv,))
 
 

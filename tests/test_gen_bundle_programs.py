@@ -1,6 +1,7 @@
 """The serving builders' programs, op for op: every program the six
 decoder builders make (``gen_lm``, ``hybrid_moe``, ``latent_moe``,
-``latent_moe_sparse``, ``block_moe``, ``window_moe``) is serialised and
+``latent_moe_sparse``, ``latent_moe_window``, ``block_moe``,
+``window_moe``) is serialised and
 its sha256 compared with ``tests/golden/gen_bundle_programs.json``.
 
 * ``toy``: prefill (or chunk), decode and train program at the module's
@@ -47,7 +48,7 @@ from paddle_tpu import models                           # noqa: E402
 from paddle_tpu.framework import unique_name_scope      # noqa: E402
 from paddle_tpu.models import (block_moe, gen_lm,       # noqa: E402
                                hybrid_moe, latent_moe, latent_moe_sparse,
-                               window_moe)
+                               latent_moe_window, window_moe)
 
 GOLDEN = os.path.join(ROOT, "tests", "golden", "gen_bundle_programs.json")
 TOY_SLOTS = 3
@@ -62,6 +63,8 @@ KINDS = {
                    "export_latent_model", True),
     "latent_moe_sparse": (latent_moe, latent_moe_sparse.SparseLatentConfig,
                           "export_latent_model", True),
+    "latent_moe_window": (latent_moe, latent_moe_window.WindowLatentConfig,
+                          "export_latent_model", True),
     "block_moe": (block_moe, block_moe.BlockMoEConfig,
                   "export_block_model", False),
     "window_moe": (window_moe, window_moe.WindowMoEConfig,
@@ -73,7 +76,8 @@ PUBLISHED = {"genlm_opt6.7b": "gen_lm",
              "kimi_k2.6_text": "latent_moe",
              "sdar_30b_a3b_chat": "block_moe",
              "glm_5.2": "latent_moe",
-             "mimo_v2_flash": "window_moe"}
+             "mimo_v2_flash": "window_moe",
+             "dots3_note_prev": "latent_moe_window"}
 
 CASES = [("toy", kind, prog) for kind in KINDS
          for prog in ("prefill", "decode", "train")] \

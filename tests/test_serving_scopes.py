@@ -27,7 +27,7 @@ from paddle_tpu.gen import GenPredictor
 from paddle_tpu.gen import predictor as predictor_mod
 from paddle_tpu.models import (block_moe, decoder, gen_lm, hybrid_decoder,
                                hybrid_moe, latent_moe, latent_moe_sparse,
-                               window_moe)
+                               latent_moe_window, window_moe)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FLAG = "jax_compilation_cache_include_metadata_in_key"
@@ -54,6 +54,8 @@ BUILDERS = {
     "latent_moe": (latent_moe, latent_moe.LatentMoEConfig,
                    ("chunk", "decode")),
     "latent_moe_sparse": (latent_moe, latent_moe_sparse.SparseLatentConfig,
+                          ("chunk", "decode")),
+    "latent_moe_window": (latent_moe, latent_moe_window.WindowLatentConfig,
                           ("chunk", "decode")),
     "block_moe": (block_moe, block_moe.BlockMoEConfig,
                   ("prefill", "decode")),

@@ -1196,3 +1196,134 @@ def test_ring_attention_of_paired_heads_compiles_for_v5e_in_place(one_chip,
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * S * R * HKV * D * 2, mem
     assert ("tpu_custom_call" in compiled.as_text()) == (rows != 256)
+
+
+# -- window layers of latent attention over a RING of latent rows beside full
+# layers of 128 heads under 64 index heads (``ops/mla_ops.py``'s
+# ``latent_window_*``; ``models/latent_moe.py`` with ``layer_types``) -------
+
+@pytest.mark.parametrize("form", ["step", "chunk"])
+def test_latent_ring_step_and_chunk_compile_for_v5e_in_place(one_chip, form):
+    """A window layer of latent attention at the long-document serving
+    cell's widths: 16 slots, a ring of 640 rows of 1152 lanes (1024
+    latent | 64 rotary | 64 zeros), 64 absorbed query heads, a window of
+    513.  The decode step: the row's scatter and the ring kernel with ONE
+    ring that is key and, its leading 1024 lanes, value.  A chunk of 1024
+    rows: both absorb products, the ring's 512 lead rows, the banded
+    flash kernel over 4 copies of the row (16 heads a copy: 128 query
+    rows a block), the ring's update.  The ring is donated: no copy of
+    it, and no temporary the size of every slot's."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mla_ops
+    S, R, W, L, H, C = 16, 640, 1152, 1024, 64, 1024
+    i32 = jnp.int32
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    ring = sds((S, R, W))
+    if form == "step":
+        fn = lambda q, row, ring, lens: mla_ops.latent_ring_step(
+            q, row, ring, lens, H, L, 1 / 16, 513, kernel=False)
+        args, donate, limit = [sds((S, H * W)), sds((S, W)), ring,
+                               sds((S,), i32)], 2, 16 << 20
+    else:
+        assert mla_ops.band_groups(C, H, 513) == 4
+        fn = lambda q, row, w, ring, slot, start, n: \
+            mla_ops.latent_window_chunk(q, row, w, ring, slot, start, n, H,
+                                        192, 64, 128, 1 / 16, 513,
+                                        interpret=False)
+        args, donate, limit = [sds((C, H * 256)), sds((C, W)),
+                               sds((L, H * 320)), ring, sds((), i32),
+                               sds((), i32), sds((), i32)], 3, 384 << 20
+    compiled = jax.jit(fn, donate_argnums=(donate,)).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert memory.alias_size_in_bytes >= S * R * W * 2, memory
+    assert memory.temp_size_in_bytes < limit, memory
+
+
+@pytest.mark.parametrize("pages", [64, 288])
+def test_latent_decode_of_128_heads_under_64_index_heads_compiles_for_v5e(
+        one_chip, pages):
+    """A full layer of the same cell: 128 absorbed query heads over the
+    640-wide row under a selection (twice the sparse-attention cell's
+    heads), and the decode step's indexer of 64 heads with its exact
+    top-2048 over the widest bucket."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A, dsa_ops
+    S, NP, PL, H, W, V = 16, 4608, 64, 128, 640, 512
+
+    def fn(q, cache, pt, select):
+        out = A._pallas_paged_attention(q, cache, None, pt,
+                                        _ragged_lens(S, pages, PL), H,
+                                        192 ** -0.5, interpret=False,
+                                        v_width=V, select=select)
+        assert out is not None, "shape gate refused the latent row"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, 1, H * W), jnp.bfloat16),
+                   ((NP, PL, W), jnp.bfloat16), ((S, pages), jnp.int32),
+                   ((S, 1, pages * PL), jnp.int32))
+    assert "tpu_custom_call" in hlo
+    if pages != 288:
+        return
+    Hi, Di, K = 64, 128, 2048
+
+    def index(q, w, cache, pt, lens):
+        rows = cache[pt].reshape(S, pages * PL, Di)
+        scores = dsa_ops.index_scores(q[:, None], rows, w[:, None])
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, 1, pages * PL), 2)
+        return dsa_ops.select_mask(scores, cols < lens[:, :, None], K) \
+            .astype(jnp.int32)
+
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                 sharding=one_chip)
+    compiled = jax.jit(index).lower(
+        sds((S, Hi, Di), jnp.bfloat16), sds((S, Hi), jnp.float32),
+        sds((NP, PL, Di), jnp.bfloat16), sds((S, pages), jnp.int32),
+        sds((S, 1), jnp.int32)).compile()
+    assert "sort" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20, \
+        compiled.memory_analysis()
+
+
+def test_a_full_layers_chunk_of_128_heads_compiles_for_v5e_in_place(one_chip):
+    """A full layer's chunk of the same cell, 1024 rows over the widest
+    page bucket a chunk takes (256 pages of 64 rows): 128 heads of 128 |
+    64 over the 640-wide row as cached, the indexer's 64 heads x 128 and
+    its exact top-2048 a query row.  Pools donated; no sort."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import dsa_ops, mla_ops
+    from paddle_tpu.ops.attention_ops import _paged_cache_update
+    C, S, PL, pages = 1024, 16, 64, 256
+    T = pages * PL
+
+    def fn(q, row, w_kvb, pool, table, pos, mask, qi, ki, wi, keys):
+        start = pos[0, 0]
+        keys, = _paged_cache_update(
+            (keys,), (ki[None],), table, (start + C).reshape(1, 1),
+            row_lens=mask > 0)
+        rows = keys[table[0]].reshape(T, 128)
+        scores = dsa_ops._query_blocks(
+            lambda qb, wb: dsa_ops.index_scores(qb, rows, wb), C, qi, wi)
+        select = dsa_ops.causal_select(scores, mask[0], 2048, start=start)
+        out, pool = mla_ops.mla_attention_chunk(
+            q, row, w_kvb, pool, table, start, mask > 0, 128, 128, 64, 128,
+            192 ** -0.5, select=select, interpret=False)
+        return out, pool, keys
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    pools = [sds((S * 288, PL, 640)), sds((S * 288, PL, 128))]
+    compiled = jax.jit(fn, donate_argnums=(3, 10)).lower(
+        sds((C, 128 * 192)), sds((C, 640)), sds((512, 128 * 256)), pools[0],
+        sds((1, pages), jnp.int32), sds((1, C), jnp.int32),
+        sds((1, C), jnp.float32), sds((C, 64, 128)), sds((C, 128)),
+        sds((C, 64), jnp.float32), pools[1]).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in hlo and "sort" not in hlo
+    assert memory.alias_size_in_bytes >= sum(
+        int(jnp.prod(jnp.asarray(p.shape))) * 2 for p in pools)
+    assert memory.temp_size_in_bytes < 384 << 20, memory
