@@ -819,28 +819,25 @@ def _c_mla_absorb(op, info):
 @rule("latent_window_attention")
 def _c_latent_window_attention(op, info):
     """A window layer of latent attention over ``T`` rows, ``T x
-    min(window, T)`` pairs.  A whole sequence EXPANDS K and V (2 L a
-    lane) and takes (nope + rope + v) lanes a head a pair; ONE CHUNK
-    over the slot's ring is absorbed: both halves of W_kvb against the
-    chunk's rows, then every head's score over a whole row and its
-    context over the row's value lanes.  A chunk reads of the ring the
+    min(window, T)`` pairs, EXPANDED: K and V of every head from the
+    latent rows (2 L a lane of W_kvb a row) and (nope + rope + v) lanes
+    a head a pair.  ONE CHUNK over the slot's ring expands the ``window
+    - 1`` rows before it beside its own; it reads of the ring the
     window's rows and writes its own, never every slot's ring."""
     q, w, lat = (_shape(info, op, s) for s in ("Q", "Wkvb", "Latent"))
     if q is None or w is None or lat is None or len(q) != 3 or \
             not _known(q[1], q[2], lat[2], *w):
         return None
     t, h, window = q[1], int(op.attr("n_head")), int(op.attr("window"))
-    pairs = t * min(window, t)
+    lanes = int(op.attr("nope_dim")) + int(op.attr("rope_dim")) \
+        + int(op.attr("v_dim"))
+    attend = 2 * t * min(window, t) * h * lanes
     if not op.input("Ring"):
-        lanes = int(op.attr("nope_dim")) + int(op.attr("rope_dim")) \
-            + int(op.attr("v_dim"))
-        return 2 * t * w[0] * w[1] + 2 * pairs * h * lanes, \
-            io_bytes(op, info)
+        return 2 * t * w[0] * w[1] + attend, io_bytes(op, info)
     item = _DTYPE_BYTES.get(str(info(op.input("Latent")[0]).dtype), 4)
-    bytes_ = (t * (q[2] + h * int(op.attr("v_dim")))
+    bytes_ = (t * (q[2] + h * int(op.attr("v_dim"))) + w[0] * w[1]
               + (2 * t + min(window, t)) * lat[2]) * item
-    return int(2 * t * w[0] * w[1] + 2 * pairs * h * (lat[2] + w[0])), \
-        int(bytes_)
+    return int(2 * (t + window - 1) * w[0] * w[1] + attend), int(bytes_)
 
 
 @rule("latent_window_step")

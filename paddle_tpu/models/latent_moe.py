@@ -43,10 +43,12 @@ precedes the untied head.
   full layer keeps in pages it keeps in a RING a slot (``state_vars``
   ``lat<i>_ring_c``, ``[num_slots, ring, its latent row]``, position
   ``p`` at row ``p mod ring``): its bytes are a constant of the bundle.
-  ``latent_window_attention`` is its whole-sequence form (expanded) and
-  its chunk form (absorbed: the ring's rows before the chunk, then the
-  chunk's own, under the band; the chunk's last rows left in the ring),
-  ``latent_window_step`` between two ``mla_absorb`` its decode step.
+  ``latent_window_attention`` is its whole-sequence form and its chunk
+  form, both EXPANDED (a chunk: K and V of every head from the ring's
+  rows before the chunk and the chunk's own, under the band; the
+  chunk's last rows left in the ring), ``latent_window_step`` between
+  two ``mla_absorb`` its decode step (absorbed: one row a slot over a
+  ring it reads once).
   ``gen_meta.json`` then carries ``window_attention`` beside
   ``sparse_attention``, and the chunk program one more feed,
   ``gen_slot``.  Every ``full_attention`` layer there holds its own
@@ -571,16 +573,14 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
             decode_fetches(x, stats, S, hp, "lat"))
 
 
-def _window_section(hp, rows):
+def _window_section(hp):
     """``gen_meta.json``'s ``window_attention``, the keys
     ``window_moe``'s has: which layer keeps a ring and which pages, and
     what the predictor counts a step's reads and a chunk's pairs from.
     ``heads``: the (query heads, K/V heads) the banded kernel is handed
-    for a window layer's chunk of ``rows`` rows (``mla_ops.band_groups``:
-    the ONE latent row goes as that many copies); ``full_heads``: a full
-    layer's heads over its one cached row."""
+    for a window layer's chunk (expanded: every head its own K/V head);
+    ``full_heads``: a full layer's heads over its one cached row."""
     import jax.numpy as jnp
-    from paddle_tpu.ops.mla_ops import band_groups
     item = jnp.dtype(hp.dtype).itemsize
     win, full = hp.attention(hp.window_layers[0]), hp.paged_layers
     return {
@@ -589,7 +589,7 @@ def _window_section(hp, rows):
         "ring_vars": ring_var_names(hp),
         "row_bytes": [hp.attention(i)["row"] * item
                       for i in hp.window_layers],
-        "heads": [win["H"], band_groups(rows, win["H"], win["window"])],
+        "heads": [win["H"], win["H"]],
         "full_heads": [hp.attention(full[0])["H"], 1] if full else None,
     }
 
@@ -614,8 +614,7 @@ def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
             own["sparse_attention"] = {"top_k": int(hp.index_topk),
                                        "indexers": len(hp.full_layers)}
         if hp.window_layers:
-            own["window_attention"] = _window_section(
-                hp, own["prefill_chunks"][-1])
+            own["window_attention"] = _window_section(hp)
         return own
 
     return export_bundle(

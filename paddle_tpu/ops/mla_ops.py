@@ -33,15 +33,17 @@ the rows under its diagonal and not its page bucket.
 A WINDOW layer of latent attention (``models/latent_moe.py`` with
 ``layer_types``: row ``t`` sees rows ``u <= t`` with ``t - u < window``)
 keeps, where a full layer keeps pages, a RING of latent rows a slot
-(``[num_slots, ring, W]``, position ``p`` at row ``p mod ring``), and
-runs the same absorbed forms over it with ``window_ops``'s kernels as
-they are: ``latent_window_step`` (the decode step: the row's scatter and
-the ring kernel handed ONE ring, whose row is every head's key and, in
-its leading ``v_width`` lanes, their value) and
-``latent_window_attention`` (one chunk: the ring's rows before the chunk
-led in front of the chunk's own under the banded flash kernel, the one
-row copied ``band_groups`` times so that the kernel's block rule admits
-64 heads; a whole sequence: expanded, the band as the kernel's).
+(``[num_slots, ring, W]``, position ``p`` at row ``p mod ring``), under
+``window_ops``'s kernels.  ``latent_window_step`` is the decode step,
+ABSORBED: the row's scatter and the ring kernel handed ONE ring, whose
+row is every head's key and, in its leading ``v_width`` lanes, their
+value (one row a slot over a ring read once: there the absorbed form is
+the cheap one).  ``latent_window_attention`` is the prefill, EXPANDED
+as ``mla_attention`` (``mla_expand``: (nope + rope + v) lanes a head a
+pair where the absorbed form takes 2 L + rope): a whole sequence, or
+one chunk, whose K and V of every head are made in one product from
+the ring's rows before the chunk and the chunk's own, under the banded
+flash kernel with every head its own K/V head.
 ``head_gate`` multiplies head ``j``'s attention output by ``sigmoid`` of
 the gate's ``j``-th logit.
 
@@ -167,6 +169,28 @@ def _split_kvb(w_kvb, n_head, nope, v_dim):
     return w[..., :nope], w[..., nope:]
 
 
+def mla_expand(latent, w_kvb, n_head, nope, rope_dim, v_dim, dtype):
+    """K and V of every head EXPANDED from latent rows: ``latent`` [T,
+    >= L + rope] (``c_kv`` after its norm | the rotated shared key |
+    lanes that pad the cached row, not read) -> ``k_nope`` [T, H, nope],
+    ``k_rope`` [T, rope] (every head's), ``v`` [T, H, v], in ``dtype``;
+    the products accumulate in float32."""
+    L = w_kvb.shape[0]
+    w_k, w_v = _split_kvb(w_kvb, n_head, nope, v_dim)
+    c_kv = latent[:, :L]
+    k_nope = jnp.einsum("tl,lhd->thd", c_kv, w_k,
+                        preferred_element_type=jnp.float32).astype(dtype)
+    v = jnp.einsum("tl,lhd->thd", c_kv, w_v,
+                   preferred_element_type=jnp.float32).astype(dtype)
+    return k_nope, latent[:, L:L + rope_dim].astype(dtype), v
+
+
+def _keys(k_nope, k_rope):
+    """Every head's key: the shared rotary key behind its ``k_nope``."""
+    return jnp.concatenate([k_nope, jnp.broadcast_to(
+        k_rope[:, None], k_nope.shape[:2] + k_rope.shape[-1:])], axis=-1)
+
+
 def mla_attention(q, latent, w_kvb, mask, n_head, nope, rope_dim, v_dim,
                   scale, block=MLA_QUERY_BLOCK, flash=None, interpret=None,
                   select=None):
@@ -191,18 +215,13 @@ def mla_attention(q, latent, w_kvb, mask, n_head, nope, rope_dim, v_dim,
     from paddle_tpu.ops import attention_ops
     if interpret is None:
         interpret = attention_ops._use_interpret()
-    T, L = q.shape[0], w_kvb.shape[0]
-    w_k, w_v = _split_kvb(w_kvb, n_head, nope, v_dim)
-    c_kv, k_rope = latent[:, :L], latent[:, L:L + rope_dim]
-    k_nope = jnp.einsum("tl,lhd->thd", c_kv, w_k,
-                        preferred_element_type=jnp.float32).astype(q.dtype)
-    v = jnp.einsum("tl,lhd->thd", c_kv, w_v,
-                   preferred_element_type=jnp.float32).astype(q.dtype)
+    T = q.shape[0]
+    k_nope, k_rope, v = mla_expand(latent, w_kvb, n_head, nope, rope_dim,
+                                   v_dim, q.dtype)
     qh = q.reshape(T, n_head, nope + rope_dim)
     if not interpret if flash is None else flash:
         heads = lambda a: a.transpose(1, 0, 2)            # [H, T, D]
-        k = jnp.concatenate([k_nope, jnp.broadcast_to(
-            k_rope[:, None], (T, n_head, rope_dim))], axis=-1)
+        k = _keys(k_nope, k_rope)
         if select is not None:
             from paddle_tpu.ops import dsa_ops
             out = dsa_ops.selected_attention(
@@ -413,39 +432,18 @@ def latent_window_attention(q, latent, w_kvb, n_head, nope, rope_dim, v_dim,
                             scale, window, interpret=None):
     """A WHOLE sequence under the band, nothing cached (the training
     forward): ``q`` [T, H * (nope + rope)] (rotated), ``latent`` [T, >=
-    L + rope]; K and V of every head EXPANDED from the latent as
-    ``mla_attention`` does, then ``window_ops``'s banded attention with
-    every head its own K/V head (row ``t`` sees rows ``u <= t`` with ``t
-    - u < window``; real rows first, so no real row sees a pad row).
-    Returns [T, H * v] in ``q``'s type."""
+    L + rope]; K and V of every head EXPANDED from the latent
+    (``mla_expand``), then ``window_ops``'s banded attention with every
+    head its own K/V head (row ``t`` sees rows ``u <= t`` with ``t - u <
+    window``; real rows first, so no real row sees a pad row).  Returns
+    [T, H * v] in ``q``'s type."""
     from paddle_tpu.ops.window_ops import prefill_attention
-    T, L = q.shape[0], w_kvb.shape[0]
-    w_k, w_v = _split_kvb(w_kvb, n_head, nope, v_dim)
-    c_kv, k_rope = latent[:, :L], latent[:, L:L + rope_dim]
-    k_nope = jnp.einsum("tl,lhd->thd", c_kv, w_k,
-                        preferred_element_type=jnp.float32).astype(q.dtype)
-    v = jnp.einsum("tl,lhd->thd", c_kv, w_v,
-                   preferred_element_type=jnp.float32).astype(q.dtype)
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(
-        k_rope[:, None].astype(q.dtype), (T, n_head, rope_dim))], axis=-1)
-    return prefill_attention(q, k.reshape(T, -1), v.reshape(T, -1), None,
-                             n_head, n_head, scale, window,
-                             interpret=interpret)
-
-
-def band_groups(rows, n_head, window):
-    """K/V heads the banded flash kernel is handed for ONE latent row
-    under ``n_head`` absorbed query heads: the kernel takes the ``G``
-    query heads of a K/V head as the rows of one product, ``G x query
-    block`` = ``window_ops.FLASH_LEFT_ROWS`` of them, and its query
-    block is whole key blocks, so 64 heads go as 4 groups of 16 over 4
-    copies of the row (128 query rows a block).  1 where no grouping
-    makes whole blocks of ``rows`` (the composed form runs)."""
-    from paddle_tpu.ops.window_ops import flash_blocks
-    for groups in (g for g in range(1, n_head + 1) if n_head % g == 0):
-        if flash_blocks(rows, n_head // groups, window) is not None:
-            return groups
-    return 1
+    T = q.shape[0]
+    k_nope, k_rope, v = mla_expand(latent, w_kvb, n_head, nope, rope_dim,
+                                   v_dim, q.dtype)
+    return prefill_attention(q, _keys(k_nope, k_rope).reshape(T, -1),
+                             v.reshape(T, -1), None, n_head, n_head, scale,
+                             window, interpret=interpret)
 
 
 def latent_window_chunk(q, row, w_kvb, ring, slot, start, n, n_head, nope,
@@ -453,31 +451,37 @@ def latent_window_chunk(q, row, w_kvb, ring, slot, start, n, n_head, nope,
     """ONE CHUNK of a prompt through a window layer of latent attention.
     ``q`` [C, H * (nope + rope)] (rotated) and ``row`` [C, W] (the latent
     rows as cached) stand at positions ``start ..``, the first ``n`` of
-    them real; ``ring`` [num_slots, R, W].  ABSORBED, as the decode
-    step: the keys are the slot's ring rows of the positions before
-    ``start`` (``window_ops.ring_lead``) followed by the chunk's own
-    rows, the values their leading ``L`` lanes, under the band
-    (``window_ops``'s banded flash kernel as it is, the row copied a
-    group of heads: ``band_groups``); the chunk's last real rows go
+    them real; ``ring`` [num_slots, R, W].  EXPANDED, as the whole
+    sequence: K and V of every head are made from the slot's ring rows
+    of the positions before ``start`` (``window_ops.ring_lead``) and the
+    chunk's own rows in ONE product, and the queries go as they come
+    under the band, every head its own K/V head (``window_ops``'s banded
+    flash kernel where its block rule admits the rows, else the composed
+    form: counted once a lowering, ``attention.latent_window_kernel`` /
+    ``attention.latent_window_composed``); the chunk's last real rows go
     through the ring.  Returns ``(out [C, H * v], ring)``."""
-    from paddle_tpu.ops.window_ops import (lead_rows, prefill_attention,
-                                           ring_after, ring_lead)
-    C, L, W = q.shape[0], w_kvb.shape[0], ring.shape[-1]
+    from paddle_tpu.ops.window_ops import (flash_blocks, lead_rows,
+                                           prefill_attention, ring_after,
+                                           ring_lead)
+    from paddle_tpu.profiler import runtime_metrics
+    C = q.shape[0]
+    runtime_metrics.inc(
+        "attention.latent_window_composed"
+        if flash_blocks(C, 1, window) is None
+        else "attention.latent_window_kernel")
     own = jax.lax.dynamic_index_in_dim(ring, slot, 0, keepdims=False)
-    groups = band_groups(C, n_head, window)
-    lead, held = ring_lead(own, start, lead_rows(C, n_head // groups,
-                                                 window))
-    q_lat = mla_absorb(q, w_kvb, n_head, nope, v_dim, "q",
-                       pad=W - L - rope_dim)
-    keys, lead = row.astype(q.dtype), lead.astype(q.dtype)
-    wide = lambda a: jnp.tile(a, (1, groups))
-    ctx = prefill_attention(
-        q_lat, wide(keys), wide(keys[:, :L]), None, n_head, groups, scale,
-        window, before=(wide(lead), wide(lead[:, :L]), held),
+    lead, held = ring_lead(own, start, lead_rows(C, 1, window))
+    k_nope, k_rope, v = mla_expand(
+        jnp.concatenate([lead, row.astype(lead.dtype)]), w_kvb, n_head,
+        nope, rope_dim, v_dim, q.dtype)
+    rows = k_nope.shape[0]
+    out = prefill_attention(
+        q, _keys(k_nope, k_rope).reshape(rows, -1), v.reshape(rows, -1),
+        None, n_head, n_head, scale, window, before=(None, None, held),
         interpret=interpret)
     ring = jax.lax.dynamic_update_index_in_dim(
         ring, ring_after(own, row, start, n), slot, 0)
-    return mla_absorb(ctx, w_kvb, n_head, nope, v_dim, "o"), ring
+    return out, ring
 
 
 def _infer_latent_window(op, block):
@@ -499,8 +503,9 @@ def latent_window_attention_lower(ctx):
     ONE CHUNK of a prompt: Ring [num_slots, ring, W] persistable; Slot
     [1, 1] int32; Pos [1, T] int32 the rows' positions ``start ..``.
     The chunk attends the ring's rows of the ``window - 1`` positions
-    before ``start`` followed by its own, absorbed, and its last real
-    rows go through the slot's ring; RingOut names the ring itself."""
+    before ``start`` followed by its own, expanded alike, and its last
+    real rows go through the slot's ring; RingOut names the ring
+    itself."""
     q, latent, w_kvb = ctx.input("Q")[0], ctx.input("Latent")[0], \
         ctx.input("Wkvb")
     sizes = (int(ctx.attr("n_head")), int(ctx.attr("nope_dim")),

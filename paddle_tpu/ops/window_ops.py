@@ -55,9 +55,12 @@ widths and a rotary on the LEADING lanes of a head.
 Both prefill ops take ``[1, T, H * Dk]`` queries over ``Hkv`` K/V heads
 (query head ``h`` reads K/V head ``h // (H / Hkv)``); the ``G = H / Hkv``
 query heads of a K/V head share its key blocks in one ``[G * rows, Dk] x
-[Dk, keys]`` product.  Where ``T`` is not whole blocks the composed
-``[Hkv, G, T, T]`` form runs (toy sizes, and what the tests hold the
-kernel to).
+[Dk, keys]`` product, over head-major copies of the operands; with ONE
+query head a K/V head and heads of whole 128-lane tiles (an expanded
+latent chunk, ``mla_ops.latent_window_attention``) a head's blocks are
+columns of the operands as they lie and nothing is copied.  Where ``T``
+is not whole blocks (``flash_blocks``) the composed ``[Hkv, G, T, T]``
+form runs (toy sizes, and what the tests hold the kernel to).
 
 Op scopes on the device trace: ``ptop_window_attention__*`` (a window
 layer's prefill, whole or a chunk), ``ptop_window_attention_step*`` (its
@@ -202,9 +205,20 @@ def flash_blocks(T, group, window, keys=None):
     """(query rows, key rows) a block at ``T`` query rows and ``group``
     query heads a K/V head, over ``keys`` key rows where the call is
     causal (None: ``T`` of them); None where they are not whole blocks
-    of both."""
+    of both.  ``FLASH_LEFT_ROWS`` left rows a step over the kind's key
+    block, but under a band with ONE query head a K/V head and a power
+    of two of rows short of that: square blocks."""
     bq = max(FLASH_LEFT_ROWS // group, 16)
     bk = BAND_KEY_BLOCK if window else CAUSAL_KEY_BLOCK
+    if window and group == 1 and bk <= T < bq and not T & (T - 1):
+        # every head its own K/V head (an expanded latent chunk) and
+        # fewer rows than a left side: no heads to stack.  Square blocks
+        # of up to 512 rows: a grid step rescales its [bq, Dv] sums
+        # whatever the key block's width, so at 1024 rows under a window
+        # of 513 the chip took 0.58 ms with 512 x 512 (1024 keys a row
+        # computed, 256 steps), 0.89 with 256 x 256, 1.38 with 256 x 128
+        # and 2.13 with 1024 x 128
+        bq = bk = min(T, 512)
     if T % bq or bq % 16:
         return None
     if bq % bk if window else (T if keys is None else keys) % bk:
@@ -242,7 +256,7 @@ def key_blocks_computed(T, group, window, start=0, keys=None):
 
 
 def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
-                  select=False):
+                  select=False, flat=False):
     """One (K/V head, query block); the key blocks that meet the band
     (``window`` > 0: the keys begin with ``lead`` blocks of the rows
     before the chunk, of which those from index ``s`` on are real) or
@@ -252,7 +266,10 @@ def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
     the K/V head are the rows of ONE product.  ``select`` (causal): an
     int8 block ``[bq, bk]`` of a selection comes beside the key block,
     and a score counts where it marks the pair (the selection is causal
-    already), for every one of the ``G`` heads."""
+    already), for every one of the ``G`` heads.  ``flat`` (``G`` = 1):
+    the blocks are ``[rows, lanes]`` columns of the operands as they lie
+    (``[T, H * D]``), not ``[1, (G,) rows, lanes]`` of head-major
+    copies."""
     if sink:
         sink_ref, *refs = refs
     q_ref, k_ref, v_ref, *refs = refs
@@ -260,7 +277,8 @@ def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
         sel_ref, *refs = refs
     o_ref, acc, m_scr, l_scr = refs
     i, j = pl.program_id(1), pl.program_id(2)
-    G = q_ref.shape[1]
+    G = 1 if flat else q_ref.shape[1]
+    block = (lambda ref: ref[...]) if flat else (lambda ref: ref[0])
     s = s_ref[0]
     # the key block this step holds (before the caller's clamp), and the
     # key index the query block's first row stands at
@@ -278,8 +296,8 @@ def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
             l_scr[...] = jnp.zeros_like(l_scr)
 
     def update(masked):
-        q = q_ref[0].reshape(G * bq, q_ref.shape[-1])
-        k, v = k_ref[0], v_ref[0]
+        q = block(q_ref).reshape(G * bq, q_ref.shape[-1])
+        k, v = block(k_ref), block(v_ref)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         if select:
@@ -319,14 +337,20 @@ def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _():
-        o_ref[0] = (acc[...] / l_scr[...]).reshape(o_ref.shape[1:]) \
-            .astype(o_ref.dtype)
+        out = acc[...] / l_scr[...]
+        if flat:
+            o_ref[...] = out.astype(o_ref.dtype)
+        else:
+            o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
 
 
-def _led(k, v, before):
+def _led(k, v, before, Tq):
     """``k`` / ``v`` with the rows ``before`` = ``(k rows, v rows, n)``
-    led in front of them, and the first key index that is real (the last
-    ``n`` of the rows before are)."""
+    led in front of them (``(None, None, n)``: ``k`` / ``v`` hold them
+    in front of the ``Tq`` own rows already), and the first key index
+    that is real (the last ``n`` of the rows before are)."""
+    if before[0] is None:
+        return k, v, k.shape[0] - Tq - before[2]
     return (jnp.concatenate([before[0].astype(k.dtype), k]),
             jnp.concatenate([before[1].astype(v.dtype), v]),
             before[0].shape[0] - before[2])
@@ -346,7 +370,9 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
     ``Tk`` whole key blocks).  Banded: ``k`` / ``v`` [Tq, ...] are the
     chunk's own rows and ``before`` = ``(k rows, v rows, n)`` the
     ``lead_rows`` rows that stand before them, of which the LAST ``n``
-    (traced or not) are real; None: none is.  ``select`` (causal alone)
+    (traced or not) are real; None: none is; ``(None, None, n)``: ``k``
+    / ``v`` [``lead_rows`` + Tq, ...] hold them in front of the chunk's
+    own already.  ``select`` (causal alone)
     [Tq, Tk] int8: query row ``r`` attends the keys it marks and no
     other, whatever its head (a selection holds no key behind its row:
     the diagonal is not looked at again).  ``blocks`` is for the tests:
@@ -366,20 +392,38 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
         if before is None:
             before = (jnp.zeros((lead * bk, k.shape[-1]), k.dtype),
                       jnp.zeros((lead * bk, v.shape[-1]), v.dtype), 0)
-        k, v, start = _led(k, v, before)
+        k, v, start = _led(k, v, before, Tq)
     n_k = k.shape[0] // bk
     n_j = lead + per if window else n_k
-    # head-major: a K/V head's G query heads side by side
-    qh = q.reshape(Tq, n_kv_head, G, Dk).transpose(1, 2, 0, 3)
-    kh = k.reshape(-1, n_kv_head, Dk).transpose(1, 0, 2)
-    vh = v.reshape(-1, n_kv_head, Dv).transpose(1, 0, 2)
     if window:
-        kv = lambda h, i, j, s: (h, i * per + j, 0)
+        kb = lambda i, j, s: i * per + j
     else:
         # a block above the diagonal is not computed: hand the kernel the
         # diagonal's again, which is not copied a second time
-        kv = lambda h, i, j, s: (h, jnp.minimum(jnp.minimum(
-            j, (s[0] + i * bq + bq - 1) // bk), n_k - 1), 0)
+        kb = lambda i, j, s: jnp.minimum(jnp.minimum(
+            j, (s[0] + i * bq + bq - 1) // bk), n_k - 1)
+    # one query head a K/V head, whole lane tiles a head: a head's blocks
+    # are columns of the rows as they lie
+    flat = G == 1 and not (Dk % 128 or Dv % 128)
+    if flat:
+        qh, kh, vh = q, k, v
+        kv = lambda h, i, j, s: (kb(i, j, s), h)
+        q_spec, k_spec, v_spec, o_spec = (
+            pl.BlockSpec((bq, Dk), lambda h, i, j, s: (i, h)),
+            pl.BlockSpec((bk, Dk), kv), pl.BlockSpec((bk, Dv), kv),
+            pl.BlockSpec((bq, Dv), lambda h, i, j, s: (i, h)))
+        out_shape = (Tq, n_head * Dv)
+    else:
+        # head-major: a K/V head's G query heads side by side
+        qh = q.reshape(Tq, n_kv_head, G, Dk).transpose(1, 2, 0, 3)
+        kh = k.reshape(-1, n_kv_head, Dk).transpose(1, 0, 2)
+        vh = v.reshape(-1, n_kv_head, Dv).transpose(1, 0, 2)
+        kv = lambda h, i, j, s: (h, kb(i, j, s), 0)
+        q_spec, k_spec, v_spec, o_spec = (
+            pl.BlockSpec((1, G, bq, Dk), lambda h, i, j, s: (h, 0, i, 0)),
+            pl.BlockSpec((1, bk, Dk), kv), pl.BlockSpec((1, bk, Dv), kv),
+            pl.BlockSpec((1, G, bq, Dv), lambda h, i, j, s: (h, 0, i, 0)))
+        out_shape = (n_kv_head, G, Tq, Dv)
     operands, in_specs = [], []
     if sink is not None:
         # the sink a row of the left side: [Hkv, G * bq, 1]
@@ -391,29 +435,27 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
     selected = []
     if select is not None:
         selected = [pl.BlockSpec(
-            (bq, bk), lambda h, i, j, s: (i, kv(h, i, j, s)[1]))]
+            (bq, bk), lambda h, i, j, s: (i, kb(i, j, s)))]
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, window=window, bq=bq,
                           bk=bk, lead=lead, sink=sink is not None,
-                          select=select is not None),
+                          select=select is not None, flat=flat),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n_kv_head, Tq // bq, n_j),
-            in_specs=in_specs + [
-                pl.BlockSpec((1, G, bq, Dk), lambda h, i, j, s: (h, 0, i, 0)),
-                pl.BlockSpec((1, bk, Dk), kv),
-                pl.BlockSpec((1, bk, Dv), kv)] + selected,
-            out_specs=pl.BlockSpec((1, G, bq, Dv),
-                                   lambda h, i, j, s: (h, 0, i, 0)),
+            in_specs=in_specs + [q_spec, k_spec, v_spec] + selected,
+            out_specs=o_spec,
             scratch_shapes=[pltpu.VMEM((G * bq, Dv), jnp.float32),
                             pltpu.VMEM((G * bq, 1), jnp.float32),
                             pltpu.VMEM((G * bq, 1), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((n_kv_head, G, Tq, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(jnp.asarray(start, jnp.int32).reshape(1), *operands, qh, kh, vh,
       *(() if select is None else (select.astype(jnp.int8),)))
+    if flat:
+        return out
     return out.transpose(2, 0, 1, 3).reshape(Tq, n_head * Dv)
 
 
@@ -431,8 +473,8 @@ def prefill_attention(q, k, v, sink, n_head, n_kv_head, scale, window,
             interpret=_use_interpret() if interpret is None else interpret)
     first = 0
     if before is not None:
-        start = before[0].shape[0]
-        k, v, first = _led(k, v, before)
+        k, v, first = _led(k, v, before, q.shape[0])
+        start = k.shape[0] - q.shape[0]
     return composed_attention(q, k, v, n_head, n_kv_head, scale, window,
                               sink, start, first, select)
 

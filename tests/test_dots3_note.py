@@ -313,17 +313,23 @@ def test_the_ring_kernel_over_latent_rows_is_the_composed_step(lens):
             assert not np.asarray(got)[s].any()
 
 
-@pytest.mark.parametrize("H, groups", [(16, 1), (32, 2)])
-@pytest.mark.parametrize("start, n", [(0, 128), (7, 100), (300, 128)])
-def test_the_banded_kernel_over_a_ring_is_the_composed_chunk(H, groups,
+@pytest.mark.parametrize("nope, vd", [(96, 128), (24, 16)],
+                         ids=["lane_tiles", "toy_lanes"])
+@pytest.mark.parametrize("start, n", [(0, 128), (7, 100), (30, 128),
+                                      (300, 128)],
+                         ids=["first", "pad_rows", "in_window", "past_wrap"])
+def test_the_banded_kernel_over_a_ring_is_the_composed_chunk(nope, vd,
                                                              start, n):
-    """A chunk of 128 rows, absorbed, over the ring's lead rows and its
-    own: the banded flash kernel as ``window_ops`` has it, the one latent
-    row copied a group of 16 heads, against the composed form; the first
-    chunk, one with a short lead and pad rows, and one past the wrap."""
-    C, L, R, nope, vd, window, ring_rows = 128, 96, 32, 24, 16, 40, 48
-    assert mla_ops.band_groups(C, H, window) == groups
-    key = jax.random.split(jax.random.PRNGKey(H + start), 4)
+    """A chunk of 128 rows, EXPANDED, over the ring's lead rows and its
+    own: the banded flash kernel with every head its own K/V head (heads
+    of whole 128-lane tiles: its blocks are read where the rows lie;
+    toy lanes: head-major copies) against the composed form; the first
+    chunk, one with a short lead and pad rows, one whose lead lies
+    inside the window, and one past the ring's wrap."""
+    C, H, L, R, window, ring_rows = 128, 4, 96, 32, 40, 48
+    assert window_ops.flash_blocks(C, 1, window) == (128, 128)
+    assert window_ops.lead_rows(C, 1, window) == 128
+    key = jax.random.split(jax.random.PRNGKey(nope + start), 4)
     q = jax.random.normal(key[0], (C, H * (nope + R)), jnp.float32) * 0.3
     row = jax.random.normal(key[1], (C, 128), jnp.float32)
     w_kvb = jax.random.normal(key[2], (L, H * (nope + vd)), jnp.float32) \
@@ -334,7 +340,7 @@ def test_the_banded_kernel_over_a_ring_is_the_composed_chunk(H, groups,
     blocks = window_ops.flash_blocks
     try:
         window_ops.flash_blocks = lambda *a, **k: None
-        assert mla_ops.band_groups(C, H, window) == 1
+        assert window_ops.lead_rows(C, 1, window) == window - 1
         want, ring_c = mla_ops.latent_window_chunk(*args)
     finally:
         window_ops.flash_blocks = blocks
@@ -346,9 +352,96 @@ def test_the_banded_kernel_over_a_ring_is_the_composed_chunk(H, groups,
     assert np.array_equal(ring_c[2], ring[2])
 
 
+def test_the_expanded_chunk_is_the_absorbed_steps_attention():
+    """The chunk form expands K and V where the decode step absorbs
+    W_kvb into the query and out of the context: one row's attention
+    over the same ring rows is the same either way."""
+    H, L, R, nope, vd, W, window, ring_rows = 2, 48, 8, 24, 16, 128, 9, 12
+    key = jax.random.split(jax.random.PRNGKey(9), 4)
+    rows = jax.random.normal(key[0], (30, W), jnp.float32)
+    q = jax.random.normal(key[1], (1, H * (nope + R)), jnp.float32) * 0.3
+    w_kvb = jax.random.normal(key[2], (L, H * (nope + vd)), jnp.float32) \
+        * 0.1
+    ring = window_ops.ring_of(rows[:29], 28, ring_rows)[None]
+    chunk, ring_c = mla_ops.latent_window_chunk(
+        q, rows[29:], w_kvb, ring, 0, jnp.int32(29), jnp.int32(1), H, nope,
+        R, vd, 0.2, window)
+    ctx, ring_s = mla_ops.latent_ring_step(
+        mla_ops.mla_absorb(q, w_kvb, H, nope, vd, "q", pad=W - L - R),
+        rows[29:], ring, jnp.asarray([30], jnp.int32), H, L, 0.2, window)
+    step = mla_ops.mla_absorb(ctx, w_kvb, H, nope, vd, "o")
+    assert np.array_equal(ring_c, ring_s)
+    assert np.allclose(chunk, step, atol=2e-5)
+
+
+def test_a_chunk_program_at_the_cells_widths_counts_six_kernels():
+    """``attention.latent_window_kernel`` / ``..._composed`` count, once
+    a lowering, which form a window layer's chunk took: the long-document
+    cell's chunk program (its adapter's configuration, BUILT, no weight
+    allocated; its six ``latent_window_attention`` ops traced over
+    shapes alone, 1024 rows a chunk) takes the banded kernel six times
+    and the composed form never."""
+    from lib import models as adapters
+    from paddle_tpu.framework import unique_name_scope
+    from paddle_tpu.ops import registry
+    from paddle_tpu.profiler import runtime_metrics
+    with open(os.path.join(BENCH, "configs", "dots3_note_prev.json")) as f:
+        published = json.load(f)
+    kept = {}
+    export = latent_moe.export_latent_model
+    try:
+        latent_moe.export_latent_model = lambda path, hp, **kw: kept.update(
+            hp=hp, **kw)
+        adapters.adapter_of(published).export("unused", published)
+    finally:
+        latent_moe.export_latent_model = export
+    hp, page_len = kept["hp"], kept["page_len"]
+    rows = decoder.chunk_rows(page_len, kept["prompt_buckets"],
+                              hp.max_len)[-1]
+    main = fluid.Program()
+    with unique_name_scope(""), fluid.program_guard(main, fluid.Program()):
+        latent_moe.build_chunk_program(
+            hp, kept["num_slots"], page_len,
+            kept["num_slots"] * hp.max_len // page_len)
+    block = main.global_block()
+    ops = [op for op in block.ops if op.type == "latent_window_attention"]
+    assert rows == 1024 and len(ops) == 6
+
+    def trace(op):
+        names = list(op.input_arg_names)
+        shapes = [jax.ShapeDtypeStruct(
+            tuple(rows if d < 0 else d for d in block.var(n).shape),
+            jnp.dtype(str(block.var(n).dtype))) for n in names]
+
+        def lower(*arrays):
+            ctx = registry.LowerContext(op, dict(zip(names, arrays)), block)
+            registry.lookup(op.type).lower(ctx)
+            return ctx.outputs
+
+        return jax.eval_shape(lower, *shapes)
+
+    counted = ("attention.latent_window_kernel",
+               "attention.latent_window_composed")
+    before = [runtime_metrics.counter(n) for n in counted]
+    for op in ops:
+        out = trace(op)
+        assert out[op.output("Out")[0]].shape == (1, rows, 64 * 128)
+        assert out[op.output("RingOut")[0]].shape == (16, 640, 1152)
+    assert [runtime_metrics.counter(n) - b
+            for n, b in zip(counted, before)] == [6, 0]
+    # a chunk the block rule refuses is counted as composed
+    q = jnp.zeros((24, 2 * 32), jnp.float32)
+    mla_ops.latent_window_chunk(
+        q, jnp.zeros((24, 128)), jnp.zeros((48, 2 * 40)),
+        jnp.zeros((1, 12, 128)), 0, jnp.int32(0), jnp.int32(24), 2, 24, 8,
+        16, 0.2, 9)
+    assert [runtime_metrics.counter(n) - b
+            for n, b in zip(counted, before)] == [6, 1]
+
+
 def test_the_whole_sequence_form_is_the_chunk_forms_band():
-    """The training forward's expanded attention under the band equals
-    the serving chunk's absorbed one over an empty ring."""
+    """The training forward's attention under the band equals the
+    serving chunk's over an empty ring."""
     T, H, L, R, nope, vd, window = 24, 2, 48, 8, 24, 16, 9
     key = jax.random.split(jax.random.PRNGKey(5), 3)
     q = jax.random.normal(key[0], (T, H * (nope + R)), jnp.float32) * 0.3
@@ -454,6 +547,14 @@ def test_the_bundle_checks_and_every_new_op_has_its_rules(bundle_dir):
     # a slot's window: 9 rows x 2 heads x (128 + 48) lanes x 2, two layers
     assert by_type["latent_window_step"]["flops"] \
         == 2 * SLOTS * WINDOW * 2 * (128 + 48) * 2
+    # a chunk of 16 rows, expanded: K and V of 2 heads from its 16 rows
+    # and the 8 before them (48 x 80 of W_kvb a row), then 16 x 9 pairs
+    # x 2 heads x (24 + 8 + 16) lanes, two layers
+    chunk = cost.estimate_at(pre[0], {
+        n: [1, 16] for n in ("gen_ids", "gen_pos", "gen_mask", "gen_last")})
+    assert chunk.by_op_type()["latent_window_attention"]["flops"] \
+        == 2 * (2 * (16 + WINDOW - 1) * 48 * 80
+                + 2 * 16 * WINDOW * 2 * 48)
     # the whole-sequence form is the training forward's, with its rules
     train = fluid.Program()
     with fluid.program_guard(train, fluid.Program()):
@@ -558,5 +659,10 @@ def test_config_takes_the_published_keys():
         f"lat{i}_paged_ik" for i in (0, 1, 5)]
     assert latent_moe.ring_var_names(hp) == [
         f"lat{i}_ring_c" for i in (2, 3, 4, 6, 7, 8)]
-    assert mla_ops.band_groups(1024, 64, 513) == 4
+    # a window layer's chunk goes expanded, every head its own K/V head,
+    # and the banded kernel's block rule admits its 1024 rows
+    hp.dtype = "bfloat16"
+    assert latent_moe._window_section(hp)["heads"] == [64, 64]
+    assert window_ops.flash_blocks(1024, 1, 513) is not None
+    assert window_ops.lead_rows(1024, 1, 513) == 512
     assert adapter.param_count(published) == pytest.approx(3.09e9, rel=0.01)

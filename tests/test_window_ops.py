@@ -140,6 +140,124 @@ def test_the_band_computes_the_blocks_that_meet_it_and_no_more():
     assert window_ops.lead_rows(40, 8, 128) == 127
 
 
+# -- the block rule at every shape the serving bundles hand the kernels ------------
+
+PINNED = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden",
+                      "flash_blocks_pinned.json")
+#: the ops that reach ``flash_blocks`` -> (its K/V heads: an attribute's
+#: name, or their number; whether the op carries a band)
+KERNEL_OPS = {"window_attention": ("n_kv_head", True),
+              "gqa_flash_attention_chunk": ("n_kv_head", False),
+              "mla_attention_chunk": (1, False)}
+PINNED_CONFIGS = ["mimo_v2_flash", "k_exaone_236b_a23b", "phi4_mini_flash",
+                  "glm_5.2", "kimi_k2.6_text", "solar_open2_250b",
+                  "dots3_note_prev"]
+
+
+def _kernel_calls(name):
+    """``[T, group, window, keys]`` of every call a published serving
+    configuration's chunk programs make into the flash kernel: the
+    configuration's adapter runs with ``export_bundle`` replaced by one
+    that keeps the exporter's meta and BUILDS its chunk programs (no
+    startup runs: no weight is allocated); group and window are read off
+    the programs' ops, the chunk rungs and page buckets off the meta."""
+    import importlib
+    from lib import models as adapters
+    from paddle_tpu.framework import unique_name_scope
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        cfg = json.load(f)
+    kept = {}
+
+    def keep(dirname, hp, where, build_prefill, build_decode, cache_vars,
+             n_layer, num_slots=8, prompt_buckets=None, page_len=64,
+             num_pages=None, page_buckets=None, state_vars=None,
+             sections=None, more_programs=None):
+        meta = {"page_len": int(page_len),
+                "prompt_buckets": list(prompt_buckets),
+                "page_buckets": list(page_buckets)}
+        meta.update(sections(meta) if callable(sections) else sections or {})
+        kept.update(meta=meta, pool=(
+            num_slots, page_len,
+            num_pages or num_slots * -(-int(hp.max_len) // page_len)),
+            builds=[build_prefill] + list((more_programs or {}).values()))
+
+    builders = [importlib.import_module("paddle_tpu.models." + m) for m in
+                ("hybrid_moe", "latent_moe", "window_moe", "hybrid_decoder")]
+    olds = [m.export_bundle for m in builders]
+    for m in builders:
+        m.export_bundle = keep
+    try:
+        adapters.adapter_of(cfg).export("unused", cfg)
+    finally:
+        for m, old in zip(builders, olds):
+            m.export_bundle = old
+    calls = set()
+    for build in kept["builds"]:
+        main = fluid.Program()
+        with unique_name_scope(""), fluid.program_guard(main,
+                                                        fluid.Program()):
+            build(*kept["pool"])
+        for op in main.global_block().ops:
+            if op.type in KERNEL_OPS:
+                hkv, banded = KERNEL_OPS[op.type]
+                if isinstance(hkv, str):
+                    hkv = int(op.attr(hkv))
+                calls.add((int(op.attr("n_head")) // hkv,
+                           int(op.attr("window")) if banded else 0))
+    meta = kept["meta"]
+    return [[T, group, window, keys]
+            for T in meta["prefill_chunks"] for group, window in sorted(calls)
+            for keys in ([None] if window else
+                         [p * meta["page_len"] for p in meta["page_buckets"]])]
+
+
+def _block_rule_at(T, group, window, keys):
+    """What the three functions of the rule say of one call: the blocks,
+    the lead rows of a band, and the key blocks computed where the chunk
+    stands at 0, at 64 and at the end of its keys."""
+    blocks = window_ops.flash_blocks(T, group, window, keys)
+    return [blocks and list(blocks),
+            window_ops.lead_rows(T, group, window) if window else None,
+            [list(window_ops.key_blocks_computed(T, group, window, start,
+                                                 keys))
+             for start in (0, 64, (keys or 8192) - T)]]
+
+
+@pytest.mark.parametrize("name", PINNED_CONFIGS)
+def test_the_block_rule_is_the_recorded_one_at_every_serving_shape(name):
+    """``flash_blocks``, ``lead_rows`` and ``key_blocks_computed`` at
+    every (rows, heads a K/V head, window, key rows) a serving bundle
+    hands the kernel, the latent builder's full layers among them,
+    against ``tests/golden/flash_blocks_pinned.json`` (recorded at
+    ``717d37d``, before the rule admitted one query head a K/V head:
+    ``PYTHONPATH=. python tests/test_window_ops.py`` rewrites it from the tree it
+    runs on).  A shape the rule refuses is recorded as refused: its
+    chunk runs the composed form and goes on doing so."""
+    with open(PINNED) as f:
+        golden = json.load(f)[name]
+    seen = [call + _block_rule_at(*call) for call in _kernel_calls(name)]
+    assert seen and seen == golden
+
+
+def test_the_block_rule_admits_one_query_head_a_kv_head():
+    """Every head its own K/V head under a band (an expanded latent
+    chunk) has no heads to stack into 2048 left rows: the rule, refused
+    there before, hands the chunk's own rows out as blocks; with heads
+    to stack, and under no band, it is as it was."""
+    blocks = window_ops.flash_blocks(1024, 1, 513)
+    assert blocks is not None and 1024 % blocks[0] == 0 \
+        and blocks[0] % blocks[1] == 0
+    assert window_ops.lead_rows(1024, 1, 513) == 512
+    assert blocks == (512, 512)
+    assert window_ops.key_blocks_computed(1024, 1, 513, start=0) == (3, 512)
+    assert window_ops.key_blocks_computed(1024, 1, 513, start=64) == (4, 512)
+    assert window_ops.flash_blocks(128, 1, 40) == (128, 128)
+    assert window_ops.flash_blocks(2048, 1, 513) == (2048, 128)
+    for refused in ((1024, 1, 0), (256, 4, 512), (512, 2, 513),
+                    (384, 1, 513), (64, 1, 513), (40, 1, 128)):
+        assert window_ops.flash_blocks(*refused) is None, refused
+
+
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_paged_kernel_takes_value_heads_of_their_own_width(dtype):
     rng = np.random.RandomState(2)
@@ -290,3 +408,12 @@ def test_config_takes_the_published_keys():
     assert adapter.window_bytes_per_row(published) == 8 * (256 + 128) * 2
     # 2.22B parameters, as the issue's arithmetic has them
     assert round(adapter.param_count(published) / 1e7) == 222
+
+
+if __name__ == "__main__":
+    with open(PINNED, "w") as f:
+        json.dump({name: [call + _block_rule_at(*call)
+                          for call in _kernel_calls(name)]
+                   for name in PINNED_CONFIGS}, f, indent=1)
+        f.write("\n")
+    print(f"{len(PINNED_CONFIGS)} configurations -> {PINNED}")
