@@ -8,8 +8,8 @@ head transposes inside the timed region, as they do in the model.
 
 Paths (``ops/attention_ops.py``):
   * ``packed``   the packed single-pass kernels (``ops/attention_packed``);
-  * ``bhsd``     transposes + the ``[B, H, S, D]`` Pallas kernels
-                 (single-pass up to S 1024, streaming flash above);
+  * ``bhsd``     transposes + the streaming ``[B, H, S, D]`` Pallas
+                 kernels (at every length since PR 59);
   * ``composed`` transposes + plain XLA (``_reference_attention``).
 
 Shapes: Transformer-base heads (H 8, D 64), bf16, 32k tokens at S 512 /
@@ -187,10 +187,26 @@ def write_markdown(rows):
         "(`attention_packed.MAX_S`); above it the op unpacks and `bhsd` is "
         "the streaming flash kernels.  The model "
         "(`models.transformer.multi_head_attention`) takes the fused op "
-        "wherever `attention_packed.plan` admits the shapes (equal "
-        "lengths, a multiple of 128 in that range, head width 32 / 64 / "
-        "128) or the keys are 512 or longer, and the composed path "
-        "elsewhere; `PERF.md` has the in-model numbers.",
+        "wherever `attention_ops.attention_lowering` says the packed "
+        "kernels admit the shapes (equal lengths, a multiple of 128 in "
+        "that range, head width 32 / 64 / 128) or the keys are 512 or "
+        "longer, and the composed path elsewhere; `PERF.md` has the "
+        "in-model numbers.",
+        "",
+        "Since PR 59 `bhsd` is the streaming kernels at EVERY length.  "
+        "Until then its rows at S <= 1024 were the single-pass "
+        "`[B, H, S, D]` pair removed there: 4.076 / 2.899 / 6.296 / "
+        "6.322 ms at S 1024 / 512 / 256 / 128 (my chip run, PR 39).  "
+        "The streaming kernels are slower than that pair on every one "
+        "of those rows and, at S 256 and 128, slower than `composed` "
+        "(my chip run, PR 59): a shape the packed kernels refuse is "
+        "better served by the composed ops under 512 keys, which is "
+        "what the model builds there.",
+        "The one study that had that pair ahead of the streaming kernels "
+        "(a chip run no longer in the tree; forward + backward, causal, "
+        "bf16, 64k tokens): S 256 15.6 ms against 18.1 XLA and 18.9 "
+        "streaming; S 512 16.2 against 19.9 and 18.0, the streaming "
+        "kernels still ahead of XLA: the model's rule of 512 keys.",
     ]
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "BENCH_ATTENTION.md"), "w") as f:

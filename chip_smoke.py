@@ -191,8 +191,8 @@ def _trainer_windows(hp, batch, seq, steps, calls, seed):
         check(params, "program has no parameters")
         off = [n for n in params if not _on_tpu(scope.find_var(n))]
         expect_chip(not off, f"parameters not on the TPU: {off[:5]}")
-        gates = {n: _counter(n) for n in ("attention.flash_fallback",
-                                          "attention.fused_softmax_fallback")}
+        gates = {"attention.flash_fallback":
+                 _counter("attention.flash_fallback")}
         say("trainer", params=len(params), window_loss_means=means, **gates)
 
 

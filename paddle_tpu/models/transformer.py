@@ -130,22 +130,20 @@ def multi_head_attention(queries, keys, values, d_key, d_value, d_model,
                       bias_attr=False, param_attr=pa("v"))
     scale = float(d_key) ** -0.5
 
-    # Which lowering the core gets is read off the operands' shapes: the
-    # fused op where the packed kernels take the projections' [B, S, H*D]
-    # as they are (``attention_packed.plan``: equal lengths, a multiple of
-    # 128 from its measured floor ``MIN_S`` to ``MAX_S``, head width 32 /
-    # 64 / 128; no head transposes, no [B,H,S,S] tensor in HBM), or where
-    # the keys are 512 or longer (the rule the [B,H,S,D] single-pass and
-    # streaming kernels were measured under: unequal lengths, S > 1024).
-    # Elsewhere the composed ops stay: short or odd lengths and narrow
-    # heads, where XLA folds the transposes into the projection matmuls
-    # and the [S,S] round trip is cheap.  BENCH_ATTENTION.md has one
-    # module alone on the three paths.
-    from paddle_tpu.ops import attention_packed
-    use_flash = use_flash and (
-        attention_packed.plan(tuple(q.shape), tuple(k.shape),
-                              tuple(v.shape), n_head, causal) is not None
-        or k.shape[1] >= 512)
+    # Which ops the core gets is read off the operands' shapes, from the
+    # ONE function that also chooses the fused op's lowering
+    # (``attention_ops.attention_lowering``): the fused op where the packed
+    # kernels take the projections' [B, S, H*D] as they are (equal
+    # lengths, a multiple of 128 up to 1024, head width 32 / 64 / 128; no
+    # head transposes, no [B,H,S,S] tensor in HBM) or the keys are 512 or
+    # longer (the rule the streaming kernels were measured under: unequal
+    # lengths, S > 1024).  Elsewhere the composed ops stay: short or odd
+    # lengths and narrow heads, where XLA folds the transposes into the
+    # projection matmuls and the [S,S] round trip is cheap.
+    # BENCH_ATTENTION.md has one module alone on the three paths.
+    from paddle_tpu.ops.attention_ops import attention_lowering
+    use_flash = use_flash and attention_lowering(
+        q.shape, k.shape, v.shape, n_head, causal).beats_composed
     # sequence/context parallelism: shard S over the mesh 'seq' axis and
     # attend with the ppermute ring (parallel/ring_attention.py); only for
     # self-attention (q and k share the sequence sharding)

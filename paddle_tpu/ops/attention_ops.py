@@ -4,43 +4,53 @@ The reference composes attention from mul/softmax/matmul graph ops
 (``python/paddle/fluid/nets.py`` scaled_dot_product_attention;
 ``test_parallel_executor.py`` transformer).  On TPU the [B,H,S,S] score
 tensor is the HBM-bandwidth hot spot, so the fused op runs
-QK^T -> mask -> softmax -> AV with the scores only ever in VMEM.  Three
-kernel families, chosen from the operands' shapes:
+QK^T -> mask -> softmax -> AV with the scores only ever in VMEM.
 
-  * PACKED operands ``[B, S, H*D]`` with an ``n_head`` attribute, as the
-    transformer's projections emit them, S a multiple of 128 up to 1024,
-    ``S_q == S_k``: the packed single-pass kernels of
+An op gets one of THREE lowerings, and ``attention_lowering`` alone says
+which, from what it can observe: the operands' shapes, ``n_head``,
+``causal``, the op's ``use_flash``, the step's mesh and batch axis, TPU
+or interpret.  Shape inference (the shape of ``Lse``), the forward and
+the grad lowering, ``fused_attention`` and the model's gate
+(``models.transformer.multi_head_attention``) ask it; none derives the
+answer again.
+
+  * ``packed``: PACKED operands ``[B, S, H*D]`` with an ``n_head``
+    attribute, as the transformer's projections emit them, S a multiple
+    of 128 up to 1024, ``S_q == S_k``: the packed single-pass kernels of
     ``attention_packed`` (no head transposes, lane-dense side arrays;
-    counted by ``attention.packed_kernel``).  A packed shape they refuse
-    is unpacked here and takes one of the two below.
-  * ``[B, H, S, D]``, same bounds on S: the single-pass ``_smalls_*``
-    kernels, one backward kernel producing dq, dk, dv.  Their residual
-    ``[BH, S, 2]``, mask ``[BH, S, 1]`` and delta ``[BH, S, 1]`` are
+    counted by ``attention.packed_kernel``).
+  * ``streaming``: ``[B, H, S, D]`` operands, and packed ones the packed
+    kernels refuse (unpacked and packed again in ``_layout``), at every
+    length ``_flash_blocks`` can tile.  K/V stream through VMEM one block
+    at a time with an online softmax (VMEM use independent of sequence
+    length); the backward runs as two kernels (dq; dk+dv) from the saved
+    residual, fully masked causal blocks skipped.  Their residual
+    ``[B, H, S, 2]``, mask ``[B, 1, S]`` and delta ``[B, H, S, 1]`` are
     padded 64-128x by the (8, 128) tiling (134 MB an array at
     B32 x S1024 x H8): what the packed kernels were written to avoid.
-    ``ops/mla_ops.py``'s prefill calls them, one sequence a call.
-  * ``[B, H, S, D]`` beyond: the streaming flash kernels.  K/V stream
-    through VMEM one block at a time with an online softmax (VMEM use
-    independent of sequence length); the backward runs as two kernels
-    (dq; dk+dv) from the saved residual, fully masked causal blocks
-    skipped.
+    ``ops/mla_ops.py``'s training prefill calls the forward, one
+    sequence a call.
+  * ``reference``: plain XLA (``_reference_attention``) where
+    ``use_flash`` is off, no kernel tiles the lengths, or the mesh does
+    not fit; counted by ``attention.flash_fallback`` where a kernel was
+    asked for.
 
 Measured on a v5e (``bench_attention.py`` -> ``BENCH_ATTENTION.md``, one
 module forward + backward, bf16, H8 x D64, operands and result in the
-projections' layout so the ``[B, H, S, D]`` paths pay their transposes;
-my chip run, PR 39): B32 x S1024 packed 2.79 ms (causal 2.13), the
-``[B, H, S, D]`` kernels 4.08, composed XLA 10.19; B64 x S512 1.51 /
-2.90 / 5.20; B256 x S256 1.89 / 6.30 / 5.56.  The model
-(``models.transformer.multi_head_attention``) builds this op wherever
-``attention_packed.plan`` admits the shapes or the keys are 512 or longer,
-and the composed ops elsewhere: no name in the environment chooses.
+projections' layout so the ``[B, H, S, D]`` path pays its transposes;
+my chip run, PR 39): B32 x S1024 packed 2.79 ms (causal 2.13), composed
+XLA 10.19; B64 x S512 1.51 / 5.20; B256 x S256 1.89 / 5.56; above
+S 1024 the streaming kernels 8.09 ms against 19.61 composed at
+B16 x S2048.  The model builds this op wherever the packed kernels take
+the shapes or the keys are ``FUSED_MIN_KEYS`` or longer, and the
+composed ops elsewhere: no name in the environment chooses.
 
 On a mesh (``ctx.aux["mesh"]``, the ``ParallelExecutor``'s) every kernel
 call runs PER SHARD of the batch over the ``data`` axis (``_per_shard``):
 the partitioner has no rule for a ``tpu_custom_call`` (Mosaic refuses to
 lower one it would have to split), and a replicated call would hand each
 chip the gathered global batch.  A mesh the batch does not fit
-(``_kernels_fit``) takes the plain-XLA reference, counted as a fallback.
+(``_kernels_fit``) takes the reference.
 
 Masking model (matches the transformer workloads):
   * ``k_mask`` [B, S_k] with 1 = attend / 0 = padding, optional;
@@ -50,6 +60,7 @@ Masking model (matches the transformer workloads):
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -282,193 +293,19 @@ def _flash_blocks(S_q, S_k, interpret=False):
     return block_q, block_k
 
 
-# ---------------------------------------------------------------------------
-# small-S single-pass kernels: when the whole [S, S] score tile fits VMEM
-# there is no reason to stream K/V or keep online-softmax scratch.  Fold
-# (B, H) into ONE grid axis with G bh-pairs per program (vs the streaming
-# grid's (B, H, nq, nk) — 2048 tiny programs at transformer-base S=256),
-# compute the softmax in one pass, and run ONE backward kernel producing
-# dq/dk/dv together (the streaming backward is two kernels, each
-# recomputing the scores).  Measured v5e fwd+bwd causal bf16, 64k tokens:
-# S=256 15.6ms vs 18.1 XLA / 18.9 streaming-flash; S=512 16.2ms vs
-# 19.9 / 18.0 (a one-off study on the chip that is no longer in the
-# tree: this comment is the numbers' record).
-# ---------------------------------------------------------------------------
-
-_SMALLS_MAX_S = 1024
-_SMALLS_SCORE_VMEM = 4 << 20      # f32 score bytes per program; G8*512^2*4
-                                  # = 8MB exceeded the 16MB scoped limit
-
-
-def _smalls_group(BH, S):
-    """Largest bh-group size whose unrolled score tiles fit the measured
-    VMEM budget; None = shape not eligible for the single-pass path."""
-    if S > _SMALLS_MAX_S or S % 128:
-        return None
-    for g in (8, 4, 2, 1):
-        if BH % g == 0 and g * S * S * 4 <= _SMALLS_SCORE_VMEM:
-            return g
-    return None
-
-
-def _causal_bias_full(S):
-    row = jax.lax.broadcasted_iota(jnp.int32, (S, S), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (S, S), 1)
-    return jnp.where(col > row, NEG_INF, 0.0)
-
-
-def _smalls_scores(q, k, mask_col, scale, bias):
-    """f32 [S, S] masked scaled scores for one bh pair; ``mask_col`` is
-    the [S, 1] key mask."""
-    s = jax.lax.dot_general(
-        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale
-    s = s + (1.0 - mask_col[:, 0].astype(jnp.float32))[None, :] * NEG_INF
-    if bias is not None:
-        s = s + bias
-    return s
-
-
-def _smalls_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, res_ref, *,
-                       causal, scale, G, S):
-    bias = _causal_bias_full(S) if causal else None
-    for g in range(G):
-        s = _smalls_scores(q_ref[g], k_ref[g], mask_ref[g], scale, bias)
-        m = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[g]
-        o = jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        o_ref[g] = (o / l).astype(o_ref.dtype)
-        # (m, log l) separately — see the streaming kernel's note
-        res_ref[g] = jnp.concatenate([m, jnp.log(l)], axis=1)
-
-
-def _smalls_bwd_kernel(q_ref, k_ref, v_ref, mask_ref, do_ref, res_ref,
-                       delta_ref, dq_ref, dk_ref, dv_ref, *, causal,
-                       scale, G, S):
-    bias = _causal_bias_full(S) if causal else None
-    for g in range(G):
-        q = q_ref[g]
-        k = k_ref[g]
-        v = v_ref[g]
-        do = do_ref[g]
-        m = res_ref[g][:, 0:1]
-        logl = res_ref[g][:, 1:2]
-        delta = delta_ref[g]
-        s = _smalls_scores(q, k, mask_ref[g], scale, bias)
-        p = jnp.exp((s - m) - logl)
-        dv_ref[g] = jax.lax.dot_general(
-            p.astype(do.dtype), do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-        dp = jax.lax.dot_general(
-            do, v, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta) * scale
-        dq_ref[g] = jax.lax.dot_general(
-            ds.astype(k.dtype), k,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-        dk_ref[g] = jax.lax.dot_general(
-            ds.astype(q.dtype), q,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-
-
-def _smalls_flat(q, k, v, k_mask):
-    B, H, S, _ = q.shape
-    BH = B * H
-    mask = jnp.broadcast_to(k_mask[:, None, :], (B, H, S)) \
-        .reshape(BH, S, 1)
-    return ([x.reshape(BH, S, x.shape[3]) for x in (q, k, v)], mask)
-
-
-def _smalls_attention(q, k, v, k_mask, causal, scale, G, interpret=False):
-    B, H, S, D_k = q.shape
-    D_v = v.shape[3]
-    BH = B * H
-    (qf, kf, vf), maskf = _smalls_flat(q, k, v, k_mask)
-
-    def spec(width):
-        return pl.BlockSpec((G, S, width), lambda t: (t, 0, 0),
-                            memory_space=pltpu.VMEM)
-
-    out, res = pl.pallas_call(
-        functools.partial(_smalls_fwd_kernel, causal=causal, scale=scale,
-                          G=G, S=S),
-        grid=(BH // G,),
-        in_specs=[spec(D_k), spec(D_k), spec(D_v), spec(1)],
-        out_specs=[spec(D_v), spec(2)],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D_v), q.dtype),
-            jax.ShapeDtypeStruct((BH, S, 2), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qf, kf, vf, maskf)
-    return out.reshape(B, H, S, D_v), res.reshape(B, H, S, 2)
-
-
-def _smalls_attention_bwd(q, k, v, k_mask, o, res, g, causal, scale, G,
-                          interpret=False):
-    B, H, S, D_k = q.shape
-    D_v = v.shape[3]
-    BH = B * H
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    (qf, kf, vf), maskf = _smalls_flat(q, k, v, k_mask)
-
-    def spec(width):
-        return pl.BlockSpec((G, S, width), lambda t: (t, 0, 0),
-                            memory_space=pltpu.VMEM)
-
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_smalls_bwd_kernel, causal=causal, scale=scale,
-                          G=G, S=S),
-        grid=(BH // G,),
-        in_specs=[spec(D_k), spec(D_k), spec(D_v), spec(1), spec(D_v),
-                  spec(2), spec(1)],
-        out_specs=[spec(D_k), spec(D_k), spec(D_v)],
-        out_shape=[
-            jax.ShapeDtypeStruct((BH, S, D_k), q.dtype),
-            jax.ShapeDtypeStruct((BH, S, D_k), k.dtype),
-            jax.ShapeDtypeStruct((BH, S, D_v), v.dtype),
-        ],
-        interpret=interpret,
-    )(qf, kf, vf, maskf, g.reshape(BH, S, D_v), res.reshape(BH, S, 2),
-      delta.reshape(BH, S, 1))
-    unflat = lambda x, w: x.reshape(B, H, S, w)
-    return unflat(dq, D_k), unflat(dk, D_k), unflat(dv, D_v)
-
-
-def _kernel_admits(S_q, S_k, interpret):
-    """Whether the ``[B, H, S, D]`` kernels take these lengths: the
-    single-pass pair (any batch: a group of one always tiles) or blocks
-    the streaming kernels can walk.  The batch plays no part, so a shard
-    of it is judged as the whole is."""
-    if S_q == S_k and _smalls_group(1, S_q) is not None:
-        return True
-    return None not in _flash_blocks(S_q, S_k, interpret)
-
-
-def _pallas_attention(q, k, v, k_mask, causal, scale, interpret=False):
-    """Returns (out, res); res [B,H,S_q,2] packs the softmax running max
-    and log-denominator, the residual consumed by the flash backward.
-    None where ``_kernel_admits`` refuses the lengths."""
+def _pallas_attention(q, k, v, k_mask, causal, scale, interpret=False,
+                      blocks=None):
+    """The streaming forward on ``[B, H, S, D]``.  Returns (out, res);
+    res [B,H,S_q,2] packs the softmax running max and log-denominator,
+    the residual consumed by the flash backward.  None where
+    ``_flash_blocks`` (asked here if the caller brings no ``blocks``)
+    cannot tile the lengths."""
     B, H, S_q, D_k = q.shape
     S_k = k.shape[2]
     D_v = v.shape[3]
-    if not _kernel_admits(S_q, S_k, interpret):
+    block_q, block_k = blocks or _flash_blocks(S_q, S_k, interpret)
+    if block_q is None or block_k is None:
         return None
-    if S_q == S_k:
-        G = _smalls_group(B * H, S_q)
-        if G is not None:
-            return _smalls_attention(q, k, v, k_mask, causal, scale, G,
-                                     interpret)
-    block_q, block_k = _flash_blocks(S_q, S_k, interpret)
     grid = (B, H, S_q // block_q, S_k // block_k)
     kernel = functools.partial(_flash_fwd_kernel, causal=causal,
                                scale=scale, block_q=block_q,
@@ -512,16 +349,11 @@ def _pallas_attention(q, k, v, k_mask, causal, scale, interpret=False):
 
 
 def _pallas_attention_bwd(q, k, v, k_mask, o, res, g, causal, scale,
-                          interpret=False):
+                          interpret=False, blocks=None):
     B, H, S_q, D_k = q.shape
     S_k = k.shape[2]
     D_v = v.shape[3]
-    if S_q == S_k:
-        G = _smalls_group(B * H, S_q)
-        if G is not None:
-            return _smalls_attention_bwd(q, k, v, k_mask, o, res, g,
-                                         causal, scale, G, interpret)
-    block_q, block_k = _flash_blocks(S_q, S_k, interpret)
+    block_q, block_k = blocks or _flash_blocks(S_q, S_k, interpret)
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1, keepdims=True)        # [B, H, S_q, 1]
     mask3 = k_mask[:, None, :]
@@ -605,9 +437,9 @@ def _use_interpret():
 
 
 def _count_flash_fallback():
-    """A flash-requested attention whose shape ``_flash_blocks`` refused
-    lowered as the composed XLA path (fires at trace time, once per
-    compiled signature)."""
+    """A flash-requested attention lowered as the plain-XLA reference:
+    ``_flash_blocks`` refused its lengths or its batch does not fit the
+    mesh (fires at trace time, once per compiled signature)."""
     from paddle_tpu.profiler import runtime_metrics
     runtime_metrics.inc("attention.flash_fallback")
 
@@ -633,12 +465,6 @@ def _pack_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(B, S, H * D)
 
 
-def _packed_blocks(q, k, v, n_head, causal):
-    """The packed kernels' blocking for rank-3 ``[B, S, H*D]`` operands,
-    None where they refuse the shape (``attention_packed.plan``)."""
-    return attention_packed.plan(q.shape, k.shape, v.shape, n_head, causal)
-
-
 def _kernels_fit(mesh, batch, batch_axis=0):
     """Whether a kernel can run in a step jitted over ``mesh``.  Mosaic
     refuses a kernel the partitioner would have to split, so on more than
@@ -649,14 +475,64 @@ def _kernels_fit(mesh, batch, batch_axis=0):
     populated: a kernel would need the features or the rows regathered;
     a batch the axis does not divide, or an executor that shards another
     dimension than the first: it would need the GLOBAL batch on every
-    chip) the caller takes ``_reference_attention``, which the
-    partitioner can split any way, and counts
-    ``attention.flash_fallback``."""
+    chip) the op takes ``_reference_attention``, which the partitioner
+    can split any way."""
     from paddle_tpu.parallel.mesh import DATA_AXIS
     if mesh is None or mesh.size == 1:
         return True
     n = mesh.shape.get(DATA_AXIS, 1)
     return n == mesh.size and batch_axis == 0 and batch % n == 0
+
+
+PACKED, STREAMING, REFERENCE = "packed", "streaming", "reference"
+# The keys from which this op beats the composed ops on a shape the
+# packed kernels refuse (unequal lengths, S > 1024, odd head widths): the
+# rule the streaming kernels were measured under (``BENCH_ATTENTION.md``).
+FUSED_MIN_KEYS = 512
+
+
+class Lowering(NamedTuple):
+    kind: str               # PACKED | STREAMING | REFERENCE
+    blocks: tuple | None    # the chosen kernels' blocking
+    # what a model that can still build the composed ops asks: True where
+    # the packed kernels take the shapes or the keys are FUSED_MIN_KEYS or
+    # longer; short or odd lengths and narrow heads read False (XLA folds
+    # the transposes into the projection matmuls and the [S, S] round
+    # trip is cheap)
+    beats_composed: bool
+
+
+def attention_lowering(q_shape, k_shape, v_shape, n_head=None, causal=False,
+                       use_flash=True, mesh=None, batch_axis=0,
+                       interpret=False):
+    """THE choice of lowering for a ``scaled_dot_product_attention`` on
+    ``[B, H, S, D]`` operands, or PACKED ``[B, S, H*D]`` ones with
+    ``n_head``.  ``mesh`` / ``batch_axis``: those of the step being
+    lowered, if any.  ``interpret``: the kernels will run interpreted
+    (any block tiles); a caller that builds a program and cannot know
+    leaves the chip's rule in force."""
+    q_shape, k_shape, v_shape = map(tuple, (q_shape, k_shape, v_shape))
+    packed = attention_packed.plan(q_shape, k_shape, v_shape, n_head,
+                                   causal)
+    beats = packed is not None or k_shape[-2] >= FUSED_MIN_KEYS
+    if not use_flash or not _kernels_fit(mesh, q_shape[0], batch_axis):
+        return Lowering(REFERENCE, None, beats)
+    if packed is not None:
+        return Lowering(PACKED, packed, beats)
+    blocks = _flash_blocks(q_shape[-2], k_shape[-2], interpret)
+    if None in blocks:
+        return Lowering(REFERENCE, None, beats)
+    return Lowering(STREAMING, blocks, beats)
+
+
+def _layout(n_head, kind):
+    """``(to_kernel, from_kernel)``: only the packed kernels take PACKED
+    operands (``n_head`` set) as they are; under the other two lowerings
+    a packed op runs on ``[B, H, S, D]`` and its results are packed
+    again."""
+    if n_head and kind != PACKED:
+        return functools.partial(_unpack_heads, n_head=n_head), _pack_heads
+    return (lambda x: x), (lambda x: x)
 
 
 def _per_shard(kernel, mesh, *arrays):
@@ -665,7 +541,7 @@ def _per_shard(kernel, mesh, *arrays):
     the Pallas call on its own rows, nothing is gathered and nothing
     reduced (attention never mixes batch rows).  With no mesh, or one of
     a single device, this is the plain call, not a ``shard_map`` over one
-    device.  The caller has asked ``_kernels_fit``."""
+    device.  ``attention_lowering`` has asked ``_kernels_fit``."""
     if mesh is None or mesh.size == 1:
         return kernel(*arrays)
     from jax import shard_map
@@ -677,37 +553,60 @@ def _per_shard(kernel, mesh, *arrays):
                      check_vma=False)(*arrays)
 
 
-def _kernel_forward(q, k, v, k_mask, causal, scale, n_head, mesh):
-    """``(out, residual)`` of the Pallas forward, per shard of the batch
-    (the caller has asked ``_kernels_fit``): the packed pair where
-    ``n_head`` is set (the caller has asked ``_packed_blocks``), else the
-    ``[B, H, S, D]`` kernels, None where those refuse the lengths."""
+def _attention_forward(q, k, v, k_mask, causal, scale, n_head, use_flash,
+                       mesh, batch_axis=0):
+    """``(out, res)`` by the lowering ``attention_lowering`` chooses: the
+    kernel's forward per shard of the batch and the residual its backward
+    reads, or the reference and None.  ``out`` is in the operands' own
+    layout."""
     interpret = _use_interpret()
-    if n_head:
+    kind, blocks, _ = attention_lowering(
+        q.shape, k.shape, v.shape, n_head, causal, use_flash, mesh,
+        batch_axis, interpret)
+    to_kernel, from_kernel = _layout(n_head, kind)
+    q, k, v = to_kernel(q), to_kernel(k), to_kernel(v)
+    if kind == REFERENCE:
+        if use_flash:
+            _count_flash_fallback()
+        return from_kernel(_reference_attention(q, k, v, k_mask, causal,
+                                                scale)), None
+    if kind == PACKED:
         _count_packed_kernel()
-        blocks = _packed_blocks(q, k, v, n_head, causal)
 
         def kernel(q, k, v, mask):
             return attention_packed.attention(
                 q, k, v, mask, causal, scale, n_head, blocks,
                 interpret=interpret)
-    elif _kernel_admits(q.shape[2], k.shape[2], interpret):
+    else:
         def kernel(q, k, v, mask):
             return _pallas_attention(q, k, v, mask, causal, scale,
-                                     interpret=interpret)
-    else:
-        return None
-    return _per_shard(kernel, mesh, q, k, v, k_mask)
+                                     interpret, blocks)
+    out, res = _per_shard(kernel, mesh, q, k, v, k_mask)
+    return from_kernel(out), res
 
 
-def _kernel_backward(q, k, v, k_mask, o, res, g, causal, scale, n_head,
-                     mesh):
-    """dq, dk, dv from the forward's saved output and residual, by the
-    backward of the kernels ``_kernel_forward`` ran, on the same shards."""
+def _attention_backward(q, k, v, k_mask, o, res, g, causal, scale, n_head,
+                        use_flash, mesh, batch_axis=0):
+    """dq, dk, dv in the operands' layout, from what ``_attention_forward``
+    handed back: the chosen kernel's backward from the saved output and
+    residual, on the same shards; the reference's ``vjp`` where it saved
+    none (``res`` None)."""
     interpret = _use_interpret()
-    if n_head:
+    kind, blocks, _ = attention_lowering(
+        q.shape, k.shape, v.shape, n_head, causal, use_flash, mesh,
+        batch_axis, interpret)
+    if res is None:     # nothing saved: the forward was the reference's
+        kind = REFERENCE
+    to_kernel, from_kernel = _layout(n_head, kind)
+    q, k, v, g = (to_kernel(x) for x in (q, k, v, g.astype(q.dtype)))
+    if kind == REFERENCE:
+        _, vjp_fn = jax.vjp(
+            lambda q_, k_, v_: _reference_attention(q_, k_, v_, k_mask,
+                                                    causal, scale),
+            q, k, v)
+        return tuple(from_kernel(x) for x in vjp_fn(g))
+    if kind == PACKED:
         _count_packed_kernel()
-        blocks = _packed_blocks(q, k, v, n_head, causal)
 
         def kernel(q, k, v, mask, o, res, g):
             return attention_packed.attention_bwd(
@@ -716,65 +615,35 @@ def _kernel_backward(q, k, v, k_mask, o, res, g, causal, scale, n_head,
     else:
         def kernel(q, k, v, mask, o, res, g):
             return _pallas_attention_bwd(q, k, v, mask, o, res, g, causal,
-                                         scale, interpret=interpret)
-    return _per_shard(kernel, mesh, q, k, v, k_mask, o, res, g)
+                                         scale, interpret, blocks)
+    grads = _per_shard(kernel, mesh, q, k, v, k_mask, to_kernel(o), res, g)
+    return tuple(from_kernel(x) for x in grads)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def fused_attention(q, k, v, k_mask, causal, scale, use_pallas,
                     n_head=None, mesh=None):
     """Differentiable fused attention.  ``q, k, v`` are ``[B, H, S, D]``,
     or PACKED ``[B, S, H*D]`` with ``n_head`` given (the output is then
-    packed too): a packed shape the packed kernels refuse is unpacked and
-    takes the ``[B, H, S, D]`` path.  ``mesh``: the mesh of the jitted
-    step this is traced in, if any; a kernel runs where the batch fits it
-    (``_kernels_fit``), per shard."""
-    if use_pallas and not _kernels_fit(mesh, q.shape[0]):
-        _count_flash_fallback()
-        use_pallas = False
-    if q.ndim == 3 and not (
-            use_pallas and _packed_blocks(q, k, v, n_head, causal)):
-        return _pack_heads(_fused_attention(
-            _unpack_heads(q, n_head), _unpack_heads(k, n_head),
-            _unpack_heads(v, n_head), k_mask, causal, scale, use_pallas,
-            None, mesh))
-    return _fused_attention(q, k, v, k_mask, causal, scale, use_pallas,
-                            n_head, mesh)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
-def _fused_attention(q, k, v, k_mask, causal, scale, use_pallas, n_head,
-                     mesh):
-    out, _ = _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head,
-                        mesh)
-    return out
+    packed too).  ``mesh``: the mesh of the jitted step this is traced
+    in, if any.  ``attention_lowering`` says which lowering runs."""
+    return _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head,
+                      mesh)[0]
 
 
 def _fused_fwd(q, k, v, k_mask, causal, scale, use_pallas, n_head, mesh):
-    if use_pallas:      # rank 3: a shape the packed kernels take
-        res = _kernel_forward(q, k, v, k_mask, causal, scale, n_head, mesh)
-        if res is not None:
-            out, lse = res
-            return out, (q, k, v, k_mask, out, lse)
-        _count_flash_fallback()
-    out = _reference_attention(q, k, v, k_mask, causal, scale)
-    return out, (q, k, v, k_mask, None, None)
+    out, res = _attention_forward(q, k, v, k_mask, causal, scale, n_head,
+                                  use_pallas, mesh)
+    return out, (q, k, v, k_mask, out, res)
 
 
-def _fused_bwd(causal, scale, use_pallas, n_head, mesh, res, g):
-    q, k, v, k_mask, o, lse = res
-    if lse is not None:
-        dq, dk, dv = _kernel_backward(q, k, v, k_mask, o, lse, g, causal,
-                                      scale, n_head, mesh)
-        return dq, dk, dv, None
-    _, vjp_fn = jax.vjp(
-        lambda q_, k_, v_: _reference_attention(q_, k_, v_, k_mask,
-                                                causal, scale),
-        q, k, v)
-    dq, dk, dv = vjp_fn(g.astype(q.dtype))
-    return dq, dk, dv, None
+def _fused_bwd(causal, scale, use_pallas, n_head, mesh, saved, g):
+    q, k, v, k_mask, o, res = saved
+    return _attention_backward(q, k, v, k_mask, o, res, g, causal, scale,
+                               n_head, use_pallas, mesh) + (None,)
 
 
-_fused_attention.defvjp(_fused_fwd, _fused_bwd)
+fused_attention.defvjp(_fused_fwd, _fused_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -788,21 +657,20 @@ def _infer_attn(op, block):
     out = block.var(op.output("Out")[0])
     if q.shape is None or v.shape is None:
         raise ShapeInferenceSkip()
-    packed = len(q.shape) == 3          # [B, S, H*D], n_head heads
     n_head = op.attr("n_head", 0)
     out.shape = tuple(q.shape[:-1]) + (v.shape[-1],)
     out.dtype = q.dtype
     lse_names = op.output("Lse")
     if lse_names:
         lse = block.var(lse_names[0])
-        if packed and k.shape is not None and attention_packed.plan(
-                tuple(q.shape), tuple(k.shape), tuple(v.shape), n_head):
+        if k.shape is not None and attention_lowering(
+                q.shape, k.shape, v.shape, n_head).kind == PACKED:
             lse.shape = attention_packed.res_shape(*q.shape)
         else:
-            # [B, H, S, 2] residual of the [B, H, S, D] kernels: (softmax
+            # [B, H, S, 2] residual of the streaming kernels: (softmax
             # running max, log denominator)
-            lse.shape = ((q.shape[0], n_head, q.shape[1]) if packed
-                         else tuple(q.shape[:3])) + (2,)
+            lse.shape = ((q.shape[0], n_head, q.shape[1])
+                         if len(q.shape) == 3 else tuple(q.shape[:3])) + (2,)
         lse.dtype = "float32"
 
 
@@ -822,19 +690,8 @@ def _attn_operands(ctx, amp_cast=True):
     return q, k, v, k_mask, n_head
 
 
-def _step_mesh(ctx, batch):
-    """``(mesh, fit)``: the mesh of the step being lowered, as the
-    executor put it into ``ctx.aux``, and whether the kernels can run on
-    it at this batch (``_kernels_fit``)."""
-    mesh = ctx.aux.get("mesh")
-    return mesh, _kernels_fit(mesh, batch, ctx.aux.get("batch_axis", 0))
-
-
 def _attn_grad_lower(ctx: LowerContext):
     qe, ke, ve, k_mask, n_head = _attn_operands(ctx, amp_cast=False)
-    causal = ctx.attr("causal", False)
-    scale = ctx.attr("scale", 1.0)
-    g = ctx.env[ctx.op.input("Out@GRAD")[0]]
     # mirror the forward's AMP cast so the vjp's output dtype matches the
     # cotangent coming back from (possibly bf16) downstream consumers;
     # emitted grads are cast back to the primal env dtypes
@@ -844,43 +701,24 @@ def _attn_grad_lower(ctx: LowerContext):
         return x.astype(jnp.bfloat16) \
             if amp and x.dtype == jnp.float32 else x
 
-    q, k, v = cast_in(qe), cast_in(ke), cast_in(ve)
-    use_flash = bool(ctx.attr("use_flash", True))
-    mesh, fit = _step_mesh(ctx, q.shape[0])
-
     # if the forward saved its residuals (Out + Lse), reuse them — the
     # backward kernels run directly, no forward recompute
     out_names = ctx.op.input("Out")
     lse_names = ctx.op.input("Lse")
     o = ctx.env.get(out_names[0]) if out_names else None
     lse = ctx.env.get(lse_names[0]) if lse_names else None
-    saved = use_flash and o is not None and lse is not None
-    blocks = _packed_blocks(q, k, v, n_head, causal) \
-        if saved and n_head else None
-    g = g.astype(q.dtype)
-    pack = (lambda x: x)
-    if n_head and not blocks:
-        # a packed op the packed kernels refused: the forward unpacked,
-        # and so does its grad
-        q, k, v, g = (_unpack_heads(x, n_head) for x in (q, k, v, g))
-        o = _unpack_heads(o, n_head) if saved else o
-        pack = _pack_heads
-    if saved:   # a kernel ran in the forward, so the mesh fits
-        dq, dk, dv = _kernel_backward(
-            q, k, v, k_mask, o, lse, g, causal, float(scale),
-            n_head if blocks else None, mesh)
-    else:
-        _, vjp_fn = jax.vjp(
-            lambda q_, k_, v_: fused_attention(
-                q_, k_, v_, k_mask, causal, scale,
-                use_flash and fit, mesh=mesh),
-            q, k, v)
-        dq, dk, dv = vjp_fn(g)
-    for slot, val, prim in (("Q@GRAD", dq, qe), ("K@GRAD", dk, ke),
-                            ("V@GRAD", dv, ve)):
+    grads = _attention_backward(
+        cast_in(qe), cast_in(ke), cast_in(ve), k_mask, o,
+        lse if o is not None else None,
+        ctx.env[ctx.op.input("Out@GRAD")[0]], ctx.attr("causal", False),
+        float(ctx.attr("scale", 1.0)), n_head,
+        bool(ctx.attr("use_flash", True)), ctx.aux.get("mesh"),
+        ctx.aux.get("batch_axis", 0))
+    for slot, val, prim in zip(("Q@GRAD", "K@GRAD", "V@GRAD"), grads,
+                               (qe, ke, ve)):
         names = ctx.op.output(slot)
         if names and names[0]:
-            ctx.outputs[names[0]] = pack(val).astype(prim.dtype)
+            ctx.outputs[names[0]] = val.astype(prim.dtype)
 
 
 @register_op("scaled_dot_product_attention", infer_shape=_infer_attn,
@@ -889,37 +727,24 @@ def _attn_grad_lower(ctx: LowerContext):
 def sdpa_lower(ctx: LowerContext):
     """Q,K,V: [B, H, S, D]; KMask: [B, S_k] (1=attend); Out: [B, H, Sq, D].
     PACKED: Q,K,V [B, S, H*D] with the ``n_head`` attribute, as projection
-    ``fc``s emit them; Out [B, Sq, H*Dv].  The packed kernels
-    (``attention_packed``) take it where ``plan`` admits the shape; else
-    the operands are unpacked here and take the [B, H, S, D] paths.
+    ``fc``s emit them; Out [B, Sq, H*Dv].  ``attention_lowering`` says
+    which lowering the op gets.
 
     attrs: causal (bool), scale (float), use_flash (bool, default True),
     n_head (int, packed form only).
     """
     q, k, v, k_mask, n_head = _attn_operands(ctx)
-    causal = ctx.attr("causal", False)
-    scale = float(ctx.attr("scale", 1.0))
-    use_flash = bool(ctx.attr("use_flash", True))
-    mesh, fit = _step_mesh(ctx, q.shape[0])
-    pack = (lambda x: x)
-    if n_head and not (use_flash and fit
-                       and _packed_blocks(q, k, v, n_head, causal)):
-        q, k, v = (_unpack_heads(x, n_head) for x in (q, k, v))
-        pack, n_head = _pack_heads, None
-    # flash path has no attention-weight dropout; the graph builder falls
-    # back to the composed path when dropout is requested in training
-    if use_flash:
-        res = _kernel_forward(q, k, v, k_mask, causal, scale, n_head,
-                              mesh) if fit else None
-        if res is not None:
-            out, lse = res
-            ctx.set_output("Out", pack(out))
-            # saved residual; consumed by the grad op (flash backward)
-            ctx.set_output("Lse", lse)
-            return
-        _count_flash_fallback()
-    ctx.set_output("Out", pack(_reference_attention(q, k, v, k_mask, causal,
-                                                    scale)))
+    # no lowering has attention-weight dropout; the graph builder builds
+    # the composed ops when dropout is requested in training
+    out, res = _attention_forward(
+        q, k, v, k_mask, ctx.attr("causal", False),
+        float(ctx.attr("scale", 1.0)), n_head,
+        bool(ctx.attr("use_flash", True)), ctx.aux.get("mesh"),
+        ctx.aux.get("batch_axis", 0))
+    ctx.set_output("Out", out)
+    if res is not None:
+        # saved residual; consumed by the grad op (the kernel's backward)
+        ctx.set_output("Lse", res)
 
 
 # ---------------------------------------------------------------------------
@@ -958,176 +783,6 @@ def ring_attention_lower(ctx):
                                    scale if scale is not None
                                    else float(q.shape[-1]) ** -0.5)
     ctx.set_output("Out", out)
-
-
-# ---------------------------------------------------------------------------
-# fused last-axis softmax (+ additive attention bias) — the composed-path
-# companion of the flash kernel.  Where the model builds the composed ops
-# (shapes no kernel takes, attention-weight dropout) XLA materializes an
-# f32 score temporary between the softmax reduction passes when the f32
-# bias add is fused in (measured r5: ~13 ms/step on Transformer-base
-# B=256 S=256, which built the composed ops then).
-# This kernel reads the bf16 scores ONCE per pass, applies the bias and
-# the full softmax in VMEM at f32, and writes bf16 — one read + one write
-# in the forward, two reads + one write in the backward.
-# ---------------------------------------------------------------------------
-
-def _fsm_fwd_kernel(x_ref, rb_ref, tb_ref, o_ref):
-    x = x_ref[0, 0].astype(jnp.float32)            # [bs, S]
-    if rb_ref is not None:
-        x = x + rb_ref[0, 0].astype(jnp.float32)[None, :]  # [S] row bias
-    if tb_ref is not None:
-        x = x + tb_ref[0].astype(jnp.float32)      # [bs, S] causal rows
-    m = jnp.max(x, axis=-1, keepdims=True)
-    e = jnp.exp(x - m)
-    o_ref[0, 0, ...] = (e / jnp.sum(e, axis=-1, keepdims=True)) \
-        .astype(o_ref.dtype)
-
-
-def _fsm_bwd_kernel(y_ref, dy_ref, dx_ref):
-    y = y_ref[0, 0].astype(jnp.float32)
-    dy = dy_ref[0, 0].astype(jnp.float32)
-    dot = jnp.sum(dy * y, axis=-1, keepdims=True)
-    dx_ref[0, 0, ...] = ((dy - dot) * y).astype(dx_ref.dtype)
-
-
-def _fsm_block(S_rows):
-    for cand in (256, 128, 64, 32, 16, 8):
-        if S_rows % cand == 0:
-            return cand
-    return None
-
-
-def _fsm_ok(Sq, Sk, interpret):
-    """Shared fwd/bwd tiling + VMEM-budget gate."""
-    bs = _fsm_block(Sq)
-    if bs is None or (not interpret and Sk % 128):
-        return None
-    if Sk > 4096 or bs * Sk * 4 * 4 > 8 * 2**20:
-        return None
-    return bs
-
-
-def _pallas_softmax_fwd(x, row_bias, tri_bias, interpret):
-    """x [B,H,Sq,Sk]; row_bias [B,Sk] or None; tri_bias [Sq,Sk] shared,
-    [1,Sq,Sk], or [B,Sq,Sk] per-batch (the decoder's combined
-    padding+causal bias, one causal plane per batch row) or None."""
-    B, H, Sq, Sk = x.shape
-    bs = _fsm_ok(Sq, Sk, interpret)
-    if bs is None:
-        return None
-    grid = (B, H, Sq // bs)
-    in_specs = [pl.BlockSpec((1, 1, bs, Sk),
-                             lambda b, h, i: (b, h, i, 0))]
-    operands = [x]
-    if row_bias is not None:
-        # [B,1,Sk] with a full (1,1,Sk) block — Mosaic wants the last two
-        # block dims (8,128)-aligned OR equal to the array dims
-        in_specs.append(pl.BlockSpec((1, 1, Sk),
-                                     lambda b, h, i: (b, 0, 0)))
-        operands.append(row_bias.reshape(B, 1, Sk))
-    if tri_bias is not None:
-        if tri_bias.ndim == 2:
-            tri_bias = tri_bias[None]
-        if tri_bias.shape[0] not in (1, B):
-            return None
-        if tri_bias.shape[0] > 1:  # per-batch plane, indexed by b
-            tb_index = lambda b, h, i: (b, i, 0)
-        else:                      # one shared causal plane
-            tb_index = lambda b, h, i: (0, i, 0)
-        in_specs.append(pl.BlockSpec((1, bs, Sk), tb_index))
-        operands.append(tri_bias)
-
-    def kernel(*refs):
-        xr = refs[0]
-        k = 1
-        rb = tb = None
-        if row_bias is not None:
-            rb = refs[k]
-            k += 1
-        if tri_bias is not None:
-            tb = refs[k]
-            k += 1
-        _fsm_fwd_kernel(xr, rb, tb, refs[-1])
-
-    return pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, bs, Sk),
-                               lambda b, h, i: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret)(*operands)
-
-
-def _pallas_softmax_bwd(y, dy, interpret):
-    """Returns None when the shape fails the SAME gate as the forward
-    (a fwd that fell back must not meet a bwd that launches).
-
-    ``dy`` keeps the INCOMING cotangent dtype (f32 under AMP): block
-    specs carry no dtype, so the kernel reads g at full precision from
-    the operand itself, like the XLA fallback does; only dx is cast
-    back to ``y.dtype`` on the way out (ADVICE r5)."""
-    B, H, Sq, Sk = y.shape
-    bs = _fsm_ok(Sq, Sk, interpret)
-    if bs is None:
-        return None
-    spec = pl.BlockSpec((1, 1, bs, Sk), lambda b, h, i: (b, h, i, 0))
-    return pl.pallas_call(
-        _fsm_bwd_kernel, grid=(B, H, Sq // bs),
-        in_specs=[spec, spec], out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct(y.shape, y.dtype),
-        interpret=interpret)(y, dy)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def fused_softmax(x, row_bias, tri_bias, interpret=False):
-    """softmax(x + biases) over the last axis, f32-internal, one VMEM
-    pass; falls back to plain XLA when the shape doesn't tile."""
-    out, _ = _fused_softmax_fwd(x, row_bias, tri_bias, interpret)
-    return out
-
-
-def _xla_softmax(x, row_bias, tri_bias):
-    xf = x.astype(jnp.float32)
-    if row_bias is not None:
-        xf = xf + row_bias[:, None, None, :].astype(jnp.float32)
-    if tri_bias is not None:
-        if tri_bias.ndim == 3:   # [B|1, Sq, Sk] per-batch planes
-            xf = xf + tri_bias[:, None].astype(jnp.float32)
-        else:                    # [Sq, Sk] shared plane
-            xf = xf + tri_bias[None, None].astype(jnp.float32)
-    return jax.nn.softmax(xf, axis=-1).astype(x.dtype)
-
-
-def _fused_softmax_fwd(x, row_bias, tri_bias, interpret):
-    out = _pallas_softmax_fwd(x, row_bias, tri_bias, interpret)
-    if out is None:
-        # tiling/VMEM-gate fallback: same coverage signal as the
-        # bias-decomposition fallback in nn_ops.softmax_lower — the
-        # counter's contract is "zero means every softmax ran the
-        # kernel", so a shape that fails _fsm_ok must move it too
-        # (fires at trace time: once per compiled signature)
-        from paddle_tpu.profiler import runtime_metrics
-        runtime_metrics.inc("attention.fused_softmax_fallback")
-        out = _xla_softmax(x, row_bias, tri_bias)
-    return out, out
-
-
-def _fused_softmax_bwd(interpret, y, g):
-    # g stays at the cotangent's own dtype (f32): casting it to bf16
-    # before the kernel would hand the Pallas backward LOWER gradient
-    # precision than its own XLA fallback below (ADVICE r5) — the
-    # constant component of g cancels in (g - sum(g*y))*y, so exactly
-    # the small differences a bf16 cast destroys are what dx is made of
-    dx = _pallas_softmax_bwd(y, g, interpret)
-    if dx is None:
-        yf = y.astype(jnp.float32)
-        gf = g.astype(jnp.float32)
-        dx = ((gf - jnp.sum(gf * yf, axis=-1, keepdims=True)) * yf) \
-            .astype(y.dtype)
-    return dx, None, None
-
-
-fused_softmax.defvjp(_fused_softmax_fwd, _fused_softmax_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -1742,7 +1397,6 @@ def paged_read(q, kc, vc, page_table, walk, n_head, scale, row_lens=None):
                                       scale, interpret=interpret,
                                       row_lens=row_lens)
     if out is None:
-        # same coverage contract as attention.fused_softmax_fallback:
         # fires at trace time, once per compiled signature, whenever a
         # decode bucket lowered without the Pallas kernel
         from paddle_tpu.profiler import runtime_metrics

@@ -38,7 +38,7 @@ padding (under ``causal`` every row permits key 0), which the program
 reads from a prefetched scalar: that batch row takes the whole square,
 and comes out as the reference gives it.
 
-Same arithmetic as the ``[B, H, S, D]`` single-pass kernels: scores and
+Same arithmetic as the ``[B, H, S, D]`` streaming kernels: scores and
 softmax float32, products bfloat16 (the operands' type) with float32
 accumulation, ``p = exp((s - m) - log l)`` in the backward.
 """
@@ -85,8 +85,9 @@ _VMEM_LIMIT = 64 << 20
 
 def plan(q_shape, k_shape, v_shape, n_head, causal=False):
     """``(rows, lane block)`` if the packed kernels take these
-    ``[B, S, H*D]`` shapes, else None (the caller unpacks to
-    ``[B, H, S, D]`` and takes the older paths)."""
+    ``[B, S, H*D]`` shapes, else None (``attention_ops.attention_lowering``,
+    the one caller, then unpacks to ``[B, H, S, D]`` for the streaming
+    kernels or the reference)."""
     if len(q_shape) != 3 or not n_head:
         return None
     B, S, HD = q_shape

@@ -10,8 +10,6 @@ the MXU) instead of the reference's im2col+GEMM / cuDNN split.
 from __future__ import annotations
 
 import functools
-import logging
-import os
 
 import jax
 import jax.numpy as jnp
@@ -19,8 +17,6 @@ import numpy as np
 
 from paddle_tpu.ops.registry import (
     register_op, infer_shape_unary, ShapeInferenceSkip)
-
-logger = logging.getLogger(__name__)
 
 
 # ---------------------------------------------------------------------------
@@ -65,56 +61,11 @@ def _conv2d_lower_impl(ctx, depthwise=False):
     # bf16 convs in f32 regardless, and requesting an f32 output makes the
     # conv's transpose rule pair an f32 cotangent with a bf16 operand
     # (dtype-mismatch TypeError under AMP training).
-    import os
-    if os.environ.get("PADDLE_TPU_CONV_IM2COL") and groups == 1 and \
-            dilations == (1, 1) and x.shape[1] >= 8:
-        out = _conv_im2col(x, w, strides, pad)
-        ctx.set_output("Output", out.astype(x.dtype))
-        return
-    if os.environ.get("PADDLE_TPU_CONV_NHWC"):
-        # layout experiment (r4): run the conv itself channels-last —
-        # per-shape device profiling showed XLA's NHWC conv up to 1.8x
-        # the NCHW one at ResNet's C=64 stage.  The IR/program layout
-        # stays NCHW; XLA's transpose folding decides whether the
-        # sandwich transposes materialize.
-        out = jax.lax.conv_general_dilated(
-            jnp.transpose(x, (0, 2, 3, 1)),
-            jnp.transpose(w, (2, 3, 1, 0)),
-            window_strides=strides, padding=pad,
-            rhs_dilation=dilations, feature_group_count=groups,
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        ctx.set_output("Output",
-                       jnp.transpose(out, (0, 3, 1, 2)).astype(x.dtype))
-        return
     out = jax.lax.conv_general_dilated(
         x, w, window_strides=strides, padding=pad,
         rhs_dilation=dilations, feature_group_count=groups,
         dimension_numbers=("NCHW", "OIHW", "NCHW"))
     ctx.set_output("Output", out.astype(x.dtype))
-
-
-def _conv_im2col(x, w, strides, pad):
-    """Experimental conv-as-explicit-GEMM (PADDLE_TPU_CONV_IM2COL=1):
-    NHWC patches via shifted slices, one [N*Ho*Wo, kh*kw*Ci] @
-    [kh*kw*Ci, Co] matmul; the caller gates unsupported configs
-    (groups/dilation).  Measured 2.1x SLOWER than XLA's native conv on
-    ResNet-50 (COVERAGE.md) — kept as the documented experiment."""
-    oc, ci, kh, kw = w.shape
-    n, _, h, wd = x.shape
-    (pt, pb), (pl, pr) = pad
-    sh, sw = strides
-    ho = (h + pt + pb - kh) // sh + 1
-    wo = (wd + pl + pr - kw) // sw + 1
-    xh = jnp.pad(x.transpose(0, 2, 3, 1),
-                 ((0, 0), (pt, pb), (pl, pr), (0, 0)))
-    cols = [xh[:, i:i + sh * (ho - 1) + 1:sh, j:j + sw * (wo - 1) + 1:sw, :]
-            for i in range(kh) for j in range(kw)]
-    patches = jnp.concatenate(cols, axis=-1).reshape(n * ho * wo,
-                                                     kh * kw * ci)
-    # filter [Co, Ci, kh, kw] -> [kh*kw*Ci, Co] matching patch order
-    wm = w.transpose(2, 3, 1, 0).reshape(kh * kw * ci, oc)
-    y = patches @ wm
-    return y.reshape(n, ho, wo, oc).transpose(0, 3, 1, 2)
 
 
 @register_op("conv2d", infer_shape=_infer_conv2d,
@@ -678,59 +629,6 @@ def softmax_lower(ctx):
     ``scores + mask`` pattern)."""
     x = ctx.input("X")
     bias = ctx.input("Bias")
-    if bias is not None and x.ndim == 4 and \
-            os.environ.get("PADDLE_TPU_FUSED_SOFTMAX", "0") == "1":
-        # attention-shaped: the Pallas single-pass kernel — measured
-        # SLOWER in-model than the XLA path below (138.9 vs 132.7 ms/step
-        # Transformer-base r5: the custom call splits the matmul/softmax
-        # fusion clusters) — kept as an opt-in experiment
-        from paddle_tpu.ops.attention_ops import (fused_softmax,
-                                                  _use_interpret)
-        B, H, Sq, Sk = x.shape
-        row_bias = tri_bias = None
-        ok = True
-        if bias.ndim == 4 and bias.shape[1] == 1 and \
-                bias.shape[2] == 1 and bias.shape[3] == Sk:
-            row_bias = bias.reshape(bias.shape[0], Sk)
-            if bias.shape[0] not in (1, B):
-                ok = False
-            elif bias.shape[0] == 1:
-                row_bias = jnp.broadcast_to(row_bias, (B, Sk))
-        elif bias.ndim == 4 and bias.shape[1] == 1 and \
-                bias.shape[2] == Sq and bias.shape[3] == Sk and \
-                bias.shape[0] in (1, B):
-            # one causal plane per batch row: covers BOTH the shared
-            # causal mask [1,1,Sq,Sk] and the decoder's combined
-            # padding+causal [B,1,Sq,Sk] bias (ADVICE r5 / ROADMAP
-            # item 4 — the kernel now spans the full decoder)
-            tri_bias = bias.reshape(bias.shape[0], Sq, Sk)
-        else:
-            ok = False
-        if ok:
-            ctx.set_output("Out", fused_softmax(
-                x, row_bias, tri_bias, _use_interpret()))
-            return
-        # fallback SIGNAL (ADVICE r5): with the kernel opted in, a bias
-        # the kernel cannot decompose silently takes the XLA path below
-        # — the counter makes partial kernel coverage measurable (an
-        # experiment reading "fused softmax on" checks it is zero), the
-        # debug log names the offending shape
-        from paddle_tpu.profiler import runtime_metrics
-        runtime_metrics.inc("attention.fused_softmax_fallback")
-        logger.debug(
-            "fused softmax (PADDLE_TPU_FUSED_SOFTMAX=1) fell back to "
-            "the XLA path for scores %s: bias shape %s is neither a "
-            "per-row padding mask [B|1,1,1,Sk] nor a causal mask "
-            "[B|1,1,Sq,Sk]",
-            tuple(x.shape), tuple(bias.shape))
-    elif bias is not None and \
-            os.environ.get("PADDLE_TPU_FUSED_SOFTMAX", "0") == "1":
-        from paddle_tpu.profiler import runtime_metrics
-        runtime_metrics.inc("attention.fused_softmax_fallback")
-        logger.debug(
-            "fused softmax (PADDLE_TPU_FUSED_SOFTMAX=1) fell back to "
-            "the XLA path: scores are rank %d, the Pallas kernel needs "
-            "4-D attention-shaped [B,H,Sq,Sk] scores", x.ndim)
     out_dtype = x.dtype
     if bias is not None:
         # add in X's dtype: under bf16 AMP the materialization candidate

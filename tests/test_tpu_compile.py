@@ -157,10 +157,10 @@ def test_packed_attention_on_a_data_mesh_compiles_per_shard(topo, shape,
     def fn(q, k, v, mask, g):
         outs = []
         for causal in (False, True):
-            out, res = A._kernel_forward(q, k, v, mask, causal, 0.125,
-                                         heads, mesh)
-            outs += A._kernel_backward(q, k, v, mask, out, res, g, causal,
-                                       0.125, heads, mesh)
+            out, res = A._attention_forward(q, k, v, mask, causal, 0.125,
+                                            heads, True, mesh)
+            outs += A._attention_backward(q, k, v, mask, out, res, g,
+                                          causal, 0.125, heads, True, mesh)
         return outs
 
     rows = NamedSharding(mesh, PartitionSpec("data"))
@@ -880,24 +880,6 @@ def test_a_full_layers_decode_attention_copies_no_projection_matrix(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 16 << 20, \
         compiled.memory_analysis()
-
-
-@pytest.mark.parametrize("bias", ["row", "causal"])
-def test_fused_softmax_compiles_for_v5e(one_chip, bias):
-    import jax.numpy as jnp
-    from paddle_tpu.ops import attention_ops as A
-    B, H, S = 32, 8, 1024
-
-    def fn(x, b):
-        row, tri = (b, None) if bias == "row" else (None, b)
-        out = A._pallas_softmax_fwd(x, row, tri, interpret=False)
-        assert out is not None, "tiling gate refused a real width"
-        return out
-
-    bshape = (B, S) if bias == "row" else (S, S)
-    hlo = _compile(fn, one_chip, ((B, H, S, S), jnp.bfloat16),
-                   (bshape, jnp.float32))
-    assert "tpu_custom_call" in hlo
 
 
 # ---------------------------------------------------------------------------
