@@ -779,11 +779,10 @@ def _c_mla_attention(op, info):
 
 @rule("mla_attention_chunk")
 def _c_mla_attention_chunk(op, info):
-    """ONE CHUNK of a prompt over the slot's pages, the latent rows as
-    they are cached: both halves of W_kvb against the chunk's rows (the
-    absorbed queries, the context taken out), then every head's scores
-    over a whole row and its context over the row's value lanes, charged
-    the pairs of the chunk's LAST position, the end of its page bucket
+    """ONE CHUNK of a prompt over the slot's pages, EXPANDED: K and V of
+    every head from the rows it attends (2 L a lane of W_kvb a row),
+    then (nope + rope + v) lanes a head a pair, charged the rows and the
+    pairs of the chunk's LAST position, the end of its page bucket
     (``C`` rows over the bucket's, less the triangle above the
     diagonal).  Bytes: the rows it attends, once, and its own; never the
     pool."""
@@ -796,7 +795,9 @@ def _c_mla_attention_chunk(op, info):
     rows, pool = max(found[1], c), found[3]
     row = pool.shape[-1]
     pairs = c * (c + 1) // 2 + c * (rows - c)
-    flops = 2 * c * w[0] * w[1] + 2 * pairs * h * (row + w[0])
+    lanes = int(op.attr("nope_dim")) + int(op.attr("rope_dim")) \
+        + int(op.attr("v_dim"))
+    flops = 2 * rows * w[0] * w[1] + 2 * pairs * h * lanes
     item = _DTYPE_BYTES.get(str(pool.dtype), 4)
     bytes_ = (c * (q[2] + h * int(op.attr("v_dim")))
               + (rows + 2 * c) * row) * item

@@ -15,14 +15,16 @@ precedes the untied head.
   | k_r]`` and nothing else, stored ``latent_row`` wide (the next
   multiple of 128 lanes, zeros behind: the chip's DMA takes whole vregs,
   and a 576-wide array is laid out 640 wide in its memory anyway).  TWO
-  attention forms over the same weights: a whole sequence (the training
-  forward) EXPANDS K and V of every head from the latent
-  (``mla_attention``); the serving programs ABSORB ``W_kvb`` into the
-  query and out of the context and attend over the cached rows as they
-  are, reading each once for scores and values: a chunk of a prompt
-  writes its rows into the slot's pages and attends the pages' rows
-  through its own (``mla_attention_chunk``), the decode step does with
-  its one row (``mla_absorb``, ``paged_attention_latent``).
+  attention forms over the same weights.  EXPANDED, K and V of every
+  head made from the latent: a whole sequence (the training forward,
+  ``mla_attention``) and a chunk of a prompt, which writes its rows into
+  the slot's pages and attends the pages' rows under its diagonal, a
+  key block expanded where the kernel uses it (``mla_attention_chunk``:
+  a thousand query rows a key row pay for the expansion).  ABSORBED,
+  ``W_kvb`` folded into the query and out of the context: the decode
+  step, whose one row a slot attends the cached rows as they are,
+  reading each once for scores and values (``mla_absorb``,
+  ``paged_attention_latent``).
 * **Learned sparse attention** (``ops/dsa_ops.py``; a configuration
   with ``index_topk``, the ``glm_moe_dsa`` family).  A layer whose
   ``indexer_types`` entry is ``"full"`` holds an indexer: it scores

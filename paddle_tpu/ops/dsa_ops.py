@@ -45,9 +45,10 @@ triangular products, not a cumulative sum over the whole row).
 A whole sequence's attention under a selection is
 ``selected_attention``, a flash forward kernel that takes the
 selection's int8 blocks beside the keys' (``mla_ops.mla_attention``
-calls it on the TPU); a chunk's is ``window_ops``'s kernel with
-``select=`` (``mla_ops.mla_attention_chunk``); the decode step's is the
-latent paged kernel with ``select=``.
+calls it on the TPU); a chunk's is ``window_ops``'s causal kernel with
+``select=`` beside the latent blocks it expands a head at a time
+(``mla_ops.mla_attention_chunk``); the decode step's is the latent paged
+kernel with ``select=``.
 
 Op scopes on the device trace: ``ptop_dsa_index*`` (projections, rotary
 lanes, scores), ``ptop_dsa_select*`` (the top-k).

@@ -23,12 +23,17 @@ cached rows between the two.
 prompt over the slot's own pages of the latent pool.  It writes the
 chunk's rows where the decode step reads them, then attends the chunk's
 queries (positions ``P .. P + C - 1``) over the pages' rows ``0 .. P + C
-- 1`` AS THEY ARE CACHED: the queries absorbed as the decode step's are,
-every head over the one 640-wide row a token (``window_ops``'s causal
-flash kernel with one K/V head, the diagonal shifted by ``P``, the
-selection's blocks beside the keys' where there is one), the context
-taken out of the latent.  Nothing is expanded, so a chunk's work follows
-the rows under its diagonal and not its page bucket.
+- 1``, EXPANDED as ``mla_attention``: every head its own key and value
+(``window_ops``'s causal flash kernel at one query head a K/V head, the
+diagonal shifted by ``P``, the selection's blocks beside the keys' where
+there is one), a (head, key block) made in VMEM from the block's latent
+rows as they are cached and the head's columns of ``W_kvb``
+(``chunk_weights``) where it is used.  No key block above the diagonal
+is expanded, so a chunk's work follows the rows under its diagonal and
+not its page bucket; a pair of rows costs a head (nope + rope + v) lanes
+where the absorbed form's costs 2 L + rope, which is why the chunk (a
+thousand query rows a key row) expands and the decode step (one) does
+not.
 
 A WINDOW layer of latent attention (``models/latent_moe.py`` with
 ``layer_types``: row ``t`` sees rows ``u <= t`` with ``t - u < window``)
@@ -347,6 +352,27 @@ def mla_absorb_lower(ctx):
     ctx.set_output("Out", out.reshape(x.shape[:-1] + out.shape[-1:]))
 
 
+def chunk_weights(w_kvb, n_head, nope, rope_dim, v_dim, width):
+    """``W_kvb`` [L, H * (nope + v)] as the chunk kernel multiplies a
+    block of cached rows ``[c_kv | k_rope | pad]`` (``width`` lanes) by
+    it: ``(w_k [width, H * Dk], w_v [L, H * v])``, the ``expand`` of
+    ``window_ops.flash_attention``.  Head ``h``'s columns of ``w_k``
+    take ``c_kv`` through ``W_k`` into the key's leading ``nope`` lanes
+    and pass the shared rotary key as it is into the ``rope_dim`` lanes
+    behind them (ones: the float32 sum of one value is that value), then
+    zeros up to ``Dk``, whole 128-lane tiles; the row's pad lanes meet
+    zeros."""
+    L = w_kvb.shape[0]
+    w_k, w_v = _split_kvb(w_kvb, n_head, nope, v_dim)
+    Dk = -(-(nope + rope_dim) // 128) * 128
+    passed = jnp.zeros((width - L, Dk), w_kvb.dtype).at[
+        jnp.arange(rope_dim), nope + jnp.arange(rope_dim)].set(1)
+    w_k = jnp.concatenate([
+        jnp.pad(w_k, ((0, 0), (0, 0), (0, Dk - nope))),
+        jnp.broadcast_to(passed[:, None], (width - L, n_head, Dk))])
+    return w_k.reshape(width, -1), w_v.reshape(L, -1)
+
+
 def mla_attention_chunk(q, row, w_kvb, pool, table, start, real, n_head,
                         nope, rope_dim, v_dim, scale, select=None,
                         interpret=None):
@@ -357,19 +383,46 @@ def mla_attention_chunk(q, row, w_kvb, pool, table, start, real, n_head,
     slot's pages; ``select`` [C, P * page_len] int8 or None.  The real
     rows are written at their positions, then the chunk attends the
     pages' rows ``0 ..`` under the diagonal shifted by ``start`` (and
-    under the selection).  Returns ``(out [C, H * v], pool)``."""
-    from paddle_tpu.ops.attention_ops import _paged_cache_update
-    from paddle_tpu.ops.window_ops import prefill_attention
+    under the selection), EXPANDED: every head its own K/V head in
+    ``window_ops``'s causal flash kernel, which makes a (head, key
+    block) from the block's latent rows where it uses it
+    (``chunk_weights``; the queries laid out as the keys are, ``Dk``
+    lanes a head) and none above the diagonal.  Where the block rule
+    refuses the rows, or on the chip the lanes are not whole tiles, the
+    composed form over the expanded bucket (toy sizes); counted once a
+    lowering, ``attention.latent_chunk_kernel`` /
+    ``attention.latent_chunk_composed``.  Returns ``(out [C, H * v],
+    pool)``."""
+    from paddle_tpu.ops.attention_ops import (_paged_cache_update,
+                                              _use_interpret)
+    from paddle_tpu.ops.window_ops import (composed_attention,
+                                           flash_attention, flash_blocks)
+    from paddle_tpu.profiler import runtime_metrics
+    if interpret is None:
+        interpret = _use_interpret()
     C, L, W = q.shape[0], w_kvb.shape[0], pool.shape[-1]
     pool, = _paged_cache_update((pool,), (row[None],), table,
                                 (start + C).reshape(1, 1), row_lens=real)
-    keys = pool[table[0]].reshape(-1, W).astype(q.dtype)
-    q_lat = mla_absorb(q, w_kvb, n_head, nope, v_dim, "q",
-                       pad=W - L - rope_dim)
-    ctx = prefill_attention(q_lat, keys, keys[:, :L], None, n_head, 1,
-                            scale, 0, start=start, interpret=interpret,
-                            select=select)
-    return mla_absorb(ctx, w_kvb, n_head, nope, v_dim, "o"), pool
+    rows = pool[table[0]].reshape(-1, W).astype(q.dtype)
+    T = rows.shape[0]
+    kernel = flash_blocks(C, 1, 0, keys=T) is not None and (
+        interpret or not (W % 128 or L % 128 or v_dim % 128))
+    runtime_metrics.inc("attention.latent_chunk_kernel" if kernel
+                        else "attention.latent_chunk_composed")
+    if not kernel:
+        k_nope, k_rope, v = mla_expand(rows, w_kvb, n_head, nope, rope_dim,
+                                       v_dim, q.dtype)
+        return composed_attention(
+            q, _keys(k_nope, k_rope).reshape(T, -1), v.reshape(T, -1),
+            n_head, n_head, scale, start=start, select=select), pool
+    expand = chunk_weights(w_kvb.astype(q.dtype), n_head, nope, rope_dim,
+                           v_dim, W)
+    q = jnp.pad(q.reshape(C, n_head, -1), ((0, 0), (0, 0), (
+        0, expand[0].shape[1] // n_head - nope - rope_dim)))
+    return flash_attention(
+        q.reshape(C, -1), rows, None, None, start, None, select, expand,
+        n_head=n_head, n_kv_head=n_head, scale=scale,
+        interpret=interpret), pool
 
 
 @register_op("mla_attention_chunk", infer_shape=_infer_mla_attention,

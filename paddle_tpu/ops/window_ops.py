@@ -42,9 +42,10 @@ widths and a rotary on the LEADING lanes of a head.
   chunk's queries (positions ``P .. P + C - 1``) over the pages' rows
   ``0 .. P + C - 1``: the causal kernel with more key rows than query
   rows and its diagonal shifted by ``P``, a scalar the kernel is handed
-  before its grid runs (the latent builder's chunk is the same call with
-  ONE K/V head, the cached row, under all its query heads, and where a
-  layer selects its rows the selection's int8 blocks beside the keys':
+  before its grid runs (the latent builder's chunk is the same kernel
+  with every head its own K/V head, EXPANDED a key block at a time in
+  VMEM from the slot's latent rows, and where a layer selects its rows
+  the selection's int8 blocks beside the keys':
   ``mla_ops.mla_attention_chunk``).  A window layer's is
   ``window_attention`` with the rings as inputs: its keys are the slot's
   ring rows of positions
@@ -58,7 +59,10 @@ query heads of a K/V head share its key blocks in one ``[G * rows, Dk] x
 [Dk, keys]`` product, over head-major copies of the operands; with ONE
 query head a K/V head and heads of whole 128-lane tiles (an expanded
 latent chunk, ``mla_ops.latent_window_attention``) a head's blocks are
-columns of the operands as they lie and nothing is copied.  Where ``T``
+columns of the operands as they lie and nothing is copied; with
+``expand=`` (a full layer's latent chunk) the keys are the LATENT rows
+and a head's key and value block is made from them where it is used.
+Where ``T``
 is not whole blocks (``flash_blocks``) the composed ``[Hkv, G, T, T]``
 form runs (toy sizes, and what the tests hold the kernel to).
 
@@ -206,19 +210,34 @@ def flash_blocks(T, group, window, keys=None):
     query heads a K/V head, over ``keys`` key rows where the call is
     causal (None: ``T`` of them); None where they are not whole blocks
     of both.  ``FLASH_LEFT_ROWS`` left rows a step over the kind's key
-    block, but under a band with ONE query head a K/V head and a power
-    of two of rows short of that: square blocks."""
+    block, but with ONE query head a K/V head and a power of two of rows
+    short of that: square blocks under a band, the chunk's own rows the
+    query block (over key blocks twice as long where the keys allow)
+    under none."""
     bq = max(FLASH_LEFT_ROWS // group, 16)
     bk = BAND_KEY_BLOCK if window else CAUSAL_KEY_BLOCK
-    if window and group == 1 and bk <= T < bq and not T & (T - 1):
+    if group == 1 and bk <= T < bq and not T & (T - 1):
         # every head its own K/V head (an expanded latent chunk) and
-        # fewer rows than a left side: no heads to stack.  Square blocks
-        # of up to 512 rows: a grid step rescales its [bq, Dv] sums
-        # whatever the key block's width, so at 1024 rows under a window
-        # of 513 the chip took 0.58 ms with 512 x 512 (1024 keys a row
-        # computed, 256 steps), 0.89 with 256 x 256, 1.38 with 256 x 128
-        # and 2.13 with 1024 x 128
-        bq = bk = min(T, 512)
+        # fewer rows than a left side: no heads to stack
+        if window:
+            # square blocks of up to 512 rows: a grid step rescales its
+            # [bq, Dv] sums whatever the key block's width, so at 1024
+            # rows under a window of 513 the chip took 0.58 ms with 512
+            # x 512 (1024 keys a row computed, 256 steps), 0.89 with 256
+            # x 256, 1.38 with 256 x 128 and 2.13 with 1024 x 128
+            bq = bk = min(T, 512)
+        else:
+            # ONE query block: a (head, key block) that is expanded
+            # where it is used (``flash_attention``'s ``expand``) is
+            # expanded once.  Key blocks of 1024 rows where the keys are
+            # whole blocks of that: 1024 rows of 128 heads at position
+            # 4096 under a selection took the chip 6.2 ms with 1024 x
+            # 1024, 7.5 with 1024 x 2048, 8.5 with 1024 x 512, 9.6 with
+            # 512 x 512 (every key block expanded twice) and 13.4 with
+            # 1024 x 256 or 1024 x 4096
+            bq = T
+            if not (T if keys is None else keys) % (2 * bk):
+                bk *= 2
     if T % bq or bq % 16:
         return None
     if bq % bk if window else (T if keys is None else keys) % bk:
@@ -256,7 +275,7 @@ def key_blocks_computed(T, group, window, start=0, keys=None):
 
 
 def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
-                  select=False, flat=False):
+                  select=False, flat=False, expand=False):
     """One (K/V head, query block); the key blocks that meet the band
     (``window`` > 0: the keys begin with ``lead`` blocks of the rows
     before the chunk, of which those from index ``s`` on are real) or
@@ -269,10 +288,17 @@ def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
     already), for every one of the ``G`` heads.  ``flat`` (``G`` = 1):
     the blocks are ``[rows, lanes]`` columns of the operands as they lie
     (``[T, H * D]``), not ``[1, (G,) rows, lanes]`` of head-major
-    copies."""
+    copies.  ``expand`` (``flat``): the key block is ``[bk, W]`` LATENT
+    rows, and the head's key and value block are made from it here, in
+    VMEM, by the head's columns of the two expansion matrices
+    (``flash_attention``'s ``expand``), rounded to the rows' type."""
     if sink:
         sink_ref, *refs = refs
-    q_ref, k_ref, v_ref, *refs = refs
+    q_ref, k_ref, *refs = refs
+    if expand:
+        w_k_ref, w_v_ref, *refs = refs
+    else:
+        v_ref, *refs = refs
     if select:
         sel_ref, *refs = refs
     o_ref, acc, m_scr, l_scr = refs
@@ -297,7 +323,15 @@ def _flash_kernel(s_ref, *refs, scale, window, bq, bk, lead, sink,
 
     def update(masked):
         q = block(q_ref).reshape(G * bq, q_ref.shape[-1])
-        k, v = block(k_ref), block(v_ref)
+        if expand:
+            rows = k_ref[...]
+            made = lambda x, w_ref: jax.lax.dot_general(
+                x, w_ref[...], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32).astype(rows.dtype)
+            k = made(rows, w_k_ref)
+            v = made(rows[:, :w_v_ref.shape[0]], w_v_ref)
+        else:
+            k, v = block(k_ref), block(v_ref)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         if select:
@@ -359,8 +393,8 @@ def _led(k, v, before, Tq):
 @functools.partial(jax.jit, static_argnames=(
     "n_head", "n_kv_head", "scale", "window", "interpret", "blocks"))
 def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
-                    *, n_head, n_kv_head, scale, window=0, interpret=False,
-                    blocks=None):
+                    expand=None, *, n_head, n_kv_head, scale, window=0,
+                    interpret=False, blocks=None):
     """``q`` [Tq, H * Dk]; ``sink`` [H] or None -> [Tq, H * Dv] in
     ``q``'s type, as ``composed_attention``.
 
@@ -375,17 +409,29 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
     own already.  ``select`` (causal alone)
     [Tq, Tk] int8: query row ``r`` attends the keys it marks and no
     other, whatever its head (a selection holds no key behind its row:
-    the diagonal is not looked at again).  ``blocks`` is for the tests:
-    the kernel reads it from the shapes (``flash_blocks``); ``Tq`` must
-    be whole blocks."""
+    the diagonal is not looked at again).  ``expand`` (causal, every
+    head its own K/V head) = ``(w_k [W, H * Dk], w_v [L, H * Dv])``:
+    ``k`` [Tk, W] holds LATENT rows and ``v`` is None; head ``h``'s key
+    block is the rows' block times ``w_k``'s columns of ``h``, its value
+    block the rows' leading ``L`` lanes times ``w_v``'s, float32 sums
+    rounded to the rows' type, made in VMEM where a (head, key block)
+    is used and never all at once (one query block: once).  ``blocks``
+    is for the tests: the kernel reads it from the shapes
+    (``flash_blocks``); ``Tq`` must be whole blocks."""
     Tq = q.shape[0]
     G = n_head // n_kv_head
-    Dk, Dv = k.shape[-1] // n_kv_head, v.shape[-1] // n_kv_head
+    if expand is None:
+        Dk, Dv = k.shape[-1] // n_kv_head, v.shape[-1] // n_kv_head
+    else:
+        Dk, Dv = (w.shape[-1] // n_head for w in expand)
     bq, bk = blocks or flash_blocks(Tq, G, window, keys=k.shape[0])
     if bq & (bq - 1):
         raise ValueError(f"query block of {bq} rows is not a power of two")
     if window and select is not None:
         raise ValueError("a selection comes with the causal form alone")
+    if expand is not None and (window or G > 1):
+        raise ValueError("rows are expanded in the causal form alone, "
+                         "every head its own K/V head")
     per = bq // bk if window else 0
     lead = -(-(window - 1) // bk) if window else 0
     if window:
@@ -404,46 +450,56 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
             j, (s[0] + i * bq + bq - 1) // bk), n_k - 1)
     # one query head a K/V head, whole lane tiles a head: a head's blocks
     # are columns of the rows as they lie
-    flat = G == 1 and not (Dk % 128 or Dv % 128)
+    flat = expand is not None or G == 1 and not (Dk % 128 or Dv % 128)
     if flat:
-        qh, kh, vh = q, k, v
-        kv = lambda h, i, j, s: (kb(i, j, s), h)
-        q_spec, k_spec, v_spec, o_spec = (
-            pl.BlockSpec((bq, Dk), lambda h, i, j, s: (i, h)),
-            pl.BlockSpec((bk, Dk), kv), pl.BlockSpec((bk, Dv), kv),
-            pl.BlockSpec((bq, Dv), lambda h, i, j, s: (i, h)))
+        q_spec, o_spec = (pl.BlockSpec((bq, D), lambda h, i, j, s: (i, h))
+                          for D in (Dk, Dv))
+        if expand is None:
+            operands = [q, k, v]
+            kv_specs = [pl.BlockSpec(
+                (bk, D), lambda h, i, j, s: (kb(i, j, s), h))
+                for D in (Dk, Dv)]
+        else:
+            # the latent block is every head's; a head's columns of the
+            # two matrices stay where they are while its key blocks pass
+            operands = [q, k, *expand]
+            kv_specs = [pl.BlockSpec(
+                (bk, k.shape[-1]), lambda h, i, j, s: (kb(i, j, s), 0))] + [
+                pl.BlockSpec((w.shape[0], D), lambda h, i, j, s: (0, h))
+                for w, D in zip(expand, (Dk, Dv))]
         out_shape = (Tq, n_head * Dv)
     else:
         # head-major: a K/V head's G query heads side by side
-        qh = q.reshape(Tq, n_kv_head, G, Dk).transpose(1, 2, 0, 3)
-        kh = k.reshape(-1, n_kv_head, Dk).transpose(1, 0, 2)
-        vh = v.reshape(-1, n_kv_head, Dv).transpose(1, 0, 2)
+        operands = [q.reshape(Tq, n_kv_head, G, Dk).transpose(1, 2, 0, 3),
+                    k.reshape(-1, n_kv_head, Dk).transpose(1, 0, 2),
+                    v.reshape(-1, n_kv_head, Dv).transpose(1, 0, 2)]
         kv = lambda h, i, j, s: (h, kb(i, j, s), 0)
-        q_spec, k_spec, v_spec, o_spec = (
-            pl.BlockSpec((1, G, bq, Dk), lambda h, i, j, s: (h, 0, i, 0)),
-            pl.BlockSpec((1, bk, Dk), kv), pl.BlockSpec((1, bk, Dv), kv),
-            pl.BlockSpec((1, G, bq, Dv), lambda h, i, j, s: (h, 0, i, 0)))
+        q_spec, o_spec = (
+            pl.BlockSpec((1, G, bq, D), lambda h, i, j, s: (h, 0, i, 0))
+            for D in (Dk, Dv))
+        kv_specs = [pl.BlockSpec((1, bk, Dk), kv),
+                    pl.BlockSpec((1, bk, Dv), kv)]
         out_shape = (n_kv_head, G, Tq, Dv)
-    operands, in_specs = [], []
+    in_specs = [q_spec] + kv_specs
     if sink is not None:
         # the sink a row of the left side: [Hkv, G * bq, 1]
-        operands.append(jnp.repeat(
+        operands.insert(0, jnp.repeat(
             sink.astype(jnp.float32).reshape(n_kv_head, G), bq,
             axis=1)[..., None])
-        in_specs.append(pl.BlockSpec((1, G * bq, 1),
-                                     lambda h, i, j, s: (h, 0, 0)))
-    selected = []
+        in_specs.insert(0, pl.BlockSpec((1, G * bq, 1),
+                                        lambda h, i, j, s: (h, 0, 0)))
     if select is not None:
-        selected = [pl.BlockSpec(
-            (bq, bk), lambda h, i, j, s: (i, kb(i, j, s)))]
+        operands.append(select.astype(jnp.int8))
+        in_specs.append(pl.BlockSpec(
+            (bq, bk), lambda h, i, j, s: (i, kb(i, j, s))))
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, window=window, bq=bq,
                           bk=bk, lead=lead, sink=sink is not None,
-                          select=select is not None, flat=flat),
+                          select=select is not None, flat=flat,
+                          expand=expand is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(n_kv_head, Tq // bq, n_j),
-            in_specs=in_specs + [q_spec, k_spec, v_spec] + selected,
-            out_specs=o_spec,
+            in_specs=in_specs, out_specs=o_spec,
             scratch_shapes=[pltpu.VMEM((G * bq, Dv), jnp.float32),
                             pltpu.VMEM((G * bq, 1), jnp.float32),
                             pltpu.VMEM((G * bq, 1), jnp.float32)]),
@@ -452,8 +508,7 @@ def flash_attention(q, k, v, sink=None, start=0, before=None, select=None,
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
-    )(jnp.asarray(start, jnp.int32).reshape(1), *operands, qh, kh, vh,
-      *(() if select is None else (select.astype(jnp.int8),)))
+    )(jnp.asarray(start, jnp.int32).reshape(1), *operands)
     if flat:
         return out
     return out.transpose(2, 0, 1, 3).reshape(Tq, n_head * Dv)

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import latent_chunks
 import paddle_tpu as fluid
 from paddle_tpu.analysis import cost
 from paddle_tpu.gen import GenPredictor
@@ -374,18 +375,13 @@ def test_the_expanded_chunk_is_the_absorbed_steps_attention():
     assert np.allclose(chunk, step, atol=2e-5)
 
 
-def test_a_chunk_program_at_the_cells_widths_counts_six_kernels():
-    """``attention.latent_window_kernel`` / ``..._composed`` count, once
-    a lowering, which form a window layer's chunk took: the long-document
-    cell's chunk program (its adapter's configuration, BUILT, no weight
-    allocated; its six ``latent_window_attention`` ops traced over
-    shapes alone, 1024 rows a chunk) takes the banded kernel six times
-    and the composed form never."""
+def _cell_chunk_program(config="dots3_note_prev"):
+    """A latent cell's chunk program (its adapter's configuration,
+    BUILT, no weight allocated; the long-document cell's by default) ->
+    its block and the rows of a chunk."""
     from lib import models as adapters
     from paddle_tpu.framework import unique_name_scope
-    from paddle_tpu.ops import registry
-    from paddle_tpu.profiler import runtime_metrics
-    with open(os.path.join(BENCH, "configs", "dots3_note_prev.json")) as f:
+    with open(os.path.join(BENCH, "configs", config + ".json")) as f:
         published = json.load(f)
     kept = {}
     export = latent_moe.export_latent_model
@@ -403,28 +399,48 @@ def test_a_chunk_program_at_the_cells_widths_counts_six_kernels():
         latent_moe.build_chunk_program(
             hp, kept["num_slots"], page_len,
             kept["num_slots"] * hp.max_len // page_len)
-    block = main.global_block()
+    return main.global_block(), rows
+
+
+def _trace_op(block, op, rows, pages=1):
+    """One op's lowering traced over shapes alone: a chunk of ``rows``
+    rows over a page bucket of ``pages`` pages (a dynamic dim is the
+    chunk's rows, but the page table's pages and a selection's key
+    rows)."""
+    from paddle_tpu.ops import registry
+    names = list(op.input_arg_names)
+    keys = sum(pages * block.var(n).shape[1] for n in op.input("Cache"))
+
+    def shape(name):
+        dims = list(block.var(name).shape)
+        if name in op.input("PageTable") + op.input("Select"):
+            dims[-1] = pages if name in op.input("PageTable") else keys
+        return tuple(rows if d < 0 else d for d in dims)
+
+    def lower(*arrays):
+        ctx = registry.LowerContext(op, dict(zip(names, arrays)), block)
+        registry.lookup(op.type).lower(ctx)
+        return ctx.outputs
+
+    return jax.eval_shape(lower, *(jax.ShapeDtypeStruct(
+        shape(n), jnp.dtype(str(block.var(n).dtype))) for n in names))
+
+
+def test_a_chunk_program_at_the_cells_widths_counts_six_kernels():
+    """``attention.latent_window_kernel`` / ``..._composed`` count, once
+    a lowering, which form a window layer's chunk took: the long-document
+    cell's chunk program (its six ``latent_window_attention`` ops traced
+    over shapes alone, 1024 rows a chunk) takes the banded kernel six
+    times and the composed form never."""
+    from paddle_tpu.profiler import runtime_metrics
+    block, rows = _cell_chunk_program()
     ops = [op for op in block.ops if op.type == "latent_window_attention"]
     assert rows == 1024 and len(ops) == 6
-
-    def trace(op):
-        names = list(op.input_arg_names)
-        shapes = [jax.ShapeDtypeStruct(
-            tuple(rows if d < 0 else d for d in block.var(n).shape),
-            jnp.dtype(str(block.var(n).dtype))) for n in names]
-
-        def lower(*arrays):
-            ctx = registry.LowerContext(op, dict(zip(names, arrays)), block)
-            registry.lookup(op.type).lower(ctx)
-            return ctx.outputs
-
-        return jax.eval_shape(lower, *shapes)
-
     counted = ("attention.latent_window_kernel",
                "attention.latent_window_composed")
     before = [runtime_metrics.counter(n) for n in counted]
     for op in ops:
-        out = trace(op)
+        out = _trace_op(block, op, rows)
         assert out[op.output("Out")[0]].shape == (1, rows, 64 * 128)
         assert out[op.output("RingOut")[0]].shape == (16, 640, 1152)
     assert [runtime_metrics.counter(n) - b
@@ -437,6 +453,54 @@ def test_a_chunk_program_at_the_cells_widths_counts_six_kernels():
         16, 0.2, 9)
     assert [runtime_metrics.counter(n) - b
             for n, b in zip(counted, before)] == [6, 1]
+
+
+@pytest.mark.parametrize("start, n", latent_chunks.STARTS,
+                         ids=latent_chunks.START_IDS)
+def test_a_full_layers_chunk_under_its_selection_is_the_whole_sequences(
+        start, n):
+    """A FULL layer's chunk at this configuration's heads (128 | 64
+    lanes of key, laid out 256 wide; values of 128) under the top-300 of
+    seeded index scores, three heads: the kernel's form against
+    ``mla_attention`` over the whole prompt."""
+    latent_chunks.chunk_is_the_whole_sequence(128, 64, 128, start, n,
+                                              top_k=300, n_head=3, seed=7)
+
+
+@pytest.mark.parametrize("config, layers, width, shapes", [
+    ("dots3_note_prev", 3, 128 * 128, [(1024, 32), (1024, 288)]),
+    ("glm_5.2", 5, 64 * 256, [(1024, 32), (1024, 288)]),
+    ("kimi_k2.6_text", 5, 64 * 128, [(512, 32), (1024, 64), (1024, 256)]),
+])
+def test_the_latent_cells_chunk_programs_count_full_layer_kernels(
+        config, layers, width, shapes):
+    """``attention.latent_chunk_kernel`` / ``..._composed`` count, once a
+    lowering, which form a full layer's chunk took: every
+    ``mla_attention_chunk`` op of the three latent cells' chunk programs,
+    traced over shapes alone at (rows a chunk, pages of the bucket) from
+    the smallest bucket a rung takes to the largest (with and without a
+    selection), takes the kernel and the composed form never; toy rows
+    take the composed form."""
+    from paddle_tpu.profiler import runtime_metrics
+    block, rows = _cell_chunk_program(config)
+    ops = [op for op in block.ops if op.type == "mla_attention_chunk"]
+    assert rows == 1024 and len(ops) == layers
+    counted = ("attention.latent_chunk_kernel",
+               "attention.latent_chunk_composed")
+    before = [runtime_metrics.counter(n) for n in counted]
+    for chunk, pages in shapes:
+        for op in ops:
+            out = _trace_op(block, op, chunk, pages)
+            assert out[op.output("Out")[0]].shape == (1, chunk, width)
+    assert [runtime_metrics.counter(n) - b
+            for n, b in zip(counted, before)] == [layers * len(shapes), 0]
+    mla_ops.mla_attention_chunk(
+        jnp.zeros((24, 2 * 32)), jnp.zeros((24, 128)),
+        jnp.zeros((48, 2 * 40)), jnp.zeros((6, 8, 128)),
+        jnp.zeros((1, 4), jnp.int32), jnp.int32(0), jnp.ones((1, 24), bool),
+        2, 24, 8, 16, 0.2)
+    assert [runtime_metrics.counter(n) - b
+            for n, b in zip(counted, before)] == [layers * len(shapes), 1]
 
 
 def test_the_whole_sequence_form_is_the_chunk_forms_band():

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import latent_chunks
 import paddle_tpu as fluid
 from paddle_tpu import profiler
 from paddle_tpu.analysis import cost
@@ -226,9 +227,26 @@ def test_a_chunks_selection_is_the_whole_prompts_rows(start, n):
     assert np.array_equal(part, whole[start:start + C])
 
 
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernel", "composed"])
+@pytest.mark.parametrize("start, n", latent_chunks.STARTS,
+                         ids=latent_chunks.START_IDS)
+def test_a_chunk_under_its_selection_is_the_whole_sequences_rows(start, n,
+                                                                 kernel):
+    """``mla_attention_chunk`` at this configuration's heads (192 | 64
+    lanes of key, 256 of value: whole tiles already) under the top-300
+    of seeded index scores, the chunk's selection made over the page
+    bucket: the kernel's form, the selection's int8 blocks beside the
+    latent blocks it expands, and the composed one, against
+    ``mla_attention`` over the whole prompt under the whole prompt's
+    selection."""
+    latent_chunks.chunk_is_the_whole_sequence(192, 64, 256, start, n,
+                                              top_k=300, kernel=kernel)
+
+
 def test_the_chunk_kernel_under_a_selection_is_the_masked_softmax():
-    """``window_ops``'s flash kernel as the latent chunk calls it
-    (interpret mode): ONE K/V head under 4 query heads, 32 query rows at
+    """``window_ops``'s flash kernel under a selection (interpret
+    mode): ONE K/V head under 4 query heads, 32 query rows at
     key index 64 of 128, the selection's int8 blocks beside the keys';
     against the composed form, and both against the softmax by hand."""
     from paddle_tpu.ops import window_ops

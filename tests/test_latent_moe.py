@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import latent_chunks
 import paddle_tpu as fluid
 from paddle_tpu import profiler
 from paddle_tpu.analysis import cost, typecheck
@@ -259,6 +260,40 @@ def test_the_flash_prefill_equals_the_composed():
     composed = mla_ops.mla_attention(*args, flash=False)
     flash = mla_ops.mla_attention(*args, flash=True, interpret=True)
     assert np.allclose(composed[:21], flash[:21], atol=2e-6)
+
+
+@pytest.mark.parametrize("kernel", [True, False],
+                         ids=["kernel", "composed"])
+@pytest.mark.parametrize("start, n", latent_chunks.STARTS,
+                         ids=latent_chunks.START_IDS)
+def test_a_chunk_through_the_op_is_the_whole_sequences_rows(start, n, kernel):
+    """``mla_attention_chunk`` at heads of 128 | 64 lanes and values of
+    128, no selection, EXPANDED (the causal flash kernel at one query
+    head a K/V head, a (head, key block) made from the block's latent
+    rows in the kernel; and the composed form over the expanded bucket)
+    against ``mla_attention`` over the whole prompt."""
+    latent_chunks.chunk_is_the_whole_sequence(128, 64, 128, start, n,
+                                              kernel=kernel)
+
+
+def test_the_chunk_kernels_matrices_pass_the_rotary_key_as_it_is():
+    """``chunk_weights``: a cached row times head ``h``'s columns is
+    ``[c_kv W_k[h] | k_rope | 0]`` to the bit of ``mla_expand``'s, in
+    whole 128-lane tiles, whatever the row's pad lanes' neighbours."""
+    T, H, L, nope, R, vd, W = 9, 3, 32, 16, 8, 16, 128
+    _, latent, w_kvb, _ = _mla_case(T, H, L, nope, R, vd)
+    w_k, w_v = mla_ops.chunk_weights(w_kvb, H, nope, R, vd, W)
+    Dk = 128
+    assert (w_k.shape, w_v.shape) == ((W, H * Dk), (L, H * vd))
+    k_nope, k_rope, v = mla_ops.mla_expand(latent, w_kvb, H, nope, R, vd,
+                                           jnp.float32)
+    keys = (latent @ w_k).reshape(T, H, Dk)
+    assert np.allclose(keys[..., :nope], k_nope, atol=1e-6)
+    assert np.array_equal(keys[..., nope:nope + R],
+                          np.broadcast_to(k_rope[:, None], (T, H, R)))
+    assert not np.asarray(keys[..., nope + R:]).any()
+    assert np.allclose((latent[:, :L] @ w_v).reshape(T, H, vd), v,
+                       atol=1e-6)
 
 
 def test_the_latent_kernel_reads_one_pool_in_bfloat16():
@@ -750,8 +785,8 @@ def test_both_programs_typecheck_and_every_new_op_has_its_rules(bundle_dir):
     h, row, lat = 4, 128, 32
     for pages, got in zip((2, 4), by_bucket):
         pairs = 16 * 17 // 2 + 16 * (pages * PAGE_LEN - 16)
-        assert got["flops"] == 3 * (2 * 16 * lat * h * 32
-                                    + 2 * pairs * h * (row + lat))
+        assert got["flops"] == 3 * (2 * pages * PAGE_LEN * lat * h * 32
+                                    + 2 * pairs * h * (24 + 16))
         assert got["bytes"] == 3 * 4 * (16 * h * (24 + 16)
                                         + (pages * PAGE_LEN + 32) * row)
     # the table's width is the one dynamic dim: priced at live rows

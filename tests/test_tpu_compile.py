@@ -716,13 +716,14 @@ def test_a_latent_prefill_chunk_compiles_for_v5e_in_place(one_chip, pages,
     wide in pages of 64; the indexer's 32 heads x 128 and its exact
     top-2048 a query row over the page bucket's rows: the identity in the
     smallest bucket, the whole slot in the widest) and at the latent
-    cell's without an indexer (heads of 128 | 64, pages of 16): the
-    chunk's rows into the slot's pages of both pools, the absorbed
-    queries over the cached rows in the key-offset flash kernel under
-    the selection's int8 blocks, the context taken out.  Pools donated;
-    no sort; no temporary of a pool's size (nothing is expanded to
-    heads: the gathered rows of ONE slot, the scores of one query block
-    and the selection)."""
+    cell's without an indexer (heads of 128 | 64 laid out 256 wide,
+    values 128, pages of 16): the chunk's rows into the slot's pages of
+    both pools, then the key-offset flash kernel with every head its own
+    K/V head, EXPANDED a (head, key block) at a time in the kernel from
+    the cached rows, under the selection's int8 blocks.  Pools donated;
+    no sort; no temporary of a pool's size and no K or V of the bucket
+    (the gathered rows of ONE slot, ``W_kvb`` laid out for the kernel,
+    the padded queries and the selection)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import dsa_ops, mla_ops
@@ -1274,14 +1275,16 @@ def test_latent_decode_of_128_heads_under_64_index_heads_compiles_for_v5e(
 
 def test_a_full_layers_chunk_of_128_heads_compiles_for_v5e_in_place(one_chip):
     """A full layer's chunk of the same cell, 1024 rows over the widest
-    page bucket a chunk takes (256 pages of 64 rows): 128 heads of 128 |
-    64 over the 640-wide row as cached, the indexer's 64 heads x 128 and
-    its exact top-2048 a query row.  Pools donated; no sort."""
+    page bucket (288 pages of 64 rows): 128 heads of 128 | 64, each its
+    own K/V head, expanded in the kernel from the 640-wide row as
+    cached, the indexer's 64 heads x 128 and its exact top-2048 a query
+    row.  Pools donated; no sort; no K or V of the bucket (1.8 GB if
+    made whole)."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.ops import dsa_ops, mla_ops
     from paddle_tpu.ops.attention_ops import _paged_cache_update
-    C, S, PL, pages = 1024, 16, 64, 256
+    C, S, PL, pages = 1024, 16, 64, 288
     T = pages * PL
 
     def fn(q, row, w_kvb, pool, table, pos, mask, qi, ki, wi, keys):

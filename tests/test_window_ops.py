@@ -7,6 +7,7 @@ ring's lead-in and what a chunk leaves in it, the count of key blocks a
 band computes, the partial rotary, the typecheck rules of the ring and
 chunk ops, and the configuration's published keys."""
 
+import hashlib
 import json
 import os
 import sys
@@ -148,7 +149,13 @@ PINNED = os.path.join(os.path.dirname(os.path.abspath(__file__)), "golden",
 #: name, or their number; whether the op carries a band)
 KERNEL_OPS = {"window_attention": ("n_kv_head", True),
               "gqa_flash_attention_chunk": ("n_kv_head", False),
-              "mla_attention_chunk": (1, False)}
+              "mla_attention_chunk": ("n_head", False)}
+#: sha256 (16 hex digits) of the rows of the configurations that build no
+#: ``mla_attention_chunk``, as ``7f3a514``'s file held them
+AT_7F3A514 = {"mimo_v2_flash": "96dd2fa4ca0bc36c",
+              "k_exaone_236b_a23b": "c077b0cb5086c3a3",
+              "phi4_mini_flash": "cf5fa69a162c6b0f",
+              "solar_open2_250b": "4f3d7c582ce2647f"}
 PINNED_CONFIGS = ["mimo_v2_flash", "k_exaone_236b_a23b", "phi4_mini_flash",
                   "glm_5.2", "kimi_k2.6_text", "solar_open2_250b",
                   "dots3_note_prev"]
@@ -228,22 +235,34 @@ def test_the_block_rule_is_the_recorded_one_at_every_serving_shape(name):
     """``flash_blocks``, ``lead_rows`` and ``key_blocks_computed`` at
     every (rows, heads a K/V head, window, key rows) a serving bundle
     hands the kernel, the latent builder's full layers among them,
-    against ``tests/golden/flash_blocks_pinned.json`` (recorded at
-    ``717d37d``, before the rule admitted one query head a K/V head:
-    ``PYTHONPATH=. python tests/test_window_ops.py`` rewrites it from the tree it
-    runs on).  A shape the rule refuses is recorded as refused: its
-    chunk runs the composed form and goes on doing so."""
+    against ``tests/golden/flash_blocks_pinned.json`` (``PYTHONPATH=.
+    python tests/test_window_ops.py`` rewrites it from the tree it runs
+    on).  A shape the rule refuses is recorded as refused: its chunk
+    runs the composed form and goes on doing so.  The file was recorded
+    at ``717d37d`` and again when the latent builder's full layers went
+    from ONE K/V head under all query heads to every head its own: the
+    rows of the other builders' four configurations are still
+    ``7f3a514``'s (their digests), and every row of the three latent
+    configurations is a full layer's chunk at ONE query head a K/V
+    head."""
     with open(PINNED) as f:
         golden = json.load(f)[name]
     seen = [call + _block_rule_at(*call) for call in _kernel_calls(name)]
     assert seen and seen == golden
+    if name in AT_7F3A514:
+        assert hashlib.sha256(json.dumps(golden).encode()).hexdigest()[
+            :16] == AT_7F3A514[name]
+    else:
+        assert all(group == 1 and not window
+                   for _, group, window, *_ in golden)
 
 
 def test_the_block_rule_admits_one_query_head_a_kv_head():
-    """Every head its own K/V head under a band (an expanded latent
-    chunk) has no heads to stack into 2048 left rows: the rule, refused
-    there before, hands the chunk's own rows out as blocks; with heads
-    to stack, and under no band, it is as it was."""
+    """Every head its own K/V head (an expanded latent chunk) has no
+    heads to stack into 2048 left rows: the rule, refused there before,
+    hands the chunk's own rows out as blocks, square under a band, ONE
+    query block over the causal key block under none; with heads to
+    stack it is as it was."""
     blocks = window_ops.flash_blocks(1024, 1, 513)
     assert blocks is not None and 1024 % blocks[0] == 0 \
         and blocks[0] % blocks[1] == 0
@@ -253,9 +272,68 @@ def test_the_block_rule_admits_one_query_head_a_kv_head():
     assert window_ops.key_blocks_computed(1024, 1, 513, start=64) == (4, 512)
     assert window_ops.flash_blocks(128, 1, 40) == (128, 128)
     assert window_ops.flash_blocks(2048, 1, 513) == (2048, 128)
-    for refused in ((1024, 1, 0), (256, 4, 512), (512, 2, 513),
-                    (384, 1, 513), (64, 1, 513), (40, 1, 128)):
+    # causal: a full layer's chunk of 512 or 1024 rows over its bucket
+    assert window_ops.flash_blocks(1024, 1, 0) == (1024, 1024)
+    assert window_ops.flash_blocks(512, 1, 0, keys=4096) == (512, 1024)
+    assert window_ops.flash_blocks(512, 1, 0) == (512, 512)
+    assert window_ops.flash_blocks(1024, 1, 0, keys=1536) == (1024, 512)
+    assert window_ops.flash_blocks(2048, 1, 0) == (2048, 512)
+    assert window_ops.key_blocks_computed(1024, 1, 0, start=4096,
+                                          keys=8192) == (5, 1024)
+    for refused in ((1024, 1, 0, 256), (256, 1, 0), (768, 1, 0),
+                    (256, 4, 512), (512, 2, 513), (384, 1, 513),
+                    (64, 1, 513), (40, 1, 128)):
         assert window_ops.flash_blocks(*refused) is None, refused
+
+
+@pytest.mark.parametrize("start", [0, 24, 64], ids=["first", "off_a_block",
+                                                    "bucket_end"])
+@pytest.mark.parametrize("form", ["columns", "head_major", "expanded"])
+def test_the_causal_kernel_at_one_head_a_kv_head_takes_a_selection(form,
+                                                                    start):
+    """Every head its own K/V head, ONE query block of 64 rows over key
+    blocks of 32 (the shape of the block rule's answer for a full latent
+    chunk), under a selection that keeps half the keys under each row:
+    a head's blocks read as columns of the rows as they lie (heads of
+    whole lane tiles), from head-major copies (toy lanes), and made in
+    the kernel from LATENT rows and the head's columns of two matrices
+    (``expand``); against the composed attention."""
+    rng = np.random.RandomState(start)
+    C, T, H = 64, 128, 3
+    dk, dv = (24, 16) if form == "head_major" else (128, 128)
+    q = jnp.asarray(rng.randn(C, H * dk), jnp.float32) * 0.3
+    cols, rows = np.arange(T)[None], start + np.arange(C)[:, None]
+    select = jnp.asarray(((rng.rand(C, T) < 0.5) | (cols == rows))
+                         & (cols <= rows), jnp.int8)
+    kernel = dict(n_head=H, n_kv_head=H, scale=0.2, interpret=True,
+                  blocks=(64, 32))
+    if form == "expanded":
+        latent = jnp.asarray(rng.randn(T, 256), jnp.float32)
+        w_k = jnp.asarray(rng.randn(256, H * dk), jnp.float32) * 0.1
+        w_v = jnp.asarray(rng.randn(128, H * dv), jnp.float32) * 0.1
+        k, v = latent @ w_k, latent[:, :128] @ w_v
+        got = window_ops.flash_attention(q, latent, None, None, start, None,
+                                         select, (w_k, w_v), **kernel)
+    else:
+        k = jnp.asarray(rng.randn(T, H * dk), jnp.float32)
+        v = jnp.asarray(rng.randn(T, H * dv), jnp.float32)
+        got = window_ops.flash_attention(q, k, v, None, start, None, select,
+                                         **kernel)
+    want = window_ops.composed_attention(q, k, v, H, H, 0.2, start=start,
+                                         select=select)
+    assert np.allclose(got, want, atol=2e-4 if form == "expanded" else 2e-5)
+    # no selection: the shifted diagonal alone
+    if form == "expanded":
+        plain = window_ops.flash_attention(q, latent, None, None, start,
+                                           expand=(w_k, w_v), **kernel)
+        with pytest.raises(ValueError, match="causal form alone"):
+            window_ops.flash_attention(
+                q, latent[:C], None, expand=(w_k, w_v),
+                **dict(kernel, window=8, blocks=(32, 8)))
+    else:
+        plain = window_ops.flash_attention(q, k, v, None, start, **kernel)
+    assert np.allclose(plain, window_ops.composed_attention(
+        q, k, v, H, H, 0.2, start=start), atol=2e-4)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
