@@ -530,11 +530,14 @@ def _c_paged_attention_latent(op, info):
         return None
     s, t, q, pool = found
     w, h, v = pool.shape[-1], int(op.attr("n_head")), int(op.attr("v_width"))
+    # rows a slot a step (a turn that carries a draft: 2): every one of
+    # them scores the live rows, which are still read once
+    rows = q.shape[1] if len(q.shape) == 3 and q.shape[1] > 0 else 1
     item = _DTYPE_BYTES.get(str(pool.dtype), 4)
-    flops = 2 * s * t * h * (w + v)
-    bytes_ = (s * t * w                # the live rows, once
-              + s * h * (w + v)        # absorbed queries in, context out
-              + 2 * s * w) * item      # this step's row in + its write
+    flops = 2 * s * rows * t * h * (w + v)
+    bytes_ = (s * t * w                       # the live rows, once
+              + s * rows * h * (w + v)        # queries in, context out
+              + 2 * s * rows * w) * item      # the step's rows in + written
     return int(flops), int(bytes_)
 
 
@@ -571,7 +574,7 @@ _ELEMENTWISE_1X = (
     "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
     "reduce_prod", "sequence_pool", "sequence_expand", "top_k",
     "accuracy", "transpose", "transpose2", "reshape", "reshape2",
-    "concat", "lod_reset",
+    "unsqueeze", "expand", "concat", "lod_reset",
 )
 
 #: transcendental elementwise families: ~10 FLOPs per element (exp/log/
@@ -865,6 +868,46 @@ def _c_latent_window_step(op, info):
 rule("head_gate")(_per_element(12))
 
 
+# hyper-connections (ops/mhc_ops.py): a wrapper's two halves, priced in
+# their LEAST form (the streams read once a half)
+
+def _mhc_sizes(op, info):
+    x = _shape(info, op, "X")
+    if x is None or len(x) < 2 or not _known(x[-2], x[-1]):
+        return None
+    item = _DTYPE_BYTES.get(str(info(op.input("X")[0]).dtype), 4)
+    return numel(x[:-2]), x[-2], x[-1], item
+
+
+@rule("mhc_pre")
+def _c_mhc_pre(op, info):
+    """The statistic (2 a value), the product with ``phi`` (2 n (n + 2)
+    a value), the aggregate (2 a value) and ``sinkhorn_iters`` rounds of
+    4 n^2 a row; reads the streams and ``phi``, writes ``u`` and the
+    float32 coefficients."""
+    found = _mhc_sizes(op, info)
+    if found is None:
+        return None
+    rows, n, c, item = found
+    coef = n * (n + 2)
+    flops = rows * (n * c * (4 + 2 * coef)
+                    + 4 * n * n * int(op.attr("sinkhorn_iters")))
+    bytes_ = rows * (n * c + c) * item + 4 * (n * c * coef + rows * coef)
+    return int(flops), int(bytes_)
+
+
+@rule("mhc_post")
+def _c_mhc_post(op, info):
+    """``H_res x + H_post^T y``: 2 n a value of the n streams, + 2; reads
+    the streams and ``y``, writes the streams."""
+    found = _mhc_sizes(op, info)
+    if found is None:
+        return None
+    rows, n, c, item = found
+    return (int(rows * n * c * (2 * n + 2)),
+            int(rows * ((2 * n + 1) * c * item + 4 * n * (n + 1))))
+
+
 # learned sparse attention (ops/dsa_ops.py): what grows with the SQUARE of
 # a prefill's rows beside the attention itself
 
@@ -1149,4 +1192,6 @@ rule("moe_experts_gated_grad")(_twice(_c_moe_experts_gated))
 rule("mla_attention_grad")(_twice(_c_mla_attention))
 rule("latent_window_attention_grad")(_twice(_c_latent_window_attention))
 rule("head_gate_grad")(_per_element(12))
+rule("mhc_pre_grad")(_twice(_c_mhc_pre))
+rule("mhc_post_grad")(_twice(_c_mhc_post))
 rule("swiglu_grad", "rope_grad")(_per_element(6))

@@ -583,6 +583,33 @@ def _r_reshape(op, tc):
     tc.set_output(op, "Out", shape=shape or None, dtype=x.dtype)
 
 
+@rule("unsqueeze")
+def _r_unsqueeze(op, tc):
+    x = tc.input_info(op, "X")
+    shape = None
+    if x.shape is not None:
+        shape = list(x.shape)
+        for a in sorted(op.attr("axes") or ()):
+            shape.insert(a, 1)
+    tc.set_output(op, "Out", shape=shape and tuple(shape), dtype=x.dtype)
+
+
+@rule("expand")
+def _r_expand(op, tc):
+    x = tc.input_info(op, "X")
+    times = list(op.attr("expand_times") or ())
+    shape = None
+    if x.shape is not None:
+        if len(times) != len(x.shape):
+            tc.report("PTA006",
+                      f"expand of `{op.input('X')[0]}` {x.shape} by "
+                      f"{len(times)} factors", op=op, var=op.input("X")[0])
+        else:
+            shape = tuple(d * t if d is not None and d >= 0 else -1
+                          for d, t in zip(x.shape, times))
+    tc.set_output(op, "Out", shape=shape, dtype=x.dtype)
+
+
 @rule("transpose", "transpose2")
 def _r_transpose(op, tc):
     x = tc.input_info(op, "X")
@@ -724,8 +751,8 @@ _GRAD_MIRROR_OPS = tuple(
         "elementwise_min", "elementwise_pow", "sum", "mean", "concat",
         "reduce_sum", "reduce_mean", "reduce_max", "reduce_min",
         "reduce_prod", "cross_entropy", "softmax_with_cross_entropy",
-        "lookup_table", "nce", "reshape", "reshape2", "transpose",
-        "transpose2", "conv2d", "pool2d", "batch_norm", "layer_norm",
+        "lookup_table", "nce", "reshape", "reshape2", "unsqueeze", "expand",
+        "transpose", "transpose2", "conv2d", "pool2d", "batch_norm", "layer_norm",
         "sequence_pool", "lstm", "write_to_array", "read_from_array",
         "array_to_lod_tensor", "lod_tensor_to_array",
         "reorder_lod_tensor_by_rank",
@@ -1358,6 +1385,11 @@ def _r_paged_attention_latent(op, tc):
     rows = _paged_rows_of(op, tc)
     if rows is not None:
         _select_matches(op, tc, rows)
+    _int_index(op, tc, "RowLens")
+    if op.input("RowLens") and op.input("Select"):
+        tc.report("PTA006", "paged_attention_latent takes a limit a row "
+                  "(RowLens) or a selection (Select), not both", op=op,
+                  var=op.input("RowLens")[0])
     shape = None if q.shape is None else tuple(q.shape[:-1]) + (h * v,)
     tc.set_output(op, "Out", shape=shape, dtype=q.dtype)
     tc.set_output(op, "CacheOut", shape=cache.shape, dtype=cache.dtype)
@@ -1403,6 +1435,62 @@ def _r_head_gate(op, tc):
     if x.shape is not None and x.shape[-1] > 0 and x.shape[-1] % h:
         tc.report("PTA006", f"head_gate: {x.shape[-1]} features do not "
                   f"divide over {h} heads", op=op, var=op.input("X")[0])
+
+
+# hyper-connections (ops/mhc_ops.py)
+
+def _mhc_streams(op, tc):
+    """``(lead, n, C)`` of the streams ``X`` [..., n, C], or None."""
+    x = tc.input_info(op, "X")
+    if x.shape is None or len(x.shape) < 2:
+        return None
+    return tuple(x.shape[:-2]), x.shape[-2], x.shape[-1]
+
+
+@rule("mhc_pre")
+def _r_mhc_pre(op, tc):
+    x, found = tc.input_info(op, "X"), _mhc_streams(op, tc)
+    if found is None:
+        for slot in ("U", "Post", "Res"):
+            tc.set_output(op, slot, shape=None, dtype=None)
+        return
+    lead, n, c = found
+    if n > 0:
+        _last_dim_is(op, tc, "Phi", n * (n + 2),
+                     "columns (pre, post and res: n (n + 2))")
+        _last_dim_is(op, tc, "Bias", n * (n + 2), "entries (n (n + 2))")
+        phi = tc.input_info(op, "Phi")
+        if c > 0 and phi.shape is not None and len(phi.shape) == 2 \
+                and phi.shape[0] > 0 and phi.shape[0] != n * c:
+            tc.report("PTA006", f"mhc_pre Phi `{op.input('Phi')[0]}` has "
+                      f"{phi.shape[0]} rows, expected {n} streams x {c}",
+                      op=op, var=op.input("Phi")[0])
+    _last_dim_is(op, tc, "Alpha", 3, "gains (pre, post, res)")
+    for slot in ("Phi", "Alpha", "Bias"):
+        inf = tc.input_info(op, slot)
+        if inf.dtype is not None and inf.dtype != "float32":
+            tc.report("PTA005", f"mhc_pre {slot} `{op.input(slot)[0]}` "
+                      f"must be float32 (the coefficients' type), got "
+                      f"{inf.dtype}", op=op, var=op.input(slot)[0])
+    tc.set_output(op, "U", shape=lead + (c,), dtype=x.dtype)
+    tc.set_output(op, "Post", shape=lead + (n,), dtype="float32")
+    tc.set_output(op, "Res", shape=lead + (n, n), dtype="float32")
+
+
+@rule("mhc_post")
+def _r_mhc_post(op, tc):
+    x, found = _same_as(op, tc), _mhc_streams(op, tc)
+    if found is None:
+        return
+    _, n, c = found
+    _last_dim_is(op, tc, "Y", c, "features (a stream's width)")
+    _last_dim_is(op, tc, "Post", n, "entries (one a stream)")
+    _last_dim_is(op, tc, "Res", n, "columns (one a stream)")
+    y = tc.input_info(op, "Y")
+    if y.dtype is not None and x.dtype is not None and y.dtype != x.dtype:
+        tc.report("PTA005", f"mhc_post Y `{op.input('Y')[0]}` is "
+                  f"{y.dtype}, the streams {x.dtype}", op=op,
+                  var=op.input("Y")[0])
 
 
 # learned sparse attention (ops/dsa_ops.py)
@@ -1613,7 +1701,8 @@ rule("split_grad", "relu2_grad", "rms_norm_grad",
      "moe_route_grad", "moe_experts_grad", "moe_experts_gated_grad",
      "gqa_attention_grad", "rope_grad", "swiglu_grad", "pad_grad",
      "mla_attention_grad", "latent_window_attention_grad",
-     "head_gate_grad", "rope_partial_grad", "window_attention_grad",
+     "head_gate_grad", "mhc_pre_grad", "mhc_post_grad",
+     "rope_partial_grad", "window_attention_grad",
      "gqa_flash_attention_grad", "attention_out_gate_grad",
      "mamba_scan_grad", "diff_attention_pad_grad",
      "diff_attention_out_grad")(_r_grad_mirror)

@@ -276,6 +276,9 @@ class GenPredictor:
         # without) and yields at most
         self.speculative = self.meta.get("speculative")
         self.spec_rows = int((self.speculative or {}).get("rows", 1))
+        # hyper-connections (``ops/mhc_ops.py``): the residual streams
+        # and the wrappers a row passes; None without
+        self.hyper_connections = self.meta.get("hyper_connections")
         # what the newest decode step's selections and the rows its two
         # kinds of layer read counted to (the step span's attributes)
         self.last_step_counts = {}
@@ -789,7 +792,8 @@ class GenPredictor:
         ``tokens``, ``start``, ``rows`` (as run, pads included),
         ``pages`` and, with window layers, its own ``band_pairs`` /
         ``causal_pairs`` / ``*_key_blocks``, under learned sparse
-        attention its own ``dsa_rows_scored`` / ``dsa_rows_selected``.
+        attention its own ``dsa_rows_scored`` / ``dsa_rows_selected``,
+        under hyper-connections its ``mhc_rows``.
         Always-on:
         ``gen.prefill.chunks``, ``gen.prefill.rows`` (real) and
         ``gen.prefill.pad_rows``."""
@@ -822,7 +826,8 @@ class GenPredictor:
                 with _span("gen.prefill", tokens=n, start=start, rows=rows,
                            pages=pages,
                            **self._chunk_pairs(start, n, rows, pages),
-                           **self._chunk_selections(start, n)):
+                           **self._chunk_selections(start, n),
+                           **self._chunk_wrapped(n)):
                     logits, = self._exe.run(prog, feed=feed,
                                             fetch_list=fetch,
                                             return_numpy=False)
@@ -847,6 +852,14 @@ class GenPredictor:
         {} for a bundle without a ``cross_decoder``."""
         rows = self._admitted.get(slot)
         return {"rows": rows[0], "cross_rows": rows[1]} if rows else {}
+
+    def _chunk_wrapped(self, n):
+        """A chunk of ``n`` real rows under hyper-connections:
+        ``mhc_rows`` = rows x the wrappers each passes (two a block),
+        what the wrappers' least bytes are counted from.  Span
+        attributes; {} without."""
+        hc = self.hyper_connections
+        return {"mhc_rows": n * int(hc["wrappers"])} if hc else {}
 
     def _chunk_selections(self, start, n):
         """A chunk of ``n`` real rows at positions ``start ..`` under

@@ -7,13 +7,14 @@ from paddle_tpu.models import (resnet, transformer, vgg, mnist,
                                seq2seq, stacked_lstm, decoder, gen_lm,
                                gen_lm_long, wide_and_deep, hybrid_moe,
                                latent_moe, latent_moe_sparse,
-                               latent_moe_window, block_moe, window_moe,
-                               hybrid_decoder)
+                               latent_moe_window, latent_moe_streams,
+                               block_moe, window_moe, hybrid_decoder)
 
 __all__ = ["resnet", "transformer", "vgg", "mnist",
            "seq2seq", "stacked_lstm", "decoder", "gen_lm", "gen_lm_long",
            "wide_and_deep", "hybrid_moe", "latent_moe",
-           "latent_moe_sparse", "latent_moe_window", "block_moe",
+           "latent_moe_sparse", "latent_moe_window", "latent_moe_streams",
+           "block_moe",
            "window_moe", "hybrid_decoder", "ZOO_MODELS",
            "build_train_program", "synth_feed", "compile_zoo_step"]
 
@@ -23,8 +24,8 @@ __all__ = ["resnet", "transformer", "vgg", "mnist",
 ZOO_MODELS = ("mnist", "resnet", "vgg", "transformer", "seq2seq",
               "stacked_lstm", "gen_lm", "gen_lm_long", "wide_and_deep",
               "hybrid_moe", "latent_moe", "latent_moe_sparse",
-              "latent_moe_window", "block_moe", "window_moe",
-              "hybrid_decoder")
+              "latent_moe_window", "latent_moe_streams", "block_moe",
+              "window_moe", "hybrid_decoder")
 
 
 #: the serving decoders' entries: name -> (configuration class, its
@@ -45,6 +46,10 @@ _DECODERS = {
     "latent_moe_window": (
         latent_moe_window.WindowLatentConfig,
         latent_moe_window.latent_moe_window_train_program),
+    # four residual streams, a wrapper a sublayer (hyper-connections)
+    "latent_moe_streams": (
+        latent_moe_streams.StreamsLatentConfig,
+        latent_moe_streams.latent_moe_streams_train_program),
     # two layers under the block-causal mask
     "block_moe": (block_moe.BlockMoEConfig,
                   block_moe.block_moe_train_program),

@@ -13,8 +13,12 @@ meta.  From here it takes
 * the blocks every pre-norm decoder has: :func:`rms`, :func:`head_norm`,
   :func:`embed`, :func:`live_rows`,
   :func:`logits`, :func:`gated_ffn`, :func:`routed_experts`, the
-  two-sublayer :func:`decoder_layer`; and the multi-token-prediction
-  module any of them can append (:func:`mtp_module`, :func:`mtp_logits`);
+  two-sublayer :func:`decoder_layer` (over ONE residual, or over the
+  ``hc_mult`` streams of hyper-connections: :func:`hc_copy_in`,
+  :func:`hc_sublayer`, :func:`hc_sum_out`); the multi-token-prediction
+  module any of them can append (:func:`mtp_module`, :func:`mtp_logits`)
+  and the self-drafting turn built on it (:func:`draft_turn`,
+  :func:`chunk_draft`);
 * the head and the tail of its three programs: :func:`prefill_inputs` /
   :func:`last_row`, :func:`decode_inputs` / :func:`decode_fetches`,
   :func:`train_inputs` / :func:`train_loss`;
@@ -50,7 +54,8 @@ from paddle_tpu.param_attr import ParamAttr
 
 __all__ = ["META_FILENAME", "PAGE_LEN_DEFAULT", "DECODE_STATS",
            "CHUNK_ROWS", "ROLES", "GROUPS", "program_role", "group",
-           "mtp_scope",
+           "mtp_scope", "mhc_scope", "hc_copy_in", "hc_sublayer",
+           "hc_sum_out", "draft_turn", "chunk_draft", "speculative_meta",
            "DecoderConfig", "default_page_buckets",
            "chunk_rows", "op", "param", "matrix",
            "vector", "data", "persistable", "rms", "head_norm", "embed",
@@ -125,6 +130,14 @@ def mtp_scope():
     else:
         with name_scope("mtp"):
             yield
+
+
+def mhc_scope():
+    """The name scope ``mhc`` of a hyper-connection wrapper's own ops,
+    INSIDE the group of the sublayer it wraps (``<role>/<group>/mhc``,
+    ``<role>/mtp/<group>/mhc``): what the wrapper costs has a name of its
+    own on the device trace and still counts with its sublayer."""
+    return name_scope("mhc")
 
 
 class DecoderConfig:
@@ -376,7 +389,7 @@ def routed_experts(h, hp, prefix, lens, *, experts, held, expert_offset,
         return out, routed["Stats"]
 
 
-def decoder_layer(x, hp, prefix, attention, ffn, *, routed):
+def decoder_layer(x, hp, prefix, attention, ffn, *, routed, hc_mult=1):
     """One pre-norm layer of two sublayers, ``x <- x +
     attention(RMSNorm(x))`` then ``x <- x + ffn(RMSNorm(x))``, the norms'
     scales ``{prefix}_norm1.scale`` / ``_norm2.scale``.  ``attention(h)
@@ -388,13 +401,79 @@ def decoder_layer(x, hp, prefix, attention, ffn, *, routed):
     first is ``attn``; the second ``experts`` where the layer is
     ``routed``, else ``dense``.  A routed layer's second norm feeds its
     shared expert as well as its router and lies under ``experts``; the
-    shared expert itself (:func:`gated_ffn`) is ``dense``."""
+    shared expert itself (:func:`gated_ffn`) is ``dense``.
+
+    ``hc_mult`` = n > 1: ``x`` is the n residual STREAMS ``[..., n, d]``
+    (:func:`hc_copy_in`) and each sublayer, its pre-norm included, is
+    wrapped by :func:`hc_sublayer` (parameters ``{prefix}_hc1.*`` /
+    ``_hc2.*``) where the one residual has its add."""
+    if int(hc_mult) > 1:
+        with group("attn"):
+            x, kept = hc_sublayer(x, hp, f"{prefix}_hc1", lambda u: attention(
+                rms(u, f"{prefix}_norm1.scale", hp)))
+        with group("experts" if routed else "dense"):
+            x, stats = hc_sublayer(x, hp, f"{prefix}_hc2", lambda u: ffn(
+                rms(u, f"{prefix}_norm2.scale", hp)))
+        return x, kept, stats
     with group("attn"):
         out, kept = attention(rms(x, f"{prefix}_norm1.scale", hp))
         x = x + out
     with group("experts" if routed else "dense"):
         out, stats = ffn(rms(x, f"{prefix}_norm2.scale", hp))
         return x + out, kept, stats
+
+
+# ---------------------------------------------------------------------------
+# hyper-connections: n residual streams (ops/mhc_ops.py)
+# ---------------------------------------------------------------------------
+
+def hc_copy_in(x, n):
+    """The model's input ``x`` [..., d] copied into ``n`` residual streams
+    ``[..., n, d]`` (arXiv:2409.19606 section 3); ``embed/mhc``."""
+    rank = len(x.shape)
+    with group("embed"), mhc_scope():
+        return layers.expand(layers.unsqueeze(x, [rank - 1]),
+                             [1] * (rank - 1) + [int(n), 1])
+
+
+def hc_sum_out(x):
+    """The streams ``[..., n, d]`` summed into the ONE residual ``[...,
+    d]`` that a final norm (or an MTP module) takes; ``head/mhc``."""
+    with group("head"), mhc_scope():
+        return layers.reduce_sum(x, dim=len(x.shape) - 2)
+
+
+def hc_sublayer(x, hp, name, sublayer):
+    """ONE sublayer under manifold-constrained hyper-connections
+    (``ops/mhc_ops.py``): ``u = H_pre x``, ``(y, kept) = sublayer(u)``,
+    ``x <- H_res x + H_post^T y`` over the streams ``x`` [..., n, d];
+    the three mappings are made from the streams themselves (``mhc_pre``;
+    ``H_res`` balanced by ``hp.hc_sinkhorn_iters`` Sinkhorn rounds).
+    Parameters, float32: ``{name}.phi`` [n d, n (n + 2)], ``{name}.alpha``
+    [3], ``{name}.bias`` [n (n + 2)].  The wrapper's own ops lie under
+    :func:`mhc_scope` inside the caller's group; the sublayer's are the
+    caller's.  Returns ``(x, kept)``."""
+    n, d = int(x.shape[-2]), int(x.shape[-1])
+    limit = (6.0 / (n * d + n * (n + 2))) ** 0.5
+    with mhc_scope():
+        pre = op("mhc_pre",
+                 {"X": x,
+                  "Phi": param(f"{name}.phi", [n * d, n * (n + 2)],
+                               "float32", init_mod.Uniform(-limit, limit)),
+                  "Alpha": param(f"{name}.alpha", [3], "float32",
+                                 init_mod.Constant(0.01)),
+                  "Bias": vector(f"{name}.bias", n * (n + 2), 0.0)},
+                 {"U": hp.dtype, "Post": "float32", "Res": "float32"},
+                 {"sinkhorn_iters": int(hp.hc_sinkhorn_iters),
+                  "eps": float(hp.hc_eps),
+                  "clamp_min": float(hp.mhc_h_res_clamp_min),
+                  "clamp_max": float(hp.mhc_h_res_clamp_max),
+                  "rms_eps": float(hp.eps)})
+    y, kept = sublayer(pre["U"])
+    with mhc_scope():
+        return op("mhc_post", {"X": x, "Y": y, "Post": pre["Post"],
+                               "Res": pre["Res"]},
+                  {"Out": hp.dtype})["Out"], kept
 
 
 def shared(name):
@@ -447,6 +526,91 @@ def mtp_logits(g2, hp, prefix):
         h = rms(g2, f"{prefix}_mtp_norm.scale", hp)
         return op("matmul", {"X": h, "Y": shared(f"{prefix}_head.w")},
                   {"Out": "float32"}, {"out_dtype": "float32"})["Out"]
+
+
+def draft_turn(hp, prefix, num_slots, draft_var, token, pos, lens,
+               forward, mtp_block):
+    """The decode TURN of a bundle whose MTP module drafts, two rows a
+    slot (``ops/spec_ops.py``), behind the caller's :func:`decode_inputs`
+    and caches: the committed token ``token`` at ``pos`` and, behind it,
+    the slot's draft (the per-slot state ``draft_var`` [S, 1] int32,
+    declared here).  One more feed, ``gen_spec`` [S, 1] int32: 0 turns a
+    slot's draft row off (the row is dead: a blocking step that commits
+    one token and returns the logits behind it).
+
+    ``forward(rows) -> (x [S, 2, d], stats)``: the caller's embedding and
+    main layers over ``spec_rows``' outputs (``Ids``, ``RowPos`` [S, 2];
+    ``End`` [S, 1], ``RowLens`` [S * 2, 1]: a row sees the rows at or
+    before its own), ``x`` the ONE residual its final norm takes.  The
+    verify keeps the draft where it is the first row's own greedy pick;
+    ``mtp_block(h, row_pos, end, row_lens) -> (g, stats)`` is the
+    module's block over the kept rows (it fills its cache), and its last live row's pick
+    is the slot's next draft.  Returns the fetches ``[logits [S, V] of
+    the committed token's row, stats [n_moe + 1, 3], yield [S, 3]
+    int32]``: a slot's (first token, second token or -1, how many: 0 for
+    a free slot)."""
+    S, d = int(num_slots), int(hp.hidden_size)
+    draft = persistable(draft_var, [S, 1], "int32")
+    with group("embed"):
+        rows = op("spec_rows",
+                  {"Token": token, "Draft": draft, "Pos": pos, "Lens": lens,
+                   "On": data("gen_spec", [S, 1], "int32")},
+                  {"Ids": "int32", "RowPos": "int32", "End": "int32",
+                   "RowLens": "int32"}, {"max_len": int(hp.max_len)})
+    x, stats = forward(rows)
+    with group("head"):
+        verdict = op("spec_verify",
+                     {"Logits": logits(layers.reshape(x, shape=[S * 2, d]),
+                                       hp, prefix),
+                      "Ids": rows["Ids"], "RowLens": rows["RowLens"]},
+                     {"Out": "int32", "NextIds": "int32", "MtpEnd": "int32",
+                      "MtpRowLens": "int32", "First": "float32"})
+    g, st = mtp_module(x, verdict["NextIds"], hp, prefix,
+                       lambda h: mtp_block(h, rows["RowPos"],
+                                           verdict["MtpEnd"],
+                                           verdict["MtpRowLens"]))
+    stats.append(st)
+    with mtp_scope(), group("head"):
+        last = op("spec_pick_row", {"X": g, "Verdict": verdict["Out"]},
+                  {"Out": hp.dtype})["Out"]
+        op("spec_draft", {"Logits": mtp_logits(last, hp, prefix),
+                          "Lens": lens, "Draft": draft},
+           {"DraftOut": draft})
+    with group("head"):
+        fetched_stats = layers.concat(stats, axis=0)
+    return [verdict["First"], fetched_stats, verdict["Out"]]
+
+
+def chunk_draft(x, first, last, slot, hp, prefix, num_slots, draft_var,
+                block):
+    """The MTP module's pass of a chunk program whose bundle drafts,
+    behind the main layers: one more feed, ``gen_next_ids`` [1, C] int32,
+    the token that FOLLOWS each row (the prompt shifted by one; -1 behind
+    the prompt's last row, which takes the main model's own pick from
+    ``first``, its logits there).  The module runs over the chunk's rows
+    ``x`` [1, C, d] (``block``: its block over the slot's caches), so
+    that its cache holds the prompt's rows too, and where the chunk
+    holds the prompt's last row (``last``) its pick there becomes the
+    slot's first draft (``draft_var``, declared here)."""
+    draft = persistable(draft_var, [int(num_slots), 1], "int32")
+    with group("head"):
+        follows = op("spec_next_ids",
+                     {"NextIds": data("gen_next_ids", [1, -1], "int32"),
+                      "Logits": first}, {"Out": "int32"})["Out"]
+    g, _ = mtp_module(x, follows, hp, prefix, block)
+    with mtp_scope(), group("head"):
+        op("spec_seed_draft",
+           {"Logits": mtp_logits(last_row(g, last, hp), hp, prefix),
+            "Last": last, "Slot": slot, "Draft": draft},
+           {"DraftOut": draft})
+
+
+def speculative_meta(draft_var):
+    """``gen_meta.json``'s ``speculative`` of a bundle built on
+    :func:`draft_turn`: a turn carries ``rows`` rows a slot and yields 1
+    .. ``rows`` tokens; the draft lives in ``draft_var`` (a state
+    array); ``feed`` turns a slot's draft row off."""
+    return {"rows": 2, "draft_var": draft_var, "feed": "gen_spec"}
 
 
 # ---------------------------------------------------------------------------

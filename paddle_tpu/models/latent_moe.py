@@ -71,9 +71,33 @@ precedes the untied head.
   expert-parallel deployment; ``moe_experts_gated``, a routed product),
   plus one shared expert on every token.
 
+* **Hyper-connections** (a configuration with ``hc_mult`` = n > 1;
+  ``decoder.hc_sublayer``, ``ops/mhc_ops.py``).  The residual is n
+  streams ``[..., n, d]``: the embedding's row is copied into them, every
+  sublayer (the MTP block's two among them) is wrapped (``u = H_pre x``,
+  ``x <- H_res x + H_post^T F(u)``, ``H_res`` balanced by
+  ``hc_sinkhorn_iters`` Sinkhorn rounds) and the streams are summed
+  before the final norm.  ``gen_meta.json`` then says
+  ``hyper_connections: {streams, sinkhorn_iters}``.
+* **Self-speculative decoding** (``num_nextn_predict_layers`` 1): the
+  multi-token-prediction module (``decoder.mtp_module``: one more MoE
+  block of latent attention, with a latent pool of its own,
+  ``lat_mtp_paged_c``) is loaded and DRAFTS, as ``models/window_moe.py``
+  has it (``decoder.draft_turn`` / ``chunk_draft``): a decode turn
+  forwards two rows a slot, the committed token and the draft kept in
+  the per-slot state ``lat_draft``, through the absorbed latent kernel
+  under a limit a row, verifies, yields one or two tokens and drafts
+  again; the chunk program runs the module over the prompt's rows and
+  seeds the first draft (feeds ``gen_slot`` and ``gen_next_ids`` more).
+  The module takes the SUMMED residual; its block copies its input into
+  the streams and sums them out as the main model does.  Beside window
+  layers or an indexer the module is not loaded (a ring of latent rows
+  under two rows, a selection a row: not built).
+
 Matrices and activations are ``dtype`` (bfloat16) with float32
 accumulation; router scores, norm statistics, rotary angles, softmax and
-logits are float32; the latent pool is ``dtype``.
+logits are float32; the latent pool is ``dtype``; a wrapper's
+coefficients are float32, the streams ``dtype``.
 
 ``export_latent_model`` writes ``prefill/`` (the chunk program: a prompt
 runs as chunks of ``prefill_chunks`` rows, ``gen_meta.json``), ``decode/``
@@ -89,20 +113,27 @@ from __future__ import annotations
 
 import paddle_tpu.layers as layers
 from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
-                                       DecoderConfig, chunk_rows, data,
-                                       decode_fetches, decode_inputs,
-                                       decoder_layer, embed,
-                                       export_bundle, gated_ffn,
-                                       last_row, live_rows, logits, matrix, op,
-                                       persistable, prefill_inputs,
+                                       DecoderConfig, chunk_draft,
+                                       chunk_rows, data, decode_fetches,
+                                       decode_inputs, decoder_layer,
+                                       draft_turn, embed, export_bundle,
+                                       gated_ffn, hc_copy_in, hc_sum_out,
+                                       last_row, live_rows, logits, matrix,
+                                       op, persistable, prefill_inputs,
                                        program_role, rms, routed_experts,
-                                       train_inputs, train_loss, vector)
+                                       speculative_meta, train_inputs,
+                                       train_loss, vector)
 from paddle_tpu.ops.mla_ops import yarn_mscale
 
 __all__ = ["LatentMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "latent_moe_train_program",
            "export_latent_model", "paged_cache_var_names",
-           "ring_var_names"]
+           "ring_var_names", "MTP", "DRAFT_VAR"]
+
+#: the layer key of the MTP module's block (its parameters are
+#: ``lat_mtp_*``), and the per-slot state that holds a slot's draft
+MTP = "_mtp"
+DRAFT_VAR = "lat_draft"
 
 
 class LatentMoEConfig(DecoderConfig):
@@ -158,6 +189,15 @@ class LatentMoEConfig(DecoderConfig):
     norm_topk_prob = True
     experts_held = None              # None: all of them
     expert_offset = 0
+    # hyper-connections: residual streams (1: the one residual), and the
+    # Sinkhorn rounds, their epsilon and the clamp of a wrapper's H_res
+    hc_mult = 1
+    hc_sinkhorn_iters = 20
+    hc_eps = 1e-6
+    mhc_h_res_clamp_min = -30.0
+    mhc_h_res_clamp_max = 30.0
+    # the multi-token-prediction module: 0 = not loaded, 1 = it drafts
+    num_nextn_predict_layers = 0
     dtype = "bfloat16"
     max_len = 64
     eos_id = -1
@@ -169,7 +209,26 @@ class LatentMoEConfig(DecoderConfig):
         hp = super().from_dict(cfg)
         if hp.rope_parameters and "rope_theta" in hp.rope_parameters:
             hp.rope_theta = hp.rope_parameters["rope_theta"]
+        if hp.drafts and (hp.layer_types or hp.index_topk):
+            # beside window layers or an indexer the module is NOT loaded
+            # (a ring of latent rows, and a selection, under two rows a
+            # slot are not built): the key is dropped, as it was before
+            # this builder could draft at all
+            hp.num_nextn_predict_layers = 0
+        if int(hp.num_nextn_predict_layers or 0) > 1:
+            raise NotImplementedError("drafts deeper than one row")
         return hp
+
+    @property
+    def drafts(self):
+        """The MTP module is loaded and a decode turn carries its draft."""
+        return int(self.num_nextn_predict_layers or 0) > 0
+
+    @property
+    def blocks(self):
+        """Every block that caches: the layers, then the MTP module's."""
+        return list(range(int(self.num_hidden_layers))) \
+            + ([MTP] if self.drafts else [])
 
     @property
     def latent_row(self):
@@ -180,8 +239,9 @@ class LatentMoEConfig(DecoderConfig):
     def is_window(self, i):
         """Layer ``i`` is a ``sliding_attention`` one (None: a full
         layer's sizes are asked for)."""
-        return i is not None and bool(self.layer_types) and self.layer_types[
-            int(self.layer_offset) + i] == "sliding_attention"
+        return i is not None and i != MTP and bool(self.layer_types) \
+            and self.layer_types[int(self.layer_offset) + i] \
+            == "sliding_attention"
 
     def attention(self, i):
         """Layer ``i``'s attention sizes by its kind: ``H``, ``q_rank``,
@@ -239,6 +299,8 @@ class LatentMoEConfig(DecoderConfig):
         return (a["nope"] + a["R"]) ** -0.5 * m * m
 
     def is_moe(self, i):
+        if i == MTP:     # the MTP block's feed-forward is the sparse one
+            return True
         if self.mlp_layer_types:
             return self.mlp_layer_types[int(self.layer_offset) + i] \
                 == "sparse"
@@ -249,7 +311,7 @@ class LatentMoEConfig(DecoderConfig):
         uses the selection of the nearest full layer before it) or None
         (no sparse attention; also a shared layer with no full layer
         before it in the layers held)."""
-        if not self.index_topk or self.is_window(i):
+        if not self.index_topk or self.is_window(i) or i == MTP:
             return None
         kinds = self.indexer_types
         at = int(self.layer_offset)
@@ -271,10 +333,9 @@ class LatentMoEConfig(DecoderConfig):
 
     @property
     def paged_layers(self):
-        """The layers that keep pages: every one that is no window
-        layer."""
-        return [i for i in range(int(self.num_hidden_layers))
-                if not self.is_window(i)]
+        """The blocks that keep pages: every one that is no window
+        layer, the MTP module's among them."""
+        return [i for i in self.blocks if not self.is_window(i)]
 
     @property
     def moe_layers(self):
@@ -344,7 +405,9 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
     expanded, nothing cached (the training forward).  ``mask`` and
     ``paged`` = (pool, page table [1, P]): ONE CHUNK of a prompt over the
     slot's own pages, which it writes and reads as they are cached.
-    ``paged`` = (pool, page table, lens): the absorbed paged decode.
+    ``paged`` = (pool, page table, lens): the absorbed paged decode;
+    with one more entry, ``row_lens`` [S * L, 1], a step of ``L`` rows a
+    slot, each under its own limit (``ops/spec_ops.py``).
     ``select``: the selection a ``shared`` layer attends under; a
     ``full`` layer makes its own (``index_pool``: its key pool in the
     serving forms).  A WINDOW layer (``hp.is_window``) keeps a ring a
@@ -418,11 +481,11 @@ def _attention(h, hp, i, pos, mask=None, paged=None, select=None,
                   **sparse},
                  {"Out": hp.dtype, "CacheOut": pool}, whole)["Out"]
     else:
-        pool, page_table, lens = paged
+        pool, page_table, lens, *row_lens = paged
         ctx = op("paged_attention_latent",
                  {"Q": absorb(q, "q", pad=a["row"] - L - R), "Row": row,
                   "Cache": pool, "PageTable": page_table, "Lens": lens,
-                  **sparse},
+                  "RowLens": row_lens[0] if row_lens else None, **sparse},
                  {"Out": hp.dtype, "CacheOut": pool},
                  {"n_head": H, "v_width": L, "scale": scale,
                   **sparse_attrs})["Out"]
@@ -465,8 +528,32 @@ def _layer(x, hp, i, pos, lens, mask=None, paged=None, select=None,
         x, hp, f"lat{i}",
         lambda h: _attention(h, hp, i, pos, mask=mask, paged=paged,
                              select=select, index_pool=index_pool),
-        lambda h: _ffn(h, hp, i, lens), routed=hp.is_moe(i))
+        lambda h: _ffn(h, hp, i, lens), routed=hp.is_moe(i),
+        hc_mult=hp.hc_mult)
     return x, stats, select
+
+
+def _streams(x, hp):
+    """The embedding's rows ``x`` [..., d] as the layers take them: the
+    ``hc_mult`` residual streams, or ``x`` itself without
+    hyper-connections."""
+    return hc_copy_in(x, hp.hc_mult) if int(hp.hc_mult) > 1 else x
+
+
+def _residual(x, hp):
+    """The ONE residual the final norm (and the MTP module) takes: the
+    streams summed, or ``x`` itself."""
+    return hc_sum_out(x) if int(hp.hc_mult) > 1 else x
+
+
+def _mtp_block(h, hp, *args, **kwargs):
+    """The MTP module's block over ITS input ``h`` [..., d] (one
+    residual in, one out: ``decoder.mtp_module``'s contract): the block
+    is a layer of the model's own kind, so under hyper-connections its
+    input is copied into the streams and its output summed.  Returns
+    ``(g, stats)``."""
+    g, stats, _ = _layer(_streams(h, hp), hp, MTP, *args, **kwargs)
+    return _residual(g, hp), stats
 
 
 def _pools(hp, num_slots, page_len, num_pages):
@@ -508,26 +595,38 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
     prompt's last row where this chunk holds it, else zeros) and
     ``gen_page_table`` [1, P] int32 (the slot's row, P bucketed by the
     predictor and covering the chunk's last real row); ``gen_slot`` [1,
-    1] int32 with window layers alone: without them nothing here is kept
-    a slot.  Persistable state, read and updated in place, as the decode
+    1] int32 with window layers or a drafting MTP module alone: without
+    them nothing here is kept a slot; ``gen_next_ids`` [1, C] int32
+    where the module drafts (``decoder.chunk_draft``: the module runs
+    over the chunk's rows behind the main layers and seeds the slot's
+    first draft).  Persistable state, read and updated in place, as the decode
     step's: the latent pools, the index-key pools and the window layers'
     rings.  Fetches ``[logits [1, V]]`` (of the row ``gen_last``
     names)."""
     ids, pos, mask, last = prefill_inputs()
-    slot = data("gen_slot", [1, 1], "int32") if hp.window_layers else None
+    per_slot = bool(hp.window_layers) or hp.drafts
+    slot = data("gen_slot", [1, 1], "int32") if per_slot else None
     page_table = data("gen_page_table", [1, -1], "int32")
     pools = _pools(hp, num_slots, page_len, num_pages)
     lens = live_rows(mask)
-    x = embed(ids, hp, "lat")
+    x = _streams(embed(ids, hp, "lat"), hp)
     select = None
     for i in range(int(hp.num_hidden_layers)):
         x, _, select = _layer(
             x, hp, i, pos, lens, mask=mask,
             paged=_cache(pools, hp, i, slot, page_table), select=select,
             index_pool=pools.get(f"lat{i}_paged_ik"))
-    return (["gen_ids", "gen_pos", "gen_mask", "gen_last"]
-            + ["gen_slot"] * bool(hp.window_layers) + ["gen_page_table"],
-            [logits(last_row(x, last, hp), hp, "lat")])
+    x = _residual(x, hp)
+    feeds = ["gen_ids", "gen_pos", "gen_mask", "gen_last"] \
+        + ["gen_slot"] * per_slot + ["gen_page_table"]
+    first = logits(last_row(x, last, hp), hp, "lat")
+    if hp.drafts:
+        chunk_draft(x, first, last, slot, hp, "lat", num_slots, DRAFT_VAR,
+                    lambda h: _mtp_block(
+                        h, hp, pos, lens, mask=mask,
+                        paged=_cache(pools, hp, MTP, slot, page_table)))
+        feeds.append("gen_next_ids")
+    return feeds, [first]
 
 
 def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
@@ -540,10 +639,11 @@ def latent_moe_train_program(seq_len, hp: LatentMoEConfig = None):
     ids, labels, rows = train_inputs(seq_len, "pos", "mask", "lens")
     x = embed(ids, hp, "lat")
     select = None
+    x = _streams(x, hp)
     for i in range(int(hp.num_hidden_layers)):
         x, _, select = _layer(x, hp, i, rows["pos"], rows["lens"],
                               mask=rows["mask"], select=select)
-    return train_loss(x, labels, hp, "lat")
+    return train_loss(_residual(x, hp), labels, hp, "lat")
 
 
 @program_role("gen_decode")
@@ -558,11 +658,38 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
     latent_row]`` in ``hp.dtype``, and one index-key pool a layer that
     holds an indexer, ``[num_pages, page_len, index_head_dim]``; a window
     layer's ring ``[S, ring, its latent row]`` in their place.  Fetches
-    ``[logits [S, V], stats [n_moe, 3]]``."""
+    ``[logits [S, V], stats [n_moe, 3]]``.
+
+    Where the MTP module drafts (``hp.drafts``) the step is a TURN of
+    two rows a slot, ``decoder.draft_turn``'s: one more feed
+    (``gen_spec``), the per-slot state ``lat_draft``, the latent kernel
+    under a limit a row, and the fetches ``[logits of the committed
+    token's row, stats [n_moe + 1, 3], yield [S, 3]]``."""
     S = int(num_slots)
     token, pos, page_table, lens = decode_inputs(S)
     pools = _pools(hp, S, page_len, num_pages)
-    x = embed(token, hp, "lat", lead=[S, 1])
+    if hp.drafts:
+        def cached(i, end, row_lens):
+            return _cache(pools, hp, i, end, page_table, end, row_lens)
+
+        def forward(rows):
+            x = _streams(embed(rows["Ids"], hp, "lat", lead=[S, 2]), hp)
+            stats = []
+            for i in range(int(hp.num_hidden_layers)):
+                x, st, _ = _layer(x, hp, i, rows["RowPos"], rows["RowLens"],
+                                  paged=cached(i, rows["End"],
+                                               rows["RowLens"]))
+                if st is not None:
+                    stats.append(st)
+            return _residual(x, hp), stats
+
+        return (["gen_token", "gen_pos", "gen_page_table", "gen_lens",
+                 "gen_spec"],
+                draft_turn(hp, "lat", S, DRAFT_VAR, token, pos, lens,
+                           forward, lambda h, row_pos, end, row_lens:
+                           _mtp_block(h, hp, row_pos, row_lens,
+                                      paged=cached(MTP, end, row_lens))))
+    x = _streams(embed(token, hp, "lat", lead=[S, 1]), hp)
     stats, select = [], None
     for i in range(int(hp.num_hidden_layers)):
         x, st, select = _layer(
@@ -572,7 +699,7 @@ def build_paged_decode_program(hp, num_slots, page_len, num_pages):
         if st is not None:
             stats.append(st)
     return (["gen_token", "gen_pos", "gen_page_table", "gen_lens"],
-            decode_fetches(x, stats, S, hp, "lat"))
+            decode_fetches(_residual(x, hp), stats, S, hp, "lat"))
 
 
 def _window_section(hp):
@@ -617,6 +744,15 @@ def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
                                        "indexers": len(hp.full_layers)}
         if hp.window_layers:
             own["window_attention"] = _window_section(hp)
+        if int(hp.hc_mult) > 1:
+            # ``wrappers``: what a row passes (two a block): the
+            # predictor counts a chunk's ``mhc_rows`` from it
+            own["hyper_connections"] = {
+                "streams": int(hp.hc_mult),
+                "sinkhorn_iters": int(hp.hc_sinkhorn_iters),
+                "wrappers": 2 * len(hp.blocks)}
+        if hp.drafts:
+            own["speculative"] = speculative_meta(DRAFT_VAR)
         return own
 
     return export_bundle(
@@ -626,4 +762,5 @@ def export_latent_model(dirname, hp: LatentMoEConfig = None, num_slots=8,
         paged_cache_var_names(hp), hp.num_hidden_layers,
         num_slots=num_slots, prompt_buckets=prompt_buckets,
         page_len=page_len, num_pages=num_pages, page_buckets=page_buckets,
-        state_vars=ring_var_names(hp), sections=sections)
+        state_vars=ring_var_names(hp) + [DRAFT_VAR] * hp.drafts,
+        sections=sections)

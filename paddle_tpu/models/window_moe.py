@@ -76,16 +76,16 @@ from __future__ import annotations
 
 import paddle_tpu.layers as layers
 from paddle_tpu.models.decoder import (DECODE_STATS, PAGE_LEN_DEFAULT,
-                                       DecoderConfig, chunk_rows, data,
-                                       decode_fetches,
-                                       decode_inputs, decoder_layer, embed,
-                                       export_bundle, gated_ffn, group,
-                                       head_norm, last_row, live_rows, logits,
-                                       matrix,
-                                       mtp_logits, mtp_module, mtp_scope, op,
+                                       DecoderConfig, chunk_draft,
+                                       chunk_rows, data, decode_fetches,
+                                       decode_inputs, decoder_layer,
+                                       draft_turn, embed, export_bundle,
+                                       gated_ffn, head_norm, last_row,
+                                       live_rows, logits, matrix, op,
                                        persistable, prefill_inputs,
                                        program_role, routed_experts,
-                                       train_inputs, train_loss, vector)
+                                       speculative_meta, train_inputs,
+                                       train_loss, vector)
 
 __all__ = ["WindowMoEConfig", "build_chunk_program",
            "build_paged_decode_program", "window_moe_train_program",
@@ -420,17 +420,8 @@ def build_chunk_program(hp, num_slots, page_len, num_pages):
              "gen_page_table"]
     first = logits(last_row(x, last, hp), hp, "win")
     if hp.drafts:
-        draft = persistable(DRAFT_VAR, [int(num_slots), 1], "int32")
-        with group("head"):
-            follows = op("spec_next_ids",
-                         {"NextIds": data("gen_next_ids", [1, -1], "int32"),
-                          "Logits": first}, {"Out": "int32"})["Out"]
-        g, _ = mtp_module(x, follows, hp, "win", lambda h: block(h, MTP))
-        with mtp_scope(), group("head"):
-            op("spec_seed_draft",
-               {"Logits": mtp_logits(last_row(g, last, hp), hp, "win"),
-                "Last": last, "Slot": slot, "Draft": draft},
-               {"DraftOut": draft})
+        chunk_draft(x, first, last, slot, hp, "win", num_slots, DRAFT_VAR,
+                    lambda h: block(h, MTP))
         feeds.append("gen_next_ids")
     return feeds, [first]
 
@@ -501,50 +492,29 @@ def _build_draft_step(hp, num_slots, page_len, num_pages):
     ``[logits [S, V] of the committed token's row, stats [n_moe + 1, 3],
     yield [S, 3] int32]``: a slot's (first token, second token or -1,
     how many: 0 for a free slot)."""
-    S, d = int(num_slots), int(hp.hidden_size)
+    S = int(num_slots)
     token, pos, page_table, lens = decode_inputs(S)
     cache = _caches(hp, S, page_len, num_pages)
-    draft = persistable(DRAFT_VAR, [S, 1], "int32")
-    with group("embed"):
-        rows = op("spec_rows",
-                  {"Token": token, "Draft": draft, "Pos": pos, "Lens": lens,
-                   "On": data("gen_spec", [S, 1], "int32")},
-                  {"Ids": "int32", "RowPos": "int32", "End": "int32",
-                   "RowLens": "int32"}, {"max_len": int(hp.max_len)})
 
-    def block(x, i, end, row_lens):
+    def block(x, i, row_pos, end, row_lens):
         held = (cache[i, "k"], cache[i, "v"])
-        return _layer(x, hp, i, rows["RowPos"], row_lens,
+        return _layer(x, hp, i, row_pos, row_lens,
                       cache=held + ((end, row_lens) if hp.is_window(i)
                                     else (page_table, end, row_lens)))
 
-    x = embed(rows["Ids"], hp, "win", lead=[S, 2])
-    stats = []
-    for i in range(int(hp.num_hidden_layers)):
-        x, st = block(x, i, rows["End"], rows["RowLens"])
-        if st is not None:
-            stats.append(st)
-    with group("head"):
-        verdict = op("spec_verify",
-                     {"Logits": logits(layers.reshape(x, shape=[S * 2, d]),
-                                       hp, "win"),
-                      "Ids": rows["Ids"], "RowLens": rows["RowLens"]},
-                     {"Out": "int32", "NextIds": "int32", "MtpEnd": "int32",
-                      "MtpRowLens": "int32", "First": "float32"})
-    g, st = mtp_module(x, verdict["NextIds"], hp, "win",
-                       lambda h: block(h, MTP, verdict["MtpEnd"],
-                                       verdict["MtpRowLens"]))
-    stats.append(st)
-    with mtp_scope(), group("head"):
-        last = op("spec_pick_row", {"X": g, "Verdict": verdict["Out"]},
-                  {"Out": hp.dtype})["Out"]
-        op("spec_draft", {"Logits": mtp_logits(last, hp, "win"),
-                          "Lens": lens, "Draft": draft},
-           {"DraftOut": draft})
-    with group("head"):
-        fetched_stats = layers.concat(stats, axis=0)
+    def forward(rows):
+        x = embed(rows["Ids"], hp, "win", lead=[S, 2])
+        stats = []
+        for i in range(int(hp.num_hidden_layers)):
+            x, st = block(x, i, rows["RowPos"], rows["End"], rows["RowLens"])
+            if st is not None:
+                stats.append(st)
+        return x, stats
+
     return (["gen_token", "gen_pos", "gen_page_table", "gen_lens",
-             "gen_spec"], [verdict["First"], fetched_stats, verdict["Out"]])
+             "gen_spec"],
+            draft_turn(hp, "win", S, DRAFT_VAR, token, pos, lens, forward,
+                       lambda h, *rows: block(h, MTP, *rows)))
 
 
 def _window_section(hp):
@@ -597,8 +567,7 @@ def export_window_model(dirname, hp: WindowMoEConfig = None, num_slots=8,
         if hp.drafts:
             # a turn carries ``rows`` rows a slot and yields 1 .. rows
             # tokens; the draft lives in ``draft_var`` (a state array)
-            own["speculative"] = {"rows": 2, "draft_var": DRAFT_VAR,
-                                  "feed": "gen_spec"}
+            own["speculative"] = speculative_meta(DRAFT_VAR)
         return own
 
     return export_bundle(

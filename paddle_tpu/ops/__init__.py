@@ -38,4 +38,5 @@ from paddle_tpu.ops import (  # noqa: F401
     window_ops,
     spec_ops,
     diff_attention_ops,
+    mhc_ops,
 )

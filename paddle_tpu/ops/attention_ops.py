@@ -986,11 +986,12 @@ def _paged_decode_kernel(pt_ref, lens_ref, q_ref, *refs, page_len,
     products, six passes each, would bound the kernel by the MXU at a
     third of the chip's bandwidth).
 
-    ``row_limits`` (grouped heads only): a ``[G, 1]`` int32 column
-    comes first among ``refs``, the rows each of a K/V head's ``G``
-    query rows sees, the same for every K/V head: a row is masked past
-    its own limit, the walk still follows ``lens`` (the largest), and
-    a query row whose limit is 0 leaves zeros.
+    ``row_limits`` (grouped heads, or the latent form, whose one row is
+    the K/V head of all ``G`` = ``H`` query rows): a ``[G, 1]`` int32
+    column comes first among ``refs``, the rows each of a K/V head's
+    ``G`` query rows sees, the same for every K/V head: a row is masked
+    past its own limit, the walk still follows ``lens`` (the largest),
+    and a query row whose limit is 0 leaves zeros.
 
     ``select`` (the latent form only): a ``[1, 1, rows]`` int32 mask of
     the slot's rows comes next among ``refs`` (``dsa_select``'s; padded
@@ -1491,26 +1492,49 @@ def paged_attention_lower(ctx: LowerContext):
 # ---------------------------------------------------------------------------
 
 def _xla_latent_attention(q, cache, page_table, lens, n_head, v_width,
-                          scale, select=None):
+                          scale, select=None, row_lens=None):
     """Gather-based fallback of the latent form, float32.  ``select`` [S,
-    1, P * page_len]: 0 masks the slot's row at that position."""
+    1, P * page_len]: 0 masks the slot's row at that position.  ``q`` [S,
+    L, H * W] with ``row_lens`` [S, L]: each of a slot's ``L`` query rows
+    reads the rows under its own limit (0: zeros)."""
     S, P = page_table.shape
     NP, PL, W = cache.shape
     T = P * PL
     rows = cache[page_table].reshape(S, T, W).astype(jnp.float32)
-    qh = q.reshape(S, n_head, W).astype(jnp.float32)
+    qh = q.reshape(S, -1, W).astype(jnp.float32)       # [S, L * H, W]
     sc = jnp.einsum("shw,stw->sht", qh, rows,
                     preferred_element_type=jnp.float32) * scale
     col = jax.lax.broadcasted_iota(jnp.int32, (S, 1, T), 2)
-    seen = col < lens[:, :, None]
+    # (made where it is used, twice: a call without ``row_lens`` traces
+    # to the equations it traced to before limits existed)
+    limit = lambda: lens[:, :, None] if row_lens is None \
+        else jnp.repeat(row_lens, n_head, axis=1)[:, :, None]
+    seen = col < limit()
     if select is not None:
         seen &= select > 0
     probs = jax.nn.softmax(jnp.where(seen, sc, NEG_INF), axis=-1)
     out = jnp.einsum("sht,stv->shv", probs, rows[..., :v_width],
                      preferred_element_type=jnp.float32)
-    # a free slot reads zeros, as the kernel writes them
-    out = jnp.where(lens[:, :, None] > 0, out, 0.0)
+    # a free slot (a dead row) reads zeros, as the kernel writes them
+    out = jnp.where(limit() > 0, out, 0.0)
     return out.reshape(q.shape[:-1] + (n_head * v_width,)).astype(q.dtype)
+
+
+def _pallas_latent_rows(q, cache, page_table, walk, n_head, scale,
+                        row_lens, interpret, v_width):
+    """The latent kernel under ``L`` rows a slot, ``q`` [S, L, H * W]
+    with ``row_lens`` [S, L]: the kernel is handed the slot's ``L * H``
+    absorbed query rows side by side, all against the one cached row (its
+    products are ``[L * H, W] x [W, rows]`` and ``[L * H, rows] x [rows,
+    v]``: the pool's rows are copied once for all ``L``), and a column of
+    ``L * H`` limits; the walk follows ``walk``, the largest."""
+    S, L, HW = q.shape
+    limits = jnp.repeat(row_lens.astype(jnp.int32), n_head,
+                        axis=1)[:, :, None]
+    out = _pallas_paged_attention(
+        q.reshape(S, 1, L * HW), cache, None, page_table, walk, L * n_head,
+        scale, interpret=interpret, v_width=v_width, row_lens=limits)
+    return None if out is None else out.reshape(S, L, n_head * v_width)
 
 
 def _infer_paged_latent(op, block):
@@ -1526,11 +1550,20 @@ def _infer_paged_latent(op, block):
 @register_op("paged_attention_latent", infer_shape=_infer_paged_latent,
              no_gradient=True, stateful_outputs=("CacheOut",))
 def paged_attention_latent_lower(ctx: LowerContext):
-    """Q: [S, 1, H*W] the absorbed queries (W = the pool's row width);
-    Row: [S, 1, W] this step's latent row; Cache: [num_pages, page_len,
-    W] persistable pool; PageTable, Lens as ``paged_attention``.  Out:
-    [S, 1, H*v_width], the context in the latent; CacheOut names the
-    cache var itself.  attrs: n_head, v_width, scale.
+    """Q: [S, L, H*W] the absorbed queries (W = the pool's row width;
+    ``L`` rows a slot, 1 where a step decodes one token a slot); Row:
+    [S, L, W] this step's latent rows; Cache: [num_pages, page_len, W]
+    persistable pool; PageTable, Lens as ``paged_attention`` (Lens: rows
+    THROUGH the step's last).  Out: [S, L, H*v_width], the context in
+    the latent; CacheOut names the cache var itself.  attrs: n_head,
+    v_width, scale.
+
+    RowLens (optional; ``L`` > 1): [S * L, 1] int32, a limit for each
+    row, as ``paged_attention``'s: row ``j`` reads the rows under ITS
+    limit (a turn's draft row sees the committed row, not the other way
+    round); a row whose limit is 0 is dead: written nowhere, its output
+    zeros.  The slot's pages are walked to the largest limit.  Not with
+    Select.
 
     Select (optional, with attr select_top_k; ``ops/dsa_ops.py``): [S, 1,
     P * page_len] int32, 0 = the slot's row at that position is left out
@@ -1541,8 +1574,15 @@ def paged_attention_latent_lower(ctx: LowerContext):
     pt, lens = ctx.input("PageTable"), ctx.input("Lens")
     n_head, v_width = int(ctx.attr("n_head")), int(ctx.attr("v_width"))
     scale = float(ctx.attr("scale", 1.0))
+    row_lens, walk = None, lens
+    if ctx.has_input("RowLens"):
+        if ctx.has_input("Select"):
+            raise NotImplementedError(
+                "paged_attention_latent: a limit a row under a selection")
+        row_lens = ctx.input("RowLens").reshape(q.shape[:2])
+        walk = jnp.max(row_lens, axis=1, keepdims=True)
     cache, = _paged_cache_update((ctx.input("Cache"),), (ctx.input("Row"),),
-                                 pt, lens)
+                                 pt, lens, row_lens)
     select = None
     if ctx.has_input("Select") and pt.shape[1] * cache.shape[1] \
             > int(ctx.attr("select_top_k", 0)):
@@ -1550,14 +1590,18 @@ def paged_attention_latent_lower(ctx: LowerContext):
     out = None
     interpret = _use_interpret()
     if _paged_kernel_enabled(interpret):
-        out = _pallas_paged_attention(q, cache, None, pt, lens, n_head,
-                                      scale, interpret=interpret,
-                                      v_width=v_width, select=select)
+        if row_lens is not None:
+            out = _pallas_latent_rows(q, cache, pt, walk, n_head, scale,
+                                      row_lens, interpret, v_width)
+        else:
+            out = _pallas_paged_attention(q, cache, None, pt, lens, n_head,
+                                          scale, interpret=interpret,
+                                          v_width=v_width, select=select)
     if out is None:
         from paddle_tpu.profiler import runtime_metrics
         runtime_metrics.inc("gen.paged.fallback")
-        out = _xla_latent_attention(q, cache, pt, lens, n_head, v_width,
-                                    scale, select=select)
+        out = _xla_latent_attention(q, cache, pt, walk, n_head, v_width,
+                                    scale, select=select, row_lens=row_lens)
     ctx.set_output("Out", out)
     ctx.set_output("CacheOut", cache)
 

@@ -1,7 +1,7 @@
 """The serving builders' programs, op for op: every program the six
 decoder builders make (``gen_lm``, ``hybrid_moe``, ``latent_moe``,
-``latent_moe_sparse``, ``latent_moe_window``, ``block_moe``,
-``window_moe``) is serialised and
+``latent_moe_sparse``, ``latent_moe_window``, ``latent_moe_streams``,
+``block_moe``, ``window_moe``) is serialised and
 its sha256 compared with ``tests/golden/gen_bundle_programs.json``.
 
 * ``toy``: prefill (or chunk), decode and train program at the module's
@@ -48,7 +48,8 @@ from paddle_tpu import models                           # noqa: E402
 from paddle_tpu.framework import unique_name_scope      # noqa: E402
 from paddle_tpu.models import (block_moe, gen_lm,       # noqa: E402
                                hybrid_moe, latent_moe, latent_moe_sparse,
-                               latent_moe_window, window_moe)
+                               latent_moe_streams, latent_moe_window,
+                               window_moe)
 
 GOLDEN = os.path.join(ROOT, "tests", "golden", "gen_bundle_programs.json")
 TOY_SLOTS = 3
@@ -69,6 +70,9 @@ KINDS = {
                   "export_block_model", False),
     "window_moe": (window_moe, window_moe.WindowMoEConfig,
                    "export_window_model", True),
+    "latent_moe_streams": (latent_moe,
+                           latent_moe_streams.StreamsLatentConfig,
+                           "export_latent_model", True),
 }
 #: published configuration -> its kind
 PUBLISHED = {"genlm_opt6.7b": "gen_lm",
@@ -77,7 +81,8 @@ PUBLISHED = {"genlm_opt6.7b": "gen_lm",
              "sdar_30b_a3b_chat": "block_moe",
              "glm_5.2": "latent_moe",
              "mimo_v2_flash": "window_moe",
-             "dots3_note_prev": "latent_moe_window"}
+             "dots3_note_prev": "latent_moe_window",
+             "xing4.0_29b_a4b": "latent_moe_streams"}
 
 CASES = [("toy", kind, prog) for kind in KINDS
          for prog in ("prefill", "decode", "train")] \

@@ -5,7 +5,8 @@ serving programs' scopes"), the predictor's own two executables theirs
 every program with its metadata, decided once.
 
 * every op of every builder's prefill / chunk / decode program carries
-  ``op_namescope`` = ``<role>/<group>`` or ``<role>/mtp/<group>``;
+  ``op_namescope`` = ``<role>/<group>`` or ``<role>/mtp/<group>``, a
+  hyper-connection wrapper's own ops ``mhc`` behind that;
 * a toy decode turn and a toy seeding call, lowered on the CPU: an
   instruction under a ``ptop_`` scope has ``gen_decode/<group>/`` before
   it, the turn's own instructions ``gen_turn``, the seed's ``gen_seed``;
@@ -27,7 +28,8 @@ from paddle_tpu.gen import GenPredictor
 from paddle_tpu.gen import predictor as predictor_mod
 from paddle_tpu.models import (block_moe, decoder, gen_lm, hybrid_decoder,
                                hybrid_moe, latent_moe, latent_moe_sparse,
-                               latent_moe_window, window_moe)
+                               latent_moe_streams, latent_moe_window,
+                               window_moe)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FLAG = "jax_compilation_cache_include_metadata_in_key"
@@ -57,6 +59,9 @@ BUILDERS = {
                           ("chunk", "decode")),
     "latent_moe_window": (latent_moe, latent_moe_window.WindowLatentConfig,
                           ("chunk", "decode")),
+    "latent_moe_streams": (latent_moe,
+                           latent_moe_streams.StreamsLatentConfig,
+                           ("chunk", "decode")),
     "block_moe": (block_moe, block_moe.BlockMoEConfig,
                   ("prefill", "decode")),
     "window_moe": (window_moe, window_moe.WindowMoEConfig,
@@ -105,16 +110,24 @@ def test_every_op_names_its_program_and_its_sublayer(name, prog):
         rest = path[1:]
         if rest[:1] == ["mtp"]:
             rest = rest[1:]
+        if rest[1:] == ["mhc"]:
+            # a wrapper's own op, inside its sublayer's group
+            assert name == "latent_moe_streams", (op.type, path)
+            assert op.type in ("mhc_pre", "mhc_post", "unsqueeze", "expand",
+                               "reduce_sum"), (op.type, path)
+            rest = rest[:1]
         assert len(rest) == 1 and rest[0] in decoder.GROUPS, (op.type, path)
-        seen.add("/".join(path[1:]))
+        assert op.type not in ("mhc_pre", "mhc_post") \
+            or path[-1] == "mhc", (op.type, path)
+        seen.add("/".join(p for p in path[1:] if p != "mhc"))
     # the vocabulary is used, not merely allowed
     assert {"embed", "attn", "head"} <= seen
     assert ("mixer" in seen) == name.startswith("hybrid_")
     assert ("experts" in seen) == (name not in ("gen_lm", "hybrid_decoder"))
+    drafting = name in ("window_moe_drafting", "latent_moe_streams")
     assert ("mtp/attn" in seen and "mtp/head" in seen
-            and "mtp/embed" in seen) == (name == "window_moe_drafting")
-    assert not any(s.startswith("mtp/") for s in seen) \
-        or name == "window_moe_drafting"
+            and "mtp/embed" in seen) == drafting
+    assert not any(s.startswith("mtp/") for s in seen) or drafting
 
 
 def test_the_vocabulary_is_the_documented_one():

@@ -1314,3 +1314,88 @@ def test_a_full_layers_chunk_of_128_heads_compiles_for_v5e_in_place(one_chip):
     assert memory.alias_size_in_bytes >= sum(
         int(jnp.prod(jnp.asarray(p.shape))) * 2 for p in pools)
     assert memory.temp_size_in_bytes < 384 << 20, memory
+
+
+@pytest.mark.parametrize("pages", [32, 288])
+def test_latent_decode_of_a_committed_row_and_a_draft_compiles_for_v5e(
+        one_chip, pages):
+    """A latent layer's decode TURN at the drafting latent cell's shapes:
+    32 slots x two query rows, each under its own limit (the draft's row
+    sees the committed one), 2 x 32 absorbed heads side by side against
+    the ONE 640-wide bfloat16 row a token; page_len 64, the smallest and
+    the widest page bucket."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import attention_ops as A
+    S, PL, H, W, V, L = 32, 64, 32, 640, 512, 2
+
+    def fn(q, cache, pt, walk, row_lens):
+        out = A._pallas_latent_rows(q, cache, pt, walk, H, 0.1447, row_lens,
+                                    False, V)
+        assert out is not None, "the gate refused the published widths"
+        return out
+
+    hlo = _compile(fn, one_chip, ((S, L, H * W), jnp.bfloat16),
+                   ((S * 288, PL, W), jnp.bfloat16), ((S, pages), jnp.int32),
+                   ((S, 1), jnp.int32), ((S, L), jnp.int32))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("rows", [64, 1024], ids=["turn", "chunk"])
+def test_a_wrapper_lowers_to_the_sinkhorn_kernel_and_a_few_fusions(one_chip,
+                                                                   rows):
+    """A hyper-connection wrapper at the published widths (4 streams of
+    3584, 20 Sinkhorn rounds) around a sublayer that does nothing: the
+    chain of rounds is ONE Pallas kernel, and what stands around it (the
+    statistic, the product with phi, the aggregate, the distribution) a
+    stated few fusions (13, the kernel, and the two layout copies of
+    this test's own entry and exit): a decode turn's 20 wrappers are
+    under 300 launches, not the 1,600 and more of a round a launch."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mhc_ops
+    n, c = 4, 3584
+
+    def fn(x, phi, alpha, bias):
+        u, post, res = mhc_ops.mhc_pre(x, phi, alpha, bias, 20, 1e-6, -30.0,
+                                       30.0, 1e-6, kernel=True)
+        return mhc_ops.mhc_post(x, u, post, res)
+
+    hlo = _compile(fn, one_chip, ((rows, n, c), jnp.bfloat16),
+                   ((n * c, n * (n + 2)), jnp.float32), ((3,), jnp.float32),
+                   ((n * (n + 2),), jnp.float32))
+    assert hlo.count("tpu_custom_call") >= 1
+    body = hlo[hlo.index("ENTRY"):]
+    launches = sum(body.count(f" {kind}(") for kind in (
+        "fusion", "custom-call", "convolution", "dot", "copy", "transpose"))
+    assert launches <= 16, launches
+    assert " while(" not in body
+
+
+@pytest.mark.parametrize("pages", [32, 288])
+def test_a_latent_chunk_of_32_heads_in_pages_of_64_compiles_for_v5e(one_chip,
+                                                                    pages):
+    """The attention of one 1024-row chunk at the four-stream latent
+    cell's widths (32 heads of 128 | 64, values 128, rows stored 640 wide
+    in pages of 64, 32 slots of 288 pages): the rows into the slot's
+    pages, then the key-offset flash kernel EXPANDED a (head, key block)
+    at a time; the pool donated and aliased, no temporary of its size."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import mla_ops
+    C, S, PL, H = 1024, 32, 64, 32
+
+    def fn(q, row, w_kvb, pool, table, pos, mask):
+        return mla_ops.mla_attention_chunk(
+            q, row, w_kvb, pool, table, pos[0, 0], mask > 0, H, 128, 64,
+            128, 0.1447, interpret=False)
+
+    sds = lambda shape, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    pool = sds((S * 288, PL, 640))
+    compiled = jax.jit(fn, donate_argnums=(3,)).lower(
+        sds((C, H * 192)), sds((C, 640)), sds((512, H * 256)), pool,
+        sds((1, pages), jnp.int32), sds((1, C), jnp.int32),
+        sds((1, C), jnp.float32)).compile()
+    hlo, memory = compiled.as_text(), compiled.memory_analysis()
+    assert "tpu_custom_call" in hlo
+    assert memory.alias_size_in_bytes >= S * 288 * PL * 640 * 2
+    assert memory.temp_size_in_bytes < 320 << 20, memory
